@@ -12,6 +12,11 @@ class EngineConfig:
     model: str = "tiny"
     served_model_name: str | None = None
     backend: str = "tpu"          # "tpu" (JAX) | "sim" (CPU simulator)
+    # Which of this process's devices the engine is bound to (weights, KV
+    # pages, step inputs; the first device of a tp/pp slice). One engine
+    # per chip: several engines in one process take one index each; a
+    # server process that was shown only its own chip keeps 0.
+    device_index: int = 0
     max_batch: int = 8            # decode batch slots
     max_model_len: int = 2048
     hbm_kv_blocks: int = 0        # 0 = derive from max_batch * max_model_len
@@ -91,18 +96,20 @@ class EngineConfig:
     client_insecure_skip_verify: bool = True
     client_ca_cert_path: str = ""
     # Decode steps fused into one device dispatch (lax.scan over the decode
-    # step + sampler on device). Amortizes per-dispatch latency — decisive
-    # when the chip sits behind a network tunnel — at the cost of bursty
-    # token streaming and up-to-(chunk-1) wasted steps for sequences that
-    # hit a stop condition mid-chunk. TTFT is unaffected (prefill emits the
-    # first token). 1 = classic per-step decode.
+    # step + sampler on device). Amortizes per-dispatch latency and the
+    # per-chunk readback at the cost of bursty token streaming and
+    # up-to-(chunk-1) wasted steps for sequences that hit a stop condition
+    # mid-chunk. TTFT is unaffected (prefill emits the first token). 1 =
+    # classic per-step decode. Not yet A/B'd on a chip attached to the host.
     decode_chunk: int = 8
     # Pallas paged-attention decode kernel. None = auto: enabled on a real
     # TPU backend for unsharded engines whose head_dim is lane-aligned
     # (head_dim % 128 == 0 — Mosaic DMA slice constraint); measured 1.76×
     # faster than the XLA gather path at llama3-8b shapes on v5e.
     pallas_attention: bool | None = None
-    pallas_interpret: bool = False  # interpret the kernel (CPU testing only)
+    # Interpret the kernels: for tests on the CPU only. The server CLI has no
+    # flag for it, so a served engine cannot run the interpreter by accident.
+    pallas_interpret: bool = False
     # Pallas grouped-matmul MoE FFN (ops/pallas_moe.py) for n_experts>0
     # models; single-device only (the ep-sharded path stays dense inside its
     # shard_map). Interpreted when pallas_interpret is set.

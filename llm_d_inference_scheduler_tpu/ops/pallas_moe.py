@@ -34,12 +34,37 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def pick_tile_divisor(d_ff: int, tf: int = 512) -> int | None:
-    """Largest lane-aligned (multiple-of-128) tile ≤ tf that divides d_ff;
-    None when no such tile exists (the grouped kernel then can't serve this
-    geometry — single source of truth for callers that gate on it)."""
+# Row tiles the kernel serves with (see moe_ffn_grouped): the bf16 sublane
+# floor for decode-scale token counts, MXU height for prefill-scale ones.
+ROW_TILES = (16, 128)
+
+# What one kernel may keep in VMEM: the TPU compiler's scoped limit. It
+# charges a grid step exactly the terms _vmem_bytes counts (compiled for a
+# described v5e: 15.47 MiB by this count passes, 16.24 MiB is refused with
+# "Scoped allocation with size 16.24M"), so the limit itself is the budget.
+VMEM_BUDGET_BYTES = 16 * 2 ** 20
+
+
+def _vmem_bytes(d_model: int, tf: int, tm: int, itemsize: int) -> int:
+    """VMEM one (row_tile, f_tile) grid step holds: the three weight blocks
+    ([D, tf], [D, tf], [tf, D]) and the x / out row tiles, each
+    double-buffered by the pipeline, plus the f32 accumulator."""
+    weights = 3 * 2 * d_model * tf * itemsize
+    rows = 2 * 2 * tm * d_model * itemsize
+    acc = tm * d_model * 4
+    return weights + rows + acc
+
+
+def pick_ff_tile(d_model: int, d_ff: int, tm: int, itemsize: int,
+                 tf: int = 512) -> int | None:
+    """Largest lane-aligned (multiple-of-128) tile ≤ tf that divides d_ff
+    and whose working set at row tile ``tm`` fits the VMEM budget; None when
+    there is none (the grouped kernel then cannot serve this geometry). The
+    one rule for the kernel's own tiling and for callers that gate on it."""
     candidates = [t for t in range(128, min(tf, d_ff) + 1, 128)
-                  if d_ff % t == 0]
+                  if d_ff % t == 0
+                  and _vmem_bytes(d_model, t, tm, itemsize)
+                  <= VMEM_BUDGET_BYTES]
     return candidates[-1] if candidates else None
 
 
@@ -110,28 +135,29 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
     lp: layer params with router/w1/w3/w2 ([E,D,F]/[E,F,D] stacked experts).
     x: [B, S, D]. Returns [B, S, D] in x.dtype.
 
-    Measured on v5e (d=1024, f=4096): vs the dense-over-experts einsums this
-    wins where routing is sparse relative to the expert count — E=64 prefill
-    1.27× faster, E=8 decode 1.2× — and loses where every expert is hit
-    anyway (E=8 prefill: dense streams all experts once at ~70% MXU). Dense
-    stays the engine default; enable via pallas_moe for fine-grained-expert
-    models. tm=None picks the row tile by shape: 128 (MXU-height) for
-    prefill-scale token counts, 16 (bf16 sublane floor) for decode.
+    Against the dense-over-experts einsums this should win where routing is
+    sparse relative to the expert count and lose where every expert is hit
+    anyway; the round-4 figures that said so (d=1024, f=4096) came from a
+    stack that is gone and are to be re-measured. Dense stays the engine
+    default; enable via pallas_moe for fine-grained-expert models. tm=None
+    picks the row tile by shape: ROW_TILES[1] (MXU height) for prefill-scale
+    token counts, ROW_TILES[0] (bf16 sublane floor) for decode.
     """
     B, S, D = x.shape
     E, k = n_experts, experts_per_token
     T = B * S
     if tm is None:
-        tm = 128 if T * k >= 1024 else 16
+        tm = ROW_TILES[1] if T * k >= 1024 else ROW_TILES[0]
     F = lp["w1"].shape[2]
     # tf must divide F (the grid truncates otherwise — tail columns would be
-    # silently dropped) and be lane-aligned. Pick the largest conforming tile
-    # no bigger than the requested one.
-    chosen = pick_tile_divisor(F, tf)
+    # silently dropped), be lane-aligned, and leave the grid step inside
+    # VMEM. Pick the largest conforming tile no bigger than the requested one.
+    chosen = pick_ff_tile(D, F, tm, jnp.dtype(x.dtype).itemsize, tf)
     if chosen is None:
         raise ValueError(
-            f"d_ff={F} has no 128-aligned tile divisor ≤ {tf}; "
-            "use the dense MoE path for this geometry")
+            f"d_ff={F} has no 128-aligned tile divisor ≤ {tf} that fits "
+            f"VMEM at d_model={D}, row tile {tm}; use the dense MoE path "
+            "for this geometry")
     tf = chosen
     xt = x.reshape(T, D)
 

@@ -346,14 +346,16 @@ class SnapshotPublisher:
         if self._task is not None:
             self._task.cancel()
             self._task = None
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        # Accepted connections first: on Python 3.12 wait_closed() waits for
+        # every one of them, so closing them after it never happens.
         for w in self._writers:
             with contextlib.suppress(Exception):
                 w.close()
         self._writers.clear()
+        if self._server is not None:
+            self._server.close()
+            await self._server.wait_closed()
+            self._server = None
         if self.kv_source is not None:
             self.kv_source.close()
         with contextlib.suppress(OSError):

@@ -1,0 +1,147 @@
+"""Compile the engine's step programs for a described TPU, without the chip.
+
+The TPU compiler is installed beside JAX and compiles for a topology that is
+described and not attached (`jax.experimental.topologies`). This hands it the
+programs a single-device `TpuEngine` serves with — the random-weight init,
+the fused decode chunk, plain prefill buckets and one prefix-prefill bucket —
+at a registered model's full size, and prints for each the compile seconds,
+`memory_analysis()` and whether the Pallas call (`tpu_custom_call`) is in the
+compiled text. What the compiler refuses here (a kernel it cannot lower, a
+program that does not fit the device) it would refuse on the chip.
+
+Nothing runs: no result and no time printed here says anything about the
+device. Each whole-model program takes minutes to compile on a few CPU cores,
+so this is a script and not a tier-1 test (`tests/test_chip_compile.py` keeps
+the kernels alone, a few seconds each).
+
+Usage:
+  JAX_PLATFORMS=cpu python scripts/aot_rehearsal.py [--model qwen3-4b]
+      [--max-batch 16] [--max-model-len 2048] [--decode-batches 1,8]
+      [--prefill 128x1,2048x1] [--prefix 16x128] [--topology v5e:2x2]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--model", default="qwen3-4b")
+    ap.add_argument("--max-batch", type=int, default=16)
+    ap.add_argument("--max-model-len", type=int, default=2048)
+    ap.add_argument("--decode-chunk", type=int, default=8)
+    ap.add_argument("--decode-batches", default="1,8",
+                    help="decode lane buckets to compile")
+    ap.add_argument("--prefill", default="128x1,2048x1",
+                    help="plain prefill programs as BUCKETxROWS")
+    ap.add_argument("--prefix", default="16x128",
+                    help="prefix-prefill programs as SUFFIXxPREFIX_BLOCKS")
+    ap.add_argument("--topology", default="v5e:2x2")
+    ap.add_argument("--skip-init", action="store_true")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without the device; keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+    from llm_d_inference_scheduler_tpu.models import llama
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=args.topology)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    cfg = EngineConfig(model=args.model, max_batch=args.max_batch,
+                       max_model_len=args.max_model_len,
+                       decode_chunk=args.decode_chunk, pallas_attention=True)
+    mcfg = cfg.model_config
+    # The jitted bodies are methods; they read only the two configs and the
+    # (absent) pipeline mesh, so a bare instance carries them — building a
+    # real engine would materialise the weights on the host.
+    eng = object.__new__(TpuEngine)
+    eng.cfg, eng.mcfg, eng.pp_mesh, eng._prefill_fns = cfg, mcfg, None, {}
+
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    params = on_chip(jax.eval_shape(
+        lambda k: llama.init_params(mcfg, k), jax.random.key(0)))
+    n_blocks = cfg.num_kv_blocks()
+    width = -(-cfg.max_model_len // mcfg.kv_block_size)
+    pages = sds((mcfg.n_layers, n_blocks, mcfg.kv_block_size,
+                 mcfg.n_kv_heads, mcfg.head_dim), jnp.dtype(mcfg.dtype))
+
+    def sampling(rows):
+        return (key, sds((rows,), jnp.float32), sds((rows,), jnp.int32),
+                sds((rows,), jnp.float32))
+
+    programs = []
+    if not args.skip_init:
+        programs.append(("init", jax.jit(
+            lambda k: llama.init_params(mcfg, k), out_shardings=one_chip),
+            (key,)))
+    for b in [int(x) for x in args.decode_batches.split(",") if x]:
+        programs.append((f"decode {b}x{width}", jax.jit(
+            eng._decode_chunk_impl, donate_argnums=(3, 4)),
+            (params, sds((b,), jnp.int32), sds((b,), jnp.int32), pages, pages,
+             sds((b, width), jnp.int32), *sampling(b))))
+    for spec in [s for s in args.prefill.split(",") if s]:
+        bucket, rows = (int(x) for x in spec.split("x"))
+        programs.append((f"prefill {rows}x{bucket}", eng._prefill_fn(bucket),
+                         (params, sds((rows, bucket), jnp.int32),
+                          sds((rows,), jnp.int32), pages, pages,
+                          sds((rows, width), jnp.int32), *sampling(rows))))
+    for spec in [s for s in args.prefix.split(",") if s]:
+        suffix, prefix_blocks = (int(x) for x in spec.split("x"))
+        programs.append((f"prefix_prefill {suffix}x{prefix_blocks}",
+                         eng._prefix_prefill_fn(suffix, prefix_blocks),
+                         (params, sds((1, suffix), jnp.int32),
+                          sds((1,), jnp.int32), sds((1,), jnp.int32),
+                          pages, pages, sds((1, width), jnp.int32),
+                          sds((1, prefix_blocks), jnp.int32), *sampling(1))))
+
+    ok = True
+    for name, fn, fn_args in programs:
+        t0 = time.monotonic()
+        try:
+            compiled = fn.lower(*fn_args).compile()
+        except Exception as e:  # the compiler's refusal is the finding
+            ok = False
+            print(json.dumps({"program": name, "compiled": False,
+                              "error": str(e)[:2000]}), flush=True)
+            continue
+        mem = compiled.memory_analysis()
+        print(json.dumps({
+            "program": name, "compiled": True,
+            "compile_s_on_this_host": round(time.monotonic() - t0, 1),
+            "tpu_custom_call": "tpu_custom_call" in compiled.as_text(),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+        }), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -23,6 +23,7 @@ Run via ``make verify-slo``; tests/test_slo.py hooks it into the pytest run.
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 
@@ -157,18 +158,27 @@ async def _drive() -> list[str]:
             # 5. abort — client walks away mid-stream; the ledger must still
             # close the record (slo_met=false, not an absent row).
             rid = "verify-slo-abort"
-            try:
-                async with c.stream(
-                        "POST", f"http://127.0.0.1:{GW}/v1/completions",
-                        json={"model": "tiny", "prompt": "ok",
-                              "max_tokens": 256, "stream": True},
-                        headers={"x-request-id": rid,
-                                 "x-gateway-destination-endpoint-subset":
-                                     f"127.0.0.1:{ENG}"}) as resp:
-                    async for _ in resp.aiter_bytes():
-                        break  # first chunk, then hang up
-            except (httpx.HTTPError, RuntimeError):
-                pass
+            # On a bare socket, so that hanging up is a close() and nothing
+            # else: httpx's early exit from a streamed response can fail
+            # inside anyio (seen once torch is imported in the process) and
+            # leave the connection open — the stream then ends normally and
+            # nothing was aborted.
+            body = json.dumps({"model": "tiny", "prompt": "ok",
+                               "max_tokens": 256, "stream": True}).encode()
+            reader, writer = await asyncio.open_connection("127.0.0.1", GW)
+            writer.write(
+                b"POST /v1/completions HTTP/1.1\r\nhost: verify\r\n"
+                b"content-type: application/json\r\n"
+                + f"x-request-id: {rid}\r\n".encode()
+                + b"x-gateway-destination-endpoint-subset: "
+                + f"127.0.0.1:{ENG}\r\n".encode()
+                + f"content-length: {len(body)}\r\n\r\n".encode() + body)
+            await writer.drain()
+            while True:  # up to the first chunk, then hang up
+                chunk = await reader.read(4096)
+                if not chunk or b"data:" in chunk:
+                    break
+            writer.close()
             # Give the gateway a few relay ticks to notice the disconnect.
             outcome = None
             for _ in range(100):

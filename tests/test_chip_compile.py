@@ -1,0 +1,113 @@
+"""The main path's kernels, handed to the TPU compiler at real widths.
+
+Interpret mode cannot show what Mosaic refuses: a DMA slice off the tiling,
+more VMEM than a kernel may hold. The TPU compiler is installed beside JAX
+and compiles for a chip that is described and not attached, so each kernel is
+lowered here for one v5e chip at the shapes the engine serves it with. Nothing
+runs — a compile that passes says nothing about results or speed. The whole
+Qwen3-4B step programs take minutes each and live in
+scripts/aot_rehearsal.py.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from llm_d_inference_scheduler_tpu.models.configs import MIXTRAL_8X7B, QWEN3_4B
+from llm_d_inference_scheduler_tpu.ops import pallas_moe
+from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
+    paged_decode_attention_pallas,
+)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without the device (the next run would warn
+    # and compile again): keep these out of it.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("batch,table_width", [(16, 128), (64, 32)])
+def test_paged_attention_compiles_at_qwen3_4b(one_chip, batch, table_width):
+    """32 Q / 8 KV heads of 128, bf16 pages of 16 tokens; the cache of
+    max_batch 16 x 2048 tokens (2,049 pages), at decode batch 16 (table 128
+    wide) and 64 (table 32 wide)."""
+    m = QWEN3_4B
+    dt = jnp.dtype(m.dtype)
+    pages = _sds(one_chip, (2049, m.kv_block_size, m.n_kv_heads, m.head_dim),
+                 dt)
+    cur = _sds(one_chip, (batch, m.n_kv_heads, m.head_dim), dt)
+    compiled = paged_decode_attention_pallas.lower(
+        _sds(one_chip, (batch, m.n_heads, m.head_dim), dt), pages, pages,
+        _sds(one_chip, (batch, table_width), jnp.int32),
+        _sds(one_chip, (batch,), jnp.int32), cur, cur).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("d_model,d_ff,n_experts,tm", [
+    # Mixtral-8x7B, the one MoE model registered, at both row tiles: three
+    # double-buffered [4096, 512] bf16 blocks were 24 MiB of a 16 MiB limit.
+    (MIXTRAL_8X7B.d_model, MIXTRAL_8X7B.d_ff, MIXTRAL_8X7B.n_experts, 16),
+    (MIXTRAL_8X7B.d_model, MIXTRAL_8X7B.d_ff, MIXTRAL_8X7B.n_experts, 128),
+    # Many small experts (the OLMoE-like shape ROADMAP R2 plans).
+    (2048, 1024, 64, 16),
+    (2048, 1024, 64, 128),
+])
+def test_grouped_moe_compiles(one_chip, d_model, d_ff, n_experts, tm):
+    tf = pallas_moe.pick_ff_tile(d_model, d_ff, tm, 2)
+    assert tf is not None
+    rows = 4 * tm
+    compiled = pallas_moe._grouped_ffn_call.lower(
+        _sds(one_chip, (rows, d_model), jnp.bfloat16),
+        _sds(one_chip, (rows // tm,), jnp.int32),
+        _sds(one_chip, (n_experts, d_model, d_ff), jnp.bfloat16),
+        _sds(one_chip, (n_experts, d_model, d_ff), jnp.bfloat16),
+        _sds(one_chip, (n_experts, d_ff, d_model), jnp.bfloat16),
+        tm=tm, tf=tf).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_moe_tile_rule_is_the_engines_gate(monkeypatch):
+    """One rule: what pick_ff_tile refuses, the engine refuses at start-up
+    (and not the compiler at the first request)."""
+    from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+    from llm_d_inference_scheduler_tpu.models import configs
+
+    # No 128-multiple divides 200; and no tile of any width fits beside
+    # d_model 16384's row tiles.
+    assert pallas_moe.pick_ff_tile(128, 200, 16, 2) is None
+    assert pallas_moe.pick_ff_tile(16384, 14336, 128, 2) is None
+    # Small widths keep the tile they always had (interpret-mode results
+    # must not move): the largest 128-multiple divisor up to 512.
+    assert pallas_moe.pick_ff_tile(128, 256, 16, 4) == 256
+    assert pallas_moe.pick_ff_tile(128, 1024, 128, 4) == 512
+
+    bad = configs.ModelConfig(**{**configs.TINY_MOE.__dict__,
+                                 "name": "tiny-moe-200", "d_ff": 200})
+    monkeypatch.setitem(configs._REGISTRY, bad.name, bad)
+    with pytest.raises(ValueError, match="pallas_moe"):
+        TpuEngine(EngineConfig(model=bad.name, pallas_moe=True,
+                               pallas_interpret=True, kv_events_port=0))

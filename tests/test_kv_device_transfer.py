@@ -27,7 +27,7 @@ def _device_transfer_available() -> bool:
             _get_transfer_server,
         )
 
-        _get_transfer_server()
+        _get_transfer_server("127.0.0.1")
         return True
     except Exception:
         return False

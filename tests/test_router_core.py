@@ -413,7 +413,9 @@ def test_decode_batch_bucket():
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
 
     eng = TpuEngine(EngineConfig(model="tiny", max_batch=8, kv_events_port=0))
-    assert eng._batch_bucket(1) == 1
+    # Never one lane: a one-row page scatter becomes a dynamic-update-slice
+    # that re-lays-out both page buffers (see TpuEngine._batch_bucket).
+    assert eng._batch_bucket(1) == 2
     assert eng._batch_bucket(2) == 2
     assert eng._batch_bucket(3) == 4
     assert eng._batch_bucket(5) == 8

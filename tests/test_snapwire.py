@@ -401,6 +401,9 @@ def test_subscriber_survives_corrupt_frame_mid_stream(tmp_path):
             assert len(conns) == 1
         finally:
             await sub.stop()
+            # Python 3.12's wait_closed() waits for accepted connections.
+            for w in conns:
+                w.close()
             server.close()
             await server.wait_closed()
 
