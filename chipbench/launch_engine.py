@@ -1,22 +1,19 @@
 """Start the program's engine server on a benchmark configuration.
 
     python chipbench/launch_engine.py --config chipbench/configs/<name>.json \
-        --weights-seed N [--trace-dir DIR] -- <engine.server arguments>
+        --weights-seed N -- <engine.server arguments>
 
-The engine CLI can serve only models its registry names, takes no seed and
-has no profiler hook, and the benchmark may not edit the program. So this
-launcher, in the engine's own process and before the CLI runs:
+The engine CLI can serve only models its registry names and takes no seed.
+So this launcher, in the engine's own process and before the CLI runs:
 
 - maps the configuration file's published keys to a `ModelConfig` with the
   program's own `config_from_hf`, and registers it under the name served;
-- gives `EngineConfig` the weights' seed (the CLI builds it without one);
-- with --trace-dir, starts a thread that starts `jax.profiler` when the file
-  `<dir>/start` appears and stops it when `<dir>/stop` does, then writes
-  `<dir>/done`: only the process that holds the chip can trace it.
+- gives `EngineConfig` the weights' seed (the CLI builds it without one).
 
 Then it calls `engine.server.main(argv)`, the normal entry point: device
-narrowing, the compile cache, the refusal of an unasked CPU and the serving
-loop are all as a user gets them.
+narrowing, the compile cache, the refusal of an unasked CPU, the serving
+loop and the profiler's control (`--profile-dir`, which run.py passes for a
+traced run) are all as a user gets them.
 """
 
 from __future__ import annotations
@@ -26,8 +23,6 @@ import functools
 import json
 import os
 import sys
-import threading
-import time
 import types
 
 # Keys of a published config.json that say nothing the model code reads.
@@ -47,35 +42,10 @@ def model_config_from_file(path: str):
                           name=doc["serve"]["model_name"])
 
 
-def _trace_on_request(trace_dir: str) -> None:
-    """Runs in a daemon thread of the engine process."""
-    import jax
-
-    start, stop = (os.path.join(trace_dir, n) for n in ("start", "stop"))
-    while not os.path.exists(start):
-        time.sleep(0.02)
-    options = jax.profiler.ProfileOptions()
-    # Device and runtime events only: tracing every Python call would slow
-    # the engine's host loop, which is part of what is measured.
-    options.python_tracer_level = 0
-    options.host_tracer_level = 1
-    t_start = time.time()
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
-    t0 = time.time()
-    while not os.path.exists(stop):
-        time.sleep(0.02)
-    t1 = time.time()
-    jax.profiler.stop_trace()
-    with open(os.path.join(trace_dir, "done"), "w") as f:
-        json.dump({"traced_s": t1 - t0, "start_trace_s": t0 - t_start,
-                   "stop_trace_s": time.time() - t1}, f)
-
-
 def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
     ap.add_argument("--weights-seed", type=int, default=0)
-    ap.add_argument("--trace-dir", default=None)
     ap.add_argument("engine_argv", nargs=argparse.REMAINDER)
     args = ap.parse_args(argv)
     engine_argv = [a for a in args.engine_argv if a != "--"]
@@ -89,10 +59,6 @@ def main(argv: list[str] | None = None) -> None:
     # 32 signed bits.
     server.EngineConfig = functools.partial(
         server.EngineConfig, seed=args.weights_seed % (2 ** 31))
-    if args.trace_dir:
-        os.makedirs(args.trace_dir, exist_ok=True)
-        threading.Thread(target=_trace_on_request, args=(args.trace_dir,),
-                         daemon=True).start()
     server.main(engine_argv)
 
 

@@ -1,6 +1,6 @@
 """Run one cell of the benchmark once.
 
-    python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 A new process per run: it starts the cell's engine server(s) and gateway as
 their own processes, warms the cell's shapes, measures for --seconds, checks
@@ -16,6 +16,16 @@ How a cell's files are found (PERF.md has the same list): BENCHMARK.json's
 `chipbench/gateways/`); `chipbench/traffic/<traffic>.json` holds the mix;
 each per-layer metric is `chipbench/layer_metrics/<name>.json`, read by
 `chipbench/readers/<kind>.py`.
+
+`--trace 0` measures with the profiler off and prints the end-to-end metrics.
+`--trace 2` does exactly that and then, on the servers still warm, sends a
+short tail of the same traffic whose last part is traced (the engine server's
+`POST /debug/profile/start|stop`, which exist only under its `--profile-dir`):
+its line holds the end-to-end metrics of the measured window and the
+per-layer metrics side by side, those a trace gives read over the tail and
+all others over the measured window. `--trace 1` is the older form, a run
+of its own whose window's last part is traced and whose line holds the
+per-layer metrics only.
 
 Other modes, not part of a check: `--sweep r1,r2,..` runs one window per rate
 in one process (finding the knee); `--platform cpu` rehearses the phases on
@@ -52,6 +62,8 @@ from procs import BenchFailure, Procs, http_get, wait_healthy  # noqa: E402
 
 BASE_PORT = 18800
 GAUGE_HZ = 5.0
+# A traced slice is at most this share of its window, at the window's end.
+TRACED_SHARE = 0.4
 
 
 def say(**fact) -> None:
@@ -80,6 +92,8 @@ def load_cell(bench_path: str, workload: str) -> dict:
             "mix": traffic.load_mix(traffic.mix_path(ROOT, cell["traffic"])),
             "end_to_end": for_cell(bench["end_to_end"]),
             "per_layer": for_cell(bench["per_layer"]),
+            "from_trace": {m["name"] for m in bench["per_layer"]
+                           if m["source"] == "device_trace"},
             "units": {m["name"]: m["unit"]
                       for m in bench["end_to_end"] + bench["per_layer"]}}
 
@@ -111,11 +125,11 @@ class Servers:
             argv = [os.path.join(HERE, "launch_engine.py"), "--config",
                     os.path.join(ROOT, self.spec["config_file"]),
                     "--weights-seed", str(self.seed)]
-            if self.trace_dirs:
-                shutil.rmtree(self.trace_dirs[i], ignore_errors=True)
-                argv += ["--trace-dir", self.trace_dirs[i]]
             argv += ["--", "--backend", "tpu", "--model", self.model_name,
                      "--port", str(port), *self.serve["engine_args"]]
+            if self.trace_dirs:
+                shutil.rmtree(self.trace_dirs[i], ignore_errors=True)
+                argv += ["--profile-dir", self.trace_dirs[i]]
             if self.platform:
                 argv += ["--platform", self.platform]
             if self.n > 1:
@@ -141,6 +155,18 @@ class Servers:
     def healths(self) -> list[dict]:
         return [json.loads(http_get(u + "/health", 10.0)[1])
                 for u in self.engine_urls]
+
+    async def profiler(self, http: httpx.AsyncClient, verb: str) -> list[dict]:
+        """`start` or `stop` on every replica at once; each one's answer.
+        A stop answers when the trace file is written, seconds later."""
+        answers = await asyncio.gather(*[
+            http.post(f"{u}/debug/profile/{verb}", timeout=180.0)
+            for u in self.engine_urls])
+        bad = [a for a in answers if a.status_code != 200]
+        if bad:
+            raise BenchFailure(f"profiler {verb}: {bad[0].status_code} "
+                               f"{bad[0].text[:300]}")
+        return [a.json() for a in answers]
 
 
 def device_of(healths: list[dict]) -> dict:
@@ -212,7 +238,8 @@ async def window(srv: Servers, plan: traffic.Plan, seconds: float,
                  trace_spec: dict | None, ramp_s: float = 0.0) -> dict:
     """One measured window. Scrapes at its start and end, gauges at 5 Hz in
     between, and with trace_spec a profiler slice inside it."""
-    side: dict = {"gauges": [], "trace_span": None}
+    side: dict = {"seconds": seconds, "gauges": [], "trace_span": None,
+                  "profiler": []}
 
     async def scrape(http, urls):
         texts = await asyncio.gather(*[http.get(u + "/metrics", timeout=10.0)
@@ -220,16 +247,12 @@ async def window(srv: Servers, plan: traffic.Plan, seconds: float,
         return [prom.parse(t.text) for t in texts]
 
     async def on_start(t0: float):
-        def touch(dirs, name):
-            for d in dirs:
-                with open(os.path.join(d, name), "w"):
-                    pass
-
         # The slice is the window's last part: the profiler writes its file
         # when it stops, which takes seconds and can stall the engine's host
         # loop; so the counters are read at the window's end BEFORE the stop,
         # and the stall falls into the drain.
-        trace_len = min(trace_spec["seconds"], 0.4 * seconds) if trace_spec else 0
+        trace_len = (min(trace_spec["seconds"], TRACED_SHARE * seconds)
+                     if trace_spec else 0)
         trace_at = seconds - trace_len if trace_spec else None
         async with httpx.AsyncClient() as http:
             await asyncio.sleep(max(0.0, t0 - time.monotonic()))
@@ -242,8 +265,8 @@ async def window(srv: Servers, plan: traffic.Plan, seconds: float,
                     break
                 if trace_at is not None and side["trace_span"] is None \
                         and now >= trace_at:
-                    touch(srv.trace_dirs, "start")
-                    side["trace_span"] = [now, None]
+                    await srv.profiler(http, "start")
+                    side["trace_span"] = [time.monotonic() - t0, None]
                 side["gauges"].append((now, await scrape(http, srv.engine_urls)))
                 tick += 1
                 await asyncio.sleep(max(0.0, t0 + tick / GAUGE_HZ
@@ -252,7 +275,7 @@ async def window(srv: Servers, plan: traffic.Plan, seconds: float,
             side["gateway_after"] = (await scrape(http, [srv.gateway_url]))[0]
             if side["trace_span"]:
                 side["trace_span"][1] = time.monotonic() - t0
-                touch(srv.trace_dirs, "stop")
+                side["profiler"] = await srv.profiler(http, "stop")
 
     records, t0 = await client.run_window(
         srv.gateway_url, srv.model_name, plan.chains, plan.temperature,
@@ -262,25 +285,36 @@ async def window(srv: Servers, plan: traffic.Plan, seconds: float,
     return side
 
 
-def wait_for_traces(trace_dirs: list[str], timeout_s: float = 120.0) -> None:
-    """The engine writes `done` once the profiler has written its file; a
-    server stopped before that leaves no trace."""
-    deadline = time.monotonic() + timeout_s
-    while not all(os.path.exists(os.path.join(d, "done")) for d in trace_dirs):
-        if time.monotonic() > deadline:
-            raise BenchFailure(f"no finished trace under {trace_dirs}")
-        time.sleep(0.2)
+async def traced_tail(srv: Servers, mix: dict, seed: int) -> dict:
+    """--trace 2, after the measured window is closed: the profiler is
+    started and stopped once for nothing (the first start costs most, and
+    should fall into no number), then a short window of the same traffic
+    with the mix's own ramp, just long enough that the mix's slice is its
+    last part, is traced. Returns that window's side."""
+    async with httpx.AsyncClient() as http:
+        await srv.profiler(http, "start")
+        first = await srv.profiler(http, "stop")
+    for d in srv.trace_dirs:
+        shutil.rmtree(os.path.join(d, "plugins"), ignore_errors=True)
+    say(profiler_first_start_and_stop=first)
+    tail_s = mix["trace"]["seconds"] / TRACED_SHARE
+    plan = traffic.build(mix, seed, tail_s, tag="t")
+    return await window(srv, plan, tail_s, mix["trace"],
+                        ramp_s=mix.get("ramp_s", 0.0))
 
 
-def run_trace_reduce(trace_dirs: list[str]) -> list[dict]:
+def run_trace_reduce(trace_dirs: list[str], profiler: list[dict]) -> list[dict]:
+    """trace_reduce.py on each replica's directory, as a program pinned to
+    the CPU; each result with what that replica's profiler said of its stop."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     done = subprocess.run(
         [sys.executable, os.path.join(HERE, "trace_reduce.py"), *trace_dirs],
         env=env, capture_output=True, text=True, timeout=240)
     if done.returncode != 0:
         raise BenchFailure(f"trace reduction failed: {done.stderr[-1500:]}")
-    return [json.loads(line) for line in done.stdout.splitlines()
-            if line.startswith("{")]
+    traces = [json.loads(line) for line in done.stdout.splitlines()
+              if line.startswith("{")]
+    return [dict(t, **said) for t, said in zip(traces, profiler)]
 
 
 def breakdown_of(traces: list[dict]) -> dict | None:
@@ -308,7 +342,7 @@ def breakdown_of(traces: list[dict]) -> dict | None:
 def run(args) -> dict:
     spec = load_cell(args.bench, args.workload)
     mix, cell = spec["mix"], spec["cell"]
-    mode = "sweep" if args.sweep else "trace" if args.trace else "run"
+    mode = "sweep" if args.sweep else ("run", "trace", "trace2")[args.trace]
     out_dir = os.path.join(ROOT, "chiprun_out", "chipbench", args.workload, mode)
     srv = Servers(spec, args.seed, args.platform, bool(args.trace), out_dir)
     try:
@@ -341,11 +375,13 @@ def run(args) -> dict:
             return sweep(args, srv, spec, device)
 
         side = asyncio.run(window(
-            srv, plan, args.seconds, mix.get("trace") if args.trace else None,
+            srv, plan, args.seconds, mix.get("trace") if args.trace == 1 else None,
             ramp_s=mix.get("ramp_s", 0.0)))
         after = asyncio.run(probe(srv, args.seed))
+        # Up to here --trace 2 has done what --trace 0 does, and nothing else.
+        tail = (asyncio.run(traced_tail(srv, mix, args.seed))
+                if args.trace == 2 else None)
         device = device_of(srv.healths())
-        wait_for_traces(srv.trace_dirs)
     finally:
         stopped = srv.procs.stop()
 
@@ -370,30 +406,57 @@ def run(args) -> dict:
     if not args.trace:
         names, values = spec["end_to_end"], e2e
     else:
-        traces = (run_trace_reduce(srv.trace_dirs)
-                  if device["platform"] == "tpu" else [])
-        ctx = layer.Context(
-            records=records, seconds=args.seconds, chips=cell["chips"],
-            engine_scrapes=list(zip(side["engines_before"], side["engines_after"])),
-            gateway_scrape=(side["gateway_before"], side["gateway_after"]),
-            gauge_samples=side["gauges"], traces=traces,
-            trace_span=tuple(side["trace_span"]) if side["trace_span"] else None,
-            model=spec["model"], device_kind=device["kind"])
-        names = spec["per_layer"]
-        values = {n: layer.read_metric(n, ctx) for n in names}
+        def context(side: dict, traces: list) -> layer.Context:
+            return layer.Context(
+                records=side["records"], seconds=side["seconds"],
+                chips=cell["chips"],
+                engine_scrapes=list(zip(side["engines_before"],
+                                        side["engines_after"])),
+                gateway_scrape=(side["gateway_before"], side["gateway_after"]),
+                gauge_samples=side["gauges"], traces=traces,
+                trace_span=tuple(side["trace_span"]) if side["trace_span"] else None,
+                model=spec["model"], device_kind=device["kind"])
+
+        traced = tail or side
+        traces = run_trace_reduce(srv.trace_dirs, traced["profiler"])
+        if device["platform"] == "tpu" and not all(t["devices"] for t in traces):
+            raise BenchFailure(f"a replica's trace has no device plane: {traces}")
+        traced_ctx = context(traced, traces)
+        names, values = spec["per_layer"], {}
+        if tail:
+            # What a trace gives is read over the traced tail; counters,
+            # histograms and client records over the measured window, where
+            # nothing was traced and there are 51 s of samples.
+            window_ctx = context(side, [])
+            values = dict(e2e)
+            names = spec["end_to_end"] + names
+            say(tail=stats.generator_report(tail["records"], tail["seconds"]),
+                tail_failed=sum(not r.ok for r in tail["records"]),
+                counted_over_the_traced_tail={
+                    n: layer.read_metric(n, traced_ctx)
+                    for n in spec["per_layer"] if n not in spec["from_trace"]})
+        else:
+            window_ctx = traced_ctx
+            say(end_to_end_in_traced_run=e2e)
+        for n in spec["per_layer"]:
+            values[n] = layer.read_metric(
+                n, traced_ctx if n in spec["from_trace"] else window_ctx)
         devices = [d for t in traces for d in t.get("devices", [])]
         if devices:
             line["device"]["busy_s"] = sum(d["busy_s"] for d in devices) / len(devices)
             line["device"]["window_s"] = sum(d["window_s"] for d in devices) / len(devices)
             line["breakdown"] = breakdown_of(traces)
-        say(layer_notes=ctx.notes, trace_span_s=side["trace_span"],
-            end_to_end_in_traced_run=e2e,
+        say(layer_notes={**window_ctx.notes, **traced_ctx.notes},
+            trace_span_s=traced["trace_span"],
             trace_files=[{k: t.get(k) for k in ("dir", "bytes", "traced_s", "start_trace_s",
                                               "stop_trace_s", "error")}
                          for t in traces])
         if args.dump_trace:
             with open(os.path.join(out_dir, "trace_reduced.json"), "w") as f:
                 json.dump(traces, f)
+        else:
+            for d in srv.trace_dirs:
+                shutil.rmtree(d, ignore_errors=True)
     missing = [n for n in names if values.get(n) is None]
     if missing and not (args.trace and device["platform"] != "tpu"):
         say(note="metrics with nothing to read, left out", metrics=missing)
@@ -443,7 +506,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--sweep", default="",
                     help="comma-separated rates (clients, for a closed loop): "
                          "one window each, a table, no contract line")
@@ -451,7 +514,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="'cpu' rehearses the phases on the CPU")
     ap.add_argument("--bench", default=os.path.join(ROOT, "BENCHMARK.json"))
     ap.add_argument("--dump-trace", action="store_true",
-                    help="keep the reduced trace under chiprun_out/")
+                    help="keep the trace and its reduction under chiprun_out/")
     args = ap.parse_args(argv)
     # Killed from outside, the run still stops its children (run()'s finally).
     signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))

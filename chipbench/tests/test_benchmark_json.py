@@ -20,8 +20,11 @@ def bench():
 
 
 def test_keys_names_units(bench):
-    assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+    assert set(bench) - {"trace_in_run"} == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"}
+    # With the key, a check traces inside its measuring runs (--trace 2).
+    assert bench.get("trace_in_run", True) is True
     assert 1 <= bench["run_seconds"] <= 51 and isinstance(bench["run_seconds"], int)
     names = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer")
              for x in bench[k]]

@@ -27,6 +27,13 @@ def run_py(root, *args, timeout=240, **env):
     return done.returncode, lines
 
 
+def end_to_end_names(root, workload):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    return {m["name"] for m in bench["end_to_end"]
+            if "workloads" not in m or workload in m["workloads"]}
+
+
 def no_leftovers():
     out = subprocess.run(["pgrep", "-f", "chipbench/launch_engine[.]py"],
                          capture_output=True, text=True).stdout
@@ -69,7 +76,8 @@ def test_added_cells_are_new_files_only(copy):
 
 @pytest.mark.parametrize("workload, trace", [
     ("tiny.tiny-chat", "0"), ("tiny.tiny-batch", "1"),
-    ("tiny-x2.tiny-sessions", "1")])
+    ("tiny-x2.tiny-sessions", "1"), ("tiny.tiny-chat", "2"),
+    ("tiny-x2.tiny-sessions", "2")])
 def test_rehearsal_on_the_cpu_named_as_such(copy, workload, trace):
     rc, lines = run_py(copy, "--workload", workload, "--seed", str(2 ** 31 + 7),
                        "--seconds", "4", "--trace", trace, "--platform", "cpu")
@@ -81,12 +89,29 @@ def test_rehearsal_on_the_cpu_named_as_such(copy, workload, trace):
     assert last["correct"] and last["failed"] == 0 and last["attempted"] > 0
     names = set(last["metrics"])
     assert not names & {"device_idle_share", "paged_attention_roofline"}
-    if trace == "0":
+    if trace in "02":
         assert "setup_s" in names and "tpot_p95_ms" in names
-        assert all(m["value"] > 0 for m in last["metrics"].values())
-    else:
-        assert "decode_chunk_ms" in names
-    if "sessions" in workload and trace == "1":
+        assert all(last["metrics"][n]["value"] > 0 for n in end_to_end_names(copy, workload))
+    if trace in "12":
+        assert {"decode_chunk_ms", "eng_loop_host_pct"} <= names
+        assert 0 < last["metrics"]["eng_loop_host_pct"]["value"] <= 100
+    if trace == "1":
+        assert not names & end_to_end_names(copy, workload)
+    if trace == "2":
+        # Both kinds side by side, and every end-to-end key of a --trace 0 line.
+        assert end_to_end_names(copy, workload) <= names
+        tail = [json.loads(ln) for ln in lines if '"tail_failed"' in ln][0]
+        assert tail["tail_failed"] == 0 and tail["tail"]["requests_in_window"] > 0
+        assert "decode_chunk_ms" in tail["counted_over_the_traced_tail"]
+        files = [json.loads(ln) for ln in lines if '"trace_files"' in ln][0]
+        assert all(f["bytes"] > 0 and f["traced_s"] > 0 for f in files["trace_files"])
+        # The trace is deleted once it is reduced.
+        assert not any(os.path.exists(f["dir"]) for f in files["trace_files"])
+    if "chat" in workload and trace == "2":
+        m = last["metrics"]
+        assert m["eng_queue_ms"]["value"] > 0 and m["xla_builds_in_window"]["value"] == 0
+        assert m["gw_queue_ms"]["value"] == 0 and m["gw_sched_ms"]["value"] > 0
+    if "sessions" in workload and trace in "12":
         assert 0 <= last["metrics"]["gw_prefix_route_share"]["value"] <= 100
         assert 0 < last["metrics"]["eng_cached_token_share"]["value"] <= 100
     generator = [json.loads(ln) for ln in lines if '"generator"' in ln][0]

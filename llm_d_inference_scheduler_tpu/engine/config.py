@@ -167,6 +167,10 @@ class EngineConfig:
     # Empty falls back to the ENGINE_CHAOS env var (same grammar).
     chaos: str = ""
     chaos_seed: int = 0
+    # Where POST /debug/profile/start writes a jax.profiler trace of this
+    # process; empty = the two endpoints do not exist (engine/server.py
+    # ProfileControl, docs/observability.md).
+    profile_dir: str = ""
 
     def resolved_kv_events_port(self) -> int:
         return self.port + 1000 if self.kv_events_port == -1 else self.kv_events_port

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import dataclasses
 import json
 import logging
@@ -100,6 +101,13 @@ def _get_transfer_server(host: str):
             _TRANSFER_SERVER = jax_transfer.start_transfer_server(
                 jax.local_devices()[0].client, "[::]:0", [f"{host}:0"])
         return _TRANSFER_SERVER
+
+
+def _named(fn, name: str):
+    """Give a step function the name its program carries in a profiler trace
+    (`jit_<name>`), so a reduction finds a prefill after any refactor."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 @dataclasses.dataclass
@@ -220,6 +228,7 @@ class TpuEngine:
                           if cfg.enable_prefix_caching
                           else BlockAllocator(self.n_blocks, block))
         self.telemetry = EngineTelemetry(block_size=block, num_blocks=self.n_blocks)
+        self.telemetry.watch_xla_builds()
 
         # Optional TP-sharded serving: params follow Megatron TP pspecs, KV
         # pages shard the kv-head axis (parallel/serve.py). tp_size=1 keeps
@@ -465,10 +474,10 @@ class TpuEngine:
         else:
             self._jit_decode_chunk = jax.jit(self._decode_chunk_impl,
                                              donate_argnums=(3, 4))
-        self._jit_import = jax.jit(
-            lambda kp, vp, blocks, k_new, v_new: (
-                kp.at[:, blocks].set(k_new), vp.at[:, blocks].set(v_new)),
-            donate_argnums=(0, 1))
+        def kv_import(kp, vp, blocks, k_new, v_new):
+            return kp.at[:, blocks].set(k_new), vp.at[:, blocks].set(v_new)
+
+        self._jit_import = jax.jit(kv_import, donate_argnums=(0, 1))
         log.info("engine %s up: %s", self.engine_id,
                  json.dumps(self.describe()))
 
@@ -569,7 +578,8 @@ class TpuEngine:
                     logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]  # [1, V]
                 tok = sample_tokens(last, key, temps, top_k, top_p)
                 return tok, k_pages, v_pages
-            self._prefill_fns[bucket] = jax.jit(impl, donate_argnums=(3, 4))
+            self._prefill_fns[bucket] = jax.jit(
+                _named(impl, f"prefill_b{bucket}"), donate_argnums=(3, 4))
         return self._prefill_fns[bucket]
 
     def _mm_prefill_fn(self, bucket: int, mm_bucket: int):
@@ -595,7 +605,9 @@ class TpuEngine:
                     logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]
                 tok = sample_tokens(last, rng, temps, top_k, top_p)
                 return tok, k_pages, v_pages
-            self._prefill_fns[key] = jax.jit(impl, donate_argnums=(5, 6))
+            self._prefill_fns[key] = jax.jit(
+                _named(impl, f"mm_prefill_b{bucket}_m{mm_bucket}"),
+                donate_argnums=(5, 6))
         return self._prefill_fns[key]
 
     def _prefix_prefill_fn(self, suffix_bucket: int, prefix_bucket: int):
@@ -616,7 +628,9 @@ class TpuEngine:
                     k_pages, v_pages, block_table_row, prior_table_row)
                 tok = sample_tokens(logits, rng, temps, top_k, top_p)
                 return tok, k_pages, v_pages
-            self._prefill_fns[key] = jax.jit(impl, donate_argnums=(4, 5))
+            self._prefill_fns[key] = jax.jit(
+                _named(impl, f"prefix_prefill_s{suffix_bucket}_p{prefix_bucket}"),
+                donate_argnums=(4, 5))
         return self._prefill_fns[key]
 
     # ---- public API (event-loop side) ---------------------------------
@@ -841,6 +855,7 @@ class TpuEngine:
                         pooled = (hidden * mask).sum(axis=1) / seq_len[0]
                         return pooled[0]
 
+                    _named(impl, f"embed_b{bucket}")
                     if self._dist:
                         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -917,6 +932,19 @@ class TpuEngine:
         log.info("engine warm-up compiled prefill/decode/sample in %.1fs",
                  time.monotonic() - t0)
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One phase of the engine loop, both ways at once so the two cannot
+        drift: a host span `engine.<name>` in the profiler's trace (inert
+        while no trace runs) and the same interval added to
+        jetstream:engine_loop_seconds_total{phase}. Phases never nest."""
+        t0 = time.monotonic()
+        try:
+            with jax.profiler.TraceAnnotation("engine." + name):
+                yield
+        finally:
+            self.telemetry.loop_seconds[name].inc(time.monotonic() - t0)
+
     def _run(self):
         if self.kv_events is not None:
             # Bind BEFORE warm-up: subscribers join during the compile window.
@@ -945,7 +973,8 @@ class TpuEngine:
                 while (not self._stop and not self._waiting and not self._import_ready
                        and not self._abort_ids and not self._embed_reqs
                        and not any(self.slots)):
-                    self._cond.wait(timeout=0.1)
+                    with self._phase("idle_wait"):
+                        self._cond.wait(timeout=0.1)
                     # Keep the 1s KV snapshot cadence alive while idle: a
                     # subscriber joining an idle-but-warm engine must still
                     # learn its cache contents (PUB/SSE have no replay).
@@ -970,29 +999,35 @@ class TpuEngine:
                 self._abort_all("engine loop failure")
 
     def _step(self):
-        self._drain_release_reqs()
-        self._drain_embed_reqs()
-        self._sweep_exports()
-        self._publish_kv_snapshot()
-        self._process_aborts()
-        self._process_imports()
-        self._admit()
-        self._advance_prefills()
+        with self._phase("housekeeping"):
+            self._drain_release_reqs()
+            self._drain_embed_reqs()
+            self._sweep_exports()
+            self._publish_kv_snapshot()
+            self._process_aborts()
+            self._process_imports()
+        with self._phase("admit"):
+            self._admit()
+        with self._phase("advance_prefills"):
+            self._advance_prefills()
         if any(s is not None and s.pending_tok is None and not s.prefilling
                for s in self.slots):
             # Decode the established lanes (the chunk dispatch queues behind
             # any just-dispatched prefills on device), THEN land pending
             # first tokens — their host transfer overlapped the chunk.
             self._decode_once()
-            self._finalize_prefills()
+            with self._phase("finalize_prefills"):
+                self._finalize_prefills()
         elif any(s is not None for s in self.slots):
-            self._finalize_prefills()
+            with self._phase("finalize_prefills"):
+                self._finalize_prefills()
         else:
             with self._cond:
                 if (self._waiting or self._import_ready) and not self._abort_ids:
                     # Head-of-line can't be placed yet (no free blocks / no slot
                     # / fetch in flight): sleep until something changes.
-                    self._cond.wait(timeout=0.05)
+                    with self._phase("idle_wait"):
+                        self._cond.wait(timeout=0.05)
 
     def _on_follower_lost(self, idx: int, why: str) -> None:
         """Peer-monitor callback (runs on the channel's watch thread)."""
@@ -1134,17 +1169,30 @@ class TpuEngine:
             need = max(need, int(ktp["remote_num_blocks"]))
         return need
 
-    def _record_queue_wait(self, request_id: str) -> None:
+    def _record_queue_wait(self, request_id: str) -> float | None:
         """Measure admission wait at the FIRST _admit pop (first-pop-wins:
         a KV-fetch re-insert finds its stamp already consumed and is not
-        re-measured). The server pops the result for x-engine-queue-ms."""
+        re-measured). The server pops the result for x-engine-queue-ms, a
+        header only an unstreamed response can still carry. Returns the
+        wait in seconds, None for a pop that is not the first."""
         t0 = self._queue_submit.pop(request_id, None)
         if t0 is None:
-            return
-        self.queue_waits[request_id] = (time.monotonic() - t0) * 1e3
+            return None
+        wait_s = time.monotonic() - t0
+        self.queue_waits[request_id] = wait_s * 1e3
         self._queue_wait_order.append(request_id)
         while len(self._queue_wait_order) > 512:
             self.queue_waits.pop(self._queue_wait_order.popleft(), None)
+        return wait_s
+
+    def _note_admission(self, req: EngineRequest) -> None:
+        """A request leaves the waiting queue. At its first pop the wait goes
+        into jetstream:queue_wait_seconds (every request, streamed or not)
+        and admit -> first token starts."""
+        wait_s = self._record_queue_wait(req.request_id)
+        if wait_s is not None:
+            req.admit_time = time.monotonic()
+            self.telemetry.queue_wait.observe(wait_s)
 
     def _admit(self):
         group: list[tuple[int, EngineRequest, Any, Any, int]] = []
@@ -1160,7 +1208,7 @@ class TpuEngine:
                     # Impossible request: reject instead of wedging the queue.
                     self._waiting.pop(0)
                     self.telemetry.waiting.set(len(self._waiting))
-                    self._record_queue_wait(req.request_id)
+                    self._note_admission(req)
                     self._emit_to(out, loop, TokenEvent(
                         request_id=req.request_id, token_id=None,
                         finish_reason=FinishReason.ABORT,
@@ -1170,7 +1218,7 @@ class TpuEngine:
                     # Fetch off-thread; the payload comes back via _import_ready.
                     self._waiting.pop(0)
                     self.telemetry.waiting.set(len(self._waiting))
-                    self._record_queue_wait(req.request_id)
+                    self._note_admission(req)
                     self._start_kv_fetch(req, out, loop)
                     continue
                 available = getattr(self.allocator, "reusable_blocks",
@@ -1182,7 +1230,7 @@ class TpuEngine:
                     break  # head-of-line waits for capacity
                 self._waiting.pop(0)
                 self.telemetry.waiting.set(len(self._waiting))
-                self._record_queue_wait(req.request_id)
+                self._note_admission(req)
             group.append((i, req, out, loop, need))
         self._flush_admissions(group)
 
@@ -1537,7 +1585,7 @@ class TpuEngine:
             slot.generated = [tok]
             slot.last_token = tok
             req = slot.req
-            self.telemetry.ttft.observe(time.monotonic() - req.arrival_time)
+            self._observe_first_token(req)
             self.telemetry.generation_tokens.inc()
 
             # Remote-decode prefill: hand KV off instead of decoding here.
@@ -1553,6 +1601,14 @@ class TpuEngine:
                 cached_tokens=slot.cached_tokens))
             slot.first_emitted = True
             self._maybe_finish_after_token(idx, tok)
+
+    def _observe_first_token(self, req: EngineRequest) -> None:
+        """A first token has landed on the host: TTFT from the request's
+        construction, and the part of it since the admission pop."""
+        now = time.monotonic()
+        self.telemetry.ttft.observe(now - req.arrival_time)
+        if req.admit_time is not None:
+            self.telemetry.admit_to_first_token.observe(now - req.admit_time)
 
     def _prefill_window(self) -> int:
         """Incremental-prefill window in tokens (a KV-block multiple so
@@ -2231,7 +2287,7 @@ class TpuEngine:
             self.kv_events.stored(slot.block_hashes)
         self.slots[idx] = slot
         self.telemetry.running.set(sum(s is not None for s in self.slots))
-        self.telemetry.ttft.observe(time.monotonic() - req.arrival_time)
+        self._observe_first_token(req)
         self._emit(slot, TokenEvent(
             request_id=req.request_id, token_id=first,
             text=self.tokenizer.decode([first]), is_first=True,
@@ -2656,37 +2712,47 @@ class TpuEngine:
         return self.max_blocks_per_seq
 
     def _decode_once(self):
-        active = [i for i, s in enumerate(self.slots)
-                  if s is not None and s.pending_tok is None
-                  and not s.prefilling]
-        B = self._batch_bucket(len(active))
-        W = self._ctx_bucket(max((len(self.slots[i].blocks) for i in active),
-                                 default=1))
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        tables = np.zeros((B, W), np.int32)
-        # Compact active slots into the low lanes; padding lanes keep their
-        # block table at the trash block 0 (their KV writes land there).
-        for lane, i in enumerate(active):
-            s = self.slots[i]
-            tokens[lane] = s.last_token
-            positions[lane] = s.position
-            tables[lane, : len(s.blocks)] = s.blocks
+        with self._phase("decode_prepare"):
+            active = [i for i, s in enumerate(self.slots)
+                      if s is not None and s.pending_tok is None
+                      and not s.prefilling]
+            B = self._batch_bucket(len(active))
+            W = self._ctx_bucket(max((len(self.slots[i].blocks)
+                                      for i in active), default=1))
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            tables = np.zeros((B, W), np.int32)
+            # Compact active slots into the low lanes; padding lanes keep
+            # their block table at the trash block 0 (their KV writes land
+            # there).
+            for lane, i in enumerate(active):
+                s = self.slots[i]
+                tokens[lane] = s.last_token
+                positions[lane] = s.position
+                tables[lane, : len(s.blocks)] = s.blocks
 
-        reqs = [self.slots[i].req for i in active]
-        reqs += [_DUMMY_REQ] * (B - len(reqs))
-        self.telemetry.batch_fill.set(len(active) / max(self.cfg.max_batch, 1))
-        was_compiled = (("decode", f"{B}x{W}") in self._seen_op_shapes)
+            reqs = [self.slots[i].req for i in active]
+            reqs += [_DUMMY_REQ] * (B - len(reqs))
+            self.telemetry.batch_fill.set(
+                len(active) / max(self.cfg.max_batch, 1))
+            was_compiled = (("decode", f"{B}x{W}") in self._seen_op_shapes)
+            args = dict(tokens=tokens, positions=positions, tables=tables,
+                        **self._sample_np(reqs))
         t0 = time.monotonic()
-        toks = self._device_call(("decode",), dict(
-            tokens=tokens, positions=positions, tables=tables,
-            **self._sample_np(reqs)))
-        sampled = np.asarray(toks)  # [K, B] — ONE readback per chunk
+        with self._phase("decode_dispatch"):
+            toks = self._device_call(("decode",), args)
+        with self._phase("decode_wait"):
+            sampled = np.asarray(toks)  # [K, B] — ONE readback per chunk
         if was_compiled:
             # Full chunk wall time (dispatch through readback); the first
             # call per shape goes to the compile histogram instead.
             self.telemetry.decode_step.observe(time.monotonic() - t0)
+        with self._phase("decode_book"):
+            self._book_chunk(active, sampled)
 
+    def _book_chunk(self, active: list[int], sampled: np.ndarray) -> None:
+        """Apply one chunk's sampled tokens [K, B] lane by lane, up to each
+        request's stop condition."""
         for lane, i in enumerate(active):
             for step in range(sampled.shape[0]):
                 if self.slots[i] is None:

@@ -39,6 +39,9 @@ class EngineRequest:
     mm_embeds: Any = None          # np.ndarray [M, D] | None
     mm_positions: list[int] | None = None
     arrival_time: float = dataclasses.field(default_factory=time.monotonic)
+    # time.monotonic() at the engine's first admission pop (engine-set; the
+    # start of jetstream:admit_to_first_token_seconds).
+    admit_time: float | None = None
 
 
 @dataclasses.dataclass

@@ -20,6 +20,7 @@ import time
 import uuid
 from typing import Any
 
+import jax
 import numpy as np
 from aiohttp import web
 
@@ -113,6 +114,64 @@ def _responses_input_to_messages(body: dict[str, Any]) -> list[dict[str, Any]]:
     return messages
 
 
+class ProfileControl:
+    """`POST /debug/profile/start` and `/stop`: a `jax.profiler` trace taken
+    from a live engine, in the process that holds the chip (no other process
+    can trace it). The directory is the operator's (`--profile-dir`); a
+    caller never names a path. Device and runtime events plus the engine
+    loop's own `engine.<phase>` spans (host_tracer_level 1); tracing every
+    Python call would slow the loop that is being looked at."""
+
+    def __init__(self, directory: str):
+        self.directory = directory
+        self._state = "idle"          # idle | starting | tracing | stopping
+        self._t_traced = 0.0
+        self._start_trace_s = 0.0
+
+    def routes(self) -> list:
+        return [web.post("/debug/profile/start", self.start),
+                web.post("/debug/profile/stop", self.stop)]
+
+    def _begin(self) -> None:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(self.directory, profiler_options=options)
+
+    async def start(self, request: web.Request) -> web.Response:
+        if self._state != "idle":
+            return web.json_response({"error": f"profiler is {self._state}"},
+                                     status=409)
+        self._state = "starting"
+        t0 = time.monotonic()
+        try:
+            await asyncio.to_thread(self._begin)
+        except BaseException:
+            self._state = "idle"
+            raise
+        self._t_traced = time.monotonic()
+        self._start_trace_s = self._t_traced - t0
+        self._state = "tracing"
+        return web.json_response({"tracing": True,
+                                  "start_trace_s": self._start_trace_s})
+
+    async def stop(self, request: web.Request) -> web.Response:
+        if self._state != "tracing":
+            return web.json_response({"error": f"profiler is {self._state}"},
+                                     status=409)
+        self._state = "stopping"
+        t1 = time.monotonic()
+        try:
+            # Writing the file takes seconds; the event loop keeps serving.
+            await asyncio.to_thread(jax.profiler.stop_trace)
+        finally:
+            self._state = "idle"
+        return web.json_response({
+            "traced_s": t1 - self._t_traced,
+            "start_trace_s": self._start_trace_s,
+            "stop_trace_s": time.monotonic() - t1})
+
+
 class EngineServer:
     def __init__(self, cfg: EngineConfig, engine=None):
         import os
@@ -158,6 +217,8 @@ class EngineServer:
             web.get("/debug/traces", self.traces),
             web.get("/debug/kv", self.kv_debug),
         ])
+        if cfg.profile_dir:
+            self.app.add_routes(ProfileControl(cfg.profile_dir).routes())
         # E/PD encode store: request_id -> staged encoder output
         # {"embeds": float32 [rows, D], "indices": global item indices}
         # (the reference reads these engine-side via an EC connector;
@@ -1435,6 +1496,10 @@ def main(argv: list[str] | None = None):
                    help="verify TLS on the engine's outbound legs (ec/kv "
                         "pulls) with the system trust store instead of the "
                         "pod-local skip-verify default")
+    p.add_argument("--profile-dir", default="",
+                   help="directory for jax.profiler traces; with it the "
+                        "server answers POST /debug/profile/start and "
+                        "/debug/profile/stop (without it they are 404)")
     p.add_argument("--client-ca-cert", default="",
                    help="CA bundle for the outbound legs (implies "
                         "verification against this bundle)")
@@ -1462,6 +1527,7 @@ def main(argv: list[str] | None = None):
                        dist_instr_port=args.dist_instr_port,
                        dist_instr_host=args.dist_instr_host,
                        chaos=args.chaos, chaos_seed=args.chaos_seed,
+                       profile_dir=args.profile_dir,
                        client_insecure_skip_verify=not (
                            args.client_verify or args.client_ca_cert),
                        client_ca_cert_path=args.client_ca_cert)

@@ -330,7 +330,9 @@ class SimEngine:
                 prompt_tokens=len(req.prompt_token_ids)))
             return
         # Admission wait = semaphore hold time (the sim's only queue).
-        self.queue_waits[req.request_id] = (time.monotonic() - t_queue) * 1e3
+        req.admit_time = time.monotonic()
+        self.telemetry.queue_wait.observe(req.admit_time - t_queue)
+        self.queue_waits[req.request_id] = (req.admit_time - t_queue) * 1e3
         while len(self.queue_waits) > 512:
             self.queue_waits.popitem(last=False)
         try:
@@ -414,7 +416,9 @@ class SimEngine:
                     # quantiles to ~0 on P/D decode pods).
                     self.telemetry.prefill_step.observe(prefill_s)
                 self.telemetry.prompt_tokens.inc(prompt_len)
-                self.telemetry.ttft.observe(time.monotonic() - req.arrival_time)
+                now = time.monotonic()
+                self.telemetry.ttft.observe(now - req.arrival_time)
+                self.telemetry.admit_to_first_token.observe(now - req.admit_time)
                 first = self._gen_tokens[0]
                 if ktp.get("do_remote_decode"):
                     rec = self.kv_exports.get(req.request_id)

@@ -10,16 +10,94 @@ fleets via its mapping registry).
 from __future__ import annotations
 
 import collections
+import threading
 import time
 from typing import Any
 
 from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram, generate_latest
+from prometheus_client.core import CounterMetricFamily
 
 WAITING = "jetstream:num_requests_waiting"
 RUNNING = "jetstream:num_requests_running"
 KV_USAGE = "jetstream:kv_cache_usage_perc"
 LORA_INFO = "jetstream:lora_requests_info"
 CACHE_CONFIG = "jetstream:cache_config_info"
+
+# The engine loop's phases, in loop order (engine/core.py `_phase`). Each is
+# a host span `engine.<phase>` in a profiler trace and a label of
+# `jetstream:engine_loop_seconds_total`; they never nest.
+LOOP_PHASES = ("housekeeping", "admit", "advance_prefills", "decode_prepare",
+               "decode_dispatch", "decode_wait", "decode_book",
+               "finalize_prefills", "idle_wait")
+
+# Fine below a second: a wait is a fraction of one decode chunk (0.2-0.3 s
+# at the benchmark's sizes), and a quantile read off coarser buckets would
+# say nothing. Sum and count are exact whatever the buckets.
+_WAIT_BUCKETS = (.001, .0025, .005, .01, .02, .035, .05, .075, .1, .15, .2,
+                 .25, .3, .4, .5, .65, .8, 1, 1.5, 2.5, 5, 10, 30)
+
+
+class XlaBuilds:
+    """Programs JAX built in this process, from `jax.monitoring`'s events.
+
+    JAX 0.9 records `/jax/core/compile/backend_compile_duration` once for
+    every program it builds, whether the backend compiled it or the
+    persistent cache held it; in the second case
+    `/jax/compilation_cache/cache_hits` fires first, on the same thread.
+    The events are the process's and a listener stays for its life, so
+    there is one tally a process (`XLA_BUILDS`), started by `watch()` and
+    shown by the registry of every engine that asked for it."""
+
+    _BUILD = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._watching = False
+        self._hit = threading.local()
+        self.counts = {"compiled": 0, "cache_loaded": 0}
+        self.seconds = 0.0
+
+    def watch(self) -> "XlaBuilds":
+        with self._lock:
+            first, self._watching = not self._watching, True
+        if first:
+            from jax import monitoring
+
+            monitoring.register_event_listener(self._on_event)
+            monitoring.register_event_duration_secs_listener(self._on_duration)
+        return self
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == self._HIT:
+            self._hit.pending = True
+
+    def _on_duration(self, event: str, duration: float, **_) -> None:
+        if event != self._BUILD:
+            return
+        loaded = getattr(self._hit, "pending", False)
+        self._hit.pending = False
+        with self._lock:
+            self.counts["cache_loaded" if loaded else "compiled"] += 1
+            self.seconds += duration
+
+    def collect(self):
+        builds = CounterMetricFamily(
+            "jetstream:xla_builds",
+            "Programs JAX built in this process: compiled by the backend, "
+            "or loaded from the persistent compilation cache", labels=("kind",))
+        with self._lock:
+            for kind, n in self.counts.items():
+                builds.add_metric((kind,), n)
+            seconds = self.seconds
+        yield builds
+        yield CounterMetricFamily(
+            "jetstream:xla_build_seconds",
+            "Seconds spent building those programs (compile or cache load)",
+            value=seconds)
+
+
+XLA_BUILDS = XlaBuilds()
 
 
 class EngineTelemetry:
@@ -60,7 +138,8 @@ class EngineTelemetry:
             buckets=(.002, .005, .01, .025, .05, .1, .25, .5, 1, 2.5))
         self.compile_events = Counter(
             "jetstream:compile_events_total",
-            "First dispatch of a novel (op, shape-bucket) — a jit compile",
+            "First dispatch of a novel (op, shape-bucket) key of the engine "
+            "(not a count of compiles: jetstream:xla_builds_total is)",
             ("op", "bucket"), registry=self.registry)
         self.compile_duration = Histogram(
             "jetstream:compile_duration_seconds",
@@ -96,6 +175,29 @@ class EngineTelemetry:
                               buckets=(.005, .01, .025, .05, .1, .25, .5, 1, 2.5, 5, 10))
         self.request_success = Counter("jetstream:request_success_total", "Finished requests",
                                        ("finished_reason",), registry=self.registry)
+        # A request's wait, split where it happens: submit() -> the first
+        # admission pop, and that pop -> the first token landing. Observed
+        # once each per request, streamed or not; their sum is the TTFT
+        # above less the hop from the request's construction to submit().
+        self.queue_wait = Histogram(
+            "jetstream:queue_wait_seconds",
+            "submit() to the request's first admission pop",
+            registry=self.registry, buckets=_WAIT_BUCKETS)
+        self.admit_to_first_token = Histogram(
+            "jetstream:admit_to_first_token_seconds",
+            "First admission pop to the first token landing on the host",
+            registry=self.registry, buckets=_WAIT_BUCKETS)
+        loop_seconds = Counter(
+            "jetstream:engine_loop_seconds_total",
+            "Wall time of the engine loop by phase (the engine.<phase> host "
+            "spans of a profiler trace, always on)", ("phase",),
+            registry=self.registry)
+        self.loop_seconds = {p: loop_seconds.labels(phase=p) for p in LOOP_PHASES}
+
+    def watch_xla_builds(self) -> None:
+        """Count the programs JAX builds from now on, and show the count
+        (engines that run JAX call this; the simulator never imports it)."""
+        self.registry.register(XLA_BUILDS.watch())
 
     def observe_allocator(self, allocator) -> None:
         """One-call snapshot of the allocator's occupancy gauges — used at

@@ -127,7 +127,7 @@ objectives:
 def test_ha_gateway_failover_e2e(tmp_path):
     """Two gateway replicas sharing a lease: leader 200, follower 503 on
     /health; kill the leader → the follower takes over and serves."""
-    ENG, GW_A, GW_B = 18741, 18742, 18743
+    ENG, GW_A, GW_B = 18976, 18977, 18978
     lease = str(tmp_path / "lease")
 
     async def body():

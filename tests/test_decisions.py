@@ -219,7 +219,7 @@ def test_verify_decisions_lint_clean():
 
 # ---- e2e tier ------------------------------------------------------------
 
-GW, EA, EB = 18860, 18861, 18862
+GW, EA, EB = 18953, 18954, 18955
 
 CFG = f"""
 featureGates: {{flowControl: true}}

@@ -313,20 +313,20 @@ def test_gateway_routes_to_kube_discovered_endpoints(fake):
         from llm_d_inference_scheduler_tpu.router.gateway import build_gateway
 
         eng = EngineServer(EngineConfig(model="tiny", backend="sim",
-                                        port=18861, kv_events_port=0))
+                                        port=18951, kv_events_port=0))
         await eng.start()
         await fake.start()
         fake.upsert(POOLS, {
             "metadata": {"name": "pool"},
             "spec": {"selector": {"matchLabels": {"app": "llmd"}},
-                     "targetPort": 18861}})
+                     "targetPort": 18951}})
         fake.upsert(PODS, pod("sim0", "127.0.0.1", {"app": "llmd"}))
 
         gw = build_gateway(
             "plugins: [{type: queue-scorer}]\n"
             "schedulingProfiles: [{name: default, plugins: "
             "[{pluginRef: queue-scorer}]}]\n",
-            port=18860,
+            port=18950,
             kube={"api_url": f"http://127.0.0.1:{fake.port}",
                   "namespace": NS, "pool_name": "pool"})
         await gw.start()
@@ -343,12 +343,12 @@ def test_gateway_routes_to_kube_discovered_endpoints(fake):
                 body = _json.dumps({"model": "tiny", "prompt": "hi there",
                                     "max_tokens": 3}).encode()
                 r = urllib.request.urlopen(urllib.request.Request(
-                    "http://127.0.0.1:18860/v1/completions", data=body,
+                    "http://127.0.0.1:18950/v1/completions", data=body,
                     headers={"Content-Type": "application/json"}), timeout=30)
                 return r.headers.get("x-gateway-destination-endpoint-served")
 
             dest = await asyncio.get_running_loop().run_in_executor(None, post)
-            assert dest == "127.0.0.1:18861"
+            assert dest == "127.0.0.1:18951"
         finally:
             await gw.stop()
             await fake.stop()

@@ -141,20 +141,20 @@ def test_sharded_pull_tp_pair_matches_monolithic():
     (kv_shards.py) and the tp decode engine pulls + assembles them under
     its own page sharding — device path, token parity with monolithic."""
     async def body():
-        mono = EngineServer(_cfg(18741, tp_size=2))
+        mono = EngineServer(_cfg(18981, tp_size=2))
         await mono.start()
         try:
             async with httpx.AsyncClient(timeout=60) as c:
-                r = await c.post("http://127.0.0.1:18741/v1/completions",
+                r = await c.post("http://127.0.0.1:18981/v1/completions",
                                  json={"prompt": PROMPT, "max_tokens": 6,
                                        "temperature": 0, "ignore_eos": True})
                 mono_text = r.json()["choices"][0]["text"]
         finally:
             await mono.stop()
 
-        pre, dec = await _pd_pair(18742, 18743, tp_size=2)
+        pre, dec = await _pd_pair(18982, 18983, tp_size=2)
         try:
-            ktp, doc = await _run_pd(18742, 18743)
+            ktp, doc = await _run_pd(18982, 18983)
             assert "transfer_shards" in ktp and "kv_mesh" in ktp
             assert ktp["kv_mesh"]["n_procs"] == 1
             assert dec.engine.kv_import_device_count == 1
@@ -207,12 +207,12 @@ def test_sharded_geometry_mismatch_falls_back_to_host():
     """tp=2 exporter, unsharded importer: geometry mismatch must degrade to
     the host-staged path (numpy resharding), not fail the request."""
     async def body():
-        pre = EngineServer(_cfg(18744, role="prefill", tp_size=2))
-        dec = EngineServer(_cfg(18745, role="decode"))
+        pre = EngineServer(_cfg(18984, role="prefill", tp_size=2))
+        dec = EngineServer(_cfg(18985, role="decode"))
         await pre.start()
         await dec.start()
         try:
-            ktp, doc = await _run_pd(18744, 18745)
+            ktp, doc = await _run_pd(18984, 18985)
             assert "transfer_shards" in ktp
             assert dec.engine.kv_import_device_count == 0
             assert dec.engine.kv_import_host_count == 1
