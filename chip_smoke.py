@@ -518,35 +518,39 @@ def kernel_check(seed: int) -> int:
         return 1
     # Qwen3-4B's decode shapes at max_batch 16 x max_model_len 2048: 32 Q /
     # 8 KV heads of 128, 2,049 bf16 pages of 16 tokens, table 128 wide; the
-    # contexts run from one token to the full 2,048.
-    batch, width, block = 16, 128, m.kv_block_size
+    # contexts run from one token to the full 2,048. The pools are stacked as
+    # the engine holds them; two layers here, each its own draw, and the
+    # kernel reads the second: the reference is handed that layer's pool
+    # alone, so a kernel that ignored the index would not match.
+    batch, width, block, layers, layer = 16, 128, m.kv_block_size, 2, 1
     dt = jnp.dtype(m.dtype)
     ks = jax.random.split(jax.random.key(seed), 5)
     n_pages = 1 + batch * width
     q = jax.random.normal(ks[0], (batch, m.n_heads, m.head_dim), dt)
     k_pages = jax.random.normal(
-        ks[1], (n_pages, block, m.n_kv_heads, m.head_dim), dt)
+        ks[1], (layers, n_pages, block, m.n_kv_heads, m.head_dim), dt)
     v_pages = jax.random.normal(
-        ks[2], (n_pages, block, m.n_kv_heads, m.head_dim), dt)
+        ks[2], (layers, n_pages, block, m.n_kv_heads, m.head_dim), dt)
     cur_k = jax.random.normal(ks[3], (batch, m.n_kv_heads, m.head_dim), dt)
     cur_v = jax.random.normal(ks[4], (batch, m.n_kv_heads, m.head_dim), dt)
     tables = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(batch, width)
     seq_lens = jnp.asarray([1, 2, 16, 17, 33, 100, 257, 512, 777, 1024, 1300,
                             1500, 1777, 2000, 2047, 2048], jnp.int32)
-    args = (q, k_pages, v_pages, tables, seq_lens, cur_k, cur_v)
+    args = (q, k_pages, v_pages, layer, tables, seq_lens, cur_k, cur_v)
     in_program = ("tpu_custom_call"
                   in paged_decode_attention_pallas.lower(*args).as_text())
     out = np.asarray(paged_decode_attention_pallas(*args), np.float32)
     ref = np.asarray(jax.jit(paged_decode_attention)(
-        q, k_pages, v_pages, tables, seq_lens, cur_k=cur_k, cur_v=cur_v),
-        np.float32)
+        q, k_pages[layer][None], v_pages[layer][None], 0, tables, seq_lens,
+        cur_k=cur_k, cur_v=cur_v), np.float32)
     err = np.abs(out - ref)
     within = bool(np.all(np.isfinite(out))
                   and np.all(err <= KERNEL_ATOL + KERNEL_RTOL * np.abs(ref)))
     say(phase="kernel_check", device_kind=dev.device_kind,
         compile_cache_dir=cache_dir, shapes={
             "batch": batch, "q_heads": m.n_heads, "kv_heads": m.n_kv_heads,
-            "head_dim": m.head_dim, "pages": n_pages, "page_tokens": block,
+            "head_dim": m.head_dim, "layers": layers, "layer_read": layer,
+            "pages": n_pages, "page_tokens": block,
             "table_width": width, "dtype": str(dt)},
         pallas_call_in_program=in_program,
         max_abs_diff=float(err.max()), max_abs_ref=float(np.abs(ref).max()),

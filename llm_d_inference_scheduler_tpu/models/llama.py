@@ -7,9 +7,10 @@ Design notes (TPU-first):
 - Parameters are a plain pytree with layer weights STACKED on a leading axis so
   the training/prefill path runs ``lax.scan`` over layers: one traced layer
   body, L-step loop — fast compiles, XLA-friendly.
-- The decode path is an unrolled layer loop over the same stacked params
-  (static slice per layer) so each layer's paged KV cache can be updated with
-  ``dynamic_update``-style scatters and donated for in-place HBM updates.
+- The decode path scans over the same stacked params and a layer index. The
+  paged KV pools stay whole: the scan's body closes over them, attention
+  reads them at (layer, page), and the step's new rows go in with one scatter
+  after the scan, so donated pools are updated in place.
 - All matmuls run in the params' dtype (bf16 by default) with f32 softmax/norm
   accumulation; logits are f32.
 - Attention is injected via ``attention_fn`` so the sequence-parallel path can
@@ -221,6 +222,12 @@ def decode_step(
     buffers are touched once per step, not once per layer. The current token
     attends to itself via the appended cur_k/cur_v attention column.
 
+    The scan carries the activations and scans over (layer params, layer
+    index) — never over the pages. The body closes over the stacked pools and
+    both attention ops read them at (layer, page); a pool scanned over would
+    reach the Pallas kernel as one layer's slice, which XLA has to copy out
+    first (a custom call's operand cannot be a fused slice).
+
     Inactive batch slots must point their block table at the dedicated trash
     block 0 (the allocator reserves it).
     """
@@ -236,7 +243,7 @@ def decode_step(
     x = params["embed"][tokens]  # [B, D]
 
     def body(x, layer_in):
-        lp, kp, vp = layer_in
+        lp, layer = layer_in
         h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
         q = (h @ lp["wq"]).reshape(B, cfg.n_heads, Dh)
         k = (h @ lp["wk"]).reshape(B, cfg.n_kv_heads, Dh)
@@ -246,18 +253,20 @@ def decode_step(
         k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
 
         if use_pallas:
-            attn = paged_decode_attention_pallas(q, kp, vp, block_tables,
-                                                 seq_lens, k, v,
+            attn = paged_decode_attention_pallas(q, k_pages, v_pages, layer,
+                                                 block_tables, seq_lens, k, v,
                                                  interpret=pallas_interpret)
         else:
-            attn = paged_decode_attention(q, kp, vp, block_tables, seq_lens,
+            attn = paged_decode_attention(q, k_pages, v_pages, layer,
+                                          block_tables, seq_lens,
                                           cur_k=k, cur_v=v)
         x = x + attn.reshape(B, -1) @ lp["wo"]
         h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
         x = x + _ffn(cfg, lp, h)
         return x, (k, v)
 
-    x, (k_cur, v_cur) = jax.lax.scan(body, x, (params["layers"], k_pages, v_pages))
+    x, (k_cur, v_cur) = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
     # One fused scatter of all layers' current-token KV: [L, B, Hkv, Dh] into
     # pages at (layer, blk_idx[b], slot[b]).
     k_pages = k_pages.at[:, blk_idx, slot].set(k_cur.astype(k_pages.dtype))

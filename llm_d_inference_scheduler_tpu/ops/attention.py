@@ -67,8 +67,9 @@ def causal_attention(
 
 def paged_decode_attention(
     q: jnp.ndarray,            # [B, H, D] — one new token per sequence
-    k_pages: jnp.ndarray,      # [N_blocks, block, Hkv, D]
-    v_pages: jnp.ndarray,      # [N_blocks, block, Hkv, D]
+    k_pages: jnp.ndarray,      # [L, N_blocks, block, Hkv, D] — every layer's pool
+    v_pages: jnp.ndarray,      # [L, N_blocks, block, Hkv, D]
+    layer: jnp.ndarray,         # int32 scalar — the layer whose pool is read
     block_tables: jnp.ndarray,  # [B, max_blocks] int32 — physical block ids
     seq_lens: jnp.ndarray,      # [B] int32 — tokens valid in cache (incl. current)
     cur_k: jnp.ndarray | None = None,  # [B, Hkv, D] current token's K (not yet in pages)
@@ -82,17 +83,20 @@ def paged_decode_attention(
     layer scan). Cache rows at the current position are masked as invalid in
     that mode.
 
-    The gather materialises [B, max_blocks*block] KV rows; a Pallas kernel with
-    scalar-prefetched block tables replaces this on the hot path (see ops/pallas).
+    The pools stay stacked and one gather reads (layer, page), so no layer's
+    pool is sliced out first; a caller with one layer's pool passes
+    ``pool[None]`` and layer 0. The gather materialises [B, max_blocks*block]
+    KV rows; a Pallas kernel with scalar-prefetched block tables replaces this
+    on the hot path (ops/pallas_paged_attention.py, same signature).
     """
     B, H, D = q.shape
-    block = k_pages.shape[1]
+    block = k_pages.shape[2]
     max_blocks = block_tables.shape[1]
     T = max_blocks * block
-    q_per_kv = H // k_pages.shape[2]
+    q_per_kv = H // k_pages.shape[3]
 
-    k = k_pages[block_tables].reshape(B, T, -1, D)  # [B, T, Hkv, D]
-    v = v_pages[block_tables].reshape(B, T, -1, D)
+    k = k_pages[layer, block_tables].reshape(B, T, -1, D)  # [B, T, Hkv, D]
+    v = v_pages[layer, block_tables].reshape(B, T, -1, D)
     cached_valid_len = seq_lens if cur_k is None else seq_lens - 1
     if cur_k is not None:
         k = jnp.concatenate([k, cur_k[:, None]], axis=1)  # [B, T+1, Hkv, D]

@@ -14,6 +14,13 @@ K and V pages (double-buffered: page j+1's DMA is in flight while page j is
 computed), then per-KV-head-group MXU matmuls with f32 accumulation.
 The current token's K/V arrives as a separate operand (the engine scatters it
 into the pages after the layer scan — see models/llama.py decode_step).
+
+The pages arrive as the engine holds them: every layer's pool stacked,
+[L, N, block, Hkv, D], left in HBM, with the layer as a third prefetched
+scalar; each DMA addresses (layer, page). XLA cannot fuse a slice into a
+custom call's operand, so a kernel handed one layer's pool out of the stack
+is handed a copy of it (2 x 67 MB a layer at Qwen3-4B, 4.8 GB a decode
+step). A caller with one layer's pool passes ``pool[None]`` and layer 0.
 """
 
 from __future__ import annotations
@@ -28,9 +35,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(bt_ref, sl_ref,            # scalar prefetch: [B*maxB], [B]
+def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
             q_ref, cur_k_ref, cur_v_ref,  # VMEM blocks per program
-            k_hbm, v_hbm,              # full page arrays (ANY/HBM)
+            k_hbm, v_hbm,              # stacked page arrays (ANY/HBM)
             out_ref,                   # [1, H, D]
             k_scratch, v_scratch, sem_k, sem_v,
             *, max_blocks: int, block: int, n_kv: int, q_per_kv: int,
@@ -42,6 +49,7 @@ def _kernel(bt_ref, sl_ref,            # scalar prefetch: [B*maxB], [B]
     q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
     q = q.reshape(n_kv, q_per_kv, head_dim)           # [G, qpk, D]
     cached_len = sl_ref[b] - 1                        # rows valid in pages
+    layer = layer_ref[0]
 
     m0 = jnp.full((n_kv, q_per_kv, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((n_kv, q_per_kv, 1), jnp.float32)
@@ -54,9 +62,9 @@ def _kernel(bt_ref, sl_ref,            # scalar prefetch: [B*maxB], [B]
     # layers blocking latencies per step).
     def _copies(j, slot):
         blk = bt_ref[b * max_blocks + j]
-        return (pltpu.make_async_copy(k_hbm.at[blk], k_scratch.at[slot],
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk], k_scratch.at[slot],
                                       sem_k.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[blk], v_scratch.at[slot],
+                pltpu.make_async_copy(v_hbm.at[layer, blk], v_scratch.at[slot],
                                       sem_v.at[slot]))
 
     @pl.when(0 < cached_len)
@@ -129,8 +137,9 @@ def _kernel(bt_ref, sl_ref,            # scalar prefetch: [B*maxB], [B]
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,            # [B, H, D]
-    k_pages: jnp.ndarray,      # [N, block, Hkv, D]
+    k_pages: jnp.ndarray,      # [L, N, block, Hkv, D] — every layer's pool
     v_pages: jnp.ndarray,
+    layer: jnp.ndarray,         # int32 scalar — the layer whose pool is read
     block_tables: jnp.ndarray,  # [B, maxB] int32
     seq_lens: jnp.ndarray,      # [B] int32 (incl. current token)
     cur_k: jnp.ndarray,         # [B, Hkv, D]
@@ -139,7 +148,7 @@ def paged_decode_attention_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, H, D = q.shape
-    N, block, n_kv, _ = k_pages.shape
+    _, _, block, n_kv, _ = k_pages.shape
     maxB = block_tables.shape[1]
     q_per_kv = H // n_kv
 
@@ -148,7 +157,7 @@ def paged_decode_attention_pallas(
         q_per_kv=q_per_kv, head_dim=D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
@@ -170,4 +179,6 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(block_tables.reshape(-1), seq_lens, q, cur_k, cur_v, k_pages, v_pages)
+    )(block_tables.reshape(-1), seq_lens,
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q, cur_k, cur_v, k_pages, v_pages)

@@ -174,7 +174,7 @@ def _decode_slab(cfg: ModelConfig, params, x, k_pages, v_pages, tables,
     slot = positions % k_pages.shape[2]
 
     def body(x, layer_in):
-        lp, kp, vp = layer_in
+        lp, layer = layer_in                                # local layer index
         h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
         q = (h @ lp["wq"]).reshape(B, -1, Dh)               # local heads
         k = (h @ lp["wk"]).reshape(B, -1, Dh)
@@ -182,15 +182,15 @@ def _decode_slab(cfg: ModelConfig, params, x, k_pages, v_pages, tables,
         q, k = llama.qk_normed(cfg, lp, q, k)
         q = llama.apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
         k = llama.apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
-        attn = paged_decode_attention(q, kp, vp, tables, seq_lens,
-                                      cur_k=k, cur_v=v)
+        attn = paged_decode_attention(q, k_pages, v_pages, layer, tables,
+                                      seq_lens, cur_k=k, cur_v=v)
         x = x + jax.lax.psum(attn.reshape(B, -1) @ lp["wo"], "tp")
         h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
         x = x + _ffn_psum(cfg, lp, h)
         return x, (k, v)
 
-    x, (k_cur, v_cur) = jax.lax.scan(body, x,
-                                     (params["layers"], k_pages, v_pages))
+    x, (k_cur, v_cur) = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
     k_pages = k_pages.at[:, eff_blk, slot].set(k_cur.astype(k_pages.dtype))
     v_pages = v_pages.at[:, eff_blk, slot].set(v_cur.astype(v_pages.dtype))
     return x, k_pages, v_pages

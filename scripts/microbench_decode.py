@@ -98,19 +98,19 @@ def main(argv=None):
         q = jnp.ones((B, mcfg.n_heads, D), jnp.bfloat16)
         cur = jnp.ones((B, G, D), jnp.bfloat16)
         seq_lens = jnp.full((B,), args.ctx + 1, jnp.int32)
-        kp1 = k_pages[0]
-        vp1 = v_pages[0]
 
-        def attn_chain(q):
-            def body(acc, _):
-                o = paged_decode_attention_pallas(q, kp1, vp1, tables,
-                                                  seq_lens, cur, cur)
+        # The stacked pools and a layer index, as decode_step calls it.
+        def attn_chain(q, k_pages, v_pages):
+            def body(acc, layer):
+                o = paged_decode_attention_pallas(q, k_pages, v_pages, layer,
+                                                  tables, seq_lens, cur, cur)
                 return acc + o.astype(jnp.float32).sum(), None
 
-            acc, _ = jax.lax.scan(body, jnp.float32(0), None, length=int(mcfg.n_layers))
+            acc, _ = jax.lax.scan(body, jnp.float32(0),
+                                  jnp.arange(L, dtype=jnp.int32))
             return acc
 
-        ms = timeit(jax.jit(attn_chain), q, iters=5)
+        ms = timeit(jax.jit(attn_chain), q, k_pages, v_pages, iters=5)
         print(json.dumps({"component": f"pallas_attn x{mcfg.n_layers}L", "B": B,
                           "ms_per_step": round(ms, 3)}))
 

@@ -6,10 +6,14 @@ and compiles for a chip that is described and not attached, so each kernel is
 lowered here for one v5e chip at the shapes the engine serves it with. Nothing
 runs — a compile that passes says nothing about results or speed. The whole
 Qwen3-4B step programs take minutes each and live in
-scripts/aot_rehearsal.py.
+scripts/aot_rehearsal.py; one decode step at Qwen3-4B's widths and two
+layers is here, as the guard that no layer's page pool is copied.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -20,6 +24,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from llm_d_inference_scheduler_tpu.models import llama
 from llm_d_inference_scheduler_tpu.models.configs import MIXTRAL_8X7B, QWEN3_4B
 from llm_d_inference_scheduler_tpu.ops import pallas_moe
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
@@ -51,19 +56,49 @@ def _sds(sharding, shape, dtype):
 
 @pytest.mark.parametrize("batch,table_width", [(16, 128), (64, 32)])
 def test_paged_attention_compiles_at_qwen3_4b(one_chip, batch, table_width):
-    """32 Q / 8 KV heads of 128, bf16 pages of 16 tokens; the cache of
-    max_batch 16 x 2048 tokens (2,049 pages), at decode batch 16 (table 128
-    wide) and 64 (table 32 wide)."""
+    """32 Q / 8 KV heads of 128, bf16 pages of 16 tokens; the 36 layers'
+    cache of max_batch 16 x 2048 tokens (2,049 pages a layer), at decode batch
+    16 (table 128 wide) and 64 (table 32 wide)."""
     m = QWEN3_4B
     dt = jnp.dtype(m.dtype)
-    pages = _sds(one_chip, (2049, m.kv_block_size, m.n_kv_heads, m.head_dim),
-                 dt)
+    pages = _sds(one_chip, (m.n_layers, 2049, m.kv_block_size, m.n_kv_heads,
+                            m.head_dim), dt)
     cur = _sds(one_chip, (batch, m.n_kv_heads, m.head_dim), dt)
     compiled = paged_decode_attention_pallas.lower(
         _sds(one_chip, (batch, m.n_heads, m.head_dim), dt), pages, pages,
+        _sds(one_chip, (), jnp.int32),
         _sds(one_chip, (batch, table_width), jnp.int32),
         _sds(one_chip, (batch,), jnp.int32), cur, cur).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_copies_no_layers_page_pool(one_chip):
+    """One decode step at Qwen3-4B's widths, depth cut to two, 16 lanes on
+    the 16 x 2048 cache, Pallas attention on. The kernel must be handed the
+    stacked pools: a pool scanned over reaches the custom call as one layer's
+    slice, which XLA copies out first (67 MB of K and of V a layer)."""
+    m = dataclasses.replace(QWEN3_4B, n_layers=2)
+    dt = jnp.dtype(m.dtype)
+    batch, width = 16, 128
+    one_layer = (2049, m.kv_block_size, m.n_kv_heads, m.head_dim)
+    pages = _sds(one_chip, (m.n_layers, *one_layer), dt)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: llama.init_params(m, k), jax.random.key(0)))
+    compiled = jax.jit(
+        lambda *a: llama.decode_step(a[0], m, *a[1:], use_pallas=True),
+        donate_argnums=(3, 4),
+    ).lower(params, _sds(one_chip, (batch,), jnp.int32),
+            _sds(one_chip, (batch,), jnp.int32), pages, pages,
+            _sds(one_chip, (batch, width), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    shape = "bf16[" + ",".join(map(str, one_layer)) + "]"
+    made = [ln.strip()[:160] for ln in hlo.splitlines()
+            if re.search(r"=\s*" + re.escape(shape), ln)]
+    assert not made, made
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < dt.itemsize * math.prod(one_layer))
 
 
 @pytest.mark.parametrize("d_model,d_ff,n_experts,tm", [

@@ -1,35 +1,52 @@
-"""Pallas paged attention (interpret mode) vs the XLA reference formulation."""
+"""Pallas paged attention (interpret mode) vs the XLA reference formulation.
+
+Both ops take every layer's pool stacked, [L, N, block, Hkv, D], and a layer
+index; the reference here is handed ONE layer's pool (``pool[layer][None]``,
+layer 0), so an op that ignores the index fails.
+"""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_d_inference_scheduler_tpu.models import llama
+from llm_d_inference_scheduler_tpu.models.configs import ModelConfig
+from llm_d_inference_scheduler_tpu.ops import apply_rope, rms_norm, rope_table
 from llm_d_inference_scheduler_tpu.ops.attention import paged_decode_attention
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
     paged_decode_attention_pallas,
 )
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
 @pytest.mark.parametrize("seq_lens_spec", [[5], [17, 3], [33, 1, 16]])
-def test_pallas_matches_xla_reference(seq_lens_spec):
+def test_pallas_matches_xla_reference(seq_lens_spec, layer):
     B = len(seq_lens_spec)
-    H, Hkv, D, block, maxB = 8, 2, 32, 16, 4
+    L, H, Hkv, D, block, maxB = 3, 8, 2, 32, 16, 4
     N = 1 + B * maxB
     key = jax.random.key(0)
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (N, block, Hkv, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (N, block, Hkv, D), jnp.float32)
+    # Every layer's pages are their own draw.
+    k_pages = jax.random.normal(ks[1], (L, N, block, Hkv, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (L, N, block, Hkv, D), jnp.float32)
     cur_k = jax.random.normal(ks[3], (B, Hkv, D), jnp.float32)
     cur_v = jax.random.normal(ks[4], (B, Hkv, D), jnp.float32)
     block_tables = jnp.arange(1, 1 + B * maxB, dtype=jnp.int32).reshape(B, maxB)
     seq_lens = jnp.array(seq_lens_spec, jnp.int32)
 
-    ref = paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
+    ref = paged_decode_attention(q, k_pages[layer][None], v_pages[layer][None],
+                                 0, block_tables, seq_lens,
                                  cur_k=cur_k, cur_v=cur_v)
-    out = paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
-                                        seq_lens, cur_k, cur_v, interpret=True)
+    xla = paged_decode_attention(q, k_pages, v_pages, layer, block_tables,
+                                 seq_lens, cur_k=cur_k, cur_v=cur_v)
+    np.testing.assert_array_equal(np.asarray(xla), np.asarray(ref))
+    out = paged_decode_attention_pallas(q, k_pages, v_pages, layer,
+                                        block_tables, seq_lens, cur_k, cur_v,
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -41,19 +58,89 @@ def test_pallas_trash_block_slots_isolated():
     key = jax.random.key(1)
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (N, block, Hkv, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (N, block, Hkv, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (1, N, block, Hkv, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (1, N, block, Hkv, D), jnp.float32)
     cur_k = jax.random.normal(ks[3], (B, Hkv, D), jnp.float32)
     cur_v = jax.random.normal(ks[4], (B, Hkv, D), jnp.float32)
     block_tables = jnp.array([[1, 2], [0, 0]], jnp.int32)  # row 1: trash
     seq_lens = jnp.array([20, 1], jnp.int32)
 
-    out = paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
+    out = paged_decode_attention_pallas(q, k_pages, v_pages, 0, block_tables,
                                         seq_lens, cur_k, cur_v, interpret=True)
     # Row 1 attends only to its own token -> output == cur_v broadcast per group
     expect = jnp.repeat(cur_v[1], H // Hkv, axis=0)
     np.testing.assert_allclose(np.asarray(out[1]), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
+
+
+def _decode_step_by_layer_loop(params, cfg, tokens, positions, k_pages,
+                               v_pages, block_tables, attend):
+    """decode_step as it was before the pools stayed stacked: a Python loop
+    over the layers that slices ``pool[l]`` out and hands attention that one
+    layer's pool."""
+    B = tokens.shape[0]
+    block = k_pages.shape[2]
+    Dh = cfg.head_dim
+    cos, sin = rope_table(positions, Dh, cfg.rope_theta)
+    seq_lens = positions + 1
+    blk_idx = block_tables[jnp.arange(B), positions // block]
+    slot = positions % block
+    x = params["embed"][tokens]
+    k_cur, v_cur = [], []
+    for l in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[l], params["layers"])
+        h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+        q = (h @ lp["wq"]).reshape(B, cfg.n_heads, Dh)
+        k = (h @ lp["wk"]).reshape(B, cfg.n_kv_heads, Dh)
+        v = (h @ lp["wv"]).reshape(B, cfg.n_kv_heads, Dh)
+        q, k = llama.qk_normed(cfg, lp, q, k)
+        q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
+        k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
+        attn = attend(q, k_pages[l][None], v_pages[l][None], 0, block_tables,
+                      seq_lens, k, v)
+        x = x + attn.reshape(B, -1) @ lp["wo"]
+        h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
+        x = x + llama._ffn(cfg, lp, h)
+        k_cur.append(k)
+        v_cur.append(v)
+    k_pages = k_pages.at[:, blk_idx, slot].set(jnp.stack(k_cur).astype(k_pages.dtype))
+    v_pages = v_pages.at[:, blk_idx, slot].set(jnp.stack(v_cur).astype(v_pages.dtype))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return (x @ params["lm_head"]).astype(jnp.float32), k_pages, v_pages
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+def test_decode_step_reads_each_layers_own_pages(use_pallas):
+    """The stacked-pool scan against the per-layer loop, every layer's pages
+    filled differently: same logits, same pages back."""
+    cfg = ModelConfig(name="t", vocab_size=64, d_model=64, n_layers=3,
+                      n_heads=4, n_kv_heads=2, head_dim_override=32, d_ff=128,
+                      dtype="float32", kv_block_size=16, qk_norm=True)
+    B, maxB = 3, 3
+    N = 1 + B * maxB
+    ks = jax.random.split(jax.random.key(2), 3)
+    params = llama.init_params(cfg, ks[0])
+    shape = (cfg.n_layers, N, cfg.kv_block_size, cfg.n_kv_heads, cfg.head_dim)
+    k_pages = jax.random.normal(ks[1], shape, jnp.float32)
+    v_pages = jax.random.normal(ks[2], shape, jnp.float32)
+    block_tables = jnp.arange(1, N, dtype=jnp.int32).reshape(B, maxB)
+    tokens = jnp.array([3, 17, 42], jnp.int32)
+    positions = jnp.array([40, 0, 15], jnp.int32)  # 3 pages, none, 1 page
+
+    # Both ops take (q, k_pages, v_pages, layer, tables, seq_lens, cur_k, cur_v).
+    attend = (functools.partial(paged_decode_attention_pallas, interpret=True)
+              if use_pallas else paged_decode_attention)
+    want = _decode_step_by_layer_loop(params, cfg, tokens, positions, k_pages,
+                                      v_pages, block_tables, attend)
+    got = llama.decode_step(params, cfg, tokens, positions, k_pages, v_pages,
+                            block_tables, use_pallas=use_pallas,
+                            pallas_interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+    # The step wrote one row a layer and lane, and nothing else.
+    assert int((np.asarray(got[1]) != np.asarray(k_pages)).any(axis=(3, 4)).sum()) \
+        == cfg.n_layers * B
 
 
 def test_engine_pallas_branch_matches_default():
