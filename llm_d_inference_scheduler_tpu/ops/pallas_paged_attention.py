@@ -9,11 +9,32 @@ indices are known before the body runs), DMAs only the blocks that exist
 VMEM. Pattern follows the ragged/paged attention design used by TPU serving
 stacks (PAPERS.md: Ragged Paged Attention, arXiv 2604.15464).
 
-Grid: one program per batch row. Per block: async HBM→VMEM copies of the
-K and V pages (double-buffered: page j+1's DMA is in flight while page j is
-computed), then per-KV-head-group MXU matmuls with f32 accumulation.
+Grid: one program per batch row. A program works in *stages* of P pages
+(P x block tokens). A stage's P K copies and P V copies are started together,
+each page `[block, Hkv, D]` into its `block` rows of a `[P*block, Hkv, D]`
+VMEM tile, and the next stage's 2P copies are in flight while this one is
+computed. The loop runs over the lane's own stages, cdiv(pages it holds, P),
+so a short lane pays for neither the table's width nor a wide stage: pages of
+its last stage past its length are not fetched (their V rows are zeroed, their
+logits masked).
+
+A stage is computed without a loop over the KV heads. The tile is read as
+[P*block*Hkv, D] — row (t, g) is token t's head g, which is how the page lies
+in memory — so one product q[H, D] . tile^T gives every query head against
+every KV head's rows, and a mask keeps for query head h the columns of its
+own KV head (h // q_per_kv) and of positions below the lane's length. One
+max / exp / sum / rescale of the running softmax and one product
+p[H, P*block*Hkv] . tile[P*block*Hkv, D] follow: the MXU is handed the same
+K and V tiles as a per-head loop would hand it, and nothing is re-laid out.
 The current token's K/V arrives as a separate operand (the engine scatters it
-into the pages after the layer scan — see models/llama.py decode_step).
+into the pages after the layer scan — see models/llama.py decode_step) and is
+absorbed the same way, first, while the first stage's copies are in flight.
+
+P is not a setting: `pages_per_stage` takes it from the shapes the call is
+traced with, as the largest power of two for which the two double-buffered
+tiles and their f32 working copies fit `STAGE_VMEM_BYTES`, and no more than
+the table is wide. K and V stay in the pool's dtype in HBM and VMEM; the
+softmax state, the logits and the probabilities are f32.
 
 The pages arrive as the engine holds them: every layer's pool stacked,
 [L, N, block, Hkv, D], left in HBM, with the layer as a third prefetched
@@ -34,104 +55,141 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# What a stage's tiles may take of VMEM: K and V, two slots each, in the
+# pool's dtype, and the f32 copies the products read. A quarter of the 16 MiB
+# a kernel may hold by default; the logits and what the compiler keeps
+# besides are a tenth of the tiles.
+STAGE_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def stage_vmem_bytes(pages: int, block: int, n_kv: int, head_dim: int,
+                     itemsize: int) -> int:
+    """Bytes of VMEM that stages of `pages` pages hold at once."""
+    tile = pages * block * n_kv * head_dim
+    return 2 * 2 * tile * itemsize + 2 * tile * 4
+
+
+def pages_per_stage(block: int, n_kv: int, head_dim: int, itemsize: int,
+                    table_width: int) -> int:
+    """P: the largest power of two whose stage fits STAGE_VMEM_BYTES, at
+    most the table's width and at least one page."""
+    fit = STAGE_VMEM_BYTES // stage_vmem_bytes(1, block, n_kv, head_dim,
+                                               itemsize)
+    p = max(1, min(fit, table_width))
+    return 1 << (p.bit_length() - 1)
+
 
 def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
             q_ref, cur_k_ref, cur_v_ref,  # VMEM blocks per program
             k_hbm, v_hbm,              # stacked page arrays (ANY/HBM)
             out_ref,                   # [1, H, D]
             k_scratch, v_scratch, sem_k, sem_v,
-            *, max_blocks: int, block: int, n_kv: int, q_per_kv: int,
-            head_dim: int):
+            *, max_blocks: int, pages: int, block: int, n_kv: int,
+            q_per_kv: int, head_dim: int):
     b = pl.program_id(0)
     H = n_kv * q_per_kv
+    rows = pages * block                              # tokens a stage
+    cols = rows * n_kv                                # (token, kv head) rows
     scale = 1.0 / (head_dim ** 0.5)
 
     q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
-    q = q.reshape(n_kv, q_per_kv, head_dim)           # [G, qpk, D]
     cached_len = sl_ref[b] - 1                        # rows valid in pages
+    n_pages = pl.cdiv(cached_len, block)
+    n_stages = pl.cdiv(n_pages, pages)
     layer = layer_ref[0]
 
-    m0 = jnp.full((n_kv, q_per_kv, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_kv, q_per_kv, 1), jnp.float32)
-    acc0 = jnp.zeros((n_kv, q_per_kv, head_dim), jnp.float32)
+    def _rows(i):
+        return pl.ds(pl.multiple_of(i * block, block), block)
 
-    # Double-buffered page pipeline: page j+1's HBM→VMEM DMA is in flight
-    # while page j is computed, so the grid's B sequential programs pay DMA
-    # latency once per program instead of once per page (the serial
-    # start/wait version was the decode wall at large batch: B × pages ×
-    # layers blocking latencies per step).
-    def _copies(j, slot):
-        blk = bt_ref[b * max_blocks + j]
-        return (pltpu.make_async_copy(k_hbm.at[layer, blk], k_scratch.at[slot],
-                                      sem_k.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, blk], v_scratch.at[slot],
-                                      sem_v.at[slot]))
+    def _each_page(s, slot, do):
+        """`do(K copy, V copy)` for each page of stage `s` the lane holds.
+        A loop, not an unroll: a stage is bound by its DMAs either way, and
+        the engine traces this body once for every decode bucket."""
+        def page(i, carry):
+            blk = bt_ref[b * max_blocks + s * pages + i]
+            do(pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                     k_scratch.at[slot, _rows(i)],
+                                     sem_k.at[slot]),
+               pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                     v_scratch.at[slot, _rows(i)],
+                                     sem_v.at[slot]))
+            return carry
 
-    @pl.when(0 < cached_len)
+        live = jnp.minimum(pages, n_pages - s * pages)
+        jax.lax.fori_loop(0, live, page, 0)
+        return live
+
+    def _start(s, slot):
+        live = _each_page(s, slot, lambda ck, cv: (ck.start(), cv.start()))
+
+        def zero_v(i, carry):
+            # Never fetched, and 0 x whatever VMEM held must be 0: K's rows
+            # are masked as logits, V's enter the product.
+            v_scratch[slot, _rows(i)] = jnp.zeros((block, n_kv, head_dim),
+                                                  v_scratch.dtype)
+            return carry
+
+        jax.lax.fori_loop(live, pages, zero_v, 0)
+
+    def _wait(s, slot):
+        _each_page(s, slot, lambda ck, cv: (ck.wait(), cv.wait()))
+
+    @pl.when(n_stages > 0)
     def _prologue():
-        ck, cv = _copies(0, 0)
-        ck.start()
-        cv.start()
+        _start(0, 0)
 
-    def block_body(j, carry):
+    # Query head h reads KV head h // q_per_kv: of a tile's rows (t, g),
+    # those with g its own.
+    def _own(n_rows):
+        g_of_row = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (1, n_rows), 1), n_kv)
+        g_of_head = jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0), q_per_kv)
+        return g_of_row == g_of_head                   # [H, n_rows]
+
+    def _absorb(carry, k, v, valid):
+        """One step of the running softmax over the rows of k / v [n, D]
+        that `valid` [H, n] keeps; every head keeps at least one."""
         m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-
-        @pl.when((j + 1) * block < cached_len)
-        def _prefetch_next():
-            ck, cv = _copies(j + 1, jax.lax.rem(j + 1, 2))
-            ck.start()
-            cv.start()
-
-        def compute(m, l, acc):
-            ck, cv = _copies(j, slot)
-            ck.wait()
-            cv.wait()
-            k = k_scratch[slot].astype(jnp.float32)    # [bs, G, D]
-            v = v_scratch[slot].astype(jnp.float32)
-            pos = j * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1)               # [1, bs]
-            valid = pos < cached_len                    # [1, bs]
-            # Static unroll over KV-head groups, rebuilt with stacks (no
-            # .at[].set — Mosaic has no scatter lowering).
-            ms, ls, accs = [], [], []
-            for g in range(n_kv):
-                logits = jnp.dot(q[g], k[:, g, :].T,
-                                 preferred_element_type=jnp.float32)  # [qpk, bs]
-                logits = jnp.where(valid, logits, NEG_INF)
-                blk_max = jnp.max(logits, axis=-1, keepdims=True)
-                new_m = jnp.maximum(m[g], blk_max)
-                p = jnp.exp(logits - new_m) * valid     # re-mask fully-masked rows
-                corr = jnp.exp(m[g] - new_m)
-                ls.append(l[g] * corr + jnp.sum(p, axis=-1, keepdims=True))
-                accs.append(acc[g] * corr + jnp.dot(
-                    p, v[:, g, :], preferred_element_type=jnp.float32))
-                ms.append(new_m)
-            return jnp.stack(ms), jnp.stack(ls), jnp.stack(accs)
-
-        return jax.lax.cond(j * block < cached_len,
-                            lambda: compute(m, l, acc),
-                            lambda: (m, l, acc))
-
-    m, l, acc = jax.lax.fori_loop(0, max_blocks, block_body, (m0, l0, acc0))
-
-    # Current token's KV: always-visible extra column.
-    cur_k = cur_k_ref[0].astype(jnp.float32)          # [G, D]
-    cur_v = cur_v_ref[0].astype(jnp.float32)
-    ls, accs = [], []
-    for g in range(n_kv):
-        logits = jnp.dot(q[g], cur_k[g][:, None],
-                         preferred_element_type=jnp.float32)  # [qpk, 1]
-        new_m = jnp.maximum(m[g], logits)
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [H, n]
+        logits = jnp.where(valid, logits, NEG_INF)
+        new_m = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
         p = jnp.exp(logits - new_m)
-        corr = jnp.exp(m[g] - new_m)
-        ls.append(l[g] * corr + p)
-        accs.append(acc[g] * corr + p * cur_v[g][None, :])
-    l = jnp.stack(ls)
-    acc = jnp.stack(accs)
+        corr = jnp.exp(m - new_m)
+        return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + jnp.dot(p, v,
+                                     preferred_element_type=jnp.float32))
 
-    out = acc / l                                      # [G, qpk, D]
-    out_ref[0] = out.reshape(H, head_dim).astype(out_ref.dtype)
+    # The current token's KV is always visible; absorbed while the first
+    # stage's copies are in flight.
+    carry = _absorb(
+        (jnp.full((H, 1), NEG_INF, jnp.float32),
+         jnp.zeros((H, 1), jnp.float32),
+         jnp.zeros((H, head_dim), jnp.float32)),
+        cur_k_ref[0].astype(jnp.float32), cur_v_ref[0].astype(jnp.float32),
+        _own(n_kv))
+
+    own = _own(cols)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+
+    def stage_body(s, carry):
+        slot = jax.lax.rem(s, 2)
+
+        @pl.when(s + 1 < n_stages)
+        def _prefetch_next():
+            _start(s + 1, 1 - slot)
+
+        _wait(s, slot)
+        k = k_scratch[slot].astype(jnp.float32).reshape(cols, head_dim)
+        v = v_scratch[slot].astype(jnp.float32).reshape(cols, head_dim)
+        # Row (t, g) is position s * rows + t.
+        valid = own & (col < (cached_len - s * rows) * n_kv)
+        return _absorb(carry, k, v, valid)
+
+    _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
+    out_ref[0] = (acc / l).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -151,9 +209,10 @@ def paged_decode_attention_pallas(
     _, _, block, n_kv, _ = k_pages.shape
     maxB = block_tables.shape[1]
     q_per_kv = H // n_kv
+    pages = pages_per_stage(block, n_kv, D, k_pages.dtype.itemsize, maxB)
 
     kernel = functools.partial(
-        _kernel, max_blocks=maxB, block=block, n_kv=n_kv,
+        _kernel, max_blocks=maxB, pages=pages, block=block, n_kv=n_kv,
         q_per_kv=q_per_kv, head_dim=D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -168,8 +227,8 @@ def paged_decode_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, block, n_kv, D), k_pages.dtype),
-            pltpu.VMEM((2, block, n_kv, D), v_pages.dtype),
+            pltpu.VMEM((2, pages * block, n_kv, D), k_pages.dtype),
+            pltpu.VMEM((2, pages * block, n_kv, D), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],

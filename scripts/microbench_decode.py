@@ -2,12 +2,15 @@
 
 Times the pieces of the fused decode step in isolation — forward (layers +
 lm head) without KV writes, the paged-attention kernel, the current-token KV
-scatter, and the sampler — at several batch sizes, so a regression in one
-component is visible without reading a profiler trace. Prints one JSON line
-per (component, B). Everything runs in this one process (one process per
-chip).
+scatter, and the sampler — at several points (lanes x context tokens a lane),
+so a regression in one component is visible without reading a profiler trace.
+Prints one JSON line per (component, point). The kernel's line also gives ms
+a call (one layer), us a page fetched, and the share of the chip's peak
+bandwidth that the bytes the call needs (chipbench/kernels.py) come to.
+Everything runs in this one process (one process per chip).
 
-Usage: python scripts/microbench_decode.py [--model llama3-3b] [--batches 16,32,64]
+Usage: python scripts/microbench_decode.py [--model qwen3-4b]
+           [--points 16x1000,16x300,8x300]
 """
 
 from __future__ import annotations
@@ -36,10 +39,10 @@ def timeit(fn, *args, iters=20):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="llama3-3b")
-    ap.add_argument("--batches", default="16,32,64")
-    ap.add_argument("--ctx", type=int, default=152)
-    ap.add_argument("--max-model-len", type=int, default=512)
+    ap.add_argument("--model", default="qwen3-4b")
+    ap.add_argument("--points", default="16x1000,16x300,8x300",
+                    help="lanes x context tokens a lane, comma-separated")
+    ap.add_argument("--max-model-len", type=int, default=1024)
     args = ap.parse_args(argv)
 
     import jax
@@ -59,11 +62,19 @@ def main(argv=None):
         paged_decode_attention_pallas,
     )
 
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench"))
+    import kernels  # the benchmark's yardstick: peaks, bytes a call needs
+
     mcfg = get_config(args.model)
     block = mcfg.kv_block_size
     params = llama.init_params(mcfg, jax.random.key(0))
 
-    for B in [int(b) for b in args.batches.split(",")]:
+    def report(component, B, ctx, ms, **more):
+        print(json.dumps({"component": component, "B": B, "ctx": ctx,
+                          "ms_per_step": round(ms, 3), **more}), flush=True)
+
+    for B, ctx in [map(int, pt.split("x")) for pt in args.points.split(",")]:
         max_blocks = args.max_model_len // block
         n_blocks = 1 + B * max_blocks
         L, G, D = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
@@ -74,7 +85,7 @@ def main(argv=None):
             tables[b] = np.arange(1 + b * max_blocks, 1 + (b + 1) * max_blocks)
         tables = jnp.asarray(tables)
         tokens = jnp.ones((B,), jnp.int32)
-        positions = jnp.full((B,), args.ctx, jnp.int32)
+        positions = jnp.full((B,), ctx, jnp.int32)
 
         # full decode step: scan of 8 steps (keeps the production scan +
         # donation semantics), reported per-step. params passed as an
@@ -91,13 +102,12 @@ def main(argv=None):
             return ls.sum()
 
         ms = timeit(jax.jit(chain), params, k_pages, v_pages, iters=5) / 8
-        print(json.dumps({"component": "decode_step(all)", "B": B,
-                          "ms_per_step": round(ms, 3)}))
+        report("decode_step(all)", B, ctx, ms)
 
         # attention kernel alone
         q = jnp.ones((B, mcfg.n_heads, D), jnp.bfloat16)
         cur = jnp.ones((B, G, D), jnp.bfloat16)
-        seq_lens = jnp.full((B,), args.ctx + 1, jnp.int32)
+        seq_lens = jnp.full((B,), ctx + 1, jnp.int32)
 
         # The stacked pools and a layer index, as decode_step calls it.
         def attn_chain(q, k_pages, v_pages):
@@ -111,8 +121,14 @@ def main(argv=None):
             return acc
 
         ms = timeit(jax.jit(attn_chain), q, k_pages, v_pages, iters=5)
-        print(json.dumps({"component": f"pallas_attn x{mcfg.n_layers}L", "B": B,
-                          "ms_per_step": round(ms, 3)}))
+        call_s = ms / L * 1e-3
+        need = kernels.paged_attention_decode(B * ctx, B, mcfg.n_heads, G, D)
+        peak = kernels.peaks(jax.devices()[0].device_kind)["bytes_per_s"]
+        report(f"pallas_attn x{L}L", B, ctx, ms,
+               ms_per_call=round(call_s * 1e3, 4),
+               us_per_page=round(call_s * 1e6 / (B * -(-ctx // block)), 4),
+               peak_bandwidth_share_pct=round(
+                   100 * need["bytes"] / call_s / peak, 2))
 
         # current-token KV scatter alone (all layers fused, K+V)
         k_cur = jnp.ones((L, B, G, D), jnp.bfloat16)
@@ -130,8 +146,7 @@ def main(argv=None):
             return kp[0, 0, 0, 0, 0]
 
         ms = timeit(jax.jit(scatter_chain), k_pages, v_pages, iters=5) / 8
-        print(json.dumps({"component": "kv_scatter(K+V, all L)", "B": B,
-                          "ms_per_step": round(ms, 3)}))
+        report("kv_scatter(K+V, all L)", B, ctx, ms)
 
         # sampler alone
         logits = jnp.ones((B, mcfg.vocab_size), jnp.float32)
@@ -149,8 +164,7 @@ def main(argv=None):
             return acc
 
         ms = timeit(jax.jit(samp_chain), logits, iters=5) / 8
-        print(json.dumps({"component": "sample_tokens", "B": B,
-                          "ms_per_step": round(ms, 3)}))
+        report("sample_tokens", B, ctx, ms)
 
 
 if __name__ == "__main__":

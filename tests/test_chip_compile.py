@@ -29,6 +29,7 @@ from llm_d_inference_scheduler_tpu.models.configs import MIXTRAL_8X7B, QWEN3_4B
 from llm_d_inference_scheduler_tpu.ops import pallas_moe
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
     paged_decode_attention_pallas,
+    pages_per_stage,
 )
 
 
@@ -64,12 +65,21 @@ def test_paged_attention_compiles_at_qwen3_4b(one_chip, batch, table_width):
     pages = _sds(one_chip, (m.n_layers, 2049, m.kv_block_size, m.n_kv_heads,
                             m.head_dim), dt)
     cur = _sds(one_chip, (batch, m.n_kv_heads, m.head_dim), dt)
-    compiled = paged_decode_attention_pallas.lower(
-        _sds(one_chip, (batch, m.n_heads, m.head_dim), dt), pages, pages,
-        _sds(one_chip, (), jnp.int32),
-        _sds(one_chip, (batch, table_width), jnp.int32),
-        _sds(one_chip, (batch,), jnp.int32), cur, cur).compile()
+    args = (_sds(one_chip, (batch, m.n_heads, m.head_dim), dt), pages, pages,
+            _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (batch, table_width), jnp.int32),
+            _sds(one_chip, (batch,), jnp.int32), cur, cur)
+    compiled = paged_decode_attention_pallas.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # A step of the kernel is a stage of P pages: the call's K and V scratch
+    # hold two tiles of P x 16 rows each, and the traced call says so. A
+    # kernel back at a page a step has `bf16[2,16,8,128]` here.
+    p = pages_per_stage(m.kv_block_size, m.n_kv_heads, m.head_dim,
+                        dt.itemsize, table_width)
+    assert p >= 8
+    tile = (f"Ref<vmem>{{bf16[2,{p * m.kv_block_size},{m.n_kv_heads},"
+            f"{m.head_dim}]}}")
+    assert tile in str(jax.make_jaxpr(paged_decode_attention_pallas)(*args))
 
 
 def test_decode_step_copies_no_layers_page_pool(one_chip):

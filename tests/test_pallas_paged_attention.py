@@ -13,19 +13,57 @@ import numpy as np
 import pytest
 
 from llm_d_inference_scheduler_tpu.models import llama
-from llm_d_inference_scheduler_tpu.models.configs import ModelConfig
+from llm_d_inference_scheduler_tpu.models.configs import (
+    MIXTRAL_8X7B,
+    QWEN3_4B,
+    ModelConfig,
+)
 from llm_d_inference_scheduler_tpu.ops import apply_rope, rms_norm, rope_table
 from llm_d_inference_scheduler_tpu.ops.attention import paged_decode_attention
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
+    STAGE_VMEM_BYTES,
     paged_decode_attention_pallas,
+    pages_per_stage,
+    stage_vmem_bytes,
 )
 
 
-@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
-@pytest.mark.parametrize("seq_lens_spec", [[5], [17, 3], [33, 1, 16]])
-def test_pallas_matches_xla_reference(seq_lens_spec, layer):
+# Heads and table of the first cases: 8 Q / 2 KV heads of 32, a table of 4
+# pages, which a stage's P clamps to. Of the staged cases: the cells' KV
+# heads (8 of 128), f32 pages, so P = 8 falls out of the VMEM budget, and a
+# table three stages wide (or narrower than one).
+_SMALL = dict(H=8, Hkv=2, D=32, maxB=4)
+_STAGED = dict(H=16, Hkv=8, D=128, maxB=24)
+_NARROW = dict(H=16, Hkv=8, D=128, maxB=3)
+
+
+def _stage_tokens(dims, block=16):
+    return block * pages_per_stage(block, dims["Hkv"], dims["D"], 4,
+                                   dims["maxB"])
+
+
+@pytest.mark.parametrize("dims,seq_lens_of,layer", [
+    *[pytest.param(_SMALL, lambda s, spec=spec: spec, layer,
+                   id=f"{name}-seq_lens_spec{i}")
+      for layer, name in enumerate(["first", "middle", "last"])
+      for i, spec in enumerate([[5], [17, 3], [33, 1, 16]])],
+    # seq_lens count the current token: the pages hold one row fewer.
+    pytest.param(_STAGED, lambda s: [s + 1], 1, id="one-whole-stage"),
+    pytest.param(_STAGED, lambda s: [s + 2, s], 2,
+                 id="one-row-into-the-second-stage-and-one-short-of-it"),
+    pytest.param(_STAGED, lambda s: [3 * s - 5, 1, 2], 0,
+                 id="three-stages-beside-an-empty-lane-and-a-one-token-lane"),
+    pytest.param(_STAGED, lambda s: [2 * s + 1, s + 17, 40], 1,
+                 id="last-stages-of-zero-one-and-three-pages"),
+    pytest.param(_NARROW, lambda s: [48, 33, 7], 2,
+                 id="stage-clamped-by-a-three-page-table"),
+])
+def test_pallas_matches_xla_reference(dims, seq_lens_of, layer):
+    H, Hkv, D, maxB = dims["H"], dims["Hkv"], dims["D"], dims["maxB"]
+    L, block = 3, 16
+    seq_lens_spec = seq_lens_of(_stage_tokens(dims, block))
+    assert max(seq_lens_spec) - 1 <= maxB * block
     B = len(seq_lens_spec)
-    L, H, Hkv, D, block, maxB = 3, 8, 2, 32, 16, 4
     N = 1 + B * maxB
     key = jax.random.key(0)
     ks = jax.random.split(key, 5)
@@ -49,6 +87,40 @@ def test_pallas_matches_xla_reference(seq_lens_spec, layer):
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_staged_cases_cross_a_stage_and_the_narrow_one_is_clamped():
+    """What the cases above lean on: at their shapes a stage is 8 pages of a
+    24-page table, and 2 pages of a 3-page one."""
+    assert _stage_tokens(_STAGED) == 8 * 16
+    assert _stage_tokens(_NARROW) == 2 * 16
+    assert _stage_tokens(_SMALL) == 4 * 16
+
+
+@pytest.mark.parametrize("m", [QWEN3_4B, MIXTRAL_8X7B],
+                         ids=lambda m: m.name)
+def test_pages_per_stage_at_the_cells_shapes(m):
+    """Both cells serve --max-model-len 2048: bf16 pages of 16 tokens, 8 KV
+    heads of 128, a table 128 wide. P is what the kernel's gain rests on
+    (one page a step was 7-9% of the roofline), and its tiles must fit the
+    budget stated beside it, well inside the 16 MiB a kernel may hold."""
+    itemsize = jnp.dtype(m.dtype).itemsize
+    table_width = 2048 // m.kv_block_size
+    assert (m.kv_block_size, m.n_kv_heads, m.head_dim, itemsize) == (16, 8, 128, 2)
+    pages = pages_per_stage(m.kv_block_size, m.n_kv_heads, m.head_dim,
+                            itemsize, table_width)
+    assert pages == 16
+    tile = pages * m.kv_block_size * m.n_kv_heads * m.head_dim
+    # K and V, two slots each, as stored; and the f32 copy of each.
+    held = 4 * tile * itemsize + 2 * tile * 4
+    assert held == stage_vmem_bytes(pages, m.kv_block_size, m.n_kv_heads,
+                                    m.head_dim, itemsize)
+    assert held <= STAGE_VMEM_BYTES <= 16 * 1024 * 1024 // 2
+    # A stage twice as long would not fit; a narrower table clamps it.
+    assert stage_vmem_bytes(2 * pages, m.kv_block_size, m.n_kv_heads,
+                            m.head_dim, itemsize) > STAGE_VMEM_BYTES
+    assert pages_per_stage(m.kv_block_size, m.n_kv_heads, m.head_dim,
+                           itemsize, 5) == 4
 
 
 def test_pallas_trash_block_slots_isolated():
