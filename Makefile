@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: test test-fast test-unit test-dist test-chaos bench bench-flowcontrol \
+.PHONY: test test-fast test-unit test-dist test-chaos bench-flowcontrol \
 	bench-router-sse bench-decisions bench-sched bench-sched-offload \
 	bench-scaleout bench-slo bench-overload bench-kvobs bench-multiturn \
 	bench-timeline bench-fleet-chaos bench-shadow bench-rebalance \
@@ -247,10 +247,8 @@ test-chaos: verify-metrics
 	CHAOS_SEED=11 $(PY) -m pytest tests/test_autoscale.py -q \
 		-k TestLifecycleChaos
 
-# Serving benchmark on the real chip (one JSON line; the driver's entry).
-bench:
-	$(PY) bench.py
-
+# The chip benchmark is `python3 chipbench/run.py` (BENCHMARK.json, PERF.md);
+# the bench-* targets here are SimEngine scenarios on the CPU.
 bench-flowcontrol:
 	$(PY) scripts/flowcontrol_bench.py
 

@@ -1,42 +1,15 @@
-"""Benchmark: serving throughput + TTFT on one real chip, with a denominator.
+"""Scenario benchmarks of the router's control plane on `SimEngine`s (CPU).
 
-Prints ONE JSON line: {"metric", "value", "unit", "device", ...}, and
-writes the full measurement matrix to benchmarks/BENCH_full.json. Without a
-TPU it prints an error line and exits non-zero: nothing here falls back to
-the CPU or to a smaller model.
+Each `--<scenario>` mode boots gateways and simulated engines in this
+process or in CPU children, drives one scenario, and writes its record to
+`benchmarks/<NAME>.json` (the `bench-*` targets of the Makefile). Nothing
+here needs or measures a chip: what `SimEngine` sleeps is a control-flow
+proof, never a speed (PERF.md).
 
-What is measured (the BASELINE.md north-star quantities at single-chip scale):
-
-- **Engine-direct sweep**: aggregate decode tokens/sec/chip through the full
-  continuous-batching engine (paged KV, jitted prefill buckets + fused decode
-  chunks) across (model, batch) configs — llama3-1b and llama3-3b (the
-  lane-aligned head_dim=128 config where the Pallas paged-attention kernel is
-  live in the served path), batch 16/32/64.
-- **HBM-bandwidth utilization**: decode at batch sizes this small is
-  weight-read bound, so the roofline denominator is param-bytes + KV-read
-  bytes per decode step × measured steps/s vs the HBM bandwidth published
-  for the device the child ran on (HBM_GBPS_BY_DEVICE_KIND; a device with
-  no entry is an error, not a default). Prefill traffic is excluded → the
-  figure slightly *under*-states true utilization.
-- **Uncontended TTFT**: single request against an idle engine (pure
-  dispatch + prefill, no queueing) — the comparator for the ≤2× disagg TTFT
-  target (BASELINE.md).
-- **Router-in-the-loop**: the same engine behind the full gateway (flow
-  control on, prefix + kv-utilization + queue scorers, streaming SSE proxy)
-  driven over real HTTP. Reports through-router tokens/s + TTFT and the
-  scheduler's per-request latency scraped from
-  inference_extension_scheduler_e2e_duration_seconds — the router overhead
-  is a captured number, not an inference.
-
-The reference publishes no numbers (BASELINE.md; its harness is the rate
-sweep at /root/reference/config/manifests/benchmark/benchmark.yaml:19-47 —
-reproduced by scripts/loadgen.py, artifact in benchmarks/).
-
-One process per chip: this parent never imports JAX; every measurement runs
-in a child process, one at a time (the probe child has exited before the
-first bench child starts), under a watchdog and an overall deadline
-(BENCH_DEADLINE, default 2700 s). Compiles are cached persistently where
-utils/compile_cache.py says, so re-runs are much cheaper than first runs.
+The chip benchmark is `python3 chipbench/run.py` (BENCHMARK.json, PERF.md);
+`python chip_smoke.py` is the proof that the served path runs on the chip.
+This file's own chip mode (a best-of sweep with an HBM-utilization figure)
+was deleted in PR 30. ROADMAP D1 holds what remains.
 """
 
 from __future__ import annotations
@@ -46,334 +19,6 @@ import os
 import subprocess
 import sys
 import time
-
-# Peak HBM bandwidth per chip in GB/s, keyed by jax's device_kind. Source:
-# Google Cloud documentation, "TPU v5e" (819 GB/s). A device that is not
-# here has no utilization figure: the child errors instead of dividing by
-# another chip's peak.
-HBM_GBPS_BY_DEVICE_KIND = {"TPU v5 lite": 819.0}
-
-# Engine-direct sweep, most-important first (the parent stops when the
-# deadline nears and reports the best completed config).
-DEFAULT_SWEEP = "llama3-3b:64,llama3-3b:32,llama3-3b:16,llama3-1b:16,llama3-1b:32"
-
-
-def _engine_bytes_per_step(mcfg, batch: int, avg_ctx: float) -> float:
-    """HBM bytes read per decode step: all weights once + the active KV
-    history for every slot. bf16 = 2 bytes."""
-    # Params: embed + lm head + per-layer attn (q,k,v,o) + ffn (3 mats) +
-    # norms (negligible). Computed from the config rather than the live tree
-    # so the child does not have to fetch device buffers.
-    d, L = mcfg.d_model, mcfg.n_layers
-    kv_dim = mcfg.n_kv_heads * mcfg.head_dim
-    # q/o projections are d × (n_heads*head_dim) — NOT d×d when head_dim is
-    # overridden (Qwen3-style configs decouple them; ADVICE r4).
-    q_dim = mcfg.n_heads * mcfg.head_dim
-    per_layer = 2 * d * q_dim + 2 * d * kv_dim + 3 * d * mcfg.d_ff
-    if mcfg.n_experts:
-        # Only the experts activated this step are read from HBM: k per
-        # token, deduped across the batch (upper-bounded by the expert
-        # count), plus the router matrix.
-        active = min(mcfg.n_experts, batch * mcfg.experts_per_token)
-        per_layer = (2 * d * q_dim + 2 * d * kv_dim
-                     + d * mcfg.n_experts + active * 3 * d * mcfg.d_ff)
-    params = 2 * mcfg.vocab_size * d + L * per_layer
-    kv_read = batch * avg_ctx * L * 2 * kv_dim
-    return 2.0 * (params + kv_read)
-
-
-def child(model: str, batch: int) -> None:
-    import asyncio
-    import statistics
-
-    import jax
-
-    from llm_d_inference_scheduler_tpu.utils.compile_cache import (
-        configure_compile_cache,
-    )
-
-    configure_compile_cache()
-    device = {"platform": jax.devices()[0].platform,
-              "kind": jax.devices()[0].device_kind,
-              "count": len(jax.devices())}
-    if device["platform"] != "tpu":
-        raise SystemExit(f"bench child needs a TPU; JAX opened {device}")
-    if device["kind"] not in HBM_GBPS_BY_DEVICE_KIND:
-        raise SystemExit(
-            f"no published HBM peak for device_kind {device['kind']!r} in "
-            "HBM_GBPS_BY_DEVICE_KIND; add it with its source")
-    hbm_peak_gbps = HBM_GBPS_BY_DEVICE_KIND[device["kind"]]
-
-    from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
-    from llm_d_inference_scheduler_tpu.models.configs import get_config
-
-    prompt_len = int(os.environ.get("BENCH_PROMPT", "120"))
-    gen_tokens = int(os.environ.get("BENCH_GEN", "64"))
-    n_requests = int(os.environ.get("BENCH_REQUESTS", str(2 * batch)))
-    decode_chunk = int(os.environ.get("BENCH_CHUNK", "16"))
-    run_router = os.environ.get("BENCH_ROUTER", "0") == "1"
-
-    pallas_env = os.environ.get("BENCH_PALLAS", "auto")
-    cfg = EngineConfig(model=model, backend="tpu", max_batch=batch,
-                       max_model_len=int(os.environ.get("BENCH_MODEL_LEN",
-                                                        "512")),
-                       decode_chunk=decode_chunk,
-                       pallas_attention=(None if pallas_env == "auto"
-                                         else pallas_env == "1"),
-                       decode_ctx_buckets=os.environ.get(
-                           "BENCH_CTX_BUCKETS", "0") == "1",
-                       # Amortize prefill weight passes across prompts
-                       # (prefill is HBM-bound at bench prompt lengths).
-                       prefill_batch=int(os.environ.get("BENCH_PREFILL_BATCH",
-                                                        "4")),
-                       # Long-context scenarios (BENCH_PROMPT >> default):
-                       # window the prefill so decode lanes keep moving.
-                       prefill_chunk=int(os.environ.get("BENCH_PREFILL_CHUNK",
-                                                        "0")),
-                       # BENCH_WARMUP=0: lazy compiles only (the buckets the
-                       # run actually touches); the full matrix is a
-                       # whole-model compile per bucket.
-                       warmup=os.environ.get("BENCH_WARMUP", "1") == "1")
-
-    async def run():
-        eng = TpuEngine(cfg)
-        server = None
-        if run_router:
-            # One engine shared between the direct and router phases (two
-            # engines would double weight HBM and not fit at 3b geometry).
-            from llm_d_inference_scheduler_tpu.engine.server import EngineServer
-
-            srv_cfg = EngineConfig(**{**cfg.__dict__, "port": 18461,
-                                      "warmup": False})
-            server = EngineServer(srv_cfg, engine=eng)
-            await server.start()  # starts the engine thread exactly once
-        else:
-            await eng.start()
-        try:
-            async def one(i, max_tokens, record):
-                prompt = [1] + [(7 * i + j) % 1000 + 10 for j in range(prompt_len - 1)]
-                req = EngineRequest(request_id=f"b{i}-{max_tokens}",
-                                    prompt_token_ids=prompt,
-                                    max_tokens=max_tokens,
-                                    ignore_eos=True)
-                t0 = time.monotonic()
-                out = eng.submit(req)
-                first = None
-                completion = 0
-                while True:
-                    ev = await out.get()
-                    if ev.token_id is not None and first is None:
-                        first = time.monotonic() - t0
-                    completion = max(completion, ev.completion_tokens)
-                    if ev.finish_reason is not None:
-                        break
-                if record is not None:
-                    record.append((first, completion))
-
-            # Compile the measured prefill bucket — a simultaneous burst so
-            # the batched [prefill_batch, S] shape compiles now, not inside
-            # the measured window.
-            await asyncio.gather(*[one(i - 100, 2, None) for i in range(
-                max(cfg.prefill_batch, 1))])
-
-            # -- engine-direct load phase -------------------------------
-            record: list[tuple[float, int]] = []
-            t_start = time.monotonic()
-            await asyncio.gather(*[one(i + 1, gen_tokens, record)
-                                   for i in range(n_requests)])
-            elapsed = time.monotonic() - t_start
-
-            # -- uncontended TTFT (idle engine, sequential) -------------
-            unc: list[tuple[float, int]] = []
-            for i in range(5):
-                await one(1000 + i, 2, unc)
-            ttft_unc = statistics.median(t for t, _ in unc if t is not None)
-
-            router = None
-            if run_router:
-                router = await router_phase(server, cfg, prompt_len,
-                                            gen_tokens, n_requests)
-        finally:
-            if server is not None:
-                await server.stop()
-            else:
-                await eng.stop()
-
-        total_tokens = sum(c for _, c in record)
-        ttfts = sorted(t for t, _ in record if t is not None)
-        tok_s = total_tokens / elapsed
-        mcfg = get_config(model)
-        avg_ctx = prompt_len + gen_tokens / 2.0
-        steps_s = tok_s / batch  # every fused step advances all busy slots
-        gbps = _engine_bytes_per_step(mcfg, batch, avg_ctx) * steps_s / 1e9
-        res = {
-            "device": device,
-            "model": model, "max_batch": batch, "prompt_len": prompt_len,
-            "gen_tokens": gen_tokens, "n_requests": n_requests,
-            "tokens_per_sec": round(tok_s, 2),
-            "ttft_p50_ms": round(statistics.median(ttfts) * 1e3, 1),
-            "ttft_p99_ms": round(
-                ttfts[min(len(ttfts) - 1, int(len(ttfts) * 0.99))] * 1e3, 1),
-            "ttft_uncontended_p50_ms": round(ttft_unc * 1e3, 1),
-            "hbm_gbps": round(gbps, 1),
-            "hbm_bw_util": round(gbps / hbm_peak_gbps, 3),
-        }
-        if router is not None:
-            res["router"] = router
-        return res
-
-    print(json.dumps(asyncio.run(run())))
-
-
-async def router_phase(server, engine_cfg, prompt_len: int, gen_tokens: int,
-                       n_requests: int) -> dict:
-    """Full stack on-chip: gateway (flowControl + default scorer profile:
-    prefix w=3, kv-utilization w=2, queue w=2) → HTTP/SSE → engine server →
-    the same TpuEngine the direct phase measured. Captures through-router
-    throughput/TTFT plus the scheduler's own per-request latency from the
-    router's Prometheus histogram (sum/count of
-    scheduler_e2e_duration_seconds)."""
-    import asyncio
-    import random
-    import statistics
-
-    import httpx
-
-    from llm_d_inference_scheduler_tpu.router import tracing
-    from llm_d_inference_scheduler_tpu.router.gateway import build_gateway
-
-    # Full-sample tracing for the measured window: the span ring buffer
-    # yields the per-phase breakdown (gateway / orchestration / engine
-    # prefill+decode) so router-vs-engine latency attribution is a captured
-    # number, not an inference. Restored afterwards.
-    trace_prev = (tracing.tracer.enabled, tracing.tracer.sample_ratio)
-    tracing.tracer.enabled, tracing.tracer.sample_ratio = True, 1.0
-    tracing.tracer.finished.clear()
-
-    eport, gport = 18461, 18460
-    gw = build_gateway(
-        f"""
-featureGates: {{flowControl: true}}
-pool:
-  endpoints:
-    - {{address: 127.0.0.1, port: {eport}}}
-""",
-        port=gport, poll_interval=0.05)
-    await gw.start()
-    rng = random.Random(0)
-    try:
-        ready = False
-        async with httpx.AsyncClient(timeout=5) as probe:
-            for _ in range(100):  # wait for first metrics poll / readiness
-                try:
-                    if (await probe.get(
-                            f"http://127.0.0.1:{gport}/health")).status_code == 200:
-                        ready = True
-                        break
-                except httpx.HTTPError:
-                    pass
-                await asyncio.sleep(0.1)
-        if not ready:
-            return {"error": "gateway never became ready"}
-        results: list[dict] = []
-
-        # aiohttp measurement client: the through-router phase pays for the
-        # client, engine server, AND proxy on one GIL (direct-phase tokens
-        # never touch HTTP), so client parser cost suppresses the router
-        # number. httpx/h11 costs ~260 µs/token of CPU here; aiohttp's C
-        # parser ~60 µs (scripts/profile_router_sse.py).
-        import aiohttp
-
-        async def one(client):
-            # unique head so prefills don't collapse onto one cached prefix
-            head = f"r{rng.randint(0, 1 << 30):010d} "
-            prompt = head + "x" * max(prompt_len - len(head), 1)
-            t0 = time.monotonic()
-            ttft = None
-            events = 0
-            usage_tokens = 0
-            async with client.post(
-                    f"http://127.0.0.1:{gport}/v1/completions",
-                    json={"model": engine_cfg.model, "prompt": prompt,
-                          "stream": True, "max_tokens": gen_tokens,
-                          "ignore_eos": True}) as r:
-                async for line in r.content:
-                    if line.startswith(b"data: ") and not line.startswith(
-                            b"data: [DONE]"):
-                        if ttft is None:
-                            ttft = time.monotonic() - t0
-                        events += 1
-                        if b'"usage"' in line:
-                            # Authoritative count: the engine coalesces
-                            # token bursts into one SSE delta under load,
-                            # so events != tokens.
-                            try:
-                                u = json.loads(line[6:]).get("usage") or {}
-                                usage_tokens = int(
-                                    u.get("completion_tokens") or 0)
-                            except Exception:
-                                pass
-            results.append({"ttft": ttft,
-                            "tokens": usage_tokens or events,
-                            "latency": time.monotonic() - t0})
-
-        async with aiohttp.ClientSession(
-                timeout=aiohttp.ClientTimeout(total=300)) as client:
-            await one(client)  # warm the HTTP path + compile
-            results.clear()
-            t0 = time.monotonic()
-            # return_exceptions: one transient HTTP failure must not void
-            # the whole child (and its already-measured direct phase).
-            errs = [e for e in await asyncio.gather(
-                *[one(client) for _ in range(n_requests)],
-                return_exceptions=True) if isinstance(e, Exception)]
-            elapsed = time.monotonic() - t0
-
-        async with httpx.AsyncClient(timeout=30) as client:
-            metrics_text = (await client.get(
-                f"http://127.0.0.1:{gport}/metrics")).text
-        sched_sum = sched_count = 0.0
-        for line in metrics_text.splitlines():
-            if line.startswith(
-                    "inference_extension_scheduler_e2e_duration_seconds_sum"):
-                sched_sum = float(line.split()[-1])
-            elif line.startswith(
-                    "inference_extension_scheduler_e2e_duration_seconds_count"):
-                sched_count = float(line.split()[-1])
-
-        # Per-phase latency attribution from the span ring buffer: mean/p50
-        # duration per span name across the measured window (gateway.request
-        # = full router pass, engine.prefill/engine.decode = engine phases —
-        # all components share the in-process tracer here).
-        by_name: dict[str, list[float]] = {}
-        for s in tracing.tracer.snapshot():
-            by_name.setdefault(s["name"], []).append(float(s["duration_ms"]))
-        span_breakdown = {
-            name: {"n": len(v),
-                   "mean_ms": round(sum(v) / len(v), 2),
-                   "p50_ms": round(statistics.median(v), 2)}
-            for name, v in sorted(by_name.items())}
-
-        ok = [r for r in results if r["ttft"] is not None]
-        ttfts = sorted(r["ttft"] for r in ok)
-        if not ttfts:
-            return {"error": "no request produced a token through the router",
-                    "request_errors": len(errs) + (len(results) - len(ok))}
-        return {
-            "tokens_per_sec": round(sum(r["tokens"] for r in ok) / elapsed, 2),
-            "ttft_p50_ms": round(statistics.median(ttfts) * 1e3, 1),
-            "ttft_p99_ms": round(
-                ttfts[min(len(ttfts) - 1, int(len(ttfts) * 0.99))] * 1e3, 1),
-            "sched_e2e_mean_ms": round(
-                sched_sum / sched_count * 1e3, 3) if sched_count else None,
-            "span_breakdown_ms": span_breakdown,
-            "n_requests": n_requests,
-            "request_errors": len(errs) + (len(results) - len(ok)),
-        }
-    finally:
-        tracing.tracer.enabled, tracing.tracer.sample_ratio = trace_prev
-        await gw.stop()
 
 
 def sched_microbench(quick: bool = False) -> dict:
@@ -5816,9 +5461,6 @@ def pd_pipeline_bench(quick: bool = False) -> dict:
 
 
 def main() -> None:
-    if len(sys.argv) > 3 and sys.argv[1] == "--child":
-        child(sys.argv[2], int(sys.argv[3]))
-        return
     if len(sys.argv) > 2 and sys.argv[1] == "--scaleout-child":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         sched_scaleout_child(sys.argv[2])
@@ -5967,111 +5609,9 @@ def main() -> None:
             json.dump(res, f, indent=1)
         return
 
-    deadline = time.monotonic() + float(os.environ.get("BENCH_DEADLINE", "2700"))
-    here = os.path.dirname(os.path.abspath(__file__))
-
-    def fail(why: str) -> None:
-        print(json.dumps({"metric": "decode_tokens_per_sec_per_chip",
-                          "value": 0.0, "unit": "tokens/s/chip",
-                          "error": why}))
-        sys.exit(1)
-
-    # Fail fast without a TPU: the probe child names the platform JAX opened
-    # and has exited (freeing the chip) before the first bench child starts.
-    try:
-        probe = subprocess.run(
-            [sys.executable, "-c",
-             "import jax; print('platform=' + jax.devices()[0].platform)"],
-            capture_output=True, text=True, timeout=120)
-    except subprocess.TimeoutExpired:
-        fail("TPU probe did not answer in 120 s")
-    if "platform=tpu" not in probe.stdout:
-        fail("no TPU: probe said "
-             f"{(probe.stdout.strip() or probe.stderr[-500:])!r}")
-
-    def run_child(model: str, batch: int, timeout_s: float,
-                  router: bool = False) -> dict | None:
-        env = dict(os.environ)
-        if router:
-            env["BENCH_ROUTER"] = "1"
-        try:
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--child",
-                 model, str(batch)],
-                capture_output=True, text=True, timeout=timeout_s, env=env)
-        except subprocess.TimeoutExpired:
-            print(f"bench child {model}:{batch} exceeded {timeout_s:.0f}s",
-                  file=sys.stderr)
-            return None
-        if proc.returncode == 0 and proc.stdout.strip():
-            try:
-                return json.loads(proc.stdout.strip().splitlines()[-1])
-            except json.JSONDecodeError:
-                pass
-        print(f"bench child {model}:{batch} failed rc={proc.returncode}:\n"
-              f"{proc.stderr[-2000:]}", file=sys.stderr)
-        return None
-
-    sweep_spec = os.environ.get("BENCH_SWEEP", DEFAULT_SWEEP)
-    per_child = float(os.environ.get("BENCH_TIMEOUT", "900"))
-    sweep: list[dict] = []
-    for item in sweep_spec.split(","):
-        model, _, bs = item.strip().partition(":")
-        budget = min(per_child, deadline - time.monotonic())
-        if budget < 120:
-            print(f"bench deadline: skipping {item}", file=sys.stderr)
-            continue
-        res = run_child(model, int(bs or 16), budget)
-        if res:
-            sweep.append(res)
-
-    if not sweep:
-        fail("all bench candidates failed")
-
-    # Copy: the merge below must not mutate the recorded sweep entry.
-    best = dict(max(sweep, key=lambda r: r["tokens_per_sec"]))
-
-    # Router-in-the-loop on the best engine config (budget permitting).
-    router = None
-    budget = min(per_child + 120, deadline - time.monotonic())
-    if budget >= 180:
-        res = run_child(best["model"], best["max_batch"], budget, router=True)
-        if res:
-            router = res.get("router")
-            if router and router.get("error"):
-                print(f"router phase failed: {router}", file=sys.stderr)
-                router = None
-            if res["tokens_per_sec"] > best["tokens_per_sec"]:
-                for k in ("tokens_per_sec", "ttft_p50_ms", "ttft_p99_ms",
-                          "ttft_uncontended_p50_ms", "hbm_gbps", "hbm_bw_util"):
-                    best[k] = res[k]
-
-    full = {"sweep": sweep, "best": best, "router": router,
-            "hbm_roofline_gbps":
-                HBM_GBPS_BY_DEVICE_KIND[best["device"]["kind"]]}
-    os.makedirs(os.path.join(here, "benchmarks"), exist_ok=True)
-    with open(os.path.join(here, "benchmarks", "BENCH_full.json"), "w") as f:
-        json.dump(full, f, indent=1)
-
-    out = {
-        "metric": (f"decode_tokens_per_sec_per_chip ({best['model']}, "
-                   f"bs={best['max_batch']}, prompt={best['prompt_len']}, "
-                   f"gen={best['gen_tokens']})"),
-        "value": best["tokens_per_sec"],
-        "unit": "tokens/s/chip",
-        "device": best["device"],
-        "ttft_p50_ms": best["ttft_p50_ms"],
-        "ttft_p99_ms": best["ttft_p99_ms"],
-        "ttft_uncontended_p50_ms": best["ttft_uncontended_p50_ms"],
-        "hbm_bw_util": best["hbm_bw_util"],
-        "sweep": [{k: r[k] for k in ("model", "max_batch", "tokens_per_sec",
-                                     "ttft_p50_ms", "hbm_bw_util")}
-                  for r in sweep],
-    }
-    if router:
-        out["router"] = router
-    print(json.dumps(out))
-
+    print("bench.py: name a scenario mode (--sched-microbench, --tails, ...); "
+          "the chip benchmark is `python3 chipbench/run.py`", file=sys.stderr)
+    sys.exit(2)
 
 if __name__ == "__main__":
     main()

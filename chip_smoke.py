@@ -500,6 +500,7 @@ def kernel_check(seed: int) -> int:
     import numpy as np
 
     sys.path.insert(0, REPO)
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
     from llm_d_inference_scheduler_tpu.models.configs import QWEN3_4B as m
     from llm_d_inference_scheduler_tpu.ops.attention import (
         paged_decode_attention,
@@ -522,15 +523,15 @@ def kernel_check(seed: int) -> int:
     # the engine holds them; two layers here, each its own draw, and the
     # kernel reads the second: the reference is handed that layer's pool
     # alone, so a kernel that ignored the index would not match.
-    batch, width, block, layers, layer = 16, 128, m.kv_block_size, 2, 1
-    dt = jnp.dtype(m.dtype)
+    batch, width, layers, layer = 16, 128, 2, 1
+    geom = PageGeometry.for_model(dataclasses.replace(m, n_layers=layers),
+                                  1 + batch * width, width)
+    n_pages, block = geom.n_blocks, geom.block
+    dt = jnp.dtype(geom.dtype)
     ks = jax.random.split(jax.random.key(seed), 5)
-    n_pages = 1 + batch * width
     q = jax.random.normal(ks[0], (batch, m.n_heads, m.head_dim), dt)
-    k_pages = jax.random.normal(
-        ks[1], (layers, n_pages, block, m.n_kv_heads, m.head_dim), dt)
-    v_pages = jax.random.normal(
-        ks[2], (layers, n_pages, block, m.n_kv_heads, m.head_dim), dt)
+    k_pages = jax.random.normal(ks[1], geom.shape, dt)
+    v_pages = jax.random.normal(ks[2], geom.shape, dt)
     cur_k = jax.random.normal(ks[3], (batch, m.n_kv_heads, m.head_dim), dt)
     cur_v = jax.random.normal(ks[4], (batch, m.n_kv_heads, m.head_dim), dt)
     tables = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(batch, width)

@@ -182,10 +182,3 @@ class EngineConfig:
     @property
     def model_name(self) -> str:
         return self.served_model_name or self.model
-
-    def num_kv_blocks(self) -> int:
-        if self.hbm_kv_blocks:
-            return self.hbm_kv_blocks
-        block = self.model_config.kv_block_size
-        per_seq = -(-self.max_model_len // block)
-        return 1 + self.max_batch * per_seq  # +1 for the trash block

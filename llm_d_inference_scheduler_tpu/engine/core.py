@@ -24,6 +24,7 @@ import asyncio
 import collections
 import contextlib
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -36,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kvcache import pages, wire
 from ..models import llama
 from ..utils.hashing import chain_block_hashes
 from .blocks import BlockAllocator, PrefixCachingAllocator
@@ -49,24 +51,6 @@ from .tokenizer import get_tokenizer
 log = logging.getLogger("engine.core")
 
 KV_EXPORT_TTL_S = 60.0
-
-# Device-pull byte accounting: kv_shape is the staged K array's shape, K and
-# V move together, and kv_dtype names the element type.
-_KV_DTYPE_BYTES = {"float32": 4, "float16": 2, "bfloat16": 2, "int8": 1,
-                   "float8_e4m3fn": 1, "float8_e5m2": 1}
-
-
-def _kv_param_bytes(ktp: dict[str, Any]) -> int | None:
-    """Bytes a device-wire pull moves, derived from the exporter's staged
-    geometry (the host path counts the payload directly)."""
-    shape = ktp.get("kv_shape")
-    if not shape:
-        return None
-    n = 1
-    for d in shape:
-        n *= int(d)
-    return 2 * n * _KV_DTYPE_BYTES.get(str(ktp.get("kv_dtype", "")), 2)
-
 
 def _tcp_preflight(address: str, timeout: float = 2.0) -> None:
     """The transfer layer blocks indefinitely on an unreachable peer; fail
@@ -182,21 +166,13 @@ class TpuEngine:
                 f"{len(jax.local_devices())} {jax.default_backend()} "
                 "device(s)")
         self.device = jax.local_devices()[cfg.device_index]
-        if cfg.pallas_attention is None:
-            # Auto: the kernel beats the XLA gather path where it compiles
-            # (lane-aligned head_dim, single-device pages, real TPU).
-            cfg.pallas_attention = (
-                self.device.platform == "tpu"
-                and cfg.tp_size == 1 and cfg.ep_size == 1
-                and self.mcfg.head_dim % 128 == 0)
-        elif cfg.pallas_attention and not cfg.pallas_interpret \
-                and self.mcfg.head_dim % 128 != 0:
-            # Asked for by name and impossible: an error, not a quiet
-            # switch to the other path.
-            raise ValueError(
-                f"pallas_attention: head_dim {self.mcfg.head_dim} is not "
-                "lane-aligned (128) — Mosaic cannot slice the page DMA; "
-                "leave the option unset to let the engine choose")
+        cfg.pallas_attention = pages.use_kernel(
+            self.mcfg.head_dim, asked=cfg.pallas_attention,
+            interpret=cfg.pallas_interpret, platform=self.device.platform,
+            sharded=cfg.tp_size > 1 or cfg.ep_size > 1)
+        self._decode_attention = functools.partial(
+            pages.decode_attention, kernel=cfg.pallas_attention,
+            interpret=cfg.pallas_interpret)
         if cfg.pallas_moe and self.mcfg.n_experts:
             if cfg.tp_size > 1 or cfg.ep_size > 1:
                 raise ValueError("pallas_moe requires tp_size=ep_size=1 "
@@ -221,9 +197,11 @@ class TpuEngine:
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
 
-        block = self.mcfg.kv_block_size
-        self.n_blocks = max(cfg.num_kv_blocks(), 2)  # ≥ trash + 1 usable
-        self.max_blocks_per_seq = -(-cfg.max_model_len // block)
+        self.geom = pages.PageGeometry.for_engine(
+            self.mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
+        block = self.geom.block
+        self.n_blocks = self.geom.n_blocks
+        self.max_blocks_per_seq = self.geom.max_blocks_per_seq
         self.allocator = (PrefixCachingAllocator(self.n_blocks, block)
                           if cfg.enable_prefix_caching
                           else BlockAllocator(self.n_blocks, block))
@@ -402,7 +380,11 @@ class TpuEngine:
                 lambda k: llama.init_params(self.mcfg, k),
                 out_shardings=SingleDeviceSharding(self.device))(
                     jax.random.key(cfg.seed))
-        self.k_pages, self.v_pages = self._alloc_pages()
+        mesh = self._page_mesh()
+        self.k_pages, self.v_pages = (
+            pages.alloc(self.geom, sharding=pages.page_sharding(mesh))
+            if mesh is not None
+            else pages.alloc(self.geom, device=self.device))
 
         self.warming = cfg.warmup  # cleared by the engine thread post-compile
         # Set by the engine thread when it cannot go on (a warm-up that
@@ -474,8 +456,8 @@ class TpuEngine:
         else:
             self._jit_decode_chunk = jax.jit(self._decode_chunk_impl,
                                              donate_argnums=(3, 4))
-        def kv_import(kp, vp, blocks, k_new, v_new):
-            return kp.at[:, blocks].set(k_new), vp.at[:, blocks].set(v_new)
+        def kv_import(kp, vp, blocks, k_new, v_new):  # `jit_kv_import`
+            return pages.scatter_blocks(kp, vp, blocks, k_new, v_new)
 
         self._jit_import = jax.jit(kv_import, donate_argnums=(0, 1))
         log.info("engine %s up: %s", self.engine_id,
@@ -513,22 +495,6 @@ class TpuEngine:
                                 "bytes_limit")},
         }
 
-    def _alloc_pages(self) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """Fresh zeroed KV page buffers."""
-        if self.pp_mesh is not None:
-            from ..parallel.pp_serve import alloc_pp_pages
-
-            return alloc_pp_pages(self.mcfg, self.pp_mesh, self.n_blocks)
-        if self.mesh is not None:
-            from ..parallel.serve import alloc_sharded_pages
-
-            return alloc_sharded_pages(self.mcfg, self.mesh, self.n_blocks)
-        kshape = (self.mcfg.n_layers, self.n_blocks, self.mcfg.kv_block_size,
-                  self.mcfg.n_kv_heads, self.mcfg.head_dim)
-        dtype = jnp.dtype(self.mcfg.dtype)
-        return (jnp.zeros(kshape, dtype, device=self.device),
-                jnp.zeros(kshape, dtype, device=self.device))
-
     # ---- jitted bodies -------------------------------------------------
 
     def _decode_chunk_impl(self, params, tokens, positions, k_pages, v_pages,
@@ -550,8 +516,7 @@ class TpuEngine:
             tokens, positions, k_pages, v_pages = carry
             logits, k_pages, v_pages = llama.decode_step(
                 params, self.mcfg, tokens, positions, k_pages, v_pages,
-                block_tables, use_pallas=self.cfg.pallas_attention,
-                pallas_interpret=self.cfg.pallas_interpret)
+                block_tables, attention_fn=self._decode_attention)
             nxt = sample_tokens(logits, k_step, temps, top_k, top_p)
             return (nxt, positions + 1, k_pages, v_pages), nxt
 
@@ -572,7 +537,7 @@ class TpuEngine:
             def impl(params, tokens, seq_len, k_pages, v_pages, block_table_row,
                      key, temps, top_k, top_p):
                 logits, (k_new, v_new) = llama.forward(params, self.mcfg, tokens, want_kv=True)
-                k_pages, v_pages = llama.write_prefill_kv(
+                k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
                 last = jnp.take_along_axis(
                     logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]  # [1, V]
@@ -599,7 +564,7 @@ class TpuEngine:
                 logits, (k_new, v_new) = llama.forward(
                     params, self.mcfg, tokens, want_kv=True,
                     mm_embeds=mm_embeds, mm_positions=mm_positions)
-                k_pages, v_pages = llama.write_prefill_kv(
+                k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
                 last = jnp.take_along_axis(
                     logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]
@@ -768,18 +733,10 @@ class TpuEngine:
         # was lost, which leaks one idle thread, not device memory.
         threading.Thread(target=drain, name="kv-drain", daemon=True).start()
 
-    def _page_layout(self):
-        """(mesh, partition spec) of the page buffers; (None, None) when the
-        engine is single-device (unsharded pages)."""
-        if self.pp_mesh is not None:
-            from ..parallel.pp_serve import PAGE_SPEC
-
-            return self.pp_mesh, PAGE_SPEC
-        if self.mesh is not None:
-            from ..parallel.serve import KV_PAGE_SPEC
-
-            return self.mesh, KV_PAGE_SPEC
-        return None, None
+    def _page_mesh(self):
+        """The mesh the page buffers are sharded over (kvcache/pages.py has
+        the rule); None when the engine is single-device."""
+        return self.pp_mesh if self.pp_mesh is not None else self.mesh
 
     def get_kv_export(self, request_id: str) -> dict[str, Any] | None:
         with self._exports_lock:
@@ -1632,7 +1589,7 @@ class TpuEngine:
         ktp = s.req.kv_transfer_params or {}
         if not (ktp.get("do_remote_decode") and ktp.get("stream_chunks")):
             return
-        if self._dist or self._page_layout()[0] is not None:
+        if self._dist or self._page_mesh() is not None:
             return
         block = self.mcfg.kv_block_size
         rid = s.req.request_id
@@ -1649,9 +1606,9 @@ class TpuEngine:
                    "blocks_staged": 0, "complete": False}
             with self._exports_lock:
                 self.kv_exports[rid] = rec
-        ids = np.asarray(s.blocks[staged:upto], np.int32)
-        k_np = np.asarray(self.k_pages[:, ids])
-        v_np = np.asarray(self.v_pages[:, ids])
+        k_np, v_np = (np.asarray(a) for a in pages.gather_blocks(
+            self.k_pages, self.v_pages,
+            np.asarray(s.blocks[staged:upto], np.int32)))
         # Append data BEFORE bumping the counters: the server's long-poll
         # reads chunks_staged without the lock, so a reader that sees N
         # staged chunks must find N chunk_data entries.
@@ -1677,8 +1634,8 @@ class TpuEngine:
         if (n > staged and rec.get("k") is not None
                 and getattr(rec["k"], "is_fully_addressable", True)
                 and not self._dist):
-            k_np, v_np = np.asarray(rec["k"]), np.asarray(rec["v"])
-            rec["chunk_data"].append((k_np[:, staged:n], v_np[:, staged:n]))
+            rec["chunk_data"].append(pages.block_range(
+                np.asarray(rec["k"]), np.asarray(rec["v"]), staged, n))
             rec["chunk_blocks"].append(n - staged)
             rec["blocks_staged"] = n
             rec["chunks_staged"] += 1
@@ -1917,7 +1874,7 @@ class TpuEngine:
                 self._pull_device_kv_sharded(pi, ktp)
                 self.kv_import_device_count += 1
                 self._note_kv_import(pi.req.request_id, t0,
-                                     _kv_param_bytes(ktp), "device")
+                                     wire.param_bytes(ktp), "device")
                 with self._cond:
                     self._import_ready.append(pi)
                     self._cond.notify()
@@ -1934,7 +1891,7 @@ class TpuEngine:
                 self._pull_device_kv(pi, ktp)
                 self.kv_import_device_count += 1
                 self._note_kv_import(pi.req.request_id, t0,
-                                     _kv_param_bytes(ktp), "device")
+                                     wire.param_bytes(ktp), "device")
                 with self._cond:
                     self._import_ready.append(pi)
                     self._cond.notify()
@@ -1993,19 +1950,14 @@ class TpuEngine:
         back to local prefill (zero client-visible errors)."""
         import httpx
 
-        k_parts: list[bytes] = []
-        v_parts: list[bytes] = []
-        chunk = 0
-        total_blocks = 0
+        chunks: list[tuple[dict[str, str], bytes]] = []
         complete_at: float | None = None
-        chunk_shape = None
-        dtype = None
         meta: dict[str, str] = {}
         deadline = t0 + self.KV_CHUNK_STREAM_TIMEOUT_S
         while True:
             if time.monotonic() > deadline:
                 raise TimeoutError("kv chunk stream stalled")
-            r = httpx.get(url, params={"chunk": chunk, "wait_ms": 2000},
+            r = httpx.get(url, params={"chunk": len(chunks), "wait_ms": 2000},
                           timeout=30.0, verify=verify)
             if r.status_code == 202:  # chunk not staged yet: re-poll
                 continue
@@ -2018,20 +1970,14 @@ class TpuEngine:
             hdrs = dict(r.headers)
             if hdrs.get("x-kv-complete") == "1" and complete_at is None:
                 complete_at = time.monotonic()
-            body = r.content
-            half = len(body) // 2
-            k_parts.append(body[:half])
-            v_parts.append(body[half:])
-            total_blocks += int(hdrs.get("x-kv-chunk-blocks") or 0)
-            if hdrs.get("x-kv-chunk-shape"):
-                chunk_shape = json.loads(hdrs["x-kv-chunk-shape"])
-                dtype = hdrs.get("x-kv-dtype")
-            chunk += 1
+            chunks.append((hdrs, r.content))
             if (hdrs.get("x-kv-complete") == "1"
-                    and chunk >= int(hdrs.get("x-kv-chunks-staged") or 0)):
+                    and len(chunks) >= int(hdrs.get("x-kv-chunks-staged")
+                                           or 0)):
                 meta = hdrs
                 break
-        if not k_parts or chunk_shape is None:
+        joined = wire.join_chunks(chunks)
+        if joined is None:
             # Exporter had no host-addressable chunks: full-payload GET.
             r = httpx.get(url, timeout=30.0, verify=verify)
             r.raise_for_status()
@@ -2041,15 +1987,9 @@ class TpuEngine:
             self._note_kv_import(pi.req.request_id, t0,
                                  len(r.content), "host")
             return
-        L, _, block, Hkv, Dh = (int(d) for d in chunk_shape)
-        pi.payload = b"".join(k_parts) + b"".join(v_parts)
-        pi.headers = {
-            "x-kv-shape": json.dumps([L, total_blocks, block, Hkv, Dh]),
-            "x-kv-seq-len": meta["x-kv-seq-len"],
-            "x-kv-dtype": str(dtype),
-            "x-kv-real-blocks": str(total_blocks),
-            "x-kv-first-token": meta.get("x-kv-first-token", ""),
-        }
+        pi.payload, pi.headers = joined
+        pi.headers["x-kv-seq-len"] = meta["x-kv-seq-len"]
+        pi.headers["x-kv-first-token"] = meta.get("x-kv-first-token", "")
         self.kv_import_host_count += 1
         t_end = time.monotonic()
         exposed_ms = (t_end - max(complete_at or t0, t0)) * 1e3
@@ -2061,11 +2001,11 @@ class TpuEngine:
         sides (symmetric P/D deployment); mismatch falls back."""
         from .kv_shards import mesh_descriptor
 
-        mesh, spec = self._page_layout()
+        mesh = self._page_mesh()
         if mesh is None:
             raise ValueError("importer is unsharded; exporter pages are "
                              "sharded — host path required")
-        mine = mesh_descriptor(mesh, spec)
+        mine = mesh_descriptor(mesh, pages.page_spec(mesh))
         theirs = ktp["kv_mesh"]
         if mine != theirs:
             raise ValueError(f"page sharding mismatch: {theirs} vs {mine}")
@@ -2179,42 +2119,21 @@ class TpuEngine:
     def _strip_remote(req: EngineRequest) -> EngineRequest:
         return dataclasses.replace(req, kv_transfer_params=None)
 
-    def _validate_kv_geometry(self, shape, seq_len: int, real_nb: int,
-                              n_alloc: int):
-        """shape's block dim may be pow2-PADDED (staging pads so gather/
-        scatter compile counts stay bounded); real_nb is the un-padded count
-        that must fit the local allocation."""
-        if len(shape) != 5:
-            raise ValueError(f"bad kv shape {shape}")
-        L, nb, block, Hkv, Dh = shape
-        if (L, block, Hkv, Dh) != (self.mcfg.n_layers, self.mcfg.kv_block_size,
-                                   self.mcfg.n_kv_heads, self.mcfg.head_dim):
-            raise ValueError(f"kv geometry mismatch: {shape} vs model "
-                             f"(L={self.mcfg.n_layers}, block={self.mcfg.kv_block_size}, "
-                             f"Hkv={self.mcfg.n_kv_heads}, Dh={self.mcfg.head_dim})")
-        if not (0 < real_nb <= nb):
-            raise ValueError(f"real block count {real_nb} outside padded {nb}")
-        if nb > self.max_blocks_per_seq or real_nb > n_alloc:
-            raise ValueError(f"{real_nb}/{nb} exported blocks exceed budget "
-                             f"(maxB={self.max_blocks_per_seq}, alloc={n_alloc})")
-        if not (0 < seq_len <= real_nb * block):
-            raise ValueError(f"kv seq_len {seq_len} outside exported blocks")
-        return L, nb, block, Hkv, Dh
-
     def _import_into_slot(self, idx: int, pi: _PendingImport, blocks: list[int]):
         """Validates and scatters fetched KV — device arrays from the
         transfer-server pull, or host bytes from the HTTP path; raises on any
         malformed/mismatched import (caller falls back to local prefill)."""
         req, headers = pi.req, pi.headers or {}
         ktp = req.kv_transfer_params or {}
+        # The exporter's un-padded block count; absent means not padded.
+        remote_nb = int(ktp.get("remote_num_blocks") or 0) or None
         if pi.dist_pull:
             # Coordinated multi-host pull: every process fetches its shards
             # from its counterpart prefill process and scatters, in lockstep.
             shape = tuple(int(d) for d in ktp["kv_shape"])
             seq_len = int(ktp["remote_seq_len"])
-            real_nb = int(ktp.get("remote_num_blocks") or shape[1])
-            _, nb, *_ = self._validate_kv_geometry(shape, seq_len, real_nb,
-                                                   len(blocks))
+            nb, real_nb = wire.validate(self.geom, shape, seq_len, remote_nb,
+                                        len(blocks))
             padded_blocks = np.zeros((nb,), np.int32)
             padded_blocks[:real_nb] = blocks[:real_nb]
             self._device_call(("pull_kv_import",), dict(
@@ -2234,35 +2153,21 @@ class TpuEngine:
             # The staging side pow2-pads the block dim, so the per-shape jit
             # cache stays at log2(max_blocks)+1 entries; padding rows scatter
             # into the trash block 0.
-            shape = tuple(int(d) for d in pi.k_dev.shape)
             seq_len = int(ktp["remote_seq_len"])
-            real_nb = int(ktp.get("remote_num_blocks") or shape[1])
-            _, nb, *_ = self._validate_kv_geometry(shape, seq_len, real_nb,
-                                                   len(blocks))
+            nb, real_nb = wire.validate(
+                self.geom, tuple(int(d) for d in pi.k_dev.shape), seq_len,
+                remote_nb, len(blocks))
             padded_blocks = np.zeros((nb,), np.int32)  # tail → trash block 0
             padded_blocks[:real_nb] = blocks[:real_nb]
             self.k_pages, self.v_pages = self._jit_import(
                 self.k_pages, self.v_pages, jnp.asarray(padded_blocks),
                 pi.k_dev, pi.v_dev)
         else:
-            shape = tuple(int(x) for x in json.loads(headers["x-kv-shape"]))
-            seq_len = int(headers["x-kv-seq-len"])
-            dtype = jnp.dtype(headers["x-kv-dtype"])
-            real_nb = int(headers.get("x-kv-real-blocks") or shape[1])
-            L, nb, block, Hkv, Dh = self._validate_kv_geometry(
-                shape, seq_len, real_nb, len(blocks))
-            expected = 2 * int(np.prod(shape)) * dtype.itemsize
-            if len(pi.payload) != expected:
-                raise ValueError(f"kv payload size {len(pi.payload)} != expected {expected}")
-            nbytes = len(pi.payload) // 2
-            k_np = np.frombuffer(pi.payload[:nbytes], dtype=dtype).reshape(shape)
-            v_np = np.frombuffer(pi.payload[nbytes:], dtype=dtype).reshape(shape)
-
+            k_np, v_np, seq_len, real_nb = wire.decode(
+                self.geom, headers, pi.payload, len(blocks))
             # Pad to the fixed per-seq block budget so the scatter compiles once.
             maxB = self.max_blocks_per_seq
-            k_pad = np.zeros((L, maxB, block, Hkv, Dh), dtype)
-            v_pad = np.zeros((L, maxB, block, Hkv, Dh), dtype)
-            k_pad[:, :nb], v_pad[:, :nb] = k_np, v_np
+            k_pad, v_pad = pages.pad_blocks(k_np, v_np, maxB)
             blocks_pad = np.zeros((maxB,), np.int32)  # padding lands in trash block 0
             blocks_pad[:real_nb] = blocks[:real_nb]
             self._device_call(("import",), dict(
@@ -2445,21 +2350,20 @@ class TpuEngine:
         every process under dist (the gather is a collective program on
         global arrays). Unsharded engines degenerate to the legacy [k, v]
         registration."""
-        from .kv_shards import local_unique_shards, staged_sharding
+        from .kv_shards import local_unique_shards
 
-        mesh, spec = self._page_layout()
+        mesh = self._page_mesh()
         idx_dev = self._put(idx)
         if mesh is not None:
             if self._jit_stage is None:
-                out_sh = staged_sharding(mesh, spec)
-                self._jit_stage = jax.jit(
-                    lambda kp, vp, i: (kp[:, i], vp[:, i]),
-                    out_shardings=(out_sh, out_sh))
+                out_sh = pages.page_sharding(mesh)
+                self._jit_stage = jax.jit(pages.gather_blocks,
+                                          out_shardings=(out_sh, out_sh))
             k_stage, v_stage = self._jit_stage(self.k_pages, self.v_pages,
                                                idx_dev)
         else:
-            k_stage = self.k_pages[:, idx_dev]
-            v_stage = self.v_pages[:, idx_dev]
+            k_stage, v_stage = pages.gather_blocks(self.k_pages,
+                                                   self.v_pages, idx_dev)
         staged_shards = None
         registered = None
         wire_uuid = None
@@ -2548,10 +2452,9 @@ class TpuEngine:
         sharding (replica devices get device_put copies)."""
         from jax.sharding import SingleDeviceSharding
 
-        from .kv_shards import local_shard_groups, staged_sharding
+        from .kv_shards import local_shard_groups
 
-        mesh, spec = self._page_layout()
-        sharding = staged_sharding(mesh, spec)
+        sharding = pages.page_sharding(self._page_mesh())
         groups = local_shard_groups(sharding, shape)
         shard_shape = sharding.shard_shape(shape)
         sds = [jax.ShapeDtypeStruct(shard_shape, dtype,
@@ -2582,11 +2485,10 @@ class TpuEngine:
         local_unique_shards(k) + local_unique_shards(v) — the same order the
         importer's local_shard_groups produces under symmetric geometry
         (enforced by _check_shard_geometry)."""
-        from .kv_shards import local_shard_groups, staged_sharding
+        from .kv_shards import local_shard_groups
         from .shard_wire import pull_shards
 
-        mesh, spec = self._page_layout()
-        sharding = staged_sharding(mesh, spec)
+        sharding = pages.page_sharding(self._page_mesh())
         groups = local_shard_groups(sharding, shape)
         shard_shape = sharding.shard_shape(shape)
         arrs = pull_shards(address, int(tuid))
@@ -2857,7 +2759,7 @@ class TpuEngine:
                     "kv_shape": [int(d) for d in rec["k"].shape],
                     "kv_dtype": str(rec["k"].dtype),
                 })
-                mesh, spec = self._page_layout()
+                mesh = self._page_mesh()
                 if mesh is None:
                     # Legacy single-device contract: one address, one
                     # [k, v] pull.
@@ -2865,7 +2767,8 @@ class TpuEngine:
                 else:
                     from .kv_shards import mesh_descriptor
 
-                    kv_params["kv_mesh"] = mesh_descriptor(mesh, spec)
+                    kv_params["kv_mesh"] = mesh_descriptor(
+                        mesh, pages.page_spec(mesh))
                     kv_params["transfer_shards"] = self._shard_addresses()
                     if self.kv_shard_wire is not None:
                         kv_params["shard_wire_addrs"] = (

@@ -3,8 +3,8 @@
 The reference's NIXL connector moves KV between vLLM engines rank-by-rank
 (connector_nixlv2.go:191-253: multi-rank transfer descriptors inside
 kv_transfer_params). The TPU equivalent here: a staged KV export is a
-jax.Array sharded like the engine's pages (kv heads over ``tp``, layers
-over ``pp``; ``dp``/``ep`` replicate), and the wire unit is the *distinct
+jax.Array sharded like the engine's pages (``kvcache/pages.py``
+``page_sharding``), and the wire unit is the *distinct
 index slice* — one single-device array per unique shard, deduped across
 replicas and ordered canonically by flattened index offsets so exporter
 and importer agree on shard identity without shipping index maps.
@@ -24,7 +24,7 @@ from typing import Any
 import jax
 
 __all__ = ["mesh_descriptor", "shard_key", "local_unique_shards",
-           "local_shard_groups", "staged_sharding"]
+           "local_shard_groups"]
 
 
 def shard_key(shard) -> tuple[int, ...]:
@@ -63,12 +63,3 @@ def local_shard_groups(sharding, global_shape) -> list[tuple[tuple, list]]:
         key = tuple(int(s.start or 0) for s in idx)
         groups.setdefault(key, []).append(dev)
     return [(k, sorted(groups[k], key=lambda d: d.id)) for k in sorted(groups)]
-
-
-def staged_sharding(mesh, page_spec):
-    """Sharding for a staged [L, nb, block, Hkv, Dh] export: identical to the
-    page sharding (the blocks axis — the only axis whose size differs from
-    the page buffer — is unsharded in every layout)."""
-    from jax.sharding import NamedSharding
-
-    return NamedSharding(mesh, page_spec)

@@ -24,6 +24,7 @@ import jax
 import numpy as np
 from aiohttp import web
 
+from ..kvcache import wire
 from .config import EngineConfig
 from .core import TpuEngine
 from .request import EngineRequest, FinishReason, TokenEvent
@@ -1115,13 +1116,8 @@ class EngineServer:
         body = b""
         data = rec.get("chunk_data")
         if data is not None:
-            import numpy as np
-
-            k_np, v_np = data[chunk]
-            k_np, v_np = np.asarray(k_np), np.asarray(v_np)
-            body = k_np.tobytes() + v_np.tobytes()
-            headers["x-kv-chunk-shape"] = json.dumps(list(k_np.shape))
-            headers["x-kv-dtype"] = str(k_np.dtype)
+            body, geometry = wire.encode(*data[chunk], chunk=True)
+            headers.update(geometry)
         return web.Response(body=body,
                             content_type="application/octet-stream",
                             headers=headers)
@@ -1129,14 +1125,14 @@ class EngineServer:
     async def kv_fetch(self, request: web.Request) -> web.Response:
         """Serve retained prefill KV pages for a request (host-staged DCN path).
 
-        Returns raw bytes: concatenated K then V, each
-        [L, n_blocks, block, Hkv, Dh] in the model dtype, plus geometry headers.
+        Returns raw bytes and geometry headers as ``kvcache/wire.py`` lays
+        them out (K then V).
 
         Chunk-streamed pipeline extension (all bounded long-polls via
         ``wait_ms``, capped at KV_CHUNK_WAIT_CAP_MS):
 
         - ``?chunk=N`` — serve staged chunk N of a chunk-streamed export
-          ([L, chunk_blocks, block, Hkv, Dh] K then V); 202 when the wait
+          (its blocks, K then V); 202 when the wait
           expires before chunk N is staged; 204 when the export is complete
           and N is past the last chunk.
         - ``?ack=1`` — the sidecar's non-consuming first-chunk ack: 200 as
@@ -1185,16 +1181,11 @@ class EngineServer:
                      "pull via transfer_shards")
         # Exports may be staged as device arrays (transfer-server path);
         # convert lazily for host-path peers.
-        import numpy as np
-
-        k, v = np.asarray(rec["k"]), np.asarray(rec["v"])
-        payload = k.tobytes() + v.tobytes()
+        payload, geometry = wire.encode(rec["k"], rec["v"],
+                                        real_blocks=rec.get("num_blocks"))
         return web.Response(body=payload, content_type="application/octet-stream", headers={
             "x-kv-seq-len": str(rec["seq_len"]),
-            "x-kv-num-blocks": str(k.shape[1]),
-            "x-kv-real-blocks": str(rec.get("num_blocks", k.shape[1])),
-            "x-kv-dtype": str(k.dtype),
-            "x-kv-shape": json.dumps(list(k.shape)),
+            **geometry,
             "x-kv-first-token": str(rec.get("first_token")),
         })
 

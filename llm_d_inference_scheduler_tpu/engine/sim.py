@@ -15,6 +15,7 @@ import uuid
 from collections import OrderedDict
 from typing import Any
 
+from ..kvcache.pages import PageGeometry
 from ..utils.hashing import chain_block_hashes
 from .config import EngineConfig
 from .request import EngineRequest, FinishReason, TokenEvent
@@ -60,7 +61,9 @@ class SimEngine:
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
         block = self.mcfg.kv_block_size
-        self.n_blocks = cfg.num_kv_blocks()
+        self.n_blocks = PageGeometry.for_engine(
+            self.mcfg, cfg.max_batch, cfg.max_model_len,
+            cfg.hbm_kv_blocks).n_blocks
         self.telemetry = EngineTelemetry(block_size=block, num_blocks=self.n_blocks)
         self._sem = asyncio.Semaphore(cfg.max_batch)
         self._waiting = 0

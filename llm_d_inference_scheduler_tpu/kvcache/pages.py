@@ -1,0 +1,271 @@
+"""The page pool: its shape, where it lives, and every read and write of it.
+
+``k_pages`` and ``v_pages`` are each ``[n_layers, n_blocks, block, n_kv_heads,
+head_dim]``: every layer's pool stacked, a page (block) of ``block`` tokens,
+a token's KV heads side by side. Block 0 is the trash block: no sequence
+owns it, and every padded or masked-off write is pointed at it so that the
+scatters keep static shapes with no branch.
+
+The pools stay stacked and are read at (layer, page) (PERF.md, PR 26: a pool
+scanned over reaches the Pallas kernel as one layer's slice, which XLA copies
+out first). The page is 16 tokens and the kernel picks its own stage from the
+shapes (PR 29).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..ops.attention import paged_decode_attention
+from ..ops.pallas_paged_attention import paged_decode_attention_pallas
+
+TRASH_BLOCK = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class PageGeometry:
+    """What a pool's shape follows from. Built once, from the model's widths
+    and either a block count or the engine's batch and context limits."""
+
+    n_layers: int
+    n_blocks: int            # the trash block among them
+    block: int               # tokens a page
+    n_kv_heads: int
+    head_dim: int
+    dtype: str
+    max_blocks_per_seq: int  # a block table's width
+
+    @classmethod
+    def for_model(cls, model: Any, n_blocks: int,
+                  max_blocks_per_seq: int | None = None,
+                  dtype: str | None = None) -> "PageGeometry":
+        """A pool of ``n_blocks`` pages at ``model``'s widths (anything with
+        n_layers, kv_block_size, n_kv_heads, head_dim and dtype). Never fewer
+        than two blocks: the trash block and one to use."""
+        n_blocks = max(n_blocks, 2)
+        return cls(model.n_layers, n_blocks, model.kv_block_size,
+                   model.n_kv_heads, model.head_dim,
+                   str(jnp.dtype(dtype or model.dtype)),
+                   max_blocks_per_seq or n_blocks - 1)
+
+    @classmethod
+    def for_engine(cls, model: Any, max_batch: int, max_model_len: int,
+                   hbm_kv_blocks: int = 0) -> "PageGeometry":
+        """The engine's pool: ``hbm_kv_blocks`` where given, else room for
+        every lane at full length beside the trash block."""
+        per_seq = -(-max_model_len // model.kv_block_size)
+        return cls.for_model(
+            model, hbm_kv_blocks or 1 + max_batch * per_seq, per_seq)
+
+    @property
+    def shape(self) -> tuple[int, int, int, int, int]:
+        return (self.n_layers, self.n_blocks, self.block, self.n_kv_heads,
+                self.head_dim)
+
+    def blocks_for(self, tokens: int) -> int:
+        return -(-tokens // self.block)
+
+    @property
+    def block_bytes(self) -> int:
+        """Bytes one block id holds: K and V, every layer."""
+        return (2 * self.n_layers * self.block * self.n_kv_heads
+                * self.head_dim * jnp.dtype(self.dtype).itemsize)
+
+    @property
+    def pool_bytes(self) -> int:
+        """Bytes of the pair of pools."""
+        return self.n_blocks * self.block_bytes
+
+
+# ---- where a pool lives -----------------------------------------------------
+
+
+def page_spec(mesh: Mesh) -> P:
+    """The sharding rule: layers follow the stage split where the mesh has
+    one (``pp``), KV heads follow ``tp``; the block pool is whole on every
+    ``dp`` / ``ep`` replica (any lane may reference any block; attention has
+    no experts axis). GQA's head grouping stays shard-local because ``tp``
+    divides n_kv_heads, so gathers and scatters need no collective."""
+    return P("pp" if "pp" in mesh.axis_names else None, None, None, "tp",
+             None)
+
+
+def page_sharding(mesh: Mesh) -> NamedSharding:
+    """Sharding of a pool, and of blocks gathered out of one: the blocks
+    axis, the only one whose size differs, is unsharded in every layout."""
+    return NamedSharding(mesh, page_spec(mesh))
+
+
+def alloc(geom: PageGeometry, *, device=None,
+          sharding=None) -> tuple[jax.Array, jax.Array]:
+    """Zeroed ``(k_pages, v_pages)``, on one device or laid out by
+    ``sharding`` (made in place, shard by shard)."""
+    dtype = jnp.dtype(geom.dtype)
+    if sharding is not None:
+        zeros = jax.jit(lambda: jnp.zeros(geom.shape, dtype),
+                        out_shardings=sharding)
+        return zeros(), zeros()
+    return (jnp.zeros(geom.shape, dtype, device=device),
+            jnp.zeros(geom.shape, dtype, device=device))
+
+
+def block_size(k_pages: jax.Array) -> int:
+    return k_pages.shape[2]
+
+
+def layer_indices(k_pages: jax.Array) -> jax.Array:
+    """int32 index of every layer the pool holds (a shard_map body sees its
+    stage's layers only): what a scan over layers carries beside the
+    parameters, since the pools themselves are closed over, not scanned."""
+    return jnp.arange(k_pages.shape[0], dtype=jnp.int32)
+
+
+# ---- writes -------------------------------------------------------------------
+
+
+def token_slots(k_pages: jax.Array, block_tables: jax.Array,
+                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(block id, slot in it), each [B], of lane b's token at
+    ``positions[b]``. A lane masked off is redirected by the caller:
+    ``jnp.where(on, blocks, TRASH_BLOCK)``."""
+    block = k_pages.shape[2]
+    blocks = block_tables[jnp.arange(positions.shape[0]), positions // block]
+    return blocks, positions % block
+
+
+def sequence_slots(k_pages: jax.Array, block_tables: jax.Array,
+                   lens: jax.Array, n_tokens: int,
+                   start: jax.Array | None = None
+                   ) -> tuple[jax.Array, jax.Array]:
+    """(block id, slot in it), each [B, n_tokens], of token t of sequence b,
+    which lies at position ``start[b] + t`` (``start`` None: at t). Tokens at
+    and past ``lens[b]`` are padding and go to the trash block."""
+    block = k_pages.shape[2]
+    t = jnp.arange(n_tokens, dtype=jnp.int32)
+    if start is None:
+        blocks = block_tables[:, t // block]
+    else:
+        pos = start[:, None] + t[None, :]
+        blocks = jnp.take_along_axis(block_tables, pos // block, axis=1)
+    valid = t[None, :] < lens[:, None]
+    blocks = jnp.where(valid, blocks, TRASH_BLOCK)
+    if start is None:
+        pos = t[None, :]
+    return blocks, jnp.where(valid, pos % block, 0)
+
+
+def write(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
+          v_new: jax.Array, blocks: jax.Array, slots: jax.Array
+          ) -> tuple[jax.Array, jax.Array]:
+    """Scatter new KV rows into their pages: ``k_new`` / ``v_new``
+    [L, ..., Hkv, D] with ``blocks`` / ``slots`` [...] from
+    :func:`token_slots` or :func:`sequence_slots` — every layer's rows in one
+    scatter a pool, so donated pools are updated in place."""
+    blocks, slots = blocks.reshape(-1), slots.reshape(-1)
+
+    def rows(new, pool):
+        return new.reshape(new.shape[0], -1, *new.shape[-2:]).astype(
+            pool.dtype)
+
+    k_rows, v_rows = rows(k_new, k_pages), rows(v_new, v_pages)
+    return (k_pages.at[:, blocks, slots].set(k_rows),
+            v_pages.at[:, blocks, slots].set(v_rows))
+
+
+def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
+                    k_new: jax.Array, v_new: jax.Array,
+                    block_tables: jax.Array, lens: jax.Array,
+                    start: jax.Array | None = None
+                    ) -> tuple[jax.Array, jax.Array]:
+    """A run of tokens a sequence, ``k_new`` / ``v_new`` [L, B, S, Hkv, D]
+    (a prefill's KV), written from position ``start[b]`` (None: 0) on;
+    padding past ``lens[b]`` lands in the trash block."""
+    return write(k_pages, v_pages, k_new, v_new,
+                 *sequence_slots(k_pages, block_tables, lens, k_new.shape[2],
+                                 start))
+
+
+# ---- reads --------------------------------------------------------------------
+
+
+def use_kernel(head_dim: int, *, asked: bool | None, interpret: bool,
+               platform: str, sharded: bool) -> bool:
+    """Whether decode attention runs the Pallas kernel or the XLA gather.
+    Left open (``asked`` None), the kernel runs where it compiles and wins: a
+    real TPU, single-device pages, a lane-aligned head_dim. Asked for by name
+    and impossible is an error, not a quiet switch to the other path."""
+    if asked is None:
+        return platform == "tpu" and not sharded and head_dim % 128 == 0
+    if asked and not interpret and head_dim % 128 != 0:
+        raise ValueError(
+            f"pallas_attention: head_dim {head_dim} is not lane-aligned "
+            "(128) — Mosaic cannot slice the page DMA; leave the option "
+            "unset to let the engine choose")
+    return asked
+
+
+def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                     layer: jax.Array, block_tables: jax.Array,
+                     seq_lens: jax.Array, cur_k: jax.Array, cur_v: jax.Array,
+                     *, kernel: bool = False,
+                     interpret: bool = False) -> jax.Array:
+    """One new token a lane, q [B, H, D], against ``layer``'s pages of the
+    stacked pools; the token's own K/V (not in the pages yet) are ``cur_k`` /
+    ``cur_v`` [B, Hkv, D]. ``seq_lens`` counts the current token. Returns
+    [B, H, D]. ``kernel`` / ``interpret`` are bound by whoever decided
+    (:func:`use_kernel`); the default is the XLA gather."""
+    if kernel:
+        return paged_decode_attention_pallas(
+            q, k_pages, v_pages, layer, block_tables, seq_lens, cur_k, cur_v,
+            interpret=interpret)
+    return paged_decode_attention(q, k_pages, v_pages, layer, block_tables,
+                                  seq_lens, cur_k=cur_k, cur_v=cur_v)
+
+
+def read_prefix(k_layer: jax.Array, v_layer: jax.Array,
+                table_row: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """A sequence's cached KV out of ONE layer's pool [N, block, Hkv, D], as
+    a scan over the stacked pools hands it to its body: the blocks of
+    ``table_row`` [1, W] in order, as [1, W * block, Hkv, D] each."""
+    def gather(pool):
+        return pool[table_row].reshape(1, -1, *pool.shape[-2:])
+
+    return gather(k_layer), gather(v_layer)
+
+
+# ---- export and import, block-wise --------------------------------------------
+
+
+def gather_blocks(k_pages, v_pages, ids):
+    """Blocks ``ids`` [n] of every layer, as a pair shaped like a pool of n
+    blocks: a copy, so the blocks may be freed at once."""
+    return k_pages[:, ids], v_pages[:, ids]
+
+
+def scatter_blocks(k_pages, v_pages, ids, k_new, v_new):
+    """The inverse: a gathered pair written at blocks ``ids`` (padding
+    entries point at the trash block)."""
+    return k_pages.at[:, ids].set(k_new), v_pages.at[:, ids].set(v_new)
+
+
+def block_range(k, v, lo: int, hi: int):
+    """Blocks [lo, hi) of a gathered pair."""
+    return k[:, lo:hi], v[:, lo:hi]
+
+
+def pad_blocks(k: np.ndarray, v: np.ndarray,
+               n_blocks: int) -> tuple[np.ndarray, np.ndarray]:
+    """A gathered pair on the host, zero-padded to ``n_blocks`` blocks so
+    that one compiled scatter serves every import."""
+    def pad(a):
+        out = np.zeros((a.shape[0], n_blocks, *a.shape[2:]), a.dtype)
+        out[:, :a.shape[1]] = a
+        return out
+
+    return pad(k), pad(v)

@@ -10,7 +10,9 @@ Design notes (TPU-first):
 - The decode path scans over the same stacked params and a layer index. The
   paged KV pools stay whole: the scan's body closes over them, attention
   reads them at (layer, page), and the step's new rows go in with one scatter
-  after the scan, so donated pools are updated in place.
+  after the scan, so donated pools are updated in place. How a pool is laid
+  out, read and written is ``kvcache/pages.py``'s business; this file hands
+  the pools through.
 - All matmuls run in the params' dtype (bf16 by default) with f32 softmax/norm
   accumulation; logits are f32.
 - Attention is injected via ``attention_fn`` so the sequence-parallel path can
@@ -24,8 +26,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from ..ops import apply_rope, causal_attention, paged_decode_attention, rms_norm, rope_table
-from ..ops.pallas_paged_attention import paged_decode_attention_pallas
+from ..kvcache import pages
+from ..ops import apply_rope, causal_attention, rms_norm, rope_table
 from .configs import ModelConfig
 
 Params = dict[str, Any]
@@ -206,12 +208,12 @@ def decode_step(
     cfg: ModelConfig,
     tokens: jnp.ndarray,       # [B] current input token per sequence
     positions: jnp.ndarray,    # [B] 0-based position of that token
-    k_pages: jnp.ndarray,      # [L, N_blocks, block, Hkv, Dh]
-    v_pages: jnp.ndarray,      # [L, N_blocks, block, Hkv, Dh]
+    k_pages: jnp.ndarray,      # the page pools (kvcache/pages.py)
+    v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     active: jnp.ndarray | None = None,  # [B] bool — padding-slot mask
-    use_pallas: bool = False,
-    pallas_interpret: bool = False,  # run the kernel interpreted (CPU tests)
+    *,
+    attention_fn: Callable[..., jnp.ndarray] = pages.decode_attention,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step with paged KV; returns (logits [B, V] f32, k_pages, v_pages).
 
@@ -224,21 +226,21 @@ def decode_step(
 
     The scan carries the activations and scans over (layer params, layer
     index) — never over the pages. The body closes over the stacked pools and
-    both attention ops read them at (layer, page); a pool scanned over would
+    ``attention_fn`` reads them at (layer, page); a pool scanned over would
     reach the Pallas kernel as one layer's slice, which XLA has to copy out
-    first (a custom call's operand cannot be a fused slice).
+    first (a custom call's operand cannot be a fused slice). ``attention_fn``
+    has ``pages.decode_attention``'s signature; the engine binds the kernel
+    into it where it decided for the kernel.
 
     Inactive batch slots must point their block table at the dedicated trash
     block 0 (the allocator reserves it).
     """
     B = tokens.shape[0]
-    block = k_pages.shape[2]
     Dh = cfg.head_dim
     cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)  # [B, half]
     seq_lens = positions + 1
 
-    blk_idx = block_tables[jnp.arange(B), positions // block]  # [B] physical block
-    slot = positions % block
+    cur_slots = pages.token_slots(k_pages, block_tables, positions)
 
     x = params["embed"][tokens]  # [B, D]
 
@@ -252,25 +254,17 @@ def decode_step(
         q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
         k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
 
-        if use_pallas:
-            attn = paged_decode_attention_pallas(q, k_pages, v_pages, layer,
-                                                 block_tables, seq_lens, k, v,
-                                                 interpret=pallas_interpret)
-        else:
-            attn = paged_decode_attention(q, k_pages, v_pages, layer,
-                                          block_tables, seq_lens,
-                                          cur_k=k, cur_v=v)
+        attn = attention_fn(q, k_pages, v_pages, layer, block_tables,
+                            seq_lens, k, v)
         x = x + attn.reshape(B, -1) @ lp["wo"]
         h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
         x = x + _ffn(cfg, lp, h)
         return x, (k, v)
 
     x, (k_cur, v_cur) = jax.lax.scan(
-        body, x, (params["layers"], jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
-    # One fused scatter of all layers' current-token KV: [L, B, Hkv, Dh] into
-    # pages at (layer, blk_idx[b], slot[b]).
-    k_pages = k_pages.at[:, blk_idx, slot].set(k_cur.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, blk_idx, slot].set(v_cur.astype(v_pages.dtype))
+        body, x, (params["layers"], pages.layer_indices(k_pages)))
+    # One fused scatter of all layers' current-token KV, [L, B, Hkv, Dh].
+    k_pages, v_pages = pages.write(k_pages, v_pages, k_cur, v_cur, *cur_slots)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
@@ -285,7 +279,7 @@ def prefill_with_prefix(
     tokens: jnp.ndarray,       # [1, S_bucket] suffix tokens (padded)
     suffix_len: jnp.ndarray,   # [1] valid suffix tokens
     prefix_len: jnp.ndarray,   # [1] tokens already present in the pages
-    k_pages: jnp.ndarray,      # [L, N, block, Hkv, Dh]
+    k_pages: jnp.ndarray,      # the page pools (kvcache/pages.py)
     v_pages: jnp.ndarray,
     block_table_row: jnp.ndarray,  # [1, max_blocks] — full table (KV scatter)
     prior_table_row: jnp.ndarray | None = None,  # [1, prefix_bucket] — gather
@@ -300,10 +294,9 @@ def prefill_with_prefix(
     """
     B, S = tokens.shape
     assert B == 1
-    block = k_pages.shape[2]
     if prior_table_row is None:
         prior_table_row = block_table_row
-    T = prior_table_row.shape[1] * block
+    T = prior_table_row.shape[1] * pages.block_size(k_pages)
     Dh = cfg.head_dim
 
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # [1,S]
@@ -326,8 +319,7 @@ def prefill_with_prefix(
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
 
-        k_prior = kp[prior_table_row].reshape(1, T, cfg.n_kv_heads, Dh)
-        v_prior = vp[prior_table_row].reshape(1, T, cfg.n_kv_heads, Dh)
+        k_prior, v_prior = pages.read_prefix(kp, vp, prior_table_row)
         k_all = jnp.concatenate([k_prior, k], axis=1)
         v_all = jnp.concatenate([v_prior, v], axis=1)
         attn = causal_attention(q, k_all, v_all, q_positions=positions,
@@ -340,49 +332,11 @@ def prefill_with_prefix(
     x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], k_pages, v_pages))
 
     # Scatter suffix KV at offset positions (padding → trash block 0).
-    t = jnp.arange(S, dtype=jnp.int32)
-    tgt = prefix_len[0] + t                                   # [S]
-    valid = t < suffix_len[0]
-    blk_for_t = jnp.where(valid, block_table_row[0, tgt // block], 0)
-    slot_for_t = jnp.where(valid, tgt % block, 0)
-    L = cfg.n_layers
-    k_flat = k_new.reshape(L, S, cfg.n_kv_heads, Dh).astype(k_pages.dtype)
-    v_flat = v_new.reshape(L, S, cfg.n_kv_heads, Dh).astype(v_pages.dtype)
-    k_pages = k_pages.at[:, blk_for_t, slot_for_t].set(k_flat)
-    v_pages = v_pages.at[:, blk_for_t, slot_for_t].set(v_flat)
+    k_pages, v_pages = pages.write_sequences(
+        k_pages, v_pages, k_new, v_new, block_table_row, suffix_len,
+        start=prefix_len)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
     logits = (last @ params["lm_head"]).astype(jnp.float32)
     return logits, k_pages, v_pages
-
-
-def write_prefill_kv(
-    k_pages: jnp.ndarray,  # [L, N, block, Hkv, Dh]
-    v_pages: jnp.ndarray,
-    k_new: jnp.ndarray,    # [L, B, S, Hkv, Dh] from forward(want_kv=True)
-    v_new: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [B, max_blocks]
-    seq_lens: jnp.ndarray,      # [B] number of valid prompt tokens
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter freshly-prefilled KV rows into their assigned pages.
-
-    Token t of sequence b lands in physical block block_tables[b, t//block] at
-    slot t%block. Padding tokens (t >= seq_lens[b]) are redirected to the trash
-    block 0 so the scatter stays static-shaped.
-    """
-    L, B, S, Hkv, Dh = k_new.shape
-    block = k_pages.shape[2]
-    t = jnp.arange(S, dtype=jnp.int32)
-    blk_for_t = block_tables[:, t // block]  # [B, S]
-    valid = t[None, :] < seq_lens[:, None]  # [B, S]
-    blk_for_t = jnp.where(valid, blk_for_t, 0)
-    slot_for_t = jnp.where(valid, t[None, :] % block, 0)
-
-    bidx = blk_for_t.reshape(-1)   # [B*S]
-    sidx = slot_for_t.reshape(-1)
-    k_flat = k_new.reshape(L, B * S, Hkv, Dh).astype(k_pages.dtype)
-    v_flat = v_new.reshape(L, B * S, Hkv, Dh).astype(v_pages.dtype)
-    k_pages = k_pages.at[:, bidx, sidx].set(k_flat)
-    v_pages = v_pages.at[:, bidx, sidx].set(v_flat)
-    return k_pages, v_pages

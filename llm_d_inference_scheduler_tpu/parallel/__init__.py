@@ -6,7 +6,6 @@ from .serve import (
     make_serve_mesh,
     serve_shardings,
     init_sharded_params,
-    alloc_sharded_pages,
     dryrun_serve,
 )
 from .pipeline import (
@@ -28,7 +27,6 @@ __all__ = [
     "make_serve_mesh",
     "serve_shardings",
     "init_sharded_params",
-    "alloc_sharded_pages",
     "dryrun_serve",
     "make_pp_mesh",
     "make_pp_forward",

@@ -52,13 +52,14 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.sampling import sample_tokens
+from ..kvcache import pages
 from ..models import llama
 from ..models.configs import ModelConfig
-from ..ops import paged_decode_attention, rms_norm, rope_table
+from ..ops import rms_norm, rope_table
 from .serve import validate_tp
 from .shardings import param_pspecs
 
-__all__ = ["make_pp_mesh", "shard_params_pp", "pp_page_sharding",
+__all__ = ["make_pp_mesh", "shard_params_pp",
            "make_pp_decode_chunk", "make_pp_prefill",
            "make_pp_prefill_with_prefix"]
 
@@ -79,15 +80,6 @@ def make_pp_mesh(devices=None, pp: int | None = None, tp: int = 1,
                          f"{len(devices)} devices")
     arr = np.array(devices[: pp * tp * ep]).reshape(pp, tp, ep)
     return Mesh(arr, PP_SERVE_AXES)
-
-
-# KV pages [L, N, block, Hkv, Dh]: layer axis follows the stage split,
-# kv-head axis follows tp.
-PAGE_SPEC = P("pp", None, None, "tp", None)
-
-
-def pp_page_sharding(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, PAGE_SPEC)
 
 
 def _param_specs(cfg: ModelConfig):
@@ -160,18 +152,17 @@ def _tp_full(x, n_tp: int, axis: int):
 
 
 def _decode_slab(cfg: ModelConfig, params, x, k_pages, v_pages, tables,
-                 positions, eff_blk):
+                 positions, eff_blk, slot):
     """One stage's layer slab for one decode token (shard_map-local view:
     L/P layers, Hkv/tp kv-heads, E/ep experts) with Megatron-TP collectives:
     psum over tp after the attention output projection, over (tp, ep) after
-    the FFN. KV for the new token scatters into ``eff_blk`` (the caller
-    trash-redirects off-turn writes). Shared by the broadcast ring and the
-    lane-group interleave."""
+    the FFN. KV for the new token scatters into (``eff_blk``, ``slot``) (the
+    caller trash-redirects off-turn writes). Shared by the broadcast ring and
+    the lane-group interleave."""
     B = x.shape[0]
     Dh = cfg.head_dim
     cos, sin = rope_table(positions, Dh, cfg.rope_theta)
     seq_lens = positions + 1
-    slot = positions % k_pages.shape[2]
 
     def body(x, layer_in):
         lp, layer = layer_in                                # local layer index
@@ -182,17 +173,17 @@ def _decode_slab(cfg: ModelConfig, params, x, k_pages, v_pages, tables,
         q, k = llama.qk_normed(cfg, lp, q, k)
         q = llama.apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
         k = llama.apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
-        attn = paged_decode_attention(q, k_pages, v_pages, layer, tables,
-                                      seq_lens, cur_k=k, cur_v=v)
+        attn = pages.decode_attention(q, k_pages, v_pages, layer, tables,
+                                      seq_lens, k, v)
         x = x + jax.lax.psum(attn.reshape(B, -1) @ lp["wo"], "tp")
         h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
         x = x + _ffn_psum(cfg, lp, h)
         return x, (k, v)
 
     x, (k_cur, v_cur) = jax.lax.scan(
-        body, x, (params["layers"], jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
-    k_pages = k_pages.at[:, eff_blk, slot].set(k_cur.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, eff_blk, slot].set(v_cur.astype(v_pages.dtype))
+        body, x, (params["layers"], pages.layer_indices(k_pages)))
+    k_pages, v_pages = pages.write(k_pages, v_pages, k_cur, v_cur, eff_blk,
+                                   slot)
     return x, k_pages, v_pages
 
 
@@ -202,18 +193,16 @@ def _ring_decode_step(cfg: ModelConfig, n_stages: int, n_tp: int, perm,
     """One token for all lanes through the stage ring. Local (per-shard)
     views: params.layers / pages carry L/P layers and Hkv/tp kv-heads.
     Returns (logits replicated, pages)."""
-    B = tokens.shape[0]
-    block = k_pages.shape[2]
-    blk_idx = block_tables[jnp.arange(B), positions // block]
+    blk_idx, slot = pages.token_slots(k_pages, block_tables, positions)
 
     x0 = _tp_full(params["embed"][tokens], n_tp, axis=1)    # [B, D]
     zero = jnp.zeros_like(x0)
 
     def slab(x, k_pages, v_pages, active):
         """This stage's layers on x; KV writes trash-redirected off-turn."""
-        eff_blk = jnp.where(active, blk_idx, 0)
+        eff_blk = jnp.where(active, blk_idx, pages.TRASH_BLOCK)
         return _decode_slab(cfg, params, x, k_pages, v_pages, block_tables,
-                            positions, eff_blk)
+                            positions, eff_blk, slot)
 
     def turn(t, carry):
         x, k_pages, v_pages = carry
@@ -295,7 +284,7 @@ def make_pp_decode_chunk(cfg: ModelConfig, mesh: Mesh, decode_chunk: int,
                     params, tokens, positions, k_pages, v_pages,
                     block_tables, key, temps, top_k, top_p)
 
-    page_spec = PAGE_SPEC
+    page_spec = pages.page_spec(mesh)
     sharded = shard_map(
         chunk, mesh=mesh,
         in_specs=(_param_specs(cfg), P(), P(), page_spec, page_spec, P(),
@@ -319,7 +308,6 @@ def _interleaved_chunk_body(cfg, n_stages, n_tp, perm, decode_chunk,
     stage = jax.lax.axis_index("pp")
     B = tokens.shape[0]
     Bg = B // n_stages
-    block = k_pages.shape[2]
     keys = jax.random.split(key, n_stages * K)
 
     def grp(arr, g):
@@ -359,11 +347,11 @@ def _interleaved_chunk_body(cfg, n_stages, n_tp, perm, decode_chunk,
         active = (t >= stage) & (i_s < K)
         pos_g = grp(pos, gs)
         tables_g = grp(block_tables, gs)
-        blk_idx = tables_g[jnp.arange(Bg), pos_g // block]
-        eff_blk = jnp.where(active, blk_idx, 0)
+        blk_idx, slot = pages.token_slots(k_pages, tables_g, pos_g)
+        eff_blk = jnp.where(active, blk_idx, pages.TRASH_BLOCK)
 
         x, k_pages, v_pages = _decode_slab(cfg, params, x, k_pages, v_pages,
-                                           tables_g, pos_g, eff_blk)
+                                           tables_g, pos_g, eff_blk, slot)
         x = jax.lax.ppermute(x, "pp", perm)
         return x, k_pages, v_pages, toks_out, cur_tok, pos
 
@@ -423,15 +411,12 @@ def make_pp_prefill(cfg: ModelConfig, mesh: Mesh, bucket: int,
         stage = jax.lax.axis_index("pp")
         S = tokens.shape[1]
         assert S == bucket, f"prefill traced at S={S}, keyed as bucket={bucket}"
-        block = k_pages.shape[2]
         Dh = cfg.head_dim
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :],
                                      (1, S))
         cos, sin = rope_table(positions, Dh, cfg.rope_theta)
-        t = jnp.arange(S, dtype=jnp.int32)
-        valid_t = t < seq_len[0]
-        blk_for_t = jnp.where(valid_t, block_table_row[0, t // block], 0)
-        slot_for_t = jnp.where(valid_t, t % block, 0)
+        blk_for_t, slot_for_t = pages.sequence_slots(
+            k_pages, block_table_row, seq_len, S)
 
         x0 = _tp_full(params["embed"][tokens], n_tp, axis=2)  # [1, S, D]
         if mm_embeds is not None:
@@ -440,21 +425,14 @@ def make_pp_prefill(cfg: ModelConfig, mesh: Mesh, bucket: int,
         zero = jnp.zeros_like(x0)
 
         def slab(x, k_pages, v_pages, active):
-            def body(x, layer_in):
-                lp, kp, vp = layer_in
+            def body(x, lp):
                 x, k, v = _tp_block(cfg, lp, x, cos, sin, positions)
                 return x, (k, v)
 
-            x, (k_new, v_new) = jax.lax.scan(
-                body, x, (params["layers"], k_pages, v_pages))
-            eff_blk = jnp.where(active, blk_for_t, 0)
-            Lp = k_new.shape[0]
-            k_flat = k_new.reshape(Lp, S, -1, Dh)           # local kv heads
-            v_flat = v_new.reshape(Lp, S, -1, Dh)
-            k_pages = k_pages.at[:, eff_blk, slot_for_t].set(
-                k_flat.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, eff_blk, slot_for_t].set(
-                v_flat.astype(v_pages.dtype))
+            x, (k_new, v_new) = jax.lax.scan(body, x, params["layers"])
+            eff_blk = jnp.where(active, blk_for_t, pages.TRASH_BLOCK)
+            k_pages, v_pages = pages.write(k_pages, v_pages, k_new, v_new,
+                                           eff_blk, slot_for_t)
             return x, k_pages, v_pages
 
         def turn(tn, carry):
@@ -476,7 +454,7 @@ def make_pp_prefill(cfg: ModelConfig, mesh: Mesh, bucket: int,
         tok = sample_tokens(logits, key, temps, top_k, top_p)
         return tok, k_pages, v_pages
 
-    page_spec = PAGE_SPEC
+    page_spec = pages.page_spec(mesh)
     if mm:
         def prefill_mm(params, tokens, seq_len, mm_embeds, mm_positions,
                        k_pages, v_pages, block_table_row, key, temps, top_k,
@@ -523,8 +501,7 @@ def make_pp_prefill_with_prefix(cfg: ModelConfig, mesh: Mesh,
         S = tokens.shape[1]
         assert S == suffix_bucket, (
             f"prefix prefill traced at S={S}, keyed as bucket={suffix_bucket}")
-        block = k_pages.shape[2]
-        T = prior_table_row.shape[1] * block
+        T = prior_table_row.shape[1] * pages.block_size(k_pages)
         Dh = cfg.head_dim
 
         positions = (prefix_len[:, None]
@@ -537,11 +514,8 @@ def make_pp_prefill_with_prefix(cfg: ModelConfig, mesh: Mesh,
         kv_positions = jnp.concatenate([prior_pos, positions], axis=1)
         kv_valid = jnp.concatenate([prior_valid, suffix_valid], axis=1)
 
-        t = jnp.arange(S, dtype=jnp.int32)
-        tgt = prefix_len[0] + t
-        valid_t = t < suffix_len[0]
-        blk_for_t = jnp.where(valid_t, block_table_row[0, tgt // block], 0)
-        slot_for_t = jnp.where(valid_t, tgt % block, 0)
+        blk_for_t, slot_for_t = pages.sequence_slots(
+            k_pages, block_table_row, suffix_len, S, start=prefix_len)
 
         x0 = _tp_full(params["embed"][tokens], n_tp, axis=2)  # [1, S, D]
         zero = jnp.zeros_like(x0)
@@ -556,8 +530,8 @@ def make_pp_prefill_with_prefix(cfg: ModelConfig, mesh: Mesh,
                 q, k = llama.qk_normed(cfg, lp, q, k)
                 q = llama.apply_rope(q, cos, sin)
                 k = llama.apply_rope(k, cos, sin)
-                k_prior = kp[prior_table_row].reshape(1, T, -1, Dh)
-                v_prior = vp[prior_table_row].reshape(1, T, -1, Dh)
+                k_prior, v_prior = pages.read_prefix(kp, vp,
+                                                     prior_table_row)
                 attn = llama.causal_attention(
                     q, jnp.concatenate([k_prior, k], axis=1),
                     jnp.concatenate([v_prior, v], axis=1),
@@ -570,14 +544,9 @@ def make_pp_prefill_with_prefix(cfg: ModelConfig, mesh: Mesh,
 
             x, (k_new, v_new) = jax.lax.scan(
                 body, x, (params["layers"], k_pages, v_pages))
-            eff_blk = jnp.where(active, blk_for_t, 0)
-            Lp = k_new.shape[0]
-            k_flat = k_new.reshape(Lp, S, -1, Dh)
-            v_flat = v_new.reshape(Lp, S, -1, Dh)
-            k_pages = k_pages.at[:, eff_blk, slot_for_t].set(
-                k_flat.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, eff_blk, slot_for_t].set(
-                v_flat.astype(v_pages.dtype))
+            eff_blk = jnp.where(active, blk_for_t, pages.TRASH_BLOCK)
+            k_pages, v_pages = pages.write(k_pages, v_pages, k_new, v_new,
+                                           eff_blk, slot_for_t)
             return x, k_pages, v_pages
 
         def turn(tn, carry):
@@ -599,7 +568,7 @@ def make_pp_prefill_with_prefix(cfg: ModelConfig, mesh: Mesh,
         tok = sample_tokens(logits, key, temps, top_k, top_p)
         return tok, k_pages, v_pages
 
-    page_spec = PAGE_SPEC
+    page_spec = pages.page_spec(mesh)
     sharded = shard_map(
         prefill, mesh=mesh,
         in_specs=(_param_specs(cfg), P(), P(), P(), page_spec, page_spec,
@@ -655,15 +624,6 @@ def make_pp_embed(cfg: ModelConfig, mesh: Mesh, bucket: int):
         in_specs=(_param_specs(cfg), P(), P()),
         out_specs=P())
     return jax.jit(sharded)
-
-
-def alloc_pp_pages(cfg: ModelConfig, mesh: Mesh, n_blocks: int):
-    shape = (cfg.n_layers, n_blocks, cfg.kv_block_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    dtype = jnp.dtype(cfg.dtype)
-    zeros = jax.jit(lambda: jnp.zeros(shape, dtype),
-                    out_shardings=pp_page_sharding(mesh))
-    return zeros(), zeros()
 
 
 def validate_pp(cfg: ModelConfig, pp: int, tp: int = 1, ep: int = 1) -> None:

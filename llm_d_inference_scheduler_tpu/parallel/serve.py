@@ -19,16 +19,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kvcache import pages
 from ..models import llama
 from ..models.configs import ModelConfig
 from .shardings import param_pspecs
 
 SERVE_AXES = ("dp", "tp", "ep")
-
-# KV pages [L, N_blocks, block, Hkv, Dh]: shard kv heads over tp, replicate the
-# block pool over dp/ep (any lane may reference any block; attention has no
-# experts axis).
-KV_PAGE_SPEC = P(None, None, None, "tp", None)
 
 
 def make_serve_mesh(devices=None, tp: int = 1, ep: int = 1) -> Mesh:
@@ -58,8 +54,7 @@ def serve_shardings(cfg: ModelConfig, mesh: Mesh):
     """(param shardings pytree, kv-page sharding) for an engine on `mesh`."""
     validate_tp(cfg, mesh.shape["tp"], mesh.shape.get("ep", 1))
     params = jax.tree.map(lambda s: NamedSharding(mesh, s), param_pspecs(cfg))
-    pages = NamedSharding(mesh, KV_PAGE_SPEC)
-    return params, pages
+    return params, pages.page_sharding(mesh)
 
 
 def init_sharded_params(cfg: ModelConfig, mesh: Mesh, key, dtype=None):
@@ -68,16 +63,6 @@ def init_sharded_params(cfg: ModelConfig, mesh: Mesh, key, dtype=None):
     return jax.jit(
         lambda k: llama.init_params(cfg, k, dtype=dtype),
         out_shardings=shardings)(key)
-
-
-def alloc_sharded_pages(cfg: ModelConfig, mesh: Mesh, n_blocks: int, dtype=None):
-    """Zeroed KV page buffers sharded on the kv-head axis."""
-    _, page_sharding = serve_shardings(cfg, mesh)
-    dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (cfg.n_layers, n_blocks, cfg.kv_block_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    zeros = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=page_sharding)
-    return zeros(), zeros()
 
 
 def dryrun_serve(cfg: ModelConfig, devices, tp: int = 2, ep: int = 1,
@@ -94,8 +79,9 @@ def dryrun_serve(cfg: ModelConfig, devices, tp: int = 2, ep: int = 1,
     block = cfg.kv_block_size
     prompt_len = min(block + block // 2, cfg.max_seq_len - decode_steps - 1)
     max_blocks = -(-(prompt_len + decode_steps) // block) + 1
-    n_blocks = 1 + B * max_blocks  # +1 trash block
     f32 = jnp.float32  # keep the cross-path comparison numerically tight
+    geom = pages.PageGeometry.for_model(
+        cfg, 1 + B * max_blocks, dtype="float32")  # +1 trash block
 
     rng = np.random.default_rng(0)
     tokens_np = rng.integers(1, cfg.vocab_size, (B, prompt_len)).astype(np.int32)
@@ -106,7 +92,7 @@ def dryrun_serve(cfg: ModelConfig, devices, tp: int = 2, ep: int = 1,
 
     def prefill(params, tokens, seq_lens, k_pages, v_pages, tables):
         logits, (k_new, v_new) = llama.forward(params, cfg, tokens, want_kv=True)
-        k_pages, v_pages = llama.write_prefill_kv(
+        k_pages, v_pages = pages.write_sequences(
             k_pages, v_pages, k_new, v_new, tables, seq_lens)
         last = jnp.take_along_axis(
             logits, (seq_lens - 1)[:, None, None], axis=1)[:, 0]
@@ -115,13 +101,13 @@ def dryrun_serve(cfg: ModelConfig, devices, tp: int = 2, ep: int = 1,
     def run(sharded: bool):
         if sharded:
             params = init_sharded_params(cfg, mesh, jax.random.key(0), dtype=f32)
-            k_pages, v_pages = alloc_sharded_pages(cfg, mesh, n_blocks, dtype=f32)
+            k_pages, v_pages = pages.alloc(
+                geom, sharding=pages.page_sharding(mesh))
             batch = NamedSharding(mesh, P("dp"))
             batch2 = NamedSharding(mesh, P("dp", None))
         else:
             params = llama.init_params(cfg, jax.random.key(0), dtype=f32)
-            shape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
-            k_pages, v_pages = jnp.zeros(shape, f32), jnp.zeros(shape, f32)
+            k_pages, v_pages = pages.alloc(geom)
             batch = batch2 = None
 
         def put(x, s):

@@ -18,11 +18,21 @@ Usage:
   JAX_PLATFORMS=cpu python scripts/aot_rehearsal.py [--model qwen3-4b]
       [--max-batch 16] [--max-model-len 2048] [--decode-batches 1,8]
       [--prefill 128x1,2048x1] [--prefix 16x128] [--topology v5e:2x2]
+
+Comparing two trees' programs: `--lowered-dir DIR --no-compile` writes each
+program's lowered StableHLO text to DIR and compiles nothing; run it in both
+checkouts and `diff -r` the directories. The text carries no source
+locations; a Pallas kernel's body (serialized in its custom call with file
+names, lines and the Python call stack) is printed as assembly without them.
+`--config-file` takes a published config.json as the benchmark's
+configurations are (`chipbench/configs/*.json`), for a model the registry
+does not name.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -32,9 +42,30 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def _without_locations(text: str) -> str:
+    """Lowered text with every Mosaic kernel body (base64 MLIR bytecode,
+    debug locations inside) replaced by its assembly without locations."""
+    import base64
+    import re
+
+    from jaxlib.mlir import ir
+
+    def body(m):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            return json.dumps(module.operation.get_asm(
+                enable_debug_info=False))
+
+    return re.sub(r'(?<=\\22body\\22: )\\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen3-4b")
+    ap.add_argument("--config-file", default="",
+                    help="a published config.json, registered as --model")
     ap.add_argument("--max-batch", type=int, default=16)
     ap.add_argument("--max-model-len", type=int, default=2048)
     ap.add_argument("--decode-chunk", type=int, default=8)
@@ -46,6 +77,9 @@ def main(argv=None) -> int:
                     help="prefix-prefill programs as SUFFIXxPREFIX_BLOCKS")
     ap.add_argument("--topology", default="v5e:2x2")
     ap.add_argument("--skip-init", action="store_true")
+    ap.add_argument("--lowered-dir", default="",
+                    help="write each program's lowered text here")
+    ap.add_argument("--no-compile", action="store_true")
     args = ap.parse_args(argv)
 
     import jax
@@ -61,7 +95,21 @@ def main(argv=None) -> int:
 
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+    from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
     from llm_d_inference_scheduler_tpu.models import llama
+
+    if args.config_file:
+        import types
+
+        from llm_d_inference_scheduler_tpu.models import configs
+        from llm_d_inference_scheduler_tpu.models.convert_hf import (
+            config_from_hf,
+        )
+
+        with open(args.config_file) as f:
+            published = json.load(f)
+        configs._REGISTRY[args.model] = config_from_hf(
+            types.SimpleNamespace(**published), name=args.model)
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
@@ -77,19 +125,21 @@ def main(argv=None) -> int:
                        max_model_len=args.max_model_len,
                        decode_chunk=args.decode_chunk, pallas_attention=True)
     mcfg = cfg.model_config
-    # The jitted bodies are methods; they read only the two configs and the
-    # (absent) pipeline mesh, so a bare instance carries them — building a
-    # real engine would materialise the weights on the host.
+    # The jitted bodies are methods; they read only the two configs, how to
+    # attend and the (absent) pipeline mesh, so a bare instance carries them
+    # — building a real engine would materialise the weights on the host.
     eng = object.__new__(TpuEngine)
     eng.cfg, eng.mcfg, eng.pp_mesh, eng._prefill_fns = cfg, mcfg, None, {}
+    eng._decode_attention = functools.partial(kvpages.decode_attention,
+                                              kernel=True)
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(
         lambda k: llama.init_params(mcfg, k), jax.random.key(0)))
-    n_blocks = cfg.num_kv_blocks()
-    width = -(-cfg.max_model_len // mcfg.kv_block_size)
-    pages = sds((mcfg.n_layers, n_blocks, mcfg.kv_block_size,
-                 mcfg.n_kv_heads, mcfg.head_dim), jnp.dtype(mcfg.dtype))
+    geom = kvpages.PageGeometry.for_engine(
+        mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
+    width = geom.max_blocks_per_seq
+    pages = sds(geom.shape, jnp.dtype(geom.dtype))
 
     def sampling(rows):
         return (key, sds((rows,), jnp.float32), sds((rows,), jnp.int32),
@@ -123,8 +173,16 @@ def main(argv=None) -> int:
     ok = True
     for name, fn, fn_args in programs:
         t0 = time.monotonic()
+        lowered = fn.lower(*fn_args)
+        if args.lowered_dir:
+            os.makedirs(args.lowered_dir, exist_ok=True)
+            with open(os.path.join(args.lowered_dir,
+                                   name.replace(" ", "_") + ".mlir"), "w") as f:
+                f.write(_without_locations(lowered.as_text()))
+        if args.no_compile:
+            continue
         try:
-            compiled = fn.lower(*fn_args).compile()
+            compiled = lowered.compile()
         except Exception as e:  # the compiler's refusal is the finding
             ok = False
             print(json.dumps({"program": name, "compiled": False,

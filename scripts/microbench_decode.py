@@ -45,6 +45,8 @@ def main(argv=None):
     ap.add_argument("--max-model-len", type=int, default=1024)
     args = ap.parse_args(argv)
 
+    import functools
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -56,11 +58,9 @@ def main(argv=None):
     configure_compile_cache()
 
     from llm_d_inference_scheduler_tpu.engine.sampling import sample_tokens
+    from llm_d_inference_scheduler_tpu.kvcache import pages
     from llm_d_inference_scheduler_tpu.models import llama
     from llm_d_inference_scheduler_tpu.models.configs import get_config
-    from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
-        paged_decode_attention_pallas,
-    )
 
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "chipbench"))
@@ -68,6 +68,7 @@ def main(argv=None):
 
     mcfg = get_config(args.model)
     block = mcfg.kv_block_size
+    kernel = functools.partial(pages.decode_attention, kernel=True)
     params = llama.init_params(mcfg, jax.random.key(0))
 
     def report(component, B, ctx, ms, **more):
@@ -76,10 +77,9 @@ def main(argv=None):
 
     for B, ctx in [map(int, pt.split("x")) for pt in args.points.split(",")]:
         max_blocks = args.max_model_len // block
-        n_blocks = 1 + B * max_blocks
         L, G, D = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
-        k_pages = jnp.zeros((L, n_blocks, block, G, D), jnp.bfloat16)
-        v_pages = jnp.zeros_like(k_pages)
+        k_pages, v_pages = pages.alloc(pages.PageGeometry.for_model(
+            mcfg, 1 + B * max_blocks, max_blocks, dtype="bfloat16"))
         tables = np.zeros((B, max_blocks), np.int32)
         for b in range(B):
             tables[b] = np.arange(1 + b * max_blocks, 1 + (b + 1) * max_blocks)
@@ -95,7 +95,7 @@ def main(argv=None):
                 kp, vp = carry
                 logits, kp, vp = llama.decode_step(
                     params, mcfg, tokens, positions, kp, vp, tables,
-                    use_pallas=True)
+                    attention_fn=kernel)
                 return (kp, vp), logits[:, 0]
 
             (kp, vp), ls = jax.lax.scan(body, (k_pages, v_pages), None, length=8)
@@ -112,8 +112,8 @@ def main(argv=None):
         # The stacked pools and a layer index, as decode_step calls it.
         def attn_chain(q, k_pages, v_pages):
             def body(acc, layer):
-                o = paged_decode_attention_pallas(q, k_pages, v_pages, layer,
-                                                  tables, seq_lens, cur, cur)
+                o = kernel(q, k_pages, v_pages, layer, tables, seq_lens, cur,
+                           cur)
                 return acc + o.astype(jnp.float32).sum(), None
 
             acc, _ = jax.lax.scan(body, jnp.float32(0),
@@ -132,18 +132,14 @@ def main(argv=None):
 
         # current-token KV scatter alone (all layers fused, K+V)
         k_cur = jnp.ones((L, B, G, D), jnp.bfloat16)
-        blk_idx = tables[jnp.arange(B), positions // block]
-        slot = positions % block
+        slots = pages.token_slots(k_pages, tables, positions)
 
         def scatter_chain(kp, vp):
             def body(carry, _):
-                kp, vp = carry
-                kp = kp.at[:, blk_idx, slot].set(k_cur)
-                vp = vp.at[:, blk_idx, slot].set(k_cur)
-                return (kp, vp), ()
+                return pages.write(*carry, k_cur, k_cur, *slots), ()
 
             (kp, vp), _ = jax.lax.scan(body, (kp, vp), None, length=8)
-            return kp[0, 0, 0, 0, 0]
+            return kp.reshape(-1)[0]
 
         ms = timeit(jax.jit(scatter_chain), k_pages, v_pages, iters=5) / 8
         report("kv_scatter(K+V, all L)", B, ctx, ms)

@@ -11,6 +11,7 @@ layers is here, as the guard that no layer's page pool is copied.
 """
 
 import dataclasses
+import functools
 import math
 import os
 import re
@@ -24,6 +25,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from llm_d_inference_scheduler_tpu.kvcache.pages import decode_attention
 from llm_d_inference_scheduler_tpu.models import llama
 from llm_d_inference_scheduler_tpu.models.configs import MIXTRAL_8X7B, QWEN3_4B
 from llm_d_inference_scheduler_tpu.ops import pallas_moe
@@ -96,7 +98,9 @@ def test_decode_step_copies_no_layers_page_pool(one_chip):
         lambda a: _sds(one_chip, a.shape, a.dtype),
         jax.eval_shape(lambda k: llama.init_params(m, k), jax.random.key(0)))
     compiled = jax.jit(
-        lambda *a: llama.decode_step(a[0], m, *a[1:], use_pallas=True),
+        lambda *a: llama.decode_step(
+            a[0], m, *a[1:],
+            attention_fn=functools.partial(decode_attention, kernel=True)),
         donate_argnums=(3, 4),
     ).lower(params, _sds(one_chip, (batch,), jnp.int32),
             _sds(one_chip, (batch,), jnp.int32), pages, pages,

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_d_inference_scheduler_tpu.kvcache import pages
 from llm_d_inference_scheduler_tpu.models import TINY, llama
 
 
@@ -48,14 +49,13 @@ def test_paged_decode_matches_full_forward(params):
     ref_logits, _ = llama.forward(params, cfg, tokens)
 
     # Paged path: prefill prompt, then decode token by token.
-    kshape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
-    k_pages = jnp.zeros(kshape, jnp.float32)
-    v_pages = jnp.zeros(kshape, jnp.float32)
+    k_pages, v_pages = pages.alloc(
+        pages.PageGeometry.for_model(cfg, n_blocks, dtype="float32"))
     block_tables = jnp.arange(1, 1 + B * max_blocks, dtype=jnp.int32).reshape(B, max_blocks)
 
     prefill_logits, (k_new, v_new) = llama.forward(params, cfg, tokens[:, :prompt_len], want_kv=True)
     seq_lens = jnp.full((B,), prompt_len, jnp.int32)
-    k_pages, v_pages = llama.write_prefill_kv(k_pages, v_pages, k_new, v_new, block_tables, seq_lens)
+    k_pages, v_pages = pages.write_sequences(k_pages, v_pages, k_new, v_new, block_tables, seq_lens)
     np.testing.assert_allclose(
         np.asarray(prefill_logits), np.asarray(ref_logits[:, :prompt_len]), rtol=2e-4, atol=2e-4
     )
@@ -84,14 +84,13 @@ def test_decode_crosses_block_boundary(params):
 
     max_blocks = 4
     n_blocks = 1 + max_blocks
-    kshape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
-    k_pages = jnp.zeros(kshape, jnp.float32)
-    v_pages = jnp.zeros(kshape, jnp.float32)
+    k_pages, v_pages = pages.alloc(
+        pages.PageGeometry.for_model(cfg, n_blocks, dtype="float32"))
     block_tables = jnp.arange(1, 1 + max_blocks, dtype=jnp.int32).reshape(1, max_blocks)
 
     prompt_len = 2
     _, (k_new, v_new) = llama.forward(params, cfg, tokens[:, :prompt_len], want_kv=True)
-    k_pages, v_pages = llama.write_prefill_kv(
+    k_pages, v_pages = pages.write_sequences(
         k_pages, v_pages, k_new, v_new, block_tables, jnp.array([prompt_len], jnp.int32)
     )
     for i in range(prompt_len, total):

@@ -283,6 +283,7 @@ def _decode_degrade_worker(pid, tok_q, err_q):
             maybe_init_distributed,
             run_follower,
         )
+        from llm_d_inference_scheduler_tpu.kvcache import pages
 
         cfg = _cfg(dist_coordinator=COORD_DEG, dist_num_processes=2,
                    dist_process_id=pid, dist_instr_port=INSTR_DEG)
@@ -300,13 +301,13 @@ def _decode_degrade_worker(pid, tok_q, err_q):
             # This decode group's wire is host (kv_wire=auto on cpu), so the
             # preflight has no usable addresses and must not touch
             # transfer_shards (port 1 would refuse anyway).
-            mesh, spec = eng._page_layout()
+            mesh = eng._page_mesh()
             assert mesh is not None and eng._kv_wire == "host"
             ktp = {
                 "remote_host": "127.0.0.1", "remote_port": 1,
                 "remote_request_id": "degrade-src",
                 "transfer_uuid": 7,
-                "kv_mesh": mesh_descriptor(mesh, spec),
+                "kv_mesh": mesh_descriptor(mesh, pages.page_spec(mesh)),
                 "transfer_shards": ["127.0.0.1:1", "127.0.0.1:1"],
             }
             req = EngineRequest(

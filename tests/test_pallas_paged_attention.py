@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_d_inference_scheduler_tpu.kvcache import pages
 from llm_d_inference_scheduler_tpu.models import llama
 from llm_d_inference_scheduler_tpu.models.configs import (
     MIXTRAL_8X7B,
@@ -204,9 +205,10 @@ def test_decode_step_reads_each_layers_own_pages(use_pallas):
               if use_pallas else paged_decode_attention)
     want = _decode_step_by_layer_loop(params, cfg, tokens, positions, k_pages,
                                       v_pages, block_tables, attend)
-    got = llama.decode_step(params, cfg, tokens, positions, k_pages, v_pages,
-                            block_tables, use_pallas=use_pallas,
-                            pallas_interpret=True)
+    got = llama.decode_step(
+        params, cfg, tokens, positions, k_pages, v_pages, block_tables,
+        attention_fn=functools.partial(pages.decode_attention,
+                                       kernel=use_pallas, interpret=True))
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-5, atol=1e-5)
@@ -216,7 +218,7 @@ def test_decode_step_reads_each_layers_own_pages(use_pallas):
 
 
 def test_engine_pallas_branch_matches_default():
-    """The engine's use_pallas decode branch (interpreted) generates the same
+    """The engine's kernel decode branch (interpreted) generates the same
     greedy tokens as the XLA path."""
     import asyncio
     from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest

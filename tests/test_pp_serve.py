@@ -13,6 +13,7 @@ import pytest
 
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
 from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+from llm_d_inference_scheduler_tpu.kvcache import pages
 from llm_d_inference_scheduler_tpu.models import llama
 from llm_d_inference_scheduler_tpu.models.configs import get_config
 
@@ -59,7 +60,6 @@ def test_pp_engine_matches_single_device():
 def test_pp_ring_logits_match_plain_decode():
     """Op-level: one ring decode step vs llama.decode_step on real pages."""
     from llm_d_inference_scheduler_tpu.parallel.pp_serve import (
-        alloc_pp_pages,
         make_pp_decode_chunk,
         make_pp_mesh,
         shard_params_pp,
@@ -72,7 +72,8 @@ def test_pp_ring_logits_match_plain_decode():
     B, n_blocks = 2, 9
     block = cfg.kv_block_size
     maxB = 4
-    kshape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
+    geom = pages.PageGeometry.for_model(cfg, n_blocks, dtype="float32")
+    kshape = geom.shape
     k_pages = jnp.asarray(
         np.random.default_rng(0).normal(size=kshape), jnp.float32)
     v_pages = jnp.asarray(
@@ -85,7 +86,7 @@ def test_pp_ring_logits_match_plain_decode():
         params, cfg, tokens, positions, k_pages, v_pages, tables)
 
     pp_params = shard_params_pp(params, cfg, mesh)
-    pk, pv = alloc_pp_pages(cfg, mesh, n_blocks)
+    pk, pv = pages.alloc(geom, sharding=pages.page_sharding(mesh))
     pk = jax.device_put(k_pages, pk.sharding)
     pv = jax.device_put(v_pages, pv.sharding)
     chunk = make_pp_decode_chunk(cfg, mesh, decode_chunk=1)
@@ -178,7 +179,6 @@ def test_pp_interleaved_chunk_matches_plain_decode_loop():
     ring pipeline) reproduces a greedy plain-decode loop, tokens AND page
     writes."""
     from llm_d_inference_scheduler_tpu.parallel.pp_serve import (
-        alloc_pp_pages,
         make_pp_decode_chunk_interleaved,
         make_pp_mesh,
         shard_params_pp,
@@ -191,7 +191,8 @@ def test_pp_interleaved_chunk_matches_plain_decode_loop():
     B, K, n_blocks = 4, 3, 25
     block = cfg.kv_block_size
     max_blocks = 6
-    kshape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
+    geom = pages.PageGeometry.for_model(cfg, n_blocks, dtype="float32")
+    kshape = geom.shape
     k_pages = jnp.asarray(
         np.random.default_rng(0).normal(size=kshape), jnp.float32)
     v_pages = jnp.asarray(
@@ -214,7 +215,7 @@ def test_pp_interleaved_chunk_matches_plain_decode_loop():
         pos = pos + 1
 
     pp_params = shard_params_pp(params, cfg, mesh)
-    pk, pv = alloc_pp_pages(cfg, mesh, n_blocks)
+    pk, pv = pages.alloc(geom, sharding=pages.page_sharding(mesh))
     pk = jax.device_put(k_pages, pk.sharding)
     pv = jax.device_put(v_pages, pv.sharding)
     chunk = make_pp_decode_chunk_interleaved(cfg, mesh, K)
@@ -235,7 +236,6 @@ def test_pp_tp_ring_logits_match_plain_decode():
     """Op-level: one pp×tp ring decode step vs llama.decode_step, including
     the KV writes landing in the (pp, tp)-sharded pages."""
     from llm_d_inference_scheduler_tpu.parallel.pp_serve import (
-        alloc_pp_pages,
         make_pp_decode_chunk,
         make_pp_mesh,
         shard_params_pp,
@@ -247,7 +247,8 @@ def test_pp_tp_ring_logits_match_plain_decode():
 
     B, n_blocks = 2, 9
     block = cfg.kv_block_size
-    kshape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
+    geom = pages.PageGeometry.for_model(cfg, n_blocks, dtype="float32")
+    kshape = geom.shape
     k_pages = jnp.asarray(
         np.random.default_rng(0).normal(size=kshape), jnp.float32)
     v_pages = jnp.asarray(
@@ -260,7 +261,7 @@ def test_pp_tp_ring_logits_match_plain_decode():
         params, cfg, tokens, positions, k_pages, v_pages, tables)
 
     pp_params = shard_params_pp(params, cfg, mesh)
-    pk, pv = alloc_pp_pages(cfg, mesh, n_blocks)
+    pk, pv = pages.alloc(geom, sharding=pages.page_sharding(mesh))
     pk = jax.device_put(k_pages, pk.sharding)
     pv = jax.device_put(v_pages, pv.sharding)
     chunk = make_pp_decode_chunk(cfg, mesh, decode_chunk=1)

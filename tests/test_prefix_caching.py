@@ -8,6 +8,7 @@ import numpy as np
 
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
 from llm_d_inference_scheduler_tpu.engine.blocks import PrefixCachingAllocator
+from llm_d_inference_scheduler_tpu.kvcache import pages
 from llm_d_inference_scheduler_tpu.models import TINY, llama
 
 
@@ -26,15 +27,14 @@ def test_prefill_with_prefix_matches_full_forward():
 
     max_blocks = 8
     n_blocks = 1 + max_blocks
-    kshape = (cfg.n_layers, n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
-    k_pages = jnp.zeros(kshape, jnp.float32)
-    v_pages = jnp.zeros(kshape, jnp.float32)
+    k_pages, v_pages = pages.alloc(
+        pages.PageGeometry.for_model(cfg, n_blocks, dtype="float32"))
     table = jnp.arange(1, 1 + max_blocks, dtype=jnp.int32).reshape(1, max_blocks)
 
     # Stage 1: prefill ONLY the prefix into the pages (simulating cached blocks).
     _, (k_new, v_new) = llama.forward(params, cfg, tokens[:, :prefix_len],
                                       want_kv=True)
-    k_pages, v_pages = llama.write_prefill_kv(
+    k_pages, v_pages = pages.write_sequences(
         k_pages, v_pages, k_new, v_new, table,
         jnp.array([prefix_len], jnp.int32))
 
