@@ -110,10 +110,6 @@ class EngineConfig:
     # Interpret the kernels: for tests on the CPU only. The server CLI has no
     # flag for it, so a served engine cannot run the interpreter by accident.
     pallas_interpret: bool = False
-    # Pallas grouped-matmul MoE FFN (ops/pallas_moe.py) for n_experts>0
-    # models; single-device only (the ep-sharded path stays dense inside its
-    # shard_map). Interpreted when pallas_interpret is set.
-    pallas_moe: bool = False
     # Tensor parallelism: shard params (Megatron TP) + KV pages (kv-head axis)
     # over a tp-sized mesh axis; remaining devices form the dp axis. 1 = the
     # single-device layout (no mesh). BASELINE.md config 4 path.

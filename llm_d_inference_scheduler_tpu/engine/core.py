@@ -39,6 +39,7 @@ import numpy as np
 
 from ..kvcache import pages, wire
 from ..models import llama
+from ..ops import pallas_moe
 from ..utils.hashing import chain_block_hashes
 from .blocks import BlockAllocator, PrefixCachingAllocator
 from .config import EngineConfig
@@ -173,27 +174,7 @@ class TpuEngine:
         self._decode_attention = functools.partial(
             pages.decode_attention, kernel=cfg.pallas_attention,
             interpret=cfg.pallas_interpret)
-        if cfg.pallas_moe and self.mcfg.n_experts:
-            if cfg.tp_size > 1 or cfg.ep_size > 1:
-                raise ValueError("pallas_moe requires tp_size=ep_size=1 "
-                                 "(the sharded path stays dense)")
-            from ..ops.pallas_moe import ROW_TILES, pick_ff_tile
-
-            # The rule the kernel itself tiles by, for both row tiles it
-            # serves with (decode and prefill): refuse here, at start-up,
-            # what the compiler would refuse at the first request.
-            itemsize = jnp.dtype(self.mcfg.dtype).itemsize
-            for tm in ROW_TILES:
-                if pick_ff_tile(self.mcfg.d_model, self.mcfg.d_ff, tm,
-                                itemsize) is None:
-                    raise ValueError(
-                        f"pallas_moe: d_ff={self.mcfg.d_ff} has no "
-                        "128-aligned tile divisor that fits VMEM at "
-                        f"d_model={self.mcfg.d_model}, row tile {tm}; use "
-                        "the dense path")
-            self.mcfg = dataclasses.replace(
-                self.mcfg, moe_impl="grouped_interpret"
-                if cfg.pallas_interpret else "grouped")
+        self._bind_moe_form(self.device.platform)
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
 
@@ -515,14 +496,37 @@ class TpuEngine:
         def step(carry, k_step):
             tokens, positions, k_pages, v_pages = carry
             logits, k_pages, v_pages = llama.decode_step(
-                params, self.mcfg, tokens, positions, k_pages, v_pages,
-                block_tables, attention_fn=self._decode_attention)
+                params, self._model_for(tokens.size), tokens, positions,
+                k_pages, v_pages, block_tables,
+                attention_fn=self._decode_attention)
             nxt = sample_tokens(logits, k_step, temps, top_k, top_p)
             return (nxt, positions + 1, k_pages, v_pages), nxt
 
         (_, _, k_pages, v_pages), toks = jax.lax.scan(
             step, (tokens, positions, k_pages, v_pages), keys)
         return toks, k_pages, v_pages
+
+    def _bind_moe_form(self, platform: str) -> None:
+        """The MoE FFN's form is chosen per program, from its token count
+        (pallas_moe.use_grouped) and from what this engine is: _model_for is
+        what a step function traces with, _device_call counts by the same
+        answer."""
+        cfg = self.cfg
+        self._moe_grouped = functools.partial(
+            pallas_moe.use_grouped, n_experts=self.mcfg.n_experts,
+            experts_per_token=self.mcfg.experts_per_token,
+            d_model=self.mcfg.d_model, d_ff=self.mcfg.d_ff,
+            platform=platform, interpret=cfg.pallas_interpret,
+            sharded=(cfg.tp_size > 1 or cfg.ep_size > 1 or cfg.pp_size > 1
+                     or cfg.dist_num_processes > 1))
+        self._mcfg_grouped = dataclasses.replace(
+            self.mcfg, moe_impl="grouped_interpret"
+            if cfg.pallas_interpret else "grouped")
+
+    def _model_for(self, tokens: int):
+        """The model as a program of ``tokens`` rows (batch x sequence,
+        padded) traces it: the MoE FFN in the form the shape calls for."""
+        return self._mcfg_grouped if self._moe_grouped(tokens) else self.mcfg
 
     def _prefill_fn(self, bucket: int):
         """Per-bucket jitted prefill: forward + KV scatter + fused first-token
@@ -536,7 +540,9 @@ class TpuEngine:
         if bucket not in self._prefill_fns:
             def impl(params, tokens, seq_len, k_pages, v_pages, block_table_row,
                      key, temps, top_k, top_p):
-                logits, (k_new, v_new) = llama.forward(params, self.mcfg, tokens, want_kv=True)
+                logits, (k_new, v_new) = llama.forward(
+                    params, self._model_for(tokens.size), tokens,
+                    want_kv=True)
                 k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
                 last = jnp.take_along_axis(
@@ -562,8 +568,8 @@ class TpuEngine:
                      k_pages, v_pages, block_table_row,
                      rng, temps, top_k, top_p):
                 logits, (k_new, v_new) = llama.forward(
-                    params, self.mcfg, tokens, want_kv=True,
-                    mm_embeds=mm_embeds, mm_positions=mm_positions)
+                    params, self._model_for(tokens.size), tokens,
+                    want_kv=True, mm_embeds=mm_embeds, mm_positions=mm_positions)
                 k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
                 last = jnp.take_along_axis(
@@ -589,7 +595,8 @@ class TpuEngine:
                      block_table_row, prior_table_row,
                      rng, temps, top_k, top_p):
                 logits, k_pages, v_pages = llama.prefill_with_prefix(
-                    params, self.mcfg, tokens, suffix_len, prefix_len,
+                    params, self._model_for(tokens.size), tokens, suffix_len,
+                    prefix_len,
                     k_pages, v_pages, block_table_row, prior_table_row)
                 tok = sample_tokens(logits, rng, temps, top_k, top_p)
                 return tok, k_pages, v_pages
@@ -805,8 +812,9 @@ class TpuEngine:
                     fn = make_pp_embed(self.mcfg, self.pp_mesh, bucket)
                 else:
                     def impl(params, tokens, seq_len):
-                        hidden, _ = llama.forward(params, self.mcfg, tokens,
-                                                  want_hidden=True)
+                        hidden, _ = llama.forward(
+                            params, self._model_for(tokens.size), tokens,
+                            want_hidden=True)
                         mask = (jnp.arange(tokens.shape[1])
                                 < seq_len[0])[None, :, None]
                         pooled = (hidden * mask).sum(axis=1) / seq_len[0]
@@ -2283,6 +2291,13 @@ class TpuEngine:
         key = self._op_shape_key(op, args)
         if key is None:
             return self._exec_op(op, args)
+        if self.mcfg.n_experts:
+            # Rows this program puts through the MoE FFN (padded; a decode
+            # chunk's lanes once a step), under the form its shape traced to.
+            rows = args["tokens"].size
+            self.telemetry.moe_ffn_tokens.labels(
+                form="grouped" if self._moe_grouped(rows) else "dense").inc(
+                    rows * (self.cfg.decode_chunk if op[0] == "decode" else 1))
         t0 = time.monotonic()
         result = self._exec_op(op, args)
         dt = time.monotonic() - t0

@@ -147,6 +147,12 @@ class EngineTelemetry:
             registry=self.registry,
             buckets=(.05, .1, .25, .5, 1, 2.5, 5, 10, 30, 60, 120))
 
+        self.moe_ffn_tokens = Counter(
+            "jetstream:moe_ffn_tokens_total",
+            "Rows (padded tokens) dispatched through the MoE FFN, by the form "
+            "their program's shape traced to (ops/pallas_moe.use_grouped); "
+            "counted on the host at dispatch, empty for a dense model",
+            ("form",), registry=self.registry)
         self.prompt_tokens = Counter("jetstream:prompt_tokens_total", "Prefilled tokens",
                                      registry=self.registry)
         self.prefix_cached_tokens = Counter(

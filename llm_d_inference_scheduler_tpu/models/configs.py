@@ -28,10 +28,11 @@ class ModelConfig:
     # Mixture-of-experts (Mixtral-family): n_experts == 0 means dense FFN.
     n_experts: int = 0
     experts_per_token: int = 2
-    # MoE FFN implementation: "dense" (dense-over-experts einsums — the
-    # correctness baseline, required under expert-parallel shard_map) |
-    # "grouped" (Pallas grouped-matmul, ops/pallas_moe.py) |
-    # "grouped_interpret" (same kernel, interpreter — CPU tests).
+    # MoE FFN form: "dense" (dense-over-experts einsums — the correctness
+    # baseline, required under expert-parallel shard_map) | "grouped" (the
+    # chosen experts' rows alone, ops/pallas_moe.py) | "grouped_interpret"
+    # (same kernel, interpreter — CPU tests). The engine sets it program by
+    # program from the shape (pallas_moe.use_grouped); tests force a form.
     moe_impl: str = "dense"
     # Qwen3 family: explicit head_dim decoupled from d_model/n_heads, and
     # per-head RMSNorm on q/k before RoPE.

@@ -120,11 +120,28 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray) -> jnp.ndarray:
             from ..ops.pallas_moe import moe_ffn_grouped
 
             y = moe_ffn_grouped(lp, h, cfg.n_experts, cfg.experts_per_token,
+                                layer=lp.get("layer"),
                                 interpret=cfg.moe_impl == "grouped_interpret")
         else:
             y = _moe_ffn(cfg, lp, h)
         return y[:, 0] if squeeze else y
     return (jax.nn.silu(h @ lp["w1"]) * (h @ lp["w3"])) @ lp["w2"]
+
+
+def _over_layers(cfg: ModelConfig, layers: Params) -> tuple[Params, Params]:
+    """(What a scan over the stacked layers slices, what its body closes
+    over and merges into the slice.) The grouped MoE FFN is a Pallas call,
+    and one layer's slice of the expert weights would reach it as a copy (a
+    custom call's operand cannot be a fused slice): there the scan goes over
+    everything else and a layer index, and the body keeps the expert weights
+    whole, which the kernel reads at (layer, expert). In every other case the
+    scan slices all of ``layers``, as it always did, and nothing is merged."""
+    if "router" not in layers or not cfg.moe_impl.startswith("grouped"):
+        return layers, {}
+    whole = {n: layers[n] for n in ("w1", "w2", "w3")}
+    sliced = {n: a for n, a in layers.items() if n not in whole}
+    sliced["layer"] = jnp.arange(layers["router"].shape[0], dtype=jnp.int32)
+    return sliced, whole
 
 
 def _layer(
@@ -188,11 +205,14 @@ def forward(
             mm_embeds.astype(x.dtype), mode="drop")
     attn_kwargs = dict(q_positions=positions, kv_positions=positions, kv_valid=kv_valid)
 
+    layers, whole = _over_layers(cfg, params["layers"])
+
     def body(x, lp):
-        x, k, v = _layer(cfg, lp, x, cos, sin, attention_fn, attn_kwargs)
+        x, k, v = _layer(cfg, {**lp, **whole}, x, cos, sin, attention_fn,
+                         attn_kwargs)
         return x, (k, v) if want_kv else None
 
-    x, kv = jax.lax.scan(body, x, params["layers"])
+    x, kv = jax.lax.scan(body, x, layers)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if want_hidden:
         # Embeddings surface: final-norm hidden states, lm head skipped
@@ -244,8 +264,11 @@ def decode_step(
 
     x = params["embed"][tokens]  # [B, D]
 
+    layers, whole = _over_layers(cfg, params["layers"])
+
     def body(x, layer_in):
         lp, layer = layer_in
+        lp = {**lp, **whole}
         h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
         q = (h @ lp["wq"]).reshape(B, cfg.n_heads, Dh)
         k = (h @ lp["wk"]).reshape(B, cfg.n_kv_heads, Dh)
@@ -262,7 +285,7 @@ def decode_step(
         return x, (k, v)
 
     x, (k_cur, v_cur) = jax.lax.scan(
-        body, x, (params["layers"], pages.layer_indices(k_pages)))
+        body, x, (layers, pages.layer_indices(k_pages)))
     # One fused scatter of all layers' current-token KV, [L, B, Hkv, Dh].
     k_pages, v_pages = pages.write(k_pages, v_pages, k_cur, v_cur, *cur_slots)
 
@@ -309,8 +332,11 @@ def prefill_with_prefix(
 
     x = params["embed"][tokens]  # [1, S, D]
 
+    layers, whole = _over_layers(cfg, params["layers"])
+
     def body(x, layer_in):
         lp, kp, vp = layer_in
+        lp = {**lp, **whole}
         h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
         q = (h @ lp["wq"]).reshape(1, S, cfg.n_heads, Dh)
         k = (h @ lp["wk"]).reshape(1, S, cfg.n_kv_heads, Dh)
@@ -329,7 +355,7 @@ def prefill_with_prefix(
         x = x + _ffn(cfg, lp, h)
         return x, (k, v)
 
-    x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], k_pages, v_pages))
+    x, (k_new, v_new) = jax.lax.scan(body, x, (layers, k_pages, v_pages))
 
     # Scatter suffix KV at offset positions (padding → trash block 0).
     k_pages, v_pages = pages.write_sequences(

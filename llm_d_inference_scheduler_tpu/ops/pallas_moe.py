@@ -1,27 +1,35 @@
-"""Pallas grouped-matmul MoE FFN (megablox-style) for Mixtral-family models.
+"""Grouped-matmul MoE FFN for Mixtral-family models, and the rule that says
+when it serves.
 
-The dense-over-experts formulation in models/llama.py:_moe_ffn computes every
-expert for every token — regular and shardable, but E/k× the necessary FLOPs
-and it always streams ALL expert weights from HBM. This kernel computes only
-the (token, selected-expert) pairs:
+The dense-over-experts form in models/llama.py:_moe_ffn computes every expert
+for every token: E/k times the FLOPs a token needs. Below the chip's ridge
+(a decode chunk's 2-16 rows) that costs nothing — both forms are the read of
+every expert's weights, and dense has no sort, scatter or padding. Above it
+(a prefill of hundreds of tokens) the extra FLOPs are time. So the form is
+chosen per traced program, from its shapes: :func:`use_grouped`.
 
-1. XLA side (:func:`moe_ffn_grouped`): router top-k → expand each token into
-   its k (token, expert) rows → stable-sort rows by expert → scatter into a
-   *group-padded* layout where each expert's rows start at a row-tile
-   boundary (buffer size is static: T·k + E·TM rows; only the offsets are
-   data). A tile→expert map is computed with a searchsorted.
-2. Pallas side (:func:`_grouped_ffn_call`): grid (row_tiles, F_tiles); the
-   tile→expert map is scalar-prefetched so each grid step's BlockSpec
-   index_map pulls w1/w3/w2 slices of exactly the ONE expert this row tile
-   belongs to (unused experts are never read from HBM). Each step computes
-   silu(x@w1_f)·(x@w3_f) @ w2_f and accumulates the [TM, D] partial into the
-   output tile across F steps (f32 accumulation, revisit pattern).
-3. Back in XLA: gather rows out of the padded layout, weight by the router
-   gates, and sum each token's k rows.
+The grouped form computes only the (token, chosen expert) rows:
 
-Reference analogue: none — the reference router is control-plane Go
-(SURVEY.md preamble); this is the engine half's hot op. Design follows the
-public megablox/ragged-matmul pattern (PAPERS.md) re-derived for this layout.
+1. XLA side (:func:`moe_ffn_grouped`): router top-k → each token becomes its k
+   (token, expert) rows → stable sort by expert → a *group-padded* layout in
+   which every expert's rows start at a row-tile boundary. The buffer is
+   static, T·k + E·tm rows; only the offsets are data, so no token is ever
+   dropped, whatever the routing. A tile → expert map and the count of tiles
+   that hold rows go to the kernel as prefetched scalars.
+2. Pallas side (:func:`_grouped_matmul`), twice: ``silu(x·w1) * (x·w3)`` →
+   ``[Tp, F]``, then ``·w2``. The grid is (N tiles, K tiles, row tiles) with
+   the ROW tiles innermost: consecutive row tiles of one expert map to the
+   same weight block, which the pipeline then does not fetch again, so an
+   expert's weights are read once a layer and not once a row tile. Partial
+   sums over K wait in an f32 scratch that holds every row tile. Tiles past
+   the last group hold no rows: they are skipped, and their block indices
+   repeat the last live tile's so that nothing is copied for them.
+3. Back in XLA: gather each token's k rows out of the padded layout, weight
+   them by the router's gates and add.
+
+bf16 operands and f32 accumulation, as the dense einsums have them. Reference
+analogue: none — the reference router is control-plane Go (SURVEY.md); the
+layout follows the public megablox / ragged-matmul pattern (PAPERS.md).
 """
 
 from __future__ import annotations
@@ -33,174 +41,246 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Rows of one tile: the MXU's height. An expert's group is padded to it.
+ROW_TILE = 128
 
-# Row tiles the kernel serves with (see moe_ffn_grouped): the bf16 sublane
-# floor for decode-scale token counts, MXU height for prefill-scale ones.
-ROW_TILES = (16, 128)
+# Token count of a program from which the grouped form serves: twice the
+# ridge. A v5e's ridge is 197 TFLOP/s / 819 GB/s = 240 rows of bf16, whatever
+# the expert count: under it both forms take the time of reading every
+# expert's weights, and at it they still measure the same (one Mixtral layer
+# at 256 tokens: dense 4.40 ms, grouped 4.27; at 512: 8.47 and 5.33; PERF.md
+# section 6, PR 31), so the line stands where the gain starts to pay for two
+# more kernel programs traced at every start.
+GROUPED_MIN_TOKENS = 512
 
-# What one kernel may keep in VMEM: the TPU compiler's scoped limit. It
-# charges a grid step exactly the terms _vmem_bytes counts (compiled for a
-# described v5e: 15.47 MiB by this count passes, 16.24 MiB is refused with
-# "Scoped allocation with size 16.24M"), so the limit itself is the budget.
-VMEM_BUDGET_BYTES = 16 * 2 ** 20
-
-
-def _vmem_bytes(d_model: int, tf: int, tm: int, itemsize: int) -> int:
-    """VMEM one (row_tile, f_tile) grid step holds: the three weight blocks
-    ([D, tf], [D, tf], [tf, D]) and the x / out row tiles, each
-    double-buffered by the pipeline, plus the f32 accumulator."""
-    weights = 3 * 2 * d_model * tf * itemsize
-    rows = 2 * 2 * tm * d_model * itemsize
-    acc = tm * d_model * 4
-    return weights + rows + acc
+# What one grouped matmul may keep in VMEM (of a v5e's 128 MiB; the
+# compiler's default scoped limit of 16 MiB is raised to what the tiles need).
+VMEM_BUDGET_BYTES = 40 * 2 ** 20
 
 
-def pick_ff_tile(d_model: int, d_ff: int, tm: int, itemsize: int,
-                 tf: int = 512) -> int | None:
-    """Largest lane-aligned (multiple-of-128) tile ≤ tf that divides d_ff
-    and whose working set at row tile ``tm`` fits the VMEM budget; None when
-    there is none (the grouped kernel then cannot serve this geometry). The
-    one rule for the kernel's own tiling and for callers that gate on it."""
-    candidates = [t for t in range(128, min(tf, d_ff) + 1, 128)
-                  if d_ff % t == 0
-                  and _vmem_bytes(d_model, t, tm, itemsize)
-                  <= VMEM_BUDGET_BYTES]
-    return candidates[-1] if candidates else None
+def use_grouped(tokens: int, *, n_experts: int, experts_per_token: int,
+                d_model: int, d_ff: int, platform: str, sharded: bool,
+                interpret: bool = False) -> bool:
+    """Whether a program that puts ``tokens`` rows (batch x sequence, padded)
+    through the MoE FFN computes the chosen experts' rows alone (True) or
+    every expert for every row (False). One rule, from what is known when
+    the program is traced: the grouped form serves where it compiles and
+    wins — a real TPU (or the interpreter, for tests), whole weights on one
+    device (a sharded engine keeps the dense einsums, which XLA partitions),
+    widths the kernel can tile, fewer chosen experts than experts, and a token
+    count past the ridge."""
+    if sharded or not (platform == "tpu" or interpret):
+        return False
+    if not 0 < experts_per_token < n_experts:
+        return False
+    if d_model % 128 or d_ff % 128:
+        return False
+    return tokens >= GROUPED_MIN_TOKENS
 
 
-def _ffn_kernel(tile_expert, x_ref, w1_ref, w3_ref, w2_ref, out_ref, acc_ref):
-    """One (row_tile, f_tile) grid step: fused SwiGLU partial for one expert.
-
-    out_ref maps only the row-tile grid axis, so it is revisited across the
-    inner F axis; acc_ref scratch carries the f32 accumulation.
-    """
-    f = pl.program_id(1)
-    x = x_ref[...]
-    up = jax.lax.dot_general(x, w1_ref[0], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    gate = jax.lax.dot_general(x, w3_ref[0], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(up) * gate).astype(x.dtype)
-    part = jax.lax.dot_general(act, w2_ref[0], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-    @pl.when(f == 0)
-    def _init():
-        acc_ref[...] = part
-
-    @pl.when(f != 0)
-    def _acc():
-        acc_ref[...] += part
-
-    @pl.when(f == pl.num_programs(1) - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+def _vmem_bytes(n_row_tiles: int, tm: int, tk: int, tn: int, n_rhs: int,
+                itemsize: int, k_tiles: int) -> int:
+    """VMEM one grouped matmul holds: weight blocks, lhs and out row tiles,
+    each double-buffered by the pipeline, and, where K is tiled, the f32
+    partial sums of every row tile."""
+    rhs = n_rhs * 2 * tk * tn * itemsize
+    rows = 2 * tm * tk * itemsize + 2 * tm * tn * itemsize
+    acc = n_rhs * n_row_tiles * tm * tn * 4 if k_tiles > 1 else 0
+    return rhs + rows + acc
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tf", "interpret"))
-def _grouped_ffn_call(x_pad, tile_expert, w1, w3, w2, *, tm: int, tf: int,
-                      interpret: bool = False):
-    """x_pad: [Tp, D] group-padded rows; tile_expert: [Tp//tm] int32;
-    w1/w3: [E, D, F]; w2: [E, F, D]. Returns [Tp, D] in x_pad.dtype."""
-    Tp, D = x_pad.shape
-    F = w1.shape[2]
-    n_row_tiles = Tp // tm
-    n_f_tiles = F // tf
+# A grid step's fixed cost (about 0.35 us) in the bytes the chip reads
+# meanwhile: what pick_tiles weighs many small steps against.
+_STEP_BYTES = 256 * 2 ** 10
+
+
+def pick_tiles(rows: int, k_dim: int, n_dim: int, n_rhs: int, itemsize: int,
+               tm: int = ROW_TILE) -> tuple[int, int]:
+    """(tk, tn) of one grouped matmul [rows, K] x [E, K, N]: lane-aligned
+    divisors of K and N that fit the VMEM budget. The weights are read once
+    whatever the tiles; the lhs is read again for every N tile, and every
+    grid step has a fixed cost: the cheapest sum of the two wins."""
+    def divisors(n):
+        return [t for t in range(128, n + 1, 128) if n % t == 0]
+
+    def cost(tk, tn):
+        n_tiles = n_dim // tn
+        steps = n_tiles * (k_dim // tk) * (rows // tm)
+        return n_tiles * rows * k_dim * itemsize + steps * _STEP_BYTES
+
+    fits = [(cost(tk, tn), tk, tn)
+            for tn in divisors(n_dim) for tk in divisors(k_dim)
+            if _vmem_bytes(rows // tm, tm, tk, tn, n_rhs, itemsize,
+                           k_dim // tk) <= VMEM_BUDGET_BYTES]
+    if not fits:
+        raise ValueError(
+            f"grouped MoE: no tile of [{rows}, {k_dim}] x [{k_dim}, {n_dim}] "
+            f"fits {VMEM_BUDGET_BYTES >> 20} MiB of VMEM")
+    return min(fits)[1:]
+
+
+def _gmm_kernel(tile_expert, n_live, layer, lhs_ref, *refs, n_rhs: int,
+                k_tiles: int):
+    """One (N tile, K tile, row tile) grid step: the row tile's [tm, tk]
+    against its expert's [tk, tn] block(s). With two weight operands the
+    result is silu(lhs·rhs0) * (lhs·rhs1)."""
+    del tile_expert, layer  # read by the index maps
+    rhs_refs, out_ref, acc_refs = refs[:n_rhs], refs[n_rhs], refs[n_rhs + 1:]
+    k, i = pl.program_id(1), pl.program_id(2)
+
+    def finish(parts):
+        y = parts[0] if n_rhs == 1 else jax.nn.silu(parts[0]) * parts[1]
+        out_ref[...] = y.astype(out_ref.dtype)
+
+    @pl.when(i < n_live[0])
+    def _live():
+        lhs = lhs_ref[...]
+        parts = [jax.lax.dot_general(lhs, r[...], (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+                 for r in rhs_refs]
+        if k_tiles == 1:
+            finish(parts)
+            return
+
+        @pl.when(k == 0)
+        def _first():
+            for acc, part in zip(acc_refs, parts):
+                acc[i] = part
+
+        @pl.when(k != 0)
+        def _add():
+            for acc, part in zip(acc_refs, parts):
+                acc[i] += part
+
+        @pl.when(k == k_tiles - 1)
+        def _flush():
+            finish([acc[i] for acc in acc_refs])
+
+
+def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
+                    tm: int = ROW_TILE, tiles: tuple[int, int] | None = None,
+                    interpret: bool = False):
+    """lhs [Tp, K] (group-padded rows) times the expert of each row tile out
+    of every ``rhs`` [L, E, K, N] at ``layer``; one rhs → lhs·rhs, two → the
+    SwiGLU of both. The weights come stacked over layers, with the layer a
+    prefetched scalar [1]: one layer's slice of them would reach the kernel
+    as a copy (a custom call's operand cannot be a fused slice), 2.8 GB a
+    Mixtral layer. tile_expert [Tp // tm] int32; n_live [1] int32, the tiles
+    that hold rows. Returns [Tp, N] in lhs.dtype; rows of tiles past n_live
+    are not written."""
+    Tp, K = lhs.shape
+    N = rhs[0].shape[3]
+    n_rhs, n_row_tiles = len(rhs), Tp // tm
+    itemsize = jnp.dtype(lhs.dtype).itemsize
+    tk, tn = tiles or pick_tiles(Tp, K, N, n_rhs, itemsize, tm)
+    k_tiles = K // tk
+
+    def row(i, live):  # a tile past the last group repeats the last live one
+        return jnp.minimum(i, live[0] - 1)
+
+    def out_map(n, k, i, te, live, layer):
+        # Written on the last K tile; until then the index stands still, so
+        # the pipeline copies no block out that holds nothing yet.
+        return jnp.where(k == k_tiles - 1, row(i, live), 0), n
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_row_tiles, n_f_tiles),
-        in_specs=[
-            pl.BlockSpec((tm, D), lambda i, f, te: (i, 0)),
-            pl.BlockSpec((1, D, tf), lambda i, f, te: (te[i], 0, f)),
-            pl.BlockSpec((1, D, tf), lambda i, f, te: (te[i], 0, f)),
-            pl.BlockSpec((1, tf, D), lambda i, f, te: (te[i], f, 0)),
-        ],
-        out_specs=pl.BlockSpec((tm, D), lambda i, f, te: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((tm, D), jnp.float32)],
+        num_scalar_prefetch=3,
+        grid=(N // tn, k_tiles, n_row_tiles),
+        in_specs=[pl.BlockSpec(
+            (tm, tk), lambda n, k, i, te, live, layer: (row(i, live), k))]
+        + [pl.BlockSpec(
+            (None, None, tk, tn), lambda n, k, i, te, live, layer:
+            (layer[0], te[row(i, live)], k, n))] * n_rhs,
+        out_specs=pl.BlockSpec((tm, tn), out_map),
+        scratch_shapes=[pltpu.VMEM((n_row_tiles, tm, tn), jnp.float32)
+                        ] * (n_rhs if k_tiles > 1 else 0),
     )
+    need = _vmem_bytes(n_row_tiles, tm, tk, tn, n_rhs, itemsize, k_tiles)
     return pl.pallas_call(
-        _ffn_kernel,
+        functools.partial(_gmm_kernel, n_rhs=n_rhs, k_tiles=k_tiles),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, D), x_pad.dtype),
+        out_shape=jax.ShapeDtypeStruct((Tp, N), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=need + 8 * 2 ** 20),
         interpret=interpret,
-    )(tile_expert, x_pad, w1, w3, w2)
+        # The op's name in a device trace, for whoever reduces one.
+        name="moe_grouped_swiglu" if n_rhs == 2 else "moe_grouped_matmul",
+    )(tile_expert, n_live, layer, lhs, *rhs)
 
 
 def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
-                    *, tm: int | None = None, tf: int = 512,
-                    interpret: bool = False) -> jnp.ndarray:
-    """Drop-in for models.llama._moe_ffn's compute (same math, grouped).
+                    *, layer=None, tm: int = ROW_TILE, interpret: bool = False,
+                    tiles_up: tuple[int, int] | None = None,
+                    tiles_down: tuple[int, int] | None = None) -> jnp.ndarray:
+    """Drop-in for models.llama._moe_ffn (same mathematics, grouped).
 
     lp: layer params with router/w1/w3/w2 ([E,D,F]/[E,F,D] stacked experts).
-    x: [B, S, D]. Returns [B, S, D] in x.dtype.
-
-    Against the dense-over-experts einsums this should win where routing is
-    sparse relative to the expert count and lose where every expert is hit
-    anyway; the round-4 figures that said so (d=1024, f=4096) came from a
-    stack that is gone and are to be re-measured. Dense stays the engine
-    default; enable via pallas_moe for fine-grained-expert models. tm=None
-    picks the row tile by shape: ROW_TILES[1] (MXU height) for prefill-scale
-    token counts, ROW_TILES[0] (bf16 sublane floor) for decode.
+    With ``layer`` (an int32 scalar) w1/w3/w2 are every layer's, [L,E,D,F] /
+    [L,E,F,D], and the kernel reads that layer's in place: how a scan over
+    layers hands them over (models.llama._over_layers).
+    x: [B, S, D]. Returns [B, S, D] in x.dtype. ``tm`` and the (tk, tn) of
+    the two matmuls are the tests' and the microbench's to set; served, they
+    come from the shapes (:func:`pick_tiles`).
     """
     B, S, D = x.shape
     E, k = n_experts, experts_per_token
     T = B * S
-    if tm is None:
-        tm = ROW_TILES[1] if T * k >= 1024 else ROW_TILES[0]
-    F = lp["w1"].shape[2]
-    # tf must divide F (the grid truncates otherwise — tail columns would be
-    # silently dropped), be lane-aligned, and leave the grid step inside
-    # VMEM. Pick the largest conforming tile no bigger than the requested one.
-    chosen = pick_ff_tile(D, F, tm, jnp.dtype(x.dtype).itemsize, tf)
-    if chosen is None:
-        raise ValueError(
-            f"d_ff={F} has no 128-aligned tile divisor ≤ {tf} that fits "
-            f"VMEM at d_model={D}, row tile {tm}; use the dense MoE path "
-            "for this geometry")
-    tf = chosen
     xt = x.reshape(T, D)
 
-    logits = (xt @ lp["router"]).astype(jnp.float32)            # [T, E]
+    # The router's logits in f32 straight from the product, said outright: on
+    # a TPU that is what _moe_ffn's cast of a bf16 product compiles to as
+    # well (the compiler keeps the accumulator's excess precision), and a
+    # logit rounded here and not there sends a token whose second and third
+    # choice lie within bf16 rounding to another expert than the dense form.
+    logits = jnp.dot(xt, lp["router"],
+                     preferred_element_type=jnp.float32)        # [T, E]
     top_vals, top_idx = jax.lax.top_k(logits, k)                # [T, k]
     gates = jax.nn.softmax(top_vals, axis=-1)                   # [T, k]
 
-    # Expand to T·k (token, expert) rows, stable-sorted by expert.
+    # The T·k (token, expert) rows, stable-sorted by expert.
     flat_expert = top_idx.reshape(-1)                           # [T*k]
     order = jnp.argsort(flat_expert, stable=True)               # [T*k]
-    src_token = order // k                                      # token of each sorted row
     sorted_expert = flat_expert[order]
 
-    # Group-padded destination layout: expert e's rows start at off[e], each
-    # group padded up to a multiple of tm. Static buffer: Tp = T*k + E*tm.
+    # Group-padded layout: expert e's rows start at off[e], every group
+    # padded up to a multiple of tm. Static buffer: Tp = T*k + E*tm rows.
     counts = jnp.bincount(flat_expert, length=E)                # [E]
     padded = ((counts + tm - 1) // tm) * tm
-    off = jnp.concatenate([jnp.zeros((1,), padded.dtype),
-                           jnp.cumsum(padded)])                 # [E+1]
-    # rank within group = position in sorted order minus group start in the
-    # *unpadded* sorted layout.
-    unpadded_start = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])    # [E+1]
-    rank = jnp.arange(T * k) - unpadded_start[sorted_expert]
-    dest = off[sorted_expert] + rank                            # [T*k]
+    zero = jnp.zeros((1,), counts.dtype)
+    off = jnp.concatenate([zero, jnp.cumsum(padded)])           # [E+1]
+    start = jnp.concatenate([zero, jnp.cumsum(counts)])         # [E+1]
+    # A sorted row's place: its group's start plus its rank in the group.
+    dest_sorted = (off[sorted_expert] + jnp.arange(T * k)
+                   - start[sorted_expert])                      # [T*k]
 
+    # Both moves of D-wide rows are gathers (small integer scatters make
+    # their indices): padded row → its source token (T: a row of zeros),
+    # and (token, choice) → its padded row.
     Tp = T * k + E * tm
-    x_pad = jnp.zeros((Tp, D), x.dtype).at[dest].set(xt[src_token])
+    src = jnp.full((Tp,), T, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32))
+    dest = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    x_pad = jnp.concatenate([xt, jnp.zeros((1, D), x.dtype)])[src]
 
-    # tile→expert: the expert whose [off[e], off[e+1]) range holds the tile's
-    # first row (pure-padding tiles map to the previous/any expert — their
-    # rows are zero and are never gathered back).
+    # tile → expert: whose [off[e], off[e+1]) holds the tile's first row.
     tile_starts = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
-    tile_expert = (jnp.searchsorted(off[1:], tile_starts, side="right")
-                   .astype(jnp.int32))
-    tile_expert = jnp.minimum(tile_expert, E - 1)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(off[1:], tile_starts, side="right"),
+        E - 1).astype(jnp.int32)
+    n_live = (off[E:] // tm).astype(jnp.int32)                  # [1]
 
-    out_pad = _grouped_ffn_call(x_pad, tile_expert, lp["w1"], lp["w3"],
-                                lp["w2"], tm=tm, tf=tf, interpret=interpret)
+    if layer is None:
+        w1, w3, w2 = (lp[n][None] for n in ("w1", "w3", "w2"))
+        layer = jnp.zeros((), jnp.int32)
+    else:
+        w1, w3, w2 = lp["w1"], lp["w3"], lp["w2"]
+    layer = layer.reshape(1).astype(jnp.int32)
+    h = _grouped_matmul(x_pad, (w1, w3), layer, tile_expert, n_live,
+                        tm=tm, tiles=tiles_up, interpret=interpret)
+    out_pad = _grouped_matmul(h, (w2,), layer, tile_expert, n_live,
+                              tm=tm, tiles=tiles_down, interpret=interpret)
 
-    rows = out_pad[dest]                                        # [T*k, D] sorted order
-    # Un-sort back to (token, k) and gate-combine.
-    unsorted = jnp.zeros_like(rows).at[order].set(rows)         # [T*k, D]
-    y = (unsorted.reshape(T, k, D)
+    y = (out_pad[dest].reshape(T, k, D)
          * gates[..., None].astype(x.dtype)).sum(axis=1)
     return y.reshape(B, S, D).astype(x.dtype)

@@ -132,6 +132,7 @@ def main(argv=None) -> int:
     eng.cfg, eng.mcfg, eng.pp_mesh, eng._prefill_fns = cfg, mcfg, None, {}
     eng._decode_attention = functools.partial(kvpages.decode_attention,
                                               kernel=True)
+    eng._bind_moe_form("tpu")  # the described chip, not this host's CPU
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(

@@ -9,14 +9,26 @@ a call (one layer), us a page fetched, and the share of the chip's peak
 bandwidth that the bytes the call needs (chipbench/kernels.py) come to.
 Everything runs in this one process (one process per chip).
 
+``--moe`` times one MoE FFN layer at prefill shapes instead (Mixtral's widths,
+T tokens of one prompt): the dense-over-experts einsums against the grouped
+form the engine serves above the ridge (ops/pallas_moe.py) and, with
+``--moe-candidates``, against the grouped matmuls jax ships (megablox ``gmm``,
+``lax.ragged_dot``) behind the same sort. FLOPs and bytes are reckoned here
+from the shapes: useful work is T*k rows, work done counts the padded rows
+too. Then it checks the mathematics (``--moe-check-seeds``): the layer's
+output, grouped and dense against a plain float32 FFN, and the last token's
+logits through the four-layer model, grouped against dense.
+
 Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--points 16x1000,16x300,8x300]
+       python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
+           [--moe-candidates] [--moe-check-seeds 0,1]
 """
 
 from __future__ import annotations
 
 import argparse
-
+import functools
 import json
 import os
 import sys
@@ -37,15 +49,205 @@ def timeit(fn, *args, iters=20):
     return (time.perf_counter() - t0) / iters * 1e3  # ms
 
 
+def _chipbench_kernels():
+    """chipbench/kernels.py, the benchmark's yardstick: the chip's peaks and
+    the bytes a call of the attention kernel needs."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench"))
+    import kernels
+
+    return kernels
+
+
+def moe_main(args):
+    """One MoE FFN layer at prefill shapes: time, shares of the peaks, and
+    the check of the mathematics."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.models import llama
+    from llm_d_inference_scheduler_tpu.models.configs import get_config
+    from llm_d_inference_scheduler_tpu.ops import pallas_moe
+
+    mcfg = dataclasses.replace(get_config(args.moe_model), n_layers=1,
+                               vocab_size=256)
+    E, k, D, F = (mcfg.n_experts, mcfg.experts_per_token, mcfg.d_model,
+                  mcfg.d_ff)
+    tm = pallas_moe.ROW_TILE
+    weight_bytes = 3 * E * D * F * 2
+    # Interpreted on the CPU the times mean nothing: no share of a peak then.
+    peak = (None if args.moe_interpret else
+            _chipbench_kernels().peaks(jax.devices()[0].device_kind))
+    init = jax.jit(lambda key, c: llama.init_params(c, key), static_argnums=1)
+    lp = jax.tree.map(lambda a: a[0], init(jax.random.key(0), mcfg)["layers"])
+
+    def line(**kv):
+        print(json.dumps(kv), flush=True)
+
+    def routed(fn):
+        """An FFN that sorts (token, expert) rows by expert and hands the
+        three grouped matmuls to ``fn(lhs, rhs, group_sizes)``."""
+        def ffn(lp, x):
+            T = x.shape[1]
+            xt = x.reshape(T, D)
+            logits = (xt @ lp["router"]).astype(jnp.float32)
+            top, idx = jax.lax.top_k(logits, k)
+            gates = jax.nn.softmax(top, axis=-1).astype(x.dtype)
+            flat = idx.reshape(-1)
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+            xs = xt[order // k]
+            h = (jax.nn.silu(fn(xs, lp["w1"], sizes))
+                 * fn(xs, lp["w3"], sizes)).astype(x.dtype)
+            out = fn(h, lp["w2"], sizes).astype(x.dtype)
+            back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+            return (out[back].reshape(T, k, D) * gates[..., None]).sum(1)[None]
+        return ffn
+
+    forms = {
+        "dense": lambda lp, x: llama._moe_ffn(mcfg, lp, x),
+        "grouped": lambda lp, x: pallas_moe.moe_ffn_grouped(
+            lp, x, E, k, interpret=args.moe_interpret),
+    }
+    if args.moe_candidates:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        for tiling in ((256, 1024, 1024), (512, 1024, 1024)):
+            forms["megablox_gmm_%dx%dx%d" % tiling] = routed(
+                functools.partial(gmm, preferred_element_type=jnp.bfloat16,
+                                  tiling=tiling,
+                                  interpret=args.moe_interpret))
+        forms["lax_ragged_dot"] = routed(functools.partial(
+            jax.lax.ragged_dot, preferred_element_type=jnp.bfloat16))
+        for name, tiles in (("grouped_up4096x512_down2048x1024",
+                             ((4096, 512), (2048, 1024))),
+                            ("grouped_up2048x1024_down1024x2048",
+                             ((2048, 1024), (1024, 2048)))):
+            forms[name] = functools.partial(
+                lambda lp, x, tiles: pallas_moe.moe_ffn_grouped(
+                    lp, x, E, k, tiles_up=tiles[0], tiles_down=tiles[1],
+                    interpret=args.moe_interpret),
+                tiles=tiles)
+
+    for T in [int(t) for t in args.moe_tokens.split(",")]:
+        x = jax.random.normal(jax.random.key(T), (1, T, D), jnp.bfloat16)
+        # Rows the grouped layout works on: every expert's group padded to tm.
+        idx = jax.lax.top_k((x[0] @ lp["router"]).astype(jnp.float32), k)[1]
+        counts = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
+        live_rows = int((-(-counts // tm) * tm).sum())
+        for name, fn in forms.items():
+            try:
+                ms = timeit(jax.jit(fn), lp, x, iters=10)
+            except Exception as e:  # a candidate the compiler refuses
+                line(component="moe_ffn", form=name, T=T,
+                     error=f"{type(e).__name__}: {str(e)[:300]}")
+                continue
+            rows = (T * E if name == "dense" else
+                    live_rows if name.startswith("grouped") else T * k)
+            share = lambda need, of: round(
+                100 * need / (ms * 1e-3) / peak[of], 2)
+            line(component="moe_ffn", form=name, T=T, ms=round(ms, 3),
+                 rows_useful=T * k, rows_done=rows,
+                 expert_counts=counts.tolist(),
+                 **({} if peak is None else dict(
+                     useful_flops_share_pct=share(T * k * 6 * D * F,
+                                                  "flops_per_s"),
+                     done_flops_share_pct=share(rows * 6 * D * F,
+                                                "flops_per_s"),
+                     weight_bytes_share_pct=share(weight_bytes,
+                                                  "bytes_per_s"))))
+
+    # The mathematics: one layer against the plain float32 FFN (the lines of
+    # chipbench/configs/reference_llama_family.py's forward, an expert at a
+    # time), then the last token's logits through four layers.
+    def reference_ffn(lp, x):
+        with jax.default_matmul_precision("highest"):
+            h = x[0].astype(jnp.float32)
+            top, idx = jax.lax.top_k(h @ lp["router"].astype(jnp.float32), k)
+            gate = jax.nn.softmax(top, axis=-1)
+            y = jnp.zeros_like(h)
+            for e in range(E):
+                w1, w3, w2 = (lp[n][e].astype(jnp.float32)
+                              for n in ("w1", "w3", "w2"))
+                out = (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+                y = y + out * jnp.sum(jnp.where(idx == e, gate, 0.0),
+                                      axis=-1)[:, None]
+            return y[None], jnp.sort(idx, axis=-1)
+
+    def program_choice(lp, x):  # the experts a bf16-rounded logit picks
+        return jnp.sort(jax.lax.top_k(
+            (x[0] @ lp["router"]).astype(jnp.float32), k)[1], axis=-1)
+
+    seeds = [int(s) for s in args.moe_check_seeds.split(",") if s]
+
+    def diff(a, b, rows=None):
+        d = jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))
+        return float((d if rows is None else d[0][rows]).max())
+
+    for seed in seeds:
+        for T in (512, 1024):
+            x = jax.random.normal(jax.random.key(seed), (1, T, D),
+                                  jnp.bfloat16)
+            ref, ref_choice = jax.jit(reference_ffn)(lp, x)
+            dense, grouped = (jax.jit(forms[n])(lp, x)
+                              for n in ("dense", "grouped"))
+            # A token whose second and third logits lie within bf16 rounding
+            # may go to another expert in the program than in the reference
+            # (float32 logits there, a bf16 product here): the comparison
+            # with the reference leaves such tokens out and counts them. The
+            # two forms of the program are compared over every token — on a
+            # TPU both route on the product's f32 accumulator.
+            same = np.asarray((ref_choice == program_choice(lp, x)).all(-1))
+            line(check="moe_layer", seed=seed, T=T,
+                 max_abs_ref=float(jnp.abs(ref).max()),
+                 tokens_routed_as_the_reference=int(same.sum()),
+                 grouped_vs_ref=diff(grouped, ref, same),
+                 dense_vs_ref=diff(dense, ref, same),
+                 grouped_vs_dense=diff(grouped, dense))
+    del lp
+    model = dataclasses.replace(get_config(args.moe_model), n_layers=4)
+    for seed in seeds:
+        params = init(jax.random.key(seed), model)
+        for T in (512, 1024):
+            tokens = jax.random.randint(jax.random.key(seed + T), (1, T), 0,
+                                        model.vocab_size)
+            last = {}
+            for impl in ("dense", "grouped"):
+                cfg = dataclasses.replace(
+                    model, moe_impl=impl + "_interpret" * (
+                        args.moe_interpret and impl == "grouped"))
+                last[impl] = jax.jit(
+                    lambda p, t, cfg=cfg: llama.forward(p, cfg, t)[0][0, -1])(
+                        params, tokens)
+            line(check="moe_last_token_logits", seed=seed, T=T, layers=4,
+                 max_abs_dense=float(jnp.abs(last["dense"]).max()),
+                 grouped_vs_dense=diff(last["grouped"], last["dense"]),
+                 same_argmax=bool(last["grouped"].argmax()
+                                  == last["dense"].argmax()))
+        del params
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen3-4b")
+    ap.add_argument("--moe", action="store_true",
+                    help="time one MoE FFN layer at prefill shapes instead")
+    ap.add_argument("--moe-model", default="mixtral-8x7b")
+    ap.add_argument("--moe-tokens", default="256,512,1024")
+    ap.add_argument("--moe-candidates", action="store_true",
+                    help="also time megablox gmm, lax.ragged_dot and other "
+                         "tiles of the repo's kernel")
+    ap.add_argument("--moe-check-seeds", default="0,1")
+    ap.add_argument("--moe-interpret", action="store_true",
+                    help="interpret the kernels: rehearses the control flow "
+                         "on the CPU; its times mean nothing")
     ap.add_argument("--points", default="16x1000,16x300,8x300",
                     help="lanes x context tokens a lane, comma-separated")
     ap.add_argument("--max-model-len", type=int, default=1024)
     args = ap.parse_args(argv)
-
-    import functools
 
     import jax
     import jax.numpy as jnp
@@ -56,15 +258,15 @@ def main(argv=None):
     )
 
     configure_compile_cache()
+    if args.moe:
+        return moe_main(args)
 
     from llm_d_inference_scheduler_tpu.engine.sampling import sample_tokens
     from llm_d_inference_scheduler_tpu.kvcache import pages
     from llm_d_inference_scheduler_tpu.models import llama
     from llm_d_inference_scheduler_tpu.models.configs import get_config
 
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "chipbench"))
-    import kernels  # the benchmark's yardstick: peaks, bytes a call needs
+    kernels = _chipbench_kernels()
 
     mcfg = get_config(args.model)
     block = mcfg.kv_block_size

@@ -115,48 +115,49 @@ def test_decode_step_copies_no_layers_page_pool(one_chip):
             < dt.itemsize * math.prod(one_layer))
 
 
-@pytest.mark.parametrize("d_model,d_ff,n_experts,tm", [
-    # Mixtral-8x7B, the one MoE model registered, at both row tiles: three
-    # double-buffered [4096, 512] bf16 blocks were 24 MiB of a 16 MiB limit.
-    (MIXTRAL_8X7B.d_model, MIXTRAL_8X7B.d_ff, MIXTRAL_8X7B.n_experts, 16),
-    (MIXTRAL_8X7B.d_model, MIXTRAL_8X7B.d_ff, MIXTRAL_8X7B.n_experts, 128),
+@pytest.mark.parametrize("d_model,d_ff,n_experts,top_k,tokens", [
+    # Mixtral-8x7B, the one MoE model registered, at the two prefill buckets
+    # the rule hands to the grouped form in mixtral-8x7b-cut.batch-full. Its
+    # weight blocks ([4096, 1024] twice, double-buffered: 32 MiB) pass the
+    # compiler's default scoped limit of 16 MiB, which the call raises.
+    (MIXTRAL_8X7B.d_model, MIXTRAL_8X7B.d_ff, MIXTRAL_8X7B.n_experts, 2, 512),
+    (MIXTRAL_8X7B.d_model, MIXTRAL_8X7B.d_ff, MIXTRAL_8X7B.n_experts, 2, 1024),
     # Many small experts (the OLMoE-like shape ROADMAP R2 plans).
-    (2048, 1024, 64, 16),
-    (2048, 1024, 64, 128),
+    (2048, 1024, 64, 8, 512),
+    (2048, 1024, 64, 8, 1024),
 ])
-def test_grouped_moe_compiles(one_chip, d_model, d_ff, n_experts, tm):
-    tf = pallas_moe.pick_ff_tile(d_model, d_ff, tm, 2)
-    assert tf is not None
-    rows = 4 * tm
-    compiled = pallas_moe._grouped_ffn_call.lower(
-        _sds(one_chip, (rows, d_model), jnp.bfloat16),
-        _sds(one_chip, (rows // tm,), jnp.int32),
-        _sds(one_chip, (n_experts, d_model, d_ff), jnp.bfloat16),
-        _sds(one_chip, (n_experts, d_model, d_ff), jnp.bfloat16),
-        _sds(one_chip, (n_experts, d_ff, d_model), jnp.bfloat16),
-        tm=tm, tf=tf).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_grouped_moe_compiles(one_chip, d_model, d_ff, n_experts, top_k,
+                              tokens):
+    bf16 = functools.partial(_sds, one_chip, dtype=jnp.bfloat16)
+    lp = {"router": bf16((d_model, n_experts)),
+          "w1": bf16((n_experts, d_model, d_ff)),
+          "w3": bf16((n_experts, d_model, d_ff)),
+          "w2": bf16((n_experts, d_ff, d_model))}
+    compiled = jax.jit(
+        lambda lp, x: pallas_moe.moe_ffn_grouped(lp, x, n_experts, top_k)
+    ).lower(lp, bf16((1, tokens, d_model))).compile()
+    # Two grouped matmuls: x.[w1|w3] with the SwiGLU, then .w2.
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
-def test_moe_tile_rule_is_the_engines_gate(monkeypatch):
-    """One rule: what pick_ff_tile refuses, the engine refuses at start-up
-    (and not the compiler at the first request)."""
-    from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
-    from llm_d_inference_scheduler_tpu.models import configs
-
-    # No 128-multiple divides 200; and no tile of any width fits beside
-    # d_model 16384's row tiles.
-    assert pallas_moe.pick_ff_tile(128, 200, 16, 2) is None
-    assert pallas_moe.pick_ff_tile(16384, 14336, 128, 2) is None
-    # Small widths keep the tile they always had (interpret-mode results
-    # must not move): the largest 128-multiple divisor up to 512.
-    assert pallas_moe.pick_ff_tile(128, 256, 16, 4) == 256
-    assert pallas_moe.pick_ff_tile(128, 1024, 128, 4) == 512
-
-    bad = configs.ModelConfig(**{**configs.TINY_MOE.__dict__,
-                                 "name": "tiny-moe-200", "d_ff": 200})
-    monkeypatch.setitem(configs._REGISTRY, bad.name, bad)
-    with pytest.raises(ValueError, match="pallas_moe"):
-        TpuEngine(EngineConfig(model=bad.name, pallas_moe=True,
-                               pallas_interpret=True, kv_events_port=0))
+def test_moe_tiles_come_from_the_shapes():
+    """One rule for the kernel's tiles (what fits VMEM, the fewest re-reads
+    of the rows) and one for the form: widths pick_tiles cannot tile are
+    widths use_grouped never hands to the kernel."""
+    rows = 2 * 1024 + 8 * pallas_moe.ROW_TILE
+    for k_dim, n_dim, n_rhs in ((4096, 14336, 2), (14336, 4096, 1)):
+        tk, tn = pallas_moe.pick_tiles(rows, k_dim, n_dim, n_rhs, 2)
+        assert k_dim % tk == 0 and n_dim % tn == 0
+        assert tk % 128 == 0 and tn % 128 == 0
+        assert pallas_moe._vmem_bytes(
+            rows // pallas_moe.ROW_TILE, pallas_moe.ROW_TILE, tk, tn, n_rhs,
+            2, k_dim // tk) <= pallas_moe.VMEM_BUDGET_BYTES
+    # Mixtral's up-projection runs whole-K (no f32 scratch) on wide N tiles.
+    assert pallas_moe.pick_tiles(rows, 4096, 14336, 2, 2) == (4096, 1024)
+    # No 128-multiple divides 200.
+    with pytest.raises(ValueError, match="no tile"):
+        pallas_moe.pick_tiles(rows, 128, 200, 2, 2)
+    facts = dict(n_experts=8, experts_per_token=2, platform="tpu",
+                 sharded=False)
+    assert not pallas_moe.use_grouped(1024, d_model=128, d_ff=200, **facts)
+    assert pallas_moe.use_grouped(1024, d_model=128, d_ff=256, **facts)

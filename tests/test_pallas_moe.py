@@ -1,10 +1,15 @@
-"""Grouped-matmul MoE kernel vs the dense-over-experts reference math.
+"""Grouped-matmul MoE FFN vs the dense-over-experts reference math, and the
+rule that chooses between them.
 
-Interpret-mode on CPU (same strategy as test_pallas_paged_attention.py);
-compiled-on-TPU validation happens in the bench A/B.
+Interpret-mode on CPU (same strategy as test_pallas_paged_attention.py); the
+kernel is handed to the TPU compiler at Mixtral's widths in
+test_chip_compile.py and checked on the chip by scripts/microbench_decode.py
+--moe.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -12,12 +17,16 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
+from llm_d_inference_scheduler_tpu.models import configs
 from llm_d_inference_scheduler_tpu.models.configs import ModelConfig
 from llm_d_inference_scheduler_tpu.models.llama import _moe_ffn
-from llm_d_inference_scheduler_tpu.ops.pallas_moe import moe_ffn_grouped
+from llm_d_inference_scheduler_tpu.ops.pallas_moe import (
+    moe_ffn_grouped,
+    use_grouped,
+)
 
 
-def _mk(E=4, D=128, F=256, k=2, seed=0):
+def _mk(E=4, D=128, F=256, k=2, seed=0, dtype=jnp.float32):
     key = jax.random.key(seed)
     ks = jax.random.split(key, 4)
     lp = {
@@ -26,6 +35,7 @@ def _mk(E=4, D=128, F=256, k=2, seed=0):
         "w3": jax.random.normal(ks[2], (E, D, F), jnp.float32) * D ** -0.5,
         "w2": jax.random.normal(ks[3], (E, F, D), jnp.float32) * F ** -0.5,
     }
+    lp = jax.tree.map(lambda a: a.astype(dtype), lp)
     cfg = ModelConfig(name="t", vocab_size=8, d_model=D, n_layers=1,
                       n_heads=2, n_kv_heads=1, d_ff=F, n_experts=E,
                       experts_per_token=k)
@@ -39,49 +49,134 @@ def test_grouped_matches_dense(shape):
     x = jax.random.normal(jax.random.key(7), (B, S, cfg.d_model), jnp.float32)
     dense = _moe_ffn(cfg, lp, x)
     grouped = moe_ffn_grouped(lp, x, cfg.n_experts, cfg.experts_per_token,
-                              tm=8, tf=128, interpret=True)
+                              tm=8, interpret=True)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
 
 
-def test_grouped_skewed_routing():
-    """All tokens on one expert (maximally ragged groups)."""
-    lp, cfg = _mk(E=4, k=1)
-    # Bias the router so expert 2 wins everywhere.
-    lp["router"] = lp["router"].at[:, 2].add(100.0)
-    x = jax.random.normal(jax.random.key(9), (2, 5, cfg.d_model), jnp.float32)
+@pytest.mark.parametrize("E,k,favoured", [
+    (4, 1, (2,)),        # every token on one expert: maximally ragged groups
+    (8, 2, (2, 5)),      # every token to the same two experts, six empty
+    (8, 2, None),        # one expert never chosen
+])
+def test_grouped_skewed_routing(E, k, favoured):
+    """Routing skewed to the limit drops no token: the layout is static and
+    only the offsets are data."""
+    lp, cfg = _mk(E=E, k=k)
+    if favoured is None:
+        lp["router"] = lp["router"].at[:, 3].add(-100.0)
+    else:
+        for rank, e in enumerate(favoured):
+            lp["router"] = lp["router"].at[:, e].add(100.0 - 10 * rank)
+    x = jax.random.normal(jax.random.key(9), (2, 40, cfg.d_model), jnp.float32)
+    # The bias is on the router's weights, so the sign of a token's sum
+    # decides: shift the rows so that every token's sum is positive.
+    x = jnp.abs(x)
+    top = jax.lax.top_k(x.reshape(-1, cfg.d_model) @ lp["router"], k)[1]
+    chosen = set(np.asarray(top).reshape(-1).tolist())
+    assert chosen == set(favoured) if favoured else 3 not in chosen
     dense = _moe_ffn(cfg, lp, x)
-    grouped = moe_ffn_grouped(lp, x, cfg.n_experts, 1, tm=8, tf=128,
-                              interpret=True)
+    grouped = moe_ffn_grouped(lp, x, cfg.n_experts, k, tm=16, interpret=True,
+                              tiles_down=(128, 128))
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
 
 
-def test_engine_grouped_moe_matches_dense():
-    """tiny-moe engine: grouped kernel produces the same greedy tokens as
-    the dense-over-experts path (full prefill+paged-decode pipeline)."""
+@pytest.mark.parametrize("tokens", [256, 512, 1024])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_grouped_at_the_cells_shape_ratios(tokens, dtype):
+    """A cut of mixtral-8x7b-cut.batch-full's prefill: 8 experts, top 2, one
+    prompt of 256 / 512 / 1,024 rows, the served row tile, small D and F. In
+    f32 the two forms agree to rounding of the sum's order; in bf16 the
+    grouped form is no further from the f32 answer than the dense form is."""
+    lp32, cfg = _mk(E=8, k=2)
+    x32 = jax.random.normal(jax.random.key(tokens), (1, tokens, cfg.d_model),
+                            jnp.float32)
+    ref = np.asarray(_moe_ffn(cfg, lp32, x32))
+    dt = jnp.dtype(dtype)
+    lp = jax.tree.map(lambda a: a.astype(dt), lp32)
+    x = x32.astype(dt)
+    dense = np.asarray(_moe_ffn(cfg, lp, x), np.float32)
+    grouped = np.asarray(moe_ffn_grouped(
+        lp, x, cfg.n_experts, cfg.experts_per_token, interpret=True,
+        tiles_down=(128, 128)), np.float32)   # two K tiles: the f32 scratch
+    if dtype == "float32":
+        np.testing.assert_allclose(grouped, dense, atol=1e-5, rtol=1e-5)
+        return
+    # The grouped form routes on the product's f32 logits (what the dense
+    # form's cast compiles to on a TPU); on the CPU the dense form rounds
+    # them to bf16 first, so a token whose second and third logits lie
+    # within that rounding takes another expert there. Such tokens are few,
+    # and are no statement about the kernel: compare the others.
+    xt = x.reshape(tokens, -1)
+    pick = lambda logits: np.sort(np.asarray(jax.lax.top_k(logits, 2)[1]), -1)
+    same = (pick(jnp.dot(xt, lp["router"], preferred_element_type=jnp.float32))
+            == pick((xt @ lp["router"]).astype(jnp.float32))).all(-1)
+    assert same.mean() > 0.98
+    assert (np.abs(grouped - ref)[0, same].max()
+            <= 1.5 * np.abs(dense - ref)[0, same].max() + 1e-3)
+    np.testing.assert_allclose(grouped[0, same], dense[0, same],
+                               atol=3e-2, rtol=3e-2)
+
+
+_MIXTRAL = dict(n_experts=8, experts_per_token=2, d_model=4096, d_ff=14336)
+
+
+@pytest.mark.parametrize("tokens,facts,grouped", [
+    # The decode chunk's lanes and the 16-token prefill bucket: under the
+    # ridge both forms are the read of every expert's weights.
+    (2, {}, False), (4, {}, False), (8, {}, False), (16, {}, False),
+    (128, {}, False),
+    # The prefill buckets past the ridge.
+    (512, {}, True), (1024, {}, True), (4 * 512, {}, True),
+    # Sharded weights keep the einsums XLA partitions.
+    (1024, {"sharded": True}, False),
+    # No kernel off the TPU, unless a test interprets it.
+    (1024, {"platform": "cpu"}, False),
+    (1024, {"platform": "cpu", "interpret": True}, True),
+    (16, {"platform": "cpu", "interpret": True}, False),
+    # Nothing to skip where every expert is chosen; a dense model has none.
+    (1024, {"experts_per_token": 8}, False),
+    (1024, {"n_experts": 0}, False),
+    # Widths the kernel cannot tile.
+    (1024, {"d_ff": 200}, False),
+])
+def test_the_form_is_a_function_of_shape_platform_and_sharding(
+        tokens, facts, grouped):
+    kw = {**_MIXTRAL, "platform": "tpu", "sharded": False, **facts}
+    assert use_grouped(tokens, **kw) is grouped
+
+
+def test_engine_serves_the_form_its_shapes_call_for(monkeypatch):
+    """A Mixtral-shaped tiny engine (8 experts, top 2): the 512-token prefill
+    bucket traces the grouped form, the decode chunk the dense one, the
+    counter reads both programs' padded token counts, and the greedy tokens
+    are those of an engine that runs dense throughout."""
     import asyncio
 
     from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.models import llama
-    from llm_d_inference_scheduler_tpu.models.configs import get_config
 
-    # f32 params: keeps greedy argmax insensitive to the two impls' different
-    # rounding points (bf16 numeric tolerance is covered by test_grouped_bf16).
-    params = llama.init_params(get_config("tiny-moe"), jax.random.key(11),
-                               dtype=jnp.float32)
+    mcfg = dataclasses.replace(configs.TINY_MOE, name="tiny-mixtral",
+                               n_experts=8, max_seq_len=1024)
+    monkeypatch.setitem(configs._REGISTRY, mcfg.name, mcfg)
+    # f32 params: keeps greedy argmax insensitive to the two forms' different
+    # rounding points (bf16 tolerance is test_grouped_bf16's).
+    params = llama.init_params(mcfg, jax.random.key(11), dtype=jnp.float32)
+    prompt = [1 + (7 * i) % 500 for i in range(300)]
 
-    async def run(pallas_moe: bool):
-        cfg = EngineConfig(model="tiny-moe", backend="tpu", max_batch=2,
-                           max_model_len=64, seed=11, decode_chunk=4,
-                           pallas_moe=pallas_moe, pallas_interpret=pallas_moe)
+    async def run(interpret: bool):
+        cfg = EngineConfig(model=mcfg.name, backend="tpu", max_batch=2,
+                           max_model_len=1024, seed=11, decode_chunk=4,
+                           warmup=False, pallas_interpret=interpret,
+                           pallas_attention=False, kv_events_port=0)
         eng = TpuEngine(cfg, params=params)
         await eng.start()
         try:
-            req = EngineRequest(request_id="moe", prompt_token_ids=[1, 5, 9, 13],
-                                max_tokens=6, temperature=0.0, ignore_eos=True)
-            out = eng.submit(req)
+            out = eng.submit(EngineRequest(
+                request_id="moe", prompt_token_ids=prompt, max_tokens=6,
+                temperature=0.0, ignore_eos=True))
             got = []
             while True:
                 ev = await out.get()
@@ -89,27 +184,42 @@ def test_engine_grouped_moe_matches_dense():
                     got.append(ev.token_id)
                 if ev.finish_reason is not None:
                     break
-            return got
+            return got, eng
         finally:
             await eng.stop()
 
-    dense = asyncio.run(run(False))
-    grouped = asyncio.run(run(True))
+    def counted(eng):
+        text = eng.telemetry.render().decode()
+        return {form: sum(float(ln.split()[-1]) for ln in text.splitlines()
+                          if ln.startswith("jetstream:moe_ffn_tokens_total{")
+                          and f'form="{form}"' in ln)
+                for form in ("grouped", "dense")}
+
+    dense, dense_eng = asyncio.run(run(False))
+    grouped, eng = asyncio.run(run(True))
     assert len(dense) == 6
     assert grouped == dense
+    # One prompt of 300 tokens pads to the 512 bucket; its first token comes
+    # with the prefill, the other five from two chunks of 4 steps on 2 lanes.
+    assert eng._model_for(512).moe_impl == "grouped_interpret"
+    assert eng._model_for(2).moe_impl == "dense"
+    assert counted(eng) == {"grouped": 512.0, "dense": 2 * 4 * 2.0}
+    # Off the TPU and not interpreting, every program is dense.
+    assert counted(dense_eng) == {"grouped": 0.0, "dense": 512 + 2 * 4 * 2.0}
 
 
 def test_grouped_rejects_unaligned_dff():
     """F with no 128-aligned divisor must raise, not silently drop columns."""
     lp, cfg = _mk(D=128, F=192)
     x = jax.random.normal(jax.random.key(1), (1, 2, cfg.d_model), jnp.float32)
-    with pytest.raises(ValueError, match="tile divisor"):
+    with pytest.raises(ValueError, match="no tile"):
         moe_ffn_grouped(lp, x, cfg.n_experts, cfg.experts_per_token,
                         interpret=True)
 
 
 def test_grouped_nondefault_tile_divisor():
-    """F=384 divides by 384 (not the default 512): tail must be computed."""
+    """F=384 has one lane-aligned divisor beside 128 (384): the tail columns
+    must be computed."""
     lp, cfg = _mk(D=128, F=384)
     x = jax.random.normal(jax.random.key(2), (2, 3, cfg.d_model), jnp.float32)
     dense = _moe_ffn(cfg, lp, x)
@@ -120,12 +230,11 @@ def test_grouped_nondefault_tile_divisor():
 
 
 def test_grouped_bf16():
-    lp, cfg = _mk()
-    lp = jax.tree.map(lambda a: a.astype(jnp.bfloat16), lp)
+    lp, cfg = _mk(dtype=jnp.bfloat16)
     x = jax.random.normal(jax.random.key(3), (2, 4, cfg.d_model), jnp.bfloat16)
     dense = _moe_ffn(cfg, lp, x)
     grouped = moe_ffn_grouped(lp, x, cfg.n_experts, cfg.experts_per_token,
-                              tm=16, tf=128, interpret=True)
+                              tm=16, interpret=True)
     np.testing.assert_allclose(
         np.asarray(grouped, np.float32), np.asarray(dense, np.float32),
         atol=3e-2, rtol=3e-2)
