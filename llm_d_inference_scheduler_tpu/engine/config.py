@@ -69,10 +69,12 @@ class EngineConfig:
     prefill_batch: int = 1
     # Incremental prefill for LONG prompts: when > 0, a prompt whose
     # un-cached suffix exceeds this many tokens prefills in windows of this
-    # size (rounded up to a KV-block multiple), one window per engine step,
-    # interleaved with the decode chunks of established lanes — bounding
-    # the decode stall a long-context prefill can cause to ~one window
-    # instead of the full prompt. Windows after the first ride the
+    # size (rounded up to a KV-block multiple), one window an engine step
+    # for each slot still prefilling (at most core.PREFILL_STEP_TOKENS of
+    # prompt a step), interleaved with the decode chunks of established
+    # lanes — bounding the decode stall a long-context prefill can cause to
+    # ~one window, or that budget when several lanes wait, instead of the
+    # full prompt. Windows after the first ride the
     # prefix-continuation jits (the same O(prefix) path prefix-cache hits
     # use). 0 = classic whole-prompt prefill. Multimodal prompts always
     # prefill whole (the embed splice targets absolute positions in the

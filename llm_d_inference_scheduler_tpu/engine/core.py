@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kvcache import pages, wire
-from ..models import llama
+from ..models import family
 from ..ops import pallas_moe
 from ..utils.hashing import chain_block_hashes
 from .blocks import BlockAllocator, PrefixCachingAllocator
@@ -52,6 +52,11 @@ from .tokenizer import get_tokenizer
 log = logging.getLogger("engine.core")
 
 KV_EXPORT_TTL_S = 60.0
+# The most prompt tokens _advance_prefills writes ahead of one decode chunk:
+# the stated stretch of the decode cadence under prefill_chunk (a prompt of
+# this length prefilled whole makes the lanes wait as long).
+PREFILL_STEP_TOKENS = 4096
+
 
 def _tcp_preflight(address: str, timeout: float = 2.0) -> None:
     """The transfer layer blocks indefinitely on an unreachable peer; fail
@@ -119,8 +124,9 @@ class _Slot:
     pending_idx: int = 0
     prompt_len: int = 0
     # Incremental (chunked) prefill: while True the slot is excluded from
-    # decode batches; _advance_prefills writes one window per engine step
-    # so long prompts never stall the decode lanes for their full length.
+    # decode batches; _advance_prefills writes it a window at a time between
+    # decode chunks, so long prompts never stall the decode lanes for their
+    # full length.
     prefilling: bool = False
     prefill_rest: list[int] = dataclasses.field(default_factory=list)
     prefill_written: int = 0
@@ -146,7 +152,8 @@ class _PendingImport:
 
 
 class TpuEngine:
-    """Continuous-batching engine over models.llama with paged KV on HBM."""
+    """Continuous-batching engine over a models/ block (models.family) with
+    a paged KV or latent cache on HBM."""
 
     def __init__(self, cfg: EngineConfig, params=None):
         self.cfg = cfg
@@ -167,19 +174,25 @@ class TpuEngine:
                 f"{len(jax.local_devices())} {jax.default_backend()} "
                 "device(s)")
         self.device = jax.local_devices()[cfg.device_index]
+        # The block's module (models/llama.py or models/mla.py: one set of
+        # entry points) and the page pool that goes with it.
+        self.model = family(self.mcfg)
+        self.geom = pages.PageGeometry.for_engine(
+            self.mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
+        if self.geom.latent_dim:
+            self._refuse_beyond_one_chip()
         cfg.pallas_attention = pages.use_kernel(
-            self.mcfg.head_dim, asked=cfg.pallas_attention,
+            self.geom.shape[-1], asked=cfg.pallas_attention,
             interpret=cfg.pallas_interpret, platform=self.device.platform,
             sharded=cfg.tp_size > 1 or cfg.ep_size > 1)
         self._decode_attention = functools.partial(
-            pages.decode_attention, kernel=cfg.pallas_attention,
+            pages.latent_decode_attention if self.geom.latent_dim
+            else pages.decode_attention, kernel=cfg.pallas_attention,
             interpret=cfg.pallas_interpret)
         self._bind_moe_form(self.device.platform)
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
 
-        self.geom = pages.PageGeometry.for_engine(
-            self.mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
         block = self.geom.block
         self.n_blocks = self.geom.n_blocks
         self.max_blocks_per_seq = self.geom.max_blocks_per_seq
@@ -358,7 +371,7 @@ class TpuEngine:
             from jax.sharding import SingleDeviceSharding
 
             self.params = jax.jit(
-                lambda k: llama.init_params(self.mcfg, k),
+                lambda k: self.model.init_params(self.mcfg, k),
                 out_shardings=SingleDeviceSharding(self.device))(
                     jax.random.key(cfg.seed))
         mesh = self._page_mesh()
@@ -444,6 +457,24 @@ class TpuEngine:
         log.info("engine %s up: %s", self.engine_id,
                  json.dumps(self.describe()))
 
+    def _refuse_beyond_one_chip(self) -> None:
+        """A latent page pool lives on the unsharded one-chip engine: it has
+        no sharding rule, no stage split and no wire format yet (ROADMAP R8),
+        and its family's vision tower is not built. Asked for any of those,
+        say so now, by name, rather than serve something else."""
+        cfg = self.cfg
+        asked = [f"{name}={value}" for name, value, plain in (
+            ("tp_size", cfg.tp_size, 1), ("ep_size", cfg.ep_size, 1),
+            ("pp_size", cfg.pp_size, 1),
+            ("dist_num_processes", cfg.dist_num_processes, 1),
+            ("role", cfg.role, "both")) if value != plain]
+        if asked:
+            raise ValueError(
+                f"model {self.mcfg.name!r} keeps a latent (MLA) page pool, "
+                f"which serves on one unsharded chip only: {', '.join(asked)} "
+                "is not supported (no sharding rule for a latent row, no "
+                "handoff of latent pages to another engine)")
+
     def describe(self) -> dict[str, Any]:
         """What this engine bound and resolved — the start-up log line and
         /health carry it, so a caller that must stay off JAX (one process
@@ -460,6 +491,10 @@ class TpuEngine:
                 "max_batch": self.cfg.max_batch,
                 "max_model_len": self.cfg.max_model_len,
                 "kv_blocks": self.n_blocks,
+                # A token's bytes in one layer's pages, the layout's padding
+                # counted, and the whole pool's.
+                "kv_token_bytes": self.geom.token_bytes,
+                "kv_pool_bytes": self.geom.pool_bytes,
                 "decode_chunk": self.cfg.decode_chunk,
                 "pallas_attention": bool(self.cfg.pallas_attention),
                 "kv_wire": ("device" if self.kv_transfer_server is not None
@@ -495,7 +530,7 @@ class TpuEngine:
 
         def step(carry, k_step):
             tokens, positions, k_pages, v_pages = carry
-            logits, k_pages, v_pages = llama.decode_step(
+            logits, k_pages, v_pages = self.model.decode_step(
                 params, self._model_for(tokens.size), tokens, positions,
                 k_pages, v_pages, block_tables,
                 attention_fn=self._decode_attention)
@@ -515,7 +550,8 @@ class TpuEngine:
         self._moe_grouped = functools.partial(
             pallas_moe.use_grouped, n_experts=self.mcfg.n_experts,
             experts_per_token=self.mcfg.experts_per_token,
-            d_model=self.mcfg.d_model, d_ff=self.mcfg.d_ff,
+            d_model=self.mcfg.d_model,
+            d_ff=self.mcfg.moe_d_ff or self.mcfg.d_ff,
             platform=platform, interpret=cfg.pallas_interpret,
             sharded=(cfg.tp_size > 1 or cfg.ep_size > 1 or cfg.pp_size > 1
                      or cfg.dist_num_processes > 1))
@@ -540,7 +576,7 @@ class TpuEngine:
         if bucket not in self._prefill_fns:
             def impl(params, tokens, seq_len, k_pages, v_pages, block_table_row,
                      key, temps, top_k, top_p):
-                logits, (k_new, v_new) = llama.forward(
+                logits, (k_new, v_new) = self.model.forward(
                     params, self._model_for(tokens.size), tokens,
                     want_kv=True)
                 k_pages, v_pages = pages.write_sequences(
@@ -567,7 +603,7 @@ class TpuEngine:
             def impl(params, tokens, seq_len, mm_embeds, mm_positions,
                      k_pages, v_pages, block_table_row,
                      rng, temps, top_k, top_p):
-                logits, (k_new, v_new) = llama.forward(
+                logits, (k_new, v_new) = self.model.forward(
                     params, self._model_for(tokens.size), tokens,
                     want_kv=True, mm_embeds=mm_embeds, mm_positions=mm_positions)
                 k_pages, v_pages = pages.write_sequences(
@@ -594,7 +630,7 @@ class TpuEngine:
             def impl(params, tokens, suffix_len, prefix_len, k_pages, v_pages,
                      block_table_row, prior_table_row,
                      rng, temps, top_k, top_p):
-                logits, k_pages, v_pages = llama.prefill_with_prefix(
+                logits, k_pages, v_pages = self.model.prefill_with_prefix(
                     params, self._model_for(tokens.size), tokens, suffix_len,
                     prefix_len,
                     k_pages, v_pages, block_table_row, prior_table_row)
@@ -650,6 +686,12 @@ class TpuEngine:
 
     def submit(self, req: EngineRequest) -> asyncio.Queue:
         """Thread-safe enqueue; returns the per-request event queue."""
+        if self.geom.latent_dim and (req.kv_transfer_params
+                                     or req.mm_embeds is not None):
+            raise ValueError(
+                f"model {self.mcfg.name!r} keeps a latent (MLA) page pool: "
+                "KV handoff to or from another engine and multimodal "
+                "embeddings are not supported")
         out: asyncio.Queue = asyncio.Queue()
         loop = asyncio.get_running_loop()
         with self._cond:
@@ -812,7 +854,7 @@ class TpuEngine:
                     fn = make_pp_embed(self.mcfg, self.pp_mesh, bucket)
                 else:
                     def impl(params, tokens, seq_len):
-                        hidden, _ = llama.forward(
+                        hidden, _ = self.model.forward(
                             params, self._model_for(tokens.size), tokens,
                             want_hidden=True)
                         mask = (jnp.arange(tokens.shape[1])
@@ -1487,7 +1529,7 @@ class TpuEngine:
         win = self._prefill_window()
         if win and len(suffix) > win and req.mm_embeds is None:
             # Long prompt: park the slot PREFILLING; _advance_prefills
-            # writes one window per engine step (interleaved with decode).
+            # writes its windows between the decode chunks.
             if matched_bids:
                 self.telemetry.prefix_cached_tokens.inc(cached_tokens)
             slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
@@ -1661,89 +1703,109 @@ class TpuEngine:
                 self.kv_exports.pop(request_id, None)
 
     def _advance_prefills(self):
-        """Write ONE window for the first PREFILLING slot (round-robin is
-        unnecessary: windows are small, and one per step keeps the decode
-        cadence). The final window's fused sample becomes the pending first
-        token; prefix-cache commit + KV events are deferred to that point."""
-        for idx, s in enumerate(self.slots):
-            if s is None or not s.prefilling:
-                continue
-            win = self._prefill_window()
-            window = s.prefill_rest[:win]
-            last = len(window) == len(s.prefill_rest)
-            written = s.prefill_written
-            block = self.mcfg.kv_block_size
-            req = s.req
-            row = np.zeros((1, self.max_blocks_per_seq), np.int32)
-            row[0, : len(s.blocks)] = s.blocks
-            try:
-                if written == 0:
-                    bucket = self._bucket(len(window))
-                    tokens = np.zeros((1, bucket), np.int32)
-                    tokens[0, : len(window)] = window
-                    tok_dev = self._device_call(("prefill", bucket), dict(
+        """Write windows for the PREFILLING slots ahead of the next decode
+        chunk: one for each such slot, at most PREFILL_STEP_TOKENS of prompt
+        a step, the request that arrived first served first until its
+        prompt is written. A chunk's device time is its weight reads, whatever
+        its lane count, so every slot still prefilling is a lane the chunk
+        pays for and does not use: one window a step admits long prompts at
+        (1 / step) windows a second and leaves three quarters of the lanes
+        empty (ROADMAP S12). A lone long prompt among decoding lanes still
+        gets one window a step (the cadence prefill_chunk exists to keep).
+        By arrival, not by slot index: a request admitted into a low slot
+        would overtake every older one in a higher slot at each turnover,
+        and those wait without bound."""
+        waiting = sorted((s.req.arrival_time, idx) for idx, s in
+                         enumerate(self.slots) if s is not None and s.prefilling)
+        if not waiting:
+            return
+        budget = min(len(waiting),
+                     max(1, PREFILL_STEP_TOKENS // self._prefill_window()))
+        for _, idx in waiting:
+            while budget and self.slots[idx].prefilling:
+                self._write_prefill_window(idx)
+                budget -= 1
+
+    def _write_prefill_window(self, idx: int) -> None:
+        """One window of slot idx's prompt into its pages. The final
+        window's fused sample becomes the pending first token; prefix-cache
+        commit + KV events are deferred to that point."""
+        s = self.slots[idx]
+        win = self._prefill_window()
+        window = s.prefill_rest[:win]
+        last = len(window) == len(s.prefill_rest)
+        written = s.prefill_written
+        block = self.mcfg.kv_block_size
+        req = s.req
+        row = np.zeros((1, self.max_blocks_per_seq), np.int32)
+        row[0, : len(s.blocks)] = s.blocks
+        try:
+            if written == 0:
+                bucket = self._bucket(len(window))
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, : len(window)] = window
+                tok_dev = self._device_call(("prefill", bucket), dict(
+                    tokens=tokens,
+                    seq_len=np.asarray([len(window)], np.int32),
+                    row=row, **self._sample_np([req])))
+            else:
+                # Continuation window: gather the already-written prefix
+                # from its (block-aligned) pages, scatter this window at
+                # offset `written` — the prefix-cache-hit jit, reused.
+                sb = self._bucket(len(window))
+                prior_n = written // block
+                pb = 1
+                while pb < prior_n:
+                    pb *= 2
+                pb = min(pb, self.max_blocks_per_seq)
+                prior = np.zeros((1, pb), np.int32)
+                prior[0, :prior_n] = s.blocks[:prior_n]
+                tokens = np.zeros((1, sb), np.int32)
+                tokens[0, : len(window)] = window
+                tok_dev = self._device_call(
+                    ("prefix_prefill", sb, pb), dict(
                         tokens=tokens,
-                        seq_len=np.asarray([len(window)], np.int32),
-                        row=row, **self._sample_np([req])))
-                else:
-                    # Continuation window: gather the already-written prefix
-                    # from its (block-aligned) pages, scatter this window at
-                    # offset `written` — the prefix-cache-hit jit, reused.
-                    sb = self._bucket(len(window))
-                    prior_n = written // block
-                    pb = 1
-                    while pb < prior_n:
-                        pb *= 2
-                    pb = min(pb, self.max_blocks_per_seq)
-                    prior = np.zeros((1, pb), np.int32)
-                    prior[0, :prior_n] = s.blocks[:prior_n]
-                    tokens = np.zeros((1, sb), np.int32)
-                    tokens[0, : len(window)] = window
-                    tok_dev = self._device_call(
-                        ("prefix_prefill", sb, pb), dict(
-                            tokens=tokens,
-                            suffix_len=np.asarray([len(window)], np.int32),
-                            prefix_len=np.asarray([written], np.int32),
-                            row=row, prior=prior,
-                            **self._sample_np([req])))
-            except Exception:
-                self.slots[idx] = None
-                self._drop_partial_export(req.request_id)
+                        suffix_len=np.asarray([len(window)], np.int32),
+                        prefix_len=np.asarray([written], np.int32),
+                        row=row, prior=prior,
+                        **self._sample_np([req])))
+        except Exception:
+            self.slots[idx] = None
+            self._drop_partial_export(req.request_id)
+            with self._cond:
+                self.allocator.free(s.blocks)
+                self.telemetry.observe_allocator(self.allocator)
+            self._emit_to(s.out, s.loop, TokenEvent(
+                request_id=req.request_id, token_id=None,
+                finish_reason=FinishReason.ABORT,
+                prompt_tokens=s.prompt_len))
+            self.telemetry.running.set(
+                sum(x is not None for x in self.slots))
+            raise
+        self.telemetry.prompt_tokens.inc(len(window))
+        s.prefill_written = written + len(window)
+        s.prefill_rest = s.prefill_rest[len(window):]
+        if not last:
+            # Chunk-streamed remote-decode prefill: stage the window's
+            # newly COMPLETE blocks so a decode peer's long-poll pulls
+            # chunk k while chunk k+1 computes. The final (partial)
+            # block rides the completion staging in _finish_slot.
+            self._maybe_stage_chunk(s)
+        if last:
+            hashes, caching = s.chunk_meta
+            s.chunk_meta = None
+            s.prefilling = False
+            s.pending_tok = tok_dev  # intermediate samples were discarded
+            n_complete = s.prompt_len // block
+            matched_n = s.cached_tokens // block
+            if caching:
                 with self._cond:
-                    self.allocator.free(s.blocks)
-                    self.telemetry.observe_allocator(self.allocator)
-                self._emit_to(s.out, s.loop, TokenEvent(
-                    request_id=req.request_id, token_id=None,
-                    finish_reason=FinishReason.ABORT,
-                    prompt_tokens=s.prompt_len))
-                self.telemetry.running.set(
-                    sum(x is not None for x in self.slots))
-                raise
-            self.telemetry.prompt_tokens.inc(len(window))
-            s.prefill_written = written + len(window)
-            s.prefill_rest = s.prefill_rest[len(window):]
-            if not last:
-                # Chunk-streamed remote-decode prefill: stage the window's
-                # newly COMPLETE blocks so a decode peer's long-poll pulls
-                # chunk k while chunk k+1 computes. The final (partial)
-                # block rides the completion staging in _finish_slot.
-                self._maybe_stage_chunk(s)
-            if last:
-                hashes, caching = s.chunk_meta
-                s.chunk_meta = None
-                s.prefilling = False
-                s.pending_tok = tok_dev  # intermediate samples were discarded
-                n_complete = s.prompt_len // block
-                matched_n = s.cached_tokens // block
-                if caching:
-                    with self._cond:
-                        self.allocator.commit_hashes(
-                            s.blocks[matched_n:n_complete],
-                            hashes[matched_n:n_complete])
-                s.block_hashes = hashes[:n_complete]
-                if self.kv_events is not None and s.block_hashes:
-                    self.kv_events.stored(s.block_hashes)
-            return  # one window per step
+                    self.allocator.commit_hashes(
+                        s.blocks[matched_n:n_complete],
+                        hashes[matched_n:n_complete])
+            s.block_hashes = hashes[:n_complete]
+            if self.kv_events is not None and s.block_hashes:
+                self.kv_events.stored(s.block_hashes)
 
     def _run_prefill_compute(self, req, prompt, suffix, cached_tokens,
                              matched_bids, row):
@@ -2298,6 +2360,15 @@ class TpuEngine:
             self.telemetry.moe_ffn_tokens.labels(
                 form="grouped" if self._moe_grouped(rows) else "dense").inc(
                     rows * (self.cfg.decode_chunk if op[0] == "decode" else 1))
+        if self.geom.latent_dim:
+            # Rows this program puts through latent attention, under the form
+            # its kind traced to (models/mla.py: one query a sequence is
+            # absorbed, a run of them expanded).
+            decode = op[0] == "decode"
+            self.telemetry.mla_attention_tokens.labels(
+                form="absorbed" if decode else "expanded").inc(
+                    args["tokens"].size
+                    * (self.cfg.decode_chunk if decode else 1))
         t0 = time.monotonic()
         result = self._exec_op(op, args)
         dt = time.monotonic() - t0

@@ -495,6 +495,14 @@ class EngineServer:
         except (TypeError, ValueError) as e:
             raise web.HTTPBadRequest(text=f"invalid sampling/limit parameter: {e}")
 
+    def _submit(self, req: EngineRequest) -> asyncio.Queue:
+        """engine.submit; what the engine refuses by name (a request its
+        page pool cannot serve) is the client's 400, not a 500."""
+        try:
+            return self.engine.submit(req)
+        except ValueError as e:
+            raise web.HTTPBadRequest(text=str(e))
+
     @staticmethod
     def _stop_strings(body: dict[str, Any]) -> list[str]:
         stop = body.get("stop")
@@ -754,7 +762,7 @@ class EngineServer:
             stops = self._stop_strings(body)
             timing: dict[str, float] = {}
             t0 = time.monotonic()
-            out = self.engine.submit(req)
+            out = self._submit(req)
             try:
                 if req.stream:
                     resp: web.StreamResponse = await self._stream(
@@ -785,7 +793,7 @@ class EngineServer:
             stops = self._stop_strings(body)
             timing: dict[str, float] = {}
             t0 = time.monotonic()
-            out = self.engine.submit(req)
+            out = self._submit(req)
             try:
                 if req.stream:
                     ws = await self._stream(request, req, out, chat=True,
@@ -877,7 +885,7 @@ class EngineServer:
             span.set_attribute("request_id", req.request_id)
             timing: dict[str, float] = {}
             t0 = time.monotonic()
-            out = self.engine.submit(req)
+            out = self._submit(req)
             try:
                 if req.stream:
                     ws = await self._stream_responses_api(request, req, out,

@@ -153,6 +153,13 @@ class EngineTelemetry:
             "their program's shape traced to (ops/pallas_moe.use_grouped); "
             "counted on the host at dispatch, empty for a dense model",
             ("form",), registry=self.registry)
+        self.mla_attention_tokens = Counter(
+            "jetstream:mla_attention_tokens_total",
+            "Rows (padded tokens) dispatched through latent attention, by the "
+            "form their program traced to (models/mla.py: a decode step is "
+            "absorbed, a prefill or a prefix-continuation window expanded); "
+            "counted on the host at dispatch, empty without a latent pool",
+            ("form",), registry=self.registry)
         self.prompt_tokens = Counter("jetstream:prompt_tokens_total", "Prefilled tokens",
                                      registry=self.registry)
         self.prefix_cached_tokens = Counter(

@@ -10,11 +10,21 @@ The pools stay stacked and are read at (layer, page) (PERF.md, PR 26: a pool
 scanned over reaches the Pallas kernel as one layer's slice, which XLA copies
 out first). The page is 16 tokens and the kernel picks its own stage from the
 shapes (PR 29).
+
+A second kind of page holds latent attention's cache (models/mla.py): ONE
+pool ``[n_layers, n_blocks, block, row_width]``, a token's row its
+``latent_dim`` values (the normed latent, then the rotated key part) shared
+by every head, padded with zeros to whole lanes (``row_width``: 576 -> 640)
+because a TPU array's minor dim is stored in tiles of 128 anyway and the
+kernel's DMA wants the page's rows to be whole tiles. Wherever this module
+takes or returns a ``(k_pages, v_pages)`` pair, a latent pool is the pair
+``(pool, None)``: there is no second array.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -22,10 +32,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.attention import paged_decode_attention
+from ..ops.attention import (latent_paged_decode_attention,
+                             paged_decode_attention)
+from ..ops.pallas_latent_attention import latent_paged_decode_attention_pallas
 from ..ops.pallas_paged_attention import paged_decode_attention_pallas
 
 TRASH_BLOCK = 0
+LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,19 +53,23 @@ class PageGeometry:
     head_dim: int
     dtype: str
     max_blocks_per_seq: int  # a block table's width
+    # Values of a latent row; 0 = the K/V pair (n_kv_heads x head_dim each).
+    latent_dim: int = 0
 
     @classmethod
     def for_model(cls, model: Any, n_blocks: int,
                   max_blocks_per_seq: int | None = None,
                   dtype: str | None = None) -> "PageGeometry":
         """A pool of ``n_blocks`` pages at ``model``'s widths (anything with
-        n_layers, kv_block_size, n_kv_heads, head_dim and dtype). Never fewer
-        than two blocks: the trash block and one to use."""
+        n_layers, kv_block_size, n_kv_heads, head_dim and dtype; a
+        ``latent_dim`` above 0 asks for the latent kind). Never fewer than
+        two blocks: the trash block and one to use."""
         n_blocks = max(n_blocks, 2)
         return cls(model.n_layers, n_blocks, model.kv_block_size,
                    model.n_kv_heads, model.head_dim,
                    str(jnp.dtype(dtype or model.dtype)),
-                   max_blocks_per_seq or n_blocks - 1)
+                   max_blocks_per_seq or n_blocks - 1,
+                   getattr(model, "latent_dim", 0))
 
     @classmethod
     def for_engine(cls, model: Any, max_batch: int, max_model_len: int,
@@ -64,22 +81,36 @@ class PageGeometry:
             model, hbm_kv_blocks or 1 + max_batch * per_seq, per_seq)
 
     @property
-    def shape(self) -> tuple[int, int, int, int, int]:
+    def row_width(self) -> int:
+        """A latent row as stored: ``latent_dim`` padded to whole lanes."""
+        return -(-self.latent_dim // LANES) * LANES
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        """One pool's shape: of K and of V each, or of the latent pool."""
+        if self.latent_dim:
+            return (self.n_layers, self.n_blocks, self.block, self.row_width)
         return (self.n_layers, self.n_blocks, self.block, self.n_kv_heads,
                 self.head_dim)
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes a token holds in one layer, the layout's padding counted."""
+        per = self.row_width or 2 * self.n_kv_heads * self.head_dim
+        return per * jnp.dtype(self.dtype).itemsize
 
     def blocks_for(self, tokens: int) -> int:
         return -(-tokens // self.block)
 
     @property
     def block_bytes(self) -> int:
-        """Bytes one block id holds: K and V, every layer."""
-        return (2 * self.n_layers * self.block * self.n_kv_heads
-                * self.head_dim * jnp.dtype(self.dtype).itemsize)
+        """Bytes one block id holds: K and V (or the padded latent rows),
+        every layer."""
+        return self.n_layers * self.block * self.token_bytes
 
     @property
     def pool_bytes(self) -> int:
-        """Bytes of the pair of pools."""
+        """Bytes of the pair of pools, or of the one latent pool."""
         return self.n_blocks * self.block_bytes
 
 
@@ -103,10 +134,16 @@ def page_sharding(mesh: Mesh) -> NamedSharding:
 
 
 def alloc(geom: PageGeometry, *, device=None,
-          sharding=None) -> tuple[jax.Array, jax.Array]:
+          sharding=None) -> tuple[jax.Array, jax.Array | None]:
     """Zeroed ``(k_pages, v_pages)``, on one device or laid out by
-    ``sharding`` (made in place, shard by shard)."""
+    ``sharding`` (made in place, shard by shard); ``(pool, None)`` for a
+    latent geometry, which has no sharding rule yet."""
     dtype = jnp.dtype(geom.dtype)
+    if geom.latent_dim:
+        if sharding is not None:
+            raise ValueError("a latent page pool is not sharded: page_spec "
+                             "splits KV heads, and a latent row has none")
+        return jnp.zeros(geom.shape, dtype, device=device), None
     if sharding is not None:
         zeros = jax.jit(lambda: jnp.zeros(geom.shape, dtype),
                         out_shardings=sharding)
@@ -166,8 +203,11 @@ def write(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
     """Scatter new KV rows into their pages: ``k_new`` / ``v_new``
     [L, ..., Hkv, D] with ``blocks`` / ``slots`` [...] from
     :func:`token_slots` or :func:`sequence_slots` — every layer's rows in one
-    scatter a pool, so donated pools are updated in place."""
+    scatter a pool, so donated pools are updated in place. A latent pool
+    (``v_pages`` None) takes its rows as ``k_new`` [L, ..., latent_dim]."""
     blocks, slots = blocks.reshape(-1), slots.reshape(-1)
+    if v_pages is None:
+        return _write_latent_rows(k_pages, k_new, blocks, slots), None
 
     def rows(new, pool):
         return new.reshape(new.shape[0], -1, *new.shape[-2:]).astype(
@@ -178,6 +218,51 @@ def write(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
             v_pages.at[:, blocks, slots].set(v_rows))
 
 
+def _stored(pool: jax.Array, rows: jax.Array) -> jax.Array:
+    """Latent rows [..., latent_dim] as the pool stores them: its dtype,
+    zero-padded to its row width."""
+    pad = [(0, 0)] * (rows.ndim - 1) + [(0, pool.shape[-1] - rows.shape[-1])]
+    return jnp.pad(rows, pad).astype(pool.dtype)
+
+
+def _write_latent_rows(pool: jax.Array, rows: jax.Array, blocks: jax.Array,
+                       slots: jax.Array) -> jax.Array:
+    """One row a (layer, token), rows [L, ..., latent_dim], into a latent
+    pool, a page at a time: the token's page is read, the row set in it, and
+    the page written back. A latent page is [block, row_width] with the
+    tokens on the second-minor axis, where a TPU packs two bf16 rows into one
+    word: scattered row by row, XLA re-lays-out the whole pool around the
+    scatter (a copy of it in and out, a decode step; AOT, PR 32), while a
+    scatter of whole pages updates the donated pool in place. Two tokens of
+    one call never share a page, but for those sent to the trash block."""
+    rows = _stored(pool, rows.reshape(rows.shape[0], -1, rows.shape[-1]))
+    at = jnp.arange(pool.shape[2], dtype=slots.dtype)[None, :] == slots[:, None]
+    pages = jnp.where(at[None, :, :, None], rows[:, :, None, :],
+                      pool[:, blocks])                   # [L, n, block, width]
+    return pool.at[:, blocks].set(pages)
+
+
+def _write_latent_run(pool: jax.Array, rows: jax.Array,
+                      block_tables: jax.Array, lens: jax.Array,
+                      start: jax.Array | None) -> jax.Array:
+    """A run of tokens a sequence, rows [L, B, S, latent_dim], into a latent
+    pool from position ``start[b]`` on, whole pages at a time (see
+    :func:`_write_latent_rows`). ``start`` is a multiple of the page (a cached
+    prefix is whole pages, and so is a prefill window) and so is S; the last
+    page's rows past ``lens[b]`` are written as computed — positions no
+    sequence length reaches until a decode step sets them — and pages wholly
+    past it go to the trash block."""
+    L, B, S, _ = rows.shape
+    block = pool.shape[2]
+    first = jnp.arange(S // block, dtype=jnp.int32)[None, :]       # [1, S/blk]
+    at = first if start is None else first + start[:, None] // block
+    ids = jnp.take_along_axis(
+        block_tables, jnp.minimum(at, block_tables.shape[1] - 1), axis=1)
+    ids = jnp.where(first * block < lens[:, None], ids, TRASH_BLOCK)
+    pages = _stored(pool, rows).reshape(L, B * (S // block), block, -1)
+    return pool.at[:, ids.reshape(-1)].set(pages)
+
+
 def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
                     k_new: jax.Array, v_new: jax.Array,
                     block_tables: jax.Array, lens: jax.Array,
@@ -186,6 +271,9 @@ def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
     """A run of tokens a sequence, ``k_new`` / ``v_new`` [L, B, S, Hkv, D]
     (a prefill's KV), written from position ``start[b]`` (None: 0) on;
     padding past ``lens[b]`` lands in the trash block."""
+    if v_pages is None:
+        return _write_latent_run(k_pages, k_new, block_tables, lens,
+                                 start), None
     return write(k_pages, v_pages, k_new, v_new,
                  *sequence_slots(k_pages, block_tables, lens, k_new.shape[2],
                                  start))
@@ -197,12 +285,14 @@ def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
 def use_kernel(head_dim: int, *, asked: bool | None, interpret: bool,
                platform: str, sharded: bool) -> bool:
     """Whether decode attention runs the Pallas kernel or the XLA gather.
+    ``head_dim`` is a page's minor dim (``PageGeometry.shape[-1]``: a latent
+    row is stored lane-aligned, so its kernel always can).
     Left open (``asked`` None), the kernel runs where it compiles and wins: a
     real TPU, single-device pages, a lane-aligned head_dim. Asked for by name
     and impossible is an error, not a quiet switch to the other path."""
     if asked is None:
-        return platform == "tpu" and not sharded and head_dim % 128 == 0
-    if asked and not interpret and head_dim % 128 != 0:
+        return platform == "tpu" and not sharded and head_dim % LANES == 0
+    if asked and not interpret and head_dim % LANES != 0:
         raise ValueError(
             f"pallas_attention: head_dim {head_dim} is not lane-aligned "
             "(128) — Mosaic cannot slice the page DMA; leave the option "
@@ -228,6 +318,34 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                   seq_lens, cur_k=cur_k, cur_v=cur_v)
 
 
+def latent_decode_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                            block_tables: jax.Array, seq_lens: jax.Array,
+                            cur_row: jax.Array, *, value_dim: int,
+                            scale: float, kernel: bool = False,
+                            interpret: bool = False) -> jax.Array:
+    """:func:`decode_attention` for a latent pool, in the absorbed form: q
+    [B, H, latent_dim] (carried into the latent space, then its rotated
+    part) against ``layer``'s rows, each key and value at once, and the
+    token's own row ``cur_row`` [B, latent_dim]. Returns [B, H, value_dim]:
+    the probabilities over the rows' leading ``value_dim`` columns (the
+    latent, which the caller carries out through the value projection)."""
+    op = (functools.partial(latent_paged_decode_attention_pallas,
+                            interpret=interpret)
+          if kernel else latent_paged_decode_attention)
+    return op(q, pool, layer, block_tables, seq_lens, cur_row,
+              value_dim=value_dim, scale=scale)
+
+
+def read_latent_prefix(pool: jax.Array, layer: jax.Array,
+                       table_row: jax.Array, latent_dim: int) -> jax.Array:
+    """A sequence's cached latent rows out of ``layer`` of the stacked pool:
+    the blocks of ``table_row`` [1, W] in order, as [1, W * block,
+    latent_dim], the stored padding dropped. One gather at (layer, page): no
+    layer's pool is sliced out first."""
+    rows = pool[layer, table_row]                    # [1, W, block, width]
+    return rows.reshape(1, -1, rows.shape[-1])[..., :latent_dim]
+
+
 def read_prefix(k_layer: jax.Array, v_layer: jax.Array,
                 table_row: jax.Array) -> tuple[jax.Array, jax.Array]:
     """A sequence's cached KV out of ONE layer's pool [N, block, Hkv, D], as
@@ -240,6 +358,8 @@ def read_prefix(k_layer: jax.Array, v_layer: jax.Array,
 
 
 # ---- export and import, block-wise --------------------------------------------
+# (of the K/V pair: a latent pool is not handed off yet, and an engine that
+# holds one refuses the roles and requests that would; ROADMAP R8)
 
 
 def gather_blocks(k_pages, v_pages, ids):

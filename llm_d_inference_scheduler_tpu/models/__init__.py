@@ -1,4 +1,13 @@
 from .configs import ModelConfig, get_config, LLAMA3_8B, LLAMA3_70B, TINY
-from . import llama
+from . import llama, mla
 
-__all__ = ["ModelConfig", "get_config", "LLAMA3_8B", "LLAMA3_70B", "TINY", "llama"]
+__all__ = ["ModelConfig", "get_config", "LLAMA3_8B", "LLAMA3_70B", "TINY",
+           "llama", "mla", "family"]
+
+
+def family(cfg: ModelConfig):
+    """The module that holds ``cfg``'s block: ``init_params``, ``forward``,
+    ``decode_step`` and ``prefill_with_prefix`` under one set of signatures.
+    Latent attention (kv_lora_rank > 0) names models/mla.py; everything else
+    is models/llama.py's block."""
+    return mla if cfg.kv_lora_rank else llama

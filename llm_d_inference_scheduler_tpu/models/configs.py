@@ -38,10 +38,34 @@ class ModelConfig:
     # per-head RMSNorm on q/k before RoPE.
     head_dim_override: int = 0
     qk_norm: bool = False
+    # Latent attention (the DeepSeek-V3 family's MLA; models/mla.py is its
+    # block): kv_lora_rank > 0 names the family. A token's cache row is its
+    # kv_lora_rank latent values and its qk_rope_head_dim rotated key values,
+    # shared by every head; a query head is qk_nope_head_dim + qk_rope_head_dim
+    # wide and a value head v_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # That family's FFN: first_k_dense leading layers are dense at d_ff; the
+    # rest hold n_experts routed experts of width moe_d_ff beside one shared
+    # expert of width n_shared_experts * moe_d_ff. The router scores with a
+    # sigmoid, selects by score plus a bias, and scales the normalised gates.
+    first_k_dense: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token keeps a layer in a latent page pool; 0 = K and V."""
+        return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
 
     @property
     def q_per_kv(self) -> int:
@@ -182,9 +206,59 @@ TINY_MOE = ModelConfig(
     experts_per_token=2,
 )
 
+# Kimi-VL-A3B-Instruct's language model (public config.json, text_config):
+# latent attention, one dense layer, then 26 layers of 64 narrow routed
+# experts (6 a token) beside a shared one. The vision tower is not built.
+KIMI_VL_A3B = ModelConfig(
+    name="kimi-vl-a3b",
+    vocab_size=163_840,
+    d_model=2048,
+    n_layers=27,
+    n_heads=16,
+    n_kv_heads=16,
+    d_ff=11264,
+    rope_theta=800_000.0,
+    max_seq_len=131_072,
+    n_experts=64,
+    experts_per_token=6,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    first_k_dense=1,
+    moe_d_ff=1408,
+    n_shared_experts=2,
+    routed_scaling_factor=2.446,
+)
+
+# The same family at small widths aligned to nothing (CI tests): 24 + 8 = 32
+# values a cache row, 1 dense layer + 2 expert layers, 8 experts, 3 a token.
+TINY_MLA = ModelConfig(
+    name="tiny-mla",
+    vocab_size=512,
+    d_model=96,
+    n_layers=3,
+    n_heads=3,
+    n_kv_heads=3,
+    d_ff=160,
+    max_seq_len=256,
+    rope_theta=10_000.0,
+    n_experts=8,
+    experts_per_token=3,
+    kv_lora_rank=24,
+    qk_nope_head_dim=20,
+    qk_rope_head_dim=8,
+    v_head_dim=12,
+    first_k_dense=1,
+    moe_d_ff=40,
+    n_shared_experts=2,
+    routed_scaling_factor=2.446,
+)
+
 _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
-                                 QWEN3_32B, QWEN3_4B, TINY_QWEN)}
+                                 QWEN3_32B, QWEN3_4B, TINY_QWEN,
+                                 KIMI_VL_A3B, TINY_MLA)}
 
 
 def get_config(name: str) -> ModelConfig:

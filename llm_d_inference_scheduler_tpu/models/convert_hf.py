@@ -29,8 +29,60 @@ from .configs import ModelConfig
 __all__ = ["config_from_hf", "convert_state_dict", "main"]
 
 
+# What models/mla.py computes of the DeepSeek-V3 family's options; a config
+# that says otherwise is refused, not served as something else.
+_MLA_ONLY = {"q_lora_rank": None, "rope_scaling": None, "n_group": 1,
+             "topk_group": 1, "scoring_func": "sigmoid",
+             "topk_method": "noaux_tc", "norm_topk_prob": True,
+             "moe_layer_freq": 1, "attention_bias": False,
+             "hidden_act": "silu", "tie_word_embeddings": False}
+
+
+def _mla_config_from_hf(hf, name: str) -> ModelConfig:
+    """The DeepSeek-V3 family (latent attention, sigmoid-routed experts
+    beside a shared one): Kimi-VL's language model is one."""
+    for key, only in _MLA_ONLY.items():
+        got = getattr(hf, key, only)
+        if got != only:
+            raise ValueError(f"{name}: {key}={got!r} is not supported "
+                             f"(models/mla.py computes {key}={only!r} only)")
+    return ModelConfig(
+        name=name,
+        vocab_size=hf.vocab_size,
+        d_model=hf.hidden_size,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=getattr(hf, "num_key_value_heads", hf.num_attention_heads),
+        d_ff=hf.intermediate_size,
+        rope_theta=float(getattr(hf, "rope_theta", 10_000.0)),
+        max_seq_len=getattr(hf, "max_position_embeddings", 8192),
+        norm_eps=hf.rms_norm_eps,
+        n_experts=hf.n_routed_experts,
+        experts_per_token=hf.num_experts_per_tok,
+        kv_lora_rank=hf.kv_lora_rank,
+        qk_nope_head_dim=hf.qk_nope_head_dim,
+        qk_rope_head_dim=hf.qk_rope_head_dim,
+        v_head_dim=hf.v_head_dim,
+        first_k_dense=hf.first_k_dense_replace,
+        moe_d_ff=hf.moe_intermediate_size,
+        n_shared_experts=hf.n_shared_experts,
+        routed_scaling_factor=float(hf.routed_scaling_factor),
+    )
+
+
 def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
-    """Map a transformers Llama/Mixtral/Qwen3 config to our ModelConfig."""
+    """Map a transformers Llama/Mixtral/Qwen3 config, or a DeepSeek-V3-family
+    one (Kimi-VL's ``text_config``), to our ModelConfig."""
+    text = getattr(hf_config, "text_config", None)
+    if text is not None:
+        # A multimodal config nests its language model; the towers beside it
+        # are not this mapping's.
+        import types
+
+        hf_config = (types.SimpleNamespace(**text) if isinstance(text, dict)
+                     else text)
+    if getattr(hf_config, "kv_lora_rank", None):
+        return _mla_config_from_hf(hf_config, name)
     n_experts = getattr(hf_config, "num_local_experts", 0) or 0
     qk_norm = getattr(hf_config, "model_type", "") == "qwen3"
     explicit_hd = getattr(hf_config, "head_dim", None) or 0
@@ -72,6 +124,12 @@ def convert_state_dict(state_dict: dict, cfg: ModelConfig,
     """HF Llama/Mixtral state dict → stacked params pytree (jnp arrays)."""
     import jax.numpy as jnp
 
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.name}: no mapping of the latent-attention family's "
+            "checkpoint names onto models/mla.py's parameter tree yet (its "
+            "rope columns are stored interleaved and need un-interleaving); "
+            "the engine serves this family on seeded random weights")
     out_dtype = jnp.dtype(dtype or cfg.dtype)
     L, E = cfg.n_layers, cfg.n_experts
 

@@ -115,3 +115,41 @@ def paged_decode_attention(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bht,bthd->bhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def latent_paged_decode_attention(
+    q: jnp.ndarray,             # [B, H, Dk] — [q absorbed into the latent | q_rope]
+    pages: jnp.ndarray,         # [L, N_blocks, block, W] — every layer's latent pool, W >= Dk
+    layer: jnp.ndarray,         # int32 scalar — the layer whose pool is read
+    block_tables: jnp.ndarray,  # [B, max_blocks] int32
+    seq_lens: jnp.ndarray,      # [B] int32 — incl. the current token
+    cur_row: jnp.ndarray,       # [B, Dk] — the current token's row, not in the pages yet
+    *,
+    value_dim: int,             # the row's leading columns that are the value
+    scale: float,
+) -> jnp.ndarray:
+    """Decode-step attention of the absorbed latent form; returns [B, H,
+    value_dim] in q.dtype.
+
+    A cached row is one vector for every head, and it is key and value at
+    once: all Dk columns against the query give the score, the first
+    ``value_dim`` of them (the latent; the rest is the rotated key part) are
+    what the probabilities weigh. Columns of a page past Dk are the layout's
+    padding and are not read. The current token is an extra, always visible
+    column, as in :func:`paged_decode_attention`; the Pallas kernel
+    (ops/pallas_latent_attention.py) has the same signature.
+    """
+    B, H, Dk = q.shape
+    block = pages.shape[2]
+    T = block_tables.shape[1] * block
+    rows = pages[layer, block_tables].reshape(B, T, -1)[..., :Dk]
+    rows = jnp.concatenate([rows, cur_row[:, None].astype(rows.dtype)],
+                           axis=1).astype(jnp.float32)        # [B, T+1, Dk]
+    logits = jnp.einsum("bhd,btd->bht", q.astype(jnp.float32), rows) * scale
+    valid = jnp.concatenate(
+        [jnp.arange(T)[None, :] < (seq_lens - 1)[:, None],
+         jnp.ones((B, 1), bool)], axis=1)
+    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bht,btd->bhd", probs, rows[..., :value_dim])
+    return out.astype(q.dtype)

@@ -223,9 +223,7 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
     come from the shapes (:func:`pick_tiles`).
     """
     B, S, D = x.shape
-    E, k = n_experts, experts_per_token
-    T = B * S
-    xt = x.reshape(T, D)
+    xt = x.reshape(B * S, D)
 
     # The router's logits in f32 straight from the product, said outright: on
     # a TPU that is what _moe_ffn's cast of a bf16 product compiles to as
@@ -234,8 +232,25 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
     # choice lie within bf16 rounding to another expert than the dense form.
     logits = jnp.dot(xt, lp["router"],
                      preferred_element_type=jnp.float32)        # [T, E]
-    top_vals, top_idx = jax.lax.top_k(logits, k)                # [T, k]
+    top_vals, top_idx = jax.lax.top_k(logits, experts_per_token)  # [T, k]
     gates = jax.nn.softmax(top_vals, axis=-1)                   # [T, k]
+    y = grouped_experts(lp, xt, top_idx, gates, n_experts, layer=layer, tm=tm,
+                        interpret=interpret, tiles_up=tiles_up,
+                        tiles_down=tiles_down)
+    return y.reshape(B, S, D)
+
+
+def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
+                    tm: int = ROW_TILE, interpret: bool = False,
+                    tiles_up: tuple[int, int] | None = None,
+                    tiles_down: tuple[int, int] | None = None) -> jnp.ndarray:
+    """The routed experts' part of an MoE FFN, whoever routed: token t of
+    ``xt`` [T, D] through its experts ``top_idx`` [T, k], weighted by
+    ``gates`` [T, k] and added. Returns [T, D] in xt.dtype. The router (its
+    scores, its selection, its gates) is the model's; the weights w1/w3/w2
+    and ``layer`` are as :func:`moe_ffn_grouped` takes them."""
+    T, D = xt.shape
+    E, k = n_experts, top_idx.shape[1]
 
     # The T·k (token, expert) rows, stable-sorted by expert.
     flat_expert = top_idx.reshape(-1)                           # [T*k]
@@ -261,7 +276,7 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
         (order // k).astype(jnp.int32))
     dest = jnp.zeros((T * k,), jnp.int32).at[order].set(
         dest_sorted.astype(jnp.int32))
-    x_pad = jnp.concatenate([xt, jnp.zeros((1, D), x.dtype)])[src]
+    x_pad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[src]
 
     # tile → expert: whose [off[e], off[e+1]) holds the tile's first row.
     tile_starts = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
@@ -282,5 +297,5 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
                               tm=tm, tiles=tiles_down, interpret=interpret)
 
     y = (out_pad[dest].reshape(T, k, D)
-         * gates[..., None].astype(x.dtype)).sum(axis=1)
-    return y.reshape(B, S, D).astype(x.dtype)
+         * gates[..., None].astype(xt.dtype)).sum(axis=1)
+    return y.astype(xt.dtype)

@@ -3,8 +3,9 @@
 The TPU compiler is installed beside JAX and compiles for a topology that is
 described and not attached (`jax.experimental.topologies`). This hands it the
 programs a single-device `TpuEngine` serves with — the random-weight init,
-the fused decode chunk, plain prefill buckets and one prefix-prefill bucket —
-at a registered model's full size, and prints for each the compile seconds,
+the fused decode chunk, plain prefill buckets and prefix-prefill buckets, of
+either block family (models.family) and its page pool — at a registered
+model's full size, and prints for each the compile seconds,
 `memory_analysis()` and whether the Pallas call (`tpu_custom_call`) is in the
 compiled text. What the compiler refuses here (a kernel it cannot lower, a
 program that does not fit the device) it would refuse on the chip.
@@ -96,7 +97,7 @@ def main(argv=None) -> int:
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
-    from llm_d_inference_scheduler_tpu.models import llama
+    from llm_d_inference_scheduler_tpu.models import family
 
     if args.config_file:
         import types
@@ -130,17 +131,21 @@ def main(argv=None) -> int:
     # — building a real engine would materialise the weights on the host.
     eng = object.__new__(TpuEngine)
     eng.cfg, eng.mcfg, eng.pp_mesh, eng._prefill_fns = cfg, mcfg, None, {}
-    eng._decode_attention = functools.partial(kvpages.decode_attention,
-                                              kernel=True)
+    eng.model = model = family(mcfg)
+    eng.geom = geom = kvpages.PageGeometry.for_engine(
+        mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
+    eng._decode_attention = functools.partial(
+        kvpages.latent_decode_attention if geom.latent_dim
+        else kvpages.decode_attention, kernel=True)
     eng._bind_moe_form("tpu")  # the described chip, not this host's CPU
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(
-        lambda k: llama.init_params(mcfg, k), jax.random.key(0)))
-    geom = kvpages.PageGeometry.for_engine(
-        mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
+        lambda k: model.init_params(mcfg, k), jax.random.key(0)))
     width = geom.max_blocks_per_seq
     pages = sds(geom.shape, jnp.dtype(geom.dtype))
+    # The pool pair as a step function takes it: K and V, or (latent, None).
+    pool = (pages, None) if geom.latent_dim else (pages, pages)
 
     def sampling(rows):
         return (key, sds((rows,), jnp.float32), sds((rows,), jnp.int32),
@@ -149,18 +154,18 @@ def main(argv=None) -> int:
     programs = []
     if not args.skip_init:
         programs.append(("init", jax.jit(
-            lambda k: llama.init_params(mcfg, k), out_shardings=one_chip),
+            lambda k: model.init_params(mcfg, k), out_shardings=one_chip),
             (key,)))
     for b in [int(x) for x in args.decode_batches.split(",") if x]:
         programs.append((f"decode {b}x{width}", jax.jit(
             eng._decode_chunk_impl, donate_argnums=(3, 4)),
-            (params, sds((b,), jnp.int32), sds((b,), jnp.int32), pages, pages,
+            (params, sds((b,), jnp.int32), sds((b,), jnp.int32), *pool,
              sds((b, width), jnp.int32), *sampling(b))))
     for spec in [s for s in args.prefill.split(",") if s]:
         bucket, rows = (int(x) for x in spec.split("x"))
         programs.append((f"prefill {rows}x{bucket}", eng._prefill_fn(bucket),
                          (params, sds((rows, bucket), jnp.int32),
-                          sds((rows,), jnp.int32), pages, pages,
+                          sds((rows,), jnp.int32), *pool,
                           sds((rows, width), jnp.int32), *sampling(rows))))
     for spec in [s for s in args.prefix.split(",") if s]:
         suffix, prefix_blocks = (int(x) for x in spec.split("x"))
@@ -168,7 +173,7 @@ def main(argv=None) -> int:
                          eng._prefix_prefill_fn(suffix, prefix_blocks),
                          (params, sds((1, suffix), jnp.int32),
                           sds((1,), jnp.int32), sds((1,), jnp.int32),
-                          pages, pages, sds((1, width), jnp.int32),
+                          *pool, sds((1, width), jnp.int32),
                           sds((1, prefix_blocks), jnp.int32), *sampling(1))))
 
     ok = True

@@ -115,6 +115,46 @@ def test_decode_step_copies_no_layers_page_pool(one_chip):
             < dt.itemsize * math.prod(one_layer))
 
 
+def test_latent_decode_step_compiles_and_copies_no_pool(one_chip):
+    """One decode step of the latent-attention family at Kimi-VL-A3B's
+    widths, one dense and one expert layer, 32 lanes on the cell's pool (32 x
+    8,192 tokens: 16,385 pages of 640 stored values a token), the latent
+    kernel on: Mosaic takes the 640-wide page DMA and the 16-row products,
+    the pool reaches the custom call whole (a layer's slice would be 336 MB
+    of copy), and the step's temporaries stay under one layer's pool."""
+    from llm_d_inference_scheduler_tpu.kvcache.pages import (
+        PageGeometry, latent_decode_attention)
+    from llm_d_inference_scheduler_tpu.models import mla
+    from llm_d_inference_scheduler_tpu.models.configs import KIMI_VL_A3B
+
+    m = dataclasses.replace(KIMI_VL_A3B, n_layers=2)
+    geom = PageGeometry.for_engine(m, 32, 8192)
+    dt = jnp.dtype(m.dtype)
+    batch = 32
+    pool = _sds(one_chip, geom.shape, dt)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    compiled = jax.jit(
+        lambda *a: mla.decode_step(
+            a[0], m, *a[1:],
+            attention_fn=functools.partial(latent_decode_attention,
+                                           kernel=True)),
+        donate_argnums=(3,),
+    ).lower(params, _sds(one_chip, (batch,), jnp.int32),
+            _sds(one_chip, (batch,), jnp.int32), pool, None,
+            _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
+            ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "mla_paged_decode_attention" in hlo
+    shape = "bf16[" + ",".join(map(str, geom.shape[1:])) + "]"
+    made = [ln.strip()[:160] for ln in hlo.splitlines()
+            if re.search(r"=\s*" + re.escape(shape), ln)]
+    assert not made, made
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < dt.itemsize * math.prod(geom.shape[1:]))
+
+
 @pytest.mark.parametrize("d_model,d_ff,n_experts,top_k,tokens", [
     # Mixtral-8x7B, the one MoE model registered, at the two prefill buckets
     # the rule hands to the grouped form in mixtral-8x7b-cut.batch-full. Its

@@ -444,6 +444,116 @@ def test_incremental_prefill_token_parity_and_no_stall():
 
 
 
+def test_prefill_windows_are_served_by_arrival_not_by_slot():
+    """Three slots, windows of 16: A (short) holds slot 0 and ends at once,
+    B and C (ten windows each) wait in slots 1 and 2; D (three windows)
+    arrives when A is done and takes slot 0. Served by slot index D would
+    overtake C (and the rest of B); served by arrival the first tokens come
+    in the order B, C, D."""
+    import time
+
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    def prompt(salt, n):
+        return [1] + [(j * salt) % 450 + 3 for j in range(n - 1)]
+
+    async def body():
+        eng = TpuEngine(EngineConfig(
+            model="tiny", backend="tpu", max_batch=3, max_model_len=256,
+            decode_chunk=2, prefill_chunk=16, kv_events_port=0, seed=3))
+        await eng.start()
+        first_at = {}
+
+        async def one(rid, ids, n):
+            out = eng.submit(EngineRequest(
+                request_id=rid, prompt_token_ids=ids, max_tokens=n,
+                temperature=0.0, ignore_eos=True))
+            while True:
+                ev = await asyncio.wait_for(out.get(), timeout=300)
+                if ev.token_id is not None:
+                    first_at.setdefault(rid, time.monotonic())
+                if ev.finish_reason is not None:
+                    return
+
+        try:
+            a = asyncio.ensure_future(one("A", prompt(5, 12), 1))
+            b = asyncio.ensure_future(one("B", prompt(7, 160), 2))
+            c = asyncio.ensure_future(one("C", prompt(11, 160), 2))
+            await a
+            await one("D", prompt(13, 40), 2)
+            await asyncio.gather(b, c)
+        finally:
+            await eng.stop()
+        return first_at
+
+    first_at = asyncio.run(body())
+    assert first_at["B"] < first_at["C"] < first_at["D"]
+
+
+@pytest.mark.parametrize("n_long, step_tokens, most", [
+    (1, 4096, 1),   # a lone long prompt: one window a step, as ever
+    (3, 4096, 3),   # three lanes wait for their prompts: a window each
+    (3, 32, 2),     # ... within the step's budget of prompt tokens
+])
+def test_prefill_windows_a_step_follow_the_waiting_lanes(
+        monkeypatch, n_long, step_tokens, most):
+    """Windows of 16 tokens. A step writes one window for every slot still
+    prefilling, at most PREFILL_STEP_TOKENS of prompt, oldest request first;
+    the tokens are those of whole-prompt prefill."""
+    from llm_d_inference_scheduler_tpu.engine import core
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    monkeypatch.setattr(core, "PREFILL_STEP_TOKENS", step_tokens)
+    prompts = [[1] + [(j * salt) % 450 + 3 for j in range(95)]
+               for salt in (7, 11, 13)[:n_long]]
+    base = dict(model="tiny", backend="tpu", max_batch=4, max_model_len=256,
+                decode_chunk=2, kv_events_port=0, seed=5)
+
+    async def serve(cfg):
+        eng = TpuEngine(cfg)
+        steps: list[list[int]] = []
+        advance, write = eng._advance_prefills, eng._write_prefill_window
+
+        def counted_advance():
+            steps.append([])
+            advance()
+
+        def counted_write(idx):
+            steps[-1].append(idx)
+            write(idx)
+
+        eng._advance_prefills = counted_advance
+        eng._write_prefill_window = counted_write
+        await eng.start()
+        try:
+            async def one(i, ids):
+                out = eng.submit(EngineRequest(
+                    request_id=f"r{i}", prompt_token_ids=ids, max_tokens=4,
+                    temperature=0.0, ignore_eos=True))
+                toks = []
+                while True:
+                    ev = await asyncio.wait_for(out.get(), timeout=300)
+                    if ev.token_id is not None:
+                        toks.append(ev.token_id)
+                    if ev.finish_reason is not None:
+                        return toks
+            toks = await asyncio.gather(
+                *(one(i, ids) for i, ids in enumerate(prompts)))
+        finally:
+            await eng.stop()
+        return toks, [s for s in steps if s]
+
+    whole, _ = asyncio.run(serve(EngineConfig(**base)))
+    chunked, steps = asyncio.run(serve(EngineConfig(**base, prefill_chunk=16)))
+    assert chunked == whole
+    assert sum(map(len, steps)) == 6 * n_long     # 96 tokens: six windows each
+    assert max(map(len, steps)) == most
+    # Oldest first: a step never leaves an older request's window undone to
+    # write a younger one's.
+    order = [idx for s in steps for idx in s]
+    assert order == sorted(order, key=order.index)
+
+
 def test_note_kv_import_dedupes_eviction_ring():
     """A re-dispatched request id overwrites its kv_import_stats entry; the
     eviction ring must not gain a duplicate slot, or a later cap eviction
