@@ -106,18 +106,20 @@ class _Slot:
     out: asyncio.Queue
     loop: asyncio.AbstractEventLoop
     blocks: list[int]
-    position: int              # next token position to be written
+    position: int              # next token position to be written (booked)
     generated: list[int]
-    last_token: int
+    # Decode steps dispatched for this slot and not yet booked: the chunk in
+    # flight, and while the one before it is being read, that one too.
+    ahead: int = 0
     first_emitted: bool = False
     aborted: bool = False
     cached_tokens: int = 0
     block_hashes: list[int] = dataclasses.field(default_factory=list)
     # Pipelined prefill: the fused prefill jit's sampled first token, still on
-    # device (host transfer in flight). The slot joins decode chunks only
-    # after _finalize_prefills() lands it — this keeps the device→host
-    # sync off the dispatch critical path (the decode chunk for the other
-    # lanes is already queued behind the prefill on device).
+    # device (host transfer in flight). The slot joins the chunk dispatched
+    # right behind its prefill (that chunk takes the token from the device,
+    # TpuEngine._slot_tokens); _finalize_prefills() lands it on the host
+    # afterwards, off the dispatch critical path.
     pending_tok: Any = None
     # Row of this slot's first token inside pending_tok (batched prefill
     # shares one [N] device array across the group; singles use row 0).
@@ -133,6 +135,15 @@ class _Slot:
     # (hashes, caching) — prefix-cache commit + KV-event publication are
     # deferred until the last window lands.
     chunk_meta: Any = None
+
+
+@dataclasses.dataclass
+class _Chunk:
+    """A decode chunk dispatched and not yet read."""
+    toks: Any                        # [K, B] sampled tokens, on the device
+    lanes: list[tuple[int, _Slot]]   # lane -> (slot index, the slot it held)
+    t0: float                        # the loop's clock at dispatch
+    timed: bool                      # its shape was built before: observe it
 
 
 @dataclasses.dataclass
@@ -454,6 +465,28 @@ class TpuEngine:
             return pages.scatter_blocks(kp, vp, blocks, k_new, v_new)
 
         self._jit_import = jax.jit(kv_import, donate_argnums=(0, 1))
+        # Every slot's newest sampled token, on the device: a prefill leaves
+        # its first token here and a chunk its last row, so the next chunk is
+        # dispatched before either has reached the host. Index max_batch is
+        # nobody's (padding lanes, samples nobody decodes from): out of
+        # range, so it reads 0 and a write to it is dropped.
+        self._slot_tokens = self._put(np.zeros((cfg.max_batch,), np.int32))
+
+        def keep_tokens(table, slots, toks):    # toks [N], or [K, N]: last row
+            return table.at[slots].set(toks.reshape(-1, slots.size)[-1],
+                                       mode="drop")
+
+        def slot_tokens(table, slots):
+            return table.at[slots].get(mode="fill", fill_value=0)
+
+        self._jit_keep_tokens = jax.jit(keep_tokens)
+        self._jit_slot_tokens = jax.jit(slot_tokens)
+        # The chunk dispatched and not yet read (_step keeps one in flight
+        # while it reads and books the one before), and when the last one
+        # was read, on the loop's clock.
+        self._inflight: _Chunk | None = None
+        self._last_readback = 0.0
+        self._clock = time.monotonic
         log.info("engine %s up: %s", self.engine_id,
                  json.dumps(self.describe()))
 
@@ -520,12 +553,21 @@ class TpuEngine:
         A ``lax.scan`` on device: each step runs the paged decode step and
         samples the next token, which feeds the following step. Returns all
         sampled tokens [K, B]; the host applies them per-lane up to each
-        request's stop condition and discards the overshoot (whose KV writes
-        land in the sequence's own still-allocated tail or the trash block —
-        never in a block another request can see as cached). This amortizes
-        dispatch latency K× vs a per-token loop (JetStream-style multistep
-        scheduling); what it saves on a chip attached to the host is not
-        measured yet."""
+        request's stop condition and discards the overshoot. A lane that ends
+        on a stop token or an abort has the next chunk in flight already
+        (_step), so the overshoot reaches up to 2K - 1 positions past the
+        request's end. Its KV writes land in the sequence's own allocated
+        tail, or past it in the table's padding (the trash block; a table
+        narrowed by decode_ctx_buckets clamps to the row's last entry, which
+        is one of the two) — never in a block of another live request, and
+        never in a block the prefix cache holds (those are whole blocks of
+        the prompt, below the first decoded position). Whatever reuses the
+        freed blocks is dispatched later on the same in-order device stream
+        and overwrites them. The input tokens come from the device
+        (_slot_tokens), so the host neither reads nor books a chunk before
+        it dispatches the next: one dispatch a K tokens, and no idle device
+        between chunks (PERF.md section 6, PR 33, has what that is worth on
+        the chip)."""
         keys = jax.random.split(key, self.cfg.decode_chunk)
 
         def step(carry, k_step):
@@ -880,12 +922,13 @@ class TpuEngine:
         decode step, sampler) — all writes land in the trash block."""
         t0 = time.monotonic()
         B = self.cfg.max_batch
+        nobody = np.full((1,), B, np.int32)  # no slot keeps a warm-up token
         bucket = self._bucket(16)  # respects max_model_len < 16
         self._device_call(("prefill", bucket), dict(
             tokens=np.zeros((1, bucket), np.int32),
             seq_len=np.asarray([1], np.int32),
             row=np.zeros((1, self.max_blocks_per_seq), np.int32),
-            warm=True, **self._sample_np([_DUMMY_REQ])))
+            slots=nobody, warm=True, **self._sample_np([_DUMMY_REQ])))
         if self.cfg.prefill_batch > 1 and self.pp_mesh is None:
             # Batched prefill pads every group to exactly prefill_batch rows,
             # so ONE extra traced shape per bucket covers it.
@@ -894,6 +937,7 @@ class TpuEngine:
                 tokens=np.zeros((K, bucket), np.int32),
                 seq_len=np.ones((K,), np.int32),
                 row=np.zeros((K, self.max_blocks_per_seq), np.int32),
+                slots=np.full((K,), B, np.int32),
                 warm=True, **self._sample_np([_DUMMY_REQ] * K)))
         if self._prefill_window():
             # Incremental prefill's mid-stream shapes: every intermediate
@@ -907,7 +951,7 @@ class TpuEngine:
                 tokens=np.zeros((1, wb), np.int32),
                 seq_len=np.asarray([1], np.int32),
                 row=np.zeros((1, self.max_blocks_per_seq), np.int32),
-                warm=True, **self._sample_np([_DUMMY_REQ])))
+                slots=nobody, warm=True, **self._sample_np([_DUMMY_REQ])))
             pb = 1
             while True:
                 self._device_call(("prefix_prefill", wb, pb), dict(
@@ -916,7 +960,7 @@ class TpuEngine:
                     prefix_len=np.asarray([0], np.int32),
                     row=np.zeros((1, self.max_blocks_per_seq), np.int32),
                     prior=np.zeros((1, pb), np.int32),
-                    warm=True, **self._sample_np([_DUMMY_REQ])))
+                    slots=nobody, warm=True, **self._sample_np([_DUMMY_REQ])))
                 if pb >= self.max_blocks_per_seq:
                     break
                 pb = min(pb * 2, self.max_blocks_per_seq)
@@ -932,7 +976,7 @@ class TpuEngine:
         for nb in buckets:
             for w in widths:
                 self._device_call(("decode",), dict(
-                    tokens=np.zeros((nb,), np.int32),
+                    slots=np.full((nb,), B, np.int32),
                     positions=np.zeros((nb,), np.int32),
                     tables=np.zeros((nb, w), np.int32),
                     warm=True, **self._sample_np([_DUMMY_REQ] * nb)))
@@ -945,12 +989,12 @@ class TpuEngine:
         drift: a host span `engine.<name>` in the profiler's trace (inert
         while no trace runs) and the same interval added to
         jetstream:engine_loop_seconds_total{phase}. Phases never nest."""
-        t0 = time.monotonic()
+        t0 = self._clock()
         try:
             with jax.profiler.TraceAnnotation("engine." + name):
                 yield
         finally:
-            self.telemetry.loop_seconds[name].inc(time.monotonic() - t0)
+            self.telemetry.loop_seconds[name].inc(self._clock() - t0)
 
     def _run(self):
         if self.kv_events is not None:
@@ -979,7 +1023,7 @@ class TpuEngine:
             with self._cond:
                 while (not self._stop and not self._waiting and not self._import_ready
                        and not self._abort_ids and not self._embed_reqs
-                       and not any(self.slots)):
+                       and not any(self.slots) and self._inflight is None):
                     with self._phase("idle_wait"):
                         self._cond.wait(timeout=0.1)
                     # Keep the 1s KV snapshot cadence alive while idle: a
@@ -990,6 +1034,7 @@ class TpuEngine:
                     for *_, fut in self._embed_reqs:
                         fut.set_exception(ValueError("engine stopping"))
                     self._embed_reqs = []
+                    self._inflight = None   # nobody will read it
                     return
             if self.dist_degraded:
                 # Drain everything (queued work included) without touching
@@ -1017,18 +1062,18 @@ class TpuEngine:
             self._admit()
         with self._phase("advance_prefills"):
             self._advance_prefills()
-        if any(s is not None and s.pending_tok is None and not s.prefilling
-               for s in self.slots):
-            # Decode the established lanes (the chunk dispatch queues behind
-            # any just-dispatched prefills on device), THEN land pending
-            # first tokens — their host transfer overlapped the chunk.
-            self._decode_once()
-            with self._phase("finalize_prefills"):
-                self._finalize_prefills()
-        elif any(s is not None for s in self.slots):
-            with self._phase("finalize_prefills"):
-                self._finalize_prefills()
-        else:
+        # The next chunk goes out BEFORE the one in flight is read: it queues
+        # on the device behind that chunk and behind the prefills above, and
+        # takes its tokens from the device (theirs, _slot_tokens), so the
+        # device has work while the host reads and books. Then the chunk
+        # before is read and booked, and last the prefills' first tokens
+        # land: one chunk period ahead of their tokens 2 to K + 1.
+        landing = self._inflight
+        self._inflight = self._dispatch_chunk()
+        if landing is not None:
+            self._land_chunk(landing)
+        self._finalize_prefills()
+        if landing is None and not any(self.slots):
             with self._cond:
                 if (self._waiting or self._import_ready) and not self._abort_ids:
                     # Head-of-line can't be placed yet (no free blocks / no slot
@@ -1045,6 +1090,7 @@ class TpuEngine:
             self._cond.notify()
 
     def _abort_all(self, reason: str):
+        self._inflight = None   # its lanes end here; nobody will read it
         for i, s in enumerate(self.slots):
             if s is not None:
                 self._finish_slot(i, FinishReason.ABORT)
@@ -1397,15 +1443,18 @@ class TpuEngine:
             tokens = np.zeros((K, bucket), np.int32)
             seq_len = np.ones((K,), np.int32)
             rows = np.zeros((K, self.max_blocks_per_seq), np.int32)
-            for k, (_, req, _, _, need, pre, blocks) in enumerate(entries):
+            slots = np.full((K,), self.cfg.max_batch, np.int32)
+            for k, (i, req, _, _, need, pre, blocks) in enumerate(entries):
                 prompt = pre[0]
                 tokens[k, : len(prompt)] = prompt
                 seq_len[k] = len(prompt)
                 rows[k, : len(blocks)] = blocks
+                slots[k] = i
             reqs = [e[1] for e in entries]
             samp = self._sample_np(reqs + [_DUMMY_REQ] * (K - len(reqs)))
             tok_dev = self._device_call(("prefill", bucket), dict(
-                tokens=tokens, seq_len=seq_len, row=rows, **samp))
+                tokens=tokens, seq_len=seq_len, row=rows, slots=slots,
+                **samp))
         except Exception:
             with self._cond:
                 for *_, blocks in entries:
@@ -1427,7 +1476,7 @@ class TpuEngine:
                 # still count into the admitted-token denominator.
                 self._note_prefix_hit(req.request_id, 0, len(prompt))
                 slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
-                             position=len(prompt), generated=[], last_token=-1,
+                             position=len(prompt), generated=[],
                              cached_tokens=0, pending_tok=tok_dev, pending_idx=k,
                              prompt_len=len(prompt))
                 n_complete = len(prompt) // block
@@ -1533,7 +1582,7 @@ class TpuEngine:
             if matched_bids:
                 self.telemetry.prefix_cached_tokens.inc(cached_tokens)
             slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
-                         position=len(prompt), generated=[], last_token=-1,
+                         position=len(prompt), generated=[],
                          cached_tokens=cached_tokens, prompt_len=len(prompt),
                          prefilling=True)
             slot.prefill_rest = list(suffix)
@@ -1546,8 +1595,9 @@ class TpuEngine:
         row = np.zeros((1, self.max_blocks_per_seq), np.int32)
         row[0, : len(blocks)] = blocks
         try:
-            tok_dev = self._run_prefill_compute(req, prompt, suffix,
-                                                cached_tokens, matched_bids, row)
+            tok_dev = self._run_prefill_compute(
+                req, prompt, suffix, cached_tokens, matched_bids, row,
+                np.asarray([idx], np.int32))
         except Exception:
             with self._cond:
                 self.allocator.free(blocks)
@@ -1565,7 +1615,7 @@ class TpuEngine:
         # the established lanes has been dispatched, hiding the readback
         # behind device work.
         slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
-                     position=len(prompt), generated=[], last_token=-1,
+                     position=len(prompt), generated=[],
                      cached_tokens=cached_tokens, pending_tok=tok_dev,
                      prompt_len=len(prompt))
         n_complete = len(prompt) // block
@@ -1582,32 +1632,39 @@ class TpuEngine:
         self.telemetry.running.set(sum(s is not None for s in self.slots))
 
     def _finalize_prefills(self):
-        """Land pending first tokens (device transfer has had the decode
-        chunk's execution time to complete) and emit/finish accordingly."""
-        for idx, slot in enumerate(self.slots):
-            if slot is None or slot.pending_tok is None or slot.prefilling:
-                continue
-            tok = int(np.asarray(slot.pending_tok)[slot.pending_idx])
-            slot.pending_tok = None
-            slot.generated = [tok]
-            slot.last_token = tok
-            req = slot.req
-            self._observe_first_token(req)
-            self.telemetry.generation_tokens.inc()
+        """Land pending first tokens and emit/finish accordingly. Reading
+        them blocks until their prefills are done, with the chunk in flight
+        queued behind those: a wait on a busy device, booked as decode_wait
+        so that finalize_prefills stays host work."""
+        pending = [(idx, slot) for idx, slot in enumerate(self.slots)
+                   if slot is not None and slot.pending_tok is not None
+                   and not slot.prefilling]
+        if not pending:
+            return
+        with self._phase("decode_wait"):
+            landed = [int(self._read_tokens(slot.pending_tok)[slot.pending_idx])
+                      for _, slot in pending]
+        with self._phase("finalize_prefills"):
+            for (idx, slot), tok in zip(pending, landed):
+                slot.pending_tok = None
+                slot.generated = [tok]
+                req = slot.req
+                self._observe_first_token(req)
+                self.telemetry.generation_tokens.inc()
 
-            # Remote-decode prefill: hand KV off instead of decoding here.
-            ktp = req.kv_transfer_params or {}
-            if ktp.get("do_remote_decode"):
-                self._finish_slot(idx, FinishReason.LENGTH,
-                                  retain_for_transfer=True, first_token=tok)
-                continue
-            self._emit(slot, TokenEvent(
-                request_id=req.request_id, token_id=tok,
-                text=self.tokenizer.decode([tok]), is_first=True,
-                prompt_tokens=slot.prompt_len, completion_tokens=1,
-                cached_tokens=slot.cached_tokens))
-            slot.first_emitted = True
-            self._maybe_finish_after_token(idx, tok)
+                # Remote-decode prefill: hand KV off instead of decoding here.
+                ktp = req.kv_transfer_params or {}
+                if ktp.get("do_remote_decode"):
+                    self._finish_slot(idx, FinishReason.LENGTH,
+                                      retain_for_transfer=True, first_token=tok)
+                    continue
+                self._emit(slot, TokenEvent(
+                    request_id=req.request_id, token_id=tok,
+                    text=self.tokenizer.decode([tok]), is_first=True,
+                    prompt_tokens=slot.prompt_len, completion_tokens=1,
+                    cached_tokens=slot.cached_tokens))
+                slot.first_emitted = True
+                self._maybe_finish_after_token(idx, tok)
 
     def _observe_first_token(self, req: EngineRequest) -> None:
         """A first token has landed on the host: TTFT from the request's
@@ -1739,6 +1796,9 @@ class TpuEngine:
         req = s.req
         row = np.zeros((1, self.max_blocks_per_seq), np.int32)
         row[0, : len(s.blocks)] = s.blocks
+        # The last window's sample is the slot's first token; the others'
+        # are nobody's.
+        slots = np.asarray([idx if last else self.cfg.max_batch], np.int32)
         try:
             if written == 0:
                 bucket = self._bucket(len(window))
@@ -1747,7 +1807,7 @@ class TpuEngine:
                 tok_dev = self._device_call(("prefill", bucket), dict(
                     tokens=tokens,
                     seq_len=np.asarray([len(window)], np.int32),
-                    row=row, **self._sample_np([req])))
+                    row=row, slots=slots, **self._sample_np([req])))
             else:
                 # Continuation window: gather the already-written prefix
                 # from its (block-aligned) pages, scatter this window at
@@ -1767,7 +1827,7 @@ class TpuEngine:
                         tokens=tokens,
                         suffix_len=np.asarray([len(window)], np.int32),
                         prefix_len=np.asarray([written], np.int32),
-                        row=row, prior=prior,
+                        row=row, prior=prior, slots=slots,
                         **self._sample_np([req])))
         except Exception:
             self.slots[idx] = None
@@ -1808,10 +1868,11 @@ class TpuEngine:
                 self.kv_events.stored(s.block_hashes)
 
     def _run_prefill_compute(self, req, prompt, suffix, cached_tokens,
-                             matched_bids, row):
+                             matched_bids, row, slots):
         """Dispatch the fused prefill+first-token jit; returns the sampled
         token as a DEVICE array ([1] i32) with its host transfer already
-        started — _finalize_prefills lands it."""
+        started — _finalize_prefills lands it. ``slots`` names the slot the
+        token is kept for on the device (_op_keep_tokens)."""
         if req.mm_embeds is not None:
             bucket = self._bucket(len(prompt))
             tokens = np.zeros((1, bucket), np.int32)
@@ -1831,7 +1892,7 @@ class TpuEngine:
             pos_pad[0, : mm.shape[0]] = positions[: mm.shape[0]]
             return self._device_call(("mm_prefill", bucket, mm_bucket), dict(
                 tokens=tokens, seq_len=np.asarray([len(prompt)], np.int32),
-                mm_pad=mm_pad, pos_pad=pos_pad, row=row,
+                mm_pad=mm_pad, pos_pad=pos_pad, row=row, slots=slots,
                 **self._sample_np([req])))
         if matched_bids:
             bucket = self._bucket(len(suffix))
@@ -1847,7 +1908,7 @@ class TpuEngine:
                                     dict(tokens=tokens,
                                          suffix_len=np.asarray([len(suffix)], np.int32),
                                          prefix_len=np.asarray([cached_tokens], np.int32),
-                                         row=row, prior=prior,
+                                         row=row, prior=prior, slots=slots,
                                          **self._sample_np([req])))
             self.telemetry.prefix_cached_tokens.inc(cached_tokens)
         else:
@@ -1856,7 +1917,7 @@ class TpuEngine:
             tokens[0, : len(prompt)] = prompt
             tok = self._device_call(("prefill", bucket), dict(
                 tokens=tokens, seq_len=np.asarray([len(prompt)], np.int32),
-                row=row, **self._sample_np([req])))
+                row=row, slots=slots, **self._sample_np([req])))
         return tok
 
     # ---- P/D import (decode side) --------------------------------------
@@ -2246,8 +2307,13 @@ class TpuEngine:
         first = int(ktp.get("remote_first_token")
                     if ktp.get("remote_first_token") is not None
                     else headers["x-kv-first-token"])
+        # The host holds this slot's first token: the chunk wants it on the
+        # device.
+        self._device_call(("keep_tokens",), dict(
+            slots=np.asarray([idx], np.int32),
+            toks=np.asarray([first], np.int32)))
         slot = _Slot(req=req, out=pi.out, loop=pi.loop, blocks=blocks,
-                     position=seq_len, generated=[first], last_token=first,
+                     position=seq_len, generated=[first],
                      cached_tokens=seq_len)
         hashes = chain_block_hashes(self.model_name,
                                     req.prompt_token_ids[:seq_len], "",
@@ -2336,7 +2402,7 @@ class TpuEngine:
         Ops with no per-shape jit variant (release/stage plumbing) are None."""
         kind = op[0]
         if kind == "decode":
-            return ("decode", f"{len(args['tokens'])}x{args['tables'].shape[1]}")
+            return ("decode", f"{len(args['slots'])}x{args['tables'].shape[1]}")
         if kind == "prefill":
             return ("prefill", f"{args['tokens'].shape[0]}x{op[1]}")
         if kind == "prefix_prefill":
@@ -2353,22 +2419,22 @@ class TpuEngine:
         key = self._op_shape_key(op, args)
         if key is None:
             return self._exec_op(op, args)
+        decode = op[0] == "decode"
+        # Rows (padded tokens) of one step of this program, and its steps.
+        rows = args["slots" if decode else "tokens"].size
+        steps = self.cfg.decode_chunk if decode else 1
         if self.mcfg.n_experts:
-            # Rows this program puts through the MoE FFN (padded; a decode
-            # chunk's lanes once a step), under the form its shape traced to.
-            rows = args["tokens"].size
+            # What it puts through the MoE FFN, under the form its shape
+            # traced to.
             self.telemetry.moe_ffn_tokens.labels(
                 form="grouped" if self._moe_grouped(rows) else "dense").inc(
-                    rows * (self.cfg.decode_chunk if op[0] == "decode" else 1))
+                    rows * steps)
         if self.geom.latent_dim:
-            # Rows this program puts through latent attention, under the form
-            # its kind traced to (models/mla.py: one query a sequence is
-            # absorbed, a run of them expanded).
-            decode = op[0] == "decode"
+            # What it puts through latent attention, under the form its kind
+            # traced to (models/mla.py: one query a sequence is absorbed, a
+            # run of them expanded).
             self.telemetry.mla_attention_tokens.labels(
-                form="absorbed" if decode else "expanded").inc(
-                    args["tokens"].size
-                    * (self.cfg.decode_chunk if decode else 1))
+                form="absorbed" if decode else "expanded").inc(rows * steps)
         t0 = time.monotonic()
         result = self._exec_op(op, args)
         dt = time.monotonic() - t0
@@ -2377,8 +2443,8 @@ class TpuEngine:
             self.telemetry.compile_events.labels(op=key[0], bucket=key[1]).inc()
             self.telemetry.compile_duration.observe(dt)
         elif key[0] in ("prefill", "prefix_prefill", "mm_prefill"):
-            # Dispatch wall time (the decode chunk's full dispatch→readback
-            # window is measured in _decode_once instead, where the sync is).
+            # Dispatch wall time (the decode chunk's own wall time is
+            # measured in _land_chunk instead, where the sync is).
             self.telemetry.prefill_step.observe(dt)
         return result
 
@@ -2394,6 +2460,8 @@ class TpuEngine:
             return self._op_mm_prefill(op[1], op[2], **args)
         if kind == "import":
             return self._op_import(**args)
+        if kind == "keep_tokens":
+            return self._op_keep_tokens(**args)
         if kind == "stage_kv":
             return self._op_stage_kv(**args)
         if kind == "release_kv_export":
@@ -2598,9 +2666,11 @@ class TpuEngine:
         k_dev.block_until_ready()
         return k_dev, v_dev
 
-    def _op_decode(self, tokens, positions, tables, temps, top_k, top_p,
+    def _op_decode(self, slots, positions, tables, temps, top_k, top_p,
                    warm=False):
-        args = (self.params, self._put(tokens), self._put(positions),
+        slots = self._put(slots)
+        args = (self.params, self._jit_slot_tokens(self._slot_tokens, slots),
+                self._put(positions),
                 self.k_pages, self.v_pages, self._put(tables),
                 self._next_key(warm), self._put(temps), self._put(top_k),
                 self._put(top_p))
@@ -2609,47 +2679,57 @@ class TpuEngine:
             # The resolved flag says what was asked for; the lowered text
             # says what the program holds. One extra lowering per shape,
             # beside that shape's compile.
-            shape = f"{len(tokens)}x{tables.shape[1]}"
+            shape = f"{len(positions)}x{tables.shape[1]}"
             if shape not in self.decode_kernel_in_program:
                 self.decode_kernel_in_program[shape] = (
                     "tpu_custom_call"
                     in self._jit_decode_chunk.lower(*args).as_text())
         toks, self.k_pages, self.v_pages = self._jit_decode_chunk(*args)
+        return self._op_keep_tokens(slots, toks)
+
+    def _op_keep_tokens(self, slots, toks):
+        """Leave sampled tokens where the next chunk finds them: row i of
+        ``toks`` ([N], or the last row of a chunk's [K, N]) is slot
+        ``slots[i]``'s newest token (max_batch: nobody's). Its own op for a
+        token the host holds (an import's first); the tail of every op that
+        samples. Starts the tokens' copy to the host."""
+        slots, toks = (x if isinstance(x, jax.Array) else self._put(x)
+                       for x in (slots, toks))
+        self._slot_tokens = self._jit_keep_tokens(self._slot_tokens, slots,
+                                                  toks)
+        toks.copy_to_host_async()
         return toks
 
-    def _op_prefill(self, bucket, tokens, seq_len, row, temps, top_k, top_p,
-                    warm=False):
+    def _op_prefill(self, bucket, tokens, seq_len, row, slots, temps, top_k,
+                    top_p, warm=False):
         fn = self._prefill_fn(bucket)
         tok, self.k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(seq_len),
             self.k_pages, self.v_pages, self._put(row),
             self._next_key(warm), self._put(temps), self._put(top_k),
             self._put(top_p))
-        tok.copy_to_host_async()
-        return tok
+        return self._op_keep_tokens(slots, tok)
 
     def _op_prefix_prefill(self, suffix_bucket, prefix_bucket, tokens,
-                           suffix_len, prefix_len, row, prior, temps, top_k,
-                           top_p, warm=False):
+                           suffix_len, prefix_len, row, prior, slots, temps,
+                           top_k, top_p, warm=False):
         fn = self._prefix_prefill_fn(suffix_bucket, prefix_bucket)
         tok, self.k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(suffix_len),
             self._put(prefix_len), self.k_pages, self.v_pages,
             self._put(row), self._put(prior), self._next_key(warm),
             self._put(temps), self._put(top_k), self._put(top_p))
-        tok.copy_to_host_async()
-        return tok
+        return self._op_keep_tokens(slots, tok)
 
     def _op_mm_prefill(self, bucket, mm_bucket, tokens, seq_len, mm_pad,
-                       pos_pad, row, temps, top_k, top_p):
+                       pos_pad, row, slots, temps, top_k, top_p):
         fn = self._mm_prefill_fn(bucket, mm_bucket)
         tok, self.k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(seq_len),
             self._put(mm_pad), self._put(pos_pad), self.k_pages,
             self.v_pages, self._put(row), self._next_key(False),
             self._put(temps), self._put(top_k), self._put(top_p))
-        tok.copy_to_host_async()
-        return tok
+        return self._op_keep_tokens(slots, tok)
 
     def _op_import(self, blocks_pad, k_pad, v_pad):
         self.k_pages, self.v_pages = self._jit_import(
@@ -2699,57 +2779,96 @@ class TpuEngine:
                 return w
         return self.max_blocks_per_seq
 
-    def _decode_once(self):
+    def _read_tokens(self, toks) -> np.ndarray:
+        """Sampled tokens to the host: the loop's one blocking read of the
+        device, for a chunk's tokens and for a prefill's first."""
+        return np.asarray(toks)
+
+    def _decode_lanes(self) -> list[tuple[int, _Slot]]:
+        """The slots the next chunk decodes: every one that has a token, on
+        the host or still on the device (a prefill dispatched this step or
+        before, a chunk in flight), and that the host cannot tell will have
+        ended before the chunk's first step: on max_tokens or the context
+        limit inside the steps already dispatched. A stop token it cannot
+        foresee: that lane's chunk is thrown away when it turns up."""
+        lanes = []
+        for i, s in enumerate(self.slots):
+            if (s is None or s.prefilling or (s.req.kv_transfer_params
+                                              or {}).get("do_remote_decode")):
+                continue
+            generated = len(s.generated) if s.pending_tok is None else 1
+            if (generated + s.ahead < s.req.max_tokens
+                    and s.position + s.ahead + 1 < self.cfg.max_model_len):
+                lanes.append((i, s))
+        return lanes
+
+    def _dispatch_chunk(self) -> _Chunk | None:
+        """Dispatch the next chunk for the lanes that have one coming, on top
+        of whatever is in flight; None when no lane has."""
         with self._phase("decode_prepare"):
-            active = [i for i, s in enumerate(self.slots)
-                      if s is not None and s.pending_tok is None
-                      and not s.prefilling]
-            B = self._batch_bucket(len(active))
-            W = self._ctx_bucket(max((len(self.slots[i].blocks)
-                                      for i in active), default=1))
-            tokens = np.zeros((B,), np.int32)
+            lanes = self._decode_lanes()
+            if not lanes:
+                return None
+            B = self._batch_bucket(len(lanes))
+            W = self._ctx_bucket(max(len(s.blocks) for _, s in lanes))
+            # Compact the lanes into the low rows; padding rows are nobody's
+            # (token 0) and keep their block table at the trash block 0
+            # (their KV writes land there).
+            slots = np.full((B,), self.cfg.max_batch, np.int32)
             positions = np.zeros((B,), np.int32)
             tables = np.zeros((B, W), np.int32)
-            # Compact active slots into the low lanes; padding lanes keep
-            # their block table at the trash block 0 (their KV writes land
-            # there).
-            for lane, i in enumerate(active):
-                s = self.slots[i]
-                tokens[lane] = s.last_token
-                positions[lane] = s.position
+            for lane, (i, s) in enumerate(lanes):
+                slots[lane] = i
+                positions[lane] = s.position + s.ahead
                 tables[lane, : len(s.blocks)] = s.blocks
-
-            reqs = [self.slots[i].req for i in active]
+            reqs = [s.req for _, s in lanes]
             reqs += [_DUMMY_REQ] * (B - len(reqs))
             self.telemetry.batch_fill.set(
-                len(active) / max(self.cfg.max_batch, 1))
-            was_compiled = (("decode", f"{B}x{W}") in self._seen_op_shapes)
-            args = dict(tokens=tokens, positions=positions, tables=tables,
+                len(lanes) / max(self.cfg.max_batch, 1))
+            timed = ("decode", f"{B}x{W}") in self._seen_op_shapes
+            args = dict(slots=slots, positions=positions, tables=tables,
                         **self._sample_np(reqs))
-        t0 = time.monotonic()
+        t0 = self._clock()
         with self._phase("decode_dispatch"):
             toks = self._device_call(("decode",), args)
-        with self._phase("decode_wait"):
-            sampled = np.asarray(toks)  # [K, B] — ONE readback per chunk
-        if was_compiled:
-            # Full chunk wall time (dispatch through readback); the first
-            # call per shape goes to the compile histogram instead.
-            self.telemetry.decode_step.observe(time.monotonic() - t0)
-        with self._phase("decode_book"):
-            self._book_chunk(active, sampled)
+        self.telemetry.decode_chunks[
+            "alone" if self._inflight is None else "ahead"].inc()
+        for _, s in lanes:
+            s.ahead += self.cfg.decode_chunk
+        return _Chunk(toks=toks, lanes=lanes, t0=t0, timed=timed)
 
-    def _book_chunk(self, active: list[int], sampled: np.ndarray) -> None:
+    def _land_chunk(self, chunk: _Chunk) -> None:
+        """Read a chunk's tokens (ONE readback a chunk) and book them."""
+        with self._phase("decode_wait"):
+            sampled = self._read_tokens(chunk.toks)  # [K, B]
+        now = self._clock()
+        if chunk.timed:
+            # The chunk's own wall time: it could not start before the chunk
+            # ahead of it was done, which the host saw at that one's
+            # readback. The first call of a shape goes to the compile
+            # histogram instead.
+            self.telemetry.decode_step.observe(
+                now - max(chunk.t0, self._last_readback))
+        self._last_readback = now
+        with self._phase("decode_book"):
+            self._book_chunk(chunk.lanes, sampled)
+
+    def _book_chunk(self, lanes: list[tuple[int, _Slot]],
+                    sampled: np.ndarray) -> None:
         """Apply one chunk's sampled tokens [K, B] lane by lane, up to each
-        request's stop condition."""
-        for lane, i in enumerate(active):
+        request's stop condition. A lane whose slot is gone, or holds another
+        request by now, ended while this chunk was in flight."""
+        for lane, (i, s) in enumerate(lanes):
+            s.ahead -= sampled.shape[0]
+            if self.slots[i] is not s:
+                self.telemetry.decode_lanes_discarded.inc()
+                continue
             for step in range(sampled.shape[0]):
                 if self.slots[i] is None:
                     break  # stop/length hit mid-chunk; overshoot discarded
-                s = self.slots[i]
                 tok = int(sampled[step, lane])
                 s.position += 1
                 s.generated.append(tok)
-                s.last_token = tok
                 self.telemetry.generation_tokens.inc()
                 if tok not in self._stop_ids(s.req):
                     self._emit(s, TokenEvent(

@@ -23,9 +23,11 @@ KV_USAGE = "jetstream:kv_cache_usage_perc"
 LORA_INFO = "jetstream:lora_requests_info"
 CACHE_CONFIG = "jetstream:cache_config_info"
 
-# The engine loop's phases, in loop order (engine/core.py `_phase`). Each is
-# a host span `engine.<phase>` in a profiler trace and a label of
-# `jetstream:engine_loop_seconds_total`; they never nest.
+# The engine loop's phases (engine/core.py `_phase`). Each is a host span
+# `engine.<phase>` in a profiler trace and a label of
+# `jetstream:engine_loop_seconds_total`; they never nest. `decode_wait` is the
+# loop blocked on the device: on the tokens of the chunk before the one in
+# flight, or on a prefill's first token (with a chunk queued behind it).
 LOOP_PHASES = ("housekeeping", "admit", "advance_prefills", "decode_prepare",
                "decode_dispatch", "decode_wait", "decode_book",
                "finalize_prefills", "idle_wait")
@@ -133,7 +135,9 @@ class EngineTelemetry:
             buckets=(.001, .0025, .005, .01, .025, .05, .1, .25, .5, 1, 2.5))
         self.decode_step = Histogram(
             "jetstream:decode_step_duration_seconds",
-            "Wall time of one fused decode chunk (dispatch through readback)",
+            "Wall time of one fused decode chunk: from its dispatch, or from "
+            "the readback of the chunk before it where that came later (the "
+            "chunk was queued behind it), through its own readback",
             registry=self.registry,
             buckets=(.002, .005, .01, .025, .05, .1, .25, .5, 1, 2.5))
         self.compile_events = Counter(
@@ -160,6 +164,18 @@ class EngineTelemetry:
             "absorbed, a prefill or a prefix-continuation window expanded); "
             "counted on the host at dispatch, empty without a latent pool",
             ("form",), registry=self.registry)
+        decode_chunks = Counter(
+            "jetstream:decode_chunks_total",
+            "Decode chunks dispatched: `ahead` while the chunk before was "
+            "still unread (the device has its next work queued), `alone` "
+            "with nothing in flight", ("dispatch",), registry=self.registry)
+        self.decode_chunks = {d: decode_chunks.labels(dispatch=d)
+                              for d in ("ahead", "alone")}
+        self.decode_lanes_discarded = Counter(
+            "jetstream:decode_lanes_discarded_total",
+            "Lanes of a chunk thrown away whole: the request ended (a stop "
+            "token, an abort) in the chunk before, with this one in flight",
+            registry=self.registry)
         self.prompt_tokens = Counter("jetstream:prompt_tokens_total", "Prefilled tokens",
                                      registry=self.registry)
         self.prefix_cached_tokens = Counter(

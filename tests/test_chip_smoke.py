@@ -145,6 +145,7 @@ def _prefill_args(eng):
     return dict(tokens=np.ones((1, 16), np.int32),
                 seq_len=np.asarray([4], np.int32),
                 row=np.zeros((1, eng.max_blocks_per_seq), np.int32),
+                slots=np.full((1,), eng.cfg.max_batch, np.int32),  # nobody's
                 temps=np.zeros((1,), np.float32),
                 top_k=np.zeros((1,), np.int32),
                 top_p=np.ones((1,), np.float32))

@@ -574,3 +574,246 @@ def test_note_kv_import_dedupes_eviction_ring():
         TpuEngine._note_kv_import(s, "r1", _time.monotonic(), 10, "host")
     assert len(s._kv_import_order) == 1
     assert s.kv_import_stats["r1"]["bytes"] == 10
+
+
+# ---------- one chunk in flight (ISSUE 33) ----------
+# The engine dispatches chunk n + 1 before it reads chunk n. These tests drive
+# TpuEngine._step() from their own thread (the engine's thread never starts),
+# so every run takes the same steps, and compare what is served with what the
+# parent commit (b0e8642: dispatch, wait, book, only then the next step)
+# served for the same requests under the same driver. _PARENT_SERVED holds
+# that commit's answers, as `python tests/test_engine.py` prints them when run
+# with PYTHONPATH at a checkout of it (XLA_FLAGS and the platform as
+# tests/conftest.py sets them); _STOP holds the stop tokens, which are tokens
+# that the parent served in those places without a stop.
+
+_CHUNK = 4
+
+
+def _tiny_f32():
+    import jax
+    import jax.numpy as jnp
+
+    from llm_d_inference_scheduler_tpu.models import llama
+    from llm_d_inference_scheduler_tpu.models.configs import get_config
+
+    return llama.init_params(get_config("tiny"), jax.random.key(11),
+                             dtype=jnp.float32)
+
+
+def _prompt(salt, n):
+    return [1] + [(j * salt) % 450 + 3 for j in range(n - 1)]
+
+
+def _req(rid, prompt, max_tokens, temperature, stop=None):
+    return EngineRequest(
+        request_id=rid, prompt_token_ids=prompt, max_tokens=max_tokens,
+        temperature=temperature, ignore_eos=True,
+        stop_token_ids=() if stop is None else (stop,))
+
+
+def _by_hand(requests, *, submit_at=None, abort_at=None, **cfg):
+    """Serve ``requests`` by calling _step() by hand. ``submit_at`` /
+    ``abort_at``: request id -> the step before which it is submitted (0
+    unless named) / aborted. Returns (tokens by id, finish reason by id, the
+    engine)."""
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    submit_at, abort_at = submit_at or {}, abort_at or {}
+    cfg.setdefault("max_batch", 4)
+
+    async def body():
+        eng = TpuEngine(EngineConfig(
+            model="tiny", backend="tpu", max_model_len=128,
+            decode_chunk=_CHUNK, seed=11, kv_events_port=0, **cfg),
+            params=_tiny_f32())
+        outs, toks, why = {}, {}, {}
+        for step in range(400):
+            for r in requests:
+                if submit_at.get(r.request_id, 0) == step:
+                    outs[r.request_id] = eng.submit(r)
+                    toks[r.request_id] = []
+                if abort_at.get(r.request_id) == step:
+                    eng.abort(r.request_id)
+            eng._step()
+            await asyncio.sleep(0)      # the events hop onto this loop
+            for rid, out in outs.items():
+                while not out.empty():
+                    ev = out.get_nowait()
+                    if ev.token_id is not None:
+                        toks[rid].append(ev.token_id)
+                    if ev.finish_reason is not None:
+                        why[rid] = ev.finish_reason.value
+            if (len(why) == len(requests)
+                    and getattr(eng, "_inflight", None) is None):
+                return toks, why, eng
+        raise AssertionError(f"not served in 400 steps: {why}")
+
+    return asyncio.run(body())
+
+
+def _mixed(t, stop, late=False):
+    """Four requests on four lanes from step 0. D, in the top lane, ends on a
+    stop token in its second chunk while A, B and C go on (so the chunk in
+    flight, with D's lane dead in it, has the parent's shape and the parent's
+    lanes 0 to 2); A and B end on max_tokens in the middle of a chunk, C at
+    the end of one. ``late``: E arrives before step 3 and takes D's slot when
+    it is free (greedy only: a lane joins the chunk dispatched right behind
+    its prefill, the parent's joined the one after, and a sampled token
+    depends on which chunk's key drew it)."""
+    reqs = [_req("A", _prompt(5, 9), 11, t), _req("B", _prompt(7, 20), 18, t),
+            _req("C", _prompt(11, 33), 25, t),
+            _req("D", _prompt(13, 14), 40, t, stop)]
+    if late:
+        reqs.append(_req("E", _prompt(17, 12), 9, t))
+    return dict(requests=reqs, submit_at={"E": 3})
+
+
+def _first_is_stop(t, stop):
+    """F's first token, the prefill's, is its stop token: the chunk
+    dispatched behind that prefill carries F's lane for nothing. G decodes
+    beside it."""
+    return dict(requests=[_req("G", _prompt(19, 10), 10, t),
+                          _req("F", _prompt(23, 21), 12, t, stop)])
+
+
+def _aborted(t, stop=None):
+    """D, in the top lane, is aborted before step 3, with a chunk that holds
+    its lane in flight."""
+    plan = _mixed(t, None)
+    plan["abort_at"] = {"D": 3}
+    return plan
+
+
+def _reused(t, stop, **cfg):
+    """Two lanes, nine usable blocks, prefix caching on. A (4 blocks) ends on
+    a stop token with a chunk in flight whose overshoot lands in A's tail
+    blocks; C, waiting with A's prompt, is admitted next, hits A's two parked
+    prompt blocks and takes freed ones for its tail, its prefill queued
+    behind that chunk; E follows with another prompt. B decodes throughout."""
+    shared = _prompt(29, 40)
+    return dict(requests=[_req("A", shared, 24, t, stop),
+                          _req("B", _prompt(31, 37), 27, t),
+                          _req("C", shared, 24, t),
+                          _req("E", _prompt(37, 35), 13, t)],
+                max_batch=2, hbm_kv_blocks=10, enable_prefix_caching=True,
+                **cfg)
+
+
+_PLANS = {"mixed": _mixed,
+          "mixed-late": lambda t, stop: _mixed(t, stop, late=True),
+          "first-is-stop": _first_is_stop, "aborted": _aborted,
+          "reused": _reused}
+# (plan, temperature) -> whose token is the stop token there, and which: the
+# token the parent served in that place when nothing stopped it.
+_STOP_AT = {("mixed", 0.0): ("D", 6), ("mixed", 0.8): ("D", 6),
+            ("mixed-late", 0.0): ("D", 6), ("first-is-stop", 0.0): ("F", 0),
+            ("first-is-stop", 0.8): ("F", 0), ("aborted", 0.0): None,
+            ("aborted", 0.8): None, ("reused", 0.0): ("A", 7)}
+_STOP = {('first-is-stop', 0.0): 187,
+ ('first-is-stop', 0.8): 4,
+ ('mixed', 0.0): 213,
+ ('mixed', 0.8): 166,
+ ('mixed-late', 0.0): 213,
+ ('reused', 0.0): 378}
+_PARENT_SERVED = {('aborted', 0.0): ({'A': [499, 364, 339, 18, 183, 8, 391, 339, 80, 466, 159],
+                     'B': [343, 244, 303, 280, 115, 489, 268, 511, 255, 476, 3, 464, 16, 22, 357,
+                           327, 445, 327],
+                     'C': [141, 21, 280, 66, 257, 65, 324, 253, 102, 280, 64, 280, 292, 246, 219,
+                           334, 395, 479, 56, 6, 416, 218, 342, 429, 187],
+                     'D': [92, 97, 489, 174, 292, 259, 213, 184, 78]},
+                    {'A': 'length', 'B': 'length', 'C': 'length', 'D': 'abort'}),
+ ('aborted', 0.8): ({'A': [461, 19, 351, 481, 465, 377, 294, 378, 309, 210, 378],
+                     'B': [359, 282, 50, 167, 489, 394, 163, 370, 285, 343, 201, 406, 110, 451, 209,
+                           105, 99, 424],
+                     'C': [48, 245, 494, 375, 195, 123, 36, 97, 451, 96, 294, 114, 369, 283, 374,
+                           280, 311, 323, 479, 285, 311, 91, 143, 83, 508],
+                     'D': [264, 285, 345, 472, 405, 140, 166, 330, 315]},
+                    {'A': 'length', 'B': 'length', 'C': 'length', 'D': 'abort'}),
+ ('first-is-stop', 0.0): ({'F': [187], 'G': [118, 212, 204, 97, 76, 280, 312, 292, 280, 118]},
+                          {'F': 'stop', 'G': 'length'}),
+ ('first-is-stop', 0.8): ({'F': [4], 'G': [477, 219, 424, 108, 125, 199, 25, 457, 314, 19]},
+                          {'F': 'stop', 'G': 'length'}),
+ ('mixed', 0.0): ({'A': [499, 364, 339, 18, 183, 8, 391, 339, 80, 466, 159],
+                   'B': [343, 244, 303, 280, 115, 489, 268, 511, 255, 476, 3, 464, 16, 22, 357, 327,
+                         445, 327],
+                   'C': [141, 21, 280, 66, 257, 65, 324, 253, 102, 280, 64, 280, 292, 246, 219, 334,
+                         395, 479, 56, 6, 416, 218, 342, 429, 187],
+                   'D': [92, 97, 489, 174, 292, 259]},
+                  {'A': 'length', 'B': 'length', 'C': 'length', 'D': 'stop'}),
+ ('mixed', 0.8): ({'A': [461, 19, 351, 481, 465, 377, 294, 378, 309, 210, 378],
+                   'B': [359, 282, 50, 167, 489, 394, 163, 370, 285, 343, 201, 406, 110, 451, 209,
+                         105, 99, 424],
+                   'C': [48, 245, 494, 375, 195, 123, 36, 97, 451, 96, 294, 114, 369, 283, 374, 280,
+                         311, 323, 479, 285, 311, 91, 143, 83, 508],
+                   'D': [264, 285, 345, 472, 405, 140]},
+                  {'A': 'length', 'B': 'length', 'C': 'length', 'D': 'stop'}),
+ ('mixed-late', 0.0): ({'A': [499, 364, 339, 18, 183, 8, 391, 339, 80, 466, 159],
+                        'B': [343, 244, 303, 280, 115, 489, 268, 511, 255, 476, 3, 464, 16, 22, 357,
+                              327, 445, 327],
+                        'C': [141, 21, 280, 66, 257, 65, 324, 253, 102, 280, 64, 280, 292, 246, 219,
+                              334, 395, 479, 56, 6, 416, 218, 342, 429, 187],
+                        'D': [92, 97, 489, 174, 292, 259],
+                        'E': [190, 219, 389, 364, 462, 279, 462, 81, 213]},
+                       {'A': 'length', 'B': 'length', 'C': 'length', 'D': 'stop', 'E': 'length'}),
+ ('reused', 0.0): ({'A': [257, 113, 458, 111, 249, 458, 111],
+                    'B': [437, 491, 437, 91, 177, 119, 48, 48, 177, 292, 451, 177, 257, 294, 177,
+                          153, 336, 81, 147, 247, 365, 199, 451, 48, 26, 219, 71],
+                    'C': [257, 113, 458, 111, 249, 458, 111, 378, 278, 223, 104, 499, 466, 18, 414,
+                          267, 507, 370, 156, 378, 195, 122, 104, 464],
+                    'E': [304, 188, 464, 407, 382, 327, 163, 150, 326, 374, 315, 487, 315]},
+                   {'A': 'stop', 'B': 'length', 'C': 'length', 'E': 'length'})}
+
+
+def _serve(plan, t, **cfg):
+    kw = _PLANS[plan](t, _STOP.get((plan, t)))
+    return _by_hand(**{**kw, **cfg})
+
+
+def _counter(eng, name, labels=None):
+    return eng.telemetry.registry.get_sample_value(name, labels)
+
+
+_CASES = [(*case, {}) for case in _STOP_AT] + [
+    # Tables narrowed to the live context: the overshoot of a lane two chunks
+    # past its end clamps to its row's last entry, its own block or the trash.
+    ("reused", 0.0, {"decode_ctx_buckets": True})]
+
+
+@pytest.mark.parametrize(
+    "plan, t, cfg", _CASES,
+    ids=[f"{plan}-{'greedy' if t == 0 else 'sampled'}{'-narrowed' * bool(cfg)}"
+         for plan, t, cfg in _CASES])
+def test_a_chunk_in_flight_serves_the_parents_tokens(plan, t, cfg):
+    toks, why, eng = _serve(plan, t, **cfg)
+    assert (toks, why) == _PARENT_SERVED[plan, t]
+    held = getattr(eng.allocator, "reusable_blocks", eng.allocator.free_blocks)
+    assert held == eng.n_blocks - 1                 # every block came back
+    # Back to back, every chunk but the first went out with one unread.
+    alone, ahead = (_counter(eng, "jetstream:decode_chunks_total",
+                             {"dispatch": d}) for d in ("alone", "ahead"))
+    assert alone == 1 and ahead >= 2
+    # A whole lane-chunk is thrown away for each request that a stop token
+    # or an abort ended with the next chunk in flight, and for no other.
+    assert _counter(eng, "jetstream:decode_lanes_discarded_total") == sum(
+        reason in ("stop", "abort") for reason in why.values()) == 1
+    if plan == "reused":
+        # C found A's prompt blocks as A's prefill left them, and decoded
+        # through blocks that A's overshoot had scribbled on.
+        assert toks["C"][:len(toks["A"])] == toks["A"]
+        assert _counter(eng, "jetstream:prefix_cached_tokens_total") == 32
+
+
+if __name__ == "__main__":
+    import pprint
+
+    _STOP.clear()
+    for case, at in _STOP_AT.items():
+        if at is not None:
+            stream = _serve(*case)[0][at[0]]
+            assert stream[at[1]] not in stream[:at[1]], (case, stream)
+            _STOP[case] = stream[at[1]]
+    print("_STOP = " + pprint.pformat(_STOP, compact=True, width=79))
+    print("_PARENT_SERVED = " + pprint.pformat(
+        {case: _serve(*case)[:2] for case in _STOP_AT},
+        compact=True, width=79))

@@ -7,7 +7,6 @@ every wait is bounded."""
 import asyncio
 import glob
 import os
-import time
 
 import httpx
 import jax
@@ -83,40 +82,80 @@ def test_wait_histograms_once_per_request_and_sum_to_ttft(backend, stream):
 
 # ---------- the loop's phases ----------
 
-async def _generate(eng, rid, max_tokens=6):
-    out = eng.submit(EngineRequest(request_id=rid, prompt_token_ids=[1, 7, 8, 9],
-                                   max_tokens=max_tokens, ignore_eos=True))
-    while True:
-        ev = await asyncio.wait_for(out.get(), timeout=60)
-        if ev.finish_reason is not None:
-            return
+_STEP_ORDER = ("housekeeping", "admit", "advance_prefills", "decode_prepare",
+               "decode_dispatch", "decode_wait", "decode_book", "decode_wait",
+               "finalize_prefills")
 
 
 def test_loop_seconds_cover_the_loops_wall_time_and_decode_wait_needs_a_chunk():
+    """The loop's accounting on a clock the test owns: it moves by one second
+    for every device op dispatched and by five for every blocking read of
+    the device, and stands still otherwise. _step() is called by hand, so
+    the phases are those of known steps: they never overlap and leave no
+    gap (their sum is the clock), a chunk is dispatched before the one in
+    flight is read and booked, a wait inside _finalize_prefills is
+    decode_wait's and not finalize_prefills', and decode_wait moves only in
+    a step that began with a chunk in flight or dispatched a prefill."""
     async def body():
-        eng = TpuEngine(_cfg("tpu", 0))
-        await eng.start()
-        try:
-            await asyncio.sleep(0.35)               # idle: only idle_wait moves
-            idle = _loop_seconds(eng.telemetry)
-            assert idle["idle_wait"] > 0.2
-            assert idle["decode_wait"] == idle["decode_book"] == 0.0
-            t0, before = time.monotonic(), sum(idle.values())
-            await asyncio.gather(_generate(eng, "a"), _generate(eng, "b"))
-            await asyncio.sleep(0.25)               # lets the last wait end
-            wall = time.monotonic() - t0
-            busy = _loop_seconds(eng.telemetry)
-            # A phase is added when it ends: one idle wait (0.1 s) may be
-            # open; between phases the loop runs a few lines, no more.
-            assert 0.9 * wall - 0.1 <= sum(busy.values()) - before <= wall + 0.01
-            assert busy["decode_wait"] > 0 and busy["decode_book"] > 0
-            assert busy["admit"] > 0 and busy["decode_dispatch"] > 0
-            await asyncio.sleep(0.3)                # idle again: no chunk runs
-            again = _loop_seconds(eng.telemetry)
-            assert again["decode_wait"] == busy["decode_wait"]
-            assert again["idle_wait"] > busy["idle_wait"]
-        finally:
-            await eng.stop()
+        eng = TpuEngine(_cfg("tpu", 0, kv_events_port=0))
+        now, ops, reads, order = [0.0], [], [0], []
+
+        def exec_op(op, args, real=eng._exec_op):
+            now[0] += 1.0
+            ops.append(op[0])
+            return real(op, args)
+
+        def read_tokens(toks, real=eng._read_tokens):
+            now[0] += 5.0
+            reads[0] += 1
+            return real(toks)
+
+        def phase(name, real=eng._phase):
+            order[-1].append(name)
+            return real(name)
+
+        eng._clock = lambda: now[0]
+        eng._exec_op, eng._read_tokens, eng._phase = exec_op, read_tokens, phase
+
+        def step():
+            order.append([])
+            before = _loop_seconds(eng.telemetry)["decode_wait"]
+            chunk, prefills = eng._inflight is not None, ops.count("prefill")
+            eng._step()
+            moved = _loop_seconds(eng.telemetry)["decode_wait"] - before
+            return moved, (chunk, ops.count("prefill") > prefills)
+
+        for _ in range(3):                   # nothing to do: nothing moves
+            assert step() == (0.0, (False, False))
+        assert now[0] == 0.0 and sum(_loop_seconds(eng.telemetry).values()) == 0.0
+
+        outs = [eng.submit(EngineRequest(
+            request_id=rid, prompt_token_ids=[1, 7, 8, 9 + i], max_tokens=10,
+            ignore_eos=True)) for i, rid in enumerate("ab")]
+        moved = [step() for _ in range(6)]
+        await asyncio.sleep(0)
+        assert all(out.qsize() == 11 for out in outs)     # 10 tokens, the end
+
+        # Step 1 had nothing to read but waited for the first tokens, in
+        # _finalize_prefills, with the first chunk queued behind the
+        # prefills; steps 2 to 4 read the chunk before the one they
+        # dispatched; the rest found nothing on the device.
+        assert [m for m, _ in moved] == [10.0, 5.0, 5.0, 5.0, 0.0, 0.0]
+        assert all((m > 0) == (chunk or first) for m, (chunk, first) in moved)
+        seconds = _loop_seconds(eng.telemetry)
+        assert seconds["decode_wait"] == 5.0 * reads[0] == 25.0
+        assert seconds["admit"] == ops.count("prefill") >= 1
+        assert seconds["decode_dispatch"] == ops.count("decode") == 3
+        assert {p for p, v in seconds.items() if v} == {
+            "admit", "decode_dispatch", "decode_wait"}
+        assert sum(seconds.values()) == now[0]            # no gap, no overlap
+        for names in order:
+            at = -1
+            for name in names:       # each step: a subsequence of the order
+                at = _STEP_ORDER.index(name, at + 1)
+        head = list(_STEP_ORDER[:5])
+        assert order[3] == head + ["decode_wait", "finalize_prefills"]
+        assert order[4] == head + ["decode_wait", "decode_book"]
 
     run(body())
 
