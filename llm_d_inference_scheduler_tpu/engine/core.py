@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kvcache import pages, wire
+from ..kvcache import pages, state as state_pool, wire
 from ..models import family
 from ..ops import pallas_moe
 from ..utils.hashing import chain_block_hashes
@@ -190,7 +190,11 @@ class TpuEngine:
         self.model = family(self.mcfg)
         self.geom = pages.PageGeometry.for_engine(
             self.mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
-        if self.geom.latent_dim:
+        # What the model's state-space layers keep a slot, beside the pages
+        # (kvcache/state.py); None for a model that keeps pages alone.
+        self.state_geom = state_pool.StateGeometry.for_engine(
+            self.mcfg, cfg.max_batch)
+        if self.geom.latent_dim or self.state_geom:
             self._refuse_beyond_one_chip()
         cfg.pallas_attention = pages.use_kernel(
             self.geom.shape[-1], asked=cfg.pallas_attention,
@@ -207,8 +211,10 @@ class TpuEngine:
         block = self.geom.block
         self.n_blocks = self.geom.n_blocks
         self.max_blocks_per_seq = self.geom.max_blocks_per_seq
+        # A cached block prefix is pages with no recurrent state to go with
+        # them: a model with state layers keeps no prefix cache.
         self.allocator = (PrefixCachingAllocator(self.n_blocks, block)
-                          if cfg.enable_prefix_caching
+                          if cfg.enable_prefix_caching and not self.state_geom
                           else BlockAllocator(self.n_blocks, block))
         self.telemetry = EngineTelemetry(block_size=block, num_blocks=self.n_blocks)
         self.telemetry.watch_xla_builds()
@@ -389,7 +395,14 @@ class TpuEngine:
         self.k_pages, self.v_pages = (
             pages.alloc(self.geom, sharding=pages.page_sharding(mesh))
             if mesh is not None
-            else pages.alloc(self.geom, device=self.device))
+            else pages.alloc(self.geom, device=self.device,
+                             state=self.state_geom))
+        # Counts of expert choices held here that step programs summed on
+        # the device (kvcache/state.py), oldest first, each with the choices
+        # its program made in all: booked once their programs are done
+        # (_note_pair_counts, behind a chunk's tokens), so that no read waits.
+        self._pair_counts: collections.deque[tuple[Any, int]] = (
+            collections.deque())
 
         self.warming = cfg.warmup  # cleared by the engine thread post-compile
         # Set by the engine thread when it cannot go on (a warm-up that
@@ -490,11 +503,20 @@ class TpuEngine:
         log.info("engine %s up: %s", self.engine_id,
                  json.dumps(self.describe()))
 
+    def _one_chip_cache(self) -> str | None:
+        """What this model keeps that lives on the unsharded one-chip engine
+        alone, in words; None for plain K/V pages."""
+        if self.geom.latent_dim:
+            return "a latent (MLA) page pool"
+        if self.state_geom:
+            return "a recurrent state pool beside its pages"
+        return None
+
     def _refuse_beyond_one_chip(self) -> None:
-        """A latent page pool lives on the unsharded one-chip engine: it has
-        no sharding rule, no stage split and no wire format yet (ROADMAP R8),
-        and its family's vision tower is not built. Asked for any of those,
-        say so now, by name, rather than serve something else."""
+        """A latent page pool, and a state pool beside the pages, live on the
+        unsharded one-chip engine: neither has a sharding rule, a stage split
+        or a wire format yet (ROADMAP R7, R8). Asked for any of those, say so
+        now, by name, rather than serve something else."""
         cfg = self.cfg
         asked = [f"{name}={value}" for name, value, plain in (
             ("tp_size", cfg.tp_size, 1), ("ep_size", cfg.ep_size, 1),
@@ -503,10 +525,10 @@ class TpuEngine:
             ("role", cfg.role, "both")) if value != plain]
         if asked:
             raise ValueError(
-                f"model {self.mcfg.name!r} keeps a latent (MLA) page pool, "
+                f"model {self.mcfg.name!r} keeps {self._one_chip_cache()}, "
                 f"which serves on one unsharded chip only: {', '.join(asked)} "
-                "is not supported (no sharding rule for a latent row, no "
-                "handoff of latent pages to another engine)")
+                "is not supported (no sharding rule for it, no handoff of it "
+                "to another engine)")
 
     def describe(self) -> dict[str, Any]:
         """What this engine bound and resolved — the start-up log line and
@@ -528,6 +550,21 @@ class TpuEngine:
                 # counted, and the whole pool's.
                 "kv_token_bytes": self.geom.token_bytes,
                 "kv_pool_bytes": self.geom.pool_bytes,
+                # What the state-space layers keep a slot and in all (0: the
+                # model keeps pages alone), and what such a model turns off.
+                "state_slot_bytes": (self.state_geom.slot_bytes
+                                     if self.state_geom else 0),
+                "state_pool_bytes": (self.state_geom.pool_bytes
+                                     if self.state_geom else 0),
+                "prefix_caching": isinstance(self.allocator,
+                                             PrefixCachingAllocator),
+                "off_for_state_layers": ([
+                    "prefix hits (a cached block prefix has no recurrent "
+                    "state to start from)",
+                    "kv events (no block is advertised to a router)",
+                    "tp/ep/pp and multi-process meshes",
+                    "roles other than both",
+                    "KV export and import"] if self.state_geom else []),
                 "decode_chunk": self.cfg.decode_chunk,
                 "pallas_attention": bool(self.cfg.pallas_attention),
                 "kv_wire": ("device" if self.kv_transfer_server is not None
@@ -592,7 +629,7 @@ class TpuEngine:
         self._moe_grouped = functools.partial(
             pallas_moe.use_grouped, n_experts=self.mcfg.n_experts,
             experts_per_token=self.mcfg.experts_per_token,
-            d_model=self.mcfg.d_model,
+            d_model=self.mcfg.moe_latent_dim or self.mcfg.d_model,
             d_ff=self.mcfg.moe_d_ff or self.mcfg.d_ff,
             platform=platform, interpret=cfg.pallas_interpret,
             sharded=(cfg.tp_size > 1 or cfg.ep_size > 1 or cfg.pp_size > 1
@@ -620,7 +657,7 @@ class TpuEngine:
                      key, temps, top_k, top_p):
                 logits, (k_new, v_new) = self.model.forward(
                     params, self._model_for(tokens.size), tokens,
-                    want_kv=True)
+                    want_kv=True, seq_len=seq_len)
                 k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
                 last = jnp.take_along_axis(
@@ -728,10 +765,10 @@ class TpuEngine:
 
     def submit(self, req: EngineRequest) -> asyncio.Queue:
         """Thread-safe enqueue; returns the per-request event queue."""
-        if self.geom.latent_dim and (req.kv_transfer_params
-                                     or req.mm_embeds is not None):
+        if self._one_chip_cache() and (req.kv_transfer_params
+                                       or req.mm_embeds is not None):
             raise ValueError(
-                f"model {self.mcfg.name!r} keeps a latent (MLA) page pool: "
+                f"model {self.mcfg.name!r} keeps {self._one_chip_cache()}: "
                 "KV handoff to or from another engine and multimodal "
                 "embeddings are not supported")
         out: asyncio.Queue = asyncio.Queue()
@@ -1405,7 +1442,8 @@ class TpuEngine:
         hashes = (chain_block_hashes(self.model_name, prompt, "",
                                      self.mcfg.kv_block_size)
                   if caching or
-                  (self.kv_events is not None and req.mm_embeds is None)
+                  (self.kv_events is not None and req.mm_embeds is None
+                   and not self.state_geom)
                   else [])
         return prompt, hashes, caching
 
@@ -1797,8 +1835,11 @@ class TpuEngine:
         row = np.zeros((1, self.max_blocks_per_seq), np.int32)
         row[0, : len(s.blocks)] = s.blocks
         # The last window's sample is the slot's first token; the others'
-        # are nobody's.
-        slots = np.asarray([idx if last else self.cfg.max_batch], np.int32)
+        # are nobody's. Where the slot also names the window's state
+        # (kvcache/state.py) every window is the slot's: the token an earlier
+        # one leaves there is overwritten before any chunk reads it.
+        slots = np.asarray([idx if last or self.state_geom
+                            else self.cfg.max_batch], np.int32)
         try:
             if written == 0:
                 bucket = self._bucket(len(window))
@@ -2435,6 +2476,16 @@ class TpuEngine:
             # run of them expanded).
             self.telemetry.mla_attention_tokens.labels(
                 form="absorbed" if decode else "expanded").inc(rows * steps)
+        if self.state_geom:
+            # What it puts through the state-space layers, under the form its
+            # kind traced to (models/hybrid.py: one position a sequence is
+            # the step form, a run of them the scan form), and the slots a
+            # first window starts afresh.
+            self.telemetry.ssm_tokens.labels(
+                form="step" if decode else "scan").inc(rows * steps)
+            if op[0] == "prefill" and not args.get("warm"):
+                self.telemetry.ssm_slot_prefills.inc(
+                    int(np.sum(args["slots"] < self.cfg.max_batch)))
         t0 = time.monotonic()
         result = self._exec_op(op, args)
         dt = time.monotonic() - t0
@@ -2668,10 +2719,13 @@ class TpuEngine:
 
     def _op_decode(self, slots, positions, tables, temps, top_k, top_p,
                    warm=False):
+        # (The cache takes the host's copy of the slots: what goes in with
+        # it is donated with it.)
+        cache = state_pool.at_slots(self.k_pages, slots)
         slots = self._put(slots)
         args = (self.params, self._jit_slot_tokens(self._slot_tokens, slots),
-                self._put(positions),
-                self.k_pages, self.v_pages, self._put(tables),
+                self._put(positions), cache, self.v_pages,
+                self._put(tables),
                 self._next_key(warm), self._put(temps), self._put(top_k),
                 self._put(top_p))
         if (self.cfg.pallas_attention and not self.cfg.pallas_interpret
@@ -2684,7 +2738,8 @@ class TpuEngine:
                 self.decode_kernel_in_program[shape] = (
                     "tpu_custom_call"
                     in self._jit_decode_chunk.lower(*args).as_text())
-        toks, self.k_pages, self.v_pages = self._jit_decode_chunk(*args)
+        toks, k_pages, self.v_pages = self._jit_decode_chunk(*args)
+        self._keep_cache(k_pages, slots.size * self.cfg.decode_chunk)
         return self._op_keep_tokens(slots, toks)
 
     def _op_keep_tokens(self, slots, toks):
@@ -2703,23 +2758,48 @@ class TpuEngine:
     def _op_prefill(self, bucket, tokens, seq_len, row, slots, temps, top_k,
                     top_p, warm=False):
         fn = self._prefill_fn(bucket)
-        tok, self.k_pages, self.v_pages = fn(
+        tok, k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(seq_len),
-            self.k_pages, self.v_pages, self._put(row),
+            state_pool.at_slots(self.k_pages, slots), self.v_pages,
+            self._put(row),
             self._next_key(warm), self._put(temps), self._put(top_k),
             self._put(top_p))
+        self._keep_cache(k_pages, tokens.size)
         return self._op_keep_tokens(slots, tok)
 
     def _op_prefix_prefill(self, suffix_bucket, prefix_bucket, tokens,
                            suffix_len, prefix_len, row, prior, slots, temps,
                            top_k, top_p, warm=False):
         fn = self._prefix_prefill_fn(suffix_bucket, prefix_bucket)
-        tok, self.k_pages, self.v_pages = fn(
+        tok, k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(suffix_len),
-            self._put(prefix_len), self.k_pages, self.v_pages,
+            self._put(prefix_len),
+            state_pool.at_slots(self.k_pages, slots), self.v_pages,
             self._put(row), self._put(prior), self._next_key(warm),
             self._put(temps), self._put(top_k), self._put(top_p))
+        self._keep_cache(k_pages, tokens.size)
         return self._op_keep_tokens(slots, tok)
+
+    def _keep_cache(self, k_pages, rows: int) -> None:
+        """Keep the cache a step returned. Where it carries a count of the
+        expert choices held here (kvcache/state.py), the count is taken out
+        and queued with the choices the step's ``rows`` made in all."""
+        self.k_pages, held = state_pool.take_counts(k_pages)
+        if held is not None:
+            held.copy_to_host_async()
+            self._pair_counts.append((
+                held, rows * self.mcfg.experts_per_token
+                * self.mcfg.layer_pattern.count("E")))
+
+    def _note_pair_counts(self) -> None:
+        """Book the queued counts whose programs are done (every one
+        dispatched before tokens the host has just read is): reading them
+        waits for nothing."""
+        while self._pair_counts and self._pair_counts[0][0].is_ready():
+            held, pairs = self._pair_counts.popleft()
+            held = int(held)
+            self.telemetry.moe_routed_pairs["yes"].inc(held)
+            self.telemetry.moe_routed_pairs["no"].inc(pairs - held)
 
     def _op_mm_prefill(self, bucket, mm_bucket, tokens, seq_len, mm_pad,
                        pos_pad, row, slots, temps, top_k, top_p):
@@ -2842,6 +2922,7 @@ class TpuEngine:
         with self._phase("decode_wait"):
             sampled = self._read_tokens(chunk.toks)  # [K, B]
         now = self._clock()
+        self._note_pair_counts()
         if chunk.timed:
             # The chunk's own wall time: it could not start before the chunk
             # ahead of it was done, which the host saw at that one's

@@ -164,6 +164,29 @@ class EngineTelemetry:
             "absorbed, a prefill or a prefix-continuation window expanded); "
             "counted on the host at dispatch, empty without a latent pool",
             ("form",), registry=self.registry)
+        self.ssm_tokens = Counter(
+            "jetstream:ssm_tokens_total",
+            "Rows (padded tokens) dispatched through the state-space layers, "
+            "by the form their program traced to (models/hybrid.py: a decode "
+            "step is the one-step recurrence `step`, a prefill or a "
+            "continuation window the chunked `scan`); counted on the host at "
+            "dispatch, empty for a model without state layers",
+            ("form",), registry=self.registry)
+        self.ssm_slot_prefills = Counter(
+            "jetstream:ssm_slot_prefills_total",
+            "Slots whose recurrent state a first prefill window started "
+            "afresh (kvcache/state.py), warm-up programs not counted",
+            registry=self.registry)
+        moe_routed_pairs = Counter(
+            "jetstream:moe_routed_pairs_total",
+            "(Token, expert) choices of the router, padded rows among them, "
+            "by whether the chosen expert is held on this chip (`yes`) or "
+            "would be another chip's (`no`, its part of the result left "
+            "out); summed on the device inside the step programs and read "
+            "once tokens dispatched later have been read; empty where every "
+            "expert is held", ("held",), registry=self.registry)
+        self.moe_routed_pairs = {h: moe_routed_pairs.labels(held=h)
+                                 for h in ("yes", "no")}
         decode_chunks = Counter(
             "jetstream:decode_chunks_total",
             "Decode chunks dispatched: `ahead` while the chunk before was "

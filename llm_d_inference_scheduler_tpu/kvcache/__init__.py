@@ -9,6 +9,9 @@ relied on here and in the two attention ops this package calls
   rule, the two writes (a token a lane, a run of tokens a sequence), the two
   reads (decode attention and which op runs it, the prefix gather), and the
   block-wise export and import on the device;
+- ``state``: the pool beside the pages that a state-space layer's recurrent
+  state lives in, indexed by engine slot, and the one value (``state.Cache``)
+  that carries pages and state through a step;
 - ``wire``: what a handoff to another engine looks like in bytes and
   headers, and the checks on what arrives.
 

@@ -36,6 +36,7 @@ from ..ops.attention import (latent_paged_decode_attention,
                              paged_decode_attention)
 from ..ops.pallas_latent_attention import latent_paged_decode_attention_pallas
 from ..ops.pallas_paged_attention import paged_decode_attention_pallas
+from . import state as state_pool
 
 TRASH_BLOCK = 0
 LANES = 128
@@ -62,10 +63,12 @@ class PageGeometry:
                   dtype: str | None = None) -> "PageGeometry":
         """A pool of ``n_blocks`` pages at ``model``'s widths (anything with
         n_layers, kv_block_size, n_kv_heads, head_dim and dtype; a
-        ``latent_dim`` above 0 asks for the latent kind). Never fewer than
-        two blocks: the trash block and one to use."""
+        ``latent_dim`` above 0 asks for the latent kind; ``n_kv_layers``
+        where not every layer keeps pages). Never fewer than two blocks: the
+        trash block and one to use."""
         n_blocks = max(n_blocks, 2)
-        return cls(model.n_layers, n_blocks, model.kv_block_size,
+        return cls(getattr(model, "n_kv_layers", model.n_layers), n_blocks,
+                   model.kv_block_size,
                    model.n_kv_heads, model.head_dim,
                    str(jnp.dtype(dtype or model.dtype)),
                    max_blocks_per_seq or n_blocks - 1,
@@ -133,11 +136,20 @@ def page_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, page_spec(mesh))
 
 
-def alloc(geom: PageGeometry, *, device=None,
-          sharding=None) -> tuple[jax.Array, jax.Array | None]:
+def alloc(geom: PageGeometry, *, device=None, sharding=None,
+          state: state_pool.StateGeometry | None = None
+          ) -> tuple[jax.Array, jax.Array | None]:
     """Zeroed ``(k_pages, v_pages)``, on one device or laid out by
     ``sharding`` (made in place, shard by shard); ``(pool, None)`` for a
-    latent geometry, which has no sharding rule yet."""
+    latent geometry, which has no sharding rule yet. With ``state`` (a model
+    with state-space layers) the pair is ``(cache, None)``: the page pools
+    and the state pool as one value (kvcache/state.py), unsharded too."""
+    if state is not None:
+        if sharding is not None or geom.latent_dim:
+            raise ValueError("a state pool lies beside an unsharded K/V page "
+                             "pool only: it has no sharding rule")
+        return state_pool.alloc(state, *alloc(geom, device=device),
+                                device=device), None
     dtype = jnp.dtype(geom.dtype)
     if geom.latent_dim:
         if sharding is not None:
@@ -270,7 +282,13 @@ def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
                     ) -> tuple[jax.Array, jax.Array]:
     """A run of tokens a sequence, ``k_new`` / ``v_new`` [L, B, S, Hkv, D]
     (a prefill's KV), written from position ``start[b]`` (None: 0) on;
-    padding past ``lens[b]`` lands in the trash block."""
+    padding past ``lens[b]`` lands in the trash block. A cache with a state
+    pool takes a first window's ``state.Fresh`` as ``k_new``: its K/V rows
+    go to the pages and its slots' state starts afresh."""
+    if isinstance(k_pages, state_pool.Cache):
+        return state_pool.start(k_pages, k_new, *write_sequences(
+            k_pages.k, k_pages.v, k_new.k, k_new.v, block_tables, lens,
+            start)), None
     if v_pages is None:
         return _write_latent_run(k_pages, k_new, block_tables, lens,
                                  start), None
@@ -347,12 +365,16 @@ def read_latent_prefix(pool: jax.Array, layer: jax.Array,
 
 
 def read_prefix(k_layer: jax.Array, v_layer: jax.Array,
-                table_row: jax.Array) -> tuple[jax.Array, jax.Array]:
+                table_row: jax.Array, layer: int | None = None
+                ) -> tuple[jax.Array, jax.Array]:
     """A sequence's cached KV out of ONE layer's pool [N, block, Hkv, D], as
     a scan over the stacked pools hands it to its body: the blocks of
-    ``table_row`` [1, W] in order, as [1, W * block, Hkv, D] each."""
+    ``table_row`` [1, W] in order, as [1, W * block, Hkv, D] each. With
+    ``layer`` the pools are the stacked ones and that layer of them is read
+    (a module that walks its layers in Python: models/hybrid.py)."""
     def gather(pool):
-        return pool[table_row].reshape(1, -1, *pool.shape[-2:])
+        rows = pool[table_row] if layer is None else pool[layer, table_row]
+        return rows.reshape(1, -1, *pool.shape[-2:])
 
     return gather(k_layer), gather(v_layer)
 

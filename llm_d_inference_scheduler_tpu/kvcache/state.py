@@ -1,0 +1,175 @@
+"""The state pool: what a state-space layer keeps a sequence, beside the pages.
+
+A Mamba-2 layer (models/hybrid.py) keeps, for every sequence, a state of
+``heads x head_dim x state`` values and the last ``conv - 1`` inputs of its
+convolution: a fixed size whatever the context. That is no page of tokens, so
+it does not live in the page pool. It lives in a pool **indexed by engine
+slot**:
+
+- ``ssm``  ``[state layers, slots + 1, heads, head_dim, state]`` float32;
+- ``conv`` ``[state layers, slots + 1, (conv - 1) * channels]`` in the model's
+  dtype, a slot's rows flat so that the minor dim is whole lanes.
+
+Row ``slots`` (the engine's ``max_batch``) is nobody's: padding lanes and
+warm-up programs read and write it, as padded page writes go to the trash
+block.
+
+Who writes a slot's rows. A request's first prefill window writes them whole
+(it starts from zeros and never reads them); later windows and every decode
+step of a lane in that slot read and rewrite them; nobody else touches them.
+The device's in-order stream is the guarantee: a lane that overshoots a
+finished request writes its own slot only, and the next request's first
+window, dispatched later, overwrites it.
+
+A model with state layers hands its whole cache through the step functions as
+one value, :class:`Cache`, where the other families hand ``(k_pages,
+v_pages)``: the pair becomes ``(cache, None)``. Besides the pools it carries
+two small things that ride with a step: ``slots`` [B], the engine slot of
+every row of this step (set by the engine before the call, :func:`at_slots`),
+and ``held``, the count of (token, expert) choices this step's programs found
+to live on this chip, which the engine takes out after the call
+(:func:`take_counts`). ``engine/core.py`` learns neither shape.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+@dataclasses.dataclass(frozen=True)
+class StateGeometry:
+    """What the state pool's shapes follow from."""
+
+    n_layers: int       # state-space layers
+    n_slots: int        # engine slots; the pool holds one more, nobody's
+    heads: int
+    head_dim: int
+    state: int
+    tail_rows: int      # conv - 1 inputs kept
+    channels: int       # what the convolution runs over
+    dtype: str          # of the tail; the state is float32
+
+    @classmethod
+    def for_engine(cls, model: Any, max_batch: int) -> "StateGeometry | None":
+        """The engine's pool for ``model`` (anything with n_state_layers and
+        the ssm_* widths); None for a model that keeps pages alone."""
+        if not getattr(model, "n_state_layers", 0):
+            return None
+        return cls(model.n_state_layers, max_batch, model.ssm_heads,
+                   model.ssm_head_dim, model.ssm_state, model.ssm_conv - 1,
+                   model.ssm_conv_dim, str(jnp.dtype(model.dtype)))
+
+    @property
+    def ssm_shape(self) -> tuple[int, ...]:
+        return (self.n_layers, self.n_slots + 1, self.heads, self.head_dim,
+                self.state)
+
+    @property
+    def conv_shape(self) -> tuple[int, ...]:
+        return (self.n_layers, self.n_slots + 1,
+                self.tail_rows * self.channels)
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one sequence keeps, every state layer."""
+        ssm = self.heads * self.head_dim * self.state * 4
+        tail = self.tail_rows * self.channels * jnp.dtype(self.dtype).itemsize
+        return self.n_layers * (ssm + tail)
+
+    @property
+    def pool_bytes(self) -> int:
+        return (self.n_slots + 1) * self.slot_bytes
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Cache:
+    """Pages and state as one value (see the module's docstring)."""
+
+    k: jax.Array                    # the K/V page pools (kvcache/pages.py)
+    v: jax.Array
+    ssm: jax.Array
+    conv: jax.Array
+    slots: jax.Array | None = None  # [B] int32: the rows of this step
+    held: jax.Array | None = None   # int32 scalar: choices held, this step
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Fresh:
+    """What a first prefill window hands to ``pages.write_sequences``: its
+    K/V rows [attention layers, B, S, Hkv, D], and for every sequence the
+    state [state layers, B, heads, head_dim, state] and the tail
+    [state layers, B, tail rows, channels] its true last token left."""
+
+    k: jax.Array
+    v: jax.Array
+    ssm: jax.Array
+    conv: jax.Array
+    held: jax.Array
+
+
+def alloc(geom: StateGeometry, k_pages: jax.Array, v_pages: jax.Array, *,
+          device=None) -> Cache:
+    """A zeroed state pool beside the given page pools."""
+    return Cache(k_pages, v_pages,
+                 jnp.zeros(geom.ssm_shape, jnp.float32, device=device),
+                 jnp.zeros(geom.conv_shape, jnp.dtype(geom.dtype),
+                           device=device))
+
+
+def at_slots(cache: Any, slots: Any) -> Any:
+    """``cache`` as a step on rows ``slots`` takes it (``slots`` from the
+    host: the step donates its cache, and what rides in it goes with it);
+    anything that is no :class:`Cache` (a page pool) goes through as it is."""
+    if not isinstance(cache, Cache):
+        return cache
+    return dataclasses.replace(cache, slots=np.asarray(slots, np.int32),
+                               held=np.zeros((), np.int32))
+
+
+def take_counts(cache: Any) -> tuple[Any, jax.Array | None]:
+    """(The cache as it is kept between steps, the step's count of held
+    expert choices or None.) The count leaves the cache so that it is not
+    donated to the next step with it."""
+    if not isinstance(cache, Cache):
+        return cache, None
+    return dataclasses.replace(cache, slots=None, held=None), cache.held
+
+
+# ---- reads and writes, by slot ---------------------------------------------------
+
+
+def read(cache: Cache, layer: int) -> tuple[jax.Array, jax.Array]:
+    """State layer ``layer`` of this step's rows: (state [B, heads, head_dim,
+    state] f32, tail [B, tail rows * channels], the rows flat as stored)."""
+    return cache.ssm[layer, cache.slots], cache.conv[layer, cache.slots]
+
+
+def write(cache: Cache, ssm: list[jax.Array], conv: list[jax.Array]) -> Cache:
+    """Every state layer's new rows, in layer order (``ssm[l]`` [B, heads,
+    head_dim, state], ``conv[l]`` [B, tail rows, channels]), into this step's
+    slots: one scatter a pool. Padding rows all name nobody's slot, and which
+    of them lands there is nobody's concern."""
+    B = cache.slots.shape[0]
+    new_ssm = jnp.stack(ssm).astype(cache.ssm.dtype)
+    new_conv = jnp.stack(conv).reshape(len(conv), B, -1).astype(
+        cache.conv.dtype)
+    return dataclasses.replace(
+        cache, ssm=cache.ssm.at[:, cache.slots].set(new_ssm),
+        conv=cache.conv.at[:, cache.slots].set(new_conv))
+
+
+def start(cache: Cache, fresh: Fresh, k_pages: jax.Array,
+          v_pages: jax.Array) -> Cache:
+    """``cache`` after a first prefill window: the page pools as the window's
+    K/V write left them, and this step's slots started afresh from
+    ``fresh``."""
+    cache = dataclasses.replace(cache, k=k_pages, v=v_pages,
+                                held=cache.held + fresh.held)
+    return write(cache, list(fresh.ssm), list(fresh.conv))

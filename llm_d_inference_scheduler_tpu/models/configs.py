@@ -55,6 +55,59 @@ class ModelConfig:
     moe_d_ff: int = 0
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
+    # A layer pattern (the nemotron_h family; models/hybrid.py is its block)
+    # names the family: one character a layer, each layer ONE mixer under its
+    # own residual -- "M" a Mamba-2 state-space layer, "E" a LatentMoE expert
+    # layer, "*" attention (GQA, no rotary embedding). n_layers is its length.
+    layer_pattern: str = ""
+    # "M": ssm_heads heads of ssm_head_dim, a state of ssm_state values a
+    # head-channel, B and C shared by the heads of one of ssm_groups groups,
+    # a causal depth-wise convolution ssm_conv wide ahead of them; a prefill
+    # computes in chunks of ssm_chunk positions (the matrix form).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # The step sizes dt_bias is drawn for (random weights only).
+    ssm_dt_range: tuple[float, float, float] = (0.001, 0.1, 1e-4)  # min, max, floor
+    # "E": routed experts of width moe_d_ff between a projection down to
+    # moe_latent_dim and one back up, not gated (relu squared), beside a
+    # shared expert of width shared_d_ff on the model's own width.
+    moe_latent_dim: int = 0
+    shared_d_ff: int = 0
+    # The experts this chip holds of the n_experts the router scores:
+    # [experts_first, experts_first + experts_held); 0 held = all of them.
+    # What the others would have added is left out (expert parallelism
+    # without its exchange: models/hybrid.py).
+    experts_held: int = 0
+    experts_first: int = 0
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that keep recurrent state a sequence (0: pages alone)."""
+        return self.layer_pattern.count("M")
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep pages of keys and values."""
+        return (self.layer_pattern.count("*") if self.layer_pattern
+                else self.n_layers)
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.experts_first, self.experts_held or self.n_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: x, then B and C a group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def head_dim(self) -> int:
@@ -255,10 +308,78 @@ TINY_MLA = ModelConfig(
     routed_scaling_factor=2.446,
 )
 
+# NVIDIA-Nemotron-3-Super-120B-A12B's language model (public config.json,
+# model_type nemotron_h): 88 layers of one mixer each -- 40 Mamba-2, 40
+# LatentMoE (512 experts of 2688 in a 1024-wide latent space, 22 a token,
+# beside a shared expert of 5376), 8 attention (32 query / 2 KV heads of
+# 128, no rotary embedding). Its draft head (multi-token prediction) is not
+# built.
+_NEMOTRON_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                     "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+NEMOTRON_3_SUPER = ModelConfig(
+    name="nemotron-3-super",
+    vocab_size=131_072,
+    d_model=4096,
+    n_layers=88,
+    n_heads=32,
+    n_kv_heads=2,
+    d_ff=2688,
+    rope_theta=10_000.0,
+    max_seq_len=262_144,
+    head_dim_override=128,
+    n_experts=512,
+    experts_per_token=22,
+    moe_d_ff=2688,
+    n_shared_experts=1,
+    routed_scaling_factor=5.0,
+    layer_pattern=_NEMOTRON_PATTERN,
+    ssm_heads=128,
+    ssm_head_dim=64,
+    ssm_state=128,
+    ssm_groups=8,
+    moe_latent_dim=1024,
+    shared_d_ff=5376,
+)
+
+# One period of it (pattern positions 26-36) with a quarter of each layer's
+# experts: what one of four chips that share each layer's experts holds of
+# one of eight stages (chipbench/configs/nemotron-3-super-cut.json).
+NEMOTRON_3_SUPER_CUT = dataclasses.replace(
+    NEMOTRON_3_SUPER, name="nemotron-3-super-cut", n_layers=11,
+    layer_pattern="EMEMEMEMEM*", experts_held=128)
+
+# The same family at small widths (CI tests): every kind of layer, two
+# state-space layers, 16 experts of which 4 a token, all held.
+TINY_HYBRID = ModelConfig(
+    name="tiny-hybrid",
+    vocab_size=512,
+    d_model=64,
+    n_layers=5,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=48,
+    max_seq_len=256,
+    head_dim_override=16,
+    n_experts=16,
+    experts_per_token=4,
+    moe_d_ff=48,
+    n_shared_experts=1,
+    routed_scaling_factor=5.0,
+    layer_pattern="MEM*E",
+    ssm_heads=8,
+    ssm_head_dim=16,
+    ssm_state=16,
+    ssm_groups=2,
+    ssm_chunk=16,
+    moe_latent_dim=32,
+    shared_d_ff=80,
+)
+
 _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
-                                 KIMI_VL_A3B, TINY_MLA)}
+                                 KIMI_VL_A3B, TINY_MLA, NEMOTRON_3_SUPER,
+                                 NEMOTRON_3_SUPER_CUT, TINY_HYBRID)}
 
 
 def get_config(name: str) -> ModelConfig:
@@ -277,5 +398,7 @@ def get_config(name: str) -> ModelConfig:
         with open(cand) as f:
             fields = json.load(f)
         known = set(ModelConfig.__dataclass_fields__)
-        return ModelConfig(**{k: v for k, v in fields.items() if k in known})
+        fields = {k: tuple(v) if isinstance(v, list) else v
+                  for k, v in fields.items() if k in known}
+        return ModelConfig(**fields)
     raise ValueError(f"unknown model config {name!r}; have {sorted(_REGISTRY)}")

@@ -70,9 +70,75 @@ def _mla_config_from_hf(hf, name: str) -> ModelConfig:
     )
 
 
+# What models/hybrid.py computes of the nemotron_h family's options.
+_HYBRID_ONLY = {"n_group": 1, "topk_group": 1, "mamba_proj_bias": False,
+                "sliding_window": None, "attention_bias": False,
+                "mlp_bias": False, "use_bias": False, "use_conv_bias": True,
+                "norm_topk_prob": True, "mamba_hidden_act": "silu",
+                "mlp_hidden_act": "relu2", "n_shared_experts": 1,
+                "tie_word_embeddings": False}
+
+
+def _hybrid_config_from_hf(hf, name: str) -> ModelConfig:
+    """The nemotron_h family (a pattern of Mamba-2, LatentMoE and attention
+    layers), from its published keys, flat. Two keys beside the published
+    ones say that this chip holds a share of each layer's experts:
+    ``n_routed_experts_published`` (what the router scores, where
+    ``n_routed_experts`` counts the experts held) and
+    ``expert_parallel_rank`` (which share: the experts from rank x held
+    on)."""
+    for key, only in _HYBRID_ONLY.items():
+        got = getattr(hf, key, only)
+        if got != only:
+            raise ValueError(f"{name}: {key}={got!r} is not supported "
+                             f"(models/hybrid.py computes {key}={only!r} only)")
+    pattern = hf.hybrid_override_pattern
+    if set(pattern) - set("ME*") or len(pattern) != hf.num_hidden_layers:
+        raise ValueError(
+            f"{name}: hybrid_override_pattern {pattern!r} must name "
+            f"num_hidden_layers={hf.num_hidden_layers} layers, each M, E or * "
+            "(models/hybrid.py has no other mixer, no dense MLP layer '-')")
+    if hf.mamba_num_heads * hf.mamba_head_dim != hf.expand * hf.hidden_size:
+        raise ValueError(f"{name}: mamba_num_heads x mamba_head_dim must be "
+                         "expand x hidden_size")
+    held = hf.n_routed_experts
+    total = getattr(hf, "n_routed_experts_published", held)
+    return ModelConfig(
+        name=name,
+        vocab_size=hf.vocab_size,
+        d_model=hf.hidden_size,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        d_ff=hf.intermediate_size,
+        rope_theta=float(getattr(hf, "rope_theta", 10_000.0)),
+        max_seq_len=getattr(hf, "max_position_embeddings", 8192),
+        norm_eps=hf.layer_norm_epsilon,
+        head_dim_override=hf.head_dim,
+        n_experts=total,
+        experts_per_token=hf.num_experts_per_tok,
+        moe_d_ff=hf.moe_intermediate_size,
+        n_shared_experts=hf.n_shared_experts,
+        routed_scaling_factor=float(hf.routed_scaling_factor),
+        layer_pattern=pattern,
+        ssm_heads=hf.mamba_num_heads,
+        ssm_head_dim=hf.mamba_head_dim,
+        ssm_state=hf.ssm_state_size,
+        ssm_groups=hf.n_groups,
+        ssm_conv=hf.conv_kernel,
+        ssm_chunk=hf.chunk_size,
+        ssm_dt_range=(hf.time_step_min, hf.time_step_max, hf.time_step_floor),
+        moe_latent_dim=hf.moe_latent_size,
+        shared_d_ff=hf.moe_shared_expert_intermediate_size,
+        experts_held=held if held != total else 0,
+        experts_first=getattr(hf, "expert_parallel_rank", 0) * held,
+    )
+
+
 def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
-    """Map a transformers Llama/Mixtral/Qwen3 config, or a DeepSeek-V3-family
-    one (Kimi-VL's ``text_config``), to our ModelConfig."""
+    """Map a transformers Llama/Mixtral/Qwen3 config, a DeepSeek-V3-family
+    one (Kimi-VL's ``text_config``), or a nemotron_h one (a layer pattern)
+    to our ModelConfig."""
     text = getattr(hf_config, "text_config", None)
     if text is not None:
         # A multimodal config nests its language model; the towers beside it
@@ -81,6 +147,8 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
 
         hf_config = (types.SimpleNamespace(**text) if isinstance(text, dict)
                      else text)
+    if getattr(hf_config, "hybrid_override_pattern", None):
+        return _hybrid_config_from_hf(hf_config, name)
     if getattr(hf_config, "kv_lora_rank", None):
         return _mla_config_from_hf(hf_config, name)
     n_experts = getattr(hf_config, "num_local_experts", 0) or 0
@@ -130,6 +198,11 @@ def convert_state_dict(state_dict: dict, cfg: ModelConfig,
             "checkpoint names onto models/mla.py's parameter tree yet (its "
             "rope columns are stored interleaved and need un-interleaving); "
             "the engine serves this family on seeded random weights")
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            f"{cfg.name}: no mapping of a layer pattern's checkpoint names "
+            "(the nemotron_h family) onto models/hybrid.py's parameter tree "
+            "yet; the engine serves this family on seeded random weights")
     out_dtype = jnp.dtype(dtype or cfg.dtype)
     L, E = cfg.n_layers, cfg.n_experts
 

@@ -1,0 +1,543 @@
+"""The nemotron_h family's decoder for the TPU engine: a pattern of layers,
+each ONE mixer under its own residual -- a Mamba-2 state-space layer ("M"), a
+LatentMoE expert layer ("E") or attention ("*"). NVIDIA's Nemotron-3-Super is
+this block; its draft head (multi-token prediction) is not built.
+
+The module has models/llama.py's four entry points with its signatures
+(``init_params``, ``forward``, ``decode_step``, ``prefill_with_prefix``), so
+the engine serves it through the same step functions; ``models.family`` picks
+the module by the presence of ``cfg.layer_pattern``. Where a signature says
+``k_pages, v_pages`` this family hands ``(cache, None)``: pages and recurrent
+state as one value (kvcache/state.py), and the "KV" a prefill returns is a
+``state.Fresh``. Parameters are stacked per kind (``ssm``, ``moe``, ``attn``)
+and walked in the pattern's order, so any pattern serves.
+
+Every layer: ``x <- x + mixer(RMSNorm(x))``; no bias but the convolution's.
+
+**M, Mamba-2.** ``[z | xBC | dt] = h W_in``; a causal depth-wise convolution
+``ssm_conv`` wide and a silu over ``xBC``, which then splits into ``x``
+(heads x head_dim), ``B`` and ``C`` (groups x state; head i reads group
+i // (heads / groups)); ``D_t = softplus(dt_t + dt_bias)``, ``A = -exp(A_log)``
+a head; ``S_t = exp(D_t A) S_(t-1) + D_t x_t (x) B_t``; ``y_t = S_t C_t + D x_t``;
+``y <- RMSNorm(y * silu(z))`` over groups of inner / groups; ``out = y W_out``.
+One mathematics in two forms, chosen by the shape of the step as models/mla.py
+chooses its attention's form:
+
+- *scan* (a run of positions a sequence: ``forward``, ``prefill_with_prefix``):
+  the chunked matrix form. Inside a chunk of ``ssm_chunk`` positions
+  ``y_t = sum_(s<=t) exp(a_t - a_s) (C_t . B_s) D_s x_s`` with ``a`` the running
+  sum of ``D A`` -- two matrix products a chunk -- and the chunks are joined by
+  a recurrence over their end states. Padded positions get ``D = 0``: no decay
+  and no input, so the state a bucket leaves is the state its true last token
+  left, and the convolution's tail is gathered at the true length.
+- *step* (one position a sequence: ``decode_step``): the recurrence as written.
+
+The state is float32; the products take their operands in the model's dtype
+with float32 accumulation.
+
+**\\*, attention.** GQA, causal, softmax(q k^T / sqrt(head_dim)) v, and NO
+rotary embedding (the family's convention: the state-space layers carry
+position; ``rope_theta`` is unused). Pages of K and V as models/llama.py's,
+one pool layer an attention layer.
+
+**E, LatentMoE.** ``s = sigmoid(h W_r)``; the experts_per_token largest of
+``s + bias``; gates the chosen scores normalised, times routed_scaling_factor
+(models/routing.py: the DeepSeek-V3 family's router). ``u = h W_down`` into
+the latent space; ``r = sum over chosen AND HELD experts of g_i
+relu(u W1_i)^2 W2_i`` (not gated); ``out = r W_up + relu(h Ws1)^2 Ws2``. The
+chip holds ``cfg.held_experts`` of the experts the router scores -- what
+expert parallelism gives one chip -- and what the absent ones would have added
+is left out: in the deployment the 1,024-wide latents are exchanged between
+``W_down`` and ``W_up``, on one chip there is no exchange and nothing stands
+in for it. The routed part runs dense over the held experts or grouped
+(ops/pallas_moe.py), chosen by the engine per program (``cfg.moe_impl``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable
+
+import jax
+import jax.numpy as jnp
+
+from ..kvcache import pages, state
+from ..ops import causal_attention, rms_norm
+from .configs import ModelConfig
+from .routing import route
+
+Params = dict[str, Any]
+
+_STACK = {"M": "ssm", "E": "moe", "*": "attn"}
+
+
+def init_params(cfg: ModelConfig, key: jax.Array,
+                dtype: jnp.dtype | None = None) -> Params:
+    """Random-init parameters, one stack a kind of layer with a leading axis
+    over that kind's layers. ``A`` is uniform in [1, 16]; ``dt_bias`` the
+    inverse softplus of a log-uniform step in cfg.ssm_dt_range; ``D`` ones;
+    norm weights and the selection bias are drawn (models/mla.py), so that a
+    run on random weights sees them."""
+    dtype = dtype or jnp.dtype(cfg.dtype)
+    D, V = cfg.d_model, cfg.vocab_size
+    Lm, Le, La = (cfg.layer_pattern.count(c) for c in "ME*")
+    H, G, N, Kc = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv
+    inner, conv_dim = cfg.ssm_inner, cfg.ssm_conv_dim
+    E, Eh = cfg.n_experts, cfg.held_experts[1]
+    Z, F, Fs = cfg.moe_latent_dim, cfg.moe_d_ff, cfg.shared_d_ff
+    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    keys = iter(jax.random.split(key, 40))
+
+    def w(shape, fan_in):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * (fan_in ** -0.5)).astype(dtype)
+
+    def norm(shape):
+        return (1.0 + 0.1 * jax.random.normal(next(keys), shape,
+                                              jnp.float32)).astype(dtype)
+
+    dt_min, dt_max, dt_floor = cfg.ssm_dt_range
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        next(keys), (Lm, H), jnp.float32, jnp.log(dt_min), jnp.log(dt_max))),
+        dt_floor)
+    return {
+        "embed": w((V, D), D), "final_norm": norm((D,)),
+        "lm_head": w((D, V), D),
+        "ssm": {
+            "ln": norm((Lm, D)),
+            "w_in": w((Lm, D, inner + conv_dim + H), D),
+            "conv_w": w((Lm, Kc, conv_dim), Kc),
+            "conv_b": (0.1 * jax.random.normal(
+                next(keys), (Lm, conv_dim), jnp.float32)).astype(dtype),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (Lm, H), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((Lm, H), jnp.float32),
+            "norm": norm((Lm, inner)),
+            "w_out": w((Lm, inner, D), inner)},
+        "moe": {
+            "ln": norm((Le, D)),
+            "router": w((Le, D, E), D),
+            "router_bias": 0.1 * jax.random.normal(
+                next(keys), (Le, E), jnp.float32),
+            "w_down": w((Le, D, Z), D), "w_up": w((Le, Z, D), Z),
+            "w1": w((Le, Eh, Z, F), Z), "w2": w((Le, Eh, F, Z), F),
+            "w1s": w((Le, D, Fs), D), "w2s": w((Le, Fs, D), Fs)},
+        "attn": {
+            "ln": norm((La, D)),
+            "wq": w((La, D, Hq * Dh), D), "wk": w((La, D, Hkv * Dh), D),
+            "wv": w((La, D, Hkv * Dh), D), "wo": w((La, Hq * Dh, D), Hq * Dh)},
+    }
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+# ---- E: LatentMoE -------------------------------------------------------------
+
+
+def latent_moe(cfg: ModelConfig, stack: Params, layer: int, h: jnp.ndarray
+               ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Expert layer ``layer`` of the stacked ``moe`` parameters on h [..., D]:
+    (output, the experts every token chose [T, k], how many of those choices
+    name an expert held here)."""
+    lp = {n: a[layer] for n, a in stack.items() if n not in ("w1", "w2")}
+    first, count = cfg.held_experts
+    ht = h.reshape(-1, h.shape[-1])
+    idx, gates = route(cfg, lp, ht)
+    here = (idx >= first) & (idx < first + count)
+    u = ht @ lp["w_down"]
+    if cfg.moe_impl.startswith("grouped"):
+        from ..ops.pallas_moe import grouped_experts
+
+        # The kernel reads the stacked weights at (layer, expert): a slice
+        # of them would reach it as a copy (models/llama._over_layers).
+        r = grouped_experts(stack, u, idx, gates, count,
+                            layer=jnp.asarray(layer, jnp.int32), first=first,
+                            gated=False,
+                            interpret=cfg.moe_impl == "grouped_interpret")
+    else:
+        # Dense over the held experts: each of them for every token, weighted
+        # by its gate or by zero; the gate goes in ahead of the second
+        # product, which then sums over experts and width at once.
+        weights = jnp.einsum(
+            "tke,tk->te",
+            jax.nn.one_hot(jnp.where(here, idx - first, -1), count,
+                           dtype=h.dtype), gates.astype(h.dtype))
+        act = _relu2(jnp.einsum("tz,ezf->tef", u, stack["w1"][layer]))
+        r = jnp.einsum("tef,efz->tz", act * weights[..., None],
+                       stack["w2"][layer])
+    y = r @ lp["w_up"] + _relu2(ht @ lp["w1s"]) @ lp["w2s"]
+    return y.reshape(h.shape), idx, jnp.sum(here, dtype=jnp.int32)
+
+
+# ---- M: Mamba-2 ---------------------------------------------------------------
+
+
+def _split_in(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
+    """h W_in as (z [..., inner], xBC [..., conv_dim], dt [..., heads])."""
+    zxbcdt = h @ lp["w_in"]
+    inner, conv_dim = cfg.ssm_inner, cfg.ssm_conv_dim
+    return (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv_dim],
+            zxbcdt[..., inner + conv_dim:])
+
+
+def _step_size(lp: Params, dt: jnp.ndarray) -> jnp.ndarray:
+    return jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+
+
+def _split_conv(cfg: ModelConfig, xbc: jnp.ndarray):
+    """The convolution's output as x [..., G, R, P] (head g * R + r), B and C
+    [..., G, N]."""
+    G, N, P = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_head_dim
+    inner = cfg.ssm_inner
+    lead = xbc.shape[:-1]
+    return (xbc[..., :inner].reshape(*lead, G, cfg.ssm_heads // G, P),
+            xbc[..., inner:inner + G * N].reshape(*lead, G, N),
+            xbc[..., inner + G * N:].reshape(*lead, G, N))
+
+
+def _gated_out(cfg: ModelConfig, lp: Params, y: jnp.ndarray, z: jnp.ndarray
+               ) -> jnp.ndarray:
+    """y [..., inner] (f32) gated by z, normed over its groups, through
+    W_out."""
+    G = cfg.ssm_groups
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    grouped = y.reshape(*y.shape[:-1], G, -1)
+    var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+    y = (grouped * jax.lax.rsqrt(var + cfg.norm_eps)).reshape(y.shape)
+    return (y * lp["norm"].astype(jnp.float32)).astype(z.dtype) @ lp["w_out"]
+
+
+def ssm_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
+             state0: jnp.ndarray | None = None,
+             tail0: jnp.ndarray | None = None
+             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A state-space layer over a run of positions, the chunked form: h
+    [B, S, D] (normed), of which the first ``lens[b]`` are real, continuing
+    from ``state0`` [B, heads, head_dim, state] f32 and the convolution's
+    ``tail0`` [B, conv - 1, channels] (None: a sequence's start, zeros).
+    Returns (out [B, S, D], and the state and the tail as position
+    ``lens[b] - 1`` left them)."""
+    B, S, _ = h.shape
+    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    R, Kc = H // G, cfg.ssm_conv
+    z, xbc, dt = _split_in(cfg, lp, h)
+    if tail0 is None:
+        tail0 = jnp.zeros((B, Kc - 1, xbc.shape[-1]), xbc.dtype)
+    if state0 is None:
+        state0 = jnp.zeros((B, H, P, N), jnp.float32)
+    seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc], axis=1)
+    # Row t + j of seq is position t - (Kc - 1) + j.
+    conv = lp["conv_b"].astype(jnp.float32) + sum(
+        seq[:, j:j + S].astype(jnp.float32) * lp["conv_w"][j].astype(jnp.float32)
+        for j in range(Kc))
+    tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, Kc - 1))(
+        seq, lens)
+    x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
+
+    real = jnp.arange(S)[None, :] < lens[:, None]
+    dt = jnp.where(real[..., None], _step_size(lp, dt), 0.0)    # [B, S, H]
+    a_head = -jnp.exp(lp["A_log"])                              # [H]
+
+    # Chunks of Q positions (a short bucket is one chunk); a run that is no
+    # whole number of them is padded with positions that change nothing.
+    Q = min(cfg.ssm_chunk, S)
+    pad = -S % Q
+    if pad:
+        x, b_mat, c_mat, dt = (
+            jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+            for t in (x, b_mat, c_mat, dt))
+    nc = (S + pad) // Q
+    x = x.reshape(B, nc, Q, G, R, P)
+    b_mat, c_mat = (t.reshape(B, nc, Q, G, N) for t in (b_mat, c_mat))
+    dt = dt.reshape(B, nc, Q, G, R)
+    a = jnp.cumsum(dt * a_head.reshape(G, R), axis=2)           # <= 0
+    f32 = dict(preferred_element_type=jnp.float32)
+    dtx = (dt[..., None] * x.astype(jnp.float32))               # D_s x_s
+
+    # Inside a chunk: y_q = sum_(s <= q) exp(a_q - a_s) (C_q . B_s) D_s x_s.
+    cb = jnp.einsum("bcqgn,bcsgn->bcqsg", c_mat, b_mat, **f32)
+    q_at, s_at = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
+    decay = jnp.exp(jnp.where(
+        (q_at >= s_at)[None, None, :, :, None, None],
+        a[:, :, :, None] - a[:, :, None, :], -jnp.inf))         # [B,c,q,s,G,R]
+    y = jnp.einsum("bcqsgr,bcsgrp->bcqgrp",
+                   (cb[..., None] * decay).astype(h.dtype),
+                   dtx.astype(h.dtype), **f32)
+
+    # What a chunk adds to the state by its end, and the recurrence over
+    # chunks: S_c = exp(a_Q) S_(c-1) + sum_s exp(a_Q - a_s) D_s x_s (x) B_s.
+    to_end = jnp.exp(a[:, :, -1:] - a)
+    added = jnp.einsum("bcsgn,bcsgrp->bcgrpn", b_mat,
+                       (dtx * to_end[..., None]).astype(h.dtype), **f32)
+
+    def join(prev, chunk):
+        add, keep = chunk
+        return prev * keep[..., None, None] + add, prev
+
+    state1, before = jax.lax.scan(
+        join, state0.reshape(B, G, R, P, N),
+        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(jnp.exp(a[:, :, -1]), 1, 0)))
+    # What the state before a chunk gives its positions: exp(a_q) C_q . S.
+    y = y + (jnp.einsum("bcqgn,cbgrpn->bcqgrp", c_mat,
+                        before.astype(h.dtype), **f32)
+             * jnp.exp(a)[..., None])
+
+    y = y + lp["D"].reshape(G, R, 1) * x.astype(jnp.float32)
+    y = y.reshape(B, nc * Q, H * P)[:, :S]
+    return (_gated_out(cfg, lp, y, z), state1.reshape(B, H, P, N), tail)
+
+
+def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray, state0: jnp.ndarray,
+             tail0: jnp.ndarray
+             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A state-space layer for one position a sequence, the recurrence as
+    written: h [B, D] (normed), state0 [B, heads, head_dim, state] f32, tail0
+    [B, conv - 1, channels]. Returns (out [B, D], state, tail)."""
+    B = h.shape[0]
+    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    R = H // G
+    z, xbc, dt = _split_in(cfg, lp, h)
+    seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
+    conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
+        seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
+    x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
+    x, b_mat, c_mat = (t.astype(jnp.float32) for t in (x, b_mat, c_mat))
+    dt = _step_size(lp, dt).reshape(B, G, R)
+    keep = jnp.exp(dt * -jnp.exp(lp["A_log"]).reshape(G, R))
+    s = state0.reshape(B, G, R, P, N)
+    s = (s * keep[..., None, None]
+         + (dt[..., None] * x)[..., None] * b_mat[:, :, None, None, :])
+    y = (jnp.einsum("bgrpn,bgn->bgrp", s, c_mat)
+         + lp["D"].reshape(G, R, 1) * x)
+    return (_gated_out(cfg, lp, y.reshape(B, H * P), z),
+            s.reshape(B, H, P, N), seq[:, 1:])
+
+
+# ---- the stack ------------------------------------------------------------------
+
+
+def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+          mixers: dict[str, Callable[[Params, jnp.ndarray, int], jnp.ndarray]]
+          ) -> tuple[jnp.ndarray, jnp.ndarray, list[jnp.ndarray]]:
+    """x through every layer in the pattern's order. ``mixers["M"]`` and
+    ``mixers["*"]`` are ``(layer's parameters, normed input, index among its
+    kind) -> mixer output`` and keep what else they make (states, K/V rows)
+    for their caller; the expert layer is the same in every step. Returns
+    (x, the count of expert choices held here, every expert layer's
+    choices)."""
+    seen = dict.fromkeys(_STACK, 0)
+    held, routes = jnp.zeros((), jnp.int32), []
+    for kind in cfg.layer_pattern:
+        stack, i = params[_STACK[kind]], seen[kind]
+        seen[kind] += 1
+        h = rms_norm(x, stack["ln"][i], cfg.norm_eps)
+        if kind == "E":
+            y, chosen, n = latent_moe(cfg, stack, i, h)
+            held, routes = held + n, routes + [chosen]
+        else:
+            y = mixers[kind]({n: a[i] for n, a in stack.items()}, h, i)
+        x = x + y
+    return x, held, routes
+
+
+def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
+    """h [..., D] -> q [..., H, Dh], k and v [..., Hkv, Dh]; no rotation."""
+    lead, Dh = h.shape[:-1], cfg.head_dim
+    return ((h @ lp["wq"]).reshape(*lead, cfg.n_heads, Dh),
+            (h @ lp["wk"]).reshape(*lead, cfg.n_kv_heads, Dh),
+            (h @ lp["wv"]).reshape(*lead, cfg.n_kv_heads, Dh))
+
+
+def _stacked(rows: list[jnp.ndarray], like: tuple[int, ...], dtype
+             ) -> jnp.ndarray:
+    """A kind's per-layer outputs stacked; a pattern without that kind gives
+    an empty stack of the right rank."""
+    return jnp.stack(rows) if rows else jnp.zeros((0, *like), dtype)
+
+
+def forward(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jnp.ndarray,                   # [B, S]
+    positions: jnp.ndarray | None = None,  # unused: no rotary embedding
+    *,
+    want_kv: bool = False,
+    want_hidden: bool = False,
+    kv_valid: jnp.ndarray | None = None,   # [B, S] padding mask
+    mm_embeds: jnp.ndarray | None = None,
+    mm_positions: jnp.ndarray | None = None,
+    seq_len: jnp.ndarray | None = None,    # [B] true lengths of the bucket
+    want_routes: bool = False,
+):
+    """Full-sequence forward from a sequence's start (a prefill, or a long
+    prompt's first window), the state-space layers in the scan form. Returns
+    (logits [B, S, V] f32, (``state.Fresh``, None) if want_kv): the K/V rows
+    of the attention layers, and the state and tail every state-space layer
+    is left with at ``seq_len[b] - 1`` (None: the whole run is real).
+    ``want_routes`` appends every expert layer's choices [expert layers, T,
+    k], as models/mla.py's does, for scripts/compare_ssm_reference.py."""
+    if mm_embeds is not None:
+        raise NotImplementedError(
+            "this family serves text: multimodal embeddings have nothing to "
+            "come from")
+    B, S = tokens.shape
+    lens = jnp.full((B,), S, jnp.int32) if seq_len is None else seq_len
+    ks, vs, ssms, tails = [], [], [], []
+
+    def ssm(lp, h, i):
+        out, s, tail = ssm_scan(cfg, lp, h, lens)
+        ssms.append(s), tails.append(tail)
+        return out
+
+    def attend(lp, h, i):
+        q, k, v = _qkv(cfg, lp, h)
+        ks.append(k), vs.append(v)
+        out = causal_attention(q, k, v, kv_valid=kv_valid)
+        return out.reshape(B, S, -1) @ lp["wo"]
+
+    x, held, routes = _walk(params, cfg, params["embed"][tokens],
+                            {"M": ssm, "*": attend})
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    kv = None
+    if want_kv:
+        dt = x.dtype
+        kv_like = (B, S, cfg.n_kv_heads, cfg.head_dim)
+        kv = (state.Fresh(
+            _stacked(ks, kv_like, dt), _stacked(vs, kv_like, dt),
+            _stacked(ssms, (B, cfg.ssm_heads, cfg.ssm_head_dim,
+                            cfg.ssm_state), jnp.float32),
+            _stacked(tails, (B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dt),
+            held), None)
+    out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
+    return (out, kv, jnp.stack(routes)) if want_routes else (out, kv)
+
+
+def decode_step(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jnp.ndarray,        # [B]
+    positions: jnp.ndarray,     # [B]
+    k_pages: state.Cache,       # pages and state, the rows' slots in it
+    v_pages: None,
+    block_tables: jnp.ndarray,  # [B, max_blocks] int32
+    active: jnp.ndarray | None = None,
+    *,
+    attention_fn: Callable[..., jnp.ndarray] = pages.decode_attention,
+    want_routes: bool = False,
+):
+    """One decode step, the state-space layers in the step form; returns
+    (logits [B, V] f32, cache, None). The attention layers read the stacked
+    K/V pools at (layer, page) as models/llama.decode_step's do; every
+    state-space layer reads its rows of the state pool at (layer, slot), and
+    all of them are written back with one scatter a pool afterwards."""
+    cache = k_pages
+    B = tokens.shape[0]
+    seq_lens = positions + 1
+    cur_slots = pages.token_slots(cache.k, block_tables, positions)
+    ks, vs, ssms, tails = [], [], [], []
+
+    def ssm(lp, h, i):
+        s0, tail0 = state.read(cache, i)
+        out, s, tail = ssm_step(cfg, lp, h, s0, tail0.reshape(
+            B, cfg.ssm_conv - 1, cfg.ssm_conv_dim))
+        ssms.append(s), tails.append(tail)
+        return out
+
+    def attend(lp, h, i):
+        q, k, v = _qkv(cfg, lp, h)
+        ks.append(k), vs.append(v)
+        out = attention_fn(q, cache.k, cache.v, jnp.asarray(i, jnp.int32),
+                           block_tables, seq_lens, k, v)
+        return out.reshape(B, -1) @ lp["wo"]
+
+    x, held, routes = _walk(params, cfg, params["embed"][tokens],
+                            {"M": ssm, "*": attend})
+    cache = _written(cache, ks, vs, ssms, tails, held, cur_slots)
+
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    if active is not None:
+        logits = jnp.where(active[:, None], logits, 0.0)
+    out = (logits, cache, None)
+    return (*out, jnp.stack(routes)) if want_routes else out
+
+
+def _written(cache: state.Cache, ks, vs, ssms, tails, held, kv_slots
+             ) -> state.Cache:
+    """``cache`` after a step: the attention layers' new rows in their pages
+    at ``kv_slots`` (block ids, slots in them), the state layers' new rows in
+    their slots, the step's count of held choices added."""
+    if ks:
+        k, v = pages.write(cache.k, cache.v, jnp.stack(ks), jnp.stack(vs),
+                           *kv_slots)
+        cache = dataclasses.replace(cache, k=k, v=v)
+    if ssms:
+        cache = state.write(cache, ssms, tails)
+    return dataclasses.replace(cache, held=cache.held + held)
+
+
+def prefill_with_prefix(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jnp.ndarray,        # [1, S_bucket] the window's tokens (padded)
+    suffix_len: jnp.ndarray,    # [1]
+    prefix_len: jnp.ndarray,    # [1] tokens already written
+    k_pages: state.Cache,
+    v_pages: None,
+    block_table_row: jnp.ndarray,               # [1, max_blocks]
+    prior_table_row: jnp.ndarray | None = None,  # [1, prefix_bucket]
+    *,
+    want_routes: bool = False,
+):
+    """A long prompt's next window: the state-space layers continue in the
+    scan form from the slot's state and tail, the attention layers read the
+    prompt's earlier K/V back from the pages. Returns (last-token logits
+    [1, V] f32, cache, None). It continues the SLOT's state: what a prefix
+    cache would hand it is pages of another slot's tokens with no state to
+    go with them, which is why an engine that serves this family keeps no
+    prefix cache (engine/core.py)."""
+    cache = k_pages
+    B, S = tokens.shape
+    assert B == 1
+    if prior_table_row is None:
+        prior_table_row = block_table_row
+    T = prior_table_row.shape[1] * pages.block_size(cache.k)
+
+    positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    prior_pos = jnp.arange(T, dtype=jnp.int32)[None, :]
+    kv_positions = jnp.concatenate([prior_pos, positions], axis=1)
+    kv_valid = jnp.concatenate(
+        [prior_pos < prefix_len[:, None],
+         jnp.arange(S)[None, :] < suffix_len[:, None]], axis=1)
+    ks, vs, ssms, tails = [], [], [], []
+
+    def ssm(lp, h, i):
+        s0, tail0 = state.read(cache, i)
+        out, s, tail = ssm_scan(cfg, lp, h, suffix_len, s0, tail0.reshape(
+            B, cfg.ssm_conv - 1, cfg.ssm_conv_dim))
+        ssms.append(s), tails.append(tail)
+        return out
+
+    def attend(lp, h, i):
+        q, k, v = _qkv(cfg, lp, h)
+        ks.append(k), vs.append(v)
+        k_prior, v_prior = pages.read_prefix(cache.k, cache.v,
+                                             prior_table_row, layer=i)
+        out = causal_attention(
+            q, jnp.concatenate([k_prior, k], axis=1),
+            jnp.concatenate([v_prior, v], axis=1), q_positions=positions,
+            kv_positions=kv_positions, kv_valid=kv_valid)
+        return out.reshape(B, S, -1) @ lp["wo"]
+
+    x, held, routes = _walk(params, cfg, params["embed"][tokens],
+                            {"M": ssm, "*": attend})
+    cache = _written(cache, ks, vs, ssms, tails, held, pages.sequence_slots(
+        cache.k, block_table_row, suffix_len, S, prefix_len))
+
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
+    out = ((last @ params["lm_head"]).astype(jnp.float32), cache, None)
+    return (*out, jnp.stack(routes)) if want_routes else out

@@ -185,6 +185,7 @@ def forward(
     kv_valid: jnp.ndarray | None = None,  # [B, S] padding mask
     mm_embeds: jnp.ndarray | None = None,     # [B, M, D] multimodal vectors
     mm_positions: jnp.ndarray | None = None,  # [B, M] target positions
+    seq_len: jnp.ndarray | None = None,  # [B]: read by models/hybrid.py alone
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray] | None]:
     """Full-sequence forward (training / prefill).
 

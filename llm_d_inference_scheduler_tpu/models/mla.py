@@ -61,6 +61,7 @@ from ..ops import apply_rope, rms_norm, rope_table
 from ..ops.attention import NEG_INF
 from .configs import ModelConfig
 from .llama import _over_layers
+from .routing import route
 
 Params = dict[str, Any]
 
@@ -120,20 +121,6 @@ def init_params(cfg: ModelConfig, key: jax.Array,
 
 
 # ---- FFN ----------------------------------------------------------------------
-
-
-def route(cfg: ModelConfig, lp: Params, h: jnp.ndarray
-          ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(experts [T, k] int32, gates [T, k] f32) of tokens h [T, D]. Scores in
-    f32 straight from the product (a score rounded to bf16 sends near-ties to
-    other experts: ops/pallas_moe.py has the same note)."""
-    scores = jax.nn.sigmoid(jnp.dot(h, lp["router"],
-                                    preferred_element_type=jnp.float32))
-    _, idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32),
-                           cfg.experts_per_token)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-    return idx, gates * cfg.routed_scaling_factor
 
 
 def _swiglu(h, w1, w3, w2):
@@ -280,6 +267,7 @@ def forward(
     mm_embeds: jnp.ndarray | None = None,
     mm_positions: jnp.ndarray | None = None,
     want_routes: bool = False,
+    seq_len: jnp.ndarray | None = None,  # [B]: read by models/hybrid.py alone
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, None] | None]:
     """Full-sequence forward (prefill), expanded attention. Returns (logits
     [B, S, V] f32, (cache rows [L, B, S, r + dr], None) if want_kv).

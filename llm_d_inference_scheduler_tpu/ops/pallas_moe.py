@@ -120,16 +120,19 @@ def pick_tiles(rows: int, k_dim: int, n_dim: int, n_rhs: int, itemsize: int,
 
 
 def _gmm_kernel(tile_expert, n_live, layer, lhs_ref, *refs, n_rhs: int,
-                k_tiles: int):
+                k_tiles: int, relu2: bool = False):
     """One (N tile, K tile, row tile) grid step: the row tile's [tm, tk]
     against its expert's [tk, tn] block(s). With two weight operands the
-    result is silu(lhs·rhs0) * (lhs·rhs1)."""
+    result is silu(lhs·rhs0) * (lhs·rhs1); with one and ``relu2`` it is
+    relu(lhs·rhs0) squared (an expert that is not gated)."""
     del tile_expert, layer  # read by the index maps
     rhs_refs, out_ref, acc_refs = refs[:n_rhs], refs[n_rhs], refs[n_rhs + 1:]
     k, i = pl.program_id(1), pl.program_id(2)
 
     def finish(parts):
         y = parts[0] if n_rhs == 1 else jax.nn.silu(parts[0]) * parts[1]
+        if relu2:
+            y = jnp.square(jnp.maximum(y, 0.0))
         out_ref[...] = y.astype(out_ref.dtype)
 
     @pl.when(i < n_live[0])
@@ -159,11 +162,11 @@ def _gmm_kernel(tile_expert, n_live, layer, lhs_ref, *refs, n_rhs: int,
 
 def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
                     tm: int = ROW_TILE, tiles: tuple[int, int] | None = None,
-                    interpret: bool = False):
+                    interpret: bool = False, relu2: bool = False):
     """lhs [Tp, K] (group-padded rows) times the expert of each row tile out
-    of every ``rhs`` [L, E, K, N] at ``layer``; one rhs → lhs·rhs, two → the
-    SwiGLU of both. The weights come stacked over layers, with the layer a
-    prefetched scalar [1]: one layer's slice of them would reach the kernel
+    of every ``rhs`` [L, E, K, N] at ``layer``; one rhs → lhs·rhs (``relu2``:
+    its relu squared), two → the SwiGLU of both. The weights come stacked
+    over layers, with the layer a prefetched scalar [1]: one layer's slice of them would reach the kernel
     as a copy (a custom call's operand cannot be a fused slice), 2.8 GB a
     Mixtral layer. tile_expert [Tp // tm] int32; n_live [1] int32, the tiles
     that hold rows. Returns [Tp, N] in lhs.dtype; rows of tiles past n_live
@@ -197,14 +200,16 @@ def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
     )
     need = _vmem_bytes(n_row_tiles, tm, tk, tn, n_rhs, itemsize, k_tiles)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, n_rhs=n_rhs, k_tiles=k_tiles),
+        functools.partial(_gmm_kernel, n_rhs=n_rhs, k_tiles=k_tiles,
+                          relu2=relu2),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=need + 8 * 2 ** 20),
         interpret=interpret,
         # The op's name in a device trace, for whoever reduces one.
-        name="moe_grouped_swiglu" if n_rhs == 2 else "moe_grouped_matmul",
+        name=("moe_grouped_swiglu" if n_rhs == 2 else
+              "moe_grouped_relu2" if relu2 else "moe_grouped_matmul"),
     )(tile_expert, n_live, layer, lhs, *rhs)
 
 
@@ -243,17 +248,30 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
 def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
                     tm: int = ROW_TILE, interpret: bool = False,
                     tiles_up: tuple[int, int] | None = None,
-                    tiles_down: tuple[int, int] | None = None) -> jnp.ndarray:
+                    tiles_down: tuple[int, int] | None = None,
+                    first: int | None = None,
+                    gated: bool = True) -> jnp.ndarray:
     """The routed experts' part of an MoE FFN, whoever routed: token t of
     ``xt`` [T, D] through its experts ``top_idx`` [T, k], weighted by
     ``gates`` [T, k] and added. Returns [T, D] in xt.dtype. The router (its
     scores, its selection, its gates) is the model's; the weights w1/w3/w2
-    and ``layer`` are as :func:`moe_ffn_grouped` takes them."""
+    and ``layer`` are as :func:`moe_ffn_grouped` takes them.
+
+    ``first`` says that the weights hold a range of the experts the router
+    chose among: the ``n_experts`` from expert id ``first`` on. A (token,
+    expert) row whose expert is absent is dropped before the group layout --
+    it is sorted behind the last group, into rows no live tile covers, and
+    contributes nothing -- so the buffer stays T·k + n_experts·tm rows and no
+    token is dropped at any skew. ``gated`` False: an expert is
+    relu(x·w1)²·w2, and there is no w3."""
     T, D = xt.shape
     E, k = n_experts, top_idx.shape[1]
 
     # The T·k (token, expert) rows, stable-sorted by expert.
     flat_expert = top_idx.reshape(-1)                           # [T*k]
+    if first is not None:
+        here = (flat_expert >= first) & (flat_expert < first + E)
+        flat_expert = jnp.where(here, flat_expert - first, E)   # absent: last
     order = jnp.argsort(flat_expert, stable=True)               # [T*k]
     sorted_expert = flat_expert[order]
 
@@ -285,17 +303,22 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
         E - 1).astype(jnp.int32)
     n_live = (off[E:] // tm).astype(jnp.int32)                  # [1]
 
+    up = ("w1", "w3") if gated else ("w1",)
     if layer is None:
-        w1, w3, w2 = (lp[n][None] for n in ("w1", "w3", "w2"))
+        *w_up, w2 = (lp[n][None] for n in (*up, "w2"))
         layer = jnp.zeros((), jnp.int32)
     else:
-        w1, w3, w2 = lp["w1"], lp["w3"], lp["w2"]
+        *w_up, w2 = (lp[n] for n in (*up, "w2"))
     layer = layer.reshape(1).astype(jnp.int32)
-    h = _grouped_matmul(x_pad, (w1, w3), layer, tile_expert, n_live,
-                        tm=tm, tiles=tiles_up, interpret=interpret)
+    h = _grouped_matmul(x_pad, tuple(w_up), layer, tile_expert, n_live,
+                        tm=tm, tiles=tiles_up, interpret=interpret,
+                        relu2=not gated)
     out_pad = _grouped_matmul(h, (w2,), layer, tile_expert, n_live,
                               tm=tm, tiles=tiles_down, interpret=interpret)
 
-    y = (out_pad[dest].reshape(T, k, D)
-         * gates[..., None].astype(xt.dtype)).sum(axis=1)
+    rows = out_pad[dest].reshape(T, k, D)
+    if first is not None:
+        # An absent expert's row lies where no tile wrote: whatever is there.
+        rows = jnp.where(here.reshape(T, k, 1), rows, 0)
+    y = (rows * gates[..., None].astype(xt.dtype)).sum(axis=1)
     return y.astype(xt.dtype)

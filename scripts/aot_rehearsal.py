@@ -4,7 +4,7 @@ The TPU compiler is installed beside JAX and compiles for a topology that is
 described and not attached (`jax.experimental.topologies`). This hands it the
 programs a single-device `TpuEngine` serves with — the random-weight init,
 the fused decode chunk, plain prefill buckets and prefix-prefill buckets, of
-either block family (models.family) and its page pool — at a registered
+any block family (models.family) and its page pool (and state pool) — at a registered
 model's full size, and prints for each the compile seconds,
 `memory_analysis()` and whether the Pallas call (`tpu_custom_call`) is in the
 compiled text. What the compiler refuses here (a kernel it cannot lower, a
@@ -97,6 +97,7 @@ def main(argv=None) -> int:
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
+    from llm_d_inference_scheduler_tpu.kvcache import state as kvstate
     from llm_d_inference_scheduler_tpu.models import family
 
     if args.config_file:
@@ -134,6 +135,8 @@ def main(argv=None) -> int:
     eng.model = model = family(mcfg)
     eng.geom = geom = kvpages.PageGeometry.for_engine(
         mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
+    eng.state_geom = state_geom = kvstate.StateGeometry.for_engine(
+        mcfg, cfg.max_batch)
     eng._decode_attention = functools.partial(
         kvpages.latent_decode_attention if geom.latent_dim
         else kvpages.decode_attention, kernel=True)
@@ -144,8 +147,16 @@ def main(argv=None) -> int:
         lambda k: model.init_params(mcfg, k), jax.random.key(0)))
     width = geom.max_blocks_per_seq
     pages = sds(geom.shape, jnp.dtype(geom.dtype))
-    # The pool pair as a step function takes it: K and V, or (latent, None).
-    pool = (pages, None) if geom.latent_dim else (pages, pages)
+
+    def pool(rows):
+        """The pool pair as a step function of ``rows`` rows takes it: K and
+        V, (latent, None), or (pages and state as one cache, None)."""
+        if state_geom:
+            return (kvstate.Cache(
+                pages, pages, sds(state_geom.ssm_shape, jnp.float32),
+                sds(state_geom.conv_shape, jnp.dtype(state_geom.dtype)),
+                slots=sds((rows,), jnp.int32), held=sds((), jnp.int32)), None)
+        return (pages, None) if geom.latent_dim else (pages, pages)
 
     def sampling(rows):
         return (key, sds((rows,), jnp.float32), sds((rows,), jnp.int32),
@@ -159,13 +170,13 @@ def main(argv=None) -> int:
     for b in [int(x) for x in args.decode_batches.split(",") if x]:
         programs.append((f"decode {b}x{width}", jax.jit(
             eng._decode_chunk_impl, donate_argnums=(3, 4)),
-            (params, sds((b,), jnp.int32), sds((b,), jnp.int32), *pool,
+            (params, sds((b,), jnp.int32), sds((b,), jnp.int32), *pool(b),
              sds((b, width), jnp.int32), *sampling(b))))
     for spec in [s for s in args.prefill.split(",") if s]:
         bucket, rows = (int(x) for x in spec.split("x"))
         programs.append((f"prefill {rows}x{bucket}", eng._prefill_fn(bucket),
                          (params, sds((rows, bucket), jnp.int32),
-                          sds((rows,), jnp.int32), *pool,
+                          sds((rows,), jnp.int32), *pool(rows),
                           sds((rows, width), jnp.int32), *sampling(rows))))
     for spec in [s for s in args.prefix.split(",") if s]:
         suffix, prefix_blocks = (int(x) for x in spec.split("x"))
@@ -173,7 +184,7 @@ def main(argv=None) -> int:
                          eng._prefix_prefill_fn(suffix, prefix_blocks),
                          (params, sds((1, suffix), jnp.int32),
                           sds((1,), jnp.int32), sds((1,), jnp.int32),
-                          *pool, sds((1, width), jnp.int32),
+                          *pool(1), sds((1, width), jnp.int32),
                           sds((1, prefix_blocks), jnp.int32), *sampling(1))))
 
     ok = True

@@ -155,6 +155,79 @@ def test_latent_decode_step_compiles_and_copies_no_pool(one_chip):
             < dt.itemsize * math.prod(geom.shape[1:]))
 
 
+def test_hybrid_decode_step_compiles_and_copies_no_pool(one_chip):
+    """One decode step of the nemotron_h family at Nemotron-3-Super's widths,
+    one layer of each kind (a LatentMoE layer holding 128 of 512 experts, a
+    Mamba-2 layer, attention with 2 KV heads), 64 lanes on the cell's pools
+    (64 x 2,048 tokens; 65 slots of 4.19 MB of f32 state a layer). The paged
+    attention kernel takes 2 KV heads; the state pool is gathered and
+    scattered by slot in XLA, and neither it nor the page pool is made anew.
+    The step's temporaries are three sets of 64 rows (268 MB each a state
+    layer: the gathered rows, the new ones, and the gather loop's buffer);
+    PERF.md section 5 has what that costs a step on the chip."""
+    from llm_d_inference_scheduler_tpu.kvcache import state
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
+    from llm_d_inference_scheduler_tpu.models import hybrid
+    from llm_d_inference_scheduler_tpu.models.configs import (
+        NEMOTRON_3_SUPER_CUT)
+
+    m = dataclasses.replace(NEMOTRON_3_SUPER_CUT, n_layers=3,
+                            layer_pattern="EM*")
+    batch = 64
+    geom = PageGeometry.for_engine(m, batch, 2048)
+    sgeom = state.StateGeometry.for_engine(m, batch)
+    dt = jnp.dtype(m.dtype)
+    pages = _sds(one_chip, geom.shape, dt)
+    cache = state.Cache(
+        pages, pages, _sds(one_chip, sgeom.ssm_shape, jnp.float32),
+        _sds(one_chip, sgeom.conv_shape, dt),
+        slots=_sds(one_chip, (batch,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: hybrid.init_params(m, k), jax.random.key(0)))
+    compiled = jax.jit(
+        lambda *a: hybrid.decode_step(
+            a[0], m, *a[1:],
+            attention_fn=functools.partial(decode_attention, kernel=True)),
+        donate_argnums=(3,),
+    ).lower(params, _sds(one_chip, (batch,), jnp.int32),
+            _sds(one_chip, (batch,), jnp.int32), cache, None,
+            _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
+            ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    for pool, dtype in ((sgeom.ssm_shape, "f32"), (geom.shape, "bf16")):
+        shape = f"{dtype}[" + ",".join(map(str, pool)) + "]"
+        made = [ln.strip()[:160] for ln in hlo.splitlines()
+                if re.search(r"=\s*" + re.escape(shape), ln)
+                and "parameter(" not in ln and "bitcast(" not in ln
+                and "scatter" not in ln and "dynamic-update-slice" not in ln
+                and "fusion(" not in ln and "get-tuple-element(" not in ln]
+        assert not made, made
+    rows = batch * 4 * math.prod(sgeom.ssm_shape[2:])
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * rows
+
+
+@pytest.mark.parametrize("tokens", [512, 1024])
+def test_grouped_relu2_experts_compile_on_a_held_range(one_chip, tokens):
+    """Nemotron-3-Super's routed experts (1024 -> 2688 -> 1024, not gated),
+    128 of 512 held, 22 a token: the one-operand relu-squared epilogue and
+    the buffer of T x 22 + 128 x 128 rows, at the cell's grouped buckets."""
+    bf16 = functools.partial(_sds, one_chip, dtype=jnp.bfloat16)
+    held, k, z, f = 128, 22, 1024, 2688
+    lp = {"w1": bf16((5, held, z, f)), "w2": bf16((5, held, f, z))}
+    compiled = jax.jit(
+        lambda lp, x, idx, gates: pallas_moe.grouped_experts(
+            lp, x, idx, gates, held, layer=jnp.asarray(3, jnp.int32),
+            first=256, gated=False)
+    ).lower(lp, bf16((tokens, z)), _sds(one_chip, (tokens, k), jnp.int32),
+            _sds(one_chip, (tokens, k), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    assert "moe_grouped_relu2" in hlo and "moe_grouped_swiglu" not in hlo
+
+
 @pytest.mark.parametrize("d_model,d_ff,n_experts,top_k,tokens", [
     # Mixtral-8x7B, the one MoE model registered, at the two prefill buckets
     # the rule hands to the grouped form in mixtral-8x7b-cut.batch-full. Its

@@ -612,11 +612,13 @@ def _req(rid, prompt, max_tokens, temperature, stop=None):
         stop_token_ids=() if stop is None else (stop,))
 
 
-def _by_hand(requests, *, submit_at=None, abort_at=None, **cfg):
+def _by_hand(requests, *, submit_at=None, abort_at=None, model="tiny",
+             **cfg):
     """Serve ``requests`` by calling _step() by hand. ``submit_at`` /
     ``abort_at``: request id -> the step before which it is submitted (0
     unless named) / aborted. Returns (tokens by id, finish reason by id, the
-    engine)."""
+    engine). ``model``: `tiny` in float32, or a registered name on its own
+    seeded weights."""
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
 
     submit_at, abort_at = submit_at or {}, abort_at or {}
@@ -624,9 +626,9 @@ def _by_hand(requests, *, submit_at=None, abort_at=None, **cfg):
 
     async def body():
         eng = TpuEngine(EngineConfig(
-            model="tiny", backend="tpu", max_model_len=128,
+            model=model, backend="tpu", max_model_len=128,
             decode_chunk=_CHUNK, seed=11, kv_events_port=0, **cfg),
-            params=_tiny_f32())
+            params=_tiny_f32() if model == "tiny" else None)
         outs, toks, why = {}, {}, {}
         for step in range(400):
             for r in requests:

@@ -302,6 +302,76 @@ def test_param_bytes():
     assert wire.param_bytes({}) is None
 
 
+# ---------- the state pool beside the pages ----------
+
+def _state_cache(slots=3):
+    from llm_d_inference_scheduler_tpu.kvcache import state
+
+    model = dataclasses.replace(SMALL, layer_pattern="M*M", n_layers=3,
+                                ssm_heads=4, ssm_head_dim=8, ssm_state=16,
+                                ssm_groups=2)
+    sgeom = state.StateGeometry.for_engine(model, slots)
+    geom = PageGeometry.for_engine(model, slots, 64)
+    cache, none = pages.alloc(geom, state=sgeom)
+    return state, model, sgeom, geom, cache, none
+
+
+def test_state_pool_is_indexed_by_slot_with_one_row_that_is_nobodys():
+    state, model, sgeom, geom, cache, none = _state_cache()
+    assert none is None
+    assert (sgeom.n_layers, geom.n_layers) == (2, 1)    # M, M and one *
+    assert cache.ssm.shape == sgeom.ssm_shape == (2, 4, 4, 8, 16)
+    assert cache.conv.shape == sgeom.conv_shape == (2, 4, 3 * (32 + 2 * 2 * 16))
+    assert cache.ssm.dtype == jnp.float32 and cache.conv.dtype == jnp.float32
+    assert cache.k.shape == geom.shape and cache.slots is None
+    assert sgeom.slot_bytes == 2 * (4 * 8 * 16 * 4 + 3 * 96 * 4)
+    assert sgeom.pool_bytes == 4 * sgeom.slot_bytes
+
+    rng = np.random.default_rng(1)
+    new_ssm = [rng.normal(size=(2, 4, 8, 16)).astype(np.float32)
+               for _ in range(2)]
+    new_conv = [rng.normal(size=(2, 3, 96)).astype(np.float32)
+                for _ in range(2)]
+    stepped = state.write(state.at_slots(cache, [2, 0]), new_ssm, new_conv)
+    kept, held = state.take_counts(stepped)
+    assert kept.slots is None and kept.held is None and int(held) == 0
+    for layer in range(2):
+        got_ssm, got_conv = state.read(state.at_slots(kept, [0, 2]), layer)
+        np.testing.assert_array_equal(np.asarray(got_ssm),
+                                      new_ssm[layer][::-1])
+        np.testing.assert_array_equal(
+            np.asarray(got_conv), new_conv[layer][::-1].reshape(2, -1))
+    # Slot 1 and nobody's row (3) were not touched; padding lanes write the
+    # latter and leave every slot as it was.
+    assert not np.asarray(kept.ssm[:, [1, 3]]).any()
+    padded = state.write(state.at_slots(kept, [3, 3]), new_ssm, new_conv)
+    np.testing.assert_array_equal(np.asarray(padded.ssm[:, :3]),
+                                  np.asarray(kept.ssm[:, :3]))
+
+
+def test_a_first_window_starts_its_slots_state_and_writes_its_pages():
+    state, model, sgeom, geom, cache, _ = _state_cache()
+    rng = np.random.default_rng(2)
+    fresh = state.Fresh(
+        k=jnp.asarray(rng.normal(size=(1, 1, 16, 2, 32)), jnp.float32),
+        v=jnp.asarray(rng.normal(size=(1, 1, 16, 2, 32)), jnp.float32),
+        ssm=jnp.asarray(rng.normal(size=(2, 1, 4, 8, 16)), jnp.float32),
+        conv=jnp.asarray(rng.normal(size=(2, 1, 3, 96)), jnp.float32),
+        held=jnp.asarray(7, jnp.int32))
+    dirty = dataclasses.replace(cache, ssm=cache.ssm + 5.0)   # a past tenant
+    got, none = pages.write_sequences(
+        state.at_slots(dirty, [1]), None, fresh, None,
+        jnp.asarray([[2, 0, 0, 0]], jnp.int32), jnp.asarray([11]))
+    assert none is None and int(got.held) == 7
+    np.testing.assert_array_equal(np.asarray(got.ssm[:, 1]),
+                                  np.asarray(fresh.ssm[:, 0]))
+    np.testing.assert_array_equal(np.asarray(got.ssm[:, 0]),
+                                  np.asarray(dirty.ssm[:, 0]))
+    np.testing.assert_array_equal(np.asarray(got.k[0, 2, :11]),
+                                  np.asarray(fresh.k[0, 0, :11]))
+    assert not np.asarray(got.k[0, 2, 11:]).any()
+
+
 # ---------- which op attends ----------
 
 @pytest.mark.parametrize("head_dim, asked, interpret, platform, sharded, want", [

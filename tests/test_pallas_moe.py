@@ -238,3 +238,74 @@ def test_grouped_bf16():
     np.testing.assert_allclose(
         np.asarray(grouped, np.float32), np.asarray(dense, np.float32),
         atol=3e-2, rtol=3e-2)
+
+
+# ---------- a held range of the experts, and experts that are not gated ----------
+
+def _plain_experts(lp, x, idx, gates, first, count, gated):
+    """Token by token, expert by expert: the held experts' part of the
+    result."""
+    out = np.zeros(x.shape, np.float32)
+    x, idx, gates = (np.asarray(a) for a in (x, idx, gates))
+    w1, w2 = np.asarray(lp["w1"]), np.asarray(lp["w2"])
+    for t in range(x.shape[0]):
+        for j, e in enumerate(idx[t]):
+            if not first <= e < first + count:
+                continue
+            up = x[t] @ w1[e - first]
+            if gated:
+                h = up / (1 + np.exp(-up)) * (x[t] @ np.asarray(lp["w3"])[e - first])
+            else:
+                h = np.square(np.maximum(up, 0.0))
+            out[t] += gates[t, j] * (h @ w2[e - first])
+    return out
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("first,count,routed_over", [
+    (0, 4, 4),       # every expert held, named as a range
+    (4, 4, 16),      # the second quarter of sixteen
+    (12, 4, 16),     # the last quarter: absent rows sort behind nothing
+    (3, 2, 8),       # a range aligned to nothing
+])
+def test_grouped_experts_on_a_held_range(gated, first, count, routed_over):
+    """Routing is over all the experts; the rows of absent ones are dropped
+    ahead of the group layout and contribute nothing, whatever lies in the
+    buffer where no tile wrote."""
+    from llm_d_inference_scheduler_tpu.ops.pallas_moe import grouped_experts
+
+    lp, _ = _mk(E=count, D=128, F=128, seed=3)
+    T, k = 37, 3
+    keys = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(keys[0], (T, 128), jnp.float32)
+    idx = jnp.argsort(jax.random.uniform(keys[1], (T, routed_over)),
+                      axis=-1)[:, :k].astype(jnp.int32)
+    gates = jax.random.uniform(keys[2], (T, k), jnp.float32, 0.2, 1.0)
+    got = grouped_experts(lp, x, idx, gates, count, first=first, gated=gated,
+                          tm=8, interpret=True)
+    want = _plain_experts(lp, x, idx, gates, first, count, gated)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-5, rtol=3e-5)
+    held = (np.asarray(idx) >= first) & (np.asarray(idx) < first + count)
+    assert held.any() and (routed_over == count or not held.all())
+    # Tokens none of whose experts live here get exactly nothing.
+    nothing = ~held.any(axis=1)
+    assert not np.asarray(got)[nothing].any()
+
+
+def test_every_choice_absent_gives_zeros_and_no_token_is_dropped_at_any_skew():
+    from llm_d_inference_scheduler_tpu.ops.pallas_moe import grouped_experts
+
+    lp, _ = _mk(E=2, D=128, F=128, seed=4)
+    x = jax.random.normal(jax.random.key(6), (24, 128), jnp.float32)
+    gates = jnp.ones((24, 2), jnp.float32)
+    absent = jnp.tile(jnp.asarray([[0, 7]], jnp.int32), (24, 1))
+    got = grouped_experts(lp, x, absent, gates, 2, first=4, gated=False,
+                          tm=8, interpret=True)
+    assert not np.asarray(got).any()
+    # Every token on the one held expert 5: its group takes all 24 rows.
+    skew = jnp.tile(jnp.asarray([[5, 0]], jnp.int32), (24, 1))
+    got = grouped_experts(lp, x, skew, gates, 2, first=4, gated=False,
+                          tm=8, interpret=True)
+    want = _plain_experts(lp, x, skew, gates, 4, 2, False)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-5, rtol=3e-5)
+    assert np.abs(want).min(axis=1).max() > 0
