@@ -39,7 +39,7 @@ import numpy as np
 
 from ..kvcache import pages, state as state_pool, wire
 from ..models import family
-from ..ops import pallas_moe
+from ..ops import pallas_moe, pallas_ssm
 from ..utils.hashing import chain_block_hashes
 from .blocks import BlockAllocator, PrefixCachingAllocator
 from .config import EngineConfig
@@ -204,6 +204,7 @@ class TpuEngine:
             pages.latent_decode_attention if self.geom.latent_dim
             else pages.decode_attention, kernel=cfg.pallas_attention,
             interpret=cfg.pallas_interpret)
+        self._bind_state_form(self.device.platform)
         self._bind_moe_form(self.device.platform)
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
@@ -556,6 +557,8 @@ class TpuEngine:
                                      if self.state_geom else 0),
                 "state_pool_bytes": (self.state_geom.pool_bytes
                                      if self.state_geom else 0),
+                "state_update": (self.mcfg.ssm_impl if self.state_geom
+                                 else None),
                 "prefix_caching": isinstance(self.allocator,
                                              PrefixCachingAllocator),
                 "off_for_state_layers": ([
@@ -619,6 +622,22 @@ class TpuEngine:
         (_, _, k_pages, v_pages), toks = jax.lax.scan(
             step, (tokens, positions, k_pages, v_pages), keys)
         return toks, k_pages, v_pages
+
+    def _bind_state_form(self, platform: str) -> None:
+        """How a decode step fetches its slots' recurrent states
+        (pallas_ssm.use_kernel), from what this engine is: every step program
+        traces with the answer (``mcfg.ssm_impl``), _device_call counts by
+        it. An engine with a state pool is unsharded (it refused the rest
+        at start)."""
+        if not self.mcfg.n_state_layers:
+            return
+        interpret = self.cfg.pallas_interpret
+        kernel = pallas_ssm.use_kernel(
+            self.mcfg.ssm_state, self.mcfg.ssm_head_dim, platform=platform,
+            sharded=False, interpret=interpret)
+        self.mcfg = dataclasses.replace(
+            self.mcfg, ssm_impl="gathered" if not kernel else
+            "kernel_interpret" if interpret else "kernel")
 
     def _bind_moe_form(self, platform: str) -> None:
         """The MoE FFN's form is chosen per program, from its token count
@@ -2483,6 +2502,10 @@ class TpuEngine:
             # first window starts afresh.
             self.telemetry.ssm_tokens.labels(
                 form="step" if decode else "scan").inc(rows * steps)
+            if decode:
+                self.telemetry.ssm_state_updates.labels(
+                    form=self.mcfg.ssm_impl.split("_")[0]).inc(
+                        rows * steps * self.state_geom.n_layers)
             if op[0] == "prefill" and not args.get("warm"):
                 self.telemetry.ssm_slot_prefills.inc(
                     int(np.sum(args["slots"] < self.cfg.max_batch)))

@@ -172,6 +172,14 @@ class EngineTelemetry:
             "continuation window the chunked `scan`); counted on the host at "
             "dispatch, empty for a model without state layers",
             ("form",), registry=self.registry)
+        self.ssm_state_updates = Counter(
+            "jetstream:ssm_state_updates_total",
+            "Recurrent states a dispatched decode step updates (its lanes, "
+            "padding among them, times the state layers), by how the step "
+            "fetches them: `kernel` in place in the state pool "
+            "(ops/pallas_ssm.py), `gathered` by slot in XLA; counted on the "
+            "host at dispatch by the rule the program traced with",
+            ("form",), registry=self.registry)
         self.ssm_slot_prefills = Counter(
             "jetstream:ssm_slot_prefills_total",
             "Slots whose recurrent state a first prefill window started "

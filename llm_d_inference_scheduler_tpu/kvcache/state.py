@@ -15,11 +15,24 @@ warm-up programs read and write it, as padded page writes go to the trash
 block.
 
 Who writes a slot's rows. A request's first prefill window writes them whole
-(it starts from zeros and never reads them); later windows and every decode
-step of a lane in that slot read and rewrite them; nobody else touches them.
-The device's in-order stream is the guarantee: a lane that overshoots a
-finished request writes its own slot only, and the next request's first
-window, dispatched later, overwrites it.
+(it starts from zeros and never reads them: :func:`start`); a later window
+reads them (:func:`read`) and writes every layer's back at its end
+(:func:`write`); a decode step of a lane in that slot updates its state a
+layer, when the layer runs (:func:`recur`), and writes the tails at the
+step's end; nobody else touches them. The device's in-order stream is the
+guarantee: a lane that overshoots a finished request writes its own slot
+only, and the next request's first window, dispatched later, overwrites it.
+
+How a decode step fetches its rows of ``ssm`` is :func:`recur`'s to choose,
+by what the caller says the program traced with (ops/pallas_ssm.use_kernel):
+the kernel updates the slots' rows in place in the pool, a slot's 4 MB a
+layer once into VMEM and once back; the plain form gathers them by slot,
+computes and scatters them back, the CPU's way and the form the kernel is
+tested against. A real slot appears at most once in a step, so the update in
+place has no hazard between lanes; padding lanes all name nobody's slot and
+may read it stale and write it in any order. The tail (61 KB a slot a layer
+at Nemotron-3-Super's widths, 1.4% of the bytes) is gathered and scattered by
+XLA in both.
 
 A model with state layers hands its whole cache through the step functions as
 one value, :class:`Cache`, where the other families hand ``(k_pages,
@@ -39,6 +52,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..ops import pallas_ssm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,21 +163,54 @@ def take_counts(cache: Any) -> tuple[Any, jax.Array | None]:
 def read(cache: Cache, layer: int) -> tuple[jax.Array, jax.Array]:
     """State layer ``layer`` of this step's rows: (state [B, heads, head_dim,
     state] f32, tail [B, tail rows * channels], the rows flat as stored)."""
-    return cache.ssm[layer, cache.slots], cache.conv[layer, cache.slots]
+    return cache.ssm[layer, cache.slots], tail(cache, layer)
 
 
-def write(cache: Cache, ssm: list[jax.Array], conv: list[jax.Array]) -> Cache:
+def tail(cache: Cache, layer: int) -> jax.Array:
+    """The convolution's tail of this step's rows at state layer ``layer``
+    [B, tail rows * channels], the rows flat as stored: what a decode step
+    gathers (its states stay where they are, :func:`recur`)."""
+    return cache.conv[layer, cache.slots]
+
+
+def recur(cache: Cache, layer: int, keep: jax.Array, dtx: jax.Array,
+          b: jax.Array, c: jax.Array, *, impl: str = "gathered"
+          ) -> tuple[Cache, jax.Array]:
+    """One step of the recurrence on this step's rows of state layer
+    ``layer``: ``S <- S * keep + dtx (x) b``, ``y = S c`` (the operands as
+    ops/pallas_ssm.update_rows takes them, a row a lane). Returns (the cache
+    with those rows updated, y [B, heads, head_dim]). ``impl`` is the form
+    the program traces with: "kernel" (in place in the pool;
+    "kernel_interpret" the same through the interpreter, for tests on the
+    CPU) or "gathered"."""
+    if impl.startswith("kernel"):
+        ssm, y = pallas_ssm.update_in_place(
+            cache.ssm, jnp.asarray(layer, jnp.int32), cache.slots, keep, dtx,
+            b, c, interpret=impl == "kernel_interpret")
+    else:
+        rows, y = pallas_ssm.update_rows(cache.ssm[layer, cache.slots], keep,
+                                         dtx, b, c)
+        ssm = cache.ssm.at[layer, cache.slots].set(rows)
+    return dataclasses.replace(cache, ssm=ssm), y
+
+
+def write(cache: Cache, ssm: list[jax.Array] | None, conv: list[jax.Array]
+          ) -> Cache:
     """Every state layer's new rows, in layer order (``ssm[l]`` [B, heads,
     head_dim, state], ``conv[l]`` [B, tail rows, channels]), into this step's
-    slots: one scatter a pool. Padding rows all name nobody's slot, and which
-    of them lands there is nobody's concern."""
+    slots: one scatter a pool. ``ssm`` None: the states were updated a layer
+    (:func:`recur`), the tails alone are written. Padding rows all name
+    nobody's slot, and which of them lands there is nobody's concern."""
     B = cache.slots.shape[0]
-    new_ssm = jnp.stack(ssm).astype(cache.ssm.dtype)
     new_conv = jnp.stack(conv).reshape(len(conv), B, -1).astype(
         cache.conv.dtype)
+    cache = dataclasses.replace(
+        cache, conv=cache.conv.at[:, cache.slots].set(new_conv))
+    if ssm is None:
+        return cache
+    new_ssm = jnp.stack(ssm).astype(cache.ssm.dtype)
     return dataclasses.replace(
-        cache, ssm=cache.ssm.at[:, cache.slots].set(new_ssm),
-        conv=cache.conv.at[:, cache.slots].set(new_conv))
+        cache, ssm=cache.ssm.at[:, cache.slots].set(new_ssm))
 
 
 def start(cache: Cache, fresh: Fresh, k_pages: jax.Array,

@@ -72,6 +72,12 @@ class ModelConfig:
     ssm_chunk: int = 128
     # The step sizes dt_bias is drawn for (random weights only).
     ssm_dt_range: tuple[float, float, float] = (0.001, 0.1, 1e-4)  # min, max, floor
+    # How a decode step fetches its slots' rows of the state pool (kvcache/
+    # state.recur): "gathered" (by slot, in XLA: the CPU's way and the plain
+    # form) | "kernel" (in place in the pool, ops/pallas_ssm.py) |
+    # "kernel_interpret" (the kernel through the interpreter: CPU tests). The
+    # engine sets it from what it is (pallas_ssm.use_kernel); tests force one.
+    ssm_impl: str = "gathered"
     # "E": routed experts of width moe_d_ff between a projection down to
     # moe_latent_dim and one back up, not gated (relu squared), beside a
     # shared expert of width shared_d_ff on the model's own width.

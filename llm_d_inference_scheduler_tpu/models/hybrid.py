@@ -30,7 +30,10 @@ chooses its attention's form:
   a recurrence over their end states. Padded positions get ``D = 0``: no decay
   and no input, so the state a bucket leaves is the state its true last token
   left, and the convolution's tail is gathered at the true length.
-- *step* (one position a sequence: ``decode_step``): the recurrence as written.
+- *step* (one position a sequence: ``decode_step``): the recurrence as
+  written, on the sequences' rows of the state pool where they lie
+  (kvcache/state.recur: in place through ops/pallas_ssm.py's kernel, or
+  gathered by slot, as ``cfg.ssm_impl`` says).
 
 The state is float32; the products take their operands in the model's dtype
 with float32 accumulation.
@@ -290,16 +293,22 @@ def ssm_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
     return (_gated_out(cfg, lp, y, z), state1.reshape(B, H, P, N), tail)
 
 
-def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray, state0: jnp.ndarray,
-             tail0: jnp.ndarray
-             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+             cache: state.Cache, layer: int
+             ) -> tuple[jnp.ndarray, state.Cache, jnp.ndarray]:
     """A state-space layer for one position a sequence, the recurrence as
-    written: h [B, D] (normed), state0 [B, heads, head_dim, state] f32, tail0
-    [B, conv - 1, channels]. Returns (out [B, D], state, tail)."""
+    written: h [B, D] (normed), the sequences' states and tails the rows of
+    ``cache`` at state layer ``layer``. The recurrence itself runs where the
+    states live (``state.recur``, in the form ``cfg.ssm_impl`` names); what
+    leads up to it and what follows is here. Returns (out [B, D], the cache
+    with the states updated, the new tails [B, conv - 1, channels], which
+    the caller writes)."""
     B = h.shape[0]
-    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    H, P, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups
     R = H // G
     z, xbc, dt = _split_in(cfg, lp, h)
+    tail0 = state.tail(cache, layer).reshape(B, cfg.ssm_conv - 1,
+                                             cfg.ssm_conv_dim)
     seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
     conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
         seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
@@ -307,13 +316,11 @@ def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray, state0: jnp.ndarray,
     x, b_mat, c_mat = (t.astype(jnp.float32) for t in (x, b_mat, c_mat))
     dt = _step_size(lp, dt).reshape(B, G, R)
     keep = jnp.exp(dt * -jnp.exp(lp["A_log"]).reshape(G, R))
-    s = state0.reshape(B, G, R, P, N)
-    s = (s * keep[..., None, None]
-         + (dt[..., None] * x)[..., None] * b_mat[:, :, None, None, :])
-    y = (jnp.einsum("bgrpn,bgn->bgrp", s, c_mat)
-         + lp["D"].reshape(G, R, 1) * x)
-    return (_gated_out(cfg, lp, y.reshape(B, H * P), z),
-            s.reshape(B, H, P, N), seq[:, 1:])
+    cache, y = state.recur(
+        cache, layer, keep.reshape(B, H), (dt[..., None] * x).reshape(B, H, P),
+        b_mat, c_mat, impl=cfg.ssm_impl)
+    y = y.reshape(B, G, R, P) + lp["D"].reshape(G, R, 1) * x
+    return _gated_out(cfg, lp, y.reshape(B, H * P), z), cache, seq[:, 1:]
 
 
 # ---- the stack ------------------------------------------------------------------
@@ -431,19 +438,19 @@ def decode_step(
     """One decode step, the state-space layers in the step form; returns
     (logits [B, V] f32, cache, None). The attention layers read the stacked
     K/V pools at (layer, page) as models/llama.decode_step's do; every
-    state-space layer reads its rows of the state pool at (layer, slot), and
-    all of them are written back with one scatter a pool afterwards."""
+    state-space layer updates its rows of the state pool at (layer, slot)
+    when it runs, and the tails are written back with one scatter
+    afterwards."""
     cache = k_pages
     B = tokens.shape[0]
     seq_lens = positions + 1
     cur_slots = pages.token_slots(cache.k, block_tables, positions)
-    ks, vs, ssms, tails = [], [], [], []
+    ks, vs, tails = [], [], []
 
     def ssm(lp, h, i):
-        s0, tail0 = state.read(cache, i)
-        out, s, tail = ssm_step(cfg, lp, h, s0, tail0.reshape(
-            B, cfg.ssm_conv - 1, cfg.ssm_conv_dim))
-        ssms.append(s), tails.append(tail)
+        nonlocal cache
+        out, cache, tail = ssm_step(cfg, lp, h, cache, i)
+        tails.append(tail)
         return out
 
     def attend(lp, h, i):
@@ -455,7 +462,7 @@ def decode_step(
 
     x, held, routes = _walk(params, cfg, params["embed"][tokens],
                             {"M": ssm, "*": attend})
-    cache = _written(cache, ks, vs, ssms, tails, held, cur_slots)
+    cache = _written(cache, ks, vs, None, tails, held, cur_slots)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
@@ -469,12 +476,13 @@ def _written(cache: state.Cache, ks, vs, ssms, tails, held, kv_slots
              ) -> state.Cache:
     """``cache`` after a step: the attention layers' new rows in their pages
     at ``kv_slots`` (block ids, slots in them), the state layers' new rows in
-    their slots, the step's count of held choices added."""
+    their slots (``ssms`` None: a decode step, whose states are in the cache
+    already), the step's count of held choices added."""
     if ks:
         k, v = pages.write(cache.k, cache.v, jnp.stack(ks), jnp.stack(vs),
                            *kv_slots)
         cache = dataclasses.replace(cache, k=k, v=v)
-    if ssms:
+    if tails:
         cache = state.write(cache, ssms, tails)
     return dataclasses.replace(cache, held=cache.held + held)
 

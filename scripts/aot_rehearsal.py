@@ -140,7 +140,8 @@ def main(argv=None) -> int:
     eng._decode_attention = functools.partial(
         kvpages.latent_decode_attention if geom.latent_dim
         else kvpages.decode_attention, kernel=True)
-    eng._bind_moe_form("tpu")  # the described chip, not this host's CPU
+    eng._bind_state_form("tpu")  # the described chip, not this host's CPU
+    eng._bind_moe_form("tpu")
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(

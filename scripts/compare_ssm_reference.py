@@ -8,7 +8,9 @@ What is compared. One sequence of random byte-range token ids. The program
 side runs what `TpuEngine`'s step functions trace — `models.hybrid.forward` /
 `prefill_with_prefix` / `decode_step` with the MoE form `TpuEngine._model_for`
 gives each shape, the decode attention the engine binds, the page writes of
-`kvcache/pages.py` and the slot state of `kvcache/state.py`, the engine's
+`kvcache/pages.py` and the slot state of `kvcache/state.py` (a decode step's
+states updated in place by ops/pallas_ssm.py's kernel where the engine's rule
+says so: `state_update` in each line), the engine's
 pools at `--max-batch` x `--max-model-len` — jitted here to hand back logits
 before the sampler and the experts each token chose, where the engine's own
 programs hand back a sampled token. `--max-batch` lanes, lane i in slot i,
@@ -119,6 +121,10 @@ def main(argv=None) -> int:
     ap.add_argument("--dtype", default="")
     ap.add_argument("--degrade", default="",
                     choices=("", "state16", "norouted"))
+    ap.add_argument("--state-update", default="",
+                    choices=("", "gathered", "kernel", "kernel_interpret"),
+                    help="how decode steps fetch the slots' states; default "
+                         "what the engine's rule gives on this device")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 
@@ -151,6 +157,10 @@ def main(argv=None) -> int:
     # threads (as scripts/aot_rehearsal.py carries them).
     eng = object.__new__(TpuEngine)
     eng.cfg, eng.mcfg = cfg, mcfg
+    if args.state_update:
+        eng.mcfg = dataclasses.replace(mcfg, ssm_impl=args.state_update)
+    else:
+        eng._bind_state_form(device.platform)
     eng._bind_moe_form(device.platform)
     geom = pages.PageGeometry.for_engine(mcfg, cfg.max_batch,
                                          cfg.max_model_len)
@@ -378,6 +388,7 @@ def main(argv=None) -> int:
                 "model": mcfg.name, "n_layers": mcfg.n_layers,
                 "lanes": B, "lane_tokens": sorted(set(lens)),
                 "decode_steps": K, "attention_kernel": bool(kernel),
+                "state_update": eng.mcfg.ssm_impl,
                 "pool_bytes": geom.pool_bytes,
                 "state_pool_bytes": state_geom.pool_bytes,
                 "memory": {k: v for k, v in (device.memory_stats() or {}).items()

@@ -19,10 +19,20 @@ too. Then it checks the mathematics (``--moe-check-seeds``): the layer's
 output, grouped and dense against a plain float32 FFN, and the last token's
 logits through the four-layer model, grouped against dense.
 
+``--ssm`` times the state-space layers' decode kernel alone instead (one
+step of the recurrence on the state pool in place, ops/pallas_ssm.py) at a
+model's widths and the cell's lanes, every state layer in one program on a
+donated pool, against the gathered form the CPU keeps (gather by slot, the
+plain update, scatter back): ms a call (one layer), the GB/s and the share of
+the chip's peak that the bytes the call needs (chipbench/kernels_ssm.py) come
+to, and how far the kernel's new state and y lie from the plain form's.
+
 Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--points 16x1000,16x300,8x300]
        python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
            [--moe-candidates] [--moe-check-seeds 0,1]
+       python scripts/microbench_decode.py --ssm [--ssm-lanes 64,16,2]
+           [--ssm-head-blocks 128,32]
 """
 
 from __future__ import annotations
@@ -230,6 +240,86 @@ def moe_main(args):
         del params
 
 
+def ssm_main(args):
+    """The state update of every state layer of ``--ssm-model`` at ``lanes``
+    lanes: the kernel (a head block each of ``--ssm-head-blocks``; the
+    default is the one the shapes choose) and the gathered form."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.models.configs import get_config
+    from llm_d_inference_scheduler_tpu.ops import pallas_ssm
+
+    kernels = _chipbench_kernels()      # puts chipbench/ on the path
+    import kernels_ssm
+
+    m = get_config(args.ssm_model)
+    L, H, P, N, G = (m.n_state_layers, m.ssm_heads, m.ssm_head_dim,
+                     m.ssm_state, m.ssm_groups)
+    device = jax.devices()[0]
+    peak = (None if args.ssm_interpret
+            else kernels.peaks(device.device_kind)["bytes_per_s"])
+
+    def gathered(ssm, layer, slots, *small):
+        rows, y = pallas_ssm.update_rows(ssm[layer, slots], *small)
+        return ssm.at[layer, slots].set(rows), y
+
+    def every_layer(update, ssm, slots, *small):
+        ys = []
+        for layer in range(L):
+            ssm, y = update(ssm, jnp.asarray(layer, jnp.int32), slots, *small)
+            ys.append(y)
+        return ssm, jnp.stack(ys)
+
+    for lanes in map(int, args.ssm_lanes.split(",")):
+        keys = jax.random.split(jax.random.key(lanes), 6)
+        slots = jax.random.permutation(keys[0], lanes).astype(jnp.int32)
+        small = (jax.random.uniform(keys[1], (lanes, H), jnp.float32, .5, 1.),
+                 jax.random.normal(keys[2], (lanes, H, P), jnp.float32),
+                 jax.random.normal(keys[3], (lanes, G, N), jnp.float32),
+                 jax.random.normal(keys[4], (lanes, G, N), jnp.float32))
+
+        def pool():
+            return jax.random.normal(keys[5], (L, lanes + 1, H, P, N),
+                                     jnp.float32)
+
+        need = kernels_ssm.ssm_state_update(lanes, H, P, N, G)
+        forms = {"gathered": gathered}
+        for hb in (args.ssm_head_blocks.split(",")
+                   if args.ssm_head_blocks else [None]):
+            forms[f"kernel hb={hb or pallas_ssm.pick_head_block(H, P, N)}"] = (
+                functools.partial(pallas_ssm.update_in_place,
+                                  head_block=hb and int(hb),
+                                  interpret=args.ssm_interpret))
+        want = None
+        for name, update in forms.items():
+            fn = jax.jit(functools.partial(every_layer, update),
+                         donate_argnums=(0,))
+            ssm, y = fn(pool(), slots, *small)
+            got = (np.asarray(ssm[:, slots[:2]]), np.asarray(y))
+            want = want or got
+            t0 = time.perf_counter()
+            for _ in range(args.ssm_iters):
+                ssm, y = fn(ssm, slots, *small)
+            jax.block_until_ready(ssm)
+            call_s = (time.perf_counter() - t0) / args.ssm_iters / L
+            del ssm
+            print(json.dumps({
+                "component": f"ssm_state_update {name}", "lanes": lanes,
+                "layers": L, "ms_per_call": round(call_s * 1e3, 4),
+                "ms_per_step": round(call_s * L * 1e3, 3),
+                "needed_GBps": round(need["bytes"] / call_s / 1e9, 1),
+                "share_of_peak_pct": (
+                    None if peak is None else
+                    round(100 * need["bytes"] / call_s / peak, 1)),
+                "state_vs_gathered": float(
+                    np.abs(got[0] - want[0]).max() / np.abs(want[0]).max()),
+                "y_vs_gathered": float(
+                    np.abs(got[1] - want[1]).max() / np.abs(want[1]).max()),
+                "device": device.device_kind}), flush=True)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen3-4b")
@@ -243,6 +333,18 @@ def main(argv=None):
     ap.add_argument("--moe-check-seeds", default="0,1")
     ap.add_argument("--moe-interpret", action="store_true",
                     help="interpret the kernels: rehearses the control flow "
+                         "on the CPU; its times mean nothing")
+    ap.add_argument("--ssm", action="store_true",
+                    help="time the state-space layers' decode kernel alone "
+                         "instead")
+    ap.add_argument("--ssm-model", default="nemotron-3-super-cut")
+    ap.add_argument("--ssm-lanes", default="64,16,2")
+    ap.add_argument("--ssm-head-blocks", default="",
+                    help="head blocks to time, comma-separated; default the "
+                         "one the shapes choose")
+    ap.add_argument("--ssm-iters", type=int, default=20)
+    ap.add_argument("--ssm-interpret", action="store_true",
+                    help="interpret the kernel: rehearses the control flow "
                          "on the CPU; its times mean nothing")
     ap.add_argument("--points", default="16x1000,16x300,8x300",
                     help="lanes x context tokens a lane, comma-separated")
@@ -260,6 +362,8 @@ def main(argv=None):
     configure_compile_cache()
     if args.moe:
         return moe_main(args)
+    if args.ssm:
+        return ssm_main(args)
 
     from llm_d_inference_scheduler_tpu.engine.sampling import sample_tokens
     from llm_d_inference_scheduler_tpu.kvcache import pages

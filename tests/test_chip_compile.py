@@ -155,24 +155,32 @@ def test_latent_decode_step_compiles_and_copies_no_pool(one_chip):
             < dt.itemsize * math.prod(geom.shape[1:]))
 
 
-def test_hybrid_decode_step_compiles_and_copies_no_pool(one_chip):
+@pytest.mark.parametrize("steps", [1, 2])
+def test_hybrid_decode_step_compiles_and_copies_no_pool(one_chip, steps):
     """One decode step of the nemotron_h family at Nemotron-3-Super's widths,
     one layer of each kind (a LatentMoE layer holding 128 of 512 experts, a
     Mamba-2 layer, attention with 2 KV heads), 64 lanes on the cell's pools
-    (64 x 2,048 tokens; 65 slots of 4.19 MB of f32 state a layer). The paged
-    attention kernel takes 2 KV heads; the state pool is gathered and
-    scattered by slot in XLA, and neither it nor the page pool is made anew.
-    The step's temporaries are three sets of 64 rows (268 MB each a state
-    layer: the gathered rows, the new ones, and the gather loop's buffer);
-    PERF.md section 5 has what that costs a step on the chip."""
+    (64 x 2,048 tokens; 65 slots of 4.19 MB of f32 state a layer), alone and
+    as a ``lax.scan`` of two steps, the way the engine's decode chunk carries
+    the cache. The paged attention kernel takes 2 KV heads; the state layer's
+    rows are updated in place in the pool by ops/pallas_ssm.py's kernel, the
+    pool aliased through the call and through the loop, and neither it nor
+    the page pool is made anew. What went with the gathered form: the three
+    sets of 64 rows a state layer (268 MB each: the gathered rows, the new
+    ones, the gather loop's buffer) that made the step's temporaries 3.5 sets
+    of rows; what is left is under one."""
     from llm_d_inference_scheduler_tpu.kvcache import state
     from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
     from llm_d_inference_scheduler_tpu.models import hybrid
     from llm_d_inference_scheduler_tpu.models.configs import (
         NEMOTRON_3_SUPER_CUT)
+    from llm_d_inference_scheduler_tpu.ops import pallas_ssm
 
     m = dataclasses.replace(NEMOTRON_3_SUPER_CUT, n_layers=3,
                             layer_pattern="EM*")
+    assert pallas_ssm.use_kernel(m.ssm_state, m.ssm_head_dim, platform="tpu",
+                                 sharded=False)
+    m = dataclasses.replace(m, ssm_impl="kernel")
     batch = 64
     geom = PageGeometry.for_engine(m, batch, 2048)
     sgeom = state.StateGeometry.for_engine(m, batch)
@@ -186,17 +194,30 @@ def test_hybrid_decode_step_compiles_and_copies_no_pool(one_chip):
     params = jax.tree.map(
         lambda a: _sds(one_chip, a.shape, a.dtype),
         jax.eval_shape(lambda k: hybrid.init_params(m, k), jax.random.key(0)))
-    compiled = jax.jit(
-        lambda *a: hybrid.decode_step(
-            a[0], m, *a[1:],
-            attention_fn=functools.partial(decode_attention, kernel=True)),
-        donate_argnums=(3,),
-    ).lower(params, _sds(one_chip, (batch,), jnp.int32),
-            _sds(one_chip, (batch,), jnp.int32), cache, None,
-            _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
-            ).compile()
+
+    def chunk(params, tokens, positions, cache, tables):
+        def step(carry, _):
+            tokens, positions, cache = carry
+            logits, cache, _ = hybrid.decode_step(
+                params, m, tokens, positions, cache, None, tables,
+                attention_fn=functools.partial(decode_attention, kernel=True))
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (nxt, positions + 1, cache), nxt
+
+        if steps == 1:
+            (_, _, cache), toks = step((tokens, positions, cache), None)
+        else:
+            (_, _, cache), toks = jax.lax.scan(
+                step, (tokens, positions, cache), None, length=steps)
+        return toks, cache
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        params, _sds(one_chip, (batch,), jnp.int32),
+        _sds(one_chip, (batch,), jnp.int32), cache,
+        _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
+    ).compile()
     hlo = compiled.as_text()
-    assert "tpu_custom_call" in hlo
+    assert "tpu_custom_call" in hlo and "ssm_state_update" in hlo
     for pool, dtype in ((sgeom.ssm_shape, "f32"), (geom.shape, "bf16")):
         shape = f"{dtype}[" + ",".join(map(str, pool)) + "]"
         made = [ln.strip()[:160] for ln in hlo.splitlines()
@@ -206,7 +227,39 @@ def test_hybrid_decode_step_compiles_and_copies_no_pool(one_chip):
                 and "fusion(" not in ln and "get-tuple-element(" not in ln]
         assert not made, made
     rows = batch * 4 * math.prod(sgeom.ssm_shape[2:])
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * rows
+
+
+@pytest.mark.parametrize("lanes,heads,head_dim,state_dim,groups,head_block", [
+    # Nemotron-3-Super's state layer at the cell's smallest and largest lane
+    # buckets: a slot-layer's 4 MB whole (16 MiB of VMEM in and out, past the
+    # compiler's default scoped limit, which the call raises), and in blocks.
+    (2, 128, 64, 128, 8, None), (64, 128, 64, 128, 8, None),
+    (64, 128, 64, 128, 8, 32),
+    # What else the rule lets through: a wider state, heads that are no whole
+    # lane tile, a head of one sublane tile.
+    (8, 64, 8, 256, 4, None), (2, 24, 16, 128, 3, None),
+    (16, 256, 64, 128, 8, None)])
+def test_ssm_state_update_compiles(one_chip, lanes, heads, head_dim,
+                                   state_dim, groups, head_block):
+    from llm_d_inference_scheduler_tpu.ops import pallas_ssm
+
+    assert pallas_ssm.use_kernel(state_dim, head_dim, platform="tpu",
+                                 sharded=False)
+    f32 = functools.partial(_sds, one_chip, dtype=jnp.float32)
+    pool = (5, lanes + 1, heads, head_dim, state_dim)
+    compiled = jax.jit(
+        functools.partial(pallas_ssm.update_in_place, head_block=head_block),
+        donate_argnums=(0,)
+    ).lower(f32(pool), _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (lanes,), jnp.int32), f32((lanes, heads)),
+            f32((lanes, heads, head_dim)), f32((lanes, groups, state_dim)),
+            f32((lanes, groups, state_dim))).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "ssm_state_update" in hlo
+    # In place: the pool is the call's and the program's, no second one.
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 4 * math.prod(pool[1:]))
 
 
 @pytest.mark.parametrize("tokens", [512, 1024])
