@@ -46,7 +46,7 @@ from .config import EngineConfig
 from .multihost import ChannelBroken
 from .request import EngineRequest, FinishReason, TokenEvent
 from .sampling import sample_tokens
-from .telemetry import EngineTelemetry, PrefixHitLog
+from .telemetry import LOOP_PHASES, EngineTelemetry, LoopStalls, PrefixHitLog
 from .tokenizer import get_tokenizer
 
 log = logging.getLogger("engine.core")
@@ -501,6 +501,12 @@ class TpuEngine:
         self._inflight: _Chunk | None = None
         self._last_readback = 0.0
         self._clock = time.monotonic
+        # The period now running (from the last readback, or from the
+        # dispatch of a chunk that went out alone): seconds by phase, prefills
+        # finalized, whether a shape ran for the first time in it; judged at
+        # its readback (_land_chunk).
+        self.stalls = LoopStalls(self.telemetry)
+        self._begin_period()
         log.info("engine %s up: %s", self.engine_id,
                  json.dumps(self.describe()))
 
@@ -1050,7 +1056,14 @@ class TpuEngine:
             with jax.profiler.TraceAnnotation("engine." + name):
                 yield
         finally:
-            self.telemetry.loop_seconds[name].inc(self._clock() - t0)
+            dt = self._clock() - t0
+            self.telemetry.loop_seconds[name].inc(dt)
+            self._period[name] += dt
+
+    def _begin_period(self) -> None:
+        self._period = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._period_prefills = 0
+        self._period_first_call = False
 
     def _run(self):
         if self.kv_events is not None:
@@ -1701,6 +1714,7 @@ class TpuEngine:
         with self._phase("decode_wait"):
             landed = [int(self._read_tokens(slot.pending_tok)[slot.pending_idx])
                       for _, slot in pending]
+        self._period_prefills += len(pending)
         with self._phase("finalize_prefills"):
             for (idx, slot), tok in zip(pending, landed):
                 slot.pending_tok = None
@@ -2514,6 +2528,7 @@ class TpuEngine:
         dt = time.monotonic() - t0
         if key not in self._seen_op_shapes:
             self._seen_op_shapes.add(key)
+            self._period_first_call = True
             self.telemetry.compile_events.labels(op=key[0], bucket=key[1]).inc()
             self.telemetry.compile_duration.observe(dt)
         elif key[0] in ("prefill", "prefix_prefill", "mm_prefill"):
@@ -2932,6 +2947,8 @@ class TpuEngine:
             args = dict(slots=slots, positions=positions, tables=tables,
                         **self._sample_np(reqs))
         t0 = self._clock()
+        if self._inflight is None:
+            self._begin_period()    # nothing ahead of it: its period is its own
         with self._phase("decode_dispatch"):
             toks = self._device_call(("decode",), args)
         self.telemetry.decode_chunks[
@@ -2951,9 +2968,19 @@ class TpuEngine:
             # ahead of it was done, which the host saw at that one's
             # readback. The first call of a shape goes to the compile
             # histogram instead.
-            self.telemetry.decode_step.observe(
-                now - max(chunk.t0, self._last_readback))
+            period = now - max(chunk.t0, self._last_readback)
+            self.telemetry.decode_step.observe(period)
+            # A period in which some shape ran for the first time (the next
+            # chunk's wider bucket, a prefill bucket) holds that program's
+            # build: like the first call itself, it is nobody's stall.
+            stall = None if self._period_first_call else self.stalls.note(
+                period, self._period, loop_clock_s=now,
+                lanes=len(chunk.lanes), batch=int(sampled.shape[1]),
+                prefills=self._period_prefills)
+            if stall is not None:
+                log.warning("engine loop stall %s", json.dumps(stall))
         self._last_readback = now
+        self._begin_period()
         with self._phase("decode_book"):
             self._book_chunk(chunk.lanes, sampled)
 

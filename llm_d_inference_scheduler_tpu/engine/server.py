@@ -154,7 +154,8 @@ class ProfileControl:
         self._start_trace_s = self._t_traced - t0
         self._state = "tracing"
         return web.json_response({"tracing": True,
-                                  "start_trace_s": self._start_trace_s})
+                                  "start_trace_s": self._start_trace_s,
+                                  "loop_clock_s": self._t_traced})
 
     async def stop(self, request: web.Request) -> web.Response:
         if self._state != "tracing":
@@ -178,9 +179,14 @@ class EngineServer:
         import os
 
         from ..router.resilience import FaultInjector
+        from ..router.schedpool import LoopLagMonitor
 
         self.cfg = cfg
         self.engine = engine or make_engine(cfg)
+        # The heartbeat of THIS loop, which writes every streamed token while
+        # the engine thread holds the GIL a chunk at a time: the gateway's
+        # class, into the engine's registry (either backend).
+        self.loop_lag = LoopLagMonitor(self.engine.telemetry.event_loop_lag)
         self.draining = False  # SIGTERM drain: health 503s, work finishes
         self._tls = None       # TlsServing when secure_serving is on
         # Chaos shim + end-to-end deadline enforcement ride one middleware
@@ -217,6 +223,7 @@ class EngineServer:
             web.get("/kv_events", self.kv_events_stream),
             web.get("/debug/traces", self.traces),
             web.get("/debug/kv", self.kv_debug),
+            web.get("/debug/stalls", self.stalls),
         ])
         if cfg.profile_dir:
             self.app.add_routes(ProfileControl(cfg.profile_dir).routes())
@@ -367,11 +374,13 @@ class EngineServer:
                            ssl_context=self._tls.ssl_context
                            if self._tls else None)
         await site.start()
+        self.loop_lag.start()
         log.info("engine %s listening on %s:%s%s", self.engine.engine_id,
                  self.cfg.host, self.cfg.port,
                  " (TLS)" if self._tls else "")
 
     async def stop(self):
+        self.loop_lag.stop()
         if self._runner:
             await self._runner.cleanup()
         if self._ec_client is not None:
@@ -1037,6 +1046,19 @@ class EngineServer:
             "totals": totals,
             "recent": ring[-n:][::-1],
         })
+
+    async def stalls(self, request: web.Request) -> web.Response:
+        """The engine loop's stalled periods (engine/telemetry.py
+        ``LoopStalls``), newest first: when, how long against the running
+        median, the seconds of every loop phase inside, the chunk's lanes
+        and batch bucket, the prefills finalized in it. The same records are
+        logged at WARNING and their excess is
+        ``jetstream:loop_stall_seconds_total{where}``. Empty for the
+        simulator, which has no loop to stall."""
+        stalls = getattr(self.engine, "stalls", None)
+        ring = list(stalls.ring) if stalls is not None else []
+        return web.json_response({"engine_id": self.engine.engine_id,
+                                  "count": len(ring), "stalls": ring[::-1]})
 
     async def health(self, request: web.Request) -> web.Response:
         warming = bool(getattr(self.engine, "warming", False))

@@ -10,12 +10,15 @@ fleets via its mapping registry).
 from __future__ import annotations
 
 import collections
+import statistics
 import threading
 import time
 from typing import Any
 
 from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram, generate_latest
 from prometheus_client.core import CounterMetricFamily
+
+from ..router.metrics import LOOP_LAG_BUCKETS, PERIOD_BUCKETS
 
 WAITING = "jetstream:num_requests_waiting"
 RUNNING = "jetstream:num_requests_running"
@@ -37,6 +40,22 @@ LOOP_PHASES = ("housekeeping", "admit", "advance_prefills", "decode_prepare",
 # say nothing. Sum and count are exact whatever the buckets.
 _WAIT_BUCKETS = (.001, .0025, .005, .01, .02, .035, .05, .075, .1, .15, .2,
                  .25, .3, .4, .5, .65, .8, 1, 1.5, 2.5, 5, 10, 30)
+
+# A period of the engine loop (one decode chunk, readback to readback) is a
+# stall when it is longer than STALL_RATIO times the median of the
+# STALL_WINDOW periods before it AND longer than that median by
+# STALL_MIN_EXCESS_S. The worst honest steps measured on the chip stay under
+# one of the two: a 133 ms chunk behind four prefill windows of up to 62 ms
+# is under twice its median; a 101 ms chunk at 7 lanes behind two 1,500-token
+# prefills is 354 ms, 3.5 times its median but 253 ms over it (PERF.md
+# section 6, PR 36). The stops this is for last 1.3 s and more. Nothing is
+# judged before STALL_MIN_PERIODS periods are known.
+STALL_WINDOW = 32
+STALL_RATIO = 3.0
+STALL_MIN_EXCESS_S = 0.5
+STALL_MIN_PERIODS = 8
+STALL_RING = 64
+STALL_WHERE = ("device_wait", "host")
 
 
 class XlaBuilds:
@@ -138,8 +157,7 @@ class EngineTelemetry:
             "Wall time of one fused decode chunk: from its dispatch, or from "
             "the readback of the chunk before it where that came later (the "
             "chunk was queued behind it), through its own readback",
-            registry=self.registry,
-            buckets=(.002, .005, .01, .025, .05, .1, .25, .5, 1, 2.5))
+            registry=self.registry, buckets=PERIOD_BUCKETS)
         self.compile_events = Counter(
             "jetstream:compile_events_total",
             "First dispatch of a novel (op, shape-bucket) key of the engine "
@@ -253,6 +271,22 @@ class EngineTelemetry:
             "spans of a profiler trace, always on)", ("phase",),
             registry=self.registry)
         self.loop_seconds = {p: loop_seconds.labels(phase=p) for p in LOOP_PHASES}
+        loop_stall_seconds = Counter(
+            "jetstream:loop_stall_seconds_total",
+            "Seconds by which stalled periods of the engine loop (a decode "
+            "chunk, readback to readback, over 3x the median of the last 32 "
+            "and over it by 0.5 s) exceeded that median: `device_wait` the "
+            "part the loop was blocked on the device for, beyond that part's "
+            "own median, `host` the rest; each stall is a record of "
+            "/debug/stalls", ("where",), registry=self.registry)
+        self.loop_stall_seconds = {w: loop_stall_seconds.labels(where=w)
+                                   for w in STALL_WHERE}
+        self.event_loop_lag = Histogram(
+            "jetstream:event_loop_lag_seconds",
+            "Lag of the engine server's event loop, which writes every "
+            "streamed token: the overshoot of a 100 ms sleep (the router's "
+            "LoopLagMonitor on this loop)",
+            registry=self.registry, buckets=LOOP_LAG_BUCKETS)
 
     def watch_xla_builds(self) -> None:
         """Count the programs JAX builds from now on, and show the count
@@ -269,6 +303,56 @@ class EngineTelemetry:
 
     def render(self) -> bytes:
         return generate_latest(self.registry)
+
+
+class LoopStalls:
+    """The engine loop's periods judged one by one (engine/core.py
+    `_land_chunk`): a running median of the last STALL_WINDOW periods and of
+    the part of each the loop was blocked on the device for; a period far
+    beyond its median is a stall, whose excess goes to
+    jetstream:loop_stall_seconds_total{where} and whose record, the seconds
+    of every phase in it, goes onto `ring` (`GET /debug/stalls`). Written by
+    the engine thread, read by server handlers: GIL-atomic deque ops."""
+
+    def __init__(self, telemetry: EngineTelemetry):
+        self._seconds = telemetry.loop_stall_seconds
+        self._periods: collections.deque[float] = \
+            collections.deque(maxlen=STALL_WINDOW)
+        self._blocked: collections.deque[float] = \
+            collections.deque(maxlen=STALL_WINDOW)
+        self.ring: collections.deque[dict[str, Any]] = \
+            collections.deque(maxlen=STALL_RING)
+
+    def note(self, period: float, phases: dict[str, float],
+             **chunk: Any) -> dict[str, Any] | None:
+        """One timed period and the seconds of each loop phase inside it;
+        `chunk` is what the record says besides (the loop's clock, lanes,
+        prefills). Returns the record if the period was a stall."""
+        blocked = phases["decode_wait"]
+        record = None
+        # The first test is the third's, without a median: most periods of
+        # most deployments end here.
+        if (period > STALL_MIN_EXCESS_S
+                and len(self._periods) >= STALL_MIN_PERIODS):
+            median = statistics.median(self._periods)
+            excess = period - median
+            if period > STALL_RATIO * median and excess > STALL_MIN_EXCESS_S:
+                blocked_median = statistics.median(self._blocked)
+                device = min(max(blocked - blocked_median, 0.0), excess)
+                host = excess - device
+                self._seconds["device_wait"].inc(device)
+                self._seconds["host"].inc(host)
+                record = {
+                    "unix": round(time.time(), 3), **chunk,
+                    "period_s": period, "median_s": median,
+                    "blocked_s": blocked, "blocked_median_s": blocked_median,
+                    "excess_s": {"device_wait": device, "host": host},
+                    "phases_s": {**phases, "none": max(
+                        period - sum(phases.values()), 0.0)}}
+                self.ring.append(record)
+        self._periods.append(period)
+        self._blocked.append(blocked)
+        return record
 
 
 class PrefixHitLog:

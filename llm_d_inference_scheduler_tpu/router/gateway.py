@@ -41,6 +41,7 @@ from .metrics import (
     DEADLINE_EXCEEDED_TOTAL,
     KV_TRANSFER_EXPOSED_MS,
     KV_TRANSFER_MS,
+    LOOP_LAG_SECONDS,
     POOL_AVG_KV_CACHE,
     POOL_AVG_QUEUE,
     POOL_READY_ENDPOINTS,
@@ -281,7 +282,7 @@ class Gateway:
             self.flow_controller.cfg.dispatch_batch = max(
                 self.flow_controller.cfg.dispatch_batch,
                 self.sched_pool.cfg.max_batch)
-        self.loop_lag = LoopLagMonitor()
+        self.loop_lag = LoopLagMonitor(LOOP_LAG_SECONDS)
 
         self.director = Director(
             datastore, cfg.scheduler, admission=admission,

@@ -10,6 +10,17 @@ from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram
 
 REGISTRY = CollectorRegistry()
 
+# One decode chunk of an engine (jetstream:decode_step_duration_seconds) and
+# the longest gap of a relayed stream, which is one chunk when nothing stops:
+# they tell the chunks deployments run apart (0.1-0.3 s) and reach a stream
+# that stops for seconds.
+PERIOD_BUCKETS = (.01, .025, .05, .075, .1, .125, .15, .2, .25, .3, .4, .5,
+                  .65, .8, 1, 1.5, 2.5, 5, 10, 30)
+# An event loop's lag (schedpool.LoopLagMonitor: the overshoot of a 100 ms
+# sleep), the gateway's and the engine server's.
+LOOP_LAG_BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5, 1,
+                    2.5, 5, 10)
+
 REQUEST_TOTAL = Counter(
     "inference_extension_request_total", "Requests handled",
     ("model", "target_model"), registry=REGISTRY)
@@ -150,8 +161,13 @@ LOOP_LAG_SECONDS = Histogram(
     "router_loop_lag_seconds",
     "Event-loop scheduling stall sampled by the gateway's heartbeat "
     "(sleep-overshoot of a 100ms timer; the stall token relays experience)",
-    registry=REGISTRY,
-    buckets=(.0001, .0005, .001, .0025, .005, .01, .025, .05, .1, .5))
+    registry=REGISTRY, buckets=LOOP_LAG_BUCKETS)
+STREAM_GAP_MAX_SECONDS = Histogram(
+    "router_stream_gap_max_seconds",
+    "Longest gap between two token-bearing chunks of one relayed stream, "
+    "observed once when the request closes (the inter-token-latency tail at "
+    "the front door)",
+    registry=REGISTRY, buckets=PERIOD_BUCKETS)
 # SLO & goodput ledger (router/slo.py): per-request serving outcomes,
 # predictor calibration, goodput vs raw token rate. The per-request detail
 # (predicted vs actual vs SLO, miss reason, transfer row) lives in the

@@ -40,7 +40,7 @@ import threading
 import time
 from typing import Any
 
-from .metrics import LOOP_LAG_SECONDS, SCHED_OFFLOAD_QUEUE_SECONDS
+from .metrics import SCHED_OFFLOAD_QUEUE_SECONDS
 from .scheduling.scheduler import Scheduler, SchedulerProfile, WeightedScorer
 
 log = logging.getLogger("router.schedpool")
@@ -313,12 +313,15 @@ class SchedulerPool:
 
 
 class LoopLagMonitor:
-    """Event-loop stall heartbeat: sleeps ``interval_s`` and records the
-    overshoot into ``router_loop_lag_seconds``. The production twin of the
-    bench's stall probe — the number the offload exists to shrink, live on
-    /metrics so a regression (a new on-loop CPU hog) is graphable."""
+    """Event-loop stall heartbeat: sleeps ``interval_s`` on the loop it is
+    started on and records the overshoot into ``histogram`` — the gateway's
+    ``router_loop_lag_seconds``, the engine server's
+    ``jetstream:event_loop_lag_seconds``. The production twin of the bench's
+    stall probe — the number the offload exists to shrink, live on /metrics
+    so a regression (a new on-loop CPU hog) is graphable."""
 
-    def __init__(self, interval_s: float = 0.1):
+    def __init__(self, histogram, interval_s: float = 0.1):
+        self.histogram = histogram
         self.interval_s = interval_s
         self._task: asyncio.Task | None = None
 
@@ -337,6 +340,6 @@ class LoopLagMonitor:
             while True:
                 t0 = loop.time()
                 await asyncio.sleep(interval)
-                LOOP_LAG_SECONDS.observe(max(loop.time() - t0 - interval, 0.0))
+                self.histogram.observe(max(loop.time() - t0 - interval, 0.0))
         except asyncio.CancelledError:
             pass

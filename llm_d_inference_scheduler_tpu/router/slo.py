@@ -48,6 +48,7 @@ from .metrics import (
     PREDICTOR_ERROR_MS,
     SLO_ATTAINMENT,
     SLO_REQUESTS_TOTAL,
+    STREAM_GAP_MAX_SECONDS,
 )
 
 # SLO request headers (reference latencyslo/plugin.go:38-40); the
@@ -397,6 +398,10 @@ class SloLedger:
                           f"slo {obs.slo_tpot_ms:.0f}ms")
             verdict = "met" if met else "missed"
         SLO_REQUESTS_TOTAL.labels(verdict).inc()
+        if obs.streamed:
+            # Once a request, here where it closes: the relay's per-chunk
+            # path (on_chunk) pays nothing for it.
+            STREAM_GAP_MAX_SECONDS.observe(obs.gap_max_ms / 1e3)
         if tokens:
             OUTPUT_TOKENS_TOTAL.labels(obs.model).inc(tokens)
             if met:

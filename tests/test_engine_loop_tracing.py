@@ -5,8 +5,11 @@ stable names, and the profiler's control on the engine server. CPU, `tiny`;
 every wait is bounded."""
 
 import asyncio
+import collections
 import glob
+import json
 import os
+import time
 
 import httpx
 import jax
@@ -18,9 +21,11 @@ from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
 from llm_d_inference_scheduler_tpu.engine.server import EngineServer
 from llm_d_inference_scheduler_tpu.engine.telemetry import (
     LOOP_PHASES,
+    STALL_WHERE,
     XLA_BUILDS,
     EngineTelemetry,
 )
+from llm_d_inference_scheduler_tpu.router.metrics import PERIOD_BUCKETS
 
 PORT = 18940
 
@@ -337,6 +342,366 @@ def test_a_request_served_while_the_profiler_stops_completes(tmp_path):
                     "text/event-stream") or a.json()["usage"]["completion_tokens"] == 24
                     for a in during)
         finally:
+            await server.stop()
+
+    run(body())
+
+
+# ---------- where a stream stopped (ISSUE 36) ----------
+
+def _value(registry, name, **labels):
+    return registry.get_sample_value(name, labels or None)
+
+
+def _stall_seconds(eng):
+    return {w: _value(eng.telemetry.registry, "jetstream:loop_stall_seconds_total",
+                      where=w) for w in STALL_WHERE}
+
+
+def _chunks(eng):
+    return _hist(eng.telemetry, "jetstream:decode_step_duration_seconds")[0]
+
+
+def _long_engine(**kw):
+    """Two lanes, 127 chunks of 4 a request: never started, stepped by hand."""
+    return TpuEngine(EngineConfig(
+        model="tiny", backend="tpu", port=0, max_batch=kw.pop("max_batch", 2),
+        max_model_len=512, decode_chunk=4, kv_events_port=0, **kw))
+
+
+def _submit(eng, rid, max_tokens=500):
+    return eng.submit(EngineRequest(
+        request_id=rid, prompt_token_ids=[1, 7, 8, 9], max_tokens=max_tokens,
+        ignore_eos=True))
+
+
+@pytest.mark.parametrize("where, other", [("device_wait", "host"),
+                                          ("host", "device_wait")])
+def test_a_stop_of_800_ms_is_one_stall_in_the_place_it_happened(where, other):
+    """Forty quiet chunks, then 0.8 s once: in the read of a chunk's tokens
+    (the device, or whatever the loop is blocked on), or in _book_chunk (the
+    engine thread alone). The real clock; only the provoked step's own
+    movement is looked at, so a hiccup of the machine elsewhere is not read."""
+    async def body():
+        eng = _long_engine()
+        _submit(eng, "a")
+        while _chunks(eng) < 40:
+            eng._step()
+        slowed = {"device_wait": "_read_tokens", "host": "_book_chunk"}[where]
+        real = getattr(eng, slowed)
+        slept = []
+
+        def slow(*args):
+            if not slept:
+                t0 = time.monotonic()
+                time.sleep(0.8)
+                slept.append(time.monotonic() - t0)   # 0.8 s and the wake-up
+            return real(*args)
+
+        setattr(eng, slowed, slow)
+        before, n = _stall_seconds(eng), len(eng.stalls.ring)
+        bucket = lambda le: _value(
+            eng.telemetry.registry,
+            "jetstream:decode_step_duration_seconds_bucket", le=le)
+        under = bucket("0.65"), bucket("1.5")
+        # The sleep in the read ends a period; the one in the booking falls
+        # into the period the NEXT readback ends.
+        for _ in range(2):
+            eng._step()
+        moved = {w: v - before[w] for w, v in _stall_seconds(eng).items()}
+        assert len(eng.stalls.ring) == n + 1
+        stall = eng.stalls.ring[-1]
+        assert moved[where] == pytest.approx(slept[0] - stall["median_s"], abs=0.05)
+        assert moved[other] == pytest.approx(0.0, abs=0.05)
+        if where == "host":
+            assert moved["device_wait"] == 0.0      # the device was long done
+            assert stall["phases_s"]["decode_book"] >= 0.8
+        else:
+            assert stall["phases_s"]["decode_wait"] >= 0.8
+        assert stall["excess_s"] == pytest.approx(moved)
+        assert stall["period_s"] == pytest.approx(slept[0], abs=0.05)
+        assert sum(stall["phases_s"].values()) == pytest.approx(
+            stall["period_s"], abs=1e-3)
+        assert set(stall["phases_s"]) == set(LOOP_PHASES) | {"none"}
+        assert (stall["lanes"], stall["batch"], stall["prefills"]) == (1, 2, 0)
+        assert abs(stall["unix"] - time.time()) < 5
+        assert abs(stall["loop_clock_s"] - time.monotonic()) < 5
+        # Two chunks landed: a quiet one, and the one in le="1" or "1.5".
+        assert (bucket("0.65"), bucket("1.5")) == (under[0] + 1, under[1] + 2)
+        return eng
+
+    eng = run(body())
+    server = EngineServer(eng.cfg, engine=eng)
+
+    async def served():
+        return json.loads((await server.stalls(None)).body)
+
+    page = run(served())
+    assert page["count"] == len(eng.stalls.ring)
+    assert page["stalls"][0] == eng.stalls.ring[-1]
+
+
+class _ScriptedDevice:
+    """A clock the test owns and a device with a queue: an op takes its cost
+    AFTER whatever was dispatched before it, and a read of an op's tokens
+    moves the clock to that op's end, if it is not there yet. The host costs
+    nothing. Reads come in the order of dispatch (a chunk, the prefills
+    behind it, the chunk behind those), so a queue of ends is enough."""
+
+    def __init__(self, eng, cost, build=lambda op, args: 0.0):
+        self.now, self.free, self.ends = 0.0, 0.0, collections.deque()
+        self.cost, self.ops = cost, []
+        self.chunks = []       # (dispatched, read) of every decode chunk
+        self._t0 = collections.deque()
+        real_op, real_read = eng._exec_op, eng._read_tokens
+
+        def exec_op(op, args):
+            self.now += build(op, args)      # the host, building a program
+            took = self.cost(op[0], len(self.ops))
+            self.ops.append(op[0])
+            if op[0] in ("prefill", "decode"):
+                self.free = max(self.free, self.now) + took
+                self.ends.append((op[0], self.free))
+                if op[0] == "decode":
+                    self._t0.append(self.now)
+            return real_op(op, args)
+
+        def read_tokens(toks):
+            kind, end = self.ends.popleft()
+            self.now = max(self.now, end)
+            if kind == "decode":
+                self.chunks.append((self._t0.popleft(), self.now))
+            return real_read(toks)
+
+        eng._clock = lambda: self.now
+        eng._exec_op, eng._read_tokens = exec_op, read_tokens
+
+    def parents_arithmetic(self):
+        """What PR 35's _land_chunk observed: each chunk but the first (a
+        shape's first call is not timed) from the later of its dispatch and
+        the readback before it to its own readback."""
+        last, spans = 0.0, []
+        for t0, read in self.chunks:
+            spans.append(read - max(t0, last))
+            last = read
+        return spans[1:]
+
+
+def test_quiet_chunks_are_no_stall_and_sum_and_count_are_the_parents():
+    """Sixty-four chunks whose device time wanders between 100 and 180 ms:
+    nothing is a stall, /debug/stalls stays empty, and the histogram's sum
+    and count are what the parent's arithmetic gives for the same clock: the
+    new buckets changed nothing that decode_chunk_ms reads."""
+    async def body():
+        eng = _long_engine()
+        dev = _ScriptedDevice(eng, lambda kind, i: 0.1 + 0.02 * (i % 5))
+        _submit(eng, "a")
+        while len(dev.chunks) < 65:
+            eng._step()
+        spans = dev.parents_arithmetic()
+        count, total = _hist(eng.telemetry, "jetstream:decode_step_duration_seconds")
+        assert count == len(spans) == 64
+        assert total == pytest.approx(sum(spans), rel=1e-12)
+        assert _stall_seconds(eng) == {"device_wait": 0.0, "host": 0.0}
+        assert not eng.stalls.ring
+        get = eng.telemetry.registry.get_sample_value
+        bounds = [s.labels["le"] for m in eng.telemetry.registry.collect()
+                  if m.name == "jetstream:decode_step_duration_seconds"
+                  for s in m.samples if s.name.endswith("_bucket")]
+        assert bounds == [str(float(b)) for b in PERIOD_BUCKETS] + ["+Inf"]
+        assert get("jetstream:decode_step_duration_seconds_bucket",
+                   {"le": "0.2"}) == 64
+
+    run(body())
+
+
+def test_three_prefills_ahead_of_a_chunk_are_no_stall_and_a_long_read_is():
+    """longdoc-batch's worst honest step: a chunk of 133 ms with three
+    prefill windows of 62 ms queued ahead of it is 319 ms, under twice the
+    median. The same device taking 2 s over one chunk is a stall, all of it
+    device_wait."""
+    async def body():
+        eng = _long_engine(max_batch=4)
+        slow = []
+        dev = _ScriptedDevice(eng, lambda kind, i: (
+            0.062 if kind == "prefill" else 2.133 if slow and slow.pop() else 0.133))
+        _submit(eng, "a")
+        while len(dev.chunks) < 12:
+            eng._step()
+        prefills = dev.ops.count("prefill")
+        for rid in "bcd":
+            _submit(eng, rid)
+        while len(dev.chunks) < 16:
+            eng._step()
+        assert dev.ops.count("prefill") == prefills + 3
+        spans = dev.parents_arithmetic()
+        assert max(spans) == pytest.approx(0.133 + 3 * 0.062)
+        assert _stall_seconds(eng) == {"device_wait": 0.0, "host": 0.0}
+        assert not eng.stalls.ring
+
+        slow.append(True)
+        n = len(dev.chunks)
+        while len(dev.chunks) < n + 3:
+            eng._step()
+        assert _stall_seconds(eng) == {"device_wait": pytest.approx(2.0),
+                                       "host": 0.0}
+        (stall,) = eng.stalls.ring
+        assert stall["period_s"] == pytest.approx(2.133)
+        assert stall["median_s"] == pytest.approx(0.133)
+        assert stall["phases_s"]["decode_wait"] == pytest.approx(2.133)
+        assert (stall["lanes"], stall["batch"]) == (4, 4)
+
+    run(body())
+
+
+def test_a_period_that_holds_a_first_call_of_a_shape_is_no_stall():
+    """Two requests join a lone lane: the chunk for four rows is built while
+    the two-row chunk in flight waits to be read, 16 s here. That chunk's
+    period holds the build and is observed as the parent observed it, but
+    it is nobody's stall, as the first call itself is not timed at all."""
+    async def body():
+        eng = _long_engine(max_batch=4)
+        built = set()
+
+        def build(op, args):
+            key = (op[0], len(args.get("slots", ())))
+            if op[0] != "decode" or key in built:
+                return 0.0
+            built.add(key)
+            return 16.0
+
+        dev = _ScriptedDevice(eng, lambda kind, i: 0.133, build)
+        _submit(eng, "a")
+        while len(dev.chunks) < 12:
+            eng._step()
+        for rid in "bc":
+            _submit(eng, rid)
+        while len(dev.chunks) < 16:
+            eng._step()
+        assert built == {("decode", 2), ("decode", 4)}
+        spans = dev.parents_arithmetic()
+        count, total = _hist(eng.telemetry, "jetstream:decode_step_duration_seconds")
+        # The four-row chunk's own first call is not timed; the two-row chunk
+        # read behind its build is, at 16 s and more.
+        held = spans.index(max(spans))
+        assert spans[held] == pytest.approx(16.0) and count == len(spans) - 1
+        assert total == pytest.approx(sum(spans) - spans[held + 1])
+        assert _stall_seconds(eng) == {"device_wait": 0.0, "host": 0.0}
+        assert not eng.stalls.ring
+
+    run(body())
+
+
+def test_one_heartbeat_class_fills_the_engines_and_the_gateways_histogram():
+    """A handler that blocks the engine server's loop for 0.3 s shows in
+    jetstream:event_loop_lag_seconds beyond 0.1 s and at or under 0.5 s,
+    on either backend's server; the gateway's monitor is the same class and
+    still fills router_loop_lag_seconds."""
+    from aiohttp import web
+
+    from llm_d_inference_scheduler_tpu.router.gateway import build_gateway
+    from llm_d_inference_scheduler_tpu.router.metrics import REGISTRY
+    from llm_d_inference_scheduler_tpu.router.schedpool import LoopLagMonitor
+
+    port = PORT + 8
+
+    async def block(request):
+        time.sleep(0.3)
+        return web.Response(text="done")
+
+    def lag(registry, name, le):
+        return _value(registry, name + "_bucket", le=le)
+
+    async def body():
+        server = EngineServer(_cfg("sim", port))
+        server.app.router.add_get("/block", block)
+        gw = build_gateway(f"pool:\n  endpoints:\n    - {{address: 127.0.0.1, "
+                           f"port: {port}}}\n", port=port + 1, poll_interval=0.05)
+        assert type(gw.loop_lag) is type(server.loop_lag) is LoopLagMonitor
+        assert gw.loop_lag.histogram is not server.loop_lag.histogram
+        await server.start()
+        await gw.start()
+        try:
+            registry = server.engine.telemetry.registry
+            names = ("jetstream:event_loop_lag_seconds", "router_loop_lag_seconds")
+            was = [(lag(registry, names[0], "0.1"), lag(registry, names[0], "0.5")),
+                   (lag(REGISTRY, names[1], "0.1"), lag(REGISTRY, names[1], "0.5"))]
+            async with httpx.AsyncClient(timeout=30) as c:
+                await asyncio.sleep(0.25)        # both heartbeats are asleep
+                assert (await c.get(f"http://127.0.0.1:{port}/block")).text == "done"
+                await asyncio.sleep(0.25)
+                text = (await c.get(f"http://127.0.0.1:{port}/metrics")).text
+            assert 'jetstream:event_loop_lag_seconds_bucket{le="0.5"}' in text
+            # One loop carries both servers here, so both heartbeats felt it.
+            for (low, high), (reg, name) in zip(was, zip((registry, REGISTRY), names)):
+                assert lag(reg, name, "0.1") - low >= 2      # the quiet beats
+                assert (lag(reg, name, "0.5") - high) \
+                    - (lag(reg, name, "0.1") - low) == 1     # the blocked one
+        finally:
+            await gw.stop()
+            await server.stop()
+
+    run(body())
+
+
+def test_a_pause_of_the_upstream_mid_stream_is_the_requests_longest_gap():
+    """An upstream that pauses 0.4 s between two tokens of a stream: the
+    gateway observes that stream's longest gap once, where it closes the
+    request, in the bucket the pause belongs to."""
+    from llm_d_inference_scheduler_tpu.router.gateway import build_gateway
+    from llm_d_inference_scheduler_tpu.router.metrics import REGISTRY
+
+    port = PORT + 10
+
+    def gaps(le=None):
+        name = "router_stream_gap_max_seconds"
+        return (_value(REGISTRY, name + "_count") if le is None
+                else _value(REGISTRY, name + "_bucket", le=le))
+
+    async def body():
+        server = EngineServer(_cfg("sim", port, sim_decode_ms_per_token=2.0))
+        real_submit, relays = server.engine.submit, []
+
+        def submit(req):
+            src, dst = real_submit(req), asyncio.Queue()
+
+            async def relay():
+                for n in range(req.max_tokens + 1):
+                    ev = await src.get()
+                    if n == 5:
+                        await asyncio.sleep(0.4)
+                    dst.put_nowait(ev)
+
+            relays.append(asyncio.get_running_loop().create_task(relay()))
+            return dst
+
+        server.engine.submit = submit
+        gw = build_gateway(f"pool:\n  endpoints:\n    - {{address: 127.0.0.1, "
+                           f"port: {port}}}\n", port=port + 1, poll_interval=0.05)
+        await server.start()
+        await gw.start()
+        try:
+            was = gaps(), gaps("0.3"), gaps("0.5")
+            async with httpx.AsyncClient(timeout=30) as c:
+                async with c.stream(
+                        "POST", f"http://127.0.0.1:{port + 1}/v1/completions",
+                        json={"model": "tiny", "prompt": "stream on",
+                              "max_tokens": 12, "stream": True}) as r:
+                    lines = [l async for l in r.aiter_lines()
+                             if l.startswith("data: ")]
+                assert r.status_code == 200 and lines[-1] == "data: [DONE]"
+                unary = await c.post(
+                    f"http://127.0.0.1:{port + 1}/v1/completions",
+                    json={"model": "tiny", "prompt": "no stream", "max_tokens": 3})
+                assert unary.status_code == 200
+                text = (await c.get(f"http://127.0.0.1:{port + 1}/metrics")).text
+            assert "router_stream_gap_max_seconds_count" in text
+            assert gaps() == was[0] + 1           # once, the streamed one alone
+            assert gaps("0.3") == was[1] and gaps("0.5") == was[2] + 1
+        finally:
+            for t in relays:
+                t.cancel()
+            await gw.stop()
             await server.stop()
 
     run(body())
