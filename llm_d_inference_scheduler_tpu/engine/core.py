@@ -112,7 +112,6 @@ class _Slot:
     # flight, and while the one before it is being read, that one too.
     ahead: int = 0
     first_emitted: bool = False
-    aborted: bool = False
     cached_tokens: int = 0
     block_hashes: list[int] = dataclasses.field(default_factory=list)
     # Pipelined prefill: the fused prefill jit's sampled first token, still on
@@ -499,6 +498,11 @@ class TpuEngine:
         # while it reads and books the one before), and when the last one
         # was read, on the loop's clock.
         self._inflight: _Chunk | None = None
+        # (slot index, request) of every request whose slot a successor took
+        # while its last chunk was unread (_admit, _place): served through
+        # that chunk's lanes, and gone from here once it is booked, later in
+        # the same step. Here for a step that fails in between (_abort_all).
+        self._retired: list[tuple[int, _Slot]] = []
         self._last_readback = 0.0
         self._clock = time.monotonic
         # The period now running (from the last readback, or from the
@@ -1160,9 +1164,9 @@ class TpuEngine:
 
     def _abort_all(self, reason: str):
         self._inflight = None   # its lanes end here; nobody will read it
-        for i, s in enumerate(self.slots):
+        for i, s in [*enumerate(self.slots), *self._retired]:
             if s is not None:
-                self._finish_slot(i, FinishReason.ABORT)
+                self._finish_slot(i, s, FinishReason.ABORT)
         with self._cond:
             drained, self._waiting = self._waiting, []
             self.telemetry.waiting.set(0)
@@ -1278,7 +1282,7 @@ class TpuEngine:
             self.telemetry.waiting.set(len(self._waiting))
         for i, s in enumerate(self.slots):
             if s is not None and s.req.request_id in ids:
-                self._finish_slot(i, FinishReason.ABORT)
+                self._finish_slot(i, s, FinishReason.ABORT)
 
     # ---- admission -----------------------------------------------------
 
@@ -1317,14 +1321,36 @@ class TpuEngine:
             self.telemetry.queue_wait.observe(wait_s)
 
     def _admit(self):
+        """Place the head of the queue, slot by slot, while it fits. The
+        empty slots first; then, for requests that still wait, the slots
+        whose request ends inside the chunk in flight (on max_tokens or the
+        context limit, _ends_in_flight: the next chunk leaves its lane out
+        already). Such a slot's successor is prefilled now, behind that
+        chunk in the device's queue, and decodes in the next one as the
+        slot's lane, which would else be nobody's for a whole chunk. What a
+        slot index names on the device (_slot_tokens, the state pool's row)
+        is the successor's from its prefill on, by the queue's order alone;
+        its pages are its own, the predecessor keeps its blocks until its
+        last chunk is booked (_place, _book_chunk)."""
         group: list[tuple[int, EngineRequest, Any, Any, int]] = []
-        for i, slot in enumerate(self.slots):
-            if slot is not None:
-                continue
+        empty = [i for i, s in enumerate(self.slots) if s is None]
+        vacating = [i for i, s in enumerate(self.slots) if s is not None
+                    and s.ahead and self._ends_in_flight(s)]
+        for i in empty + vacating:
+            when = "after" if self.slots[i] is None else "ahead"
             with self._cond:
                 if not self._waiting:
                     break
                 req, out, loop = self._waiting[0]
+                ktp = req.kv_transfer_params or {}
+                if when == "ahead" and (
+                        ktp.get("remote_host") is not None
+                        or ktp.get("do_remote_decode")
+                        or req.mm_embeds is not None):
+                    # An import is placed when its pages arrive, an export
+                    # and an image prompt never had a lane to wait for: the
+                    # head of the queue waits for an empty slot, as before.
+                    break
                 need = self._blocks_needed(req)
                 if need > self.n_blocks - 1:
                     # Impossible request: reject instead of wedging the queue.
@@ -1336,7 +1362,7 @@ class TpuEngine:
                         finish_reason=FinishReason.ABORT,
                         prompt_tokens=len(req.prompt_token_ids)))
                     continue
-                if (req.kv_transfer_params or {}).get("remote_host") is not None:
+                if ktp.get("remote_host") is not None:
                     # Fetch off-thread; the payload comes back via _import_ready.
                     self._waiting.pop(0)
                     self.telemetry.waiting.set(len(self._waiting))
@@ -1353,6 +1379,7 @@ class TpuEngine:
                 self._waiting.pop(0)
                 self.telemetry.waiting.set(len(self._waiting))
                 self._note_admission(req)
+                self.telemetry.slot_refills[when].inc()
             group.append((i, req, out, loop, need))
         self._flush_admissions(group)
 
@@ -1557,7 +1584,7 @@ class TpuEngine:
                 slot.block_hashes = hashes[:n_complete]
                 if self.kv_events is not None and slot.block_hashes:
                     self.kv_events.stored(slot.block_hashes)
-                self.slots[i] = slot
+                self._place(i, slot)
         except BaseException:
             # Post-dispatch bookkeeping failed (hash commit / event publish):
             # the dispatch itself landed, but entries not yet slotted would
@@ -1574,11 +1601,18 @@ class TpuEngine:
                     request_id=req.request_id, token_id=None,
                     finish_reason=FinishReason.ABORT,
                     prompt_tokens=len(pre[0])))
-            self.telemetry.running.set(sum(s is not None for s in self.slots))
             raise
-        self.telemetry.running.set(sum(s is not None for s in self.slots))
 
     # ---- prefill -------------------------------------------------------
+
+    def _place(self, idx: int, slot: _Slot) -> None:
+        """``slot`` takes engine slot idx. A request still there is one
+        that _admit saw vacating: it is retired, served on through the
+        lanes of the chunk in flight until that chunk is booked."""
+        if self.slots[idx] is not None:
+            self._retired.append((idx, self.slots[idx]))
+        self.slots[idx] = slot
+        self.telemetry.running.set(sum(s is not None for s in self.slots))
 
     def _prefill_into_slot(self, idx, req, out, loop, need: int,
                            precomputed=None):
@@ -1658,8 +1692,7 @@ class TpuEngine:
             slot.prefill_rest = list(suffix)
             slot.prefill_written = cached_tokens
             slot.chunk_meta = (hashes, caching)
-            self.slots[idx] = slot
-            self.telemetry.running.set(sum(s is not None for s in self.slots))
+            self._place(idx, slot)
             return
 
         row = np.zeros((1, self.max_blocks_per_seq), np.int32)
@@ -1698,8 +1731,7 @@ class TpuEngine:
         slot.block_hashes = hashes[:n_complete]
         if self.kv_events is not None and slot.block_hashes:
             self.kv_events.stored(slot.block_hashes)
-        self.slots[idx] = slot
-        self.telemetry.running.set(sum(s is not None for s in self.slots))
+        self._place(idx, slot)
 
     def _finalize_prefills(self):
         """Land pending first tokens and emit/finish accordingly. Reading
@@ -1726,7 +1758,7 @@ class TpuEngine:
                 # Remote-decode prefill: hand KV off instead of decoding here.
                 ktp = req.kv_transfer_params or {}
                 if ktp.get("do_remote_decode"):
-                    self._finish_slot(idx, FinishReason.LENGTH,
+                    self._finish_slot(idx, slot, FinishReason.LENGTH,
                                       retain_for_transfer=True, first_token=tok)
                     continue
                 self._emit(slot, TokenEvent(
@@ -1735,7 +1767,7 @@ class TpuEngine:
                     prompt_tokens=slot.prompt_len, completion_tokens=1,
                     cached_tokens=slot.cached_tokens))
                 slot.first_emitted = True
-                self._maybe_finish_after_token(idx, tok)
+                self._maybe_finish_after_token(idx, slot, tok)
 
     def _observe_first_token(self, req: EngineRequest) -> None:
         """A first token has landed on the host: TTFT from the request's
@@ -2400,8 +2432,7 @@ class TpuEngine:
                                              hashes[:n_complete])
         if self.kv_events is not None and slot.block_hashes:
             self.kv_events.stored(slot.block_hashes)
-        self.slots[idx] = slot
-        self.telemetry.running.set(sum(s is not None for s in self.slots))
+        self._place(idx, slot)
         self._observe_first_token(req)
         self._emit(slot, TokenEvent(
             request_id=req.request_id, token_id=first,
@@ -2409,7 +2440,7 @@ class TpuEngine:
             prompt_tokens=seq_len, completion_tokens=1,
             cached_tokens=seq_len))
         slot.first_emitted = True
-        self._maybe_finish_after_token(idx, first)
+        self._maybe_finish_after_token(idx, slot, first)
 
     # ---- decode --------------------------------------------------------
 
@@ -2909,16 +2940,18 @@ class TpuEngine:
         ended before the chunk's first step: on max_tokens or the context
         limit inside the steps already dispatched. A stop token it cannot
         foresee: that lane's chunk is thrown away when it turns up."""
-        lanes = []
-        for i, s in enumerate(self.slots):
-            if (s is None or s.prefilling or (s.req.kv_transfer_params
-                                              or {}).get("do_remote_decode")):
-                continue
-            generated = len(s.generated) if s.pending_tok is None else 1
-            if (generated + s.ahead < s.req.max_tokens
-                    and s.position + s.ahead + 1 < self.cfg.max_model_len):
-                lanes.append((i, s))
-        return lanes
+        return [(i, s) for i, s in enumerate(self.slots)
+                if not (s is None or s.prefilling
+                        or (s.req.kv_transfer_params
+                            or {}).get("do_remote_decode")
+                        or self._ends_in_flight(s))]
+
+    def _ends_in_flight(self, s: _Slot) -> bool:
+        """Whether the steps already dispatched for s, if any, reach its
+        request's end on max_tokens or the context limit."""
+        generated = len(s.generated) if s.pending_tok is None else 1
+        return (generated + s.ahead >= s.req.max_tokens
+                or s.position + s.ahead + 1 >= self.cfg.max_model_len)
 
     def _dispatch_chunk(self) -> _Chunk | None:
         """Dispatch the next chunk for the lanes that have one coming, on top
@@ -2987,16 +3020,17 @@ class TpuEngine:
     def _book_chunk(self, lanes: list[tuple[int, _Slot]],
                     sampled: np.ndarray) -> None:
         """Apply one chunk's sampled tokens [K, B] lane by lane, up to each
-        request's stop condition. A lane whose slot is gone, or holds another
-        request by now, ended while this chunk was in flight."""
+        request's stop condition. A lane's request either holds its slot
+        still, or is retired (a successor holds the slot and this chunk has
+        the request's last tokens), or ended while this chunk was in flight
+        (a stop token in the chunk before, an abort)."""
         for lane, (i, s) in enumerate(lanes):
             s.ahead -= sampled.shape[0]
-            if self.slots[i] is not s:
+            if self.slots[i] is not s and not any(
+                    r is s for _, r in self._retired):
                 self.telemetry.decode_lanes_discarded.inc()
                 continue
             for step in range(sampled.shape[0]):
-                if self.slots[i] is None:
-                    break  # stop/length hit mid-chunk; overshoot discarded
                 tok = int(sampled[step, lane])
                 s.position += 1
                 s.generated.append(tok)
@@ -3007,7 +3041,8 @@ class TpuEngine:
                         text=self.tokenizer.decode([tok]), is_first=not s.first_emitted,
                         completion_tokens=len(s.generated)))
                     s.first_emitted = True
-                self._maybe_finish_after_token(i, tok)
+                if self._maybe_finish_after_token(i, s, tok):
+                    break  # stop/length hit mid-chunk; overshoot discarded
 
     def _stop_ids(self, req: EngineRequest) -> set[int]:
         stop_ids = set(req.stop_token_ids)
@@ -3015,8 +3050,8 @@ class TpuEngine:
             stop_ids.add(self.tokenizer.eos_id)
         return stop_ids
 
-    def _maybe_finish_after_token(self, idx: int, tok: int):
-        s = self.slots[idx]
+    def _maybe_finish_after_token(self, idx: int, s: _Slot, tok: int) -> bool:
+        """Finish s, of slot idx, if ``tok`` was its last; whether it was."""
         stop_ids = self._stop_ids(s.req)
         reason = None
         if tok in stop_ids:
@@ -3026,12 +3061,17 @@ class TpuEngine:
         elif s.position + 1 >= self.cfg.max_model_len:
             reason = FinishReason.LENGTH
         if reason is not None:
-            self._finish_slot(idx, reason)
+            self._finish_slot(idx, s, reason)
+        return reason is not None
 
-    def _finish_slot(self, idx: int, reason: FinishReason, *,
+    def _finish_slot(self, idx: int, s: _Slot, reason: FinishReason, *,
                      retain_for_transfer: bool = False, first_token: int | None = None):
-        s = self.slots[idx]
-        self.slots[idx] = None
+        """End s, the request of slot idx: it holds the slot, or is retired
+        and the slot is its successor's."""
+        if self.slots[idx] is s:
+            self.slots[idx] = None
+        else:
+            self._retired = [r for r in self._retired if r[1] is not s]
         kv_params = None
         if not retain_for_transfer:
             # Abort/error of a chunk-streaming prefill: reclaim the partial

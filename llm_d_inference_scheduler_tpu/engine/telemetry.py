@@ -220,6 +220,15 @@ class EngineTelemetry:
             "with nothing in flight", ("dispatch",), registry=self.registry)
         self.decode_chunks = {d: decode_chunks.labels(dispatch=d)
                               for d in ("ahead", "alone")}
+        slot_refills = Counter(
+            "jetstream:slot_refills_total",
+            "Requests admitted into an engine slot: `ahead` of the booking "
+            "of the slot's last request, known to end inside the chunk in "
+            "flight (the prefill queues behind that chunk and the slot's "
+            "lane is in the next), or `after` it, into an empty slot",
+            ("when",), registry=self.registry)
+        self.slot_refills = {w: slot_refills.labels(when=w)
+                             for w in ("ahead", "after")}
         self.decode_lanes_discarded = Counter(
             "jetstream:decode_lanes_discarded_total",
             "Lanes of a chunk thrown away whole: the request ended (a stop "

@@ -613,12 +613,13 @@ def _req(rid, prompt, max_tokens, temperature, stop=None):
 
 
 def _by_hand(requests, *, submit_at=None, abort_at=None, model="tiny",
-             **cfg):
+             watch=None, **cfg):
     """Serve ``requests`` by calling _step() by hand. ``submit_at`` /
     ``abort_at``: request id -> the step before which it is submitted (0
     unless named) / aborted. Returns (tokens by id, finish reason by id, the
     engine). ``model``: `tiny` in float32, or a registered name on its own
-    seeded weights."""
+    seeded weights. ``watch(eng, step)`` is called once with step None when
+    the engine is built, then before every step."""
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
 
     submit_at, abort_at = submit_at or {}, abort_at or {}
@@ -630,6 +631,8 @@ def _by_hand(requests, *, submit_at=None, abort_at=None, model="tiny",
             decode_chunk=_CHUNK, seed=11, kv_events_port=0, **cfg),
             params=_tiny_f32() if model == "tiny" else None)
         outs, toks, why = {}, {}, {}
+        if watch is not None:
+            watch(eng, None)
         for step in range(400):
             for r in requests:
                 if submit_at.get(r.request_id, 0) == step:
@@ -637,6 +640,8 @@ def _by_hand(requests, *, submit_at=None, abort_at=None, model="tiny",
                     toks[r.request_id] = []
                 if abort_at.get(r.request_id) == step:
                     eng.abort(r.request_id)
+            if watch is not None:
+                watch(eng, step)
             eng._step()
             await asyncio.sleep(0)      # the events hop onto this loop
             for rid, out in outs.items():
@@ -776,6 +781,10 @@ def _counter(eng, name, labels=None):
     return eng.telemetry.registry.get_sample_value(name, labels)
 
 
+def _free_blocks(eng):
+    return getattr(eng.allocator, "reusable_blocks", eng.allocator.free_blocks)
+
+
 _CASES = [(*case, {}) for case in _STOP_AT] + [
     # Tables narrowed to the live context: the overshoot of a lane two chunks
     # past its end clamps to its row's last entry, its own block or the trash.
@@ -789,8 +798,7 @@ _CASES = [(*case, {}) for case in _STOP_AT] + [
 def test_a_chunk_in_flight_serves_the_parents_tokens(plan, t, cfg):
     toks, why, eng = _serve(plan, t, **cfg)
     assert (toks, why) == _PARENT_SERVED[plan, t]
-    held = getattr(eng.allocator, "reusable_blocks", eng.allocator.free_blocks)
-    assert held == eng.n_blocks - 1                 # every block came back
+    assert _free_blocks(eng) == eng.n_blocks - 1    # every block came back
     # Back to back, every chunk but the first went out with one unread.
     alone, ahead = (_counter(eng, "jetstream:decode_chunks_total",
                              {"dispatch": d}) for d in ("alone", "ahead"))
@@ -804,6 +812,224 @@ def test_a_chunk_in_flight_serves_the_parents_tokens(plan, t, cfg):
         # through blocks that A's overshoot had scribbled on.
         assert toks["C"][:len(toks["A"])] == toks["A"]
         assert _counter(eng, "jetstream:prefix_cached_tokens_total") == 32
+
+
+# ---- a slot that the chunk in flight vacates is refilled ahead -------------
+
+@pytest.fixture(params=["tiny", "tiny-hybrid"])
+def family(request):
+    """The llama family, and the hybrid one (a state pool row a slot) in
+    float32 under a name of its own."""
+    if request.param == "tiny":
+        yield "tiny"
+        return
+    import dataclasses
+
+    from llm_d_inference_scheduler_tpu.models import configs
+
+    name = "tiny-hybrid-f32-refill"
+    configs._REGISTRY[name] = dataclasses.replace(
+        configs.get_config("tiny-hybrid"), name=name, dtype="float32")
+    yield name
+    del configs._REGISTRY[name]
+
+
+def _queue(stop=None):
+    """Four requests for two lanes: A and B decode first, C and D wait. The
+    ends fall in the middle of a chunk (A, C), on its last step (B) and on
+    its first (D)."""
+    return [_req("A", _prompt(5, 9), 11, 0.0, stop),
+            _req("B", _prompt(7, 20), 17, 0.0),
+            _req("C", _prompt(11, 27), 14, 0.0),
+            _req("D", _prompt(13, 14), 6, 0.0)]
+
+
+class _Watch:
+    """What a run did, step by step, seen from outside the loop."""
+
+    def __init__(self):
+        self.chunks = []      # (requests waiting before the step, its lanes)
+        self.running = []     # every value the gauge was set to
+        self.freed = []       # every block list handed back
+        self.held = set()     # the blocks out now
+        self.twice = []       # blocks freed while not out
+        self.retired = []     # retired requests left over between steps
+
+    def __call__(self, eng, step):
+        if step is None:
+            gauge, dispatch = eng.telemetry.running.set, eng._dispatch_chunk
+            alloc, free = eng.allocator.alloc, eng.allocator.free
+            eng.telemetry.running.set = lambda v: (self.running.append(v),
+                                                   gauge(v))[1]
+
+            def allocated(n):
+                blocks = alloc(n)
+                self.held |= set(blocks)
+                return blocks
+
+            def freed(blocks):
+                self.freed.append(list(blocks))
+                self.twice += [b for b in blocks if b not in self.held]
+                self.held -= set(blocks)
+                free(blocks)
+
+            eng.allocator.alloc, eng.allocator.free = allocated, freed
+
+            def dispatched():
+                chunk = dispatch()
+                if chunk is not None:
+                    self.chunks.append(
+                        (self.waiting, [s.req.request_id
+                                        for _, s in chunk.lanes]))
+                return chunk
+
+            eng._dispatch_chunk = dispatched
+            return
+        self.waiting = len(eng._waiting)
+        self.retired += eng._retired
+
+
+def _alone(requests, family):
+    """Each request's tokens when it is served alone, on one engine."""
+    toks, why, _ = _by_hand(
+        requests, model=family, max_batch=2,
+        submit_at={r.request_id: 40 * n for n, r in enumerate(requests)})
+    assert set(why.values()) <= {"length"}
+    return toks
+
+
+def _refills(eng):
+    return tuple(_counter(eng, "jetstream:slot_refills_total", {"when": w})
+                 for w in ("ahead", "after"))
+
+
+_QUEUED = {}
+
+
+def _queued(family):
+    """_queue() on two lanes, served once a family for the tests below."""
+    if family not in _QUEUED:
+        watch = _Watch()
+        toks, why, eng = _by_hand(_queue(), model=family, max_batch=2,
+                                  watch=watch)
+        _QUEUED[family] = toks, why, eng, watch
+    return _QUEUED[family]
+
+
+def test_refilled_ahead_every_request_gets_the_tokens_it_gets_alone(family):
+    toks, why, _, _ = _queued(family)
+    assert toks == _alone(_queue(), family)
+    assert [len(toks[r.request_id]) for r in _queue()] == [11, 17, 14, 6]
+
+
+def test_refilled_ahead_the_predecessor_is_served_to_its_end(family):
+    toks, why, eng, watch = _queued(family)
+    # Its last tokens came through the chunk it was retired in, and its end.
+    assert why == dict.fromkeys("ABCD", "length")
+    # Every request's blocks came back once, and nothing else did.
+    assert len(watch.freed) == 4 and not watch.twice and not watch.held
+    assert _free_blocks(eng) == eng.n_blocks - 1
+    assert max(watch.running) == 2 and watch.running[-1] == 0
+    assert not watch.retired and not eng._retired
+
+
+def test_refilled_ahead_no_lane_is_empty_while_a_request_waits(family):
+    _, _, eng, watch = _queued(family)
+    # C took A's slot and D took B's with their last chunks unread.
+    assert _refills(eng) == (2, 2)
+    waited = [lanes for waiting, lanes in watch.chunks[1:] if waiting]
+    assert waited and all(len(lanes) == 2 for lanes in waited)
+    # A's and C's lanes are one slot's, in consecutive chunks.
+    ids = [lanes for _, lanes in watch.chunks]
+    last_a = max(n for n, lanes in enumerate(ids) if "A" in lanes)
+    assert "C" in ids[last_a + 1] and "A" not in ids[last_a + 1]
+    assert _counter(eng, "jetstream:decode_lanes_discarded_total") == 0
+
+
+def test_refill_waits_for_the_booking_where_the_pool_is_too_small(family):
+    """Four usable blocks, two a request: the predecessor's come back at its
+    booking, and until then the head of the queue does not fit."""
+    watch = _Watch()
+    toks, why, eng = _by_hand(_queue(), model=family, max_batch=2,
+                              hbm_kv_blocks=5, watch=watch)
+    assert _refills(eng) == (0, 4)
+    assert why == dict.fromkeys("ABCD", "length")
+    assert toks == _queued(family)[0]
+    assert len(watch.freed) == 4 and not watch.twice and not watch.held
+    assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+@pytest.mark.parametrize("who, at", [
+    # A is aborted before the step that would have found it vacating: C takes
+    # the empty slot, and D takes B's ahead.
+    ("A", 3),
+    # C is aborted in A's slot, a step after it was refilled ahead, in a chunk
+    # in flight: D takes the empty slot.
+    ("C", 4)])
+def test_refill_ahead_with_an_abort_leaks_nothing(family, who, at):
+    watch = _Watch()
+    toks, why, eng = _by_hand(_queue(), model=family, max_batch=2,
+                              abort_at={who: at}, watch=watch)
+    assert why == {**dict.fromkeys("ABCD", "length"), who: "abort"}
+    served = _queued(family)[0]
+    assert all(toks[r] == served[r] for r in "ABCD" if r != who)
+    assert toks[who] == served[who][:len(toks[who])]
+    assert _refills(eng) == (1, 3)
+    assert len(watch.freed) == 4 and not watch.twice and not watch.held
+    assert _free_blocks(eng) == eng.n_blocks - 1
+    assert not watch.retired and not eng._retired
+
+
+def test_refill_ahead_then_a_failed_step_ends_the_retired_request_too(family):
+    """The loop fails between C's prefill into A's slot and the booking of
+    A's last chunk: A is in no slot, and is aborted with the rest."""
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    async def body():
+        eng = TpuEngine(EngineConfig(
+            model=family, backend="tpu", max_model_len=128, max_batch=2,
+            decode_chunk=_CHUNK, seed=11, kv_events_port=0),
+            params=_tiny_f32() if family == "tiny" else None)
+        outs = {r.request_id: eng.submit(r) for r in _queue()}
+        read = eng._read_tokens
+        eng._read_tokens = lambda toks: (
+            read(toks) if not eng._retired else 1 / 0)
+        for _ in range(40):
+            try:
+                eng._step()
+            except ZeroDivisionError:
+                eng._abort_all("engine loop failure")   # as _run does
+                break
+        else:
+            raise AssertionError("no request was retired in 40 steps")
+        await asyncio.sleep(0)
+        ends = {}
+        for rid, out in outs.items():
+            while not out.empty():
+                ev = out.get_nowait()
+                if ev.finish_reason is not None:
+                    ends[rid] = ev.finish_reason.value
+        return ends, eng
+
+    ends, eng = asyncio.run(body())
+    assert ends == dict.fromkeys("ABCD", "abort")
+    assert not eng._retired and not any(eng.slots)
+    assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+def test_a_lane_that_ends_on_a_stop_token_is_not_refilled_ahead(family):
+    """A's sixth token is its stop token: nothing says so before it is read,
+    and C takes the slot after the booking, as it always did."""
+    served = _queued(family)[0]["A"]
+    at = next(n for n in range(5, 11) if served[n] not in served[:n])
+    requests = _queue(stop=served[at])[:3]
+    requests[1].max_tokens = 40                 # B outlasts both
+    toks, why, eng = _by_hand(requests, model=family, max_batch=2)
+    assert why == {"A": "stop", "B": "length", "C": "length"}
+    assert toks["A"] == served[:at] and toks["C"] == _queued(family)[0]["C"]
+    assert _refills(eng) == (0, 3)
+    assert _counter(eng, "jetstream:decode_lanes_discarded_total") == 1
+    assert _free_blocks(eng) == eng.n_blocks - 1
 
 
 if __name__ == "__main__":
