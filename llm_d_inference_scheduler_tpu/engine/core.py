@@ -396,12 +396,15 @@ class TpuEngine:
             pages.alloc(self.geom, sharding=pages.page_sharding(mesh))
             if mesh is not None
             else pages.alloc(self.geom, device=self.device,
-                             state=self.state_geom))
-        # Counts of expert choices held here that step programs summed on
-        # the device (kvcache/state.py), oldest first, each with the choices
-        # its program made in all: booked once their programs are done
-        # (_note_pair_counts, behind a chunk's tokens), so that no read waits.
-        self._pair_counts: collections.deque[tuple[Any, int]] = (
+                             state=self.state_geom,
+                             counted=self.mcfg.tallies_choices,
+                             counts_zero=bool(self.mcfg.n_zero_experts)))
+        # Counts of expert choices held here, and of zero-compute ones, that
+        # step programs summed on the device (kvcache/state.py), oldest
+        # first, each with the choices its program made in all: booked once
+        # their programs are done (_note_pair_counts, behind a chunk's
+        # tokens), so that no read waits.
+        self._pair_counts: collections.deque[tuple[Any, Any, int]] = (
             collections.deque())
 
         self.warming = cfg.warmup  # cleared by the engine thread post-compile
@@ -557,10 +560,18 @@ class TpuEngine:
                 "max_batch": self.cfg.max_batch,
                 "max_model_len": self.cfg.max_model_len,
                 "kv_blocks": self.n_blocks,
-                # A token's bytes in one layer's pages, the layout's padding
-                # counted, and the whole pool's.
+                # The cache layers (two a double layer), a token's bytes in
+                # one of them, the layout's padding counted, and the whole
+                # pool's.
+                "kv_layers": self.geom.n_layers,
                 "kv_token_bytes": self.geom.token_bytes,
                 "kv_pool_bytes": self.geom.pool_bytes,
+                # The experts this chip holds of those its router scores
+                # (all of them: first 0, held n_experts), and the router's
+                # outputs that compute nothing.
+                "experts_first": self.mcfg.held_experts[0],
+                "experts_held": self.mcfg.held_experts[1],
+                "zero_experts": self.mcfg.n_zero_experts,
                 # What the state-space layers keep a slot and in all (0: the
                 # model keeps pages alone), and what such a model turns off.
                 "state_slot_bytes": (self.state_geom.slot_bytes
@@ -2850,25 +2861,30 @@ class TpuEngine:
         return self._op_keep_tokens(slots, tok)
 
     def _keep_cache(self, k_pages, rows: int) -> None:
-        """Keep the cache a step returned. Where it carries a count of the
-        expert choices held here (kvcache/state.py), the count is taken out
-        and queued with the choices the step's ``rows`` made in all."""
-        self.k_pages, held = state_pool.take_counts(k_pages)
+        """Keep the cache a step returned. Where it carries counts of the
+        router's choices (kvcache/state.py: held here, zero-compute), they
+        are taken out and queued with the choices the step's ``rows`` made in
+        all."""
+        self.k_pages, held, zero = state_pool.take_counts(k_pages)
         if held is not None:
             held.copy_to_host_async()
+            if zero is not None:
+                zero.copy_to_host_async()
             self._pair_counts.append((
-                held, rows * self.mcfg.experts_per_token
-                * self.mcfg.layer_pattern.count("E")))
+                held, zero, rows * self.mcfg.experts_per_token
+                * self.mcfg.n_expert_layers))
 
     def _note_pair_counts(self) -> None:
         """Book the queued counts whose programs are done (every one
         dispatched before tokens the host has just read is): reading them
         waits for nothing."""
         while self._pair_counts and self._pair_counts[0][0].is_ready():
-            held, pairs = self._pair_counts.popleft()
-            held = int(held)
+            held, zero, pairs = self._pair_counts.popleft()
+            held, zero = int(held), 0 if zero is None else int(zero)
             self.telemetry.moe_routed_pairs["yes"].inc(held)
-            self.telemetry.moe_routed_pairs["no"].inc(pairs - held)
+            self.telemetry.moe_routed_pairs["no"].inc(pairs - held - zero)
+            if zero:
+                self.telemetry.moe_zero_pairs().inc(zero)
 
     def _op_mm_prefill(self, bucket, mm_bucket, tokens, seq_len, mm_pad,
                        pos_pad, row, slots, temps, top_k, top_p):

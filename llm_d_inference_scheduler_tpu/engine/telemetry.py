@@ -10,6 +10,7 @@ fleets via its mapping registry).
 from __future__ import annotations
 
 import collections
+import functools
 import statistics
 import threading
 import time
@@ -173,14 +174,16 @@ class EngineTelemetry:
             "jetstream:moe_ffn_tokens_total",
             "Rows (padded tokens) dispatched through the MoE FFN, by the form "
             "their program's shape traced to (ops/pallas_moe.use_grouped); "
-            "counted on the host at dispatch, empty for a dense model",
+            "counted on the host at dispatch, a token once a program however "
+            "many expert layers it passes; empty for a dense model",
             ("form",), registry=self.registry)
         self.mla_attention_tokens = Counter(
             "jetstream:mla_attention_tokens_total",
             "Rows (padded tokens) dispatched through latent attention, by the "
             "form their program traced to (models/mla.py: a decode step is "
             "absorbed, a prefill or a prefix-continuation window expanded); "
-            "counted on the host at dispatch, empty without a latent pool",
+            "counted on the host at dispatch, a token once a program and not "
+            "once an attention sublayer; empty without a latent pool",
             ("form",), registry=self.registry)
         self.ssm_tokens = Counter(
             "jetstream:ssm_tokens_total",
@@ -206,13 +209,20 @@ class EngineTelemetry:
         moe_routed_pairs = Counter(
             "jetstream:moe_routed_pairs_total",
             "(Token, expert) choices of the router, padded rows among them, "
-            "by whether the chosen expert is held on this chip (`yes`) or "
+            "by whether the chosen expert is held on this chip (`yes`), "
             "would be another chip's (`no`, its part of the result left "
-            "out); summed on the device inside the step programs and read "
-            "once tokens dispatched later have been read; empty where every "
-            "expert is held", ("held",), registry=self.registry)
+            "out) or computes nothing (`zero`: the token itself times its "
+            "gate, on whichever chip the token is; a series only a model "
+            "whose router has such outputs brings); summed on the device "
+            "inside the step programs and read once tokens dispatched later "
+            "have been read; empty where every choice is an expert held "
+            "here", ("held",), registry=self.registry)
         self.moe_routed_pairs = {h: moe_routed_pairs.labels(held=h)
                                  for h in ("yes", "no")}
+        # `zero` appears with its first count: a model without such experts
+        # never exposes it.
+        self.moe_zero_pairs = functools.partial(moe_routed_pairs.labels,
+                                                held="zero")
         decode_chunks = Counter(
             "jetstream:decode_chunks_total",
             "Decode chunks dispatched: `ahead` while the chunk before was "

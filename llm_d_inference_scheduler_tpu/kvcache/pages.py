@@ -137,19 +137,25 @@ def page_sharding(mesh: Mesh) -> NamedSharding:
 
 
 def alloc(geom: PageGeometry, *, device=None, sharding=None,
-          state: state_pool.StateGeometry | None = None
+          state: state_pool.StateGeometry | None = None,
+          counted: bool = False, counts_zero: bool = False
           ) -> tuple[jax.Array, jax.Array | None]:
     """Zeroed ``(k_pages, v_pages)``, on one device or laid out by
     ``sharding`` (made in place, shard by shard); ``(pool, None)`` for a
     latent geometry, which has no sharding rule yet. With ``state`` (a model
     with state-space layers) the pair is ``(cache, None)``: the page pools
-    and the state pool as one value (kvcache/state.py), unsharded too."""
-    if state is not None:
-        if sharding is not None or geom.latent_dim:
+    and the state pool as one value (kvcache/state.py), unsharded too. So it
+    is with ``counted`` (a model whose step programs count their router's
+    choices, ``counts_zero``: the zero-compute ones too), whose counts that
+    value carries."""
+    if state is not None or counted:
+        if sharding is not None or (state is not None and geom.latent_dim):
             raise ValueError("a state pool lies beside an unsharded K/V page "
-                             "pool only: it has no sharding rule")
+                             "pool only, and a step's counts ride with an "
+                             "unsharded pool only: no sharding rule for "
+                             "either")
         return state_pool.alloc(state, *alloc(geom, device=device),
-                                device=device), None
+                                device=device, counts_zero=counts_zero), None
     dtype = jnp.dtype(geom.dtype)
     if geom.latent_dim:
         if sharding is not None:

@@ -37,11 +37,19 @@ XLA in both.
 A model with state layers hands its whole cache through the step functions as
 one value, :class:`Cache`, where the other families hand ``(k_pages,
 v_pages)``: the pair becomes ``(cache, None)``. Besides the pools it carries
-two small things that ride with a step: ``slots`` [B], the engine slot of
+small things that ride with a step: ``slots`` [B], the engine slot of
 every row of this step (set by the engine before the call, :func:`at_slots`),
-and ``held``, the count of (token, expert) choices this step's programs found
-to live on this chip, which the engine takes out after the call
-(:func:`take_counts`). ``engine/core.py`` learns neither shape.
+and the counts its programs sum on the device, which the engine takes out
+after the call (:func:`take_counts`): ``held``, the (token, expert) choices
+this step's programs found to live on this chip, and ``zero``, those that
+named an expert that computes nothing. ``engine/core.py`` learns none of
+these shapes.
+
+The counts have this one carrier in every family. A latent page pool whose
+model counts its router's choices (models/mla.py, where a chip holds a share
+of the experts or the router has zero-compute outputs:
+``ModelConfig.tallies_choices``) rides in a :class:`Cache` as well: ``k`` the
+latent pool, ``v`` None, and no state pools (``ssm`` and ``conv`` None).
 """
 
 from __future__ import annotations
@@ -106,12 +114,16 @@ class StateGeometry:
 class Cache:
     """Pages and state as one value (see the module's docstring)."""
 
-    k: jax.Array                    # the K/V page pools (kvcache/pages.py)
-    v: jax.Array
-    ssm: jax.Array
-    conv: jax.Array
+    k: jax.Array                    # the K/V page pools (kvcache/pages.py),
+    v: jax.Array | None             # or a latent pool and None
+    ssm: jax.Array | None           # None: a model without state layers
+    conv: jax.Array | None
     slots: jax.Array | None = None  # [B] int32: the rows of this step
     held: jax.Array | None = None   # int32 scalar: choices held, this step
+    zero: jax.Array | None = None   # the same of zero-compute choices,
+    # which only a model whose router has such outputs counts.
+    counts_zero: bool = dataclasses.field(default=False,
+                                          metadata=dict(static=True))
 
 
 @jax.tree_util.register_dataclass
@@ -123,19 +135,24 @@ class Fresh:
     [state layers, B, tail rows, channels] its true last token left."""
 
     k: jax.Array
-    v: jax.Array
-    ssm: jax.Array
-    conv: jax.Array
+    v: jax.Array | None
+    ssm: jax.Array | None
+    conv: jax.Array | None
     held: jax.Array
+    zero: jax.Array | None = None
 
 
-def alloc(geom: StateGeometry, k_pages: jax.Array, v_pages: jax.Array, *,
-          device=None) -> Cache:
-    """A zeroed state pool beside the given page pools."""
+def alloc(geom: StateGeometry | None, k_pages: jax.Array,
+          v_pages: jax.Array | None, *, device=None,
+          counts_zero: bool = False) -> Cache:
+    """A zeroed state pool beside the given page pools; ``geom`` None: the
+    page pools alone, in the value that carries a step's counts."""
+    if geom is None:
+        return Cache(k_pages, v_pages, None, None, counts_zero=counts_zero)
     return Cache(k_pages, v_pages,
                  jnp.zeros(geom.ssm_shape, jnp.float32, device=device),
                  jnp.zeros(geom.conv_shape, jnp.dtype(geom.dtype),
-                           device=device))
+                           device=device), counts_zero=counts_zero)
 
 
 def at_slots(cache: Any, slots: Any) -> Any:
@@ -144,17 +161,29 @@ def at_slots(cache: Any, slots: Any) -> Any:
     anything that is no :class:`Cache` (a page pool) goes through as it is."""
     if not isinstance(cache, Cache):
         return cache
-    return dataclasses.replace(cache, slots=np.asarray(slots, np.int32),
-                               held=np.zeros((), np.int32))
+    return dataclasses.replace(
+        cache, slots=np.asarray(slots, np.int32), held=np.zeros((), np.int32),
+        zero=np.zeros((), np.int32) if cache.counts_zero else None)
 
 
-def take_counts(cache: Any) -> tuple[Any, jax.Array | None]:
+def take_counts(cache: Any
+                ) -> tuple[Any, jax.Array | None, jax.Array | None]:
     """(The cache as it is kept between steps, the step's count of held
-    expert choices or None.) The count leaves the cache so that it is not
-    donated to the next step with it."""
+    expert choices or None, its count of zero-compute choices or None.) The
+    counts leave the cache so that they are not donated to the next step
+    with it."""
     if not isinstance(cache, Cache):
-        return cache, None
-    return dataclasses.replace(cache, slots=None, held=None), cache.held
+        return cache, None, None
+    return (dataclasses.replace(cache, slots=None, held=None, zero=None),
+            cache.held, cache.zero)
+
+
+def counted(cache: Cache, held: jax.Array, zero: jax.Array | None = None
+            ) -> Cache:
+    """``cache`` with a program's counts added to those it carries."""
+    return dataclasses.replace(
+        cache, held=cache.held + held,
+        zero=None if cache.zero is None else cache.zero + zero)
 
 
 # ---- reads and writes, by slot ---------------------------------------------------
@@ -218,6 +247,8 @@ def start(cache: Cache, fresh: Fresh, k_pages: jax.Array,
     """``cache`` after a first prefill window: the page pools as the window's
     K/V write left them, and this step's slots started afresh from
     ``fresh``."""
-    cache = dataclasses.replace(cache, k=k_pages, v=v_pages,
-                                held=cache.held + fresh.held)
+    cache = counted(dataclasses.replace(cache, k=k_pages, v=v_pages),
+                    fresh.held, fresh.zero)
+    if cache.ssm is None:
+        return cache
     return write(cache, list(fresh.ssm), list(fresh.conv))

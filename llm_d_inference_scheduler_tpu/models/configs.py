@@ -89,6 +89,24 @@ class ModelConfig:
     # without its exchange: models/hybrid.py).
     experts_held: int = 0
     experts_first: int = 0
+    # LongCat-Flash's layer (models/mla.py serves it too; attn_sublayers == 2
+    # names it): two latent-attention sublayers, each with a dense SwiGLU of
+    # d_ff behind it, and ONE expert layer that reads the first sublayer's
+    # FFN input and is added after the second sublayer's FFN (the shortcut).
+    # A layer keeps two cache layers. Its query is low-rank (q_lora_rank > 0:
+    # W_qa, an RMSNorm, W_qb) and the query and the normed latent are scaled
+    # by sqrt(d_model / their rank) where the two flags say so.
+    attn_sublayers: int = 1
+    q_lora_rank: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # That family's router (models/routing.py): "softmax" scores over ALL its
+    # outputs, the chosen scores' gates not normalised, where the other
+    # families' "sigmoid" scores are normalised over the chosen. Its outputs
+    # are the n_experts experts and then n_zero_experts experts that compute
+    # nothing: a choice of one returns the token itself times its gate.
+    router_scoring: str = "sigmoid"
+    n_zero_experts: int = 0
 
     @property
     def n_state_layers(self) -> int:
@@ -97,9 +115,32 @@ class ModelConfig:
 
     @property
     def n_kv_layers(self) -> int:
-        """Layers that keep pages of keys and values."""
+        """Layers that keep pages of keys and values (cache layers)."""
         return (self.layer_pattern.count("*") if self.layer_pattern
-                else self.n_layers)
+                else self.n_layers * self.attn_sublayers)
+
+    @property
+    def n_expert_layers(self) -> int:
+        """Layers with a router (0: a dense model)."""
+        if not self.n_experts:
+            return 0
+        if self.layer_pattern:
+            return self.layer_pattern.count("E")
+        return self.n_layers - self.first_k_dense
+
+    @property
+    def router_width(self) -> int:
+        """Outputs of the router: the experts, then the zero-compute ones."""
+        return self.n_experts + self.n_zero_experts
+
+    @property
+    def tallies_choices(self) -> bool:
+        """Whether the step programs count their router's choices on the
+        device (held here / zero-compute; kvcache/state.Cache carries the
+        counts out): models/hybrid.py's always do, models/mla.py's where not
+        every choice is an expert held here."""
+        return bool(self.layer_pattern or self.experts_held
+                    or self.n_zero_experts)
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -314,6 +355,35 @@ TINY_MLA = ModelConfig(
     routed_scaling_factor=2.446,
 )
 
+# LongCat-Flash's double layer at small widths aligned to nothing (CI tests):
+# 2 layers = 4 cache layers, a query rank of 20, a router of 16 experts + 8
+# zero-compute ones, 5 a token; every expert held (tests cut a share).
+TINY_LONGCAT = ModelConfig(
+    name="tiny-longcat",
+    vocab_size=512,
+    d_model=96,
+    n_layers=2,
+    n_heads=3,
+    n_kv_heads=3,
+    d_ff=160,
+    max_seq_len=256,
+    rope_theta=10_000.0,
+    n_experts=16,
+    experts_per_token=5,
+    kv_lora_rank=24,
+    qk_nope_head_dim=20,
+    qk_rope_head_dim=8,
+    v_head_dim=12,
+    moe_d_ff=40,
+    routed_scaling_factor=6.0,
+    attn_sublayers=2,
+    q_lora_rank=20,
+    mla_scale_q_lora=True,
+    mla_scale_kv_lora=True,
+    router_scoring="softmax",
+    n_zero_experts=8,
+)
+
 # NVIDIA-Nemotron-3-Super-120B-A12B's language model (public config.json,
 # model_type nemotron_h): 88 layers of one mixer each -- 40 Mamba-2, 40
 # LatentMoE (512 experts of 2688 in a 1024-wide latent space, 22 a token,
@@ -384,7 +454,8 @@ TINY_HYBRID = ModelConfig(
 _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
-                                 KIMI_VL_A3B, TINY_MLA, NEMOTRON_3_SUPER,
+                                 KIMI_VL_A3B, TINY_MLA, TINY_LONGCAT,
+                                 NEMOTRON_3_SUPER,
                                  NEMOTRON_3_SUPER_CUT, TINY_HYBRID)}
 
 

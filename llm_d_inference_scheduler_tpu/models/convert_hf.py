@@ -29,6 +29,29 @@ from .configs import ModelConfig
 __all__ = ["config_from_hf", "convert_state_dict", "main"]
 
 
+def _refuse_other_options(hf, name: str, only: dict, module: str) -> None:
+    """A config that sets an option to something ``module`` does not compute
+    is refused, not served as something else."""
+    for key, value in only.items():
+        got = getattr(hf, key, value)
+        if got != value:
+            raise ValueError(f"{name}: {key}={got!r} is not supported "
+                             f"({module} computes {key}={value!r} only)")
+
+
+def _held_share(hf) -> dict:
+    """The ModelConfig fields of a chip's share of each layer's experts, from
+    the two keys that state it beside the published ones:
+    ``n_routed_experts_published`` (what the router scores, where
+    ``n_routed_experts`` counts the experts held) and
+    ``expert_parallel_rank`` (which share: the experts from rank x held
+    on)."""
+    held = hf.n_routed_experts
+    total = getattr(hf, "n_routed_experts_published", held)
+    return dict(n_experts=total, experts_held=held if held != total else 0,
+                experts_first=getattr(hf, "expert_parallel_rank", 0) * held)
+
+
 # What models/mla.py computes of the DeepSeek-V3 family's options; a config
 # that says otherwise is refused, not served as something else.
 _MLA_ONLY = {"q_lora_rank": None, "rope_scaling": None, "n_group": 1,
@@ -41,11 +64,7 @@ _MLA_ONLY = {"q_lora_rank": None, "rope_scaling": None, "n_group": 1,
 def _mla_config_from_hf(hf, name: str) -> ModelConfig:
     """The DeepSeek-V3 family (latent attention, sigmoid-routed experts
     beside a shared one): Kimi-VL's language model is one."""
-    for key, only in _MLA_ONLY.items():
-        got = getattr(hf, key, only)
-        if got != only:
-            raise ValueError(f"{name}: {key}={got!r} is not supported "
-                             f"(models/mla.py computes {key}={only!r} only)")
+    _refuse_other_options(hf, name, _MLA_ONLY, "models/mla.py")
     return ModelConfig(
         name=name,
         vocab_size=hf.vocab_size,
@@ -70,6 +89,53 @@ def _mla_config_from_hf(hf, name: str) -> ModelConfig:
     )
 
 
+# What models/mla.py computes of LongCat-Flash's options.
+_LONGCAT_ONLY = {"attention_method": "MLA", "zero_expert_type": "identity",
+                 "attention_bias": False, "rope_scaling": None,
+                 "norm_topk_prob": False, "router_bias": False,
+                 "hidden_act": "silu", "tie_word_embeddings": False}
+
+
+def _longcat_config_from_hf(hf, name: str) -> ModelConfig:
+    """LongCat-Flash (a double layer of two latent-attention sublayers, a
+    low-rank query, a softmax router over experts and zero-compute experts),
+    from its published keys, flat: ``num_layers`` double layers,
+    ``ffn_hidden_size`` the dense FFNs', ``expert_ffn_hidden_size`` an
+    expert's, ``moe_topk`` choices a token; a chip's share of the experts as
+    :func:`_held_share` reads it."""
+    _refuse_other_options(hf, name, _LONGCAT_ONLY, "models/mla.py")
+    if not getattr(hf, "q_lora_rank", None):
+        raise ValueError(f"{name}: LongCat-Flash's query is low-rank "
+                         "(q_lora_rank); models/mla.py has no full-rank "
+                         "query for the double layer")
+    return ModelConfig(
+        name=name,
+        vocab_size=hf.vocab_size,
+        d_model=hf.hidden_size,
+        n_layers=hf.num_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_attention_heads,
+        d_ff=hf.ffn_hidden_size,
+        rope_theta=float(getattr(hf, "rope_theta", 10_000.0)),
+        max_seq_len=getattr(hf, "max_position_embeddings", 8192),
+        norm_eps=hf.rms_norm_eps,
+        experts_per_token=hf.moe_topk,
+        kv_lora_rank=hf.kv_lora_rank,
+        qk_nope_head_dim=hf.qk_nope_head_dim,
+        qk_rope_head_dim=hf.qk_rope_head_dim,
+        v_head_dim=hf.v_head_dim,
+        moe_d_ff=hf.expert_ffn_hidden_size,
+        routed_scaling_factor=float(hf.routed_scaling_factor),
+        **_held_share(hf),
+        attn_sublayers=2,
+        q_lora_rank=hf.q_lora_rank,
+        mla_scale_q_lora=bool(getattr(hf, "mla_scale_q_lora", False)),
+        mla_scale_kv_lora=bool(getattr(hf, "mla_scale_kv_lora", False)),
+        router_scoring="softmax",
+        n_zero_experts=hf.zero_expert_num or 0,
+    )
+
+
 # What models/hybrid.py computes of the nemotron_h family's options.
 _HYBRID_ONLY = {"n_group": 1, "topk_group": 1, "mamba_proj_bias": False,
                 "sliding_window": None, "attention_bias": False,
@@ -81,17 +147,9 @@ _HYBRID_ONLY = {"n_group": 1, "topk_group": 1, "mamba_proj_bias": False,
 
 def _hybrid_config_from_hf(hf, name: str) -> ModelConfig:
     """The nemotron_h family (a pattern of Mamba-2, LatentMoE and attention
-    layers), from its published keys, flat. Two keys beside the published
-    ones say that this chip holds a share of each layer's experts:
-    ``n_routed_experts_published`` (what the router scores, where
-    ``n_routed_experts`` counts the experts held) and
-    ``expert_parallel_rank`` (which share: the experts from rank x held
-    on)."""
-    for key, only in _HYBRID_ONLY.items():
-        got = getattr(hf, key, only)
-        if got != only:
-            raise ValueError(f"{name}: {key}={got!r} is not supported "
-                             f"(models/hybrid.py computes {key}={only!r} only)")
+    layers), from its published keys, flat; a chip's share of each layer's
+    experts as :func:`_held_share` reads it."""
+    _refuse_other_options(hf, name, _HYBRID_ONLY, "models/hybrid.py")
     pattern = hf.hybrid_override_pattern
     if set(pattern) - set("ME*") or len(pattern) != hf.num_hidden_layers:
         raise ValueError(
@@ -101,8 +159,6 @@ def _hybrid_config_from_hf(hf, name: str) -> ModelConfig:
     if hf.mamba_num_heads * hf.mamba_head_dim != hf.expand * hf.hidden_size:
         raise ValueError(f"{name}: mamba_num_heads x mamba_head_dim must be "
                          "expand x hidden_size")
-    held = hf.n_routed_experts
-    total = getattr(hf, "n_routed_experts_published", held)
     return ModelConfig(
         name=name,
         vocab_size=hf.vocab_size,
@@ -115,7 +171,6 @@ def _hybrid_config_from_hf(hf, name: str) -> ModelConfig:
         max_seq_len=getattr(hf, "max_position_embeddings", 8192),
         norm_eps=hf.layer_norm_epsilon,
         head_dim_override=hf.head_dim,
-        n_experts=total,
         experts_per_token=hf.num_experts_per_tok,
         moe_d_ff=hf.moe_intermediate_size,
         n_shared_experts=hf.n_shared_experts,
@@ -130,15 +185,14 @@ def _hybrid_config_from_hf(hf, name: str) -> ModelConfig:
         ssm_dt_range=(hf.time_step_min, hf.time_step_max, hf.time_step_floor),
         moe_latent_dim=hf.moe_latent_size,
         shared_d_ff=hf.moe_shared_expert_intermediate_size,
-        experts_held=held if held != total else 0,
-        experts_first=getattr(hf, "expert_parallel_rank", 0) * held,
+        **_held_share(hf),
     )
 
 
 def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
     """Map a transformers Llama/Mixtral/Qwen3 config, a DeepSeek-V3-family
-    one (Kimi-VL's ``text_config``), or a nemotron_h one (a layer pattern)
-    to our ModelConfig."""
+    one (Kimi-VL's ``text_config``), a LongCat-Flash one (``zero_expert_num``
+    names it) or a nemotron_h one (a layer pattern) to our ModelConfig."""
     text = getattr(hf_config, "text_config", None)
     if text is not None:
         # A multimodal config nests its language model; the towers beside it
@@ -149,6 +203,8 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
                      else text)
     if getattr(hf_config, "hybrid_override_pattern", None):
         return _hybrid_config_from_hf(hf_config, name)
+    if getattr(hf_config, "zero_expert_num", None) is not None:
+        return _longcat_config_from_hf(hf_config, name)
     if getattr(hf_config, "kv_lora_rank", None):
         return _mla_config_from_hf(hf_config, name)
     n_experts = getattr(hf_config, "num_local_experts", 0) or 0

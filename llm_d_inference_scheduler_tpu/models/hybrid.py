@@ -484,7 +484,7 @@ def _written(cache: state.Cache, ks, vs, ssms, tails, held, kv_slots
         cache = dataclasses.replace(cache, k=k, v=v)
     if tails:
         cache = state.write(cache, ssms, tails)
-    return dataclasses.replace(cache, held=cache.held + held)
+    return state.counted(cache, held)
 
 
 def prefill_with_prefix(

@@ -1,6 +1,9 @@
-"""The DeepSeek-V3 family's decoder block for the TPU engine: latent attention
-(MLA) and sigmoid-routed narrow experts beside a shared one. Kimi-VL-A3B's
-language model is this block (its vision tower is not built).
+"""The latent-attention (MLA) decoder blocks for the TPU engine, two of them.
+The DeepSeek-V3 family's: latent attention and sigmoid-routed narrow experts
+beside a shared one; Kimi-VL-A3B's language model is this block (its vision
+tower is not built). And LongCat-Flash's double layer (``cfg.attn_sublayers``
+2, described last); LongCat-Flash-Omni's language model is that one (its
+encoders and its codec decoder are not built).
 
 The module has models/llama.py's four entry points with its signatures
 (``init_params``, ``forward``, ``decode_step``, ``prefill_with_prefix``), so
@@ -47,16 +50,44 @@ routed_scaling_factor``; ``y = sum g_i SwiGLU_i(h) + SwiGLU_shared(h)``. The
 routed part runs dense over the experts or grouped (ops/pallas_moe.py),
 chosen by the engine per program as for Mixtral (``cfg.moe_impl``); the
 shared expert is a plain SwiGLU beside either.
+
+**The double layer** (``params["layers"]`` alone; what a sublayer has --
+its attention and its dense FFN -- is stacked a SUBLAYER, 2 L rows, row
+``2 l + i`` the cache layer's own number, and what the expert layer has a
+layer, L rows). For i in (0, 1):
+``x += MLA_i(RMSNorm(x)) W_o[i]``, which writes cache layer ``2 l + i``; ``h =
+RMSNorm(x)``; for i == 0 the expert layer's ``m = MoE(h)`` is taken here;
+``x += SwiGLU_i(h)`` at width d_ff. After sublayer 1: ``x += m`` (the
+shortcut: the experts of a layer compute beside its second attention and
+both dense FFNs). Its attention has a low-rank query (``c_q = RMSNorm(h
+W_qa)``, ``q = c_q W_qb``) and two scale factors, ``sqrt(d_model /
+q_lora_rank)`` on q and ``sqrt(d_model / kv_lora_rank)`` on the normed latent
+before it is cached, where the configuration's flags say so; everything after
+the projection is the attention above. Its router (models/routing.py,
+"softmax") scores ``n_experts + n_zero_experts`` outputs: a choice past the
+experts names an expert that computes nothing, ``E(h) = h``, so ``m = sum over
+chosen AND HELD experts of g_i SwiGLU_i(h) + (sum of the gates of the zero
+choices) h``. No shared expert. The chip holds ``cfg.held_experts`` of the
+experts (expert parallelism without its exchange, as models/hybrid.py: what
+the absent ones would have added is left out); the zero term is computed
+where the token is, on every chip, and is no chip's share.
+
+Where ``cfg.tallies_choices`` (a held range, or zero-compute outputs) the
+step programs count the choices held here and the zero ones, and the pool
+rides in a ``kvcache/state.Cache`` that carries the counts out, as
+models/hybrid.py's does; elsewhere (Kimi) the pool is passed bare and nothing
+is counted.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
-from ..kvcache import pages
+from ..kvcache import pages, state
 from ..ops import apply_rope, rms_norm, rope_table
 from ..ops.attention import NEG_INF
 from .configs import ModelConfig
@@ -87,20 +118,50 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                                               jnp.float32)).astype(dtype)
 
     def attention(L):
+        # Where the block scales q or the latent by sqrt(d_model / rank), the
+        # up-projection is drawn at the width that factor refers to (variance
+        # 1 / d_model: the scale is there to correct exactly that), so q, k
+        # and v come out at unit variance as a trained model's do. Drawn
+        # fan-in scaled they come out sqrt(d_model / rank) too large, 7 x
+        # in the attention logits at the published ranks: attention turns
+        # one-hot, and bf16 rounding then flips WHICH row it attends to
+        # (chip run, PR 39: 20-50% of max |logit| against the reference).
+        rq = cfg.q_lora_rank
+        query = ({"wqa": w((L, D, rq), D), "q_norm": norm((L, rq)),
+                  "wqb": w((L, rq, H * (dn + dr)),
+                           D if cfg.mla_scale_q_lora else rq)} if rq
+                 else {"wq": w((L, D, H * (dn + dr)), D)})
         return {
-            "wq": w((L, D, H * (dn + dr)), D),
+            **query,
             "wkva": w((L, D, r + dr), D),
             "kv_norm": norm((L, r)),
-            "wkvb": w((L, r, H * (dn + dv)), r),
+            "wkvb": w((L, r, H * (dn + dv)),
+                      D if cfg.mla_scale_kv_lora else r),
             "wo": w((L, H * dv, D), H * dv),
             "ln_attn": norm((L, D)),
             "ln_mlp": norm((L, D)),
         }
 
-    Ld = cfg.first_k_dense
-    Le = cfg.n_layers - Ld
     params = {"embed": w((V, D), D), "final_norm": norm((D,)),
               "lm_head": w((D, V), D)}
+    if cfg.attn_sublayers == 2:
+        L, Eh, F = cfg.n_layers, cfg.held_experts[1], cfg.d_ff
+        width = cfg.router_width
+        params["layers"] = {
+            **attention(2 * L),
+            "w1d": w((2 * L, D, F), D), "w3d": w((2 * L, D, F), D),
+            "w2d": w((2 * L, F, D), F),
+            "router": w((L, D, width), D),
+            # Of the order of the scores' spread, as below: a softmax over
+            # `width` outputs spreads its scores by about 1 / width (0.1 here
+            # would hand every token the same experts_per_token outputs).
+            "router_bias": (0.5 / width) * jax.random.normal(
+                next(keys), (L, width), jnp.float32),
+            "w1": w((L, Eh, D, Fm), D), "w3": w((L, Eh, D, Fm), D),
+            "w2": w((L, Eh, Fm, D), Fm)}
+        return params
+    Ld = cfg.first_k_dense
+    Le = cfg.n_layers - Ld
     if Ld:
         params["dense"] = {
             **attention(Ld),
@@ -128,31 +189,54 @@ def _swiglu(h, w1, w3, w2):
 
 
 def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray
-         ) -> tuple[jnp.ndarray, jnp.ndarray | None]:
-    """A layer's FFN on h [..., D] — dense or experts, by the pytree — and
-    the experts its tokens chose ([T, k]; None of a dense layer)."""
+         ) -> tuple[jnp.ndarray, jnp.ndarray | None, jnp.ndarray | None]:
+    """A layer's FFN on h [..., D] -- dense or experts, by the pytree -- the
+    outputs of the router its tokens chose ([T, k]; None of a dense layer),
+    and where ``cfg.tallies_choices`` how many of those choices name an
+    expert held here and how many a zero-compute one (int32 [2]; else
+    None)."""
     if "router" not in lp:
-        return _swiglu(h, lp["w1"], lp["w3"], lp["w2"]), None
+        return _swiglu(h, lp["w1"], lp["w3"], lp["w2"]), None, None
     ht = h.reshape(-1, h.shape[-1])
     idx, gates = route(cfg, lp, ht)
+    # The experts this chip holds: all of them (nothing to tell apart, and
+    # the parameters are indexed by the router's own ids), or a range.
+    first, count = cfg.held_experts
+    here = ((idx >= first) & (idx < first + count) if cfg.tallies_choices
+            else None)
     if cfg.moe_impl.startswith("grouped"):
         from ..ops.pallas_moe import grouped_experts
 
-        y = grouped_experts(lp, ht, idx, gates, cfg.n_experts,
+        # A choice of no expert held here (another chip's, or a zero-compute
+        # one) is sorted behind the last group and adds no row to any.
+        y = grouped_experts(lp, ht, idx, gates, count,
                             layer=lp.get("layer"),
+                            first=None if here is None else first,
                             interpret=cfg.moe_impl == "grouped_interpret")
     else:
-        # Dense over the experts: every expert for every token, weighted by
-        # its gate or by zero (models/llama._moe_ffn's form).
+        # Dense over the held experts: each of them for every token, weighted
+        # by its gate or by zero (models/llama._moe_ffn's form).
+        local = idx if here is None else jnp.where(here, idx - first, -1)
         weights = jnp.einsum(
-            "tke,tk->te", jax.nn.one_hot(idx, cfg.n_experts, dtype=h.dtype),
+            "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
             gates.astype(h.dtype))
         up = jnp.einsum("td,edf->tef", ht, lp["w1"])
         gate = jnp.einsum("td,edf->tef", ht, lp["w3"])
         out = jnp.einsum("tef,efd->ted", jax.nn.silu(up) * gate, lp["w2"])
         y = jnp.einsum("ted,te->td", out, weights)
-    y = y + _swiglu(ht, lp["w1s"], lp["w3s"], lp["w2s"])
-    return y.reshape(h.shape), idx
+    if "w1s" in lp:
+        y = y + _swiglu(ht, lp["w1s"], lp["w3s"], lp["w2s"])
+    if here is None:
+        return y.reshape(h.shape), idx, None
+    zero = idx >= cfg.n_experts
+    if cfg.n_zero_experts:
+        # An expert that computes nothing returns the token: the zero
+        # choices' gates, summed, times h, beside either form above.
+        y = y + (jnp.sum(jnp.where(zero, gates, 0.0), axis=-1, keepdims=True)
+                 * ht.astype(jnp.float32)).astype(h.dtype)
+    counts = jnp.stack([jnp.sum(here, dtype=jnp.int32),
+                        jnp.sum(zero, dtype=jnp.int32)])
+    return y.reshape(h.shape), idx, counts
 
 
 # ---- attention ------------------------------------------------------------------
@@ -171,9 +255,19 @@ def _project(cfg: ModelConfig, lp: Params, h: jnp.ndarray, cos, sin
     [..., H, dn], q_rope [..., H, dr] (rotated), and the tokens' cache rows
     [..., r + dr] = [normed latent | rotated key part]."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    q = (h @ lp["wq"]).reshape(*h.shape[:-1], cfg.n_heads, -1)
+    if cfg.q_lora_rank:
+        q = rms_norm(h @ lp["wqa"], lp["q_norm"], cfg.norm_eps) @ lp["wqb"]
+        if cfg.mla_scale_q_lora:
+            q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
+    else:
+        q = h @ lp["wq"]
+    q = q.reshape(*h.shape[:-1], cfg.n_heads, -1)
     kva = h @ lp["wkva"]
     c = rms_norm(kva[..., :r], lp["kv_norm"], cfg.norm_eps)
+    if cfg.mla_scale_kv_lora:
+        # Ahead of W_kvb and of the cache: a cached row holds the scaled
+        # latent, so both forms of attention read what they should.
+        c = c * (cfg.d_model / r) ** 0.5
     q_rope = apply_rope(q[..., dn:], cos, sin)
     k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
     return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], axis=-1)
@@ -220,23 +314,40 @@ def absorbed_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, cur_row,
 # ---- the stack ------------------------------------------------------------------
 
 
+# What the double layer's parameters hold once a SUBLAYER (2 L rows, row
+# 2 l + i); everything else of ``params["layers"]`` is the expert layer's (L
+# rows). The scan over layers closes over the former and reads row 2 l + i
+# where it lies: sliced a layer as [2, ...] and then a sublayer, XLA copies
+# every weight out of the stack once a layer a step (AOT, PR 39: 0.9 GB of
+# temporaries a decode step, none this way).
+_SUBLAYER = ("wqa", "q_norm", "wqb", "wkva", "kv_norm", "wkvb", "wo",
+             "ln_attn", "ln_mlp", "w1d", "w3d", "w2d")
+
+
 def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             attend: Callable[[Params, jnp.ndarray, jnp.ndarray],
                              tuple[jnp.ndarray, jnp.ndarray]]
-            ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+            ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
+                       jnp.ndarray | None]:
     """x through every block: the leading dense layers, then the expert
     layers, one scan each. ``attend(lp, h, layer)`` -> (attention output
-    [..., H * dv], the tokens' cache rows); ``layer`` counts over both
-    stacks, as the page pool does. Returns (x, rows [n_layers, ...], the
-    experts chosen in every expert layer [n_expert_layers, T, k])."""
-    rows, routes, first = [], None, 0
+    [..., H * dv], the tokens' cache rows); ``layer`` counts cache layers over
+    both stacks, as the page pool does. Returns (x, rows [n_kv_layers, ...],
+    the router's outputs chosen in every expert layer [n_expert_layers, T,
+    k], and where ``cfg.tallies_choices`` the counts [2] of those that are
+    held here and of those that compute nothing)."""
+    rows, routes, counts, first = [], None, None, 0
     for name in ("dense", "layers"):
         if name not in params:
             continue
         # Where the grouped kernel serves, the routed experts' weights stay
         # whole beside the scan (models/llama._over_layers, of one stack).
-        sliced, whole = _over_layers(cfg, params[name])
-        n = params[name]["wq"].shape[0]
+        double = cfg.attn_sublayers == 2
+        stack = params[name]
+        subs = {k: stack[k] for k in _SUBLAYER} if double else {}
+        sliced, whole = _over_layers(
+            cfg, {k: v for k, v in stack.items() if k not in subs})
+        n = stack["wo"].shape[0] // cfg.attn_sublayers
 
         def body(x, layer_in):
             lp, layer = layer_in
@@ -244,15 +355,51 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             a, row = attend(lp, rms_norm(x, lp["ln_attn"], cfg.norm_eps),
                             layer)
             x = x + a @ lp["wo"]
-            y, chosen = _ffn(cfg, lp, rms_norm(x, lp["ln_mlp"], cfg.norm_eps))
-            return x + y, (row, chosen)
+            y, chosen, tally = _ffn(cfg, lp, rms_norm(x, lp["ln_mlp"],
+                                                      cfg.norm_eps))
+            return x + y, (row, chosen, tally)
 
-        x, (stack_rows, chosen) = jax.lax.scan(
-            body, x, (sliced, first + jnp.arange(n, dtype=jnp.int32)))
+        def double_body(x, layer_in):
+            lp, layer = layer_in
+            lp = {**lp, **whole}
+            made = []
+            for i in range(2):
+                sub = {k: v[2 * layer + i] for k, v in subs.items()}
+                a, row = attend(sub, rms_norm(x, sub["ln_attn"],
+                                              cfg.norm_eps), 2 * layer + i)
+                made.append(row)
+                x = x + a @ sub["wo"]
+                h = rms_norm(x, sub["ln_mlp"], cfg.norm_eps)
+                if i == 0:
+                    m, chosen, tally = _ffn(cfg, lp, h)
+                x = x + _swiglu(h, sub["w1d"], sub["w3d"], sub["w2d"])
+            return x + m, (jnp.stack(made), chosen, tally)
+
+        x, (stack_rows, chosen, tally) = jax.lax.scan(
+            double_body if double else body, x,
+            (sliced, first + jnp.arange(n, dtype=jnp.int32)))
+        if double:                    # [n, 2, ...] -> a cache layer a row
+            stack_rows = stack_rows.reshape(-1, *stack_rows.shape[2:])
         rows.append(stack_rows)
         routes = chosen if chosen is not None else routes
+        counts = tally.sum(axis=0) if tally is not None else counts
         first += n
-    return x, jnp.concatenate(rows, axis=0), routes
+    return x, jnp.concatenate(rows, axis=0), routes, counts
+
+
+def _pool_of(k_pages):
+    """The latent pool of what a step was handed: the pool itself, or the
+    ``state.Cache`` it rides in where the programs count (the module's
+    docstring)."""
+    return k_pages.k if isinstance(k_pages, state.Cache) else k_pages
+
+
+def _kept(k_pages, pool: jnp.ndarray, counts: jnp.ndarray | None):
+    """What a step hands back in ``k_pages``' place: the pool as the step
+    left it, the step's counts added where it rides with them."""
+    if not isinstance(k_pages, state.Cache):
+        return pool
+    return state.counted(dataclasses.replace(k_pages, k=pool), *counts)
 
 
 def forward(
@@ -292,9 +439,15 @@ def forward(
         q_nope, q_rope, rows = _project(cfg, lp, h, cos, sin)
         return expanded_attention(cfg, lp, q_nope, q_rope, rows, mask), rows
 
-    x, rows, routes = _blocks(params, cfg, params["embed"][tokens], attend)
+    x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
+                                      attend)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    kv = (rows, None) if want_kv else None
+    kv = None
+    if want_kv:
+        # With counts, the rows go to ``pages.write_sequences`` in the value
+        # that hands the counts to the cache as well.
+        kv = ((rows if counts is None
+               else state.Fresh(rows, None, None, None, *counts)), None)
     out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
     return (out, kv, routes) if want_routes else (out, kv)
 
@@ -321,20 +474,23 @@ def decode_step(
     kernel into it."""
     cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
     seq_lens = positions + 1
-    cur_slots = pages.token_slots(k_pages, block_tables, positions)
+    pool = _pool_of(k_pages)
+    cur_slots = pages.token_slots(pool, block_tables, positions)
 
     def attend(lp, h, layer):
         q_nope, q_rope, row = _project(cfg, lp, h, cos, sin)
 
         def paged(q, cur_row):
-            return attention_fn(q, k_pages, layer, block_tables, seq_lens,
+            return attention_fn(q, pool, layer, block_tables, seq_lens,
                                 cur_row, value_dim=cfg.kv_lora_rank,
                                 scale=_scale(cfg))
 
         return absorbed_attention(cfg, lp, q_nope, q_rope, row, paged), row
 
-    x, rows, routes = _blocks(params, cfg, params["embed"][tokens], attend)
-    k_pages, _ = pages.write(k_pages, None, rows, None, *cur_slots)
+    x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
+                                      attend)
+    pool, _ = pages.write(pool, None, rows, None, *cur_slots)
+    k_pages = _kept(k_pages, pool, counts)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
@@ -366,7 +522,8 @@ def prefill_with_prefix(
     assert B == 1
     if prior_table_row is None:
         prior_table_row = block_table_row
-    T = prior_table_row.shape[1] * pages.block_size(k_pages)
+    pool = _pool_of(k_pages)
+    T = prior_table_row.shape[1] * pages.block_size(pool)
 
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
@@ -380,15 +537,16 @@ def prefill_with_prefix(
 
     def attend(lp, h, layer):
         q_nope, q_rope, rows = _project(cfg, lp, h, cos, sin)
-        prior = pages.read_latent_prefix(k_pages, layer, prior_table_row,
+        prior = pages.read_latent_prefix(pool, layer, prior_table_row,
                                          cfg.latent_dim)
         seen = jnp.concatenate([prior.astype(rows.dtype), rows], axis=1)
         return expanded_attention(cfg, lp, q_nope, q_rope, seen, mask), rows
 
-    x, rows, routes = _blocks(params, cfg, params["embed"][tokens], attend)
-    k_pages, _ = pages.write_sequences(k_pages, None, rows, None,
-                                       block_table_row, suffix_len,
-                                       start=prefix_len)
+    x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
+                                      attend)
+    pool, _ = pages.write_sequences(pool, None, rows, None, block_table_row,
+                                    suffix_len, start=prefix_len)
+    k_pages = _kept(k_pages, pool, counts)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]

@@ -302,6 +302,13 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
         jnp.searchsorted(off[1:], tile_starts, side="right"),
         E - 1).astype(jnp.int32)
     n_live = (off[E:] // tm).astype(jnp.int32)                  # [1]
+    if first is not None:
+        # A program none of whose choices is held has no group at all, and
+        # the kernel's block index min(i, n_live - 1) would be -1: the chip
+        # halts on that copy's bounds check (the interpreter clamps it and
+        # says nothing). One tile then counts as live; what it computes lies
+        # in rows of absent choices, which are masked below.
+        n_live = jnp.maximum(n_live, 1)
 
     up = ("w1", "w3") if gated else ("w1",)
     if layer is None:

@@ -157,6 +157,12 @@ def main(argv=None) -> int:
                 pages, pages, sds(state_geom.ssm_shape, jnp.float32),
                 sds(state_geom.conv_shape, jnp.dtype(state_geom.dtype)),
                 slots=sds((rows,), jnp.int32), held=sds((), jnp.int32)), None)
+        if mcfg.tallies_choices:   # a latent pool that rides with counts
+            return (kvstate.Cache(
+                pages, None, None, None, slots=sds((rows,), jnp.int32),
+                held=sds((), jnp.int32),
+                zero=sds((), jnp.int32) if mcfg.n_zero_experts else None,
+                counts_zero=bool(mcfg.n_zero_experts)), None)
         return (pages, None) if geom.latent_dim else (pages, pages)
 
     def sampling(rows):

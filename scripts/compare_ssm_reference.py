@@ -270,7 +270,7 @@ def main(argv=None) -> int:
             got, routes, cache = first_window(
                 params, toks, jnp.full((1,), a, jnp.int32), jnp.asarray(at),
                 at_lanes(cache, [lane]), row)
-            cache, n_held = state.take_counts(cache)
+            cache, n_held, _ = state.take_counts(cache)
             chose = [np.asarray(routes)[:, :a]]
             if keep:
                 held += int(n_held)
@@ -287,7 +287,7 @@ def main(argv=None) -> int:
                     got, routes, cache = window_fn(prior)(
                         params, toks, jnp.full((1,), n, jnp.int32), written,
                         at_lanes(cache, [lane]), row)
-                    cache, _ = state.take_counts(cache)
+                    cache, *_ = state.take_counts(cache)
                     gots.append(np.asarray(got))
                     short_routes.append(np.asarray(routes)[:, :n])
                     if n != b:
@@ -314,7 +314,7 @@ def main(argv=None) -> int:
             logits, routes, cache = decode(
                 params, seq[positions], positions,
                 at_lanes(cache, np.arange(B)), tables)
-            cache, _ = state.take_counts(cache)
+            cache, *_ = state.take_counts(cache)
             if args.degrade == "state16":
                 cache = state_in_bf16(cache)
             steps.append(np.asarray(logits))            # [B, V]

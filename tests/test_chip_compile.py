@@ -155,6 +155,64 @@ def test_latent_decode_step_compiles_and_copies_no_pool(one_chip):
             < dt.itemsize * math.prod(geom.shape[1:]))
 
 
+def test_double_layer_decode_step_compiles_and_copies_no_weight(one_chip):
+    """One decode step of LongCat-Flash's double layer at the cell's widths
+    (`chipbench/configs/longcat-flash-omni-cut.json`, two layers of its four),
+    64 lanes on the cell's pool (64 x 2,048 tokens, four cache layers here),
+    the pool in the cache that carries the counts: Mosaic takes the latent
+    kernel's 64-row products (four times Kimi's), the pool reaches the custom
+    call whole, and no weight is copied out of its stack -- a sublayer's
+    tensors sliced a layer as [2, ...] and then a sublayer were, every layer
+    of every step (0.9 GB of temporaries at four layers; AOT, PR 39), which
+    is why the scan closes over them and reads row 2 l + i where it lies."""
+    import json
+    import types
+
+    from llm_d_inference_scheduler_tpu.kvcache import state
+    from llm_d_inference_scheduler_tpu.kvcache.pages import (
+        PageGeometry, latent_decode_attention)
+    from llm_d_inference_scheduler_tpu.models import mla
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "longcat-flash-omni-cut.json")) as f:
+        published = json.load(f)
+    m = config_from_hf(types.SimpleNamespace(**{**published, "num_layers": 2}))
+    assert (m.n_heads, m.n_kv_layers, m.held_experts) == (64, 4, (0, 16))
+    geom = PageGeometry.for_engine(m, 64, 2048)
+    dt = jnp.dtype(m.dtype)
+    batch = 64
+    cache = state.Cache(
+        _sds(one_chip, geom.shape, dt), None, None, None,
+        slots=_sds(one_chip, (batch,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32), zero=_sds(one_chip, (), jnp.int32),
+        counts_zero=True)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    compiled = jax.jit(
+        lambda *a: mla.decode_step(
+            a[0], m, *a[1:],
+            attention_fn=functools.partial(latent_decode_attention,
+                                           kernel=True)),
+        donate_argnums=(3,),
+    ).lower(params, _sds(one_chip, (batch,), jnp.int32),
+            _sds(one_chip, (batch,), jnp.int32), cache, None,
+            _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
+            ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "mla_paged_decode_attention" in hlo
+    shape = "bf16[" + ",".join(map(str, geom.shape[1:])) + "]"
+    made = [ln.strip()[:160] for ln in hlo.splitlines()
+            if re.search(r"=\s*" + re.escape(shape), ln)]
+    assert not made, made
+    # Under the two dense-FFN matrices of one sublayer: the copy of a layer's
+    # [2, 6144, 12288] slices alone was three times that.
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 2 * dt.itemsize * m.d_model * m.d_ff)
+
+
 @pytest.mark.parametrize("steps", [1, 2])
 def test_hybrid_decode_step_compiles_and_copies_no_pool(one_chip, steps):
     """One decode step of the nemotron_h family at Nemotron-3-Super's widths,

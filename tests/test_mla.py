@@ -464,3 +464,7 @@ def test_engine_serves_through_chunked_prefill_prefix_cache_and_kernel(served):
     assert counted_c["expanded"] > counted["expanded"]   # warm-up's ladder
     assert settings["kv_token_bytes"] == 128 * 4 and settings_c["pallas_attention"]
     assert settings["kv_pool_bytes"] == 3 * 65 * 16 * 512
+    # Every expert is held and none computes nothing: this block's programs
+    # count no choices (models/mla.py).
+    assert (settings["kv_layers"], settings["experts_first"],
+            settings["experts_held"], settings["zero_experts"]) == (3, 0, 8, 0)

@@ -309,3 +309,35 @@ def test_every_choice_absent_gives_zeros_and_no_token_is_dropped_at_any_skew():
     want = _plain_experts(lp, x, skew, gates, 4, 2, False)
     np.testing.assert_allclose(np.asarray(got), want, atol=3e-5, rtol=3e-5)
     assert np.abs(want).min(axis=1).max() > 0
+
+
+def test_every_choice_absent_keeps_the_kernels_block_indices_in_the_buffer(
+        monkeypatch):
+    """A program none of whose choices is held has no group. The kernel's
+    block index is min(i, n_live - 1): with no live tile it is -1, which the
+    interpreter clamps and the chip halts on (a bounds check of the row
+    tile's copy; longcat-flash-omni-cut, 2 of 6 seeds, PR 39). So what
+    reaches the kernel names at least one tile, and never more than it has."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_moe
+
+    seen = []
+    kernel = pallas_moe._grouped_matmul
+
+    def watched(lhs, rhs, layer, tile_expert, n_live, **kw):
+        seen.append((lhs.shape[0] // kw["tm"], int(n_live[0]),
+                     np.asarray(tile_expert)))
+        return kernel(lhs, rhs, layer, tile_expert, n_live, **kw)
+
+    monkeypatch.setattr(pallas_moe, "_grouped_matmul", watched)
+    lp, _ = _mk(E=2, D=128, F=128, seed=4)
+    x = jax.random.normal(jax.random.key(6), (24, 128), jnp.float32)
+    gates = jnp.ones((24, 2), jnp.float32)
+    for choices in ([0, 7], [5, 0], [4, 5]):
+        idx = jnp.tile(jnp.asarray([choices], jnp.int32), (24, 1))
+        pallas_moe.grouped_experts(lp, x, idx, gates, 2, first=4, gated=False,
+                                   tm=8, interpret=True)
+    assert len(seen) == 6
+    for tiles, live, tile_expert in seen:
+        assert 1 <= live <= tiles
+        assert ((0 <= tile_expert) & (tile_expert < 2)).all()
+    assert [live for _, live, _ in seen] == [1, 1, 3, 3, 6, 6]

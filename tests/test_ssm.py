@@ -189,7 +189,7 @@ def test_prefill_then_decode_through_state_and_pages_equals_the_full_forward():
             params, CFG, tokens[:, t], jnp.full((2,), t, jnp.int32),
             state.at_slots(cache, [0, 1]), None, TABLES)
         assert none is None
-        cache, held = state.take_counts(cache)
+        cache, held, _ = state.take_counts(cache)
         assert int(held) == 2 * 2 * CFG.experts_per_token   # every expert held
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(logits[:, t]), **TOL)
@@ -218,7 +218,7 @@ def test_a_second_prefill_window_continues_from_slot_state():
                 state.at_slots(cache, [lane]), None, TABLES[lane:lane + 1],
                 TABLES[lane:lane + 1, :prior])
             assert none is None
-            cache, _ = state.take_counts(cache)
+            cache, *_ = state.take_counts(cache)
             np.testing.assert_allclose(
                 np.asarray(got[0]), np.asarray(logits[lane, lo + n - 1]),
                 **TOL)
@@ -250,7 +250,7 @@ def test_a_state_pool_lies_beside_unsharded_kv_pages_only():
         pages.alloc(geom, sharding=pages.page_sharding(mesh), state=sgeom)
     # A plain pool passes through the two seams untouched.
     k, v = pages.alloc(geom)
-    assert state.at_slots(k, [0]) is k and state.take_counts(k) == (k, None)
+    assert state.at_slots(k, [0]) is k and state.take_counts(k) == (k, None, None)
 
 
 def test_models_hybrid_knows_no_pool_layout():
