@@ -56,6 +56,20 @@ KV_EXPORT_TTL_S = 60.0
 # the stated stretch of the decode cadence under prefill_chunk (a prompt of
 # this length prefilled whole makes the lanes wait as long).
 PREFILL_STEP_TOKENS = 4096
+# How long before the chunk in flight is reckoned to end a held chunk goes out
+# (_hold_for_arrival). Sized on the chip (qwen3-4b, 16 lanes x 2,048, chunks
+# of 8 steps, 111 ms; PERF.md section 6, PR 40): from the deadline to "chunk
+# enqueued" the loop took 4.1-4.7 ms at the median (waking up, 0.1 ms of
+# decode_prepare, 3.3-3.8 ms of decode_dispatch), 6.6-9.5 ms at the fifth
+# largest of a hundred and 9.5 ms at most inside a measured window, a 5 ms
+# turn of the GIL among them; with 20 ms the read of the chunk ahead then
+# still blocked for 11.8 ms at least. Err early: a chunk that goes out late
+# idles the device and lengthens every running stream, one that goes out
+# early only costs an arrival its place.
+HOLD_MARGIN_S = 0.020
+# A shape's expected device time is the least of its last clean periods: a
+# period can only read longer than the chunk took (a late read, a late chunk).
+HOLD_PERIODS = 8
 
 
 def _tcp_preflight(address: str, timeout: float = 2.0) -> None:
@@ -143,6 +157,8 @@ class _Chunk:
     lanes: list[tuple[int, _Slot]]   # lane -> (slot index, the slot it held)
     t0: float                        # the loop's clock at dispatch
     timed: bool                      # its shape was built before: observe it
+    shape: str                       # "lanes x table width", its program's
+    behind: int                      # device calls queued since the chunk before
 
 
 @dataclasses.dataclass
@@ -508,6 +524,15 @@ class TpuEngine:
         self._retired: list[tuple[int, _Slot]] = []
         self._last_readback = 0.0
         self._clock = time.monotonic
+        # What _hold_until reckons the end of the chunk in flight from: when
+        # the first tokens of the prefills queued ahead of it were read (it
+        # started no sooner), the device calls made since the last chunk went
+        # out (they sit ahead of the next), and for every decode shape the
+        # periods of its last chunks that had no such call ahead of them:
+        # their device time, as _land_chunk measured it.
+        self._first_tokens_read = 0.0
+        self._calls_since_chunk = 0
+        self._chunk_times: dict[str, collections.deque] = {}
         # The period now running (from the last readback, or from the
         # dispatch of a chunk that went out alone): seconds by phase, prefills
         # finalized, whether a shape ran for the first time in it; judged at
@@ -1142,10 +1167,15 @@ class TpuEngine:
             self._publish_kv_snapshot()
             self._process_aborts()
             self._process_imports()
-        with self._phase("admit"):
-            self._admit()
-        with self._phase("advance_prefills"):
-            self._advance_prefills()
+        at = "step"
+        while True:
+            with self._phase("admit"):
+                self._admit(at)
+            with self._phase("advance_prefills"):
+                self._advance_prefills()
+            if not self._hold_for_arrival():
+                break
+            at = "hold"     # woken by an arrival: placed now, and held on
         # The next chunk goes out BEFORE the one in flight is read: it queues
         # on the device behind that chunk and behind the prefills above, and
         # takes its tokens from the device (theirs, _slot_tokens), so the
@@ -1164,6 +1194,77 @@ class TpuEngine:
                     # / fetch in flight): sleep until something changes.
                     with self._phase("idle_wait"):
                         self._cond.wait(timeout=0.05)
+
+    def _other_work(self) -> bool:
+        """Whether anything but a request has come in for the loop to do;
+        under _cond."""
+        return bool(self._abort_ids or self._import_ready or self._embed_reqs
+                    or self._release_reqs or self._stop or self.dist_degraded)
+
+    def _work_arrived(self) -> bool:
+        return bool(self._waiting) or self._other_work()
+
+    def _open_slots(self) -> tuple[list[int], list[int]]:
+        """The slots a request can be admitted into: the empty ones, and
+        those whose request ends inside the chunk in flight (on max_tokens
+        or the context limit: the next chunk leaves its lane out already)."""
+        empty = [i for i, s in enumerate(self.slots) if s is None]
+        vacating = [i for i, s in enumerate(self.slots) if s is not None
+                    and s.ahead and self._ends_in_flight(s)]
+        return empty, vacating
+
+    def _hold_until(self) -> float | None:
+        """Until when, on the loop's clock, the next chunk can be held back
+        for an arrival; None where it goes out now. It can where the device
+        has a chunk to work on whose end the loop can reckon (it has timed
+        that shape with nothing ahead of it), a slot is open, nobody waits
+        for it, and no slot has windows still to write. The chunk in flight
+        started no sooner than it was dispatched, than the chunk before it
+        was read, and than the first tokens of the prefills ahead of it
+        were; what else sits ahead of it makes it end later than reckoned,
+        and the next chunk is then early, which costs an arrival its place
+        and the device nothing."""
+        chunk = self._inflight
+        times = chunk and self._chunk_times.get(chunk.shape)
+        if not times or any(s is not None and s.prefilling
+                            for s in self.slots):
+            return None
+        with self._cond:
+            if self._work_arrived():
+                return None
+        if not any(self._open_slots()):
+            return None
+        until = (max(chunk.t0, self._last_readback, self._first_tokens_read)
+                 + min(times) - HOLD_MARGIN_S)
+        return until if until > self._clock() else None
+
+    def _await_work(self, until: float) -> bool:
+        """Sleep until something comes in for the loop to do (submit(),
+        abort() and the rest notify _cond) or its clock reads `until`;
+        whether something has."""
+        with self._cond:
+            return self._cond.wait_for(
+                self._work_arrived, timeout=max(until - self._clock(), 0.0))
+
+    def _hold_for_arrival(self) -> bool:
+        """Hold the next chunk back until shortly before the one in flight
+        ends, where _hold_until says it can be; whether an arrival, and
+        nothing else, ended the hold sooner (_step places it and holds on).
+        Dispatched now, a whole chunk period before the device needs it, the
+        chunk would stand in the device's queue ahead of the prefill of
+        every request that arrives in that period: a first token then waits
+        out a chunk it has no part in. Held, the loop sleeps on _cond where
+        it would have slept inside the readback; an arrival wakes it and is
+        admitted at once, its prefill behind the running chunk alone and its
+        lane in the next. The sleep is time the device works and the loop
+        does not: decode_wait's."""
+        until = self._hold_until()
+        if until is None:
+            return False
+        with self._phase("decode_wait"):
+            self._await_work(until)
+        with self._cond:
+            return bool(self._waiting) and not self._other_work()
 
     def _on_follower_lost(self, idx: int, why: str) -> None:
         """Peer-monitor callback (runs on the channel's watch thread)."""
@@ -1331,8 +1432,9 @@ class TpuEngine:
             req.admit_time = time.monotonic()
             self.telemetry.queue_wait.observe(wait_s)
 
-    def _admit(self):
-        """Place the head of the queue, slot by slot, while it fits. The
+    def _admit(self, at: str = "step"):
+        """Place the head of the queue, slot by slot, while it fits (`at`
+        the top of a step, or woken inside a `hold`: _hold_for_arrival). The
         empty slots first; then, for requests that still wait, the slots
         whose request ends inside the chunk in flight (on max_tokens or the
         context limit, _ends_in_flight: the next chunk leaves its lane out
@@ -1344,9 +1446,7 @@ class TpuEngine:
         its pages are its own, the predecessor keeps its blocks until its
         last chunk is booked (_place, _book_chunk)."""
         group: list[tuple[int, EngineRequest, Any, Any, int]] = []
-        empty = [i for i, s in enumerate(self.slots) if s is None]
-        vacating = [i for i, s in enumerate(self.slots) if s is not None
-                    and s.ahead and self._ends_in_flight(s)]
+        empty, vacating = self._open_slots()
         for i in empty + vacating:
             when = "after" if self.slots[i] is None else "ahead"
             with self._cond:
@@ -1391,6 +1491,7 @@ class TpuEngine:
                 self.telemetry.waiting.set(len(self._waiting))
                 self._note_admission(req)
                 self.telemetry.slot_refills[when].inc()
+                self.telemetry.admissions[at].inc()
             group.append((i, req, out, loop, need))
         self._flush_admissions(group)
 
@@ -1757,6 +1858,7 @@ class TpuEngine:
         with self._phase("decode_wait"):
             landed = [int(self._read_tokens(slot.pending_tok)[slot.pending_idx])
                       for _, slot in pending]
+        self._first_tokens_read = self._clock()
         self._period_prefills += len(pending)
         with self._phase("finalize_prefills"):
             for (idx, slot), tok in zip(pending, landed):
@@ -2533,9 +2635,10 @@ class TpuEngine:
         if self._instr_channel is not None and self._instr_channel.leader:
             self._instr_channel.broadcast(op, args)
         key = self._op_shape_key(op, args)
+        decode = op[0] == "decode"
+        self._calls_since_chunk += not decode
         if key is None:
             return self._exec_op(op, args)
-        decode = op[0] == "decode"
         # Rows (padded tokens) of one step of this program, and its steps.
         rows = args["slots" if decode else "tokens"].size
         steps = self.cfg.decode_chunk if decode else 1
@@ -2992,7 +3095,8 @@ class TpuEngine:
             reqs += [_DUMMY_REQ] * (B - len(reqs))
             self.telemetry.batch_fill.set(
                 len(lanes) / max(self.cfg.max_batch, 1))
-            timed = ("decode", f"{B}x{W}") in self._seen_op_shapes
+            shape = f"{B}x{W}"
+            timed = ("decode", shape) in self._seen_op_shapes
             args = dict(slots=slots, positions=positions, tables=tables,
                         **self._sample_np(reqs))
         t0 = self._clock()
@@ -3004,7 +3108,9 @@ class TpuEngine:
             "alone" if self._inflight is None else "ahead"].inc()
         for _, s in lanes:
             s.ahead += self.cfg.decode_chunk
-        return _Chunk(toks=toks, lanes=lanes, t0=t0, timed=timed)
+        behind, self._calls_since_chunk = self._calls_since_chunk, 0
+        return _Chunk(toks=toks, lanes=lanes, t0=t0, timed=timed,
+                      shape=shape, behind=behind)
 
     def _land_chunk(self, chunk: _Chunk) -> None:
         """Read a chunk's tokens (ONE readback a chunk) and book them."""
@@ -3028,6 +3134,11 @@ class TpuEngine:
                 prefills=self._period_prefills)
             if stall is not None:
                 log.warning("engine loop stall %s", json.dumps(stall))
+            if not (chunk.behind or self._period_first_call):
+                # Nothing sat between it and the chunk before: the period
+                # is the chunk's device time, what _hold_until reckons with.
+                self._chunk_times.setdefault(chunk.shape, collections.deque(
+                    maxlen=HOLD_PERIODS)).append(period)
         self._last_readback = now
         self._begin_period()
         with self._phase("decode_book"):

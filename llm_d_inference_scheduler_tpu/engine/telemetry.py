@@ -239,6 +239,14 @@ class EngineTelemetry:
             ("when",), registry=self.registry)
         self.slot_refills = {w: slot_refills.labels(when=w)
                              for w in ("ahead", "after")}
+        admissions = Counter(
+            "jetstream:admissions_total",
+            "Requests admitted into an engine slot: woken by the arrival "
+            "inside a `hold` of the next decode chunk (the prefill queues "
+            "behind the chunk that runs alone, ahead of the next), or at the "
+            "top of a `step` of the loop", ("at",), registry=self.registry)
+        self.admissions = {a: admissions.labels(at=a)
+                           for a in ("hold", "step")}
         self.decode_lanes_discarded = Counter(
             "jetstream:decode_lanes_discarded_total",
             "Lanes of a chunk thrown away whole: the request ended (a stop "

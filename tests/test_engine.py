@@ -1,12 +1,18 @@
 """Engine tests: continuous batching core, HTTP surface, telemetry, P/D handoff."""
 
 import asyncio
+import collections
+import contextlib
+import functools
 import json
+import signal
+import time
 
 import httpx
 import pytest
 
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
+from llm_d_inference_scheduler_tpu.engine.core import HOLD_MARGIN_S
 from llm_d_inference_scheduler_tpu.engine.server import EngineServer
 
 
@@ -1030,6 +1036,368 @@ def test_a_lane_that_ends_on_a_stop_token_is_not_refilled_ahead(family):
     assert _refills(eng) == (0, 3)
     assert _counter(eng, "jetstream:decode_lanes_discarded_total") == 1
     assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+# ---- the next chunk is held back for an arrival ----------------------------
+
+_PREFILL_S, _CHUNK_S = 0.040, 0.100
+
+
+@contextlib.contextmanager
+def _time_limit(seconds):
+    """Fail the test, and do not hang the run, if the block is not done."""
+    def late(*_):
+        raise AssertionError(f"not done in {seconds} s")
+
+    before = signal.signal(signal.SIGALRM, late)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
+class _Device:
+    """A clock the test owns and a device with an in-order queue: a prefill
+    takes 40 ms and a chunk 100, each after whatever was dispatched before
+    it; reading an op's tokens moves the clock to that op's end; the host
+    costs nothing. The sleep of a held chunk (TpuEngine._await_work) is the
+    test's too: hold number n (from 0) finds in ``script[n]`` what happens in
+    it, a list of (seconds into the hold, something to call); after the last
+    of them, or with none, the clock moves to the deadline. ``log`` holds
+    every device call and every hold as (kind, the clock, what): a decode
+    chunk's request ids, a prefill's slots, a hold's deadline; ``reads`` the
+    clock after every read."""
+
+    def __init__(self, eng):
+        self.script = {}
+        self.now, self.free, self.ends = 0.0, 0.0, collections.deque()
+        self.log, self.reads, self.n_holds, self._due = [], [], 0, None
+        self.real_wait = eng._await_work
+        real_op, real_read, real_chunk = (eng._exec_op, eng._read_tokens,
+                                          eng._dispatch_chunk)
+
+        def exec_op(op, args):
+            if op[0] in ("prefill", "prefix_prefill", "decode"):
+                took = _CHUNK_S if op[0] == "decode" else _PREFILL_S
+                self.free = max(self.free, self.now) + took
+                self.ends.append(self.free)
+                self.log.append((op[0], self.now,
+                                 [int(i) for i in args["slots"]]))
+            return real_op(op, args)
+
+        def read_tokens(toks):
+            self.now = max(self.now, self.ends.popleft())
+            self.reads.append(self.now)
+            return real_read(toks)
+
+        def dispatch_chunk():
+            chunk = real_chunk()
+            if chunk is not None:
+                kind, at, _ = self.log[-1]
+                self.log[-1] = (kind, at, [s.req.request_id
+                                           for _, s in chunk.lanes])
+            return chunk
+
+        eng._clock = lambda: self.now
+        eng._exec_op, eng._read_tokens = exec_op, read_tokens
+        eng._dispatch_chunk, eng._await_work = dispatch_chunk, self.await_work
+
+    def await_work(self, until):
+        if self._due is None:                   # a hold begins
+            self.log.append(("hold", self.now, until))
+            self._due = [(self.now + after, call) for after, call
+                         in self.script.get(self.n_holds, ())]
+            self.n_holds += 1
+        if self._due:
+            at, call = self._due.pop(0)
+            assert self.now <= at < until
+            self.now = at
+            return bool(call())
+        self.now, self._due = until, None
+        return False
+
+    def end_hold(self):
+        """For a script: nothing more happens in this hold."""
+        self._due = None
+
+    def kinds(self):
+        return [kind for kind, *_ in self.log]
+
+
+def _held(requests, *, script=None, at_step=None, hold=True, steps=80,
+          setup=None, **cfg):
+    """Serve by hand on a _Device. ``at_step``: request id -> the step before
+    which it is submitted. ``script``: hold number -> [(seconds into it, the
+    id of a request to submit then, or something to call with both)].
+    ``hold`` False: the deadline is always now, no hold is ever taken.
+    ``setup(eng, dev)`` runs once before the first step. Returns (tokens by
+    id, finish reason by id, engine, device)."""
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    at_step = at_step or {}
+    by_id = {r.request_id: r for r in requests}
+    cfg.setdefault("max_batch", 4)
+
+    async def body():
+        eng = TpuEngine(EngineConfig(
+            model="tiny", backend="tpu", max_model_len=128,
+            decode_chunk=_CHUNK, seed=11, kv_events_port=0, **cfg),
+            params=_tiny_f32())
+        outs, toks, why = {}, {}, {}
+
+        def submit(rid):
+            outs[rid], toks[rid] = eng.submit(by_id[rid]), []
+            return True
+
+        dev = _Device(eng)
+        dev.script = {
+            n: [(after, functools.partial(submit, what)
+                 if isinstance(what, str) else functools.partial(what, eng, dev))
+                for after, what in events]
+            for n, events in (script or {}).items()}
+        if not hold:
+            eng._hold_until = lambda: None
+        if setup is not None:
+            setup(eng, dev)
+        for step in range(steps):
+            for rid, at in at_step.items():
+                if at == step:
+                    submit(rid)
+            dev.log.append(("step", dev.now, step))
+            eng._step()
+            await asyncio.sleep(0)
+            for rid, out in outs.items():
+                while not out.empty():
+                    ev = out.get_nowait()
+                    if ev.token_id is not None:
+                        toks[rid].append(ev.token_id)
+                    if ev.finish_reason is not None:
+                        why[rid] = ev.finish_reason.value
+            if len(why) == len(requests) and eng._inflight is None:
+                break
+        return toks, why, eng, dev
+
+    with _time_limit(120):
+        return asyncio.run(body())
+
+
+def _admissions(eng):
+    return tuple(_counter(eng, "jetstream:admissions_total", {"at": at})
+                 for at in ("hold", "step"))
+
+
+def _long(rid="A", n=9, max_tokens=100):
+    return _req(rid, _prompt(5, n), max_tokens, 0.0)
+
+
+def test_an_arrival_in_a_hold_is_prefilled_ahead_of_the_next_chunk():
+    """A decodes alone on four lanes; B arrives 30 ms into the first hold and
+    C 20 ms after it. Each prefill goes out at the arrival, behind the chunk
+    that runs and ahead of the held one, which goes out at the deadline with
+    both as lanes; their first tokens are read a prefill after that chunk's
+    end and not a chunk later."""
+    reqs = [_long(), _req("B", _prompt(7, 20), 9, 0.0),
+            _req("C", _prompt(11, 12), 5, 0.0)]
+    toks, why, eng, dev = _held(reqs, at_step={"A": 0},
+                                script={0: [(0.030, "B"), (0.050, "C")]})
+    assert why == dict.fromkeys("ABC", "length")
+    assert [len(toks[r]) for r in "ABC"] == [100, 9, 5]
+    first = dev.kinds().index("hold")
+    (_, began, until), *after = dev.log[first:]
+    assert [(kind, round(at - began, 6)) for kind, at, _ in after[:3]] == [
+        ("prefill", 0.030), ("prefill", 0.050),
+        ("decode", round(until - began, 6))]
+    assert after[2][2] == ["A", "B", "C"]
+    assert _admissions(eng) == (2, 1)
+    assert _refills(eng) == (0, 3)
+    # The step's three reads: the running chunk at its end, then B's first
+    # token a prefill later and C's behind it, the held chunk behind both.
+    ends = until + HOLD_MARGIN_S
+    assert [at for at in dev.reads if at > began][:3] == pytest.approx(
+        [ends, ends + _PREFILL_S, ends + 2 * _PREFILL_S])
+
+
+def test_with_no_arrival_a_held_chunk_goes_out_at_the_deadline():
+    """Every chunk from the first hold on is dispatched HOLD_MARGIN_S before
+    the end of the chunk ahead of it, never later and (the periods being all
+    alike) not sooner; the device is never without work, and every chunk
+    still goes out with the one before it unread."""
+    toks, why, eng, dev = _held([_long(max_tokens=61)], at_step={"A": 0})
+    assert why == {"A": "length"} and len(toks["A"]) == 61
+    chunks = [(at, ids) for kind, at, ids in dev.log if kind == "decode"]
+    holds = [(at, until) for kind, at, until in dev.log if kind == "hold"]
+    # Three chunks go out as they always did, until the second is read and
+    # the shape is timed; the last hold is behind the last chunk, which
+    # nothing follows.
+    assert len(chunks) == 15 and len(holds) == len(chunks) - 3 + 1
+    # The first chunk ends a prefill and a chunk after time 0, the rest back
+    # to back.
+    ends = [_PREFILL_S + _CHUNK_S * (n + 1) for n in range(len(chunks))]
+    assert [until for _, until in holds] == pytest.approx(
+        [end - HOLD_MARGIN_S for end in ends[2:]], abs=1e-9)
+    assert [at for at, _ in chunks[3:]] == [until for _, until in holds[:-1]]
+    assert dev.free == pytest.approx(ends[-1])          # no gap on the device
+    assert _counter(eng, "jetstream:decode_chunks_total",
+                    {"dispatch": "alone"}) == 1
+    assert _admissions(eng) == (0, 1)
+    # The sleep is decode_wait's: with a host that costs nothing, all of the
+    # loop's time is.
+    get = eng.telemetry.registry.get_sample_value
+    assert get("jetstream:engine_loop_seconds_total",
+               {"phase": "decode_wait"}) == pytest.approx(dev.now)
+
+
+def _no_hold_waits():
+    """B needs seven blocks where two are left: it stays the head of the
+    queue until A's come back, and the chunks go out as they always did."""
+    return dict(requests=[_long(), _long("B")], at_step={"A": 0, "B": 8},
+                hbm_kv_blocks=10), "waiting", range(8, 26)
+
+
+def _no_hold_busy():
+    """Two lanes, both decoding: no slot for an arrival until A's last chunk
+    is in flight (a slot that is known to vacate is open)."""
+    return dict(requests=[_long(), _long("B")], at_step={"A": 0, "B": 8},
+                max_batch=2), "open", range(8, 25)
+
+
+def _no_hold_prefilling():
+    """B's prompt is written in six windows of 16, one a step."""
+    return dict(requests=[_long(), _req("B", _prompt(7, 90), 8, 0.0)],
+                at_step={"A": 0, "B": 8}, prefill_chunk=16), "prefilling", range(8, 13)
+
+
+def _no_hold_idle():
+    """A has ended and its last chunk is booked: the steps after it, and B's
+    own first, find nothing in flight, though the shape is timed and every
+    slot is free."""
+    return dict(requests=[_long(max_tokens=21), _long("B", max_tokens=5)],
+                at_step={"A": 0, "B": 9}), "inflight", range(6, 10)
+
+
+def _no_hold_untimed():
+    """The first chunk of a shape is not timed and the second is read in
+    the third's step: nothing to reckon an end from until then."""
+    return dict(requests=[_long()], at_step={"A": 0}), "timed", range(1, 3)
+
+
+@pytest.mark.parametrize("case", [
+    _no_hold_waits, _no_hold_busy, _no_hold_prefilling, _no_hold_idle,
+    _no_hold_untimed], ids=lambda case: case.__name__[9:])
+def test_no_hold_is_taken(case):
+    """In the steps named, the one thing named stands in the way and the
+    chunk goes out at once; over the whole run a chunk is held in exactly
+    the steps in which nothing does."""
+    plan, blocker, quiet = case()
+    steps = {}
+
+    def watch(eng, dev):
+        def hold_until(real=eng._hold_until):
+            step = [what for kind, _, what in dev.log if kind == "step"][-1]
+            chunk = eng._inflight
+            state = dict(
+                inflight=chunk is not None, waiting=not eng._waiting,
+                open=any(eng._open_slots()),
+                prefilling=not any(s is not None and s.prefilling
+                                   for s in eng.slots),
+                timed=bool(eng._chunk_times.get(chunk.shape) if chunk
+                           else eng._chunk_times))
+            until = real()
+            steps.setdefault(step, (state, until))
+            return until
+
+        eng._hold_until = hold_until
+
+    toks, why, eng, dev = _held(setup=watch, **plan)
+    assert set(why.values()) == {"length"}
+    for step, (state, until) in steps.items():
+        assert (until is not None) == all(state.values()), (step, state)
+    for step in quiet:
+        assert [k for k, ok in steps[step][0].items() if not ok] == [blocker]
+    assert steps[quiet[0] - 1][1] is not None or blocker == "timed"
+    assert any(until is not None for step, (_, until) in steps.items()
+               if step > quiet[-1])
+
+
+@pytest.mark.parametrize("what", ["abort", "stop"])
+def test_an_abort_or_a_stop_ends_a_hold_at_once(what):
+    """The loop's own wait (not the test's), asked to sleep to a deadline
+    70 ms away on a clock that stands still: an abort or the stop notifies
+    _cond and the wait is over; the chunk goes out there and then, not at
+    the deadline, and the step goes on to its end."""
+    woke = []
+
+    def interrupt(eng, dev):
+        if what == "abort":
+            eng.abort("A")
+        else:
+            with eng._cond:
+                eng._stop = True
+                eng._cond.notify()
+        until = [e for e in dev.log if e[0] == "hold"][-1][2]
+        t0 = time.monotonic()
+        woke.append(dev.real_wait(until))
+        woke.append(time.monotonic() - t0)
+        dev.end_hold()
+        return woke[0]
+
+    toks, why, eng, dev = _held(
+        [_long()], at_step={"A": 0}, script={1: [(0.010, interrupt)]}, steps=7)
+    assert woke[0] is True and woke[1] < 0.05
+    second = [n for n, kind in enumerate(dev.kinds()) if kind == "hold"][1]
+    (_, began, until), (kind, at, ids), *rest = dev.log[second:]
+    assert kind == "decode" and at == pytest.approx(began + 0.010)
+    assert at < until - 0.05 and ids == ["A"]
+    if what == "abort":
+        # Processed at the top of the next step, as an abort that arrives
+        # during a readback always was.
+        assert why == {"A": "abort"} and rest[0][0] == "step"
+
+
+def _mixed_arrivals():
+    return [_req("A", _prompt(5, 9), 41, 0.0), _req("B", _prompt(7, 20), 18, 0.0),
+            _req("C", _prompt(11, 33), 25, 0.0), _req("D", _prompt(13, 14), 9, 0.0),
+            _req("E", _prompt(17, 12), 13, 0.0), _req("F", _prompt(19, 25), 6, 0.0)]
+
+
+def test_greedy_streams_are_the_same_held_and_never_held():
+    """Six requests on four lanes: B and C arrive inside holds, D at the top
+    of a step, E and F while every lane is taken (they are refilled ahead).
+    The same requests with no hold ever taken (the deadline always now), all
+    arriving at the top of steps: every stream is the same, token for token;
+    so is each request alone."""
+    reqs = _mixed_arrivals()
+    at_step = {"A": 0, "D": 7, "E": 8, "F": 8}
+    toks, why, eng, dev = _held(
+        reqs, at_step=at_step, script={0: [(0.020, "B")], 2: [(0.045, "C")]})
+    assert why == dict.fromkeys("ABCDEF", "length")
+    assert [len(toks[r.request_id]) for r in reqs] == [41, 18, 25, 9, 13, 6]
+    assert _admissions(eng) == (2, 4)
+    plain, why_plain, eng_plain, dev_plain = _held(
+        reqs, at_step={**at_step, "B": 4, "C": 6}, hold=False)
+    assert "hold" not in dev_plain.kinds() and "hold" in dev.kinds()
+    assert _admissions(eng_plain) == (0, 6)
+    assert toks == plain and why == why_plain
+    alone, _, _ = _by_hand(reqs, submit_at={
+        r.request_id: 40 * n for n, r in enumerate(reqs)})
+    assert toks == alone
+    for e in (eng, eng_plain):
+        assert _free_blocks(e) == e.n_blocks - 1
+
+
+def test_admissions_are_counted_once_a_request():
+    """Held or not, placed at once or after a wait for blocks, refilled ahead
+    or into an empty slot: one count a request, where slot_refills counts
+    it; a request that is refused counts in neither."""
+    reqs = _mixed_arrivals() + [_req("X", _prompt(23, 30), 400, 0.0)]
+    toks, why, eng, dev = _held(
+        reqs, at_step={"A": 0, "D": 7, "E": 8, "F": 8, "X": 9},
+        script={0: [(0.020, "B")], 2: [(0.045, "C")]}, hbm_kv_blocks=8)
+    assert why == {**dict.fromkeys("ABCDEF", "length"), "X": "abort"}
+    hold, step = _admissions(eng)
+    assert hold >= 1 and hold + step == 6 == sum(_refills(eng))
 
 
 if __name__ == "__main__":

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
-from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+from llm_d_inference_scheduler_tpu.engine.core import HOLD_MARGIN_S, TpuEngine
 from llm_d_inference_scheduler_tpu.engine.server import EngineServer
 from llm_d_inference_scheduler_tpu.engine.telemetry import (
     LOOP_PHASES,
@@ -87,9 +87,9 @@ def test_wait_histograms_once_per_request_and_sum_to_ttft(backend, stream):
 
 # ---------- the loop's phases ----------
 
-_STEP_ORDER = ("housekeeping", "admit", "advance_prefills", "decode_prepare",
-               "decode_dispatch", "decode_wait", "decode_book", "decode_wait",
-               "finalize_prefills")
+_STEP_ORDER = ("housekeeping", "admit", "advance_prefills", "decode_wait",
+               "decode_prepare", "decode_dispatch", "decode_wait",
+               "decode_book", "decode_wait", "finalize_prefills")
 
 
 def test_loop_seconds_cover_the_loops_wall_time_and_decode_wait_needs_a_chunk():
@@ -100,7 +100,11 @@ def test_loop_seconds_cover_the_loops_wall_time_and_decode_wait_needs_a_chunk():
     gap (their sum is the clock), a chunk is dispatched before the one in
     flight is read and booked, a wait inside _finalize_prefills is
     decode_wait's and not finalize_prefills', and decode_wait moves only in
-    a step that began with a chunk in flight or dispatched a prefill."""
+    a step that began with a chunk in flight or dispatched a prefill. Where
+    the next chunk is held back for an arrival (TpuEngine._hold_for_arrival: a slot
+    is free, nobody waits, and a chunk of the shape in flight has been timed)
+    the sleep is decode_wait's too, ahead of decode_prepare; here it costs
+    nothing, and nothing arrives in it."""
     async def body():
         eng = TpuEngine(_cfg("tpu", 0, kv_events_port=0))
         now, ops, reads, order = [0.0], [], [0], []
@@ -121,6 +125,7 @@ def test_loop_seconds_cover_the_loops_wall_time_and_decode_wait_needs_a_chunk():
 
         eng._clock = lambda: now[0]
         eng._exec_op, eng._read_tokens, eng._phase = exec_op, read_tokens, phase
+        eng._await_work = lambda until: bool(order[-1].append("hold"))
 
         def step():
             order.append([])
@@ -154,11 +159,17 @@ def test_loop_seconds_cover_the_loops_wall_time_and_decode_wait_needs_a_chunk():
         assert {p for p, v in seconds.items() if v} == {
             "admit", "decode_dispatch", "decode_wait"}
         assert sum(seconds.values()) == now[0]            # no gap, no overlap
+        # The third chunk's step is the first that finds a chunk in flight
+        # whose shape has been timed (the second chunk's period, read in the
+        # step before): the one hold, to 20 ms before the end it reckons.
+        assert [names.count("hold") for names in order[3:]] == [0, 0, 0, 1, 0, 0]
+        assert order[6][:5] == list(_STEP_ORDER[:4]) + ["hold"]
         for names in order:
             at = -1
             for name in names:       # each step: a subsequence of the order
-                at = _STEP_ORDER.index(name, at + 1)
-        head = list(_STEP_ORDER[:5])
+                if name != "hold":
+                    at = _STEP_ORDER.index(name, at + 1)
+        head = [*_STEP_ORDER[:3], *_STEP_ORDER[4:6]]
         assert order[3] == head + ["decode_wait", "finalize_prefills"]
         assert order[4] == head + ["decode_wait", "decode_book"]
 
@@ -473,8 +484,13 @@ class _ScriptedDevice:
                 self.chunks.append((self._t0.popleft(), self.now))
             return real_read(toks)
 
+        def await_work(until):   # a held chunk's sleep: nothing arrives
+            self.now = max(self.now, until)
+            return False
+
         eng._clock = lambda: self.now
         eng._exec_op, eng._read_tokens = exec_op, read_tokens
+        eng._await_work = await_work
 
     def parents_arithmetic(self):
         """What PR 35's _land_chunk observed: each chunk but the first (a
@@ -583,8 +599,11 @@ def test_a_period_that_holds_a_first_call_of_a_shape_is_no_stall():
         count, total = _hist(eng.telemetry, "jetstream:decode_step_duration_seconds")
         # The four-row chunk's own first call is not timed; the two-row chunk
         # read behind its build is, at 16 s and more.
+        # (With a slot still free and nobody waiting, the build began where
+        # the held chunk went out: 20 ms before the end of the one in flight.)
         held = spans.index(max(spans))
-        assert spans[held] == pytest.approx(16.0) and count == len(spans) - 1
+        assert spans[held] == pytest.approx(16.0 + 0.133 - HOLD_MARGIN_S)
+        assert count == len(spans) - 1
         assert total == pytest.approx(sum(spans) - spans[held + 1])
         assert _stall_seconds(eng) == {"device_wait": 0.0, "host": 0.0}
         assert not eng.stalls.ring
