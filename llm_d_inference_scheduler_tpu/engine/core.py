@@ -220,6 +220,7 @@ class TpuEngine:
             else pages.decode_attention, kernel=cfg.pallas_attention,
             interpret=cfg.pallas_interpret)
         self._bind_state_form(self.device.platform)
+        self._bind_index_form(self.device.platform)
         self._bind_moe_form(self.device.platform)
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
@@ -545,6 +546,9 @@ class TpuEngine:
     def _one_chip_cache(self) -> str | None:
         """What this model keeps that lives on the unsharded one-chip engine
         alone, in words; None for plain K/V pages."""
+        if self.geom.index_dim:
+            return ("a latent (MLA) page pool and its indexer's key pool "
+                    "beside it, under one block table")
         if self.geom.latent_dim:
             return "a latent (MLA) page pool"
         if self.state_geom:
@@ -554,8 +558,11 @@ class TpuEngine:
     def _refuse_beyond_one_chip(self) -> None:
         """A latent page pool, and a state pool beside the pages, live on the
         unsharded one-chip engine: neither has a sharding rule, a stage split
-        or a wire format yet (ROADMAP R7, R8). Asked for any of those, say so
-        now, by name, rather than serve something else."""
+        or a wire format yet (ROADMAP R7, R8), and an indexer's key pool
+        beside a latent one has none either: a selecting block's handoff
+        would carry both pools' pages, and a selection over sharded keys
+        needs every shard's scores. Asked for any of those, say so now, by
+        name, rather than serve something else."""
         cfg = self.cfg
         asked = [f"{name}={value}" for name, value, plain in (
             ("tp_size", cfg.tp_size, 1), ("ep_size", cfg.ep_size, 1),
@@ -591,6 +598,15 @@ class TpuEngine:
                 "kv_layers": self.geom.n_layers,
                 "kv_token_bytes": self.geom.token_bytes,
                 "kv_pool_bytes": self.geom.pool_bytes,
+                # A block that selects the rows it attends to (0: none):
+                # how many a query keeps, and what a token's indexer key
+                # holds a cache layer in its own pool, under the same block
+                # ids (kv_token_bytes stays the latent row's).
+                "index_topk": self.mcfg.index_topk,
+                "index_token_bytes": self.geom.index_token_bytes,
+                "index_pool_bytes": self.geom.index_pool_bytes,
+                "index_scores": (self.mcfg.index_impl if self.mcfg.index_topk
+                                 else None),
                 # The experts this chip holds of those its router scores
                 # (all of them: first 0, held n_experts), and the router's
                 # outputs that compute nothing.
@@ -684,6 +700,47 @@ class TpuEngine:
         self.mcfg = dataclasses.replace(
             self.mcfg, ssm_impl="gathered" if not kernel else
             "kernel_interpret" if interpret else "kernel")
+
+    def _bind_index_form(self, platform: str) -> None:
+        """How a selecting block's programs compute their indexer's scores:
+        the kernel (ops/pallas_dsa.py) on a TPU, where the per-head products
+        must not reach HBM, and through the interpreter where the tests ask
+        for it; the plain form on the CPU. Such an engine is unsharded (it
+        refused the rest at start)."""
+        if not self.mcfg.index_topk:
+            return
+        interpret = self.cfg.pallas_interpret
+        self.mcfg = dataclasses.replace(
+            self.mcfg, index_impl="kernel_interpret" if interpret else
+            "kernel" if platform == "tpu" else "xla")
+
+    def _note_selection(self, op: tuple, args: dict) -> None:
+        """Book what a program of a selecting block puts through it
+        (jetstream:dsa_*): its query tokens' contexts, from the positions the
+        host already holds. Real lanes and prompt tokens alone; a decode
+        chunk's steps are its lanes' next decode_chunk positions."""
+        if op[0] == "decode":
+            first = args["positions"][args["slots"] < self.cfg.max_batch] + 1
+            n = np.full(first.shape, self.cfg.decode_chunk)
+        elif op[0] == "prefill":
+            n = args["seq_len"]
+            first = np.ones_like(n)
+        elif op[0] == "prefix_prefill":
+            first, n = args["prefix_len"] + 1, args["suffix_len"]
+        else:
+            return
+        first, n = first.astype(np.int64), n.astype(np.int64)
+        last, topk = first + n - 1, self.mcfg.index_topk
+        scored = int(np.sum((first + last) * n // 2))
+        # The contexts beyond index_topk: a query of context c attends to
+        # index_topk rows and leaves c - index_topk.
+        lo = np.maximum(first, topk + 1)
+        m = np.maximum(last - lo + 1, 0)
+        left = int(np.sum((lo + last) * m // 2 - m * topk))
+        self.telemetry.dsa_query_tokens["selected"].inc(int(m.sum()))
+        self.telemetry.dsa_query_tokens["all"].inc(int((n - m).sum()))
+        self.telemetry.dsa_rows["scored"].inc(scored)
+        self.telemetry.dsa_rows["attended"].inc(scored - left)
 
     def _bind_moe_form(self, platform: str) -> None:
         """The MoE FFN's form is chosen per program, from its token count
@@ -2654,6 +2711,8 @@ class TpuEngine:
             # run of them expanded).
             self.telemetry.mla_attention_tokens.labels(
                 form="absorbed" if decode else "expanded").inc(rows * steps)
+            if self.mcfg.index_topk and not args.get("warm"):
+                self._note_selection(op, args)
         if self.state_geom:
             # What it puts through the state-space layers, under the form its
             # kind traced to (models/hybrid.py: one position a sequence is

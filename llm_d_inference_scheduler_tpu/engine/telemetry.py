@@ -185,6 +185,28 @@ class EngineTelemetry:
             "counted on the host at dispatch, a token once a program and not "
             "once an attention sublayer; empty without a latent pool",
             ("form",), registry=self.registry)
+        dsa_query_tokens = Counter(
+            "jetstream:dsa_query_tokens_total",
+            "Query tokens put through a block that selects the rows it "
+            "attends to (learned sparse attention, models/mla.py), real lanes "
+            "and prompt tokens alone: `selected` where the query's context "
+            "outnumbers index_topk (its indexer's choice narrows the "
+            "softmax), `all` where it attends to every row it may see; "
+            "counted on the host at dispatch from positions, a token once a "
+            "program; empty for a block without an indexer",
+            ("form",), registry=self.registry)
+        self.dsa_query_tokens = {f: dsa_query_tokens.labels(form=f)
+                                 for f in ("selected", "all")}
+        dsa_rows = Counter(
+            "jetstream:dsa_rows_total",
+            "Cached rows a layer of such a block deals with for those query "
+            "tokens: `scored`, the rows a query may see (its context: what "
+            "its indexer scores where it selects), and `attended`, "
+            "min(context, index_topk) of them; `attended` over `scored` is "
+            "the share of the context that attention reads for",
+            ("kind",), registry=self.registry)
+        self.dsa_rows = {k: dsa_rows.labels(kind=k)
+                         for k in ("scored", "attended")}
         self.ssm_tokens = Counter(
             "jetstream:ssm_tokens_total",
             "Rows (padded tokens) dispatched through the state-space layers, "

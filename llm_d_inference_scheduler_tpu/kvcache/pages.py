@@ -19,6 +19,19 @@ because a TPU array's minor dim is stored in tiles of 128 anyway and the
 kernel's DMA wants the page's rows to be whole tiles. Wherever this module
 takes or returns a ``(k_pages, v_pages)`` pair, a latent pool is the pair
 ``(pool, None)``: there is no second array.
+
+A third kind lies beside a latent pool where the block selects rows
+(DeepSeek-V3.2's indexer, models/mla.py): the indexer's key pool
+``[n_layers, n_blocks, block, index_dim]``, a token's ``index_dim`` values a
+layer (128: whole lanes as they are), under the SAME block ids — one block
+table, one allocator, so a page of it is allocated, freed, parked in the
+prefix cache and found again with the latent page of that id. It is a pool of
+its own, not more columns of the latent row, so that the indexer reads its
+256 B a token without touching the 1,280 B beside them. It has the latent
+kind's layout at another width, so the latent kind's writes and reads serve
+it (:func:`write`, :func:`write_sequences` with ``v_pages`` None,
+:func:`read_latent_prefix`, :func:`read_rows`); the pair of them rides in a
+``kvcache/state.Cache`` (``k`` the latent pool, ``idx`` this one).
 """
 
 from __future__ import annotations
@@ -34,8 +47,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import (latent_paged_decode_attention,
                              paged_decode_attention)
+from ..ops.pallas_dsa import sparse_latent_paged_decode_attention_pallas
 from ..ops.pallas_latent_attention import latent_paged_decode_attention_pallas
 from ..ops.pallas_paged_attention import paged_decode_attention_pallas
+from ..ops.sparse_attention import sparse_latent_paged_decode_attention
 from . import state as state_pool
 
 TRASH_BLOCK = 0
@@ -56,6 +71,9 @@ class PageGeometry:
     max_blocks_per_seq: int  # a block table's width
     # Values of a latent row; 0 = the K/V pair (n_kv_heads x head_dim each).
     latent_dim: int = 0
+    # Values of an indexer key, in a pool of its own beside the latent one;
+    # 0 = no such pool.
+    index_dim: int = 0
 
     @classmethod
     def for_model(cls, model: Any, n_blocks: int,
@@ -72,7 +90,8 @@ class PageGeometry:
                    model.n_kv_heads, model.head_dim,
                    str(jnp.dtype(dtype or model.dtype)),
                    max_blocks_per_seq or n_blocks - 1,
-                   getattr(model, "latent_dim", 0))
+                   getattr(model, "latent_dim", 0),
+                   getattr(model, "index_dim", 0))
 
     @classmethod
     def for_engine(cls, model: Any, max_batch: int, max_model_len: int,
@@ -95,6 +114,23 @@ class PageGeometry:
             return (self.n_layers, self.n_blocks, self.block, self.row_width)
         return (self.n_layers, self.n_blocks, self.block, self.n_kv_heads,
                 self.head_dim)
+
+    @property
+    def index_shape(self) -> tuple[int, ...] | None:
+        """The indexer's key pool's shape; None where there is none."""
+        if not self.index_dim:
+            return None
+        return (self.n_layers, self.n_blocks, self.block, self.index_dim)
+
+    @property
+    def index_token_bytes(self) -> int:
+        """Bytes a token's indexer key holds in one layer."""
+        return self.index_dim * jnp.dtype(self.dtype).itemsize
+
+    @property
+    def index_pool_bytes(self) -> int:
+        return (self.n_layers * self.n_blocks * self.block
+                * self.index_token_bytes)
 
     @property
     def token_bytes(self) -> int:
@@ -139,7 +175,7 @@ def page_sharding(mesh: Mesh) -> NamedSharding:
 def alloc(geom: PageGeometry, *, device=None, sharding=None,
           state: state_pool.StateGeometry | None = None,
           counted: bool = False, counts_zero: bool = False
-          ) -> tuple[jax.Array, jax.Array | None]:
+          ) -> tuple[Any, jax.Array | None]:
     """Zeroed ``(k_pages, v_pages)``, on one device or laid out by
     ``sharding`` (made in place, shard by shard); ``(pool, None)`` for a
     latent geometry, which has no sharding rule yet. With ``state`` (a model
@@ -147,15 +183,20 @@ def alloc(geom: PageGeometry, *, device=None, sharding=None,
     and the state pool as one value (kvcache/state.py), unsharded too. So it
     is with ``counted`` (a model whose step programs count their router's
     choices, ``counts_zero``: the zero-compute ones too), whose counts that
-    value carries."""
-    if state is not None or counted:
+    value carries, and with an indexer's key pool (``geom.index_dim``), which
+    rides there beside the latent one."""
+    if state is not None or counted or geom.index_dim:
         if sharding is not None or (state is not None and geom.latent_dim):
             raise ValueError("a state pool lies beside an unsharded K/V page "
                              "pool only, and a step's counts ride with an "
                              "unsharded pool only: no sharding rule for "
                              "either")
-        return state_pool.alloc(state, *alloc(geom, device=device),
-                                device=device, counts_zero=counts_zero), None
+        idx = (jnp.zeros(geom.index_shape, jnp.dtype(geom.dtype),
+                         device=device) if geom.index_dim else None)
+        plain = dataclasses.replace(geom, index_dim=0)
+        return state_pool.alloc(state, *alloc(plain, device=device),
+                                device=device, counts_zero=counts_zero,
+                                idx=idx), None
     dtype = jnp.dtype(geom.dtype)
     if geom.latent_dim:
         if sharding is not None:
@@ -292,9 +333,13 @@ def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
     pool takes a first window's ``state.Fresh`` as ``k_new``: its K/V rows
     go to the pages and its slots' state starts afresh."""
     if isinstance(k_pages, state_pool.Cache):
-        return state_pool.start(k_pages, k_new, *write_sequences(
+        cache = state_pool.start(k_pages, k_new, *write_sequences(
             k_pages.k, k_pages.v, k_new.k, k_new.v, block_tables, lens,
-            start)), None
+            start))
+        if cache.idx is not None:
+            cache = dataclasses.replace(cache, idx=_write_latent_run(
+                cache.idx, k_new.idx, block_tables, lens, start))
+        return cache, None
     if v_pages is None:
         return _write_latent_run(k_pages, k_new, block_tables, lens,
                                  start), None
@@ -346,13 +391,24 @@ def latent_decode_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
                             block_tables: jax.Array, seq_lens: jax.Array,
                             cur_row: jax.Array, *, value_dim: int,
                             scale: float, kernel: bool = False,
-                            interpret: bool = False) -> jax.Array:
+                            interpret: bool = False,
+                            keep: jax.Array | None = None,
+                            cur_keep: jax.Array | None = None) -> jax.Array:
     """:func:`decode_attention` for a latent pool, in the absorbed form: q
     [B, H, latent_dim] (carried into the latent space, then its rotated
     part) against ``layer``'s rows, each key and value at once, and the
     token's own row ``cur_row`` [B, latent_dim]. Returns [B, H, value_dim]:
     the probabilities over the rows' leading ``value_dim`` columns (the
-    latent, which the caller carries out through the value projection)."""
+    latent, which the caller carries out through the value projection).
+    With ``keep`` [B, table width x block] and ``cur_keep`` [B] (a block that
+    selects rows) the softmax is over the selected rows alone, the current
+    token among them or not."""
+    if keep is not None:
+        op = (functools.partial(sparse_latent_paged_decode_attention_pallas,
+                                interpret=interpret)
+              if kernel else sparse_latent_paged_decode_attention)
+        return op(q, pool, layer, block_tables, seq_lens, cur_row, keep,
+                  cur_keep, value_dim=value_dim, scale=scale)
     op = (functools.partial(latent_paged_decode_attention_pallas,
                             interpret=interpret)
           if kernel else latent_paged_decode_attention)
@@ -368,6 +424,16 @@ def read_latent_prefix(pool: jax.Array, layer: jax.Array,
     layer's pool is sliced out first."""
     rows = pool[layer, table_row]                    # [1, W, block, width]
     return rows.reshape(1, -1, rows.shape[-1])[..., :latent_dim]
+
+
+def read_rows(pool: jax.Array, layer: jax.Array,
+              block_tables: jax.Array) -> jax.Array:
+    """Every lane's rows of ``layer`` of a stacked latent-kind pool (the
+    indexer's keys at decode), ``block_tables`` [B, W] in order: [B, W *
+    block, width], padding entries the trash block's. One gather at (layer,
+    page)."""
+    rows = pool[layer, block_tables]                  # [B, W, block, width]
+    return rows.reshape(rows.shape[0], -1, rows.shape[-1])
 
 
 def read_prefix(k_layer: jax.Array, v_layer: jax.Array,

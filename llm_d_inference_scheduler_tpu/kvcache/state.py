@@ -49,7 +49,9 @@ The counts have this one carrier in every family. A latent page pool whose
 model counts its router's choices (models/mla.py, where a chip holds a share
 of the experts or the router has zero-compute outputs:
 ``ModelConfig.tallies_choices``) rides in a :class:`Cache` as well: ``k`` the
-latent pool, ``v`` None, and no state pools (``ssm`` and ``conv`` None).
+latent pool, ``v`` None, and no state pools (``ssm`` and ``conv`` None). Where
+that model's block selects rows, the indexer's key pool (kvcache/pages.py)
+rides there too, as ``idx``.
 """
 
 from __future__ import annotations
@@ -124,6 +126,7 @@ class Cache:
     # which only a model whose router has such outputs counts.
     counts_zero: bool = dataclasses.field(default=False,
                                           metadata=dict(static=True))
+    idx: jax.Array | None = None    # the indexer's key pool beside a latent k
 
 
 @jax.tree_util.register_dataclass
@@ -140,15 +143,18 @@ class Fresh:
     conv: jax.Array | None
     held: jax.Array
     zero: jax.Array | None = None
+    idx: jax.Array | None = None    # the rows' indexer keys [L, B, S, width]
 
 
 def alloc(geom: StateGeometry | None, k_pages: jax.Array,
           v_pages: jax.Array | None, *, device=None,
-          counts_zero: bool = False) -> Cache:
+          counts_zero: bool = False, idx: jax.Array | None = None) -> Cache:
     """A zeroed state pool beside the given page pools; ``geom`` None: the
-    page pools alone, in the value that carries a step's counts."""
+    page pools alone (with ``idx``, an indexer's key pool beside a latent
+    one), in the value that carries a step's counts."""
     if geom is None:
-        return Cache(k_pages, v_pages, None, None, counts_zero=counts_zero)
+        return Cache(k_pages, v_pages, None, None, counts_zero=counts_zero,
+                     idx=idx)
     return Cache(k_pages, v_pages,
                  jnp.zeros(geom.ssm_shape, jnp.float32, device=device),
                  jnp.zeros(geom.conv_shape, jnp.dtype(geom.dtype),

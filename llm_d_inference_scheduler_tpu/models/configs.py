@@ -107,6 +107,29 @@ class ModelConfig:
     # nothing: a choice of one returns the token itself times its gate.
     router_scoring: str = "sigmoid"
     n_zero_experts: int = 0
+    # DeepSeek-V3's group-limited selection (models/routing.py): the router's
+    # outputs lie in n_group equal groups, a group scores the sum of its two
+    # largest biased scores, and a token chooses inside its topk_group best
+    # groups. n_group 1 is no grouping.
+    n_group: int = 1
+    topk_group: int = 1
+    # YaRN (ops/rope.py): (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale_all_dim); empty = plain rotary embedding.
+    rope_yarn: tuple[float, ...] = ()
+    # DeepSeek-V3.2's learned sparse attention (models/mla.py; index_topk > 0
+    # names it, and nothing else says that a block selects): an indexer of
+    # index_n_heads heads of index_head_dim scores every cached token, and a
+    # query attends to the index_topk rows that score highest. A token keeps
+    # its indexer key (index_head_dim values) in a second page pool beside
+    # its latent row (kvcache/pages.py).
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    # The form of such a block's programs: "xla" (ops/sparse_attention.py:
+    # the CPU's way and the plain form) | "kernel" (ops/pallas_dsa.py: the
+    # indexer's scores and a window's attention over the selected rows) |
+    # "kernel_interpret" (CPU tests). The engine sets it from what it is.
+    index_impl: str = "xla"
 
     @property
     def n_state_layers(self) -> int:
@@ -138,9 +161,10 @@ class ModelConfig:
         """Whether the step programs count their router's choices on the
         device (held here / zero-compute; kvcache/state.Cache carries the
         counts out): models/hybrid.py's always do, models/mla.py's where not
-        every choice is an expert held here."""
+        every choice is an expert held here, and where the block selects rows
+        (its two pools ride in that value anyway)."""
         return bool(self.layer_pattern or self.experts_held
-                    or self.n_zero_experts)
+                    or self.n_zero_experts or self.index_topk)
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -166,6 +190,11 @@ class ModelConfig:
     def latent_dim(self) -> int:
         """Values a token keeps a layer in a latent page pool; 0 = K and V."""
         return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
+
+    @property
+    def index_dim(self) -> int:
+        """Values a token keeps a layer in the indexer's key pool; 0 = none."""
+        return self.index_head_dim if self.index_topk else 0
 
     @property
     def q_per_kv(self) -> int:
@@ -384,6 +413,15 @@ TINY_LONGCAT = ModelConfig(
     n_zero_experts=8,
 )
 
+# DeepSeek-V3.2-Exp's block at small widths aligned to nothing (CI tests): a
+# low-rank query, YaRN, a router of 16 experts in 4 groups of which 2 are
+# kept, and an indexer of 4 heads of 16 whose queries attend to 24 rows.
+TINY_DSA = dataclasses.replace(
+    TINY_MLA, name="tiny-dsa", n_experts=16, experts_per_token=3,
+    q_lora_rank=20, n_group=4, topk_group=2,
+    rope_yarn=(40.0, 32, 32.0, 1.0, 1.0), index_topk=24, index_n_heads=4,
+    index_head_dim=16)
+
 # NVIDIA-Nemotron-3-Super-120B-A12B's language model (public config.json,
 # model_type nemotron_h): 88 layers of one mixer each -- 40 Mamba-2, 40
 # LatentMoE (512 experts of 2688 in a 1024-wide latent space, 22 a token,
@@ -454,7 +492,7 @@ TINY_HYBRID = ModelConfig(
 _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
-                                 KIMI_VL_A3B, TINY_MLA, TINY_LONGCAT,
+                                 KIMI_VL_A3B, TINY_MLA, TINY_LONGCAT, TINY_DSA,
                                  NEMOTRON_3_SUPER,
                                  NEMOTRON_3_SUPER_CUT, TINY_HYBRID)}
 

@@ -54,17 +54,54 @@ def _held_share(hf) -> dict:
 
 # What models/mla.py computes of the DeepSeek-V3 family's options; a config
 # that says otherwise is refused, not served as something else.
-_MLA_ONLY = {"q_lora_rank": None, "rope_scaling": None, "n_group": 1,
-             "topk_group": 1, "scoring_func": "sigmoid",
+_MLA_ONLY = {"scoring_func": "sigmoid",
              "topk_method": "noaux_tc", "norm_topk_prob": True,
              "moe_layer_freq": 1, "attention_bias": False,
              "hidden_act": "silu", "tie_word_embeddings": False}
 
 
+def _yarn(hf, name: str) -> tuple[float, ...]:
+    """``rope_scaling`` as ModelConfig.rope_yarn; none, or YaRN as the
+    DeepSeek family states it (cos/sin unscaled: mscale == mscale_all_dim)."""
+    scaling = getattr(hf, "rope_scaling", None)
+    if not scaling:
+        return ()
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn" or scaling.get("mscale", 1) != scaling.get(
+            "mscale_all_dim", 1):
+        raise ValueError(
+            f"{name}: rope_scaling={scaling!r} is not supported (ops/rope.py "
+            "computes none, or type 'yarn' with mscale == mscale_all_dim)")
+    return (float(scaling["factor"]),
+            float(scaling["original_max_position_embeddings"]),
+            float(scaling.get("beta_fast", 32)),
+            float(scaling.get("beta_slow", 1)),
+            float(scaling.get("mscale_all_dim", 1)))
+
+
 def _mla_config_from_hf(hf, name: str) -> ModelConfig:
     """The DeepSeek-V3 family (latent attention, sigmoid-routed experts
-    beside a shared one): Kimi-VL's language model is one."""
+    beside a shared one): Kimi-VL's language model is one, and DeepSeek-V3.2's
+    (``model_type`` deepseek_v32: a low-rank query, YaRN, group-limited
+    routing and the indexer's three ``index_*`` keys) another; a chip's
+    share of the experts as :func:`_held_share` reads it."""
     _refuse_other_options(hf, name, _MLA_ONLY, "models/mla.py")
+    share = _held_share(hf)
+    n_group = getattr(hf, "n_group", None) or 1
+    topk_group = getattr(hf, "topk_group", None) or 1
+    if share["n_experts"] % n_group or not 1 <= topk_group <= n_group or (
+            n_group > 1 and share["n_experts"] // n_group < 2):
+        raise ValueError(
+            f"{name}: n_group={n_group} must divide the {share['n_experts']} "
+            f"experts the router scores into groups of two or more, and "
+            f"topk_group={topk_group} lie in 1..n_group")
+    index = [getattr(hf, k, None) or 0
+             for k in ("index_topk", "index_n_heads", "index_head_dim")]
+    if any(index) and not (all(index) and getattr(hf, "q_lora_rank", None)):
+        raise ValueError(
+            f"{name}: an indexer needs index_topk, index_n_heads and "
+            "index_head_dim together, and the low-rank query its own query "
+            "is projected from (q_lora_rank)")
     return ModelConfig(
         name=name,
         vocab_size=hf.vocab_size,
@@ -76,7 +113,6 @@ def _mla_config_from_hf(hf, name: str) -> ModelConfig:
         rope_theta=float(getattr(hf, "rope_theta", 10_000.0)),
         max_seq_len=getattr(hf, "max_position_embeddings", 8192),
         norm_eps=hf.rms_norm_eps,
-        n_experts=hf.n_routed_experts,
         experts_per_token=hf.num_experts_per_tok,
         kv_lora_rank=hf.kv_lora_rank,
         qk_nope_head_dim=hf.qk_nope_head_dim,
@@ -86,6 +122,14 @@ def _mla_config_from_hf(hf, name: str) -> ModelConfig:
         moe_d_ff=hf.moe_intermediate_size,
         n_shared_experts=hf.n_shared_experts,
         routed_scaling_factor=float(hf.routed_scaling_factor),
+        **share,
+        q_lora_rank=getattr(hf, "q_lora_rank", None) or 0,
+        n_group=n_group,
+        topk_group=topk_group,
+        rope_yarn=_yarn(hf, name),
+        index_topk=index[0],
+        index_n_heads=index[1],
+        index_head_dim=index[2],
     )
 
 

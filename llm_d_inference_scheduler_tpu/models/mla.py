@@ -1,7 +1,9 @@
 """The latent-attention (MLA) decoder blocks for the TPU engine, two of them.
 The DeepSeek-V3 family's: latent attention and sigmoid-routed narrow experts
 beside a shared one; Kimi-VL-A3B's language model is this block (its vision
-tower is not built). And LongCat-Flash's double layer (``cfg.attn_sublayers``
+tower is not built), and so is DeepSeek-V3.2's, whose queries attend to the
+rows an indexer picked (**Selection**, below; its draft head is not built).
+And LongCat-Flash's double layer (``cfg.attn_sublayers``
 2, described last); LongCat-Flash-Omni's language model is that one (its
 encoders and its codec decoder are not built).
 
@@ -72,7 +74,29 @@ experts (expert parallelism without its exchange, as models/hybrid.py: what
 the absent ones would have added is left out); the zero term is computed
 where the token is, on every chip, and is no chip's share.
 
-Where ``cfg.tallies_choices`` (a held range, or zero-compute outputs) the
+**Selection** (``cfg.index_topk`` > 0 is what says a block selects; there is
+no option). Beside its cache row a token keeps an indexer key ``k^I =
+LayerNorm(h W_k^I)`` (index_head_dim values, the first d_rope rotated), in a
+second page pool under the same block ids (kvcache/pages.py). A query's
+indexer has index_n_heads light heads ``q^I = c_q W_qb^I`` (from the query
+latent; the first d_rope columns of each rotated) and a weight a head ``w = h
+W_w / sqrt(heads x head_dim)``; cached token s scores ``I[t, s] = sum_j w[t,
+j] relu(q^I[t, j] . k^I[s])`` in f32, and the query attends to the
+``min(index_topk, t + 1)`` tokens s <= t that score highest, ties to the
+lower position: the attention above with every other row at minus infinity.
+Both forms take the set as a mask over the rows they read (ops/
+sparse_attention.py, ops/pallas_dsa.py): the absorbed form walks the lane's
+pages and masks; the expanded form works a tile of (queries, rows) at a time
+with a running softmax, so that no [heads, S, T] tensor is ever whole (8.6 GB
+at 128 heads, a 1,024-token window and 16k rows). A program whose rows cannot outnumber index_topk (a first
+window of 1,024 under index_topk 2,048) computes the keys and caches them,
+and neither scores nor selects: it IS dense latent attention. YaRN
+(``cfg.rope_yarn``) stretches the rotary frequencies and scales the softmax
+by its magnitude factor squared; the router selects inside its best groups
+(models/routing.py).
+
+Where ``cfg.tallies_choices`` (a held range, or zero-compute outputs, or a
+block that selects: its two pools ride there anyway) the
 step programs count the choices held here and the zero ones, and the pool
 rides in a ``kvcache/state.Cache`` that carries the counts out, as
 models/hybrid.py's does; elsewhere (Kimi) the pool is passed bare and nothing
@@ -88,8 +112,10 @@ import jax
 import jax.numpy as jnp
 
 from ..kvcache import pages, state
-from ..ops import apply_rope, rms_norm, rope_table
+from ..ops import (apply_rope, pallas_dsa, rms_norm, rope_table,
+                   sparse_attention)
 from ..ops.attention import NEG_INF
+from ..ops.rope import yarn_mscale
 from .configs import ModelConfig
 from .llama import _over_layers
 from .routing import route
@@ -108,14 +134,30 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     Fm, Fs = cfg.moe_d_ff, cfg.n_shared_experts * cfg.moe_d_ff
     keys = iter(jax.random.split(key, 40))
+    # (The indexer draws from keys of its own, so that the blocks without
+    # one keep the weights they had.)
+    index_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
 
-    def w(shape, fan_in):
+    def w(shape, fan_in, keys=keys):
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
-    def norm(shape):
+    def norm(shape, keys=keys):
         return (1.0 + 0.1 * jax.random.normal(next(keys), shape,
                                               jnp.float32)).astype(dtype)
+
+    def indexer(L):
+        if not cfg.index_topk:
+            return {}
+        Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+        return {
+            "wqb_idx": w((L, cfg.q_lora_rank, Hi * Di), cfg.q_lora_rank,
+                         index_keys),
+            "wk_idx": w((L, D, Di), D, index_keys),
+            "k_norm_idx": norm((L, Di), index_keys),
+            "k_bias_idx": (0.1 * jax.random.normal(
+                next(index_keys), (L, Di), jnp.float32)).astype(dtype),
+            "w_idx": w((L, D, Hi), D, index_keys)}
 
     def attention(L):
         # Where the block scales q or the latent by sqrt(d_model / rank), the
@@ -133,6 +175,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                  else {"wq": w((L, D, H * (dn + dr)), D)})
         return {
             **query,
+            **indexer(L),
             "wkva": w((L, D, r + dr), D),
             "kv_norm": norm((L, r)),
             "wkvb": w((L, r, H * (dn + dv)),
@@ -160,7 +203,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             "w1": w((L, Eh, D, Fm), D), "w3": w((L, Eh, D, Fm), D),
             "w2": w((L, Eh, Fm, D), Fm)}
         return params
-    Ld = cfg.first_k_dense
+    Ld, Eh = cfg.first_k_dense, cfg.held_experts[1]
     Le = cfg.n_layers - Ld
     if Ld:
         params["dense"] = {
@@ -174,8 +217,9 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         # order of the scores' spread; drawn so that it changes selections.
         "router_bias": (0.1 * jax.random.normal(
             next(keys), (Le, E), jnp.float32)),
-        "w1": w((Le, E, D, Fm), D), "w3": w((Le, E, D, Fm), D),
-        "w2": w((Le, E, Fm, D), Fm),
+        # (The experts held here: all of them, or a chip's share.)
+        "w1": w((Le, Eh, D, Fm), D), "w3": w((Le, Eh, D, Fm), D),
+        "w2": w((Le, Eh, Fm, D), Fm),
         "w1s": w((Le, D, Fs), D), "w3s": w((Le, D, Fs), D),
         "w2s": w((Le, Fs, D), Fs)}
     return params
@@ -253,10 +297,14 @@ def _project(cfg: ModelConfig, lp: Params, h: jnp.ndarray, cos, sin
              ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """h [..., D] at the positions of cos/sin [..., d_rope/2] -> q_nope
     [..., H, dn], q_rope [..., H, dr] (rotated), and the tokens' cache rows
-    [..., r + dr] = [normed latent | rotated key part]."""
+    [..., r + dr] = [normed latent | rotated key part]; where the block
+    selects, the rows carry the indexer's keys behind them ([..., r + dr +
+    index_dim]: :func:`_split_rows`) and a fourth value is the indexer's
+    (queries, weights) of these tokens."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     if cfg.q_lora_rank:
-        q = rms_norm(h @ lp["wqa"], lp["q_norm"], cfg.norm_eps) @ lp["wqb"]
+        c_q = rms_norm(h @ lp["wqa"], lp["q_norm"], cfg.norm_eps)
+        q = c_q @ lp["wqb"]
         if cfg.mla_scale_q_lora:
             q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
     else:
@@ -270,11 +318,110 @@ def _project(cfg: ModelConfig, lp: Params, h: jnp.ndarray, cos, sin
         c = c * (cfg.d_model / r) ** 0.5
     q_rope = apply_rope(q[..., dn:], cos, sin)
     k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+    if cfg.index_topk:
+        q_idx, k_idx, w_idx = _index_project(cfg, lp, h, c_q, cos, sin)
+        return (q[..., :dn], q_rope,
+                jnp.concatenate([c, k_rope, k_idx], axis=-1), (q_idx, w_idx))
     return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], axis=-1)
 
 
+def _index_project(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+                   c_q: jnp.ndarray, cos, sin
+                   ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The indexer's side of tokens h [..., D] with query latents c_q: its
+    queries [..., Hi, Di] and keys [..., Di], the first d_rope columns of
+    each rotated, and its head weights [..., Hi] f32, scaled by ``(Hi x
+    Di) ** -0.5``."""
+    Hi, Di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    q = (c_q @ lp["wqb_idx"]).reshape(*h.shape[:-1], Hi, Di)
+    q = jnp.concatenate([apply_rope(q[..., :dr], cos, sin), q[..., dr:]],
+                        axis=-1)
+    k = (h @ lp["wk_idx"]).astype(jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    k = (k * lp["k_norm_idx"].astype(jnp.float32)
+         + lp["k_bias_idx"].astype(jnp.float32)).astype(h.dtype)
+    k = jnp.concatenate(
+        [apply_rope(k[..., None, :dr], cos, sin)[..., 0, :], k[..., dr:]],
+        axis=-1)
+    w = jnp.dot(h, lp["w_idx"], preferred_element_type=jnp.float32)
+    return q, k, w * (Hi * Di) ** -0.5
+
+
+def _split_rows(cfg: ModelConfig, rows: jnp.ndarray
+                ) -> tuple[jnp.ndarray, jnp.ndarray | None]:
+    """What :func:`_project` made a token's row, as (latent rows, indexer
+    keys or None): what each of the two pools takes. (Behind the keys a
+    comparison's program may carry each token's selection out,
+    :func:`_with_picked`; :func:`_picked` reads it.)"""
+    if not cfg.index_topk:
+        return rows, None
+    return (rows[..., :cfg.latent_dim],
+            rows[..., cfg.latent_dim:cfg.latent_dim + cfg.index_dim])
+
+
+def _with_picked(want: bool, rows: jnp.ndarray, keep: jnp.ndarray):
+    """``rows`` with each token's selection ``keep`` [..., T] behind them
+    where ``want``: scripts/compare_dsa_reference.py's second hook into the
+    program (``want_routes``), by the one way out of the scan over layers
+    that is there; no server asks."""
+    return (jnp.concatenate([rows, keep.astype(rows.dtype)], axis=-1)
+            if want else rows)
+
+
+def _picked(cfg: ModelConfig, rows: jnp.ndarray) -> jnp.ndarray:
+    return rows[..., cfg.latent_dim + cfg.index_dim:] > 0
+
+
+def _index_scores(cfg: ModelConfig, q_idx, w_idx, keys) -> jnp.ndarray:
+    """ops/sparse_attention.index_scores in the form this program traces
+    with (``cfg.index_impl``, the engine's to set as ``moe_impl`` is)."""
+    if cfg.index_impl.startswith("kernel"):
+        return pallas_dsa.index_scores_pallas(
+            q_idx, w_idx, keys, interpret=cfg.index_impl == "kernel_interpret")
+    return sparse_attention.index_scores(q_idx, w_idx, keys)
+
+
+def _selected(cfg: ModelConfig, index, keys: jnp.ndarray,
+              mask: jnp.ndarray) -> jnp.ndarray:
+    """``mask`` [B, S, T] (the rows a query may see) narrowed to those it
+    selected, by its indexer's (queries, weights) ``index`` against ``keys``
+    [B, T, Di]. T rows or fewer than index_topk: every row a query may see is
+    selected, and nothing is scored."""
+    if not cfg.index_topk or keys.shape[1] <= cfg.index_topk:
+        return mask
+    return sparse_attention.select_top(_index_scores(cfg, *index, keys), mask,
+                                       cfg.index_topk)
+
+
+def _selected_of_lanes(cfg: ModelConfig, q_idx, w_idx, idx_pool, layer,
+                       block_tables, seq_lens, cur_key, seen) -> jnp.ndarray:
+    """:func:`_selected` for one query a lane (decode): ``seen`` [B, T + 1]
+    (the table's T rows and the token's own, last) narrowed to the rows each
+    lane's query selected. The cached keys are the key pool's pages under
+    ``block_tables``: read by the kernel a page at a time where the programs
+    run their kernels, gathered whole for the plain form."""
+    if seen.shape[1] <= cfg.index_topk:
+        return seen
+    own = sparse_attention.index_scores(
+        q_idx[:, None], w_idx[:, None],
+        cur_key[:, None].astype(q_idx.dtype))[:, 0]               # [B, 1]
+    if cfg.index_impl.startswith("kernel"):
+        cached = pallas_dsa.index_scores_paged_pallas(
+            q_idx, w_idx, idx_pool, layer, block_tables, seq_lens,
+            interpret=cfg.index_impl == "kernel_interpret")
+    else:
+        cached = sparse_attention.index_scores(
+            q_idx[:, None], w_idx[:, None],
+            pages.read_rows(idx_pool, layer, block_tables))[:, 0]
+    return sparse_attention.select_top(
+        jnp.concatenate([cached, own], axis=1), seen, cfg.index_topk)
+
+
 def _scale(cfg: ModelConfig) -> float:
-    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            * yarn_mscale(cfg.rope_yarn) ** 2)
 
 
 def expanded_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, rows,
@@ -282,11 +429,21 @@ def expanded_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, rows,
     """Queries [B, S, H, .] against cache rows [B, T, r + dr], every row
     carried out to its keys and values; ``mask`` [B, S, T] says which rows a
     query sees. Returns [B, S, H * dv]. Products in the operands' dtype with
-    f32 accumulation, the softmax in f32."""
+    f32 accumulation, the softmax in f32. Where the block's programs run
+    their kernels (``cfg.index_impl``: a block that selects, on a TPU) the
+    scores stay in VMEM a tile at a time (ops/pallas_dsa.py): whole, at 128
+    heads, they would be 8.6 GB for a window over 16k rows."""
     B, S, H, _ = q_nope.shape
     r = cfg.kv_lora_rank
     w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
-    c, k_rope = rows[..., :r], rows[..., r:]
+    c, k_rope = rows[..., :r], rows[..., r:cfg.latent_dim]
+    if cfg.index_impl.startswith("kernel"):
+        out = pallas_dsa.masked_window_attention_pallas(
+            jnp.swapaxes(q_nope, 1, 2), jnp.swapaxes(q_rope, 1, 2),
+            jnp.einsum("btr,rhd->bhtd", c, w_uk), k_rope,
+            jnp.einsum("btr,rhd->bhtd", c, w_uv), mask, scale=_scale(cfg),
+            interpret=cfg.index_impl == "kernel_interpret")
+        return jnp.swapaxes(out, 1, 2).reshape(B, S, -1)
     k_nope = jnp.einsum("btr,rhd->bthd", c, w_uk)
     v = jnp.einsum("btr,rhd->bthd", c, w_uv)
     f32 = dict(preferred_element_type=jnp.float32)
@@ -394,12 +551,15 @@ def _pool_of(k_pages):
     return k_pages.k if isinstance(k_pages, state.Cache) else k_pages
 
 
-def _kept(k_pages, pool: jnp.ndarray, counts: jnp.ndarray | None):
+def _kept(k_pages, pool: jnp.ndarray, counts: jnp.ndarray | None,
+          idx: jnp.ndarray | None = None):
     """What a step hands back in ``k_pages``' place: the pool as the step
-    left it, the step's counts added where it rides with them."""
+    left it (and the indexer's key pool ``idx`` where there is one), the
+    step's counts added where it rides with them."""
     if not isinstance(k_pages, state.Cache):
         return pool
-    return state.counted(dataclasses.replace(k_pages, k=pool), *counts)
+    return state.counted(dataclasses.replace(k_pages, k=pool, idx=idx),
+                         *counts)
 
 
 def forward(
@@ -430,24 +590,33 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :],
                                      (B, S))
-    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                          cfg.rope_yarn)
     mask = positions[:, :, None] >= positions[:, None, :]          # [B, S, S]
     if kv_valid is not None:
         mask = mask & kv_valid[:, None, :]
 
     def attend(lp, h, layer):
-        q_nope, q_rope, rows = _project(cfg, lp, h, cos, sin)
-        return expanded_attention(cfg, lp, q_nope, q_rope, rows, mask), rows
+        q_nope, q_rope, rows, *index = _project(cfg, lp, h, cos, sin)
+        if not index:
+            return expanded_attention(cfg, lp, q_nope, q_rope, rows, mask), rows
+        seen = _selected(cfg, *index, _split_rows(cfg, rows)[1], mask)
+        return (expanded_attention(cfg, lp, q_nope, q_rope, rows, seen),
+                _with_picked(want_routes, rows, seen))
 
     x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
                                       attend)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     kv = None
+    if want_routes and cfg.index_topk:
+        routes = (routes, _picked(cfg, rows))
     if want_kv:
         # With counts, the rows go to ``pages.write_sequences`` in the value
         # that hands the counts to the cache as well.
+        rows, idx = _split_rows(cfg, rows)
         kv = ((rows if counts is None
-               else state.Fresh(rows, None, None, None, *counts)), None)
+               else state.Fresh(rows, None, None, None, *counts, idx=idx)),
+              None)
     out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
     return (out, kv, routes) if want_routes else (out, kv)
 
@@ -472,25 +641,47 @@ def decode_step(
     attention's extra column. ``attention_fn`` has
     ``pages.latent_decode_attention``'s signature; the engine binds the
     kernel into it."""
-    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                          cfg.rope_yarn)
     seq_lens = positions + 1
     pool = _pool_of(k_pages)
+    idx_pool = k_pages.idx if cfg.index_topk else None
     cur_slots = pages.token_slots(pool, block_tables, positions)
 
     def attend(lp, h, layer):
-        q_nope, q_rope, row = _project(cfg, lp, h, cos, sin)
+        q_nope, q_rope, row, *index = _project(cfg, lp, h, cos, sin)
+        chosen = {}
+        if index:
+            # The lane's cached keys and the token's own, which competes
+            # with them; the table's padding and the rows past the lane's
+            # length are nobody's to see.
+            (q_idx, w_idx), cur_key = index[0], _split_rows(cfg, row)[1]
+            T = block_tables.shape[1] * pages.block_size(pool)
+            seen = jnp.concatenate(
+                [jnp.arange(T)[None, :] < positions[:, None],
+                 jnp.ones((positions.shape[0], 1), bool)], axis=1)
+            keep = _selected_of_lanes(cfg, q_idx, w_idx, idx_pool, layer,
+                                      block_tables, seq_lens, cur_key, seen)
+            chosen = dict(keep=keep[:, :T], cur_keep=keep[:, T])
+            row = _with_picked(want_routes, row, keep)
 
         def paged(q, cur_row):
             return attention_fn(q, pool, layer, block_tables, seq_lens,
                                 cur_row, value_dim=cfg.kv_lora_rank,
-                                scale=_scale(cfg))
+                                scale=_scale(cfg), **chosen)
 
-        return absorbed_attention(cfg, lp, q_nope, q_rope, row, paged), row
+        return absorbed_attention(cfg, lp, q_nope, q_rope,
+                                  _split_rows(cfg, row)[0], paged), row
 
     x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
                                       attend)
+    if want_routes and cfg.index_topk:
+        routes = (routes, _picked(cfg, rows))
+    rows, idx_rows = _split_rows(cfg, rows)
     pool, _ = pages.write(pool, None, rows, None, *cur_slots)
-    k_pages = _kept(k_pages, pool, counts)
+    if idx_rows is not None:
+        idx_pool, _ = pages.write(idx_pool, None, idx_rows, None, *cur_slots)
+    k_pages = _kept(k_pages, pool, counts, idx_pool)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
@@ -523,10 +714,12 @@ def prefill_with_prefix(
     if prior_table_row is None:
         prior_table_row = block_table_row
     pool = _pool_of(k_pages)
+    idx_pool = k_pages.idx if cfg.index_topk else None
     T = prior_table_row.shape[1] * pages.block_size(pool)
 
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                          cfg.rope_yarn)
     prior_pos = jnp.arange(T, dtype=jnp.int32)[None, :]
     kv_pos = jnp.concatenate([prior_pos, positions], axis=1)        # [1, T+S]
     kv_valid = jnp.concatenate(
@@ -536,17 +729,33 @@ def prefill_with_prefix(
             & kv_valid[:, None, :])                                 # [1,S,T+S]
 
     def attend(lp, h, layer):
-        q_nope, q_rope, rows = _project(cfg, lp, h, cos, sin)
+        q_nope, q_rope, rows, *index = _project(cfg, lp, h, cos, sin)
+        own, own_keys = _split_rows(cfg, rows)
         prior = pages.read_latent_prefix(pool, layer, prior_table_row,
                                          cfg.latent_dim)
-        seen = jnp.concatenate([prior.astype(rows.dtype), rows], axis=1)
-        return expanded_attention(cfg, lp, q_nope, q_rope, seen, mask), rows
+        seen = jnp.concatenate([prior.astype(rows.dtype), own], axis=1)
+        chosen = mask
+        if index:
+            keys = jnp.concatenate(
+                [pages.read_latent_prefix(idx_pool, layer, prior_table_row,
+                                          cfg.index_dim).astype(rows.dtype),
+                 own_keys], axis=1)
+            chosen = _selected(cfg, *index, keys, mask)
+            rows = _with_picked(want_routes, rows, chosen)
+        return expanded_attention(cfg, lp, q_nope, q_rope, seen, chosen), rows
 
     x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
                                       attend)
+    if want_routes and cfg.index_topk:
+        routes = (routes, _picked(cfg, rows))
+    rows, idx_rows = _split_rows(cfg, rows)
     pool, _ = pages.write_sequences(pool, None, rows, None, block_table_row,
                                     suffix_len, start=prefix_len)
-    k_pages = _kept(k_pages, pool, counts)
+    if idx_rows is not None:
+        idx_pool, _ = pages.write_sequences(
+            idx_pool, None, idx_rows, None, block_table_row, suffix_len,
+            start=prefix_len)
+    k_pages = _kept(k_pages, pool, counts, idx_pool)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]

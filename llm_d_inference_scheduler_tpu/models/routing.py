@@ -12,7 +12,10 @@ both find them. Which one a layer has is the configuration's to say
   they weigh).
 
 Either selects the experts_per_token largest of score plus the selection bias,
-which selects and does not weigh."""
+which selects and does not weigh. Where the configuration groups the router's
+outputs (``n_group`` > 1, DeepSeek-V3's group-limited selection) the choice is
+made inside the ``topk_group`` groups whose two largest biased scores sum
+highest; ``n_group`` 1 is the plain choice and traces as it always did."""
 
 from __future__ import annotations
 
@@ -39,8 +42,15 @@ def route(cfg: ModelConfig, lp: dict[str, Any], h: jnp.ndarray
     logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
     scores = (jax.nn.softmax(logits, axis=-1) if over_all
               else jax.nn.sigmoid(logits))
-    _, idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32),
-                           cfg.experts_per_token)
+    biased = scores + lp["router_bias"].astype(jnp.float32)
+    if cfg.n_group > 1:
+        groups = biased.reshape(*biased.shape[:-1], cfg.n_group, -1)
+        best = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)    # [T, n_group]
+        _, kept = jax.lax.top_k(best, cfg.topk_group)
+        open_ = jnp.any(kept[..., None] == jnp.arange(cfg.n_group), axis=-2)
+        biased = jnp.where(open_[..., None], groups,
+                           -jnp.inf).reshape(biased.shape)
+    _, idx = jax.lax.top_k(biased, cfg.experts_per_token)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     gates = (chosen if over_all
              else chosen / jnp.sum(chosen, axis=-1, keepdims=True))

@@ -76,6 +76,9 @@ def main(argv=None) -> int:
                     help="plain prefill programs as BUCKETxROWS")
     ap.add_argument("--prefix", default="16x128",
                     help="prefix-prefill programs as SUFFIXxPREFIX_BLOCKS")
+    ap.add_argument("--hbm-kv-blocks", type=int, default=0,
+                    help="pages in the pool (0: every lane at max_model_len), "
+                         "to rehearse a smaller pool beside the weights")
     ap.add_argument("--topology", default="v5e:2x2")
     ap.add_argument("--skip-init", action="store_true")
     ap.add_argument("--lowered-dir", default="",
@@ -125,7 +128,8 @@ def main(argv=None) -> int:
 
     cfg = EngineConfig(model=args.model, max_batch=args.max_batch,
                        max_model_len=args.max_model_len,
-                       decode_chunk=args.decode_chunk, pallas_attention=True)
+                       decode_chunk=args.decode_chunk, pallas_attention=True,
+                       hbm_kv_blocks=args.hbm_kv_blocks)
     mcfg = cfg.model_config
     # The jitted bodies are methods; they read only the two configs, how to
     # attend and the (absent) pipeline mesh, so a bare instance carries them
@@ -141,7 +145,9 @@ def main(argv=None) -> int:
         kvpages.latent_decode_attention if geom.latent_dim
         else kvpages.decode_attention, kernel=True)
     eng._bind_state_form("tpu")  # the described chip, not this host's CPU
+    eng._bind_index_form("tpu")
     eng._bind_moe_form("tpu")
+    mcfg = eng.mcfg
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(
@@ -162,7 +168,9 @@ def main(argv=None) -> int:
                 pages, None, None, None, slots=sds((rows,), jnp.int32),
                 held=sds((), jnp.int32),
                 zero=sds((), jnp.int32) if mcfg.n_zero_experts else None,
-                counts_zero=bool(mcfg.n_zero_experts)), None)
+                counts_zero=bool(mcfg.n_zero_experts),
+                idx=(sds(geom.index_shape, jnp.dtype(geom.dtype))
+                     if geom.index_dim else None)), None)
         return (pages, None) if geom.latent_dim else (pages, pages)
 
     def sampling(rows):

@@ -385,3 +385,123 @@ def test_moe_tiles_come_from_the_shapes():
                  sharded=False)
     assert not pallas_moe.use_grouped(1024, d_model=128, d_ff=200, **facts)
     assert pallas_moe.use_grouped(1024, d_model=128, d_ff=256, **facts)
+
+
+# ---------- learned sparse attention (DeepSeek-V3.2's block) ----------
+
+def _dsa_cut(n_layers=2):
+    """The cell's configuration at its published widths and fewer layers (the
+    dense one and one expert layer: every kind), forms as the engine binds
+    them on a TPU."""
+    import json
+    import types
+
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs",
+        "deepseek-v3.2-exp-cut.json")
+    with open(path) as f:
+        doc = json.load(f)
+    doc["num_hidden_layers"] = n_layers
+    return dataclasses.replace(
+        config_from_hf(types.SimpleNamespace(**doc), name="dsa-cut"),
+        index_impl="kernel")
+
+
+def _array_elements(hlo):
+    """(elements, its text) of every array type the compiled program names."""
+    found = {}
+    for m in re.finditer(r"\b(?:f32|bf16|s32|u32|pred|s8|u8)\[([\d,]+)\]", hlo):
+        found[m.group(0)] = math.prod(int(d) for d in m.group(1).split(","))
+    return found
+
+
+def test_no_window_of_the_selecting_block_holds_a_heads_by_queries_by_rows(
+        one_chip):
+    """A 1,024-token window over a 16k prefix at 128 heads and 64 indexer
+    heads: the per-head scores of either would be [64 or 128, 1024, 17408]
+    (4.6 / 9.1 GB in f32). Both stay in VMEM a tile at a time (ops/
+    pallas_dsa.py: the indexer's kernel and the window's attention with its
+    running softmax): the compiled program names no array of 64 x 1,024 x
+    17,408 elements, and its temporaries are the rows' keys and values for
+    all heads (2 x 17,408 x 128 x 128 bf16 = 1.14 GB) and little else."""
+    from llm_d_inference_scheduler_tpu.kvcache import state
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
+    from llm_d_inference_scheduler_tpu.models import mla
+
+    m = dataclasses.replace(_dsa_cut(), moe_impl="grouped")
+    geom = PageGeometry.for_engine(m, 4, 18432)
+    dt = jnp.dtype(m.dtype)
+    cache = state.Cache(
+        _sds(one_chip, geom.shape, dt), None, None, None,
+        slots=_sds(one_chip, (1,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32),
+        idx=_sds(one_chip, geom.index_shape, dt))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    S, prior = 1024, 1024
+    one = _sds(one_chip, (1,), jnp.int32)
+    compiled = jax.jit(lambda p, *a: mla.prefill_with_prefix(p, m, *a),
+                       donate_argnums=(4,)).lower(
+        params, _sds(one_chip, (1, S), jnp.int32), one, one, cache, None,
+        _sds(one_chip, (1, geom.max_blocks_per_seq), jnp.int32),
+        _sds(one_chip, (1, prior), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "dsa_index_scores_window" in hlo and "dsa_window_attention" in hlo
+    rows = prior * geom.block + S
+    too_large = {t: n for t, n in _array_elements(hlo).items()
+                 if n >= 64 * S * rows}
+    assert not too_large, too_large
+    # What IS whole: a query's scores over the rows, [1, 1024, 17408] f32.
+    assert any(n == S * rows for n in _array_elements(hlo).values())
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
+
+
+def test_the_selecting_blocks_decode_step_compiles_with_both_kernels(one_chip):
+    """One decode step of 32 lanes over a table 1,152 blocks wide: the
+    indexer's kernel over the lanes' key pages (read by the block table, not
+    gathered), the selection, and the masked kernel over the latent pages,
+    with neither pool made anew."""
+    from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
+    from llm_d_inference_scheduler_tpu.kvcache import state
+    from llm_d_inference_scheduler_tpu.models import mla
+
+    m = _dsa_cut()
+    batch = 32
+    geom = kvpages.PageGeometry.for_engine(m, batch, 18432)
+    dt = jnp.dtype(m.dtype)
+    cache = state.Cache(
+        _sds(one_chip, geom.shape, dt), None, None, None,
+        slots=_sds(one_chip, (batch,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32),
+        idx=_sds(one_chip, geom.index_shape, dt))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    lanes = _sds(one_chip, (batch,), jnp.int32)
+    compiled = jax.jit(lambda p, *a: mla.decode_step(
+        p, m, *a, attention_fn=functools.partial(
+            kvpages.latent_decode_attention, kernel=True)),
+        donate_argnums=(3,)).lower(
+        params, lanes, lanes, cache, None,
+        _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "dsa_index_scores_decode" in hlo
+    assert "dsa_paged_decode_attention" in hlo
+    assert "mla_paged_decode_attention" not in hlo
+    for pool in (geom.shape, geom.index_shape):
+        shape = "bf16[" + ",".join(map(str, pool)) + "]"
+        made = [ln.strip()[:160] for ln in hlo.splitlines()
+                if re.search(r"=\s*" + re.escape(shape), ln)
+                and "parameter(" not in ln and "bitcast(" not in ln
+                and "scatter" not in ln and "dynamic-update-slice" not in ln
+                and "fusion(" not in ln and "get-tuple-element(" not in ln]
+        assert not made, made
+    # No lane's keys are gathered (32 x 18,432 x 128 bf16 would be 151 MB a
+    # layer): the step's temporaries are the dense parts' and the scores'.
+    gathered = "bf16[" + ",".join(map(str, (
+        batch, geom.max_blocks_per_seq, geom.block, geom.index_dim))) + "]"
+    assert gathered not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

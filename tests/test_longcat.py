@@ -412,12 +412,14 @@ def test_config_from_hf_refuses_what_the_double_layer_does_not_compute(
 
 
 def test_a_family_the_tree_does_not_know_stays_a_clean_error():
-    """What the parent says of this PR's configuration file, the tree still
-    says of the next unknown one: the DeepSeek-V3 family with a low-rank
-    query (no zero_expert_num) is refused by name, at once."""
+    """What the parent said of this configuration's file, the tree still says
+    of the next unknown one: a DeepSeek-V3-family config (no zero_expert_num)
+    whose router scores with a softmax is refused by name, at once."""
     hf = {k: v for k, v in _published().items()
           if not k.startswith("zero_expert")}
-    with pytest.raises(ValueError, match="q_lora_rank=1536 is not supported"):
+    hf.update(scoring_func="softmax")
+    with pytest.raises(ValueError, match="scoring_func='softmax' is not "
+                                         "supported"):
         config_from_hf(types.SimpleNamespace(**hf), name="unknown")
 
 

@@ -375,8 +375,9 @@ def test_config_from_hf_reads_the_nested_language_model(nested):
 
 
 @pytest.mark.parametrize("key,value", [
-    ("q_lora_rank", 1536), ("n_group", 8), ("scoring_func", "softmax"),
-    ("rope_scaling", {"type": "yarn"}), ("norm_topk_prob", False)])
+    ("index_topk", 2048), ("n_group", 7), ("scoring_func", "softmax"),
+    ("rope_scaling", {"type": "linear", "factor": 4}),
+    ("norm_topk_prob", False)])
 def test_config_from_hf_refuses_what_the_block_does_not_compute(key, value):
     with pytest.raises(ValueError, match=key):
         config_from_hf(types.SimpleNamespace(**{**PUBLISHED, key: value}))
