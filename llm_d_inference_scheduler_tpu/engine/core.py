@@ -40,8 +40,9 @@ import numpy as np
 from ..kvcache import pages, state as state_pool, wire
 from ..models import family
 from ..ops import pallas_moe, pallas_ssm
+from ..ops.pallas_latent_attention import RUN_PAGES
 from ..utils.hashing import chain_block_hashes
-from .blocks import BlockAllocator, PrefixCachingAllocator
+from .blocks import BlockAllocator, PrefixCachingAllocator, table_groups
 from .config import EngineConfig
 from .multihost import ChannelBroken
 from .request import EngineRequest, FinishReason, TokenEvent
@@ -598,6 +599,9 @@ class TpuEngine:
                 "kv_layers": self.geom.n_layers,
                 "kv_token_bytes": self.geom.token_bytes,
                 "kv_pool_bytes": self.geom.pool_bytes,
+                # Table entries the latent decode kernels fetch as one copy
+                # where they name adjacent blocks (None: no latent pool).
+                "kv_run_pages": RUN_PAGES if self.geom.latent_dim else None,
                 # A block that selects the rows it attends to (0: none):
                 # how many a query keeps, and what a token's indexer key
                 # holds a cache layer in its own pool, under the same block
@@ -1691,7 +1695,17 @@ class TpuEngine:
             self.telemetry.observe_allocator(self.allocator)
         if evicted and self.kv_events is not None:
             self.kv_events.removed(evicted)
+        self._note_table(blocks)
         return blocks
+
+    def _note_table(self, blocks: list[int]) -> None:
+        """Count an admitted request's block table by the groups the latent
+        decode kernels fetch it in (kv_table_groups_total)."""
+        if not self.geom.latent_dim:
+            return
+        runs, splits = table_groups(blocks, RUN_PAGES)
+        self.telemetry.kv_table_groups["run"].inc(runs)
+        self.telemetry.kv_table_groups["split"].inc(splits)
 
     def _run_batched_prefill(self, bucket: int, entries: list[tuple]):
         """One fused [K, bucket] prefill dispatch for up to K plain prompts.
@@ -1843,6 +1857,7 @@ class TpuEngine:
             self.telemetry.observe_allocator(self.allocator)
         if evicted and self.kv_events is not None:
             self.kv_events.removed(evicted)
+        self._note_table(blocks)
 
         cached_tokens = len(matched_bids) * block
         suffix = prompt[cached_tokens:]

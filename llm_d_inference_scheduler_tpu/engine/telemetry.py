@@ -261,6 +261,17 @@ class EngineTelemetry:
             ("when",), registry=self.registry)
         self.slot_refills = {w: slot_refills.labels(when=w)
                              for w in ("ahead", "after")}
+        kv_table_groups = Counter(
+            "jetstream:kv_table_groups_total",
+            "Groups of RUN_PAGES entries of the block tables handed to "
+            "admitted requests, as the latent decode kernels walk them "
+            "(ops/pallas_latent_attention.stage_fetch): `run` where a group "
+            "names adjacent blocks in ascending order (one copy), `split` "
+            "otherwise (a copy a page; a short last group is one); counted "
+            "on the host at admission, on an engine with a latent pool",
+            ("kind",), registry=self.registry)
+        self.kv_table_groups = {k: kv_table_groups.labels(kind=k)
+                                for k in ("run", "split")}
         admissions = Counter(
             "jetstream:admissions_total",
             "Requests admitted into an engine slot: woken by the arrival "

@@ -11,7 +11,8 @@ is [queries, keys] f32. One query a lane (decode) walks the lane's key pages
 by its block table as the attention kernels walk the latent pages (a program
 a lane, stages of P pages double-buffered), so that a step reads a context's
 keys once, 256 B a token a layer, and nothing of the table's width beyond
-the lane's length.
+the lane's length. Both decode walks fetch a stage as the latent kernel does
+(``stage_fetch``: adjacent pages of the table as one copy).
 
 **A window's attention over the selected rows.** The expanded form of a run
 of queries (a prefill or continuation window) against every row carried out
@@ -41,7 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_latent_attention import pages_per_stage
+from .pallas_latent_attention import (pages_per_stage, run_pages,
+                                      stage_fetch, table_runs)
 from .pallas_paged_attention import NEG_INF
 
 # Queries a tile of the indexer (their heads flattened: 32 x 64 = 2,048 rows
@@ -94,47 +96,42 @@ def index_scores_pallas(q: jnp.ndarray,     # [B, S, Hi, Di]
     return out[:, :S, :T]
 
 
-def _index_paged_kernel(bt_ref, sl_ref, layer_ref,   # scalar prefetch
+def _index_paged_kernel(bt_ref, run_ref, sl_ref, layer_ref,  # prefetch
                         q_ref, w_ref,                # [1, Hi, Di], [1, Hi, 1]
                         pool_hbm,                    # [L, N, block, Di] (ANY)
                         out_ref,                     # [1, stages, P * block]
                         tile, sem,
-                        *, max_blocks: int, pages: int, block: int):
+                        *, max_blocks: int, pages: int, block: int,
+                        group: int):
     b = pl.program_id(0)
     q, w = q_ref[0], w_ref[0]
     n_pages = pl.cdiv(sl_ref[b] - 1, block)           # the lane's cached rows
     n_stages = pl.cdiv(n_pages, pages)
-    layer = layer_ref[0]
 
-    def _each_page(s, slot, do):
-        def page(i, carry):
-            blk = bt_ref[b * max_blocks + s * pages + i]
-            rows = pl.ds(pl.multiple_of(i * block, block), block)
-            do(pltpu.make_async_copy(pool_hbm.at[layer, blk],
-                                     tile.at[slot, rows], sem.at[slot]))
-            return carry
-
-        jax.lax.fori_loop(0, jnp.minimum(pages, n_pages - s * pages), page, 0)
+    # A last stage's pages past the lane's length are never fetched: they
+    # score what the slot held, and the caller masks rows by length.
+    _start, _wait = stage_fetch(
+        bt_ref, run_ref, pool_hbm, tile, sem, lane=b, layer=layer_ref[0],
+        n_pages=n_pages, max_blocks=max_blocks, group=group, zero_rest=False)
 
     # Stages past the lane's length are nobody's to read: zeros, not what
-    # VMEM held. (So are a last stage's pages past it: never fetched, they
-    # score what the slot held, and the caller masks rows by length.)
+    # VMEM held.
     out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
     @pl.when(n_stages > 0)
     def _prologue():
-        _each_page(0, 0, lambda c: c.start())
+        _start(0, 0)
 
     def stage_body(s, carry):
         slot = jax.lax.rem(s, 2)
 
         @pl.when(s + 1 < n_stages)
         def _prefetch_next():
-            _each_page(s + 1, 1 - slot, lambda c: c.start())
+            _start(s + 1, 1 - slot)
 
-        _each_page(s, slot, lambda c: c.wait())
+        _wait(s, slot)
         per_head = jax.lax.dot_general(
-            q, tile[slot], (((1,), (1,)), ((), ())),
+            q, tile[slot].reshape(pages * block, -1), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # [Hi, P * block]
         out_ref[0, pl.ds(s, 1), :] = jnp.sum(
             jnp.maximum(per_head, 0.0) * w, axis=0, keepdims=True)
@@ -161,10 +158,11 @@ def index_scores_paged_pallas(
     _, _, block, _ = pages.shape
     maxB = block_tables.shape[1]
     n_pages = pages_per_stage(block, Di, pages.dtype.itemsize, maxB)
+    group = run_pages(n_pages)
     rows = n_pages * block
     stages = -(-maxB // n_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, Hi, Di), lambda b, *_: (b, 0, 0)),
@@ -173,18 +171,19 @@ def index_scores_paged_pallas(
         ],
         out_specs=pl.BlockSpec((1, stages, rows), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, rows, Di), pages.dtype),
+            pltpu.VMEM((2, n_pages, block, Di), pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_index_paged_kernel, max_blocks=maxB, pages=n_pages,
-                          block=block),
+                          block=block, group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, stages, rows), jnp.float32),
         interpret=interpret,
         name="dsa_index_scores_decode",
-    )(block_tables.reshape(-1), seq_lens,
+    )(block_tables.reshape(-1),
+      table_runs(block_tables, seq_lens, block, group).reshape(-1), seq_lens,
       jnp.asarray(layer, jnp.int32).reshape(1), q.astype(pages.dtype),
       w.astype(jnp.float32)[..., None], pages)
     return out.reshape(B, stages * rows)[:, :maxB * block]
@@ -309,13 +308,13 @@ def masked_window_attention_pallas(
     return out[:, :, :S]
 
 
-def _attention_kernel(bt_ref, sl_ref, ck_ref, layer_ref,  # scalar prefetch
+def _attention_kernel(bt_ref, run_ref, sl_ref, ck_ref, layer_ref,  # prefetch
                       q_ref, cur_ref,          # [1, H, W], [1, 1, W]
                       keep_ref,                # [1, stages, P * block] int32
                       pool_hbm,                # [L, N, block, W] (ANY/HBM)
                       out_ref,                 # [1, H, value_dim]
                       tile, sem,
-                      *, max_blocks: int, pages: int, block: int,
+                      *, max_blocks: int, pages: int, block: int, group: int,
                       value_dim: int, scale: float):
     b = pl.program_id(0)
     rows = pages * block
@@ -326,34 +325,9 @@ def _attention_kernel(bt_ref, sl_ref, ck_ref, layer_ref,  # scalar prefetch
     n_stages = pl.cdiv(n_pages, pages)
     layer = layer_ref[0]
 
-    def _rows(i):
-        return pl.ds(pl.multiple_of(i * block, block), block)
-
-    def _each_page(s, slot, do):
-        def page(i, carry):
-            blk = bt_ref[b * max_blocks + s * pages + i]
-            do(pltpu.make_async_copy(pool_hbm.at[layer, blk],
-                                     tile.at[slot, _rows(i)], sem.at[slot]))
-            return carry
-
-        live = jnp.minimum(pages, n_pages - s * pages)
-        jax.lax.fori_loop(0, live, page, 0)
-        return live
-
-    def _start(s, slot):
-        live = _each_page(s, slot, lambda c: c.start())
-
-        def zero(i, carry):
-            # Never fetched, and the rows are values too: 0 x whatever VMEM
-            # held must be 0.
-            tile[slot, _rows(i)] = jnp.zeros((block, tile.shape[-1]),
-                                             tile.dtype)
-            return carry
-
-        jax.lax.fori_loop(live, pages, zero, 0)
-
-    def _wait(s, slot):
-        _each_page(s, slot, lambda c: c.wait())
+    _start, _wait = stage_fetch(
+        bt_ref, run_ref, pool_hbm, tile, sem, lane=b, layer=layer,
+        n_pages=n_pages, max_blocks=max_blocks, group=group, zero_rest=True)
 
     @pl.when(n_stages > 0)
     def _prologue():
@@ -381,7 +355,7 @@ def _attention_kernel(bt_ref, sl_ref, ck_ref, layer_ref,  # scalar prefetch
 
         _wait(s, slot)
         logits = jax.lax.dot_general(
-            q, tile[slot], (((1,), (1,)), ((), ())),
+            q, tile[slot].reshape(rows, -1), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [H, rows]
         seen = ((keep_ref[0, pl.ds(s, 1), :] > 0)
                 & (col < cached_len - s * rows))             # [1, rows]
@@ -391,7 +365,8 @@ def _attention_kernel(bt_ref, sl_ref, ck_ref, layer_ref,  # scalar prefetch
         corr = jnp.exp(m - new_m)
         return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
                 acc * corr + jnp.dot(p.astype(tile.dtype),
-                                     tile[slot, :, :value_dim],
+                                     tile[slot, :, :, :value_dim].reshape(
+                                         rows, value_dim),
                                      preferred_element_type=jnp.float32))
 
     _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
@@ -420,6 +395,7 @@ def sparse_latent_paged_decode_attention_pallas(
     _, _, block, W = pages.shape
     maxB = block_tables.shape[1]
     n_pages = pages_per_stage(block, W, pages.dtype.itemsize, maxB)
+    group = run_pages(n_pages)
     rows = n_pages * block
     stages = -(-maxB // n_pages)
     pad = [(0, 0)] * 2 + [(0, W - Dk)]
@@ -431,9 +407,9 @@ def sparse_latent_paged_decode_attention_pallas(
 
     kernel = functools.partial(
         _attention_kernel, max_blocks=maxB, pages=n_pages, block=block,
-        value_dim=value_dim, scale=scale)
+        group=group, value_dim=value_dim, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
@@ -443,7 +419,7 @@ def sparse_latent_paged_decode_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, H, value_dim), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, rows, W), pages.dtype),
+            pltpu.VMEM((2, n_pages, block, W), pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -453,5 +429,7 @@ def sparse_latent_paged_decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
         interpret=interpret,
         name="dsa_paged_decode_attention",
-    )(block_tables.reshape(-1), seq_lens, cur_keep.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, cur, keep, pages)
+    )(block_tables.reshape(-1),
+      table_runs(block_tables, seq_lens, block, group).reshape(-1), seq_lens,
+      cur_keep.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q, cur, keep, pages)

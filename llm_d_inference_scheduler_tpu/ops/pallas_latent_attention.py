@@ -19,6 +19,18 @@ nothing to a score, and the value's columns stop before it. The tile goes to
 the MXU in the pool's dtype with f32 accumulation; the softmax state and the
 logits are f32, and the probabilities are rounded to the pool's dtype for the
 second product, as the values they weigh are.
+
+**A stage's fetch** (:func:`stage_fetch`; ops/pallas_dsa.py's two walks call
+it too). A copy's issue and its wait cost the scalar core some 38 ns a page
+whatever the page holds (4 KB or 20 KB; PERF.md section 6, PR 42), in the
+same instruction stream as the stage's matmuls, so a stage is fetched in
+groups of ``RUN_PAGES`` table entries: where a group names adjacent blocks
+``b, b+1, ...`` and lies whole inside the lane's cached pages it is ONE copy
+of ``pool[layer, b : b + R]``; any other group is a copy a page, as every
+page was. Which groups are runs is read off the block table in the jitted
+wrapper (:func:`table_runs`) and prefetched beside it; the allocator hands a
+request its blocks in ascending order so that most are (engine/blocks.py).
+And a full stage is waited for once, not a copy at a time.
 """
 
 from __future__ import annotations
@@ -43,13 +55,125 @@ def pages_per_stage(block: int, width: int, itemsize: int,
     return 1 << (p.bit_length() - 1)
 
 
-def _kernel(bt_ref, sl_ref, layer_ref,   # scalar prefetch: [B*maxB], [B], [1]
+# Table entries a group (R): a power of two, so that it divides every stage.
+# Chosen on the chip from 4 / 8 / 16 / 32 (scripts/microbench_decode.py
+# --latent; PERF.md section 6, PR 42): on tables that are all runs 16 is a
+# little ahead, on the tables an allocator's churn leaves 8 is.
+RUN_PAGES = 8
+
+
+def run_pages(pages: int) -> int:
+    """R for a stage of ``pages`` pages: RUN_PAGES, at most the stage."""
+    return min(RUN_PAGES, pages)
+
+
+def table_runs(block_tables: jnp.ndarray, seq_lens: jnp.ndarray, block: int,
+               group: int) -> jnp.ndarray:
+    """[B, ceil(maxB / group)] int32: 1 where the aligned group of ``group``
+    table entries names adjacent blocks in ascending order AND every page of
+    it holds cached rows of the lane (``seq_lens`` counts the current token,
+    which is not cached): nothing is fetched that a copy a page would not
+    fetch."""
+    B, width = block_tables.shape
+    n = -(-width // group)
+    table = jnp.pad(block_tables, ((0, 0), (0, n * group - width)),
+                    constant_values=-1).reshape(B, n, group)
+    adjacent = jnp.all(
+        table == table[..., :1] + jnp.arange(group, dtype=table.dtype),
+        axis=-1)
+    n_pages = -(-(seq_lens - 1) // block)
+    inside = jnp.arange(1, n + 1) * group <= n_pages[:, None]
+    return (adjacent & inside).astype(jnp.int32)
+
+
+def stage_fetch(bt_ref, run_ref, pool_hbm, tile, sem, *, lane, layer, n_pages,
+                max_blocks: int, group: int, zero_rest: bool):
+    """``start(s, slot)`` and ``wait(s, slot)`` for stage ``s`` of ``lane``'s
+    pages: the stage's live pages of ``pool_hbm[layer]`` into ``tile[slot]``
+    ([P, block, W]), a group of ``group`` table entries at a time, one copy
+    where ``run_ref`` (:func:`table_runs`, flattened) says the group is a
+    run, a copy a page where it does not and past the stage's last whole
+    group. With ``zero_rest`` the start also zeroes the stage's pages past
+    the lane's last (never fetched, and where rows are values too, 0 x
+    whatever VMEM held must be 0). A DMA semaphore counts bytes, so the wait
+    is for a region's bytes however many copies brought them: the whole slot
+    in one wait where the stage is full, a group and then a page at a time
+    in a lane's last stage. Loops, not unrolls: the engine traces a kernel's
+    body for every decode bucket."""
+    pages = tile.shape[1]
+    groups = -(-max_blocks // group)
+
+    def live_pages(s):
+        return jnp.minimum(pages, n_pages - s * pages)
+
+    def start(s, slot):
+        live = live_pages(s)
+
+        def page(i, carry):
+            blk = bt_ref[lane * max_blocks + s * pages + i]
+            pltpu.make_async_copy(pool_hbm.at[layer, blk], tile.at[slot, i],
+                                  sem.at[slot]).start()
+            return carry
+
+        def of_group(g, carry):
+            entry = s * pages + g * group
+
+            def run():
+                blk = bt_ref[lane * max_blocks + entry]
+                pltpu.make_async_copy(
+                    pool_hbm.at[layer, pl.ds(blk, group)],
+                    tile.at[slot, pl.ds(g * group, group)],
+                    sem.at[slot]).start()
+
+            def split():
+                jax.lax.fori_loop(g * group, (g + 1) * group, page, 0)
+
+            jax.lax.cond(run_ref[lane * groups + entry // group] > 0,
+                         run, split)
+            return carry
+
+        whole = live // group
+        jax.lax.fori_loop(0, whole, of_group, 0)
+        jax.lax.fori_loop(whole * group, live, page, 0)
+        if zero_rest:
+            def zero(i, carry):
+                tile[slot, i] = jnp.zeros(tile.shape[2:], tile.dtype)
+                return carry
+
+            jax.lax.fori_loop(live, pages, zero, 0)
+
+    def wait(s, slot):
+        live = live_pages(s)
+
+        def arrived(region):
+            pltpu.make_async_copy(region, region, sem.at[slot]).wait()
+
+        def by_parts():
+            def of_group(g, carry):
+                arrived(tile.at[slot, pl.ds(g * group, group)])
+                return carry
+
+            def page(i, carry):
+                arrived(tile.at[slot, i])
+                return carry
+
+            whole = live // group
+            jax.lax.fori_loop(0, whole, of_group, 0)
+            jax.lax.fori_loop(whole * group, live, page, 0)
+
+        jax.lax.cond(live == pages, lambda: arrived(tile.at[slot]), by_parts)
+
+    return start, wait
+
+
+def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
+            #                                      [B*maxB/R], [B], [1]
             q_ref, cur_ref,              # [1, H, W], [1, 1, W]
             pool_hbm,                    # [L, N, block, W] (ANY/HBM)
             out_ref,                     # [1, H, value_dim]
             tile, sem,
-            *, max_blocks: int, pages: int, block: int, value_dim: int,
-            scale: float):
+            *, max_blocks: int, pages: int, block: int, group: int,
+            value_dim: int, scale: float):
     b = pl.program_id(0)
     rows = pages * block
     q = q_ref[0]                                      # [H, W]
@@ -59,36 +183,9 @@ def _kernel(bt_ref, sl_ref, layer_ref,   # scalar prefetch: [B*maxB], [B], [1]
     n_stages = pl.cdiv(n_pages, pages)
     layer = layer_ref[0]
 
-    def _rows(i):
-        return pl.ds(pl.multiple_of(i * block, block), block)
-
-    def _each_page(s, slot, do):
-        """`do(copy)` for each page of stage `s` the lane holds; a loop, not
-        an unroll (the engine traces this body for every decode bucket)."""
-        def page(i, carry):
-            blk = bt_ref[b * max_blocks + s * pages + i]
-            do(pltpu.make_async_copy(pool_hbm.at[layer, blk],
-                                     tile.at[slot, _rows(i)], sem.at[slot]))
-            return carry
-
-        live = jnp.minimum(pages, n_pages - s * pages)
-        jax.lax.fori_loop(0, live, page, 0)
-        return live
-
-    def _start(s, slot):
-        live = _each_page(s, slot, lambda c: c.start())
-
-        def zero(i, carry):
-            # Never fetched, and the rows are values too: 0 x whatever VMEM
-            # held must be 0.
-            tile[slot, _rows(i)] = jnp.zeros((block, tile.shape[-1]),
-                                             tile.dtype)
-            return carry
-
-        jax.lax.fori_loop(live, pages, zero, 0)
-
-    def _wait(s, slot):
-        _each_page(s, slot, lambda c: c.wait())
+    _start, _wait = stage_fetch(
+        bt_ref, run_ref, pool_hbm, tile, sem, lane=b, layer=layer,
+        n_pages=n_pages, max_blocks=max_blocks, group=group, zero_rest=True)
 
     @pl.when(n_stages > 0)
     def _prologue():
@@ -113,7 +210,7 @@ def _kernel(bt_ref, sl_ref, layer_ref,   # scalar prefetch: [B*maxB], [B], [1]
 
         _wait(s, slot)
         logits = jax.lax.dot_general(
-            q, tile[slot], (((1,), (1,)), ((), ())),
+            q, tile[slot].reshape(rows, -1), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [H, rows]
         logits = jnp.where(col < cached_len - s * rows, logits, NEG_INF)
         new_m = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
@@ -121,7 +218,8 @@ def _kernel(bt_ref, sl_ref, layer_ref,   # scalar prefetch: [B*maxB], [B], [1]
         corr = jnp.exp(m - new_m)
         return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
                 acc * corr + jnp.dot(p.astype(tile.dtype),
-                                     tile[slot, :, :value_dim],
+                                     tile[slot, :, :, :value_dim].reshape(
+                                         rows, value_dim),
                                      preferred_element_type=jnp.float32))
 
     _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
@@ -147,15 +245,16 @@ def latent_paged_decode_attention_pallas(
     _, _, block, W = pages.shape
     maxB = block_tables.shape[1]
     n_pages = pages_per_stage(block, W, pages.dtype.itemsize, maxB)
+    group = run_pages(n_pages)
     pad = [(0, 0)] * 2 + [(0, W - Dk)]
     q = jnp.pad(q, pad).astype(pages.dtype)
     cur = jnp.pad(cur_row[:, None], pad).astype(pages.dtype)
 
     kernel = functools.partial(
-        _kernel, max_blocks=maxB, pages=n_pages, block=block,
+        _kernel, max_blocks=maxB, pages=n_pages, block=block, group=group,
         value_dim=value_dim, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
@@ -164,7 +263,7 @@ def latent_paged_decode_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, H, value_dim), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, n_pages * block, W), pages.dtype),
+            pltpu.VMEM((2, n_pages, block, W), pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -175,5 +274,6 @@ def latent_paged_decode_attention_pallas(
         interpret=interpret,
         # The op's name in a device trace, for whoever reduces one.
         name="mla_paged_decode_attention",
-    )(block_tables.reshape(-1), seq_lens,
+    )(block_tables.reshape(-1),
+      table_runs(block_tables, seq_lens, block, group).reshape(-1), seq_lens,
       jnp.asarray(layer, jnp.int32).reshape(1), q, cur, pages)

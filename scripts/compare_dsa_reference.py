@@ -120,6 +120,11 @@ def main(argv=None) -> int:
     ap.add_argument("--dtype", default="")
     ap.add_argument("--degrade", default="",
                     choices=("", "latent8", "index8"))
+    ap.add_argument("--shuffle-tables", action="store_true",
+                    help="hand the lanes the pool's blocks in a shuffled "
+                         "order (no runs of adjacent pages for the decode "
+                         "kernels to fetch as one copy); default ascending, "
+                         "all runs")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 
@@ -224,9 +229,15 @@ def main(argv=None) -> int:
         cache, _ = pages.alloc(geom, device=device, counted=True)
         seq = np.asarray(jax.random.randint(
             jax.random.key(seed + 1000), (max(lens) + K,), 0, 257))
-        tables = jnp.asarray(np.stack(
+        tables = np.stack(
             [1 + lane * per_seq + np.arange(per_seq) for lane in range(B)]
-        ).astype(np.int32))
+        ).astype(np.int32)
+        if args.shuffle_tables:
+            # No two neighbours of a table adjacent in the pool: the decode
+            # kernels fetch every group of entries a page at a time.
+            tables = np.random.default_rng(seed).permutation(
+                tables.reshape(-1)).reshape(tables.shape)
+        tables = jnp.asarray(tables)
         looked = [[] for _ in lens]        # (position, logits) a lane
         routes_of = [[] for _ in lens]     # [Le, tokens, k] pieces a lane
         picked_of = [np.zeros((geom.n_layers, n + K, n + K), bool)
@@ -312,6 +323,7 @@ def main(argv=None) -> int:
         least_shared = min(min(s) for s in shared_by_layer)
         first_shared = min(s[0] for s in shared_by_layer)
         line = {"seed": seed, "degrade": args.degrade or None,
+                "tables": "shuffled" if args.shuffle_tables else "runs",
                 "device": {"platform": device.platform,
                            "kind": device.device_kind},
                 "model": mcfg.name, "n_layers": mcfg.n_layers,

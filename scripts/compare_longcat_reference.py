@@ -111,6 +111,11 @@ def main(argv=None) -> int:
     ap.add_argument("--positions", type=int, default=8)
     ap.add_argument("--dtype", default="")
     ap.add_argument("--degrade", default="", choices=("", "cache8"))
+    ap.add_argument("--shuffle-tables", action="store_true",
+                    help="hand the lanes the pool's blocks in a shuffled "
+                         "order (no runs of adjacent pages for the decode "
+                         "kernels to fetch as one copy); default ascending, "
+                         "all runs")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 
@@ -208,9 +213,15 @@ def main(argv=None) -> int:
         seq = jax.random.randint(jax.random.key(seed + 1000),
                                  (longest + K,), 0, 257)
         per_seq = geom.max_blocks_per_seq
-        tables = jnp.asarray(np.stack(
+        tables = np.stack(
             [1 + lane * per_seq + np.arange(per_seq) for lane in range(B)]
-        ).astype(np.int32))
+        ).astype(np.int32)
+        if args.shuffle_tables:
+            # No two neighbours of a table adjacent in the pool: the decode
+            # kernels fetch every group of entries a page at a time.
+            tables = np.random.default_rng(seed).permutation(
+                tables.reshape(-1)).reshape(tables.shape)
+        tables = jnp.asarray(tables)
         plan_of = [plans[lane % len(plans)] for lane in range(B)]
         lens = [sum(p) for p in plan_of]
         counted, chosen_sum, choices = [0, 0], [0, 0], [0]
@@ -325,6 +336,7 @@ def main(argv=None) -> int:
                 [d["same_routes_as_its_length"] for d in decode_parts])),
             "ok": all(d["ok"] for d in decode_parts)}
         line = {"seed": seed, "degrade": args.degrade or None,
+                "tables": "shuffled" if args.shuffle_tables else "runs",
                 "device": {"platform": device.platform,
                            "kind": device.device_kind},
                 "model": mcfg.name, "n_layers": mcfg.n_layers,

@@ -27,12 +27,26 @@ plain update, scatter back): ms a call (one layer), the GB/s and the share of
 the chip's peak that the bytes the call needs (chipbench/kernels_ssm.py) come
 to, and how far the kernel's new state and y lie from the plain form's.
 
+``--latent`` times the latent family's paged decode walks alone instead
+(ops/pallas_latent_attention.py; for a block that selects rows, the two
+decode kernels of ops/pallas_dsa.py as well) at a benchmark configuration's
+widths and a point (lanes x context), over block tables whose groups of R
+entries name adjacent pages never (a shuffled pool), as often as the
+allocator leaves them under the cell's churn (``churned_tables``), and always
+(run share 0 / the churn's / 1), for R of ``--latent-groups``: ms a call, ns
+a page, the GB/s of the bytes the call needs, the least time by
+chipbench/kernels_dsa.py / kernels_mla.py, and how far the kernel lies from
+its plain form on that table. It is what RUN_PAGES was chosen from.
+
 Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--points 16x1000,16x300,8x300]
        python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
            [--moe-candidates] [--moe-check-seeds 0,1]
        python scripts/microbench_decode.py --ssm [--ssm-lanes 64,16,2]
            [--ssm-head-blocks 128,32]
+       python scripts/microbench_decode.py --latent
+           [--latent-config deepseek-v3.2-exp-cut] [--latent-points 32x9700]
+           [--latent-groups 4,8,16] [--latent-tables shuffled,churn,runs]
 """
 
 from __future__ import annotations
@@ -320,6 +334,230 @@ def ssm_main(args):
                 "device": device.device_kind}), flush=True)
 
 
+def churned_tables(lanes: int, max_model_len: int, prompts=(4096, 16384),
+                   outputs=(512, 1536), *, block: int = 16,
+                   turnovers: int = 500, warm_up: int = 100, seed: int = 0,
+                   allocator=None) -> list[list[int]]:
+    """The block tables an engine's allocator hands out under a closed
+    loop's churn, newest last: ``lanes`` requests in flight, each reserving
+    prompt + output at admission (prompts log-uniform, outputs uniform, as
+    the batch cells draw them; the defaults are longctx-reason's), every
+    complete prompt block hash-committed as the engine commits it, a random
+    lane ending and its successor admitted ``warm_up + turnovers`` times; the
+    tables of the last ``turnovers`` admissions. A count of what the
+    allocator does, not a measurement. The pool goes on fragmenting as it
+    ages (PERF.md section 7 (63)): how many of a table's groups are runs
+    depends on how many turnovers it has seen. ``allocator``: the class to
+    drive (default: the engine's, with the pool the engine would give it)."""
+    import math
+    import random
+
+    from llm_d_inference_scheduler_tpu.engine.blocks import (
+        PrefixCachingAllocator,
+    )
+
+    rng = random.Random(seed)
+    alloc = (allocator or PrefixCachingAllocator)(
+        1 + lanes * -(-max_model_len // block), block)
+    hashes = iter(range(1 << 62))
+
+    def admit():
+        prompt = int(math.exp(rng.uniform(*map(math.log, prompts))))
+        table = alloc.alloc(alloc.blocks_for_tokens(
+            prompt + rng.randint(*outputs)))
+        whole = table[:prompt // block]
+        alloc.commit_hashes(whole, [next(hashes) for _ in whole])
+        return table
+
+    live = [admit() for _ in range(lanes)]
+    handed = []
+    for turn in range(warm_up + turnovers):
+        lane = rng.randrange(lanes)
+        alloc.release(live[lane])
+        live[lane] = admit()
+        if turn >= warm_up:
+            handed.append(live[lane])
+    return handed
+
+
+def latent_main(args):
+    """The latent family's paged decode walks at ``--latent-config``'s
+    widths, over tables with runs of adjacent pages and without."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.kvcache import pages
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+    from llm_d_inference_scheduler_tpu.ops import (pallas_dsa,
+                                                   pallas_latent_attention,
+                                                   sparse_attention)
+    from llm_d_inference_scheduler_tpu.ops.attention import (
+        latent_paged_decode_attention,
+    )
+
+    kernels = _chipbench_kernels()      # puts chipbench/ on the path
+    import kernels_dsa
+    import kernels_mla
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           args.latent_config + ".json")) as f:
+        m = config_from_hf(types.SimpleNamespace(**json.load(f)),
+                           name=args.latent_config)
+    interpret = args.latent_interpret
+    device = jax.devices()[0]
+    dt = jnp.dtype(m.dtype)
+    block, max_len = m.kv_block_size, args.latent_max_model_len
+    width = -(-max_len // block)
+    H, Dk, value = m.n_heads, m.latent_dim, m.kv_lora_rank
+    Hi, Di = m.index_n_heads, m.index_dim
+    kw = dict(value_dim=value, scale=0.1)
+    churn = [tuple(map(int, r.split("-")))
+             for r in (args.latent_prompts, args.latent_outputs)]
+    latent = pallas_latent_attention.latent_paged_decode_attention_pallas
+    masked = pallas_dsa.sparse_latent_paged_decode_attention_pallas
+    scores = pallas_dsa.index_scores_paged_pallas
+
+    def table_of(kind, lanes, n_pages):
+        ids = 1 + np.arange(lanes * width)
+        if kind == "runs":
+            return ids.reshape(lanes, width)
+        if kind == "shuffled":
+            return np.random.default_rng(args.latent_seed).permutation(
+                ids).reshape(lanes, width)
+        # The churn's newest tables that reach the point's context (not in
+        # flight together: two lanes may read one page, which a walk cannot
+        # feel).
+        reach = [t for t in churned_tables(lanes, max_len, *churn, block=block,
+                                           seed=args.latent_seed)
+                 if len(t) >= n_pages][-lanes:]
+        if len(reach) < lanes:
+            raise SystemExit(
+                f"the churn handed out too few tables of {n_pages} pages")
+        table = np.zeros((lanes, width), np.int32)
+        for row, t in zip(table, reach):
+            row[:len(t)] = t
+        return table
+
+    def timed(fn, *operands):
+        """Seconds a call of ``fn(layer, *operands)``, the calls chained in
+        one program over the pool's two layers in turn."""
+        n = args.latent_iters
+
+        def chain(*operands):
+            def body(acc, layer):
+                out = fn(layer, *operands)
+                return acc + out.astype(jnp.float32).sum(), None
+
+            return jax.lax.scan(body, jnp.float32(0),
+                                jnp.arange(n, dtype=jnp.int32) % 2)[0]
+
+        return timeit(jax.jit(chain), *operands, iters=3) / n * 1e-3
+
+    def rel_err(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    served_with = pallas_latent_attention.RUN_PAGES
+    try:
+        for lanes, ctx in [map(int, pt.split("x"))
+                           for pt in args.latent_points.split(",")]:
+            keys = jax.random.split(jax.random.key(args.latent_seed), 7)
+            n_pages = -(-ctx // block)
+            n_blocks = 1 + lanes * width
+            pool = jax.random.normal(
+                keys[0], (2, n_blocks, block, -(-Dk // 128) * 128), dt)
+            pool = pool.at[..., Dk:].set(0)
+            q = jax.random.normal(keys[1], (lanes, H, Dk), dt)
+            cur = jax.random.normal(keys[2], (lanes, Dk), dt)
+            seq_lens = jnp.full((lanes,), ctx + 1, jnp.int32)
+            if m.index_topk:
+                keys_pool = jax.random.normal(
+                    keys[3], (2, n_blocks, block, Di), dt)
+                q_idx = jax.random.normal(keys[4], (lanes, Hi, Di), dt)
+                w_idx = jax.random.normal(keys[5], (lanes, Hi), jnp.float32)
+                # A selection as the indexer leaves it: index_topk rows of
+                # the context, anywhere.
+                chosen = jax.vmap(lambda k: jax.random.permutation(k, ctx))(
+                    jax.random.split(keys[6], lanes))[:, :m.index_topk]
+                keep = jnp.zeros((lanes, width * block), bool).at[
+                    jnp.arange(lanes)[:, None], chosen].set(True)
+                cur_keep = jnp.ones((lanes,), bool)
+
+            # name -> (the jitted kernel, a call of it at a layer, the
+            # operands the chained program takes, what a call needs, its
+            # plain form at a layer and a table)
+            walks = {"mla_paged_decode_attention": (
+                latent,
+                lambda layer, q, pool, bt, cur: latent(
+                    q, pool, layer, bt, seq_lens, cur, interpret=interpret,
+                    **kw),
+                lambda bt: (q, pool, bt, cur),
+                kernels_mla.latent_attention_decode(
+                    lanes * ctx, lanes, H, Dk, value),
+                lambda bt: latent_paged_decode_attention(
+                    q, pool, 1, bt, seq_lens, cur, **kw))}
+            if m.index_topk:
+                walks["dsa_paged_decode_attention"] = (
+                    masked,
+                    lambda layer, q, pool, bt, cur: masked(
+                        q, pool, layer, bt, seq_lens, cur, keep, cur_keep,
+                        interpret=interpret, **kw),
+                    lambda bt: (q, pool, bt, cur),
+                    kernels_dsa.selected_attention_decode(
+                        lanes * ctx, lanes, m.index_topk, H, Dk, value),
+                    lambda bt: (
+                        sparse_attention.sparse_latent_paged_decode_attention(
+                            q, pool, jnp.asarray(1), bt, seq_lens, cur, keep,
+                            cur_keep, **kw)))
+                walks["dsa_index_scores_decode"] = (
+                    scores,
+                    lambda layer, q_idx, w_idx, keys_pool, bt: scores(
+                        q_idx, w_idx, keys_pool, layer, bt, seq_lens,
+                        interpret=interpret)[:, :ctx],
+                    lambda bt: (q_idx, w_idx, keys_pool, bt),
+                    kernels_dsa.indexer_decode(lanes * ctx, lanes, Hi, Di),
+                    lambda bt: sparse_attention.index_scores(
+                        q_idx[:, None], w_idx[:, None],
+                        pages.read_rows(keys_pool, 1, bt))[:, 0, :ctx])
+
+            for kind in args.latent_tables.split(","):
+                bt = jnp.asarray(table_of(kind, lanes, n_pages), jnp.int32)
+                plain = {name: walk[4](bt) for name, walk in walks.items()}
+                for R in map(int, args.latent_groups.split(",")):
+                    pallas_latent_attention.RUN_PAGES = R
+                    share = np.asarray(pallas_latent_attention.table_runs(
+                        bt, seq_lens, block, R))[:, :n_pages // R].mean()
+                    for name, (kernel, call, operands, need, _) in (
+                            walks.items()):
+                        kernel.clear_cache()    # R is read at the trace
+                        call_s = timed(call, *operands(bt))
+                        least, bound = (
+                            (None, None) if interpret else
+                            kernels.roofline_seconds(need, device.device_kind))
+                        print(json.dumps({
+                            "component": name, "R": R, "table": kind,
+                            "run_share_pct": round(100 * float(share), 1),
+                            "lanes": lanes, "ctx": ctx,
+                            "ms_per_call": round(call_s * 1e3, 4),
+                            "ns_per_page": round(
+                                call_s * 1e9 / (lanes * n_pages), 2),
+                            "needed_GBps": round(
+                                need["bytes"] / call_s / 1e9, 1),
+                            "least_ms": least and round(least * 1e3, 4),
+                            "bound": bound,
+                            "roofline_pct": least and round(
+                                100 * least / call_s, 2),
+                            "max_err_vs_plain": rel_err(
+                                call(1, *operands(bt)), plain[name]),
+                            "device": device.device_kind}), flush=True)
+    finally:
+        pallas_latent_attention.RUN_PAGES = served_with
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen3-4b")
@@ -346,6 +584,28 @@ def main(argv=None):
     ap.add_argument("--ssm-interpret", action="store_true",
                     help="interpret the kernel: rehearses the control flow "
                          "on the CPU; its times mean nothing")
+    ap.add_argument("--latent", action="store_true",
+                    help="time the latent family's paged decode walks alone "
+                         "instead, over tables with runs and without")
+    ap.add_argument("--latent-config", default="deepseek-v3.2-exp-cut",
+                    help="a file of chipbench/configs: the widths")
+    ap.add_argument("--latent-points", default="32x9700",
+                    help="lanes x context tokens a lane, comma-separated")
+    ap.add_argument("--latent-max-model-len", type=int, default=18432)
+    ap.add_argument("--latent-groups", default="4,8,16",
+                    help="values of RUN_PAGES to time")
+    ap.add_argument("--latent-tables", default="shuffled,churn,runs",
+                    help="block tables: a shuffled pool (no runs), the "
+                         "allocator's under the cell's churn, ascending")
+    ap.add_argument("--latent-prompts", default="4096-16384",
+                    help="the churn's prompts (log-uniform)")
+    ap.add_argument("--latent-outputs", default="512-1536",
+                    help="the churn's outputs (uniform)")
+    ap.add_argument("--latent-iters", type=int, default=20)
+    ap.add_argument("--latent-seed", type=int, default=0)
+    ap.add_argument("--latent-interpret", action="store_true",
+                    help="interpret the kernels: rehearses the control flow "
+                         "on the CPU; its times mean nothing")
     ap.add_argument("--points", default="16x1000,16x300,8x300",
                     help="lanes x context tokens a lane, comma-separated")
     ap.add_argument("--max-model-len", type=int, default=1024)
@@ -364,6 +624,8 @@ def main(argv=None):
         return moe_main(args)
     if args.ssm:
         return ssm_main(args)
+    if args.latent:
+        return latent_main(args)
 
     from llm_d_inference_scheduler_tpu.engine.sampling import sample_tokens
     from llm_d_inference_scheduler_tpu.kvcache import pages

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import latent_table_cases as table_cases
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
 from llm_d_inference_scheduler_tpu.kvcache import pages, state
 from llm_d_inference_scheduler_tpu.models import configs, family, mla
@@ -363,6 +364,104 @@ def test_sparse_decode_kernel_matches_the_plain_form_at_its_extremes(
         rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("case", table_cases.CASES)
+def test_paged_index_scores_kernel_over_tables_with_runs_and_without(
+        case, monkeypatch):
+    """The indexer's decode walk against the plain form over every kind of
+    table (tests/latent_table_cases.py), at a lane's cached rows; stages past
+    a lane's length read zero."""
+    c = table_cases
+    monkeypatch.setattr(pallas_latent_attention, "STAGE_VMEM_BYTES",
+                        c.STAGE_VMEM_BYTES)
+    Hi, Di = 4, 128
+    assert pallas_dsa.pages_per_stage(c.BLOCK, Di, 4, c.WIDTH) == c.STAGE
+    tables, lens = c.tables(case, pallas_dsa.run_pages(c.STAGE))
+    pool = jnp.asarray(c.pool_under(tables, lens, Di, Di, seed=31))
+    ks = jax.random.split(jax.random.key(32), 2)
+    q = jax.random.normal(ks[0], (len(lens), Hi, Di), jnp.float32)
+    w = jax.random.normal(ks[1], (len(lens), Hi), jnp.float32)
+    got = np.asarray(pallas_dsa.index_scores_paged_pallas(
+        q, w, pool, jnp.int32(1), jnp.asarray(tables), jnp.asarray(lens),
+        interpret=True))
+    want = np.asarray(sparse_attention.index_scores(
+        q[:, None], w[:, None],
+        pages.read_rows(pool, 1, jnp.asarray(tables))))[:, 0]
+    assert got.shape == want.shape == (len(lens), c.WIDTH * c.BLOCK)
+    for lane, n in enumerate(np.maximum(lens - 1, 0)):
+        np.testing.assert_allclose(got[lane, :n], want[lane, :n],
+                                   rtol=1e-4, atol=1e-3)
+        past = -(-n // (c.STAGE * c.BLOCK)) * c.STAGE * c.BLOCK
+        assert not got[lane, past:].any()
+
+
+@pytest.mark.parametrize("case", table_cases.CASES)
+def test_sparse_decode_kernel_over_tables_with_runs_and_without(
+        case, monkeypatch):
+    """The masked decode walk against the plain form over every kind of
+    table, a third of the rows selected: which groups it takes as one copy
+    is what was counted by hand, and rows past a lane's length weigh
+    nothing."""
+    c = table_cases
+    monkeypatch.setattr(pallas_latent_attention, "STAGE_VMEM_BYTES",
+                        c.STAGE_VMEM_BYTES)
+    H, Dk, value, W = 3, 72, 40, 128
+    assert pallas_dsa.pages_per_stage(c.BLOCK, W, 4, c.WIDTH) == c.STAGE
+    group = pallas_dsa.run_pages(c.STAGE)
+    tables, lens = c.tables(case, group)
+    runs = np.asarray(pallas_dsa.table_runs(
+        jnp.asarray(tables), jnp.asarray(lens), c.BLOCK, group))
+    np.testing.assert_array_equal(runs, c.runs_by_hand(tables, lens, group))
+    pool = jnp.asarray(c.pool_under(tables, lens, Dk, W, seed=41))
+    ks = jax.random.split(jax.random.key(42), 3)
+    B = len(lens)
+    q = jax.random.normal(ks[0], (B, H, Dk), jnp.float32)
+    cur = jax.random.normal(ks[1], (B, Dk), jnp.float32)
+    keep = jax.random.bernoulli(ks[2], 0.3, (B, c.WIDTH * c.BLOCK))
+    cur_keep = jnp.asarray([True, False, True])
+    kw = dict(value_dim=value, scale=0.2)
+    want = sparse_attention.sparse_latent_paged_decode_attention(
+        q, pool, jnp.asarray(1), jnp.asarray(tables),
+        jnp.maximum(jnp.asarray(lens), 1), cur, keep, cur_keep, **kw)
+    got = pallas_dsa.sparse_latent_paged_decode_attention_pallas(
+        q, pool, jnp.asarray(1), jnp.asarray(tables), jnp.asarray(lens), cur,
+        keep, cur_keep, **kw, interpret=True)
+    assert np.abs(np.asarray(want)).max() < 10
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_the_latent_microbenchmark_rehearses_on_the_cpu(capsys, monkeypatch):
+    """scripts/microbench_decode.py --latent at the cell's widths and a small
+    point, kernels interpreted: a line a (kernel, table, R), every kernel
+    within bf16's rounding of its plain form on tables with runs, without,
+    and as the allocator's churn leaves them."""
+    spec = importlib.util.spec_from_file_location(
+        "microbench_decode", REPO / "scripts" / "microbench_decode.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    # The script's main points JAX's compile cache at the checkout: not from
+    # a test worker.
+    monkeypatch.setattr("llm_d_inference_scheduler_tpu.utils.compile_cache."
+                        "configure_compile_cache", lambda: "")
+    bench.main(["--latent", "--latent-interpret", "--latent-points", "2x2300",
+                "--latent-max-model-len", "4096", "--latent-prompts",
+                "1500-3500", "--latent-outputs", "100-500",
+                "--latent-groups", "8", "--latent-iters", "1"])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert {(ln["component"], ln["table"]) for ln in lines} == {
+        (k, t) for k in ("mla_paged_decode_attention",
+                         "dsa_paged_decode_attention",
+                         "dsa_index_scores_decode")
+        for t in ("shuffled", "churn", "runs")}
+    for ln in lines:
+        assert ln["R"] == 8 and ln["max_err_vs_plain"] < 0.02, ln
+        assert ln["roofline_pct"] is None      # no chip, no share of a peak
+    share = {ln["table"]: ln["run_share_pct"] for ln in lines}
+    assert share["shuffled"] == 0 and share["runs"] == 100
+    assert 30 < share["churn"] < 100
+
+
 # ---------- the router, the rotary table, the shares ----------
 
 def test_grouped_router_matches_the_reference_and_one_group_is_unchanged():
@@ -575,6 +674,7 @@ def test_engine_serves_through_windows_both_pools_and_the_prefix_cache(served):
     assert settings["index_token_bytes"] == 16 * 4
     assert settings["index_pool_bytes"] == 3 * 17 * 16 * 16 * 4
     assert settings["index_scores"] == "kernel_interpret"
+    assert settings["kv_run_pages"] == pallas_latent_attention.RUN_PAGES
     assert settings["prefix_caching"] and settings["pallas_attention"]
 
 

@@ -21,11 +21,14 @@ from llm_d_inference_scheduler_tpu.kvcache import pages
 from llm_d_inference_scheduler_tpu.models import configs, family, llama, mla
 from llm_d_inference_scheduler_tpu.models.convert_hf import (
     config_from_hf, convert_state_dict)
-from llm_d_inference_scheduler_tpu.ops import pallas_moe
+import latent_table_cases as table_cases
+from llm_d_inference_scheduler_tpu.ops import (pallas_latent_attention,
+                                               pallas_moe)
 from llm_d_inference_scheduler_tpu.ops.attention import (
     latent_paged_decode_attention)
 from llm_d_inference_scheduler_tpu.ops.pallas_latent_attention import (
-    latent_paged_decode_attention_pallas, pages_per_stage)
+    latent_paged_decode_attention_pallas, pages_per_stage, run_pages,
+    table_runs)
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 CFG = dataclasses.replace(configs.get_config("tiny-mla"), dtype="float32")
@@ -253,6 +256,110 @@ def test_latent_kernel_matches_the_gather_at_ragged_lengths(
                         (H, value_dim)), rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("case", table_cases.CASES)
+def test_latent_kernel_fetches_runs_of_adjacent_pages_as_one_copy(
+        case, monkeypatch):
+    """The kernel against the gather over every kind of table
+    (tests/latent_table_cases.py): which groups it takes as one copy is what
+    was counted by hand, and the result is the gather's either way. Rows
+    past a lane's length and pages it does not own hold large values: they
+    weigh nothing."""
+    c = table_cases
+    monkeypatch.setattr(pallas_latent_attention, "STAGE_VMEM_BYTES",
+                        c.STAGE_VMEM_BYTES)
+    assert pages_per_stage(c.BLOCK, 128, 4, c.WIDTH) == c.STAGE
+    group = run_pages(c.STAGE)
+    tables, lens = c.tables(case, group)
+    runs = np.asarray(table_runs(jnp.asarray(tables), jnp.asarray(lens),
+                                 c.BLOCK, group))
+    np.testing.assert_array_equal(runs, c.runs_by_hand(tables, lens, group))
+    if group == 8:
+        assert runs.sum(axis=1).tolist() == c.RUNS[case]
+    H, width, value_dim = 3, 72, 40
+    key = jax.random.split(jax.random.key(21), 2)
+    pool = c.pool_under(tables, lens, width, 128, seed=21)
+    q = jax.random.normal(key[0], (len(lens), H, width), jnp.float32)
+    cur = jax.random.normal(key[1], (len(lens), width), jnp.float32)
+    args = (q, jnp.asarray(pool), 1, jnp.asarray(tables),
+            jnp.maximum(jnp.asarray(lens), 1), cur)
+    kw = dict(value_dim=value_dim, scale=0.11)
+    want = latent_paged_decode_attention(*args, **kw)
+    got = latent_paged_decode_attention_pallas(
+        *args[:4], jnp.asarray(lens), cur, interpret=True, **kw)
+    assert np.abs(np.asarray(want)).max() < 10
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", table_cases.CASES)
+def test_a_stages_fetch_waits_for_every_byte_it_starts(case):
+    """stage_fetch alone, driven as the three kernels drive it (the next
+    stage started before this one is waited for, two slots): a DMA semaphore
+    counts bytes, the wait is for a region's bytes however many copies bring
+    them, and after a lane's last stage both semaphores read zero again —
+    no copy is left in flight, none is waited for twice. (Pages of 16 x 8
+    values: the interpreter's semaphore is an int16.) What the stages held
+    is the lane's cached pages and zeros."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c = table_cases
+    group, W = run_pages(c.STAGE), 8
+    tables, lens = c.tables(case, group)
+    pool = c.pool_under(tables, lens, W, W, seed=5)
+
+    def kernel(bt_ref, run_ref, sl_ref, pool_hbm, sems_ref, sum_ref, tile,
+               sem):
+        b = pl.program_id(0)
+        n_pages = pl.cdiv(sl_ref[b] - 1, c.BLOCK)
+        n_stages = pl.cdiv(n_pages, c.STAGE)
+        start, wait = pallas_latent_attention.stage_fetch(
+            bt_ref, run_ref, pool_hbm, tile, sem, lane=b, layer=1,
+            n_pages=n_pages, max_blocks=c.WIDTH, group=group, zero_rest=True)
+
+        @pl.when(n_stages > 0)
+        def _prologue():
+            start(0, 0)
+
+        def stage(s, acc):
+            slot = jax.lax.rem(s, 2)
+
+            @pl.when(s + 1 < n_stages)
+            def _next():
+                start(s + 1, 1 - slot)
+
+            wait(s, slot)
+            return acc + jnp.sum(tile[slot])
+
+        sum_ref[0, 0] = jax.lax.fori_loop(0, n_stages, stage, jnp.float32(0))
+        sems_ref[0, 0] = pltpu.semaphore_read(sem.at[0])
+        sems_ref[0, 1] = pltpu.semaphore_read(sem.at[1])
+
+    def in_smem(width):
+        return pl.BlockSpec((1, width), lambda b, *_: (b, 0),
+                            memory_space=pltpu.SMEM)
+
+    sems, sums = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(len(lens),),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[in_smem(2), in_smem(1)],
+            scratch_shapes=[pltpu.VMEM((2, c.STAGE, c.BLOCK, W), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((len(lens), 2), jnp.int32),
+                   jax.ShapeDtypeStruct((len(lens), 1), jnp.float32)],
+        interpret=True,
+    )(jnp.asarray(tables).reshape(-1),
+      table_runs(jnp.asarray(tables), jnp.asarray(lens), c.BLOCK,
+                 group).reshape(-1), jnp.asarray(lens), jnp.asarray(pool))
+    assert not np.asarray(sems).any()
+    for lane, n in enumerate(lens):
+        owned = tables[lane, :-(-max(int(n) - 1, 0) // c.BLOCK)]
+        np.testing.assert_allclose(float(sums[lane, 0]),
+                                   pool[1, owned].sum(), rtol=1e-4)
+
+
 def test_latent_stage_comes_from_the_vmem_budget():
     assert pages_per_stage(16, 640, 2, 512) == 32     # the cell's shapes
     assert pages_per_stage(16, 640, 2, 8) == 8        # never past the table
@@ -448,7 +555,13 @@ def test_engine_serves_through_chunked_prefill_prefix_cache_and_kernel(served):
                 for m in eng.telemetry.registry.collect()
                 for s in m.samples
                 if s.name == "jetstream:mla_attention_tokens_total"}
-            return first, again, counted, eng.describe()["settings"]
+            groups = {
+                s.labels["kind"]: s.value
+                for m in eng.telemetry.registry.collect()
+                for s in m.samples
+                if s.name == "jetstream:kv_table_groups_total"}
+            return (first, again, counted,
+                    dict(eng.describe()["settings"], table_groups=groups))
         finally:
             await eng.stop()
 
@@ -469,3 +582,10 @@ def test_engine_serves_through_chunked_prefill_prefix_cache_and_kernel(served):
     # count no choices (models/mla.py).
     assert (settings["kv_layers"], settings["experts_first"],
             settings["experts_held"], settings["zero_experts"]) == (3, 0, 8, 0)
+    # Tables counted at admission by the groups the latent kernel fetches:
+    # the long prompt's ten pages ascend (a run of 8, a short group), the
+    # short one's three are a short group, and the rerun's nine borrowed
+    # pages are the long prompt's first nine, in its order.
+    assert settings["kv_run_pages"] == settings_c["kv_run_pages"] == 8
+    assert settings["table_groups"] == {"run": 2.0, "split": 3.0}
+    assert settings_c["table_groups"] == {"run": 2.0, "split": 3.0}
