@@ -97,12 +97,15 @@ class EngineConfig:
     # real verification on (router/tlsutil.py client_verify).
     client_insecure_skip_verify: bool = True
     client_ca_cert_path: str = ""
-    # Decode steps fused into one device dispatch (lax.scan over the decode
-    # step + sampler on device). Amortizes per-dispatch latency and the
-    # per-chunk readback at the cost of bursty token streaming and
+    # The most decode steps fused into one device dispatch (a loop over the
+    # decode step + sampler on device, its step count an operand). Amortizes
+    # per-dispatch latency, the per-chunk readback and the device's
+    # once-a-chunk work at the cost of bursty token streaming, of
     # up-to-(chunk-1) wasted steps for sequences that hit a stop condition
-    # mid-chunk. TTFT is unaffected (prefill emits the first token). 1 =
-    # classic per-step decode. Not yet A/B'd on a chip attached to the host.
+    # mid-chunk, and of an arrival's wait for the chunk that runs: the loop
+    # dispatches half of it where a slot is open and nobody waits
+    # (core.TpuEngine._chunk_steps; PERF.md section 6, PR 43, has both
+    # lengths on the chip). 1 = classic per-step decode.
     decode_chunk: int = 8
     # Pallas paged-attention decode kernel. None = auto: enabled on a real
     # TPU backend for unsharded engines whose head_dim is lane-aligned

@@ -71,6 +71,16 @@ HOLD_MARGIN_S = 0.020
 # A shape's expected device time is the least of its last clean periods: a
 # period can only read longer than the chunk took (a late read, a late chunk).
 HOLD_PERIODS = 8
+# A short chunk is decode_chunk // SHORT_CHUNK_DIV steps (_chunk_steps: a slot
+# open, nobody waiting, the host's work a chunk fitting inside it). Sized on
+# the chip with the length fixed by --decode-chunk (qwen3-4b.chat-steady,
+# 3.6 requests/s on 16 lanes, three seeds each; PERF.md section 6, PR 43):
+# at 8 steps ttft_p50_ms 115.7, tpot_p95_ms 18.75, a chunk 111.7 ms; at 4
+# steps 84.9-86.6, 18.55-18.84 (-1.1 to +0.4%), 57.4 ms; at 2 steps
+# 63.7-66.3, 19.52-19.85 (+4.1 to +5.8%), 30.5 ms. A quarter costs a running
+# stream more than the 4% it was allowed (the chunk's once-a-chunk work,
+# four times a period), so half it is.
+SHORT_CHUNK_DIV = 2
 
 
 def _tcp_preflight(address: str, timeout: float = 2.0) -> None:
@@ -155,6 +165,7 @@ class _Slot:
 class _Chunk:
     """A decode chunk dispatched and not yet read."""
     toks: Any                        # [K, B] sampled tokens, on the device
+    steps: int                       # the rows of toks that are its own
     lanes: list[tuple[int, _Slot]]   # lane -> (slot index, the slot it held)
     t0: float                        # the loop's clock at dispatch
     timed: bool                      # its shape was built before: observe it
@@ -500,14 +511,14 @@ class TpuEngine:
 
         self._jit_import = jax.jit(kv_import, donate_argnums=(0, 1))
         # Every slot's newest sampled token, on the device: a prefill leaves
-        # its first token here and a chunk its last row, so the next chunk is
+        # its first token here and a chunk its last step's, so the next chunk is
         # dispatched before either has reached the host. Index max_batch is
         # nobody's (padding lanes, samples nobody decodes from): out of
         # range, so it reads 0 and a write to it is dropped.
         self._slot_tokens = self._put(np.zeros((cfg.max_batch,), np.int32))
 
-        def keep_tokens(table, slots, toks):    # toks [N], or [K, N]: last row
-            return table.at[slots].set(toks.reshape(-1, slots.size)[-1],
+        def keep_tokens(table, slots, toks, row):   # toks [N], or [K, N]
+            return table.at[slots].set(toks.reshape(-1, slots.size)[row],
                                        mode="drop")
 
         def slot_tokens(table, slots):
@@ -529,12 +540,16 @@ class TpuEngine:
         # What _hold_until reckons the end of the chunk in flight from: when
         # the first tokens of the prefills queued ahead of it were read (it
         # started no sooner), the device calls made since the last chunk went
-        # out (they sit ahead of the next), and for every decode shape the
-        # periods of its last chunks that had no such call ahead of them:
-        # their device time, as _land_chunk measured it.
+        # out (they sit ahead of the next), and for every decode shape and
+        # length in steps the periods of its last chunks that had no such
+        # call ahead of them: their device time, as _land_chunk measured it.
+        # Beside them what the loop itself did in each of the last periods
+        # (every phase but the waits), which _chunk_steps reckons with.
         self._first_tokens_read = 0.0
         self._calls_since_chunk = 0
-        self._chunk_times: dict[str, collections.deque] = {}
+        self._chunk_times: dict[tuple[str, int], collections.deque] = {}
+        self._host_work: collections.deque = collections.deque(
+            maxlen=HOLD_PERIODS)
         # The period now running (from the last readback, or from the
         # dispatch of a chunk that went out alone): seconds by phase, prefills
         # finalized, whether a shape ran for the first time in it; judged at
@@ -653,40 +668,47 @@ class TpuEngine:
     # ---- jitted bodies -------------------------------------------------
 
     def _decode_chunk_impl(self, params, tokens, positions, k_pages, v_pages,
-                           block_tables, key, temps, top_k, top_p):
-        """``decode_chunk`` fused decode+sample steps in ONE dispatch.
+                           block_tables, key, temps, top_k, top_p, n_steps):
+        """``n_steps`` fused decode+sample steps in ONE dispatch, of at most
+        ``decode_chunk``: the count is an operand, so a bucket has one
+        program whatever lengths the loop asks for (_chunk_steps).
 
-        A ``lax.scan`` on device: each step runs the paged decode step and
-        samples the next token, which feeds the following step. Returns all
-        sampled tokens [K, B]; the host applies them per-lane up to each
-        request's stop condition and discards the overshoot. A lane that ends
-        on a stop token or an abort has the next chunk in flight already
-        (_step), so the overshoot reaches up to 2K - 1 positions past the
-        request's end. Its KV writes land in the sequence's own allocated
-        tail, or past it in the table's padding (the trash block; a table
-        narrowed by decode_ctx_buckets clamps to the row's last entry, which
-        is one of the two) — never in a block of another live request, and
-        never in a block the prefix cache holds (those are whole blocks of
-        the prompt, below the first decoded position). Whatever reuses the
-        freed blocks is dispatched later on the same in-order device stream
-        and overwrites them. The input tokens come from the device
-        (_slot_tokens), so the host neither reads nor books a chunk before
-        it dispatches the next: one dispatch a K tokens, and no idle device
-        between chunks (PERF.md section 6, PR 33, has what that is worth on
-        the chip)."""
-        keys = jax.random.split(key, self.cfg.decode_chunk)
+        A loop on device: each step runs the paged decode step and samples
+        the next token, which feeds the following step. Returns the sampled
+        tokens [K, B], rows past ``n_steps`` nobody's (step i draws with
+        ``split(key, K)[i]`` whatever the count, so a chunk of K steps gives
+        what the ``lax.scan`` it was gave); the host applies them per-lane
+        up to each request's stop condition and discards the overshoot. A
+        lane that ends on a stop token or an abort has the next chunk in
+        flight already (_step), so the overshoot reaches up to 2K - 1
+        positions past the request's end. Its KV writes land in the
+        sequence's own allocated tail, or past it in the table's padding
+        (the trash block; a table narrowed by decode_ctx_buckets clamps to
+        the row's last entry, which is one of the two) — never in a block of
+        another live request, and never in a block the prefix cache holds
+        (those are whole blocks of the prompt, below the first decoded
+        position). Whatever reuses the freed blocks is dispatched later on
+        the same in-order device stream and overwrites them. The input
+        tokens come from the device (_slot_tokens), so the host neither
+        reads nor books a chunk before it dispatches the next: one dispatch
+        a chunk, and no idle device between chunks (PERF.md section 6, PR
+        33, has what that is worth on the chip)."""
+        K = self.cfg.decode_chunk
+        keys = jax.random.split(key, K)
 
-        def step(carry, k_step):
-            tokens, positions, k_pages, v_pages = carry
+        def step(i, carry):
+            tokens, positions, k_pages, v_pages, toks = carry
             logits, k_pages, v_pages = self.model.decode_step(
                 params, self._model_for(tokens.size), tokens, positions,
                 k_pages, v_pages, block_tables,
                 attention_fn=self._decode_attention)
-            nxt = sample_tokens(logits, k_step, temps, top_k, top_p)
-            return (nxt, positions + 1, k_pages, v_pages), nxt
+            nxt = sample_tokens(logits, keys[i], temps, top_k, top_p)
+            return (nxt, positions + 1, k_pages, v_pages,
+                    toks.at[i].set(nxt))
 
-        (_, _, k_pages, v_pages), toks = jax.lax.scan(
-            step, (tokens, positions, k_pages, v_pages), keys)
+        *_, k_pages, v_pages, toks = jax.lax.fori_loop(
+            0, n_steps, step, (tokens, positions, k_pages, v_pages,
+                               jnp.zeros((K, tokens.size), tokens.dtype)))
         return toks, k_pages, v_pages
 
     def _bind_state_form(self, platform: str) -> None:
@@ -722,10 +744,10 @@ class TpuEngine:
         """Book what a program of a selecting block puts through it
         (jetstream:dsa_*): its query tokens' contexts, from the positions the
         host already holds. Real lanes and prompt tokens alone; a decode
-        chunk's steps are its lanes' next decode_chunk positions."""
+        chunk's steps are its lanes' next positions, one a step."""
         if op[0] == "decode":
             first = args["positions"][args["slots"] < self.cfg.max_batch] + 1
-            n = np.full(first.shape, self.cfg.decode_chunk)
+            n = np.full(first.shape, args["steps"])
         elif op[0] == "prefill":
             n = args["seq_len"]
             first = np.ones_like(n)
@@ -1142,7 +1164,8 @@ class TpuEngine:
                     slots=np.full((nb,), B, np.int32),
                     positions=np.zeros((nb,), np.int32),
                     tables=np.zeros((nb, w), np.int32),
-                    warm=True, **self._sample_np([_DUMMY_REQ] * nb)))
+                    steps=self.cfg.decode_chunk, warm=True,
+                    **self._sample_np([_DUMMY_REQ] * nb)))
         log.info("engine warm-up compiled prefill/decode/sample in %.1fs",
                  time.monotonic() - t0)
 
@@ -1274,29 +1297,72 @@ class TpuEngine:
                     and s.ahead and self._ends_in_flight(s)]
         return empty, vacating
 
+    def _room_for_arrival(self) -> bool:
+        """Whether a request that arrived now would be placed at once, which
+        is what holding a chunk back (_hold_until) and cutting it short
+        (_chunk_steps) are both for: a slot is open, nobody waits for it
+        (nor anything else for the loop), and no slot has windows still to
+        write."""
+        if any(s is not None and s.prefilling for s in self.slots):
+            return False
+        with self._cond:
+            if self._work_arrived():
+                return False
+        return any(self._open_slots())
+
+    def _chunk_time(self, shape: str, steps: int) -> float | None:
+        """What a chunk of this shape and length is reckoned to take on the
+        device: the least of its last clean periods; pro rata from the same
+        shape at another length until it has one of its own (a shorter
+        chunk bears the chunk's fixed costs too, so reckoned from a longer
+        one it reads early, the way the hold errs); None where the shape was
+        never timed."""
+        times = self._chunk_times.get((shape, steps))
+        if times:
+            return min(times)
+        return next((min(times) * steps / n for (sh, n), times
+                     in self._chunk_times.items() if sh == shape and times),
+                    None)
+
+    def _chunk_steps(self, shape: str) -> int:
+        """How many steps the chunk now going out runs: decode_chunk, or the
+        short length where an arrival could be placed at once
+        (_room_for_arrival) and its prefill would go ahead of the chunk
+        after this one: it then waits out the rest of a chunk half as long.
+        A short chunk pays the chunk's fixed costs twice as often, and the
+        loop must still do its own work a chunk and send the next one
+        HOLD_MARGIN_S early inside it: where what it measured of itself
+        over the last periods does not fit, and wherever an arrival could
+        not be placed sooner anyway (no open slot, a queue, windows being
+        written), the chunk is full. A pp engine's program is K steps."""
+        full = self.cfg.decode_chunk
+        short = max(full // SHORT_CHUNK_DIV, 1)
+        if (short == full or self.pp_mesh is not None
+                or not self._room_for_arrival()):
+            return full
+        reckoned = self._chunk_time(shape, short)
+        if reckoned is None or not self._host_work:
+            return full
+        host = sum(self._host_work) / len(self._host_work)
+        return short if host + HOLD_MARGIN_S <= reckoned else full
+
     def _hold_until(self) -> float | None:
         """Until when, on the loop's clock, the next chunk can be held back
         for an arrival; None where it goes out now. It can where the device
         has a chunk to work on whose end the loop can reckon (it has timed
-        that shape with nothing ahead of it), a slot is open, nobody waits
-        for it, and no slot has windows still to write. The chunk in flight
+        that shape with nothing ahead of it) and an arrival would be placed
+        at once (_room_for_arrival). The chunk in flight
         started no sooner than it was dispatched, than the chunk before it
         was read, and than the first tokens of the prefills ahead of it
         were; what else sits ahead of it makes it end later than reckoned,
         and the next chunk is then early, which costs an arrival its place
         and the device nothing."""
         chunk = self._inflight
-        times = chunk and self._chunk_times.get(chunk.shape)
-        if not times or any(s is not None and s.prefilling
-                            for s in self.slots):
-            return None
-        with self._cond:
-            if self._work_arrived():
-                return None
-        if not any(self._open_slots()):
+        reckoned = chunk and self._chunk_time(chunk.shape, chunk.steps)
+        if not reckoned or not self._room_for_arrival():
             return None
         until = (max(chunk.t0, self._last_readback, self._first_tokens_read)
-                 + min(times) - HOLD_MARGIN_S)
+                 + reckoned - HOLD_MARGIN_S)
         return until if until > self._clock() else None
 
     def _await_work(self, until: float) -> bool:
@@ -2713,7 +2779,7 @@ class TpuEngine:
             return self._exec_op(op, args)
         # Rows (padded tokens) of one step of this program, and its steps.
         rows = args["slots" if decode else "tokens"].size
-        steps = self.cfg.decode_chunk if decode else 1
+        steps = args["steps"] if decode else 1
         if self.mcfg.n_experts:
             # What it puts through the MoE FFN, under the form its shape
             # traced to.
@@ -2974,7 +3040,7 @@ class TpuEngine:
         k_dev.block_until_ready()
         return k_dev, v_dev
 
-    def _op_decode(self, slots, positions, tables, temps, top_k, top_p,
+    def _op_decode(self, slots, positions, tables, steps, temps, top_k, top_p,
                    warm=False):
         # (The cache takes the host's copy of the slots: what goes in with
         # it is donated with it.)
@@ -2985,6 +3051,8 @@ class TpuEngine:
                 self._put(tables),
                 self._next_key(warm), self._put(temps), self._put(top_k),
                 self._put(top_p))
+        if self.pp_mesh is None:    # (a pp chunk is decode_chunk steps long)
+            args += (self._put(np.int32(steps)),)
         if (self.cfg.pallas_attention and not self.cfg.pallas_interpret
                 and self.pp_mesh is None):
             # The resolved flag says what was asked for; the lowered text
@@ -2996,19 +3064,19 @@ class TpuEngine:
                     "tpu_custom_call"
                     in self._jit_decode_chunk.lower(*args).as_text())
         toks, k_pages, self.v_pages = self._jit_decode_chunk(*args)
-        self._keep_cache(k_pages, slots.size * self.cfg.decode_chunk)
-        return self._op_keep_tokens(slots, toks)
+        self._keep_cache(k_pages, slots.size * steps)
+        return self._op_keep_tokens(slots, toks, row=steps - 1)
 
-    def _op_keep_tokens(self, slots, toks):
-        """Leave sampled tokens where the next chunk finds them: row i of
-        ``toks`` ([N], or the last row of a chunk's [K, N]) is slot
-        ``slots[i]``'s newest token (max_batch: nobody's). Its own op for a
-        token the host holds (an import's first); the tail of every op that
-        samples. Starts the tokens' copy to the host."""
+    def _op_keep_tokens(self, slots, toks, row=0):
+        """Leave sampled tokens where the next chunk finds them: entry i of
+        ``toks`` ([N], or row ``row`` of a chunk's [K, N]: its last step's)
+        is slot ``slots[i]``'s newest token (max_batch: nobody's). Its own op
+        for a token the host holds (an import's first); the tail of every op
+        that samples. Starts the tokens' copy to the host."""
         slots, toks = (x if isinstance(x, jax.Array) else self._put(x)
                        for x in (slots, toks))
-        self._slot_tokens = self._jit_keep_tokens(self._slot_tokens, slots,
-                                                  toks)
+        self._slot_tokens = self._jit_keep_tokens(
+            self._slot_tokens, slots, toks, self._put(np.int32(row)))
         toks.copy_to_host_async()
         return toks
 
@@ -3171,8 +3239,9 @@ class TpuEngine:
                 len(lanes) / max(self.cfg.max_batch, 1))
             shape = f"{B}x{W}"
             timed = ("decode", shape) in self._seen_op_shapes
+            steps = self._chunk_steps(shape)
             args = dict(slots=slots, positions=positions, tables=tables,
-                        **self._sample_np(reqs))
+                        steps=steps, **self._sample_np(reqs))
         t0 = self._clock()
         if self._inflight is None:
             self._begin_period()    # nothing ahead of it: its period is its own
@@ -3180,16 +3249,18 @@ class TpuEngine:
             toks = self._device_call(("decode",), args)
         self.telemetry.decode_chunks[
             "alone" if self._inflight is None else "ahead"].inc()
+        self.telemetry.decode_chunk_lengths[
+            "full" if steps == self.cfg.decode_chunk else "short"].inc()
         for _, s in lanes:
-            s.ahead += self.cfg.decode_chunk
+            s.ahead += steps
         behind, self._calls_since_chunk = self._calls_since_chunk, 0
-        return _Chunk(toks=toks, lanes=lanes, t0=t0, timed=timed,
+        return _Chunk(toks=toks, steps=steps, lanes=lanes, t0=t0, timed=timed,
                       shape=shape, behind=behind)
 
     def _land_chunk(self, chunk: _Chunk) -> None:
         """Read a chunk's tokens (ONE readback a chunk) and book them."""
         with self._phase("decode_wait"):
-            sampled = self._read_tokens(chunk.toks)  # [K, B]
+            sampled = self._read_tokens(chunk.toks)[:chunk.steps]  # [n, B]
         now = self._clock()
         self._note_pair_counts()
         if chunk.timed:
@@ -3208,11 +3279,16 @@ class TpuEngine:
                 prefills=self._period_prefills)
             if stall is not None:
                 log.warning("engine loop stall %s", json.dumps(stall))
+            if not self._period_first_call:
+                self._host_work.append(sum(
+                    dt for phase, dt in self._period.items()
+                    if phase not in ("decode_wait", "idle_wait")))
             if not (chunk.behind or self._period_first_call):
                 # Nothing sat between it and the chunk before: the period
                 # is the chunk's device time, what _hold_until reckons with.
-                self._chunk_times.setdefault(chunk.shape, collections.deque(
-                    maxlen=HOLD_PERIODS)).append(period)
+                self._chunk_times.setdefault(
+                    (chunk.shape, chunk.steps), collections.deque(
+                        maxlen=HOLD_PERIODS)).append(period)
         self._last_readback = now
         self._begin_period()
         with self._phase("decode_book"):
@@ -3220,7 +3296,7 @@ class TpuEngine:
 
     def _book_chunk(self, lanes: list[tuple[int, _Slot]],
                     sampled: np.ndarray) -> None:
-        """Apply one chunk's sampled tokens [K, B] lane by lane, up to each
+        """Apply one chunk's sampled tokens [n, B] lane by lane, up to each
         request's stop condition. A lane's request either holds its slot
         still, or is retired (a successor holds the slot and this chunk has
         the request's last tokens), or ended while this chunk was in flight

@@ -1473,7 +1473,9 @@ def main(argv: list[str] | None = None):
                    help="pipeline-parallel stages (stage-ring serving; "
                         "composes with --tp-size/--ep-size)")
     p.add_argument("--decode-chunk", type=int, default=8,
-                   help="decode steps fused per device dispatch")
+                   help="the most decode steps fused per device dispatch "
+                        "(the loop sends half where a slot is open and "
+                        "nobody waits)")
     p.add_argument("--prefill-batch", type=int, default=1,
                    help="same-bucket prompts fused per prefill dispatch")
     p.add_argument("--prefill-chunk", type=int, default=0,

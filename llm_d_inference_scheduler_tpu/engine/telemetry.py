@@ -252,6 +252,16 @@ class EngineTelemetry:
             "with nothing in flight", ("dispatch",), registry=self.registry)
         self.decode_chunks = {d: decode_chunks.labels(dispatch=d)
                               for d in ("ahead", "alone")}
+        decode_chunk_lengths = Counter(
+            "jetstream:decode_chunk_lengths_total",
+            "Decode chunks dispatched, by their length in steps: `full` "
+            "(decode_chunk), or `short` (half of it) where a slot was open, "
+            "nobody waited and the loop's own work a chunk fitted inside: an "
+            "arrival then waits out a shorter chunk", ("length",),
+            registry=self.registry)
+        self.decode_chunk_lengths = {
+            n: decode_chunk_lengths.labels(length=n)
+            for n in ("short", "full")}
         slot_refills = Counter(
             "jetstream:slot_refills_total",
             "Requests admitted into an engine slot: `ahead` of the booking "

@@ -186,7 +186,7 @@ def main(argv=None) -> int:
         programs.append((f"decode {b}x{width}", jax.jit(
             eng._decode_chunk_impl, donate_argnums=(3, 4)),
             (params, sds((b,), jnp.int32), sds((b,), jnp.int32), *pool(b),
-             sds((b, width), jnp.int32), *sampling(b))))
+             sds((b, width), jnp.int32), *sampling(b), sds((), jnp.int32))))
     for spec in [s for s in args.prefill.split(",") if s]:
         bucket, rows = (int(x) for x in spec.split("x"))
         programs.append((f"prefill {rows}x{bucket}", eng._prefill_fn(bucket),

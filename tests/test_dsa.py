@@ -688,8 +688,10 @@ def test_engine_refuses_what_the_second_pool_cannot_do(served):
                                    **extra))
 
 
-def test_selection_counters_from_positions():
-    """_note_selection's sums against a count by hand, a decode chunk and a
+@pytest.mark.parametrize("steps", [4, 2])
+def test_selection_counters_from_positions(steps):
+    """_note_selection's sums against a count by hand, a decode chunk (of the
+    steps it was dispatched with, whatever decode_chunk says) and a
     continuation window."""
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.engine.telemetry import EngineTelemetry
@@ -700,12 +702,13 @@ def test_selection_counters_from_positions():
     eng.telemetry = EngineTelemetry(block_size=16, num_blocks=8)
     eng._note_selection(("decode",), dict(
         positions=np.asarray([21, 40, 0, 0], np.int32),
-        slots=np.asarray([0, 2, 4, 4], np.int32)))
+        slots=np.asarray([0, 2, 4, 4], np.int32), steps=steps))
     eng._note_selection(("prefix_prefill", 16, 2), dict(
         prefix_len=np.asarray([16], np.int32),
         suffix_len=np.asarray([12], np.int32)))
     eng._note_selection(("embed", 16), {})
-    contexts = [22, 23, 24, 25, 41, 42, 43, 44] + list(range(17, 29))
+    contexts = ([22, 23, 24, 25][:steps] + [41, 42, 43, 44][:steps]
+                + list(range(17, 29)))
     q = _counters(eng, "jetstream:dsa_query_tokens_total", "form")
     r = _counters(eng, "jetstream:dsa_rows_total", "kind")
     assert q == {"selected": sum(c > TOPK for c in contexts),

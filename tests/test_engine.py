@@ -12,7 +12,10 @@ import httpx
 import pytest
 
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
-from llm_d_inference_scheduler_tpu.engine.core import HOLD_MARGIN_S
+from llm_d_inference_scheduler_tpu.engine.core import (
+    HOLD_MARGIN_S,
+    SHORT_CHUNK_DIV,
+)
 from llm_d_inference_scheduler_tpu.engine.server import EngineServer
 
 
@@ -1041,6 +1044,7 @@ def test_a_lane_that_ends_on_a_stop_token_is_not_refilled_ahead(family):
 # ---- the next chunk is held back for an arrival ----------------------------
 
 _PREFILL_S, _CHUNK_S = 0.040, 0.100
+_SHORT = _CHUNK // SHORT_CHUNK_DIV
 
 
 @contextlib.contextmanager
@@ -1060,27 +1064,32 @@ def _time_limit(seconds):
 
 class _Device:
     """A clock the test owns and a device with an in-order queue: a prefill
-    takes 40 ms and a chunk 100, each after whatever was dispatched before
-    it; reading an op's tokens moves the clock to that op's end; the host
-    costs nothing. The sleep of a held chunk (TpuEngine._await_work) is the
+    takes 40 ms and a chunk 25 ms a step (100 at its full length), each
+    after whatever was dispatched before it; reading an op's tokens moves
+    the clock to that op's end; the host costs nothing. The sleep of a held
+    chunk (TpuEngine._await_work) is the
     test's too: hold number n (from 0) finds in ``script[n]`` what happens in
     it, a list of (seconds into the hold, something to call); after the last
     of them, or with none, the clock moves to the deadline. ``log`` holds
     every device call and every hold as (kind, the clock, what): a decode
-    chunk's request ids, a prefill's slots, a hold's deadline; ``reads`` the
-    clock after every read."""
+    chunk's request ids, a prefill's slots, a hold's deadline; ``lengths``
+    every chunk's steps; ``reads`` the clock after every read."""
 
     def __init__(self, eng):
         self.script = {}
         self.now, self.free, self.ends = 0.0, 0.0, collections.deque()
         self.log, self.reads, self.n_holds, self._due = [], [], 0, None
+        self.lengths = []
         self.real_wait = eng._await_work
         real_op, real_read, real_chunk = (eng._exec_op, eng._read_tokens,
                                           eng._dispatch_chunk)
 
         def exec_op(op, args):
             if op[0] in ("prefill", "prefix_prefill", "decode"):
-                took = _CHUNK_S if op[0] == "decode" else _PREFILL_S
+                took = _PREFILL_S
+                if op[0] == "decode":
+                    took = _CHUNK_S * args["steps"] / _CHUNK
+                    self.lengths.append(args["steps"])
                 self.free = max(self.free, self.now) + took
                 self.ends.append(self.free)
                 self.log.append((op[0], self.now,
@@ -1219,22 +1228,32 @@ def test_an_arrival_in_a_hold_is_prefilled_ahead_of_the_next_chunk():
         [ends, ends + _PREFILL_S, ends + 2 * _PREFILL_S])
 
 
+def _lengths(eng):
+    return tuple(_counter(eng, "jetstream:decode_chunk_lengths_total",
+                          {"length": n}) for n in ("short", "full"))
+
+
 def test_with_no_arrival_a_held_chunk_goes_out_at_the_deadline():
     """Every chunk from the first hold on is dispatched HOLD_MARGIN_S before
     the end of the chunk ahead of it, never later and (the periods being all
     alike) not sooner; the device is never without work, and every chunk
-    still goes out with the one before it unread."""
+    still goes out with the one before it unread. From the first hold on the
+    chunks are short (three slots are open and nobody waits), and the first
+    of them is reckoned pro rata from the full ones."""
     toks, why, eng, dev = _held([_long(max_tokens=61)], at_step={"A": 0})
     assert why == {"A": "length"} and len(toks["A"]) == 61
     chunks = [(at, ids) for kind, at, ids in dev.log if kind == "decode"]
     holds = [(at, until) for kind, at, until in dev.log if kind == "hold"]
     # Three chunks go out as they always did, until the second is read and
-    # the shape is timed; the last hold is behind the last chunk, which
-    # nothing follows.
-    assert len(chunks) == 15 and len(holds) == len(chunks) - 3 + 1
+    # the shape is timed: 12 of the 60 tokens; the last hold is behind the
+    # last chunk, which nothing follows.
+    assert dev.lengths == [_CHUNK] * 3 + [_SHORT] * (48 // _SHORT)
+    assert _lengths(eng) == (48 // _SHORT, 3)
+    assert len(holds) == len(chunks) - 3 + 1
     # The first chunk ends a prefill and a chunk after time 0, the rest back
     # to back.
-    ends = [_PREFILL_S + _CHUNK_S * (n + 1) for n in range(len(chunks))]
+    ends = [_PREFILL_S + _CHUNK_S * sum(dev.lengths[:n + 1]) / _CHUNK
+            for n in range(len(chunks))]
     assert [until for _, until in holds] == pytest.approx(
         [end - HOLD_MARGIN_S for end in ends[2:]], abs=1e-9)
     assert [at for at, _ in chunks[3:]] == [until for _, until in holds[:-1]]
@@ -1260,7 +1279,7 @@ def _no_hold_busy():
     """Two lanes, both decoding: no slot for an arrival until A's last chunk
     is in flight (a slot that is known to vacate is open)."""
     return dict(requests=[_long(), _long("B")], at_step={"A": 0, "B": 8},
-                max_batch=2), "open", range(8, 25)
+                max_batch=2), "open", range(8, 20)
 
 
 def _no_hold_prefilling():
@@ -1272,9 +1291,9 @@ def _no_hold_prefilling():
 def _no_hold_idle():
     """A has ended and its last chunk is booked: the steps after it, and B's
     own first, find nothing in flight, though the shape is timed and every
-    slot is free."""
+    slot is free (so B's first chunk, held back by nothing, is short)."""
     return dict(requests=[_long(max_tokens=21), _long("B", max_tokens=5)],
-                at_step={"A": 0, "B": 9}), "inflight", range(6, 10)
+                at_step={"A": 0, "B": 13}), "inflight", range(10, 14)
 
 
 def _no_hold_untimed():
@@ -1283,48 +1302,137 @@ def _no_hold_untimed():
     return dict(requests=[_long()], at_step={"A": 0}), "timed", range(1, 3)
 
 
+def _slow_booking(seconds):
+    def setup(eng, dev):
+        def book(lanes, sampled, real=eng._book_chunk):
+            dev.now += seconds
+            return real(lanes, sampled)
+
+        eng._book_chunk = book
+    return setup
+
+
+def _full_for_the_host():
+    """Booking a chunk costs the host 40 ms: with the 20 ms of margin that is
+    more than a short chunk's 50, so every chunk is full; the holds are taken
+    as ever (60 ms are left of a full chunk's 100)."""
+    return dict(requests=[_long()], at_step={"A": 0},
+                also=_slow_booking(0.040)), "host", range(3, 24)
+
+
 @pytest.mark.parametrize("case", [
     _no_hold_waits, _no_hold_busy, _no_hold_prefilling, _no_hold_idle,
-    _no_hold_untimed], ids=lambda case: case.__name__[9:])
-def test_no_hold_is_taken(case):
-    """In the steps named, the one thing named stands in the way and the
-    chunk goes out at once; over the whole run a chunk is held in exactly
-    the steps in which nothing does."""
+    _no_hold_untimed, _full_for_the_host], ids=lambda case: case.__name__[1:])
+def test_a_chunk_is_held_back_and_cut_short_only_where_an_arrival_fits(case):
+    """In the steps named, the one thing named stands in the way: of the
+    hold (the chunk goes out at once) and of the short length (it is full),
+    or of the one it concerns alone: nothing in flight stops a hold and no
+    length, the host's own work a length and no hold. Over the whole run a
+    chunk is held in exactly the steps in which nothing stands in the hold's
+    way, and short in exactly those in which nothing stands in its length's:
+    an arrival could be placed at once, the chunk's shape has been timed (at
+    either length), and the loop's work a chunk fits inside the short one."""
     plan, blocker, quiet = case()
-    steps = {}
+    also = plan.pop("also", None)
+    holds, lengths = {}, {}
+
+    def room(eng):
+        return dict(
+            waiting=not eng._waiting, open=any(eng._open_slots()),
+            prefilling=not any(s is not None and s.prefilling
+                               for s in eng.slots))
 
     def watch(eng, dev):
+        def step():
+            return [what for kind, _, what in dev.log if kind == "step"][-1]
+
         def hold_until(real=eng._hold_until):
-            step = [what for kind, _, what in dev.log if kind == "step"][-1]
             chunk = eng._inflight
             state = dict(
-                inflight=chunk is not None, waiting=not eng._waiting,
-                open=any(eng._open_slots()),
-                prefilling=not any(s is not None and s.prefilling
-                                   for s in eng.slots),
-                timed=bool(eng._chunk_times.get(chunk.shape) if chunk
-                           else eng._chunk_times))
+                inflight=chunk is not None, **room(eng),
+                timed=any(shape == chunk.shape for shape, _ in eng._chunk_times)
+                if chunk else bool(eng._chunk_times))
             until = real()
-            steps.setdefault(step, (state, until))
+            holds.setdefault(step(), (state, until))
             return until
 
-        eng._hold_until = hold_until
+        def chunk_steps(shape, real=eng._chunk_steps):
+            state = dict(
+                **room(eng),
+                timed=any(sh == shape for sh, _ in eng._chunk_times),
+                host=max(eng._host_work, default=0) + HOLD_MARGIN_S
+                <= _CHUNK_S * _SHORT / _CHUNK)
+            lengths[step()] = (state, real(shape))
+            return lengths[step()][1]
+
+        eng._hold_until, eng._chunk_steps = hold_until, chunk_steps
+        if also is not None:
+            also(eng, dev)
 
     toks, why, eng, dev = _held(setup=watch, **plan)
     assert set(why.values()) == {"length"}
-    for step, (state, until) in steps.items():
+    for step, (state, until) in holds.items():
         assert (until is not None) == all(state.values()), (step, state)
+    for step, (state, steps) in lengths.items():
+        assert steps == (_SHORT if all(state.values()) else _CHUNK), (
+            step, state)
+    assert sorted(dev.lengths) == sorted(n for _, n in lengths.values())
+    assert _lengths(eng) == (dev.lengths.count(_SHORT),
+                             dev.lengths.count(_CHUNK))
     for step in quiet:
-        assert [k for k, ok in steps[step][0].items() if not ok] == [blocker]
-    assert steps[quiet[0] - 1][1] is not None or blocker == "timed"
-    assert any(until is not None for step, (_, until) in steps.items()
+        for state, _ in filter(None, (holds.get(step), lengths.get(step))):
+            if blocker in state:    # (the blocker of one of the two alone)
+                assert [k for k, ok in state.items()
+                        if not ok] == [blocker], (step, state)
+    if blocker == "host":
+        assert set(dev.lengths) == {_CHUNK}
+        assert all(holds[step][1] is not None for step in quiet)
+    elif blocker == "inflight":
+        assert lengths[quiet[-1]][1] == _SHORT      # B's first chunk
+    else:
+        assert holds[quiet[0] - 1][1] is not None or blocker == "timed"
+        assert all(lengths[step][1] == _CHUNK for step in quiet)
+    assert any(until is not None for step, (_, until) in holds.items()
                if step > quiet[-1])
+    assert _SHORT in [n for step, (_, n) in lengths.items()
+                      if step > quiet[-1]] or blocker == "host"
+
+
+def test_a_request_is_served_whole_through_short_and_full_chunks():
+    """Two lanes. A (19 tokens, 18 of them decoded: no multiple of either
+    length once three full chunks are gone) decodes alone, through full
+    chunks until its shape is timed and short ones from then on; B and C
+    arrive together: B takes the empty slot, C waits, so the chunk that
+    follows is full, and A's end lies inside it; C is prefilled ahead into
+    A's slot and is a lane of the very next chunk, which A is not. Every
+    request gets exactly its tokens, the ones it gets alone."""
+    reqs = [_req("A", _prompt(5, 9), 19, 0.0), _req("B", _prompt(7, 20), 14, 0.0),
+            _req("C", _prompt(11, 27), 7, 0.0)]
+    toks, why, eng, dev = _held(
+        reqs, at_step={"A": 0, "B": 5, "C": 5}, max_batch=2)
+    assert why == dict.fromkeys("ABC", "length")
+    assert [len(toks[r.request_id]) for r in reqs] == [19, 14, 7]
+    alone, _, _ = _by_hand(reqs, max_batch=2, submit_at={
+        r.request_id: 40 * n for n, r in enumerate(reqs)})
+    assert toks == alone
+    ids = [ids for kind, _, ids in dev.log if kind == "decode"]
+    assert [n for lanes, n in zip(ids, dev.lengths) if lanes == ["A"]] == [
+        _CHUNK] * 3 + [_SHORT] * 2
+    last_a = max(n for n, lanes in enumerate(ids) if "A" in lanes)
+    assert ids[last_a] == ["A", "B"] and dev.lengths[last_a] == _CHUNK
+    assert "C" in ids[last_a + 1] and "A" not in ids[last_a + 1]
+    assert _refills(eng) == (1, 2)
+    short, full = _lengths(eng)
+    assert short >= 1 and full >= 1 and short + full == len(ids)
+    assert _counter(eng, "jetstream:decode_lanes_discarded_total") == 0
+    assert _free_blocks(eng) == eng.n_blocks - 1
 
 
 @pytest.mark.parametrize("what", ["abort", "stop"])
 def test_an_abort_or_a_stop_ends_a_hold_at_once(what):
     """The loop's own wait (not the test's), asked to sleep to a deadline
-    70 ms away on a clock that stands still: an abort or the stop notifies
+    30 ms away (a short chunk's 50 less the margin) on a clock that stands
+    still: an abort or the stop notifies
     _cond and the wait is over; the chunk goes out there and then, not at
     the deadline, and the step goes on to its end."""
     woke = []
@@ -1349,7 +1457,7 @@ def test_an_abort_or_a_stop_ends_a_hold_at_once(what):
     second = [n for n, kind in enumerate(dev.kinds()) if kind == "hold"][1]
     (_, began, until), (kind, at, ids), *rest = dev.log[second:]
     assert kind == "decode" and at == pytest.approx(began + 0.010)
-    assert at < until - 0.05 and ids == ["A"]
+    assert at < until - 0.015 and ids == ["A"]
     if what == "abort":
         # Processed at the top of the next step, as an abort that arrives
         # during a readback always was.
@@ -1371,7 +1479,7 @@ def test_greedy_streams_are_the_same_held_and_never_held():
     reqs = _mixed_arrivals()
     at_step = {"A": 0, "D": 7, "E": 8, "F": 8}
     toks, why, eng, dev = _held(
-        reqs, at_step=at_step, script={0: [(0.020, "B")], 2: [(0.045, "C")]})
+        reqs, at_step=at_step, script={0: [(0.020, "B")], 2: [(0.015, "C")]})
     assert why == dict.fromkeys("ABCDEF", "length")
     assert [len(toks[r.request_id]) for r in reqs] == [41, 18, 25, 9, 13, 6]
     assert _admissions(eng) == (2, 4)
@@ -1379,6 +1487,9 @@ def test_greedy_streams_are_the_same_held_and_never_held():
         reqs, at_step={**at_step, "B": 4, "C": 6}, hold=False)
     assert "hold" not in dev_plain.kinds() and "hold" in dev.kinds()
     assert _admissions(eng_plain) == (0, 6)
+    # Both were served through chunks of both lengths, not the same ones.
+    assert {_SHORT, _CHUNK} == set(dev.lengths) == set(dev_plain.lengths)
+    assert dev.lengths != dev_plain.lengths
     assert toks == plain and why == why_plain
     alone, _, _ = _by_hand(reqs, submit_at={
         r.request_id: 40 * n for n, r in enumerate(reqs)})
@@ -1394,7 +1505,7 @@ def test_admissions_are_counted_once_a_request():
     reqs = _mixed_arrivals() + [_req("X", _prompt(23, 30), 400, 0.0)]
     toks, why, eng, dev = _held(
         reqs, at_step={"A": 0, "D": 7, "E": 8, "F": 8, "X": 9},
-        script={0: [(0.020, "B")], 2: [(0.045, "C")]}, hbm_kv_blocks=8)
+        script={0: [(0.020, "B")], 2: [(0.015, "C")]}, hbm_kv_blocks=8)
     assert why == {**dict.fromkeys("ABCDEF", "length"), "X": "abort"}
     hold, step = _admissions(eng)
     assert hold >= 1 and hold + step == 6 == sum(_refills(eng))

@@ -250,7 +250,7 @@ def test_the_decode_chunk_keeps_its_name(engine):
     args = (engine.params, np.zeros((2,), np.int32), np.zeros((2,), np.int32),
             engine.k_pages, engine.v_pages,
             np.zeros((2, engine.max_blocks_per_seq), np.int32),
-            *_sampling(engine, 2))
+            *_sampling(engine, 2), np.int32(1))
     assert "@jit__decode_chunk_impl " in \
         engine._jit_decode_chunk.lower(*args).as_text()
 
