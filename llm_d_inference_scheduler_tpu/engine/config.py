@@ -50,14 +50,6 @@ class EngineConfig:
     checkpoint_path: str = ""     # orbax dir; empty = random init (dev/bench)
     enable_prefix_caching: bool = True  # automatic prefix caching (block reuse)
     warmup: bool = False          # compile prefill/decode/sample before serving
-    # Pow2 context buckets for the decode block table: narrow the traced
-    # table width to the live context instead of always max_model_len —
-    # the XLA gather attention path's HBM traffic is O(table width), so
-    # head_dim-64 models gain materially. Opt-in: enabling multiplies the
-    # decode compile matrix by the width count (warmup covers the FULL
-    # batch×width matrix to keep its no-lazy-compile guarantee, which can
-    # take minutes on a cold cache).
-    decode_ctx_buckets: bool = False
     # Batched prefill: admit up to N same-bucket plain prompts per fused
     # prefill dispatch ([N, S] forward instead of N × [1, S]) — prefill is
     # HBM-bound at serving prompt lengths, so one weights pass covers N
@@ -117,7 +109,7 @@ class EngineConfig:
     pallas_interpret: bool = False
     # Tensor parallelism: shard params (Megatron TP) + KV pages (kv-head axis)
     # over a tp-sized mesh axis; remaining devices form the dp axis. 1 = the
-    # single-device layout (no mesh). BASELINE.md config 4 path.
+    # single-device layout (no mesh). The path of a 70B model over a multi-host slice.
     tp_size: int = 1
     # Expert parallelism (MoE models): shard the experts axis over ep_size
     # devices (composes with tp_size; total devices = tp_size * ep_size).

@@ -24,7 +24,6 @@ import asyncio
 import collections
 import contextlib
 import dataclasses
-import functools
 import json
 import logging
 import os
@@ -38,9 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kvcache import pages, state as state_pool, wire
-from ..models import family
-from ..ops import pallas_moe, pallas_ssm
-from ..ops.pallas_latent_attention import RUN_PAGES
+from ..models import bind
 from ..utils.hashing import chain_block_hashes
 from .blocks import BlockAllocator, PrefixCachingAllocator, table_groups
 from .config import EngineConfig
@@ -212,28 +209,28 @@ class TpuEngine:
                 f"{len(jax.local_devices())} {jax.default_backend()} "
                 "device(s)")
         self.device = jax.local_devices()[cfg.device_index]
-        # The block's module (models/llama.py or models/mla.py: one set of
-        # entry points) and the page pool that goes with it.
-        self.model = family(self.mcfg)
+        # The model's cache (kvcache/: its pages, and what it keeps a slot
+        # beside them) and how a decode step attends over it.
         self.geom = pages.PageGeometry.for_engine(
             self.mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
-        # What the model's state-space layers keep a slot, beside the pages
-        # (kvcache/state.py); None for a model that keeps pages alone.
-        self.state_geom = state_pool.StateGeometry.for_engine(
-            self.mcfg, cfg.max_batch)
-        if self.geom.latent_dim or self.state_geom:
+        if self.geom.one_chip_only:
             self._refuse_beyond_one_chip()
         cfg.pallas_attention = pages.use_kernel(
             self.geom.shape[-1], asked=cfg.pallas_attention,
             interpret=cfg.pallas_interpret, platform=self.device.platform,
             sharded=cfg.tp_size > 1 or cfg.ep_size > 1)
-        self._decode_attention = functools.partial(
-            pages.latent_decode_attention if self.geom.latent_dim
-            else pages.decode_attention, kernel=cfg.pallas_attention,
+        self._decode_attention = pages.attention_for(
+            self.geom, kernel=cfg.pallas_attention,
             interpret=cfg.pallas_interpret)
-        self._bind_state_form(self.device.platform)
-        self._bind_index_form(self.device.platform)
-        self._bind_moe_form(self.device.platform)
+        # The model's family as this engine serves it (models/binding.py):
+        # the block's module, the configuration its programs trace with (each
+        # kernel in the form this device calls for), what a program counts as.
+        self.bound = bind(
+            self.mcfg, platform=self.device.platform,
+            interpret=cfg.pallas_interpret,
+            sharded=(cfg.tp_size > 1 or cfg.ep_size > 1 or cfg.pp_size > 1
+                     or cfg.dist_num_processes > 1))
+        self.model, self.mcfg = self.bound.module, self.bound.mcfg
         self.tokenizer = get_tokenizer(cfg.tokenizer, self.mcfg.vocab_size)
         self.model_name = cfg.model_name
 
@@ -241,9 +238,9 @@ class TpuEngine:
         self.n_blocks = self.geom.n_blocks
         self.max_blocks_per_seq = self.geom.max_blocks_per_seq
         # A cached block prefix is pages with no recurrent state to go with
-        # them: a model with state layers keeps no prefix cache.
+        # them: a model that keeps state a slot keeps no prefix cache.
         self.allocator = (PrefixCachingAllocator(self.n_blocks, block)
-                          if cfg.enable_prefix_caching and not self.state_geom
+                          if cfg.enable_prefix_caching and not self.geom.state
                           else BlockAllocator(self.n_blocks, block))
         self.telemetry = EngineTelemetry(block_size=block, num_blocks=self.n_blocks)
         self.telemetry.watch_xla_builds()
@@ -353,7 +350,7 @@ class TpuEngine:
             validate_pp(self.mcfg, cfg.pp_size, cfg.tp_size, cfg.ep_size)
             n_model = cfg.pp_size * cfg.tp_size * cfg.ep_size
             if self._dist:
-                # Stage ring spanning hosts (BASELINE config-4 shape: a 70B
+                # Stage ring spanning hosts (the deployment this is for: a 70B
                 # pipeline across a multi-host slice). The global device
                 # list orders process-major, so the (pp, tp) reshape puts
                 # consecutive stages on consecutive hosts: tp collectives
@@ -424,17 +421,7 @@ class TpuEngine:
         self.k_pages, self.v_pages = (
             pages.alloc(self.geom, sharding=pages.page_sharding(mesh))
             if mesh is not None
-            else pages.alloc(self.geom, device=self.device,
-                             state=self.state_geom,
-                             counted=self.mcfg.tallies_choices,
-                             counts_zero=bool(self.mcfg.n_zero_experts)))
-        # Counts of expert choices held here, and of zero-compute ones, that
-        # step programs summed on the device (kvcache/state.py), oldest
-        # first, each with the choices its program made in all: booked once
-        # their programs are done (_note_pair_counts, behind a chunk's
-        # tokens), so that no read waits.
-        self._pair_counts: collections.deque[tuple[Any, Any, int]] = (
-            collections.deque())
+            else pages.alloc(self.geom, device=self.device))
 
         self.warming = cfg.warmup  # cleared by the engine thread post-compile
         # Set by the engine thread when it cannot go on (a warm-up that
@@ -559,25 +546,10 @@ class TpuEngine:
         log.info("engine %s up: %s", self.engine_id,
                  json.dumps(self.describe()))
 
-    def _one_chip_cache(self) -> str | None:
-        """What this model keeps that lives on the unsharded one-chip engine
-        alone, in words; None for plain K/V pages."""
-        if self.geom.index_dim:
-            return ("a latent (MLA) page pool and its indexer's key pool "
-                    "beside it, under one block table")
-        if self.geom.latent_dim:
-            return "a latent (MLA) page pool"
-        if self.state_geom:
-            return "a recurrent state pool beside its pages"
-        return None
-
     def _refuse_beyond_one_chip(self) -> None:
-        """A latent page pool, and a state pool beside the pages, live on the
-        unsharded one-chip engine: neither has a sharding rule, a stage split
-        or a wire format yet (ROADMAP R7, R8), and an indexer's key pool
-        beside a latent one has none either: a selecting block's handoff
-        would carry both pools' pages, and a selection over sharded keys
-        needs every shard's scores. Asked for any of those, say so now, by
+        """A cache that lives on the unsharded one-chip engine alone
+        (kvcache/pages.py PageGeometry.one_chip_only says which, and why):
+        asked for sharding, stages, processes or a role, say so now, by
         name, rather than serve something else."""
         cfg = self.cfg
         asked = [f"{name}={value}" for name, value, plain in (
@@ -587,7 +559,7 @@ class TpuEngine:
             ("role", cfg.role, "both")) if value != plain]
         if asked:
             raise ValueError(
-                f"model {self.mcfg.name!r} keeps {self._one_chip_cache()}, "
+                f"model {self.mcfg.name!r} keeps {self.geom.one_chip_only}, "
                 f"which serves on one unsharded chip only: {', '.join(asked)} "
                 "is not supported (no sharding rule for it, no handoff of it "
                 "to another engine)")
@@ -608,47 +580,11 @@ class TpuEngine:
                 "max_batch": self.cfg.max_batch,
                 "max_model_len": self.cfg.max_model_len,
                 "kv_blocks": self.n_blocks,
-                # The cache layers (two a double layer), a token's bytes in
-                # one of them, the layout's padding counted, and the whole
-                # pool's.
-                "kv_layers": self.geom.n_layers,
-                "kv_token_bytes": self.geom.token_bytes,
-                "kv_pool_bytes": self.geom.pool_bytes,
-                # Table entries the latent decode kernels fetch as one copy
-                # where they name adjacent blocks (None: no latent pool).
-                "kv_run_pages": RUN_PAGES if self.geom.latent_dim else None,
-                # A block that selects the rows it attends to (0: none):
-                # how many a query keeps, and what a token's indexer key
-                # holds a cache layer in its own pool, under the same block
-                # ids (kv_token_bytes stays the latent row's).
-                "index_topk": self.mcfg.index_topk,
-                "index_token_bytes": self.geom.index_token_bytes,
-                "index_pool_bytes": self.geom.index_pool_bytes,
-                "index_scores": (self.mcfg.index_impl if self.mcfg.index_topk
-                                 else None),
-                # The experts this chip holds of those its router scores
-                # (all of them: first 0, held n_experts), and the router's
-                # outputs that compute nothing.
-                "experts_first": self.mcfg.held_experts[0],
-                "experts_held": self.mcfg.held_experts[1],
-                "zero_experts": self.mcfg.n_zero_experts,
-                # What the state-space layers keep a slot and in all (0: the
-                # model keeps pages alone), and what such a model turns off.
-                "state_slot_bytes": (self.state_geom.slot_bytes
-                                     if self.state_geom else 0),
-                "state_pool_bytes": (self.state_geom.pool_bytes
-                                     if self.state_geom else 0),
-                "state_update": (self.mcfg.ssm_impl if self.state_geom
-                                 else None),
+                # What the cache holds and the family's forms: each says
+                # its own (every key on every engine).
+                **self.geom.describe(), **self.bound.describe(),
                 "prefix_caching": isinstance(self.allocator,
                                              PrefixCachingAllocator),
-                "off_for_state_layers": ([
-                    "prefix hits (a cached block prefix has no recurrent "
-                    "state to start from)",
-                    "kv events (no block is advertised to a router)",
-                    "tp/ep/pp and multi-process meshes",
-                    "roles other than both",
-                    "KV export and import"] if self.state_geom else []),
                 "decode_chunk": self.cfg.decode_chunk,
                 "pallas_attention": bool(self.cfg.pallas_attention),
                 "kv_wire": ("device" if self.kv_transfer_server is not None
@@ -683,12 +619,11 @@ class TpuEngine:
         flight already (_step), so the overshoot reaches up to 2K - 1
         positions past the request's end. Its KV writes land in the
         sequence's own allocated tail, or past it in the table's padding
-        (the trash block; a table narrowed by decode_ctx_buckets clamps to
-        the row's last entry, which is one of the two) — never in a block of
-        another live request, and never in a block the prefix cache holds
-        (those are whole blocks of the prompt, below the first decoded
-        position). Whatever reuses the freed blocks is dispatched later on
-        the same in-order device stream and overwrites them. The input
+        (the trash block) — never in a block of another live request, and
+        never in a block the prefix cache holds (those are whole blocks of
+        the prompt, below the first decoded position). Whatever reuses the
+        freed blocks is dispatched later on the same in-order device stream
+        and overwrites them. The input
         tokens come from the device (_slot_tokens), so the host neither
         reads nor books a chunk before it dispatches the next: one dispatch
         a chunk, and no idle device between chunks (PERF.md section 6, PR
@@ -699,7 +634,7 @@ class TpuEngine:
         def step(i, carry):
             tokens, positions, k_pages, v_pages, toks = carry
             logits, k_pages, v_pages = self.model.decode_step(
-                params, self._model_for(tokens.size), tokens, positions,
+                params, self.bound.model_for(tokens.size), tokens, positions,
                 k_pages, v_pages, block_tables,
                 attention_fn=self._decode_attention)
             nxt = sample_tokens(logits, keys[i], temps, top_k, top_p)
@@ -710,86 +645,6 @@ class TpuEngine:
             0, n_steps, step, (tokens, positions, k_pages, v_pages,
                                jnp.zeros((K, tokens.size), tokens.dtype)))
         return toks, k_pages, v_pages
-
-    def _bind_state_form(self, platform: str) -> None:
-        """How a decode step fetches its slots' recurrent states
-        (pallas_ssm.use_kernel), from what this engine is: every step program
-        traces with the answer (``mcfg.ssm_impl``), _device_call counts by
-        it. An engine with a state pool is unsharded (it refused the rest
-        at start)."""
-        if not self.mcfg.n_state_layers:
-            return
-        interpret = self.cfg.pallas_interpret
-        kernel = pallas_ssm.use_kernel(
-            self.mcfg.ssm_state, self.mcfg.ssm_head_dim, platform=platform,
-            sharded=False, interpret=interpret)
-        self.mcfg = dataclasses.replace(
-            self.mcfg, ssm_impl="gathered" if not kernel else
-            "kernel_interpret" if interpret else "kernel")
-
-    def _bind_index_form(self, platform: str) -> None:
-        """How a selecting block's programs compute their indexer's scores:
-        the kernel (ops/pallas_dsa.py) on a TPU, where the per-head products
-        must not reach HBM, and through the interpreter where the tests ask
-        for it; the plain form on the CPU. Such an engine is unsharded (it
-        refused the rest at start)."""
-        if not self.mcfg.index_topk:
-            return
-        interpret = self.cfg.pallas_interpret
-        self.mcfg = dataclasses.replace(
-            self.mcfg, index_impl="kernel_interpret" if interpret else
-            "kernel" if platform == "tpu" else "xla")
-
-    def _note_selection(self, op: tuple, args: dict) -> None:
-        """Book what a program of a selecting block puts through it
-        (jetstream:dsa_*): its query tokens' contexts, from the positions the
-        host already holds. Real lanes and prompt tokens alone; a decode
-        chunk's steps are its lanes' next positions, one a step."""
-        if op[0] == "decode":
-            first = args["positions"][args["slots"] < self.cfg.max_batch] + 1
-            n = np.full(first.shape, args["steps"])
-        elif op[0] == "prefill":
-            n = args["seq_len"]
-            first = np.ones_like(n)
-        elif op[0] == "prefix_prefill":
-            first, n = args["prefix_len"] + 1, args["suffix_len"]
-        else:
-            return
-        first, n = first.astype(np.int64), n.astype(np.int64)
-        last, topk = first + n - 1, self.mcfg.index_topk
-        scored = int(np.sum((first + last) * n // 2))
-        # The contexts beyond index_topk: a query of context c attends to
-        # index_topk rows and leaves c - index_topk.
-        lo = np.maximum(first, topk + 1)
-        m = np.maximum(last - lo + 1, 0)
-        left = int(np.sum((lo + last) * m // 2 - m * topk))
-        self.telemetry.dsa_query_tokens["selected"].inc(int(m.sum()))
-        self.telemetry.dsa_query_tokens["all"].inc(int((n - m).sum()))
-        self.telemetry.dsa_rows["scored"].inc(scored)
-        self.telemetry.dsa_rows["attended"].inc(scored - left)
-
-    def _bind_moe_form(self, platform: str) -> None:
-        """The MoE FFN's form is chosen per program, from its token count
-        (pallas_moe.use_grouped) and from what this engine is: _model_for is
-        what a step function traces with, _device_call counts by the same
-        answer."""
-        cfg = self.cfg
-        self._moe_grouped = functools.partial(
-            pallas_moe.use_grouped, n_experts=self.mcfg.n_experts,
-            experts_per_token=self.mcfg.experts_per_token,
-            d_model=self.mcfg.moe_latent_dim or self.mcfg.d_model,
-            d_ff=self.mcfg.moe_d_ff or self.mcfg.d_ff,
-            platform=platform, interpret=cfg.pallas_interpret,
-            sharded=(cfg.tp_size > 1 or cfg.ep_size > 1 or cfg.pp_size > 1
-                     or cfg.dist_num_processes > 1))
-        self._mcfg_grouped = dataclasses.replace(
-            self.mcfg, moe_impl="grouped_interpret"
-            if cfg.pallas_interpret else "grouped")
-
-    def _model_for(self, tokens: int):
-        """The model as a program of ``tokens`` rows (batch x sequence,
-        padded) traces it: the MoE FFN in the form the shape calls for."""
-        return self._mcfg_grouped if self._moe_grouped(tokens) else self.mcfg
 
     def _prefill_fn(self, bucket: int):
         """Per-bucket jitted prefill: forward + KV scatter + fused first-token
@@ -804,7 +659,7 @@ class TpuEngine:
             def impl(params, tokens, seq_len, k_pages, v_pages, block_table_row,
                      key, temps, top_k, top_p):
                 logits, (k_new, v_new) = self.model.forward(
-                    params, self._model_for(tokens.size), tokens,
+                    params, self.bound.model_for(tokens.size), tokens,
                     want_kv=True, seq_len=seq_len)
                 k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
@@ -831,7 +686,7 @@ class TpuEngine:
                      k_pages, v_pages, block_table_row,
                      rng, temps, top_k, top_p):
                 logits, (k_new, v_new) = self.model.forward(
-                    params, self._model_for(tokens.size), tokens,
+                    params, self.bound.model_for(tokens.size), tokens,
                     want_kv=True, mm_embeds=mm_embeds, mm_positions=mm_positions)
                 k_pages, v_pages = pages.write_sequences(
                     k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
@@ -858,8 +713,8 @@ class TpuEngine:
                      block_table_row, prior_table_row,
                      rng, temps, top_k, top_p):
                 logits, k_pages, v_pages = self.model.prefill_with_prefix(
-                    params, self._model_for(tokens.size), tokens, suffix_len,
-                    prefix_len,
+                    params, self.bound.model_for(tokens.size), tokens,
+                    suffix_len, prefix_len,
                     k_pages, v_pages, block_table_row, prior_table_row)
                 tok = sample_tokens(logits, rng, temps, top_k, top_p)
                 return tok, k_pages, v_pages
@@ -904,7 +759,7 @@ class TpuEngine:
         if busy:
             return False
         # Staged P/D exports pin device KV a decode peer may still be
-        # mid-pull on (ADVICE r5): draining a prefill pod while kv_exports
+        # mid-pull on: draining a prefill pod while kv_exports
         # is non-empty (or releases are queued but not yet broadcast) would
         # tear the pages out from under the peer. Checked outside _cond —
         # no other path nests these locks in this order.
@@ -913,10 +768,10 @@ class TpuEngine:
 
     def submit(self, req: EngineRequest) -> asyncio.Queue:
         """Thread-safe enqueue; returns the per-request event queue."""
-        if self._one_chip_cache() and (req.kv_transfer_params
-                                       or req.mm_embeds is not None):
+        if self.geom.one_chip_only and (req.kv_transfer_params
+                                        or req.mm_embeds is not None):
             raise ValueError(
-                f"model {self.mcfg.name!r} keeps {self._one_chip_cache()}: "
+                f"model {self.mcfg.name!r} keeps {self.geom.one_chip_only}: "
                 "KV handoff to or from another engine and multimodal "
                 "embeddings are not supported")
         out: asyncio.Queue = asyncio.Queue()
@@ -1070,7 +925,7 @@ class TpuEngine:
     def _embed_fn_for(self, bucket: int):
         # Lock the per-bucket fn creation: two concurrent first calls would
         # otherwise each build+compile their own jit (benign race, duplicated
-        # compile work — ADVICE r4). Sharing one fn lets jax's own dispatch
+        # compile work). Sharing one fn lets jax's own dispatch
         # cache dedup the compilation.
         with self._embed_fns_lock:
             fn = self._embed_fns.get(bucket)
@@ -1082,7 +937,7 @@ class TpuEngine:
                 else:
                     def impl(params, tokens, seq_len):
                         hidden, _ = self.model.forward(
-                            params, self._model_for(tokens.size), tokens,
+                            params, self.bound.model_for(tokens.size), tokens,
                             want_hidden=True)
                         mask = (jnp.arange(tokens.shape[1])
                                 < seq_len[0])[None, :, None]
@@ -1152,20 +1007,13 @@ class TpuEngine:
         # Compile EVERY decode bucket _batch_bucket can produce (2, 4, …,
         # max_batch): a gate-able warm-up must leave no lazy compile to stall
         # the engine thread mid-serving.
-        buckets = sorted({self._batch_bucket(n) for n in range(1, B + 1)})
-        # With context buckets on, warm the FULL batch×width matrix — the
-        # no-lazy-compile guarantee is the point of a gated warmup (cold
-        # cache cost is why decode_ctx_buckets is opt-in).
-        widths = (self._ctx_widths() if self.cfg.decode_ctx_buckets
-                  else [self.max_blocks_per_seq])
-        for nb in buckets:
-            for w in widths:
-                self._device_call(("decode",), dict(
-                    slots=np.full((nb,), B, np.int32),
-                    positions=np.zeros((nb,), np.int32),
-                    tables=np.zeros((nb, w), np.int32),
-                    steps=self.cfg.decode_chunk, warm=True,
-                    **self._sample_np([_DUMMY_REQ] * nb)))
+        for nb in sorted({self._batch_bucket(n) for n in range(1, B + 1)}):
+            self._device_call(("decode",), dict(
+                slots=np.full((nb,), B, np.int32),
+                positions=np.zeros((nb,), np.int32),
+                tables=np.zeros((nb, self.max_blocks_per_seq), np.int32),
+                steps=self.cfg.decode_chunk, warm=True,
+                **self._sample_np([_DUMMY_REQ] * nb)))
         log.info("engine warm-up compiled prefill/decode/sample in %.1fs",
                  time.monotonic() - t0)
 
@@ -1741,7 +1589,7 @@ class TpuEngine:
                                      self.mcfg.kv_block_size)
                   if caching or
                   (self.kv_events is not None and req.mm_embeds is None
-                   and not self.state_geom)
+                   and not self.geom.state)
                   else [])
         return prompt, hashes, caching
 
@@ -1767,9 +1615,9 @@ class TpuEngine:
     def _note_table(self, blocks: list[int]) -> None:
         """Count an admitted request's block table by the groups the latent
         decode kernels fetch it in (kv_table_groups_total)."""
-        if not self.geom.latent_dim:
+        if not self.geom.run_pages:
             return
-        runs, splits = table_groups(blocks, RUN_PAGES)
+        runs, splits = table_groups(blocks, self.geom.run_pages)
         self.telemetry.kv_table_groups["run"].inc(runs)
         self.telemetry.kv_table_groups["split"].inc(splits)
 
@@ -1837,7 +1685,7 @@ class TpuEngine:
         except BaseException:
             # Post-dispatch bookkeeping failed (hash commit / event publish):
             # the dispatch itself landed, but entries not yet slotted would
-            # leak their blocks and strand their clients (ADVICE r5). Clean
+            # leak their blocks and strand their clients. Clean
             # up every entry whose slot assignment did not happen.
             for i, req, out, loop, need, pre, blocks in entries:
                 s = self.slots[i]
@@ -2154,7 +2002,7 @@ class TpuEngine:
         # are nobody's. Where the slot also names the window's state
         # (kvcache/state.py) every window is the slot's: the token an earlier
         # one leaves there is overwritten before any chunk reads it.
-        slots = np.asarray([idx if last or self.state_geom
+        slots = np.asarray([idx if last or self.geom.state
                             else self.cfg.max_batch], np.int32)
         try:
             if written == 0:
@@ -2516,7 +2364,7 @@ class TpuEngine:
     def _client_tls_verify(self):
         """TLS verification policy for the engine's outbound HTTP legs
         (host-staged /kv pulls + release DELETEs): default skip-verify for
-        pod-local certs, or the configured CA bundle (ADVICE r5). Memoized —
+        pod-local certs, or the configured CA bundle. Memoized —
         the config is immutable after startup and SSLContext construction is
         not free on the latency-sensitive transfer path."""
         verify = getattr(self, "_http_verify", None)
@@ -2777,37 +2625,13 @@ class TpuEngine:
         self._calls_since_chunk += not decode
         if key is None:
             return self._exec_op(op, args)
-        # Rows (padded tokens) of one step of this program, and its steps.
+        # Rows (padded tokens) of one step of this program, and its steps:
+        # the model's family says what they count as.
         rows = args["slots" if decode else "tokens"].size
         steps = args["steps"] if decode else 1
-        if self.mcfg.n_experts:
-            # What it puts through the MoE FFN, under the form its shape
-            # traced to.
-            self.telemetry.moe_ffn_tokens.labels(
-                form="grouped" if self._moe_grouped(rows) else "dense").inc(
-                    rows * steps)
-        if self.geom.latent_dim:
-            # What it puts through latent attention, under the form its kind
-            # traced to (models/mla.py: one query a sequence is absorbed, a
-            # run of them expanded).
-            self.telemetry.mla_attention_tokens.labels(
-                form="absorbed" if decode else "expanded").inc(rows * steps)
-            if self.mcfg.index_topk and not args.get("warm"):
-                self._note_selection(op, args)
-        if self.state_geom:
-            # What it puts through the state-space layers, under the form its
-            # kind traced to (models/hybrid.py: one position a sequence is
-            # the step form, a run of them the scan form), and the slots a
-            # first window starts afresh.
-            self.telemetry.ssm_tokens.labels(
-                form="step" if decode else "scan").inc(rows * steps)
-            if decode:
-                self.telemetry.ssm_state_updates.labels(
-                    form=self.mcfg.ssm_impl.split("_")[0]).inc(
-                        rows * steps * self.state_geom.n_layers)
-            if op[0] == "prefill" and not args.get("warm"):
-                self.telemetry.ssm_slot_prefills.inc(
-                    int(np.sum(args["slots"] < self.cfg.max_batch)))
+        real, queries = self._requests_part(op, args)
+        self.telemetry.book_program(self.bound.program_counts(
+            op[0], rows, steps, real=real, queries=queries))
         t0 = time.monotonic()
         result = self._exec_op(op, args)
         dt = time.monotonic() - t0
@@ -2821,6 +2645,26 @@ class TpuEngine:
             # measured in _land_chunk instead, where the sync is).
             self.telemetry.prefill_step.observe(dt)
         return result
+
+    def _requests_part(self, op: tuple, args: dict):
+        """What of a program is somebody's, from what the host holds of it:
+        (its sequences that are a request's, their runs of query tokens as
+        (the context of each run's first query, its length)). A decode
+        chunk's steps are its real lanes' next positions, one a step; a
+        warm-up program is nobody's."""
+        if args.get("warm") or "slots" not in args:
+            return 0, None
+        real = args["slots"] < self.cfg.max_batch
+        if op[0] == "decode":
+            first = args["positions"][real] + 1
+            queries = first, np.full(first.shape, args["steps"])
+        elif op[0] == "prefill":
+            queries = np.ones_like(args["seq_len"]), args["seq_len"]
+        elif op[0] == "prefix_prefill":
+            queries = args["prefix_len"] + 1, args["suffix_len"]
+        else:
+            queries = None
+        return int(real.sum()), queries
 
     def _exec_op(self, op: tuple, args: dict):
         kind = op[0]
@@ -3109,27 +2953,10 @@ class TpuEngine:
         """Keep the cache a step returned. Where it carries counts of the
         router's choices (kvcache/state.py: held here, zero-compute), they
         are taken out and queued with the choices the step's ``rows`` made in
-        all."""
+        all (booked behind a chunk's tokens, _land_chunk)."""
         self.k_pages, held, zero = state_pool.take_counts(k_pages)
-        if held is not None:
-            held.copy_to_host_async()
-            if zero is not None:
-                zero.copy_to_host_async()
-            self._pair_counts.append((
-                held, zero, rows * self.mcfg.experts_per_token
-                * self.mcfg.n_expert_layers))
-
-    def _note_pair_counts(self) -> None:
-        """Book the queued counts whose programs are done (every one
-        dispatched before tokens the host has just read is): reading them
-        waits for nothing."""
-        while self._pair_counts and self._pair_counts[0][0].is_ready():
-            held, zero, pairs = self._pair_counts.popleft()
-            held, zero = int(held), 0 if zero is None else int(zero)
-            self.telemetry.moe_routed_pairs["yes"].inc(held)
-            self.telemetry.moe_routed_pairs["no"].inc(pairs - held - zero)
-            if zero:
-                self.telemetry.moe_zero_pairs().inc(zero)
+        self.telemetry.keep_pair_counts(
+            held, zero, rows * self.bound.pairs_per_row)
 
     def _op_mm_prefill(self, bucket, mm_bucket, tokens, seq_len, mm_pad,
                        pos_pad, row, slots, temps, top_k, top_p):
@@ -3160,34 +2987,6 @@ class TpuEngine:
         while b < n:
             b *= 2
         return min(b, self.cfg.max_batch)
-
-    def _ctx_widths(self) -> list[int]:
-        """The pow2 table widths _ctx_bucket can produce, ascending — the
-        single source for both bucketing and the warmup compile matrix."""
-        widths = []
-        w = 4
-        while w < self.max_blocks_per_seq:
-            widths.append(w)
-            w *= 2
-        widths.append(self.max_blocks_per_seq)
-        return widths
-
-    def _ctx_bucket(self, n_blocks: int) -> int:
-        """Pow2 block-table width covering the busiest active slot. The XLA
-        gather decode path materialises [B, width*block] KV rows per layer —
-        O(width) HBM traffic regardless of true context — so narrowing the
-        table to the live context (e.g. 16 of 32 blocks at bench geometry)
-        halves its gather bytes. The Pallas kernel already bounds page DMAs
-        by seq_len; a narrower table is free there. Chunk-overshoot scatter
-        indices past the width clamp (XLA gather/scatter clamp semantics) to
-        the row's tail entry — the sequence's own last block or the trash
-        block — never another row. Opt-in via decode_ctx_buckets."""
-        if not self.cfg.decode_ctx_buckets:
-            return self.max_blocks_per_seq
-        for w in self._ctx_widths():
-            if n_blocks <= w:
-                return w
-        return self.max_blocks_per_seq
 
     def _read_tokens(self, toks) -> np.ndarray:
         """Sampled tokens to the host: the loop's one blocking read of the
@@ -3222,13 +3021,12 @@ class TpuEngine:
             if not lanes:
                 return None
             B = self._batch_bucket(len(lanes))
-            W = self._ctx_bucket(max(len(s.blocks) for _, s in lanes))
             # Compact the lanes into the low rows; padding rows are nobody's
             # (token 0) and keep their block table at the trash block 0
             # (their KV writes land there).
             slots = np.full((B,), self.cfg.max_batch, np.int32)
             positions = np.zeros((B,), np.int32)
-            tables = np.zeros((B, W), np.int32)
+            tables = np.zeros((B, self.max_blocks_per_seq), np.int32)
             for lane, (i, s) in enumerate(lanes):
                 slots[lane] = i
                 positions[lane] = s.position + s.ahead
@@ -3237,7 +3035,7 @@ class TpuEngine:
             reqs += [_DUMMY_REQ] * (B - len(reqs))
             self.telemetry.batch_fill.set(
                 len(lanes) / max(self.cfg.max_batch, 1))
-            shape = f"{B}x{W}"
+            shape = f"{B}x{self.max_blocks_per_seq}"
             timed = ("decode", shape) in self._seen_op_shapes
             steps = self._chunk_steps(shape)
             args = dict(slots=slots, positions=positions, tables=tables,
@@ -3262,7 +3060,7 @@ class TpuEngine:
         with self._phase("decode_wait"):
             sampled = self._read_tokens(chunk.toks)[:chunk.steps]  # [n, B]
         now = self._clock()
-        self._note_pair_counts()
+        self.telemetry.book_pair_counts()
         if chunk.timed:
             # The chunk's own wall time: it could not start before the chunk
             # ahead of it was done, which the host saw at that one's

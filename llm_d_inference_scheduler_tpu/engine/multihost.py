@@ -1,6 +1,6 @@
 """Multi-host serving: leader fan-in over a jax.distributed global mesh.
 
-BASELINE.md config 4 at real scale needs engines whose mesh spans hosts
+A 70B-class deployment at real scale needs engines whose mesh spans hosts
 (e.g. 70B TP-sharded over a v5e-16 multi-host slice). JAX is
 multi-controller SPMD: EVERY process must enter the same jitted computation
 in the same order. The reference has no analogue (its engines are external

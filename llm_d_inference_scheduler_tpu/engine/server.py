@@ -1254,7 +1254,7 @@ class EngineServer:
         return resp
 
     def _vision(self):
-        """Lazy vision tower (encode workers; BASELINE config 5 CPU encode).
+        """Lazy vision tower (encode workers: E/P/D's encode leg, on CPUs).
 
         The projection width follows the SERVED model's d_model (deploy
         encode workers with the same --model as the serving fleet), so the
@@ -1468,7 +1468,7 @@ def main(argv: list[str] | None = None):
                    help="compile prefill/decode before serving")
     p.add_argument("--tp-size", type=int, default=1,
                    help="tensor-parallel degree: shard params + KV pages over "
-                        "this many devices (BASELINE config 4 path)")
+                        "this many devices (a 70B model over a slice)")
     p.add_argument("--pp-size", type=int, default=1,
                    help="pipeline-parallel stages (stage-ring serving; "
                         "composes with --tp-size/--ep-size)")

@@ -58,6 +58,13 @@ STALL_MIN_PERIODS = 8
 STALL_RING = 64
 STALL_WHERE = ("device_wait", "host")
 
+# The counters a model family's programs are booked in (book_program), by
+# their attribute of EngineTelemetry: a new family declares its own below
+# and names them here and in models/binding.py Bound.program_counts.
+PROGRAM_COUNTERS = ("moe_ffn_tokens", "mla_attention_tokens",
+                    "dsa_query_tokens", "dsa_rows", "ssm_tokens",
+                    "ssm_state_updates", "ssm_slot_prefills")
+
 
 class XlaBuilds:
     """Programs JAX built in this process, from `jax.monitoring`'s events.
@@ -185,7 +192,7 @@ class EngineTelemetry:
             "counted on the host at dispatch, a token once a program and not "
             "once an attention sublayer; empty without a latent pool",
             ("form",), registry=self.registry)
-        dsa_query_tokens = Counter(
+        self.dsa_query_tokens = Counter(
             "jetstream:dsa_query_tokens_total",
             "Query tokens put through a block that selects the rows it "
             "attends to (learned sparse attention, models/mla.py), real lanes "
@@ -195,9 +202,7 @@ class EngineTelemetry:
             "counted on the host at dispatch from positions, a token once a "
             "program; empty for a block without an indexer",
             ("form",), registry=self.registry)
-        self.dsa_query_tokens = {f: dsa_query_tokens.labels(form=f)
-                                 for f in ("selected", "all")}
-        dsa_rows = Counter(
+        self.dsa_rows = Counter(
             "jetstream:dsa_rows_total",
             "Cached rows a layer of such a block deals with for those query "
             "tokens: `scored`, the rows a query may see (its context: what "
@@ -205,8 +210,11 @@ class EngineTelemetry:
             "min(context, index_topk) of them; `attended` over `scored` is "
             "the share of the context that attention reads for",
             ("kind",), registry=self.registry)
-        self.dsa_rows = {k: dsa_rows.labels(kind=k)
-                         for k in ("scored", "attended")}
+        # (Both series of each are exposed from the start, at 0.)
+        for form in ("selected", "all"):
+            self.dsa_query_tokens.labels(form)
+        for kind in ("scored", "attended"):
+            self.dsa_rows.labels(kind)
         self.ssm_tokens = Counter(
             "jetstream:ssm_tokens_total",
             "Rows (padded tokens) dispatched through the state-space layers, "
@@ -245,6 +253,10 @@ class EngineTelemetry:
         # never exposes it.
         self.moe_zero_pairs = functools.partial(moe_routed_pairs.labels,
                                                 held="zero")
+        # Those counts as step programs summed them on the device, oldest
+        # first, each with the choices its program made in all (below).
+        self._pair_counts: collections.deque[tuple[Any, Any, int]] = (
+            collections.deque())
         decode_chunks = Counter(
             "jetstream:decode_chunks_total",
             "Decode chunks dispatched: `ahead` while the chunk before was "
@@ -357,6 +369,38 @@ class EngineTelemetry:
             "streamed token: the overshoot of a 100 ms sleep (the router's "
             "LoopLagMonitor on this loop)",
             registry=self.registry, buckets=LOOP_LAG_BUCKETS)
+
+    def book_program(self, counts) -> None:
+        """Book what a model family says a dispatched program runs as
+        (Bound.program_counts): (one of PROGRAM_COUNTERS, its label's value
+        or None, the amount) each."""
+        for name, label, amount in counts:
+            assert name in PROGRAM_COUNTERS, name
+            counter = getattr(self, name)
+            (counter if label is None else counter.labels(label)).inc(amount)
+
+    def keep_pair_counts(self, held, zero, pairs: int) -> None:
+        """Queue one step's counts of its router's choices, still on the
+        device (``held`` None: its cache carries none; ``zero`` None: no
+        such outputs), with the ``pairs`` its rows made in all."""
+        if held is None:
+            return
+        held.copy_to_host_async()
+        if zero is not None:
+            zero.copy_to_host_async()
+        self._pair_counts.append((held, zero, pairs))
+
+    def book_pair_counts(self) -> None:
+        """Book the queued counts whose programs are done (every one
+        dispatched before tokens the host has just read is): reading them
+        waits for nothing."""
+        while self._pair_counts and self._pair_counts[0][0].is_ready():
+            held, zero, pairs = self._pair_counts.popleft()
+            held, zero = int(held), 0 if zero is None else int(zero)
+            self.moe_routed_pairs["yes"].inc(held)
+            self.moe_routed_pairs["no"].inc(pairs - held - zero)
+            if zero:
+                self.moe_zero_pairs().inc(zero)
 
     def watch_xla_builds(self) -> None:
         """Count the programs JAX builds from now on, and show the count

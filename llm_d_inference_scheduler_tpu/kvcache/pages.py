@@ -48,7 +48,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.attention import (latent_paged_decode_attention,
                              paged_decode_attention)
 from ..ops.pallas_dsa import sparse_latent_paged_decode_attention_pallas
-from ..ops.pallas_latent_attention import latent_paged_decode_attention_pallas
+from ..ops.pallas_latent_attention import (
+    RUN_PAGES, latent_paged_decode_attention_pallas)
 from ..ops.pallas_paged_attention import paged_decode_attention_pallas
 from ..ops.sparse_attention import sparse_latent_paged_decode_attention
 from . import state as state_pool
@@ -74,6 +75,13 @@ class PageGeometry:
     # Values of an indexer key, in a pool of its own beside the latent one;
     # 0 = no such pool.
     index_dim: int = 0
+    # Whether the model's step programs count their router's choices
+    # (``counts_zero``: the zero-compute ones too): see :func:`alloc`.
+    counted: bool = False
+    counts_zero: bool = False
+    # What state-space layers keep an engine slot beside the pages
+    # (kvcache/state.py); None without them, and for no engine's pool.
+    state: state_pool.StateGeometry | None = None
 
     @classmethod
     def for_model(cls, model: Any, n_blocks: int,
@@ -82,8 +90,9 @@ class PageGeometry:
         """A pool of ``n_blocks`` pages at ``model``'s widths (anything with
         n_layers, kv_block_size, n_kv_heads, head_dim and dtype; a
         ``latent_dim`` above 0 asks for the latent kind; ``n_kv_layers``
-        where not every layer keeps pages). Never fewer than two blocks: the
-        trash block and one to use."""
+        where not every layer keeps pages; ``tallies_choices`` and
+        ``n_zero_experts`` where its programs count). Never fewer than two
+        blocks: the trash block and one to use."""
         n_blocks = max(n_blocks, 2)
         return cls(getattr(model, "n_kv_layers", model.n_layers), n_blocks,
                    model.kv_block_size,
@@ -91,16 +100,21 @@ class PageGeometry:
                    str(jnp.dtype(dtype or model.dtype)),
                    max_blocks_per_seq or n_blocks - 1,
                    getattr(model, "latent_dim", 0),
-                   getattr(model, "index_dim", 0))
+                   getattr(model, "index_dim", 0),
+                   bool(getattr(model, "tallies_choices", False)),
+                   bool(getattr(model, "n_zero_experts", 0)))
 
     @classmethod
     def for_engine(cls, model: Any, max_batch: int, max_model_len: int,
                    hbm_kv_blocks: int = 0) -> "PageGeometry":
         """The engine's pool: ``hbm_kv_blocks`` where given, else room for
-        every lane at full length beside the trash block."""
+        every lane at full length beside the trash block; and a state pool
+        of ``max_batch`` slots where the model has state-space layers."""
         per_seq = -(-max_model_len // model.kv_block_size)
-        return cls.for_model(
-            model, hbm_kv_blocks or 1 + max_batch * per_seq, per_seq)
+        return dataclasses.replace(
+            cls.for_model(model, hbm_kv_blocks or 1 + max_batch * per_seq,
+                          per_seq),
+            state=state_pool.StateGeometry.for_engine(model, max_batch))
 
     @property
     def row_width(self) -> int:
@@ -152,6 +166,48 @@ class PageGeometry:
         """Bytes of the pair of pools, or of the one latent pool."""
         return self.n_blocks * self.block_bytes
 
+    @property
+    def run_pages(self) -> int | None:
+        """Table entries the latent decode kernels fetch as one copy where
+        they name adjacent blocks (None: no latent pool)."""
+        return RUN_PAGES if self.latent_dim else None
+
+    @property
+    def one_chip_only(self) -> str | None:
+        """What this cache keeps that lives on the unsharded one-chip engine
+        alone, in words (None: plain K/V pages): a latent pool, a state pool
+        and an indexer's key pool have no sharding rule, stage split or wire
+        format yet (ROADMAP R7, R8; a selection over sharded keys would need
+        every shard's scores)."""
+        if self.index_dim:
+            return ("a latent (MLA) page pool and its indexer's key pool "
+                    "beside it, under one block table")
+        if self.latent_dim:
+            return "a latent (MLA) page pool"
+        if self.state:
+            return "a recurrent state pool beside its pages"
+        return None
+
+    def describe(self) -> dict[str, Any]:
+        """What the cache holds, as /health's ``settings`` say it (every key
+        on every engine)."""
+        return {
+            # The cache layers (two a double layer), a token's bytes in one
+            # of them, the layout's padding counted, and the whole pool's.
+            "kv_layers": self.n_layers,
+            "kv_token_bytes": self.token_bytes,
+            "kv_pool_bytes": self.pool_bytes,
+            "kv_run_pages": self.run_pages,
+            # An indexer's key pool (kv_token_bytes stays the latent row's).
+            "index_token_bytes": self.index_token_bytes,
+            "index_pool_bytes": self.index_pool_bytes,
+            # What state-space layers keep a slot, and turn off.
+            "state_slot_bytes": self.state.slot_bytes if self.state else 0,
+            "state_pool_bytes": self.state.pool_bytes if self.state else 0,
+            "off_for_state_layers": (list(state_pool.OFF_FOR_STATE_LAYERS)
+                                     if self.state else []),
+        }
+
 
 # ---- where a pool lives -----------------------------------------------------
 
@@ -172,31 +228,32 @@ def page_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, page_spec(mesh))
 
 
-def alloc(geom: PageGeometry, *, device=None, sharding=None,
-          state: state_pool.StateGeometry | None = None,
-          counted: bool = False, counts_zero: bool = False
+def alloc(geom: PageGeometry, *, device=None, sharding=None
           ) -> tuple[Any, jax.Array | None]:
     """Zeroed ``(k_pages, v_pages)``, on one device or laid out by
     ``sharding`` (made in place, shard by shard); ``(pool, None)`` for a
-    latent geometry, which has no sharding rule yet. With ``state`` (a model
-    with state-space layers) the pair is ``(cache, None)``: the page pools
-    and the state pool as one value (kvcache/state.py), unsharded too. So it
-    is with ``counted`` (a model whose step programs count their router's
-    choices, ``counts_zero``: the zero-compute ones too), whose counts that
-    value carries, and with an indexer's key pool (``geom.index_dim``), which
-    rides there beside the latent one."""
-    if state is not None or counted or geom.index_dim:
-        if sharding is not None or (state is not None and geom.latent_dim):
+    latent geometry, which has no sharding rule yet. With ``geom.state``,
+    ``geom.counted`` or an indexer's key pool (``geom.index_dim``) the pair
+    is ``(cache, None)``: the pools, the state pool and a step's counts as
+    one value (kvcache/state.py), unsharded too."""
+    if geom.state is not None or geom.counted or geom.index_dim:
+        if sharding is not None or (geom.state is not None
+                                    and geom.latent_dim):
             raise ValueError("a state pool lies beside an unsharded K/V page "
                              "pool only, and a step's counts ride with an "
                              "unsharded pool only: no sharding rule for "
                              "either")
         idx = (jnp.zeros(geom.index_shape, jnp.dtype(geom.dtype),
                          device=device) if geom.index_dim else None)
-        plain = dataclasses.replace(geom, index_dim=0)
-        return state_pool.alloc(state, *alloc(plain, device=device),
-                                device=device, counts_zero=counts_zero,
+        return state_pool.alloc(geom.state, *_alloc_pools(geom, device),
+                                device=device, counts_zero=geom.counts_zero,
                                 idx=idx), None
+    return _alloc_pools(geom, device, sharding)
+
+
+def _alloc_pools(geom: PageGeometry, device=None, sharding=None
+                 ) -> tuple[jax.Array, jax.Array | None]:
+    """The K/V pair, or the latent pool and None, alone."""
     dtype = jnp.dtype(geom.dtype)
     if geom.latent_dim:
         if sharding is not None:
@@ -367,6 +424,14 @@ def use_kernel(head_dim: int, *, asked: bool | None, interpret: bool,
             "(128) — Mosaic cannot slice the page DMA; leave the option "
             "unset to let the engine choose")
     return asked
+
+
+def attention_for(geom: PageGeometry, *, kernel: bool, interpret: bool):
+    """The decode attention of ``geom``'s kind of pool (a block's
+    ``attention_fn``), bound to what :func:`use_kernel` decided."""
+    return functools.partial(
+        latent_decode_attention if geom.latent_dim else decode_attention,
+        kernel=kernel, interpret=interpret)
 
 
 def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

@@ -66,6 +66,16 @@ import numpy as np
 from ..ops import pallas_ssm
 
 
+# What an engine whose model has state-space layers turns off, as /health
+# lists it (``settings.off_for_state_layers``).
+OFF_FOR_STATE_LAYERS = (
+    "prefix hits (a cached block prefix has no recurrent state to start from)",
+    "kv events (no block is advertised to a router)",
+    "tp/ep/pp and multi-process meshes",
+    "roles other than both",
+    "KV export and import")
+
+
 @dataclasses.dataclass(frozen=True)
 class StateGeometry:
     """What the state pool's shapes follow from."""

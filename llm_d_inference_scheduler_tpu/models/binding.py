@@ -1,0 +1,176 @@
+"""What an engine binds of a model family, and what its programs count as.
+
+``family(cfg)`` names the module whose ``init_params``, ``forward``,
+``decode_step`` and ``prefill_with_prefix`` serve a configuration.
+:func:`bind` is the other half of the seam: it decides, once, which form each
+of the family's kernels takes on the device an engine is (the rules are the
+kernels' own), and the value it returns answers what the engine asks of a
+family afterwards: ``model_for(tokens)``, ``program_counts(...)`` (booked by
+``EngineTelemetry.book_program``), ``pairs_per_row``, ``describe()``.
+
+Nothing here imports ``engine/``. A new family brings its rules here, its
+block to ``models/``, its kernels to ``ops/``, its cache to ``kvcache/`` and
+its counters' declarations to ``engine/telemetry.py``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import numpy as np
+
+from ..ops import pallas_moe, pallas_ssm
+from . import hybrid, llama, mla
+from .configs import ModelConfig
+
+
+def family(cfg: ModelConfig):
+    """The module that holds ``cfg``'s block: ``init_params``, ``forward``,
+    ``decode_step`` and ``prefill_with_prefix`` under one set of signatures.
+    A layer pattern (layer_pattern set) names models/hybrid.py, whose layers
+    are state-space, expert and attention mixers in that pattern; latent
+    attention (kv_lora_rank > 0) names models/mla.py; everything else is
+    models/llama.py's block."""
+    if cfg.layer_pattern:
+        return hybrid
+    return mla if cfg.kv_lora_rank else llama
+
+
+def selection_counts(first: np.ndarray, n: np.ndarray, topk: int
+                     ) -> dict[str, int]:
+    """What runs of query tokens put through a block that selects the rows
+    it attends to (``jetstream:dsa_*``). Run i is ``n[i]`` queries of contexts
+    ``first[i]``, ``first[i]`` + 1, ...: a query of context c has c rows
+    ``scored`` and min(c, topk) ``attended``, and is ``selected`` where c
+    outnumbers ``topk``, else ``all``."""
+    first, n = first.astype(np.int64), n.astype(np.int64)
+    last = first + n - 1
+    scored = int(np.sum((first + last) * n // 2))
+    # The contexts beyond topk: a query of context c attends to topk rows
+    # and leaves c - topk.
+    lo = np.maximum(first, topk + 1)
+    m = np.maximum(last - lo + 1, 0)
+    left = int(np.sum((lo + last) * m // 2 - m * topk))
+    return {"selected": int(m.sum()), "all": int((n - m).sum()),
+            "scored": scored, "attended": scored - left}
+
+
+@dataclasses.dataclass(frozen=True)
+class Bound:
+    """A model as one engine serves it (:func:`bind`)."""
+
+    module: Any             # family(mcfg)
+    mcfg: ModelConfig       # ssm_impl and index_impl resolved, the FFN dense
+    grouped: ModelConfig    # the same with the MoE FFN's grouped form
+    platform: str
+    interpret: bool
+    sharded: bool
+
+    def moe_grouped(self, tokens: int) -> bool:
+        """Whether a program of ``tokens`` rows (batch x sequence, padded)
+        computes the chosen experts' rows alone."""
+        m = self.mcfg
+        return pallas_moe.use_grouped(
+            tokens, n_experts=m.n_experts,
+            experts_per_token=m.experts_per_token,
+            d_model=m.moe_latent_dim or m.d_model,
+            d_ff=m.moe_d_ff or m.d_ff, platform=self.platform,
+            interpret=self.interpret, sharded=self.sharded)
+
+    def model_for(self, tokens: int) -> ModelConfig:
+        """The model as a program of ``tokens`` rows (batch x sequence,
+        padded) traces it: the MoE FFN in the form the shape calls for."""
+        return self.grouped if self.moe_grouped(tokens) else self.mcfg
+
+    @property
+    def pairs_per_row(self) -> int:
+        """(Token, expert) choices the routers make of one row of a step."""
+        return self.mcfg.experts_per_token * self.mcfg.n_expert_layers
+
+    def program_counts(self, kind: str, rows: int, steps: int, *,
+                       real: int = 0,
+                       queries: tuple[np.ndarray, np.ndarray] | None = None
+                       ) -> list[tuple[str, str | None, int]]:
+        """What one dispatched program runs as: (a name of
+        ``engine/telemetry.PROGRAM_COUNTERS``, its label's value or None,
+        the amount) each. ``kind`` is the engine's op, ``rows`` the rows
+        (padded tokens) of one of its ``steps``; of a program that serves
+        requests, ``real`` is how many of its sequences are somebody's and
+        ``queries`` their runs of query tokens (:func:`selection_counts`)."""
+        m, decode, tokens = self.mcfg, kind == "decode", rows * steps
+        counts: list[tuple[str, str | None, int]] = []
+        if m.n_experts:
+            # Under the form its shape traced to.
+            counts.append(("moe_ffn_tokens", "grouped" if self.moe_grouped(
+                rows) else "dense", tokens))
+        if m.kv_lora_rank:
+            # models/mla.py: one query a sequence is absorbed, a run of them
+            # expanded.
+            counts.append(("mla_attention_tokens",
+                           "absorbed" if decode else "expanded", tokens))
+            if m.index_topk and queries is not None:
+                got = selection_counts(*queries, m.index_topk)
+                counts += [("dsa_query_tokens", "selected", got["selected"]),
+                           ("dsa_query_tokens", "all", got["all"]),
+                           ("dsa_rows", "scored", got["scored"]),
+                           ("dsa_rows", "attended", got["attended"])]
+        if m.n_state_layers:
+            # models/hybrid.py: one position a sequence is the step form, a
+            # run of them the scan form; a first window starts its slots.
+            counts.append(("ssm_tokens", "step" if decode else "scan",
+                           tokens))
+            if decode:
+                counts.append(("ssm_state_updates", m.ssm_impl.split("_")[0],
+                               tokens * m.n_state_layers))
+            if kind == "prefill" and real:
+                counts.append(("ssm_slot_prefills", None, real))
+        return counts
+
+    def describe(self) -> dict[str, Any]:
+        """The family's part of /health's ``settings`` (every key on every
+        engine; 0 or None where the model has no such thing)."""
+        m = self.mcfg
+        return {
+            # A block that selects the rows it attends to (0: none).
+            "index_topk": m.index_topk,
+            "index_scores": m.index_impl if m.index_topk else None,
+            # The experts this chip holds of those its router scores, and
+            # the router's outputs that compute nothing.
+            "experts_first": m.held_experts[0],
+            "experts_held": m.held_experts[1],
+            "zero_experts": m.n_zero_experts,
+            # How a decode step fetches its slots' recurrent states.
+            "state_update": m.ssm_impl if m.n_state_layers else None,
+        }
+
+
+def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
+         sharded: bool = False, forced: dict[str, str] | None = None
+         ) -> Bound:
+    """``mcfg`` as an engine on ``platform`` serves it (an argument, not
+    looked up: a rehearsal binds for "tpu" on a CPU host): ``interpret``
+    where it runs its kernels through the interpreter (tests on the CPU),
+    ``sharded`` where its weights or pools span devices. How a decode step
+    fetches its slots' recurrent states (``ssm_impl``) is
+    ``pallas_ssm.use_kernel``'s; a selecting block's indexer (``index_impl``)
+    runs its kernel (ops/pallas_dsa.py) on a TPU, where the per-head products
+    must not reach HBM, and the plain form on the CPU; the MoE FFN's form is
+    chosen per program (``Bound.model_for``). ``forced`` names form fields a
+    caller sets over the rules (a comparison of two forms on one device)."""
+    forms: dict[str, str] = {}
+    if mcfg.n_state_layers:
+        kernel = pallas_ssm.use_kernel(
+            mcfg.ssm_state, mcfg.ssm_head_dim, platform=platform,
+            sharded=sharded, interpret=interpret)
+        forms["ssm_impl"] = ("gathered" if not kernel else
+                             "kernel_interpret" if interpret else "kernel")
+    if mcfg.index_topk:
+        forms["index_impl"] = ("kernel_interpret" if interpret else
+                               "kernel" if platform == "tpu" else "xla")
+    mcfg = dataclasses.replace(mcfg, **{**forms, **(forced or {})})
+    return Bound(
+        module=family(mcfg), mcfg=mcfg,
+        grouped=dataclasses.replace(
+            mcfg, moe_impl="grouped_interpret" if interpret else "grouped"),
+        platform=platform, interpret=interpret, sharded=sharded)

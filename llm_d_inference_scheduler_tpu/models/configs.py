@@ -31,8 +31,9 @@ class ModelConfig:
     # MoE FFN form: "dense" (dense-over-experts einsums — the correctness
     # baseline, required under expert-parallel shard_map) | "grouped" (the
     # chosen experts' rows alone, ops/pallas_moe.py) | "grouped_interpret"
-    # (same kernel, interpreter — CPU tests). The engine sets it program by
-    # program from the shape (pallas_moe.use_grouped); tests force a form.
+    # (same kernel, interpreter — CPU tests). models.bind hands the engine
+    # both, program by program from the shape (pallas_moe.use_grouped); tests
+    # force a form.
     moe_impl: str = "dense"
     # Qwen3 family: explicit head_dim decoupled from d_model/n_heads, and
     # per-head RMSNorm on q/k before RoPE.
@@ -75,8 +76,9 @@ class ModelConfig:
     # How a decode step fetches its slots' rows of the state pool (kvcache/
     # state.recur): "gathered" (by slot, in XLA: the CPU's way and the plain
     # form) | "kernel" (in place in the pool, ops/pallas_ssm.py) |
-    # "kernel_interpret" (the kernel through the interpreter: CPU tests). The
-    # engine sets it from what it is (pallas_ssm.use_kernel); tests force one.
+    # "kernel_interpret" (the kernel through the interpreter: CPU tests).
+    # models.bind sets it from what the engine is (pallas_ssm.use_kernel);
+    # tests force one.
     ssm_impl: str = "gathered"
     # "E": routed experts of width moe_d_ff between a projection down to
     # moe_latent_dim and one back up, not gated (relu squared), beside a
@@ -128,7 +130,8 @@ class ModelConfig:
     # The form of such a block's programs: "xla" (ops/sparse_attention.py:
     # the CPU's way and the plain form) | "kernel" (ops/pallas_dsa.py: the
     # indexer's scores and a window's attention over the selected rows) |
-    # "kernel_interpret" (CPU tests). The engine sets it from what it is.
+    # "kernel_interpret" (CPU tests). models.bind sets it from what the
+    # engine is.
     index_impl: str = "xla"
 
     @property

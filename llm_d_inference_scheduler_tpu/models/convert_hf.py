@@ -405,18 +405,21 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--dtype", default=None, help="override param dtype")
     args = ap.parse_args(argv)
 
-    from transformers import AutoConfig
-
-    from ..engine.checkpoint import save_params
-
     import dataclasses
+
+    import orbax.checkpoint as ocp
+    from transformers import AutoConfig
 
     hf_cfg = AutoConfig.from_pretrained(args.src, local_files_only=True)
     cfg = config_from_hf(hf_cfg)
     if args.dtype:
         cfg = dataclasses.replace(cfg, dtype=args.dtype)
     params = convert_state_dict(load_hf_state_dict(args.src), cfg)
-    save_params(args.out, params)
+    # (engine/checkpoint.load_params reads it back: models/ imports nothing
+    # from engine/.)
+    ckptr = ocp.StandardCheckpointer()
+    ckptr.save(os.path.abspath(args.out), params)
+    ckptr.wait_until_finished()
     with open(os.path.join(args.out, "model_config.json"), "w") as f:
         json.dump({k: getattr(cfg, k) for k in cfg.__dataclass_fields__}, f,
                   indent=2)

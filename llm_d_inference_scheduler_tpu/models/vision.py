@@ -1,4 +1,4 @@
-"""Vision encoder for E/P/D multimodal serving (BASELINE config 5 shape:
+"""Vision encoder for E/P/D multimodal serving (the E/P/D deployment:
 CPU/TPU encode workers producing embeddings for TPU prefill).
 
 The reference routes multimodal requests to encode workers but the towers

@@ -70,7 +70,7 @@ def dryrun_serve(cfg: ModelConfig, devices, tp: int = 2, ep: int = 1,
     """Prefill + N decode steps with TP/EP-sharded params/pages and a
     dp-sharded batch; asserts logits match the unsharded single-device path.
 
-    Driver-facing stepping stone to BASELINE.md config 4 (70B TP-sharded
+    Driver-facing stepping stone to a 70B deployment (TP-sharded
     decode): proves the serving jits compile and execute SPMD over a mesh.
     """
     mesh = make_serve_mesh(devices, tp=tp, ep=ep)

@@ -29,7 +29,7 @@ class MetricsDataSource(PluginBase):
         self._client: httpx.AsyncClient | None = None
         # TLS verification for https scrape targets: default skip-verify
         # (pod-local certs, the reference scrape client's default), or a CA
-        # bundle for real verification (tlsutil.client_verify; ADVICE r5).
+        # bundle for real verification (tlsutil.client_verify).
         self._insecure_skip_verify = True
         self._ca_cert_path: str | None = None
 

@@ -217,7 +217,7 @@ class Gateway:
         # Outbound TLS verification policy for router-side client legs
         # (upstream proxy, /debug/traces + /v1/models fan-out). Default:
         # skip-verify (in-cluster pod-local certs); `tlsClient.caCertPath`
-        # turns real verification on (ADVICE r5).
+        # turns real verification on.
         from .tlsutil import client_verify
 
         tc = cfg.tls_client or {}
@@ -514,7 +514,7 @@ class Gateway:
         if self.flow_controller is not None:
             await self.flow_controller.start()
         # Verification policy from tlsClient config (default skip-verify:
-        # pod-local certs — no longer hardcoded, ADVICE r5).
+        # pod-local certs — configured, not hardcoded).
         self._client = httpx.AsyncClient(timeout=httpx.Timeout(300.0, connect=5.0),
                                          verify=self._client_tls_verify)
         # The proxy hop uses aiohttp's client: its C http parser costs a

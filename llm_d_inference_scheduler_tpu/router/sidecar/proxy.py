@@ -118,7 +118,7 @@ class Sidecar:
             web.get("/debug/traces", self._traces),
             web.get("/v1/models", self._proxy_get),
             # Streaming: the precise-prefix scorer's SSE subscriber must work
-            # against sidecar-fronted decode endpoints too (ADVICE r1).
+            # against sidecar-fronted decode endpoints too.
             web.get("/kv_events", self._proxy_get_stream),
         ])
         self._runner: web.AppRunner | None = None

@@ -33,7 +33,6 @@ does not name.
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
@@ -101,7 +100,7 @@ def main(argv=None) -> int:
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
     from llm_d_inference_scheduler_tpu.kvcache import state as kvstate
-    from llm_d_inference_scheduler_tpu.models import family
+    from llm_d_inference_scheduler_tpu.models import bind
 
     if args.config_file:
         import types
@@ -130,24 +129,21 @@ def main(argv=None) -> int:
                        max_model_len=args.max_model_len,
                        decode_chunk=args.decode_chunk, pallas_attention=True,
                        hbm_kv_blocks=args.hbm_kv_blocks)
-    mcfg = cfg.model_config
-    # The jitted bodies are methods; they read only the two configs, how to
-    # attend and the (absent) pipeline mesh, so a bare instance carries them
-    # — building a real engine would materialise the weights on the host.
+    # The jitted bodies are methods; they read only the engine's config, the
+    # bound model, how to attend and the (absent) pipeline mesh, so a bare
+    # instance carries them — building a real engine would materialise the
+    # weights on the host.
     eng = object.__new__(TpuEngine)
-    eng.cfg, eng.mcfg, eng.pp_mesh, eng._prefill_fns = cfg, mcfg, None, {}
-    eng.model = model = family(mcfg)
+    eng.cfg, eng.pp_mesh, eng._prefill_fns = cfg, None, {}
+    # (Bound for the described chip, not this host's CPU.)
+    eng.bound = bind(cfg.model_config, platform="tpu")
+    eng.model = model = eng.bound.module
+    eng.mcfg = mcfg = eng.bound.mcfg
     eng.geom = geom = kvpages.PageGeometry.for_engine(
         mcfg, cfg.max_batch, cfg.max_model_len, cfg.hbm_kv_blocks)
-    eng.state_geom = state_geom = kvstate.StateGeometry.for_engine(
-        mcfg, cfg.max_batch)
-    eng._decode_attention = functools.partial(
-        kvpages.latent_decode_attention if geom.latent_dim
-        else kvpages.decode_attention, kernel=True)
-    eng._bind_state_form("tpu")  # the described chip, not this host's CPU
-    eng._bind_index_form("tpu")
-    eng._bind_moe_form("tpu")
-    mcfg = eng.mcfg
+    state_geom = geom.state
+    eng._decode_attention = kvpages.attention_for(geom, kernel=True,
+                                                  interpret=False)
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(
@@ -163,12 +159,12 @@ def main(argv=None) -> int:
                 pages, pages, sds(state_geom.ssm_shape, jnp.float32),
                 sds(state_geom.conv_shape, jnp.dtype(state_geom.dtype)),
                 slots=sds((rows,), jnp.int32), held=sds((), jnp.int32)), None)
-        if mcfg.tallies_choices:   # a latent pool that rides with counts
+        if geom.counted:   # a latent pool that rides with counts
             return (kvstate.Cache(
                 pages, None, None, None, slots=sds((rows,), jnp.int32),
                 held=sds((), jnp.int32),
-                zero=sds((), jnp.int32) if mcfg.n_zero_experts else None,
-                counts_zero=bool(mcfg.n_zero_experts),
+                zero=sds((), jnp.int32) if geom.counts_zero else None,
+                counts_zero=geom.counts_zero,
                 idx=(sds(geom.index_shape, jnp.dtype(geom.dtype))
                      if geom.index_dim else None)), None)
         return (pages, None) if geom.latent_dim else (pages, pages)

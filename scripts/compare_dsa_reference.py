@@ -15,8 +15,8 @@ runs, the expanded form attends by the mask), then `--decode-steps`
 teacher-forced decode steps of both lanes at once (`decode_step` with the
 attention the engine binds: the indexer over the lanes' key pages, the
 selection, the Pallas kernel over the selected rows of the latent pages). The
-MoE form is `TpuEngine._model_for`'s for each shape, the indexer's form
-`TpuEngine._bind_index_form`'s, the pools ride in the `kvcache/state.Cache`.
+MoE form and the indexer's form are `models.bind`'s for this device (the MoE
+form shape by shape), the pools ride in the `kvcache/state.Cache`.
 Jitted here to hand back logits before the sampler, the router's choices and
 every query's selected rows.
 
@@ -133,9 +133,8 @@ def main(argv=None) -> int:
     import numpy as np
 
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages, state
-    from llm_d_inference_scheduler_tpu.models import configs, mla
+    from llm_d_inference_scheduler_tpu.models import bind, configs, mla
     from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
     from llm_d_inference_scheduler_tpu.utils.compile_cache import (
         configure_compile_cache)
@@ -156,13 +155,11 @@ def main(argv=None) -> int:
     cfg = EngineConfig(model=args.model, max_batch=B,
                        max_model_len=args.max_model_len,
                        pallas_attention=True, pallas_interpret=not on_tpu)
-    # The engine's own rules for a program's forms, without its servers and
-    # threads (as scripts/aot_rehearsal.py carries them).
-    eng = object.__new__(TpuEngine)
-    eng.cfg, eng.mcfg = cfg, configs.get_config(args.model)
-    eng._bind_index_form(device.platform)
-    eng._bind_moe_form(device.platform)
-    mcfg = eng.mcfg
+    # The forms an engine on this device binds (models/binding.py), without
+    # the engine.
+    bound = bind(configs.get_config(args.model), platform=device.platform,
+                 interpret=cfg.pallas_interpret)
+    mcfg = bound.mcfg
     attend = functools.partial(pages.latent_decode_attention, kernel=True,
                                interpret=not on_tpu)
     geom = pages.PageGeometry.for_engine(mcfg, B, cfg.max_model_len)
@@ -186,7 +183,7 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(3,))
     def prefill(params, tokens, n, cache, row):
         logits, (fresh, _), (routes, picked) = mla.forward(
-            params, eng._model_for(tokens.size), tokens, want_kv=True,
+            params, bound.model_for(tokens.size), tokens, want_kv=True,
             want_routes=True)
         cache, _ = pages.write_sequences(cache, None, fresh, None, row, n)
         return logits[0, n[0] - 1], routes, picked[:, 0], cache
@@ -194,14 +191,14 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(4,), static_argnums=(6,))
     def window(params, tokens, n, written, cache, row, prior_blocks):
         logits, cache, _, (routes, picked) = mla.prefill_with_prefix(
-            params, eng._model_for(tokens.size), tokens, n, written, cache,
+            params, bound.model_for(tokens.size), tokens, n, written, cache,
             None, row, row[:, :prior_blocks], want_routes=True)
         return logits[0], routes, picked[:, 0], cache
 
     @functools.partial(jax.jit, donate_argnums=(3,))
     def decode(params, tokens, positions, cache, tables):
         logits, cache, _, (routes, picked) = mla.decode_step(
-            params, eng._model_for(tokens.size), tokens, positions, cache,
+            params, bound.model_for(tokens.size), tokens, positions, cache,
             None, tables, attention_fn=attend, want_routes=True)
         return logits, routes, picked, cache
 
@@ -226,7 +223,7 @@ def main(argv=None) -> int:
         t0 = time.monotonic()
         params = jax.jit(lambda k: mla.init_params(mcfg, k))(
             jax.random.key(seed))
-        cache, _ = pages.alloc(geom, device=device, counted=True)
+        cache, _ = pages.alloc(geom, device=device)
         seq = np.asarray(jax.random.randint(
             jax.random.key(seed + 1000), (max(lens) + K,), 0, 257))
         tables = np.stack(

@@ -11,7 +11,7 @@ expert matmuls, the two smaller the dense-over-held einsums), one window that
 continues a cached prefix, and decode steps of all `--max-batch` lanes at once
 through the engine's pool at `--max-batch` x `--max-model-len`. The program
 side runs what `TpuEngine`'s step functions trace -- `models.mla.forward` /
-`prefill_with_prefix` / `decode_step` with the MoE form `TpuEngine._model_for`
+`prefill_with_prefix` / `decode_step` with the MoE form `models.bind(...).model_for`
 gives each shape, the decode attention the engine binds (the Pallas latent
 kernel on a TPU, 64 heads), the page writes of `kvcache/pages.py`, the pool in
 the `kvcache/state.Cache` that carries the counts -- jitted here to hand back
@@ -124,9 +124,8 @@ def main(argv=None) -> int:
     import numpy as np
 
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages, state
-    from llm_d_inference_scheduler_tpu.models import configs, mla
+    from llm_d_inference_scheduler_tpu.models import bind, configs, mla
     from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
     from llm_d_inference_scheduler_tpu.utils.compile_cache import (
         configure_compile_cache)
@@ -146,11 +145,10 @@ def main(argv=None) -> int:
     cfg = EngineConfig(model=args.model, max_batch=args.max_batch,
                        max_model_len=args.max_model_len,
                        pallas_attention=True, pallas_interpret=not on_tpu)
-    # The engine's own rules for a program's forms, without its servers and
-    # threads (as scripts/aot_rehearsal.py carries them).
-    eng = object.__new__(TpuEngine)
-    eng.cfg, eng.mcfg = cfg, mcfg
-    eng._bind_moe_form(device.platform)
+    # The forms an engine on this device binds (models/binding.py), without
+    # the engine.
+    bound = bind(mcfg, platform=device.platform,
+                 interpret=cfg.pallas_interpret)
     attend = functools.partial(pages.latent_decode_attention, kernel=True,
                                interpret=not on_tpu)
     geom = pages.PageGeometry.for_engine(mcfg, cfg.max_batch,
@@ -177,7 +175,7 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(4,))
     def prefill(params, tokens, n, at, cache, row):
         logits, (fresh, _), routes = mla.forward(
-            params, eng._model_for(tokens.size), tokens, want_kv=True,
+            params, bound.model_for(tokens.size), tokens, want_kv=True,
             want_routes=True)
         cache, _ = pages.write_sequences(cache, None, fresh, None, row, n)
         return logits[0, at], routes, cache
@@ -187,14 +185,14 @@ def main(argv=None) -> int:
         # The engine's program: it hands back the last valid position alone,
         # so a shorter `n` looks at an earlier one.
         logits, cache, _, routes = mla.prefill_with_prefix(
-            params, eng._model_for(tokens.size), tokens, n, written, cache,
+            params, bound.model_for(tokens.size), tokens, n, written, cache,
             None, row, row[:, :prior_blocks], want_routes=True)
         return logits[0], routes, cache
 
     @functools.partial(jax.jit, donate_argnums=(3,))
     def decode(params, tokens, positions, cache, tables):
         logits, cache, _, routes = mla.decode_step(
-            params, eng._model_for(tokens.size), tokens, positions, cache,
+            params, bound.model_for(tokens.size), tokens, positions, cache,
             None, tables, attention_fn=attend, want_routes=True)
         return logits, routes, cache
 
@@ -208,8 +206,7 @@ def main(argv=None) -> int:
         t0 = time.monotonic()
         params = jax.jit(lambda k: mla.init_params(mcfg, k))(
             jax.random.key(seed))
-        cache, _ = pages.alloc(geom, device=device, counted=True,
-                               counts_zero=bool(mcfg.n_zero_experts))
+        cache, _ = pages.alloc(geom, device=device)
         seq = jax.random.randint(jax.random.key(seed + 1000),
                                  (longest + K,), 0, 257)
         per_seq = geom.max_blocks_per_seq
@@ -251,7 +248,7 @@ def main(argv=None) -> int:
             chose = [np.asarray(routes)[:, :n]]
             if keep:
                 looked[f"prefill_{n}_in_{bucket}" + (
-                    "_grouped" if eng._moe_grouped(bucket) else "")] = (
+                    "_grouped" if bound.moe_grouped(bucket) else "")] = (
                         sum(plan), at, np.asarray(got))
             if len(plan) == 2:
                 m = plan[1]

@@ -7,7 +7,7 @@ benchmark configuration's widths and a cell's sizes.
 What is compared. One sequence of random byte-range token ids, `--prompt-tokens`
 long plus `--decode-steps`. The program side runs what `TpuEngine`'s step
 functions trace — `models.mla.forward` / `prefill_with_prefix` / `decode_step`
-with the MoE form `TpuEngine._model_for` gives each shape, the decode attention
+with the MoE form `models.bind(...).model_for` gives each shape, the decode attention
 the engine binds (the Pallas latent kernel on a TPU), the page writes of
 `kvcache/pages.py`, the engine's pool at `--max-batch` x `--max-model-len` —
 jitted here to hand back logits before the sampler and the experts each token
@@ -111,9 +111,8 @@ def main(argv=None) -> int:
     import numpy as np
 
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages
-    from llm_d_inference_scheduler_tpu.models import configs, mla
+    from llm_d_inference_scheduler_tpu.models import bind, configs, mla
     from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
     from llm_d_inference_scheduler_tpu.utils.compile_cache import (
         configure_compile_cache)
@@ -134,11 +133,10 @@ def main(argv=None) -> int:
                        max_model_len=args.max_model_len,
                        prefill_chunk=args.window,
                        pallas_attention=True, pallas_interpret=not on_tpu)
-    # The engine's own rules for a program's forms, without its servers and
-    # threads (as scripts/aot_rehearsal.py carries them).
-    eng = object.__new__(TpuEngine)
-    eng.cfg, eng.mcfg = cfg, mcfg
-    eng._bind_moe_form(device.platform)
+    # The forms an engine on this device binds (models/binding.py), without
+    # the engine.
+    bound = bind(mcfg, platform=device.platform,
+                 interpret=cfg.pallas_interpret)
     attend = functools.partial(pages.latent_decode_attention, kernel=True,
                                interpret=not on_tpu)
     geom = pages.PageGeometry.for_engine(mcfg, cfg.max_batch,
@@ -158,7 +156,7 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(3,))
     def first_window(params, tokens, at, pool, row):
         logits, (rows, _), routes = mla.forward(
-            params, eng._model_for(tokens.size), tokens, want_kv=True,
+            params, bound.model_for(tokens.size), tokens, want_kv=True,
             want_routes=True)
         pool, _ = pages.write_sequences(
             pool, None, rows, None, row,
@@ -173,7 +171,7 @@ def main(argv=None) -> int:
             # masked, as in a prompt's last window) and the whole window,
             # run last, leaves the rows it should.
             logits, pool, _, routes = mla.prefill_with_prefix(
-                params, eng._model_for(tokens.size), tokens, n, written, pool,
+                params, bound.model_for(tokens.size), tokens, n, written, pool,
                 None, row, row[:, :prior_blocks], want_routes=True)
             return logits[0], routes, pool
         return step
@@ -181,7 +179,7 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(3,))
     def decode(params, tokens, positions, pool, tables):
         logits, pool, _, routes = mla.decode_step(
-            params, eng._model_for(tokens.size), tokens, positions, pool,
+            params, bound.model_for(tokens.size), tokens, positions, pool,
             None, tables, attention_fn=attend, want_routes=True)
         return logits, routes, pool
 
@@ -278,7 +276,7 @@ def main(argv=None) -> int:
         @functools.partial(jax.jit, static_argnums=(5,))
         def expanded_step(params, tokens, written, pool, row, prior_blocks):
             logits, _, _, routes = mla.prefill_with_prefix(
-                params, eng._model_for(tokens.size), tokens,
+                params, bound.model_for(tokens.size), tokens,
                 jnp.ones((1,), jnp.int32), written, pool, None, row,
                 row[:, :prior_blocks], want_routes=True)
             return logits[0], routes[:, 0]

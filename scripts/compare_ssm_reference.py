@@ -6,7 +6,7 @@ reference, at a benchmark configuration's widths and a cell's sizes.
 
 What is compared. One sequence of random byte-range token ids. The program
 side runs what `TpuEngine`'s step functions trace — `models.hybrid.forward` /
-`prefill_with_prefix` / `decode_step` with the MoE form `TpuEngine._model_for`
+`prefill_with_prefix` / `decode_step` with the MoE form `models.bind(...).model_for`
 gives each shape, the decode attention the engine binds, the page writes of
 `kvcache/pages.py` and the slot state of `kvcache/state.py` (a decode step's
 states updated in place by ops/pallas_ssm.py's kernel where the engine's rule
@@ -133,9 +133,8 @@ def main(argv=None) -> int:
     import numpy as np
 
     from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.kvcache import pages, state
-    from llm_d_inference_scheduler_tpu.models import configs, hybrid
+    from llm_d_inference_scheduler_tpu.models import bind, configs, hybrid
     from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
     from llm_d_inference_scheduler_tpu.utils.compile_cache import (
         configure_compile_cache)
@@ -153,18 +152,14 @@ def main(argv=None) -> int:
     device = jax.devices()[0]
     cfg = EngineConfig(model=args.model, max_batch=args.max_batch,
                        max_model_len=args.max_model_len)
-    # The engine's own rules for a program's forms, without its servers and
-    # threads (as scripts/aot_rehearsal.py carries them).
-    eng = object.__new__(TpuEngine)
-    eng.cfg, eng.mcfg = cfg, mcfg
-    if args.state_update:
-        eng.mcfg = dataclasses.replace(mcfg, ssm_impl=args.state_update)
-    else:
-        eng._bind_state_form(device.platform)
-    eng._bind_moe_form(device.platform)
+    # The forms an engine on this device binds (models/binding.py), without
+    # the engine; --state-update sets that one over the rule.
+    bound = bind(mcfg, platform=device.platform,
+                 interpret=cfg.pallas_interpret,
+                 forced=({"ssm_impl": args.state_update}
+                         if args.state_update else None))
     geom = pages.PageGeometry.for_engine(mcfg, cfg.max_batch,
                                          cfg.max_model_len)
-    state_geom = state.StateGeometry.for_engine(mcfg, cfg.max_batch)
     kernel = pages.use_kernel(geom.shape[-1], asked=None, interpret=False,
                               platform=device.platform, sharded=False)
     attend = functools.partial(pages.decode_attention, kernel=kernel)
@@ -196,7 +191,7 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(4,))
     def first_window(params, tokens, n, at, cache, row):
         logits, (fresh, _), routes = hybrid.forward(
-            params, eng._model_for(tokens.size), tokens, want_kv=True,
+            params, bound.model_for(tokens.size), tokens, want_kv=True,
             seq_len=n, want_routes=True)
         cache, _ = pages.write_sequences(cache, None, fresh, None, row, n)
         return logits[0, at], routes, cache
@@ -205,7 +200,7 @@ def main(argv=None) -> int:
         @functools.partial(jax.jit, donate_argnums=(4,))
         def step(params, tokens, n, written, cache, row):
             logits, cache, _, routes = hybrid.prefill_with_prefix(
-                params, eng._model_for(tokens.size), tokens, n, written,
+                params, bound.model_for(tokens.size), tokens, n, written,
                 cache, None, row, row[:, :prior_blocks], want_routes=True)
             return logits[0], routes, cache
         return step
@@ -222,7 +217,7 @@ def main(argv=None) -> int:
     @functools.partial(jax.jit, donate_argnums=(3,))
     def decode(params, tokens, positions, cache, tables):
         logits, cache, _, routes = hybrid.decode_step(
-            params, eng._model_for(tokens.size), tokens, positions, cache,
+            params, bound.model_for(tokens.size), tokens, positions, cache,
             None, tables, attention_fn=attend, want_routes=True)
         return logits, routes, cache
 
@@ -248,7 +243,7 @@ def main(argv=None) -> int:
         t0 = time.monotonic()
         params = jax.jit(lambda k: hybrid.init_params(mcfg, k))(
             jax.random.key(seed))
-        cache, _ = pages.alloc(geom, device=device, state=state_geom)
+        cache, _ = pages.alloc(geom, device=device)
         seq = jax.random.randint(jax.random.key(seed + 1000),
                                  (max(lens) + K,), 0, 257)
         per_seq = geom.max_blocks_per_seq
@@ -388,9 +383,9 @@ def main(argv=None) -> int:
                 "model": mcfg.name, "n_layers": mcfg.n_layers,
                 "lanes": B, "lane_tokens": sorted(set(lens)),
                 "decode_steps": K, "attention_kernel": bool(kernel),
-                "state_update": eng.mcfg.ssm_impl,
+                "state_update": bound.mcfg.ssm_impl,
                 "pool_bytes": geom.pool_bytes,
-                "state_pool_bytes": state_geom.pool_bytes,
+                "state_pool_bytes": geom.state.pool_bytes,
                 "memory": {k: v for k, v in (device.memory_stats() or {}).items()
                            if k in ("peak_bytes_in_use", "bytes_limit")},
                 "routing": {"max_shortfall": max(shortfalls),

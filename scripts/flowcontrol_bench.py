@@ -228,7 +228,8 @@ async def run_topology_churn(n: int = 5000, concurrency: int = 100) -> dict:
 
 async def run_priority_isolation(n: int = 4000, limit: int = 8,
                                  service_s: float = 0.001) -> dict:
-    """BASELINE config 4's target: **priority isolation under saturation**.
+    """What a pool with priority tiers must show: **priority isolation
+    under saturation**.
 
     A saturated egress (limit concurrent dispatches, each `service_s`) with
     a 50/50 mix of premium (priority 10) and normal (priority 0) arrivals;

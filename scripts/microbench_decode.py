@@ -92,7 +92,7 @@ def moe_main(args):
     import jax.numpy as jnp
     import numpy as np
 
-    from llm_d_inference_scheduler_tpu.models import llama
+    from llm_d_inference_scheduler_tpu.models import bind, llama
     from llm_d_inference_scheduler_tpu.models.configs import get_config
     from llm_d_inference_scheduler_tpu.ops import pallas_moe
 
@@ -233,16 +233,17 @@ def moe_main(args):
                  grouped_vs_dense=diff(grouped, dense))
     del lp
     model = dataclasses.replace(get_config(args.moe_model), n_layers=4)
+    # Both forms of the model as an engine here binds them.
+    bound = bind(model, platform=jax.devices()[0].platform,
+                 interpret=args.moe_interpret)
     for seed in seeds:
         params = init(jax.random.key(seed), model)
         for T in (512, 1024):
             tokens = jax.random.randint(jax.random.key(seed + T), (1, T), 0,
                                         model.vocab_size)
             last = {}
-            for impl in ("dense", "grouped"):
-                cfg = dataclasses.replace(
-                    model, moe_impl=impl + "_interpret" * (
-                        args.moe_interpret and impl == "grouped"))
+            for impl, cfg in (("dense", bound.mcfg),
+                              ("grouped", bound.grouped)):
                 last[impl] = jax.jit(
                     lambda p, t, cfg=cfg: llama.forward(p, cfg, t)[0][0, -1])(
                         params, tokens)

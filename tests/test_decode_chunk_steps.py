@@ -113,7 +113,7 @@ def test_a_chunk_counts_by_its_own_steps(model, n):
     if model == "tiny-hybrid":
         assert sums("jetstream:ssm_tokens_total", "form") == {"step": 2 * n}
         assert sums("jetstream:ssm_state_updates_total", "form") == {
-            "gathered": 2 * n * eng.state_geom.n_layers}
+            "gathered": 2 * n * eng.geom.state.n_layers}
         return
     assert sums("jetstream:mla_attention_tokens_total", "form") == {
         "absorbed": 2 * n}

@@ -96,7 +96,8 @@ def _cache_with(fresh, n_tokens):
     both sequences."""
     geom = pages.PageGeometry.for_engine(CFG, 2, 96)
     assert geom.shape == (3, 13, 16, 128) and geom.index_shape == (3, 13, 16, 16)
-    cache, none = pages.alloc(geom, counted=True)
+    assert geom.counted and not geom.counts_zero
+    cache, none = pages.alloc(geom)
     assert none is None and cache.ssm is None and cache.v is None
     assert cache.idx.shape == geom.index_shape
     bucket = -(-n_tokens // 16) * 16    # a prefill hands over whole pages
@@ -690,23 +691,37 @@ def test_engine_refuses_what_the_second_pool_cannot_do(served):
 
 @pytest.mark.parametrize("steps", [4, 2])
 def test_selection_counters_from_positions(steps):
-    """_note_selection's sums against a count by hand, a decode chunk (of the
-    steps it was dispatched with, whatever decode_chunk says) and a
-    continuation window."""
+    """What _device_call books of a selecting block's programs (the engine's
+    _requests_part, the family's program_counts, the telemetry's
+    book_program) against a count by hand: a decode chunk (of the steps it
+    was dispatched with, whatever decode_chunk says), a continuation window,
+    and two ops that put nothing through the block."""
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
     from llm_d_inference_scheduler_tpu.engine.telemetry import EngineTelemetry
+    from llm_d_inference_scheduler_tpu.models import bind
 
     eng = object.__new__(TpuEngine)
     eng.cfg = EngineConfig(model="tiny-dsa", max_batch=4, decode_chunk=4)
-    eng.mcfg = CFG
+    eng.bound = bind(CFG, platform="cpu")
     eng.telemetry = EngineTelemetry(block_size=16, num_blocks=8)
-    eng._note_selection(("decode",), dict(
-        positions=np.asarray([21, 40, 0, 0], np.int32),
-        slots=np.asarray([0, 2, 4, 4], np.int32), steps=steps))
-    eng._note_selection(("prefix_prefill", 16, 2), dict(
-        prefix_len=np.asarray([16], np.int32),
-        suffix_len=np.asarray([12], np.int32)))
-    eng._note_selection(("embed", 16), {})
+    for op, args in (
+            (("decode",), dict(
+                positions=np.asarray([21, 40, 0, 0], np.int32),
+                slots=np.asarray([0, 2, 4, 4], np.int32), steps=steps)),
+            (("prefix_prefill", 16, 2), dict(
+                tokens=np.zeros((1, 16), np.int32),
+                slots=np.asarray([1], np.int32),
+                prefix_len=np.asarray([16], np.int32),
+                suffix_len=np.asarray([12], np.int32))),
+            (("embed", 16), dict(tokens=np.zeros((1, 16), np.int32))),
+            (("prefill", 16), dict(          # a warm-up program: nobody's
+                tokens=np.zeros((1, 16), np.int32), warm=True,
+                slots=np.asarray([4], np.int32),
+                seq_len=np.asarray([1], np.int32)))):
+        real, queries = eng._requests_part(op, args)
+        eng.telemetry.book_program(eng.bound.program_counts(
+            op[0], args["slots" if op[0] == "decode" else "tokens"].size,
+            args.get("steps", 1), real=real, queries=queries))
     contexts = ([22, 23, 24, 25][:steps] + [41, 42, 43, 44][:steps]
                 + list(range(17, 29)))
     q = _counters(eng, "jetstream:dsa_query_tokens_total", "form")

@@ -1,6 +1,6 @@
 """Full P/D disaggregation path: gateway → sidecar → prefill/decode engines.
 
-BASELINE config #3 shape at CPU-test scale: the disagg profile handler gates a
+A prefill pool and a decode pool at CPU-test scale: the disagg profile handler gates a
 remote prefill on the decode pod's prefix state, the sidecar runs the 2-phase
 tpu-dcn connector, and the decode engine imports the prefilled KV.
 """
@@ -429,7 +429,7 @@ schedulingProfiles:
 
 def test_sidecar_proxies_kv_events_stream():
     """The precise-prefix SSE subscriber must work against sidecar-fronted
-    decode endpoints: GET /kv_events is stream-proxied (ADVICE r1)."""
+    decode endpoints: GET /kv_events is stream-proxied."""
     DEC6, SC6 = 18375, 18376
 
     async def body():

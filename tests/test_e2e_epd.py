@@ -124,7 +124,7 @@ def test_vision_tower_shapes_and_determinism():
 
 
 def test_epd_embeddings_reach_prefill_and_change_output():
-    """Phase 2 (BASELINE config 5 shape): the encode worker's embeddings are
+    """Phase 2 (CPU encode, TPU prefill and decode): the encode worker's embeddings are
     pulled by the serving engine and spliced into prefill — two different
     images must produce different generations for the same text."""
     DEC2, ENC2, SC2 = 18470, 18471, 18472

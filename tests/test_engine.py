@@ -227,57 +227,6 @@ def test_engine_warmup_compiles_before_serving():
     run(body())
 
 
-def test_decode_ctx_buckets_token_parity():
-    """Pow2 context-bucketed block tables (decode_ctx_buckets) must be
-    token-identical to full-width tables, across mixed request lengths and
-    a width drop when the long request finishes first."""
-    import jax
-    import jax.numpy as jnp
-
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
-    from llm_d_inference_scheduler_tpu.models import llama
-    from llm_d_inference_scheduler_tpu.models.configs import get_config
-
-    params = llama.init_params(get_config("tiny"), jax.random.key(11),
-                               dtype=jnp.float32)
-
-    async def serve(ctx_buckets: bool):
-        eng = TpuEngine(EngineConfig(
-            model="tiny", backend="tpu", max_batch=4, max_model_len=128,
-            decode_chunk=4, seed=11, kv_events_port=0,
-            enable_prefix_caching=False, decode_ctx_buckets=ctx_buckets),
-            params=params)
-        await eng.start()
-        try:
-            async def one(rid, n_prompt, n_gen):
-                req = EngineRequest(
-                    request_id=rid,
-                    prompt_token_ids=[1] + [(i * 3) % 400 + 5
-                                            for i in range(n_prompt - 1)],
-                    max_tokens=n_gen, temperature=0.0, ignore_eos=True)
-                out = eng.submit(req)
-                toks = []
-                while True:
-                    ev = await out.get()
-                    if ev.token_id is not None:
-                        toks.append(ev.token_id)
-                    if ev.finish_reason is not None:
-                        return toks
-
-            # short (3 blocks) + long (7 blocks) concurrently: W=8 while both
-            # live, drops to 4 after the long one finishes first.
-            long_t, short_t = await asyncio.gather(
-                one("long", 100, 6), one("short", 40, 24))
-            return long_t, short_t
-        finally:
-            await eng.stop()
-
-    bucketed = asyncio.run(serve(True))
-    full = asyncio.run(serve(False))
-    assert bucketed == full
-    assert len(bucketed[0]) == 6 and len(bucketed[1]) == 24
-
-
 def test_batched_prefill_token_parity():
     """prefill_batch > 1: same-bucket plain prompts admitted together run
     as ONE [K, S] fused prefill (padded to K) — greedy tokens must match
@@ -794,18 +743,11 @@ def _free_blocks(eng):
     return getattr(eng.allocator, "reusable_blocks", eng.allocator.free_blocks)
 
 
-_CASES = [(*case, {}) for case in _STOP_AT] + [
-    # Tables narrowed to the live context: the overshoot of a lane two chunks
-    # past its end clamps to its row's last entry, its own block or the trash.
-    ("reused", 0.0, {"decode_ctx_buckets": True})]
-
-
 @pytest.mark.parametrize(
-    "plan, t, cfg", _CASES,
-    ids=[f"{plan}-{'greedy' if t == 0 else 'sampled'}{'-narrowed' * bool(cfg)}"
-         for plan, t, cfg in _CASES])
-def test_a_chunk_in_flight_serves_the_parents_tokens(plan, t, cfg):
-    toks, why, eng = _serve(plan, t, **cfg)
+    "plan, t", _STOP_AT,
+    ids=[f"{plan}-{'greedy' if t == 0 else 'sampled'}" for plan, t in _STOP_AT])
+def test_a_chunk_in_flight_serves_the_parents_tokens(plan, t):
+    toks, why, eng = _serve(plan, t)
     assert (toks, why) == _PARENT_SERVED[plan, t]
     assert _free_blocks(eng) == eng.n_blocks - 1    # every block came back
     # Back to back, every chunk but the first went out with one unread.

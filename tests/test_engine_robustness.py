@@ -410,7 +410,7 @@ def test_drain_timeout_aborts_stragglers():
 def test_drain_gate_waits_for_staged_kv_export():
     """SIGTERM drain must not tear down a prefill pod while a staged KV
     export is waiting for (or mid-way through) a decode peer's pull:
-    idle() counts kv_exports and queued release requests (ADVICE r5)."""
+    idle() counts kv_exports and queued release requests."""
     async def body():
         from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
 

@@ -310,9 +310,10 @@ def _state_cache(slots=3):
     model = dataclasses.replace(SMALL, layer_pattern="M*M", n_layers=3,
                                 ssm_heads=4, ssm_head_dim=8, ssm_state=16,
                                 ssm_groups=2)
-    sgeom = state.StateGeometry.for_engine(model, slots)
     geom = PageGeometry.for_engine(model, slots, 64)
-    cache, none = pages.alloc(geom, state=sgeom)
+    sgeom = geom.state
+    assert sgeom == state.StateGeometry.for_engine(model, slots)
+    cache, none = pages.alloc(geom)
     return state, model, sgeom, geom, cache, none
 
 

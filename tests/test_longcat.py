@@ -89,7 +89,8 @@ def _cache_with(fresh, n_tokens):
     of both sequences."""
     geom = pages.PageGeometry.for_engine(CFG, 2, 64)
     assert geom.shape == (4, 9, 16, 128)        # two cache layers a layer
-    cache, none = pages.alloc(geom, counted=True, counts_zero=True)
+    assert geom.counted and geom.counts_zero    # the model's router says
+    cache, none = pages.alloc(geom)
     assert none is None and cache.ssm is None and cache.v is None
     bucket = -(-n_tokens // 16) * 16    # a prefill hands over whole pages
     cut = dataclasses.replace(fresh, k=fresh.k[:, :, :bucket])
@@ -345,7 +346,7 @@ def _row_writers():
     @jax.jit
     def run(params, tokens):
         _, (fresh, _) = mla.forward(params, CFG, tokens[:, :16], want_kv=True)
-        cache, _ = pages.alloc(geom, counted=True, counts_zero=True)
+        cache, _ = pages.alloc(geom)
         cache, _ = pages.write_sequences(
             state.at_slots(cache, [0]), None, fresh, None, TABLES[:1],
             jnp.asarray([16]))

@@ -178,7 +178,7 @@ INSTR_PP = 19816
 def _dist_pp_worker(pid: int, q) -> None:
     """2 processes × 2 devices → a global (pp=2, tp=2) mesh: each host owns
     one full pipeline stage (tp inside the host), the stage-hop ppermute
-    crosses processes — the BASELINE config-4 shape (70B pipeline over a
+    crosses processes — the shape of a 70B deployment (pipeline over a
     multi-host slice) at test scale."""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import jax

@@ -167,7 +167,7 @@ def test_dist_pd_pp_sharded_handoff_matches_monolithic():
     """Disaggregation across HOST-SPANNING pp groups: a 2-process pp2×tp2
     prefill group stages layer-axis page shards, the pp decode group runs
     the coordinated pull — tokens match a single-process pp2×tp2 engine.
-    (The BASELINE config-4 deployment: deep pipeline spanning hosts, P/D
+    (The 70B-class deployment: deep pipeline spanning hosts, P/D
     split on top.)"""
     _sharded_handoff_roundtrip(
         {"pp_size": 2, "tp_size": 2},

@@ -201,8 +201,8 @@ def test_engine_serves_the_form_its_shapes_call_for(monkeypatch):
     assert grouped == dense
     # One prompt of 300 tokens pads to the 512 bucket; its first token comes
     # with the prefill, the other five from two chunks of 4 steps on 2 lanes.
-    assert eng._model_for(512).moe_impl == "grouped_interpret"
-    assert eng._model_for(2).moe_impl == "dense"
+    assert eng.bound.model_for(512).moe_impl == "grouped_interpret"
+    assert eng.bound.model_for(2).moe_impl == "dense"
     assert counted(eng) == {"grouped": 512.0, "dense": 2 * 4 * 2.0}
     # Off the TPU and not interpreting, every program is dense.
     assert counted(dense_eng) == {"grouped": 0.0, "dense": 512 + 2 * 4 * 2.0}

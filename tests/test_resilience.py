@@ -264,8 +264,12 @@ def test_chaos_failover_breaker_opens_and_recovers():
     (connection reset on every request). All traffic still completes via
     failover (zero client-visible 502s), the ejected endpoint shows
     breaker-open state in /metrics, and after the open window a half-open
-    probe recovers it."""
+    probe recovers it. The open window passes on a clock the test moves
+    (the breakers' injectable one): on the wall clock twenty requests beside
+    five compiling workers outlast any window short enough to sleep through,
+    and the breaker is then found half-open where the test reads open."""
     GW, EA, EB = 18750, 18751, 18752
+    OPEN_S = 0.5
     cfg = f"""
 pool:
   endpoints:
@@ -282,13 +286,15 @@ schedulingProfiles:
 resilience:
   maxAttempts: 3
   breakerFailureThreshold: 2
-  breakerOpenS: 0.5
+  breakerOpenS: {OPEN_S}
 """
 
     async def body():
         ea = await _sim(EA, chaos="reset:100", chaos_seed=CHAOS_SEED)
         eb = await _sim(EB)
         gw = build_gateway(cfg, port=GW, poll_interval=0.02)
+        now = [1000.0]
+        gw.datastore.breakers._clock = lambda: now[0]   # (none exists yet)
         await gw.start()
         try:
             async with httpx.AsyncClient(timeout=30) as c:
@@ -309,10 +315,17 @@ resilience:
                 assert _metric_value(
                     m, 'router_retries_total{kind="connect"}') > 0
 
+                # Inside the window the breaker never left open: one
+                # opening, nothing else.
+                transitions = 'router_circuit_breaker_transitions_total' \
+                    '{endpoint="127.0.0.1:%d",to_state="%%s"}' % EA
+                assert [_metric_value(m, transitions % to) for to in
+                        ("open", "half-open", "closed")] == [1, 0, 0]
+
                 # Heal the endpoint; after the open window a half-open probe
                 # closes the breaker and traffic returns to A.
                 ea.chaos.enabled = False
-                await asyncio.sleep(0.6)
+                now[0] += OPEN_S
                 served = set()
                 for i in range(30):
                     r = await c.post(f"http://127.0.0.1:{GW}/v1/completions",
@@ -328,6 +341,8 @@ resilience:
                 assert _metric_value(
                     m, 'router_endpoint_circuit_breaker_state'
                        '{endpoint="127.0.0.1:%d"}' % EA) == 0.0  # closed
+                assert [_metric_value(m, transitions % to) for to in
+                        ("open", "half-open", "closed")] == [1, 1, 1]
         finally:
             await gw.stop()
             await ea.stop()

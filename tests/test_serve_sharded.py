@@ -1,7 +1,7 @@
 """TP-sharded serving path (parallel/serve.py) on the virtual 8-CPU mesh.
 
 Covers the driver's `dryrun_multichip` serving leg plus the engine running
-with tp_size>1 end-to-end — the stepping stone to BASELINE.md config 4
+with tp_size>1 end-to-end — the stepping stone to a 70B-class deployment
 (TP-sharded decode). Reference analogue: vLLM's --tensor-parallel-size,
 orchestrated but never implemented by the router (SURVEY §2.12).
 """

@@ -60,8 +60,8 @@ def _fixture():
 
 def _cache(n_slots=2, max_len=64):
     geom = pages.PageGeometry.for_engine(CFG, n_slots, max_len)
-    cache, none = pages.alloc(
-        geom, state=state.StateGeometry.for_engine(CFG, n_slots))
+    assert geom.state == state.StateGeometry.for_engine(CFG, n_slots)
+    cache, none = pages.alloc(geom)
     assert none is None and isinstance(cache, state.Cache)
     return cache
 
@@ -243,13 +243,12 @@ def test_state_geometry_at_the_cells_sizes():
 
 def test_a_state_pool_lies_beside_unsharded_kv_pages_only():
     geom = pages.PageGeometry.for_engine(CFG, 2, 64)
-    sgeom = state.StateGeometry.for_engine(CFG, 2)
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2),
                              ("dp", "tp"))
     with pytest.raises(ValueError, match="no sharding rule"):
-        pages.alloc(geom, sharding=pages.page_sharding(mesh), state=sgeom)
+        pages.alloc(geom, sharding=pages.page_sharding(mesh))
     # A plain pool passes through the two seams untouched.
-    k, v = pages.alloc(geom)
+    k, v = pages.alloc(dataclasses.replace(geom, state=None, counted=False))
     assert state.at_slots(k, [0]) is k and state.take_counts(k) == (k, None, None)
 
 
