@@ -170,6 +170,115 @@ class PrefixCachingAllocator(BlockAllocator):
         self.release(blocks)
 
 
+class Table(list):
+    """A request's block table where the cache has two kinds of layer
+    (:class:`WindowedAllocator`): the list is the table of the layers that
+    keep the whole context, as every other request's is, and beside it ride
+    the pages the request holds of the window layers' pool, ``window[i]`` the
+    page of logical page ``first + i`` (0 where it holds none: the trash
+    block)."""
+
+    def __init__(self, blocks=()):
+        super().__init__(blocks)
+        self.first: int = 0
+        self.window: list[int] = []
+
+
+class WindowedAllocator(BlockAllocator):
+    """One owner of both kinds of cache layer (kvcache/pages.py): the blocks
+    of the layers that keep the whole context are this allocator's own, a
+    request's whole table at admission as ever; the pages of the layers that
+    keep a WINDOW of it come from a second pool of ``window_blocks`` ids, a
+    step at a time (:meth:`slide`), and go back once every row of them lies
+    more than ``window - 1`` behind the request's position.
+
+    Admission reserves by kind: a table is handed out only while a
+    reservation of ``lane_pages`` window pages is left for it (``lanes`` of
+    them: the pool's size follows the engine's lanes, never a request's
+    length), so :meth:`slide` cannot run dry; with none left the allocator
+    reports no free block and the head of the queue waits."""
+
+    def __init__(self, n_blocks: int, block_size: int, *, window_blocks: int,
+                 window: int, lanes: int, lane_pages: int):
+        super().__init__(n_blocks, block_size)
+        self.window = window
+        self.lanes, self.lane_pages = lanes, lane_pages
+        self.pages = BlockAllocator(window_blocks, block_size)
+        self.tables = 0          # live tables: each holds a reservation
+
+    @property
+    def free_blocks(self) -> int:
+        return len(self._free) if self.tables < self.lanes else 0
+
+    @property
+    def window_used_fraction(self) -> float:
+        return self.pages.used_fraction
+
+    def alloc(self, n: int) -> Table:
+        if self.tables >= self.lanes:
+            raise OutOfBlocks(
+                f"every reservation of the window pool is taken "
+                f"({self.lanes} tables of {self.lane_pages} pages)")
+        self.tables += 1
+        return Table(super().alloc(n))
+
+    def free(self, blocks: list[int]) -> None:
+        super().free(blocks)
+        self.pages.free([b for b in blocks.window if b])
+        blocks.window = []
+        self.tables -= 1
+
+    def slide(self, table: Table, start: int, end: int, row,
+              ahead: bool = False) -> None:
+        """``table``'s window pages for a step that writes positions
+        [start, end) and whose first query sits at ``start``: the pages all
+        of whose rows lie before ``start - (window - 1)`` go back to the
+        pool, the pages up to ``end - 1`` that the request's next step can
+        still see are taken (a long prefill window's early pages never are:
+        their rows go to the trash block), and ``row`` (a table row by
+        logical page, zeros) is filled with what the request holds. With
+        ``ahead`` (a step that is one program run once: a prefill window)
+        what only THIS step reads, the pages before ``end - (window - 1)``,
+        goes back as soon as the row is filled: the device runs its programs
+        in order, so whoever takes such a page writes it after this step has
+        read it. A decode chunk runs its steps in one program and keeps
+        them."""
+        block, reach = self.block_size, self.window - 1
+        self._drop(table, max(start - reach, 0) // block)
+        keep = max(start, end - reach, 0) // block
+        # (A chunk that overshoots the table's width writes nobody's rows.)
+        for page in range(table.first + len(table.window),
+                          min((end - 1) // block, len(row) - 1) + 1):
+            table.window.append(self.pages.alloc(1)[0] if page >= keep else 0)
+        row[table.first:table.first + len(table.window)] = table.window
+        if ahead:
+            self._drop(table, max(end - reach, 0) // block)
+
+    def _drop(self, table: Table, upto: int) -> None:
+        """Give back ``table``'s window pages before logical page ``upto``."""
+        n = min(max(upto - table.first, 0), len(table.window))
+        self.pages.free([b for b in table.window[:n] if b])
+        del table.window[:n]
+        table.first += n
+        if not table.window:      # nothing held: the next page taken is upto
+            table.first = max(table.first, upto)
+
+
+def allocator_for(geom, prefix_caching: bool) -> BlockAllocator:
+    """The owner of an engine's cache blocks, from what its cache keeps
+    (``geom``: kvcache/pages.PageGeometry). A cached block prefix is pages
+    with no recurrent state and no window rows to go with them: a model that
+    keeps either keeps no prefix cache."""
+    if geom.window is not None:
+        w = geom.window
+        return WindowedAllocator(
+            geom.n_blocks, geom.block, window_blocks=w.n_blocks,
+            window=w.window, lanes=w.lanes, lane_pages=w.lane_pages)
+    if prefix_caching and not geom.state:
+        return PrefixCachingAllocator(geom.n_blocks, geom.block)
+    return BlockAllocator(geom.n_blocks, geom.block)
+
+
 def table_groups(blocks: list[int], group: int) -> tuple[int, int]:
     """(runs, splits) of a request's block table, as the latent kernels walk
     it at full length: aligned groups of ``group`` entries, a run where they

@@ -39,7 +39,7 @@ import numpy as np
 from ..kvcache import pages, state as state_pool, wire
 from ..models import bind
 from ..utils.hashing import chain_block_hashes
-from .blocks import BlockAllocator, PrefixCachingAllocator, table_groups
+from .blocks import PrefixCachingAllocator, allocator_for, table_groups
 from .config import EngineConfig
 from .multihost import ChannelBroken
 from .request import EngineRequest, FinishReason, TokenEvent
@@ -237,11 +237,8 @@ class TpuEngine:
         block = self.geom.block
         self.n_blocks = self.geom.n_blocks
         self.max_blocks_per_seq = self.geom.max_blocks_per_seq
-        # A cached block prefix is pages with no recurrent state to go with
-        # them: a model that keeps state a slot keeps no prefix cache.
-        self.allocator = (PrefixCachingAllocator(self.n_blocks, block)
-                          if cfg.enable_prefix_caching and not self.geom.state
-                          else BlockAllocator(self.n_blocks, block))
+        # (Which allocator is the cache's to say: engine/blocks.py.)
+        self.allocator = allocator_for(self.geom, cfg.enable_prefix_caching)
         self.telemetry = EngineTelemetry(block_size=block, num_blocks=self.n_blocks)
         self.telemetry.watch_xla_builds()
 
@@ -968,7 +965,8 @@ class TpuEngine:
             tokens=np.zeros((1, bucket), np.int32),
             seq_len=np.asarray([1], np.int32),
             row=np.zeros((1, self.max_blocks_per_seq), np.int32),
-            slots=nobody, warm=True, **self._sample_np([_DUMMY_REQ])))
+            slots=nobody, warm=True, **self._window_tables([], 1),
+            **self._sample_np([_DUMMY_REQ])))
         if self.cfg.prefill_batch > 1 and self.pp_mesh is None:
             # Batched prefill pads every group to exactly prefill_batch rows,
             # so ONE extra traced shape per bucket covers it.
@@ -978,7 +976,8 @@ class TpuEngine:
                 seq_len=np.ones((K,), np.int32),
                 row=np.zeros((K, self.max_blocks_per_seq), np.int32),
                 slots=np.full((K,), B, np.int32),
-                warm=True, **self._sample_np([_DUMMY_REQ] * K)))
+                warm=True, **self._window_tables([], K),
+                **self._sample_np([_DUMMY_REQ] * K)))
         if self._prefill_window():
             # Incremental prefill's mid-stream shapes: every intermediate
             # window is FULL-width, so precompiling (win_bucket × pb ladder)
@@ -991,7 +990,8 @@ class TpuEngine:
                 tokens=np.zeros((1, wb), np.int32),
                 seq_len=np.asarray([1], np.int32),
                 row=np.zeros((1, self.max_blocks_per_seq), np.int32),
-                slots=nobody, warm=True, **self._sample_np([_DUMMY_REQ])))
+                slots=nobody, warm=True, **self._window_tables([], 1),
+                **self._sample_np([_DUMMY_REQ])))
             pb = 1
             while True:
                 self._device_call(("prefix_prefill", wb, pb), dict(
@@ -1000,7 +1000,8 @@ class TpuEngine:
                     prefix_len=np.asarray([0], np.int32),
                     row=np.zeros((1, self.max_blocks_per_seq), np.int32),
                     prior=np.zeros((1, pb), np.int32),
-                    slots=nobody, warm=True, **self._sample_np([_DUMMY_REQ])))
+                    slots=nobody, warm=True, **self._window_tables([], 1),
+                    **self._sample_np([_DUMMY_REQ])))
                 if pb >= self.max_blocks_per_seq:
                     break
                 pb = min(pb * 2, self.max_blocks_per_seq)
@@ -1013,6 +1014,7 @@ class TpuEngine:
                 positions=np.zeros((nb,), np.int32),
                 tables=np.zeros((nb, self.max_blocks_per_seq), np.int32),
                 steps=self.cfg.decode_chunk, warm=True,
+                **self._window_tables([], nb),
                 **self._sample_np([_DUMMY_REQ] * nb)))
         log.info("engine warm-up compiled prefill/decode/sample in %.1fs",
                  time.monotonic() - t0)
@@ -1589,7 +1591,7 @@ class TpuEngine:
                                      self.mcfg.kv_block_size)
                   if caching or
                   (self.kv_events is not None and req.mm_embeds is None
-                   and not self.geom.state)
+                   and not (self.geom.state or self.geom.window))
                   else [])
         return prompt, hashes, caching
 
@@ -1611,6 +1613,23 @@ class TpuEngine:
             self.kv_events.removed(evicted)
         self._note_table(blocks)
         return blocks
+
+    def _window_tables(self, steps, rows: int) -> dict:
+        """What rides with a program where some cache layers keep a window
+        of the context (kvcache/pages.py): ``wt``, a second table a row,
+        zeros but for what ``steps`` say, (a request's blocks, the positions
+        [start, end) its row of the program writes) each; with ``ahead`` a
+        program run once, a prefill window. The allocator slides each
+        request's window pages to the step as it fills the row
+        (engine/blocks.WindowedAllocator). Nothing for any other cache."""
+        if self.geom.window is None:
+            return {}
+        wt = np.zeros((rows, self.max_blocks_per_seq), np.int32)
+        with self._cond:
+            for lane, (blocks, start, end, ahead) in enumerate(steps):
+                self.allocator.slide(blocks, start, end, wt[lane], ahead)
+            self.telemetry.observe_allocator(self.allocator)
+        return {"wt": wt}
 
     def _note_table(self, blocks: list[int]) -> None:
         """Count an admitted request's block table by the groups the latent
@@ -1648,6 +1667,8 @@ class TpuEngine:
             samp = self._sample_np(reqs + [_DUMMY_REQ] * (K - len(reqs)))
             tok_dev = self._device_call(("prefill", bucket), dict(
                 tokens=tokens, seq_len=seq_len, row=rows, slots=slots,
+                **self._window_tables(
+                    [(e[6], 0, len(e[5][0]), True) for e in entries], K),
                 **samp))
         except Exception:
             with self._cond:
@@ -1767,7 +1788,9 @@ class TpuEngine:
                 self.allocator.acquire_cached(matched_bids)
             new_bids = self.allocator.alloc(need - len(matched_bids))
             evicted = list(getattr(self.allocator, "last_evicted_hashes", []))
-            blocks = matched_bids + new_bids
+            # (Nothing matched: the table as the allocator handed it out,
+            # with what rides on it.)
+            blocks = matched_bids + new_bids if matched_bids else new_bids
             self.telemetry.observe_allocator(self.allocator)
         if evicted and self.kv_events is not None:
             self.kv_events.removed(evicted)
@@ -1798,7 +1821,7 @@ class TpuEngine:
         try:
             tok_dev = self._run_prefill_compute(
                 req, prompt, suffix, cached_tokens, matched_bids, row,
-                np.asarray([idx], np.int32))
+                np.asarray([idx], np.int32), blocks)
         except Exception:
             with self._cond:
                 self.allocator.free(blocks)
@@ -2005,6 +2028,8 @@ class TpuEngine:
         slots = np.asarray([idx if last or self.geom.state
                             else self.cfg.max_batch], np.int32)
         try:
+            riders = self._window_tables(
+                [(s.blocks, written, written + len(window), True)], 1)
             if written == 0:
                 bucket = self._bucket(len(window))
                 tokens = np.zeros((1, bucket), np.int32)
@@ -2012,7 +2037,8 @@ class TpuEngine:
                 tok_dev = self._device_call(("prefill", bucket), dict(
                     tokens=tokens,
                     seq_len=np.asarray([len(window)], np.int32),
-                    row=row, slots=slots, **self._sample_np([req])))
+                    row=row, slots=slots, **riders,
+                    **self._sample_np([req])))
             else:
                 # Continuation window: gather the already-written prefix
                 # from its (block-aligned) pages, scatter this window at
@@ -2032,7 +2058,7 @@ class TpuEngine:
                         tokens=tokens,
                         suffix_len=np.asarray([len(window)], np.int32),
                         prefix_len=np.asarray([written], np.int32),
-                        row=row, prior=prior, slots=slots,
+                        row=row, prior=prior, slots=slots, **riders,
                         **self._sample_np([req])))
         except Exception:
             self.slots[idx] = None
@@ -2073,7 +2099,7 @@ class TpuEngine:
                 self.kv_events.stored(s.block_hashes)
 
     def _run_prefill_compute(self, req, prompt, suffix, cached_tokens,
-                             matched_bids, row, slots):
+                             matched_bids, row, slots, blocks):
         """Dispatch the fused prefill+first-token jit; returns the sampled
         token as a DEVICE array ([1] i32) with its host transfer already
         started — _finalize_prefills lands it. ``slots`` names the slot the
@@ -2122,7 +2148,9 @@ class TpuEngine:
             tokens[0, : len(prompt)] = prompt
             tok = self._device_call(("prefill", bucket), dict(
                 tokens=tokens, seq_len=np.asarray([len(prompt)], np.int32),
-                row=row, slots=slots, **self._sample_np([req])))
+                row=row, slots=slots,
+                **self._window_tables([(blocks, 0, len(prompt), True)], 1),
+                **self._sample_np([req])))
         return tok
 
     # ---- P/D import (decode side) --------------------------------------
@@ -2885,10 +2913,10 @@ class TpuEngine:
         return k_dev, v_dev
 
     def _op_decode(self, slots, positions, tables, steps, temps, top_k, top_p,
-                   warm=False):
+                   warm=False, wt=None):
         # (The cache takes the host's copy of the slots: what goes in with
         # it is donated with it.)
-        cache = state_pool.at_slots(self.k_pages, slots)
+        cache = state_pool.at_slots(self.k_pages, slots, wt)
         slots = self._put(slots)
         args = (self.params, self._jit_slot_tokens(self._slot_tokens, slots),
                 self._put(positions), cache, self.v_pages,
@@ -2925,11 +2953,11 @@ class TpuEngine:
         return toks
 
     def _op_prefill(self, bucket, tokens, seq_len, row, slots, temps, top_k,
-                    top_p, warm=False):
+                    top_p, warm=False, wt=None):
         fn = self._prefill_fn(bucket)
         tok, k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(seq_len),
-            state_pool.at_slots(self.k_pages, slots), self.v_pages,
+            state_pool.at_slots(self.k_pages, slots, wt), self.v_pages,
             self._put(row),
             self._next_key(warm), self._put(temps), self._put(top_k),
             self._put(top_p))
@@ -2938,12 +2966,12 @@ class TpuEngine:
 
     def _op_prefix_prefill(self, suffix_bucket, prefix_bucket, tokens,
                            suffix_len, prefix_len, row, prior, slots, temps,
-                           top_k, top_p, warm=False):
+                           top_k, top_p, warm=False, wt=None):
         fn = self._prefix_prefill_fn(suffix_bucket, prefix_bucket)
         tok, k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(suffix_len),
             self._put(prefix_len),
-            state_pool.at_slots(self.k_pages, slots), self.v_pages,
+            state_pool.at_slots(self.k_pages, slots, wt), self.v_pages,
             self._put(row), self._put(prior), self._next_key(warm),
             self._put(temps), self._put(top_k), self._put(top_p))
         self._keep_cache(k_pages, tokens.size)
@@ -3039,7 +3067,11 @@ class TpuEngine:
             timed = ("decode", shape) in self._seen_op_shapes
             steps = self._chunk_steps(shape)
             args = dict(slots=slots, positions=positions, tables=tables,
-                        steps=steps, **self._sample_np(reqs))
+                        steps=steps, **self._sample_np(reqs),
+                        **self._window_tables(
+                            [(s.blocks, s.position + s.ahead,
+                              s.position + s.ahead + steps, False)
+                             for _, s in lanes], B))
         t0 = self._clock()
         if self._inflight is None:
             self._begin_period()    # nothing ahead of it: its period is its own

@@ -24,6 +24,7 @@ from ..router.metrics import LOOP_LAG_BUCKETS, PERIOD_BUCKETS
 WAITING = "jetstream:num_requests_waiting"
 RUNNING = "jetstream:num_requests_running"
 KV_USAGE = "jetstream:kv_cache_usage_perc"
+KV_WINDOW_USAGE = "jetstream:kv_window_cache_usage_perc"
 LORA_INFO = "jetstream:lora_requests_info"
 CACHE_CONFIG = "jetstream:cache_config_info"
 
@@ -63,7 +64,7 @@ STALL_WHERE = ("device_wait", "host")
 # and names them here and in models/binding.py Bound.program_counts.
 PROGRAM_COUNTERS = ("moe_ffn_tokens", "mla_attention_tokens",
                     "dsa_query_tokens", "dsa_rows", "ssm_tokens",
-                    "ssm_state_updates", "ssm_slot_prefills")
+                    "ssm_state_updates", "ssm_slot_prefills", "swa_rows")
 
 
 class XlaBuilds:
@@ -136,6 +137,11 @@ class EngineTelemetry:
         self.waiting = g(WAITING, "Requests waiting for admission")
         self.running = g(RUNNING, "Requests actively decoding")
         self.kv_usage = g(KV_USAGE, "Fraction of HBM KV blocks in use")
+        self.kv_window_usage = g(
+            KV_WINDOW_USAGE,
+            "Fraction of the window layers' page pool (kvcache/pages.py: the "
+            "pool of the cache layers that keep a window of the context) held "
+            "by live requests; 0 for a model without such layers")
         self.lora_info = g(LORA_INFO, "Active/waiting LoRA adapters",
                            ("running_lora_adapters", "waiting_lora_adapters", "max_lora"))
         self.cache_config = g(CACHE_CONFIG, "KV cache geometry",
@@ -215,6 +221,18 @@ class EngineTelemetry:
             self.dsa_query_tokens.labels(form)
         for kind in ("scored", "attended"):
             self.dsa_rows.labels(kind)
+        self.swa_rows = Counter(
+            "jetstream:swa_rows_total",
+            "Cached rows a layer that attends to a window of the context "
+            "(models/mla.py, ModelConfig.window_attn) deals with for its query "
+            "tokens, real lanes and prompt tokens alone: `context`, the rows "
+            "a query could see with no window (its context), and `attended`, "
+            "min(context, window) of them; counted on the host at dispatch "
+            "from positions, a token once a program; empty for a model "
+            "without window layers",
+            ("kind",), registry=self.registry)
+        for kind in ("context", "attended"):
+            self.swa_rows.labels(kind)
         self.ssm_tokens = Counter(
             "jetstream:ssm_tokens_total",
             "Rows (padded tokens) dispatched through the state-space layers, "
@@ -414,6 +432,8 @@ class EngineTelemetry:
         self.kv_usage.set(allocator.used_fraction)
         self.free_blocks.set(allocator.free_blocks)
         self.cached_blocks.set(getattr(allocator, "cached_block_count", 0))
+        self.kv_window_usage.set(
+            getattr(allocator, "window_used_fraction", 0.0))
 
     def render(self) -> bytes:
         return generate_latest(self.registry)

@@ -32,6 +32,18 @@ kind's layout at another width, so the latent kind's writes and reads serve
 it (:func:`write`, :func:`write_sequences` with ``v_pages`` None,
 :func:`read_latent_prefix`, :func:`read_rows`); the pair of them rides in a
 ``kvcache/state.Cache`` (``k`` the latent pool, ``idx`` this one).
+
+A fourth kind is the latent pool of layers that attend to a WINDOW of the
+context (models/mla.py, ``ModelConfig.window_attn``): ``[window layers,
+n_blocks, block, row_width]`` at those layers' own row width, with block ids
+and a block table of its own (:class:`WindowGeometry`). A request holds the
+pages its window reaches and no others: the owner (engine/blocks.py) gives a
+page back once every row of it lies more than ``window - 1`` behind the
+request's position, so the pool's size follows the engine's lanes and not the
+context. Its table is indexed by logical page like the other (entry p the page
+of positions ``p * block ...``; 0, the trash block, where the request holds
+none), rides with a step in the ``kvcache/state.Cache`` (``win`` the pool,
+``wt`` the step's tables), and the latent kind's writes and reads serve it.
 """
 
 from __future__ import annotations
@@ -46,16 +58,102 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import (latent_paged_decode_attention,
-                             paged_decode_attention)
+                             paged_decode_attention,
+                             swa_latent_decode_attention)
 from ..ops.pallas_dsa import sparse_latent_paged_decode_attention_pallas
 from ..ops.pallas_latent_attention import (
-    RUN_PAGES, latent_paged_decode_attention_pallas)
+    RUN_PAGES, latent_paged_decode_attention_pallas,
+    swa_latent_decode_attention_pallas)
 from ..ops.pallas_paged_attention import paged_decode_attention_pallas
 from ..ops.sparse_attention import sparse_latent_paged_decode_attention
 from . import state as state_pool
 
 TRASH_BLOCK = 0
 LANES = 128
+
+# What an engine whose model has window layers turns off, as /health lists it
+# (``settings.off_for_window_layers``).
+OFF_FOR_WINDOW_LAYERS = (
+    "prefix hits (a cached block prefix has no window rows: they were given "
+    "back while the request ran)",
+    "tp/ep/pp and multi-process meshes",
+    "roles other than both",
+    "KV export and import")
+
+
+def _whole_lanes(values: int) -> int:
+    return -(-values // LANES) * LANES
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowGeometry:
+    """The pool of the cache layers that keep a window of the context: what
+    its shape follows from."""
+
+    n_layers: int
+    n_blocks: int            # the trash block among them
+    block: int
+    latent_dim: int          # values of a row; stored padded to whole lanes
+    window: int              # tokens a query sees, its own among them
+    lanes: int               # requests that may hold pages at once
+    dtype: str
+
+    @classmethod
+    def for_engine(cls, model: Any, max_batch: int) -> "WindowGeometry | None":
+        """The engine's window pool for ``model`` (anything with
+        n_window_layers and of_window()); None for a model without such
+        layers. Every request that may be live holds ``lane_pages`` at most
+        (``max_batch`` slots, and an eighth as many again for requests that
+        finish inside the chunk in flight while a successor has their slot),
+        and one prefill window's new pages exist beside its old ones for the
+        length of a call: ``lanes x lane_pages + lane_pages`` and the trash
+        block, whatever ``max_model_len``."""
+        if not getattr(model, "n_window_layers", 0):
+            return None
+        w = model.of_window()
+        window, block = w.window_attn.window, model.kv_block_size
+        lanes = max_batch + max(2, max_batch // 8)
+        return cls(model.n_window_layers,
+                   1 + (lanes + 1) * cls.pages_of(window, block), block,
+                   w.latent_dim, window, lanes, str(jnp.dtype(model.dtype)))
+
+    @staticmethod
+    def pages_of(window: int, block: int) -> int:
+        """Pages a request holds at most between two steps: the window's
+        rows and a decode chunk's, whichever way they lie across pages."""
+        return -(-window // block) + 1
+
+    def describe(self, n_full: int) -> dict[str, Any]:
+        """The window pool's part of /health's ``settings``, beside the
+        ``n_full`` cache layers that keep the whole context."""
+        return {
+            "kv_layers_full": n_full,
+            "kv_layers_window": self.n_layers,
+            "window": self.window,
+            "window_token_bytes": self.token_bytes,
+            "window_pool_bytes": self.pool_bytes,
+            "window_blocks": self.n_blocks,
+            "off_for_window_layers": list(OFF_FOR_WINDOW_LAYERS)}
+
+    @property
+    def lane_pages(self) -> int:
+        return self.pages_of(self.window, self.block)
+
+    @property
+    def row_width(self) -> int:
+        return _whole_lanes(self.latent_dim)
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        return (self.n_layers, self.n_blocks, self.block, self.row_width)
+
+    @property
+    def token_bytes(self) -> int:
+        return self.row_width * jnp.dtype(self.dtype).itemsize
+
+    @property
+    def pool_bytes(self) -> int:
+        return self.n_layers * self.n_blocks * self.block * self.token_bytes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +180,9 @@ class PageGeometry:
     # What state-space layers keep an engine slot beside the pages
     # (kvcache/state.py); None without them, and for no engine's pool.
     state: state_pool.StateGeometry | None = None
+    # The pool of the layers that keep a window of the context, with block
+    # ids of its own; None without such layers, and for no engine's pool.
+    window: WindowGeometry | None = None
 
     @classmethod
     def for_model(cls, model: Any, n_blocks: int,
@@ -114,12 +215,13 @@ class PageGeometry:
         return dataclasses.replace(
             cls.for_model(model, hbm_kv_blocks or 1 + max_batch * per_seq,
                           per_seq),
-            state=state_pool.StateGeometry.for_engine(model, max_batch))
+            state=state_pool.StateGeometry.for_engine(model, max_batch),
+            window=WindowGeometry.for_engine(model, max_batch))
 
     @property
     def row_width(self) -> int:
         """A latent row as stored: ``latent_dim`` padded to whole lanes."""
-        return -(-self.latent_dim // LANES) * LANES
+        return _whole_lanes(self.latent_dim)
 
     @property
     def shape(self) -> tuple[int, ...]:
@@ -179,6 +281,10 @@ class PageGeometry:
         and an indexer's key pool have no sharding rule, stage split or wire
         format yet (ROADMAP R7, R8; a selection over sharded keys would need
         every shard's scores)."""
+        if self.window:
+            return ("a latent (MLA) page pool and a second one of the layers "
+                    "that keep a window of the context, under a block table "
+                    "each")
         if self.index_dim:
             return ("a latent (MLA) page pool and its indexer's key pool "
                     "beside it, under one block table")
@@ -190,7 +296,9 @@ class PageGeometry:
 
     def describe(self) -> dict[str, Any]:
         """What the cache holds, as /health's ``settings`` say it (every key
-        on every engine)."""
+        on every engine, but a window pool's, which only an engine that has
+        one reports: tests/test_family_seam.py holds the others' keys to
+        PR 43's)."""
         return {
             # The cache layers (two a double layer), a token's bytes in one
             # of them, the layout's padding counted, and the whole pool's.
@@ -206,6 +314,7 @@ class PageGeometry:
             "state_pool_bytes": self.state.pool_bytes if self.state else 0,
             "off_for_state_layers": (list(state_pool.OFF_FOR_STATE_LAYERS)
                                      if self.state else []),
+            **(self.window.describe(self.n_layers) if self.window else {}),
         }
 
 
@@ -236,7 +345,8 @@ def alloc(geom: PageGeometry, *, device=None, sharding=None
     ``geom.counted`` or an indexer's key pool (``geom.index_dim``) the pair
     is ``(cache, None)``: the pools, the state pool and a step's counts as
     one value (kvcache/state.py), unsharded too."""
-    if geom.state is not None or geom.counted or geom.index_dim:
+    if (geom.state is not None or geom.counted or geom.index_dim
+            or geom.window):
         if sharding is not None or (geom.state is not None
                                     and geom.latent_dim):
             raise ValueError("a state pool lies beside an unsharded K/V page "
@@ -245,9 +355,11 @@ def alloc(geom: PageGeometry, *, device=None, sharding=None
                              "either")
         idx = (jnp.zeros(geom.index_shape, jnp.dtype(geom.dtype),
                          device=device) if geom.index_dim else None)
+        win = (jnp.zeros(geom.window.shape, jnp.dtype(geom.dtype),
+                         device=device) if geom.window else None)
         return state_pool.alloc(geom.state, *_alloc_pools(geom, device),
                                 device=device, counts_zero=geom.counts_zero,
-                                idx=idx), None
+                                idx=idx, win=win), None
     return _alloc_pools(geom, device, sharding)
 
 
@@ -396,6 +508,11 @@ def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
         if cache.idx is not None:
             cache = dataclasses.replace(cache, idx=_write_latent_run(
                 cache.idx, k_new.idx, block_tables, lens, start))
+        if cache.win is not None:
+            # The window layers' rows, under the step's window tables: the
+            # pages the request does not keep are the trash block there.
+            cache = dataclasses.replace(cache, win=_write_latent_run(
+                cache.win, k_new.win, cache.wt, lens, start))
         return cache, None
     if v_pages is None:
         return _write_latent_run(k_pages, k_new, block_tables, lens,
@@ -479,6 +596,46 @@ def latent_decode_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
           if kernel else latent_paged_decode_attention)
     return op(q, pool, layer, block_tables, seq_lens, cur_row,
               value_dim=value_dim, scale=scale)
+
+
+def window_decode_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                            block_tables: jax.Array, seq_lens: jax.Array,
+                            cur_row: jax.Array, *, value_dim: int,
+                            scale: float, window: int,
+                            impl: str = "xla") -> jax.Array:
+    """:func:`latent_decode_attention` for a window pool: the query sees its
+    own row and the ``window - 1`` cached before it, through the lane's
+    window table. ``impl`` is the form the program traces with
+    (``ModelConfig.swa_impl``): "kernel", "kernel_interpret" or "xla"."""
+    if impl.startswith("kernel"):
+        return swa_latent_decode_attention_pallas(
+            q, pool, layer, block_tables, seq_lens, cur_row,
+            value_dim=value_dim, scale=scale, window=window,
+            interpret=impl == "kernel_interpret")
+    return swa_latent_decode_attention(
+        q, pool, layer, block_tables, seq_lens, cur_row,
+        value_dim=value_dim, scale=scale, window=window)
+
+
+def window_prefix_pages(table_row: jax.Array, prefix_len: jax.Array,
+                        block: int, window: int
+                        ) -> tuple[jax.Array, jax.Array]:
+    """The pages of a window pool that a prefill window starting at
+    ``prefix_len`` [1] (a multiple of the page) can still see, out of the
+    sequence's window table [1, W]: (their ids [1, P], the positions of
+    their rows [1, P * block]), the P pages that end where the window starts
+    (from page 0 where fewer lie before it: the pages at and past
+    ``prefix_len`` are read as the trash block, and the caller masks their
+    positions). :func:`read_latent_prefix` reads them."""
+    n = -(-(window - 1) // block)
+    first = jnp.maximum(prefix_len // block - n, 0)               # [1]
+    at = first[:, None] + jnp.arange(n, dtype=first.dtype)[None, :]
+    ids = jnp.take_along_axis(
+        table_row, jnp.minimum(at, table_row.shape[1] - 1), axis=1)
+    ids = jnp.where(at * block < prefix_len[:, None], ids, TRASH_BLOCK)
+    pos = (first[:, None] * block
+           + jnp.arange(n * block, dtype=first.dtype)[None, :])
+    return ids, pos
 
 
 def read_latent_prefix(pool: jax.Array, layer: jax.Array,

@@ -137,6 +137,11 @@ class Cache:
     counts_zero: bool = dataclasses.field(default=False,
                                           metadata=dict(static=True))
     idx: jax.Array | None = None    # the indexer's key pool beside a latent k
+    # The latent pool of the layers that keep a window of the context
+    # (kvcache/pages.py), and this step's tables of it [B, table width],
+    # which ride with the step as ``slots`` do.
+    win: jax.Array | None = None
+    wt: jax.Array | None = None
 
 
 @jax.tree_util.register_dataclass
@@ -154,32 +159,37 @@ class Fresh:
     held: jax.Array
     zero: jax.Array | None = None
     idx: jax.Array | None = None    # the rows' indexer keys [L, B, S, width]
+    win: jax.Array | None = None    # the window layers' rows [Lw, B, S, width]
 
 
 def alloc(geom: StateGeometry | None, k_pages: jax.Array,
           v_pages: jax.Array | None, *, device=None,
-          counts_zero: bool = False, idx: jax.Array | None = None) -> Cache:
+          counts_zero: bool = False, idx: jax.Array | None = None,
+          win: jax.Array | None = None) -> Cache:
     """A zeroed state pool beside the given page pools; ``geom`` None: the
     page pools alone (with ``idx``, an indexer's key pool beside a latent
-    one), in the value that carries a step's counts."""
+    one; with ``win``, the window layers' pool), in the value that carries a
+    step's counts."""
     if geom is None:
         return Cache(k_pages, v_pages, None, None, counts_zero=counts_zero,
-                     idx=idx)
+                     idx=idx, win=win)
     return Cache(k_pages, v_pages,
                  jnp.zeros(geom.ssm_shape, jnp.float32, device=device),
                  jnp.zeros(geom.conv_shape, jnp.dtype(geom.dtype),
                            device=device), counts_zero=counts_zero)
 
 
-def at_slots(cache: Any, slots: Any) -> Any:
+def at_slots(cache: Any, slots: Any, wt: Any = None) -> Any:
     """``cache`` as a step on rows ``slots`` takes it (``slots`` from the
-    host: the step donates its cache, and what rides in it goes with it);
+    host: the step donates its cache, and what rides in it goes with it),
+    with the step's window tables ``wt`` where it keeps a window pool;
     anything that is no :class:`Cache` (a page pool) goes through as it is."""
     if not isinstance(cache, Cache):
         return cache
     return dataclasses.replace(
         cache, slots=np.asarray(slots, np.int32), held=np.zeros((), np.int32),
-        zero=np.zeros((), np.int32) if cache.counts_zero else None)
+        zero=np.zeros((), np.int32) if cache.counts_zero else None,
+        wt=None if wt is None else np.asarray(wt, np.int32))
 
 
 def take_counts(cache: Any
@@ -190,7 +200,8 @@ def take_counts(cache: Any
     with it."""
     if not isinstance(cache, Cache):
         return cache, None, None
-    return (dataclasses.replace(cache, slots=None, held=None, zero=None),
+    return (dataclasses.replace(cache, slots=None, held=None, zero=None,
+                                wt=None),
             cache.held, cache.zero)
 
 

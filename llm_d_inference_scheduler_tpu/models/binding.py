@@ -28,13 +28,14 @@ from .configs import ModelConfig
 def family(cfg: ModelConfig):
     """The module that holds ``cfg``'s block: ``init_params``, ``forward``,
     ``decode_step`` and ``prefill_with_prefix`` under one set of signatures.
-    A layer pattern (layer_pattern set) names models/hybrid.py, whose layers
-    are state-space, expert and attention mixers in that pattern; latent
-    attention (kv_lora_rank > 0) names models/mla.py; everything else is
-    models/llama.py's block."""
-    if cfg.layer_pattern:
-        return hybrid
-    return mla if cfg.kv_lora_rank else llama
+    Latent attention (kv_lora_rank > 0) names models/mla.py, whose layer
+    pattern, where it has one, says which of two kinds of attention a layer
+    is; any other layer pattern names models/hybrid.py, whose layers are
+    state-space, expert and attention mixers in that pattern; everything else
+    is models/llama.py's block."""
+    if cfg.kv_lora_rank:
+        return mla
+    return hybrid if cfg.layer_pattern else llama
 
 
 def selection_counts(first: np.ndarray, n: np.ndarray, topk: int
@@ -54,6 +55,15 @@ def selection_counts(first: np.ndarray, n: np.ndarray, topk: int
     left = int(np.sum((lo + last) * m // 2 - m * topk))
     return {"selected": int(m.sum()), "all": int((n - m).sum()),
             "scored": scored, "attended": scored - left}
+
+
+def window_counts(first: np.ndarray, n: np.ndarray, window: int
+                  ) -> dict[str, int]:
+    """What the same runs put through a layer that attends to a window
+    (``jetstream:swa_rows_total``): a query of context c has c rows of
+    ``context`` and min(c, window) ``attended``."""
+    got = selection_counts(first, n, window)
+    return {"context": got["scored"], "attended": got["attended"]}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,6 +125,10 @@ class Bound:
                            ("dsa_query_tokens", "all", got["all"]),
                            ("dsa_rows", "scored", got["scored"]),
                            ("dsa_rows", "attended", got["attended"])]
+            if m.window_attn and queries is not None:
+                counts += [("swa_rows", kind, amount) for kind, amount in
+                           window_counts(*queries,
+                                         m.window_attn.window).items()]
         if m.n_state_layers:
             # models/hybrid.py: one position a sequence is the step form, a
             # run of them the scan form; a first window starts its slots.
@@ -142,6 +156,9 @@ class Bound:
             "zero_experts": m.n_zero_experts,
             # How a decode step fetches its slots' recurrent states.
             "state_update": m.ssm_impl if m.n_state_layers else None,
+            # The form of the layers that attend to a window of the context
+            # (a key only a model with such layers has).
+            **({"window_attention": m.swa_impl} if m.window_attn else {}),
         }
 
 
@@ -155,7 +172,8 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     fetches its slots' recurrent states (``ssm_impl``) is
     ``pallas_ssm.use_kernel``'s; a selecting block's indexer (``index_impl``)
     runs its kernel (ops/pallas_dsa.py) on a TPU, where the per-head products
-    must not reach HBM, and the plain form on the CPU; the MoE FFN's form is
+    must not reach HBM, and the plain form on the CPU, and so do the layers that
+    attend to a window (``swa_impl``); the MoE FFN's form is
     chosen per program (``Bound.model_for``). ``forced`` names form fields a
     caller sets over the rules (a comparison of two forms on one device)."""
     forms: dict[str, str] = {}
@@ -168,6 +186,12 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     if mcfg.index_topk:
         forms["index_impl"] = ("kernel_interpret" if interpret else
                                "kernel" if platform == "tpu" else "xla")
+    if mcfg.window_attn:
+        # The window layers' two kernels (ops/pallas_latent_attention.py's
+        # walk over the window's pages, ops/pallas_dsa.py's tiles under the
+        # band) on a TPU, the plain forms on the CPU.
+        forms["swa_impl"] = ("kernel_interpret" if interpret else
+                             "kernel" if platform == "tpu" else "xla")
     mcfg = dataclasses.replace(mcfg, **{**forms, **(forced or {})})
     return Bound(
         module=family(mcfg), mcfg=mcfg,

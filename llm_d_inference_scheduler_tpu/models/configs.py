@@ -8,6 +8,29 @@ Dimensions follow the public Llama-3 architecture card.
 from __future__ import annotations
 
 import dataclasses
+import functools
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """What a second kind of latent-attention layer has of its own where a
+    model mixes two (``ModelConfig.window_attn``): the widths a layer of the
+    first kind reads off the configuration's flat fields of the same names,
+    and the window. :meth:`ModelConfig.of_window` is the configuration as
+    such a layer's code reads it, so no block has a second copy of anything."""
+
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    # Tokens a query attends to, its own position among them: a query at t
+    # sees s with 0 <= t - s < window.
+    window: int
+    # A sigmoid gate a head on the attention's output (``attn_gate``).
+    gate: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,6 +156,19 @@ class ModelConfig:
     # "kernel_interpret" (CPU tests). models.bind sets it from what the
     # engine is.
     index_impl: str = "xla"
+    # A gate a head on the attention's output, ahead of W_o: ``o_j *=
+    # sigmoid(h W_g)_j`` from the layer's normed input (models/mla.py).
+    attn_gate: bool = False
+    # Window and full latent attention in one model (models/mla.py): with
+    # kv_lora_rank > 0 the layer pattern says which kind a layer is, "*" the
+    # kind the flat fields above describe (the whole context, or the rows an
+    # indexer selects) and "W" a layer of ``window_attn``'s widths that
+    # attends to the last ``window_attn.window`` tokens through a page pool of
+    # its own (kvcache/pages.py).
+    window_attn: AttnKind | None = None
+    # The form of the window layers' programs: "xla" | "kernel"
+    # (ops/pallas_swa.py) | "kernel_interpret". models.bind sets it.
+    swa_impl: str = "xla"
 
     @property
     def n_state_layers(self) -> int:
@@ -146,11 +182,21 @@ class ModelConfig:
                 else self.n_layers * self.attn_sublayers)
 
     @property
+    def n_window_layers(self) -> int:
+        """Cache layers that keep a window of the context alone (0: none)."""
+        return self.layer_pattern.count("W") if self.window_attn else 0
+
+    def of_window(self) -> "ModelConfig":
+        """The configuration as a window layer's attention reads it: the
+        flat attention fields at ``window_attn``'s values, and no indexer."""
+        return _of_window(self)
+
+    @property
     def n_expert_layers(self) -> int:
         """Layers with a router (0: a dense model)."""
         if not self.n_experts:
             return 0
-        if self.layer_pattern:
+        if self.layer_pattern and not self.kv_lora_rank:
             return self.layer_pattern.count("E")
         return self.n_layers - self.first_k_dense
 
@@ -202,6 +248,18 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+
+@functools.lru_cache(maxsize=None)
+def _of_window(cfg: ModelConfig) -> ModelConfig:
+    w = cfg.window_attn
+    return dataclasses.replace(
+        cfg, n_heads=w.n_heads, n_kv_heads=w.n_heads,
+        q_lora_rank=w.q_lora_rank, kv_lora_rank=w.kv_lora_rank,
+        qk_nope_head_dim=w.qk_nope_head_dim,
+        qk_rope_head_dim=w.qk_rope_head_dim, v_head_dim=w.v_head_dim,
+        rope_theta=w.rope_theta, rope_yarn=(), attn_gate=w.gate,
+        index_topk=0)
 
 
 LLAMA3_8B = ModelConfig(
@@ -425,6 +483,21 @@ TINY_DSA = dataclasses.replace(
     rope_yarn=(40.0, 32, 32.0, 1.0, 1.0), index_topk=24, index_n_heads=4,
     index_head_dim=16)
 
+# Window and full latent attention mixed, at small widths aligned to nothing
+# (CI tests): a dense layer and an expert layer that select 6 rows, then three
+# window layers of other widths (2 heads, a rank of 40, 12 + 8 a query head)
+# that see 7 tokens; a gate a head on both kinds, both low-rank rescales, a
+# page of 4. Every expert held (tests cut a share).
+TINY_SWA = dataclasses.replace(
+    TINY_MLA, name="tiny-swa", n_layers=5, n_experts=16, experts_per_token=3,
+    q_lora_rank=20, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+    index_topk=6, index_n_heads=4, index_head_dim=16, kv_block_size=4,
+    attn_gate=True, layer_pattern="**WWW",
+    window_attn=AttnKind(n_heads=2, q_lora_rank=28, kv_lora_rank=40,
+                         qk_nope_head_dim=12, qk_rope_head_dim=8,
+                         v_head_dim=16, rope_theta=500.0, window=7,
+                         gate=True))
+
 # NVIDIA-Nemotron-3-Super-120B-A12B's language model (public config.json,
 # model_type nemotron_h): 88 layers of one mixer each -- 40 Mamba-2, 40
 # LatentMoE (512 experts of 2688 in a 1024-wide latent space, 22 a token,
@@ -496,6 +569,7 @@ _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
                                  KIMI_VL_A3B, TINY_MLA, TINY_LONGCAT, TINY_DSA,
+                                 TINY_SWA,
                                  NEMOTRON_3_SUPER,
                                  NEMOTRON_3_SUPER_CUT, TINY_HYBRID)}
 
@@ -518,5 +592,7 @@ def get_config(name: str) -> ModelConfig:
         known = set(ModelConfig.__dataclass_fields__)
         fields = {k: tuple(v) if isinstance(v, list) else v
                   for k, v in fields.items() if k in known}
+        if isinstance(fields.get("window_attn"), dict):
+            fields["window_attn"] = AttnKind(**fields["window_attn"])
         return ModelConfig(**fields)
     raise ValueError(f"unknown model config {name!r}; have {sorted(_REGISTRY)}")

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from .configs import ModelConfig
+from .configs import AttnKind, ModelConfig
 
 __all__ = ["config_from_hf", "convert_state_dict", "main"]
 
@@ -79,13 +79,90 @@ def _yarn(hf, name: str) -> tuple[float, ...]:
             float(scaling.get("mscale_all_dim", 1)))
 
 
+# The keys that state a second kind of layer and a gate (``model_type``
+# dots3_note): what models/mla.py computes of each, and every key of the
+# window kind that has to be there. A config that names a layer kind, a gate
+# or a ``swa_*`` key beyond these is refused, not served as something else.
+_LAYER_KINDS = {"full_attention": "*", "sliding_attention": "W"}
+_GATES = {None: False, "none": False, "headwise": True}
+_SWA_KEYS = ("swa_num_attention_heads", "swa_q_lora_rank", "swa_kv_lora_rank",
+             "swa_qk_nope_head_dim", "swa_qk_rope_head_dim", "swa_v_head_dim",
+             "swa_rope_theta")
+_SWA_OTHER = {"swa_num_key_value_heads", "swa_attention_gate_type"}
+
+
+def _layer_kinds(hf, name: str) -> dict:
+    """The ModelConfig fields of a model whose layers are of two kinds
+    (``layer_types``, the ``swa_*`` widths, ``sliding_window_size``), of its
+    gates (``attention_gate_type``, ``swa_attention_gate_type``) and of its
+    low-rank rescale (``apply_mla_qkv_lora_rescale``: LongCat-Flash's two
+    factors, on both kinds); nothing for a config that states none of it."""
+    gates = {}
+    for key in ("attention_gate_type", "swa_attention_gate_type"):
+        kind = getattr(hf, key, None)
+        if kind not in _GATES:
+            raise ValueError(
+                f"{name}: {key}={kind!r} is not supported (models/mla.py "
+                f"computes a gate a head, 'headwise', or none)")
+        gates[key] = _GATES[kind]
+    rescale = bool(getattr(hf, "apply_mla_qkv_lora_rescale", False))
+    out = dict(attn_gate=gates["attention_gate_type"],
+               mla_scale_q_lora=rescale, mla_scale_kv_lora=rescale)
+    swa = sorted(k for k in vars(hf) if k.startswith("swa_"))
+    kinds = getattr(hf, "layer_types", None)
+    if kinds is None:
+        if swa or getattr(hf, "sliding_window_size", None):
+            raise ValueError(
+                f"{name}: {swa or ['sliding_window_size']} without "
+                "layer_types: no layer says it is of that kind")
+        return out
+    other = sorted(set(kinds) - set(_LAYER_KINDS))
+    if other or len(kinds) != hf.num_hidden_layers:
+        raise ValueError(
+            f"{name}: layer_types names {other or len(kinds)} (models/mla.py "
+            f"computes {sorted(_LAYER_KINDS)}, one for each of the "
+            f"{hf.num_hidden_layers} layers)")
+    pattern = "".join(_LAYER_KINDS[k] for k in kinds)
+    unknown = sorted(set(swa) - set(_SWA_KEYS) - _SWA_OTHER)
+    if unknown:
+        raise ValueError(f"{name}: {unknown} is not supported (models/mla.py "
+                         f"reads {sorted(_SWA_KEYS)} of a window layer)")
+    if "W" not in pattern:
+        if swa:
+            raise ValueError(f"{name}: {swa} with no sliding_attention layer")
+        return out
+    missing = [k for k in (*_SWA_KEYS, "sliding_window_size")
+               if not getattr(hf, k, None)]
+    if missing or "W" in pattern[:hf.first_k_dense_replace]:
+        raise ValueError(
+            f"{name}: a sliding_attention layer needs {missing} too, and the "
+            "leading dense layers are of the full kind")
+    heads = hf.swa_num_attention_heads
+    if getattr(hf, "swa_num_key_value_heads", heads) != heads:
+        raise ValueError(f"{name}: swa_num_key_value_heads must equal "
+                         "swa_num_attention_heads (latent attention)")
+    return dict(
+        **out, layer_pattern=pattern,
+        window_attn=AttnKind(
+            n_heads=heads, q_lora_rank=hf.swa_q_lora_rank,
+            kv_lora_rank=hf.swa_kv_lora_rank,
+            qk_nope_head_dim=hf.swa_qk_nope_head_dim,
+            qk_rope_head_dim=hf.swa_qk_rope_head_dim,
+            v_head_dim=hf.swa_v_head_dim,
+            rope_theta=float(hf.swa_rope_theta),
+            window=hf.sliding_window_size,
+            gate=gates["swa_attention_gate_type"]))
+
+
 def _mla_config_from_hf(hf, name: str) -> ModelConfig:
     """The DeepSeek-V3 family (latent attention, sigmoid-routed experts
-    beside a shared one): Kimi-VL's language model is one, and DeepSeek-V3.2's
+    beside a shared one): Kimi-VL's language model is one, DeepSeek-V3.2's
     (``model_type`` deepseek_v32: a low-rank query, YaRN, group-limited
-    routing and the indexer's three ``index_*`` keys) another; a chip's
-    share of the experts as :func:`_held_share` reads it."""
+    routing and the indexer's three ``index_*`` keys) another, and one whose
+    layers are of two kinds (``model_type`` dots3_note: :func:`_layer_kinds`)
+    a third; a chip's share of the experts as :func:`_held_share` reads it."""
     _refuse_other_options(hf, name, _MLA_ONLY, "models/mla.py")
+    kinds = _layer_kinds(hf, name)
     share = _held_share(hf)
     n_group = getattr(hf, "n_group", None) or 1
     topk_group = getattr(hf, "topk_group", None) or 1
@@ -130,6 +207,7 @@ def _mla_config_from_hf(hf, name: str) -> ModelConfig:
         index_topk=index[0],
         index_n_heads=index[1],
         index_head_dim=index[2],
+        **kinds,
     )
 
 

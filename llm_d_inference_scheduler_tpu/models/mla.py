@@ -95,6 +95,23 @@ and neither scores nor selects: it IS dense latent attention. YaRN
 by its magnitude factor squared; the router selects inside its best groups
 (models/routing.py).
 
+**Two kinds of layer in one model** (``cfg.window_attn``; the layer pattern
+says which a layer is, "*" or "W"). A "*" layer is the block above at the
+configuration's flat widths. A "W" layer is the same latent attention at
+``cfg.window_attn``'s widths (:meth:`ModelConfig.of_window`: its own head
+count, ranks, head sizes and rotary base; no indexer) whose query at t attends
+to s with ``0 <= t - s < window``. Its rows live in a page pool of their own
+under a table of their own (kvcache/pages.py, ``state.Cache.win`` / ``.wt``),
+of which a request keeps the pages its window reaches: decode walks those
+alone (``swa_latent_decode_attention``), a prefill window reads the pages that
+end where it starts beside its own rows (``swa_window_attention``), and a
+first window the band of its own. The window layers are a third stack of the
+parameter tree (``params["window"]``, expert layers of the window kind);
+:func:`_segments` walks the stacks in the published order, a scan a run of
+like layers. Where ``cfg.attn_gate`` (a kind's own flag) the attention's
+output is gated a head, ``o_j *= sigmoid(h W_g)_j`` from the layer's normed
+input, ahead of ``W_o``.
+
 Where ``cfg.tallies_choices`` (a held range, or zero-compute outputs, or a
 block that selects: its two pools ride there anyway) the
 step programs count the choices held here and the zero ones, and the pool
@@ -129,14 +146,14 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     Norm weights and the selection bias are drawn too, not ones and zeros:
     a run on random weights then sees them."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    D, V, H, E = cfg.d_model, cfg.vocab_size, cfg.n_heads, cfg.n_experts
-    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    D, V, E = cfg.d_model, cfg.vocab_size, cfg.n_experts
     Fm, Fs = cfg.moe_d_ff, cfg.n_shared_experts * cfg.moe_d_ff
     keys = iter(jax.random.split(key, 40))
     # (The indexer draws from keys of its own, so that the blocks without
     # one keep the weights they had.)
     index_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
+    gate_keys = iter(jax.random.split(jax.random.fold_in(key, 2), 8))
+    window_keys = iter(jax.random.split(jax.random.fold_in(key, 3), 40))
 
     def w(shape, fan_in, keys=keys):
         return (jax.random.normal(next(keys), shape, jnp.float32)
@@ -159,7 +176,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                 next(index_keys), (L, Di), jnp.float32)).astype(dtype),
             "w_idx": w((L, D, Hi), D, index_keys)}
 
-    def attention(L):
+    def attention(L, c=cfg, keys=keys):
         # Where the block scales q or the latent by sqrt(d_model / rank), the
         # up-projection is drawn at the width that factor refers to (variance
         # 1 / d_model: the scale is there to correct exactly that), so q, k
@@ -168,22 +185,41 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         # in the attention logits at the published ranks: attention turns
         # one-hot, and bf16 rounding then flips WHICH row it attends to
         # (chip run, PR 39: 20-50% of max |logit| against the reference).
-        rq = cfg.q_lora_rank
-        query = ({"wqa": w((L, D, rq), D), "q_norm": norm((L, rq)),
+        # ``c`` is the configuration at the layers' kind (cfg.of_window()).
+        H, rq, r = c.n_heads, c.q_lora_rank, c.kv_lora_rank
+        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        query = ({"wqa": w((L, D, rq), D, keys), "q_norm": norm((L, rq), keys),
                   "wqb": w((L, rq, H * (dn + dr)),
-                           D if cfg.mla_scale_q_lora else rq)} if rq
-                 else {"wq": w((L, D, H * (dn + dr)), D)})
+                           D if c.mla_scale_q_lora else rq, keys)} if rq
+                 else {"wq": w((L, D, H * (dn + dr)), D, keys)})
+        # (The gate draws from keys of its own, as the indexer does.)
+        gate = ({"wg": w((L, D, H), D, gate_keys)} if c.attn_gate else {})
         return {
             **query,
-            **indexer(L),
-            "wkva": w((L, D, r + dr), D),
-            "kv_norm": norm((L, r)),
+            **(indexer(L) if c.index_topk else {}),
+            **gate,
+            "wkva": w((L, D, r + dr), D, keys),
+            "kv_norm": norm((L, r), keys),
             "wkvb": w((L, r, H * (dn + dv)),
-                      D if cfg.mla_scale_kv_lora else r),
-            "wo": w((L, H * dv, D), H * dv),
-            "ln_attn": norm((L, D)),
-            "ln_mlp": norm((L, D)),
+                      D if c.mla_scale_kv_lora else r, keys),
+            "wo": w((L, H * dv, D), H * dv, keys),
+            "ln_attn": norm((L, D), keys),
+            "ln_mlp": norm((L, D), keys),
         }
+
+    def experts(L, keys=keys):
+        Eh = cfg.held_experts[1]
+        return {
+            "router": w((L, D, E), D, keys),
+            # The published bias is what load balancing left behind, of the
+            # order of the scores' spread; drawn so that it changes selections.
+            "router_bias": (0.1 * jax.random.normal(
+                next(keys), (L, E), jnp.float32)),
+            # (The experts held here: all of them, or a chip's share.)
+            "w1": w((L, Eh, D, Fm), D, keys), "w3": w((L, Eh, D, Fm), D, keys),
+            "w2": w((L, Eh, Fm, D), Fm, keys),
+            "w1s": w((L, D, Fs), D, keys), "w3s": w((L, D, Fs), D, keys),
+            "w2s": w((L, Fs, D), Fs, keys)}
 
     params = {"embed": w((V, D), D), "final_norm": norm((D,)),
               "lm_head": w((D, V), D)}
@@ -203,25 +239,19 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             "w1": w((L, Eh, D, Fm), D), "w3": w((L, Eh, D, Fm), D),
             "w2": w((L, Eh, Fm, D), Fm)}
         return params
-    Ld, Eh = cfg.first_k_dense, cfg.held_experts[1]
-    Le = cfg.n_layers - Ld
+    Ld, Lw = cfg.first_k_dense, cfg.n_window_layers
+    Le = cfg.n_layers - Ld - Lw
     if Ld:
         params["dense"] = {
             **attention(Ld),
             "w1": w((Ld, D, cfg.d_ff), D), "w3": w((Ld, D, cfg.d_ff), D),
             "w2": w((Ld, cfg.d_ff, D), cfg.d_ff)}
-    params["layers"] = {
-        **attention(Le),
-        "router": w((Le, D, E), D),
-        # The published bias is what load balancing left behind, of the
-        # order of the scores' spread; drawn so that it changes selections.
-        "router_bias": (0.1 * jax.random.normal(
-            next(keys), (Le, E), jnp.float32)),
-        # (The experts held here: all of them, or a chip's share.)
-        "w1": w((Le, Eh, D, Fm), D), "w3": w((Le, Eh, D, Fm), D),
-        "w2": w((Le, Eh, Fm, D), Fm),
-        "w1s": w((Le, D, Fs), D), "w3s": w((Le, D, Fs), D),
-        "w2s": w((Le, Fs, D), Fs)}
+    params["layers"] = {**attention(Le), **experts(Le)}
+    if Lw:
+        # The window layers, expert layers all: a stack of the window kind's
+        # shapes, from keys of its own.
+        params["window"] = {**attention(Lw, cfg.of_window(), window_keys),
+                            **experts(Lw, window_keys)}
     return params
 
 
@@ -425,24 +455,28 @@ def _scale(cfg: ModelConfig) -> float:
 
 
 def expanded_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, rows,
-                       mask) -> jnp.ndarray:
+                       mask, *, impl: str | None = None,
+                       name: str = "dsa_window_attention") -> jnp.ndarray:
     """Queries [B, S, H, .] against cache rows [B, T, r + dr], every row
     carried out to its keys and values; ``mask`` [B, S, T] says which rows a
     query sees. Returns [B, S, H * dv]. Products in the operands' dtype with
     f32 accumulation, the softmax in f32. Where the block's programs run
     their kernels (``cfg.index_impl``: a block that selects, on a TPU) the
     scores stay in VMEM a tile at a time (ops/pallas_dsa.py): whole, at 128
-    heads, they would be 8.6 GB for a window over 16k rows."""
+    heads, they would be 8.6 GB for a window over 16k rows. ``impl`` and
+    ``name`` are the window layers' to pass: their own form
+    (``cfg.swa_impl``) and the kernel's name in a device trace."""
     B, S, H, _ = q_nope.shape
     r = cfg.kv_lora_rank
     w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
     c, k_rope = rows[..., :r], rows[..., r:cfg.latent_dim]
-    if cfg.index_impl.startswith("kernel"):
+    impl = cfg.index_impl if impl is None else impl
+    if impl.startswith("kernel"):
         out = pallas_dsa.masked_window_attention_pallas(
             jnp.swapaxes(q_nope, 1, 2), jnp.swapaxes(q_rope, 1, 2),
             jnp.einsum("btr,rhd->bhtd", c, w_uk), k_rope,
             jnp.einsum("btr,rhd->bhtd", c, w_uv), mask, scale=_scale(cfg),
-            interpret=cfg.index_impl == "kernel_interpret")
+            interpret=impl == "kernel_interpret", name=name)
         return jnp.swapaxes(out, 1, 2).reshape(B, S, -1)
     k_nope = jnp.einsum("btr,rhd->bthd", c, w_uk)
     v = jnp.einsum("btr,rhd->bthd", c, w_uv)
@@ -481,37 +515,87 @@ _SUBLAYER = ("wqa", "q_norm", "wqb", "wkva", "kv_norm", "wkvb", "wo",
              "ln_attn", "ln_mlp", "w1d", "w3d", "w2d")
 
 
-def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
-            attend: Callable[[Params, jnp.ndarray, jnp.ndarray],
-                             tuple[jnp.ndarray, jnp.ndarray]]
+def _gated(cfg: ModelConfig, lp: Params, h: jnp.ndarray, a: jnp.ndarray
+           ) -> jnp.ndarray:
+    """The attention's output ``a`` [..., H * dv] gated a head by the
+    layer's normed input h: ``o_j *= sigmoid(h W_g)_j`` (f32), where the
+    layer's kind has a gate (``cfg`` at that kind)."""
+    if not cfg.attn_gate:
+        return a
+    g = jax.nn.sigmoid(jnp.dot(h, lp["wg"],
+                               preferred_element_type=jnp.float32))
+    out = a.reshape(*g.shape, -1).astype(jnp.float32) * g[..., None]
+    return out.astype(a.dtype).reshape(a.shape)
+
+
+def _segments(params: Params, cfg: ModelConfig
+              ) -> list[tuple[str, int, int, str, int]]:
+    """The stacks in layer order: (stack, its layers [lo, hi), their kind,
+    the first one's number among its kind's cache layers) a run of like
+    layers. Without window layers: the leading dense layers, then the expert
+    layers, each whole. With them, by the layer pattern: "W" is the next
+    layer of ``params["window"]``, "*" the next of ``dense`` (while the
+    leading dense layers last) or of ``layers``."""
+    if not cfg.window_attn:
+        out, first = [], 0
+        for name in ("dense", "layers"):
+            if name in params:
+                n = params[name]["wo"].shape[0] // cfg.attn_sublayers
+                out.append((name, 0, n, "full", first))
+                first += n
+        return out
+    segs: list[list] = []
+    used = dict(dense=0, layers=0, window=0)
+    caches = dict(full=0, window=0)
+    for i, ch in enumerate(cfg.layer_pattern):
+        kind = "window" if ch == "W" else "full"
+        name = ("window" if ch == "W" else
+                "dense" if i < cfg.first_k_dense else "layers")
+        if segs and segs[-1][0] == name:
+            segs[-1][2] += 1
+        else:
+            segs.append([name, used[name], used[name] + 1, kind,
+                         caches[kind]])
+        used[name] += 1
+        caches[kind] += 1
+    return [tuple(seg) for seg in segs]
+
+
+def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend
             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
-                       jnp.ndarray | None]:
-    """x through every block: the leading dense layers, then the expert
-    layers, one scan each. ``attend(lp, h, layer)`` -> (attention output
-    [..., H * dv], the tokens' cache rows); ``layer`` counts cache layers over
-    both stacks, as the page pool does. Returns (x, rows [n_kv_layers, ...],
-    the router's outputs chosen in every expert layer [n_expert_layers, T,
-    k], and where ``cfg.tallies_choices`` the counts [2] of those that are
-    held here and of those that compute nothing)."""
-    rows, routes, counts, first = [], None, None, 0
-    for name in ("dense", "layers"):
-        if name not in params:
-            continue
+                       jnp.ndarray | None, jnp.ndarray | None]:
+    """x through every block, a scan a run of like layers
+    (:func:`_segments`). ``attend(lp, h, layer)`` -> (attention output [..., H
+    * dv], the tokens' cache rows); ``layer`` counts the cache layers of the
+    layer's kind, as its page pool does. A model with window layers passes a
+    function a kind, ``{"full": ..., "window": ...}``. Returns (x, rows
+    [n_kv_layers, ...], the router's outputs chosen in every expert layer
+    [n_expert_layers, T, k], where ``cfg.tallies_choices`` the counts [2] of
+    those that are held here and of those that compute nothing, and the
+    window layers' rows [n_window_layers, ...] or None)."""
+    attends = attend if isinstance(attend, dict) else {"full": attend}
+    rows = dict(full=[], window=[])
+    routes, counts = [], None
+    double = cfg.attn_sublayers == 2
+    for name, lo, hi, kind, first in _segments(params, cfg):
         # Where the grouped kernel serves, the routed experts' weights stay
         # whole beside the scan (models/llama._over_layers, of one stack).
-        double = cfg.attn_sublayers == 2
-        stack = params[name]
+        stack, attend = params[name], attends[kind]
+        kcfg = cfg.of_window() if kind == "window" else cfg
         subs = {k: stack[k] for k in _SUBLAYER} if double else {}
         sliced, whole = _over_layers(
             cfg, {k: v for k, v in stack.items() if k not in subs})
-        n = stack["wo"].shape[0] // cfg.attn_sublayers
+        n = hi - lo
+        if n != stack["wo"].shape[0] // cfg.attn_sublayers:
+            # A run inside a stack (a pattern with more than one period).
+            sliced = jax.tree.map(lambda a: a[lo:hi], sliced)
 
         def body(x, layer_in):
             lp, layer = layer_in
             lp = {**lp, **whole}
-            a, row = attend(lp, rms_norm(x, lp["ln_attn"], cfg.norm_eps),
-                            layer)
-            x = x + a @ lp["wo"]
+            h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+            a, row = attend(lp, h, layer)
+            x = x + _gated(kcfg, lp, h, a) @ lp["wo"]
             y, chosen, tally = _ffn(cfg, lp, rms_norm(x, lp["ln_mlp"],
                                                       cfg.norm_eps))
             return x + y, (row, chosen, tally)
@@ -537,11 +621,18 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             (sliced, first + jnp.arange(n, dtype=jnp.int32)))
         if double:                    # [n, 2, ...] -> a cache layer a row
             stack_rows = stack_rows.reshape(-1, *stack_rows.shape[2:])
-        rows.append(stack_rows)
-        routes = chosen if chosen is not None else routes
-        counts = tally.sum(axis=0) if tally is not None else counts
-        first += n
-    return x, jnp.concatenate(rows, axis=0), routes, counts
+        rows[kind].append(stack_rows)
+        if chosen is not None:
+            routes.append(chosen)
+        if tally is not None:
+            tally = tally.sum(axis=0)
+            counts = tally if counts is None else counts + tally
+    if len(routes) > 1:               # every expert layer's, in layer order
+        routes = [jnp.concatenate(routes, axis=0)]
+    return (x, jnp.concatenate(rows["full"], axis=0),
+            routes[0] if routes else None, counts,
+            jnp.concatenate(rows["window"], axis=0) if rows["window"]
+            else None)
 
 
 def _pool_of(k_pages):
@@ -552,14 +643,26 @@ def _pool_of(k_pages):
 
 
 def _kept(k_pages, pool: jnp.ndarray, counts: jnp.ndarray | None,
-          idx: jnp.ndarray | None = None):
+          idx: jnp.ndarray | None = None, win: jnp.ndarray | None = None):
     """What a step hands back in ``k_pages``' place: the pool as the step
-    left it (and the indexer's key pool ``idx`` where there is one), the
-    step's counts added where it rides with them."""
+    left it (and the indexer's key pool ``idx`` and the window layers' pool
+    ``win`` where there is one), the step's counts added where it rides with
+    them."""
     if not isinstance(k_pages, state.Cache):
         return pool
-    return state.counted(dataclasses.replace(k_pages, k=pool, idx=idx),
-                         *counts)
+    return state.counted(
+        dataclasses.replace(k_pages, k=pool, idx=idx, win=win), *counts)
+
+
+def _window_side(cfg: ModelConfig, positions: jnp.ndarray):
+    """What the window layers of a mixed model read beside the other kind's:
+    (their configuration, the rotary table of ``positions`` at their base,
+    the window in tokens); None without such layers."""
+    if not cfg.window_attn:
+        return None
+    wcfg = cfg.of_window()
+    return (wcfg, rope_table(positions, wcfg.qk_rope_head_dim,
+                             wcfg.rope_theta), wcfg.window_attn.window)
 
 
 def forward(
@@ -604,8 +707,20 @@ def forward(
         return (expanded_attention(cfg, lp, q_nope, q_rope, rows, seen),
                 _with_picked(want_routes, rows, seen))
 
-    x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
-                                      attend)
+    if cfg.window_attn:
+        wcfg, (wcos, wsin), window = _window_side(cfg, positions)
+        band = mask & (positions[:, :, None] - positions[:, None, :] < window)
+
+        def attend_window(lp, h, layer):
+            q_nope, q_rope, rows = _project(wcfg, lp, h, wcos, wsin)
+            return expanded_attention(
+                wcfg, lp, q_nope, q_rope, rows, band, impl=cfg.swa_impl,
+                name="swa_window_attention"), rows
+
+        attend = dict(full=attend, window=attend_window)
+
+    x, rows, routes, counts, wrows = _blocks(
+        params, cfg, params["embed"][tokens], attend)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     kv = None
     if want_routes and cfg.index_topk:
@@ -615,7 +730,8 @@ def forward(
         # that hands the counts to the cache as well.
         rows, idx = _split_rows(cfg, rows)
         kv = ((rows if counts is None
-               else state.Fresh(rows, None, None, None, *counts, idx=idx)),
+               else state.Fresh(rows, None, None, None, *counts, idx=idx,
+                                win=wrows)),
               None)
     out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
     return (out, kv, routes) if want_routes else (out, kv)
@@ -673,15 +789,38 @@ def decode_step(
         return absorbed_attention(cfg, lp, q_nope, q_rope,
                                   _split_rows(cfg, row)[0], paged), row
 
-    x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
-                                      attend)
+    win_pool = None
+    if cfg.window_attn:
+        wcfg, (wcos, wsin), window = _window_side(cfg, positions)
+        win_pool, win_tables = k_pages.win, k_pages.wt
+
+        def attend_window(lp, h, layer):
+            q_nope, q_rope, row = _project(wcfg, lp, h, wcos, wsin)
+
+            def paged(q, cur_row):
+                return pages.window_decode_attention(
+                    q, win_pool, layer, win_tables, seq_lens, cur_row,
+                    value_dim=wcfg.kv_lora_rank, scale=_scale(wcfg),
+                    window=window, impl=cfg.swa_impl)
+
+            return absorbed_attention(wcfg, lp, q_nope, q_rope, row,
+                                      paged), row
+
+        attend = dict(full=attend, window=attend_window)
+
+    x, rows, routes, counts, wrows = _blocks(
+        params, cfg, params["embed"][tokens], attend)
     if want_routes and cfg.index_topk:
         routes = (routes, _picked(cfg, rows))
     rows, idx_rows = _split_rows(cfg, rows)
     pool, _ = pages.write(pool, None, rows, None, *cur_slots)
     if idx_rows is not None:
         idx_pool, _ = pages.write(idx_pool, None, idx_rows, None, *cur_slots)
-    k_pages = _kept(k_pages, pool, counts, idx_pool)
+    if wrows is not None:
+        win_pool, _ = pages.write(
+            win_pool, None, wrows, None,
+            *pages.token_slots(win_pool, win_tables, positions))
+    k_pages = _kept(k_pages, pool, counts, idx_pool, win_pool)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
@@ -744,8 +883,35 @@ def prefill_with_prefix(
             rows = _with_picked(want_routes, rows, chosen)
         return expanded_attention(cfg, lp, q_nope, q_rope, seen, chosen), rows
 
-    x, rows, routes, counts = _blocks(params, cfg, params["embed"][tokens],
-                                      attend)
+    win_pool = None
+    if cfg.window_attn:
+        # The window layers: the pages of their own pool that end where this
+        # window starts, then its own rows, by the band.
+        wcfg, (wcos, wsin), window = _window_side(cfg, positions)
+        win_pool, win_table = k_pages.win, k_pages.wt
+        ids, near_pos = pages.window_prefix_pages(
+            win_table, prefix_len, pages.block_size(win_pool), window)
+        near = jnp.concatenate([near_pos, positions], axis=1)
+        band = ((positions[:, :, None] >= near[:, None, :])
+                & (positions[:, :, None] - near[:, None, :] < window)
+                & jnp.concatenate(
+                    [near_pos < prefix_len[:, None],
+                     jnp.arange(S)[None, :] < suffix_len[:, None]],
+                    axis=1)[:, None, :])
+
+        def attend_window(lp, h, layer):
+            q_nope, q_rope, rows = _project(wcfg, lp, h, wcos, wsin)
+            prior = pages.read_latent_prefix(win_pool, layer, ids,
+                                             wcfg.latent_dim)
+            seen = jnp.concatenate([prior.astype(rows.dtype), rows], axis=1)
+            return expanded_attention(
+                wcfg, lp, q_nope, q_rope, seen, band, impl=cfg.swa_impl,
+                name="swa_window_attention"), rows
+
+        attend = dict(full=attend, window=attend_window)
+
+    x, rows, routes, counts, wrows = _blocks(
+        params, cfg, params["embed"][tokens], attend)
     if want_routes and cfg.index_topk:
         routes = (routes, _picked(cfg, rows))
     rows, idx_rows = _split_rows(cfg, rows)
@@ -755,7 +921,11 @@ def prefill_with_prefix(
         idx_pool, _ = pages.write_sequences(
             idx_pool, None, idx_rows, None, block_table_row, suffix_len,
             start=prefix_len)
-    k_pages = _kept(k_pages, pool, counts, idx_pool)
+    if wrows is not None:
+        win_pool, _ = pages.write_sequences(
+            win_pool, None, wrows, None, win_table, suffix_len,
+            start=prefix_len)
+    k_pages = _kept(k_pages, pool, counts, idx_pool, win_pool)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]

@@ -153,3 +153,60 @@ def latent_paged_decode_attention(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bht,btd->bhd", probs, rows[..., :value_dim])
     return out.astype(q.dtype)
+
+
+def window_pages(block: int, window: int) -> int:
+    """Pages the cached rows of a window can lie across: ``window - 1`` rows
+    (the query's own is not cached) from any offset into the first page."""
+    return (window + block - 3) // block + 1
+
+
+def window_table(block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
+                 block: int, window: int
+                 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A lane's table cut to its window: (the entries of the pages that hold
+    the rows a query at ``seq_lens - 1`` sees [B, window_pages], the lane's
+    length counted from the first of those pages [B], the rows of that page
+    that lie before the window [B])."""
+    first_row = jnp.maximum(seq_lens - window, 0)
+    first = first_row // block
+    at = first[:, None] + jnp.arange(window_pages(block, window),
+                                     dtype=first.dtype)[None, :]
+    tables = jnp.take_along_axis(
+        block_tables, jnp.minimum(at, block_tables.shape[1] - 1), axis=1)
+    return tables, seq_lens - first * block, first_row - first * block
+
+
+def swa_latent_decode_attention(
+    q: jnp.ndarray,             # [B, H, Dk]
+    pages: jnp.ndarray,         # [Lw, N_blocks, block, W] — the window layers' pool
+    layer: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, max_blocks] int32, by logical page
+    seq_lens: jnp.ndarray,      # [B] int32 — incl. the current token
+    cur_row: jnp.ndarray,       # [B, Dk]
+    *,
+    value_dim: int,
+    scale: float,
+    window: int,
+) -> jnp.ndarray:
+    """:func:`latent_paged_decode_attention` for layers that attend to a
+    window of the context: the query at ``t = seq_lens - 1`` sees its own row
+    and the cached rows s with ``t - s < window``. Only the pages the window
+    reaches are gathered (:func:`window_table`): the entries of the table
+    before them may name pages the lane gave back."""
+    B, H, Dk = q.shape
+    block = pages.shape[2]
+    tables, lens, skip = window_table(block_tables, seq_lens, block, window)
+    T = tables.shape[1] * block
+    rows = pages[layer, tables].reshape(B, T, -1)[..., :Dk]
+    rows = jnp.concatenate([rows, cur_row[:, None].astype(rows.dtype)],
+                           axis=1).astype(jnp.float32)        # [B, T+1, Dk]
+    logits = jnp.einsum("bhd,btd->bht", q.astype(jnp.float32), rows) * scale
+    col = jnp.arange(T)[None, :]
+    valid = jnp.concatenate(
+        [(col >= skip[:, None]) & (col < (lens - 1)[:, None]),
+         jnp.ones((B, 1), bool)], axis=1)
+    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bht,btd->bhd", probs, rows[..., :value_dim])
+    return out.astype(q.dtype)

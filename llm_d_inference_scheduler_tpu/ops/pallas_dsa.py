@@ -240,7 +240,7 @@ def _window_kernel(live_ref,                      # scalar prefetch
                       ).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
 def masked_window_attention_pallas(
     q_nope: jnp.ndarray,   # [B, H, S, dn]
     q_rope: jnp.ndarray,   # [B, H, S, dr]
@@ -251,9 +251,13 @@ def masked_window_attention_pallas(
     *,
     scale: float,
     interpret: bool = False,
+    name: str = "dsa_window_attention",
 ) -> jnp.ndarray:
     """ops/sparse_attention.masked_window_attention, as a kernel: [B, H, S,
-    dv] in q_nope.dtype."""
+    dv] in q_nope.dtype. ``name`` is the op's in a device trace: the layers
+    that attend to a band of the context call it as ``swa_window_attention``
+    (tiles wholly outside the band are skipped, as those nothing selected
+    are)."""
     B, H, S, dn = q_nope.shape
     T, dr, dv = k_nope.shape[2], q_rope.shape[-1], v.shape[-1]
     sq = min(WINDOW_QUERIES, -(-S // 32) * 32)
@@ -302,7 +306,7 @@ def masked_window_attention_pallas(
                                  "arbitrary"),
             vmem_limit_bytes=_WINDOW_VMEM_BYTES),
         interpret=interpret,
-        name="dsa_window_attention",
+        name=name,
     )(live.astype(jnp.int32).reshape(-1), q_nope, q_rope, k_nope, k_rope, v,
       keep.astype(q_nope.dtype))
     return out[:, :, :S]

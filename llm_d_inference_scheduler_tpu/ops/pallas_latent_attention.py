@@ -31,6 +31,15 @@ page was. Which groups are runs is read off the block table in the jitted
 wrapper (:func:`table_runs`) and prefetched beside it; the allocator hands a
 request its blocks in ascending order so that most are (engine/blocks.py).
 And a full stage is waited for once, not a copy at a time.
+
+**A window of the context** (:func:`swa_latent_decode_attention_pallas`, the
+op ``swa_latent_decode_attention``): layers that attend to the last ``window``
+tokens keep their rows in a pool of their own width under a table of their own
+(kvcache/pages.py), and a lane's walk starts at the page of its first visible
+row, ``max(0, t - (window - 1))``, not at page 0. The wrapper cuts the lane's
+table down to the pages the window reaches (:func:`window_table`), so the
+kernel is the walk above over a short table, with one more mask for the rows
+of the first page that lie before the window.
 """
 
 from __future__ import annotations
@@ -42,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .attention import window_pages, window_table  # noqa: F401
 from .pallas_paged_attention import NEG_INF, STAGE_VMEM_BYTES
 
 
@@ -173,7 +183,7 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
             out_ref,                     # [1, H, value_dim]
             tile, sem,
             *, max_blocks: int, pages: int, block: int, group: int,
-            value_dim: int, scale: float):
+            value_dim: int, scale: float, skip_ref=None):
     b = pl.program_id(0)
     rows = pages * block
     q = q_ref[0]                                      # [H, W]
@@ -212,7 +222,11 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
         logits = jax.lax.dot_general(
             q, tile[slot].reshape(rows, -1), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [H, rows]
-        logits = jnp.where(col < cached_len - s * rows, logits, NEG_INF)
+        seen = col < cached_len - s * rows
+        if skip_ref is not None:
+            # The first page's rows that lie before the lane's window.
+            seen = seen & (col >= skip_ref[b] - s * rows)
+        logits = jnp.where(seen, logits, NEG_INF)
         new_m = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
         p = jnp.exp(logits - new_m)
         corr = jnp.exp(m - new_m)
@@ -224,6 +238,13 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
 
     _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
     out_ref[0] = (acc / l).astype(out_ref.dtype)
+
+
+def _window_kernel(bt_ref, run_ref, sl_ref, skip_ref, layer_ref, *refs, **kw):
+    """:func:`_kernel` over the pages a lane's window reaches: ``sl_ref``
+    counts from the first of them, ``skip_ref`` [B] is how many rows of that
+    page lie before the window."""
+    _kernel(bt_ref, run_ref, sl_ref, layer_ref, *refs, skip_ref=skip_ref, **kw)
 
 
 @functools.partial(jax.jit,
@@ -276,4 +297,59 @@ def latent_paged_decode_attention_pallas(
         name="mla_paged_decode_attention",
     )(block_tables.reshape(-1),
       table_runs(block_tables, seq_lens, block, group).reshape(-1), seq_lens,
+      jnp.asarray(layer, jnp.int32).reshape(1), q, cur, pages)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale", "window",
+                                             "interpret"))
+def swa_latent_decode_attention_pallas(
+    q: jnp.ndarray,             # [B, H, Dk]
+    pages: jnp.ndarray,         # [Lw, N, block, W] — the window layers' pool
+    layer: jnp.ndarray,         # int32 scalar
+    block_tables: jnp.ndarray,  # [B, maxB] int32, by logical page
+    seq_lens: jnp.ndarray,      # [B] int32 (incl. current token)
+    cur_row: jnp.ndarray,       # [B, Dk]
+    *,
+    value_dim: int,
+    scale: float,
+    window: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """ops/attention.swa_latent_decode_attention, as a kernel: a query sees
+    its own row and the ``window - 1`` cached before it."""
+    B, H, Dk = q.shape
+    _, _, block, W = pages.shape
+    tables, lens, skip = window_table(block_tables, seq_lens, block, window)
+    maxB = tables.shape[1]
+    n_pages = pages_per_stage(block, W, pages.dtype.itemsize, maxB)
+    group = run_pages(n_pages)
+    pad = [(0, 0)] * 2 + [(0, W - Dk)]
+    q = jnp.pad(q, pad).astype(pages.dtype)
+    cur = jnp.pad(cur_row[:, None], pad).astype(pages.dtype)
+
+    kernel = functools.partial(
+        _window_kernel, max_blocks=maxB, pages=n_pages, block=block,
+        group=group, value_dim=value_dim, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, value_dim), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, n_pages, block, W), pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
+        interpret=interpret,
+        name="swa_latent_decode_attention",
+    )(tables.reshape(-1),
+      table_runs(tables, lens, block, group).reshape(-1), lens, skip,
       jnp.asarray(layer, jnp.int32).reshape(1), q, cur, pages)

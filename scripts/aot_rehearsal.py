@@ -166,7 +166,12 @@ def main(argv=None) -> int:
                 zero=sds((), jnp.int32) if geom.counts_zero else None,
                 counts_zero=geom.counts_zero,
                 idx=(sds(geom.index_shape, jnp.dtype(geom.dtype))
-                     if geom.index_dim else None)), None)
+                     if geom.index_dim else None),
+                # (A window pool rides with its step's tables.)
+                win=(sds(geom.window.shape, jnp.dtype(geom.dtype))
+                     if geom.window else None),
+                wt=(sds((rows, width), jnp.int32)
+                    if geom.window else None)), None)
         return (pages, None) if geom.latent_dim else (pages, pages)
 
     def sampling(rows):

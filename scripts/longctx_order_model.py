@@ -39,12 +39,34 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "chipbench"))
 import traffic  # noqa: E402
 
-# The chip's times, ms (my chip runs, PR 41, `--trace 2 --dump-trace`).
-FIRST_WINDOW_MS = 37.0
-CONTINUATION_MS = {64: 40.0, 128: 50.1, 256: 59.7, 512: 59.7, 1024: 75.0}
-ROW_MS, ROW_FROM = 6.0, 4          # a window's attention by rows written, k
-STEP_MS, STEP_MS_PER_TOKEN = 14.6, 10.0 / 286e3   # weights; pages of a lane
-WINDOW, CHUNK, LANES, STEP_WINDOWS = 1024, 8, 32, 4
+# The chip's times, ms, a mix (`use`): longctx-reason's from my chip runs,
+# PR 41, `--trace 2 --dump-trace`; longctx-wide's from my chip run, PR 45
+# (seed 3000000011: 40 decode steps of ~57 lanes at ~10k in 0.6527 s, of
+# which the two full layers' attention and indexer 3.7 ms a step; the first
+# window 16.6, continuations 29.5 / 33.5 / 37.7 / 42.5 / 59.5 by bucket).
+TIMES = {
+    "longctx-reason": dict(
+        FIRST_WINDOW_MS=37.0,
+        CONTINUATION_MS={64: 40.0, 128: 50.1, 256: 59.7, 512: 59.7,
+                         1024: 75.0},
+        ROW_MS=6.0, ROW_FROM=4, STEP_MS=14.6, STEP_MS_PER_TOKEN=10.0 / 286e3,
+        LANES=32),
+    "longctx-wide": dict(
+        FIRST_WINDOW_MS=16.6,
+        CONTINUATION_MS={64: 29.5, 128: 33.5, 256: 37.7, 512: 39.5,
+                         1024: 47.0},
+        ROW_MS=2.4, ROW_FROM=4, STEP_MS=12.6, STEP_MS_PER_TOKEN=3.7 / 570e3,
+        LANES=64),
+}
+WINDOW, CHUNK, STEP_WINDOWS = 1024, 8, 4
+
+
+def use(mix_name: str) -> None:
+    """Take `mix_name`'s times and lanes as the module's."""
+    globals().update(TIMES[mix_name])
+
+
+use("longctx-reason")
 
 
 def window_ms(written_k: int) -> float:
@@ -219,6 +241,7 @@ def main() -> int:
     ap.add_argument("--search", type=int, nargs=2, metavar=("LO", "HI"))
     ap.add_argument("--keep", type=int, default=6000)
     args = ap.parse_args()
+    use(args.mix)
     mix = traffic.load_mix(traffic.mix_path(ROOT, args.mix))
     if args.search is None:
         order = mix.get("order", 0) if args.order is None else args.order

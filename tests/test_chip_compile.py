@@ -505,3 +505,128 @@ def test_the_selecting_blocks_decode_step_compiles_with_both_kernels(one_chip):
         batch, geom.max_blocks_per_seq, geom.block, geom.index_dim))) + "]"
     assert gathered not in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---------- window and full latent attention in one model ----------
+
+def _dots3_cut():
+    """The cell's configuration at its published widths (the dense layer,
+    one full and three window expert layers), forms as the engine binds them
+    on a TPU."""
+    import json
+    import types
+
+    from llm_d_inference_scheduler_tpu.models import bind
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs",
+        "dots3-note-prev-cut.json")
+    with open(path) as f:
+        doc = json.load(f)
+    return bind(config_from_hf(types.SimpleNamespace(**doc), name="dots3-cut"),
+                platform="tpu").mcfg
+
+
+def _dots3_cache(one_chip, m, geom, rows):
+    from llm_d_inference_scheduler_tpu.kvcache import state
+
+    dt = jnp.dtype(m.dtype)
+    return state.Cache(
+        _sds(one_chip, geom.shape, dt), None, None, None,
+        slots=_sds(one_chip, (rows,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32),
+        idx=_sds(one_chip, geom.index_shape, dt),
+        win=_sds(one_chip, geom.window.shape, dt),
+        wt=_sds(one_chip, (rows, geom.max_blocks_per_seq), jnp.int32))
+
+
+def test_the_window_decode_kernel_compiles_at_the_cells_widths(one_chip):
+    """64 lanes, 64 heads over rows stored 1,152 wide, a window of 513 under
+    a table 1,152 entries wide: the walk starts at the window's first page
+    (33 table entries a lane reach the kernel, not 1,152)."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_latent_attention as la
+
+    lanes, heads, dk, width = 64, 64, 1088, 1152
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(functools.partial(
+        la.swa_latent_decode_attention_pallas, value_dim=1024, scale=0.0625,
+        window=513)).lower(
+        _sds(one_chip, (lanes, heads, dk), bf16),
+        _sds(one_chip, (3, 2483, 16, width), bf16),
+        _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (lanes, 1152), jnp.int32),
+        _sds(one_chip, (lanes,), jnp.int32),
+        _sds(one_chip, (lanes, dk), bf16)).compile()
+    hlo = compiled.as_text()
+    assert "swa_latent_decode_attention" in hlo and "tpu_custom_call" in hlo
+    assert f"s32[{lanes * la.window_pages(16, 513)}]" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_mixed_models_decode_step_compiles_and_copies_no_pool(one_chip):
+    """One decode step of 64 lanes: the full layers' indexer and masked walk,
+    the window layers' walk over their own pool by their own table, and none
+    of the three pools made anew."""
+    from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
+    from llm_d_inference_scheduler_tpu.models import mla
+
+    m = _dots3_cut()
+    assert (m.index_impl, m.swa_impl) == ("kernel", "kernel")
+    batch = 64
+    geom = kvpages.PageGeometry.for_engine(m, batch, 18432)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    lanes = _sds(one_chip, (batch,), jnp.int32)
+    compiled = jax.jit(lambda p, *a: mla.decode_step(
+        p, m, *a, attention_fn=functools.partial(
+            kvpages.latent_decode_attention, kernel=True)),
+        donate_argnums=(3,)).lower(
+        params, lanes, lanes, _dots3_cache(one_chip, m, geom, batch), None,
+        _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    for op in ("dsa_index_scores_decode", "dsa_paged_decode_attention",
+               "swa_latent_decode_attention"):
+        assert op in hlo, op
+    assert "mla_paged_decode_attention" not in hlo
+    for pool in (geom.shape, geom.index_shape, geom.window.shape):
+        shape = "bf16[" + ",".join(map(str, pool)) + "]"
+        made = [ln.strip()[:160] for ln in hlo.splitlines()
+                if re.search(r"=\s*" + re.escape(shape), ln)
+                and "parameter(" not in ln and "bitcast(" not in ln
+                and "scatter" not in ln and "dynamic-update-slice" not in ln
+                and "fusion(" not in ln and "get-tuple-element(" not in ln]
+        assert not made, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_a_window_of_the_mixed_model_compiles_with_both_kinds_kernels(
+        one_chip):
+    """A 1,024-token window over a 16k prefix: the full layers score and
+    select over 17,408 rows, the window layers attend over the 32 pages that
+    end where the window starts and its own rows (1,536 rows), each a tile at
+    a time; the program fits beside the cell's weights and pools."""
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
+    from llm_d_inference_scheduler_tpu.models import mla
+
+    m = dataclasses.replace(_dots3_cut(), moe_impl="grouped")
+    geom = PageGeometry.for_engine(m, 64, 18432)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    S, prior = 1024, 1024
+    one = _sds(one_chip, (1,), jnp.int32)
+    compiled = jax.jit(lambda p, *a: mla.prefill_with_prefix(p, m, *a),
+                       donate_argnums=(4,)).lower(
+        params, _sds(one_chip, (1, S), jnp.int32), one, one,
+        _dots3_cache(one_chip, m, geom, 1), None,
+        _sds(one_chip, (1, geom.max_blocks_per_seq), jnp.int32),
+        _sds(one_chip, (1, prior), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    for op in ("dsa_index_scores_window", "dsa_window_attention",
+               "swa_window_attention"):
+        assert op in hlo, op
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 << 29
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9

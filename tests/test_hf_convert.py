@@ -296,3 +296,81 @@ def test_qwen3_engine_serves_token_exact(tmp_path):
             await eng.stop()
 
     assert asyncio.run(run()) == ref
+
+
+# ---------- model_type dots3_note: two kinds of layer, a gate a head ----------
+
+def _dots3_published(**changes):
+    import json
+    import pathlib
+    import types
+
+    path = (pathlib.Path(__file__).resolve().parent.parent / "chipbench"
+            / "configs" / "dots3-note-prev-cut.json")
+    doc = json.loads(path.read_text())
+    doc = {k: v for k, v in doc.items()
+           if k not in ("source", "reduced", "assumed", "departures",
+                        "deployment", "serve", "reference")}
+    doc.update(changes)
+    return types.SimpleNamespace(**{k: v for k, v in doc.items()
+                                    if v != "absent"})
+
+
+def test_config_from_hf_maps_the_two_kinds_of_layer():
+    from llm_d_inference_scheduler_tpu.models import configs
+
+    cfg = config_from_hf(_dots3_published(), name="cut")
+    assert cfg == configs.ModelConfig(
+        name="cut", vocab_size=19008, d_model=5120, n_layers=5, n_heads=128,
+        n_kv_heads=128, d_ff=13824, rope_theta=8e7, max_seq_len=524288,
+        norm_eps=1e-5, n_experts=256, experts_per_token=8, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        first_k_dense=1, moe_d_ff=1536, n_shared_experts=1,
+        routed_scaling_factor=1.0, experts_held=32, experts_first=0,
+        q_lora_rank=1024, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        index_topk=2048, index_n_heads=64, index_head_dim=128,
+        attn_gate=True, layer_pattern="**WWW",
+        window_attn=configs.AttnKind(
+            n_heads=64, q_lora_rank=1024, kv_lora_rank=1024,
+            qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=128,
+            rope_theta=5e4, window=513, gate=True))
+    # No layer of the second kind: the block the tree had, with its gate.
+    full = config_from_hf(_dots3_published(
+        layer_types=["full_attention"] * 5,
+        **{k: "absent" for k in vars(_dots3_published())
+           if k.startswith("swa_") or k == "sliding_window_size"}), name="f")
+    assert full.window_attn is None and not full.layer_pattern
+    assert full.attn_gate and full.mla_scale_kv_lora
+    # The configurations the mapping served before read as they did.
+    plain = config_from_hf(_dots3_published(
+        layer_types="absent", attention_gate_type="absent",
+        apply_mla_qkv_lora_rescale="absent",
+        **{k: "absent" for k in vars(_dots3_published())
+           if k.startswith("swa_") or k == "sliding_window_size"}), name="p")
+    assert not (plain.attn_gate or plain.mla_scale_q_lora
+                or plain.layer_pattern or plain.window_attn)
+
+
+@pytest.mark.parametrize("change, says", [
+    (dict(layer_types=["full_attention", "full_attention",
+                       "sliding_attention", "chunked_attention",
+                       "sliding_attention"]), "chunked_attention"),
+    (dict(layer_types=["full_attention"] * 4), "layer_types"),
+    (dict(layer_types=["sliding_attention"] + ["full_attention"] * 4),
+     "leading dense layers"),
+    (dict(attention_gate_type="elementwise"), "attention_gate_type"),
+    (dict(swa_attention_gate_type="elementwise"), "swa_attention_gate_type"),
+    (dict(swa_index_topk=512), "swa_index_topk"),
+    (dict(swa_rope_scaling={"type": "yarn"}), "swa_rope_scaling"),
+    (dict(swa_kv_lora_rank="absent"), "swa_kv_lora_rank"),
+    (dict(sliding_window_size="absent"), "sliding_window_size"),
+    (dict(swa_num_key_value_heads=8), "swa_num_key_value_heads"),
+    (dict(layer_types="absent"), "without layer_types"),
+    (dict(layer_types=["full_attention"] * 5), "no sliding_attention"),
+])
+def test_config_from_hf_refuses_a_layer_kind_gate_or_swa_key_it_does_not_compute(
+        change, says):
+    """Not served as something else: the parent read this model's file as
+    five full layers."""
+    with pytest.raises(ValueError, match=says):
+        config_from_hf(_dots3_published(**change), name="cut")

@@ -43,3 +43,34 @@ def test_pinned_order_is_steadier_than_the_default_over_every_start():
     # A quarter of each bound: six runs then spread about half of it.
     assert tokens < 0.01 and tpot < 0.0125
     assert tokens < spread[0][0] / 2 and tpot < spread[0][1] / 2
+
+
+# ---------- longctx-wide (PR 45): the same model at that cell's times ----------
+
+@pytest.fixture
+def wide():
+    model.use("longctx-wide")
+    yield model.traffic.load_mix(model.traffic.mix_path(ROOT, "longctx-wide"))
+    model.use("longctx-reason")
+
+
+def test_model_reads_the_wide_cells_first_run(wide):
+    """My chip run, PR 45, seed 3000000011 under `order` 0 (start 57 of the 96
+    shapes): 1,576.04 tokens/s, tpot_p95_ms 36.337, 75 requests."""
+    prompts, outputs = model.shapes(wide, 0)
+    run = model.simulate(wide, prompts, outputs, 57, 51.0)
+    assert run["out_tokens_per_s"] == pytest.approx(1576.04, rel=0.02)
+    assert run["tpot_p95_ms"] == pytest.approx(36.337, rel=0.02)
+    assert 60 <= run["requests"] <= 90
+
+
+def test_the_wide_cells_pinned_order_is_steadier_than_the_default(wide):
+    spread = {}
+    for order in (0, wide["order"]):
+        runs = model.starts(wide, order, 51.0)
+        spread[order] = [model.relative_sd([r[name] for r in runs])
+                         for name in ("out_tokens_per_s", "tpot_p95_ms")]
+    tokens, tpot = spread[wide["order"]]
+    # A quarter of each bound: six runs then spread about half of it.
+    assert tokens < 0.005 and tpot < 0.00625
+    assert tokens < spread[0][0]
