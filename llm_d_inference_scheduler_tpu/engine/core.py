@@ -2915,8 +2915,11 @@ class TpuEngine:
     def _op_decode(self, slots, positions, tables, steps, temps, top_k, top_p,
                    warm=False, wt=None):
         # (The cache takes the host's copy of the slots: what goes in with
-        # it is donated with it.)
-        cache = state_pool.at_slots(self.k_pages, slots, wt)
+        # it is donated with it.) Where the bucket's program reads the chosen
+        # experts alone, the cache carries its count of them out.
+        visits = self.bound.decode_expert_visits(len(slots)) * steps
+        cache = state_pool.at_slots(self.k_pages, slots, wt,
+                                    reads=visits > 0)
         slots = self._put(slots)
         args = (self.params, self._jit_slot_tokens(self._slot_tokens, slots),
                 self._put(positions), cache, self.v_pages,
@@ -2936,7 +2939,7 @@ class TpuEngine:
                     "tpu_custom_call"
                     in self._jit_decode_chunk.lower(*args).as_text())
         toks, k_pages, self.v_pages = self._jit_decode_chunk(*args)
-        self._keep_cache(k_pages, slots.size * steps)
+        self._keep_cache(k_pages, slots.size * steps, visits)
         return self._op_keep_tokens(slots, toks, row=steps - 1)
 
     def _op_keep_tokens(self, slots, toks, row=0):
@@ -2977,14 +2980,16 @@ class TpuEngine:
         self._keep_cache(k_pages, tokens.size)
         return self._op_keep_tokens(slots, tok)
 
-    def _keep_cache(self, k_pages, rows: int) -> None:
+    def _keep_cache(self, k_pages, rows: int, visits: int = 0) -> None:
         """Keep the cache a step returned. Where it carries counts of the
         router's choices (kvcache/state.py: held here, zero-compute), they
         are taken out and queued with the choices the step's ``rows`` made in
-        all (booked behind a chunk's tokens, _land_chunk)."""
-        self.k_pages, held, zero = state_pool.take_counts(k_pages)
+        all (booked behind a chunk's tokens, _land_chunk); and with them, of
+        a decode chunk whose program reads the chosen experts alone, the
+        held experts it read of the ``visits`` it made."""
+        self.k_pages, held, zero, read = state_pool.take_counts(k_pages)
         self.telemetry.keep_pair_counts(
-            held, zero, rows * self.bound.pairs_per_row)
+            held, zero, rows * self.bound.pairs_per_row, read, visits)
 
     def _op_mm_prefill(self, bucket, mm_bucket, tokens, seq_len, mm_pad,
                        pos_pad, row, slots, temps, top_k, top_p):

@@ -271,10 +271,24 @@ class EngineTelemetry:
         # never exposes it.
         self.moe_zero_pairs = functools.partial(moe_routed_pairs.labels,
                                                 held="zero")
+        # A series appears with its first count, as `zero` does: an engine
+        # whose decode programs read every held expert never exposes it.
+        self.moe_decode_experts = functools.partial(Counter(
+            "jetstream:moe_decode_experts_total",
+            "Held experts a decode step passed, an expert once a step and "
+            "expert layer, by whether the step read its weights (`yes`: a "
+            "lane that is somebody's chose it, or it stood in where none "
+            "was chosen) or not (`no`), where the step's program reads the "
+            "chosen experts alone (ops/pallas_moe.use_chosen); summed on the "
+            "device and read as moe_routed_pairs_total is; empty where "
+            "decode programs read every expert they hold", ("read",),
+            registry=self.registry).labels)
         # Those counts as step programs summed them on the device, oldest
-        # first, each with the choices its program made in all (below).
-        self._pair_counts: collections.deque[tuple[Any, Any, int]] = (
-            collections.deque())
+        # first, each with the choices its program made in all and, of a
+        # decode chunk that reads the chosen experts alone, the experts it
+        # read and passed (below).
+        self._pair_counts: collections.deque[
+            tuple[Any, Any, int, Any, int]] = collections.deque()
         decode_chunks = Counter(
             "jetstream:decode_chunks_total",
             "Decode chunks dispatched: `ahead` while the chunk before was "
@@ -397,28 +411,34 @@ class EngineTelemetry:
             counter = getattr(self, name)
             (counter if label is None else counter.labels(label)).inc(amount)
 
-    def keep_pair_counts(self, held, zero, pairs: int) -> None:
+    def keep_pair_counts(self, held, zero, pairs: int, read=None,
+                         visits: int = 0) -> None:
         """Queue one step's counts of its router's choices, still on the
         device (``held`` None: its cache carries none; ``zero`` None: no
-        such outputs), with the ``pairs`` its rows made in all."""
+        such outputs), with the ``pairs`` its rows made in all; ``read`` of
+        ``visits`` held experts where its program reads the chosen ones
+        alone (else None)."""
         if held is None:
             return
-        held.copy_to_host_async()
-        if zero is not None:
-            zero.copy_to_host_async()
-        self._pair_counts.append((held, zero, pairs))
+        for count in (held, zero, read):
+            if count is not None:
+                count.copy_to_host_async()
+        self._pair_counts.append((held, zero, pairs, read, visits))
 
     def book_pair_counts(self) -> None:
         """Book the queued counts whose programs are done (every one
         dispatched before tokens the host has just read is): reading them
         waits for nothing."""
         while self._pair_counts and self._pair_counts[0][0].is_ready():
-            held, zero, pairs = self._pair_counts.popleft()
+            held, zero, pairs, read, visits = self._pair_counts.popleft()
             held, zero = int(held), 0 if zero is None else int(zero)
             self.moe_routed_pairs["yes"].inc(held)
             self.moe_routed_pairs["no"].inc(pairs - held - zero)
             if zero:
                 self.moe_zero_pairs().inc(zero)
+            if read is not None:
+                self.moe_decode_experts(read="yes").inc(int(read))
+                self.moe_decode_experts(read="no").inc(visits - int(read))
 
     def watch_xla_builds(self) -> None:
         """Count the programs JAX builds from now on, and show the count

@@ -394,6 +394,13 @@ def layer_indices(k_pages: jax.Array) -> jax.Array:
 # ---- writes -------------------------------------------------------------------
 
 
+def lanes_in_use(block_tables: jax.Array) -> jax.Array:
+    """Which rows of a decode step's ``block_tables`` [B, width] are
+    somebody's [B]: a padding lane's table is the trash block throughout,
+    a request's starts with a block of its own."""
+    return block_tables[:, 0] != TRASH_BLOCK
+
+
 def token_slots(k_pages: jax.Array, block_tables: jax.Array,
                 positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(block id, slot in it), each [B], of lane b's token at

@@ -41,9 +41,12 @@ small things that ride with a step: ``slots`` [B], the engine slot of
 every row of this step (set by the engine before the call, :func:`at_slots`),
 and the counts its programs sum on the device, which the engine takes out
 after the call (:func:`take_counts`): ``held``, the (token, expert) choices
-this step's programs found to live on this chip, and ``zero``, those that
-named an expert that computes nothing. ``engine/core.py`` learns none of
-these shapes.
+this step's programs found to live on this chip, ``zero``, those that
+named an expert that computes nothing, and -- only where the engine says the
+step's program counts them (``at_slots(..., reads=True)``: a decode chunk in
+the form that reads the chosen experts alone, ops/pallas_moe.chosen_experts)
+-- ``read``, the held experts whose weights its expert layers read.
+``engine/core.py`` learns none of these shapes.
 
 The counts have this one carrier in every family. A latent page pool whose
 model counts its router's choices (models/mla.py, where a chip holds a share
@@ -136,6 +139,7 @@ class Cache:
     # which only a model whose router has such outputs counts.
     counts_zero: bool = dataclasses.field(default=False,
                                           metadata=dict(static=True))
+    read: jax.Array | None = None   # held experts read, where a step counts
     idx: jax.Array | None = None    # the indexer's key pool beside a latent k
     # The latent pool of the layers that keep a window of the context
     # (kvcache/pages.py), and this step's tables of it [B, table width],
@@ -179,38 +183,43 @@ def alloc(geom: StateGeometry | None, k_pages: jax.Array,
                            device=device), counts_zero=counts_zero)
 
 
-def at_slots(cache: Any, slots: Any, wt: Any = None) -> Any:
+def at_slots(cache: Any, slots: Any, wt: Any = None, *, reads: bool = False
+             ) -> Any:
     """``cache`` as a step on rows ``slots`` takes it (``slots`` from the
     host: the step donates its cache, and what rides in it goes with it),
-    with the step's window tables ``wt`` where it keeps a window pool;
-    anything that is no :class:`Cache` (a page pool) goes through as it is."""
+    with the step's window tables ``wt`` where it keeps a window pool, and
+    ``reads`` where its program counts the held experts it read; anything
+    that is no :class:`Cache` (a page pool) goes through as it is."""
     if not isinstance(cache, Cache):
         return cache
     return dataclasses.replace(
         cache, slots=np.asarray(slots, np.int32), held=np.zeros((), np.int32),
         zero=np.zeros((), np.int32) if cache.counts_zero else None,
+        read=np.zeros((), np.int32) if reads else None,
         wt=None if wt is None else np.asarray(wt, np.int32))
 
 
-def take_counts(cache: Any
-                ) -> tuple[Any, jax.Array | None, jax.Array | None]:
+def take_counts(cache: Any) -> tuple[Any, jax.Array | None,
+                                     jax.Array | None, jax.Array | None]:
     """(The cache as it is kept between steps, the step's count of held
-    expert choices or None, its count of zero-compute choices or None.) The
-    counts leave the cache so that they are not donated to the next step
-    with it."""
+    expert choices or None, its count of zero-compute choices or None, its
+    count of held experts read or None.) The counts leave the cache so that
+    they are not donated to the next step with it."""
     if not isinstance(cache, Cache):
-        return cache, None, None
+        return cache, None, None, None
     return (dataclasses.replace(cache, slots=None, held=None, zero=None,
-                                wt=None),
-            cache.held, cache.zero)
+                                read=None, wt=None),
+            cache.held, cache.zero, cache.read)
 
 
-def counted(cache: Cache, held: jax.Array, zero: jax.Array | None = None
-            ) -> Cache:
+def counted(cache: Cache, held: jax.Array, zero: jax.Array | None = None,
+            read: jax.Array | None = None) -> Cache:
     """``cache`` with a program's counts added to those it carries."""
     return dataclasses.replace(
         cache, held=cache.held + held,
-        zero=None if cache.zero is None else cache.zero + zero)
+        zero=None if cache.zero is None else cache.zero + zero,
+        read=(cache.read if read is None or cache.read is None
+              else cache.read + read))
 
 
 # ---- reads and writes, by slot ---------------------------------------------------

@@ -73,25 +73,48 @@ class Bound:
     module: Any             # family(mcfg)
     mcfg: ModelConfig       # ssm_impl and index_impl resolved, the FFN dense
     grouped: ModelConfig    # the same with the MoE FFN's grouped form
+    chosen: ModelConfig     # the same, dense over the experts a row chose
     platform: str
     interpret: bool
     sharded: bool
 
+    def _moe_facts(self) -> dict[str, Any]:
+        """What both rules of the MoE FFN's form ask of the model and the
+        engine, beside a program's rows."""
+        m = self.mcfg
+        return dict(n_experts=m.n_experts,
+                    experts_per_token=m.experts_per_token,
+                    d_model=m.moe_latent_dim or m.d_model,
+                    d_ff=m.moe_d_ff or m.d_ff, platform=self.platform,
+                    interpret=self.interpret, sharded=self.sharded)
+
     def moe_grouped(self, tokens: int) -> bool:
         """Whether a program of ``tokens`` rows (batch x sequence, padded)
         computes the chosen experts' rows alone."""
-        m = self.mcfg
-        return pallas_moe.use_grouped(
-            tokens, n_experts=m.n_experts,
-            experts_per_token=m.experts_per_token,
-            d_model=m.moe_latent_dim or m.d_model,
-            d_ff=m.moe_d_ff or m.d_ff, platform=self.platform,
-            interpret=self.interpret, sharded=self.sharded)
+        return pallas_moe.use_grouped(tokens, **self._moe_facts())
+
+    def moe_chosen(self, tokens: int) -> bool:
+        """Whether a program of ``tokens`` rows reads the weights of the
+        held experts some row chose, and no others."""
+        return pallas_moe.use_chosen(
+            tokens, router_outputs=self.mcfg.router_width,
+            held=self.mcfg.held_experts[1], **self._moe_facts())
 
     def model_for(self, tokens: int) -> ModelConfig:
         """The model as a program of ``tokens`` rows (batch x sequence,
         padded) traces it: the MoE FFN in the form the shape calls for."""
+        if self.moe_chosen(tokens):
+            return self.chosen
         return self.grouped if self.moe_grouped(tokens) else self.mcfg
+
+    def decode_expert_visits(self, rows: int) -> int:
+        """(Held expert, expert layer) pairs one decode step of ``rows`` rows
+        passes, where its program reads the chosen ones alone and counts
+        them (``jetstream:moe_decode_experts_total``); 0 where it reads
+        all."""
+        m = self.mcfg
+        return (m.held_experts[1] * m.n_expert_layers
+                if self.moe_chosen(rows) else 0)
 
     @property
     def pairs_per_row(self) -> int:
@@ -111,7 +134,8 @@ class Bound:
         m, decode, tokens = self.mcfg, kind == "decode", rows * steps
         counts: list[tuple[str, str | None, int]] = []
         if m.n_experts:
-            # Under the form its shape traced to.
+            # Under the form its shape traced to (dense over the chosen
+            # experts is dense over the experts, less the unchosen).
             counts.append(("moe_ffn_tokens", "grouped" if self.moe_grouped(
                 rows) else "dense", tokens))
         if m.kv_lora_rank:
@@ -154,6 +178,12 @@ class Bound:
             "experts_first": m.held_experts[0],
             "experts_held": m.held_experts[1],
             "zero_experts": m.n_zero_experts,
+            # Rows an expert can expect (rows x choices / router outputs) up
+            # to which a program of one row tile reads the chosen held
+            # experts alone (None: every program reads all it holds).
+            "experts_chosen_max_rows": (
+                pallas_moe.CHOSEN_MAX_ROWS_PER_EXPERT
+                if self.moe_chosen(1) else None),
             # How a decode step fetches its slots' recurrent states.
             "state_update": m.ssm_impl if m.n_state_layers else None,
             # The form of the layers that attend to a window of the context
@@ -193,8 +223,9 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
         forms["swa_impl"] = ("kernel_interpret" if interpret else
                              "kernel" if platform == "tpu" else "xla")
     mcfg = dataclasses.replace(mcfg, **{**forms, **(forced or {})})
+    suffix = "_interpret" if interpret else ""
     return Bound(
         module=family(mcfg), mcfg=mcfg,
-        grouped=dataclasses.replace(
-            mcfg, moe_impl="grouped_interpret" if interpret else "grouped"),
+        grouped=dataclasses.replace(mcfg, moe_impl="grouped" + suffix),
+        chosen=dataclasses.replace(mcfg, moe_impl="chosen" + suffix),
         platform=platform, interpret=interpret, sharded=sharded)

@@ -53,10 +53,11 @@ class ModelConfig:
     experts_per_token: int = 2
     # MoE FFN form: "dense" (dense-over-experts einsums — the correctness
     # baseline, required under expert-parallel shard_map) | "grouped" (the
-    # chosen experts' rows alone, ops/pallas_moe.py) | "grouped_interpret"
-    # (same kernel, interpreter — CPU tests). models.bind hands the engine
-    # both, program by program from the shape (pallas_moe.use_grouped); tests
-    # force a form.
+    # chosen experts' rows alone, ops/pallas_moe.py) | "chosen" (a program of
+    # few rows: dense over the held experts some row chose) | "grouped_interpret"
+    # / "chosen_interpret" (same kernels, interpreter — CPU tests). models.bind
+    # hands the engine all three, program by program from the shape
+    # (pallas_moe.use_grouped, use_chosen); tests force a form.
     moe_impl: str = "dense"
     # Qwen3 family: explicit head_dim decoupled from d_model/n_heads, and
     # per-head RMSNorm on q/k before RoPE.

@@ -52,8 +52,9 @@ chip holds ``cfg.held_experts`` of the experts the router scores -- what
 expert parallelism gives one chip -- and what the absent ones would have added
 is left out: in the deployment the 1,024-wide latents are exchanged between
 ``W_down`` and ``W_up``, on one chip there is no exchange and nothing stands
-in for it. The routed part runs dense over the held experts or grouped
-(ops/pallas_moe.py), chosen by the engine per program (``cfg.moe_impl``).
+in for it. The routed part runs dense over the held experts, grouped, or
+dense over the held experts some row chose (ops/pallas_moe.py), chosen by the
+engine per program (``cfg.moe_impl``).
 """
 
 from __future__ import annotations
@@ -140,17 +141,24 @@ def _relu2(x):
 # ---- E: LatentMoE -------------------------------------------------------------
 
 
-def latent_moe(cfg: ModelConfig, stack: Params, layer: int, h: jnp.ndarray
-               ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def latent_moe(cfg: ModelConfig, stack: Params, layer: int, h: jnp.ndarray,
+               real: jnp.ndarray | None = None
+               ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
+                          jnp.ndarray | None]:
     """Expert layer ``layer`` of the stacked ``moe`` parameters on h [..., D]:
     (output, the experts every token chose [T, k], how many of those choices
-    name an expert held here)."""
+    name an expert held here, and -- in the form that reads the chosen
+    experts alone, else None -- how many held experts' weights the layer
+    read). ``real`` [T] says which rows are somebody's (None: all): the
+    others' choices make no expert worth reading."""
     lp = {n: a[layer] for n, a in stack.items() if n not in ("w1", "w2")}
     first, count = cfg.held_experts
     ht = h.reshape(-1, h.shape[-1])
     idx, gates = route(cfg, lp, ht)
     here = (idx >= first) & (idx < first + count)
     u = ht @ lp["w_down"]
+    local = jnp.where(here, idx - first, -1)
+    read = None
     if cfg.moe_impl.startswith("grouped"):
         from ..ops.pallas_moe import grouped_experts
 
@@ -160,19 +168,28 @@ def latent_moe(cfg: ModelConfig, stack: Params, layer: int, h: jnp.ndarray
                             layer=jnp.asarray(layer, jnp.int32), first=first,
                             gated=False,
                             interpret=cfg.moe_impl == "grouped_interpret")
+    elif cfg.moe_impl.startswith("chosen"):
+        from ..ops.pallas_moe import chosen_experts
+
+        # Dense over the held experts that a row of somebody's chose.
+        if real is not None:
+            local = jnp.where(real[:, None], local, -1)
+        r, read = chosen_experts(stack, u, local, gates, count,
+                                 layer=jnp.asarray(layer, jnp.int32),
+                                 gated=False,
+                                 interpret=cfg.moe_impl == "chosen_interpret")
     else:
         # Dense over the held experts: each of them for every token, weighted
         # by its gate or by zero; the gate goes in ahead of the second
         # product, which then sums over experts and width at once.
         weights = jnp.einsum(
-            "tke,tk->te",
-            jax.nn.one_hot(jnp.where(here, idx - first, -1), count,
-                           dtype=h.dtype), gates.astype(h.dtype))
+            "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
+            gates.astype(h.dtype))
         act = _relu2(jnp.einsum("tz,ezf->tef", u, stack["w1"][layer]))
         r = jnp.einsum("tef,efz->tz", act * weights[..., None],
                        stack["w2"][layer])
     y = r @ lp["w_up"] + _relu2(ht @ lp["w1s"]) @ lp["w2s"]
-    return y.reshape(h.shape), idx, jnp.sum(here, dtype=jnp.int32)
+    return y.reshape(h.shape), idx, jnp.sum(here, dtype=jnp.int32), read
 
 
 # ---- M: Mamba-2 ---------------------------------------------------------------
@@ -327,27 +344,32 @@ def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
 
 
 def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
-          mixers: dict[str, Callable[[Params, jnp.ndarray, int], jnp.ndarray]]
-          ) -> tuple[jnp.ndarray, jnp.ndarray, list[jnp.ndarray]]:
+          mixers: dict[str, Callable[[Params, jnp.ndarray, int], jnp.ndarray]],
+          real: jnp.ndarray | None = None
+          ) -> tuple[jnp.ndarray, jnp.ndarray, list[jnp.ndarray],
+                     jnp.ndarray | None]:
     """x through every layer in the pattern's order. ``mixers["M"]`` and
     ``mixers["*"]`` are ``(layer's parameters, normed input, index among its
     kind) -> mixer output`` and keep what else they make (states, K/V rows)
-    for their caller; the expert layer is the same in every step. Returns
-    (x, the count of expert choices held here, every expert layer's
-    choices)."""
+    for their caller; the expert layer is the same in every step (``real``
+    is :func:`latent_moe`'s). Returns (x, the count of expert choices held
+    here, every expert layer's choices, the count of held experts read or
+    None: :func:`latent_moe`)."""
     seen = dict.fromkeys(_STACK, 0)
-    held, routes = jnp.zeros((), jnp.int32), []
+    held, routes, read = jnp.zeros((), jnp.int32), [], None
     for kind in cfg.layer_pattern:
         stack, i = params[_STACK[kind]], seen[kind]
         seen[kind] += 1
         h = rms_norm(x, stack["ln"][i], cfg.norm_eps)
         if kind == "E":
-            y, chosen, n = latent_moe(cfg, stack, i, h)
+            y, chosen, n, n_read = latent_moe(cfg, stack, i, h, real)
             held, routes = held + n, routes + [chosen]
+            if n_read is not None:
+                read = n_read if read is None else read + n_read
         else:
             y = mixers[kind]({n: a[i] for n, a in stack.items()}, h, i)
         x = x + y
-    return x, held, routes
+    return x, held, routes, read
 
 
 def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
@@ -405,8 +427,8 @@ def forward(
         out = causal_attention(q, k, v, kv_valid=kv_valid)
         return out.reshape(B, S, -1) @ lp["wo"]
 
-    x, held, routes = _walk(params, cfg, params["embed"][tokens],
-                            {"M": ssm, "*": attend})
+    x, held, routes, _ = _walk(params, cfg, params["embed"][tokens],
+                               {"M": ssm, "*": attend})
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     kv = None
     if want_kv:
@@ -460,9 +482,13 @@ def decode_step(
                            block_tables, seq_lens, k, v)
         return out.reshape(B, -1) @ lp["wo"]
 
-    x, held, routes = _walk(params, cfg, params["embed"][tokens],
-                            {"M": ssm, "*": attend})
-    cache = _written(cache, ks, vs, None, tails, held, cur_slots)
+    # A padding lane's choices are nobody's (asked only by the form that reads
+    # the chosen experts).
+    x, held, routes, read = _walk(
+        params, cfg, params["embed"][tokens], {"M": ssm, "*": attend},
+        real=(pages.lanes_in_use(block_tables)
+              if cfg.moe_impl.startswith("chosen") else None))
+    cache = _written(cache, ks, vs, None, tails, held, cur_slots, read)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
@@ -472,19 +498,20 @@ def decode_step(
     return (*out, jnp.stack(routes)) if want_routes else out
 
 
-def _written(cache: state.Cache, ks, vs, ssms, tails, held, kv_slots
-             ) -> state.Cache:
+def _written(cache: state.Cache, ks, vs, ssms, tails, held, kv_slots,
+             read=None) -> state.Cache:
     """``cache`` after a step: the attention layers' new rows in their pages
     at ``kv_slots`` (block ids, slots in them), the state layers' new rows in
     their slots (``ssms`` None: a decode step, whose states are in the cache
-    already), the step's count of held choices added."""
+    already), the step's count of held choices added (and, where it counted
+    them, of held experts ``read``)."""
     if ks:
         k, v = pages.write(cache.k, cache.v, jnp.stack(ks), jnp.stack(vs),
                            *kv_slots)
         cache = dataclasses.replace(cache, k=k, v=v)
     if tails:
         cache = state.write(cache, ssms, tails)
-    return state.counted(cache, held)
+    return state.counted(cache, held, read=read)
 
 
 def prefill_with_prefix(
@@ -540,8 +567,8 @@ def prefill_with_prefix(
             kv_positions=kv_positions, kv_valid=kv_valid)
         return out.reshape(B, S, -1) @ lp["wo"]
 
-    x, held, routes = _walk(params, cfg, params["embed"][tokens],
-                            {"M": ssm, "*": attend})
+    x, held, routes, _ = _walk(params, cfg, params["embed"][tokens],
+                               {"M": ssm, "*": attend})
     cache = _written(cache, ks, vs, ssms, tails, held, pages.sequence_slots(
         cache.k, block_table_row, suffix_len, S, prefix_len))
 

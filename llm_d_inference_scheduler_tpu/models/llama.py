@@ -130,13 +130,14 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray) -> jnp.ndarray:
 
 def _over_layers(cfg: ModelConfig, layers: Params) -> tuple[Params, Params]:
     """(What a scan over the stacked layers slices, what its body closes
-    over and merges into the slice.) The grouped MoE FFN is a Pallas call,
+    over and merges into the slice.) The grouped MoE FFN is a Pallas call
+    (and so is models/mla.py's form of few rows, over the chosen experts),
     and one layer's slice of the expert weights would reach it as a copy (a
     custom call's operand cannot be a fused slice): there the scan goes over
     everything else and a layer index, and the body keeps the expert weights
     whole, which the kernel reads at (layer, expert). In every other case the
     scan slices all of ``layers``, as it always did, and nothing is merged."""
-    if "router" not in layers or not cfg.moe_impl.startswith("grouped"):
+    if "router" not in layers or cfg.moe_impl == "dense":
         return layers, {}
     whole = {n: layers[n] for n in ("w1", "w2", "w3")}
     sliced = {n: a for n, a in layers.items() if n not in whole}

@@ -49,9 +49,10 @@ other layers (``params["layers"]``): scores ``s = sigmoid(h W_r)`` in f32;
 the experts_per_token experts with the largest ``s + b`` (``b`` the selection
 bias: it selects and does not weigh); gates ``s_i / sum_chosen s x
 routed_scaling_factor``; ``y = sum g_i SwiGLU_i(h) + SwiGLU_shared(h)``. The
-routed part runs dense over the experts or grouped (ops/pallas_moe.py),
-chosen by the engine per program as for Mixtral (``cfg.moe_impl``); the
-shared expert is a plain SwiGLU beside either.
+routed part runs dense over the experts, grouped, or -- a program of few
+rows on a chip that holds a range of the experts -- dense over the experts
+some row chose (ops/pallas_moe.py), chosen by the engine per program as for
+Mixtral (``cfg.moe_impl``); the shared expert is a plain SwiGLU beside any.
 
 **The double layer** (``params["layers"]`` alone; what a sublayer has --
 its attention and its dense FFN -- is stacked a SUBLAYER, 2 L rows, row
@@ -262,13 +263,17 @@ def _swiglu(h, w1, w3, w2):
     return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
 
 
-def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray
+def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+         real: jnp.ndarray | None = None
          ) -> tuple[jnp.ndarray, jnp.ndarray | None, jnp.ndarray | None]:
     """A layer's FFN on h [..., D] -- dense or experts, by the pytree -- the
     outputs of the router its tokens chose ([T, k]; None of a dense layer),
     and where ``cfg.tallies_choices`` how many of those choices name an
-    expert held here and how many a zero-compute one (int32 [2]; else
-    None)."""
+    expert held here and how many a zero-compute one (int32 [2]; else None)
+    -- and third, in the form that reads the chosen experts alone, how many
+    held experts' weights the layer read. ``real`` [T] says which rows are
+    somebody's (None: all): the others' choices make no expert worth
+    reading."""
     if "router" not in lp:
         return _swiglu(h, lp["w1"], lp["w3"], lp["w2"]), None, None
     ht = h.reshape(-1, h.shape[-1])
@@ -278,6 +283,8 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray
     first, count = cfg.held_experts
     here = ((idx >= first) & (idx < first + count) if cfg.tallies_choices
             else None)
+    local = idx if here is None else jnp.where(here, idx - first, -1)
+    read = []
     if cfg.moe_impl.startswith("grouped"):
         from ..ops.pallas_moe import grouped_experts
 
@@ -287,10 +294,19 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray
                             layer=lp.get("layer"),
                             first=None if here is None else first,
                             interpret=cfg.moe_impl == "grouped_interpret")
+    elif cfg.moe_impl.startswith("chosen"):
+        from ..ops.pallas_moe import chosen_experts
+
+        # Dense over the held experts that a row of somebody's chose.
+        if real is not None:
+            local = jnp.where(real[:, None], local, -1)
+        y, n_read = chosen_experts(
+            lp, ht, local, gates, count, layer=lp.get("layer"),
+            interpret=cfg.moe_impl == "chosen_interpret")
+        read = [n_read]
     else:
         # Dense over the held experts: each of them for every token, weighted
         # by its gate or by zero (models/llama._moe_ffn's form).
-        local = idx if here is None else jnp.where(here, idx - first, -1)
         weights = jnp.einsum(
             "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
             gates.astype(h.dtype))
@@ -309,7 +325,7 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray
         y = y + (jnp.sum(jnp.where(zero, gates, 0.0), axis=-1, keepdims=True)
                  * ht.astype(jnp.float32)).astype(h.dtype)
     counts = jnp.stack([jnp.sum(here, dtype=jnp.int32),
-                        jnp.sum(zero, dtype=jnp.int32)])
+                        jnp.sum(zero, dtype=jnp.int32), *read])
     return y.reshape(h.shape), idx, counts
 
 
@@ -561,7 +577,8 @@ def _segments(params: Params, cfg: ModelConfig
     return [tuple(seg) for seg in segs]
 
 
-def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend
+def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend,
+            real: jnp.ndarray | None = None
             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
                        jnp.ndarray | None, jnp.ndarray | None]:
     """x through every block, a scan a run of like layers
@@ -570,16 +587,18 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend
     layer's kind, as its page pool does. A model with window layers passes a
     function a kind, ``{"full": ..., "window": ...}``. Returns (x, rows
     [n_kv_layers, ...], the router's outputs chosen in every expert layer
-    [n_expert_layers, T, k], where ``cfg.tallies_choices`` the counts [2] of
-    those that are held here and of those that compute nothing, and the
-    window layers' rows [n_window_layers, ...] or None)."""
+    [n_expert_layers, T, k], where ``cfg.tallies_choices`` the counts of
+    those that are held here and of those that compute nothing ([2]; [3]
+    with the held experts' weights read, in the form that counts them:
+    :func:`_ffn`, which ``real`` [T] is for), and the window layers' rows
+    [n_window_layers, ...] or None)."""
     attends = attend if isinstance(attend, dict) else {"full": attend}
     rows = dict(full=[], window=[])
     routes, counts = [], None
     double = cfg.attn_sublayers == 2
     for name, lo, hi, kind, first in _segments(params, cfg):
-        # Where the grouped kernel serves, the routed experts' weights stay
-        # whole beside the scan (models/llama._over_layers, of one stack).
+        # Where a kernel serves the routed experts, their weights stay whole
+        # beside the scan (models/llama._over_layers, of one stack).
         stack, attend = params[name], attends[kind]
         kcfg = cfg.of_window() if kind == "window" else cfg
         subs = {k: stack[k] for k in _SUBLAYER} if double else {}
@@ -597,7 +616,7 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend
             a, row = attend(lp, h, layer)
             x = x + _gated(kcfg, lp, h, a) @ lp["wo"]
             y, chosen, tally = _ffn(cfg, lp, rms_norm(x, lp["ln_mlp"],
-                                                      cfg.norm_eps))
+                                                      cfg.norm_eps), real)
             return x + y, (row, chosen, tally)
 
         def double_body(x, layer_in):
@@ -612,7 +631,7 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend
                 x = x + a @ sub["wo"]
                 h = rms_norm(x, sub["ln_mlp"], cfg.norm_eps)
                 if i == 0:
-                    m, chosen, tally = _ffn(cfg, lp, h)
+                    m, chosen, tally = _ffn(cfg, lp, h, real)
                 x = x + _swiglu(h, sub["w1d"], sub["w3d"], sub["w2d"])
             return x + m, (jnp.stack(made), chosen, tally)
 
@@ -730,8 +749,8 @@ def forward(
         # that hands the counts to the cache as well.
         rows, idx = _split_rows(cfg, rows)
         kv = ((rows if counts is None
-               else state.Fresh(rows, None, None, None, *counts, idx=idx,
-                                win=wrows)),
+               else state.Fresh(rows, None, None, None, *counts[:2],
+                                idx=idx, win=wrows)),
               None)
     out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
     return (out, kv, routes) if want_routes else (out, kv)
@@ -808,8 +827,12 @@ def decode_step(
 
         attend = dict(full=attend, window=attend_window)
 
+    # A padding lane's choices are nobody's (asked only by the form that reads
+    # the chosen experts).
     x, rows, routes, counts, wrows = _blocks(
-        params, cfg, params["embed"][tokens], attend)
+        params, cfg, params["embed"][tokens], attend,
+        real=(pages.lanes_in_use(block_tables)
+              if cfg.moe_impl.startswith("chosen") else None))
     if want_routes and cfg.index_topk:
         routes = (routes, _picked(cfg, rows))
     rows, idx_rows = _split_rows(cfg, rows)

@@ -1,12 +1,18 @@
-"""Grouped-matmul MoE FFN for Mixtral-family models, and the rule that says
-when it serves.
+"""Grouped-matmul MoE FFN for Mixtral-family models, a form of few rows that
+reads the chosen experts alone, and the rules that say when each serves.
 
 The dense-over-experts form in models/llama.py:_moe_ffn computes every expert
 for every token: E/k times the FLOPs a token needs. Below the chip's ridge
-(a decode chunk's 2-16 rows) that costs nothing — both forms are the read of
-every expert's weights, and dense has no sort, scatter or padding. Above it
-(a prefill of hundreds of tokens) the extra FLOPs are time. So the form is
-chosen per traced program, from its shapes: :func:`use_grouped`.
+(a decode chunk's 2-16 rows) the FLOPs cost nothing, and both forms are the
+read of every expert's weights -- WHERE every expert has a row: a chip that
+holds all of its router's experts at a full batch (Mixtral: 16 rows x 2
+choices over 8 experts, an expert without a row is a 1% event), and dense has
+no sort, scatter or padding. Above the ridge (a prefill of hundreds of tokens)
+the extra FLOPs are time. And a chip that holds a RANGE of the experts its
+router scores sees about one row an expert a decode step: a third of the held
+experts have no row, and dense reads their weights for nobody. So the form is
+chosen per traced program, from its shapes: :func:`use_grouped`,
+:func:`use_chosen`.
 
 The grouped form computes only the (token, chosen expert) rows:
 
@@ -26,6 +32,12 @@ The grouped form computes only the (token, chosen expert) rows:
    repeat the last live tile's so that nothing is copied for them.
 3. Back in XLA: gather each token's k rows out of the padded layout, weight
    them by the router's gates and add.
+
+The form of few rows (:func:`chosen_experts`) is the same kernel with ONE
+row tile, the program's rows whole, shared by every expert some row chose: no
+sort, no gather, no group padding; the tile -> expert map is the list of
+chosen experts, and the tiles past its end are skipped as above. What it
+saves is the unchosen experts' weights.
 
 bf16 operands and f32 accumulation, as the dense einsums have them. Reference
 analogue: none — the reference router is control-plane Go (SURVEY.md); the
@@ -53,6 +65,23 @@ ROW_TILE = 128
 # more kernel programs traced at every start.
 GROUPED_MIN_TOKENS = 512
 
+# Rows an expert can expect of a program (rows x experts_per_token / router
+# outputs) up to which a program of one row tile reads the chosen experts'
+# weights alone (:func:`use_chosen`). The kernel reads an expert's weights as
+# fast as XLA's einsums do (700-735 GB/s of those it reads, at every width
+# below), so it wins what the unchosen experts are, less a combine: one layer
+# on the chip, dense / chosen ms (PERF.md section 6, PR 46) -- at 1.0 rows an
+# expert 1.72 / 1.16 (LongCat, 64 rows, 16 held) and 1.98 / 1.30 (DeepSeek,
+# 32 rows); at 2.0, 2.04 / 1.82 (dots3, 64 rows, 32 held); at 2.75, 1.91 /
+# 1.80 (Nemotron, 64 rows, 128 held, not gated); at 4.0, 1.92 / 1.94 and
+# 2.06 / 2.07: even; at 5.5, 1.90 / 2.19. The line stands between the last
+# gain and the first draw.
+CHOSEN_MAX_ROWS_PER_EXPERT = 3.0
+
+# Rows the one shared tile of :func:`chosen_experts` is padded to: a bf16
+# tile's sublanes.
+_CHOSEN_ROW_ALIGN = 16
+
 # What one grouped matmul may keep in VMEM (of a v5e's 128 MiB; the
 # compiler's default scoped limit of 16 MiB is raised to what the tiles need).
 VMEM_BUDGET_BYTES = 40 * 2 ** 20
@@ -76,6 +105,26 @@ def use_grouped(tokens: int, *, n_experts: int, experts_per_token: int,
     if d_model % 128 or d_ff % 128:
         return False
     return tokens >= GROUPED_MIN_TOKENS
+
+
+def use_chosen(tokens: int, *, router_outputs: int, experts_per_token: int,
+               n_experts: int, held: int, d_model: int, d_ff: int,
+               platform: str, sharded: bool, interpret: bool = False) -> bool:
+    """Whether a program of ``tokens`` rows runs its held experts dense over
+    those a row chose (:func:`chosen_experts`) and not over all of them. From
+    what is known when the program is traced: where the kernel compiles (as
+    :func:`use_grouped`), on a chip that holds a RANGE of the ``n_experts``
+    its router scores (``held`` of them; one that holds them all sees every
+    row's every choice, three rows an expert and more at a full batch), for
+    rows that are one tile, and while the rows an expert can expect -- rows x
+    choices over the router's outputs, its zero-compute ones among them --
+    leave enough experts without a row to pay for the kernel."""
+    if sharded or not (platform == "tpu" or interpret):
+        return False
+    if not 0 < held < n_experts or d_model % 128 or d_ff % 128:
+        return False
+    return (tokens <= ROW_TILE and tokens * experts_per_token
+            <= CHOSEN_MAX_ROWS_PER_EXPERT * router_outputs)
 
 
 def _vmem_bytes(n_row_tiles: int, tm: int, tk: int, tn: int, n_rhs: int,
@@ -162,7 +211,8 @@ def _gmm_kernel(tile_expert, n_live, layer, lhs_ref, *refs, n_rhs: int,
 
 def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
                     tm: int = ROW_TILE, tiles: tuple[int, int] | None = None,
-                    interpret: bool = False, relu2: bool = False):
+                    interpret: bool = False, relu2: bool = False,
+                    shared_tiles: int = 0):
     """lhs [Tp, K] (group-padded rows) times the expert of each row tile out
     of every ``rhs`` [L, E, K, N] at ``layer``; one rhs → lhs·rhs (``relu2``:
     its relu squared), two → the SwiGLU of both. The weights come stacked
@@ -170,10 +220,13 @@ def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
     as a copy (a custom call's operand cannot be a fused slice), 2.8 GB a
     Mixtral layer. tile_expert [Tp // tm] int32; n_live [1] int32, the tiles
     that hold rows. Returns [Tp, N] in lhs.dtype; rows of tiles past n_live
-    are not written."""
-    Tp, K = lhs.shape
+    are not written. ``shared_tiles`` n: lhs is ONE tile [tm, K] that each of
+    n tiles multiplies by its own expert (:func:`chosen_experts`); it stays
+    in VMEM from tile to tile, and the result is [n * tm, N]."""
+    K = lhs.shape[1]
     N = rhs[0].shape[3]
-    n_rhs, n_row_tiles = len(rhs), Tp // tm
+    n_rhs, n_row_tiles = len(rhs), shared_tiles or lhs.shape[0] // tm
+    Tp = n_row_tiles * tm
     itemsize = jnp.dtype(lhs.dtype).itemsize
     tk, tn = tiles or pick_tiles(Tp, K, N, n_rhs, itemsize, tm)
     k_tiles = K // tk
@@ -190,7 +243,8 @@ def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
         num_scalar_prefetch=3,
         grid=(N // tn, k_tiles, n_row_tiles),
         in_specs=[pl.BlockSpec(
-            (tm, tk), lambda n, k, i, te, live, layer: (row(i, live), k))]
+            (tm, tk), lambda n, k, i, te, live, layer:
+            (0 if shared_tiles else row(i, live), k))]
         + [pl.BlockSpec(
             (None, None, tk, tn), lambda n, k, i, te, live, layer:
             (layer[0], te[row(i, live)], k, n))] * n_rhs,
@@ -211,6 +265,19 @@ def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
         name=("moe_grouped_swiglu" if n_rhs == 2 else
               "moe_grouped_relu2" if relu2 else "moe_grouped_matmul"),
     )(tile_expert, n_live, layer, lhs, *rhs)
+
+
+def _stacked(lp, layer, gated: bool):
+    """(The up weights, (w2,), the layer [1] int32) as :func:`_grouped_matmul`
+    takes them: every layer's weights and ``layer``, or one layer's (``layer``
+    None) as a stack of one."""
+    up = ("w1", "w3") if gated else ("w1",)
+    if layer is None:
+        *w_up, w2 = (lp[n][None] for n in (*up, "w2"))
+        layer = jnp.zeros((), jnp.int32)
+    else:
+        *w_up, w2 = (lp[n] for n in (*up, "w2"))
+    return tuple(w_up), (w2,), layer.reshape(1).astype(jnp.int32)
 
 
 def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
@@ -310,17 +377,11 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
         # in rows of absent choices, which are masked below.
         n_live = jnp.maximum(n_live, 1)
 
-    up = ("w1", "w3") if gated else ("w1",)
-    if layer is None:
-        *w_up, w2 = (lp[n][None] for n in (*up, "w2"))
-        layer = jnp.zeros((), jnp.int32)
-    else:
-        *w_up, w2 = (lp[n] for n in (*up, "w2"))
-    layer = layer.reshape(1).astype(jnp.int32)
-    h = _grouped_matmul(x_pad, tuple(w_up), layer, tile_expert, n_live,
+    w_up, w2, layer = _stacked(lp, layer, gated)
+    h = _grouped_matmul(x_pad, w_up, layer, tile_expert, n_live,
                         tm=tm, tiles=tiles_up, interpret=interpret,
                         relu2=not gated)
-    out_pad = _grouped_matmul(h, (w2,), layer, tile_expert, n_live,
+    out_pad = _grouped_matmul(h, w2, layer, tile_expert, n_live,
                               tm=tm, tiles=tiles_down, interpret=interpret)
 
     rows = out_pad[dest].reshape(T, k, D)
@@ -329,3 +390,50 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
         rows = jnp.where(here.reshape(T, k, 1), rows, 0)
     y = (rows * gates[..., None].astype(xt.dtype)).sum(axis=1)
     return y.astype(xt.dtype)
+
+
+def chosen_experts(lp, xt, local, gates, n_experts: int, *, layer=None,
+                   interpret: bool = False, gated: bool = True
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed experts' part of an MoE FFN for a program of few rows (one
+    row tile or less): dense over the held experts that some row chose. Every
+    such expert takes ALL of ``xt`` [T, D] -- under the ridge the rows cost
+    nothing, so there is no sort, no gather and no group layout -- and an
+    expert nobody chose is neither computed nor read: it contributes the zero
+    it contributes to the dense form. ``local`` [T, k] is the held expert each
+    choice names, 0 .. n_experts - 1, or -1: an expert not held here, or a row
+    that is nobody's (a padding lane's choices make no expert live).
+    ``gates`` [T, k]; the weights, ``layer`` and ``gated`` as
+    :func:`grouped_experts` takes them. Returns ([T, D] in xt.dtype, the
+    experts whose weights were read: int32 scalar)."""
+    T, D = xt.shape
+    E = n_experts
+    tile = jnp.arange(E, dtype=jnp.int32)
+    hit = local[..., None] == tile                                # [T, k, E]
+    weights = jnp.einsum("tke,tk->te", hit.astype(xt.dtype),
+                         gates.astype(xt.dtype))                  # [T, E]
+    live = jnp.any(hit, axis=(0, 1))                              # [E]
+    # Tile i is the i-th live expert; the tiles past them are skipped.
+    tile_expert = jnp.nonzero(live, size=E, fill_value=0)[0].astype(jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    # A step in which nobody chose a held expert (two lanes of a ramp; every
+    # warm-up program) has no live tile, and the kernel's block index
+    # min(i, n_live - 1) would be -1: the chip halts on that copy's bounds
+    # check (grouped_experts has the note). One tile then counts as live,
+    # and is masked with the dead ones below.
+    n_read = jnp.maximum(n_live, 1)
+
+    Tp = -(-T // _CHOSEN_ROW_ALIGN) * _CHOSEN_ROW_ALIGN
+    x_pad = jnp.pad(xt, ((0, Tp - T), (0, 0)))
+    w_up, w2, layer = _stacked(lp, layer, gated)
+    h = _grouped_matmul(x_pad, w_up, layer, tile_expert, n_read[None], tm=Tp,
+                        interpret=interpret, relu2=not gated, shared_tiles=E)
+    out = _grouped_matmul(h, w2, layer, tile_expert, n_read[None], tm=Tp,
+                          interpret=interpret)
+
+    # A dead tile's rows hold whatever was there: masked, not weighed by 0.
+    is_live = tile < n_live
+    out = jnp.where(is_live[:, None, None], out.reshape(E, Tp, D)[:, :T], 0)
+    y = jnp.einsum("itd,ti->td", out,
+                   jnp.where(is_live, weights[:, tile_expert], 0))
+    return y.astype(xt.dtype), n_read

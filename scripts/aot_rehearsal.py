@@ -151,18 +151,21 @@ def main(argv=None) -> int:
     width = geom.max_blocks_per_seq
     pages = sds(geom.shape, jnp.dtype(geom.dtype))
 
-    def pool(rows):
+    def pool(rows, reads=False):
         """The pool pair as a step function of ``rows`` rows takes it: K and
-        V, (latent, None), or (pages and state as one cache, None)."""
+        V, (latent, None), or (pages and state as one cache, None);
+        ``reads``: a decode chunk that counts the held experts it read."""
+        read = sds((), jnp.int32) if reads else None
         if state_geom:
             return (kvstate.Cache(
                 pages, pages, sds(state_geom.ssm_shape, jnp.float32),
                 sds(state_geom.conv_shape, jnp.dtype(state_geom.dtype)),
-                slots=sds((rows,), jnp.int32), held=sds((), jnp.int32)), None)
+                slots=sds((rows,), jnp.int32), held=sds((), jnp.int32),
+                read=read), None)
         if geom.counted:   # a latent pool that rides with counts
             return (kvstate.Cache(
                 pages, None, None, None, slots=sds((rows,), jnp.int32),
-                held=sds((), jnp.int32),
+                held=sds((), jnp.int32), read=read,
                 zero=sds((), jnp.int32) if geom.counts_zero else None,
                 counts_zero=geom.counts_zero,
                 idx=(sds(geom.index_shape, jnp.dtype(geom.dtype))
@@ -186,7 +189,8 @@ def main(argv=None) -> int:
     for b in [int(x) for x in args.decode_batches.split(",") if x]:
         programs.append((f"decode {b}x{width}", jax.jit(
             eng._decode_chunk_impl, donate_argnums=(3, 4)),
-            (params, sds((b,), jnp.int32), sds((b,), jnp.int32), *pool(b),
+            (params, sds((b,), jnp.int32), sds((b,), jnp.int32),
+             *pool(b, reads=eng.bound.decode_expert_visits(b) > 0),
              sds((b, width), jnp.int32), *sampling(b), sds((), jnp.int32))))
     for spec in [s for s in args.prefill.split(",") if s]:
         bucket, rows = (int(x) for x in spec.split("x"))

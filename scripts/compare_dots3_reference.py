@@ -310,7 +310,7 @@ def main(argv=None) -> int:
                     got, routes, picked, cache = window(
                         params, toks, np.full((1,), m, np.int32),
                         np.full((1,), lo, np.int32), at, row, prior)
-                cache, _, _ = state.take_counts(cache)
+                cache, *_ = state.take_counts(cache)
                 looked[lane].append((lo + m - 1, np.asarray(got)))
                 routes_of[lane].append(np.asarray(routes)[:, :m])
                 picked = np.asarray(picked)  # [Lf, bucket, prior x blk + bucket]
@@ -337,7 +337,7 @@ def main(argv=None) -> int:
             logits, routes, picked, cache = decode(
                 params, seq[positions], positions,
                 state.at_slots(cache, np.arange(B), wt), tables)
-            cache, _, _ = state.take_counts(cache)
+            cache, *_ = state.take_counts(cache)
             steps.append(np.asarray(logits))                    # [B, V]
             routes, picked = np.asarray(routes), np.asarray(picked)
             for lane, t in enumerate(positions):

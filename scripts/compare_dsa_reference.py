@@ -211,7 +211,7 @@ def main(argv=None) -> int:
     def kept(cache):
         """The cache between two programs: the counts out, and with
         --degrade one pool as 8 bits would hold it."""
-        cache, _, _ = state.take_counts(cache)
+        cache, *_ = state.take_counts(cache)
         if args.degrade == "latent8":
             cache = dataclasses.replace(cache, k=rounded(cache.k))
         if args.degrade == "index8":

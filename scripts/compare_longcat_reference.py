@@ -224,7 +224,7 @@ def main(argv=None) -> int:
         counted, chosen_sum, choices = [0, 0], [0, 0], [0]
 
         def book(cache, chose):
-            cache, held, zero = state.take_counts(cache)
+            cache, held, zero, _ = state.take_counts(cache)
             for i, n in enumerate((held, zero)):
                 counted[i] += int(n)
             for i, n in enumerate(tally(chose)):

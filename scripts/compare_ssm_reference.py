@@ -265,7 +265,7 @@ def main(argv=None) -> int:
             got, routes, cache = first_window(
                 params, toks, jnp.full((1,), a, jnp.int32), jnp.asarray(at),
                 at_lanes(cache, [lane]), row)
-            cache, n_held, _ = state.take_counts(cache)
+            cache, n_held, *_ = state.take_counts(cache)
             chose = [np.asarray(routes)[:, :a]]
             if keep:
                 held += int(n_held)

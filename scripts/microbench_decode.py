@@ -19,6 +19,16 @@ too. Then it checks the mathematics (``--moe-check-seeds``): the layer's
 output, grouped and dense against a plain float32 FFN, and the last token's
 logits through the four-layer model, grouped against dense.
 
+``--moe-decode`` times the held experts of one expert layer at DECODE shapes
+instead (a chip that holds a range of the experts its router scores, 2-128
+rows, the widths of the benchmark's four such configurations): the dense
+einsums over every held expert against the form that reads the chosen
+experts alone (ops/pallas_moe.chosen_experts; ``--moe-candidates``: and a
+loop of ``lax.cond`` over the held experts in plain XLA), ms and the GB/s of
+the weights each has to read; it is what CHOSEN_MAX_ROWS_PER_EXPERT was set
+from, and its last lines run the two routings that would halt a chip on a
+block index of -1 (no held expert chosen; a lane whose choices do not count).
+
 ``--ssm`` times the state-space layers' decode kernel alone instead (one
 step of the recurrence on the state pool in place, ops/pallas_ssm.py) at a
 model's widths and the cell's lanes, every state layer in one program on a
@@ -42,6 +52,9 @@ Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--points 16x1000,16x300,8x300]
        python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
            [--moe-candidates] [--moe-check-seeds 0,1]
+       python scripts/microbench_decode.py --moe-decode [--moe-candidates]
+           [--moe-decode-configs longcat-flash-omni-cut,...]
+           [--moe-decode-rows 2,8,16,32,64,128]
        python scripts/microbench_decode.py --ssm [--ssm-lanes 64,16,2]
            [--ssm-head-blocks 128,32]
        python scripts/microbench_decode.py --latent
@@ -253,6 +266,164 @@ def moe_main(args):
                  same_argmax=bool(last["grouped"].argmax()
                                   == last["dense"].argmax()))
         del params
+
+
+def moe_decode_main(args):
+    """The held experts' part of one expert layer at decode shapes (a row a
+    lane, a chip that holds a range of the experts its router scores), at the
+    widths of ``--moe-decode-configs``: the dense einsums over every held
+    expert as the models have them, against the form that reads the chosen
+    experts alone (ops/pallas_moe.chosen_experts) and, with
+    ``--moe-candidates``, a loop over the held experts in plain XLA with a
+    ``lax.cond`` around one expert's products. Choices are drawn evenly over
+    the router's outputs, ``--moe-decode-draws`` routings a point; GB/s is of
+    the weights a form has to read (dense: every held expert's; the others:
+    the live ones'). The last lines run the form at two rows with no held
+    expert chosen, and with a row whose choices do not count: on the chip
+    either would halt the core if a block index went to -1."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+    from llm_d_inference_scheduler_tpu.ops import pallas_moe
+
+    interpret = args.moe_interpret
+    device = jax.devices()[0]
+    peak = (None if interpret else
+            _chipbench_kernels().peaks(device.device_kind)["bytes_per_s"])
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    L, layer = 2, jnp.asarray(1, jnp.int32)   # stacked, the second layer read
+
+    def line(**kv):
+        print(json.dumps({**kv, "device": device.device_kind}), flush=True)
+
+    def weights_of(local, gates, E, dtype):
+        return jnp.einsum("tke,tk->te", jax.nn.one_hot(local, E, dtype=dtype),
+                          gates.astype(dtype))
+
+    for name in args.moe_decode_configs.split(","):
+        with open(os.path.join(root, "chipbench", "configs",
+                               name + ".json")) as f:
+            m = config_from_hf(types.SimpleNamespace(**json.load(f)),
+                               name=name)
+        first, E = m.held_experts
+        k, outputs = m.experts_per_token, m.router_width
+        gated = not m.moe_latent_dim          # models/hybrid.py: relu squared
+        D, F = m.moe_latent_dim or m.d_model, m.moe_d_ff or m.d_ff
+        if interpret:                         # the control flow alone
+            D, F = 256, 128
+        dt = jnp.dtype(m.dtype)
+        expert_bytes = (3 if gated else 2) * D * F * dt.itemsize
+        keys = iter(jax.random.split(jax.random.key(0), 8))
+
+        def w(shape, fan_in):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    * fan_in ** -0.5).astype(dt)
+
+        stack = {"w1": w((L, E, D, F), D), "w2": w((L, E, F, D), F),
+                 **({"w3": w((L, E, D, F), D)} if gated else {})}
+
+        def dense(stack, x, local, gates):
+            lp = {n: a[layer] for n, a in stack.items()}
+            weights = weights_of(local, gates, E, x.dtype)
+            up = jnp.einsum("td,edf->tef", x, lp["w1"])
+            if not gated:     # the gate ahead of the one sum (models/hybrid)
+                act = jnp.square(jax.nn.relu(up)) * weights[..., None]
+                return jnp.einsum("tef,efz->tz", act, lp["w2"])
+            act = jax.nn.silu(up) * jnp.einsum("td,edf->tef", x, lp["w3"])
+            return jnp.einsum("ted,te->td",
+                              jnp.einsum("tef,efd->ted", act, lp["w2"]),
+                              weights)
+
+        def chosen(stack, x, local, gates):
+            return pallas_moe.chosen_experts(
+                stack, x, local, gates, E, layer=layer, gated=gated,
+                interpret=interpret)[0]
+
+        def xla_cond(stack, x, local, gates):
+            weights = weights_of(local, gates, E, x.dtype)
+            live = jnp.any(local[..., None] == jnp.arange(E), axis=(0, 1))
+
+            def expert(e, y):
+                def run(y):
+                    up = x @ stack["w1"][layer, e]
+                    act = (jax.nn.silu(up) * (x @ stack["w3"][layer, e])
+                           if gated else jnp.square(jax.nn.relu(up)))
+                    out = (act @ stack["w2"][layer, e]).astype(jnp.float32)
+                    return y + out * weights[:, e, None]
+                return jax.lax.cond(live[e], run, lambda y: y, y)
+
+            y = jax.lax.fori_loop(0, E, expert,
+                                  jnp.zeros(x.shape, jnp.float32))
+            return y.astype(x.dtype)
+
+        forms = {"dense": dense, "chosen": chosen}
+        if args.moe_candidates:
+            forms["xla_cond"] = xla_cond
+        forms = {n: jax.jit(fn) for n, fn in forms.items()}
+        rng = np.random.default_rng(0)
+
+        def draw(T):
+            idx = np.argsort(rng.random((T, outputs)), axis=1)[:, :k]
+            local = np.where((idx >= first) & (idx < first + E),
+                             idx - first, -1).astype(np.int32)
+            return jnp.asarray(local), jnp.asarray(
+                rng.random((T, k), np.float32))
+
+        for T in [int(t) for t in args.moe_decode_rows.split(",")]:
+            x = jax.random.normal(jax.random.key(T), (T, D), dt)
+            draws = [draw(T) for _ in range(args.moe_decode_draws)]
+            live = [len(set(np.asarray(loc).ravel()) - {-1})
+                    for loc, _ in draws]
+            ms, err = {}, 0.0
+            for form, fn in forms.items():
+                try:
+                    ms[form] = float(np.mean(
+                        [timeit(fn, stack, x, *d, iters=args.moe_decode_iters)
+                         for d in draws]))
+                except Exception as e:   # a form the compiler refuses
+                    line(component="moe_decode", config=name, form=form, T=T,
+                         error=f"{type(e).__name__}: {str(e)[:300]}")
+            want = forms["dense"](stack, x, *draws[0]).astype(jnp.float32)
+            if "chosen" in ms:
+                got = forms["chosen"](stack, x, *draws[0]).astype(jnp.float32)
+                err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+            def gbps(form, experts):
+                return (None if peak is None or form not in ms else round(
+                    experts * expert_bytes / (ms[form] * 1e-3) / 1e9, 1))
+
+            line(component="moe_decode", config=name, T=T, held=E, D=D, F=F,
+                 rows_per_expert=round(T * k / outputs, 3),
+                 live_mean=round(float(np.mean(live)), 2),
+                 unread_share_pct=round(100 * (1 - np.mean(live) / E), 1),
+                 ms={n: round(v, 4) for n, v in ms.items()},
+                 read_GBps={n: gbps(n, E if n == "dense" else
+                                    max(float(np.mean(live)), 1.0))
+                            for n in ms},
+                 chosen_vs_dense=(round(ms["dense"] / ms["chosen"], 3)
+                                  if "chosen" in ms else None),
+                 chosen_err_vs_dense=err)
+
+        # What halts a chip and not the interpreter: no tile live at all, and
+        # a lane whose choices do not count.
+        x = jax.random.normal(jax.random.key(2), (2, D), dt)
+        local, gates = draw(2)
+        local = local.at[0, 0].set(0)              # a held expert, chosen ...
+        nobody = jnp.full_like(local, -1)
+        one = local.at[1].set(-1)                  # ... by row 0 alone
+        none = forms["chosen"](stack, x, nobody, gates)
+        got = forms["chosen"](stack, x, one, gates).astype(jnp.float32)
+        want = forms["dense"](stack, x, one, gates).astype(jnp.float32)
+        line(check="moe_decode_edges", config=name,
+             none_chosen_max_abs=float(jnp.abs(none.astype(jnp.float32)).max()),
+             one_row_err_vs_dense=float(jnp.abs(got - want).max()
+                                        / jnp.abs(want).max()),
+             one_row_second_row_max_abs=float(jnp.abs(got[1]).max()))
+        del stack
 
 
 def ssm_main(args):
@@ -573,6 +744,17 @@ def main(argv=None):
     ap.add_argument("--moe-interpret", action="store_true",
                     help="interpret the kernels: rehearses the control flow "
                          "on the CPU; its times mean nothing")
+    ap.add_argument("--moe-decode", action="store_true",
+                    help="time the held experts of one expert layer at "
+                         "decode shapes instead: dense over all of them "
+                         "against the chosen ones alone")
+    ap.add_argument("--moe-decode-configs",
+                    default="longcat-flash-omni-cut,deepseek-v3.2-exp-cut,"
+                            "dots3-note-prev-cut,nemotron-3-super-cut",
+                    help="files of chipbench/configs: the widths")
+    ap.add_argument("--moe-decode-rows", default="2,8,16,32,64,128")
+    ap.add_argument("--moe-decode-draws", type=int, default=4)
+    ap.add_argument("--moe-decode-iters", type=int, default=20)
     ap.add_argument("--ssm", action="store_true",
                     help="time the state-space layers' decode kernel alone "
                          "instead")
@@ -621,6 +803,8 @@ def main(argv=None):
     )
 
     configure_compile_cache()
+    if args.moe_decode:
+        return moe_decode_main(args)
     if args.moe:
         return moe_main(args)
     if args.ssm:

@@ -339,6 +339,40 @@ def test_grouped_relu2_experts_compile_on_a_held_range(one_chip, tokens):
     assert "moe_grouped_relu2" in hlo and "moe_grouped_swiglu" not in hlo
 
 
+@pytest.mark.parametrize("rows,held,k,d_model,d_ff,gated", [
+    # The decode buckets of the four cells that hold a range of their
+    # router's experts (chipbench/configs), at their full batch and at the
+    # two lanes of a ramp.
+    (64, 16, 12, 6144, 2048, True),      # longcat-flash-omni-cut
+    (2, 16, 12, 6144, 2048, True),
+    (32, 16, 8, 7168, 2048, True),       # deepseek-v3.2-exp-cut
+    (64, 32, 8, 5120, 1536, True),       # dots3-note-prev-cut
+    (64, 128, 22, 1024, 2688, False),    # nemotron-3-super-cut's latent
+])
+def test_chosen_experts_compile_at_the_held_range_cells_widths(
+        one_chip, rows, held, k, d_model, d_ff, gated):
+    """Dense over the chosen experts: the grouped matmul twice with one row
+    tile of the step's rows (padded to a bf16 tile's 16), the weights stacked
+    over layers and read in place."""
+    bf16 = functools.partial(_sds, one_chip, dtype=jnp.bfloat16)
+    lp = {"w1": bf16((3, held, d_model, d_ff)),
+          "w2": bf16((3, held, d_ff, d_model)),
+          **({"w3": bf16((3, held, d_model, d_ff))} if gated else {})}
+    compiled = jax.jit(
+        lambda lp, x, local, gates: pallas_moe.chosen_experts(
+            lp, x, local, gates, held, layer=jnp.asarray(2, jnp.int32),
+            gated=gated)
+    ).lower(lp, bf16((rows, d_model)), _sds(one_chip, (rows, k), jnp.int32),
+            _sds(one_chip, (rows, k), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    assert ("moe_grouped_swiglu" if gated else "moe_grouped_relu2") in hlo
+    # Nothing the size of a layer's weights is made: the largest temporary
+    # is the tiles' outputs, held x rows x the wider width.
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 8 * held * max(rows, 16) * max(d_model, d_ff))
+
+
 @pytest.mark.parametrize("d_model,d_ff,n_experts,top_k,tokens", [
     # Mixtral-8x7B, the one MoE model registered, at the two prefill buckets
     # the rule hands to the grouped form in mixtral-8x7b-cut.batch-full. Its
@@ -459,16 +493,20 @@ def test_no_window_of_the_selecting_block_holds_a_heads_by_queries_by_rows(
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
 
 
-def test_the_selecting_blocks_decode_step_compiles_with_both_kernels(one_chip):
+@pytest.mark.parametrize("moe_impl", ["dense", "chosen"])
+def test_the_selecting_blocks_decode_step_compiles_with_both_kernels(
+        one_chip, moe_impl):
     """One decode step of 32 lanes over a table 1,152 blocks wide: the
     indexer's kernel over the lanes' key pages (read by the block table, not
     gathered), the selection, and the masked kernel over the latent pages,
-    with neither pool made anew."""
+    with neither pool made anew. In the form the cell's decode buckets trace
+    (``chosen``: the held experts a lane chose) the experts' weights reach
+    the kernel whole beside the scan: no layer's 1.4 GB of them is copied."""
     from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
     from llm_d_inference_scheduler_tpu.kvcache import state
     from llm_d_inference_scheduler_tpu.models import mla
 
-    m = _dsa_cut()
+    m = dataclasses.replace(_dsa_cut(), moe_impl=moe_impl)
     batch = 32
     geom = kvpages.PageGeometry.for_engine(m, batch, 18432)
     dt = jnp.dtype(m.dtype)
@@ -476,6 +514,7 @@ def test_the_selecting_blocks_decode_step_compiles_with_both_kernels(one_chip):
         _sds(one_chip, geom.shape, dt), None, None, None,
         slots=_sds(one_chip, (batch,), jnp.int32),
         held=_sds(one_chip, (), jnp.int32),
+        read=_sds(one_chip, (), jnp.int32),
         idx=_sds(one_chip, geom.index_shape, dt))
     params = jax.tree.map(
         lambda a: _sds(one_chip, a.shape, a.dtype),
@@ -491,6 +530,7 @@ def test_the_selecting_blocks_decode_step_compiles_with_both_kernels(one_chip):
     assert "dsa_index_scores_decode" in hlo
     assert "dsa_paged_decode_attention" in hlo
     assert "mla_paged_decode_attention" not in hlo
+    assert ("moe_grouped_swiglu" in hlo) == (moe_impl == "chosen")
     for pool in (geom.shape, geom.index_shape):
         shape = "bf16[" + ",".join(map(str, pool)) + "]"
         made = [ln.strip()[:160] for ln in hlo.splitlines()

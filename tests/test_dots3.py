@@ -220,7 +220,7 @@ def test_windows_then_decode_through_both_pools(kernels):
         else:
             last, cache = later(toks, jnp.asarray([m]), jnp.asarray([lo]),
                                 held, row)
-        cache, _, _ = state.take_counts(cache)
+        cache, *_ = state.take_counts(cache)
         np.testing.assert_allclose(np.asarray(last), want[lo + m - 1], **TOL)
     tables = np.zeros((2, per), np.int32)
     tables[0] = row[0]
@@ -232,7 +232,7 @@ def test_windows_then_decode_through_both_pools(kernels):
         logits, cache = decode(
             jnp.asarray([seq[t], 0]), jnp.asarray([t, 0]),
             state.at_slots(_poison(cache, owner), [0, 2], wt), tables)
-        cache, _, _ = state.take_counts(cache)
+        cache, *_ = state.take_counts(cache)
         np.testing.assert_allclose(np.asarray(logits), want[t], **TOL)
     # Pages came back and went out again, and a decoding lane never held
     # more than the window's pages and one.

@@ -169,7 +169,7 @@ def test_windows_then_decode_through_both_pools(kernels):
                 k_pages=state.at_slots(cache, [b]), v_pages=None,
                 block_table_row=TABLES[b:b + 1],
                 prior_table_row=TABLES[b:b + 1, :w])
-            cache, _, _ = state.take_counts(cache)
+            cache, *_ = state.take_counts(cache)
             np.testing.assert_allclose(np.asarray(got[0]),
                                        np.asarray(logits[b, 16 * w + 15]),
                                        **TOL)
@@ -188,7 +188,7 @@ def test_windows_then_decode_through_both_pools(kernels):
             params, tokens=tokens[:, t], positions=jnp.asarray([t, t]),
             k_pages=state.at_slots(cache, [0, 1]), v_pages=None,
             block_tables=TABLES)
-        cache, _, _ = state.take_counts(cache)
+        cache, *_ = state.take_counts(cache)
         np.testing.assert_allclose(np.asarray(got), np.asarray(logits[:, t]),
                                    **TOL)
         kept = np.asarray(kept)                   # [L, B, 96 cached + 1 own]

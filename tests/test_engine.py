@@ -1453,6 +1453,74 @@ def test_admissions_are_counted_once_a_request():
     assert hold >= 1 and hold + step == 6 == sum(_refills(eng))
 
 
+# ---------- the experts a decode step read, counted on the device ----------
+
+def _decode_expert_counts(interpret):
+    """Two requests on a chip's share of a double-layer model (8 of its 16
+    experts, widths the kernel tiles), stepped by hand with every decode
+    chunk the device was handed written down: (jetstream:moe_decode_experts_
+    total by label, [(lanes, steps) a chunk], the engine)."""
+    import dataclasses
+
+    from llm_d_inference_scheduler_tpu.models import configs
+
+    name = "tiny-longcat-share"
+    configs._REGISTRY[name] = dataclasses.replace(
+        configs.get_config("tiny-longcat"), name=name, d_model=128,
+        moe_d_ff=128, experts_held=8, experts_first=4)
+    chunks = []
+
+    def watch(eng, step):
+        if step is not None:
+            return
+        real = eng._exec_op
+
+        def exec_op(op, args):
+            if op[0] == "decode":
+                chunks.append((len(args["slots"]), args["steps"]))
+            return real(op, args)
+
+        eng._exec_op = exec_op
+
+    try:
+        *_, eng = _by_hand(
+            [_req("A", _prompt(7, 20), 9, 0.0), _req("B", _prompt(5, 33), 6,
+                                                     0.0)],
+            submit_at={"B": 2}, model=name, watch=watch, warmup=False,
+            max_batch=2, pallas_attention=False, pallas_interpret=interpret)
+    finally:
+        del configs._REGISTRY[name]
+    eng.telemetry.book_pair_counts()
+    return ({read: _counter(eng, "jetstream:moe_decode_experts_total",
+                            {"read": read}) for read in ("yes", "no")},
+            chunks, eng)
+
+
+def test_the_experts_a_decode_step_read_add_up_to_held_x_layers_x_steps():
+    """Where decode programs read the chosen experts alone, every (held
+    expert, expert layer, step) is booked once, read or not: at least one an
+    expert layer a step (the tile that stands in where none is chosen), and
+    fewer than all of them."""
+    counts, chunks, eng = _decode_expert_counts(interpret=True)
+    assert eng.bound.model_for(2).moe_impl == "chosen_interpret"
+    steps = sum(n for _, n in chunks)
+    assert chunks and {lanes for lanes, _ in chunks} == {2}
+    assert counts["yes"] + counts["no"] == 8 * 2 * steps
+    assert 2 * steps <= counts["yes"] < 8 * 2 * steps
+    # The host's count of rows by form reads these programs as dense.
+    assert _counter(eng, "jetstream:moe_ffn_tokens_total",
+                    {"form": "dense"}) >= 2 * steps
+
+
+def test_an_engine_whose_decode_programs_stay_dense_has_no_such_series():
+    counts, chunks, eng = _decode_expert_counts(interpret=False)
+    assert eng.bound.model_for(2).moe_impl == "dense" and chunks
+    assert counts == {"yes": None, "no": None}
+    assert "moe_decode_experts_total{" not in eng.telemetry.render().decode()
+    assert _counter(eng, "jetstream:moe_routed_pairs_total",
+                    {"held": "yes"}) > 0
+
+
 if __name__ == "__main__":
     import pprint
 

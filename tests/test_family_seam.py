@@ -130,6 +130,9 @@ def test_bind_resolves_a_familys_forms(name, module, widen, kw, forms):
     assert bound.grouped == dataclasses.replace(
         bound.mcfg, moe_impl="grouped_interpret" if kw.get("interpret")
         else "grouped")
+    assert bound.chosen == dataclasses.replace(
+        bound.mcfg, moe_impl="chosen_interpret" if kw.get("interpret")
+        else "chosen")
     with pytest.raises(dataclasses.FrozenInstanceError):
         bound.mcfg = cfg
 
@@ -148,6 +151,64 @@ def test_bind_model_for_is_the_moe_rule_shape_by_shape():
         assert other.model_for(4096) is other.mcfg
     assert bind(cfg, platform="cpu", interpret=True).model_for(
         4096).moe_impl == "grouped_interpret"
+
+
+def _benchmark_configuration(name):
+    """A configuration of chipbench/configs as the launcher maps it."""
+    import json
+    import types
+
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+
+    with open(PKG.parent / "chipbench" / "configs" / f"{name}.json") as f:
+        doc = json.load(f)
+    return config_from_hf(types.SimpleNamespace(**doc), name=name)
+
+
+_ROWS = (2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
+
+
+@pytest.mark.parametrize("name, chosen_upto, grouped", [
+    # No experts: one form.
+    ("qwen3-4b", 0, False),
+    # Every expert held (3 to 4 rows an expert at a full batch): the decode
+    # buckets trace the parent's object, whatever their width.
+    ("mixtral-8x7b-cut", 0, True),
+    ("kimi-vl-a3b-cut", 0, True),
+    # A range held: rows x choices / router outputs <= 3 reads the chosen.
+    ("nemotron-3-super-cut", 64, True),       # 64 x 22 / 512 = 2.75
+    ("longcat-flash-omni-cut", 128, True),    # 128 x 12 / 768 = 2.0
+    ("deepseek-v3.2-exp-cut", 64, True),      # 64 x 8 / 256 = 2.0; 128: 4.0
+    ("dots3-note-prev-cut", 64, True),
+])
+def test_the_moe_form_of_every_benchmark_configurations_programs(
+        name, chosen_upto, grouped):
+    """Which form each decode bucket and prefill bucket of the seven
+    configurations traces on the chip, from its shapes alone."""
+    cfg = _benchmark_configuration(name)
+    bound = bind(cfg, platform="tpu")
+    assert bound.mcfg.moe_impl == "dense"
+    for rows in _ROWS:
+        want = (bound.chosen if rows <= chosen_upto else
+                bound.grouped if grouped and rows >= 512 else bound.mcfg)
+        assert bound.model_for(rows) is want, rows
+        assert bound.decode_expert_visits(rows) == (
+            cfg.held_experts[1] * cfg.n_expert_layers
+            if rows <= chosen_upto else 0)
+        # The host's count of rows by form knows grouped and dense alone.
+        if cfg.n_experts:
+            assert bound.program_counts("decode", rows, 1)[0] == (
+                "moe_ffn_tokens", "grouped" if want is bound.grouped
+                else "dense", rows)
+    assert bound.chosen.moe_impl == "chosen"
+    assert bound.describe()["experts_chosen_max_rows"] == (
+        3.0 if chosen_upto else None)
+    # Off the chip, and where the weights span devices, the dense einsums.
+    for other in (bind(cfg, platform="cpu"),
+                  bind(cfg, platform="tpu", sharded=True)):
+        assert all(other.model_for(rows) is other.mcfg for rows in _ROWS)
+    assert bind(cfg, platform="cpu", interpret=True).model_for(2).moe_impl == (
+        "chosen_interpret" if chosen_upto else "dense")
 
 
 def test_bind_takes_a_forced_form_over_the_rule():
@@ -319,7 +380,8 @@ SETTINGS_KEYS = {
     "model", "n_layers", "dtype", "max_batch", "max_model_len", "kv_blocks",
     "kv_layers", "kv_token_bytes", "kv_pool_bytes", "kv_run_pages",
     "index_topk", "index_token_bytes", "index_pool_bytes", "index_scores",
-    "experts_first", "experts_held", "zero_experts", "state_slot_bytes",
+    "experts_first", "experts_held", "zero_experts",
+    "experts_chosen_max_rows", "state_slot_bytes",
     "state_pool_bytes", "state_update", "prefix_caching",
     "off_for_state_layers", "decode_chunk", "pallas_attention", "kv_wire",
     "kv_wire_error", "compile_cache_dir"}
@@ -351,7 +413,7 @@ def test_settings_are_the_parents_keys_each_family_saying_its_own(
     bound = bind(cfg, platform="cpu")
     assert geom.one_chip_only == one_chip
     said = {**geom.describe(), **bound.describe()}
-    assert len(said) == len(geom.describe()) + len(bound.describe()) == 15
+    assert len(said) == len(geom.describe()) + len(bound.describe()) == 16
     assert said["kv_pool_bytes"] == geom.pool_bytes
     assert set(said) < SETTINGS_KEYS
     assert {k: said[k] for k in want} == want

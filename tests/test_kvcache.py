@@ -334,7 +334,7 @@ def test_state_pool_is_indexed_by_slot_with_one_row_that_is_nobodys():
     new_conv = [rng.normal(size=(2, 3, 96)).astype(np.float32)
                 for _ in range(2)]
     stepped = state.write(state.at_slots(cache, [2, 0]), new_ssm, new_conv)
-    kept, held, zero = state.take_counts(stepped)
+    kept, held, zero, _ = state.take_counts(stepped)
     assert kept.slots is None and kept.held is None and int(held) == 0
     assert zero is None          # only a router with zero-compute outputs
     for layer in range(2):

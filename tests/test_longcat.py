@@ -268,7 +268,8 @@ def test_the_four_shares_and_the_zero_term_once_add_up_to_the_uncut_layer(
 def test_dense_over_held_and_grouped_forms_agree(held, rank):
     """Widths the kernel can tile (interpreted): a choice of an absent or a
     zero-compute expert is dropped ahead of the group layout, none of a held
-    one's is, and the zero term stands beside either form."""
+    one's is, and the zero term stands beside either form -- and beside the
+    form of few rows, which reads the experts a row chose and counts them."""
     cfg = dataclasses.replace(CFG, d_model=128, moe_d_ff=128)
     params = mla.init_params(cfg, jax.random.key(2), dtype=jnp.float32)
     if held:
@@ -282,6 +283,14 @@ def test_dense_over_held_and_grouped_forms_agree(held, rank):
     assert (np.asarray(chose) == np.asarray(chose_g)).all()
     assert counts.tolist() == counts_g.tolist()
     assert 0 < counts[1] < chose.size
+    few, chose_f, counts_f = mla._ffn(
+        dataclasses.replace(cfg, moe_impl="chosen_interpret"), lp, h)
+    np.testing.assert_allclose(np.asarray(few), np.asarray(dense), **TOL)
+    assert (np.asarray(chose) == np.asarray(chose_f)).all()
+    first, count = cfg.held_experts
+    local = np.asarray(chose) - first
+    assert counts_f.tolist() == [*counts.tolist(), len(
+        set(local[(local >= 0) & (local < count)].tolist()))]
 
 
 # ---------- prefill, pages, decode ----------
@@ -306,7 +315,7 @@ def test_prefill_then_paged_decode_equals_the_full_forward(kernel):
                                    np.asarray(logits[:, t]), **TOL)
         assert (np.sort(np.asarray(chose), -1)
                 == np.sort(whole[:, :, t], -1)).all()
-        cache, held, zero = state.take_counts(cache)
+        cache, held, zero, _ = state.take_counts(cache)
         chose = np.asarray(chose)
         assert int(held) == ((chose >= first) & (chose < first + count)).sum()
         assert int(zero) == (chose >= cfg.n_experts).sum()

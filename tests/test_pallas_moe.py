@@ -341,3 +341,77 @@ def test_every_choice_absent_keeps_the_kernels_block_indices_in_the_buffer(
         assert 1 <= live <= tiles
         assert ((0 <= tile_expert) & (tile_expert < 2)).all()
     assert [live for _, live, _ in seen] == [1, 1, 3, 3, 6, 6]
+
+
+# ---------- few rows: dense over the held experts that a row chose ----------
+
+def _chosen_case(routing, T, count=8, routed_over=256, k=3, seed=9):
+    """(idx [T, k] over ``routed_over`` outputs, real [T]) of a seeded
+    routing that leaves held experts (``first`` 4, ``count`` of them)
+    without a row."""
+    first = 4
+    rng = np.random.default_rng(seed + T)
+    real = np.ones((T,), bool)
+    if routing == "some_empty":       # even routing: some held experts idle
+        idx = np.argsort(rng.random((T, routed_over)), axis=1)[:, :k]
+    elif routing == "all_but_one":    # one held expert takes every row's pick
+        idx = np.stack([np.full((T,), first + 2), np.zeros((T,), int),
+                        np.full((T,), routed_over - 1)], axis=1)
+    elif routing == "none":           # nobody chose an expert held here
+        idx = np.stack([np.arange(T) % first, np.full((T,), 9 + count),
+                        np.full((T,), 12 + count)], axis=1)
+    elif routing == "padding_lane":   # the last row alone names expert 5 ...
+        idx = np.stack([np.full((T,), first), np.zeros((T,), int),
+                        np.full((T,), routed_over - 1)], axis=1)
+        idx[-1, 1] = first + 1
+        real[-1] = False              # ... and it is nobody's
+    return jnp.asarray(idx, jnp.int32), real, first
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("T", [2, 32, 64])
+@pytest.mark.parametrize("routing", ["some_empty", "all_but_one", "none",
+                                     "padding_lane"])
+def test_chosen_experts_match_dense_and_read_no_expert_nobody_chose(
+        routing, T, gated, monkeypatch):
+    """The form of few rows against the plain sum over (token, held expert):
+    the same result at any routing, no token dropped, and only the experts a
+    row of somebody's chose reach the kernel -- at least one tile, so that no
+    block index is -1 (the chip halts on it; the interpreter does not)."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_moe
+
+    count = 8
+    lp, _ = _mk(E=count, D=128, F=128, seed=3)
+    idx, real, first = _chosen_case(routing, T, count)
+    keys = jax.random.split(jax.random.key(T), 2)
+    x = jax.random.normal(keys[0], (T, 128), jnp.float32)
+    gates = jax.random.uniform(keys[1], idx.shape, jnp.float32, 0.2, 1.0)
+    here = (idx >= first) & (idx < first + count)
+    local = jnp.where(here & jnp.asarray(real)[:, None], idx - first, -1)
+
+    seen = []
+    kernel = pallas_moe._grouped_matmul
+
+    def watched(lhs, rhs, layer, tile_expert, n_live, **kw):
+        seen.append((int(n_live[0]), np.asarray(tile_expert)))
+        return kernel(lhs, rhs, layer, tile_expert, n_live, **kw)
+
+    monkeypatch.setattr(pallas_moe, "_grouped_matmul", watched)
+    got, read = pallas_moe.chosen_experts(lp, x, local, gates, count,
+                                          gated=gated, interpret=True)
+    want = _plain_experts(lp, x, idx, gates, first, count, gated)
+    want[~real] = 0.0                 # a row that is nobody's gets nothing
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-5, rtol=3e-5)
+
+    chosen = sorted(set(np.asarray(local).ravel().tolist()) - {-1})
+    assert len(chosen) == {"all_but_one": 1, "none": 0,
+                           "padding_lane": 1}.get(routing, len(chosen))
+    assert len(chosen) < count        # every case leaves experts unread
+    assert int(read) == max(len(chosen), 1)
+    assert len(seen) == 2             # up (and gate), then down
+    for live, tile_expert in seen:
+        assert live == int(read) and 1 <= live <= count
+        assert tile_expert[:len(chosen)].tolist() == chosen
+        assert ((0 <= tile_expert) & (tile_expert < count)).all()
+    if routing == "none":
+        assert not np.asarray(got).any()

@@ -189,7 +189,7 @@ def test_prefill_then_decode_through_state_and_pages_equals_the_full_forward():
             params, CFG, tokens[:, t], jnp.full((2,), t, jnp.int32),
             state.at_slots(cache, [0, 1]), None, TABLES)
         assert none is None
-        cache, held, _ = state.take_counts(cache)
+        cache, held, *_ = state.take_counts(cache)
         assert int(held) == 2 * 2 * CFG.experts_per_token   # every expert held
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(logits[:, t]), **TOL)
@@ -249,7 +249,7 @@ def test_a_state_pool_lies_beside_unsharded_kv_pages_only():
         pages.alloc(geom, sharding=pages.page_sharding(mesh))
     # A plain pool passes through the two seams untouched.
     k, v = pages.alloc(dataclasses.replace(geom, state=None, counted=False))
-    assert state.at_slots(k, [0]) is k and state.take_counts(k) == (k, None, None)
+    assert state.at_slots(k, [0]) is k and state.take_counts(k) == (k, None, None, None)
 
 
 def test_models_hybrid_knows_no_pool_layout():
@@ -289,7 +289,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference(layer):
     parts, held_pairs = [], 0
     for rank in range(4):
         cfg, moe = _share(params, CFG, rank, 4)
-        y, chose, held = hybrid.latent_moe(cfg, moe, layer, h)
+        y, chose, held, _ = hybrid.latent_moe(cfg, moe, layer, h)
         parts.append(y - shared)
         held_pairs += int(held)
         assert chose.shape == (N_TOKENS, 4)           # routed over all 16
@@ -322,12 +322,20 @@ def test_grouped_and_dense_forms_agree_on_a_held_range(rank):
     params = hybrid.init_params(cfg, jax.random.key(2), dtype=jnp.float32)
     h = _layer_input()
     cfg, moe = _share(params, cfg, rank, 4)
-    dense, chose, held = hybrid.latent_moe(cfg, moe, 1, h)
-    grouped, chose_g, held_g = hybrid.latent_moe(
+    dense, chose, held, read = hybrid.latent_moe(cfg, moe, 1, h)
+    grouped, chose_g, held_g, read_g = hybrid.latent_moe(
         dataclasses.replace(cfg, moe_impl="grouped_interpret"), moe, 1, h)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense), **TOL)
     assert (np.asarray(chose) == np.asarray(chose_g)).all()
     assert 0 < int(held) == int(held_g) < chose.size
+    assert read is read_g is None
+    # The form of few rows: the same again, and the experts it read counted.
+    few, chose_f, held_f, read_f = hybrid.latent_moe(
+        dataclasses.replace(cfg, moe_impl="chosen_interpret"), moe, 1, h)
+    np.testing.assert_allclose(np.asarray(few), np.asarray(dense), **TOL)
+    local = np.asarray(chose_f) - cfg.held_experts[0]
+    assert (int(held_f), int(read_f)) == (int(held), len(set(
+        local[(local >= 0) & (local < cfg.held_experts[1])].tolist())))
     # A whole model in the grouped form, every expert held.
     tokens = _fixture()[1][:1]
     cfg = dataclasses.replace(CFG, moe_latent_dim=128, moe_d_ff=128)
