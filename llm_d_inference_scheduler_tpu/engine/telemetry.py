@@ -63,6 +63,7 @@ STALL_WHERE = ("device_wait", "host")
 # their attribute of EngineTelemetry: a new family declares its own below
 # and names them here and in models/binding.py Bound.program_counts.
 PROGRAM_COUNTERS = ("moe_ffn_tokens", "mla_attention_tokens",
+                    "mla_window_attention_tokens",
                     "dsa_query_tokens", "dsa_rows", "ssm_tokens",
                     "ssm_state_updates", "ssm_slot_prefills", "swa_rows")
 
@@ -197,6 +198,16 @@ class EngineTelemetry:
             "absorbed, a prefill or a prefix-continuation window expanded); "
             "counted on the host at dispatch, a token once a program and not "
             "once an attention sublayer; empty without a latent pool",
+            ("form",), registry=self.registry)
+        self.mla_window_attention_tokens = Counter(
+            "jetstream:mla_window_attention_tokens_total",
+            "Rows (padded tokens) of the prefill and prefix-continuation "
+            "programs dispatched through latent attention's expanded form, "
+            "by how the program keeps its scores (models/binding.bind, "
+            "ModelConfig.expanded_impl): `kernel` a tile of queries against "
+            "a tile of rows in VMEM (ops/pallas_dsa.py), `xla` whole in "
+            "memory, [heads, queries, rows] in f32; counted on the host at "
+            "dispatch, a token once a program; empty without a latent pool",
             ("form",), registry=self.registry)
         self.dsa_query_tokens = Counter(
             "jetstream:dsa_query_tokens_total",

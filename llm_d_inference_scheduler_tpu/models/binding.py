@@ -71,7 +71,7 @@ class Bound:
     """A model as one engine serves it (:func:`bind`)."""
 
     module: Any             # family(mcfg)
-    mcfg: ModelConfig       # ssm_impl and index_impl resolved, the FFN dense
+    mcfg: ModelConfig       # the kernels' forms resolved, the FFN dense
     grouped: ModelConfig    # the same with the MoE FFN's grouped form
     chosen: ModelConfig     # the same, dense over the experts a row chose
     platform: str
@@ -143,6 +143,10 @@ class Bound:
             # expanded.
             counts.append(("mla_attention_tokens",
                            "absorbed" if decode else "expanded", tokens))
+            if not decode:
+                # By the form the expanded attention traced with.
+                counts.append(("mla_window_attention_tokens",
+                               m.expanded_impl.split("_")[0], tokens))
             if m.index_topk and queries is not None:
                 got = selection_counts(*queries, m.index_topk)
                 counts += [("dsa_query_tokens", "selected", got["selected"]),
@@ -173,6 +177,11 @@ class Bound:
             # A block that selects the rows it attends to (0: none).
             "index_topk": m.index_topk,
             "index_scores": m.index_impl if m.index_topk else None,
+            # How a latent block's prefill and continuation windows attend:
+            # the scores a tile at a time in VMEM, or whole (None: no latent
+            # attention).
+            "expanded_attention": (m.expanded_impl if m.kv_lora_rank
+                                   else None),
             # The experts this chip holds of those its router scores, and
             # the router's outputs that compute nothing.
             "experts_first": m.held_experts[0],
@@ -200,10 +209,13 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     where it runs its kernels through the interpreter (tests on the CPU),
     ``sharded`` where its weights or pools span devices. How a decode step
     fetches its slots' recurrent states (``ssm_impl``) is
-    ``pallas_ssm.use_kernel``'s; a selecting block's indexer (``index_impl``)
-    runs its kernel (ops/pallas_dsa.py) on a TPU, where the per-head products
-    must not reach HBM, and the plain form on the CPU, and so do the layers that
-    attend to a window (``swa_impl``); the MoE FFN's form is
+    ``pallas_ssm.use_kernel``'s; the three forms of ops/pallas_dsa.py's
+    kernels follow one rule, the kernel on a TPU, where a [heads, queries,
+    rows] product must not reach HBM, and the plain form on the CPU: every
+    latent block's expanded attention (``expanded_impl``: a prefill, a window
+    that continues a cached prefix, whether or not the block selects), a
+    selecting block's indexer (``index_impl``), and the layers that attend to
+    a window (``swa_impl``); the MoE FFN's form is
     chosen per program (``Bound.model_for``). ``forced`` names form fields a
     caller sets over the rules (a comparison of two forms on one device)."""
     forms: dict[str, str] = {}
@@ -213,15 +225,17 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
             sharded=sharded, interpret=interpret)
         forms["ssm_impl"] = ("gathered" if not kernel else
                              "kernel_interpret" if interpret else "kernel")
+    tiled = ("kernel_interpret" if interpret else
+             "kernel" if platform == "tpu" else "xla")
+    if mcfg.kv_lora_rank:
+        forms["expanded_impl"] = tiled
     if mcfg.index_topk:
-        forms["index_impl"] = ("kernel_interpret" if interpret else
-                               "kernel" if platform == "tpu" else "xla")
+        forms["index_impl"] = tiled
     if mcfg.window_attn:
         # The window layers' two kernels (ops/pallas_latent_attention.py's
         # walk over the window's pages, ops/pallas_dsa.py's tiles under the
         # band) on a TPU, the plain forms on the CPU.
-        forms["swa_impl"] = ("kernel_interpret" if interpret else
-                             "kernel" if platform == "tpu" else "xla")
+        forms["swa_impl"] = tiled
     mcfg = dataclasses.replace(mcfg, **{**forms, **(forced or {})})
     suffix = "_interpret" if interpret else ""
     return Bound(

@@ -151,12 +151,19 @@ class ModelConfig:
     index_topk: int = 0
     index_n_heads: int = 0
     index_head_dim: int = 0
-    # The form of such a block's programs: "xla" (ops/sparse_attention.py:
+    # The form of such a block's indexer: "xla" (ops/sparse_attention.py:
     # the CPU's way and the plain form) | "kernel" (ops/pallas_dsa.py: the
-    # indexer's scores and a window's attention over the selected rows) |
-    # "kernel_interpret" (CPU tests). models.bind sets it from what the
-    # engine is.
+    # indexer's scores over a window's keys and over a lane's key pages, and
+    # decode attention with the selection as a mask) | "kernel_interpret"
+    # (CPU tests). models.bind sets it from what the engine is.
     index_impl: str = "xla"
+    # The form of every latent block's expanded attention (a prefill, a
+    # window that continues a cached prefix; models/mla.py), selecting or
+    # not: "xla" (the scores whole, [heads, queries, rows] in f32: the CPU's
+    # way and the plain form) | "kernel" (ops/pallas_dsa.py: a tile of
+    # queries against a tile of rows, the softmax running in VMEM) |
+    # "kernel_interpret". models.bind sets it.
+    expanded_impl: str = "xla"
     # A gate a head on the attention's output, ahead of W_o: ``o_j *=
     # sigmoid(h W_g)_j`` from the layer's normed input (models/mla.py).
     attn_gate: bool = False
@@ -168,7 +175,9 @@ class ModelConfig:
     # its own (kvcache/pages.py).
     window_attn: AttnKind | None = None
     # The form of the window layers' programs: "xla" | "kernel"
-    # (ops/pallas_swa.py) | "kernel_interpret". models.bind sets it.
+    # (ops/pallas_latent_attention.py's walk over the window's pages,
+    # ops/pallas_dsa.py's tiles under the band) | "kernel_interpret".
+    # models.bind sets it.
     swa_impl: str = "xla"
 
     @property

@@ -23,7 +23,12 @@ split a head into ``[W_uk | W_uv]``:
   row is carried out to a head's ``k_nope = c W_uk`` and ``v = c W_uv``;
   scores ``(q_nope . k_nope + q_r . k_r) / sqrt(d_nope + d_rope)``. Costs
   rows x heads x (d_nope + d_v) products once a window, which a window's
-  hundreds of queries share.
+  hundreds of queries share. Its scores are [heads, queries, rows]: on a TPU
+  they are worked a tile of (queries, rows) at a time with a running softmax
+  and never written whole (ops/pallas_dsa.py; ``cfg.expanded_impl``, set by
+  ``models.bind`` for every configuration of this module, selecting or not),
+  on the CPU they are one f32 tensor (0.6 GB at 16 heads, a 1,024-token
+  window and 8k cached rows).
 - *absorbed* (decode): the query is carried in instead, ``q_lat = q_nope
   W_uk^T``; scores ``(q_lat . c + q_r . k_r)`` under the same scale, ``o_lat =
   sum p c``, and ``o_lat W_uv`` afterwards. Nothing is expanded a cached row:
@@ -87,9 +92,10 @@ j] relu(q^I[t, j] . k^I[s])`` in f32, and the query attends to the
 lower position: the attention above with every other row at minus infinity.
 Both forms take the set as a mask over the rows they read (ops/
 sparse_attention.py, ops/pallas_dsa.py): the absorbed form walks the lane's
-pages and masks; the expanded form works a tile of (queries, rows) at a time
-with a running softmax, so that no [heads, S, T] tensor is ever whole (8.6 GB
-at 128 heads, a 1,024-token window and 16k rows). A program whose rows cannot outnumber index_topk (a first
+pages and masks; the expanded form hands its tiles the set in place of the
+causal mask (whole, its scores would be 8.6 GB at 128 heads, a 1,024-token
+window and 16k rows), and skips a tile nothing of which was selected. The
+indexer's own kernels follow ``cfg.index_impl``. A program whose rows cannot outnumber index_topk (a first
 window of 1,024 under index_topk 2,048) computes the keys and caches them,
 and neither scores nor selects: it IS dense latent attention. YaRN
 (``cfg.rope_yarn``) stretches the rotary frequencies and scales the softmax
@@ -472,21 +478,28 @@ def _scale(cfg: ModelConfig) -> float:
 
 def expanded_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, rows,
                        mask, *, impl: str | None = None,
-                       name: str = "dsa_window_attention") -> jnp.ndarray:
+                       name: str | None = None) -> jnp.ndarray:
     """Queries [B, S, H, .] against cache rows [B, T, r + dr], every row
     carried out to its keys and values; ``mask`` [B, S, T] says which rows a
     query sees. Returns [B, S, H * dv]. Products in the operands' dtype with
-    f32 accumulation, the softmax in f32. Where the block's programs run
-    their kernels (``cfg.index_impl``: a block that selects, on a TPU) the
-    scores stay in VMEM a tile at a time (ops/pallas_dsa.py): whole, at 128
-    heads, they would be 8.6 GB for a window over 16k rows. ``impl`` and
-    ``name`` are the window layers' to pass: their own form
-    (``cfg.swa_impl``) and the kernel's name in a device trace."""
+    f32 accumulation, the softmax in f32. Where the engine runs its kernels
+    (``cfg.expanded_impl``: on a TPU, whether or not the block selects) the
+    scores stay in VMEM a tile at a time (ops/pallas_dsa.py), and a tile no
+    query of which sees a row (above the diagonal, past the prefix in its
+    bucket, outside the selection) is skipped: whole they would be 0.6 GB at
+    16 heads for a window over 8k rows, 8.6 GB at 128 heads over 16k. The
+    kernel is ``mla_window_attention`` in a device trace, and
+    ``dsa_window_attention`` for a block that selects. ``impl`` and ``name``
+    are the window layers' to pass: their own form (``cfg.swa_impl``) and
+    their name."""
     B, S, H, _ = q_nope.shape
     r = cfg.kv_lora_rank
     w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
     c, k_rope = rows[..., :r], rows[..., r:cfg.latent_dim]
-    impl = cfg.index_impl if impl is None else impl
+    impl = cfg.expanded_impl if impl is None else impl
+    if name is None:
+        name = ("dsa_window_attention" if cfg.index_topk
+                else "mla_window_attention")
     if impl.startswith("kernel"):
         out = pallas_dsa.masked_window_attention_pallas(
             jnp.swapaxes(q_nope, 1, 2), jnp.swapaxes(q_rope, 1, 2),

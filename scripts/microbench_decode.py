@@ -48,6 +48,15 @@ a page, the GB/s of the bytes the call needs, the least time by
 chipbench/kernels_dsa.py / kernels_mla.py, and how far the kernel lies from
 its plain form on that table. It is what RUN_PAGES was chosen from.
 
+``--window`` times the latent family's expanded attention alone instead
+(models/mla.expanded_attention: a 1,024-token window's queries against a
+prior table's bucket of cached rows and its own, keys and values carried out
+a head) at a benchmark configuration's widths over prior buckets of
+``--window-priors`` blocks, in both forms: the scores whole in memory (the
+CPU's form) and a tile at a time in VMEM (ops/pallas_dsa.py, the form a TPU
+engine binds): ms a call (one layer), the bytes of scores the form puts into
+memory, and how far the tiled form lies from the whole one.
+
 Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--points 16x1000,16x300,8x300]
        python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
@@ -60,6 +69,9 @@ Usage: python scripts/microbench_decode.py [--model qwen3-4b]
        python scripts/microbench_decode.py --latent
            [--latent-config deepseek-v3.2-exp-cut] [--latent-points 32x9700]
            [--latent-groups 4,8,16] [--latent-tables shuffled,churn,runs]
+       python scripts/microbench_decode.py --window
+           [--window-config kimi-vl-a3b-cut] [--window-priors 0,64,128,256,512]
+           [--window-live 1.0]
 """
 
 from __future__ import annotations
@@ -730,6 +742,68 @@ def latent_main(args):
         pallas_latent_attention.RUN_PAGES = served_with
 
 
+def window_main(args):
+    """The latent family's expanded attention at ``--window-config``'s
+    widths, whole and tiled, over prior buckets."""
+    import dataclasses
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.models import mla
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           args.window_config + ".json")) as f:
+        m = config_from_hf(types.SimpleNamespace(**json.load(f)),
+                           name=args.window_config)
+    tiled = "kernel_interpret" if args.window_interpret else "kernel"
+    dt = jnp.dtype(m.dtype)
+    S, H, block = args.window_tokens, m.n_heads, m.kv_block_size
+    keys = jax.random.split(jax.random.key(0), 4)
+    lp = {"wkvb": (jax.random.normal(
+        keys[0], (m.kv_lora_rank, H * (m.qk_nope_head_dim + m.v_head_dim)))
+        * m.kv_lora_rank ** -0.5).astype(dt)}
+    q_nope = jax.random.normal(keys[1], (1, S, H, m.qk_nope_head_dim), dt)
+    q_rope = jax.random.normal(keys[2], (1, S, H, m.qk_rope_head_dim), dt)
+    for prior in map(int, args.window_priors.split(",")):
+        # models/mla.prefill_with_prefix's mask: the prior bucket's rows up
+        # to the prefix, then the window's own under the diagonal (a first
+        # window, ``forward``: its own alone).
+        T = prior * block
+        prefix = int(T * args.window_live)
+        pos = prefix + np.arange(S)[None]
+        kv_pos = np.concatenate([np.arange(T)[None], pos], axis=1)
+        valid = np.concatenate([np.arange(T)[None] < prefix,
+                                np.ones((1, S), bool)], axis=1)
+        mask = jnp.asarray((pos[:, :, None] >= kv_pos[:, None, :])
+                           & valid[:, None])
+        rows = jax.random.normal(keys[3], (1, T + S, m.latent_dim), dt)
+        got = {}
+        for form in ("xla", tiled):
+            fn = jax.jit(functools.partial(
+                mla.expanded_attention,
+                dataclasses.replace(m, expanded_impl=form)))
+            ms = timeit(fn, lp, q_nope, q_rope, rows, mask,
+                        iters=args.window_iters)
+            got[form] = np.asarray(fn(lp, q_nope, q_rope, rows, mask),
+                                   np.float32)
+            print(json.dumps({
+                "component": "mla_window_attention", "form": form,
+                "heads": H, "window": S, "prior_blocks": prior,
+                "rows": T + S, "live_rows": prefix + S,
+                "ms_per_call": round(ms, 3),
+                # What the form writes to memory of the scores: [H, S, rows]
+                # in f32 whole, nothing where a tile stays in VMEM.
+                "score_bytes": H * S * (T + S) * 4 if form == "xla" else 0,
+                "max_err_vs_plain": (None if form == "xla" else float(
+                    np.abs(got[form] - got["xla"]).max())),
+            }), flush=True)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen3-4b")
@@ -789,6 +863,21 @@ def main(argv=None):
     ap.add_argument("--latent-interpret", action="store_true",
                     help="interpret the kernels: rehearses the control flow "
                          "on the CPU; its times mean nothing")
+    ap.add_argument("--window", action="store_true",
+                    help="time the latent family's expanded attention alone "
+                         "instead, whole and tiled, over prior buckets")
+    ap.add_argument("--window-config", default="kimi-vl-a3b-cut",
+                    help="a file of chipbench/configs: the widths")
+    ap.add_argument("--window-tokens", type=int, default=1024)
+    ap.add_argument("--window-priors", default="0,64,128,256,512",
+                    help="prior table buckets in blocks, comma-separated")
+    ap.add_argument("--window-live", type=float, default=1.0,
+                    help="share of a prior bucket's rows that the prefix "
+                         "fills (the rest is the bucket's padding)")
+    ap.add_argument("--window-iters", type=int, default=20)
+    ap.add_argument("--window-interpret", action="store_true",
+                    help="interpret the kernel: rehearses the control flow "
+                         "on the CPU; its times mean nothing")
     ap.add_argument("--points", default="16x1000,16x300,8x300",
                     help="lanes x context tokens a lane, comma-separated")
     ap.add_argument("--max-model-len", type=int, default=1024)
@@ -811,6 +900,8 @@ def main(argv=None):
         return ssm_main(args)
     if args.latent:
         return latent_main(args)
+    if args.window:
+        return window_main(args)
 
     from llm_d_inference_scheduler_tpu.engine.sampling import sample_tokens
     from llm_d_inference_scheduler_tpu.kvcache import pages

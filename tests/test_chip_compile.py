@@ -155,6 +155,78 @@ def test_latent_decode_step_compiles_and_copies_no_pool(one_chip):
             < dt.itemsize * math.prod(geom.shape[1:]))
 
 
+def test_no_window_of_a_latent_block_holds_its_scores_whole(one_chip):
+    """A 1,024-token window of Kimi-VL-A3B's block over a prior table of 512
+    blocks (8,192 rows), as an engine on a TPU binds it: the scores would be
+    f32[1,16,1024,9216], 604 MB written and read three times a layer (4.3
+    ms a layer on the chip; the windows' scores were 13.7% of longdoc-batch's
+    busy time; builder's trace, PR 47). They stay in
+    VMEM a tile at a time (ops/pallas_dsa.py under the name
+    ``mla_window_attention``): the compiled program names no array of 16 x
+    1,024 x 9,216 elements, and its temporaries are the rows' keys and values
+    for all heads (2 x 9,216 x 16 x 128 bf16 = 75 MB), the experts' grouped
+    rows and little else."""
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
+    from llm_d_inference_scheduler_tpu.models import bind, mla
+    from llm_d_inference_scheduler_tpu.models.configs import KIMI_VL_A3B
+
+    S, prior = 1024, 512
+    bound = bind(dataclasses.replace(KIMI_VL_A3B, n_layers=2), platform="tpu")
+    m = bound.model_for(S)
+    assert (m.expanded_impl, m.index_impl, m.moe_impl) == (
+        "kernel", "xla", "grouped")
+    geom = PageGeometry.for_engine(m, 32, 8192)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    one = _sds(one_chip, (1,), jnp.int32)
+    compiled = jax.jit(lambda p, *a: mla.prefill_with_prefix(p, m, *a),
+                       donate_argnums=(4,)).lower(
+        params, _sds(one_chip, (1, S), jnp.int32), one, one,
+        _sds(one_chip, geom.shape, jnp.dtype(m.dtype)), None,
+        _sds(one_chip, (1, geom.max_blocks_per_seq), jnp.int32),
+        _sds(one_chip, (1, prior), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "mla_window_attention" in hlo and "dsa_window_attention" not in hlo
+    rows = prior * geom.block + S
+    # (The pool, the head and the experts' weights are larger: no rows' axis.)
+    too_large = {t: n for t, n in _array_elements(hlo).items()
+                 if n >= m.n_heads * S * rows and str(rows) in t}
+    assert not too_large, too_large
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
+
+
+def test_a_padded_batch_of_the_double_layers_prefill_takes_the_tiles(one_chip):
+    """LongCat-Flash's double layer at the cell's widths (64 heads), a batch
+    of two prompts in the 512 bucket under a padding mask: both sublayers'
+    attention through the tiled kernel (16 grid steps of four heads a
+    sequence), no f32[2,64,512,512] of scores."""
+    import json
+    import types
+
+    from llm_d_inference_scheduler_tpu.models import bind, mla
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "longcat-flash-omni-cut.json")) as f:
+        published = json.load(f)
+    B, S = 2, 512
+    m = bind(config_from_hf(types.SimpleNamespace(
+        **{**published, "num_layers": 1})), platform="tpu").model_for(B * S)
+    assert (m.n_heads, m.expanded_impl) == (64, "kernel")
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: mla.init_params(m, k), jax.random.key(0)))
+    compiled = jax.jit(lambda p, t, v: mla.forward(
+        p, m, t, want_kv=True, kv_valid=v)).lower(
+        params, _sds(one_chip, (B, S), jnp.int32),
+        _sds(one_chip, (B, S), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("mla_window_attention") >= 2
+    assert not re.search(rf"\[{B},{m.n_heads},{S},{S}\]", hlo)
+
+
 def test_double_layer_decode_step_compiles_and_copies_no_weight(one_chip):
     """One decode step of LongCat-Flash's double layer at the cell's widths
     (`chipbench/configs/longcat-flash-omni-cut.json`, two layers of its four),
@@ -440,7 +512,7 @@ def _dsa_cut(n_layers=2):
     doc["num_hidden_layers"] = n_layers
     return dataclasses.replace(
         config_from_hf(types.SimpleNamespace(**doc), name="dsa-cut"),
-        index_impl="kernel")
+        index_impl="kernel", expanded_impl="kernel")
 
 
 def _array_elements(hlo):

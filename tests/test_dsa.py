@@ -157,7 +157,8 @@ def test_windows_then_decode_through_both_pools(kernels):
     plain forms and with both kernels interpreted."""
     cfg, params, tokens, logits, fresh, _, picked = _fixture()
     if kernels:
-        cfg = dataclasses.replace(cfg, index_impl="kernel_interpret")
+        cfg = dataclasses.replace(cfg, index_impl="kernel_interpret",
+                                  expanded_impl="kernel_interpret")
     cache = _cache_with(fresh, 16)
     step = jax.jit(functools.partial(mla.prefill_with_prefix, cfg=cfg,
                                      want_routes=True))

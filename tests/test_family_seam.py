@@ -25,7 +25,8 @@ PKG = pathlib.Path(__file__).resolve().parent.parent / \
 
 # ---------- the engine names no family ----------
 
-_FIELD_PREFIXES = ("ssm_", "index_", "moe_", "kv_lora", "mla_", "dsa_")
+_FIELD_PREFIXES = ("ssm_", "index_", "moe_", "kv_lora", "mla_", "dsa_",
+                   "expanded_")
 _FIELDS = {"n_experts", "n_zero_experts", "held_experts"}
 _COUNTERS = ("jetstream:moe_", "jetstream:mla_", "jetstream:ssm_",
              "jetstream:dsa_")
@@ -102,12 +103,29 @@ WIDE_SSM = dict(ssm_state=128, ssm_head_dim=8)    # whole (8, 128) tiles
     # models/llama.py: nothing to resolve, the MoE twin alone.
     ("tiny-moe", llama, {}, dict(platform="cpu"), {}),
     ("tiny-moe", llama, {}, dict(platform="tpu"), {}),
-    # models/mla.py: a selecting block's indexer.
-    ("tiny-mla", mla, {}, dict(platform="tpu"), dict(index_impl="xla")),
-    ("tiny-dsa", mla, {}, dict(platform="cpu"), dict(index_impl="xla")),
-    ("tiny-dsa", mla, {}, dict(platform="tpu"), dict(index_impl="kernel")),
+    # models/mla.py: every latent block's expanded attention, and a
+    # selecting block's indexer beside it (``index_impl`` stays "xla" where
+    # nothing selects).
+    ("tiny-mla", mla, {}, dict(platform="cpu"),
+     dict(expanded_impl="xla", index_impl="xla")),
+    ("tiny-mla", mla, {}, dict(platform="tpu"),
+     dict(expanded_impl="kernel", index_impl="xla")),
+    ("tiny-mla", mla, {}, dict(platform="cpu", interpret=True),
+     dict(expanded_impl="kernel_interpret", index_impl="xla")),
+    ("tiny-longcat", mla, {}, dict(platform="cpu"),
+     dict(expanded_impl="xla", index_impl="xla")),
+    ("tiny-longcat", mla, {}, dict(platform="tpu"),
+     dict(expanded_impl="kernel", index_impl="xla")),
+    ("tiny-dsa", mla, {}, dict(platform="cpu"),
+     dict(expanded_impl="xla", index_impl="xla")),
+    ("tiny-dsa", mla, {}, dict(platform="tpu"),
+     dict(expanded_impl="kernel", index_impl="kernel")),
     ("tiny-dsa", mla, {}, dict(platform="cpu", interpret=True),
-     dict(index_impl="kernel_interpret")),
+     dict(expanded_impl="kernel_interpret", index_impl="kernel_interpret")),
+    ("tiny-swa", mla, {}, dict(platform="cpu"),
+     dict(expanded_impl="xla", index_impl="xla", swa_impl="xla")),
+    ("tiny-swa", mla, {}, dict(platform="tpu"),
+     dict(expanded_impl="kernel", index_impl="kernel", swa_impl="kernel")),
     # models/hybrid.py: the state update (tiny-hybrid's state is 16 wide).
     ("tiny-hybrid", hybrid, {}, dict(platform="tpu"),
      dict(ssm_impl="gathered")),
@@ -135,6 +153,49 @@ def test_bind_resolves_a_familys_forms(name, module, widen, kw, forms):
         else "chosen")
     with pytest.raises(dataclasses.FrozenInstanceError):
         bound.mcfg = cfg
+
+
+@pytest.mark.parametrize("name, kernels", [
+    ("tiny-dsa", ["dsa_index_scores_window", "dsa_window_attention"]),
+    ("tiny-swa", ["dsa_index_scores_window", "dsa_window_attention",
+                  "swa_window_attention"]),
+])
+def test_the_blocks_that_had_the_kernel_trace_the_programs_they_had(
+        name, kernels, monkeypatch):
+    """PR 47 gave every latent block's expanded attention a form of its own
+    (``expanded_impl``); the blocks that select took the kernel by
+    ``index_impl`` before, under the name ``dsa_window_attention``. Bound
+    for a TPU, their continuation window traces to the program that rule
+    gave, kernel for kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = bind(configs.get_config(name), platform="tpu").mcfg
+    geom = pages.PageGeometry.for_engine(cfg, 2, 256)
+    cache, _ = pages.alloc(geom)
+    row = jnp.zeros((1, geom.max_blocks_per_seq), jnp.int32)
+    cache = state.at_slots(cache, [0], *([row] if cfg.window_attn else []))
+    params = jax.eval_shape(lambda k: mla.init_params(cfg, k),
+                            jax.random.key(0))
+    S, prior = 32, 128 // geom.block
+    one = jnp.ones((1,), jnp.int32)
+
+    def traced():
+        return str(jax.make_jaxpr(
+            lambda p, c: mla.prefill_with_prefix(
+                p, cfg, jnp.zeros((1, S), jnp.int32), one * S, one * 128, c,
+                None, row, row[:, :prior]))(params, cache))
+
+    now = traced()
+    for kernel in kernels:
+        assert f"name={kernel}" in now, kernel
+    assert "mla_window_attention" not in now
+    was = mla.expanded_attention
+    monkeypatch.setattr(
+        mla, "expanded_attention",
+        lambda c, *a, impl=None, name="dsa_window_attention": was(
+            c, *a, impl=c.index_impl if impl is None else impl, name=name))
+    assert traced() == now
 
 
 def test_bind_model_for_is_the_moe_rule_shape_by_shape():
@@ -271,7 +332,12 @@ def test_selection_counts_do_not_overflow_int32_positions():
      [("moe_ffn_tokens", "dense", 8), ("mla_attention_tokens", "absorbed", 8)]),
     ("tiny-mla", "prefix_prefill", 64, 1, 1,
      [("moe_ffn_tokens", "dense", 64),
-      ("mla_attention_tokens", "expanded", 64)]),
+      ("mla_attention_tokens", "expanded", 64),
+      ("mla_window_attention_tokens", "xla", 64)]),
+    ("tiny-longcat", "prefill", 2 * 32, 1, 2,
+     [("moe_ffn_tokens", "dense", 64),
+      ("mla_attention_tokens", "expanded", 64),
+      ("mla_window_attention_tokens", "xla", 64)]),
     ("tiny-hybrid", "decode", 4, 2, 2,
      [("moe_ffn_tokens", "dense", 8), ("ssm_tokens", "step", 8),
       ("ssm_state_updates", "gathered", 8 * 2)]),
@@ -304,6 +370,26 @@ def test_program_counts_name_the_form_the_program_traced_with():
                                    **WIDE_SSM), platform="cpu", interpret=True)
     assert ("ssm_state_updates", "kernel", 16) in ssm.program_counts(
         "decode", 4, 2)
+
+
+@pytest.mark.parametrize("name", ["tiny-mla", "tiny-longcat", "tiny-dsa",
+                                  "tiny-swa"])
+@pytest.mark.parametrize("kw, form", [
+    (dict(platform="cpu"), "xla"), (dict(platform="tpu"), "kernel"),
+    (dict(platform="cpu", interpret=True), "kernel")])
+def test_a_latent_engines_windows_are_counted_by_their_form(name, kw, form):
+    """Every latent engine, selecting or not: a prefill's and a continuation
+    window's rows under the bound form's first word, a decode step's not at
+    all; /health says the form whole."""
+    bound = bind(configs.get_config(name), **kw)
+    for kind in ("prefill", "prefix_prefill"):
+        assert ("mla_window_attention_tokens", form, 96) in (
+            bound.program_counts(kind, 96, 1, real=1))
+    assert not [c for c in bound.program_counts("decode", 4, 2, real=4)
+                if c[0] == "mla_window_attention_tokens"]
+    assert bound.describe()["expanded_attention"] == (
+        bound.mcfg.expanded_impl)
+    assert bound.mcfg.expanded_impl.split("_")[0] == form
 
 
 def test_telemetry_books_a_familys_answer():
@@ -375,12 +461,13 @@ def test_pairs_per_row_come_from_the_bound_value():
 
 # ---------- what a family keeps, in words ----------
 
-# /health's settings as PR 43 served them (chipbench/ reads them).
+# /health's settings as PR 43 served them (chipbench/ reads them), and
+# PR 47's ``expanded_attention``.
 SETTINGS_KEYS = {
     "model", "n_layers", "dtype", "max_batch", "max_model_len", "kv_blocks",
     "kv_layers", "kv_token_bytes", "kv_pool_bytes", "kv_run_pages",
     "index_topk", "index_token_bytes", "index_pool_bytes", "index_scores",
-    "experts_first", "experts_held", "zero_experts",
+    "expanded_attention", "experts_first", "experts_held", "zero_experts",
     "experts_chosen_max_rows", "state_slot_bytes",
     "state_pool_bytes", "state_update", "prefix_caching",
     "off_for_state_layers", "decode_chunk", "pallas_attention", "kv_wire",
@@ -390,18 +477,20 @@ SETTINGS_KEYS = {
 @pytest.mark.parametrize("name, one_chip, want", [
     ("tiny", None, dict(
         kv_run_pages=None, index_topk=0, index_token_bytes=0,
-        index_pool_bytes=0, index_scores=None, experts_first=0,
+        index_pool_bytes=0, index_scores=None, expanded_attention=None,
+        experts_first=0,
         experts_held=0, zero_experts=0, state_slot_bytes=0,
         state_pool_bytes=0, state_update=None, off_for_state_layers=[])),
     ("tiny-mla", "a latent (MLA) page pool", dict(
-        kv_run_pages=8, index_topk=0, index_scores=None, state_update=None)),
+        kv_run_pages=8, index_topk=0, index_scores=None,
+        expanded_attention="xla", state_update=None)),
     ("tiny-longcat", "a latent (MLA) page pool", dict(
         kv_run_pages=8, zero_experts=configs.get_config(
             "tiny-longcat").n_zero_experts)),
     ("tiny-dsa", "a latent (MLA) page pool and its indexer's key pool "
      "beside it, under one block table", dict(
          kv_run_pages=8, index_topk=configs.get_config("tiny-dsa").index_topk,
-         index_scores="xla", state_slot_bytes=0)),
+         index_scores="xla", expanded_attention="xla", state_slot_bytes=0)),
     ("tiny-hybrid", "a recurrent state pool beside its pages", dict(
         kv_run_pages=None, state_update="gathered",
         off_for_state_layers=list(state.OFF_FOR_STATE_LAYERS))),
@@ -413,7 +502,7 @@ def test_settings_are_the_parents_keys_each_family_saying_its_own(
     bound = bind(cfg, platform="cpu")
     assert geom.one_chip_only == one_chip
     said = {**geom.describe(), **bound.describe()}
-    assert len(said) == len(geom.describe()) + len(bound.describe()) == 16
+    assert len(said) == len(geom.describe()) + len(bound.describe()) == 17
     assert said["kv_pool_bytes"] == geom.pool_bytes
     assert set(said) < SETTINGS_KEYS
     assert {k: said[k] for k in want} == want

@@ -173,15 +173,19 @@ def test_prefill_then_paged_decode_equals_the_full_forward(kernel):
                                np.asarray(want[:, 1:]), **TOL)
 
 
-def test_a_prompt_prefilled_in_windows_equals_one_prefilled_whole():
+@pytest.mark.parametrize("form", ["xla", "kernel_interpret"])
+def test_a_prompt_prefilled_in_windows_equals_one_prefilled_whole(form):
+    """With the scores whole, and with the tiled kernel interpreted (the form
+    an engine on a TPU binds, models/binding.py)."""
     params, tokens, logits, rows, _ = _fixture()
+    cfg = dataclasses.replace(CFG, expanded_impl=form)
     pool, tables = _pool_with(rows, 16)          # the first window, plain
     row = tables[:1]
     for lo, n, bucket, prior in [(16, 16, 16, 1), (32, 7, 16, 2)]:
         window = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(
             tokens[0, lo:lo + n])
         got, pool, none = mla.prefill_with_prefix(
-            params, CFG, window, jnp.asarray([n]), jnp.asarray([lo]), pool,
+            params, cfg, window, jnp.asarray([n]), jnp.asarray([lo]), pool,
             None, row, row[:, :prior])
         assert none is None
         np.testing.assert_allclose(np.asarray(got[0]),
@@ -191,6 +195,148 @@ def test_a_prompt_prefilled_in_windows_equals_one_prefilled_whole():
     np.testing.assert_allclose(
         np.asarray(pool[:, blocks]).reshape(3, 48, -1)[:, :39],
         np.asarray(want[:, blocks]).reshape(3, 48, -1)[:, :39], **TOL)
+
+
+def _window_masks(case):
+    """(B, S, T, mask [B, S, T]) as ``forward`` (a prefill: causal, and the
+    padding mask of a batch) and ``prefill_with_prefix`` (the prior bucket's
+    rows up to ``prefix_len``, then the window's own up to ``suffix_len``)
+    build them."""
+    if case in ("first_window", "padded_batch_of_two"):
+        B, S = (1, 40) if case == "first_window" else (2, 48)
+        pos = np.broadcast_to(np.arange(S), (B, S))
+        mask = pos[:, :, None] >= pos[:, None, :]
+        if B == 2:
+            mask = mask & (np.arange(S)[None] < np.array([[48], [19]])
+                           )[:, None, :]
+        return B, S, S, mask
+    S, suffix, T, prefix = {"partly_dead_prior": (40, 33, 8192, 5000),
+                            "one_token_suffix": (16, 1, 256, 200)}[case]
+    pos = prefix + np.arange(S)[None]
+    kv_pos = np.concatenate([np.arange(T)[None], pos], axis=1)
+    valid = np.concatenate([np.arange(T)[None] < prefix,
+                            np.arange(S)[None] < suffix], axis=1)
+    return 1, S, T + S, (pos[:, :, None] >= kv_pos[:, None, :]) & valid[:, None]
+
+
+def _both_forms(heads, case, dtype):
+    """(tiled, whole, mask): models/mla.expanded_attention on one draw of
+    queries, rows and ``W_kvb`` in the form a TPU engine binds (ops/
+    pallas_dsa.py's tiles, interpreted) and in the plain form, as f32."""
+    cfg = dataclasses.replace(CFG, n_heads=heads)
+    B, S, T, mask = _window_masks(case)
+    ks = jax.random.split(jax.random.key(heads + T), 4)
+    lp = {"wkvb": (jax.random.normal(
+        ks[0], (cfg.kv_lora_rank,
+                heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)))
+        * cfg.kv_lora_rank ** -0.5).astype(dtype)}
+    q_nope = jax.random.normal(ks[1], (B, S, heads, cfg.qk_nope_head_dim),
+                               dtype)
+    q_rope = jax.random.normal(ks[2], (B, S, heads, cfg.qk_rope_head_dim),
+                               dtype)
+    rows = jax.random.normal(ks[3], (B, T, cfg.latent_dim), dtype)
+    tiled, whole = (np.asarray(mla.expanded_attention(
+        dataclasses.replace(cfg, expanded_impl=form), lp, q_nope, q_rope,
+        rows, jnp.asarray(mask)), np.float32)
+        for form in ("kernel_interpret", "xla"))
+    assert tiled.shape == (B, S, heads * cfg.v_head_dim)
+    return tiled, whole, mask
+
+
+@pytest.mark.parametrize("case", ["first_window", "partly_dead_prior",
+                                  "padded_batch_of_two", "one_token_suffix"])
+@pytest.mark.parametrize("heads", [16, 64])      # Kimi's, LongCat's
+def test_expanded_attention_tiled_equals_whole_where_nothing_selects(
+        heads, case):
+    """The form a TPU engine binds for every latent block against the plain
+    form, under the masks the two entry points build: no head, row or window
+    is left out, and a prior bucket's dead tiles change nothing."""
+    got, want, mask = _both_forms(heads, case, jnp.float32)
+    # A padded query that sees no row at all is nobody's: the plain form
+    # averages every row there, the kernel writes zeros.
+    seen = mask.any(-1)
+    assert seen.sum() >= {"one_token_suffix": 16}.get(case, 33)
+    np.testing.assert_allclose(got[seen], want[seen], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got).all()
+
+
+def test_expanded_attention_tiled_equals_whole_in_bf16():
+    """At the cells' dtype: the kernel rounds the exponentials to the
+    values' dtype and divides by their f32 sum afterwards, the plain form
+    rounds the quotients; bf16's rounding apart."""
+    got, want, mask = _both_forms(16, "partly_dead_prior", jnp.bfloat16)
+    seen = mask.any(-1)
+    assert np.abs(got[seen] - want[seen]).max() < 0.02
+
+
+def test_expanded_attention_reads_its_own_form_and_names_its_kernel():
+    """``index_impl`` is the indexer's: the expanded attention of a block
+    that does not select takes the kernel by ``expanded_impl`` alone, under
+    a name of its own in a device trace, which the decode kernel's metric
+    (``mla_decode_roofline``: ``mla_paged_decode_attention``) does not
+    match."""
+    import json
+    import re
+
+    with open(REPO / "chipbench" / "layer_metrics"
+              / "mla_decode_roofline.json") as f:
+        decode_metric = json.load(f)["op_regex"]
+    cfg = dataclasses.replace(CFG, n_heads=4)
+    lp = {"wkvb": jnp.zeros((cfg.kv_lora_rank, 4 * (
+        cfg.qk_nope_head_dim + cfg.v_head_dim)))}
+    args = (lp, jnp.zeros((1, 8, 4, cfg.qk_nope_head_dim)),
+            jnp.zeros((1, 8, 4, cfg.qk_rope_head_dim)),
+            jnp.zeros((1, 8, cfg.latent_dim)), jnp.ones((1, 8, 8), bool))
+
+    def traced(c):
+        return str(jax.make_jaxpr(
+            lambda *a: mla.expanded_attention(c, *a))(*args))
+
+    tiled = traced(dataclasses.replace(cfg, expanded_impl="kernel_interpret"))
+    assert "mla_window_attention" in tiled and "pallas_call" in tiled
+    assert not re.search(decode_metric, tiled)
+    assert "dsa_window_attention" not in tiled
+    whole = traced(dataclasses.replace(cfg, index_impl="kernel_interpret"))
+    assert "pallas_call" not in whole
+    selecting = traced(dataclasses.replace(
+        cfg, index_topk=4, expanded_impl="kernel_interpret"))
+    assert ("dsa_window_attention" in selecting
+            and "mla_window_attention" not in selecting)
+
+
+@pytest.mark.parametrize("config,heads", [("kimi-vl-a3b-cut", 16),
+                                          ("longcat-flash-omni-cut", 64)])
+def test_the_window_microbenchmark_rehearses_on_the_cpu(config, heads, capsys,
+                                                        monkeypatch):
+    """scripts/microbench_decode.py --window at a cell's widths and a small
+    window, the kernel interpreted: a line a (form, prior bucket), the tiled
+    form within bf16's rounding of the whole one, and only the whole one
+    writing scores."""
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "microbench_decode", REPO / "scripts" / "microbench_decode.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    monkeypatch.setattr("llm_d_inference_scheduler_tpu.utils.compile_cache."
+                        "configure_compile_cache", lambda: "")
+    bench.main(["--window", "--window-interpret", "--window-config", config,
+                "--window-tokens", "32", "--window-priors", "0,8",
+                "--window-live", "0.6", "--window-iters", "1"])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert [(ln["form"], ln["prior_blocks"]) for ln in lines] == [
+        ("xla", 0), ("kernel_interpret", 0), ("xla", 8),
+        ("kernel_interpret", 8)]
+    for ln in lines:
+        assert ln["component"] == "mla_window_attention"
+        assert (ln["heads"], ln["window"]) == (heads, 32)
+        assert ln["rows"] == ln["prior_blocks"] * 16 + 32
+        if ln["form"] == "xla":
+            assert ln["score_bytes"] == heads * 32 * ln["rows"] * 4
+        else:
+            assert ln["score_bytes"] == 0 and ln["max_err_vs_plain"] < 0.05
+    assert lines[-1]["live_rows"] == int(128 * 0.6) + 32
 
 
 def test_absorbed_attention_equals_expanded_on_the_same_cache():
@@ -550,18 +696,17 @@ def test_engine_serves_through_chunked_prefill_prefix_cache_and_kernel(served):
                 eng.submit(EngineRequest(
                     request_id="pd", prompt_token_ids=short,
                     kv_transfer_params={"do_remote_decode": True}))
-            counted = {
-                s.labels["form"]: s.value
-                for m in eng.telemetry.registry.collect()
-                for s in m.samples
-                if s.name == "jetstream:mla_attention_tokens_total"}
-            groups = {
-                s.labels["kind"]: s.value
-                for m in eng.telemetry.registry.collect()
-                for s in m.samples
-                if s.name == "jetstream:kv_table_groups_total"}
-            return (first, again, counted,
-                    dict(eng.describe()["settings"], table_groups=groups))
+            def series(name, label):
+                return {s.labels[label]: s.value
+                        for m in eng.telemetry.registry.collect()
+                        for s in m.samples if s.name == f"jetstream:{name}"}
+
+            return (first, again,
+                    series("mla_attention_tokens_total", "form"),
+                    dict(eng.describe()["settings"],
+                         table_groups=series("kv_table_groups_total", "kind"),
+                         window_tokens=series(
+                             "mla_window_attention_tokens_total", "form")))
         finally:
             await eng.stop()
 
@@ -576,6 +721,13 @@ def test_engine_serves_through_chunked_prefill_prefix_cache_and_kernel(served):
     assert aw[1] >= 144 and ac[1] >= 144             # the rerun hit the cache
     assert counted["expanded"] > 0 and counted["absorbed"] > 0
     assert counted_c["expanded"] > counted["expanded"]   # warm-up's ladder
+    # Every expanded row under the form the engine bound: the scores whole
+    # on the CPU, the tiled kernel where the engine interprets its kernels
+    # (on a TPU: "kernel"), and /health says which.
+    assert settings["window_tokens"] == {"xla": counted["expanded"]}
+    assert settings_c["window_tokens"] == {"kernel": counted_c["expanded"]}
+    assert (settings["expanded_attention"], settings_c["expanded_attention"],
+            settings["index_scores"]) == ("xla", "kernel_interpret", None)
     assert settings["kv_token_bytes"] == 128 * 4 and settings_c["pallas_attention"]
     assert settings["kv_pool_bytes"] == 3 * 65 * 16 * 512
     # Every expert is held and none computes nothing: this block's programs
