@@ -44,6 +44,12 @@ context. Its table is indexed by logical page like the other (entry p the page
 of positions ``p * block ...``; 0, the trash block, where the request holds
 none), rides with a step in the ``kvcache/state.Cache`` (``win`` the pool,
 ``wt`` the step's tables), and the latent kind's writes and reads serve it.
+
+A fifth kind is the same window pool for K/V attention (models/llama.py,
+``ModelConfig.kv_window``): a PAIR of pools ``[window layers, n_blocks, block,
+n_kv_heads, head_dim]`` (``win`` and ``win_v`` of the ``state.Cache``) under
+the same owner, table and rules, beside the pair of the layers that keep the
+whole context; the K/V kind's writes and reads serve it.
 """
 
 from __future__ import annotations
@@ -59,12 +65,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import (latent_paged_decode_attention,
                              paged_decode_attention,
-                             swa_latent_decode_attention)
+                             swa_latent_decode_attention,
+                             swa_paged_decode_attention)
 from ..ops.pallas_dsa import sparse_latent_paged_decode_attention_pallas
 from ..ops.pallas_latent_attention import (
     RUN_PAGES, latent_paged_decode_attention_pallas,
     swa_latent_decode_attention_pallas)
-from ..ops.pallas_paged_attention import paged_decode_attention_pallas
+from ..ops.pallas_paged_attention import (paged_decode_attention_pallas,
+                                          swa_paged_decode_attention_kernel)
 from ..ops.sparse_attention import sparse_latent_paged_decode_attention
 from . import state as state_pool
 
@@ -97,25 +105,30 @@ class WindowGeometry:
     window: int              # tokens a query sees, its own among them
     lanes: int               # requests that may hold pages at once
     dtype: str
+    # A K/V pair of pools (latent_dim 0): a token's KV heads side by side.
+    n_kv_heads: int = 0
+    head_dim: int = 0
 
     @classmethod
     def for_engine(cls, model: Any, max_batch: int) -> "WindowGeometry | None":
         """The engine's window pool for ``model`` (anything with
-        n_window_layers and of_window()); None for a model without such
-        layers. Every request that may be live holds ``lane_pages`` at most
-        (``max_batch`` slots, and an eighth as many again for requests that
-        finish inside the chunk in flight while a successor has their slot),
-        and one prefill window's new pages exist beside its old ones for the
-        length of a call: ``lanes x lane_pages + lane_pages`` and the trash
-        block, whatever ``max_model_len``."""
+        n_window_layers, window and, for a latent pool, of_window()); None
+        for a model without such layers. Every request that may be live
+        holds ``lane_pages`` at most (``max_batch`` slots, and an eighth as
+        many again for requests that finish inside the chunk in flight while
+        a successor has their slot), and one prefill window's new pages exist
+        beside its old ones for the length of a call: ``lanes x lane_pages +
+        lane_pages`` and the trash block, whatever ``max_model_len``."""
         if not getattr(model, "n_window_layers", 0):
             return None
-        w = model.of_window()
-        window, block = w.window_attn.window, model.kv_block_size
+        window, block = model.window, model.kv_block_size
         lanes = max_batch + max(2, max_batch // 8)
+        latent = model.of_window().latent_dim if model.latent_dim else 0
         return cls(model.n_window_layers,
                    1 + (lanes + 1) * cls.pages_of(window, block), block,
-                   w.latent_dim, window, lanes, str(jnp.dtype(model.dtype)))
+                   latent, window, lanes, str(jnp.dtype(model.dtype)),
+                   n_kv_heads=0 if latent else model.n_kv_heads,
+                   head_dim=0 if latent else model.head_dim)
 
     @staticmethod
     def pages_of(window: int, block: int) -> int:
@@ -145,14 +158,21 @@ class WindowGeometry:
 
     @property
     def shape(self) -> tuple[int, ...]:
-        return (self.n_layers, self.n_blocks, self.block, self.row_width)
+        """The pool's shape: the latent pool's, or of K and of V each."""
+        if self.latent_dim:
+            return (self.n_layers, self.n_blocks, self.block, self.row_width)
+        return (self.n_layers, self.n_blocks, self.block, self.n_kv_heads,
+                self.head_dim)
 
     @property
     def token_bytes(self) -> int:
-        return self.row_width * jnp.dtype(self.dtype).itemsize
+        """Bytes a token holds in one window layer (K and V, or its row)."""
+        per = self.row_width or 2 * self.n_kv_heads * self.head_dim
+        return per * jnp.dtype(self.dtype).itemsize
 
     @property
     def pool_bytes(self) -> int:
+        """Bytes of the latent pool, or of the pair."""
         return self.n_layers * self.n_blocks * self.block * self.token_bytes
 
 
@@ -282,9 +302,11 @@ class PageGeometry:
         format yet (ROADMAP R7, R8; a selection over sharded keys would need
         every shard's scores)."""
         if self.window:
-            return ("a latent (MLA) page pool and a second one of the layers "
-                    "that keep a window of the context, under a block table "
-                    "each")
+            return (("a latent (MLA) page pool and a second one"
+                     if self.latent_dim else
+                     "K/V page pools and a second pair of them")
+                    + " of the layers that keep a window of the context, "
+                    "under a block table each")
         if self.index_dim:
             return ("a latent (MLA) page pool and its indexer's key pool "
                     "beside it, under one block table")
@@ -353,13 +375,18 @@ def alloc(geom: PageGeometry, *, device=None, sharding=None
                              "pool only, and a step's counts ride with an "
                              "unsharded pool only: no sharding rule for "
                              "either")
-        idx = (jnp.zeros(geom.index_shape, jnp.dtype(geom.dtype),
-                         device=device) if geom.index_dim else None)
-        win = (jnp.zeros(geom.window.shape, jnp.dtype(geom.dtype),
-                         device=device) if geom.window else None)
-        return state_pool.alloc(geom.state, *_alloc_pools(geom, device),
-                                device=device, counts_zero=geom.counts_zero,
-                                idx=idx, win=win), None
+
+        def zeros(shape):
+            return (None if shape is None else
+                    jnp.zeros(shape, jnp.dtype(geom.dtype), device=device))
+
+        window = geom.window.shape if geom.window else None
+        return state_pool.alloc(
+            geom.state, *_alloc_pools(geom, device), device=device,
+            counts_zero=geom.counts_zero, idx=zeros(geom.index_shape),
+            win=zeros(window),
+            win_v=zeros(None if geom.latent_dim else window),
+            counted=geom.counted), None
     return _alloc_pools(geom, device, sharding)
 
 
@@ -515,9 +542,16 @@ def write_sequences(k_pages: jax.Array, v_pages: jax.Array,
         if cache.idx is not None:
             cache = dataclasses.replace(cache, idx=_write_latent_run(
                 cache.idx, k_new.idx, block_tables, lens, start))
-        if cache.win is not None:
-            # The window layers' rows, under the step's window tables: the
+        if cache.win_v is not None:
+            # The window layers' K and V, under the step's window tables: the
             # pages the request does not keep are the trash block there.
+            win, win_v = write(
+                cache.win, cache.win_v, k_new.win, k_new.win_v,
+                *sequence_slots(cache.win, cache.wt, lens,
+                                k_new.win.shape[2], start))
+            cache = dataclasses.replace(cache, win=win, win_v=win_v)
+        elif cache.win is not None:
+            # (A latent window pool: the same, whole pages at a time.)
             cache = dataclasses.replace(cache, win=_write_latent_run(
                 cache.win, k_new.win, cache.wt, lens, start))
         return cache, None
@@ -622,6 +656,23 @@ def window_decode_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
     return swa_latent_decode_attention(
         q, pool, layer, block_tables, seq_lens, cur_row,
         value_dim=value_dim, scale=scale, window=window)
+
+
+def window_kv_decode_attention(q: jax.Array, k_pages: jax.Array,
+                               v_pages: jax.Array, layer: jax.Array,
+                               block_tables: jax.Array, seq_lens: jax.Array,
+                               cur_k: jax.Array, cur_v: jax.Array, *,
+                               window: int, impl: str = "xla") -> jax.Array:
+    """:func:`decode_attention` for a window pool's K/V pair: the query sees
+    its own K/V and the ``window - 1`` rows cached before it, through the
+    lane's window table. ``impl`` as :func:`window_decode_attention`'s."""
+    if impl.startswith("kernel"):
+        return swa_paged_decode_attention_kernel(
+            q, k_pages, v_pages, layer, block_tables, seq_lens, cur_k, cur_v,
+            window=window, interpret=impl == "kernel_interpret")
+    return swa_paged_decode_attention(q, k_pages, v_pages, layer,
+                                      block_tables, seq_lens, cur_k, cur_v,
+                                      window=window)
 
 
 def window_prefix_pages(table_row: jax.Array, prefix_len: jax.Array,

@@ -142,10 +142,17 @@ class Cache:
     read: jax.Array | None = None   # held experts read, where a step counts
     idx: jax.Array | None = None    # the indexer's key pool beside a latent k
     # The latent pool of the layers that keep a window of the context
-    # (kvcache/pages.py), and this step's tables of it [B, table width],
-    # which ride with the step as ``slots`` do.
+    # (kvcache/pages.py), or their K pool and, in ``win_v``, their V pool;
+    # and this step's tables of it [B, table width], which ride with the step
+    # as ``slots`` do.
     win: jax.Array | None = None
     wt: jax.Array | None = None
+    win_v: jax.Array | None = None
+    # Whether the model's programs count their router's choices at all
+    # (``PageGeometry.counted``): a cache that is this value for its window
+    # pools' sake alone carries no count.
+    counted: bool = dataclasses.field(default=True,
+                                      metadata=dict(static=True))
 
 
 @jax.tree_util.register_dataclass
@@ -163,20 +170,22 @@ class Fresh:
     held: jax.Array
     zero: jax.Array | None = None
     idx: jax.Array | None = None    # the rows' indexer keys [L, B, S, width]
-    win: jax.Array | None = None    # the window layers' rows [Lw, B, S, width]
+    win: jax.Array | None = None    # the window layers' rows [Lw, B, S, width],
+    win_v: jax.Array | None = None  # or their K and V [Lw, B, S, Hkv, D] each
 
 
 def alloc(geom: StateGeometry | None, k_pages: jax.Array,
           v_pages: jax.Array | None, *, device=None,
           counts_zero: bool = False, idx: jax.Array | None = None,
-          win: jax.Array | None = None) -> Cache:
+          win: jax.Array | None = None, win_v: jax.Array | None = None,
+          counted: bool = True) -> Cache:
     """A zeroed state pool beside the given page pools; ``geom`` None: the
     page pools alone (with ``idx``, an indexer's key pool beside a latent
-    one; with ``win``, the window layers' pool), in the value that carries a
-    step's counts."""
+    one; with ``win``, the window layers' pool, or with ``win_v`` their
+    pair), in the value that carries a step's counts (``counted``)."""
     if geom is None:
         return Cache(k_pages, v_pages, None, None, counts_zero=counts_zero,
-                     idx=idx, win=win)
+                     idx=idx, win=win, win_v=win_v, counted=counted)
     return Cache(k_pages, v_pages,
                  jnp.zeros(geom.ssm_shape, jnp.float32, device=device),
                  jnp.zeros(geom.conv_shape, jnp.dtype(geom.dtype),
@@ -193,7 +202,8 @@ def at_slots(cache: Any, slots: Any, wt: Any = None, *, reads: bool = False
     if not isinstance(cache, Cache):
         return cache
     return dataclasses.replace(
-        cache, slots=np.asarray(slots, np.int32), held=np.zeros((), np.int32),
+        cache, slots=np.asarray(slots, np.int32),
+        held=np.zeros((), np.int32) if cache.counted else None,
         zero=np.zeros((), np.int32) if cache.counts_zero else None,
         read=np.zeros((), np.int32) if reads else None,
         wt=None if wt is None else np.asarray(wt, np.int32))
@@ -215,6 +225,8 @@ def take_counts(cache: Any) -> tuple[Any, jax.Array | None,
 def counted(cache: Cache, held: jax.Array, zero: jax.Array | None = None,
             read: jax.Array | None = None) -> Cache:
     """``cache`` with a program's counts added to those it carries."""
+    if cache.held is None:
+        return cache
     return dataclasses.replace(
         cache, held=cache.held + held,
         zero=None if cache.zero is None else cache.zero + zero,

@@ -30,12 +30,13 @@ def family(cfg: ModelConfig):
     ``decode_step`` and ``prefill_with_prefix`` under one set of signatures.
     Latent attention (kv_lora_rank > 0) names models/mla.py, whose layer
     pattern, where it has one, says which of two kinds of attention a layer
-    is; any other layer pattern names models/hybrid.py, whose layers are
+    is; a layer pattern of mixers names models/hybrid.py, whose layers are
     state-space, expert and attention mixers in that pattern; everything else
-    is models/llama.py's block."""
+    is models/llama.py's block, two kinds of K/V attention layer in one model
+    (``kv_window``) among it."""
     if cfg.kv_lora_rank:
         return mla
-    return hybrid if cfg.layer_pattern else llama
+    return hybrid if cfg.mixer_pattern else llama
 
 
 def selection_counts(first: np.ndarray, n: np.ndarray, topk: int
@@ -153,10 +154,10 @@ class Bound:
                            ("dsa_query_tokens", "all", got["all"]),
                            ("dsa_rows", "scored", got["scored"]),
                            ("dsa_rows", "attended", got["attended"])]
-            if m.window_attn and queries is not None:
-                counts += [("swa_rows", kind, amount) for kind, amount in
-                           window_counts(*queries,
-                                         m.window_attn.window).items()]
+        if m.window and queries is not None:
+            # Either family's window layers, from the queries' positions.
+            counts += [("swa_rows", kind, amount) for kind, amount in
+                       window_counts(*queries, m.window).items()]
         if m.n_state_layers:
             # models/hybrid.py: one position a sequence is the step form, a
             # run of them the scan form; a first window starts its slots.
@@ -197,7 +198,13 @@ class Bound:
             "state_update": m.ssm_impl if m.n_state_layers else None,
             # The form of the layers that attend to a window of the context
             # (a key only a model with such layers has).
-            **({"window_attention": m.swa_impl} if m.window_attn else {}),
+            **({"window_attention": m.swa_impl} if m.window else {}),
+            # What models/llama.py's router reads and its experts' activation
+            # where they are not Mixtral's (keys only such a model has).
+            **({"router_input": m.router_input,
+                "expert_activation": m.expert_act}
+               if (m.router_input, m.expert_act) != ("ffn", "swiglu")
+               else {}),
         }
 
 
@@ -231,11 +238,15 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
         forms["expanded_impl"] = tiled
     if mcfg.index_topk:
         forms["index_impl"] = tiled
-    if mcfg.window_attn:
-        # The window layers' two kernels (ops/pallas_latent_attention.py's
-        # walk over the window's pages, ops/pallas_dsa.py's tiles under the
-        # band) on a TPU, the plain forms on the CPU.
-        forms["swa_impl"] = tiled
+    if mcfg.window:
+        # The window layers' kernels (the latent family's two: ops/
+        # pallas_latent_attention.py's walk over the window's pages, ops/
+        # pallas_dsa.py's tiles under the band; the K/V family's one: ops/
+        # pallas_paged_attention.py's walk) on a TPU, the plain forms on the
+        # CPU.
+        # (A K/V page's DMA wants whole lanes: kvcache/pages.use_kernel.)
+        aligned = mcfg.kv_lora_rank or interpret or mcfg.head_dim % 128 == 0
+        forms["swa_impl"] = tiled if aligned else "xla"
     mcfg = dataclasses.replace(mcfg, **{**forms, **(forced or {})})
     suffix = "_interpret" if interpret else ""
     return Bound(

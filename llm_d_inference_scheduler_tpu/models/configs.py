@@ -179,6 +179,36 @@ class ModelConfig:
     # ops/pallas_dsa.py's tiles under the band) | "kernel_interpret".
     # models.bind sets it.
     swa_impl: str = "xla"
+    # Window and full attention in one K/V model (models/llama.py; kv_window
+    # > 0 names it, SmallThinker's language model is one): the layer pattern
+    # says which kind a layer is, "*" the whole context through the page
+    # pools every K/V model has and "W" the last ``kv_window`` tokens, the
+    # query's own among them, through K/V pools of their own
+    # (kvcache/pages.py). ``full_nope``: the "*" layers carry no position
+    # code (q and k are not rotated); the "W" layers rotate as ever.
+    # ``swa_impl`` is the form of the "W" layers' decode walk here too.
+    kv_window: int = 0
+    full_nope: bool = False
+    # What an expert layer's router reads in models/llama.py: "ffn", the
+    # FFN's normed input (Mixtral) | "attn", the normed input of the SAME
+    # layer's attention, so that the experts are known before attention ends.
+    router_input: str = "ffn"
+    # The experts' activation there: "swiglu" silu(x w1) * (x w3) | "reglu"
+    # relu(x w1) * (x w3).
+    expert_act: str = "swiglu"
+
+    @property
+    def mixer_pattern(self) -> bool:
+        """Whether the layer pattern names one mixer a layer (models/
+        hybrid.py), and not which of two kinds of attention a layer is."""
+        return bool(self.layer_pattern) and not (self.kv_lora_rank
+                                                 or self.kv_window)
+
+    @property
+    def window(self) -> int:
+        """Tokens a window layer's query sees, its own among them (0: no
+        layer attends to a window)."""
+        return self.window_attn.window if self.window_attn else self.kv_window
 
     @property
     def n_state_layers(self) -> int:
@@ -194,7 +224,7 @@ class ModelConfig:
     @property
     def n_window_layers(self) -> int:
         """Cache layers that keep a window of the context alone (0: none)."""
-        return self.layer_pattern.count("W") if self.window_attn else 0
+        return self.layer_pattern.count("W") if self.window else 0
 
     def of_window(self) -> "ModelConfig":
         """The configuration as a window layer's attention reads it: the
@@ -206,7 +236,7 @@ class ModelConfig:
         """Layers with a router (0: a dense model)."""
         if not self.n_experts:
             return 0
-        if self.layer_pattern and not self.kv_lora_rank:
+        if self.mixer_pattern:
             return self.layer_pattern.count("E")
         return self.n_layers - self.first_k_dense
 
@@ -221,9 +251,11 @@ class ModelConfig:
         device (held here / zero-compute; kvcache/state.Cache carries the
         counts out): models/hybrid.py's always do, models/mla.py's where not
         every choice is an expert held here, and where the block selects rows
-        (its two pools ride in that value anyway)."""
-        return bool(self.layer_pattern or self.experts_held
-                    or self.n_zero_experts or self.index_topk)
+        (its two pools ride in that value anyway); models/llama.py's never:
+        every choice there is an expert held here."""
+        return bool((self.layer_pattern and not self.kv_window)
+                    or self.experts_held or self.n_zero_experts
+                    or self.index_topk)
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -508,6 +540,32 @@ TINY_SWA = dataclasses.replace(
                          v_head_dim=16, rope_theta=500.0, window=7,
                          gate=True))
 
+# Window and full attention mixed in a K/V model, SmallThinker's block at small
+# widths (CI tests): two periods of a full layer with no position code and
+# three window layers that rotate and see 11 tokens, 7 query heads a KV head,
+# a page of 4, a router of 8 experts that reads the attention's input, ReGLU.
+TINY_SWA_KV = ModelConfig(
+    name="tiny-swa-kv",
+    vocab_size=512,
+    d_model=64,
+    n_layers=8,
+    n_heads=14,
+    n_kv_heads=2,
+    d_ff=48,
+    max_seq_len=256,
+    rope_theta=10_000.0,
+    norm_eps=1e-6,
+    kv_block_size=4,
+    head_dim_override=16,
+    n_experts=8,
+    experts_per_token=3,
+    layer_pattern="*WWW*WWW",
+    kv_window=11,
+    full_nope=True,
+    router_input="attn",
+    expert_act="reglu",
+)
+
 # NVIDIA-Nemotron-3-Super-120B-A12B's language model (public config.json,
 # model_type nemotron_h): 88 layers of one mixer each -- 40 Mamba-2, 40
 # LatentMoE (512 experts of 2688 in a 1024-wide latent space, 22 a token,
@@ -579,7 +637,7 @@ _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
                                  KIMI_VL_A3B, TINY_MLA, TINY_LONGCAT, TINY_DSA,
-                                 TINY_SWA,
+                                 TINY_SWA, TINY_SWA_KV,
                                  NEMOTRON_3_SUPER,
                                  NEMOTRON_3_SUPER_CUT, TINY_HYBRID)}
 

@@ -311,10 +311,68 @@ def _hybrid_config_from_hf(hf, name: str) -> ModelConfig:
     )
 
 
+# What models/llama.py computes of SmallThinker's options.
+_SMALLTHINKER_ONLY = {"moe_primary_router_apply_softmax": True,
+                      "rope_scaling": None, "tie_word_embeddings": False,
+                      "attention_bias": False}
+
+
+def _smallthinker_config_from_hf(hf, name: str) -> ModelConfig:
+    """SmallThinker (``moe_num_primary_experts`` names it): K/V attention
+    whose layers attend to a window (``sliding_window_layout`` 1) or to the
+    whole context, rotate q and k or carry no position code (``rope_layout``
+    0), a softmax router over the chosen that reads the attention's normed
+    input, ReGLU experts of ``moe_ffn_hidden_size``. A layer that attends to
+    a window rotates; the others all do or none does."""
+    _refuse_other_options(hf, name, _SMALLTHINKER_ONLY, "models/llama.py")
+    L = hf.num_hidden_layers
+    window = list(getattr(hf, "sliding_window_layout", None) or [0] * L)
+    rope = list(getattr(hf, "rope_layout", None) or [1] * L)
+    for key, layout in (("sliding_window_layout", window),
+                        ("rope_layout", rope)):
+        if len(layout) != L or set(layout) - {0, 1}:
+            raise ValueError(
+                f"{name}: {key}={layout!r} must hold a 0 or a 1 for each of "
+                f"the {L} layers")
+    nope = [w for w, r in zip(window, rope) if not r]
+    if any(nope) or (nope and (len(nope) != window.count(0)
+                               or not any(window))):
+        raise ValueError(
+            f"{name}: rope_layout={rope!r} is not supported beside "
+            f"sliding_window_layout={window!r} (models/llama.py rotates in "
+            "every window layer, and beside them in all of the others or in "
+            "none)")
+    if any(window) and not getattr(hf, "sliding_window_size", None):
+        raise ValueError(f"{name}: a window layer needs sliding_window_size")
+    default_hd = hf.hidden_size // hf.num_attention_heads
+    return ModelConfig(
+        name=name,
+        vocab_size=hf.vocab_size,
+        d_model=hf.hidden_size,
+        n_layers=L,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        d_ff=hf.moe_ffn_hidden_size,
+        rope_theta=float(getattr(hf, "rope_theta", 10_000.0)),
+        max_seq_len=getattr(hf, "max_position_embeddings", 8192),
+        norm_eps=hf.rms_norm_eps,
+        n_experts=hf.moe_num_primary_experts,
+        experts_per_token=hf.moe_num_active_primary_experts,
+        head_dim_override=(hf.head_dim if hf.head_dim != default_hd else 0),
+        layer_pattern=("".join("*W"[w] for w in window) if any(window)
+                       else ""),
+        kv_window=hf.sliding_window_size if any(window) else 0,
+        full_nope=bool(nope),
+        router_input="attn",
+        expert_act="reglu",
+    )
+
+
 def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
     """Map a transformers Llama/Mixtral/Qwen3 config, a DeepSeek-V3-family
     one (Kimi-VL's ``text_config``), a LongCat-Flash one (``zero_expert_num``
-    names it) or a nemotron_h one (a layer pattern) to our ModelConfig."""
+    names it), a nemotron_h one (a layer pattern) or a SmallThinker one
+    (``moe_num_primary_experts``) to our ModelConfig."""
     text = getattr(hf_config, "text_config", None)
     if text is not None:
         # A multimodal config nests its language model; the towers beside it
@@ -329,6 +387,8 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
         return _longcat_config_from_hf(hf_config, name)
     if getattr(hf_config, "kv_lora_rank", None):
         return _mla_config_from_hf(hf_config, name)
+    if getattr(hf_config, "moe_num_primary_experts", None):
+        return _smallthinker_config_from_hf(hf_config, name)
     n_experts = getattr(hf_config, "num_local_experts", 0) or 0
     qk_norm = getattr(hf_config, "model_type", "") == "qwen3"
     explicit_hd = getattr(hf_config, "head_dim", None) or 0
@@ -376,11 +436,11 @@ def convert_state_dict(state_dict: dict, cfg: ModelConfig,
             "checkpoint names onto models/mla.py's parameter tree yet (its "
             "rope columns are stored interleaved and need un-interleaving); "
             "the engine serves this family on seeded random weights")
-    if cfg.layer_pattern:
+    if cfg.layer_pattern or cfg.router_input != "ffn":
         raise NotImplementedError(
             f"{cfg.name}: no mapping of a layer pattern's checkpoint names "
-            "(the nemotron_h family) onto models/hybrid.py's parameter tree "
-            "yet; the engine serves this family on seeded random weights")
+            "(the nemotron_h family, SmallThinker) onto the parameter tree "
+            "yet; the engine serves these on seeded random weights")
     out_dtype = jnp.dtype(dtype or cfg.dtype)
     L, E = cfg.n_layers, cfg.n_experts
 

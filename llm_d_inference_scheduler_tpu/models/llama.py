@@ -17,17 +17,35 @@ Design notes (TPU-first):
   accumulation; logits are f32.
 - Attention is injected via ``attention_fn`` so the sequence-parallel path can
   substitute a ring-attention shard_map without changing the model.
+
+**Two kinds of layer in one model** (``cfg.kv_window`` > 0; the layer pattern
+says which a layer is; SmallThinker's language model). A "W" layer attends to
+the last ``kv_window`` tokens through a K/V pool pair of its own under a table
+of its own (kvcache/pages.py; ``state.Cache.win`` / ``.win_v`` / ``.wt``, of
+which a request keeps the pages its window reaches), a "*" layer to the whole
+context through the pools every K/V model has, without a position code where
+``cfg.full_nope`` says so. The layers stay ONE stack under ONE scan: the
+kind is a flag scanned with the layer (:func:`_kinds`), the rotation a
+``where`` and the attention a ``lax.cond`` between the two kinds' reads, so
+no run of layers is sliced out of the stacked weights. The router of such a
+model may read the attention's normed input (``cfg.router_input`` "attn":
+:func:`_route`, computed before the attention, applied to the FFN's input)
+and its experts may be ReGLU (``cfg.expert_act``). A model without the flag
+traces exactly the programs it always did.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..kvcache import pages
+from ..kvcache import pages, state
 from ..ops import apply_rope, causal_attention, rms_norm, rope_table
+from ..ops.attention import banded_attention
 from .configs import ModelConfig
 
 Params = dict[str, Any]
@@ -76,7 +94,20 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None
     }
 
 
-def _moe_ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray) -> jnp.ndarray:
+def _route(cfg: ModelConfig, lp: Params, h: jnp.ndarray
+           ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The router on ``h`` [..., D], whichever layer input that is: (the
+    experts chosen [..., k], their gates [..., k] f32, a softmax over the
+    chosen logits). The logits are f32 straight from the product
+    (ops/pallas_moe.moe_ffn_grouped has the reason)."""
+    logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
+    top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
+    return top_idx, jax.nn.softmax(top_vals, axis=-1)
+
+
+def _moe_ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+             route: tuple[jnp.ndarray, jnp.ndarray] | None = None
+             ) -> jnp.ndarray:
     """Top-k mixture-of-experts FFN (Mixtral-style), dense-over-experts.
 
     Compute is formulated as batched einsums over the experts axis — static
@@ -84,17 +115,22 @@ def _moe_ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray) -> jnp.ndarray:
     out on the ``ep`` mesh axis each device computes its local experts and
     XLA reduces the weighted combine with one psum. (At production scale the
     dense form trades FLOPs for regularity; a Pallas grouped-matmul drops in
-    behind this same signature.)
+    behind this same signature.) ``route``: the experts and gates as a router
+    that read another input chose them (:func:`_route`).
     """
-    logits = (h @ lp["router"]).astype(jnp.float32)          # [B, S, E]
-    top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
-    gates = jax.nn.softmax(top_vals, axis=-1)                # [B, S, k]
+    if route is None:
+        logits = (h @ lp["router"]).astype(jnp.float32)          # [B, S, E]
+        top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
+        gates = jax.nn.softmax(top_vals, axis=-1)                # [B, S, k]
+    else:
+        top_idx, gates = route
     onehot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=h.dtype)  # [B,S,k,E]
     weights = jnp.einsum("bske,bsk->bse", onehot, gates.astype(h.dtype))
 
     up = jnp.einsum("bsd,edf->bsef", h, lp["w1"])
     gate = jnp.einsum("bsd,edf->bsef", h, lp["w3"])
-    out = jnp.einsum("bsef,efd->bsed", jax.nn.silu(up) * gate, lp["w2"])
+    act = jax.nn.relu if cfg.expert_act == "reglu" else jax.nn.silu
+    out = jnp.einsum("bsef,efd->bsed", act(up) * gate, lp["w2"])
     return jnp.einsum("bsed,bse->bsd", out, weights)
 
 
@@ -110,20 +146,32 @@ def qk_normed(cfg: ModelConfig, lp: Params, q: jnp.ndarray,
     return q, k
 
 
-def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray) -> jnp.ndarray:
-    """Dense or MoE FFN — dispatched on pytree structure at trace time."""
+def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+         route: tuple[jnp.ndarray, jnp.ndarray] | None = None) -> jnp.ndarray:
+    """Dense or MoE FFN — dispatched on pytree structure at trace time.
+    ``route``: what a router that read the attention's input chose."""
     if "router" in lp:
         squeeze = h.ndim == 2  # decode step: [B, D]
         if squeeze:
             h = h[:, None]
-        if cfg.moe_impl.startswith("grouped"):
+        if cfg.moe_impl.startswith("grouped") and route is not None:
+            from ..ops.pallas_moe import grouped_experts
+
+            y = grouped_experts(
+                lp, h.reshape(-1, h.shape[-1]),
+                *(r.reshape(-1, r.shape[-1]) for r in route), cfg.n_experts,
+                layer=lp.get("layer"),
+                interpret=cfg.moe_impl == "grouped_interpret",
+                reglu=cfg.expert_act == "reglu").reshape(h.shape)
+        elif cfg.moe_impl.startswith("grouped"):
             from ..ops.pallas_moe import moe_ffn_grouped
 
+            assert cfg.expert_act == "swiglu"   # its own router, and SwiGLU
             y = moe_ffn_grouped(lp, h, cfg.n_experts, cfg.experts_per_token,
                                 layer=lp.get("layer"),
                                 interpret=cfg.moe_impl == "grouped_interpret")
         else:
-            y = _moe_ffn(cfg, lp, h)
+            y = _moe_ffn(cfg, lp, h, route)
         return y[:, 0] if squeeze else y
     return (jax.nn.silu(h @ lp["w1"]) * (h @ lp["w3"])) @ lp["w2"]
 
@@ -174,6 +222,61 @@ def _layer(
     return x, k, v
 
 
+# A window no context reaches: what a layer that attends to the whole context
+# passes where a window layer passes ``cfg.kv_window``.
+_NO_WINDOW = 2 ** 30
+
+
+def _kinds(cfg: ModelConfig) -> tuple[np.ndarray, np.ndarray] | None:
+    """Of a model whose layers are of two kinds (``cfg.kv_window``): (which
+    layers attend to a window [L] bool, a layer's number among its kind's
+    cache layers [L] int32); None for every other model. What a scan over
+    the layers carries beside them."""
+    if not cfg.kv_window:
+        return None
+    window = np.array([ch == "W" for ch in cfg.layer_pattern])
+    among = np.where(window, np.cumsum(window), np.cumsum(~window)) - 1
+    return window, among.astype(np.int32)
+
+
+def _mixed_block(cfg: ModelConfig, lp: Params, x: jnp.ndarray, cos, sin,
+                 is_window: jnp.ndarray,
+                 attend: Callable[..., jnp.ndarray]
+                 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One block of a model whose layers are of two kinds, x [B, S, D]:
+    :func:`_layer` with the router ahead of the attention where the model
+    says so, the rotation only where this layer rotates, and
+    ``attend(q, k, v)`` -> [B, S, H, Dh] the caller's (it knows the layer's
+    kind and cache). Returns (x, k, v) as :func:`_layer`, and the experts
+    the early router chose [B, S, k] (None where the FFN routes itself)."""
+    B, S, _ = x.shape
+    Dh = cfg.head_dim
+
+    h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+    route = _route(cfg, lp, h) if cfg.router_input == "attn" else None
+    q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, Dh)
+    k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, Dh)
+    v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, Dh)
+    q, k = qk_normed(cfg, lp, q, k)
+    if cfg.full_nope:       # no position code where the whole context is seen
+        q = jnp.where(is_window, apply_rope(q, cos, sin), q)
+        k = jnp.where(is_window, apply_rope(k, cos, sin), k)
+    else:
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+
+    x = x + attend(q, k, v).reshape(B, S, -1) @ lp["wo"]
+    h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
+    x = x + _ffn(cfg, lp, h, route)
+    return x, k, v, None if route is None else route[0]
+
+
+def _by_kind(kinds, k: jnp.ndarray, v: jnp.ndarray):
+    """Every layer's new K/V [L, ...] as (the full layers' K, V, the window
+    layers' K, V)."""
+    full, window = np.flatnonzero(~kinds[0]), np.flatnonzero(kinds[0])
+    return k[full], v[full], k[window], v[window]
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -187,6 +290,7 @@ def forward(
     mm_embeds: jnp.ndarray | None = None,     # [B, M, D] multimodal vectors
     mm_positions: jnp.ndarray | None = None,  # [B, M] target positions
     seq_len: jnp.ndarray | None = None,  # [B]: read by models/hybrid.py alone
+    want_routes: bool = False,
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray] | None]:
     """Full-sequence forward (training / prefill).
 
@@ -208,13 +312,36 @@ def forward(
     attn_kwargs = dict(q_positions=positions, kv_positions=positions, kv_valid=kv_valid)
 
     layers, whole = _over_layers(cfg, params["layers"])
+    kinds = _kinds(cfg)
 
     def body(x, lp):
         x, k, v = _layer(cfg, {**lp, **whole}, x, cos, sin, attention_fn,
                          attn_kwargs)
         return x, (k, v) if want_kv else None
 
-    x, kv = jax.lax.scan(body, x, layers)
+    def mixed_body(x, layer_in):
+        lp, is_window = layer_in
+
+        def attend(q, k, v):
+            return banded_attention(
+                q, k, v, **attn_kwargs,
+                window=jnp.where(is_window, cfg.kv_window, _NO_WINDOW))
+
+        x, k, v, chose = _mixed_block(cfg, {**lp, **whole}, x, cos, sin,
+                                      is_window, attend)
+        return x, ((k, v) if want_kv else None,
+                   chose if want_routes else None)
+
+    routes = None
+    if kinds is None:
+        x, kv = jax.lax.scan(body, x, layers)
+    else:
+        x, (kv, routes) = jax.lax.scan(mixed_body, x, (layers, kinds[0]))
+        if want_kv:
+            # The two kinds' rows go to two pool pairs: in the value that
+            # ``pages.write_sequences`` takes a cache's rows in.
+            k, v, wk, wv = _by_kind(kinds, *kv)
+            kv = state.Fresh(k, v, None, None, None, win=wk, win_v=wv), None
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if want_hidden:
         # Embeddings surface: final-norm hidden states, lm head skipped
@@ -222,7 +349,10 @@ def forward(
         # routed by the EPP's embeddings body shape — types.go:74-75).
         return x.astype(jnp.float32), kv
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, kv
+    # ``want_routes`` (a model of two kinds of layer alone; here and on the
+    # two step functions below) appends the experts every layer's early
+    # router chose [L, B, S, k]: what a comparison holds its reference to.
+    return (logits, kv, routes) if want_routes else (logits, kv)
 
 
 def decode_step(
@@ -236,6 +366,7 @@ def decode_step(
     active: jnp.ndarray | None = None,  # [B] bool — padding-slot mask
     *,
     attention_fn: Callable[..., jnp.ndarray] = pages.decode_attention,
+    want_routes: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step with paged KV; returns (logits [B, V] f32, k_pages, v_pages).
 
@@ -257,6 +388,10 @@ def decode_step(
     Inactive batch slots must point their block table at the dedicated trash
     block 0 (the allocator reserves it).
     """
+    if cfg.kv_window:
+        return _mixed_decode_step(params, cfg, tokens, positions, k_pages,
+                                  block_tables, active, attention_fn,
+                                  want_routes)
     B = tokens.shape[0]
     Dh = cfg.head_dim
     cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)  # [B, half]
@@ -308,6 +443,7 @@ def prefill_with_prefix(
     v_pages: jnp.ndarray,
     block_table_row: jnp.ndarray,  # [1, max_blocks] — full table (KV scatter)
     prior_table_row: jnp.ndarray | None = None,  # [1, prefix_bucket] — gather
+    want_routes: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Prefill continuing from cached prefix KV (automatic prefix caching).
 
@@ -321,6 +457,10 @@ def prefill_with_prefix(
     assert B == 1
     if prior_table_row is None:
         prior_table_row = block_table_row
+    if cfg.kv_window:
+        return _mixed_prefill_with_prefix(
+            params, cfg, tokens, suffix_len, prefix_len, k_pages,
+            block_table_row, prior_table_row, want_routes)
     T = prior_table_row.shape[1] * pages.block_size(k_pages)
     Dh = cfg.head_dim
 
@@ -368,3 +508,129 @@ def prefill_with_prefix(
     last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
     logits = (last @ params["lm_head"]).astype(jnp.float32)
     return logits, k_pages, v_pages
+
+
+# ---- two kinds of layer: the step programs ------------------------------------
+
+
+def _mixed_decode_step(params: Params, cfg: ModelConfig, tokens, positions,
+                       cache: state.Cache, block_tables, active,
+                       attention_fn, want_routes: bool = False):
+    """:func:`decode_step` for a model whose layers are of two kinds: the
+    full layers read ``cache.k`` / ``cache.v`` through ``block_tables`` with
+    ``attention_fn``, the window layers ``cache.win`` / ``cache.win_v``
+    through the step's window tables ``cache.wt`` from the window's first
+    page; each kind's new rows go to its own pools with one scatter a pool
+    after the scan. Returns (logits, cache, None)."""
+    B = tokens.shape[0]
+    window, among = kinds = _kinds(cfg)
+    cos, sin = rope_table(positions[:, None], cfg.head_dim, cfg.rope_theta)
+    seq_lens = positions + 1
+    layers, whole = _over_layers(cfg, params["layers"])
+
+    def body(x, layer_in):
+        lp, is_window, at = layer_in
+
+        def attend(q, k, v):
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]
+            return jax.lax.cond(
+                is_window,
+                lambda: pages.window_kv_decode_attention(
+                    q, cache.win, cache.win_v, at, cache.wt, seq_lens, k, v,
+                    window=cfg.kv_window, impl=cfg.swa_impl),
+                lambda: attention_fn(q, cache.k, cache.v, at, block_tables,
+                                     seq_lens, k, v))[:, None]
+
+        x, k, v, chose = _mixed_block(cfg, {**lp, **whole}, x, cos, sin,
+                                      is_window, attend)
+        return x, (k[:, 0], v[:, 0], chose if want_routes else None)
+
+    x, (*kv, routes) = jax.lax.scan(body, params["embed"][tokens][:, None],
+                                    (layers, window, among))
+    k, v, wk, wv = _by_kind(kinds, *kv)
+    k_pages, v_pages = pages.write(
+        cache.k, cache.v, k, v,
+        *pages.token_slots(cache.k, block_tables, positions))
+    win, win_v = pages.write(
+        cache.win, cache.win_v, wk, wv,
+        *pages.token_slots(cache.win, cache.wt, positions))
+
+    x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    if active is not None:
+        logits = jnp.where(active[:, None], logits, 0.0)
+    out = (logits, dataclasses.replace(cache, k=k_pages, v=v_pages, win=win,
+                                       win_v=win_v), None)
+    return (*out, routes) if want_routes else out
+
+
+def _mixed_prefill_with_prefix(params: Params, cfg: ModelConfig, tokens,
+                               suffix_len, prefix_len, cache: state.Cache,
+                               block_table_row, prior_table_row,
+                               want_routes: bool = False):
+    """:func:`prefill_with_prefix` for a model whose layers are of two kinds
+    (a long prompt's next window: such an engine keeps no prefix cache). A
+    full layer reads the whole prefix out of its pools through
+    ``prior_table_row``; a window layer reads, out of its own pools, the
+    pages that end where this window starts and its band still reaches
+    (``pages.window_prefix_pages``). Both at (layer, page) of the stacked
+    pools, which the scan closes over; the scores go a block of queries at a
+    time (ops/attention.banded_attention). Returns (last-token logits, cache,
+    None)."""
+    S = tokens.shape[1]
+    window, among = kinds = _kinds(cfg)
+    block = pages.block_size(cache.k)
+    positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
+    own_valid = jnp.arange(S)[None, :] < suffix_len[:, None]
+    prior_pos = jnp.arange(prior_table_row.shape[1] * block,
+                           dtype=jnp.int32)[None, :]
+    near_ids, near_pos = pages.window_prefix_pages(
+        cache.wt, prefix_len, block, cfg.kv_window)
+    layers, whole = _over_layers(cfg, params["layers"])
+
+    def seen(q, k, v, pools, table, at, pos, reach):
+        """The window's queries against the rows cached at ``pos`` (pages
+        ``table`` of ``pools`` at layer ``at``) and its own. (The compiler
+        re-lays out each V pool once a program for the probabilities'
+        product: PERF.md section 7, PR 48.)"""
+        k_prior, v_prior = pages.read_prefix(*pools, table, layer=at)
+        return banded_attention(
+            q, jnp.concatenate([k_prior.astype(k.dtype), k], axis=1),
+            jnp.concatenate([v_prior.astype(v.dtype), v], axis=1),
+            q_positions=positions,
+            kv_positions=jnp.concatenate([pos, positions], axis=1),
+            kv_valid=jnp.concatenate([pos < prefix_len[:, None], own_valid],
+                                     axis=1),
+            window=reach)
+
+    def body(x, layer_in):
+        lp, is_window, at = layer_in
+
+        def attend(q, k, v):
+            return jax.lax.cond(
+                is_window,
+                lambda: seen(q, k, v, (cache.win, cache.win_v), near_ids, at,
+                             near_pos, cfg.kv_window),
+                lambda: seen(q, k, v, (cache.k, cache.v), prior_table_row,
+                             at, prior_pos, None))
+
+        x, k, v, chose = _mixed_block(cfg, {**lp, **whole}, x, cos, sin,
+                                      is_window, attend)
+        return x, (k, v, chose if want_routes else None)
+
+    x, (*kv, routes) = jax.lax.scan(body, params["embed"][tokens],
+                                    (layers, window, among))
+    k, v, wk, wv = _by_kind(kinds, *kv)
+    k_pages, v_pages = pages.write_sequences(
+        cache.k, cache.v, k, v, block_table_row, suffix_len, start=prefix_len)
+    win, win_v = pages.write_sequences(
+        cache.win, cache.win_v, wk, wv, cache.wt, suffix_len,
+        start=prefix_len)
+
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
+    logits = (last @ params["lm_head"]).astype(jnp.float32)
+    out = (logits, dataclasses.replace(cache, k=k_pages, v=v_pages, win=win,
+                                       win_v=win_v), None)
+    return (*out, routes) if want_routes else out

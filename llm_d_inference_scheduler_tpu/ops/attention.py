@@ -65,6 +65,66 @@ def causal_attention(
     return out.astype(q.dtype)
 
 
+# Queries a step of :func:`banded_attention`: the scores it holds at once are
+# [heads, QUERY_BLOCK, rows] in f32 (186 MB at 28 heads over 13k rows).
+QUERY_BLOCK = 128
+
+
+def banded_attention(
+    q: jnp.ndarray,  # [B, S, H, D]
+    k: jnp.ndarray,  # [B, T, Hkv, D]
+    v: jnp.ndarray,  # [B, T, Hkv, D]
+    *,
+    q_positions: jnp.ndarray,   # [B, S]
+    kv_positions: jnp.ndarray,  # [B, T]
+    kv_valid: jnp.ndarray | None = None,  # [B, T] bool
+    window: int | jnp.ndarray | None = None,
+    q_block: int = QUERY_BLOCK,
+) -> jnp.ndarray:
+    """:func:`causal_attention` for a model some of whose layers attend to a
+    window and whose contexts are long: with ``window`` (a number, or a traced
+    scalar where a scan decides a layer) a query at t sees s with ``0 <= t -
+    s < window``; the queries go ``q_block`` at a time (a
+    loop on the device where S is more, S padded to whole blocks), so the
+    scores are never whole in memory ([H, S, T] in f32: 1.5 GB for 1,024
+    queries over 13k rows at 28 heads); and a KV head's rows are not repeated for its query heads.
+    Products in the operands' dtype with f32 accumulation, the softmax in
+    f32. Returns [B, S, H, D] in q.dtype."""
+    B, S, H, D = q.shape
+    n_kv = k.shape[2]
+    scale = 1.0 / (D ** 0.5)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def block(qb, pos):                        # [B, s, H, D], [B, s]
+        s = qb.shape[1]
+        grouped = qb.reshape(B, s, n_kv, H // n_kv, D)
+        logits = jnp.einsum("bsngd,btnd->bngst", grouped, k, **f32) * scale
+        back = pos[:, :, None] - kv_positions[:, None, :]        # [B, s, T]
+        mask = back >= 0
+        if window is not None:
+            mask = mask & (back < window)
+        if kv_valid is not None:
+            mask = mask & kv_valid[:, None, :]
+        logits = jnp.where(mask[:, None, None], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bngst,btnd->bsngd", probs, v, **f32)
+        return out.reshape(B, s, H, D).astype(q.dtype)
+
+    if S <= q_block:
+        return block(q, q_positions)
+    n = -(-S // q_block)
+    spare = n * q_block - S     # queries that fill the last block: they
+    if spare:                   # repeat the last position, and are dropped
+        q = jnp.pad(q, ((0, 0), (0, spare), (0, 0), (0, 0)))
+        q_positions = jnp.pad(q_positions, ((0, 0), (0, spare)), mode="edge")
+    out = jax.lax.map(
+        lambda x: block(*x),
+        (jnp.moveaxis(q.reshape(B, n, q_block, H, D), 1, 0),
+         jnp.moveaxis(q_positions.reshape(B, n, q_block), 1, 0)))
+    out = jnp.moveaxis(out, 0, 1).reshape(B, n * q_block, H, D)
+    return out[:, :S] if spare else out
+
+
 def paged_decode_attention(
     q: jnp.ndarray,            # [B, H, D] — one new token per sequence
     k_pages: jnp.ndarray,      # [L, N_blocks, block, Hkv, D] — every layer's pool
@@ -210,3 +270,44 @@ def swa_latent_decode_attention(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bht,btd->bhd", probs, rows[..., :value_dim])
     return out.astype(q.dtype)
+
+
+def swa_paged_decode_attention(
+    q: jnp.ndarray,             # [B, H, D]
+    k_pages: jnp.ndarray,       # [Lw, N_blocks, block, Hkv, D] — the window layers' K pool
+    v_pages: jnp.ndarray,
+    layer: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, max_blocks] int32, by logical page
+    seq_lens: jnp.ndarray,      # [B] int32 — incl. the current token
+    cur_k: jnp.ndarray,         # [B, Hkv, D]
+    cur_v: jnp.ndarray,
+    *,
+    window: int,
+) -> jnp.ndarray:
+    """:func:`paged_decode_attention` for layers that attend to a window of
+    the context, as :func:`swa_latent_decode_attention` is the latent form's:
+    the query at ``t = seq_lens - 1`` sees its own K/V and the cached rows s
+    with ``t - s < window``, and only the pages the window reaches are
+    gathered (:func:`window_table`). The Pallas kernel
+    (ops/pallas_paged_attention.py, op ``swa_paged_decode_attention``) has the
+    same signature."""
+    B, H, D = q.shape
+    block = k_pages.shape[2]
+    q_per_kv = H // k_pages.shape[3]
+    tables, lens, skip = window_table(block_tables, seq_lens, block, window)
+    T = tables.shape[1] * block
+
+    def rows(pool, cur):
+        got = pool[layer, tables].reshape(B, T, -1, D)
+        return _repeat_kv(jnp.concatenate([got, cur[:, None]], axis=1),
+                          q_per_kv).astype(jnp.float32)          # [B, T+1, H, D]
+
+    k, v = rows(k_pages, cur_k), rows(v_pages, cur_v)
+    logits = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32), k) / (D ** 0.5)
+    col = jnp.arange(T)[None, :]
+    valid = jnp.concatenate(
+        [(col >= skip[:, None]) & (col < (lens - 1)[:, None]),
+         jnp.ones((B, 1), bool)], axis=1)
+    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bht,bthd->bhd", probs, v).astype(q.dtype)

@@ -169,17 +169,21 @@ def pick_tiles(rows: int, k_dim: int, n_dim: int, n_rhs: int, itemsize: int,
 
 
 def _gmm_kernel(tile_expert, n_live, layer, lhs_ref, *refs, n_rhs: int,
-                k_tiles: int, relu2: bool = False):
+                k_tiles: int, relu2: bool = False, reglu: bool = False):
     """One (N tile, K tile, row tile) grid step: the row tile's [tm, tk]
     against its expert's [tk, tn] block(s). With two weight operands the
-    result is silu(lhs·rhs0) * (lhs·rhs1); with one and ``relu2`` it is
-    relu(lhs·rhs0) squared (an expert that is not gated)."""
+    result is silu(lhs·rhs0) * (lhs·rhs1), or with ``reglu`` relu(lhs·rhs0)
+    * (lhs·rhs1); with one and ``relu2`` it is relu(lhs·rhs0) squared (an
+    expert that is not gated)."""
     del tile_expert, layer  # read by the index maps
     rhs_refs, out_ref, acc_refs = refs[:n_rhs], refs[n_rhs], refs[n_rhs + 1:]
     k, i = pl.program_id(1), pl.program_id(2)
 
     def finish(parts):
-        y = parts[0] if n_rhs == 1 else jax.nn.silu(parts[0]) * parts[1]
+        if reglu:
+            y = jnp.maximum(parts[0], 0.0) * parts[1]
+        else:
+            y = parts[0] if n_rhs == 1 else jax.nn.silu(parts[0]) * parts[1]
         if relu2:
             y = jnp.square(jnp.maximum(y, 0.0))
         out_ref[...] = y.astype(out_ref.dtype)
@@ -212,11 +216,11 @@ def _gmm_kernel(tile_expert, n_live, layer, lhs_ref, *refs, n_rhs: int,
 def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
                     tm: int = ROW_TILE, tiles: tuple[int, int] | None = None,
                     interpret: bool = False, relu2: bool = False,
-                    shared_tiles: int = 0):
+                    reglu: bool = False, shared_tiles: int = 0):
     """lhs [Tp, K] (group-padded rows) times the expert of each row tile out
     of every ``rhs`` [L, E, K, N] at ``layer``; one rhs → lhs·rhs (``relu2``:
-    its relu squared), two → the SwiGLU of both. The weights come stacked
-    over layers, with the layer a prefetched scalar [1]: one layer's slice of them would reach the kernel
+    its relu squared), two → the SwiGLU of both (``reglu``: their ReGLU).
+    The weights come stacked over layers, with the layer a prefetched scalar [1]: one layer's slice of them would reach the kernel
     as a copy (a custom call's operand cannot be a fused slice), 2.8 GB a
     Mixtral layer. tile_expert [Tp // tm] int32; n_live [1] int32, the tiles
     that hold rows. Returns [Tp, N] in lhs.dtype; rows of tiles past n_live
@@ -255,14 +259,15 @@ def _grouped_matmul(lhs, rhs, layer, tile_expert, n_live, *,
     need = _vmem_bytes(n_row_tiles, tm, tk, tn, n_rhs, itemsize, k_tiles)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, n_rhs=n_rhs, k_tiles=k_tiles,
-                          relu2=relu2),
+                          relu2=relu2, reglu=reglu),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=need + 8 * 2 ** 20),
         interpret=interpret,
         # The op's name in a device trace, for whoever reduces one.
-        name=("moe_grouped_swiglu" if n_rhs == 2 else
+        name=("moe_grouped_reglu" if reglu else
+              "moe_grouped_swiglu" if n_rhs == 2 else
               "moe_grouped_relu2" if relu2 else "moe_grouped_matmul"),
     )(tile_expert, n_live, layer, lhs, *rhs)
 
@@ -317,7 +322,7 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
                     tiles_up: tuple[int, int] | None = None,
                     tiles_down: tuple[int, int] | None = None,
                     first: int | None = None,
-                    gated: bool = True) -> jnp.ndarray:
+                    gated: bool = True, reglu: bool = False) -> jnp.ndarray:
     """The routed experts' part of an MoE FFN, whoever routed: token t of
     ``xt`` [T, D] through its experts ``top_idx`` [T, k], weighted by
     ``gates`` [T, k] and added. Returns [T, D] in xt.dtype. The router (its
@@ -330,7 +335,8 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
     it is sorted behind the last group, into rows no live tile covers, and
     contributes nothing -- so the buffer stays T·k + n_experts·tm rows and no
     token is dropped at any skew. ``gated`` False: an expert is
-    relu(x·w1)²·w2, and there is no w3."""
+    relu(x·w1)²·w2, and there is no w3. ``reglu``: a gated expert's
+    activation is relu(x·w1) * (x·w3), not SwiGLU's."""
     T, D = xt.shape
     E, k = n_experts, top_idx.shape[1]
 
@@ -380,7 +386,7 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
     w_up, w2, layer = _stacked(lp, layer, gated)
     h = _grouped_matmul(x_pad, w_up, layer, tile_expert, n_live,
                         tm=tm, tiles=tiles_up, interpret=interpret,
-                        relu2=not gated)
+                        relu2=not gated, reglu=reglu)
     out_pad = _grouped_matmul(h, w2, layer, tile_expert, n_live,
                               tm=tm, tiles=tiles_down, interpret=interpret)
 

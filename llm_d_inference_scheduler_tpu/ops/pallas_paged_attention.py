@@ -42,6 +42,18 @@ scalar; each DMA addresses (layer, page). XLA cannot fuse a slice into a
 custom call's operand, so a kernel handed one layer's pool out of the stack
 is handed a copy of it (2 x 67 MB a layer at Qwen3-4B, 4.8 GB a decode
 step). A caller with one layer's pool passes ``pool[None]`` and layer 0.
+
+**A window of the context** (:func:`swa_paged_decode_attention_kernel`, the op
+``swa_paged_decode_attention`` in a device trace, a name of its own so that a
+trace tells the window layers' walks from the others'): layers that attend to
+the last ``window`` tokens keep a pool pair of their own under a table of their
+own (kvcache/pages.py). The wrapper cuts a lane's table to the pages its
+window reaches (ops/attention.window_table: ``window_pages`` entries, 257 for
+4,096 tokens in pages of 16) and the same stages walk those, with the rows of
+the first page that lie before the window masked (``skip_ref``).
+
+Neither form pads its query heads: 28 heads on 4 KV heads (7 a group, no
+multiple of 8) compile for a v5e as they are (tests/test_chip_compile.py).
 """
 
 from __future__ import annotations
@@ -52,6 +64,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .attention import window_table
 
 NEG_INF = -1e30
 
@@ -85,7 +99,7 @@ def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
             out_ref,                   # [1, H, D]
             k_scratch, v_scratch, sem_k, sem_v,
             *, max_blocks: int, pages: int, block: int, n_kv: int,
-            q_per_kv: int, head_dim: int):
+            q_per_kv: int, head_dim: int, skip_ref=None):
     b = pl.program_id(0)
     H = n_kv * q_per_kv
     rows = pages * block                              # tokens a stage
@@ -186,6 +200,8 @@ def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
         v = v_scratch[slot].astype(jnp.float32).reshape(cols, head_dim)
         # Row (t, g) is position s * rows + t.
         valid = own & (col < (cached_len - s * rows) * n_kv)
+        if skip_ref is not None:   # the first page's rows before the window
+            valid = valid & (col >= (skip_ref[b] - s * rows) * n_kv)
         return _absorb(carry, k, v, valid)
 
     _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
@@ -205,18 +221,56 @@ def paged_decode_attention_pallas(
     *,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    return _call(_kernel, (block_tables.reshape(-1), seq_lens), q, k_pages,
+                 v_pages, layer, cur_k, cur_v, block_tables.shape[1],
+                 interpret=interpret)
+
+
+def _window_kernel(bt_ref, sl_ref, skip_ref, layer_ref, *refs, **kw):
+    """:func:`_kernel` over a table cut to the lane's window: ``sl_ref``
+    counts from the first of those pages, ``skip_ref`` [B] is how many rows
+    of that page lie before the window."""
+    _kernel(bt_ref, sl_ref, layer_ref, *refs, skip_ref=skip_ref, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def swa_paged_decode_attention_kernel(
+    q: jnp.ndarray,            # [B, H, D]
+    k_pages: jnp.ndarray,      # [Lw, N, block, Hkv, D] — the window layers' pools
+    v_pages: jnp.ndarray,
+    layer: jnp.ndarray,         # int32 scalar
+    block_tables: jnp.ndarray,  # [B, maxB] int32, by logical page
+    seq_lens: jnp.ndarray,      # [B] int32 (incl. current token)
+    cur_k: jnp.ndarray,         # [B, Hkv, D]
+    cur_v: jnp.ndarray,
+    *,
+    window: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """ops/attention.swa_paged_decode_attention, as a kernel: a query sees
+    its own K/V and the ``window - 1`` rows cached before it."""
+    tables, lens, skip = window_table(block_tables, seq_lens,
+                                      k_pages.shape[2], window)
+    return _call(_window_kernel, (tables.reshape(-1), lens, skip), q, k_pages,
+                 v_pages, layer, cur_k, cur_v, tables.shape[1],
+                 interpret=interpret, name="swa_paged_decode_attention")
+
+
+def _call(kernel, prefetch, q, k_pages, v_pages, layer, cur_k, cur_v,
+          maxB: int, *, interpret: bool, name: str | None = None):
+    """One program a batch row over the stacked pools; ``prefetch`` are the
+    kernel's leading scalar operands, ahead of the layer."""
     B, H, D = q.shape
     _, _, block, n_kv, _ = k_pages.shape
-    maxB = block_tables.shape[1]
     q_per_kv = H // n_kv
     pages = pages_per_stage(block, n_kv, D, k_pages.dtype.itemsize, maxB)
 
     kernel = functools.partial(
-        _kernel, max_blocks=maxB, pages=pages, block=block, n_kv=n_kv,
+        kernel, max_blocks=maxB, pages=pages, block=block, n_kv=n_kv,
         q_per_kv=q_per_kv, head_dim=D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch) + 1,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
@@ -238,6 +292,6 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(block_tables.reshape(-1), seq_lens,
-      jnp.asarray(layer, jnp.int32).reshape(1),
+        name=name,      # the op's in a device trace; None: the caller's own
+    )(*prefetch, jnp.asarray(layer, jnp.int32).reshape(1),
       q, cur_k, cur_v, k_pages, v_pages)

@@ -162,19 +162,23 @@ def main(argv=None) -> int:
                 sds(state_geom.conv_shape, jnp.dtype(state_geom.dtype)),
                 slots=sds((rows,), jnp.int32), held=sds((), jnp.int32),
                 read=read), None)
-        if geom.counted:   # a latent pool that rides with counts
+        if geom.counted or geom.window:
+            # A latent pool that rides with counts, or pools of either kind
+            # beside a window pool, which rides with its step's tables.
+            window = (sds(geom.window.shape, jnp.dtype(geom.dtype))
+                      if geom.window else None)
             return (kvstate.Cache(
-                pages, None, None, None, slots=sds((rows,), jnp.int32),
-                held=sds((), jnp.int32), read=read,
+                pages, None if geom.latent_dim else pages, None, None,
+                slots=sds((rows,), jnp.int32),
+                held=sds((), jnp.int32) if geom.counted else None, read=read,
                 zero=sds((), jnp.int32) if geom.counts_zero else None,
                 counts_zero=geom.counts_zero,
                 idx=(sds(geom.index_shape, jnp.dtype(geom.dtype))
                      if geom.index_dim else None),
-                # (A window pool rides with its step's tables.)
-                win=(sds(geom.window.shape, jnp.dtype(geom.dtype))
-                     if geom.window else None),
+                win=window, win_v=None if geom.latent_dim else window,
                 wt=(sds((rows, width), jnp.int32)
-                    if geom.window else None)), None)
+                    if geom.window else None),
+                counted=geom.counted), None)
         return (pages, None) if geom.latent_dim else (pages, pages)
 
     def sampling(rows):

@@ -40,7 +40,8 @@ sys.path.insert(0, os.path.join(ROOT, "chipbench"))
 import traffic  # noqa: E402
 
 # The chip's times, ms, a mix (`use`): longctx-reason's from my chip runs,
-# PR 41, `--trace 2 --dump-trace`; longctx-wide's from my chip run, PR 45
+# PR 41, `--trace 2 --dump-trace`; longctx-16k's below; longctx-wide's from
+# my chip run, PR 45
 # (seed 3000000011: 40 decode steps of ~57 lanes at ~10k in 0.6527 s, of
 # which the two full layers' attention and indexer 3.7 ms a step; the first
 # window 16.6, continuations 29.5 / 33.5 / 37.7 / 42.5 / 59.5 by bucket).
@@ -57,13 +58,43 @@ TIMES = {
                          1024: 47.0},
         ROW_MS=2.4, ROW_FROM=4, STEP_MS=12.6, STEP_MS_PER_TOKEN=3.7 / 570e3,
         LANES=64),
+    # my chip runs, PR 48, `--trace 2 --dump-trace` (seeds 3000000011,
+    # 2148000101, 2148000404): 47 decode steps of ~28 lanes at ~8.1k in
+    # 0.828 s, 17.6 ms a step: the weights' reads, head and sampler 10.4 ms
+    # whatever the lanes, the six window layers' walks 4.40 ms = 0.155 ms a
+    # lane (4,096 rows each whatever the context), the two full layers' 2.81
+    # ms for 231k rows; the first window 22.8, continuations 27.5 / 27.6 /
+    # 28.0 / 35.9 / 44.1 by bucket, whatever the rows written (XLA attends
+    # over the whole padded bucket). The ramp decodes at 2-16 lanes, where
+    # a step is 11-13 ms: without the lane's term the model reaches the
+    # window's start seconds late. 10.1 and not the trace's 10.4: fitted
+    # to seven runs' `out_tokens_per_s` and `tpot_p95_ms` (six starts of
+    # `order` 37059, one of 0), which the model then reads within 0.5%.
+    # `DRAWN_AS_CLIENT`: this mix's shapes go to arrivals as
+    # `chipbench/client.run_chain` hands them out (`simulate`); the two
+    # older mixes keep the assignment their orders were pinned under, until
+    # a `benchmark` PR pins them anew (PERF.md section 7 (95)). `STRAY_RUNS`:
+    # a window of this mix admits ~60 consecutive shapes of 96, and what it
+    # leaves out is a run of ~36.
+    "longctx-16k": dict(
+        FIRST_WINDOW_MS=22.8,
+        CONTINUATION_MS={64: 27.5, 128: 27.6, 256: 28.0, 512: 35.9,
+                         1024: 44.1},
+        ROW_MS=0.0, ROW_FROM=4, STEP_MS=10.1, STEP_MS_PER_LANE=0.155,
+        STEP_MS_PER_TOKEN=2.81 / 231e3, LANES=32, DRAWN_AS_CLIENT=True,
+        STRAY_RUNS=(4, 8, 12, 16, 20, 24, 32, 36, 48, 60)),
 }
+# What a mix that states none of them takes: a step's cost a lane is in its
+# `STEP_MS`, a shape goes to each request in arrival order, and the search's
+# proxy looks at runs of 4-24 consecutive shapes.
+DEFAULTS = dict(STEP_MS_PER_LANE=0.0, DRAWN_AS_CLIENT=False,
+                STRAY_RUNS=(4, 8, 12, 16, 20, 24))
 WINDOW, CHUNK, STEP_WINDOWS = 1024, 8, 4
 
 
 def use(mix_name: str) -> None:
     """Take `mix_name`'s times and lanes as the module's."""
-    globals().update(TIMES[mix_name])
+    globals().update({**DEFAULTS, **TIMES[mix_name]})
 
 
 use("longctx-reason")
@@ -99,7 +130,13 @@ def simulate(mix: dict, prompts: list[int], outputs: list[int], offset: int,
     """One run from the ramp to the last answer; times in seconds from the
     window's start."""
     ramp, clients, pool = mix["ramp_s"], mix["clients"], len(prompts)
-    pending = sorted((-ramp + ramp * c / clients, c) for c in range(clients))
+    # Under `DRAWN_AS_CLIENT` every client draws its first shape before the
+    # first is due (the k-th client the k-th of the cycle: `client.run_chain`
+    # starts all its tasks at once) and a later request takes the next shape
+    # when the one before it ends; without it, a request takes the next shape
+    # as it arrives.
+    pending = sorted((-ramp + ramp * c / clients, c, c) for c in range(clients))
+    drawn = clients
     waiting, requests, pieces = [], [], []
     slots: list[dict | None] = [None] * LANES
     inflight = None                  # (lanes, end of the chunk)
@@ -107,10 +144,10 @@ def simulate(mix: dict, prompts: list[int], outputs: list[int], offset: int,
 
     def arrive() -> None:
         while pending and pending[0][0] <= now:
-            due, client = pending.pop(0)
+            due, client, k = pending.pop(0)
             if due >= seconds:       # nothing is due after the window's end
                 continue
-            i = (offset + len(requests)) % pool
+            i = (offset + (k if DRAWN_AS_CLIENT else len(requests))) % pool
             req = dict(due=due, prompt=prompts[i], out=outputs[i], client=client,
                        written=0, made=0, ahead=0, prefilling=True,
                        first_token_due=False, first=None, last=None)
@@ -121,8 +158,10 @@ def simulate(mix: dict, prompts: list[int], outputs: list[int], offset: int,
         return 1 if s["first_token_due"] else s["made"]
 
     def finish(s: dict) -> None:
+        nonlocal drawn
         s["last"] = now
-        pending.append((now + 0.002, s["client"]))   # the client's next
+        pending.append((now + 0.002, s["client"], drawn))   # the client's next
+        drawn += 1
         pending.sort()
 
     while True:
@@ -158,7 +197,8 @@ def simulate(mix: dict, prompts: list[int], outputs: list[int], offset: int,
         if lanes:
             context = sum(s["prompt"] + made(s) + s["ahead"] + CHUNK // 2
                           for s in lanes)
-            chunk_ms = CHUNK * (STEP_MS + STEP_MS_PER_TOKEN * context)
+            chunk_ms = CHUNK * (STEP_MS + STEP_MS_PER_TOKEN * context
+                                + STEP_MS_PER_LANE * len(lanes))
             for s in lanes:
                 s["ahead"] += CHUNK
         if inflight is not None:     # land the chunk before
@@ -203,16 +243,17 @@ def relative_sd(values: list[float]) -> float:
 
 # ---- the search -------------------------------------------------------------
 
-def strays(values: list[float], lengths=(4, 8, 12, 16, 20, 24)) -> float:
+def strays(values: list[float]) -> float:
     """The largest deviation from the mean of any run of consecutive shapes
-    (cyclic), over a few run lengths, as a share of one cycle's sum."""
+    (cyclic), over the mix's `STRAY_RUNS` run lengths, as a share of one
+    cycle's sum."""
     mean = sum(values) / len(values)
     twice = [v - mean for v in values] * 2
     sums = [0.0]
     for v in twice:
         sums.append(sums[-1] + v)
     worst = max(abs(sums[k + n] - sums[k])
-                for n in lengths for k in range(len(values)))
+                for n in STRAY_RUNS for k in range(len(values)))
     return worst / sum(values)
 
 

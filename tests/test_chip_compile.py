@@ -742,3 +742,54 @@ def test_a_window_of_the_mixed_model_compiles_with_both_kinds_kernels(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 3 << 29
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+
+
+# ---- window and full K/V attention in one model (SmallThinker's widths) ------
+
+@pytest.mark.parametrize("window", [0, 4096])
+def test_the_kv_kernels_compile_at_seven_heads_a_group(one_chip, window):
+    """28 query heads on 4 KV heads of 128 (7 a group: no multiple of 8, and
+    the query block is not padded), 32 lanes under a table 1,024 entries wide:
+    the full layers' walk, and the window layers' from the window's first
+    page (257 table entries a lane reach the kernel, not 1,024)."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_paged_attention as pa
+
+    lanes, heads, kv, d = 32, 28, 4, 128
+    bf16 = jnp.bfloat16
+    blocks = 9510 if window else 32769
+    pool = _sds(one_chip, (6 if window else 2, blocks, 16, kv, d), bf16)
+    cur = _sds(one_chip, (lanes, kv, d), bf16)
+    args = (_sds(one_chip, (lanes, heads, d), bf16), pool, pool,
+            _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (lanes, 1024), jnp.int32),
+            _sds(one_chip, (lanes,), jnp.int32), cur, cur)
+    fn = (functools.partial(pa.swa_paged_decode_attention_kernel,
+                            window=window) if window
+          else pa.paged_decode_attention_pallas)
+    compiled = jax.jit(fn).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert ("swa_paged_decode_attention" in hlo) == bool(window)
+    entries = 257 if window else 1024
+    assert f"s32[{lanes * entries}]" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("tokens", [512, 1024])
+def test_grouped_reglu_experts_compile_at_the_cells_widths(one_chip, tokens):
+    """64 experts of 2,560 x 768, 6 a token, the router's choices made ahead
+    of the attention: the two-operand ReGLU epilogue under its own op name,
+    at the cell's grouped prefill buckets."""
+    e, k, d, f = 64, 6, 2560, 768
+    bf16 = functools.partial(_sds, one_chip, dtype=jnp.bfloat16)
+    lp = {"w1": bf16((e, d, f)), "w3": bf16((e, d, f)), "w2": bf16((e, f, d))}
+    assert pallas_moe.use_grouped(tokens, n_experts=e, experts_per_token=k,
+                                  d_model=d, d_ff=f, platform="tpu",
+                                  interpret=False, sharded=False)
+    compiled = jax.jit(lambda lp, x, idx, gates: pallas_moe.grouped_experts(
+        lp, x, idx, gates, e, reglu=True)).lower(
+        lp, bf16((tokens, d)), _sds(one_chip, (tokens, k), jnp.int32),
+        _sds(one_chip, (tokens, k), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    assert "moe_grouped_reglu" in hlo and "moe_grouped_swiglu" not in hlo

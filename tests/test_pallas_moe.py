@@ -242,9 +242,9 @@ def test_grouped_bf16():
 
 # ---------- a held range of the experts, and experts that are not gated ----------
 
-def _plain_experts(lp, x, idx, gates, first, count, gated):
+def _plain_experts(lp, x, idx, gates, first, count, gated, reglu=False):
     """Token by token, expert by expert: the held experts' part of the
-    result."""
+    result (``reglu``: a gated expert's activation is relu, not silu)."""
     out = np.zeros(x.shape, np.float32)
     x, idx, gates = (np.asarray(a) for a in (x, idx, gates))
     w1, w2 = np.asarray(lp["w1"]), np.asarray(lp["w2"])
@@ -254,21 +254,22 @@ def _plain_experts(lp, x, idx, gates, first, count, gated):
                 continue
             up = x[t] @ w1[e - first]
             if gated:
-                h = up / (1 + np.exp(-up)) * (x[t] @ np.asarray(lp["w3"])[e - first])
+                act = np.maximum(up, 0.0) if reglu else up / (1 + np.exp(-up))
+                h = act * (x[t] @ np.asarray(lp["w3"])[e - first])
             else:
                 h = np.square(np.maximum(up, 0.0))
             out[t] += gates[t, j] * (h @ w2[e - first])
     return out
 
 
-@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("act", ["swiglu", "relu2", "reglu"])
 @pytest.mark.parametrize("first,count,routed_over", [
     (0, 4, 4),       # every expert held, named as a range
     (4, 4, 16),      # the second quarter of sixteen
     (12, 4, 16),     # the last quarter: absent rows sort behind nothing
     (3, 2, 8),       # a range aligned to nothing
 ])
-def test_grouped_experts_on_a_held_range(gated, first, count, routed_over):
+def test_grouped_experts_on_a_held_range(act, first, count, routed_over):
     """Routing is over all the experts; the rows of absent ones are dropped
     ahead of the group layout and contribute nothing, whatever lies in the
     buffer where no tile wrote."""
@@ -281,9 +282,10 @@ def test_grouped_experts_on_a_held_range(gated, first, count, routed_over):
     idx = jnp.argsort(jax.random.uniform(keys[1], (T, routed_over)),
                       axis=-1)[:, :k].astype(jnp.int32)
     gates = jax.random.uniform(keys[2], (T, k), jnp.float32, 0.2, 1.0)
+    gated, reglu = act != "relu2", act == "reglu"
     got = grouped_experts(lp, x, idx, gates, count, first=first, gated=gated,
-                          tm=8, interpret=True)
-    want = _plain_experts(lp, x, idx, gates, first, count, gated)
+                          reglu=reglu, tm=8, interpret=True)
+    want = _plain_experts(lp, x, idx, gates, first, count, gated, reglu)
     np.testing.assert_allclose(np.asarray(got), want, atol=3e-5, rtol=3e-5)
     held = (np.asarray(idx) >= first) & (np.asarray(idx) < first + count)
     assert held.any() and (routed_over == count or not held.all())
