@@ -7,6 +7,8 @@ the hot-path scatters stay static-shaped with no masking branches.
 
 from __future__ import annotations
 
+import numpy as np
+
 
 class OutOfBlocks(Exception):
     pass
@@ -174,9 +176,9 @@ class Table(list):
     """A request's block table where the cache has two kinds of layer
     (:class:`WindowedAllocator`): the list is the table of the layers that
     keep the whole context, as every other request's is, and beside it ride
-    the pages the request holds of the window layers' pool, ``window[i]`` the
-    page of logical page ``first + i`` (0 where it holds none: the trash
-    block)."""
+    the pages the request holds of the window layers' pool, whole stretches
+    of them: ``window[i]`` the page of logical page ``first + i``, ``first``
+    a multiple of the stretch (0 where it holds none: the trash block)."""
 
     def __init__(self, blocks=()):
         super().__init__(blocks)
@@ -188,22 +190,38 @@ class WindowedAllocator(BlockAllocator):
     """One owner of both kinds of cache layer (kvcache/pages.py): the blocks
     of the layers that keep the whole context are this allocator's own, a
     request's whole table at admission as ever; the pages of the layers that
-    keep a WINDOW of it come from a second pool of ``window_blocks`` ids, a
-    step at a time (:meth:`slide`), and go back once every row of them lies
-    more than ``window - 1`` behind the request's position.
+    keep a WINDOW of it come from a second pool, a step at a time
+    (:meth:`slide`), and go back once every row of them lies more than
+    ``window - 1`` behind the request's position.
+
+    The window pool is given and taken back in aligned STRETCHES of ``run``
+    pages: logical pages ``[run * k, run * k + run)`` of a request are
+    physical pages ``[1 + run * j, 1 + run * j + run)``, taken when the
+    request first writes into them, given back when every row of all of them
+    is out of reach. The decode kernels fetch a table in aligned groups of
+    ``run`` entries, ONE copy where a group names adjacent ascending blocks
+    (ops/pallas_latent_attention.stage_fetch), and cut a lane's window table
+    at a multiple of ``run`` (ops/attention.window_table): a stretch is such
+    a group whatever its neighbours are, so the free list of stretches stays
+    LIFO. (A page at a time off a LIFO list that every lane shares, the
+    window tables held next to no run: 22% of a decoding lane's groups after
+    250 chunks of the long-context cell's traffic, none after 2,000.)
 
     Admission reserves by kind: a table is handed out only while a
-    reservation of ``lane_pages`` window pages is left for it (``lanes`` of
+    reservation of ``lane_stretches`` stretches is left for it (``lanes`` of
     them: the pool's size follows the engine's lanes, never a request's
     length), so :meth:`slide` cannot run dry; with none left the allocator
     reports no free block and the head of the queue waits."""
 
     def __init__(self, n_blocks: int, block_size: int, *, window_blocks: int,
-                 window: int, lanes: int, lane_pages: int):
+                 window: int, lanes: int, lane_stretches: int, run: int):
         super().__init__(n_blocks, block_size)
-        self.window = window
-        self.lanes, self.lane_pages = lanes, lane_pages
-        self.pages = BlockAllocator(window_blocks, block_size)
+        self.window, self.run = window, run
+        self.lanes, self.lane_stretches = lanes, lane_stretches
+        # Stretch j (from 1: 0 stays the trash block's) is pages
+        # [1 + run * (j - 1), 1 + run * j) of the window pool.
+        self.stretches = BlockAllocator(1 + (window_blocks - 1) // run,
+                                        block_size)
         self.tables = 0          # live tables: each holds a reservation
 
     @property
@@ -212,56 +230,74 @@ class WindowedAllocator(BlockAllocator):
 
     @property
     def window_used_fraction(self) -> float:
-        return self.pages.used_fraction
+        return self.stretches.used_fraction
+
+    def free_window_pages(self) -> list[int]:
+        """The window pool's pages that no request holds."""
+        return [page for j in self.stretches._free
+                for page in range(1 + self.run * (j - 1), 1 + self.run * j)]
 
     def alloc(self, n: int) -> Table:
         if self.tables >= self.lanes:
             raise OutOfBlocks(
                 f"every reservation of the window pool is taken "
-                f"({self.lanes} tables of {self.lane_pages} pages)")
+                f"({self.lanes} tables of {self.lane_stretches} stretches of "
+                f"{self.run} pages)")
         self.tables += 1
         return Table(super().alloc(n))
 
     def free(self, blocks: list[int]) -> None:
         super().free(blocks)
-        self.pages.free([b for b in blocks.window if b])
+        self._give_back(blocks.window)
         blocks.window = []
         self.tables -= 1
 
     def slide(self, table: Table, start: int, end: int, row,
               ahead: bool = False) -> None:
         """``table``'s window pages for a step that writes positions
-        [start, end) and whose first query sits at ``start``: the pages all
-        of whose rows lie before ``start - (window - 1)`` go back to the
-        pool, the pages up to ``end - 1`` that the request's next step can
-        still see are taken (a long prefill window's early pages never are:
-        their rows go to the trash block), and ``row`` (a table row by
-        logical page, zeros) is filled with what the request holds. With
-        ``ahead`` (a step that is one program run once: a prefill window)
-        what only THIS step reads, the pages before ``end - (window - 1)``,
-        goes back as soon as the row is filled: the device runs its programs
-        in order, so whoever takes such a page writes it after this step has
-        read it. A decode chunk runs its steps in one program and keeps
-        them."""
-        block, reach = self.block_size, self.window - 1
+        [start, end) and whose first query sits at ``start``: the stretches
+        all of whose rows lie before ``start - (window - 1)`` go back to the
+        pool, the stretches up to ``end - 1``'s that hold a page the
+        request's next step can still see are taken (a long prefill window's
+        early stretches never are: their rows go to the trash block), and
+        ``row`` (a table row by logical page, zeros) is filled with what the
+        request holds. With ``ahead`` (a step that is one program run once: a
+        prefill window) what only THIS step reads, the stretches before
+        ``end - (window - 1)``'s, goes back as soon as the row is filled:
+        the device runs its programs in order, so whoever takes such a page
+        writes it after this step has read it. A decode chunk runs its steps
+        in one program and keeps them."""
+        block, reach, run = self.block_size, self.window - 1, self.run
         self._drop(table, max(start - reach, 0) // block)
         keep = max(start, end - reach, 0) // block
         # (A chunk that overshoots the table's width writes nobody's rows.)
-        for page in range(table.first + len(table.window),
-                          min((end - 1) // block, len(row) - 1) + 1):
-            table.window.append(self.pages.alloc(1)[0] if page >= keep else 0)
-        row[table.first:table.first + len(table.window)] = table.window
+        last = min((end - 1) // block, len(row) - 1)
+        for at in range(table.first + len(table.window), last + 1, run):
+            if at + run > keep:
+                page = 1 + run * (self.stretches.alloc(1)[0] - 1)
+                table.window += range(page, page + run)
+            else:
+                table.window += [0] * run
+        held = table.window[:max(len(row) - table.first, 0)]
+        row[table.first:table.first + len(held)] = held
         if ahead:
             self._drop(table, max(end - reach, 0) // block)
 
     def _drop(self, table: Table, upto: int) -> None:
-        """Give back ``table``'s window pages before logical page ``upto``."""
+        """Give back ``table``'s stretches that lie whole before logical
+        page ``upto``."""
+        upto -= upto % self.run
         n = min(max(upto - table.first, 0), len(table.window))
-        self.pages.free([b for b in table.window[:n] if b])
+        self._give_back(table.window[:n])
         del table.window[:n]
         table.first += n
-        if not table.window:      # nothing held: the next page taken is upto
+        if not table.window:      # nothing held: the next stretch is upto's
             table.first = max(table.first, upto)
+
+    def _give_back(self, pages: list[int]) -> None:
+        """The stretches whose pages ``pages`` lists, whole and in order."""
+        self.stretches.free([1 + (page - 1) // self.run
+                             for page in pages[::self.run] if page])
 
 
 def allocator_for(geom, prefix_caching: bool) -> BlockAllocator:
@@ -273,14 +309,15 @@ def allocator_for(geom, prefix_caching: bool) -> BlockAllocator:
         w = geom.window
         return WindowedAllocator(
             geom.n_blocks, geom.block, window_blocks=w.n_blocks,
-            window=w.window, lanes=w.lanes, lane_pages=w.lane_pages)
+            window=w.window, lanes=w.lanes, lane_stretches=w.lane_stretches,
+            run=geom.run_pages)
     if prefix_caching and not geom.state:
         return PrefixCachingAllocator(geom.n_blocks, geom.block)
     return BlockAllocator(geom.n_blocks, geom.block)
 
 
 def table_groups(blocks: list[int], group: int) -> tuple[int, int]:
-    """(runs, splits) of a request's block table, as the latent kernels walk
+    """(runs, splits) of a request's block table, as the decode kernels walk
     it at full length: aligned groups of ``group`` entries, a run where they
     name adjacent blocks in ascending order (one copy), a split otherwise
     (a copy a page; a short last group is one)."""
@@ -288,3 +325,21 @@ def table_groups(blocks: list[int], group: int) -> tuple[int, int]:
         blocks[i:i + group] == list(range(blocks[i], blocks[i] + group))
         for i in range(0, len(blocks) - group + 1, group))
     return runs, -(-len(blocks) // group) - runs
+
+
+def window_table_groups(row, position: int, block: int, window: int,
+                        group: int) -> tuple[int, int]:
+    """(runs, splits) of a decoding lane's window table ``row`` (by logical
+    page) as the window layers' decode kernels cut and walk it for the query
+    at ``position`` (ops/attention.window_table with ``align`` = ``group``,
+    ops/pallas_latent_attention.table_runs): from the aligned group of the
+    window's first page, a run where a group lies whole inside the lane's
+    cached pages and names adjacent ascending blocks, a split otherwise (the
+    short last group among them)."""
+    first = max(position + 1 - window, 0) // block
+    first -= first % group
+    n_pages = -(-position // block) - first
+    whole = np.asarray(row[first:first + n_pages // group * group]
+                       ).reshape(-1, group)
+    runs = int((whole == whole[:, :1] + np.arange(group)).all(axis=1).sum())
+    return runs, -(-n_pages // group) - runs

@@ -39,7 +39,8 @@ import numpy as np
 from ..kvcache import pages, state as state_pool, wire
 from ..models import bind
 from ..utils.hashing import chain_block_hashes
-from .blocks import PrefixCachingAllocator, allocator_for, table_groups
+from .blocks import (PrefixCachingAllocator, allocator_for, table_groups,
+                     window_table_groups)
 from .config import EngineConfig
 from .multihost import ChannelBroken
 from .request import EngineRequest, FinishReason, TokenEvent
@@ -1621,21 +1622,29 @@ class TpuEngine:
         [start, end) its row of the program writes) each; with ``ahead`` a
         program run once, a prefill window. The allocator slides each
         request's window pages to the step as it fills the row
-        (engine/blocks.WindowedAllocator). Nothing for any other cache."""
-        if self.geom.window is None:
+        (engine/blocks.WindowedAllocator), and a decoding lane's row is
+        counted by the groups the window layers' kernels fetch it in
+        (kv_window_table_groups_total). Nothing for any other cache."""
+        w = self.geom.window
+        if w is None:
             return {}
         wt = np.zeros((rows, self.max_blocks_per_seq), np.int32)
+        runs = splits = 0
         with self._cond:
             for lane, (blocks, start, end, ahead) in enumerate(steps):
                 self.allocator.slide(blocks, start, end, wt[lane], ahead)
+                if not ahead:
+                    r, s = window_table_groups(wt[lane], start, w.block,
+                                               w.window, self.geom.run_pages)
+                    runs, splits = runs + r, splits + s
             self.telemetry.observe_allocator(self.allocator)
+        self.telemetry.kv_window_table_groups["run"].inc(runs)
+        self.telemetry.kv_window_table_groups["split"].inc(splits)
         return {"wt": wt}
 
     def _note_table(self, blocks: list[int]) -> None:
-        """Count an admitted request's block table by the groups the latent
-        decode kernels fetch it in (kv_table_groups_total)."""
-        if not self.geom.run_pages:
-            return
+        """Count an admitted request's block table by the groups the decode
+        kernels fetch it in (kv_table_groups_total)."""
         runs, splits = table_groups(blocks, self.geom.run_pages)
         self.telemetry.kv_table_groups["run"].inc(runs)
         self.telemetry.kv_table_groups["split"].inc(splits)

@@ -329,14 +329,31 @@ class EngineTelemetry:
         kv_table_groups = Counter(
             "jetstream:kv_table_groups_total",
             "Groups of RUN_PAGES entries of the block tables handed to "
-            "admitted requests, as the latent decode kernels walk them "
+            "admitted requests, as the paged decode kernels of either "
+            "family walk them at full length "
             "(ops/pallas_latent_attention.stage_fetch): `run` where a group "
             "names adjacent blocks in ascending order (one copy), `split` "
             "otherwise (a copy a page; a short last group is one); counted "
-            "on the host at admission, on an engine with a latent pool",
+            "on the host at admission",
             ("kind",), registry=self.registry)
         self.kv_table_groups = {k: kv_table_groups.labels(kind=k)
                                 for k in ("run", "split")}
+        kv_window_table_groups = Counter(
+            "jetstream:kv_window_table_groups_total",
+            "Groups of RUN_PAGES entries of the WINDOW tables of decoding "
+            "lanes, as the window layers' decode kernels cut and walk them "
+            "(ops/attention.window_table from an aligned entry, "
+            "ops/pallas_latent_attention.table_runs): `run` where a group "
+            "lies inside the lane's cached pages and names adjacent blocks "
+            "in ascending order (one copy), `split` otherwise (a copy a "
+            "page; the short last group a lane is writing into is one); "
+            "counted on the host once a lane a dispatched decode chunk, at "
+            "the chunk's first position, where the window pool's owner fills "
+            "the row (engine/blocks.WindowedAllocator.slide); nothing on an "
+            "engine without window layers",
+            ("kind",), registry=self.registry)
+        self.kv_window_table_groups = {
+            k: kv_window_table_groups.labels(kind=k) for k in ("run", "split")}
         admissions = Counter(
             "jetstream:admissions_total",
             "Requests admitted into an engine slot: woken by the arrival "

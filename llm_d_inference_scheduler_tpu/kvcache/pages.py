@@ -113,19 +113,22 @@ class WindowGeometry:
     def for_engine(cls, model: Any, max_batch: int) -> "WindowGeometry | None":
         """The engine's window pool for ``model`` (anything with
         n_window_layers, window and, for a latent pool, of_window()); None
-        for a model without such layers. Every request that may be live
-        holds ``lane_pages`` at most (``max_batch`` slots, and an eighth as
-        many again for requests that finish inside the chunk in flight while
-        a successor has their slot), and one prefill window's new pages exist
-        beside its old ones for the length of a call: ``lanes x lane_pages +
-        lane_pages`` and the trash block, whatever ``max_model_len``."""
+        for a model without such layers. The pool is handed out in aligned
+        stretches of RUN_PAGES pages (engine/blocks.WindowedAllocator).
+        Every request that may be live holds ``lane_stretches`` of them at
+        most (``max_batch`` slots, and an eighth as many again for requests
+        that finish inside the chunk in flight while a successor has their
+        slot), and one prefill window's new stretches exist beside its old
+        ones for the length of a call: ``(lanes + 1) x lane_stretches``
+        stretches and the trash block, whatever ``max_model_len``."""
         if not getattr(model, "n_window_layers", 0):
             return None
         window, block = model.window, model.kv_block_size
         lanes = max_batch + max(2, max_batch // 8)
         latent = model.of_window().latent_dim if model.latent_dim else 0
         return cls(model.n_window_layers,
-                   1 + (lanes + 1) * cls.pages_of(window, block), block,
+                   1 + (lanes + 1) * RUN_PAGES
+                   * cls.stretches_of(cls.pages_of(window, block)), block,
                    latent, window, lanes, str(jnp.dtype(model.dtype)),
                    n_kv_heads=0 if latent else model.n_kv_heads,
                    head_dim=0 if latent else model.head_dim)
@@ -135,6 +138,12 @@ class WindowGeometry:
         """Pages a request holds at most between two steps: the window's
         rows and a decode chunk's, whichever way they lie across pages."""
         return -(-window // block) + 1
+
+    @staticmethod
+    def stretches_of(pages: int) -> int:
+        """Aligned stretches of RUN_PAGES that ``pages`` pages in a row, and
+        one more a decode chunk in flight writes, can lie across."""
+        return -(-pages // RUN_PAGES) + 1
 
     def describe(self, n_full: int) -> dict[str, Any]:
         """The window pool's part of /health's ``settings``, beside the
@@ -151,6 +160,12 @@ class WindowGeometry:
     @property
     def lane_pages(self) -> int:
         return self.pages_of(self.window, self.block)
+
+    @property
+    def lane_stretches(self) -> int:
+        """Stretches (of ``PageGeometry.run_pages`` pages) reserved for a
+        request."""
+        return self.stretches_of(self.lane_pages)
 
     @property
     def row_width(self) -> int:
@@ -289,10 +304,11 @@ class PageGeometry:
         return self.n_blocks * self.block_bytes
 
     @property
-    def run_pages(self) -> int | None:
-        """Table entries the latent decode kernels fetch as one copy where
-        they name adjacent blocks (None: no latent pool)."""
-        return RUN_PAGES if self.latent_dim else None
+    def run_pages(self) -> int:
+        """Table entries the paged decode kernels of either family fetch as
+        one copy where they name adjacent ascending blocks; and the pages of
+        a stretch, the unit a window pool is handed out in."""
+        return RUN_PAGES
 
     @property
     def one_chip_only(self) -> str | None:

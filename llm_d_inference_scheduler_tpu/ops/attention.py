@@ -215,26 +215,47 @@ def latent_paged_decode_attention(
     return out.astype(q.dtype)
 
 
-def window_pages(block: int, window: int) -> int:
+def window_pages(block: int, window: int, align: int = 1) -> int:
     """Pages the cached rows of a window can lie across: ``window - 1`` rows
-    (the query's own is not cached) from any offset into the first page."""
-    return (window + block - 3) // block + 1
+    (the query's own is not cached) from any offset into the first page; and
+    ``align - 1`` more ahead of them for a walk that starts at a multiple of
+    ``align`` table entries (:func:`window_cut`)."""
+    return (window + block - 3) // block + align
+
+
+def window_cut(seq_lens: jnp.ndarray, block: int, window: int,
+               align: int = 1
+               ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Where a lane's walk over its window starts, by logical page: (the
+    first table entry of the walk [B], the lane's length counted from that
+    page [B], the rows from there that lie before the window [B]) for a query
+    at ``seq_lens - 1``. The walk starts at a multiple of ``align`` entries,
+    at most ``align - 1`` before the window's first page: a kernel that
+    fetches aligned groups of the table as one copy
+    (ops/pallas_latent_attention.stage_fetch) then sees the groups as the
+    window pool handed them out (engine/blocks.WindowedAllocator, whose
+    stretches a lane keeps whole while any row of them is in reach)."""
+    first_row = jnp.maximum(seq_lens - window, 0)
+    first = first_row // block
+    first -= first % align
+    return first, seq_lens - first * block, first_row - first * block
 
 
 def window_table(block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
-                 block: int, window: int
+                 block: int, window: int, align: int = 1
                  ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """A lane's table cut to its window: (the entries of the pages that hold
-    the rows a query at ``seq_lens - 1`` sees [B, window_pages], the lane's
-    length counted from the first of those pages [B], the rows of that page
-    that lie before the window [B])."""
-    first_row = jnp.maximum(seq_lens - window, 0)
-    first = first_row // block
-    at = first[:, None] + jnp.arange(window_pages(block, window),
+    """A lane's table cut to its window (:func:`window_cut`): (the entries of
+    the pages that hold the rows a query at ``seq_lens - 1`` sees [B,
+    window_pages], the lane's length counted from the first of
+    those pages [B], the rows of those pages that lie before the window
+    [B]). The Pallas walks read the table from the cut's first entry
+    themselves; the gather here is the plain forms'."""
+    first, lens, skip = window_cut(seq_lens, block, window, align)
+    at = first[:, None] + jnp.arange(window_pages(block, window, align),
                                      dtype=first.dtype)[None, :]
     tables = jnp.take_along_axis(
         block_tables, jnp.minimum(at, block_tables.shape[1] - 1), axis=1)
-    return tables, seq_lens - first * block, first_row - first * block
+    return tables, lens, skip
 
 
 def swa_latent_decode_attention(

@@ -20,26 +20,32 @@ the MXU in the pool's dtype with f32 accumulation; the softmax state and the
 logits are f32, and the probabilities are rounded to the pool's dtype for the
 second product, as the values they weigh are.
 
-**A stage's fetch** (:func:`stage_fetch`; ops/pallas_dsa.py's two walks call
-it too). A copy's issue and its wait cost the scalar core some 38 ns a page
-whatever the page holds (4 KB or 20 KB; PERF.md section 6, PR 42), in the
-same instruction stream as the stage's matmuls, so a stage is fetched in
+**A stage's fetch** (:func:`stage_fetch`; ops/pallas_dsa.py's two walks and
+the K/V family's, ops/pallas_paged_attention.py, call it too: a tile is
+``[2, P, block, ...]`` whatever a page's trailing dims are, a latent row or
+KV heads x head_dim). A copy's issue and its wait cost the scalar core some
+38 ns a page whatever the page holds (4 KB or 20 KB; PERF.md section 6,
+PR 42), in the same instruction stream as the stage's matmuls, so a stage is fetched in
 groups of ``RUN_PAGES`` table entries: where a group names adjacent blocks
 ``b, b+1, ...`` and lies whole inside the lane's cached pages it is ONE copy
 of ``pool[layer, b : b + R]``; any other group is a copy a page, as every
 page was. Which groups are runs is read off the block table in the jitted
 wrapper (:func:`table_runs`) and prefetched beside it; the allocator hands a
-request its blocks in ascending order so that most are (engine/blocks.py).
-And a full stage is waited for once, not a copy at a time.
+request its blocks in ascending order, and a window's pages in aligned
+stretches of ``RUN_PAGES``, so that most are (engine/blocks.py). And a full
+stage is waited for once, not a copy at a time.
 
 **A window of the context** (:func:`swa_latent_decode_attention_pallas`, the
 op ``swa_latent_decode_attention``): layers that attend to the last ``window``
 tokens keep their rows in a pool of their own width under a table of their own
 (kvcache/pages.py), and a lane's walk starts at the page of its first visible
 row, ``max(0, t - (window - 1))``, not at page 0. The wrapper cuts the lane's
-table down to the pages the window reaches (:func:`window_table`), so the
-kernel is the walk above over a short table, with one more mask for the rows
-of the first page that lie before the window.
+walk down to the pages the window reaches (ops/attention.window_cut), from a
+multiple of ``RUN_PAGES`` entries so that the walk's groups are the window
+pool's stretches, and the kernel is the walk above from that entry of the
+lane's table row on, with one more mask for the rows that lie before the
+window. (The table is not gathered down to the cut: an entry at a time that
+gather was 2% of a long-context decode step.)
 """
 
 from __future__ import annotations
@@ -51,8 +57,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import window_pages, window_table  # noqa: F401
-from .pallas_paged_attention import NEG_INF, STAGE_VMEM_BYTES
+from .attention import (NEG_INF, window_cut, window_pages,  # noqa: F401
+                        window_table)
+
+# What a stage's tiles may take of VMEM, here and in the K/V family's walks
+# (ops/pallas_paged_attention.py: K and V, two slots each, in the pool's
+# dtype, and the f32 copies the products read). A quarter of the 16 MiB a
+# kernel may hold by default; the logits and what the compiler keeps besides
+# are a tenth of the tiles.
+STAGE_VMEM_BYTES = 4 * 1024 * 1024
 
 
 def pages_per_stage(block: int, width: int, itemsize: int,
@@ -97,19 +110,27 @@ def table_runs(block_tables: jnp.ndarray, seq_lens: jnp.ndarray, block: int,
 
 
 def stage_fetch(bt_ref, run_ref, pool_hbm, tile, sem, *, lane, layer, n_pages,
-                max_blocks: int, group: int, zero_rest: bool):
+                max_blocks: int, group: int, zero_rest: bool, first=0,
+                also=()):
     """``start(s, slot)`` and ``wait(s, slot)`` for stage ``s`` of ``lane``'s
-    pages: the stage's live pages of ``pool_hbm[layer]`` into ``tile[slot]``
-    ([P, block, W]), a group of ``group`` table entries at a time, one copy
+    pages, counted from entry ``first`` of its table row (a multiple of
+    ``group``; a window's walk starts inside the row): the stage's live
+    pages of ``pool_hbm[layer]`` into ``tile[slot]`` ([P, block, ...]: a
+    page's trailing dims are the pool's, a latent row or KV heads x
+    head_dim), a group of ``group`` table entries at a time, one copy
     where ``run_ref`` (:func:`table_runs`, flattened) says the group is a
     run, a copy a page where it does not and past the stage's last whole
     group. With ``zero_rest`` the start also zeroes the stage's pages past
     the lane's last (never fetched, and where rows are values too, 0 x
-    whatever VMEM held must be 0). A DMA semaphore counts bytes, so the wait
+    whatever VMEM held must be 0). ``also``: more ``(pool_hbm, tile, sem,
+    zero_rest)`` under the same table (V beside K), each into its own tile
+    on its own semaphore in the same pass: the table and the flags are read
+    once, whatever the pools. A DMA semaphore counts bytes, so the wait
     is for a region's bytes however many copies brought them: the whole slot
     in one wait where the stage is full, a group and then a page at a time
     in a lane's last stage. Loops, not unrolls: the engine traces a kernel's
     body for every decode bucket."""
+    pools = ((pool_hbm, tile, sem, zero_rest), *also)
     pages = tile.shape[1]
     groups = -(-max_blocks // group)
 
@@ -119,21 +140,22 @@ def stage_fetch(bt_ref, run_ref, pool_hbm, tile, sem, *, lane, layer, n_pages,
     def start(s, slot):
         live = live_pages(s)
 
+        def fetch(blk, at):
+            """``at`` of every tile's slot from block(s) ``blk`` on."""
+            for hbm, to, done, _ in pools:
+                pltpu.make_async_copy(hbm.at[layer, blk], to.at[slot, at],
+                                      done.at[slot]).start()
+
         def page(i, carry):
-            blk = bt_ref[lane * max_blocks + s * pages + i]
-            pltpu.make_async_copy(pool_hbm.at[layer, blk], tile.at[slot, i],
-                                  sem.at[slot]).start()
+            fetch(bt_ref[lane * max_blocks + first + s * pages + i], i)
             return carry
 
         def of_group(g, carry):
-            entry = s * pages + g * group
+            entry = first + s * pages + g * group
 
             def run():
-                blk = bt_ref[lane * max_blocks + entry]
-                pltpu.make_async_copy(
-                    pool_hbm.at[layer, pl.ds(blk, group)],
-                    tile.at[slot, pl.ds(g * group, group)],
-                    sem.at[slot]).start()
+                fetch(pl.ds(bt_ref[lane * max_blocks + entry], group),
+                      pl.ds(g * group, group))
 
             def split():
                 jax.lax.fori_loop(g * group, (g + 1) * group, page, 0)
@@ -145,33 +167,38 @@ def stage_fetch(bt_ref, run_ref, pool_hbm, tile, sem, *, lane, layer, n_pages,
         whole = live // group
         jax.lax.fori_loop(0, whole, of_group, 0)
         jax.lax.fori_loop(whole * group, live, page, 0)
-        if zero_rest:
-            def zero(i, carry):
-                tile[slot, i] = jnp.zeros(tile.shape[2:], tile.dtype)
-                return carry
 
+        def zero(i, carry):
+            for _, to, _, rest in pools:
+                if rest:
+                    to[slot, i] = jnp.zeros(to.shape[2:], to.dtype)
+            return carry
+
+        if any(rest for *_, rest in pools):
             jax.lax.fori_loop(live, pages, zero, 0)
 
     def wait(s, slot):
         live = live_pages(s)
 
-        def arrived(region):
-            pltpu.make_async_copy(region, region, sem.at[slot]).wait()
+        def arrived(at):
+            for _, to, done, _ in pools:
+                region = to.at[slot] if at is None else to.at[slot, at]
+                pltpu.make_async_copy(region, region, done.at[slot]).wait()
 
         def by_parts():
             def of_group(g, carry):
-                arrived(tile.at[slot, pl.ds(g * group, group)])
+                arrived(pl.ds(g * group, group))
                 return carry
 
             def page(i, carry):
-                arrived(tile.at[slot, i])
+                arrived(i)
                 return carry
 
             whole = live // group
             jax.lax.fori_loop(0, whole, of_group, 0)
             jax.lax.fori_loop(whole * group, live, page, 0)
 
-        jax.lax.cond(live == pages, lambda: arrived(tile.at[slot]), by_parts)
+        jax.lax.cond(live == pages, lambda: arrived(None), by_parts)
 
     return start, wait
 
@@ -183,7 +210,7 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
             out_ref,                     # [1, H, value_dim]
             tile, sem,
             *, max_blocks: int, pages: int, block: int, group: int,
-            value_dim: int, scale: float, skip_ref=None):
+            value_dim: int, scale: float, skip_ref=None, first_ref=None):
     b = pl.program_id(0)
     rows = pages * block
     q = q_ref[0]                                      # [H, W]
@@ -195,7 +222,8 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
 
     _start, _wait = stage_fetch(
         bt_ref, run_ref, pool_hbm, tile, sem, lane=b, layer=layer,
-        n_pages=n_pages, max_blocks=max_blocks, group=group, zero_rest=True)
+        n_pages=n_pages, max_blocks=max_blocks, group=group, zero_rest=True,
+        first=0 if first_ref is None else first_ref[b])
 
     @pl.when(n_stages > 0)
     def _prologue():
@@ -240,11 +268,14 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
     out_ref[0] = (acc / l).astype(out_ref.dtype)
 
 
-def _window_kernel(bt_ref, run_ref, sl_ref, skip_ref, layer_ref, *refs, **kw):
-    """:func:`_kernel` over the pages a lane's window reaches: ``sl_ref``
-    counts from the first of them, ``skip_ref`` [B] is how many rows of that
-    page lie before the window."""
-    _kernel(bt_ref, run_ref, sl_ref, layer_ref, *refs, skip_ref=skip_ref, **kw)
+def _window_kernel(bt_ref, run_ref, sl_ref, skip_ref, first_ref, layer_ref,
+                   *refs, **kw):
+    """:func:`_kernel` over the pages a lane's window reaches
+    (ops/attention.window_cut): the walk starts at entry ``first_ref`` [B] of
+    the lane's table row, ``sl_ref`` counts from that page, ``skip_ref`` [B]
+    is how many rows from there lie before the window."""
+    _kernel(bt_ref, run_ref, sl_ref, layer_ref, *refs, skip_ref=skip_ref,
+            first_ref=first_ref, **kw)
 
 
 @functools.partial(jax.jit,
@@ -319,9 +350,12 @@ def swa_latent_decode_attention_pallas(
     its own row and the ``window - 1`` cached before it."""
     B, H, Dk = q.shape
     _, _, block, W = pages.shape
-    tables, lens, skip = window_table(block_tables, seq_lens, block, window)
-    maxB = tables.shape[1]
-    n_pages = pages_per_stage(block, W, pages.dtype.itemsize, maxB)
+    maxB = block_tables.shape[1]
+    first, lens, skip = window_cut(seq_lens, block, window, align=RUN_PAGES)
+    # A stage as the walk's own length allows, not the whole table's.
+    n_pages = pages_per_stage(
+        block, W, pages.dtype.itemsize,
+        min(maxB, window_pages(block, window, align=RUN_PAGES)))
     group = run_pages(n_pages)
     pad = [(0, 0)] * 2 + [(0, W - Dk)]
     q = jnp.pad(q, pad).astype(pages.dtype)
@@ -331,7 +365,7 @@ def swa_latent_decode_attention_pallas(
         _window_kernel, max_blocks=maxB, pages=n_pages, block=block,
         group=group, value_dim=value_dim, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
@@ -344,12 +378,15 @@ def swa_latent_decode_attention_pallas(
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
+    # The table whole, as the full layers' walks take theirs: the kernel
+    # reads it from ``first`` on, and its groups of entries are the table's
+    # own aligned groups (``first`` is a multiple of RUN_PAGES).
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
         interpret=interpret,
         name="swa_latent_decode_attention",
-    )(tables.reshape(-1),
-      table_runs(tables, lens, block, group).reshape(-1), lens, skip,
-      jnp.asarray(layer, jnp.int32).reshape(1), q, cur, pages)
+    )(block_tables.reshape(-1),
+      table_runs(block_tables, seq_lens, block, group).reshape(-1), lens,
+      skip, first, jnp.asarray(layer, jnp.int32).reshape(1), q, cur, pages)

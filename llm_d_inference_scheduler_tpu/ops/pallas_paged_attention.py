@@ -10,13 +10,24 @@ VMEM. Pattern follows the ragged/paged attention design used by TPU serving
 stacks (PAPERS.md: Ragged Paged Attention, arXiv 2604.15464).
 
 Grid: one program per batch row. A program works in *stages* of P pages
-(P x block tokens). A stage's P K copies and P V copies are started together,
-each page `[block, Hkv, D]` into its `block` rows of a `[P*block, Hkv, D]`
-VMEM tile, and the next stage's 2P copies are in flight while this one is
-computed. The loop runs over the lane's own stages, cdiv(pages it holds, P),
-so a short lane pays for neither the table's width nor a wide stage: pages of
-its last stage past its length are not fetched (their V rows are zeroed, their
-logits masked).
+(P x block tokens). A stage's K pages and V pages are fetched together, each
+pool's into a `[P, block, Hkv, D]` VMEM tile of its own, and the next stage's
+copies are in flight while this one is computed. The loop runs over the
+lane's own stages, cdiv(pages it holds, P), so a short lane pays for neither
+the table's width nor a wide stage: pages of its last stage past its length
+are not fetched (their V rows are zeroed, their logits masked).
+
+A stage is fetched as the latent family's walks fetch theirs
+(ops/pallas_latent_attention.stage_fetch, one function for all five): a
+copy's issue costs the scalar core ~38 ns whatever the page holds, against
+20-40 ns of bytes a 16-32 KB page of K and V, so the table is taken in groups
+of R = ``run_pages(P)`` entries, ONE copy of ``pool[layer, b : b + R]`` where
+a group names adjacent ascending blocks inside the lane's cached pages
+(``table_runs``, computed in the jitted wrappers and prefetched beside the
+table), a copy a page elsewhere, K and V in the same pass over the table, and
+a full stage is waited for once a pool.
+The allocators hand out blocks so that the runs exist (engine/blocks.py: a
+table ascending, a window's pages in aligned stretches of ``RUN_PAGES``).
 
 A stage is computed without a loop over the KV heads. The tile is read as
 [P*block*Hkv, D] — row (t, g) is token t's head g, which is how the page lies
@@ -47,10 +58,15 @@ step). A caller with one layer's pool passes ``pool[None]`` and layer 0.
 ``swa_paged_decode_attention`` in a device trace, a name of its own so that a
 trace tells the window layers' walks from the others'): layers that attend to
 the last ``window`` tokens keep a pool pair of their own under a table of their
-own (kvcache/pages.py). The wrapper cuts a lane's table to the pages its
-window reaches (ops/attention.window_table: ``window_pages`` entries, 257 for
-4,096 tokens in pages of 16) and the same stages walk those, with the rows of
-the first page that lie before the window masked (``skip_ref``).
+own (kvcache/pages.py). The wrapper reckons where in a lane's table row its
+window starts (ops/attention.window_cut: ``window_pages`` entries are in
+reach, 257 for 4,096 tokens in pages of 16) and the same stages walk the row
+from there (``first_ref``), with the rows that lie before the window masked
+(``skip_ref``). The walk starts at a multiple of ``RUN_PAGES`` table entries,
+up to ``RUN_PAGES - 1`` pages before the window's first, so that the kernel's
+groups are the aligned stretches the window pool hands out
+(engine/blocks.WindowedAllocator) and not a shifted view of them that holds
+no run.
 
 Neither form pads its query heads: 28 heads on 4 KV heads (7 a group, no
 multiple of 8) compile for a v5e as they are (tests/test_chip_compile.py).
@@ -65,20 +81,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import window_table
-
-NEG_INF = -1e30
-
-# What a stage's tiles may take of VMEM: K and V, two slots each, in the
-# pool's dtype, and the f32 copies the products read. A quarter of the 16 MiB
-# a kernel may hold by default; the logits and what the compiler keeps
-# besides are a tenth of the tiles.
-STAGE_VMEM_BYTES = 4 * 1024 * 1024
+from .attention import NEG_INF, window_cut, window_pages
+from .pallas_latent_attention import (RUN_PAGES, STAGE_VMEM_BYTES, run_pages,
+                                      stage_fetch, table_runs)
 
 
 def stage_vmem_bytes(pages: int, block: int, n_kv: int, head_dim: int,
                      itemsize: int) -> int:
-    """Bytes of VMEM that stages of `pages` pages hold at once."""
+    """Bytes of VMEM that stages of `pages` pages hold at once: K and V, two
+    slots each, in the pool's dtype, and the f32 copies the products read."""
     tile = pages * block * n_kv * head_dim
     return 2 * 2 * tile * itemsize + 2 * tile * 4
 
@@ -93,13 +104,14 @@ def pages_per_stage(block: int, n_kv: int, head_dim: int, itemsize: int,
     return 1 << (p.bit_length() - 1)
 
 
-def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
+def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
+            #                                      [B*maxB/R], [B], [1]
             q_ref, cur_k_ref, cur_v_ref,  # VMEM blocks per program
             k_hbm, v_hbm,              # stacked page arrays (ANY/HBM)
             out_ref,                   # [1, H, D]
-            k_scratch, v_scratch, sem_k, sem_v,
-            *, max_blocks: int, pages: int, block: int, n_kv: int,
-            q_per_kv: int, head_dim: int, skip_ref=None):
+            k_tile, v_tile, sem_k, sem_v,   # [2, P, block, Hkv, D] each pool
+            *, max_blocks: int, pages: int, block: int, group: int, n_kv: int,
+            q_per_kv: int, head_dim: int, skip_ref=None, first_ref=None):
     b = pl.program_id(0)
     H = n_kv * q_per_kv
     rows = pages * block                              # tokens a stage
@@ -110,43 +122,15 @@ def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
     cached_len = sl_ref[b] - 1                        # rows valid in pages
     n_pages = pl.cdiv(cached_len, block)
     n_stages = pl.cdiv(n_pages, pages)
-    layer = layer_ref[0]
 
-    def _rows(i):
-        return pl.ds(pl.multiple_of(i * block, block), block)
-
-    def _each_page(s, slot, do):
-        """`do(K copy, V copy)` for each page of stage `s` the lane holds.
-        A loop, not an unroll: a stage is bound by its DMAs either way, and
-        the engine traces this body once for every decode bucket."""
-        def page(i, carry):
-            blk = bt_ref[b * max_blocks + s * pages + i]
-            do(pltpu.make_async_copy(k_hbm.at[layer, blk],
-                                     k_scratch.at[slot, _rows(i)],
-                                     sem_k.at[slot]),
-               pltpu.make_async_copy(v_hbm.at[layer, blk],
-                                     v_scratch.at[slot, _rows(i)],
-                                     sem_v.at[slot]))
-            return carry
-
-        live = jnp.minimum(pages, n_pages - s * pages)
-        jax.lax.fori_loop(0, live, page, 0)
-        return live
-
-    def _start(s, slot):
-        live = _each_page(s, slot, lambda ck, cv: (ck.start(), cv.start()))
-
-        def zero_v(i, carry):
-            # Never fetched, and 0 x whatever VMEM held must be 0: K's rows
-            # are masked as logits, V's enter the product.
-            v_scratch[slot, _rows(i)] = jnp.zeros((block, n_kv, head_dim),
-                                                  v_scratch.dtype)
-            return carry
-
-        jax.lax.fori_loop(live, pages, zero_v, 0)
-
-    def _wait(s, slot):
-        _each_page(s, slot, lambda ck, cv: (ck.wait(), cv.wait()))
+    # K and V in one pass over the table, each into its own tile on its own
+    # semaphore. K's rows past the lane's last page are masked as logits;
+    # V's enter the product, and 0 x whatever VMEM held must be 0.
+    _start, _wait = stage_fetch(
+        bt_ref, run_ref, k_hbm, k_tile, sem_k, zero_rest=False,
+        also=((v_hbm, v_tile, sem_v, True),), lane=b, layer=layer_ref[0],
+        n_pages=n_pages, max_blocks=max_blocks, group=group,
+        first=0 if first_ref is None else first_ref[b])
 
     @pl.when(n_stages > 0)
     def _prologue():
@@ -196,11 +180,11 @@ def _kernel(bt_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB], [B], [1]
             _start(s + 1, 1 - slot)
 
         _wait(s, slot)
-        k = k_scratch[slot].astype(jnp.float32).reshape(cols, head_dim)
-        v = v_scratch[slot].astype(jnp.float32).reshape(cols, head_dim)
+        k = k_tile[slot].astype(jnp.float32).reshape(cols, head_dim)
+        v = v_tile[slot].astype(jnp.float32).reshape(cols, head_dim)
         # Row (t, g) is position s * rows + t.
         valid = own & (col < (cached_len - s * rows) * n_kv)
-        if skip_ref is not None:   # the first page's rows before the window
+        if skip_ref is not None:   # the rows that lie before the window
             valid = valid & (col >= (skip_ref[b] - s * rows) * n_kv)
         return _absorb(carry, k, v, valid)
 
@@ -221,16 +205,19 @@ def paged_decode_attention_pallas(
     *,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    return _call(_kernel, (block_tables.reshape(-1), seq_lens), q, k_pages,
+    return _call(_kernel, block_tables, seq_lens, seq_lens, (), q, k_pages,
                  v_pages, layer, cur_k, cur_v, block_tables.shape[1],
                  interpret=interpret)
 
 
-def _window_kernel(bt_ref, sl_ref, skip_ref, layer_ref, *refs, **kw):
-    """:func:`_kernel` over a table cut to the lane's window: ``sl_ref``
-    counts from the first of those pages, ``skip_ref`` [B] is how many rows
-    of that page lie before the window."""
-    _kernel(bt_ref, sl_ref, layer_ref, *refs, skip_ref=skip_ref, **kw)
+def _window_kernel(bt_ref, run_ref, sl_ref, skip_ref, first_ref, layer_ref,
+                   *refs, **kw):
+    """:func:`_kernel` over the pages a lane's window reaches
+    (ops/attention.window_cut): the walk starts at entry ``first_ref`` [B] of
+    the lane's table row, ``sl_ref`` counts from that page, ``skip_ref`` [B]
+    is how many rows from there lie before the window."""
+    _kernel(bt_ref, run_ref, sl_ref, layer_ref, *refs, skip_ref=skip_ref,
+            first_ref=first_ref, **kw)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -249,25 +236,39 @@ def swa_paged_decode_attention_kernel(
 ) -> jnp.ndarray:
     """ops/attention.swa_paged_decode_attention, as a kernel: a query sees
     its own K/V and the ``window - 1`` rows cached before it."""
-    tables, lens, skip = window_table(block_tables, seq_lens,
-                                      k_pages.shape[2], window)
-    return _call(_window_kernel, (tables.reshape(-1), lens, skip), q, k_pages,
-                 v_pages, layer, cur_k, cur_v, tables.shape[1],
+    block = k_pages.shape[2]
+    first, lens, skip = window_cut(seq_lens, block, window, align=RUN_PAGES)
+    # The table whole, as the full layers' walk takes its own: the kernel
+    # reads it from ``first`` on, and its groups of entries are the table's
+    # own aligned groups (``first`` is a multiple of RUN_PAGES).
+    return _call(_window_kernel, block_tables, seq_lens, lens, (skip, first),
+                 q, k_pages, v_pages, layer, cur_k, cur_v,
+                 min(block_tables.shape[1],
+                     window_pages(block, window, align=RUN_PAGES)),
                  interpret=interpret, name="swa_paged_decode_attention")
 
 
-def _call(kernel, prefetch, q, k_pages, v_pages, layer, cur_k, cur_v,
-          maxB: int, *, interpret: bool, name: str | None = None):
-    """One program a batch row over the stacked pools; ``prefetch`` are the
-    kernel's leading scalar operands, ahead of the layer."""
+def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
+          cur_k, cur_v, walk: int, *, interpret: bool,
+          name: str | None = None):
+    """One program a batch row over the stacked pools. The kernel's scalar
+    operands: ``tables`` [B, maxB], which of their groups are runs (by
+    ``seq_lens``, the lanes' whole lengths), ``lens`` [B] (the lengths the
+    walk counts), whatever ``more`` holds, and the layer. ``walk``: the table
+    entries a lane's walk reads at most, which bounds a stage."""
     B, H, D = q.shape
     _, _, block, n_kv, _ = k_pages.shape
+    maxB = tables.shape[1]
     q_per_kv = H // n_kv
-    pages = pages_per_stage(block, n_kv, D, k_pages.dtype.itemsize, maxB)
+    pages = pages_per_stage(block, n_kv, D, k_pages.dtype.itemsize, walk)
+    group = run_pages(pages)
+    prefetch = (tables.reshape(-1),
+                table_runs(tables, seq_lens, block, group).reshape(-1), lens,
+                *more)
 
     kernel = functools.partial(
-        kernel, max_blocks=maxB, pages=pages, block=block, n_kv=n_kv,
-        q_per_kv=q_per_kv, head_dim=D)
+        kernel, max_blocks=maxB, pages=pages, block=block, group=group,
+        n_kv=n_kv, q_per_kv=q_per_kv, head_dim=D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch) + 1,
@@ -281,8 +282,8 @@ def _call(kernel, prefetch, q, k_pages, v_pages, layer, cur_k, cur_v,
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, pages * block, n_kv, D), k_pages.dtype),
-            pltpu.VMEM((2, pages * block, n_kv, D), v_pages.dtype),
+            pltpu.VMEM((2, pages, block, n_kv, D), k_pages.dtype),
+            pltpu.VMEM((2, pages, block, n_kv, D), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],

@@ -139,6 +139,12 @@ def main(argv=None) -> int:
     ap.add_argument("--dtype", default="")
     ap.add_argument("--fault", default="", choices=(
         "", "router_bf16", "no_gate", "window_512", "stale_page"))
+    ap.add_argument("--shuffle-tables", action="store_true",
+                    help="put both pools' pages at shuffled physical ids "
+                         "under the owner's tables (no runs of adjacent "
+                         "pages for the decode kernels to fetch as one "
+                         "copy); default as the owner hands them out: "
+                         "ascending tables, the window pool in stretches")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 
@@ -284,6 +290,15 @@ def main(argv=None) -> int:
         tables = np.zeros((B, per_seq), np.int32)
         for lane, blocks in enumerate(held):
             tables[lane, :len(blocks)] = blocks
+        # Where each pool's page ids lie: as the owner names them, or (the
+        # trash page apart) shuffled, one fixed bijection a pool a seed, so
+        # that no two neighbours of a table are adjacent in the pool.
+        place, place_w = (
+            np.concatenate([[0], 1 + (np.random.default_rng(
+                seed + i).permutation(n - 1) if args.shuffle_tables
+                else np.arange(n - 1))]).astype(np.int32)
+            for i, n in enumerate((geom.n_blocks, geom.window.n_blocks)))
+        tables = place[tables]
         looked = [[] for _ in lens]        # (position, logits) a lane
         routes_of = [[] for _ in lens]     # [Le, tokens, k] pieces a lane
         picked_of = [np.zeros((geom.n_layers, n + K, n + K), bool)
@@ -300,7 +315,7 @@ def main(argv=None) -> int:
                 toks[0, :m] = seq[lo:lo + m]
                 wt = np.zeros((1, per_seq), np.int32)
                 owner.slide(held[lane], lo, lo + m, wt[0], True)
-                at = state.at_slots(cache, [lane], wt)
+                at = state.at_slots(cache, [lane], place_w[wt])
                 if lo == 0:
                     got, routes, picked, cache = prefill(
                         params, toks, np.full((1,), m, np.int32), at, row)
@@ -331,12 +346,14 @@ def main(argv=None) -> int:
                 owner.slide(held[lane], int(t), int(t) + 1, wt[lane])
                 given_back += held[lane].first - before
             if args.fault == "stale_page":
-                for lane in range(B):
+                reach = mcfg.window_attn.window - 1
+                for lane, t in enumerate(positions):
                     other = held[(lane + 1) % B]
-                    wt[lane, held[lane].first] = other.window[-1]
+                    wt[lane, max(int(t) - reach, 0) // block] = \
+                        other.window[-1]
             logits, routes, picked, cache = decode(
                 params, seq[positions], positions,
-                state.at_slots(cache, np.arange(B), wt), tables)
+                state.at_slots(cache, np.arange(B), place_w[wt]), tables)
             cache, *_ = state.take_counts(cache)
             steps.append(np.asarray(logits))                    # [B, V]
             routes, picked = np.asarray(routes), np.asarray(picked)
@@ -416,6 +433,7 @@ def main(argv=None) -> int:
                 "window_attention": mcfg.swa_impl,
                 "held_experts": list(mcfg.held_experts),
                 "lane_tokens": lens, "prefill_window": W, "decode_steps": K,
+                "tables": "shuffled" if args.shuffle_tables else "owner's",
                 "window_pages": {"given_back_in_decode": given_back,
                                  "most_held_by_a_lane": most_pages,
                                  "pool": geom.window.n_blocks - 1},

@@ -138,6 +138,12 @@ def main(argv=None) -> int:
     ap.add_argument("--q-block", type=int, default=256)
     ap.add_argument("--dtype", default="")
     ap.add_argument("--fault", default="", choices=FAULTS)
+    ap.add_argument("--shuffle-tables", action="store_true",
+                    help="put both pools' pages at shuffled physical ids "
+                         "under the owner's tables (no runs of adjacent "
+                         "pages for the decode kernels to fetch as one "
+                         "copy); default as the owner hands them out: "
+                         "ascending tables, the window pool in stretches")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 
@@ -287,6 +293,15 @@ def main(argv=None) -> int:
         tables = np.zeros((B, per_seq), np.int32)
         for lane, blocks in enumerate(held):
             tables[lane, :len(blocks)] = blocks
+        # Where each pool's page ids lie: as the owner names them, or (the
+        # trash page apart) shuffled, one fixed bijection a pool a seed, so
+        # that no two neighbours of a table are adjacent in the pool.
+        place, place_w = (
+            np.concatenate([[0], 1 + (np.random.default_rng(
+                seed + i).permutation(n - 1) if args.shuffle_tables
+                else np.arange(n - 1))]).astype(np.int32)
+            for i, n in enumerate((geom.n_blocks, geom.window.n_blocks)))
+        tables = place[tables]
         looked = [[] for _ in lens]        # (position, logits) a lane
         routes_of = [[] for _ in lens]     # [L, tokens, k] pieces a lane
         given_back = 0
@@ -301,7 +316,7 @@ def main(argv=None) -> int:
                 toks[0, :m] = seq[lo:lo + m]
                 wt = np.zeros((1, per_seq), np.int32)
                 owner.slide(held[lane], lo, lo + m, wt[0], True)
-                at = state.at_slots(cache, [lane], wt)
+                at = state.at_slots(cache, [lane], place_w[wt])
                 if lo == 0:
                     got, routes, cache = prefill(
                         params, toks, np.full((1,), m, np.int32), at, row)
@@ -327,7 +342,7 @@ def main(argv=None) -> int:
                 given_back += held[lane].first - before
             logits, routes, cache = decode(
                 params, seq[positions], positions,
-                state.at_slots(cache, np.arange(B), wt), tables)
+                state.at_slots(cache, np.arange(B), place_w[wt]), tables)
             cache, *_ = state.take_counts(cache)
             steps.append(np.asarray(logits))                    # [B, V]
             for lane in range(B):
@@ -405,6 +420,7 @@ def main(argv=None) -> int:
                 "moe_form": {str(rows): bound.model_for(rows).moe_impl
                              for rows in (B, W)},
                 "lane_tokens": lens, "prefill_window": W, "decode_steps": K,
+                "tables": "shuffled" if args.shuffle_tables else "owner's",
                 "window_pages": {"given_back_in_decode": given_back,
                                  "most_held_by_a_lane": most_pages,
                                  "pool": geom.window.n_blocks - 1},

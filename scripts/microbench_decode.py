@@ -7,7 +7,19 @@ so a regression in one component is visible without reading a profiler trace.
 Prints one JSON line per (component, point). The kernel's line also gives ms
 a call (one layer), us a page fetched, and the share of the chip's peak
 bandwidth that the bytes the call needs (chipbench/kernels.py) come to.
-Everything runs in this one process (one process per chip).
+Everything runs in this one process (one process per chip). With ``--tables``
+the kernel's line is printed for block tables whose groups of RUN_PAGES
+entries name adjacent pages never (``shuffled``), as often as the allocator
+leaves them under a closed loop's churn (``churn``) and always (``runs``, the
+default), and with ``--kv-window N`` the window layers' walk
+(``swa_paged_decode_attention``) is timed beside it over window tables of the
+same kinds, the churned ones as engine/blocks.WindowedAllocator leaves them
+(``window_churn``). ``--config-file`` takes the shapes from a benchmark
+configuration and ``--attn-only`` leaves out the components that need the
+model's weights: SmallThinker's two walks are
+``--config-file chipbench/configs/smallthinker-21b-a3b-cut.json --attn-only
+--points 32x8100 --max-model-len 16384 --tables shuffled,churn,runs
+--kv-window 4096``.
 
 ``--moe`` times one MoE FFN layer at prefill shapes instead (Mixtral's widths,
 T tokens of one prompt): the dense-over-experts einsums against the grouped
@@ -58,7 +70,8 @@ engine binds): ms a call (one layer), the bytes of scores the form puts into
 memory, and how far the tiled form lies from the whole one.
 
 Usage: python scripts/microbench_decode.py [--model qwen3-4b]
-           [--points 16x1000,16x300,8x300]
+           [--points 16x1000,16x300,8x300] [--tables shuffled,churn,runs]
+           [--kv-window 4096] [--config-file FILE] [--attn-only]
        python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
            [--moe-candidates] [--moe-check-seeds 0,1]
        python scripts/microbench_decode.py --moe-decode [--moe-candidates]
@@ -564,6 +577,67 @@ def churned_tables(lanes: int, max_model_len: int, prompts=(4096, 16384),
     return handed
 
 
+def window_churn(geom, lanes: int, prompts=(4096, 12288),
+                 outputs=(512, 1536), *,
+                 prefill: int = 1024, chunk: int = 8, chunks: int = 300,
+                 seed: int = 0):
+    """The window tables engine/blocks.WindowedAllocator leaves under a
+    closed loop's churn (``geom``: the engine's kvcache/pages.PageGeometry;
+    the defaults are longctx-16k's): ``lanes`` requests in flight, prompts
+    log-uniform and outputs uniform, a prompt written in windows of
+    ``prefill`` tokens ahead of the decode chunks (at most 4,096 tokens of
+    prompt a step, oldest request first, as the engine's loop does), a decode
+    chunk of ``chunk`` steps a decoding lane a turn, a lane that ends freed
+    and its successor admitted at the next turn. Yields, ``chunks`` times,
+    (the allocator, [(table, window row, position)] of the lanes that decoded
+    this turn), and frees what is live at the end. A count of what the
+    allocator does, not a measurement."""
+    import math
+    import random
+
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.engine.blocks import allocator_for
+
+    rng = random.Random(seed)
+    owner = allocator_for(geom, False)
+    width, block = geom.max_blocks_per_seq, geom.block
+    slots = [None] * lanes
+    for turn in range(chunks):
+        for i, s in enumerate(slots):
+            if s is None:
+                prompt = int(math.exp(rng.uniform(*map(math.log, prompts))))
+                prompt = max(1, min(prompt, width * block - outputs[1]))
+                end = prompt + rng.randint(*outputs)
+                slots[i] = dict(table=owner.alloc(-(-end // block)),
+                                prompt=prompt, end=end, written=0, at=turn)
+        budget = max(1, 4096 // prefill)
+        for _, i in sorted((s["at"], i) for i, s in enumerate(slots)
+                           if s["written"] < s["prompt"]):
+            s = slots[i]
+            while budget and s["written"] < s["prompt"]:
+                hi = min(s["written"] + prefill, s["prompt"])
+                owner.slide(s["table"], s["written"], hi,
+                            np.zeros(width, np.int32), True)
+                s["written"], budget = hi, budget - 1
+                s["pos"] = hi
+        decoded = []
+        for i, s in enumerate(slots):
+            if s["written"] < s["prompt"]:
+                continue
+            row = np.zeros(width, np.int32)
+            owner.slide(s["table"], s["pos"], s["pos"] + chunk, row)
+            decoded.append((s["table"], row, s["pos"]))
+            s["pos"] += chunk
+        yield owner, decoded
+        for i, s in enumerate(slots):
+            if s["written"] == s["prompt"] and s["pos"] >= s["end"]:
+                owner.free(s["table"])
+                slots[i] = None
+    for s in filter(None, slots):
+        owner.free(s["table"])
+
+
 def latent_main(args):
     """The latent family's paged decode walks at ``--latent-config``'s
     widths, over tables with runs of adjacent pages and without."""
@@ -881,6 +955,21 @@ def main(argv=None):
     ap.add_argument("--points", default="16x1000,16x300,8x300",
                     help="lanes x context tokens a lane, comma-separated")
     ap.add_argument("--max-model-len", type=int, default=1024)
+    ap.add_argument("--tables", default="runs",
+                    help="block tables to time the attention kernel over: "
+                         "shuffled, churn, runs")
+    ap.add_argument("--kv-window", type=int, default=0,
+                    help="also time the window layers' walk "
+                         "(swa_paged_decode_attention) at this window")
+    ap.add_argument("--config-file", default="",
+                    help="take the shapes from this benchmark configuration "
+                         "(chipbench/configs/<name>.json), not from --model")
+    ap.add_argument("--attn-only", action="store_true",
+                    help="the attention kernels alone: no weights are made")
+    ap.add_argument("--interpret", action="store_true",
+                    help="rehearse the attention kernels through the Pallas "
+                         "interpreter on the CPU; the times mean nothing")
+    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     import jax
@@ -903,31 +992,66 @@ def main(argv=None):
     if args.window:
         return window_main(args)
 
+    import types
+
     from llm_d_inference_scheduler_tpu.engine.sampling import sample_tokens
     from llm_d_inference_scheduler_tpu.kvcache import pages
     from llm_d_inference_scheduler_tpu.models import llama
     from llm_d_inference_scheduler_tpu.models.configs import get_config
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+    from llm_d_inference_scheduler_tpu.ops import attention as plain
+    from llm_d_inference_scheduler_tpu.ops import pallas_paged_attention
+    from llm_d_inference_scheduler_tpu.ops.pallas_latent_attention import (
+        RUN_PAGES,
+        table_runs,
+    )
 
     kernels = _chipbench_kernels()
 
-    mcfg = get_config(args.model)
+    if args.config_file:
+        with open(args.config_file) as f:
+            mcfg = config_from_hf(types.SimpleNamespace(**json.load(f)),
+                                  name=os.path.basename(
+                                      args.config_file)[:-len(".json")])
+    else:
+        mcfg = get_config(args.model)
     block = mcfg.kv_block_size
-    kernel = functools.partial(pages.decode_attention, kernel=True)
-    params = llama.init_params(mcfg, jax.random.key(0))
+    kernel = functools.partial(pages.decode_attention, kernel=True,
+                               interpret=args.interpret)
+    params = None if args.attn_only else llama.init_params(
+        mcfg, jax.random.key(0))
+    # Interpreted on the CPU the times mean nothing: no share of a peak then.
+    peak = None if args.interpret else kernels.peaks(
+        jax.devices()[0].device_kind)["bytes_per_s"]
 
     def report(component, B, ctx, ms, **more):
         print(json.dumps({"component": component, "B": B, "ctx": ctx,
                           "ms_per_step": round(ms, 3), **more}), flush=True)
 
+    def rel_err(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    def run_share(tables, seq_lens):
+        """Of the whole groups inside the lanes' cached pages, the runs."""
+        runs = np.asarray(table_runs(tables, seq_lens, block, RUN_PAGES))
+        whole = -(-(np.asarray(seq_lens) - 1) // block) // RUN_PAGES
+        return round(100 * float(runs.sum() / max(whole.sum(), 1)), 1)
+
     for B, ctx in [map(int, pt.split("x")) for pt in args.points.split(",")]:
         max_blocks = args.max_model_len // block
         L, G, D = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
-        k_pages, v_pages = pages.alloc(pages.PageGeometry.for_model(
-            mcfg, 1 + B * max_blocks, max_blocks, dtype="bfloat16"))
-        tables = np.zeros((B, max_blocks), np.int32)
-        for b in range(B):
-            tables[b] = np.arange(1 + b * max_blocks, 1 + (b + 1) * max_blocks)
-        tables = jnp.asarray(tables)
+        if args.attn_only:
+            # The walks alone: two layers' pools do, read in turn CALLS times.
+            L, calls = 2, 24
+            k_pages = v_pages = jnp.zeros(
+                (L, 1 + B * max_blocks, block, G, D), jnp.bfloat16)
+        else:
+            calls = L
+            k_pages, v_pages = pages.alloc(pages.PageGeometry.for_model(
+                mcfg, 1 + B * max_blocks, max_blocks, dtype="bfloat16"))
+        ids = 1 + np.arange(B * max_blocks, dtype=np.int32)
+        tables = jnp.asarray(ids.reshape(B, max_blocks))
         tokens = jnp.ones((B,), jnp.int32)
         positions = jnp.full((B,), ctx, jnp.int32)
 
@@ -945,34 +1069,132 @@ def main(argv=None):
             (kp, vp), ls = jax.lax.scan(body, (k_pages, v_pages), None, length=8)
             return ls.sum()
 
-        ms = timeit(jax.jit(chain), params, k_pages, v_pages, iters=5) / 8
-        report("decode_step(all)", B, ctx, ms)
+        if not args.attn_only:
+            ms = timeit(jax.jit(chain), params, k_pages, v_pages, iters=5) / 8
+            report("decode_step(all)", B, ctx, ms)
 
         # attention kernel alone
-        q = jnp.ones((B, mcfg.n_heads, D), jnp.bfloat16)
-        cur = jnp.ones((B, G, D), jnp.bfloat16)
+        keys = jax.random.split(jax.random.key(args.seed), 4)
+        q = jax.random.normal(keys[0], (B, mcfg.n_heads, D), jnp.bfloat16)
+        cur = jax.random.normal(keys[1], (B, G, D), jnp.bfloat16)
         seq_lens = jnp.full((B,), ctx + 1, jnp.int32)
+        if args.tables != "runs" or args.kv_window:
+            # Rows worth comparing with the plain form's.
+            k_pages = jax.random.normal(keys[2], k_pages.shape, k_pages.dtype)
+            v_pages = jax.random.normal(keys[3], v_pages.shape, v_pages.dtype)
+        n_pages = -(-ctx // block)
+
+        def table_of(kind):
+            if kind == "runs":
+                return tables
+            if kind == "shuffled":
+                return jnp.asarray(np.random.default_rng(
+                    args.seed).permutation(ids).reshape(B, max_blocks))
+            # The churn's newest tables that reach the point's context.
+            room = args.max_model_len - ctx
+            reach = [t for t in churned_tables(
+                B, args.max_model_len, (max(ctx // 2, block), ctx),
+                (min(512, room), min(1536, room)), block=block,
+                seed=args.seed) if len(t) >= n_pages][-B:]
+            if len(reach) < B:
+                raise SystemExit(
+                    f"the churn handed out too few tables of {n_pages} pages")
+            table = np.zeros((B, max_blocks), np.int32)
+            for row, t in zip(table, reach):
+                row[:len(t)] = t[:max_blocks]
+            return jnp.asarray(table)
 
         # The stacked pools and a layer index, as decode_step calls it.
-        def attn_chain(q, k_pages, v_pages):
+        def attn_chain(walk, q, k_pages, v_pages, tables, seq_lens):
             def body(acc, layer):
-                o = kernel(q, k_pages, v_pages, layer, tables, seq_lens, cur,
-                           cur)
+                o = walk(q, k_pages, v_pages, layer, tables, seq_lens, cur,
+                         cur)
                 return acc + o.astype(jnp.float32).sum(), None
 
             acc, _ = jax.lax.scan(body, jnp.float32(0),
-                                  jnp.arange(L, dtype=jnp.int32))
+                                  jnp.arange(calls, dtype=jnp.int32) % L)
             return acc
 
-        ms = timeit(jax.jit(attn_chain), q, k_pages, v_pages, iters=5)
-        call_s = ms / L * 1e-3
-        need = kernels.paged_attention_decode(B * ctx, B, mcfg.n_heads, G, D)
-        peak = kernels.peaks(jax.devices()[0].device_kind)["bytes_per_s"]
-        report(f"pallas_attn x{L}L", B, ctx, ms,
-               ms_per_call=round(call_s * 1e3, 4),
-               us_per_page=round(call_s * 1e6 / (B * -(-ctx // block)), 4),
-               peak_bandwidth_share_pct=round(
-                   100 * need["bytes"] / call_s / peak, 2))
+        def time_walk(name, walk, plain_walk, need, k_pages, v_pages, tables,
+                      seq_lens, kind, pages_read):
+            ms = timeit(jax.jit(functools.partial(attn_chain, walk)), q,
+                        k_pages, v_pages, tables, seq_lens, iters=5)
+            call_s = ms / calls * 1e-3
+            report(f"{name} x{calls}", B, ctx, ms, table=kind,
+                   run_share_pct=run_share(*pages_read[:2]),
+                   ms_per_call=round(call_s * 1e3, 4),
+                   us_per_page=round(call_s * 1e6 / pages_read[2], 4),
+                   peak_bandwidth_share_pct=peak and round(
+                       100 * need["bytes"] / call_s / peak, 2),
+                   # (Two lanes: the plain form gathers a lane's whole
+                   # table of rows, a query head at a time.)
+                   max_err_vs_plain=rel_err(*(
+                       form(q[:2], k_pages, v_pages, 1, tables[:2],
+                            seq_lens[:2], cur[:2], cur[:2])
+                       for form in (walk, plain_walk))))
+
+        for kind in args.tables.split(","):
+            bt = table_of(kind)
+            time_walk("pallas_attn", kernel, plain.paged_decode_attention,
+                      kernels.paged_attention_decode(B * ctx, B, mcfg.n_heads,
+                                                     G, D),
+                      k_pages, v_pages, bt, seq_lens, kind,
+                      (bt, seq_lens, B * n_pages))
+
+        if args.kv_window:
+            W = args.kv_window
+            swa = functools.partial(
+                pallas_paged_attention.swa_paged_decode_attention_kernel,
+                window=W, interpret=args.interpret)
+            swa_plain = functools.partial(plain.swa_paged_decode_attention,
+                                          window=W)
+            wmodel = types.SimpleNamespace(
+                n_layers=L, n_window_layers=L, window=W, kv_block_size=block,
+                latent_dim=0, n_kv_heads=G, head_dim=D, dtype="bfloat16")
+            geom = pages.PageGeometry.for_engine(wmodel, B, args.max_model_len)
+            pool_w = geom.window.n_blocks
+            kw, vw = (jax.random.normal(k, (L, pool_w, block, G, D),
+                                        jnp.bfloat16) for k in keys[2:])
+            first = max(ctx + 1 - W, 0) // block
+            # The logical pages in reach, from a stretch's first as the
+            # window pool hands them out.
+            held = np.arange(first - first % RUN_PAGES, n_pages + 1)
+            for kind in args.tables.split(","):
+                wt, lens = np.zeros((B, max_blocks), np.int32), seq_lens
+                if kind == "churn":
+                    # longctx-16k's mix at 16,384; each lane's newest row
+                    # (not all in flight together: a walk cannot feel it).
+                    n, newest = args.max_model_len, {}
+                    for _, decoded in window_churn(
+                            geom, B, (n // 4, 3 * n // 4),
+                            (n // 32, 3 * n // 32), prefill=n // 16,
+                            seed=args.seed):
+                        for lane in decoded:
+                            newest.pop(id(lane[0]), None)
+                            newest[id(lane[0])] = lane
+                    decoded = list(newest.values())[-B:]
+                    for row, (_, got, _) in zip(wt, decoded):
+                        row[:] = got
+                    lens = jnp.asarray([pos + 1 for *_, pos in decoded],
+                                       jnp.int32)
+                else:
+                    ids_w = 1 + np.arange(B * len(held), dtype=np.int32)
+                    if kind == "shuffled":
+                        ids_w = np.random.default_rng(args.seed).permutation(
+                            ids_w)
+                    wt[:, held] = ids_w.reshape(B, len(held))
+                wt = jnp.asarray(wt)
+                cut = plain.window_table(wt, lens, block, W, RUN_PAGES)
+                seen = int(np.minimum(np.asarray(lens) - 1, W - 1).sum())
+                time_walk("swa_paged_decode_attention", swa, swa_plain,
+                          kernels.paged_attention_decode(
+                              seen, B, mcfg.n_heads, G, D),
+                          kw, vw, wt, lens, kind,
+                          (cut[0], cut[1],
+                           int((-(-(np.asarray(cut[1]) - 1) // block)).sum())))
+
+        if args.attn_only:
+            continue
 
         # current-token KV scatter alone (all layers fused, K+V)
         k_cur = jnp.ones((L, B, G, D), jnp.bfloat16)

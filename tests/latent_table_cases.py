@@ -3,7 +3,9 @@
 entries, one copy where a group names adjacent blocks in ascending order and
 lies inside the lane's cached pages): every way a table can hold runs or
 none. tests/test_mla.py and tests/test_dsa.py run each kernel against its
-plain form over all of them.
+plain form over all of them, and tests/test_pallas_paged_attention.py and
+tests/test_smallthinker.py the K/V family's two walks, which fetch a stage
+the same way (:func:`kv_pools_under`).
 
 Three lanes a case, pages of 16 tokens, a table of 48 entries, stages of 16
 pages (the tests shrink the VMEM budget to that), so R = 8: two groups a
@@ -133,3 +135,32 @@ def pool_under(table, seq_lens, width: int, stored: int, seed: int):
         if cached % BLOCK:
             pool[1, table[lane, cached // BLOCK], cached % BLOCK:] = 1e4
     return pool
+
+
+def kv_pools_under(table, seq_lens, n_kv: int, head_dim: int, seed: int):
+    """A K and a V pool [2, N_BLOCKS, BLOCK, n_kv, head_dim] f32 under
+    ``table`` as :func:`pool_under` lays one out: random rows at the pages
+    each lane owns up to its length in the second layer, large values
+    elsewhere."""
+    return [pool_under(table, seq_lens, n_kv * head_dim, n_kv * head_dim,
+                       seed=seed + i).reshape(2, N_BLOCKS, BLOCK, n_kv,
+                                              head_dim) for i in range(2)]
+
+
+def moved(table, pools, seed: int):
+    """The same rows under the same logical tables at other physical pages,
+    shuffled so that no group is a run: (table, pools)."""
+    to = np.concatenate([[0], 1 + np.random.default_rng(seed).permutation(
+        N_BLOCKS - 1)])
+    back = np.argsort(to)
+    return to[table].astype(np.int32), [pool[:, back] for pool in pools]
+
+
+def shrink_kv_stage(monkeypatch, paged, n_kv: int, head_dim: int,
+                    width: int = WIDTH) -> int:
+    """Hold ops/pallas_paged_attention (``paged``) to stages of STAGE pages
+    of f32 at these heads; P for a walk of ``width`` table entries."""
+    monkeypatch.setattr(
+        paged, "STAGE_VMEM_BYTES",
+        STAGE * paged.stage_vmem_bytes(1, BLOCK, n_kv, head_dim, 4))
+    return paged.pages_per_stage(BLOCK, n_kv, head_dim, 4, width)

@@ -57,12 +57,14 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("batch,table_width", [(16, 128), (64, 32)])
-def test_paged_attention_compiles_at_qwen3_4b(one_chip, batch, table_width):
+@pytest.mark.parametrize("m,batch,table_width", [
+    (QWEN3_4B, 16, 128), (QWEN3_4B, 64, 32), (MIXTRAL_8X7B, 16, 128)],
+    ids=lambda v: getattr(v, "name", str(v)))
+def test_paged_attention_compiles_at_qwen3_4b(one_chip, m, batch, table_width):
     """32 Q / 8 KV heads of 128, bf16 pages of 16 tokens; the 36 layers'
     cache of max_batch 16 x 2048 tokens (2,049 pages a layer), at decode batch
-    16 (table 128 wide) and 64 (table 32 wide)."""
-    m = QWEN3_4B
+    16 (table 128 wide) and 64 (table 32 wide); and Mixtral's 32 layers at
+    the same heads."""
     dt = jnp.dtype(m.dtype)
     pages = _sds(one_chip, (m.n_layers, 2049, m.kv_block_size, m.n_kv_heads,
                             m.head_dim), dt)
@@ -74,14 +76,17 @@ def test_paged_attention_compiles_at_qwen3_4b(one_chip, batch, table_width):
     compiled = paged_decode_attention_pallas.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # A step of the kernel is a stage of P pages: the call's K and V scratch
-    # hold two tiles of P x 16 rows each, and the traced call says so. A
-    # kernel back at a page a step has `bf16[2,16,8,128]` here.
+    # hold two tiles of P pages of 16 rows each, a page a leading index so
+    # that a run of adjacent pages is one copy, and the traced call says so.
+    # A kernel back at a page a step has `bf16[2,1,16,8,128]` here. Which
+    # groups of 8 table entries are runs rides beside the table.
     p = pages_per_stage(m.kv_block_size, m.n_kv_heads, m.head_dim,
                         dt.itemsize, table_width)
     assert p >= 8
-    tile = (f"Ref<vmem>{{bf16[2,{p * m.kv_block_size},{m.n_kv_heads},"
+    tile = (f"Ref<vmem>{{bf16[2,{p},{m.kv_block_size},{m.n_kv_heads},"
             f"{m.head_dim}]}}")
     assert tile in str(jax.make_jaxpr(paged_decode_attention_pallas)(*args))
+    assert f"s32[{batch * table_width // 8}]" in compiled.as_text()
 
 
 def test_decode_step_copies_no_layers_page_pool(one_chip):
@@ -655,24 +660,30 @@ def _dots3_cache(one_chip, m, geom, rows):
 
 def test_the_window_decode_kernel_compiles_at_the_cells_widths(one_chip):
     """64 lanes, 64 heads over rows stored 1,152 wide, a window of 513 under
-    a table 1,152 entries wide: the walk starts at the window's first page
-    (33 table entries a lane reach the kernel, not 1,152)."""
+    a table 1,152 entries wide: the walk starts at the aligned group of the
+    window's first page, read out of the whole table by the kernel (no gather
+    ahead of it), in stages of 16 pages as 33 + 7 entries allow."""
     from llm_d_inference_scheduler_tpu.ops import pallas_latent_attention as la
 
     lanes, heads, dk, width = 64, 64, 1088, 1152
     bf16 = jnp.bfloat16
+    args = (_sds(one_chip, (lanes, heads, dk), bf16),
+            _sds(one_chip, (3, 3505, 16, width), bf16),
+            _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (lanes, 1152), jnp.int32),
+            _sds(one_chip, (lanes,), jnp.int32),
+            _sds(one_chip, (lanes, dk), bf16))
     compiled = jax.jit(functools.partial(
         la.swa_latent_decode_attention_pallas, value_dim=1024, scale=0.0625,
-        window=513)).lower(
-        _sds(one_chip, (lanes, heads, dk), bf16),
-        _sds(one_chip, (3, 2483, 16, width), bf16),
-        _sds(one_chip, (), jnp.int32),
-        _sds(one_chip, (lanes, 1152), jnp.int32),
-        _sds(one_chip, (lanes,), jnp.int32),
-        _sds(one_chip, (lanes, dk), bf16)).compile()
+        window=513)).lower(*args).compile()
     hlo = compiled.as_text()
     assert "swa_latent_decode_attention" in hlo and "tpu_custom_call" in hlo
-    assert f"s32[{lanes * la.window_pages(16, 513)}]" in hlo
+    assert f"s32[{lanes * 1152}]" in hlo and "gather" not in hlo
+    assert la.window_pages(16, 513, 8) == 40 and "bf16[2,16,16,1152]" in str(
+        jax.make_jaxpr(functools.partial(
+            la.swa_latent_decode_attention_pallas, value_dim=1024,
+            scale=0.0625, window=513))(
+                *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
@@ -750,13 +761,15 @@ def test_a_window_of_the_mixed_model_compiles_with_both_kinds_kernels(
 def test_the_kv_kernels_compile_at_seven_heads_a_group(one_chip, window):
     """28 query heads on 4 KV heads of 128 (7 a group: no multiple of 8, and
     the query block is not padded), 32 lanes under a table 1,024 entries wide:
-    the full layers' walk, and the window layers' from the window's first
-    page (257 table entries a lane reach the kernel, not 1,024)."""
+    the full layers' walk, and the window layers' from the aligned group of
+    the window's first page (read out of the whole table by the kernel, no
+    gather ahead of it; stages of 32 pages either way), each with a flag a
+    group of 8 entries beside its table."""
     from llm_d_inference_scheduler_tpu.ops import pallas_paged_attention as pa
 
     lanes, heads, kv, d = 32, 28, 4, 128
     bf16 = jnp.bfloat16
-    blocks = 9510 if window else 32769
+    blocks = 10065 if window else 32769
     pool = _sds(one_chip, (6 if window else 2, blocks, 16, kv, d), bf16)
     cur = _sds(one_chip, (lanes, kv, d), bf16)
     args = (_sds(one_chip, (lanes, heads, d), bf16), pool, pool,
@@ -770,8 +783,10 @@ def test_the_kv_kernels_compile_at_seven_heads_a_group(one_chip, window):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert ("swa_paged_decode_attention" in hlo) == bool(window)
-    entries = 257 if window else 1024
-    assert f"s32[{lanes * entries}]" in hlo
+    assert f"s32[{lanes * 1024}]" in hlo and f"s32[{lanes * 128}]" in hlo
+    assert "gather" not in hlo
+    assert "bf16[2,32,16,4,128]" in str(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 

@@ -34,6 +34,7 @@ WINDOW, TOPK, BLOCK = CFG.window_attn.window, CFG.index_topk, CFG.kv_block_size
 # float32 on both sides, different summation order (test_reference.py's).
 TOL = dict(rtol=2e-4, atol=2e-4)
 N = 45                       # tokens of the sequence the tests follow
+RUN = pages.RUN_PAGES        # pages a stretch of the window pool
 
 
 def _reference():
@@ -159,7 +160,7 @@ def test_two_periods_of_the_pattern_match_the_reference():
 def _poison(cache, owner):
     """The window pool's pages that are nobody's, overwritten: a step that
     read one would show it."""
-    free = np.asarray(owner.pages._free, np.int32)
+    free = np.asarray(owner.free_window_pages(), np.int32)
     return dataclasses.replace(cache, win=cache.win.at[:, free].set(1e4))
 
 
@@ -176,7 +177,7 @@ def test_windows_then_decode_through_both_pools(kernels):
     geom = pages.PageGeometry.for_engine(mcfg, 2, 64)
     owner = allocator_for(geom, True)
     cache, _ = pages.alloc(geom)
-    assert cache.win.shape == geom.window.shape == (3, 16, 4, 128)
+    assert cache.win.shape == geom.window.shape == (3, 81, 4, 128)
     per, prompt, win = geom.max_blocks_per_seq, 29, 8
     table = owner.alloc(per)
     row = np.zeros((1, per), np.int32)
@@ -234,11 +235,12 @@ def test_windows_then_decode_through_both_pools(kernels):
             state.at_slots(_poison(cache, owner), [0, 2], wt), tables)
         cache, *_ = state.take_counts(cache)
         np.testing.assert_allclose(np.asarray(logits), want[t], **TOL)
-    # Pages came back and went out again, and a decoding lane never held
-    # more than the window's pages and one.
-    assert len(set(handed)) < len(table) and most <= -(-WINDOW // BLOCK) + 1
+    # The first stretch came back (and was poisoned) while the lane decoded
+    # on, and the lane never held more than its reservation.
+    assert table.first == RUN and len(set(handed)) == 2 * RUN
+    assert most <= geom.window.lane_stretches * RUN
     owner.free(table)
-    assert owner.pages.free_blocks == geom.window.n_blocks - 1
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
     assert owner.tables == 0
 
 
@@ -356,14 +358,15 @@ def test_pool_bytes_follow_the_lanes_and_not_the_context():
     assert long.pool_bytes > 50 * short.pool_bytes
     assert long.window == short.window
     assert short.window.lane_pages == 3             # ceil(7 / 4) + 1
+    assert short.window.lane_stretches == 2         # ceil(3 / 8) + 1
     assert short.window.lanes == 4 + 2
-    assert short.window.n_blocks == 1 + (6 + 1) * 3
-    assert wide.window.n_blocks == 1 + (10 + 1) * 3
+    assert short.window.n_blocks == 1 + (6 + 1) * 2 * RUN
+    assert wide.window.n_blocks == 1 + (10 + 1) * 2 * RUN
     got = short.describe()
     assert (got["kv_layers_full"], got["kv_layers_window"], got["window"]) \
         == (2, 3, 7)
     assert got["window_token_bytes"] == 128 * 4
-    assert got["window_pool_bytes"] == 3 * 22 * 4 * 128 * 4
+    assert got["window_pool_bytes"] == 3 * 113 * 4 * 128 * 4
     assert got["kv_layers"] == 2 and got["index_token_bytes"] == 16 * 4
     assert any("prefix hits" in s for s in got["off_for_window_layers"])
     assert "window of the context" in short.one_chip_only
@@ -373,9 +376,10 @@ def test_pool_bytes_follow_the_lanes_and_not_the_context():
             CFG.window_attn, kv_lora_rank=1024, qk_rope_head_dim=64,
             window=513), dtype="bfloat16")
     geom = pages.PageGeometry.for_engine(cell, 64, 18432)
-    assert geom.window.lane_pages == 34
+    assert geom.window.lane_pages == 34 and geom.window.lane_stretches == 6
     assert geom.window.token_bytes == 2304
-    assert geom.window.pool_bytes == 3 * (1 + 73 * 34) * 16 * 2304 < 0.5e9
+    assert geom.window.n_blocks == 1 + 73 * 6 * 8 == 3505
+    assert geom.window.pool_bytes == 3 * 3505 * 16 * 2304 < 0.5e9
     # Every other cache: no window pool, the allocators it had.
     plain = pages.PageGeometry.for_engine(configs.get_config("tiny-dsa"), 2, 64)
     assert plain.window is None and "window_pool_bytes" not in plain.describe()
@@ -388,7 +392,8 @@ def test_a_lane_holds_the_windows_pages_and_admission_reserves_by_kind():
     geom = pages.PageGeometry.for_engine(CFG, 2, 512)
     owner = allocator_for(geom, True)
     per, w = geom.max_blocks_per_seq, geom.window
-    assert owner.pages.n_blocks == w.n_blocks and owner.lanes == 4
+    assert RUN * (owner.stretches.n_blocks - 1) == w.n_blocks - 1
+    assert owner.lanes == 4 and owner.run == RUN
     # Four tables take the four reservations whatever their length; a fifth
     # finds no free block of the other kind either, and is refused.
     tables = [owner.alloc(n) for n in (per, 3, 1, 9)]
@@ -398,35 +403,47 @@ def test_a_lane_holds_the_windows_pages_and_admission_reserves_by_kind():
     owner.free(tables.pop())
     assert owner.free_blocks == owner.n_blocks - 1 - per - 4
     # A prompt of 200 in windows of 32, then 40 decode chunks of 4 steps:
-    # between steps the lane holds the window's pages and one at most, its
-    # table row names them by logical page, and what it gave back is handed
-    # out again.
-    table, seen = tables[0], set()
+    # the lane holds whole aligned stretches, those that hold a page in reach
+    # and no other, its table row names them by logical page, and what it
+    # gave back is handed out again.
+    table, seen, taken = tables[0], set(), 0
+
+    def held_stretches():
+        assert table.first % RUN == 0 and len(table.window) % RUN == 0
+        groups = [table.window[i:i + RUN]
+                  for i in range(0, len(table.window), RUN)]
+        assert all(g == list(range(g[0], g[0] + RUN)) and g[0] % RUN == 1
+                   for g in groups)
+        seen.update(table.window)
+        return [table.first // RUN + i for i in range(len(groups))]
+
     for lo in range(0, 200, 32):
-        row = np.zeros(per, np.int32)
-        owner.slide(table, lo, min(lo + 32, 200), row, True)
-        held = [b for b in table.window if b]
-        assert len(held) <= w.lane_pages - 1 and 0 not in held
-        assert len(table.window) == len(held)      # the skipped pages: gone
         hi = min(lo + 32, 200)
-        assert table.first == max(hi - (WINDOW - 1), lo) // BLOCK
-        seen.update(held)
+        row = np.zeros(per, np.int32)
+        owner.slide(table, lo, hi, row, True)
+        assert held_stretches() == list(range(
+            max(hi - (WINDOW - 1), 0) // BLOCK // RUN,
+            (hi - 1) // BLOCK // RUN + 1))
     pos = 200
     for _ in range(40):
         row = np.zeros(per, np.int32)
         owner.slide(table, pos, pos + 4, row)
-        assert len(table.window) <= w.lane_pages
         first, last = (pos - (WINDOW - 1)) // BLOCK, (pos + 3) // BLOCK
-        assert table.first == first
-        assert list(row[first:last + 1]) == table.window and all(table.window)
-        assert not row[:first].any() and not row[last + 1:].any()
-        seen.update(table.window)
+        assert held_stretches() == list(range(first // RUN, last // RUN + 1))
+        assert len(table.window) <= w.lane_stretches * RUN
+        lo, hi = table.first, table.first + len(table.window)
+        assert list(row[lo:hi]) == table.window
+        assert not row[:lo].any() and not row[hi:].any()
+        taken = max(taken, last // RUN + 1)
         pos += 4
-    assert len(seen) <= w.n_blocks - 1 < (200 + 160) // BLOCK
-    assert owner.window_used_fraction == len(table.window) / (w.n_blocks - 1)
+    # 12 stretches were written into; LIFO, two ids served them all.
+    assert taken == 12 and len(seen) == 2 * RUN
+    assert owner.window_used_fraction == (
+        len(table.window) // RUN / (owner.stretches.n_blocks - 1))
     for t in tables:
         owner.free(t)
-    assert owner.tables == 0 and owner.pages.free_blocks == w.n_blocks - 1
+    assert owner.tables == 0
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
     assert owner.free_blocks == owner.n_blocks - 1
 
 
@@ -554,7 +571,7 @@ def test_engine_serves_through_windows_and_both_kinds_of_pool(served,
     assert got == plain and [len(t) for t in got] == [14, 22, 9]
     # Every request gave everything back, of both kinds.
     assert usage == [0.0] and owner.tables == 0
-    assert owner.pages.free_blocks == owner.pages.n_blocks - 1
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
     assert owner.free_blocks == owner.n_blocks - 1
     assert 0 < rows["attended"] < rows["context"]
     assert attn["expanded"] > 0 and attn["absorbed"] > 0
@@ -562,7 +579,7 @@ def test_engine_serves_through_windows_and_both_kinds_of_pool(served,
             settings["window"]) == (2, 3, WINDOW)
     assert settings["window_attention"] == (
         "kernel_interpret" if kernels else "xla")
-    assert settings["window_pool_bytes"] == 3 * (1 + 5 * 3) * 4 * 128 * 4
+    assert settings["window_pool_bytes"] == 3 * (1 + 5 * 2 * RUN) * 4 * 128 * 4
     assert not settings["prefix_caching"]      # asked for or not
 
 

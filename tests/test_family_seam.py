@@ -476,7 +476,7 @@ SETTINGS_KEYS = {
 
 @pytest.mark.parametrize("name, one_chip, want", [
     ("tiny", None, dict(
-        kv_run_pages=None, index_topk=0, index_token_bytes=0,
+        kv_run_pages=8, index_topk=0, index_token_bytes=0,
         index_pool_bytes=0, index_scores=None, expanded_attention=None,
         experts_first=0,
         experts_held=0, zero_experts=0, state_slot_bytes=0,
@@ -492,7 +492,7 @@ SETTINGS_KEYS = {
          kv_run_pages=8, index_topk=configs.get_config("tiny-dsa").index_topk,
          index_scores="xla", expanded_attention="xla", state_slot_bytes=0)),
     ("tiny-hybrid", "a recurrent state pool beside its pages", dict(
-        kv_run_pages=None, state_update="gathered",
+        kv_run_pages=8, state_update="gathered",
         off_for_state_layers=list(state.OFF_FOR_STATE_LAYERS))),
 ])
 def test_settings_are_the_parents_keys_each_family_saying_its_own(

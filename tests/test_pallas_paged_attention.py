@@ -9,6 +9,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import latent_table_cases as table_cases
 import numpy as np
 import pytest
 
@@ -19,8 +20,14 @@ from llm_d_inference_scheduler_tpu.models.configs import (
     QWEN3_4B,
     ModelConfig,
 )
-from llm_d_inference_scheduler_tpu.ops import apply_rope, rms_norm, rope_table
+from llm_d_inference_scheduler_tpu.ops import (apply_rope,
+                                               pallas_paged_attention,
+                                               rms_norm, rope_table)
 from llm_d_inference_scheduler_tpu.ops.attention import paged_decode_attention
+from llm_d_inference_scheduler_tpu.ops.pallas_latent_attention import (
+    run_pages,
+    table_runs,
+)
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
     STAGE_VMEM_BYTES,
     paged_decode_attention_pallas,
@@ -88,6 +95,50 @@ def test_pallas_matches_xla_reference(dims, seq_lens_of, layer):
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n_kv", [4, 8])
+@pytest.mark.parametrize("case", table_cases.CASES)
+def test_kernel_fetches_runs_of_adjacent_pages_as_one_copy(case, n_kv,
+                                                           monkeypatch):
+    """The K/V walk against the gather over every kind of table
+    (tests/latent_table_cases.py), 7 query heads a KV head: which groups it
+    takes as one copy is what was counted by hand, the result is the
+    gather's either way, and it is the same to the last bit when the same
+    rows lie at shuffled pages where every group is a copy a page, as every
+    page was before the runs: a copy is a copy. Rows past a lane's length
+    and pages it does not own hold large values: they weigh nothing."""
+    c, D = table_cases, 32
+    assert c.shrink_kv_stage(monkeypatch, pallas_paged_attention, n_kv,
+                             D) == c.STAGE
+    group = run_pages(c.STAGE)
+    tables, lens = c.tables(case, group)
+    runs = np.asarray(table_runs(jnp.asarray(tables), jnp.asarray(lens),
+                                 c.BLOCK, group))
+    np.testing.assert_array_equal(runs, c.runs_by_hand(tables, lens, group))
+    assert runs.sum(axis=1).tolist() == c.RUNS[case]
+    pools = c.kv_pools_under(tables, lens, n_kv, D, seed=31)
+    keys = jax.random.split(jax.random.key(31), 3)
+    q = jax.random.normal(keys[0], (len(lens), 7 * n_kv, D), jnp.float32)
+    cur_k, cur_v = (jax.random.normal(k, (len(lens), n_kv, D), jnp.float32)
+                    for k in keys[1:])
+    # (The gather reads a lane of length 0 as one that holds its own row.)
+    want = paged_decode_attention(q, *pools, 1, jnp.asarray(tables),
+                                  jnp.maximum(jnp.asarray(lens), 1),
+                                  cur_k=cur_k, cur_v=cur_v)
+    got = paged_decode_attention_pallas(
+        q, *pools, 1, jnp.asarray(tables), jnp.asarray(lens), cur_k, cur_v,
+        interpret=True)
+    assert np.abs(np.asarray(want)).max() < 10
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    far_tables, far_pools = c.moved(tables, pools, seed=3)
+    assert not np.asarray(table_runs(jnp.asarray(far_tables),
+                                     jnp.asarray(lens), c.BLOCK, group)).any()
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention_pallas(
+            q, *far_pools, 1, jnp.asarray(far_tables), jnp.asarray(lens),
+            cur_k, cur_v, interpret=True)), np.asarray(got))
 
 
 def test_staged_cases_cross_a_stage_and_the_narrow_one_is_clamped():

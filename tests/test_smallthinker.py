@@ -15,10 +15,12 @@ import dataclasses
 import functools
 import importlib.util
 import pathlib
+import sys
 import types
 
 import jax
 import jax.numpy as jnp
+import latent_table_cases as table_cases
 import numpy as np
 import pytest
 
@@ -30,6 +32,8 @@ from llm_d_inference_scheduler_tpu.models import bind, configs, family, llama
 from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
 from llm_d_inference_scheduler_tpu.ops import attention as plain_ops
 from llm_d_inference_scheduler_tpu.ops import pallas_paged_attention as paged
+from llm_d_inference_scheduler_tpu.ops.pallas_latent_attention import (
+    run_pages, table_runs)
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 CFG = dataclasses.replace(configs.get_config("tiny-swa-kv"), dtype="float32")
@@ -37,6 +41,7 @@ WINDOW, BLOCK = CFG.kv_window, CFG.kv_block_size
 # float32 on both sides, different summation order (test_reference.py's).
 TOL = dict(rtol=2e-4, atol=2e-4)
 N = 45                       # tokens of the sequence the tests follow
+RUN = pages.RUN_PAGES        # pages a stretch of the window pool
 
 
 def _reference():
@@ -125,7 +130,7 @@ def test_grouped_experts_take_the_early_routers_choices():
 def _poison(cache, owner):
     """The window pools' pages that are nobody's, overwritten: a step that
     read one would show it."""
-    free = np.asarray(owner.pages._free, np.int32)
+    free = np.asarray(owner.free_window_pages(), np.int32)
     return dataclasses.replace(cache, win=cache.win.at[:, free].set(1e4),
                                win_v=cache.win_v.at[:, free].set(1e4))
 
@@ -145,7 +150,7 @@ def test_windows_then_decode_through_both_pools(kernels):
     cache, _ = pages.alloc(geom)
     assert cache.k.shape == cache.v.shape == geom.shape == (2, 33, 4, 2, 16)
     assert cache.win.shape == cache.win_v.shape == geom.window.shape \
-        == (6, 21, 4, 2, 16)
+        == (6, 81, 4, 2, 16)      # (4 lanes + 1) x 2 stretches of 8 pages
     per, prompt, win = geom.max_blocks_per_seq, 29, 8
     table = owner.alloc(per)
     row = np.zeros((1, per), np.int32)
@@ -204,11 +209,12 @@ def test_windows_then_decode_through_both_pools(kernels):
             state.at_slots(_poison(cache, owner), [0, 2], wt), tables)
         cache, *_ = state.take_counts(cache)
         np.testing.assert_allclose(np.asarray(logits), want[t], **TOL)
-    # Pages came back and went out again, and a decoding lane never held
-    # more than the window's pages and one.
-    assert len(set(handed)) < len(table) and most <= -(-WINDOW // BLOCK) + 1
+    # The first stretch came back (and was poisoned) while the lane decoded
+    # on, and the lane never held more than its reservation.
+    assert table.first == RUN and len(set(handed)) == 2 * RUN
+    assert most <= geom.window.lane_stretches * RUN
     owner.free(table)
-    assert owner.pages.free_blocks == geom.window.n_blocks - 1
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
     assert owner.tables == 0
 
 
@@ -298,6 +304,95 @@ def test_window_decode_kernel_matches_the_plain_form(positions):
                                        (p / p.sum()) @ vs[:, h // 7], **TOL)
 
 
+@pytest.mark.parametrize("n_kv", [4, 8])
+@pytest.mark.parametrize("case", table_cases.CASES)
+def test_window_kernel_fetches_runs_of_adjacent_pages_as_one_copy(
+        case, n_kv, monkeypatch):
+    """The window walk over every kind of table (tests/latent_table_cases.py
+    read as tables by logical page, a window of 200 rows: the cut is 21
+    entries from an aligned one, two stages), 7 query heads a KV head: the
+    gather's result (whose cut starts at the window's own first page), and
+    the same to the last bit with the same rows at shuffled pages, where no
+    group is a run."""
+    c, D, window = table_cases, 32, 200
+    tables, lens = c.tables(case)
+    cut, cut_lens, skip = plain_ops.window_table(
+        jnp.asarray(tables), jnp.asarray(lens), c.BLOCK, window,
+        align=pages.RUN_PAGES)
+    assert cut.shape[1] == plain_ops.window_pages(c.BLOCK, window, 8) == 21
+    assert c.shrink_kv_stage(monkeypatch, paged, n_kv, D, cut.shape[1]) == c.STAGE
+    pools = [jnp.asarray(pool) for pool in
+             c.kv_pools_under(tables, lens, n_kv, D, seed=41)]
+    keys = jax.random.split(jax.random.key(41), 3)
+    q = jax.random.normal(keys[0], (len(lens), 7 * n_kv, D), jnp.float32)
+    cur_k, cur_v = (jax.random.normal(k, (len(lens), n_kv, D), jnp.float32)
+                    for k in keys[1:])
+    seq_lens = jnp.maximum(jnp.asarray(lens), 1)
+    want = plain_ops.swa_paged_decode_attention(
+        q, *pools, 1, jnp.asarray(tables), seq_lens, cur_k, cur_v,
+        window=window)
+    got = paged.swa_paged_decode_attention_kernel(
+        q, *pools, 1, jnp.asarray(tables), seq_lens, cur_k, cur_v,
+        window=window, interpret=True)
+    assert np.abs(np.asarray(want)).max() < 10
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+    far_tables, far_pools = c.moved(tables, pools, seed=4)
+    far_cut = plain_ops.window_table(
+        jnp.asarray(far_tables), seq_lens, c.BLOCK, window,
+        align=pages.RUN_PAGES)
+    assert not np.asarray(table_runs(far_cut[0], far_cut[1], c.BLOCK, 8)).any()
+    np.testing.assert_array_equal(
+        np.asarray(paged.swa_paged_decode_attention_kernel(
+            q, *far_pools, 1, jnp.asarray(far_tables), seq_lens, cur_k,
+            cur_v, window=window, interpret=True)), np.asarray(got))
+
+
+@pytest.mark.parametrize("n_kv", [4, 8])
+def test_window_kernel_walks_the_pools_stretches_from_every_offset(
+        n_kv, monkeypatch):
+    """Tables as the window pool's owner fills them (aligned stretches of 8
+    pages, each anywhere in the pool; what lies behind the lane's first
+    stretch given back and another's): the window's first page at every
+    offset into its stretch, a lane shorter than the window, a lane in its
+    first page and one with nothing cached. Every whole group of the cut
+    table is a run, and the result is the gather's."""
+    c, D, window, R = table_cases, 32, 200, pages.RUN_PAGES
+    seq_lens = np.asarray(
+        [window + c.BLOCK * (8 + off) + 5 + off for off in range(R)]
+        + [window + c.BLOCK * 11, 60, 3, 1], np.int32)
+    B = len(seq_lens)
+    rng = np.random.default_rng(9)
+    stretch = rng.permutation((c.N_BLOCKS - 1) // R)[:B * 4]
+    owned = np.zeros((B, c.WIDTH), np.int32)     # four stretches a lane
+    owned[:, :4 * R] = (1 + R * stretch[:, None] + np.arange(R)).reshape(B, -1)
+    pools = [jnp.asarray(pool) for pool in
+             c.kv_pools_under(owned, seq_lens, n_kv, D, seed=43)]
+    tables = owned.copy()
+    for lane, n in enumerate(seq_lens):      # the stretches out of reach
+        first = max(n - window, 0) // c.BLOCK
+        tables[lane, :first - first % R] = c.N_BLOCKS - 1
+    pools = [pool.at[:, c.N_BLOCKS - 1].set(1e4) for pool in pools]
+    cut, cut_lens, skip = plain_ops.window_table(
+        jnp.asarray(tables), jnp.asarray(seq_lens), c.BLOCK, window, align=R)
+    assert c.shrink_kv_stage(monkeypatch, paged, n_kv, D, cut.shape[1]) == c.STAGE
+    assert sorted(set((np.asarray(skip[:R]) // c.BLOCK).tolist())) \
+        == list(range(R))
+    runs = np.asarray(table_runs(cut, cut_lens, c.BLOCK, run_pages(c.STAGE)))
+    whole = -(-(np.asarray(cut_lens) - 1) // c.BLOCK) // R
+    assert runs.sum(axis=1).tolist() == whole.tolist() and whole[:R].all()
+    keys = jax.random.split(jax.random.key(43), 3)
+    q = jax.random.normal(keys[0], (B, 7 * n_kv, D), jnp.float32)
+    cur_k, cur_v = (jax.random.normal(k, (B, n_kv, D), jnp.float32)
+                    for k in keys[1:])
+    args = (q, *pools, 1, jnp.asarray(tables), jnp.asarray(seq_lens), cur_k,
+            cur_v)
+    want = plain_ops.swa_paged_decode_attention(*args, window=window)
+    got = paged.swa_paged_decode_attention_kernel(*args, window=window,
+                                                  interpret=True)
+    assert np.abs(np.asarray(want)).max() < 10
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
 def test_the_full_layers_kernel_reads_seven_heads_a_group():
     """paged_decode_attention_pallas at 14 query heads on 2 (no multiple of
     8 a group) against the gather."""
@@ -327,26 +422,30 @@ def test_pool_bytes_follow_the_lanes_and_not_the_context():
     assert long.pool_bytes > 50 * short.pool_bytes
     assert long.window == short.window
     assert short.window.lane_pages == 4             # ceil(11 / 4) + 1
+    assert short.window.lane_stretches == 2         # ceil(4 / 8) + 1
     assert short.window.lanes == 4 + 2
-    assert short.window.n_blocks == 1 + (6 + 1) * 4
-    assert wide.window.n_blocks == 1 + (10 + 1) * 4
+    assert short.window.n_blocks == 1 + (6 + 1) * 2 * RUN
+    assert wide.window.n_blocks == 1 + (10 + 1) * 2 * RUN
     got = short.describe()
     assert (got["kv_layers"], got["kv_layers_full"], got["kv_layers_window"],
             got["window"]) == (2, 2, 6, 11)
     assert got["window_token_bytes"] == got["kv_token_bytes"] == 2 * 2 * 16 * 4
-    assert got["window_pool_bytes"] == 6 * 29 * 4 * 256
+    assert got["window_pool_bytes"] == 6 * 113 * 4 * 256
     assert any("prefix hits" in s for s in got["off_for_window_layers"])
     assert short.one_chip_only.startswith("K/V page pools and a second pair")
     assert type(allocator_for(short, True)) is WindowedAllocator
     # At the cell's widths (chipbench/configs/smallthinker-21b-a3b-cut.json):
-    # 257 pages a lane, 2,048 B a token a window layer, whatever the context.
+    # 257 pages a lane in 34 stretches of 8, 2,048 B a token a window layer,
+    # whatever the context.
     cell = dataclasses.replace(
         CFG, kv_block_size=16, kv_window=4096, n_heads=28, n_kv_heads=4,
         head_dim_override=128, dtype="bfloat16")
     geom = pages.PageGeometry.for_engine(cell, 32, 16384)
     assert geom.window.lane_pages == 257 and geom.window.lanes == 36
+    assert (geom.run_pages, geom.window.lane_stretches) == (8, 34)
     assert geom.window.token_bytes == geom.token_bytes == 2048
-    assert geom.window.pool_bytes == 6 * (1 + 37 * 257) * 16 * 2048
+    assert geom.window.n_blocks == 1 + 37 * 34 * 8 == 10065
+    assert geom.window.pool_bytes == 6 * 10065 * 16 * 2048
     assert geom.pool_bytes == 2 * (1 + 32 * 1024) * 16 * 2048
     assert pages.PageGeometry.for_engine(cell, 32, 8192).window == geom.window
 
@@ -355,36 +454,98 @@ def test_a_lane_holds_the_windows_pages_and_admission_reserves_by_kind():
     geom = pages.PageGeometry.for_engine(CFG, 2, 512)
     owner = allocator_for(geom, True)
     per, w = geom.max_blocks_per_seq, geom.window
-    assert owner.pages.n_blocks == w.n_blocks and owner.lanes == 4
+    assert RUN * (owner.stretches.n_blocks - 1) == w.n_blocks - 1
+    assert owner.lanes == 4 and owner.run == RUN
     tables = [owner.alloc(n) for n in (per, 3, 1, 9)]
     assert owner.free_blocks == 0 and owner.tables == 4
     with pytest.raises(OutOfBlocks, match="reservation"):
         owner.alloc(1)
     owner.free(tables.pop())
     # A prompt of 200 in windows of 32, then 40 decode chunks of 4 steps:
-    # between steps the lane holds the window's pages and one at most.
+    # the lane holds whole aligned stretches, those that hold a page in reach
+    # and no other, and never more than its reservation.
     table, seen = tables[0], set()
+
+    def stretches(first_page, last_page):
+        return list(range(first_page // RUN, last_page // RUN + 1))
+
+    def held_stretches():
+        assert table.first % RUN == 0 and len(table.window) % RUN == 0
+        groups = [table.window[i:i + RUN]
+                  for i in range(0, len(table.window), RUN)]
+        assert all(g == list(range(g[0], g[0] + RUN)) and g[0] % RUN == 1
+                   for g in groups)
+        seen.update(table.window)
+        return [table.first // RUN + i for i in range(len(groups))]
+
     for lo in range(0, 200, 32):
+        hi = min(lo + 32, 200)
         row = np.zeros(per, np.int32)
-        owner.slide(table, lo, min(lo + 32, 200), row, True)
-        held = [b for b in table.window if b]
-        assert len(held) <= w.lane_pages - 1 and 0 not in held
-        seen.update(held)
+        owner.slide(table, lo, hi, row, True)
+        # What only this window read is back already.
+        assert held_stretches() == stretches(
+            max(hi - (WINDOW - 1), 0) // BLOCK, (hi - 1) // BLOCK)
+        assert len(table.window) <= w.lane_stretches * RUN
     pos = 200
     for _ in range(40):
         row = np.zeros(per, np.int32)
         owner.slide(table, pos, pos + 4, row)
-        assert len(table.window) <= w.lane_pages
         first, last = (pos - (WINDOW - 1)) // BLOCK, (pos + 3) // BLOCK
-        assert table.first == first
-        assert list(row[first:last + 1]) == table.window and all(table.window)
-        assert not row[:first].any() and not row[last + 1:].any()
-        seen.update(table.window)
+        assert held_stretches() == stretches(first, last)
+        assert len(table.window) <= w.lane_stretches * RUN
+        lo, hi = table.first, table.first + len(table.window)
+        assert list(row[lo:hi]) == table.window
+        assert not row[:lo].any() and not row[hi:].any()
         pos += 4
-    assert len(seen) <= w.n_blocks - 1 < (200 + 160) // BLOCK
+    assert len(seen) <= w.n_blocks - 1
     for t in tables:
         owner.free(t)
-    assert owner.tables == 0 and owner.pages.free_blocks == w.n_blocks - 1
+    assert owner.tables == 0
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
+    assert owner.free_blocks == owner.n_blocks - 1
+
+
+@pytest.mark.parametrize("window, lanes, max_len, floor", [
+    (4096, 32, 16384, 0.95),     # longctx-16k: 257 pages in reach
+    (513, 64, 18432, 0.70),      # longctx-wide: 33, of which a group is 8
+], ids=["smallthinker", "dots3"])
+def test_the_window_tables_hold_runs_under_the_long_context_cells_churn(
+        window, lanes, max_len, floor):
+    """Why the window pool is handed out in stretches: the owner under the
+    two window cells' shapes (scripts/microbench_decode.window_churn: prompts
+    log-uniform 4,096-12,288 written in windows of 1,024 ahead of the decode
+    chunks, outputs 512-1,536, chunks of 8 steps). Every whole group of a
+    decoding lane's table, cut as the kernels cut it, is a run, which is 97%
+    of the groups at 257 pages a lane (a page at a time off the shared LIFO
+    list it was 22% after 250 chunks and 0 after 2,000); no page is two
+    lanes' at once, no lane holds more than its reservation, the pool never
+    runs dry, and every stretch comes back."""
+    sys.path.insert(0, str(REPO / "scripts"))
+    from microbench_decode import window_churn
+
+    from llm_d_inference_scheduler_tpu.engine.blocks import window_table_groups
+
+    cell = dataclasses.replace(
+        CFG, kv_block_size=16, kv_window=window, n_heads=28, n_kv_heads=4,
+        head_dim_override=128, dtype="bfloat16")
+    geom = pages.PageGeometry.for_engine(cell, lanes, max_len)
+    w, runs, splits, most = geom.window, 0, 0, 0
+    for owner, decoded in window_churn(geom, lanes, chunks=250, seed=2):
+        held = [page for table, _, _ in decoded for page in table.window
+                if page]
+        assert len(held) == len(set(held))
+        for table, row, pos in decoded:
+            assert len(table.window) <= w.lane_stretches * RUN
+            r, s = window_table_groups(row, pos, 16, window, RUN)
+            first = max(pos + 1 - window, 0) // 16 // RUN * RUN
+            assert r == (-(-pos // 16) - first) // RUN     # every whole one
+            runs, splits = runs + r, splits + s
+        most = max(most, owner.stretches.n_blocks - 1
+                   - owner.stretches.free_blocks)
+    assert runs / (runs + splits) >= floor and runs > 10_000
+    assert most <= lanes * w.lane_stretches + w.lane_stretches
+    assert owner.tables == 0
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
     assert owner.free_blocks == owner.n_blocks - 1
 
 
@@ -508,11 +669,15 @@ def test_engine_serves_through_windows_and_both_kinds_of_pool(served):
                               "kind"),
                     _counters(eng.telemetry,
                               "jetstream:moe_ffn_tokens_total", "form"),
+                    {name: _counters(eng.telemetry, f"jetstream:{name}_total",
+                                     "kind")
+                     for name in ("kv_table_groups",
+                                  "kv_window_table_groups")},
                     eng.describe()["settings"])
         finally:
             await eng.stop()
 
-    got, plain, usage, owner, rows, ffn, settings = asyncio.run(serve(
+    got, plain, usage, owner, rows, ffn, groups, settings = asyncio.run(serve(
         EngineConfig(model=served, backend="tpu", max_batch=2,
                      max_model_len=96, decode_chunk=4, kv_events_port=0,
                      seed=7, prefill_chunk=8, pallas_attention=True,
@@ -520,16 +685,26 @@ def test_engine_serves_through_windows_and_both_kinds_of_pool(served):
     assert got == plain and [len(t) for t in got] == [14, 22, 9]
     # Every request gave everything back, of both kinds.
     assert usage == [0.0] and owner.tables == 0
-    assert owner.pages.free_blocks == owner.pages.n_blocks - 1
+    assert owner.stretches.free_blocks == owner.stretches.n_blocks - 1
     assert owner.free_blocks == owner.n_blocks - 1
     assert 0 < rows["attended"] < rows["context"]
     assert ffn["dense"] > 0
+    # The three admitted tables (13, 8 and 8 pages, prompt and output) in
+    # groups of 8 as the full layers' kernel fetches them, and the window
+    # tables of the decoding lanes, once a chunk of 4 steps (a window of 11
+    # tokens in pages of 4 seldom fills a group of 8: most are the short
+    # last one; a whole one is a stretch, so a run).
+    assert settings["kv_run_pages"] == RUN
+    assert groups["kv_table_groups"] == {"run": 3.0, "split": 1.0}
+    window_groups = groups["kv_window_table_groups"]
+    assert 0 < window_groups["run"] < window_groups["split"]
+    assert sum(window_groups.values()) >= (14 + 22 + 9) // 4
     assert (settings["kv_layers_full"], settings["kv_layers_window"],
             settings["window"]) == (2, 6, WINDOW)
     assert (settings["window_attention"], settings["router_input"],
             settings["expert_activation"]) == ("kernel_interpret", "attn",
                                                "reglu")
-    assert settings["window_pool_bytes"] == 6 * (1 + 5 * 4) * 4 * 256
+    assert settings["window_pool_bytes"] == 6 * (1 + 5 * 2 * RUN) * 4 * 256
     assert not settings["prefix_caching"]      # asked for or not
 
 
