@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kvcache import pages, state as state_pool, wire
-from ..models import bind
+from ..models import bind, scopes
 from ..utils.hashing import chain_block_hashes
 from .blocks import (PrefixCachingAllocator, allocator_for, table_groups,
                      window_table_groups)
@@ -635,7 +635,8 @@ class TpuEngine:
                 params, self.bound.model_for(tokens.size), tokens, positions,
                 k_pages, v_pages, block_tables,
                 attention_fn=self._decode_attention)
-            nxt = sample_tokens(logits, keys[i], temps, top_k, top_p)
+            with scopes.block("sample"):
+                nxt = sample_tokens(logits, keys[i], temps, top_k, top_p)
             return (nxt, positions + 1, k_pages, v_pages,
                     toks.at[i].set(nxt))
 
@@ -659,11 +660,15 @@ class TpuEngine:
                 logits, (k_new, v_new) = self.model.forward(
                     params, self.bound.model_for(tokens.size), tokens,
                     want_kv=True, seq_len=seq_len)
-                k_pages, v_pages = pages.write_sequences(
-                    k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
-                last = jnp.take_along_axis(
-                    logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]  # [1, V]
-                tok = sample_tokens(last, key, temps, top_k, top_p)
+                with scopes.block("kv.write"):
+                    k_pages, v_pages = pages.write_sequences(
+                        k_pages, v_pages, k_new, v_new, block_table_row,
+                        seq_len)
+                with scopes.block("head"):
+                    last = jnp.take_along_axis(
+                        logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]
+                with scopes.block("sample"):
+                    tok = sample_tokens(last, key, temps, top_k, top_p)
                 return tok, k_pages, v_pages
             self._prefill_fns[bucket] = jax.jit(
                 _named(impl, f"prefill_b{bucket}"), donate_argnums=(3, 4))
@@ -686,11 +691,15 @@ class TpuEngine:
                 logits, (k_new, v_new) = self.model.forward(
                     params, self.bound.model_for(tokens.size), tokens,
                     want_kv=True, mm_embeds=mm_embeds, mm_positions=mm_positions)
-                k_pages, v_pages = pages.write_sequences(
-                    k_pages, v_pages, k_new, v_new, block_table_row, seq_len)
-                last = jnp.take_along_axis(
-                    logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]
-                tok = sample_tokens(last, rng, temps, top_k, top_p)
+                with scopes.block("kv.write"):
+                    k_pages, v_pages = pages.write_sequences(
+                        k_pages, v_pages, k_new, v_new, block_table_row,
+                        seq_len)
+                with scopes.block("head"):
+                    last = jnp.take_along_axis(
+                        logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]
+                with scopes.block("sample"):
+                    tok = sample_tokens(last, rng, temps, top_k, top_p)
                 return tok, k_pages, v_pages
             self._prefill_fns[key] = jax.jit(
                 _named(impl, f"mm_prefill_b{bucket}_m{mm_bucket}"),
@@ -714,7 +723,8 @@ class TpuEngine:
                     params, self.bound.model_for(tokens.size), tokens,
                     suffix_len, prefix_len,
                     k_pages, v_pages, block_table_row, prior_table_row)
-                tok = sample_tokens(logits, rng, temps, top_k, top_p)
+                with scopes.block("sample"):
+                    tok = sample_tokens(logits, rng, temps, top_k, top_p)
                 return tok, k_pages, v_pages
             self._prefill_fns[key] = jax.jit(
                 _named(impl, f"prefix_prefill_s{suffix_bucket}_p{prefix_bucket}"),

@@ -67,7 +67,9 @@ import jax.numpy as jnp
 
 from ..kvcache import pages, state
 from ..ops import causal_attention, rms_norm
+from . import scopes
 from .configs import ModelConfig
+from .llama import _embedded, _last_logits, _logits
 from .routing import route
 
 Params = dict[str, Any]
@@ -154,42 +156,53 @@ def latent_moe(cfg: ModelConfig, stack: Params, layer: int, h: jnp.ndarray,
     lp = {n: a[layer] for n, a in stack.items() if n not in ("w1", "w2")}
     first, count = cfg.held_experts
     ht = h.reshape(-1, h.shape[-1])
-    idx, gates = route(cfg, lp, ht)
-    here = (idx >= first) & (idx < first + count)
-    u = ht @ lp["w_down"]
-    local = jnp.where(here, idx - first, -1)
-    read = None
-    if cfg.moe_impl.startswith("grouped"):
-        from ..ops.pallas_moe import grouped_experts
+    with scopes.block("ffn.router"):
+        idx, gates = route(cfg, lp, ht)
+        here = (idx >= first) & (idx < first + count)
+    # The latent's down and up projections are the experts' own: every token
+    # pays them for its routed experts, whichever they are. (The grouped
+    # form's glue names itself inside.)
+    with scopes.block("ffn.experts"):
+        u = ht @ lp["w_down"]
+        local = jnp.where(here, idx - first, -1)
+        read = None
+        if cfg.moe_impl.startswith("grouped"):
+            from ..ops.pallas_moe import grouped_experts
 
-        # The kernel reads the stacked weights at (layer, expert): a slice
-        # of them would reach it as a copy (models/llama._over_layers).
-        r = grouped_experts(stack, u, idx, gates, count,
-                            layer=jnp.asarray(layer, jnp.int32), first=first,
-                            gated=False,
-                            interpret=cfg.moe_impl == "grouped_interpret")
-    elif cfg.moe_impl.startswith("chosen"):
-        from ..ops.pallas_moe import chosen_experts
+            # The kernel reads the stacked weights at (layer, expert): a
+            # slice of them would reach it as a copy
+            # (models/llama._over_layers).
+            r = grouped_experts(stack, u, idx, gates, count,
+                                layer=jnp.asarray(layer, jnp.int32),
+                                first=first, gated=False,
+                                interpret=cfg.moe_impl == "grouped_interpret")
+        elif cfg.moe_impl.startswith("chosen"):
+            from ..ops.pallas_moe import chosen_experts
 
-        # Dense over the held experts that a row of somebody's chose.
-        if real is not None:
-            local = jnp.where(real[:, None], local, -1)
-        r, read = chosen_experts(stack, u, local, gates, count,
-                                 layer=jnp.asarray(layer, jnp.int32),
-                                 gated=False,
-                                 interpret=cfg.moe_impl == "chosen_interpret")
-    else:
-        # Dense over the held experts: each of them for every token, weighted
-        # by its gate or by zero; the gate goes in ahead of the second
-        # product, which then sums over experts and width at once.
-        weights = jnp.einsum(
-            "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
-            gates.astype(h.dtype))
-        act = _relu2(jnp.einsum("tz,ezf->tef", u, stack["w1"][layer]))
-        r = jnp.einsum("tef,efz->tz", act * weights[..., None],
-                       stack["w2"][layer])
-    y = r @ lp["w_up"] + _relu2(ht @ lp["w1s"]) @ lp["w2s"]
-    return y.reshape(h.shape), idx, jnp.sum(here, dtype=jnp.int32), read
+            # Dense over the held experts that a row of somebody's chose.
+            if real is not None:
+                local = jnp.where(real[:, None], local, -1)
+            r, read = chosen_experts(
+                stack, u, local, gates, count,
+                layer=jnp.asarray(layer, jnp.int32), gated=False,
+                interpret=cfg.moe_impl == "chosen_interpret")
+        else:
+            # Dense over the held experts: each of them for every token,
+            # weighted by its gate or by zero; the gate goes in ahead of the
+            # second product, which then sums over experts and width at once.
+            weights = jnp.einsum(
+                "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
+                gates.astype(h.dtype))
+            act = _relu2(jnp.einsum("tz,ezf->tef", u, stack["w1"][layer]))
+            r = jnp.einsum("tef,efz->tz", act * weights[..., None],
+                           stack["w2"][layer])
+        y = r @ lp["w_up"]
+    with scopes.block("ffn.shared"):
+        y = y + _relu2(ht @ lp["w1s"]) @ lp["w2s"]
+    y = y.reshape(h.shape)
+    with scopes.block("ffn.router"):
+        held = jnp.sum(here, dtype=jnp.int32)
+    return y, idx, held, read
 
 
 # ---- M: Mamba-2 ---------------------------------------------------------------
@@ -243,71 +256,75 @@ def ssm_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
     B, S, _ = h.shape
     H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
     R, Kc = H // G, cfg.ssm_conv
-    z, xbc, dt = _split_in(cfg, lp, h)
-    if tail0 is None:
-        tail0 = jnp.zeros((B, Kc - 1, xbc.shape[-1]), xbc.dtype)
-    if state0 is None:
-        state0 = jnp.zeros((B, H, P, N), jnp.float32)
-    seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc], axis=1)
-    # Row t + j of seq is position t - (Kc - 1) + j.
-    conv = lp["conv_b"].astype(jnp.float32) + sum(
-        seq[:, j:j + S].astype(jnp.float32) * lp["conv_w"][j].astype(jnp.float32)
-        for j in range(Kc))
-    tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, Kc - 1))(
-        seq, lens)
-    x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
+    with scopes.block("state.proj"):
+        z, xbc, dt = _split_in(cfg, lp, h)
+        if tail0 is None:
+            tail0 = jnp.zeros((B, Kc - 1, xbc.shape[-1]), xbc.dtype)
+        if state0 is None:
+            state0 = jnp.zeros((B, H, P, N), jnp.float32)
+        seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc], axis=1)
+        # Row t + j of seq is position t - (Kc - 1) + j.
+        conv = lp["conv_b"].astype(jnp.float32) + sum(
+            seq[:, j:j + S].astype(jnp.float32) * lp["conv_w"][j].astype(jnp.float32)
+            for j in range(Kc))
+        tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, Kc - 1))(
+            seq, lens)
+        x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
 
-    real = jnp.arange(S)[None, :] < lens[:, None]
-    dt = jnp.where(real[..., None], _step_size(lp, dt), 0.0)    # [B, S, H]
-    a_head = -jnp.exp(lp["A_log"])                              # [H]
+    with scopes.block("state.update"):
+        real = jnp.arange(S)[None, :] < lens[:, None]
+        dt = jnp.where(real[..., None], _step_size(lp, dt), 0.0)    # [B, S, H]
+        a_head = -jnp.exp(lp["A_log"])                              # [H]
 
-    # Chunks of Q positions (a short bucket is one chunk); a run that is no
-    # whole number of them is padded with positions that change nothing.
-    Q = min(cfg.ssm_chunk, S)
-    pad = -S % Q
-    if pad:
-        x, b_mat, c_mat, dt = (
-            jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
-            for t in (x, b_mat, c_mat, dt))
-    nc = (S + pad) // Q
-    x = x.reshape(B, nc, Q, G, R, P)
-    b_mat, c_mat = (t.reshape(B, nc, Q, G, N) for t in (b_mat, c_mat))
-    dt = dt.reshape(B, nc, Q, G, R)
-    a = jnp.cumsum(dt * a_head.reshape(G, R), axis=2)           # <= 0
-    f32 = dict(preferred_element_type=jnp.float32)
-    dtx = (dt[..., None] * x.astype(jnp.float32))               # D_s x_s
+        # Chunks of Q positions (a short bucket is one chunk); a run that is no
+        # whole number of them is padded with positions that change nothing.
+        Q = min(cfg.ssm_chunk, S)
+        pad = -S % Q
+        if pad:
+            x, b_mat, c_mat, dt = (
+                jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+                for t in (x, b_mat, c_mat, dt))
+        nc = (S + pad) // Q
+        x = x.reshape(B, nc, Q, G, R, P)
+        b_mat, c_mat = (t.reshape(B, nc, Q, G, N) for t in (b_mat, c_mat))
+        dt = dt.reshape(B, nc, Q, G, R)
+        a = jnp.cumsum(dt * a_head.reshape(G, R), axis=2)           # <= 0
+        f32 = dict(preferred_element_type=jnp.float32)
+        dtx = (dt[..., None] * x.astype(jnp.float32))               # D_s x_s
 
-    # Inside a chunk: y_q = sum_(s <= q) exp(a_q - a_s) (C_q . B_s) D_s x_s.
-    cb = jnp.einsum("bcqgn,bcsgn->bcqsg", c_mat, b_mat, **f32)
-    q_at, s_at = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
-    decay = jnp.exp(jnp.where(
-        (q_at >= s_at)[None, None, :, :, None, None],
-        a[:, :, :, None] - a[:, :, None, :], -jnp.inf))         # [B,c,q,s,G,R]
-    y = jnp.einsum("bcqsgr,bcsgrp->bcqgrp",
-                   (cb[..., None] * decay).astype(h.dtype),
-                   dtx.astype(h.dtype), **f32)
+        # Inside a chunk: y_q = sum_(s <= q) exp(a_q - a_s) (C_q . B_s) D_s x_s.
+        cb = jnp.einsum("bcqgn,bcsgn->bcqsg", c_mat, b_mat, **f32)
+        q_at, s_at = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
+        decay = jnp.exp(jnp.where(
+            (q_at >= s_at)[None, None, :, :, None, None],
+            a[:, :, :, None] - a[:, :, None, :], -jnp.inf))         # [B,c,q,s,G,R]
+        y = jnp.einsum("bcqsgr,bcsgrp->bcqgrp",
+                       (cb[..., None] * decay).astype(h.dtype),
+                       dtx.astype(h.dtype), **f32)
 
-    # What a chunk adds to the state by its end, and the recurrence over
-    # chunks: S_c = exp(a_Q) S_(c-1) + sum_s exp(a_Q - a_s) D_s x_s (x) B_s.
-    to_end = jnp.exp(a[:, :, -1:] - a)
-    added = jnp.einsum("bcsgn,bcsgrp->bcgrpn", b_mat,
-                       (dtx * to_end[..., None]).astype(h.dtype), **f32)
+        # What a chunk adds to the state by its end, and the recurrence over
+        # chunks: S_c = exp(a_Q) S_(c-1) + sum_s exp(a_Q - a_s) D_s x_s (x) B_s.
+        to_end = jnp.exp(a[:, :, -1:] - a)
+        added = jnp.einsum("bcsgn,bcsgrp->bcgrpn", b_mat,
+                           (dtx * to_end[..., None]).astype(h.dtype), **f32)
 
-    def join(prev, chunk):
-        add, keep = chunk
-        return prev * keep[..., None, None] + add, prev
+        def join(prev, chunk):
+            add, keep = chunk
+            return prev * keep[..., None, None] + add, prev
 
-    state1, before = jax.lax.scan(
-        join, state0.reshape(B, G, R, P, N),
-        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(jnp.exp(a[:, :, -1]), 1, 0)))
-    # What the state before a chunk gives its positions: exp(a_q) C_q . S.
-    y = y + (jnp.einsum("bcqgn,cbgrpn->bcqgrp", c_mat,
-                        before.astype(h.dtype), **f32)
-             * jnp.exp(a)[..., None])
+        state1, before = jax.lax.scan(
+            join, state0.reshape(B, G, R, P, N),
+            (jnp.moveaxis(added, 1, 0), jnp.moveaxis(jnp.exp(a[:, :, -1]), 1, 0)))
+        # What the state before a chunk gives its positions: exp(a_q) C_q . S.
+        y = y + (jnp.einsum("bcqgn,cbgrpn->bcqgrp", c_mat,
+                            before.astype(h.dtype), **f32)
+                 * jnp.exp(a)[..., None])
 
-    y = y + lp["D"].reshape(G, R, 1) * x.astype(jnp.float32)
-    y = y.reshape(B, nc * Q, H * P)[:, :S]
-    return (_gated_out(cfg, lp, y, z), state1.reshape(B, H, P, N), tail)
+        y = y + lp["D"].reshape(G, R, 1) * x.astype(jnp.float32)
+        y = y.reshape(B, nc * Q, H * P)[:, :S]
+    with scopes.block("state.proj"):
+        out = _gated_out(cfg, lp, y, z)
+    return out, state1.reshape(B, H, P, N), tail
 
 
 def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
@@ -323,24 +340,31 @@ def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
     B = h.shape[0]
     H, P, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups
     R = H // G
-    z, xbc, dt = _split_in(cfg, lp, h)
-    tail0 = state.tail(cache, layer).reshape(B, cfg.ssm_conv - 1,
-                                             cfg.ssm_conv_dim)
-    seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
-    conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
-        seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
-    x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
-    x, b_mat, c_mat = (t.astype(jnp.float32) for t in (x, b_mat, c_mat))
-    dt = _step_size(lp, dt).reshape(B, G, R)
-    keep = jnp.exp(dt * -jnp.exp(lp["A_log"]).reshape(G, R))
-    cache, y = state.recur(
-        cache, layer, keep.reshape(B, H), (dt[..., None] * x).reshape(B, H, P),
-        b_mat, c_mat, impl=cfg.ssm_impl)
-    y = y.reshape(B, G, R, P) + lp["D"].reshape(G, R, 1) * x
-    return _gated_out(cfg, lp, y.reshape(B, H * P), z), cache, seq[:, 1:]
+    with scopes.block("state.proj"):
+        z, xbc, dt = _split_in(cfg, lp, h)
+        tail0 = state.tail(cache, layer).reshape(B, cfg.ssm_conv - 1,
+                                                 cfg.ssm_conv_dim)
+        seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
+        conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
+            seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
+        x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
+        x, b_mat, c_mat = (t.astype(jnp.float32) for t in (x, b_mat, c_mat))
+    with scopes.block("state.update"):
+        dt = _step_size(lp, dt).reshape(B, G, R)
+        keep = jnp.exp(dt * -jnp.exp(lp["A_log"]).reshape(G, R))
+        cache, y = state.recur(
+            cache, layer, keep.reshape(B, H), (dt[..., None] * x).reshape(B, H, P),
+            b_mat, c_mat, impl=cfg.ssm_impl)
+        y = y.reshape(B, G, R, P) + lp["D"].reshape(G, R, 1) * x
+    with scopes.block("state.proj"):
+        out = _gated_out(cfg, lp, y.reshape(B, H * P), z)
+    return out, cache, seq[:, 1:]
 
 
 # ---- the stack ------------------------------------------------------------------
+
+
+_NORM_SCOPE = {"M": "state.proj", "*": "attn.proj", "E": "ffn.router"}
 
 
 def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -360,7 +384,8 @@ def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     for kind in cfg.layer_pattern:
         stack, i = params[_STACK[kind]], seen[kind]
         seen[kind] += 1
-        h = rms_norm(x, stack["ln"][i], cfg.norm_eps)
+        with scopes.block(_NORM_SCOPE[kind]):   # with what reads it first
+            h = rms_norm(x, stack["ln"][i], cfg.norm_eps)
         if kind == "E":
             y, chosen, n, n_read = latent_moe(cfg, stack, i, h, real)
             held, routes = held + n, routes + [chosen]
@@ -375,9 +400,16 @@ def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
 def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
     """h [..., D] -> q [..., H, Dh], k and v [..., Hkv, Dh]; no rotation."""
     lead, Dh = h.shape[:-1], cfg.head_dim
-    return ((h @ lp["wq"]).reshape(*lead, cfg.n_heads, Dh),
-            (h @ lp["wk"]).reshape(*lead, cfg.n_kv_heads, Dh),
-            (h @ lp["wv"]).reshape(*lead, cfg.n_kv_heads, Dh))
+    with scopes.block("attn.proj"):
+        return ((h @ lp["wq"]).reshape(*lead, cfg.n_heads, Dh),
+                (h @ lp["wk"]).reshape(*lead, cfg.n_kv_heads, Dh),
+                (h @ lp["wv"]).reshape(*lead, cfg.n_kv_heads, Dh))
+
+
+def _out(lp: Params, attn: jnp.ndarray) -> jnp.ndarray:
+    """The attention's output [..., H, Dh] through ``wo``."""
+    with scopes.block("attn.proj"):
+        return attn.reshape(*attn.shape[:-2], -1) @ lp["wo"]
 
 
 def _stacked(rows: list[jnp.ndarray], like: tuple[int, ...], dtype
@@ -424,12 +456,14 @@ def forward(
     def attend(lp, h, i):
         q, k, v = _qkv(cfg, lp, h)
         ks.append(k), vs.append(v)
-        out = causal_attention(q, k, v, kv_valid=kv_valid)
-        return out.reshape(B, S, -1) @ lp["wo"]
+        with scopes.block("attn.core"):
+            out = causal_attention(q, k, v, kv_valid=kv_valid)
+        return _out(lp, out)
 
-    x, held, routes, _ = _walk(params, cfg, params["embed"][tokens],
+    x, held, routes, _ = _walk(params, cfg, _embedded(params, tokens),
                                {"M": ssm, "*": attend})
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with scopes.block("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     kv = None
     if want_kv:
         dt = x.dtype
@@ -440,7 +474,9 @@ def forward(
                             cfg.ssm_state), jnp.float32),
             _stacked(tails, (B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dt),
             held), None)
-    out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
+    with scopes.block("head"):
+        out = (x if want_hidden
+               else x @ params["lm_head"]).astype(jnp.float32)
     return (out, kv, jnp.stack(routes)) if want_routes else (out, kv)
 
 
@@ -466,7 +502,8 @@ def decode_step(
     cache = k_pages
     B = tokens.shape[0]
     seq_lens = positions + 1
-    cur_slots = pages.token_slots(cache.k, block_tables, positions)
+    with scopes.block("kv.write"):
+        cur_slots = pages.token_slots(cache.k, block_tables, positions)
     ks, vs, tails = [], [], []
 
     def ssm(lp, h, i):
@@ -478,23 +515,20 @@ def decode_step(
     def attend(lp, h, i):
         q, k, v = _qkv(cfg, lp, h)
         ks.append(k), vs.append(v)
-        out = attention_fn(q, cache.k, cache.v, jnp.asarray(i, jnp.int32),
-                           block_tables, seq_lens, k, v)
-        return out.reshape(B, -1) @ lp["wo"]
+        with scopes.block("attn.core"):
+            out = attention_fn(q, cache.k, cache.v, jnp.asarray(i, jnp.int32),
+                               block_tables, seq_lens, k, v)
+        return _out(lp, out)
 
     # A padding lane's choices are nobody's (asked only by the form that reads
     # the chosen experts).
     x, held, routes, read = _walk(
-        params, cfg, params["embed"][tokens], {"M": ssm, "*": attend},
+        params, cfg, _embedded(params, tokens), {"M": ssm, "*": attend},
         real=(pages.lanes_in_use(block_tables)
               if cfg.moe_impl.startswith("chosen") else None))
     cache = _written(cache, ks, vs, None, tails, held, cur_slots, read)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    if active is not None:
-        logits = jnp.where(active[:, None], logits, 0.0)
-    out = (logits, cache, None)
+    out = (_logits(params, cfg, x, active), cache, None)
     return (*out, jnp.stack(routes)) if want_routes else out
 
 
@@ -505,12 +539,13 @@ def _written(cache: state.Cache, ks, vs, ssms, tails, held, kv_slots,
     their slots (``ssms`` None: a decode step, whose states are in the cache
     already), the step's count of held choices added (and, where it counted
     them, of held experts ``read``)."""
-    if ks:
-        k, v = pages.write(cache.k, cache.v, jnp.stack(ks), jnp.stack(vs),
-                           *kv_slots)
-        cache = dataclasses.replace(cache, k=k, v=v)
-    if tails:
-        cache = state.write(cache, ssms, tails)
+    with scopes.block("kv.write"):
+        if ks:
+            k, v = pages.write(cache.k, cache.v, jnp.stack(ks), jnp.stack(vs),
+                               *kv_slots)
+            cache = dataclasses.replace(cache, k=k, v=v)
+        if tails:
+            cache = state.write(cache, ssms, tails)
     return state.counted(cache, held, read=read)
 
 
@@ -559,20 +594,19 @@ def prefill_with_prefix(
     def attend(lp, h, i):
         q, k, v = _qkv(cfg, lp, h)
         ks.append(k), vs.append(v)
-        k_prior, v_prior = pages.read_prefix(cache.k, cache.v,
-                                             prior_table_row, layer=i)
-        out = causal_attention(
-            q, jnp.concatenate([k_prior, k], axis=1),
-            jnp.concatenate([v_prior, v], axis=1), q_positions=positions,
-            kv_positions=kv_positions, kv_valid=kv_valid)
-        return out.reshape(B, S, -1) @ lp["wo"]
+        with scopes.block("attn.core"):
+            k_prior, v_prior = pages.read_prefix(cache.k, cache.v,
+                                                 prior_table_row, layer=i)
+            out = causal_attention(
+                q, jnp.concatenate([k_prior, k], axis=1),
+                jnp.concatenate([v_prior, v], axis=1), q_positions=positions,
+                kv_positions=kv_positions, kv_valid=kv_valid)
+        return _out(lp, out)
 
-    x, held, routes, _ = _walk(params, cfg, params["embed"][tokens],
+    x, held, routes, _ = _walk(params, cfg, _embedded(params, tokens),
                                {"M": ssm, "*": attend})
     cache = _written(cache, ks, vs, ssms, tails, held, pages.sequence_slots(
         cache.k, block_table_row, suffix_len, S, prefix_len))
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
-    out = ((last @ params["lm_head"]).astype(jnp.float32), cache, None)
+    out = (_last_logits(params, cfg, x, suffix_len), cache, None)
     return (*out, jnp.stack(routes)) if want_routes else out

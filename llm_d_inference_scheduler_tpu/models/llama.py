@@ -46,6 +46,7 @@ import numpy as np
 from ..kvcache import pages, state
 from ..ops import apply_rope, causal_attention, rms_norm, rope_table
 from ..ops.attention import banded_attention
+from . import scopes
 from .configs import ModelConfig
 
 Params = dict[str, Any]
@@ -100,9 +101,10 @@ def _route(cfg: ModelConfig, lp: Params, h: jnp.ndarray
     experts chosen [..., k], their gates [..., k] f32, a softmax over the
     chosen logits). The logits are f32 straight from the product
     (ops/pallas_moe.moe_ffn_grouped has the reason)."""
-    logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
-    top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
-    return top_idx, jax.nn.softmax(top_vals, axis=-1)
+    with scopes.block("ffn.router"):
+        logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
+        top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
+        return top_idx, jax.nn.softmax(top_vals, axis=-1)
 
 
 def _moe_ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
@@ -119,19 +121,21 @@ def _moe_ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
     that read another input chose them (:func:`_route`).
     """
     if route is None:
-        logits = (h @ lp["router"]).astype(jnp.float32)          # [B, S, E]
-        top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
-        gates = jax.nn.softmax(top_vals, axis=-1)                # [B, S, k]
+        with scopes.block("ffn.router"):
+            logits = (h @ lp["router"]).astype(jnp.float32)      # [B, S, E]
+            top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
+            gates = jax.nn.softmax(top_vals, axis=-1)            # [B, S, k]
     else:
         top_idx, gates = route
-    onehot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=h.dtype)  # [B,S,k,E]
-    weights = jnp.einsum("bske,bsk->bse", onehot, gates.astype(h.dtype))
+    with scopes.block("ffn.experts"):
+        onehot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=h.dtype)
+        weights = jnp.einsum("bske,bsk->bse", onehot, gates.astype(h.dtype))
 
-    up = jnp.einsum("bsd,edf->bsef", h, lp["w1"])
-    gate = jnp.einsum("bsd,edf->bsef", h, lp["w3"])
-    act = jax.nn.relu if cfg.expert_act == "reglu" else jax.nn.silu
-    out = jnp.einsum("bsef,efd->bsed", act(up) * gate, lp["w2"])
-    return jnp.einsum("bsed,bse->bsd", out, weights)
+        up = jnp.einsum("bsd,edf->bsef", h, lp["w1"])
+        gate = jnp.einsum("bsd,edf->bsef", h, lp["w3"])
+        act = jax.nn.relu if cfg.expert_act == "reglu" else jax.nn.silu
+        out = jnp.einsum("bsef,efd->bsed", act(up) * gate, lp["w2"])
+        return jnp.einsum("bsed,bse->bsd", out, weights)
 
 
 def qk_normed(cfg: ModelConfig, lp: Params, q: jnp.ndarray,
@@ -154,26 +158,36 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
         squeeze = h.ndim == 2  # decode step: [B, D]
         if squeeze:
             h = h[:, None]
-        if cfg.moe_impl.startswith("grouped") and route is not None:
-            from ..ops.pallas_moe import grouped_experts
+        # (The router and the grouped form's glue name themselves inside.)
+        with scopes.block("ffn.experts"):
+            if cfg.moe_impl.startswith("grouped") and route is not None:
+                from ..ops.pallas_moe import grouped_experts
 
-            y = grouped_experts(
-                lp, h.reshape(-1, h.shape[-1]),
-                *(r.reshape(-1, r.shape[-1]) for r in route), cfg.n_experts,
-                layer=lp.get("layer"),
-                interpret=cfg.moe_impl == "grouped_interpret",
-                reglu=cfg.expert_act == "reglu").reshape(h.shape)
-        elif cfg.moe_impl.startswith("grouped"):
-            from ..ops.pallas_moe import moe_ffn_grouped
+                y = grouped_experts(
+                    lp, h.reshape(-1, h.shape[-1]),
+                    *(r.reshape(-1, r.shape[-1]) for r in route),
+                    cfg.n_experts, layer=lp.get("layer"),
+                    interpret=cfg.moe_impl == "grouped_interpret",
+                    reglu=cfg.expert_act == "reglu").reshape(h.shape)
+            elif cfg.moe_impl.startswith("grouped"):
+                from ..ops.pallas_moe import moe_ffn_grouped
 
-            assert cfg.expert_act == "swiglu"   # its own router, and SwiGLU
-            y = moe_ffn_grouped(lp, h, cfg.n_experts, cfg.experts_per_token,
-                                layer=lp.get("layer"),
-                                interpret=cfg.moe_impl == "grouped_interpret")
-        else:
-            y = _moe_ffn(cfg, lp, h, route)
+                assert cfg.expert_act == "swiglu"   # its own router, and SwiGLU
+                y = moe_ffn_grouped(
+                    lp, h, cfg.n_experts, cfg.experts_per_token,
+                    layer=lp.get("layer"),
+                    interpret=cfg.moe_impl == "grouped_interpret")
+            else:
+                y = _moe_ffn(cfg, lp, h, route)
         return y[:, 0] if squeeze else y
-    return (jax.nn.silu(h @ lp["w1"]) * (h @ lp["w3"])) @ lp["w2"]
+    with scopes.block("ffn.dense"):
+        return (jax.nn.silu(h @ lp["w1"]) * (h @ lp["w3"])) @ lp["w2"]
+
+
+def _ffn_input(cfg: ModelConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """The FFN's normed input, under the scope of what reads it first."""
+    with scopes.block("ffn.router" if "router" in lp else "ffn.dense"):
+        return rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
 
 
 def _over_layers(cfg: ModelConfig, layers: Params) -> tuple[Params, Params]:
@@ -206,19 +220,21 @@ def _layer(
     B, S, _ = x.shape
     Dh = cfg.head_dim
 
-    h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, Dh)
-    k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, Dh)
-    v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, Dh)
-    q, k = qk_normed(cfg, lp, q, k)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with scopes.block("attn.proj"):
+        h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+        q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, Dh)
+        k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, Dh)
+        v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, Dh)
+        q, k = qk_normed(cfg, lp, q, k)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    attn = attention_fn(q, k, v, **attn_kwargs)
-    x = x + attn.reshape(B, S, -1) @ lp["wo"]
+    with scopes.block("attn.core"):
+        attn = attention_fn(q, k, v, **attn_kwargs)
+    with scopes.block("attn.proj"):
+        x = x + attn.reshape(B, S, -1) @ lp["wo"]
 
-    h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
-    x = x + _ffn(cfg, lp, h)
+    x = x + _ffn(cfg, lp, _ffn_input(cfg, lp, x))
     return x, k, v
 
 
@@ -252,21 +268,25 @@ def _mixed_block(cfg: ModelConfig, lp: Params, x: jnp.ndarray, cos, sin,
     B, S, _ = x.shape
     Dh = cfg.head_dim
 
-    h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
-    route = _route(cfg, lp, h) if cfg.router_input == "attn" else None
-    q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, Dh)
-    k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, Dh)
-    v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, Dh)
-    q, k = qk_normed(cfg, lp, q, k)
-    if cfg.full_nope:       # no position code where the whole context is seen
-        q = jnp.where(is_window, apply_rope(q, cos, sin), q)
-        k = jnp.where(is_window, apply_rope(k, cos, sin), k)
-    else:
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    with scopes.block("attn.proj"):
+        h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+        # (The router names itself, inside.)
+        route = _route(cfg, lp, h) if cfg.router_input == "attn" else None
+        q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, Dh)
+        k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, Dh)
+        v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, Dh)
+        q, k = qk_normed(cfg, lp, q, k)
+        if cfg.full_nope:   # no position code where the whole context is seen
+            q = jnp.where(is_window, apply_rope(q, cos, sin), q)
+            k = jnp.where(is_window, apply_rope(k, cos, sin), k)
+        else:
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
-    x = x + attend(q, k, v).reshape(B, S, -1) @ lp["wo"]
-    h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
-    x = x + _ffn(cfg, lp, h, route)
+    with scopes.block("attn.core"):
+        attn = attend(q, k, v)
+    with scopes.block("attn.proj"):
+        x = x + attn.reshape(B, S, -1) @ lp["wo"]
+    x = x + _ffn(cfg, lp, _ffn_input(cfg, lp, x), route)
     return x, k, v, None if route is None else route[0]
 
 
@@ -303,12 +323,14 @@ def forward(
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
 
-    x = params["embed"][tokens]  # [B, S, D]
-    if mm_embeds is not None:
-        x = x.at[jnp.arange(B)[:, None], mm_positions].set(
-            mm_embeds.astype(x.dtype), mode="drop")
+    with scopes.block("embed"):
+        x = params["embed"][tokens]  # [B, S, D]
+        if mm_embeds is not None:
+            x = x.at[jnp.arange(B)[:, None], mm_positions].set(
+                mm_embeds.astype(x.dtype), mode="drop")
     attn_kwargs = dict(q_positions=positions, kv_positions=positions, kv_valid=kv_valid)
 
     layers, whole = _over_layers(cfg, params["layers"])
@@ -342,13 +364,15 @@ def forward(
             # ``pages.write_sequences`` takes a cache's rows in.
             k, v, wk, wv = _by_kind(kinds, *kv)
             kv = state.Fresh(k, v, None, None, None, win=wk, win_v=wv), None
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if want_hidden:
-        # Embeddings surface: final-norm hidden states, lm head skipped
-        # (reference analogue: vLLM embedding models behind /v1/embeddings,
-        # routed by the EPP's embeddings body shape — types.go:74-75).
-        return x.astype(jnp.float32), kv
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with scopes.block("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if want_hidden:
+            # Embeddings surface: final-norm hidden states, lm head skipped
+            # (reference analogue: vLLM embedding models behind
+            # /v1/embeddings, routed by the EPP's embeddings body shape —
+            # types.go:74-75).
+            return x.astype(jnp.float32), kv
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     # ``want_routes`` (a model of two kinds of layer alone; here and on the
     # two step functions below) appends the experts every layer's early
     # router chose [L, B, S, k]: what a comparison holds its reference to.
@@ -394,43 +418,72 @@ def decode_step(
                                   want_routes)
     B = tokens.shape[0]
     Dh = cfg.head_dim
-    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)  # [B, half]
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)  # [B, half]
     seq_lens = positions + 1
 
-    cur_slots = pages.token_slots(k_pages, block_tables, positions)
+    with scopes.block("kv.write"):
+        cur_slots = pages.token_slots(k_pages, block_tables, positions)
 
-    x = params["embed"][tokens]  # [B, D]
+    x = _embedded(params, tokens)  # [B, D]
 
     layers, whole = _over_layers(cfg, params["layers"])
 
     def body(x, layer_in):
         lp, layer = layer_in
         lp = {**lp, **whole}
-        h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, cfg.n_heads, Dh)
-        k = (h @ lp["wk"]).reshape(B, cfg.n_kv_heads, Dh)
-        v = (h @ lp["wv"]).reshape(B, cfg.n_kv_heads, Dh)
-        q, k = qk_normed(cfg, lp, q, k)
-        q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
-        k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
+        with scopes.block("attn.proj"):
+            h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+            q = (h @ lp["wq"]).reshape(B, cfg.n_heads, Dh)
+            k = (h @ lp["wk"]).reshape(B, cfg.n_kv_heads, Dh)
+            v = (h @ lp["wv"]).reshape(B, cfg.n_kv_heads, Dh)
+            q, k = qk_normed(cfg, lp, q, k)
+            q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
+            k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
 
-        attn = attention_fn(q, k_pages, v_pages, layer, block_tables,
-                            seq_lens, k, v)
-        x = x + attn.reshape(B, -1) @ lp["wo"]
-        h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
-        x = x + _ffn(cfg, lp, h)
+        with scopes.block("attn.core"):
+            attn = attention_fn(q, k_pages, v_pages, layer, block_tables,
+                                seq_lens, k, v)
+        with scopes.block("attn.proj"):
+            x = x + attn.reshape(B, -1) @ lp["wo"]
+        x = x + _ffn(cfg, lp, _ffn_input(cfg, lp, x))
         return x, (k, v)
 
     x, (k_cur, v_cur) = jax.lax.scan(
         body, x, (layers, pages.layer_indices(k_pages)))
     # One fused scatter of all layers' current-token KV, [L, B, Hkv, Dh].
-    k_pages, v_pages = pages.write(k_pages, v_pages, k_cur, v_cur, *cur_slots)
+    with scopes.block("kv.write"):
+        k_pages, v_pages = pages.write(k_pages, v_pages, k_cur, v_cur,
+                                       *cur_slots)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    if active is not None:
-        logits = jnp.where(active[:, None], logits, 0.0)
-    return logits, k_pages, v_pages
+    return _logits(params, cfg, x, active), k_pages, v_pages
+
+
+def _embedded(params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
+    with scopes.block("embed"):
+        return params["embed"][tokens]
+
+
+def _logits(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+            active: jnp.ndarray | None = None) -> jnp.ndarray:
+    """The head on x [B, D]: final norm and ``lm_head``, logits [B, V] f32,
+    a padding lane's (``active`` False) zeroed."""
+    with scopes.block("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        if active is not None:
+            logits = jnp.where(active[:, None], logits, 0.0)
+        return logits
+
+
+def _last_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                 suffix_len: jnp.ndarray) -> jnp.ndarray:
+    """The head on the last real position of x [1, S, D]: [1, V] f32."""
+    with scopes.block("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        last = jnp.take_along_axis(
+            x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
+        return (last @ params["lm_head"]).astype(jnp.float32)
 
 
 def prefill_with_prefix(
@@ -465,49 +518,51 @@ def prefill_with_prefix(
     Dh = cfg.head_dim
 
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # [1,S]
-    cos, sin = rope_table(positions, Dh, cfg.rope_theta)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, Dh, cfg.rope_theta)
     suffix_valid = jnp.arange(S)[None, :] < suffix_len[:, None]          # [1,S]
     prior_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (1, T))
     prior_valid = prior_pos < prefix_len[:, None]                        # [1,T]
     kv_positions = jnp.concatenate([prior_pos, positions], axis=1)       # [1,T+S]
     kv_valid = jnp.concatenate([prior_valid, suffix_valid], axis=1)
 
-    x = params["embed"][tokens]  # [1, S, D]
+    x = _embedded(params, tokens)  # [1, S, D]
 
     layers, whole = _over_layers(cfg, params["layers"])
 
     def body(x, layer_in):
         lp, kp, vp = layer_in
         lp = {**lp, **whole}
-        h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(1, S, cfg.n_heads, Dh)
-        k = (h @ lp["wk"]).reshape(1, S, cfg.n_kv_heads, Dh)
-        v = (h @ lp["wv"]).reshape(1, S, cfg.n_kv_heads, Dh)
-        q, k = qk_normed(cfg, lp, q, k)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with scopes.block("attn.proj"):
+            h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+            q = (h @ lp["wq"]).reshape(1, S, cfg.n_heads, Dh)
+            k = (h @ lp["wk"]).reshape(1, S, cfg.n_kv_heads, Dh)
+            v = (h @ lp["wv"]).reshape(1, S, cfg.n_kv_heads, Dh)
+            q, k = qk_normed(cfg, lp, q, k)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-        k_prior, v_prior = pages.read_prefix(kp, vp, prior_table_row)
-        k_all = jnp.concatenate([k_prior, k], axis=1)
-        v_all = jnp.concatenate([v_prior, v], axis=1)
-        attn = causal_attention(q, k_all, v_all, q_positions=positions,
-                                kv_positions=kv_positions, kv_valid=kv_valid)
-        x = x + attn.reshape(1, S, -1) @ lp["wo"]
-        h = rms_norm(x, lp["ln_mlp"], cfg.norm_eps)
-        x = x + _ffn(cfg, lp, h)
+        with scopes.block("attn.core"):
+            k_prior, v_prior = pages.read_prefix(kp, vp, prior_table_row)
+            k_all = jnp.concatenate([k_prior, k], axis=1)
+            v_all = jnp.concatenate([v_prior, v], axis=1)
+            attn = causal_attention(
+                q, k_all, v_all, q_positions=positions,
+                kv_positions=kv_positions, kv_valid=kv_valid)
+        with scopes.block("attn.proj"):
+            x = x + attn.reshape(1, S, -1) @ lp["wo"]
+        x = x + _ffn(cfg, lp, _ffn_input(cfg, lp, x))
         return x, (k, v)
 
     x, (k_new, v_new) = jax.lax.scan(body, x, (layers, k_pages, v_pages))
 
     # Scatter suffix KV at offset positions (padding → trash block 0).
-    k_pages, v_pages = pages.write_sequences(
-        k_pages, v_pages, k_new, v_new, block_table_row, suffix_len,
-        start=prefix_len)
+    with scopes.block("kv.write"):
+        k_pages, v_pages = pages.write_sequences(
+            k_pages, v_pages, k_new, v_new, block_table_row, suffix_len,
+            start=prefix_len)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
-    logits = (last @ params["lm_head"]).astype(jnp.float32)
-    return logits, k_pages, v_pages
+    return _last_logits(params, cfg, x, suffix_len), k_pages, v_pages
 
 
 # ---- two kinds of layer: the step programs ------------------------------------
@@ -524,7 +579,8 @@ def _mixed_decode_step(params: Params, cfg: ModelConfig, tokens, positions,
     after the scan. Returns (logits, cache, None)."""
     B = tokens.shape[0]
     window, among = kinds = _kinds(cfg)
-    cos, sin = rope_table(positions[:, None], cfg.head_dim, cfg.rope_theta)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions[:, None], cfg.head_dim, cfg.rope_theta)
     seq_lens = positions + 1
     layers, whole = _over_layers(cfg, params["layers"])
 
@@ -545,22 +601,20 @@ def _mixed_decode_step(params: Params, cfg: ModelConfig, tokens, positions,
                                       is_window, attend)
         return x, (k[:, 0], v[:, 0], chose if want_routes else None)
 
-    x, (*kv, routes) = jax.lax.scan(body, params["embed"][tokens][:, None],
+    x, (*kv, routes) = jax.lax.scan(body, _embedded(params, tokens)[:, None],
                                     (layers, window, among))
-    k, v, wk, wv = _by_kind(kinds, *kv)
-    k_pages, v_pages = pages.write(
-        cache.k, cache.v, k, v,
-        *pages.token_slots(cache.k, block_tables, positions))
-    win, win_v = pages.write(
-        cache.win, cache.win_v, wk, wv,
-        *pages.token_slots(cache.win, cache.wt, positions))
+    with scopes.block("kv.write"):
+        k, v, wk, wv = _by_kind(kinds, *kv)
+        k_pages, v_pages = pages.write(
+            cache.k, cache.v, k, v,
+            *pages.token_slots(cache.k, block_tables, positions))
+        win, win_v = pages.write(
+            cache.win, cache.win_v, wk, wv,
+            *pages.token_slots(cache.win, cache.wt, positions))
 
-    x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    if active is not None:
-        logits = jnp.where(active[:, None], logits, 0.0)
-    out = (logits, dataclasses.replace(cache, k=k_pages, v=v_pages, win=win,
-                                       win_v=win_v), None)
+    out = (_logits(params, cfg, x[:, 0], active),
+           dataclasses.replace(cache, k=k_pages, v=v_pages, win=win,
+                               win_v=win_v), None)
     return (*out, routes) if want_routes else out
 
 
@@ -581,7 +635,8 @@ def _mixed_prefill_with_prefix(params: Params, cfg: ModelConfig, tokens,
     window, among = kinds = _kinds(cfg)
     block = pages.block_size(cache.k)
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
     own_valid = jnp.arange(S)[None, :] < suffix_len[:, None]
     prior_pos = jnp.arange(prior_table_row.shape[1] * block,
                            dtype=jnp.int32)[None, :]
@@ -619,18 +674,18 @@ def _mixed_prefill_with_prefix(params: Params, cfg: ModelConfig, tokens,
                                       is_window, attend)
         return x, (k, v, chose if want_routes else None)
 
-    x, (*kv, routes) = jax.lax.scan(body, params["embed"][tokens],
+    x, (*kv, routes) = jax.lax.scan(body, _embedded(params, tokens),
                                     (layers, window, among))
-    k, v, wk, wv = _by_kind(kinds, *kv)
-    k_pages, v_pages = pages.write_sequences(
-        cache.k, cache.v, k, v, block_table_row, suffix_len, start=prefix_len)
-    win, win_v = pages.write_sequences(
-        cache.win, cache.win_v, wk, wv, cache.wt, suffix_len,
-        start=prefix_len)
+    with scopes.block("kv.write"):
+        k, v, wk, wv = _by_kind(kinds, *kv)
+        k_pages, v_pages = pages.write_sequences(
+            cache.k, cache.v, k, v, block_table_row, suffix_len,
+            start=prefix_len)
+        win, win_v = pages.write_sequences(
+            cache.win, cache.win_v, wk, wv, cache.wt, suffix_len,
+            start=prefix_len)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
-    logits = (last @ params["lm_head"]).astype(jnp.float32)
-    out = (logits, dataclasses.replace(cache, k=k_pages, v=v_pages, win=win,
-                                       win_v=win_v), None)
+    out = (_last_logits(params, cfg, x, suffix_len),
+           dataclasses.replace(cache, k=k_pages, v=v_pages, win=win,
+                               win_v=win_v), None)
     return (*out, routes) if want_routes else out

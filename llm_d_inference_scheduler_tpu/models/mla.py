@@ -140,8 +140,10 @@ from ..ops import (apply_rope, pallas_dsa, rms_norm, rope_table,
                    sparse_attention)
 from ..ops.attention import NEG_INF
 from ..ops.rope import yarn_mscale
+from . import scopes
 from .configs import ModelConfig
-from .llama import _over_layers
+from .llama import (_embedded, _ffn_input, _last_logits, _logits,
+                    _over_layers)
 from .routing import route
 
 Params = dict[str, Any]
@@ -265,8 +267,9 @@ def init_params(cfg: ModelConfig, key: jax.Array,
 # ---- FFN ----------------------------------------------------------------------
 
 
-def _swiglu(h, w1, w3, w2):
-    return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+def _swiglu(h, w1, w3, w2, scope: str = "ffn.dense"):
+    with scopes.block(scope):
+        return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
 
 
 def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
@@ -283,55 +286,62 @@ def _ffn(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
     if "router" not in lp:
         return _swiglu(h, lp["w1"], lp["w3"], lp["w2"]), None, None
     ht = h.reshape(-1, h.shape[-1])
-    idx, gates = route(cfg, lp, ht)
-    # The experts this chip holds: all of them (nothing to tell apart, and
-    # the parameters are indexed by the router's own ids), or a range.
-    first, count = cfg.held_experts
-    here = ((idx >= first) & (idx < first + count) if cfg.tallies_choices
-            else None)
-    local = idx if here is None else jnp.where(here, idx - first, -1)
+    with scopes.block("ffn.router"):
+        idx, gates = route(cfg, lp, ht)
+        # The experts this chip holds: all of them (nothing to tell apart, and
+        # the parameters are indexed by the router's own ids), or a range.
+        first, count = cfg.held_experts
+        here = ((idx >= first) & (idx < first + count) if cfg.tallies_choices
+                else None)
+        local = idx if here is None else jnp.where(here, idx - first, -1)
     read = []
-    if cfg.moe_impl.startswith("grouped"):
-        from ..ops.pallas_moe import grouped_experts
+    # (The grouped form's glue names itself inside.)
+    with scopes.block("ffn.experts"):
+        if cfg.moe_impl.startswith("grouped"):
+            from ..ops.pallas_moe import grouped_experts
 
-        # A choice of no expert held here (another chip's, or a zero-compute
-        # one) is sorted behind the last group and adds no row to any.
-        y = grouped_experts(lp, ht, idx, gates, count,
-                            layer=lp.get("layer"),
-                            first=None if here is None else first,
-                            interpret=cfg.moe_impl == "grouped_interpret")
-    elif cfg.moe_impl.startswith("chosen"):
-        from ..ops.pallas_moe import chosen_experts
+            # A choice of no expert held here (another chip's, or a
+            # zero-compute one) is sorted behind the last group and adds no
+            # row to any.
+            y = grouped_experts(lp, ht, idx, gates, count,
+                                layer=lp.get("layer"),
+                                first=None if here is None else first,
+                                interpret=cfg.moe_impl == "grouped_interpret")
+        elif cfg.moe_impl.startswith("chosen"):
+            from ..ops.pallas_moe import chosen_experts
 
-        # Dense over the held experts that a row of somebody's chose.
-        if real is not None:
-            local = jnp.where(real[:, None], local, -1)
-        y, n_read = chosen_experts(
-            lp, ht, local, gates, count, layer=lp.get("layer"),
-            interpret=cfg.moe_impl == "chosen_interpret")
-        read = [n_read]
-    else:
-        # Dense over the held experts: each of them for every token, weighted
-        # by its gate or by zero (models/llama._moe_ffn's form).
-        weights = jnp.einsum(
-            "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
-            gates.astype(h.dtype))
-        up = jnp.einsum("td,edf->tef", ht, lp["w1"])
-        gate = jnp.einsum("td,edf->tef", ht, lp["w3"])
-        out = jnp.einsum("tef,efd->ted", jax.nn.silu(up) * gate, lp["w2"])
-        y = jnp.einsum("ted,te->td", out, weights)
+            # Dense over the held experts that a row of somebody's chose.
+            if real is not None:
+                local = jnp.where(real[:, None], local, -1)
+            y, n_read = chosen_experts(
+                lp, ht, local, gates, count, layer=lp.get("layer"),
+                interpret=cfg.moe_impl == "chosen_interpret")
+            read = [n_read]
+        else:
+            # Dense over the held experts: each of them for every token,
+            # weighted by its gate or by zero (models/llama._moe_ffn's form).
+            weights = jnp.einsum(
+                "tke,tk->te", jax.nn.one_hot(local, count, dtype=h.dtype),
+                gates.astype(h.dtype))
+            up = jnp.einsum("td,edf->tef", ht, lp["w1"])
+            gate = jnp.einsum("td,edf->tef", ht, lp["w3"])
+            out = jnp.einsum("tef,efd->ted", jax.nn.silu(up) * gate, lp["w2"])
+            y = jnp.einsum("ted,te->td", out, weights)
     if "w1s" in lp:
-        y = y + _swiglu(ht, lp["w1s"], lp["w3s"], lp["w2s"])
+        y = y + _swiglu(ht, lp["w1s"], lp["w3s"], lp["w2s"], "ffn.shared")
     if here is None:
         return y.reshape(h.shape), idx, None
     zero = idx >= cfg.n_experts
     if cfg.n_zero_experts:
         # An expert that computes nothing returns the token: the zero
         # choices' gates, summed, times h, beside either form above.
-        y = y + (jnp.sum(jnp.where(zero, gates, 0.0), axis=-1, keepdims=True)
-                 * ht.astype(jnp.float32)).astype(h.dtype)
-    counts = jnp.stack([jnp.sum(here, dtype=jnp.int32),
-                        jnp.sum(zero, dtype=jnp.int32), *read])
+        with scopes.block("ffn.experts"):
+            y = y + (jnp.sum(jnp.where(zero, gates, 0.0), axis=-1,
+                             keepdims=True)
+                     * ht.astype(jnp.float32)).astype(h.dtype)
+    with scopes.block("ffn.router"):
+        counts = jnp.stack([jnp.sum(here, dtype=jnp.int32),
+                            jnp.sum(zero, dtype=jnp.int32), *read])
     return y.reshape(h.shape), idx, counts
 
 
@@ -354,27 +364,31 @@ def _project(cfg: ModelConfig, lp: Params, h: jnp.ndarray, cos, sin
     index_dim]: :func:`_split_rows`) and a fourth value is the indexer's
     (queries, weights) of these tokens."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    if cfg.q_lora_rank:
-        c_q = rms_norm(h @ lp["wqa"], lp["q_norm"], cfg.norm_eps)
-        q = c_q @ lp["wqb"]
-        if cfg.mla_scale_q_lora:
-            q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
-    else:
-        q = h @ lp["wq"]
-    q = q.reshape(*h.shape[:-1], cfg.n_heads, -1)
-    kva = h @ lp["wkva"]
-    c = rms_norm(kva[..., :r], lp["kv_norm"], cfg.norm_eps)
-    if cfg.mla_scale_kv_lora:
-        # Ahead of W_kvb and of the cache: a cached row holds the scaled
-        # latent, so both forms of attention read what they should.
-        c = c * (cfg.d_model / r) ** 0.5
-    q_rope = apply_rope(q[..., dn:], cos, sin)
-    k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
-    if cfg.index_topk:
-        q_idx, k_idx, w_idx = _index_project(cfg, lp, h, c_q, cos, sin)
-        return (q[..., :dn], q_rope,
-                jnp.concatenate([c, k_rope, k_idx], axis=-1), (q_idx, w_idx))
-    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], axis=-1)
+    with scopes.block("attn.proj"):
+        if cfg.q_lora_rank:
+            c_q = rms_norm(h @ lp["wqa"], lp["q_norm"], cfg.norm_eps)
+            q = c_q @ lp["wqb"]
+            if cfg.mla_scale_q_lora:
+                q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
+        else:
+            q = h @ lp["wq"]
+        q = q.reshape(*h.shape[:-1], cfg.n_heads, -1)
+        kva = h @ lp["wkva"]
+        c = rms_norm(kva[..., :r], lp["kv_norm"], cfg.norm_eps)
+        if cfg.mla_scale_kv_lora:
+            # Ahead of W_kvb and of the cache: a cached row holds the scaled
+            # latent, so both forms of attention read what they should.
+            c = c * (cfg.d_model / r) ** 0.5
+        q_rope = apply_rope(q[..., dn:], cos, sin)
+        k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+        if cfg.index_topk:
+            with scopes.block("attn.index"):
+                q_idx, k_idx, w_idx = _index_project(cfg, lp, h, c_q, cos,
+                                                     sin)
+            return (q[..., :dn], q_rope,
+                    jnp.concatenate([c, k_rope, k_idx], axis=-1),
+                    (q_idx, w_idx))
+        return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], axis=-1)
 
 
 def _index_project(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
@@ -494,28 +508,35 @@ def expanded_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, rows,
     their name."""
     B, S, H, _ = q_nope.shape
     r = cfg.kv_lora_rank
-    w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
-    c, k_rope = rows[..., :r], rows[..., r:cfg.latent_dim]
+    with scopes.block("attn.expand"):
+        w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
+        c, k_rope = rows[..., :r], rows[..., r:cfg.latent_dim]
     impl = cfg.expanded_impl if impl is None else impl
     if name is None:
         name = ("dsa_window_attention" if cfg.index_topk
                 else "mla_window_attention")
     if impl.startswith("kernel"):
-        out = pallas_dsa.masked_window_attention_pallas(
-            jnp.swapaxes(q_nope, 1, 2), jnp.swapaxes(q_rope, 1, 2),
-            jnp.einsum("btr,rhd->bhtd", c, w_uk), k_rope,
-            jnp.einsum("btr,rhd->bhtd", c, w_uv), mask, scale=_scale(cfg),
-            interpret=impl == "kernel_interpret", name=name)
-        return jnp.swapaxes(out, 1, 2).reshape(B, S, -1)
-    k_nope = jnp.einsum("btr,rhd->bthd", c, w_uk)
-    v = jnp.einsum("btr,rhd->bthd", c, w_uv)
-    f32 = dict(preferred_element_type=jnp.float32)
-    scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope, **f32)
-              + jnp.einsum("bshd,btd->bhst", q_rope, k_rope, **f32))
-    scores = jnp.where(mask[:, None], scores * _scale(cfg), NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", probs, v, **f32)
-    return out.astype(q_nope.dtype).reshape(B, S, -1)
+        with scopes.block("attn.core"):
+            q_nope, q_rope = (jnp.swapaxes(q, 1, 2) for q in (q_nope, q_rope))
+        with scopes.block("attn.expand"):
+            k_nope = jnp.einsum("btr,rhd->bhtd", c, w_uk)
+            v = jnp.einsum("btr,rhd->bhtd", c, w_uv)
+        with scopes.block("attn.core"):
+            out = pallas_dsa.masked_window_attention_pallas(
+                q_nope, q_rope, k_nope, k_rope, v, mask, scale=_scale(cfg),
+                interpret=impl == "kernel_interpret", name=name)
+            return jnp.swapaxes(out, 1, 2).reshape(B, S, -1)
+    with scopes.block("attn.expand"):
+        k_nope = jnp.einsum("btr,rhd->bthd", c, w_uk)
+        v = jnp.einsum("btr,rhd->bthd", c, w_uv)
+    with scopes.block("attn.core"):
+        f32 = dict(preferred_element_type=jnp.float32)
+        scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope, **f32)
+                  + jnp.einsum("bshd,btd->bhst", q_rope, k_rope, **f32))
+        scores = jnp.where(mask[:, None], scores * _scale(cfg), NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bhst,bthd->bshd", probs, v, **f32)
+        return out.astype(q_nope.dtype).reshape(B, S, -1)
 
 
 def absorbed_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, cur_row,
@@ -524,11 +545,14 @@ def absorbed_attention(cfg: ModelConfig, lp: Params, q_nope, q_rope, cur_row,
     absorbed form: ``attend(q [B, H, r + dr], cur_row)`` -> [B, H, r] is the
     attention over cache rows as they lie (the paged pool, or any rows at
     all in the tests). Returns [B, H * dv]."""
-    w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
-    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_uk)
-    o_lat = attend(jnp.concatenate([q_lat, q_rope], axis=-1), cur_row)
-    out = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)
-    return out.reshape(out.shape[0], -1)
+    with scopes.block("attn.proj"):
+        w_uk, w_uv = _split_kvb(cfg, lp["wkvb"])
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_uk)
+    with scopes.block("attn.core"):
+        o_lat = attend(jnp.concatenate([q_lat, q_rope], axis=-1), cur_row)
+    with scopes.block("attn.proj"):
+        out = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)
+        return out.reshape(out.shape[0], -1)
 
 
 # ---- the stack ------------------------------------------------------------------
@@ -625,11 +649,12 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend,
         def body(x, layer_in):
             lp, layer = layer_in
             lp = {**lp, **whole}
-            h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+            with scopes.block("attn.proj"):
+                h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
             a, row = attend(lp, h, layer)
-            x = x + _gated(kcfg, lp, h, a) @ lp["wo"]
-            y, chosen, tally = _ffn(cfg, lp, rms_norm(x, lp["ln_mlp"],
-                                                      cfg.norm_eps), real)
+            with scopes.block("attn.proj"):
+                x = x + _gated(kcfg, lp, h, a) @ lp["wo"]
+            y, chosen, tally = _ffn(cfg, lp, _ffn_input(cfg, lp, x), real)
             return x + y, (row, chosen, tally)
 
         def double_body(x, layer_in):
@@ -638,11 +663,13 @@ def _blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray, attend,
             made = []
             for i in range(2):
                 sub = {k: v[2 * layer + i] for k, v in subs.items()}
-                a, row = attend(sub, rms_norm(x, sub["ln_attn"],
-                                              cfg.norm_eps), 2 * layer + i)
+                with scopes.block("attn.proj"):
+                    h = rms_norm(x, sub["ln_attn"], cfg.norm_eps)
+                a, row = attend(sub, h, 2 * layer + i)
                 made.append(row)
-                x = x + a @ sub["wo"]
-                h = rms_norm(x, sub["ln_mlp"], cfg.norm_eps)
+                with scopes.block("attn.proj"):
+                    x = x + a @ sub["wo"]
+                h = _ffn_input(cfg, sub, x)
                 if i == 0:
                     m, chosen, tally = _ffn(cfg, lp, h, real)
                 x = x + _swiglu(h, sub["w1d"], sub["w3d"], sub["w2d"])
@@ -693,8 +720,9 @@ def _window_side(cfg: ModelConfig, positions: jnp.ndarray):
     if not cfg.window_attn:
         return None
     wcfg = cfg.of_window()
-    return (wcfg, rope_table(positions, wcfg.qk_rope_head_dim,
-                             wcfg.rope_theta), wcfg.window_attn.window)
+    with scopes.block("attn.proj"):
+        table = rope_table(positions, wcfg.qk_rope_head_dim, wcfg.rope_theta)
+    return wcfg, table, wcfg.window_attn.window
 
 
 def forward(
@@ -725,8 +753,9 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :],
                                      (B, S))
-    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
-                          cfg.rope_yarn)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                              cfg.rope_yarn)
     mask = positions[:, :, None] >= positions[:, None, :]          # [B, S, S]
     if kv_valid is not None:
         mask = mask & kv_valid[:, None, :]
@@ -735,7 +764,8 @@ def forward(
         q_nope, q_rope, rows, *index = _project(cfg, lp, h, cos, sin)
         if not index:
             return expanded_attention(cfg, lp, q_nope, q_rope, rows, mask), rows
-        seen = _selected(cfg, *index, _split_rows(cfg, rows)[1], mask)
+        with scopes.block("attn.index"):
+            seen = _selected(cfg, *index, _split_rows(cfg, rows)[1], mask)
         return (expanded_attention(cfg, lp, q_nope, q_rope, rows, seen),
                 _with_picked(want_routes, rows, seen))
 
@@ -752,8 +782,9 @@ def forward(
         attend = dict(full=attend, window=attend_window)
 
     x, rows, routes, counts, wrows = _blocks(
-        params, cfg, params["embed"][tokens], attend)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        params, cfg, _embedded(params, tokens), attend)
+    with scopes.block("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     kv = None
     if want_routes and cfg.index_topk:
         routes = (routes, _picked(cfg, rows))
@@ -765,7 +796,9 @@ def forward(
                else state.Fresh(rows, None, None, None, *counts[:2],
                                 idx=idx, win=wrows)),
               None)
-    out = (x if want_hidden else x @ params["lm_head"]).astype(jnp.float32)
+    with scopes.block("head"):
+        out = (x if want_hidden
+               else x @ params["lm_head"]).astype(jnp.float32)
     return (out, kv, routes) if want_routes else (out, kv)
 
 
@@ -789,12 +822,14 @@ def decode_step(
     attention's extra column. ``attention_fn`` has
     ``pages.latent_decode_attention``'s signature; the engine binds the
     kernel into it."""
-    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
-                          cfg.rope_yarn)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                              cfg.rope_yarn)
     seq_lens = positions + 1
     pool = _pool_of(k_pages)
     idx_pool = k_pages.idx if cfg.index_topk else None
-    cur_slots = pages.token_slots(pool, block_tables, positions)
+    with scopes.block("kv.write"):
+        cur_slots = pages.token_slots(pool, block_tables, positions)
 
     def attend(lp, h, layer):
         q_nope, q_rope, row, *index = _project(cfg, lp, h, cos, sin)
@@ -805,12 +840,14 @@ def decode_step(
             # length are nobody's to see.
             (q_idx, w_idx), cur_key = index[0], _split_rows(cfg, row)[1]
             T = block_tables.shape[1] * pages.block_size(pool)
-            seen = jnp.concatenate(
-                [jnp.arange(T)[None, :] < positions[:, None],
-                 jnp.ones((positions.shape[0], 1), bool)], axis=1)
-            keep = _selected_of_lanes(cfg, q_idx, w_idx, idx_pool, layer,
-                                      block_tables, seq_lens, cur_key, seen)
-            chosen = dict(keep=keep[:, :T], cur_keep=keep[:, T])
+            with scopes.block("attn.index"):
+                seen = jnp.concatenate(
+                    [jnp.arange(T)[None, :] < positions[:, None],
+                     jnp.ones((positions.shape[0], 1), bool)], axis=1)
+                keep = _selected_of_lanes(
+                    cfg, q_idx, w_idx, idx_pool, layer, block_tables,
+                    seq_lens, cur_key, seen)
+                chosen = dict(keep=keep[:, :T], cur_keep=keep[:, T])
             row = _with_picked(want_routes, row, keep)
 
         def paged(q, cur_row):
@@ -843,26 +880,24 @@ def decode_step(
     # A padding lane's choices are nobody's (asked only by the form that reads
     # the chosen experts).
     x, rows, routes, counts, wrows = _blocks(
-        params, cfg, params["embed"][tokens], attend,
+        params, cfg, _embedded(params, tokens), attend,
         real=(pages.lanes_in_use(block_tables)
               if cfg.moe_impl.startswith("chosen") else None))
     if want_routes and cfg.index_topk:
         routes = (routes, _picked(cfg, rows))
-    rows, idx_rows = _split_rows(cfg, rows)
-    pool, _ = pages.write(pool, None, rows, None, *cur_slots)
-    if idx_rows is not None:
-        idx_pool, _ = pages.write(idx_pool, None, idx_rows, None, *cur_slots)
-    if wrows is not None:
-        win_pool, _ = pages.write(
-            win_pool, None, wrows, None,
-            *pages.token_slots(win_pool, win_tables, positions))
+    with scopes.block("kv.write"):
+        rows, idx_rows = _split_rows(cfg, rows)
+        pool, _ = pages.write(pool, None, rows, None, *cur_slots)
+        if idx_rows is not None:
+            idx_pool, _ = pages.write(idx_pool, None, idx_rows, None,
+                                      *cur_slots)
+        if wrows is not None:
+            win_pool, _ = pages.write(
+                win_pool, None, wrows, None,
+                *pages.token_slots(win_pool, win_tables, positions))
     k_pages = _kept(k_pages, pool, counts, idx_pool, win_pool)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    if active is not None:
-        logits = jnp.where(active[:, None], logits, 0.0)
-    out = (logits, k_pages, None)
+    out = (_logits(params, cfg, x, active), k_pages, None)
     return (*out, routes) if want_routes else out
 
 
@@ -893,8 +928,9 @@ def prefill_with_prefix(
     T = prior_table_row.shape[1] * pages.block_size(pool)
 
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
-                          cfg.rope_yarn)
+    with scopes.block("attn.proj"):
+        cos, sin = rope_table(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                              cfg.rope_yarn)
     prior_pos = jnp.arange(T, dtype=jnp.int32)[None, :]
     kv_pos = jnp.concatenate([prior_pos, positions], axis=1)        # [1, T+S]
     kv_valid = jnp.concatenate(
@@ -906,16 +942,18 @@ def prefill_with_prefix(
     def attend(lp, h, layer):
         q_nope, q_rope, rows, *index = _project(cfg, lp, h, cos, sin)
         own, own_keys = _split_rows(cfg, rows)
-        prior = pages.read_latent_prefix(pool, layer, prior_table_row,
-                                         cfg.latent_dim)
-        seen = jnp.concatenate([prior.astype(rows.dtype), own], axis=1)
+        with scopes.block("attn.expand"):    # the rows it carries out
+            prior = pages.read_latent_prefix(pool, layer, prior_table_row,
+                                             cfg.latent_dim)
+            seen = jnp.concatenate([prior.astype(rows.dtype), own], axis=1)
         chosen = mask
         if index:
-            keys = jnp.concatenate(
-                [pages.read_latent_prefix(idx_pool, layer, prior_table_row,
-                                          cfg.index_dim).astype(rows.dtype),
-                 own_keys], axis=1)
-            chosen = _selected(cfg, *index, keys, mask)
+            with scopes.block("attn.index"):
+                keys = jnp.concatenate(
+                    [pages.read_latent_prefix(
+                        idx_pool, layer, prior_table_row,
+                        cfg.index_dim).astype(rows.dtype), own_keys], axis=1)
+                chosen = _selected(cfg, *index, keys, mask)
             rows = _with_picked(want_routes, rows, chosen)
         return expanded_attention(cfg, lp, q_nope, q_rope, seen, chosen), rows
 
@@ -937,9 +975,11 @@ def prefill_with_prefix(
 
         def attend_window(lp, h, layer):
             q_nope, q_rope, rows = _project(wcfg, lp, h, wcos, wsin)
-            prior = pages.read_latent_prefix(win_pool, layer, ids,
-                                             wcfg.latent_dim)
-            seen = jnp.concatenate([prior.astype(rows.dtype), rows], axis=1)
+            with scopes.block("attn.expand"):
+                prior = pages.read_latent_prefix(win_pool, layer, ids,
+                                                 wcfg.latent_dim)
+                seen = jnp.concatenate([prior.astype(rows.dtype), rows],
+                                       axis=1)
             return expanded_attention(
                 wcfg, lp, q_nope, q_rope, seen, band, impl=cfg.swa_impl,
                 name="swa_window_attention"), rows
@@ -947,23 +987,23 @@ def prefill_with_prefix(
         attend = dict(full=attend, window=attend_window)
 
     x, rows, routes, counts, wrows = _blocks(
-        params, cfg, params["embed"][tokens], attend)
+        params, cfg, _embedded(params, tokens), attend)
     if want_routes and cfg.index_topk:
         routes = (routes, _picked(cfg, rows))
-    rows, idx_rows = _split_rows(cfg, rows)
-    pool, _ = pages.write_sequences(pool, None, rows, None, block_table_row,
-                                    suffix_len, start=prefix_len)
-    if idx_rows is not None:
-        idx_pool, _ = pages.write_sequences(
-            idx_pool, None, idx_rows, None, block_table_row, suffix_len,
+    with scopes.block("kv.write"):
+        rows, idx_rows = _split_rows(cfg, rows)
+        pool, _ = pages.write_sequences(
+            pool, None, rows, None, block_table_row, suffix_len,
             start=prefix_len)
-    if wrows is not None:
-        win_pool, _ = pages.write_sequences(
-            win_pool, None, wrows, None, win_table, suffix_len,
-            start=prefix_len)
+        if idx_rows is not None:
+            idx_pool, _ = pages.write_sequences(
+                idx_pool, None, idx_rows, None, block_table_row, suffix_len,
+                start=prefix_len)
+        if wrows is not None:
+            win_pool, _ = pages.write_sequences(
+                win_pool, None, wrows, None, win_table, suffix_len,
+                start=prefix_len)
     k_pages = _kept(k_pages, pool, counts, idx_pool, win_pool)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(x, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
-    out = ((last @ params["lm_head"]).astype(jnp.float32), k_pages, None)
+    out = (_last_logits(params, cfg, x, suffix_len), k_pages, None)
     return (*out, routes) if want_routes else out

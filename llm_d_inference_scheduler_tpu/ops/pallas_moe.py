@@ -299,6 +299,8 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
     the two matmuls are the tests' and the microbench's to set; served, they
     come from the shapes (:func:`pick_tiles`).
     """
+    from ..models import scopes     # (at call time: models imports ops)
+
     B, S, D = x.shape
     xt = x.reshape(B * S, D)
 
@@ -307,10 +309,11 @@ def moe_ffn_grouped(lp, x, n_experts: int, experts_per_token: int,
     # well (the compiler keeps the accumulator's excess precision), and a
     # logit rounded here and not there sends a token whose second and third
     # choice lie within bf16 rounding to another expert than the dense form.
-    logits = jnp.dot(xt, lp["router"],
-                     preferred_element_type=jnp.float32)        # [T, E]
-    top_vals, top_idx = jax.lax.top_k(logits, experts_per_token)  # [T, k]
-    gates = jax.nn.softmax(top_vals, axis=-1)                   # [T, k]
+    with scopes.block("ffn.router"):
+        logits = jnp.dot(xt, lp["router"],
+                         preferred_element_type=jnp.float32)    # [T, E]
+        top_vals, top_idx = jax.lax.top_k(logits, experts_per_token)  # [T, k]
+        gates = jax.nn.softmax(top_vals, axis=-1)               # [T, k]
     y = grouped_experts(lp, xt, top_idx, gates, n_experts, layer=layer, tm=tm,
                         interpret=interpret, tiles_up=tiles_up,
                         tiles_down=tiles_down)
@@ -340,48 +343,54 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
     T, D = xt.shape
     E, k = n_experts, top_idx.shape[1]
 
-    # The T·k (token, expert) rows, stable-sorted by expert.
-    flat_expert = top_idx.reshape(-1)                           # [T*k]
-    if first is not None:
-        here = (flat_expert >= first) & (flat_expert < first + E)
-        flat_expert = jnp.where(here, flat_expert - first, E)   # absent: last
-    order = jnp.argsort(flat_expert, stable=True)               # [T*k]
-    sorted_expert = flat_expert[order]
+    # The glue names itself in a device trace, inside whatever scope the
+    # model gave the experts (models/scopes.py; imported at call time:
+    # models imports ops).
+    from ..models import scopes
 
-    # Group-padded layout: expert e's rows start at off[e], every group
-    # padded up to a multiple of tm. Static buffer: Tp = T*k + E*tm rows.
-    counts = jnp.bincount(flat_expert, length=E)                # [E]
-    padded = ((counts + tm - 1) // tm) * tm
-    zero = jnp.zeros((1,), counts.dtype)
-    off = jnp.concatenate([zero, jnp.cumsum(padded)])           # [E+1]
-    start = jnp.concatenate([zero, jnp.cumsum(counts)])         # [E+1]
-    # A sorted row's place: its group's start plus its rank in the group.
-    dest_sorted = (off[sorted_expert] + jnp.arange(T * k)
-                   - start[sorted_expert])                      # [T*k]
+    with scopes.block("ffn.experts.glue"):
+        # The T·k (token, expert) rows, stable-sorted by expert.
+        flat_expert = top_idx.reshape(-1)                           # [T*k]
+        if first is not None:
+            here = (flat_expert >= first) & (flat_expert < first + E)
+            flat_expert = jnp.where(here, flat_expert - first, E)   # absent: last
+        order = jnp.argsort(flat_expert, stable=True)               # [T*k]
+        sorted_expert = flat_expert[order]
 
-    # Both moves of D-wide rows are gathers (small integer scatters make
-    # their indices): padded row → its source token (T: a row of zeros),
-    # and (token, choice) → its padded row.
-    Tp = T * k + E * tm
-    src = jnp.full((Tp,), T, jnp.int32).at[dest_sorted].set(
-        (order // k).astype(jnp.int32))
-    dest = jnp.zeros((T * k,), jnp.int32).at[order].set(
-        dest_sorted.astype(jnp.int32))
-    x_pad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[src]
+        # Group-padded layout: expert e's rows start at off[e], every group
+        # padded up to a multiple of tm. Static buffer: Tp = T*k + E*tm rows.
+        counts = jnp.bincount(flat_expert, length=E)                # [E]
+        padded = ((counts + tm - 1) // tm) * tm
+        zero = jnp.zeros((1,), counts.dtype)
+        off = jnp.concatenate([zero, jnp.cumsum(padded)])           # [E+1]
+        start = jnp.concatenate([zero, jnp.cumsum(counts)])         # [E+1]
+        # A sorted row's place: its group's start plus its rank in the group.
+        dest_sorted = (off[sorted_expert] + jnp.arange(T * k)
+                       - start[sorted_expert])                      # [T*k]
 
-    # tile → expert: whose [off[e], off[e+1]) holds the tile's first row.
-    tile_starts = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(off[1:], tile_starts, side="right"),
-        E - 1).astype(jnp.int32)
-    n_live = (off[E:] // tm).astype(jnp.int32)                  # [1]
-    if first is not None:
-        # A program none of whose choices is held has no group at all, and
-        # the kernel's block index min(i, n_live - 1) would be -1: the chip
-        # halts on that copy's bounds check (the interpreter clamps it and
-        # says nothing). One tile then counts as live; what it computes lies
-        # in rows of absent choices, which are masked below.
-        n_live = jnp.maximum(n_live, 1)
+        # Both moves of D-wide rows are gathers (small integer scatters make
+        # their indices): padded row → its source token (T: a row of zeros),
+        # and (token, choice) → its padded row.
+        Tp = T * k + E * tm
+        src = jnp.full((Tp,), T, jnp.int32).at[dest_sorted].set(
+            (order // k).astype(jnp.int32))
+        dest = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            dest_sorted.astype(jnp.int32))
+        x_pad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[src]
+
+        # tile → expert: whose [off[e], off[e+1]) holds the tile's first row.
+        tile_starts = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(off[1:], tile_starts, side="right"),
+            E - 1).astype(jnp.int32)
+        n_live = (off[E:] // tm).astype(jnp.int32)                  # [1]
+        if first is not None:
+            # A program none of whose choices is held has no group at all, and
+            # the kernel's block index min(i, n_live - 1) would be -1: the chip
+            # halts on that copy's bounds check (the interpreter clamps it and
+            # says nothing). One tile then counts as live; what it computes lies
+            # in rows of absent choices, which are masked below.
+            n_live = jnp.maximum(n_live, 1)
 
     w_up, w2, layer = _stacked(lp, layer, gated)
     h = _grouped_matmul(x_pad, w_up, layer, tile_expert, n_live,
@@ -390,12 +399,13 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
     out_pad = _grouped_matmul(h, w2, layer, tile_expert, n_live,
                               tm=tm, tiles=tiles_down, interpret=interpret)
 
-    rows = out_pad[dest].reshape(T, k, D)
-    if first is not None:
-        # An absent expert's row lies where no tile wrote: whatever is there.
-        rows = jnp.where(here.reshape(T, k, 1), rows, 0)
-    y = (rows * gates[..., None].astype(xt.dtype)).sum(axis=1)
-    return y.astype(xt.dtype)
+    with scopes.block("ffn.experts.glue"):
+        rows = out_pad[dest].reshape(T, k, D)
+        if first is not None:
+            # An absent expert's row lies where no tile wrote: whatever is there.
+            rows = jnp.where(here.reshape(T, k, 1), rows, 0)
+        y = (rows * gates[..., None].astype(xt.dtype)).sum(axis=1)
+        return y.astype(xt.dtype)
 
 
 def chosen_experts(lp, xt, local, gates, n_experts: int, *, layer=None,

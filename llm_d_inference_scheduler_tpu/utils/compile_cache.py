@@ -22,13 +22,19 @@ DEFAULT_DIR = os.path.join(
 def configure_compile_cache() -> str:
     """Point JAX at the shared cache and return the directory in use.
 
-    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
-    nothing is set in code, so whoever runs the program can place the cache.
-    Call before the first compile."""
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    directory is set in code, so whoever runs the program can place the
+    cache. Either way a program's metadata is part of its key: JAX leaves it
+    out by default, and an executable served from the cache then carries the
+    metadata of whichever tree compiled it first -- other source lines, and
+    none of the blocks' scope names (models/scopes.py) where that tree had
+    none, which is what a device trace is booked by
+    (chipbench/trace_scopes.py). Call before the first compile."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV_VAR)
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR

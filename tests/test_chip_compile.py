@@ -89,11 +89,11 @@ def test_paged_attention_compiles_at_qwen3_4b(one_chip, m, batch, table_width):
     assert f"s32[{batch * table_width // 8}]" in compiled.as_text()
 
 
-def test_decode_step_copies_no_layers_page_pool(one_chip):
+@functools.cache
+def _two_layer_decode_step(one_chip):
     """One decode step at Qwen3-4B's widths, depth cut to two, 16 lanes on
-    the 16 x 2048 cache, Pallas attention on. The kernel must be handed the
-    stacked pools: a pool scanned over reaches the custom call as one layer's
-    slice, which XLA copies out first (67 MB of K and of V a layer)."""
+    the 16 x 2048 cache, Pallas attention on, compiled for ``one_chip``:
+    (the model, one layer's pool shape, the compiled program)."""
     m = dataclasses.replace(QWEN3_4B, n_layers=2)
     dt = jnp.dtype(m.dtype)
     batch, width = 16, 128
@@ -110,6 +110,14 @@ def test_decode_step_copies_no_layers_page_pool(one_chip):
     ).lower(params, _sds(one_chip, (batch,), jnp.int32),
             _sds(one_chip, (batch,), jnp.int32), pages, pages,
             _sds(one_chip, (batch, width), jnp.int32)).compile()
+    return m, one_layer, compiled
+
+
+def test_decode_step_copies_no_layers_page_pool(one_chip):
+    """The kernel must be handed the stacked pools: a pool scanned over
+    reaches the custom call as one layer's slice, which XLA copies out first
+    (67 MB of K and of V a layer)."""
+    m, one_layer, compiled = _two_layer_decode_step(one_chip)
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     shape = "bf16[" + ",".join(map(str, one_layer)) + "]"
@@ -117,7 +125,27 @@ def test_decode_step_copies_no_layers_page_pool(one_chip):
             if re.search(r"=\s*" + re.escape(shape), ln)]
     assert not made, made
     assert (compiled.memory_analysis().temp_size_in_bytes
-            < dt.itemsize * math.prod(one_layer))
+            < jnp.dtype(m.dtype).itemsize * math.prod(one_layer))
+
+
+def test_decode_step_names_its_blocks_for_the_device_trace(one_chip):
+    """The same step as the TPU compiler leaves it: the Pallas call carries
+    ``blk.attn.core`` in its ``op_name`` and the matmul fusions the scope of
+    their block, which is what the profiler records as an op's framework
+    name and chipbench/trace_scopes.py books device time by. (A fusion takes
+    its root's ``op_name``.)"""
+    _, _, compiled = _two_layer_decode_step(one_chip)
+    named = {}
+    for ln in compiled.as_text().splitlines():
+        op = re.search(r'op_name="([^"]*)"', ln)
+        if op and " = " in ln:
+            named[ln.split(" = ")[0].split()[-1]] = (ln, op.group(1))
+    calls = [path for ln, path in named.values() if "tpu_custom_call" in ln]
+    assert calls and all("/blk.attn.core/" in path for path in calls), calls
+    matmuls = {path.split("/blk.")[-1].split("/")[0]
+               for ln, path in named.values()
+               if " fusion(" in ln and path.endswith("/dot_general")}
+    assert matmuls == {"attn.proj", "ffn.dense", "head"}, matmuls
 
 
 def test_latent_decode_step_compiles_and_copies_no_pool(one_chip):

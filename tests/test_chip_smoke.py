@@ -174,14 +174,14 @@ def test_warmup_that_raises_ends_the_server(monkeypatch):
 
 @pytest.mark.parametrize("placed", [None, "/tmp/placed-from-outside"])
 def test_compile_cache_dir(placed):
-    """Placed from outside: nothing is set in code (JAX reads the variable
-    itself). Otherwise <checkout>/.jax_cache, whatever the working
-    directory."""
-    code = ("import sys; "
-            "from llm_d_inference_scheduler_tpu.utils.compile_cache import "
-            "configure_compile_cache as c; d = c(); "
-            "touched = 'jax' in sys.modules; import jax; "
-            "print(d, touched, jax.config.jax_compilation_cache_dir)")
+    """Placed from outside: no directory is set in code (JAX reads the
+    variable itself). Otherwise <checkout>/.jax_cache, whatever the working
+    directory. Either way a program's metadata is part of its cache key, so
+    a cached executable names its blocks as the tree that runs it does."""
+    code = ("from llm_d_inference_scheduler_tpu.utils.compile_cache import "
+            "configure_compile_cache as c; d = c(); import jax; "
+            "print(d, jax.config.jax_compilation_cache_include_metadata_in_key,"
+            " jax.config.jax_compilation_cache_dir)")
     env = _env()
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     if placed:
@@ -189,4 +189,4 @@ def test_compile_cache_dir(placed):
     r = subprocess.run([sys.executable, "-c", code], env=env, cwd="/",
                        capture_output=True, text=True, timeout=120)
     want = placed or os.path.join(REPO, ".jax_cache")
-    assert r.stdout.split() == [want, str(placed is None), want], r.stderr
+    assert r.stdout.split() == [want, "True", want], r.stderr
