@@ -65,7 +65,8 @@ STALL_WHERE = ("device_wait", "host")
 PROGRAM_COUNTERS = ("moe_ffn_tokens", "mla_attention_tokens",
                     "mla_window_attention_tokens",
                     "dsa_query_tokens", "dsa_rows", "ssm_tokens",
-                    "ssm_state_updates", "ssm_slot_prefills", "swa_rows")
+                    "ssm_state_updates", "ssm_slot_prefills", "swa_rows",
+                    "kv_prefill_attention_tokens")
 
 
 class XlaBuilds:
@@ -208,6 +209,20 @@ class EngineTelemetry:
             "a tile of rows in VMEM (ops/pallas_dsa.py), `xla` whole in "
             "memory, [heads, queries, rows] in f32; counted on the host at "
             "dispatch, a token once a program; empty without a latent pool",
+            ("form",), registry=self.registry)
+        self.kv_prefill_attention_tokens = Counter(
+            "jetstream:kv_prefill_attention_tokens_total",
+            "Rows (padded tokens) of the prefix-continuation programs of a "
+            "K/V model with window and full layers (models/llama.py, "
+            "ModelConfig.kv_window: a long prompt's windows after its "
+            "first), by how their attention reads the cached rows "
+            "(models/binding.bind, ModelConfig.swa_impl): `kernel` one "
+            "tiled kernel that walks the pages the prompt holds, the scores "
+            "in VMEM (ops/pallas_paged_attention."
+            "kv_window_prefill_attention), `xla` the prior table's bucket "
+            "gathered whole and banded, the scores a block of queries at a "
+            "time in memory; counted on the host at dispatch, a token once "
+            "a program; empty for any other model",
             ("form",), registry=self.registry)
         self.dsa_query_tokens = Counter(
             "jetstream:dsa_query_tokens_total",

@@ -63,7 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.attention import (latent_paged_decode_attention,
+from ..ops.attention import (banded_attention,
+                             latent_paged_decode_attention,
                              paged_decode_attention,
                              swa_latent_decode_attention,
                              swa_paged_decode_attention)
@@ -71,7 +72,8 @@ from ..ops.pallas_dsa import sparse_latent_paged_decode_attention_pallas
 from ..ops.pallas_latent_attention import (
     RUN_PAGES, latent_paged_decode_attention_pallas,
     swa_latent_decode_attention_pallas)
-from ..ops.pallas_paged_attention import (paged_decode_attention_pallas,
+from ..ops.pallas_paged_attention import (kv_window_prefill_attention,
+                                          paged_decode_attention_pallas,
                                           swa_paged_decode_attention_kernel)
 from ..ops.sparse_attention import sparse_latent_paged_decode_attention
 from . import state as state_pool
@@ -745,6 +747,57 @@ def read_prefix(k_layer: jax.Array, v_layer: jax.Array,
         return rows.reshape(1, -1, *pool.shape[-2:])
 
     return gather(k_layer), gather(v_layer)
+
+
+def prefill_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                      pools: tuple[jax.Array, jax.Array],
+                      near_pools: tuple[jax.Array, jax.Array],
+                      is_near: jax.Array, layer: jax.Array,
+                      table_row: jax.Array, near_table_row: jax.Array,
+                      near_first_pos: jax.Array, prefix_len: jax.Array,
+                      suffix_len: jax.Array, *, window: int,
+                      impl: str = "xla") -> jax.Array:
+    """A continuation window's queries q [1, S, H, D] (at ``prefix_len`` [1]
+    on, ``suffix_len`` [1] of them real) against the window's own K/V [1, S,
+    Hkv, D], not in the pages yet, and the rows cached before it, for a
+    layer of either kind of a model with both (``is_near``, traced: a scan
+    decides it; ``layer`` counts among its kind): a layer that sees
+    everything reads ``pools`` (the stacked K and V) by ``table_row`` [1, W],
+    positions 0 on; one that sees a ``window`` reads ``near_pools`` by
+    ``near_table_row`` (:func:`window_prefix_pages`), positions
+    ``near_first_pos`` [1] on; in either the rows below ``prefix_len``.
+    Returns [1, S, H, D]. ``impl`` as :func:`window_decode_attention`'s: the
+    kernel is ONE call for both kinds that walks the pages the prompt holds
+    itself, the pools left as they lie, and reads nothing of a table past
+    them; the plain form gathers a table's rows whole (:func:`read_prefix`)
+    and bands them a block of queries at a time, the scores through memory,
+    a ``cond`` branch a kind (and the compiler re-lays out each V pool once
+    a program for the probabilities' product: PERF.md section 7, PR 48)."""
+    if impl.startswith("kernel"):
+        return kv_window_prefill_attention(
+            q, k_new, v_new, *pools, *near_pools, is_near, layer, table_row,
+            near_table_row, near_first_pos, prefix_len, suffix_len,
+            window=window, interpret=impl == "kernel_interpret")
+    own = jnp.arange(q.shape[1], dtype=jnp.int32)[None, :]
+    positions = prefix_len[:, None] + own
+
+    def banded(pools, table_row, first_pos, window):
+        k_prior, v_prior = read_prefix(*pools, table_row, layer=layer)
+        pos = first_pos[:, None] + jnp.arange(k_prior.shape[1],
+                                              dtype=jnp.int32)[None, :]
+        return banded_attention(
+            q, jnp.concatenate([k_prior.astype(k_new.dtype), k_new], axis=1),
+            jnp.concatenate([v_prior.astype(v_new.dtype), v_new], axis=1),
+            q_positions=positions,
+            kv_positions=jnp.concatenate([pos, positions], axis=1),
+            kv_valid=jnp.concatenate([pos < prefix_len[:, None],
+                                      own < suffix_len[:, None]], axis=1),
+            window=window)
+
+    return jax.lax.cond(
+        is_near,
+        lambda: banded(near_pools, near_table_row, near_first_pos, window),
+        lambda: banded(pools, table_row, jnp.zeros_like(prefix_len), None))
 
 
 # ---- export and import, block-wise --------------------------------------------

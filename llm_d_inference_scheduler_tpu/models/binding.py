@@ -154,6 +154,12 @@ class Bound:
                            ("dsa_query_tokens", "all", got["all"]),
                            ("dsa_rows", "scored", got["scored"]),
                            ("dsa_rows", "attended", got["attended"])]
+        if m.kv_window and kind == "prefix_prefill":
+            # models/llama.py: a continuation window's queries against the
+            # K/V pages before it, by the form that attention traced with
+            # (a first window reads no page: it is not counted).
+            counts.append(("kv_prefill_attention_tokens",
+                           m.swa_impl.split("_")[0], tokens))
         if m.window and queries is not None:
             # Either family's window layers, from the queries' positions.
             counts += [("swa_rows", kind, amount) for kind, amount in
@@ -197,7 +203,9 @@ class Bound:
             # How a decode step fetches its slots' recurrent states.
             "state_update": m.ssm_impl if m.n_state_layers else None,
             # The form of the layers that attend to a window of the context
-            # (a key only a model with such layers has).
+            # (a key only a model with such layers has); in the K/V family
+            # also that of a continuation window's attention, both kinds of
+            # layer.
             **({"window_attention": m.swa_impl} if m.window else {}),
             # What models/llama.py's router reads and its experts' activation
             # where they are not Mixtral's (keys only such a model has).
@@ -241,9 +249,10 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     if mcfg.window:
         # The window layers' kernels (the latent family's two: ops/
         # pallas_latent_attention.py's walk over the window's pages, ops/
-        # pallas_dsa.py's tiles under the band; the K/V family's one: ops/
-        # pallas_paged_attention.py's walk) on a TPU, the plain forms on the
-        # CPU.
+        # pallas_dsa.py's tiles under the band; the K/V family's two: ops/
+        # pallas_paged_attention.py's decode walk, and its tiled walk under
+        # a continuation window's queries, which the full layers take too)
+        # on a TPU, the plain forms on the CPU.
         # (A K/V page's DMA wants whole lanes: kvcache/pages.use_kernel.)
         aligned = mcfg.kv_lora_rank or interpret or mcfg.head_dim % 128 == 0
         forms["swa_impl"] = tiled if aligned else "xla"

@@ -628,47 +628,33 @@ def _mixed_prefill_with_prefix(params: Params, cfg: ModelConfig, tokens,
     ``prior_table_row``; a window layer reads, out of its own pools, the
     pages that end where this window starts and its band still reaches
     (``pages.window_prefix_pages``). Both at (layer, page) of the stacked
-    pools, which the scan closes over; the scores go a block of queries at a
-    time (ops/attention.banded_attention). Returns (last-token logits, cache,
-    None)."""
+    pools, which the scan closes over, in the form ``cfg.swa_impl`` says
+    (set by models.bind; ``pages.prefill_attention``): one kernel that walks
+    the pages the prompt holds, or the rows gathered whole and banded in
+    XLA. Returns (last-token logits, cache, None)."""
     S = tokens.shape[1]
     window, among = kinds = _kinds(cfg)
-    block = pages.block_size(cache.k)
     positions = prefix_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     with scopes.block("attn.proj"):
         cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
-    own_valid = jnp.arange(S)[None, :] < suffix_len[:, None]
-    prior_pos = jnp.arange(prior_table_row.shape[1] * block,
-                           dtype=jnp.int32)[None, :]
     near_ids, near_pos = pages.window_prefix_pages(
-        cache.wt, prefix_len, block, cfg.kv_window)
+        cache.wt, prefix_len, pages.block_size(cache.k), cfg.kv_window)
+    if cfg.swa_impl.startswith("kernel"):
+        # The kernel walks the pages the prompt holds whatever the table's
+        # width: handed the sequence's whole table, its text is one for
+        # every prior bucket (and traced once for them all).
+        prior_table_row = block_table_row
     layers, whole = _over_layers(cfg, params["layers"])
-
-    def seen(q, k, v, pools, table, at, pos, reach):
-        """The window's queries against the rows cached at ``pos`` (pages
-        ``table`` of ``pools`` at layer ``at``) and its own. (The compiler
-        re-lays out each V pool once a program for the probabilities'
-        product: PERF.md section 7, PR 48.)"""
-        k_prior, v_prior = pages.read_prefix(*pools, table, layer=at)
-        return banded_attention(
-            q, jnp.concatenate([k_prior.astype(k.dtype), k], axis=1),
-            jnp.concatenate([v_prior.astype(v.dtype), v], axis=1),
-            q_positions=positions,
-            kv_positions=jnp.concatenate([pos, positions], axis=1),
-            kv_valid=jnp.concatenate([pos < prefix_len[:, None], own_valid],
-                                     axis=1),
-            window=reach)
 
     def body(x, layer_in):
         lp, is_window, at = layer_in
 
         def attend(q, k, v):
-            return jax.lax.cond(
-                is_window,
-                lambda: seen(q, k, v, (cache.win, cache.win_v), near_ids, at,
-                             near_pos, cfg.kv_window),
-                lambda: seen(q, k, v, (cache.k, cache.v), prior_table_row,
-                             at, prior_pos, None))
+            return pages.prefill_attention(
+                q, k, v, (cache.k, cache.v), (cache.win, cache.win_v),
+                is_window, at, prior_table_row, near_ids, near_pos[:, 0],
+                prefix_len, suffix_len, window=cfg.kv_window,
+                impl=cfg.swa_impl)
 
         x, k, v, chose = _mixed_block(cfg, {**lp, **whole}, x, cos, sin,
                                       is_window, attend)

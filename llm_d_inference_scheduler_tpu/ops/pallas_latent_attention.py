@@ -129,7 +129,10 @@ def stage_fetch(bt_ref, run_ref, pool_hbm, tile, sem, *, lane, layer, n_pages,
     is for a region's bytes however many copies brought them: the whole slot
     in one wait where the stage is full, a group and then a page at a time
     in a lane's last stage. Loops, not unrolls: the engine traces a kernel's
-    body for every decode bucket."""
+    body for every decode bucket. A pool may be ``(flag, pool, other)``: two
+    pools of one page shape, read from ``other`` where the traced ``flag`` is
+    set (one kernel for the layers of both kinds of a K/V model:
+    ops/pallas_paged_attention.kv_window_prefill_attention)."""
     pools = ((pool_hbm, tile, sem, zero_rest), *also)
     pages = tile.shape[1]
     groups = -(-max_blocks // group)
@@ -143,8 +146,15 @@ def stage_fetch(bt_ref, run_ref, pool_hbm, tile, sem, *, lane, layer, n_pages,
         def fetch(blk, at):
             """``at`` of every tile's slot from block(s) ``blk`` on."""
             for hbm, to, done, _ in pools:
-                pltpu.make_async_copy(hbm.at[layer, blk], to.at[slot, at],
-                                      done.at[slot]).start()
+                def copy(src, to=to, done=done):
+                    pltpu.make_async_copy(src.at[layer, blk], to.at[slot, at],
+                                          done.at[slot]).start()
+
+                if isinstance(hbm, tuple):   # (flag, pool, the pool if set)
+                    jax.lax.cond(hbm[0], lambda: copy(hbm[2]),
+                                 lambda: copy(hbm[1]))
+                else:
+                    copy(hbm)
 
         def page(i, carry):
             fetch(bt_ref[lane * max_blocks + first + s * pages + i], i)

@@ -70,6 +70,11 @@ no run.
 
 Neither form pads its query heads: 28 heads on 4 KV heads (7 a group, no
 multiple of 8) compile for a v5e as they are (tests/test_chip_compile.py).
+
+**A window of a prompt** (:func:`kv_window_prefill_attention`, the op of that
+name): the same stages under a tile of queries, for the windows of a long
+prompt that continue what earlier ones cached; described where it stands, at
+the end of this module.
 """
 
 from __future__ import annotations
@@ -296,3 +301,288 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
         name=name,      # the op's in a device trace; None: the caller's own
     )(*prefetch, jnp.asarray(layer, jnp.int32).reshape(1),
       q, cur_k, cur_v, k_pages, v_pages)
+
+
+# ---- a window of a prompt over the pages before it -----------------------------
+#
+# A continuation window of a long prompt (models/llama.py
+# ``_mixed_prefill_with_prefix``): S queries at positions ``prefix_len ...``
+# against the ``prefix_len`` rows the earlier windows left in the pools and
+# against the window's own new K/V, which are not in the pages yet. The op is
+# ``kv_window_prefill_attention`` in a device trace. It is the decode walks'
+# stages (``stage_fetch``: the pages of the table as it stands, runs of
+# adjacent pages as one copy, two slots) under a tile of queries instead of
+# one query a lane:
+#
+# - A program a tile of ``QUERY_TILE`` queries, every KV head in it, so a
+#   page is fetched once a tile for all its heads. A KV head's query heads are
+#   folded into the rows ([heads a group x queries, D]: 7 x 256 at
+#   SmallThinker's 28 on 4), so a KV head's rows meet the MXU once.
+# - A stage's tile lies in VMEM as the page lies in the pool, row (token, KV
+#   head). One head's rows are every ``Hkv``-th of them: a strided load (of
+#   32-bit words, two heads of a 16-bit pool at once), not a product against
+#   the other heads' rows under a mask as one query a lane can afford.
+# - The scores stay in VMEM, a running maximum and sum a query row in f32
+#   scratch. Products in the pools' dtype with f32 accumulation, the
+#   probabilities rounded to the pool's dtype for the second product: the
+#   arithmetic ops/attention.banded_attention states, in another order of
+#   the softmax's sums.
+# - It walks what the prompt holds: ``cdiv(prefix_len - first_pos, block)``
+#   pages from the table's first, whatever the table's width (the prior
+#   bucket); a stage wholly before a tile's band (a window layer) is skipped,
+#   as are the own rows in a tile's future. The own rows come first, from
+#   VMEM, a stage's worth at a time; the first stage's copies start under
+#   the last of them.
+# - **Its text is set-up time.** Mosaic unrolls a [7 x 256, 512] step into
+#   every vector register it touches, and the engine traces and lowers the
+#   kernel for each of a cell's 19 continuation programs at every start,
+#   cache or no cache, about a millisecond an operation of the traced body
+#   on the chip's host. So the products and the softmax stand ONCE in it:
+#   one loop over the steps (own chunks, then stages) around one loop over
+#   the KV heads, one ``start`` of a stage's copies; and the layers of BOTH
+#   kinds of a model take the same call, the kind a traced flag that picks
+#   the pool pair a copy reads (``stage_fetch``), the table's half and the
+#   band, so a program holds the kernel once and not once a ``cond`` branch,
+#   and its shapes do not depend on the prior table's bucket. (Unrolled over
+#   4 heads and 2 kinds of step, a call a branch, it cost the cell 31% of a
+#   warm ``setup_s``; with the loops alone 12%: PERF.md section 6, PR 51.)
+
+# Queries a program.
+QUERY_TILE = 256
+_PREFILL_VMEM_BYTES = 64 * 2 ** 20
+# The band of a layer that sees everything.
+_NO_BAND = 1 << 30
+
+
+def _head_rows(tile_ref, slot, n_kv: int):
+    """The ``[P, block, Hkv, D]`` tile of ``slot`` as a list of ``[P * block,
+    D]`` arrays, one a KV head, in the tile's dtype."""
+    pages, block, _, head_dim = tile_ref.shape[1:]
+    rows = pages * block
+    flat = tile_ref.at[slot].reshape(rows * n_kv, head_dim)
+    if tile_ref.dtype.itemsize == 4:
+        return [flat[pl.ds(g, rows, stride=n_kv), :] for g in range(n_kv)]
+    assert tile_ref.dtype == jnp.bfloat16 and n_kv % 2 == 0, (
+        tile_ref.dtype, n_kv)
+    # Rows (t, 2j) and (t, 2j + 1) share a 32-bit word, low half first: a
+    # bf16 is the high half of the f32 of its value.
+    words = flat.bitcast(jnp.uint32)
+    heads = []
+    for j in range(n_kv // 2):
+        pair = words[pl.ds(j, rows, stride=n_kv // 2), :]
+        heads += [
+            pltpu.bitcast(pair << 16, jnp.float32).astype(jnp.bfloat16),
+            pltpu.bitcast(pair & jnp.uint32(0xFFFF0000),
+                          jnp.float32).astype(jnp.bfloat16)]
+    return heads
+
+
+def _prefill_kernel(bt_ref, run_ref, meta_ref,   # scalar prefetch
+                    q_ref,               # [Hkv, G, Sq, D] — this tile's queries
+                    own_k_ref, own_v_ref,    # [Hkv, S, D] — the window's own
+                    k_hbm, v_hbm, near_k_hbm, near_v_hbm,   # pools (ANY/HBM)
+                    out_ref,             # [Hkv, G, Sq, D]
+                    k_tile, v_tile, sem_k, sem_v,   # [2, P, block, Hkv, D]
+                    k_step, v_step,      # [Hkv, P * block, D] — a step's rows
+                    m_sc, l_sc, acc_sc,  # [Hkv, G * Sq, 1 | 1 | D] f32
+                    *, width: int, near_width: int, pages: int, block: int,
+                    group: int, window: int):
+    i = pl.program_id(0)
+    n_kv, per_kv, sq, head_dim = q_ref.shape
+    rows = pages * block                       # rows a step: a stage's
+    scale = 1.0 / (head_dim ** 0.5)
+    prefix_len, suffix_len, layer = meta_ref[0], meta_ref[1], meta_ref[2]
+    near = meta_ref[3] > 0
+    # The positions of the table's first row, the band, and where in the
+    # prefetched tables the layer's own starts.
+    first_pos = jnp.where(near, meta_ref[4], 0)
+    band = jnp.where(near, window, _NO_BAND)
+    first_entry = jnp.where(near, width, 0)
+
+    def over(a, b: int):
+        """a // b of a count (``//`` traces a dozen operations to floor a
+        negative quotient)."""
+        return jax.lax.div(a, jnp.int32(b))
+
+    n_pages = over(prefix_len - first_pos + (block - 1), block)
+    n_stages = over(n_pages + (pages - 1), pages)
+    # The tile's queries, and the first row any of them sees.
+    q_pos = prefix_len + i * sq + jax.lax.broadcasted_iota(
+        jnp.int32, (sq, 1), 0)
+    reach = jnp.maximum(prefix_len + i * sq - (band - 1), first_pos)
+    # Steps: the own rows' chunks from the one ``reach`` lies in (or the
+    # first) to the one the tile's last query lies in, then the stages from
+    # ``reach``'s (past the last where no cached row is in reach).
+    c_lo = over(jnp.maximum(reach - prefix_len, 0), rows)
+    n_own = over((i + 1) * sq + (rows - 1), rows) - c_lo
+    s_lo = jnp.minimum(over(reach - first_pos, rows), n_stages)
+
+    _start, _wait = stage_fetch(
+        bt_ref, run_ref, (near, k_hbm, near_k_hbm), k_tile, sem_k,
+        zero_rest=False, also=(((near, v_hbm, near_v_hbm), v_tile, sem_v,
+                                True),),
+        lane=0, layer=layer, n_pages=n_pages, max_blocks=width + near_width,
+        group=group, first=first_entry)
+
+    m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+    l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+
+    def step(t, carry):
+        own = t < n_own
+        s = s_lo + t - n_own
+
+        # The next step's stage, if it is one: its copies fly under this
+        # step's products (the first stage's under the last own chunk's).
+        @pl.when((t + 1 >= n_own) & (s + 1 < n_stages))
+        def _fetch_ahead():
+            _start(s + 1, (s + 1) & 1)
+
+        @pl.when(own)
+        def _own_chunk():
+            at = pl.multiple_of((c_lo + t) * rows, rows)
+            k_step[...] = own_k_ref[:, pl.ds(at, rows), :]
+            v_step[...] = own_v_ref[:, pl.ds(at, rows), :]
+
+        @pl.when(jnp.logical_not(own))
+        def _stage():
+            _wait(s, s & 1)
+            # (The cached rows in the new rows' dtype, a KV head apart.)
+            for g, (k, v) in enumerate(zip(_head_rows(k_tile, s & 1, n_kv),
+                                           _head_rows(v_tile, s & 1, n_kv))):
+                k_step[g] = k.astype(k_step.dtype)
+                v_step[g] = v.astype(v_step.dtype)
+
+        # The step's rows' positions, and which of them exist: the cached
+        # ones below the prefix, the own ones below the window's length.
+        at = jnp.where(own, prefix_len + (c_lo + t) * rows,
+                       first_pos + s * rows) + col
+        seen = ((at <= q_pos) & (q_pos - at < band)
+                & (at < prefix_len + jnp.where(own, suffix_len, 0)))
+
+        def head(g, carry):
+            """One step of KV head ``g``'s running softmax. (A query that
+            has seen nothing yet weighs what it is shown by 1; its first
+            seen row, and every query sees its own, scales that to
+            nothing.)"""
+            logits = jax.lax.dot_general(
+                q_ref[g].reshape(per_kv * sq, head_dim), k_step[g],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [G * Sq, rows]
+            logits = jnp.where(seen[None], logits.reshape(per_kv, sq, rows),
+                               NEG_INF).reshape(per_kv * sq, rows)
+            m = m_sc[g]
+            new_m = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - new_m)
+            corr = jnp.exp(m - new_m)
+            l_sc[g] = l_sc[g] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_sc[g] = acc_sc[g] * corr + jnp.dot(
+                p.astype(v_step.dtype), v_step[g],
+                preferred_element_type=jnp.float32)
+            m_sc[g] = new_m
+            return carry
+
+        return jax.lax.fori_loop(0, n_kv, head, carry)
+
+    jax.lax.fori_loop(0, n_own + n_stages - s_lo, step, 0)
+    out = acc_sc[...] / l_sc[...]
+    out_ref[...] = out.reshape(out_ref.shape).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def kv_window_prefill_attention(
+    q: jnp.ndarray,             # [1, S, H, D] — the window's queries
+    k_new: jnp.ndarray,         # [1, S, Hkv, D] — its own K/V, not in the pages
+    v_new: jnp.ndarray,
+    k_pages: jnp.ndarray,       # [L, N, block, Hkv, D] — the pools of the layers
+    v_pages: jnp.ndarray,       # that see everything
+    near_k_pages: jnp.ndarray,  # [Lw, Nw, block, Hkv, D] — and of those that
+    near_v_pages: jnp.ndarray,  # see a window
+    is_near: jnp.ndarray,       # bool scalar — this layer is one of the latter
+    layer: jnp.ndarray,         # int32 scalar — which of its kind
+    table_row: jnp.ndarray,     # [1, W] int32 — the sequence's pages, from 0
+    near_table_row: jnp.ndarray,    # [1, Ww] — its window pool's, those that
+    near_first_pos: jnp.ndarray,    # end where the window starts, from here [1]
+    prefix_len: jnp.ndarray,    # [1] int32 — rows cached: the window's start
+    suffix_len: jnp.ndarray,    # [1] int32 — the window's real tokens
+    *,
+    window: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """ops/attention.banded_attention of a continuation window over the
+    cached rows and its own, for a layer of either kind of a model with
+    both (``is_near``, traced: a scan decides it). The cached rows are read
+    out of the layer's kind's stacked pools by that kind's table: positions
+    0 on of ``table_row``, ``near_first_pos`` on of ``near_table_row``, in
+    either those below ``prefix_len`` (a table's entries past them are never
+    read). A query at t of a window layer sees s with ``0 <= t - s <
+    window``, of the other kind every s up to t. Returns [1, S, H, D] in
+    q.dtype; the rows past ``suffix_len`` are nobody's."""
+    _, S, H, D = q.shape
+    _, _, block, n_kv, _ = k_pages.shape
+    assert near_k_pages.shape[2:] == k_pages.shape[2:], (
+        near_k_pages.shape, k_pages.shape)
+    per_kv = H // n_kv
+    sq = min(QUERY_TILE, -(-S // 16) * 16)
+    s_pad = -(-S // sq) * sq
+    pages = pages_per_stage(block, n_kv, D, k_pages.dtype.itemsize,
+                            min(table_row.shape[1], near_table_row.shape[1]))
+    group = run_pages(pages)
+    rows = pages * block
+    own_pad = -(-s_pad // rows) * rows      # the own rows in whole steps
+    # The two tables one after the other, each in whole groups.
+    tables, runs = zip(*(
+        (jnp.pad(t, ((0, 0), (0, -t.shape[1] % group))),
+         table_runs(t, prefix_len - first + 1, block, group))
+        for t, first in ((table_row, 0), (near_table_row, near_first_pos))))
+
+    def by_head(x, to):     # [1, S, Hkv, ..., D] -> [Hkv, ..., to, D]
+        x = jnp.moveaxis(x[0], 0, -2)
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, to - S), (0, 0)])
+
+    kernel = functools.partial(
+        _prefill_kernel, width=tables[0].shape[1],
+        near_width=tables[1].shape[1], pages=pages, block=block, group=group,
+        window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_pad // sq,),
+        in_specs=[
+            pl.BlockSpec((n_kv, per_kv, sq, D), lambda i, *_: (0, 0, i, 0)),
+            pl.BlockSpec((n_kv, own_pad, D), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec((n_kv, own_pad, D), lambda i, *_: (0, 0, 0)),
+            *[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+        ],
+        out_specs=pl.BlockSpec((n_kv, per_kv, sq, D),
+                               lambda i, *_: (0, 0, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, block, n_kv, D), k_pages.dtype),
+            pltpu.VMEM((2, pages, block, n_kv, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((n_kv, rows, D), k_new.dtype),
+            pltpu.VMEM((n_kv, rows, D), v_new.dtype),
+            pltpu.VMEM((n_kv, per_kv * sq, 1), jnp.float32),
+            pltpu.VMEM((n_kv, per_kv * sq, 1), jnp.float32),
+            pltpu.VMEM((n_kv, per_kv * sq, D), jnp.float32),
+        ],
+    )
+    one = functools.partial(jnp.reshape, shape=(1,))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_kv, per_kv, s_pad, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret,
+        name="kv_window_prefill_attention",
+    )(jnp.concatenate(tables, axis=1).reshape(-1),
+      jnp.concatenate(runs, axis=1).reshape(-1),
+      jnp.concatenate([prefix_len, suffix_len, one(layer), one(is_near),
+                       near_first_pos]).astype(jnp.int32),
+      by_head(q.reshape(1, S, n_kv, per_kv, D), s_pad),
+      by_head(k_new, own_pad), by_head(v_new, own_pad), k_pages, v_pages,
+      near_k_pages, near_v_pages)
+    return jnp.moveaxis(out[:, :, :S], 2, 0).reshape(1, S, H, D)

@@ -35,6 +35,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import sys
 import time
 
@@ -230,10 +231,18 @@ def main(argv=None) -> int:
                               "error": str(e)[:2000]}), flush=True)
             continue
         mem = compiled.memory_analysis()
+        text = compiled.as_text()
+        # A ``copy`` whose result has a page pool's shape is the compiler
+        # re-laying a whole pool out (PERF.md section 7, PR 48).
+        pool_copy = re.compile("|".join(
+            rf"= \w+\[{','.join(map(str, shape))}\]\S* copy\("
+            for shape in (geom.shape, geom.window and geom.window.shape)
+            if shape))
         print(json.dumps({
             "program": name, "compiled": True,
             "compile_s_on_this_host": round(time.monotonic() - t0, 1),
-            "tpu_custom_call": "tpu_custom_call" in compiled.as_text(),
+            "tpu_custom_call": "tpu_custom_call" in text,
+            "pool_copies": len(pool_copy.findall(text)),
             "argument_bytes": mem.argument_size_in_bytes,
             "output_bytes": mem.output_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,

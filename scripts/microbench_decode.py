@@ -69,6 +69,18 @@ CPU's form) and a tile at a time in VMEM (ops/pallas_dsa.py, the form a TPU
 engine binds): ms a call (one layer), the bytes of scores the form puts into
 memory, and how far the tiled form lies from the whole one.
 
+``--kv-prefill`` times ONE continuation window's attention alone of a K/V
+model with window and full layers (models/llama.py ``kv_window``; the
+smallthinker configuration's widths and pool sizes): 1,024 queries against a
+prior table of ``--kv-prefill-priors`` blocks filled to ``--kv-prefill-live``
+of the bucket and the window's own rows, a full layer and a window layer, in
+both forms: the rows gathered whole and banded in XLA (the scan over the
+kind's layers closes over the stacked pools, as the step program's does, so
+the compiler's once-a-program re-layout of the V pool is in the time) and the
+tiled kernel that walks the pages (ops/pallas_paged_attention.
+kv_window_prefill_attention): ms a layer, the share of the MXU's peak the
+rows a query sees make of it, and how far the kernel lies from the plain form.
+
 Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--points 16x1000,16x300,8x300] [--tables shuffled,churn,runs]
            [--kv-window 4096] [--config-file FILE] [--attn-only]
@@ -85,6 +97,8 @@ Usage: python scripts/microbench_decode.py [--model qwen3-4b]
        python scripts/microbench_decode.py --window
            [--window-config kimi-vl-a3b-cut] [--window-priors 0,64,128,256,512]
            [--window-live 1.0]
+       python scripts/microbench_decode.py --kv-prefill
+           [--kv-prefill-priors 64,128,256,512,1024] [--kv-prefill-live 0.75]
 """
 
 from __future__ import annotations
@@ -878,6 +892,115 @@ def window_main(args):
             }), flush=True)
 
 
+def kv_prefill_main(args):
+    """One continuation window's attention of a K/V model with two kinds of
+    layer at ``--kv-prefill-config``'s widths, a full and a window layer, the
+    plain form and the kernel, over prior buckets."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.kvcache import pages
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+    from llm_d_inference_scheduler_tpu.ops import pallas_paged_attention as paged
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           args.kv_prefill_config + ".json")) as f:
+        m = config_from_hf(types.SimpleNamespace(**json.load(f)),
+                           name=args.kv_prefill_config)
+    interpret = args.kv_prefill_interpret
+    peak = None if interpret else _chipbench_kernels().peaks(
+        jax.devices()[0].device_kind)["flops_per_s"]
+    dt = jnp.dtype(m.dtype)
+    S, H, block, band = (args.kv_prefill_tokens, m.n_heads, m.kv_block_size,
+                         m.kv_window)
+    geom = pages.PageGeometry.for_engine(m, args.kv_prefill_lanes,
+                                         args.max_model_len)
+    keys = jax.random.split(jax.random.key(args.seed), 7)
+    q = jax.random.normal(keys[0], (1, S, H, m.head_dim), dt)
+    k_new, v_new = (jax.random.normal(k, (1, S, m.n_kv_heads, m.head_dim), dt)
+                    for k in keys[1:3])
+    suffix_len = jnp.asarray([S], jnp.int32)
+    tiles = [int(t) for t in args.kv_prefill_query_tiles.split(",") if t] \
+        or [paged.QUERY_TILE]
+
+    def attend(impl, pools, near_pools, is_near, at, prefix_len):
+        # models/llama._mixed_prefill_with_prefix's call, in either form:
+        # the engine's tables ascend (blocks.py; block 0 is the trash), a
+        # window layer's are the pages that end where the window starts.
+        near_table, pos = pages.window_prefix_pages(table, prefix_len, block,
+                                                    band)
+        return pages.prefill_attention(
+            q, k_new, v_new, pools, near_pools, is_near, at, table,
+            near_table, pos[:, 0], prefix_len, suffix_len, window=band,
+            impl=impl)
+
+    def chain(impl, pools, near_pools, is_near, prefix_len):
+        # Every layer of the kind, the scan closing over the stacked pools.
+        def body(acc, at):
+            out = attend(impl, pools, near_pools, is_near, at, prefix_len)
+            return acc + out.astype(jnp.float32).sum(), None
+
+        return jax.lax.scan(body, jnp.float32(0), jnp.arange(
+            (near_pools if is_near else pools)[0].shape[0],
+            dtype=jnp.int32))[0]
+
+    served_tile = paged.QUERY_TILE
+    tiled = "kernel_interpret" if interpret else "kernel"
+    pools, near_pools = (
+        tuple(jax.random.normal(k, shape, dt) for k in ks)
+        for ks, shape in ((keys[3:5], geom.shape),
+                          (keys[5:7], geom.window.shape)))
+    for kind in ("full", "window"):
+        is_near = kind == "window"
+        layers = (near_pools if is_near else pools)[0].shape[0]
+        for prior in map(int, args.kv_prefill_priors.split(",")):
+            prefix = int(prior * block * args.kv_prefill_live) // block * block
+            # The kernel takes the sequence's whole table, the plain form
+            # the prior bucket of it.
+            widths = {"xla": prior, "kernel": geom.max_blocks_per_seq}
+            prefix_len = jnp.asarray([prefix], jnp.int32)
+            # (Query, row) pairs a query sees: every row up to itself, or
+            # its band of them.
+            at = prefix + np.arange(S)
+            seen = int(np.sum(np.minimum(at + 1, band) if is_near
+                              else at + 1))
+            got = {}
+            for name, impl, tile in [("xla", "xla", None)] + [
+                    ("kernel", tiled, t) for t in tiles]:
+                if tile:    # read where the kernel's wrapper is traced
+                    paged.QUERY_TILE = tile
+                    jax.clear_caches()
+                table = jnp.asarray(
+                    1 + np.arange(widths[name])[None] % (geom.n_blocks - 1),
+                    jnp.int32)
+                operands = (pools, near_pools, is_near, prefix_len)
+                ms = timeit(jax.jit(functools.partial(chain, impl),
+                                    static_argnums=2), *operands,
+                            iters=args.kv_prefill_iters) / layers
+                got[name] = np.asarray(jax.jit(
+                    functools.partial(attend, impl), static_argnums=2)(
+                    *operands[:3], jnp.int32(layers - 1), prefix_len),
+                    np.float32)
+                print(json.dumps({
+                    "component": "kv_window_prefill_attention", "form": name,
+                    "layer": kind, "heads": H, "window": S,
+                    "prior_blocks": prior, "live_rows": prefix,
+                    "table_width": widths[name] if kind == "full" else -(
+                        -(band - 1) // block), "query_tile": tile,
+                    "ms_per_layer": round(ms, 4),
+                    "mxu_peak_share_pct": peak and round(
+                        100 * 4.0 * H * m.head_dim * seen
+                        / (ms * 1e-3) / peak, 2),
+                    "max_err_vs_plain": (None if name == "xla" else float(
+                        np.abs(got[name] - got["xla"]).max())),
+                }), flush=True)
+    paged.QUERY_TILE = served_tile
+    jax.clear_caches()
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen3-4b")
@@ -952,6 +1075,25 @@ def main(argv=None):
     ap.add_argument("--window-interpret", action="store_true",
                     help="interpret the kernel: rehearses the control flow "
                          "on the CPU; its times mean nothing")
+    ap.add_argument("--kv-prefill", action="store_true",
+                    help="time one continuation window's K/V attention, a "
+                         "full and a window layer, gathered and tiled")
+    ap.add_argument("--kv-prefill-config", default="smallthinker-21b-a3b-cut",
+                    help="chipbench/configs/<name>.json")
+    ap.add_argument("--kv-prefill-tokens", type=int, default=1024)
+    ap.add_argument("--kv-prefill-lanes", type=int, default=32)
+    ap.add_argument("--kv-prefill-priors", default="64,128,256,512,1024",
+                    help="prior table widths (blocks)")
+    ap.add_argument("--kv-prefill-live", type=float, default=0.75,
+                    help="share of the prior bucket that holds cached rows")
+    ap.add_argument("--kv-prefill-query-tiles", default="",
+                    help="queries a program of the kernel, to choose "
+                         "ops/pallas_paged_attention.QUERY_TILE from "
+                         "(default: the served one)")
+    ap.add_argument("--kv-prefill-iters", type=int, default=10)
+    ap.add_argument("--kv-prefill-interpret", action="store_true",
+                    help="run the kernel through the interpreter (CPU "
+                         "rehearsal)")
     ap.add_argument("--points", default="16x1000,16x300,8x300",
                     help="lanes x context tokens a lane, comma-separated")
     ap.add_argument("--max-model-len", type=int, default=1024)
@@ -991,6 +1133,8 @@ def main(argv=None):
         return latent_main(args)
     if args.window:
         return window_main(args)
+    if args.kv_prefill:
+        return kv_prefill_main(args)
 
     import types
 

@@ -818,6 +818,44 @@ def test_the_kv_kernels_compile_at_seven_heads_a_group(one_chip, window):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("S", [1024, 128, 16])
+def test_the_continuation_windows_kernel_compiles_and_copies_no_pool(
+        one_chip, S):
+    """A window's 28 query heads (1,024 of them, and the cell's narrower
+    window buckets) against BOTH kinds' stacked pools at the cell's sizes,
+    the sequence's whole table and the 256 pages a band of 4,096 reaches:
+    one custom call that is handed the pools as they lie — no ``copy`` of a
+    pool's shape, which XLA's banded form paid once a program for each V
+    pool, and no gather — with stages of 32 pages and a program's
+    temporaries a few query-sized arrays."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_paged_attention as pa
+
+    heads, kv, d = 28, 4, 128
+    bf16 = jnp.bfloat16
+    shapes = ((2, 32769, 16, kv, d), (6, 10065, 16, kv, d))
+    full, near = (_sds(one_chip, shape, bf16) for shape in shapes)
+    one = _sds(one_chip, (1,), jnp.int32)
+    own = _sds(one_chip, (1, S, kv, d), bf16)
+    args = (_sds(one_chip, (1, S, heads, d), bf16), own, own, full, full,
+            near, near, _sds(one_chip, (), jnp.bool_),
+            _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (1, 1024), jnp.int32),
+            _sds(one_chip, (1, 256), jnp.int32), one, one, one)
+    fn = functools.partial(pa.kv_window_prefill_attention, window=4096)
+    compiled = jax.jit(fn).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "kv_window_prefill_attention" in hlo
+    for shape in shapes:
+        dims = ",".join(map(str, shape))
+        assert f"bf16[{dims}]" in hlo
+        assert not re.search(rf"= bf16\[{dims}\]\S* copy\(", hlo)
+    assert "gather" not in hlo
+    assert "s32[1280]" in hlo and "s32[160]" in hlo
+    assert "bf16[2,32,16,4,128]" in str(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("tokens", [512, 1024])
 def test_grouped_reglu_experts_compile_at_the_cells_widths(one_chip, tokens):
     """64 experts of 2,560 x 768, 6 a token, the router's choices made ahead

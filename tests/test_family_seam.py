@@ -349,6 +349,14 @@ def test_selection_counts_do_not_overflow_int32_positions():
      [("moe_ffn_tokens", "dense", 32), ("ssm_tokens", "scan", 32)]),
     ("tiny-hybrid", "prefix_prefill", 32, 1, 1,
      [("moe_ffn_tokens", "dense", 32), ("ssm_tokens", "scan", 32)]),
+    # A K/V model with window and full layers: a continuation window's rows
+    # by the form its attention reads the pages with; a first window reads
+    # none, and a decode step's walks are not a window's.
+    ("tiny-swa-kv", "prefix_prefill", 16, 1, 1,
+     [("moe_ffn_tokens", "dense", 16),
+      ("kv_prefill_attention_tokens", "xla", 16)]),
+    ("tiny-swa-kv", "prefill", 16, 1, 1, [("moe_ffn_tokens", "dense", 16)]),
+    ("tiny-swa-kv", "decode", 4, 2, 3, [("moe_ffn_tokens", "dense", 8)]),
 ])
 def test_program_counts_by_family_and_kind(name, kind, rows, steps, real,
                                            want):
@@ -370,6 +378,11 @@ def test_program_counts_name_the_form_the_program_traced_with():
                                    **WIDE_SSM), platform="cpu", interpret=True)
     assert ("ssm_state_updates", "kernel", 16) in ssm.program_counts(
         "decode", 4, 2)
+    for kw, form in ((dict(platform="cpu", interpret=True), "kernel"),
+                     (dict(platform="tpu"), "xla")):   # head_dim 16: no DMA
+        swa = bind(configs.get_config("tiny-swa-kv"), **kw)
+        assert ("kv_prefill_attention_tokens", form, 24) in (
+            swa.program_counts("prefix_prefill", 24, 1, real=1))
 
 
 @pytest.mark.parametrize("name", ["tiny-mla", "tiny-longcat", "tiny-dsa",

@@ -30,6 +30,7 @@ from llm_d_inference_scheduler_tpu.ops.pallas_latent_attention import (
 )
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
     STAGE_VMEM_BYTES,
+    kv_window_prefill_attention,
     paged_decode_attention_pallas,
     pages_per_stage,
     stage_vmem_bytes,
@@ -297,3 +298,166 @@ def test_engine_pallas_branch_matches_default():
     t_pallas = asyncio.run(gen(EngineConfig(**base, pallas_attention=True,
                                             pallas_interpret=True)))
     assert t_pallas == t_default
+
+
+# ---------- a continuation window's queries over the pages before it ----------
+
+# 7 query heads a KV head, pages of 16 rows, stages of 4 pages (so a table
+# holds several), 96 queries in tiles of 32: three programs, whose bands
+# start in different stages.
+_PF = dict(H=14, Hkv=2, D=32, block=16, S=96, stage=4, tile=32, width=64,
+           n_blocks=200, layers=3, layer=1)
+_BAND = 150                   # a window layer's: it starts inside a page
+_STAGE_ROWS = _PF["stage"] * _PF["block"]
+
+
+def _prefill_case(kind, prefix_len, suffix_len, table_kind, dtype, seed=0):
+    """The operands of ``pages.prefill_attention`` for one continuation
+    window of a layer of ``kind``: (q, k_new, v_new, pools, near_pools,
+    is_near, layer, table [1, W], near_table [1, 10], near_first_pos [1],
+    prefix_len [1], suffix_len [1]). BOTH pool pairs hold random rows at the
+    pages the sequence's table names up to ``prefix_len`` in the layer that
+    is read, each its own, and large values everywhere else (the other
+    layers, the rest of the last page, pages nobody owns, the trash page),
+    in float32; ``dtype`` is cast last."""
+    p = _PF
+    rng = np.random.default_rng([seed, prefix_len])
+    n_pages = -(-prefix_len // p["block"])
+    logical = {"ascending": 5 + np.arange(p["width"]),
+               "shuffled": 1 + rng.permutation(p["n_blocks"] - 1)[:p["width"]],
+               }.get(table_kind.split("+")[0])
+    # Entries past the prompt's pages: the engine's table holds the trash
+    # block there; "own" leaves pages the sequence will write next.
+    table_row = np.where(np.arange(p["width"]) < n_pages, logical,
+                         logical if table_kind.endswith("+own") else 0)
+    pools = []
+    for i in range(4):       # K and V of the one kind, K and V of the other
+        pool = np.full((p["layers"], p["n_blocks"], p["block"], p["Hkv"],
+                        p["D"]), 1e4, np.float32)
+        rows = rng.standard_normal((n_pages * p["block"], p["Hkv"], p["D"]),
+                                   np.float32)
+        rows[prefix_len:] = 1e4
+        pool[p["layer"], table_row[:n_pages]] = rows.reshape(
+            n_pages, p["block"], p["Hkv"], p["D"])
+        pools.append(jnp.asarray(pool, dtype))
+    keys = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(keys[0], (1, p["S"], p["H"], p["D"]), dtype)
+    k_new, v_new = (jax.random.normal(k, (1, p["S"], p["Hkv"], p["D"]), dtype)
+                    for k in keys[1:])
+    prefix = jnp.asarray([prefix_len], jnp.int32)
+    table = jnp.asarray(table_row[None], jnp.int32)
+    near_table, pos = pages.window_prefix_pages(table, prefix, p["block"],
+                                                _BAND)
+    return (q, k_new, v_new, tuple(pools[:2]), tuple(pools[2:]),
+            jnp.asarray(kind == "window"), jnp.int32(p["layer"]), table,
+            near_table, pos[:, 0], prefix,
+            jnp.asarray([suffix_len], jnp.int32))
+
+
+def _attend(case, impl):
+    return pages.prefill_attention(*case, window=_BAND, impl=impl)
+
+
+@pytest.fixture
+def shrunk_prefill(monkeypatch):
+    """Stages of ``_PF['stage']`` pages and tiles of ``_PF['tile']`` queries
+    (read where the wrapper is traced: its cache is emptied around them)."""
+    p = _PF
+    monkeypatch.setattr(
+        pallas_paged_attention, "STAGE_VMEM_BYTES",
+        p["stage"] * stage_vmem_bytes(1, p["block"], p["Hkv"], p["D"], 4))
+    monkeypatch.setattr(pallas_paged_attention, "QUERY_TILE", p["tile"])
+    kv_window_prefill_attention.clear_cache()
+    yield
+    kv_window_prefill_attention.clear_cache()
+
+
+@pytest.mark.parametrize("kind, prefix_len, suffix_len, table_kind, dtype", [
+    # The prefix: one row, one page, short of a stage's multiple by a page,
+    # whole stages, one page past them; both kinds of layer.
+    *[(kind, n, _PF["S"], "shuffled", "float32")
+      for kind in ("full", "window")
+      for n in (1, 16, 1008, 4 * _STAGE_ROWS, 4 * _STAGE_ROWS + 16)],
+    # A prefix that ends inside a page; a window shorter than the tile, and
+    # one whose last tile is all padding.
+    ("full", 1003, 96, "shuffled", "float32"),
+    ("full", 1008, 70, "shuffled", "float32"),
+    ("window", 1008, 33, "shuffled", "float32"),
+    ("window", 16, 5, "ascending", "float32"),
+    # An ascending table (every whole group a run), one that holds pages of
+    # the sequence's own past the prefix, one the trash block there.
+    ("full", 1008, 96, "ascending", "float32"),
+    ("full", 528, 96, "ascending+own", "float32"),
+    ("window", 1008, 96, "ascending", "float32"),
+    ("window", 272, 96, "ascending+own", "float32"),
+    ("full", 272, 96, "shuffled+own", "float32"),
+    # The pools as a chip holds them.
+    ("full", 1008, 96, "shuffled", "bfloat16"),
+    ("full", 528, 80, "ascending", "bfloat16"),
+    ("window", 1008, 96, "ascending", "bfloat16"),
+    ("window", 16, 96, "shuffled", "bfloat16"),
+])
+def test_window_prefill_kernel_is_banded_attention_over_the_gathered_rows(
+        kind, prefix_len, suffix_len, table_kind, dtype, shrunk_prefill):
+    """ONE kernel for the layers of both kinds, which walks the pages,
+    against the plain form, which gathers the kind's table's rows whole and
+    bands them in XLA: the same to rounding for every real query (the padded
+    ones are nobody's), with large values wherever the walk must not look
+    (the other kind's pools hold other rows at the same pages)."""
+    case = _prefill_case(kind, prefix_len, suffix_len, table_kind,
+                         jnp.dtype(dtype))
+    q, table, near_table, near_first = case[0], *case[7:10]
+    assert table.shape[1] == _PF["width"]
+    # A window layer's pages end where the window starts; its band starts
+    # inside one (150 rows back from a multiple of 16).
+    assert near_table.shape[1] == -(-(_BAND - 1) // _PF["block"]) == 10
+    assert (_BAND - 1) % _PF["block"]
+    if kind == "full" and table_kind.startswith("ascending"):
+        runs = np.asarray(table_runs(table, case[10] + 1, _PF["block"],
+                                     run_pages(_PF["stage"])))
+        assert runs.sum() == -(-prefix_len // _PF["block"]) // 4 > 0
+    want = _attend(case, "xla")
+    got = _attend(case, "kernel_interpret")
+    assert got.shape == q.shape and got.dtype == q.dtype
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == "float32" else dict(
+        rtol=2e-2, atol=2e-2)
+    want = np.asarray(want, np.float32)[0, :suffix_len]
+    assert np.abs(want).max() < 10
+    np.testing.assert_allclose(np.asarray(got, np.float32)[0, :suffix_len],
+                               want, **tol)
+    # The other kind of layer over the same operands reads the other pools.
+    flipped = (*case[:5], jnp.logical_not(case[5]), *case[6:])
+    assert np.abs(np.asarray(_attend(flipped, "kernel_interpret"), np.float32)
+                  [0, :suffix_len] - want).max() > 1e-2
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_window_prefill_kernel_reads_what_the_prompt_holds_not_the_bucket(
+        kind, shrunk_prefill):
+    """Pages a table names past ``prefix_len`` are never fetched, nor is
+    anything of the other kind's pools: filled with NaN (where the plain
+    form's 0 x NaN spoils every query), the kernel's output is what it was,
+    to the last bit."""
+    case = _prefill_case(kind, 272, _PF["S"], "ascending+own",
+                         jnp.dtype("float32"))
+    pools, near_pools, table = case[3], case[4], case[7]
+    # The sequence's own pages past the prefix, and the trash page (which
+    # ``window_prefix_pages`` names there).
+    past = np.concatenate([np.asarray(table)[0, 272 // 16:], [0]])
+    assert len(past) > 1 and (past < _PF["n_blocks"]).all()
+    mine, other = (near_pools, pools) if kind == "window" else (pools,
+                                                               near_pools)
+    spoiled = dict(
+        mine=[pool.at[:, past].set(jnp.nan) for pool in mine],
+        other=[jnp.full_like(pool, jnp.nan) for pool in other])
+    if kind == "window":
+        spoiled = (tuple(spoiled["other"]), tuple(spoiled["mine"]))
+    else:
+        spoiled = (tuple(spoiled["mine"]), tuple(spoiled["other"]))
+    bad = (*case[:3], *spoiled, *case[5:])
+    got = np.asarray(_attend(bad, "kernel_interpret"))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(
+        got, np.asarray(_attend(case, "kernel_interpret")))
+    if kind == "full":
+        assert np.isnan(np.asarray(_attend(bad, "xla"))).all()

@@ -609,6 +609,114 @@ def test_bind_resolves_the_window_walk_by_the_latent_familys_rule():
         & set(other)
 
 
+# ---------- a continuation window as one kernel over the pages ----------
+
+def _cell_model():
+    """``smallthinker-21b-a3b-cut`` as the benchmark's file states it."""
+    import json
+
+    with open(REPO / "chipbench" / "configs"
+              / "smallthinker-21b-a3b-cut.json") as f:
+        return config_from_hf(types.SimpleNamespace(**json.load(f)),
+                              name="smallthinker-21b-a3b-cut")
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the programs under its scans,
+    conds and calls; a kernel's own body is the kernel's."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("form, kernels, gathers", [("kernel", 1, 0),
+                                                    ("xla", 0, 4)])
+def test_the_cells_continuation_program_walks_the_pools_in_the_kernel(
+        form, kernels, gathers):
+    """The continuation program of the benchmark's configuration (1,024
+    queries behind a prior table of 256 blocks, traced on shapes alone):
+    bound for a TPU it holds the kernel's call ONCE, for the layers of both
+    kinds (its text is set-up time), handed both kinds' stacked pools whole
+    and the sequence's whole table, and NO gather reads a pool; the plain
+    form gathers K and V of each kind in a ``cond`` branch each, and calls no
+    kernel."""
+    bound = bind(_cell_model(), platform="tpu")
+    assert bound.mcfg.swa_impl == "kernel"
+    mcfg = dataclasses.replace(bound.mcfg, swa_impl=form)
+    geom = pages.PageGeometry.for_engine(mcfg, 32, 16384)
+    shapes = (geom.shape, geom.window.shape)
+    assert shapes == ((2, 32769, 16, 4, 128), (6, 10065, 16, 4, 128))
+    params = jax.eval_shape(lambda k: llama.init_params(mcfg, k),
+                            jax.random.key(0))
+    cache = state.at_slots(
+        jax.eval_shape(lambda: pages.alloc(geom)[0]), [0],
+        np.zeros((1, geom.max_blocks_per_seq), np.int32))
+    one = jax.ShapeDtypeStruct((1,), jnp.int32)
+    traced = jax.make_jaxpr(
+        lambda *a: llama.prefill_with_prefix(a[0], mcfg, *a[1:]))(
+        params, jax.ShapeDtypeStruct((1, 1024), jnp.int32), one, one, cache,
+        None, jax.ShapeDtypeStruct((1, geom.max_blocks_per_seq), jnp.int32),
+        jax.ShapeDtypeStruct((1, 256), jnp.int32))
+    calls, reads = [], []
+    for eqn in _eqns(traced.jaxpr):
+        of_pools = [v.aval.shape for v in eqn.invars
+                    if getattr(v.aval, "shape", None) in shapes]
+        if eqn.primitive.name == "pallas_call":
+            calls.append((str(eqn.params["name"]), of_pools,
+                          [v.aval.shape for v in eqn.invars[:2]]))
+        elif eqn.primitive.name == "gather" and of_pools:
+            reads.append(of_pools)
+    assert len(reads) == gathers
+    walks = [c for c in calls if "kv_window_prefill_attention" in c[0]]
+    assert len(walks) == kernels
+    for _, of_pools, (tables, runs) in walks:
+        assert of_pools == [shapes[0]] * 2 + [shapes[1]] * 2
+        # The sequence's 1,024 entries and the 256 pages a band of 4,096
+        # reaches, a flag a group of 8: nothing of the prior bucket.
+        assert (tables, runs) == ((1024 + 256,), ((1024 + 256) // 8,))
+
+
+def test_the_continuation_microbenchmark_rehearses_on_the_cpu(capsys,
+                                                              monkeypatch):
+    """scripts/microbench_decode.py --kv-prefill at the cell's widths and a
+    small window, the kernel interpreted: a line a (layer kind, prior
+    bucket, form), the kernel within bf16's rounding of the plain form."""
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "microbench_decode", REPO / "scripts" / "microbench_decode.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    monkeypatch.setattr("llm_d_inference_scheduler_tpu.utils.compile_cache."
+                        "configure_compile_cache", lambda: "")
+    bench.main(["--kv-prefill", "--kv-prefill-interpret",
+                "--kv-prefill-tokens", "32", "--kv-prefill-lanes", "1",
+                "--max-model-len", "1024", "--kv-prefill-priors", "8,40",
+                "--kv-prefill-iters", "1", "--kv-prefill-query-tiles", "16"])
+    assert paged.QUERY_TILE == 256         # the script put it back
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert [(ln["layer"], ln["prior_blocks"], ln["form"]) for ln in lines] \
+        == [(kind, prior, form) for kind in ("full", "window")
+            for prior in (8, 40) for form in ("xla", "kernel")]
+    for ln in lines:
+        assert ln["component"] == "kv_window_prefill_attention"
+        assert (ln["heads"], ln["window"]) == (28, 32)
+        assert ln["live_rows"] == ln["prior_blocks"] * 12
+        # A full layer's table is the prior bucket, or for the kernel the
+        # sequence's whole table (1,024 tokens in pages of 16); a window
+        # layer's the pages its band of 4,096 reaches.
+        assert ln["table_width"] == (
+            256 if ln["layer"] == "window" else
+            ln["prior_blocks"] if ln["form"] == "xla" else 64)
+        assert ln["mxu_peak_share_pct"] is None      # no chip: no share
+        if ln["form"] == "kernel":
+            assert ln["query_tile"] == 16 and ln["max_err_vs_plain"] < 0.05
+
+
 # ---------- the engine, end to end ----------
 
 @pytest.fixture
@@ -673,11 +781,15 @@ def test_engine_serves_through_windows_and_both_kinds_of_pool(served):
                                      "kind")
                      for name in ("kv_table_groups",
                                   "kv_window_table_groups")},
+                    _counters(eng.telemetry,
+                              "jetstream:kv_prefill_attention_tokens_total",
+                              "form"),
                     eng.describe()["settings"])
         finally:
             await eng.stop()
 
-    got, plain, usage, owner, rows, ffn, groups, settings = asyncio.run(serve(
+    (got, plain, usage, owner, rows, ffn, groups, continued,
+     settings) = asyncio.run(serve(
         EngineConfig(model=served, backend="tpu", max_batch=2,
                      max_model_len=96, decode_chunk=4, kv_events_port=0,
                      seed=7, prefill_chunk=8, pallas_attention=True,
@@ -689,6 +801,11 @@ def test_engine_serves_through_windows_and_both_kinds_of_pool(served):
     assert owner.free_blocks == owner.n_blocks - 1
     assert 0 < rows["attended"] < rows["context"]
     assert ffn["dense"] > 0
+    # Every continuation window (4 + 1 + 2 of 8 padded tokens after the
+    # prompts' first, and the warm-up's) went through the kernel that walks
+    # the pages.
+    assert set(continued) == {"kernel"} and continued["kernel"] >= 7 * 8
+    assert continued["kernel"] % 8 == 0
     # The three admitted tables (13, 8 and 8 pages, prompt and output) in
     # groups of 8 as the full layers' kernel fetches them, and the window
     # tables of the decoding lanes, once a chunk of 4 steps (a window of 11
