@@ -66,7 +66,7 @@ PROGRAM_COUNTERS = ("moe_ffn_tokens", "mla_attention_tokens",
                     "mla_window_attention_tokens",
                     "dsa_query_tokens", "dsa_rows", "ssm_tokens",
                     "ssm_state_updates", "ssm_slot_prefills", "swa_rows",
-                    "kv_prefill_attention_tokens")
+                    "kv_prefill_attention_tokens", "ssm_scan_tokens")
 
 
 class XlaBuilds:
@@ -223,6 +223,18 @@ class EngineTelemetry:
             "gathered whole and banded, the scores a block of queries at a "
             "time in memory; counted on the host at dispatch, a token once "
             "a program; empty for any other model",
+            ("form",), registry=self.registry)
+        self.ssm_scan_tokens = Counter(
+            "jetstream:ssm_scan_tokens_total",
+            "Rows (padded tokens) of the prefill and prefix-continuation "
+            "programs of a model whose state-space layers have no matrix "
+            "form (a decay a channel a state value: ModelConfig.ssm_dt_rank), "
+            "by how the program runs the recurrence over a window's rows "
+            "(models/binding.bind, ModelConfig.ssm_scan_impl): `kernel` the "
+            "state tile resident in VMEM over the rows in order "
+            "(ops/pallas_ssm.selective_scan), `xla` a scan over positions "
+            "with the state through memory; counted on the host at dispatch, "
+            "a token once a program; empty for any other model",
             ("form",), registry=self.registry)
         self.dsa_query_tokens = Counter(
             "jetstream:dsa_query_tokens_total",

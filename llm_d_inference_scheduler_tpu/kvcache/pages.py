@@ -226,7 +226,8 @@ class PageGeometry:
                   max_blocks_per_seq: int | None = None,
                   dtype: str | None = None) -> "PageGeometry":
         """A pool of ``n_blocks`` pages at ``model``'s widths (anything with
-        n_layers, kv_block_size, n_kv_heads, head_dim and dtype; a
+        n_layers, kv_block_size, n_kv_heads (``kv_heads_kept`` where it
+        keeps a head more than once), head_dim and dtype; a
         ``latent_dim`` above 0 asks for the latent kind; ``n_kv_layers``
         where not every layer keeps pages; ``tallies_choices`` and
         ``n_zero_experts`` where its programs count). Never fewer than two
@@ -234,7 +235,8 @@ class PageGeometry:
         n_blocks = max(n_blocks, 2)
         return cls(getattr(model, "n_kv_layers", model.n_layers), n_blocks,
                    model.kv_block_size,
-                   model.n_kv_heads, model.head_dim,
+                   getattr(model, "kv_heads_kept", model.n_kv_heads),
+                   model.head_dim,
                    str(jnp.dtype(dtype or model.dtype)),
                    max_blocks_per_seq or n_blocks - 1,
                    getattr(model, "latent_dim", 0),

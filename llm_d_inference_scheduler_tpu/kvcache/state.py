@@ -14,6 +14,21 @@ Row ``slots`` (the engine's ``max_batch``) is nobody's: padding lanes and
 warm-up programs read and write it, as padded page writes go to the trash
 block.
 
+A Mamba-1 layer (models/hybrid.py's "S" layers) keeps another kind of state:
+no heads, a decay of its own for every channel and state value. Its pool is
+the SECOND LAYOUT, under the same slots, owner and functions:
+
+- ``ssm``  ``[state layers, slots + 1, state, inner]`` float32: the channels
+  (5,120 at Jamba2-3B's widths) on the lanes and the 16 state values on the
+  sublanes, where 40 "heads" of ``[128, 16]`` would put 16 values on 128
+  lanes;
+- ``conv`` ``[state layers, slots + 1, (conv - 1) * inner]``: the
+  convolution there runs over x alone.
+
+``StateGeometry.inner`` says which layout a pool has; a decode step of such
+a layer goes through :func:`recur1`, whose kernel takes the exponential of
+``dt A`` itself from the layer's own ``A`` tile.
+
 Who writes a slot's rows. A request's first prefill window writes them whole
 (it starts from zeros and never reads them: :func:`start`); a later window
 reads them (:func:`read`) and writes every layer's back at its end
@@ -91,6 +106,10 @@ class StateGeometry:
     tail_rows: int      # conv - 1 inputs kept
     channels: int       # what the convolution runs over
     dtype: str          # of the tail; the state is float32
+    # The second layout, a Mamba-1 layer's: ``[state, inner]`` a slot a
+    # layer, the channels on the lanes (no heads: ``heads`` and ``head_dim``
+    # 0); 0: the layout by heads.
+    inner: int = 0
 
     @classmethod
     def for_engine(cls, model: Any, max_batch: int) -> "StateGeometry | None":
@@ -98,14 +117,24 @@ class StateGeometry:
         the ssm_* widths); None for a model that keeps pages alone."""
         if not getattr(model, "n_state_layers", 0):
             return None
+        if getattr(model, "ssm_dt_rank", 0):
+            return cls(model.n_state_layers, max_batch, 0, 0, model.ssm_state,
+                       model.ssm_conv - 1, model.ssm_conv_dim,
+                       str(jnp.dtype(model.dtype)), inner=model.ssm_inner)
         return cls(model.n_state_layers, max_batch, model.ssm_heads,
                    model.ssm_head_dim, model.ssm_state, model.ssm_conv - 1,
                    model.ssm_conv_dim, str(jnp.dtype(model.dtype)))
 
     @property
+    def row_shape(self) -> tuple[int, ...]:
+        """One sequence's state in one layer."""
+        if self.inner:
+            return (self.state, self.inner)
+        return (self.heads, self.head_dim, self.state)
+
+    @property
     def ssm_shape(self) -> tuple[int, ...]:
-        return (self.n_layers, self.n_slots + 1, self.heads, self.head_dim,
-                self.state)
+        return (self.n_layers, self.n_slots + 1, *self.row_shape)
 
     @property
     def conv_shape(self) -> tuple[int, ...]:
@@ -115,7 +144,7 @@ class StateGeometry:
     @property
     def slot_bytes(self) -> int:
         """Bytes one sequence keeps, every state layer."""
-        ssm = self.heads * self.head_dim * self.state * 4
+        ssm = int(np.prod(self.row_shape)) * 4
         tail = self.tail_rows * self.channels * jnp.dtype(self.dtype).itemsize
         return self.n_layers * (ssm + tail)
 
@@ -239,7 +268,8 @@ def counted(cache: Cache, held: jax.Array, zero: jax.Array | None = None,
 
 def read(cache: Cache, layer: int) -> tuple[jax.Array, jax.Array]:
     """State layer ``layer`` of this step's rows: (state [B, heads, head_dim,
-    state] f32, tail [B, tail rows * channels], the rows flat as stored)."""
+    state] f32 -- [B, state, inner] in the second layout --, tail [B, tail
+    rows * channels], the rows flat as stored)."""
     return cache.ssm[layer, cache.slots], tail(cache, layer)
 
 
@@ -267,6 +297,24 @@ def recur(cache: Cache, layer: int, keep: jax.Array, dtx: jax.Array,
     else:
         rows, y = pallas_ssm.update_rows(cache.ssm[layer, cache.slots], keep,
                                          dtx, b, c)
+        ssm = cache.ssm.at[layer, cache.slots].set(rows)
+    return dataclasses.replace(cache, ssm=ssm), y
+
+
+def recur1(cache: Cache, layer: int, dt: jax.Array, x: jax.Array,
+           b: jax.Array, c: jax.Array, a: jax.Array, d: jax.Array, *,
+           impl: str = "gathered") -> tuple[Cache, jax.Array]:
+    """:func:`recur` for the second layout (a Mamba-1 layer's rows, ``[state,
+    inner]``): ``S <- exp(dt A) S + dt x (x) b``, ``y = S c + d x``, the
+    operands as ops/pallas_ssm.update1_rows takes them. Returns (the cache
+    with those rows updated, y [B, inner]); ``impl`` as there."""
+    if impl.startswith("kernel"):
+        ssm, y = pallas_ssm.update1_in_place(
+            cache.ssm, jnp.asarray(layer, jnp.int32), cache.slots, dt, x, b,
+            c, a, d, interpret=impl == "kernel_interpret")
+    else:
+        rows, y = pallas_ssm.update1_rows(cache.ssm[layer, cache.slots], dt,
+                                          x, b, c, a, d)
         ssm = cache.ssm.at[layer, cache.slots].set(rows)
     return dataclasses.replace(cache, ssm=ssm), y
 

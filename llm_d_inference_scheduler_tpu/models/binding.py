@@ -174,6 +174,11 @@ class Bound:
                                tokens * m.n_state_layers))
             if kind == "prefill" and real:
                 counts.append(("ssm_slot_prefills", None, real))
+            if m.ssm_dt_rank and not decode:
+                # A window's rows through the recurrence in order, by the
+                # form that scan traced with.
+                counts.append(("ssm_scan_tokens",
+                               m.ssm_scan_impl.split("_")[0], tokens))
         return counts
 
     def describe(self) -> dict[str, Any]:
@@ -202,6 +207,10 @@ class Bound:
                 if self.moe_chosen(1) else None),
             # How a decode step fetches its slots' recurrent states.
             "state_update": m.ssm_impl if m.n_state_layers else None,
+            # How a prompt window runs a recurrence that has no matrix form,
+            # and the layers of each kind (keys only such a model has).
+            **({"state_scan": m.ssm_scan_impl,
+                "state_layers": m.n_state_layers} if m.ssm_dt_rank else {}),
             # The form of the layers that attend to a window of the context
             # (a key only a model with such layers has); in the K/V family
             # also that of a continuation window's attention, both kinds of
@@ -224,9 +233,11 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     where it runs its kernels through the interpreter (tests on the CPU),
     ``sharded`` where its weights or pools span devices. How a decode step
     fetches its slots' recurrent states (``ssm_impl``) is
-    ``pallas_ssm.use_kernel``'s; the three forms of ops/pallas_dsa.py's
-    kernels follow one rule, the kernel on a TPU, where a [heads, queries,
-    rows] product must not reach HBM, and the plain form on the CPU: every
+    ``pallas_ssm.use_kernel``'s, and so is how a prompt window of a model
+    whose recurrence has no matrix form runs it (``ssm_scan_impl``); the
+    three forms of ops/pallas_dsa.py's kernels follow one rule, the kernel
+    on a TPU, where a [heads, queries, rows] product must not reach HBM, and
+    the plain form on the CPU: every
     latent block's expanded attention (``expanded_impl``: a prefill, a window
     that continues a cached prefix, whether or not the block selects), a
     selecting block's indexer (``index_impl``), and the layers that attend to
@@ -235,11 +246,18 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     caller sets over the rules (a comparison of two forms on one device)."""
     forms: dict[str, str] = {}
     if mcfg.n_state_layers:
+        # A slot's tile, [sublanes, lanes]: a head's [head_dim, state], or a
+        # Mamba-1 layer's [state, inner].
         kernel = pallas_ssm.use_kernel(
-            mcfg.ssm_state, mcfg.ssm_head_dim, platform=platform,
+            mcfg.ssm_row[-1], mcfg.ssm_row[-2], platform=platform,
             sharded=sharded, interpret=interpret)
         forms["ssm_impl"] = ("gathered" if not kernel else
                              "kernel_interpret" if interpret else "kernel")
+        if mcfg.ssm_dt_rank:
+            # The prompt windows' scan keeps the same tile resident.
+            forms["ssm_scan_impl"] = ("xla" if not kernel else
+                                      "kernel_interpret" if interpret
+                                      else "kernel")
     tiled = ("kernel_interpret" if interpret else
              "kernel" if platform == "tpu" else "xla")
     if mcfg.kv_lora_rank:

@@ -84,6 +84,10 @@ class ModelConfig:
     # names the family: one character a layer, each layer ONE mixer under its
     # own residual -- "M" a Mamba-2 state-space layer, "E" a LatentMoE expert
     # layer, "*" attention (GQA, no rotary embedding). n_layers is its length.
+    # The jamba family's layers are two more letters of the same block: "S" a
+    # Mamba-1 state-space mixer and "A" attention (the same, no rotary
+    # embedding), EACH followed by a dense SwiGLU of d_ff under a residual of
+    # its own.
     layer_pattern: str = ""
     # "M": ssm_heads heads of ssm_head_dim, a state of ssm_state values a
     # head-channel, B and C shared by the heads of one of ssm_groups groups,
@@ -104,6 +108,18 @@ class ModelConfig:
     # models.bind sets it from what the engine is (pallas_ssm.use_kernel);
     # tests force one.
     ssm_impl: str = "gathered"
+    # "S", Mamba-1 (ssm_dt_rank > 0 names it): ssm_expand x d_model channels,
+    # each with a state of ssm_state values and a decay of its own a state
+    # value (no heads, no groups, no matrix form); the convolution (ssm_conv
+    # wide, with a bias) runs over x alone; the step size comes through a
+    # projection of rank ssm_dt_rank with a bias; RMSNorms on dt, B and C.
+    ssm_dt_rank: int = 0
+    ssm_expand: int = 0
+    # How such a model's prompt windows run the recurrence (models/hybrid.py):
+    # "xla" (``lax.scan`` over positions: the CPU's way and the plain form) |
+    # "kernel" (ops/pallas_ssm.selective_scan: the state tile resident in VMEM
+    # over the window's rows) | "kernel_interpret". models.bind sets it.
+    ssm_scan_impl: str = "xla"
     # "E": routed experts of width moe_d_ff between a projection down to
     # moe_latent_dim and one back up, not gated (relu squared), beside a
     # shared expert of width shared_d_ff on the model's own width.
@@ -213,13 +229,27 @@ class ModelConfig:
     @property
     def n_state_layers(self) -> int:
         """Layers that keep recurrent state a sequence (0: pages alone)."""
-        return self.layer_pattern.count("M")
+        return self.layer_pattern.count("M") + self.layer_pattern.count("S")
 
     @property
     def n_kv_layers(self) -> int:
         """Layers that keep pages of keys and values (cache layers)."""
+        if self.mixer_pattern:
+            return self.layer_pattern.count("*") + self.layer_pattern.count("A")
         return (self.layer_pattern.count("*") if self.layer_pattern
                 else self.n_layers * self.attn_sublayers)
+
+    @property
+    def kv_heads_kept(self) -> int:
+        """KV heads a page holds. models/hybrid.py keeps a model's ONE KV
+        head twice: a bf16 page's minor tile is two rows of 128 lanes, so a
+        pool ``[.., block, 1, 128]`` lies padded to two heads in HBM whatever
+        is written there, and the paged decode kernel cannot slice a padded
+        dim; kept twice, the bytes are the same and the walk is the
+        two-KV-head program (each copy serves half the query heads)."""
+        if self.mixer_pattern and self.n_kv_heads == 1:
+            return 2
+        return self.n_kv_heads
 
     @property
     def n_window_layers(self) -> int:
@@ -264,11 +294,24 @@ class ModelConfig:
 
     @property
     def ssm_inner(self) -> int:
+        if self.ssm_dt_rank:
+            return self.ssm_expand * self.d_model
         return self.ssm_heads * self.ssm_head_dim
 
     @property
+    def ssm_row(self) -> tuple[int, ...]:
+        """One sequence's recurrent state in one layer (kvcache/state.py's
+        two layouts)."""
+        if self.ssm_dt_rank:
+            return (self.ssm_state, self.ssm_inner)
+        return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+
+    @property
     def ssm_conv_dim(self) -> int:
-        """Channels the convolution runs over: x, then B and C a group."""
+        """Channels the convolution runs over: x, then B and C a group
+        (Mamba-1: x alone)."""
+        if self.ssm_dt_rank:
+            return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
@@ -633,13 +676,34 @@ TINY_HYBRID = ModelConfig(
     shared_d_ff=80,
 )
 
+# The jamba family at small widths (CI tests): two periods of four layers
+# with the attention layer second, Mamba-1 mixers of 2 x 48 = 96 channels, a
+# state of 6 and a step-size rank of 5 (aligned to nothing), 6 query heads on
+# ONE KV head, a dense SwiGLU behind every mixer.
+TINY_JAMBA = ModelConfig(
+    name="tiny-jamba",
+    vocab_size=512,
+    d_model=48,
+    n_layers=8,
+    n_heads=6,
+    n_kv_heads=1,
+    d_ff=72,
+    max_seq_len=256,
+    norm_eps=1e-6,
+    layer_pattern="SASSSASS",
+    ssm_state=6,
+    ssm_dt_rank=5,
+    ssm_expand=2,
+)
+
 _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
                                  KIMI_VL_A3B, TINY_MLA, TINY_LONGCAT, TINY_DSA,
                                  TINY_SWA, TINY_SWA_KV,
                                  NEMOTRON_3_SUPER,
-                                 NEMOTRON_3_SUPER_CUT, TINY_HYBRID)}
+                                 NEMOTRON_3_SUPER_CUT, TINY_HYBRID,
+                                 TINY_JAMBA)}
 
 
 def get_config(name: str) -> ModelConfig:

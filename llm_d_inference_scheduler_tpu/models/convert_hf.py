@@ -368,11 +368,69 @@ def _smallthinker_config_from_hf(hf, name: str) -> ModelConfig:
     )
 
 
+# What models/hybrid.py computes of the jamba family's options.
+_JAMBA_ONLY = {"num_experts": 1, "mamba_proj_bias": False,
+               "mamba_conv_bias": True, "sliding_window": None,
+               "hidden_act": "silu", "tie_word_embeddings": True}
+
+# The letters of a jamba file's derived layer order (``hybrid_override_
+# pattern``, the nemotron_h family's notation with two letters of its own):
+# a Mamba-1 mixer, an attention mixer, each with a dense FFN behind it.
+JAMBA_LAYERS = {"mamba": "S", "attention": "A"}
+
+
+def _jamba_config_from_hf(hf, name: str) -> ModelConfig:
+    """The jamba family (``model_type`` names it): Mamba-1 and attention
+    layers by ``attn_layer_period`` / ``attn_layer_offset``, each with a
+    dense SwiGLU (``num_experts`` 1). A mixture of experts, a bias on the
+    mixer's projections, a convolution without one, a sliding window and an
+    untied head are not built and refused. A file may state the order it
+    derived (``hybrid_override_pattern``); it has to be the one the two keys
+    give."""
+    _refuse_other_options(hf, name, _JAMBA_ONLY, "models/hybrid.py")
+    L = hf.num_hidden_layers
+    pattern = "".join(
+        JAMBA_LAYERS["attention" if i % hf.attn_layer_period
+                     == hf.attn_layer_offset else "mamba"] for i in range(L))
+    if hf.num_key_value_heads == 1 and hf.num_attention_heads % 2:
+        raise ValueError(
+            f"{name}: num_attention_heads={hf.num_attention_heads} on one KV "
+            "head: models/hybrid.py keeps a single KV head twice a page "
+            "(ModelConfig.kv_heads_kept), half the query heads on each copy")
+    stated = getattr(hf, "hybrid_override_pattern", None)
+    if stated is not None and stated != pattern:
+        raise ValueError(
+            f"{name}: hybrid_override_pattern {stated!r} is not the order "
+            f"attn_layer_period={hf.attn_layer_period} and attn_layer_offset="
+            f"{hf.attn_layer_offset} give {L} layers ({pattern!r})")
+    return ModelConfig(
+        name=name,
+        vocab_size=hf.vocab_size,
+        d_model=hf.hidden_size,
+        n_layers=L,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        d_ff=hf.intermediate_size,
+        max_seq_len=getattr(hf, "max_position_embeddings", 8192),
+        norm_eps=hf.rms_norm_eps,
+        layer_pattern=pattern,
+        ssm_state=hf.mamba_d_state,
+        ssm_conv=hf.mamba_d_conv,
+        ssm_dt_rank=hf.mamba_dt_rank,
+        ssm_expand=hf.mamba_expand,
+    )
+
+
+# The ``model_type``s the plain mapping below serves.
+_LLAMA_TYPES = ("llama", "mixtral", "qwen3")
+
+
 def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
     """Map a transformers Llama/Mixtral/Qwen3 config, a DeepSeek-V3-family
     one (Kimi-VL's ``text_config``), a LongCat-Flash one (``zero_expert_num``
-    names it), a nemotron_h one (a layer pattern) or a SmallThinker one
-    (``moe_num_primary_experts``) to our ModelConfig."""
+    names it), a nemotron_h one (a layer pattern), a SmallThinker one
+    (``moe_num_primary_experts``) or a jamba one (``model_type``) to our
+    ModelConfig. A ``model_type`` that none of these is gets refused."""
     text = getattr(hf_config, "text_config", None)
     if text is not None:
         # A multimodal config nests its language model; the towers beside it
@@ -381,6 +439,8 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
 
         hf_config = (types.SimpleNamespace(**text) if isinstance(text, dict)
                      else text)
+    if getattr(hf_config, "model_type", None) == "jamba":
+        return _jamba_config_from_hf(hf_config, name)
     if getattr(hf_config, "hybrid_override_pattern", None):
         return _hybrid_config_from_hf(hf_config, name)
     if getattr(hf_config, "zero_expert_num", None) is not None:
@@ -389,8 +449,17 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
         return _mla_config_from_hf(hf_config, name)
     if getattr(hf_config, "moe_num_primary_experts", None):
         return _smallthinker_config_from_hf(hf_config, name)
+    model_type = getattr(hf_config, "model_type", None)
+    if model_type not in _LLAMA_TYPES:
+        raise ValueError(
+            f"{name}: no mapping for model_type {model_type!r}: the plain "
+            f"mapping serves {', '.join(_LLAMA_TYPES)}; deepseek_v3-family "
+            "(kv_lora_rank), LongCat-Flash (zero_expert_num), nemotron_h "
+            "(hybrid_override_pattern), SmallThinker "
+            "(moe_num_primary_experts) and jamba files are known by those "
+            "keys")
     n_experts = getattr(hf_config, "num_local_experts", 0) or 0
-    qk_norm = getattr(hf_config, "model_type", "") == "qwen3"
+    qk_norm = model_type == "qwen3"
     explicit_hd = getattr(hf_config, "head_dim", None) or 0
     default_hd = hf_config.hidden_size // hf_config.num_attention_heads
     return ModelConfig(

@@ -1,7 +1,10 @@
 """The nemotron_h family's decoder for the TPU engine: a pattern of layers,
 each ONE mixer under its own residual -- a Mamba-2 state-space layer ("M"), a
 LatentMoE expert layer ("E") or attention ("*"). NVIDIA's Nemotron-3-Super is
-this block; its draft head (multi-token prediction) is not built.
+this block; its draft head (multi-token prediction) is not built. The jamba
+family (AI21's Jamba2-3B) is two more letters of the same walk: "S" a
+Mamba-1 mixer and "A" the same attention, EACH followed by a dense SwiGLU
+under a second residual (described below the three).
 
 The module has models/llama.py's four entry points with its signatures
 (``init_params``, ``forward``, ``decode_step``, ``prefill_with_prefix``), so
@@ -55,6 +58,25 @@ is left out: in the deployment the 1,024-wide latents are exchanged between
 in for it. The routed part runs dense over the held experts, grouped, or
 dense over the held experts some row chose (ops/pallas_moe.py), chosen by the
 engine per program (``cfg.moe_impl``).
+
+**S, Mamba-1** (``cfg.ssm_dt_rank`` > 0). ``[x | z] = h W_in``; the
+convolution (with its bias) and a silu over ``x`` ALONE; ``[dt_r | B | C] = x
+W_x`` and an RMSNorm with a learned weight on each of the three; ``dt =
+softplus(dt_r W_dt + b_dt)``; ``A = -exp(A_log)``, a value for every channel
+and state value, f32; ``S_t[c, n] = exp(dt_t[c] A[c, n]) S_(t-1)[c, n] +
+dt_t[c] B_t[n] x_t[c]``; ``y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]``;
+``out = (y * silu(z)) W_out``. The decay differs by channel AND state value,
+so there is no matrix form: a run of positions goes through the recurrence
+in order (*scan*: ops/pallas_ssm.selective_scan, the state tile resident in
+VMEM over the window's rows, or ``lax.scan`` over positions, as
+``cfg.ssm_scan_impl`` says), one position is the *step* form on the pool's
+second layout (``state.recur1``). The state and ``A`` lie ``[state, inner]``,
+the channels minor.
+
+**A** is ``*`` with an FFN behind it; **the FFN** of an "S" or "A" layer is
+models/llama.py's dense SwiGLU (``ffn`` stack: ``ln_mlp``, ``w1``, ``w3``,
+``w2``, a row a layer in layer order). A model's ONE KV head is kept twice a
+page (``cfg.kv_heads_kept``: :func:`_qkv`).
 """
 
 from __future__ import annotations
@@ -69,12 +91,17 @@ from ..kvcache import pages, state
 from ..ops import causal_attention, rms_norm
 from . import scopes
 from .configs import ModelConfig
-from .llama import _embedded, _last_logits, _logits
+from ..ops import pallas_ssm
+from .llama import _embedded, _ffn, _ffn_input, _last_logits, _logits
 from .routing import route
 
 Params = dict[str, Any]
 
-_STACK = {"M": "ssm", "E": "moe", "*": "attn"}
+# A layer's letter -> the stack that holds its mixer's parameters (and the
+# pool layer it keeps is its index among that stack's layers); "S" and "A"
+# carry a dense SwiGLU too, a row of the ``ffn`` stack each.
+_STACK = {"M": "ssm", "E": "moe", "*": "attn", "S": "ssm1", "A": "attn"}
+_WITH_FFN = "SA"
 
 
 def init_params(cfg: ModelConfig, key: jax.Array,
@@ -83,7 +110,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     over that kind's layers. ``A`` is uniform in [1, 16]; ``dt_bias`` the
     inverse softplus of a log-uniform step in cfg.ssm_dt_range; ``D`` ones;
     norm weights and the selection bias are drawn (models/mla.py), so that a
-    run on random weights sees them."""
+    run on random weights sees them. (The jamba family's: :func:`_init_jamba`.)"""
     dtype = dtype or jnp.dtype(cfg.dtype)
     D, V = cfg.d_model, cfg.vocab_size
     Lm, Le, La = (cfg.layer_pattern.count(c) for c in "ME*")
@@ -102,10 +129,16 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         return (1.0 + 0.1 * jax.random.normal(next(keys), shape,
                                               jnp.float32)).astype(dtype)
 
-    dt_min, dt_max, dt_floor = cfg.ssm_dt_range
-    dt = jnp.maximum(jnp.exp(jax.random.uniform(
-        next(keys), (Lm, H), jnp.float32, jnp.log(dt_min), jnp.log(dt_max))),
-        dt_floor)
+    def step(shape):
+        """A log-uniform step in cfg.ssm_dt_range."""
+        dt_min, dt_max, dt_floor = cfg.ssm_dt_range
+        return jnp.maximum(jnp.exp(jax.random.uniform(
+            next(keys), shape, jnp.float32, jnp.log(dt_min),
+            jnp.log(dt_max))), dt_floor)
+
+    dt = step((Lm, H))
+    if cfg.ssm_dt_rank:
+        return _init_jamba(cfg, dtype, w, norm, step, keys)
     return {
         "embed": w((V, D), D), "final_norm": norm((D,)),
         "lm_head": w((D, V), D),
@@ -115,7 +148,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             "conv_w": w((Lm, Kc, conv_dim), Kc),
             "conv_b": (0.1 * jax.random.normal(
                 next(keys), (Lm, conv_dim), jnp.float32)).astype(dtype),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "dt_bias": _inverse_softplus(dt),
             "A_log": jnp.log(jax.random.uniform(
                 next(keys), (Lm, H), jnp.float32, 1.0, 16.0)),
             "D": jnp.ones((Lm, H), jnp.float32),
@@ -133,6 +166,56 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             "ln": norm((La, D)),
             "wq": w((La, D, Hq * Dh), D), "wk": w((La, D, Hkv * Dh), D),
             "wv": w((La, D, Hkv * Dh), D), "wo": w((La, Hq * Dh, D), Hq * Dh)},
+    }
+
+
+def _inverse_softplus(dt: jnp.ndarray) -> jnp.ndarray:
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _init_jamba(cfg: ModelConfig, dtype, w, norm, step, keys) -> Params:
+    """The jamba family's parameters (:func:`init_params`' helpers): the
+    Mamba-1 mixers' stack ``ssm1``, the attention layers' ``attn``, and every
+    layer's dense SwiGLU in ``ffn`` (models/llama._ffn's names). ``A`` is
+    uniform in [1, 16] a channel a state value, kept as ``A_log`` [layers,
+    state, inner] f32 (the channels minor, as the state lies); the step
+    size's bias as the Mamba-2 layers'; ``D``, the three norms on dt, B and C
+    and the convolution's bias are drawn, so that a run sees them. The head
+    is the embedding transposed (tied): two tensors that hold the same
+    values."""
+    D, V, F = cfg.d_model, cfg.vocab_size, cfg.d_ff
+    Ls, La = (cfg.layer_pattern.count(c) for c in "SA")
+    N, R, Kc, inner = cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv, cfg.ssm_inner
+    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+
+    def near(shape, around=0.0):
+        return around + 0.1 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    embed = w((V, D), D)
+    return {
+        "embed": embed, "final_norm": norm((D,)), "lm_head": embed.T,
+        "ssm1": {
+            "ln": norm((Ls, D)),
+            "w_in": w((Ls, D, 2 * inner), D),
+            "conv_w": w((Ls, Kc, inner), Kc),
+            "conv_b": near((Ls, inner)).astype(dtype),
+            "w_x": w((Ls, inner, R + 2 * N), inner),
+            "dt_norm": norm((Ls, R)), "b_norm": norm((Ls, N)),
+            "c_norm": norm((Ls, N)),
+            "w_dt": w((Ls, R, inner), R),
+            "dt_bias": _inverse_softplus(step((Ls, inner))),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (Ls, N, inner), jnp.float32, 1.0, 16.0)),
+            "D": near((Ls, inner), 1.0),
+            "w_out": w((Ls, inner, D), inner)},
+        "attn": {
+            "ln": norm((La, D)),
+            "wq": w((La, D, Hq * Dh), D), "wk": w((La, D, Hkv * Dh), D),
+            "wv": w((La, D, Hkv * Dh), D), "wo": w((La, Hq * Dh, D), Hq * Dh)},
+        "ffn": {
+            "ln_mlp": norm((Ls + La, D)),
+            "w1": w((Ls + La, D, F), D), "w3": w((Ls + La, D, F), D),
+            "w2": w((Ls + La, F, D), F)},
     }
 
 
@@ -243,6 +326,33 @@ def _gated_out(cfg: ModelConfig, lp: Params, y: jnp.ndarray, z: jnp.ndarray
     return (y * lp["norm"].astype(jnp.float32)).astype(z.dtype) @ lp["w_out"]
 
 
+def _conv_run(lp: Params, xbc: jnp.ndarray, tail0: jnp.ndarray,
+              lens: jnp.ndarray, Kc: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The causal depth-wise convolution (with its bias, before the silu,
+    f32) over a run xbc [B, S, channels] that continues ``tail0`` [B, Kc - 1,
+    channels], and the tail as position ``lens[b] - 1`` leaves it."""
+    S = xbc.shape[1]
+    seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc], axis=1)
+    # Row t + j of seq is position t - (Kc - 1) + j.
+    conv = lp["conv_b"].astype(jnp.float32) + sum(
+        seq[:, j:j + S].astype(jnp.float32) * lp["conv_w"][j].astype(jnp.float32)
+        for j in range(Kc))
+    tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, Kc - 1))(
+        seq, lens)
+    return conv, tail
+
+
+def _conv_step(lp: Params, xbc: jnp.ndarray, tail0: jnp.ndarray
+               ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`_conv_run` for one position a sequence: xbc [B, channels]
+    behind ``tail0`` [B, Kc - 1, channels]; (the convolution's output, the
+    Kc rows it ran over: the last Kc - 1 are the new tail)."""
+    seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
+    conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
+        seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
+    return conv, seq
+
+
 def ssm_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
              state0: jnp.ndarray | None = None,
              tail0: jnp.ndarray | None = None
@@ -262,13 +372,7 @@ def ssm_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
             tail0 = jnp.zeros((B, Kc - 1, xbc.shape[-1]), xbc.dtype)
         if state0 is None:
             state0 = jnp.zeros((B, H, P, N), jnp.float32)
-        seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc], axis=1)
-        # Row t + j of seq is position t - (Kc - 1) + j.
-        conv = lp["conv_b"].astype(jnp.float32) + sum(
-            seq[:, j:j + S].astype(jnp.float32) * lp["conv_w"][j].astype(jnp.float32)
-            for j in range(Kc))
-        tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, Kc - 1))(
-            seq, lens)
+        conv, tail = _conv_run(lp, xbc, tail0, lens, Kc)
         x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
 
     with scopes.block("state.update"):
@@ -344,9 +448,7 @@ def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
         z, xbc, dt = _split_in(cfg, lp, h)
         tail0 = state.tail(cache, layer).reshape(B, cfg.ssm_conv - 1,
                                                  cfg.ssm_conv_dim)
-        seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
-        conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
-            seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
+        conv, seq = _conv_step(lp, xbc, tail0)
         x, b_mat, c_mat = _split_conv(cfg, jax.nn.silu(conv).astype(h.dtype))
         x, b_mat, c_mat = (t.astype(jnp.float32) for t in (x, b_mat, c_mat))
     with scopes.block("state.update"):
@@ -361,10 +463,99 @@ def ssm_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
     return out, cache, seq[:, 1:]
 
 
+# ---- S: Mamba-1 ---------------------------------------------------------------
+
+
+def _ssm1_in(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
+    """h W_in as (x [..., inner], z [..., inner])."""
+    xz = h @ lp["w_in"]
+    return xz[..., :cfg.ssm_inner], xz[..., cfg.ssm_inner:]
+
+
+def _ssm1_operands(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
+    """What the recurrence takes of the convolution's output x [..., inner]
+    (the model's dtype): (dt, the step sizes, and x [..., inner], B and C
+    [..., state], all f32). ``[dt_r | B | C] = x W_x``, an RMSNorm on each,
+    ``dt = softplus(dt_r W_dt + b_dt)``."""
+    R, N = cfg.ssm_dt_rank, cfg.ssm_state
+    dbc = x @ lp["w_x"]
+    dt_r = rms_norm(dbc[..., :R], lp["dt_norm"], cfg.norm_eps)
+    b = rms_norm(dbc[..., R:R + N], lp["b_norm"], cfg.norm_eps)
+    c = rms_norm(dbc[..., R + N:], lp["c_norm"], cfg.norm_eps)
+    dt = jax.nn.softplus((dt_r @ lp["w_dt"]).astype(jnp.float32)
+                         + lp["dt_bias"])
+    return dt, *(t.astype(jnp.float32) for t in (x, b, c))
+
+
+def _ssm1_out(lp: Params, y: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
+    """y [..., inner] (f32) gated by z, through W_out."""
+    return (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype) @ lp["w_out"]
+
+
+def ssm1_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
+              state0: jnp.ndarray | None = None,
+              tail0: jnp.ndarray | None = None
+              ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A Mamba-1 layer over a run of positions (:func:`ssm_scan`'s arguments
+    and results; the state [B, state, inner] f32). There is no matrix form:
+    the rows go through the recurrence in order, in the form
+    ``cfg.ssm_scan_impl`` names (ops/pallas_ssm.py: one kernel with the state
+    resident in VMEM, or ``lax.scan`` over positions). A padded position gets
+    a step size of 0: no decay and no input."""
+    B, S, _ = h.shape
+    Kc = cfg.ssm_conv
+    with scopes.block("state.proj"):
+        x, z = _ssm1_in(cfg, lp, h)
+        if tail0 is None:
+            tail0 = jnp.zeros((B, Kc - 1, x.shape[-1]), x.dtype)
+        if state0 is None:
+            state0 = jnp.zeros((B, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
+        conv, tail = _conv_run(lp, x, tail0, lens, Kc)
+        dt, x, b, c = _ssm1_operands(cfg, lp,
+                                     jax.nn.silu(conv).astype(h.dtype))
+    with scopes.block("state.update"):
+        real = jnp.arange(S)[None, :] < lens[:, None]
+        dt = jnp.where(real[..., None], dt, 0.0)
+        a = -jnp.exp(lp["A_log"])
+        if cfg.ssm_scan_impl.startswith("kernel"):
+            y, state1 = pallas_ssm.selective_scan(
+                dt, x, b, c, a, lp["D"], state0,
+                interpret=cfg.ssm_scan_impl == "kernel_interpret")
+        else:
+            y, state1 = pallas_ssm.scan_rows(dt, x, b, c, a, lp["D"], state0)
+    with scopes.block("state.proj"):
+        out = _ssm1_out(lp, y, z)
+    return out, state1, tail
+
+
+def ssm1_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+              cache: state.Cache, layer: int
+              ) -> tuple[jnp.ndarray, state.Cache, jnp.ndarray]:
+    """A Mamba-1 layer for one position a sequence (:func:`ssm_step`'s
+    arguments and results): the recurrence where the states live
+    (``state.recur1``, in the form ``cfg.ssm_impl`` names)."""
+    B = h.shape[0]
+    with scopes.block("state.proj"):
+        x, z = _ssm1_in(cfg, lp, h)
+        tail0 = state.tail(cache, layer).reshape(B, cfg.ssm_conv - 1,
+                                                 cfg.ssm_inner)
+        conv, seq = _conv_step(lp, x, tail0)
+        dt, x, b, c = _ssm1_operands(cfg, lp,
+                                     jax.nn.silu(conv).astype(h.dtype))
+    with scopes.block("state.update"):
+        cache, y = state.recur1(cache, layer, dt, x, b, c,
+                                -jnp.exp(lp["A_log"]), lp["D"],
+                                impl=cfg.ssm_impl)
+    with scopes.block("state.proj"):
+        out = _ssm1_out(lp, y, z)
+    return out, cache, seq[:, 1:]
+
+
 # ---- the stack ------------------------------------------------------------------
 
 
-_NORM_SCOPE = {"M": "state.proj", "*": "attn.proj", "E": "ffn.router"}
+_NORM_SCOPE = {"M": "state.proj", "*": "attn.proj", "E": "ffn.router",
+               "S": "state.proj", "A": "attn.proj"}
 
 
 def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -372,18 +563,20 @@ def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
           real: jnp.ndarray | None = None
           ) -> tuple[jnp.ndarray, jnp.ndarray, list[jnp.ndarray],
                      jnp.ndarray | None]:
-    """x through every layer in the pattern's order. ``mixers["M"]`` and
-    ``mixers["*"]`` are ``(layer's parameters, normed input, index among its
-    kind) -> mixer output`` and keep what else they make (states, K/V rows)
-    for their caller; the expert layer is the same in every step (``real``
-    is :func:`latent_moe`'s). Returns (x, the count of expert choices held
-    here, every expert layer's choices, the count of held experts read or
-    None: :func:`latent_moe`)."""
-    seen = dict.fromkeys(_STACK, 0)
-    held, routes, read = jnp.zeros((), jnp.int32), [], None
+    """x through every layer in the pattern's order. ``mixers["M"]``,
+    ``mixers["S"]`` and ``mixers["*"]`` (an "A" layer's too) are ``(layer's
+    parameters, normed input, index among its stack's layers) -> mixer
+    output`` and keep what else they make (states, K/V rows) for their
+    caller; the expert layer is the same in every step (``real`` is
+    :func:`latent_moe`'s), and so is the dense SwiGLU behind an "S" or "A"
+    mixer. Returns (x, the count of expert choices held here, every expert
+    layer's choices, the count of held experts read or None:
+    :func:`latent_moe`)."""
+    seen = dict.fromkeys(_STACK.values(), 0)
+    held, routes, read, n_ffn = jnp.zeros((), jnp.int32), [], None, 0
     for kind in cfg.layer_pattern:
-        stack, i = params[_STACK[kind]], seen[kind]
-        seen[kind] += 1
+        stack, i = params[_STACK[kind]], seen[_STACK[kind]]
+        seen[_STACK[kind]] += 1
         with scopes.block(_NORM_SCOPE[kind]):   # with what reads it first
             h = rms_norm(x, stack["ln"][i], cfg.norm_eps)
         if kind == "E":
@@ -392,24 +585,45 @@ def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             if n_read is not None:
                 read = n_read if read is None else read + n_read
         else:
-            y = mixers[kind]({n: a[i] for n, a in stack.items()}, h, i)
+            y = mixers["*" if kind == "A" else kind](
+                {n: a[i] for n, a in stack.items()}, h, i)
         x = x + y
+        if kind in _WITH_FFN:
+            lp = {n: a[n_ffn] for n, a in params["ffn"].items()}
+            n_ffn += 1
+            x = x + _ffn(cfg, lp, _ffn_input(cfg, lp, x))
     return x, held, routes, read
 
 
 def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
-    """h [..., D] -> q [..., H, Dh], k and v [..., Hkv, Dh]; no rotation."""
+    """h [..., D] -> q [..., H, Dh], k and v [..., Hkv, Dh]; no rotation. A
+    model's ONE KV head is handed on twice (``cfg.kv_heads_kept``): the pages
+    keep it so, and each copy serves half the query heads."""
     lead, Dh = h.shape[:-1], cfg.head_dim
     with scopes.block("attn.proj"):
-        return ((h @ lp["wq"]).reshape(*lead, cfg.n_heads, Dh),
-                (h @ lp["wk"]).reshape(*lead, cfg.n_kv_heads, Dh),
-                (h @ lp["wv"]).reshape(*lead, cfg.n_kv_heads, Dh))
+        q = (h @ lp["wq"]).reshape(*lead, cfg.n_heads, Dh)
+        k = (h @ lp["wk"]).reshape(*lead, cfg.n_kv_heads, Dh)
+        v = (h @ lp["wv"]).reshape(*lead, cfg.n_kv_heads, Dh)
+        if cfg.kv_heads_kept != cfg.n_kv_heads:
+            k, v = (jnp.repeat(t, cfg.kv_heads_kept // cfg.n_kv_heads,
+                               axis=-2) for t in (k, v))
+        return q, k, v
 
 
 def _out(lp: Params, attn: jnp.ndarray) -> jnp.ndarray:
     """The attention's output [..., H, Dh] through ``wo``."""
     with scopes.block("attn.proj"):
         return attn.reshape(*attn.shape[:-2], -1) @ lp["wo"]
+
+
+def _scan_of(cfg: ModelConfig):
+    """The state-space layers' form for a run of positions."""
+    return ssm1_scan if cfg.ssm_dt_rank else ssm_scan
+
+
+def _step_of(cfg: ModelConfig):
+    """The state-space layers' form for one position a sequence."""
+    return ssm1_step if cfg.ssm_dt_rank else ssm_step
 
 
 def _stacked(rows: list[jnp.ndarray], like: tuple[int, ...], dtype
@@ -449,7 +663,7 @@ def forward(
     ks, vs, ssms, tails = [], [], [], []
 
     def ssm(lp, h, i):
-        out, s, tail = ssm_scan(cfg, lp, h, lens)
+        out, s, tail = _scan_of(cfg)(cfg, lp, h, lens)
         ssms.append(s), tails.append(tail)
         return out
 
@@ -461,17 +675,16 @@ def forward(
         return _out(lp, out)
 
     x, held, routes, _ = _walk(params, cfg, _embedded(params, tokens),
-                               {"M": ssm, "*": attend})
+                               {"M": ssm, "S": ssm, "*": attend})
     with scopes.block("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     kv = None
     if want_kv:
         dt = x.dtype
-        kv_like = (B, S, cfg.n_kv_heads, cfg.head_dim)
+        kv_like = (B, S, cfg.kv_heads_kept, cfg.head_dim)
         kv = (state.Fresh(
             _stacked(ks, kv_like, dt), _stacked(vs, kv_like, dt),
-            _stacked(ssms, (B, cfg.ssm_heads, cfg.ssm_head_dim,
-                            cfg.ssm_state), jnp.float32),
+            _stacked(ssms, (B, *cfg.ssm_row), jnp.float32),
             _stacked(tails, (B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dt),
             held), None)
     with scopes.block("head"):
@@ -508,7 +721,7 @@ def decode_step(
 
     def ssm(lp, h, i):
         nonlocal cache
-        out, cache, tail = ssm_step(cfg, lp, h, cache, i)
+        out, cache, tail = _step_of(cfg)(cfg, lp, h, cache, i)
         tails.append(tail)
         return out
 
@@ -523,7 +736,7 @@ def decode_step(
     # A padding lane's choices are nobody's (asked only by the form that reads
     # the chosen experts).
     x, held, routes, read = _walk(
-        params, cfg, _embedded(params, tokens), {"M": ssm, "*": attend},
+        params, cfg, _embedded(params, tokens), {"M": ssm, "S": ssm, "*": attend},
         real=(pages.lanes_in_use(block_tables)
               if cfg.moe_impl.startswith("chosen") else None))
     cache = _written(cache, ks, vs, None, tails, held, cur_slots, read)
@@ -586,7 +799,7 @@ def prefill_with_prefix(
 
     def ssm(lp, h, i):
         s0, tail0 = state.read(cache, i)
-        out, s, tail = ssm_scan(cfg, lp, h, suffix_len, s0, tail0.reshape(
+        out, s, tail = _scan_of(cfg)(cfg, lp, h, suffix_len, s0, tail0.reshape(
             B, cfg.ssm_conv - 1, cfg.ssm_conv_dim))
         ssms.append(s), tails.append(tail)
         return out
@@ -604,7 +817,7 @@ def prefill_with_prefix(
         return _out(lp, out)
 
     x, held, routes, _ = _walk(params, cfg, _embedded(params, tokens),
-                               {"M": ssm, "*": attend})
+                               {"M": ssm, "S": ssm, "*": attend})
     cache = _written(cache, ks, vs, ssms, tails, held, pages.sequence_slots(
         cache.k, block_table_row, suffix_len, S, prefix_len))
 

@@ -91,6 +91,9 @@ Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--moe-decode-rows 2,8,16,32,64,128]
        python scripts/microbench_decode.py --ssm [--ssm-lanes 64,16,2]
            [--ssm-head-blocks 128,32]
+       python scripts/microbench_decode.py --ssm1 [--ssm-lanes 64,8]
+           [--ssm1-channel-blocks ,1280] [--ssm1-rows 1024,128]
+           [--ssm1-time-blocks 128,256]
        python scripts/microbench_decode.py --latent
            [--latent-config deepseek-v3.2-exp-cut] [--latent-points 32x9700]
            [--latent-groups 4,8,16] [--latent-tables shuffled,churn,runs]
@@ -542,6 +545,129 @@ def ssm_main(args):
                     np.abs(got[0] - want[0]).max() / np.abs(want[0]).max()),
                 "y_vs_gathered": float(
                     np.abs(got[1] - want[1]).max() / np.abs(want[1]).max()),
+                "device": device.device_kind}), flush=True)
+
+
+def ssm1_main(args):
+    """Both kernels of a Mamba-1 layer alone, at ``--ssm1-config``'s widths:
+    the decode step's update of every state layer at ``--ssm-lanes`` lanes
+    (the kernel at each of ``--ssm1-channel-blocks``, and the gathered form)
+    with the share of the chip's peak its needed bytes come to
+    (chipbench/kernels_ssm1.py), and one prompt window's scan of one layer at
+    ``--ssm1-rows`` rows (the kernel at each of
+    ``--ssm1-scan-channel-blocks`` x ``--ssm1-time-blocks``, against
+    ``lax.scan`` over positions)."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_inference_scheduler_tpu.models.convert_hf import config_from_hf
+    from llm_d_inference_scheduler_tpu.ops import pallas_ssm
+
+    kernels = _chipbench_kernels()      # puts chipbench/ on the path
+    import kernels_ssm1
+
+    with open(args.ssm1_config) as f:
+        m = config_from_hf(types.SimpleNamespace(**json.load(f)), "ssm1")
+    L, (N, C) = m.n_state_layers, m.ssm_row
+    device = jax.devices()[0]
+    interpret = args.ssm_interpret
+    peak = (None if interpret
+            else kernels.peaks(device.device_kind)["bytes_per_s"])
+    blocks = [int(b) if b else None
+              for b in args.ssm1_channel_blocks.split(",")]
+
+    def operands(key, lead):
+        k = jax.random.split(key, 6)
+        return (jax.random.uniform(k[0], (*lead, C), jnp.float32, 1e-3, .1),
+                jax.random.normal(k[1], (*lead, C), jnp.float32),
+                jax.random.normal(k[2], (*lead, N), jnp.float32),
+                jax.random.normal(k[3], (*lead, N), jnp.float32),
+                -jax.random.uniform(k[4], (N, C), jnp.float32, 1., 16.),
+                jax.random.normal(k[5], (C,), jnp.float32))
+
+    def relative(got, want):
+        return float(np.abs(np.asarray(got) - np.asarray(want)).max()
+                     / np.abs(np.asarray(want)).max())
+
+    def gathered(ssm, layer, slots, *small):
+        rows, y = pallas_ssm.update1_rows(ssm[layer, slots], *small)
+        return ssm.at[layer, slots].set(rows), y
+
+    def every_layer(update, ssm, slots, *small):
+        ys = []
+        for layer in range(L):
+            ssm, y = update(ssm, jnp.asarray(layer, jnp.int32), slots, *small)
+            ys.append(y)
+        return ssm, jnp.stack(ys)
+
+    for lanes in map(int, args.ssm_lanes.split(",")):
+        slots = jax.random.permutation(jax.random.key(lanes), lanes).astype(
+            jnp.int32)
+        small = operands(jax.random.key(lanes + 1), (lanes,))
+        need = kernels_ssm1.ssm1_state_update(lanes, C, N)
+        forms = {"gathered": gathered}
+        for cb in blocks:
+            forms[f"kernel cb={cb or C}"] = functools.partial(
+                pallas_ssm.update1_in_place, channel_block=cb,
+                interpret=interpret)
+        want = None
+        for name, update in forms.items():
+            fn = jax.jit(functools.partial(every_layer, update),
+                         donate_argnums=(0,))
+            ssm, y = fn(jax.random.normal(jax.random.key(7),
+                                          (L, lanes + 1, N, C), jnp.float32),
+                        slots, *small)
+            got = (np.asarray(ssm[:, slots[:2]]), np.asarray(y))
+            want = want or got
+            t0 = time.perf_counter()
+            for _ in range(args.ssm_iters):
+                ssm, y = fn(ssm, slots, *small)
+            jax.block_until_ready(ssm)
+            call_s = (time.perf_counter() - t0) / args.ssm_iters / L
+            del ssm
+            print(json.dumps({
+                "component": f"ssm1_state_update {name}", "lanes": lanes,
+                "layers": L, "ms_per_call": round(call_s * 1e3, 4),
+                "ms_per_step": round(call_s * L * 1e3, 3),
+                "needed_GBps": round(need["bytes"] / call_s / 1e9, 1),
+                "share_of_peak_pct": (
+                    None if peak is None else
+                    round(100 * need["bytes"] / call_s / peak, 1)),
+                "state_vs_gathered": relative(got[0], want[0]),
+                "y_vs_gathered": relative(got[1], want[1]),
+                "device": device.device_kind}), flush=True)
+
+    for rows in map(int, args.ssm1_rows.split(",")):
+        small = operands(jax.random.key(rows), (1, rows))
+        s0 = jax.random.normal(jax.random.key(rows + 1), (1, N, C), jnp.float32)
+        forms = {"lax.scan": pallas_ssm.scan_rows}
+        for cb in map(int, args.ssm1_scan_channel_blocks.split(",")):
+            for tb in map(int, args.ssm1_time_blocks.split(",")):
+                if rows % tb == 0:
+                    forms[f"kernel cb={cb} tb={tb}"] = functools.partial(
+                        pallas_ssm.selective_scan, channel_block=cb,
+                        time_block=tb, interpret=interpret)
+        want = None
+        for name, scan in forms.items():
+            fn = jax.jit(scan)
+            got = fn(*small, s0)
+            want = want or got
+            jax.block_until_ready(got)
+            t0 = time.perf_counter()
+            for _ in range(args.ssm_iters):
+                out = fn(*small, s0)
+            jax.block_until_ready(out)
+            call_s = (time.perf_counter() - t0) / args.ssm_iters
+            print(json.dumps({
+                "component": f"ssm1_selective_scan {name}", "rows": rows,
+                "ms_per_layer_window": round(call_s * 1e3, 4),
+                "ms_per_window": round(call_s * L * 1e3, 2),
+                "us_per_row": round(call_s / rows * 1e6, 3),
+                "y_vs_scan": relative(got[0], want[0]),
+                "state_vs_scan": relative(got[1], want[1]),
                 "device": device.device_kind}), flush=True)
 
 
@@ -1029,6 +1155,25 @@ def main(argv=None):
     ap.add_argument("--ssm", action="store_true",
                     help="time the state-space layers' decode kernel alone "
                          "instead")
+    ap.add_argument("--ssm1", action="store_true",
+                    help="time a Mamba-1 layer's two kernels alone "
+                         "(ops/pallas_ssm.py: the decode step's update in "
+                         "place and a prompt window's scan); --ssm-lanes, "
+                         "--ssm-iters and --ssm-interpret are shared")
+    ap.add_argument("--ssm1-config",
+                    default=os.path.join(
+                        os.path.dirname(os.path.dirname(
+                            os.path.abspath(__file__))),
+                        "chipbench", "configs", "ai21-jamba2-3b.json"))
+    ap.add_argument("--ssm1-channel-blocks", default="",
+                    help="comma-separated channel blocks of the update to "
+                         "try (an empty one: a lane's whole tile, which is "
+                         "what is served and the default)")
+    ap.add_argument("--ssm1-scan-channel-blocks", default="1024",
+                    help="the scan kernel's (its tile is carried in vector "
+                         "registers; 16 x 5,120 does not fit its VMEM)")
+    ap.add_argument("--ssm1-time-blocks", default="128")
+    ap.add_argument("--ssm1-rows", default="1024,128")
     ap.add_argument("--ssm-model", default="nemotron-3-super-cut")
     ap.add_argument("--ssm-lanes", default="64,16,2")
     ap.add_argument("--ssm-head-blocks", default="",
@@ -1129,6 +1274,8 @@ def main(argv=None):
         return moe_main(args)
     if args.ssm:
         return ssm_main(args)
+    if args.ssm1:
+        return ssm1_main(args)
     if args.latent:
         return latent_main(args)
     if args.window:

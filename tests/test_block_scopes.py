@@ -41,6 +41,7 @@ FAMILIES = [
      {"attn.expand"}),
     ("tiny-longcat", MOE | {"ffn.dense"}, {"attn.expand"}),
     ("tiny-hybrid", MOE | {"ffn.shared", "state.proj", "state.update"}, set()),
+    ("tiny-jamba", {"ffn.dense", "state.proj", "state.update"}, set()),
 ]
 LANES, WINDOW = 4, 512      # decode rows; a prefill window's tokens
 NAMED = ("dot_general", "pallas_call")

@@ -874,3 +874,143 @@ def test_grouped_reglu_experts_compile_at_the_cells_widths(one_chip, tokens):
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 2
     assert "moe_grouped_reglu" in hlo and "moe_grouped_swiglu" not in hlo
+
+
+# ---- Mamba-1 state beside one-KV-head attention (AI21-Jamba2-3B's widths) ----
+
+@pytest.mark.parametrize("lanes,channel_block", [(2, None), (64, None),
+                                                 (64, 1280)])
+def test_ssm1_state_update_compiles_at_16_by_5120(one_chip, lanes,
+                                                  channel_block):
+    """The decode step of a Mamba-1 layer in place in the pool's second
+    layout, 26 layers of 65 slots of [16, 5120] f32: a lane's tile whole, and
+    in channel blocks."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_ssm
+
+    assert pallas_ssm.use_kernel(5120, 16, platform="tpu", sharded=False)
+    f32 = functools.partial(_sds, one_chip, dtype=jnp.float32)
+    pool = (26, 65, 16, 5120)
+    compiled = jax.jit(
+        functools.partial(pallas_ssm.update1_in_place,
+                          channel_block=channel_block),
+        donate_argnums=(0,)
+    ).lower(f32(pool), _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (lanes,), jnp.int32), f32((lanes, 5120)),
+            f32((lanes, 5120)), f32((lanes, 16)), f32((lanes, 16)),
+            f32((16, 5120)), f32((5120,))).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "ssm1_state_update" in hlo
+    # In place: the pool is the call's and the program's, no second one.
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 4 * math.prod(pool[2:]) * 65)
+
+
+@pytest.mark.parametrize("rows", [1024, 128, 16])
+def test_ssm1_selective_scan_compiles_at_16_by_5120(one_chip, rows):
+    """A prompt window's recurrence, the state tile of a channel block
+    resident over the rows: the cell's largest window, one lane tile of rows,
+    and the smallest bucket (one block of rows that is no lane tile)."""
+    from llm_d_inference_scheduler_tpu.ops import pallas_ssm
+
+    f32 = functools.partial(_sds, one_chip, dtype=jnp.float32)
+    compiled = jax.jit(pallas_ssm.selective_scan).lower(
+        f32((1, rows, 5120)), f32((1, rows, 5120)), f32((1, rows, 16)),
+        f32((1, rows, 16)), f32((16, 5120)), f32((5120,)),
+        f32((1, 16, 5120))).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "ssm1_selective_scan" in hlo
+    # dt, x and y a row: nothing the size of (exp(dt A), dt B x), 16 times
+    # that, is made.
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 4 * 4 * rows * 5120 + (1 << 20))
+
+
+def test_the_paged_walk_compiles_at_one_kv_head_and_twenty_query_heads(
+        one_chip):
+    """20 query heads on ONE KV head of 128, 64 lanes under a table 320
+    entries wide over the cell's two attention layers' 20,481 pages. A bf16
+    pool [.., 16, 1, 128] lies padded to two heads in HBM and Mosaic refuses
+    to slice the padded dim, so the pages keep the head twice
+    (``ModelConfig.kv_heads_kept``) and the walk is the two-KV-head program
+    at ten query heads a group, not padded."""
+    from llm_d_inference_scheduler_tpu.models.configs import ModelConfig
+
+    lanes, heads, d = 64, 20, 128
+    kv = ModelConfig(name="j", vocab_size=8, d_model=2560, n_layers=1,
+                     n_heads=20, n_kv_heads=1, d_ff=8,
+                     layer_pattern="A").kv_heads_kept
+    assert kv == 2
+    bf16 = jnp.bfloat16
+    pool = _sds(one_chip, (2, 20481, 16, kv, d), bf16)
+    cur = _sds(one_chip, (lanes, kv, d), bf16)
+    args = (_sds(one_chip, (lanes, heads, d), bf16), pool, pool,
+            _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (lanes, 320), jnp.int32),
+            _sds(one_chip, (lanes,), jnp.int32), cur, cur)
+    compiled = paged_decode_attention_pallas.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "gather" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_jamba_decode_step_compiles_and_copies_no_pool(one_chip):
+    """One decode chunk step at AI21-Jamba2-3B's widths, two Mamba-1 layers
+    and one attention layer with their dense FFNs, 64 lanes on the cell's
+    pools, as a ``lax.scan`` of two steps: both kernels are in the program,
+    and neither the state pool nor the page pool is made anew."""
+    from llm_d_inference_scheduler_tpu.kvcache import state
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
+    from llm_d_inference_scheduler_tpu.models import bind, hybrid
+    from llm_d_inference_scheduler_tpu.models.configs import ModelConfig
+
+    m = bind(ModelConfig(
+        name="jamba-cut", vocab_size=65536, d_model=2560, n_layers=3,
+        n_heads=20, n_kv_heads=1, d_ff=8192, norm_eps=1e-6,
+        layer_pattern="SAS", ssm_state=16, ssm_dt_rank=160, ssm_expand=2),
+        platform="tpu").mcfg
+    assert (m.ssm_impl, m.ssm_scan_impl) == ("kernel", "kernel")
+    batch = 64
+    geom = PageGeometry.for_engine(m, batch, 5120)
+    sgeom = geom.state
+    dt = jnp.dtype(m.dtype)
+    pages = _sds(one_chip, geom.shape, dt)
+    cache = state.Cache(
+        pages, pages, _sds(one_chip, sgeom.ssm_shape, jnp.float32),
+        _sds(one_chip, sgeom.conv_shape, dt),
+        slots=_sds(one_chip, (batch,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: hybrid.init_params(m, k), jax.random.key(0)))
+
+    def chunk(params, tokens, positions, cache, tables):
+        def step(carry, _):
+            tokens, positions, cache = carry
+            logits, cache, _ = hybrid.decode_step(
+                params, m, tokens, positions, cache, None, tables,
+                attention_fn=functools.partial(decode_attention, kernel=True))
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (nxt, positions + 1, cache), nxt
+
+        (_, _, cache), toks = jax.lax.scan(
+            step, (tokens, positions, cache), None, length=2)
+        return toks, cache
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        params, _sds(one_chip, (batch,), jnp.int32),
+        _sds(one_chip, (batch,), jnp.int32), cache,
+        _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
+    ).compile()
+    hlo = compiled.as_text()
+    assert "ssm1_state_update" in hlo and "paged_decode_attention" in hlo
+    for pool, dtype in ((sgeom.ssm_shape, "f32"), (geom.shape, "bf16")):
+        shape = f"{dtype}[" + ",".join(map(str, pool)) + "]"
+        made = [ln.strip()[:160] for ln in hlo.splitlines()
+                if re.search(r"=\s*" + re.escape(shape), ln)
+                and "parameter(" not in ln and "bitcast(" not in ln
+                and "scatter" not in ln and "dynamic-update-slice" not in ln
+                and "fusion(" not in ln and "get-tuple-element(" not in ln]
+        assert not made, made
+    # Under one set of the 64 lanes' rows of one layer.
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < batch * 4 * math.prod(sgeom.ssm_shape[2:]))
