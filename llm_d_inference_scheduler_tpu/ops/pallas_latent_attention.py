@@ -61,8 +61,8 @@ from .attention import (NEG_INF, window_cut, window_pages,  # noqa: F401
                         window_table)
 
 # What a stage's tiles may take of VMEM, here and in the K/V family's walks
-# (ops/pallas_paged_attention.py: K and V, two slots each, in the pool's
-# dtype, and the f32 copies the products read). A quarter of the 16 MiB a
+# (ops/pallas_paged_attention.py: K and V, two slots each, and a stage's
+# rows a KV head apart, in the pool's dtype). A quarter of the 16 MiB a
 # kernel may hold by default; the logits and what the compiler keeps besides
 # are a tenth of the tiles.
 STAGE_VMEM_BYTES = 4 * 1024 * 1024

@@ -29,23 +29,39 @@ a full stage is waited for once a pool.
 The allocators hand out blocks so that the runs exist (engine/blocks.py: a
 table ascending, a window's pages in aligned stretches of ``RUN_PAGES``).
 
-A stage is computed without a loop over the KV heads. The tile is read as
-[P*block*Hkv, D] — row (t, g) is token t's head g, which is how the page lies
-in memory — so one product q[H, D] . tile^T gives every query head against
-every KV head's rows, and a mask keeps for query head h the columns of its
-own KV head (h // q_per_kv) and of positions below the lane's length. One
-max / exp / sum / rescale of the running softmax and one product
-p[H, P*block*Hkv] . tile[P*block*Hkv, D] follow: the MXU is handed the same
-K and V tiles as a per-head loop would hand it, and nothing is re-laid out.
+A stage is computed a KV head apart, in the pool's dtype. The tile lies in
+VMEM as the page lies in the pool, row (token t, KV head g), so head g's rows
+are every Hkv-th of it: strided loads take them out (:func:`_head_rows`; of
+32-bit words, two heads of a 16-bit pool at once) and they are stacked
+[Hkv, P*block, D]. Each head's rows meet its own query heads alone, ONE
+product over the head axis q[Hkv, q_per_kv, D] . k[Hkv, rows, D]^T and one
+p . v, between them one step of every head's running softmax (its state
+[Hkv, q_per_kv, 1 | 1 | D] in f32, the stage loop's carry). Both products go
+to the MXU in the pool's dtype with f32 accumulation; the scale is applied to
+the f32 logits, the mask is the positions alone (the lane's length, a
+window's first row), and the probabilities are rounded to the pool's dtype
+for the second product, as the values they weigh are: the arithmetic
+ops/attention.banded_attention states and the latent walks use. No f32 copy
+of a tile is made. The heads are a batch dimension and not a loop of
+products: a step of one head's softmax is a chain of latencies (product,
+maximum, exponential, sum, product) that nothing of the same head can fill,
+and the compiler interleaves the heads' chains only where it sees them side
+by side (a `fori_loop` over the heads read 63 ns a 4-head page and 251 an
+8-head one where this reads 46 and 113; PERF.md section 6, PR 53).
 The current token's K/V arrives as a separate operand (the engine scatters it
 into the pages after the layer scan — see models/llama.py decode_step) and is
-absorbed the same way, first, while the first stage's copies are in flight.
+what the softmax starts from, a KV head against its own query heads, while
+the first stage's copies are in flight.
 
 P is not a setting: `pages_per_stage` takes it from the shapes the call is
 traced with, as the largest power of two for which the two double-buffered
-tiles and their f32 working copies fit `STAGE_VMEM_BYTES`, and no more than
-the table is wide. K and V stay in the pool's dtype in HBM and VMEM; the
-softmax state, the logits and the probabilities are f32.
+tiles and the computed stage's rows laid out a KV head apart fit
+`STAGE_VMEM_BYTES`, and no more than the table is wide: 32 pages at 4 KV
+heads of 128 in bf16, 16 at 8, 64 at 2. (A lane's first stage is fetched with
+nothing to compute beside it, so a stage twice as long, which the tiles alone
+would fit, read 2-10% slower at every head count: PERF.md section 6, PR 53.)
+K and V stay in the pool's dtype in HBM and VMEM; the softmax state and the
+logits are f32.
 
 The pages arrive as the engine holds them: every layer's pool stacked,
 [L, N, block, Hkv, D], left in HBM, with the layer as a third prefetched
@@ -94,9 +110,10 @@ from .pallas_latent_attention import (RUN_PAGES, STAGE_VMEM_BYTES, run_pages,
 def stage_vmem_bytes(pages: int, block: int, n_kv: int, head_dim: int,
                      itemsize: int) -> int:
     """Bytes of VMEM that stages of `pages` pages hold at once: K and V, two
-    slots each, in the pool's dtype, and the f32 copies the products read."""
+    slots each, and the rows of the stage that is computed laid out a KV
+    head apart, a tile of K and of V, all in the pool's dtype."""
     tile = pages * block * n_kv * head_dim
-    return 2 * 2 * tile * itemsize + 2 * tile * 4
+    return (2 * 2 + 2) * tile * itemsize
 
 
 def pages_per_stage(block: int, n_kv: int, head_dim: int, itemsize: int,
@@ -111,19 +128,23 @@ def pages_per_stage(block: int, n_kv: int, head_dim: int, itemsize: int,
 
 def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
             #                                      [B*maxB/R], [B], [1]
-            q_ref, cur_k_ref, cur_v_ref,  # VMEM blocks per program
+            q_ref,                     # [1, H, D], in the pool's dtype
+            cur_k_ref, cur_v_ref,      # [1, Hkv, D]
             k_hbm, v_hbm,              # stacked page arrays (ANY/HBM)
             out_ref,                   # [1, H, D]
             k_tile, v_tile, sem_k, sem_v,   # [2, P, block, Hkv, D] each pool
-            *, max_blocks: int, pages: int, block: int, group: int, n_kv: int,
-            q_per_kv: int, head_dim: int, skip_ref=None, first_ref=None):
+            *, max_blocks: int, pages: int, block: int, group: int,
+            skip_ref=None, first_ref=None):
     b = pl.program_id(0)
-    H = n_kv * q_per_kv
+    n_kv, head_dim = cur_k_ref.shape[1:]
+    H = q_ref.shape[1]
+    q_per_kv = H // n_kv
     rows = pages * block                              # tokens a stage
-    cols = rows * n_kv                                # (token, kv head) rows
     scale = 1.0 / (head_dim ** 0.5)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
+    # A KV head's query heads apart (nothing is padded: 7 a group as they
+    # are), once a program.
+    q = q_ref[0].reshape(n_kv, q_per_kv, head_dim)
     cached_len = sl_ref[b] - 1                        # rows valid in pages
     n_pages = pl.cdiv(cached_len, block)
     n_stages = pl.cdiv(n_pages, pages)
@@ -141,43 +162,20 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
     def _prologue():
         _start(0, 0)
 
-    # Query head h reads KV head h // q_per_kv: of a tile's rows (t, g),
-    # those with g its own.
-    def _own(n_rows):
-        g_of_row = jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (1, n_rows), 1), n_kv)
-        g_of_head = jax.lax.div(
-            jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0), q_per_kv)
-        return g_of_row == g_of_head                   # [H, n_rows]
+    # The current token's K/V is always visible: every head's softmax starts
+    # from it (its own logit the maximum, weight 1), while the first stage's
+    # copies are in flight.
+    cur_k, cur_v = (ref[0].astype(jnp.float32)[:, None, :]
+                    for ref in (cur_k_ref, cur_v_ref))       # [Hkv, 1, D]
+    carry = (jnp.sum(q.astype(jnp.float32) * cur_k, axis=-1,
+                     keepdims=True) * scale,
+             jnp.ones((n_kv, q_per_kv, 1), jnp.float32),
+             jnp.broadcast_to(cur_v, (n_kv, q_per_kv, head_dim)))
 
-    def _absorb(carry, k, v, valid):
-        """One step of the running softmax over the rows of k / v [n, D]
-        that `valid` [H, n] keeps; every head keeps at least one."""
-        m, l, acc = carry
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [H, n]
-        logits = jnp.where(valid, logits, NEG_INF)
-        new_m = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
-        p = jnp.exp(logits - new_m)
-        corr = jnp.exp(m - new_m)
-        return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
-                acc * corr + jnp.dot(p, v,
-                                     preferred_element_type=jnp.float32))
-
-    # The current token's KV is always visible; absorbed while the first
-    # stage's copies are in flight.
-    carry = _absorb(
-        (jnp.full((H, 1), NEG_INF, jnp.float32),
-         jnp.zeros((H, 1), jnp.float32),
-         jnp.zeros((H, head_dim), jnp.float32)),
-        cur_k_ref[0].astype(jnp.float32), cur_v_ref[0].astype(jnp.float32),
-        _own(n_kv))
-
-    own = _own(cols)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
 
     def stage_body(s, carry):
+        m, l, acc = carry                      # [Hkv, q_per_kv, 1 | 1 | D]
         slot = jax.lax.rem(s, 2)
 
         @pl.when(s + 1 < n_stages)
@@ -185,16 +183,28 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
             _start(s + 1, 1 - slot)
 
         _wait(s, slot)
-        k = k_tile[slot].astype(jnp.float32).reshape(cols, head_dim)
-        v = v_tile[slot].astype(jnp.float32).reshape(cols, head_dim)
-        # Row (t, g) is position s * rows + t.
-        valid = own & (col < (cached_len - s * rows) * n_kv)
+        # The stage's rows a KV head apart, [Hkv, rows, D]: one product over
+        # the head axis, and one step of every head's running softmax.
+        k = jnp.stack(_head_rows(k_tile, slot, n_kv))
+        v = jnp.stack(_head_rows(v_tile, slot, n_kv))
+        logits = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [Hkv, q_per_kv, rows]
+        # Row t of a head's is position s * rows + t.
+        seen = col < cached_len - s * rows
         if skip_ref is not None:   # the rows that lie before the window
-            valid = valid & (col >= (skip_ref[b] - s * rows) * n_kv)
-        return _absorb(carry, k, v, valid)
+            seen = seen & (col >= skip_ref[b] - s * rows)
+        logits = jnp.where(seen, logits, NEG_INF)
+        new_m = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - new_m)
+        corr = jnp.exp(m - new_m)
+        return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32))
 
     _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
-    out_ref[0] = (acc / l).astype(out_ref.dtype)
+    out_ref[0] = (acc / l).reshape(H, head_dim).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -260,11 +270,12 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
     operands: ``tables`` [B, maxB], which of their groups are runs (by
     ``seq_lens``, the lanes' whole lengths), ``lens`` [B] (the lengths the
     walk counts), whatever ``more`` holds, and the layer. ``walk``: the table
-    entries a lane's walk reads at most, which bounds a stage."""
+    entries a lane's walk reads at most, which bounds a stage. The query
+    goes in in the pool's dtype; no operation stands between the caller's
+    arrays and the kernel, or behind it."""
     B, H, D = q.shape
     _, _, block, n_kv, _ = k_pages.shape
     maxB = tables.shape[1]
-    q_per_kv = H // n_kv
     pages = pages_per_stage(block, n_kv, D, k_pages.dtype.itemsize, walk)
     group = run_pages(pages)
     prefetch = (tables.reshape(-1),
@@ -272,8 +283,7 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
                 *more)
 
     kernel = functools.partial(
-        kernel, max_blocks=maxB, pages=pages, block=block, group=group,
-        n_kv=n_kv, q_per_kv=q_per_kv, head_dim=D)
+        kernel, max_blocks=maxB, pages=pages, block=block, group=group)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch) + 1,
@@ -300,7 +310,7 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
         interpret=interpret,
         name=name,      # the op's in a device trace; None: the caller's own
     )(*prefetch, jnp.asarray(layer, jnp.int32).reshape(1),
-      q, cur_k, cur_v, k_pages, v_pages)
+      q.astype(k_pages.dtype), cur_k, cur_v, k_pages, v_pages)
 
 
 # ---- a window of a prompt over the pages before it -----------------------------
@@ -320,8 +330,9 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
 #   SmallThinker's 28 on 4), so a KV head's rows meet the MXU once.
 # - A stage's tile lies in VMEM as the page lies in the pool, row (token, KV
 #   head). One head's rows are every ``Hkv``-th of them: a strided load (of
-#   32-bit words, two heads of a 16-bit pool at once), not a product against
-#   the other heads' rows under a mask as one query a lane can afford.
+#   32-bit words, two heads of a 16-bit pool at once; ``_head_rows``, which
+#   the decode walks take their heads' rows out with too), laid out a KV
+#   head apart in a step's scratch.
 # - The scores stay in VMEM, a running maximum and sum a query row in f32
 #   scratch. Products in the pools' dtype with f32 accumulation, the
 #   probabilities rounded to the pool's dtype for the second product: the
@@ -356,14 +367,16 @@ _NO_BAND = 1 << 30
 
 def _head_rows(tile_ref, slot, n_kv: int):
     """The ``[P, block, Hkv, D]`` tile of ``slot`` as a list of ``[P * block,
-    D]`` arrays, one a KV head, in the tile's dtype."""
+    D]`` arrays, one a KV head, in the tile's dtype: head g's rows are every
+    ``Hkv``-th of the tile as it lies."""
     pages, block, _, head_dim = tile_ref.shape[1:]
     rows = pages * block
     flat = tile_ref.at[slot].reshape(rows * n_kv, head_dim)
     if tile_ref.dtype.itemsize == 4:
         return [flat[pl.ds(g, rows, stride=n_kv), :] for g in range(n_kv)]
     assert tile_ref.dtype == jnp.bfloat16 and n_kv % 2 == 0, (
-        tile_ref.dtype, n_kv)
+        "a 16-bit pool's KV heads are taken out of a page in pairs",
+        tile_ref.dtype, tile_ref.shape)
     # Rows (t, 2j) and (t, 2j + 1) share a 32-bit word, low half first: a
     # bf16 is the high half of the f32 of its value.
     words = flat.bitcast(jnp.uint32)

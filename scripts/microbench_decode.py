@@ -1331,7 +1331,8 @@ def main(argv=None):
 
     for B, ctx in [map(int, pt.split("x")) for pt in args.points.split(",")]:
         max_blocks = args.max_model_len // block
-        L, G, D = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
+        # (A pool keeps a lone KV head twice; the yardstick counts it once.)
+        L, G, D = mcfg.n_layers, mcfg.kv_heads_kept, mcfg.head_dim
         if args.attn_only:
             # The walks alone: two layers' pools do, read in turn CALLS times.
             L, calls = 2, 24
@@ -1428,7 +1429,7 @@ def main(argv=None):
             bt = table_of(kind)
             time_walk("pallas_attn", kernel, plain.paged_decode_attention,
                       kernels.paged_attention_decode(B * ctx, B, mcfg.n_heads,
-                                                     G, D),
+                                                     mcfg.n_kv_heads, D),
                       k_pages, v_pages, bt, seq_lens, kind,
                       (bt, seq_lens, B * n_pages))
 
@@ -1479,7 +1480,7 @@ def main(argv=None):
                 seen = int(np.minimum(np.asarray(lens) - 1, W - 1).sum())
                 time_walk("swa_paged_decode_attention", swa, swa_plain,
                           kernels.paged_attention_decode(
-                              seen, B, mcfg.n_heads, G, D),
+                              seen, B, mcfg.n_heads, mcfg.n_kv_heads, D),
                           kw, vw, wt, lens, kind,
                           (cut[0], cut[1],
                            int((-(-(np.asarray(cut[1]) - 1) // block)).sum())))

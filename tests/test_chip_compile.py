@@ -813,8 +813,16 @@ def test_the_kv_kernels_compile_at_seven_heads_a_group(one_chip, window):
     assert ("swa_paged_decode_attention" in hlo) == bool(window)
     assert f"s32[{lanes * 1024}]" in hlo and f"s32[{lanes * 128}]" in hlo
     assert "gather" not in hlo
-    assert "bf16[2,32,16,4,128]" in str(jax.make_jaxpr(fn)(
+    traced = str(jax.make_jaxpr(fn)(
         *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)))
+    assert "bf16[2,32,16,4,128]" in traced
+    # A stage is computed a KV head apart in the pool's dtype: no f32 array
+    # of a stage's tile, as it lies or as rows, is in the program (a head's
+    # rows pass through f32 words on their way out of a pair), and the
+    # products read the stage's rows stacked by head.
+    assert "f32[32,16,4,128]" not in traced and "f32[2048,128]" not in traced
+    assert "f32[4,512,128]" not in traced
+    assert "f32[512,128]" in traced and "bf16[4,512,128]" in traced
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 

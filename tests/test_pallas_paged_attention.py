@@ -142,9 +142,120 @@ def test_kernel_fetches_runs_of_adjacent_pages_as_one_copy(case, n_kv,
             cur_k, cur_v, interpret=True)), np.asarray(got))
 
 
+# The cells' query heads on their KV heads: SmallThinker, Qwen3-4B and
+# Mixtral, Jamba (its one head kept twice), Nemotron; and four a group on two.
+_CELL_HEADS = [(28, 4), (32, 8), (20, 2), (32, 2), (8, 2)]
+_BF16_STAGE = 4          # pages a stage: 64 rows
+_BF16_WINDOW = 70
+
+
+@pytest.mark.parametrize("window", [0, _BF16_WINDOW], ids=["full", "window"])
+@pytest.mark.parametrize("heads,n_kv", _CELL_HEADS,
+                         ids=[f"{h}on{g}" for h, g in _CELL_HEADS])
+def test_walks_over_bf16_pools_are_banded_attentions_arithmetic(
+        heads, n_kv, window, monkeypatch):
+    """Both walks as a chip runs them: bf16 pools, heads of 128, a KV head's
+    rows against its own query heads, the products in the pool's dtype with
+    f32 accumulation and the probabilities rounded for the second. The
+    reference is ops/attention.banded_attention (whose text states that
+    arithmetic) over the rows ops/attention's own table forms gather, the
+    softmax's sums in another order. Stages of 4 pages under a table of 32:
+    a lane with no cached page, lanes that end inside a first and inside a
+    later stage, one that ends on a stage's edge; of a window of 70 rows,
+    one that starts two rows into the walk's first stage and one whose walk
+    starts 117 rows before it, a whole masked stage and most of the next.
+    Rows past a lane's length and pages nobody owns hold large values."""
+    from llm_d_inference_scheduler_tpu.ops.attention import (banded_attention,
+                                                             window_table)
+    from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
+        swa_paged_decode_attention_kernel)
+
+    block, D, maxB, L, layer = 16, 128, 32, 2, 1
+    monkeypatch.setattr(
+        pallas_paged_attention, "STAGE_VMEM_BYTES",
+        _BF16_STAGE * stage_vmem_bytes(1, block, n_kv, D, 2))
+    lens = [1, 30, 315, 200] if window else [1, 100, 129, 250]
+    B = len(lens)
+    rng = np.random.default_rng([heads, n_kv, window])
+    tables = 1 + rng.permutation(B * maxB).reshape(B, maxB).astype(np.int32)
+    pools = []
+    for _ in range(2):
+        pool = np.full((L, 1 + B * maxB, block, n_kv, D), 1e4, np.float32)
+        for lane, n in enumerate(lens):
+            rows = np.full((maxB * block, n_kv, D), 1e4, np.float32)
+            rows[:n - 1] = rng.standard_normal((n - 1, n_kv, D))
+            pool[layer, tables[lane]] = rows.reshape(maxB, block, n_kv, D)
+        pools.append(jnp.asarray(pool, jnp.bfloat16))
+    q = jnp.asarray(rng.standard_normal((B, heads, D)), jnp.bfloat16)
+    cur_k, cur_v = (jnp.asarray(rng.standard_normal((B, n_kv, D)),
+                                jnp.bfloat16) for _ in range(2))
+    tables, seq_lens = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+
+    # (P is read where the wrapper is traced: no trace of another budget.)
+    jitted, more = ((swa_paged_decode_attention_kernel, dict(window=window))
+                    if window else (paged_decode_attention_pallas, {}))
+    jitted.clear_cache()
+    got = jitted(q, *pools, layer, tables, seq_lens, cur_k, cur_v,
+                 interpret=True, **more)
+    assert got.shape == q.shape and got.dtype == q.dtype
+
+    # The rows a lane's table names (a window's: those in reach), then its
+    # own; which of them it sees.
+    if window:
+        at, upto, skip = window_table(tables, seq_lens, block, window)
+        assert np.asarray(skip).tolist() == [0, 0, 5, 2]
+    else:
+        at, upto, skip = tables, seq_lens, jnp.zeros((B,), jnp.int32)
+    col = jnp.arange(at.shape[1] * block)[None, :]
+    seen = jnp.concatenate(
+        [(col >= skip[:, None]) & (col < (upto - 1)[:, None]),
+         jnp.ones((B, 1), bool)], axis=1)
+    k, v = (jnp.concatenate(
+        [pool[layer, at].reshape(B, -1, n_kv, D), cur[:, None]], axis=1)
+        for pool, cur in zip(pools, (cur_k, cur_v)))
+    nowhere = jnp.zeros(seen.shape, jnp.int32)
+    want = banded_attention(q[:, None], k, v, q_positions=nowhere[:, :1],
+                            kv_positions=nowhere, kv_valid=seen)[:, 0]
+    want = np.asarray(want, np.float32)
+    assert np.abs(want).max() < 10
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=2e-2, atol=2e-2)
+    # The lane with no cached page sees its own token alone.
+    np.testing.assert_array_equal(
+        np.asarray(got[0], np.float32),
+        np.repeat(np.asarray(cur_v[0], np.float32), heads // n_kv, axis=0))
+    jitted.clear_cache()
+
+
+def test_a_bf16_pool_of_one_kv_head_is_refused_where_the_kernel_is_traced():
+    """A 16-bit pool's heads leave a page two at a time (a 32-bit word holds
+    a row of each): an odd number of them has no such pairs, and the trace
+    says so with the tile's shape. (models/hybrid.py keeps a lone head
+    twice, ``ModelConfig.kv_heads_kept``; an f32 pool takes any number.)"""
+    B, H, D, block, maxB = 2, 4, 128, 16, 2
+    q = jnp.ones((B, H, D), jnp.bfloat16)
+    tables = jnp.arange(1, 1 + B * maxB, dtype=jnp.int32).reshape(B, maxB)
+    lens = jnp.array([20, 3], jnp.int32)
+
+    def walk(n_kv, dtype):
+        pool = jnp.ones((1, 1 + B * maxB, block, n_kv, D), dtype)
+        cur = jnp.ones((B, n_kv, D), dtype)
+        return paged_decode_attention_pallas(q, pool, pool, 0, tables, lens,
+                                             cur, cur, interpret=True)
+
+    with pytest.raises(AssertionError, match=r"pairs.*\(2, 2, 16, 1, 128\)"):
+        walk(1, jnp.bfloat16)
+    np.testing.assert_allclose(np.asarray(walk(1, jnp.float32), np.float32),
+                               1.0, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(walk(2, jnp.bfloat16), np.float32),
+                               1.0, rtol=1e-2)
+
+
 def test_staged_cases_cross_a_stage_and_the_narrow_one_is_clamped():
     """What the cases above lean on: at their shapes a stage is 8 pages of a
-    24-page table, and 2 pages of a 3-page one."""
+    24-page table, and 2 pages of a 3-page one. (P is what it was while an
+    f32 copy of each tile was made and counted: an f32 pool's rows laid out
+    a KV head apart take the bytes those copies took.)"""
     assert _stage_tokens(_STAGED) == 8 * 16
     assert _stage_tokens(_NARROW) == 2 * 16
     assert _stage_tokens(_SMALL) == 4 * 16
@@ -156,7 +267,12 @@ def test_pages_per_stage_at_the_cells_shapes(m):
     """Both cells serve --max-model-len 2048: bf16 pages of 16 tokens, 8 KV
     heads of 128, a table 128 wide. P is what the kernel's gain rests on
     (one page a step was 7-9% of the roofline), and its tiles must fit the
-    budget stated beside it, well inside the 16 MiB a kernel may hold."""
+    budget stated beside it, well inside the 16 MiB a kernel may hold. P is
+    16 here, 32 at SmallThinker's 4 KV heads and 64 at the 2 of Nemotron's
+    and Jamba's pools, as before the kernel stopped making an f32 copy of
+    each tile: what a stage holds besides its tiles is now its rows a KV
+    head apart in the pool's dtype, and a stage twice as long read slower
+    on the chip at every head count (PERF.md section 6, PR 53)."""
     itemsize = jnp.dtype(m.dtype).itemsize
     table_width = 2048 // m.kv_block_size
     assert (m.kv_block_size, m.n_kv_heads, m.head_dim, itemsize) == (16, 8, 128, 2)
@@ -164,11 +280,14 @@ def test_pages_per_stage_at_the_cells_shapes(m):
                             itemsize, table_width)
     assert pages == 16
     tile = pages * m.kv_block_size * m.n_kv_heads * m.head_dim
-    # K and V, two slots each, as stored; and the f32 copy of each.
-    held = 4 * tile * itemsize + 2 * tile * 4
+    # K and V, two slots each, and the computed stage's rows of each a KV
+    # head apart, all as stored: nothing of a tile's size is f32.
+    held = 6 * tile * itemsize
     assert held == stage_vmem_bytes(pages, m.kv_block_size, m.n_kv_heads,
                                     m.head_dim, itemsize)
     assert held <= STAGE_VMEM_BYTES <= 16 * 1024 * 1024 // 2
+    assert [pages_per_stage(16, n_kv, 128, 2, 1024) for n_kv in (4, 2)] == [
+        32, 64]
     # A stage twice as long would not fit; a narrower table clamps it.
     assert stage_vmem_bytes(2 * pages, m.kv_block_size, m.n_kv_heads,
                             m.head_dim, itemsize) > STAGE_VMEM_BYTES
