@@ -273,11 +273,12 @@ class EngineTelemetry:
             self.swa_rows.labels(kind)
         self.ssm_tokens = Counter(
             "jetstream:ssm_tokens_total",
-            "Rows (padded tokens) dispatched through the state-space layers, "
-            "by the form their program traced to (models/hybrid.py: a decode "
-            "step is the one-step recurrence `step`, a prefill or a "
-            "continuation window the chunked `scan`); counted on the host at "
-            "dispatch, empty for a model without state layers",
+            "Rows (padded tokens) dispatched through the layers that keep a "
+            "slot row (state-space layers, gated short convolutions), by the "
+            "form their program traced to (models/hybrid.py: a decode step is "
+            "the one-row `step`, a prefill or a continuation window the "
+            "`scan` over a run of rows); counted on the host at dispatch, "
+            "empty for a model without state layers",
             ("form",), registry=self.registry)
         self.ssm_state_updates = Counter(
             "jetstream:ssm_state_updates_total",
@@ -285,12 +286,14 @@ class EngineTelemetry:
             "padding among them, times the state layers), by how the step "
             "fetches them: `kernel` in place in the state pool "
             "(ops/pallas_ssm.py), `gathered` by slot in XLA; counted on the "
-            "host at dispatch by the rule the program traced with",
+            "host at dispatch by the rule the program traced with; empty "
+            "where the layers keep a convolution's tail and no state",
             ("form",), registry=self.registry)
         self.ssm_slot_prefills = Counter(
             "jetstream:ssm_slot_prefills_total",
-            "Slots whose recurrent state a first prefill window started "
-            "afresh (kvcache/state.py), warm-up programs not counted",
+            "Slots whose rows of the state pool (recurrent state and tail, or "
+            "the tail alone) a first prefill window started afresh "
+            "(kvcache/state.py), warm-up programs not counted",
             registry=self.registry)
         moe_routed_pairs = Counter(
             "jetstream:moe_routed_pairs_total",

@@ -6,6 +6,22 @@ a token's KV heads side by side. Block 0 is the trash block: no sequence
 owns it, and every padded or masked-off write is pointed at it so that the
 scatters keep static shapes with no branch.
 
+**Heads narrower than a lane.** A bf16 array's minor dim is stored in tiles
+of 128 lanes: in the row-major layout a kernel's operand has, a pool ``[..,
+block, 8, 64]`` lies padded to ``[.., 8, 128]`` in HBM whatever is written
+there (twice the model's bytes: tests/test_chip_compile.py; left to itself
+the compiler lays such a pool out with the BLOCKS minor, which no kernel
+can read), and the decode kernel's page copies want whole lanes. Where the
+model says so
+(``ModelConfig.kv_heads_a_row`` > 1: models/hybrid.py's lfm2_moe family, 8 KV
+heads of 64) a page keeps that many ADJACENT KV heads side by side as one row
+of 128: ``[.., block, n_kv_heads / 2, 128]``, which is the same bytes in the
+same order as ``[.., block, n_kv_heads, 64]`` -- so every write and read here
+takes and gives the model's own ``[.., Hkv, D]`` rows and reshapes, and
+``kv_token_bytes`` is the model's (2,048 at 8 x 64 in bf16). The decode walk
+over such pages is the kernel of half as many heads twice as wide
+(:func:`decode_attention`).
+
 The pools stay stacked and are read at (layer, page) (PERF.md, PR 26: a pool
 scanned over reaches the Pallas kernel as one layer's slice, which XLA copies
 out first). The page is 16 tokens and the kernel picks its own stage from the
@@ -227,16 +243,19 @@ class PageGeometry:
                   dtype: str | None = None) -> "PageGeometry":
         """A pool of ``n_blocks`` pages at ``model``'s widths (anything with
         n_layers, kv_block_size, n_kv_heads (``kv_heads_kept`` where it
-        keeps a head more than once), head_dim and dtype; a
+        keeps a head more than once; ``kv_heads_a_row`` where a page row
+        holds several), head_dim and dtype; a
         ``latent_dim`` above 0 asks for the latent kind; ``n_kv_layers``
         where not every layer keeps pages; ``tallies_choices`` and
         ``n_zero_experts`` where its programs count). Never fewer than two
         blocks: the trash block and one to use."""
         n_blocks = max(n_blocks, 2)
+        # KV heads narrower than a lane lie side by side, a row of 128.
+        side = getattr(model, "kv_heads_a_row", 1)
         return cls(getattr(model, "n_kv_layers", model.n_layers), n_blocks,
                    model.kv_block_size,
-                   getattr(model, "kv_heads_kept", model.n_kv_heads),
-                   model.head_dim,
+                   getattr(model, "kv_heads_kept", model.n_kv_heads) // side,
+                   model.head_dim * side,
                    str(jnp.dtype(dtype or model.dtype)),
                    max_blocks_per_seq or n_blocks - 1,
                    getattr(model, "latent_dim", 0),
@@ -486,13 +505,15 @@ def write(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
     [L, ..., Hkv, D] with ``blocks`` / ``slots`` [...] from
     :func:`token_slots` or :func:`sequence_slots` — every layer's rows in one
     scatter a pool, so donated pools are updated in place. A latent pool
-    (``v_pages`` None) takes its rows as ``k_new`` [L, ..., latent_dim]."""
+    (``v_pages`` None) takes its rows as ``k_new`` [L, ..., latent_dim]. A
+    token's heads go in as the page's rows hold them (several narrow heads
+    side by side: the same values in the same order)."""
     blocks, slots = blocks.reshape(-1), slots.reshape(-1)
     if v_pages is None:
         return _write_latent_rows(k_pages, k_new, blocks, slots), None
 
     def rows(new, pool):
-        return new.reshape(new.shape[0], -1, *new.shape[-2:]).astype(
+        return new.reshape(new.shape[0], -1, *pool.shape[-2:]).astype(
             pool.dtype)
 
     k_rows, v_rows = rows(k_new, k_pages), rows(v_new, v_pages)
@@ -590,10 +611,12 @@ def use_kernel(head_dim: int, *, asked: bool | None, interpret: bool,
                platform: str, sharded: bool) -> bool:
     """Whether decode attention runs the Pallas kernel or the XLA gather.
     ``head_dim`` is a page's minor dim (``PageGeometry.shape[-1]``: a latent
-    row is stored lane-aligned, so its kernel always can).
+    row is stored lane-aligned, and so is a K/V page whose rows hold several
+    narrow heads side by side, ``ModelConfig.kv_heads_a_row``: their kernels
+    always can; a K/V page of one narrow head a row cannot).
     Left open (``asked`` None), the kernel runs where it compiles and wins: a
-    real TPU, single-device pages, a lane-aligned head_dim. Asked for by name
-    and impossible is an error, not a quiet switch to the other path."""
+    real TPU, single-device pages, a lane-aligned minor dim. Asked for by
+    name and impossible is an error, not a quiet switch to the other path."""
     if asked is None:
         return platform == "tpu" and not sharded and head_dim % LANES == 0
     if asked and not interpret and head_dim % LANES != 0:
@@ -621,11 +644,40 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     stacked pools; the token's own K/V (not in the pages yet) are ``cur_k`` /
     ``cur_v`` [B, Hkv, D]. ``seq_lens`` counts the current token. Returns
     [B, H, D]. ``kernel`` / ``interpret`` are bound by whoever decided
-    (:func:`use_kernel`); the default is the XLA gather."""
+    (:func:`use_kernel`); the default is the XLA gather.
+
+    Where a page row holds ``side`` narrow heads side by side (the pool's
+    minor dim ``side`` x D), the kernel walks it as Hkv / side heads of
+    ``side`` x D: a query row goes in zero outside the lanes of its own KV
+    head, so its products with the row's other heads' keys add nothing, the
+    scale stays 1 / sqrt(D), and the kernel takes each row's own D lanes of
+    the ``side`` x D it accumulated and hands them back ``side`` query heads
+    a row of whole lanes, ``[B, H / side, side x D]``: the same values in
+    the same order as ``[B, H, D]``, which the reshape below and the
+    caller's own to ``[B, H x D]`` undo with no operation (a ``[B, H, 64]``
+    result would be re-laid out by a copy behind the kernel). The products
+    multiply by ``side`` (the walk is bound by the page copies) and the
+    bytes do not. The plain form reads the same pool as the ``[.., Hkv, D]``
+    it is."""
+    side = k_pages.shape[-1] // q.shape[-1]
     if kernel:
-        return paged_decode_attention_pallas(
+        if side > 1:
+            B, H, D = q.shape
+            # Query head h belongs to KV head h // (H / Hkv), which lies at
+            # lanes (that % side) x D of its page row.
+            own = (jnp.arange(H) // (H // cur_k.shape[1])) % side
+            q = jnp.where(
+                (own[:, None] == jnp.arange(side))[None, :, :, None],
+                q[:, :, None, :], 0).reshape(B, H, side * D)
+            cur_k, cur_v = (t.reshape(B, -1, side * D) for t in (cur_k, cur_v))
+        out = paged_decode_attention_pallas(
             q, k_pages, v_pages, layer, block_tables, seq_lens, cur_k, cur_v,
-            interpret=interpret)
+            side=side, interpret=interpret)
+        return (out if side == 1 else
+                out.reshape(out.shape[0], -1, out.shape[-1] // side))
+    if side > 1:
+        k_pages, v_pages = (p.reshape(*p.shape[:3], -1, q.shape[-1])
+                            for p in (k_pages, v_pages))
     return paged_decode_attention(q, k_pages, v_pages, layer, block_tables,
                                   seq_lens, cur_k=cur_k, cur_v=cur_v)
 
@@ -737,16 +789,19 @@ def read_rows(pool: jax.Array, layer: jax.Array,
 
 
 def read_prefix(k_layer: jax.Array, v_layer: jax.Array,
-                table_row: jax.Array, layer: int | None = None
+                table_row: jax.Array, layer: int | None = None,
+                heads: tuple[int, int] | None = None
                 ) -> tuple[jax.Array, jax.Array]:
     """A sequence's cached KV out of ONE layer's pool [N, block, Hkv, D], as
     a scan over the stacked pools hands it to its body: the blocks of
     ``table_row`` [1, W] in order, as [1, W * block, Hkv, D] each. With
     ``layer`` the pools are the stacked ones and that layer of them is read
-    (a module that walks its layers in Python: models/hybrid.py)."""
+    (a module that walks its layers in Python: models/hybrid.py). ``heads``:
+    the model's own (Hkv, D) where a page row holds several heads side by
+    side (None: the pool's)."""
     def gather(pool):
         rows = pool[table_row] if layer is None else pool[layer, table_row]
-        return rows.reshape(1, -1, *pool.shape[-2:])
+        return rows.reshape(1, -1, *(heads or pool.shape[-2:]))
 
     return gather(k_layer), gather(v_layer)
 

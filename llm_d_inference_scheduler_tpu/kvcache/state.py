@@ -29,6 +29,19 @@ the SECOND LAYOUT, under the same slots, owner and functions:
 a layer goes through :func:`recur1`, whose kernel takes the exponential of
 ``dt A`` itself from the layer's own ``A`` tile.
 
+A gated short convolution (models/hybrid.py's "C" layers, the lfm2_moe
+family) keeps the THIRD KIND of slot row: the tail and nothing else.
+
+- ``ssm``  None: there is no recurrent state (``StateGeometry.state`` 0, an
+  empty ``row_shape``), no :func:`recur` / :func:`recur1` call and no kernel
+  of ops/pallas_ssm.py;
+- ``conv`` ``[state layers, slots + 1, (conv - 1) * d_model]`` in the model's
+  dtype: the last two rows of ``B * u`` at LFM2-8B-A1B's convolution of 3,
+  8,192 B a slot a layer.
+
+The slots, the owner and :func:`start` / :func:`read` / :func:`write` are the
+same; where they take or give a state, such a pool takes and gives None.
+
 Who writes a slot's rows. A request's first prefill window writes them whole
 (it starts from zeros and never reads them: :func:`start`); a later window
 reads them (:func:`read`) and writes every layer's back at its end
@@ -117,6 +130,11 @@ class StateGeometry:
         the ssm_* widths); None for a model that keeps pages alone."""
         if not getattr(model, "n_state_layers", 0):
             return None
+        if not model.ssm_row:
+            # A tail and nothing else (a gated short convolution).
+            return cls(model.n_state_layers, max_batch, 0, 0, 0,
+                       model.ssm_conv - 1, model.ssm_conv_dim,
+                       str(jnp.dtype(model.dtype)))
         if getattr(model, "ssm_dt_rank", 0):
             return cls(model.n_state_layers, max_batch, 0, 0, model.ssm_state,
                        model.ssm_conv - 1, model.ssm_conv_dim,
@@ -127,13 +145,19 @@ class StateGeometry:
 
     @property
     def row_shape(self) -> tuple[int, ...]:
-        """One sequence's state in one layer."""
+        """One sequence's recurrent state in one layer; empty where a layer
+        keeps its convolution's tail alone."""
+        if not self.state:
+            return ()
         if self.inner:
             return (self.state, self.inner)
         return (self.heads, self.head_dim, self.state)
 
     @property
-    def ssm_shape(self) -> tuple[int, ...]:
+    def ssm_shape(self) -> tuple[int, ...] | None:
+        """The recurrent states' pool; None where there is none."""
+        if not self.row_shape:
+            return None
         return (self.n_layers, self.n_slots + 1, *self.row_shape)
 
     @property
@@ -144,7 +168,7 @@ class StateGeometry:
     @property
     def slot_bytes(self) -> int:
         """Bytes one sequence keeps, every state layer."""
-        ssm = int(np.prod(self.row_shape)) * 4
+        ssm = int(np.prod(self.row_shape)) * 4 if self.row_shape else 0
         tail = self.tail_rows * self.channels * jnp.dtype(self.dtype).itemsize
         return self.n_layers * (ssm + tail)
 
@@ -160,8 +184,8 @@ class Cache:
 
     k: jax.Array                    # the K/V page pools (kvcache/pages.py),
     v: jax.Array | None             # or a latent pool and None
-    ssm: jax.Array | None           # None: a model without state layers
-    conv: jax.Array | None
+    ssm: jax.Array | None           # None: no layer keeps a recurrent state
+    conv: jax.Array | None          # None: a model without state layers
     slots: jax.Array | None = None  # [B] int32: the rows of this step
     held: jax.Array | None = None   # int32 scalar: choices held, this step
     zero: jax.Array | None = None   # the same of zero-compute choices,
@@ -189,8 +213,9 @@ class Cache:
 class Fresh:
     """What a first prefill window hands to ``pages.write_sequences``: its
     K/V rows [attention layers, B, S, Hkv, D], and for every sequence the
-    state [state layers, B, heads, head_dim, state] and the tail
-    [state layers, B, tail rows, channels] its true last token left."""
+    state [state layers, B, heads, head_dim, state] (None where the layers
+    keep a tail alone) and the tail [state layers, B, tail rows, channels]
+    its true last token left."""
 
     k: jax.Array
     v: jax.Array | None
@@ -216,6 +241,7 @@ def alloc(geom: StateGeometry | None, k_pages: jax.Array,
         return Cache(k_pages, v_pages, None, None, counts_zero=counts_zero,
                      idx=idx, win=win, win_v=win_v, counted=counted)
     return Cache(k_pages, v_pages,
+                 None if geom.ssm_shape is None else
                  jnp.zeros(geom.ssm_shape, jnp.float32, device=device),
                  jnp.zeros(geom.conv_shape, jnp.dtype(geom.dtype),
                            device=device), counts_zero=counts_zero)
@@ -268,9 +294,11 @@ def counted(cache: Cache, held: jax.Array, zero: jax.Array | None = None,
 
 def read(cache: Cache, layer: int) -> tuple[jax.Array, jax.Array]:
     """State layer ``layer`` of this step's rows: (state [B, heads, head_dim,
-    state] f32 -- [B, state, inner] in the second layout --, tail [B, tail
-    rows * channels], the rows flat as stored)."""
-    return cache.ssm[layer, cache.slots], tail(cache, layer)
+    state] f32 -- [B, state, inner] in the second layout, None where the pool
+    keeps tails alone --, tail [B, tail rows * channels], the rows flat as
+    stored)."""
+    return (None if cache.ssm is None else cache.ssm[layer, cache.slots],
+            tail(cache, layer))
 
 
 def tail(cache: Cache, layer: int) -> jax.Array:
@@ -324,8 +352,9 @@ def write(cache: Cache, ssm: list[jax.Array] | None, conv: list[jax.Array]
     """Every state layer's new rows, in layer order (``ssm[l]`` [B, heads,
     head_dim, state], ``conv[l]`` [B, tail rows, channels]), into this step's
     slots: one scatter a pool. ``ssm`` None: the states were updated a layer
-    (:func:`recur`), the tails alone are written. Padding rows all name
-    nobody's slot, and which of them lands there is nobody's concern."""
+    (:func:`recur`), or there are none, and the tails alone are written.
+    Padding rows all name nobody's slot, and which of them lands there is
+    nobody's concern."""
     B = cache.slots.shape[0]
     new_conv = jnp.stack(conv).reshape(len(conv), B, -1).astype(
         cache.conv.dtype)
@@ -345,6 +374,7 @@ def start(cache: Cache, fresh: Fresh, k_pages: jax.Array,
     ``fresh``."""
     cache = counted(dataclasses.replace(cache, k=k_pages, v=v_pages),
                     fresh.held, fresh.zero)
-    if cache.ssm is None:
+    if cache.conv is None:
         return cache
-    return write(cache, list(fresh.ssm), list(fresh.conv))
+    return write(cache, None if fresh.ssm is None else list(fresh.ssm),
+                 list(fresh.conv))

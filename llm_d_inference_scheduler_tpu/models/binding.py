@@ -166,10 +166,12 @@ class Bound:
                        window_counts(*queries, m.window).items()]
         if m.n_state_layers:
             # models/hybrid.py: one position a sequence is the step form, a
-            # run of them the scan form; a first window starts its slots.
+            # run of them the scan form (a convolution's run form among
+            # them); a first window starts its slots.
             counts.append(("ssm_tokens", "step" if decode else "scan",
                            tokens))
-            if decode:
+            if decode and m.n_recurrent_layers:
+                # (Layers that keep a tail alone update no recurrent state.)
                 counts.append(("ssm_state_updates", m.ssm_impl.split("_")[0],
                                tokens * m.n_state_layers))
             if kind == "prefill" and real:
@@ -205,12 +207,16 @@ class Bound:
             "experts_chosen_max_rows": (
                 pallas_moe.CHOSEN_MAX_ROWS_PER_EXPERT
                 if self.moe_chosen(1) else None),
-            # How a decode step fetches its slots' recurrent states.
-            "state_update": m.ssm_impl if m.n_state_layers else None,
+            # How a decode step fetches its slots' recurrent states (None:
+            # no layer keeps one).
+            "state_update": m.ssm_impl if m.n_recurrent_layers else None,
             # How a prompt window runs a recurrence that has no matrix form,
-            # and the layers of each kind (keys only such a model has).
-            **({"state_scan": m.ssm_scan_impl,
-                "state_layers": m.n_state_layers} if m.ssm_dt_rank else {}),
+            # and the layers that keep a slot row -- such a state, or a
+            # convolution's tail and nothing else (keys only such models
+            # have).
+            **({"state_scan": m.ssm_scan_impl} if m.ssm_dt_rank else {}),
+            **({"state_layers": m.n_state_layers}
+               if m.ssm_dt_rank or m.conv_mixers else {}),
             # The form of the layers that attend to a window of the context
             # (a key only a model with such layers has); in the K/V family
             # also that of a continuation window's attention, both kinds of
@@ -245,7 +251,7 @@ def bind(mcfg: ModelConfig, *, platform: str, interpret: bool = False,
     chosen per program (``Bound.model_for``). ``forced`` names form fields a
     caller sets over the rules (a comparison of two forms on one device)."""
     forms: dict[str, str] = {}
-    if mcfg.n_state_layers:
+    if mcfg.n_recurrent_layers:
         # A slot's tile, [sublanes, lanes]: a head's [head_dim, state], or a
         # Mamba-1 layer's [state, inner].
         kernel = pallas_ssm.use_kernel(

@@ -87,7 +87,13 @@ class ModelConfig:
     # The jamba family's layers are two more letters of the same block: "S" a
     # Mamba-1 state-space mixer and "A" attention (the same, no rotary
     # embedding), EACH followed by a dense SwiGLU of d_ff under a residual of
-    # its own.
+    # its own. The lfm2_moe family's are two more: "C" a gated short
+    # convolution (``ssm_conv`` wide over d_model channels; it keeps the
+    # convolution's tail and NO recurrent state) and "Q" attention with an
+    # RMSNorm a head on q and k and rotary embedding (``qk_norm``,
+    # ``rope_theta``), each followed by an FFN under a residual of its own:
+    # the dense SwiGLU of d_ff in the first ``first_k_dense`` layers, else
+    # n_experts routed SwiGLU experts of moe_d_ff (models/routing.py chooses).
     layer_pattern: str = ""
     # "M": ssm_heads heads of ssm_head_dim, a state of ssm_state values a
     # head-channel, B and C shared by the heads of one of ssm_groups groups,
@@ -227,15 +233,30 @@ class ModelConfig:
         return self.window_attn.window if self.window_attn else self.kv_window
 
     @property
+    def conv_mixers(self) -> bool:
+        """Whether the mixers that keep a slot row are gated short
+        convolutions ("C"): a tail and no recurrent state."""
+        return self.mixer_pattern and "C" in self.layer_pattern
+
+    @property
     def n_state_layers(self) -> int:
-        """Layers that keep recurrent state a sequence (0: pages alone)."""
-        return self.layer_pattern.count("M") + self.layer_pattern.count("S")
+        """Layers that keep a row of the state pool a sequence (a recurrent
+        state and a convolution's tail, or the tail alone; 0: pages alone)."""
+        if not self.mixer_pattern:
+            return 0
+        return sum(self.layer_pattern.count(c) for c in "MSC")
+
+    @property
+    def n_recurrent_layers(self) -> int:
+        """Those of them whose row holds a recurrent state (0: the rows, if
+        any, are tails alone)."""
+        return 0 if self.conv_mixers else self.n_state_layers
 
     @property
     def n_kv_layers(self) -> int:
         """Layers that keep pages of keys and values (cache layers)."""
         if self.mixer_pattern:
-            return self.layer_pattern.count("*") + self.layer_pattern.count("A")
+            return sum(self.layer_pattern.count(c) for c in "*AQ")
         return (self.layer_pattern.count("*") if self.layer_pattern
                 else self.n_layers * self.attn_sublayers)
 
@@ -252,6 +273,22 @@ class ModelConfig:
         return self.n_kv_heads
 
     @property
+    def kv_heads_a_row(self) -> int:
+        """Adjacent KV heads a page keeps side by side as ONE row of 128
+        lanes (1: a head a row, every model with heads of 128 or more).
+        models/hybrid.py keeps heads narrower than a lane so (two heads of
+        64: ``[.., block, Hkv / 2, 128]``): a bf16 pool whose minor dim is 64
+        lies padded to 128 lanes in HBM whatever is written there, twice the
+        model's bytes, and the paged decode kernel's page copies want whole
+        lanes. The bytes are the model's and the walk is the program of half
+        as many heads twice as wide (kvcache/pages.decode_attention)."""
+        per = 128 // self.head_dim if 0 < self.head_dim < 128 else 1
+        if (self.mixer_pattern and per > 1 and 128 % self.head_dim == 0
+                and self.kv_heads_kept % per == 0):
+            return per
+        return 1
+
+    @property
     def n_window_layers(self) -> int:
         """Cache layers that keep a window of the context alone (0: none)."""
         return self.layer_pattern.count("W") if self.window else 0
@@ -266,7 +303,7 @@ class ModelConfig:
         """Layers with a router (0: a dense model)."""
         if not self.n_experts:
             return 0
-        if self.mixer_pattern:
+        if self.mixer_pattern and not self.conv_mixers:
             return self.layer_pattern.count("E")
         return self.n_layers - self.first_k_dense
 
@@ -304,12 +341,16 @@ class ModelConfig:
         two layouts)."""
         if self.ssm_dt_rank:
             return (self.ssm_state, self.ssm_inner)
+        if self.conv_mixers:
+            return ()       # a tail and nothing else
         return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
 
     @property
     def ssm_conv_dim(self) -> int:
         """Channels the convolution runs over: x, then B and C a group
-        (Mamba-1: x alone)."""
+        (Mamba-1: x alone; a gated short convolution: the model's width)."""
+        if self.conv_mixers:
+            return self.d_model
         if self.ssm_dt_rank:
             return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
@@ -696,6 +737,31 @@ TINY_JAMBA = ModelConfig(
     ssm_expand=2,
 )
 
+# The lfm2_moe family at small widths (CI tests): two periods of two gated
+# short convolutions and an attention layer, 8 query heads on 4 KV heads of
+# 64 (two page rows of 128, two heads each: ``kv_heads_a_row``), a
+# convolution 3 wide, the first layer's FFN dense and the others' 8 routed
+# experts of 128 (a width the grouped kernel tiles), 3 a token.
+TINY_LFM2 = ModelConfig(
+    name="tiny-lfm2",
+    vocab_size=512,
+    d_model=128,
+    n_layers=6,
+    n_heads=8,
+    n_kv_heads=4,
+    d_ff=96,
+    max_seq_len=256,
+    rope_theta=10_000.0,
+    head_dim_override=64,
+    qk_norm=True,
+    n_experts=8,
+    experts_per_token=3,
+    first_k_dense=1,
+    moe_d_ff=128,
+    layer_pattern="CCQCCQ",
+    ssm_conv=3,
+)
+
 _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY, MIXTRAL_8X7B, TINY_MOE,
                                  QWEN3_32B, QWEN3_4B, TINY_QWEN,
@@ -703,7 +769,7 @@ _REGISTRY = {c.name: c for c in (LLAMA3_8B, LLAMA3_70B, LLAMA3_1B, LLAMA3_3B,
                                  TINY_SWA, TINY_SWA_KV,
                                  NEMOTRON_3_SUPER,
                                  NEMOTRON_3_SUPER_CUT, TINY_HYBRID,
-                                 TINY_JAMBA)}
+                                 TINY_JAMBA, TINY_LFM2)}
 
 
 def get_config(name: str) -> ModelConfig:

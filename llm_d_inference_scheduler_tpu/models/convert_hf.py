@@ -421,6 +421,67 @@ def _jamba_config_from_hf(hf, name: str) -> ModelConfig:
     )
 
 
+# What models/hybrid.py computes of the lfm2_moe family's options.
+_LFM2_ONLY = {"conv_bias": False, "norm_topk_prob": True,
+              "use_expert_bias": True, "tie_word_embeddings": True}
+
+# The letters of an lfm2_moe file's ``layer_types``: a gated short
+# convolution, an attention mixer that norms and rotates q and k, each with an
+# FFN behind it.
+LFM2_LAYERS = {"conv": "C", "full_attention": "Q"}
+
+
+def _lfm2_config_from_hf(hf, name: str) -> ModelConfig:
+    """The lfm2_moe family (``model_type`` names it): gated short
+    convolutions ``conv_L_cache`` wide and GQA layers in the order
+    ``layer_types`` lists, the first ``num_dense_layers`` with a dense SwiGLU
+    and the others with ``num_experts`` routed experts of
+    ``moe_intermediate_size`` behind a sigmoid router whose bias selects. A
+    convolution with a bias, gates that are not normalised over the chosen, a
+    router without the selection bias, an untied head and a layer type that
+    is neither of the two are not built and refused."""
+    _refuse_other_options(hf, name, _LFM2_ONLY, "models/hybrid.py")
+    kinds = list(hf.layer_types)
+    unknown = sorted(set(kinds) - set(LFM2_LAYERS))
+    if unknown:
+        raise ValueError(
+            f"{name}: layer_types names {unknown}: models/hybrid.py builds "
+            f"{sorted(LFM2_LAYERS)} of this family only")
+    if len(kinds) != hf.num_hidden_layers:
+        raise ValueError(
+            f"{name}: layer_types lists {len(kinds)} layers and "
+            f"num_hidden_layers says {hf.num_hidden_layers}")
+    if hf.conv_L_cache < 2:
+        raise ValueError(
+            f"{name}: conv_L_cache={hf.conv_L_cache}: a convolution that "
+            "reaches no earlier row keeps no tail, and the state pool's slot "
+            "row is that tail")
+    if not 0 < hf.num_experts_per_tok < hf.num_experts:
+        raise ValueError(
+            f"{name}: num_experts_per_tok={hf.num_experts_per_tok} of "
+            f"num_experts={hf.num_experts}: a router has to choose")
+    return ModelConfig(
+        name=name,
+        vocab_size=hf.vocab_size,
+        d_model=hf.hidden_size,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        d_ff=hf.intermediate_size,
+        rope_theta=float(hf.rope_theta),
+        max_seq_len=getattr(hf, "max_position_embeddings", 8192),
+        norm_eps=hf.norm_eps,
+        qk_norm=True,
+        n_experts=hf.num_experts,
+        experts_per_token=hf.num_experts_per_tok,
+        first_k_dense=hf.num_dense_layers,
+        moe_d_ff=hf.moe_intermediate_size,
+        routed_scaling_factor=float(hf.routed_scaling_factor),
+        layer_pattern="".join(LFM2_LAYERS[k] for k in kinds),
+        ssm_conv=hf.conv_L_cache,
+    )
+
+
 # The ``model_type``s the plain mapping below serves.
 _LLAMA_TYPES = ("llama", "mixtral", "qwen3")
 
@@ -429,8 +490,9 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
     """Map a transformers Llama/Mixtral/Qwen3 config, a DeepSeek-V3-family
     one (Kimi-VL's ``text_config``), a LongCat-Flash one (``zero_expert_num``
     names it), a nemotron_h one (a layer pattern), a SmallThinker one
-    (``moe_num_primary_experts``) or a jamba one (``model_type``) to our
-    ModelConfig. A ``model_type`` that none of these is gets refused."""
+    (``moe_num_primary_experts``), a jamba or an lfm2_moe one
+    (``model_type``) to our ModelConfig. A ``model_type`` that none of these
+    is gets refused."""
     text = getattr(hf_config, "text_config", None)
     if text is not None:
         # A multimodal config nests its language model; the towers beside it
@@ -441,6 +503,8 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
                      else text)
     if getattr(hf_config, "model_type", None) == "jamba":
         return _jamba_config_from_hf(hf_config, name)
+    if getattr(hf_config, "model_type", None) == "lfm2_moe":
+        return _lfm2_config_from_hf(hf_config, name)
     if getattr(hf_config, "hybrid_override_pattern", None):
         return _hybrid_config_from_hf(hf_config, name)
     if getattr(hf_config, "zero_expert_num", None) is not None:
@@ -456,8 +520,8 @@ def config_from_hf(hf_config, name: str = "converted") -> ModelConfig:
             f"mapping serves {', '.join(_LLAMA_TYPES)}; deepseek_v3-family "
             "(kv_lora_rank), LongCat-Flash (zero_expert_num), nemotron_h "
             "(hybrid_override_pattern), SmallThinker "
-            "(moe_num_primary_experts) and jamba files are known by those "
-            "keys")
+            "(moe_num_primary_experts), jamba and lfm2_moe files are known by "
+            "those keys")
     n_experts = getattr(hf_config, "num_local_experts", 0) or 0
     qk_norm = model_type == "qwen3"
     explicit_hd = getattr(hf_config, "head_dim", None) or 0
@@ -505,6 +569,12 @@ def convert_state_dict(state_dict: dict, cfg: ModelConfig,
             "checkpoint names onto models/mla.py's parameter tree yet (its "
             "rope columns are stored interleaved and need un-interleaving); "
             "the engine serves this family on seeded random weights")
+    if cfg.conv_mixers:
+        tree = _lfm2_state_dict(state_dict, cfg)
+        bias = tree["experts"].pop("router_bias")   # stays float32
+        tree = _cast(tree, jnp.dtype(dtype or cfg.dtype))
+        tree["experts"]["router_bias"] = jnp.asarray(bias, jnp.float32)
+        return tree
     if cfg.layer_pattern or cfg.router_input != "ffn":
         raise NotImplementedError(
             f"{cfg.name}: no mapping of a layer pattern's checkpoint names "
@@ -564,6 +634,84 @@ def convert_state_dict(state_dict: dict, cfg: ModelConfig,
         "lm_head": lm_head,
     }
     return _cast(params, out_dtype)
+
+
+def _lfm2_state_dict(state_dict: dict, cfg: ModelConfig) -> dict:
+    """The lfm2_moe family's checkpoint names onto models/hybrid.py's stacks
+    (float32 numpy; the caller casts). A layer's ``operator_norm`` is its
+    mixer's ``ln`` and its ``ffn_norm`` its FFN's ``ln_mlp``; ``conv.conv``
+    is a depth-wise Conv1d ``[d_model, 1, taps]`` and lies ``[taps,
+    d_model]`` here, tap j on the row ``taps - 1 - j`` back, as the
+    published convolution applies it; ``conv.in_proj``'s 3 x d_model outputs
+    are B, C and u in that order; ``feed_forward.w1`` / ``w3`` / ``w2`` are
+    gate, up and down, of the dense layers and of every expert alike. The
+    head is the embedding transposed."""
+    def get(key):
+        if key not in state_dict:
+            raise KeyError(f"checkpoint missing {key!r}")
+        return state_dict[key]
+
+    def layer(i, rest):
+        return get(f"model.layers.{i}.{rest}")
+
+    def stack(at, fn, *like):
+        """fn(layer) over the layers ``at``; none of them: an empty stack of
+        rows shaped ``like``."""
+        return (np.stack([fn(i) for i in at]) if at
+                else np.zeros((0, *like), np.float32))
+
+    def ffn(i, name, expert=None):
+        where = ("feed_forward." if expert is None
+                 else f"feed_forward.experts.{expert}.")
+        return _t(layer(i, where + name + ".weight"))
+
+    convs = [i for i, c in enumerate(cfg.layer_pattern) if c == "C"]
+    attns = [i for i, c in enumerate(cfg.layer_pattern) if c == "Q"]
+    dense = list(range(cfg.first_k_dense))
+    sparse = list(range(cfg.first_k_dense, cfg.n_layers))
+    E = range(cfg.n_experts)
+
+    D, F = cfg.d_model, cfg.d_ff
+    embed = _vec(get("model.embed_tokens.weight"))
+    return {
+        "embed": embed,
+        "final_norm": _vec(get("model.embedding_norm.weight")),
+        "lm_head": embed.T,
+        "conv": {
+            "ln": stack(convs, lambda i: _vec(layer(i, "operator_norm.weight"))),
+            "w_in": stack(convs, lambda i: _t(layer(i, "conv.in_proj.weight"))),
+            "conv_w": stack(convs, lambda i: _vec(
+                layer(i, "conv.conv.weight"))[:, 0, :].T),
+            "w_out": stack(convs, lambda i: _t(layer(i, "conv.out_proj.weight")))},
+        "attn": {
+            "ln": stack(attns, lambda i: _vec(layer(i, "operator_norm.weight"))),
+            "wq": stack(attns, lambda i: _t(layer(i, "self_attn.q_proj.weight"))),
+            "wk": stack(attns, lambda i: _t(layer(i, "self_attn.k_proj.weight"))),
+            "wv": stack(attns, lambda i: _t(layer(i, "self_attn.v_proj.weight"))),
+            "wo": stack(attns, lambda i: _t(layer(i, "self_attn.out_proj.weight"))),
+            "q_norm": stack(attns, lambda i: _vec(
+                layer(i, "self_attn.q_layernorm.weight"))),
+            "k_norm": stack(attns, lambda i: _vec(
+                layer(i, "self_attn.k_layernorm.weight")))},
+        "ffn": {
+            "ln_mlp": stack(dense, lambda i: _vec(layer(i, "ffn_norm.weight")),
+                            D),
+            "w1": stack(dense, lambda i: ffn(i, "w1"), D, F),
+            "w3": stack(dense, lambda i: ffn(i, "w3"), D, F),
+            "w2": stack(dense, lambda i: ffn(i, "w2"), F, D)},
+        "experts": {
+            "ln_mlp": stack(sparse, lambda i: _vec(layer(i, "ffn_norm.weight"))),
+            "router": stack(sparse, lambda i: _t(
+                layer(i, "feed_forward.gate.weight"))),
+            "router_bias": stack(sparse, lambda i: _vec(
+                layer(i, "feed_forward.expert_bias"))),
+            "w1": stack(sparse, lambda i: np.stack(
+                [ffn(i, "w1", e) for e in E])),
+            "w3": stack(sparse, lambda i: np.stack(
+                [ffn(i, "w3", e) for e in E])),
+            "w2": stack(sparse, lambda i: np.stack(
+                [ffn(i, "w2", e) for e in E]))},
+    }
 
 
 def _cast(tree, dtype):

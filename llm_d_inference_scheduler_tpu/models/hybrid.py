@@ -77,6 +77,41 @@ the channels minor.
 models/llama.py's dense SwiGLU (``ffn`` stack: ``ln_mlp``, ``w1``, ``w3``,
 ``w2``, a row a layer in layer order). A model's ONE KV head is kept twice a
 page (``cfg.kv_heads_kept``: :func:`_qkv`).
+
+The lfm2_moe family (LiquidAI's LFM2-8B-A1B) is two more letters, each with
+an FFN behind it under a second residual:
+
+**C, a gated short convolution.** ``[B | C | u] = h W_in`` (d_model -> 3 x
+d_model, the thirds in that order); ``g = B * u``; ``c_t = sum_j w[:, j] *
+g_(t - (K - 1) + j)``, depth-wise and causal, ``ssm_conv`` (K = 3) wide, no
+bias, zeros before the sequence's first row; ``out = (C * c) W_out``. No
+activation and NO recurrent state: a sequence keeps the last K - 1 rows of
+``g`` a layer, in the model's dtype, in a slot row that is a tail and nothing
+else (kvcache/state.py's third kind). The *run* form (:func:`conv_run`) goes
+over a window's rows from the slot's carried tail and gathers the new tail
+at the true length of a padded window; the *step* form (:func:`conv_step`)
+is one row a lane. ``g`` is rounded to the model's dtype before the
+convolution in both, as the tail stores it, so a window's first rows see
+what the window before it saw; the three products accumulate in f32.
+
+**Q, attention that rotates.** models/llama.py's Qwen3 attention at this
+family's widths: an RMSNorm with a learned weight a head on q and on k
+(``llama.qk_normed``), rotary embedding (``cfg.rope_theta``, rotate-half),
+causal softmax(q k^T / sqrt(head_dim)) v. Heads of 64 lie two a page row
+(``cfg.kv_heads_a_row``, kvcache/pages.py): this module hands and takes its
+own ``[.., Hkv, 64]`` rows.
+
+**The FFN behind a C or Q mixer**, by the layer's index: the dense SwiGLU
+(the ``ffn`` stack) in the first ``cfg.first_k_dense`` layers; from there on
+``cfg.n_experts`` routed SwiGLU experts ``cfg.moe_d_ff`` wide and no shared
+one (the ``experts`` stack: ``ln_mlp``, ``router``, ``router_bias``, ``w1``,
+``w3``, ``w2``): ``s = sigmoid(h W_r)`` in f32, the experts_per_token
+largest of ``s + bias`` (the bias selects and does not weigh), the chosen
+``s`` over their sum (models/routing.route, ``n_group`` 1; the published
+code adds 1e-6 to that sum and this one does not: a relative 1e-6 of a
+gate), then models/llama._ffn in the form the program's shape calls for
+(``cfg.moe_impl``: grouped from 512 padded tokens, dense over all below).
+Every choice names an expert held here.
 """
 
 from __future__ import annotations
@@ -89,10 +124,12 @@ import jax.numpy as jnp
 
 from ..kvcache import pages, state
 from ..ops import causal_attention, rms_norm
+from ..ops.rope import apply_rope, rope_table
 from . import scopes
 from .configs import ModelConfig
 from ..ops import pallas_ssm
-from .llama import _embedded, _ffn, _ffn_input, _last_logits, _logits
+from .llama import (_embedded, _ffn, _ffn_input, _last_logits, _logits,
+                    qk_normed)
 from .routing import route
 
 Params = dict[str, Any]
@@ -100,8 +137,9 @@ Params = dict[str, Any]
 # A layer's letter -> the stack that holds its mixer's parameters (and the
 # pool layer it keeps is its index among that stack's layers); "S" and "A"
 # carry a dense SwiGLU too, a row of the ``ffn`` stack each.
-_STACK = {"M": "ssm", "E": "moe", "*": "attn", "S": "ssm1", "A": "attn"}
-_WITH_FFN = "SA"
+_STACK = {"M": "ssm", "E": "moe", "*": "attn", "S": "ssm1", "A": "attn",
+          "C": "conv", "Q": "attn"}
+_WITH_FFN = "SACQ"
 
 
 def init_params(cfg: ModelConfig, key: jax.Array,
@@ -139,6 +177,8 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     dt = step((Lm, H))
     if cfg.ssm_dt_rank:
         return _init_jamba(cfg, dtype, w, norm, step, keys)
+    if cfg.conv_mixers:
+        return _init_lfm2(cfg, dtype, w, norm, keys)
     return {
         "embed": w((V, D), D), "final_norm": norm((D,)),
         "lm_head": w((D, V), D),
@@ -216,6 +256,47 @@ def _init_jamba(cfg: ModelConfig, dtype, w, norm, step, keys) -> Params:
             "ln_mlp": norm((Ls + La, D)),
             "w1": w((Ls + La, D, F), D), "w3": w((Ls + La, D, F), D),
             "w2": w((Ls + La, F, D), F)},
+    }
+
+
+def _init_lfm2(cfg: ModelConfig, dtype, w, norm, keys) -> Params:
+    """The lfm2_moe family's parameters (:func:`init_params`' helpers): the
+    convolution mixers' stack ``conv`` (``w_in`` [d_model, 3 d_model]: B, C,
+    u; ``conv_w`` [taps, d_model]; ``w_out``), the attention layers' ``attn``
+    with a norm weight a head for q and for k, the leading layers' dense
+    SwiGLU in ``ffn`` and the later layers' routed experts in ``experts``
+    (models/llama._ffn's names, a row a layer in layer order each). Every
+    norm weight, the convolution's taps and the selection bias are drawn, so
+    that a run sees them. The head is the embedding transposed (tied): two
+    tensors that hold the same values."""
+    D, V, F, Fe = cfg.d_model, cfg.vocab_size, cfg.d_ff, cfg.moe_d_ff
+    Lc, La = (cfg.layer_pattern.count(c) for c in "CQ")
+    Ld, Le, E = cfg.first_k_dense, cfg.n_expert_layers, cfg.n_experts
+    Hq, Hkv, Dh, Kc = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.ssm_conv
+    embed = w((V, D), D)
+    return {
+        "embed": embed, "final_norm": norm((D,)), "lm_head": embed.T,
+        "conv": {
+            "ln": norm((Lc, D)),
+            "w_in": w((Lc, D, 3 * D), D),
+            "conv_w": w((Lc, Kc, D), Kc),
+            "w_out": w((Lc, D, D), D)},
+        "attn": {
+            "ln": norm((La, D)),
+            "wq": w((La, D, Hq * Dh), D), "wk": w((La, D, Hkv * Dh), D),
+            "wv": w((La, D, Hkv * Dh), D), "wo": w((La, Hq * Dh, D), Hq * Dh),
+            "q_norm": norm((La, Dh)), "k_norm": norm((La, Dh))},
+        "ffn": {
+            "ln_mlp": norm((Ld, D)),
+            "w1": w((Ld, D, F), D), "w3": w((Ld, D, F), D),
+            "w2": w((Ld, F, D), F)},
+        "experts": {
+            "ln_mlp": norm((Le, D)),
+            "router": w((Le, D, E), D),
+            "router_bias": 0.1 * jax.random.normal(
+                next(keys), (Le, E), jnp.float32),
+            "w1": w((Le, E, D, Fe), D), "w3": w((Le, E, D, Fe), D),
+            "w2": w((Le, E, Fe, D), Fe)},
     }
 
 
@@ -328,15 +409,19 @@ def _gated_out(cfg: ModelConfig, lp: Params, y: jnp.ndarray, z: jnp.ndarray
 
 def _conv_run(lp: Params, xbc: jnp.ndarray, tail0: jnp.ndarray,
               lens: jnp.ndarray, Kc: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The causal depth-wise convolution (with its bias, before the silu,
-    f32) over a run xbc [B, S, channels] that continues ``tail0`` [B, Kc - 1,
-    channels], and the tail as position ``lens[b] - 1`` leaves it."""
+    """The causal depth-wise convolution (with its bias where the layer has
+    one, before any activation, f32) over a run xbc [B, S, channels] that
+    continues ``tail0`` [B, Kc - 1, channels], and the tail as position
+    ``lens[b] - 1`` leaves it."""
     S = xbc.shape[1]
     seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc], axis=1)
     # Row t + j of seq is position t - (Kc - 1) + j.
-    conv = lp["conv_b"].astype(jnp.float32) + sum(
+    bias = _conv_bias(lp)
+    conv = sum(
         seq[:, j:j + S].astype(jnp.float32) * lp["conv_w"][j].astype(jnp.float32)
         for j in range(Kc))
+    if bias is not None:
+        conv = bias + conv
     tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, Kc - 1))(
         seq, lens)
     return conv, tail
@@ -348,9 +433,15 @@ def _conv_step(lp: Params, xbc: jnp.ndarray, tail0: jnp.ndarray
     behind ``tail0`` [B, Kc - 1, channels]; (the convolution's output, the
     Kc rows it ran over: the last Kc - 1 are the new tail)."""
     seq = jnp.concatenate([tail0.astype(xbc.dtype), xbc[:, None]], axis=1)
-    conv = lp["conv_b"].astype(jnp.float32) + jnp.sum(
+    bias = _conv_bias(lp)
+    conv = jnp.sum(
         seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32), axis=1)
-    return conv, seq
+    return (conv if bias is None else bias + conv), seq
+
+
+def _conv_bias(lp: Params) -> jnp.ndarray | None:
+    """The convolution's bias in f32; None for a layer without one."""
+    return lp["conv_b"].astype(jnp.float32) if "conv_b" in lp else None
 
 
 def ssm_scan(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
@@ -551,11 +642,94 @@ def ssm1_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
     return out, cache, seq[:, 1:]
 
 
+# ---- C: a gated short convolution ------------------------------------------------
+
+
+def _conv_in(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
+    """h W_in as (g = B * u, what the convolution runs over, and C), each
+    [..., d_model] in the model's dtype."""
+    D = cfg.d_model
+    bcu = h @ lp["w_in"]
+    return bcu[..., :D] * bcu[..., 2 * D:], bcu[..., D:2 * D]
+
+
+def _conv_out(lp: Params, c: jnp.ndarray, conv: jnp.ndarray) -> jnp.ndarray:
+    """The convolution's output (f32) gated by C, through W_out."""
+    return (c.astype(jnp.float32) * conv).astype(c.dtype) @ lp["w_out"]
+
+
+def conv_run(cfg: ModelConfig, lp: Params, h: jnp.ndarray, lens: jnp.ndarray,
+             state0: None = None, tail0: jnp.ndarray | None = None
+             ) -> tuple[jnp.ndarray, None, jnp.ndarray]:
+    """A gated short convolution over a run of positions (:func:`ssm_scan`'s
+    arguments and results, the state None: the layer keeps none): h [B, S, D]
+    (normed), the first ``lens[b]`` rows real, continuing from the slot's
+    ``tail0`` [B, conv - 1, D] (None: a sequence's start, zeros). A padded
+    row lies behind every real one, so it changes no real row's output, and
+    the tail is gathered at the true length."""
+    B = h.shape[0]
+    with scopes.block("state.proj"):
+        g, c = _conv_in(cfg, lp, h)
+    with scopes.block("state.update"):
+        if tail0 is None:
+            tail0 = jnp.zeros((B, cfg.ssm_conv - 1, g.shape[-1]), g.dtype)
+        conv, tail = _conv_run(lp, g, tail0, lens, cfg.ssm_conv)
+    with scopes.block("state.proj"):
+        out = _conv_out(lp, c, conv)
+    return out, None, tail
+
+
+def conv_step(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+              cache: state.Cache, layer: int
+              ) -> tuple[jnp.ndarray, state.Cache, jnp.ndarray]:
+    """A gated short convolution for one position a sequence
+    (:func:`ssm_step`'s arguments and results): h [B, D], the sequences'
+    tails the rows of ``cache`` at state layer ``layer``; the cache comes
+    back as it was (there is no state to update) and the caller writes the
+    new tails."""
+    B = h.shape[0]
+    with scopes.block("state.proj"):
+        g, c = _conv_in(cfg, lp, h)
+    with scopes.block("state.update"):
+        tail0 = state.tail(cache, layer).reshape(B, cfg.ssm_conv - 1,
+                                                 cfg.ssm_conv_dim)
+        conv, seq = _conv_step(lp, g, tail0)
+    with scopes.block("state.proj"):
+        out = _conv_out(lp, c, conv)
+    return out, cache, seq[:, 1:]
+
+
+def _expert_ffn(cfg: ModelConfig, stack: Params, i: int, x: jnp.ndarray
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Row ``i`` of the ``experts`` stack behind a mixer, on the residual
+    stream x [B, S, D]: (the routed experts' output, the experts every token
+    chose [T, k]). The router is models/routing.route on the FFN's normed
+    input; the experts are models/llama._ffn's, in the form ``cfg.moe_impl``
+    names (the grouped form reads the stacked weights at (layer, expert): a
+    slice of them would reach the kernel as a copy)."""
+    lp = {n: stack[n][i] for n in ("ln_mlp", "router", "router_bias")}
+    h = _ffn_input(cfg, lp, x)
+    with scopes.block("ffn.router"):
+        idx, gates = route(cfg, lp, h.reshape(-1, h.shape[-1]))
+    whole = cfg.moe_impl.startswith("grouped")
+    lp.update({n: stack[n] if whole else stack[n][i]
+               for n in ("w1", "w3", "w2")})
+    if whole:
+        lp["layer"] = jnp.asarray(i, jnp.int32)
+    chosen = (idx.reshape(*h.shape[:-1], -1), gates.reshape(*h.shape[:-1], -1))
+    return _ffn(cfg, lp, h, chosen), idx
+
+
 # ---- the stack ------------------------------------------------------------------
 
 
 _NORM_SCOPE = {"M": "state.proj", "*": "attn.proj", "E": "ffn.router",
-               "S": "state.proj", "A": "attn.proj"}
+               "S": "state.proj", "A": "attn.proj", "C": "state.proj",
+               "Q": "attn.proj"}
+# The letter whose mixer a layer's letter runs: an attention layer with an FFN
+# behind it is the attention, a convolution keeps a slot row as the
+# state-space mixers do.
+_MIXER = {"A": "*", "Q": "*", "C": "S"}
 
 
 def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -564,17 +738,19 @@ def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
           ) -> tuple[jnp.ndarray, jnp.ndarray, list[jnp.ndarray],
                      jnp.ndarray | None]:
     """x through every layer in the pattern's order. ``mixers["M"]``,
-    ``mixers["S"]`` and ``mixers["*"]`` (an "A" layer's too) are ``(layer's
-    parameters, normed input, index among its stack's layers) -> mixer
-    output`` and keep what else they make (states, K/V rows) for their
-    caller; the expert layer is the same in every step (``real`` is
-    :func:`latent_moe`'s), and so is the dense SwiGLU behind an "S" or "A"
-    mixer. Returns (x, the count of expert choices held here, every expert
-    layer's choices, the count of held experts read or None:
+    ``mixers["S"]`` (a "C" layer's too) and ``mixers["*"]`` (an "A" or "Q"
+    layer's too) are ``(layer's parameters, normed input, index among its
+    stack's layers) -> mixer output`` and keep what else they make (states,
+    K/V rows) for their caller; the expert layer is the same in every step
+    (``real`` is :func:`latent_moe`'s), and so is the FFN behind an "S",
+    "A", "C" or "Q" mixer: the dense SwiGLU, or from layer
+    ``cfg.first_k_dense`` of a model with experts on, the routed experts
+    (:func:`_expert_ffn`). Returns (x, the count of expert choices held here,
+    every expert layer's choices, the count of held experts read or None:
     :func:`latent_moe`)."""
     seen = dict.fromkeys(_STACK.values(), 0)
     held, routes, read, n_ffn = jnp.zeros((), jnp.int32), [], None, 0
-    for kind in cfg.layer_pattern:
+    for at, kind in enumerate(cfg.layer_pattern):
         stack, i = params[_STACK[kind]], seen[_STACK[kind]]
         seen[_STACK[kind]] += 1
         with scopes.block(_NORM_SCOPE[kind]):   # with what reads it first
@@ -585,18 +761,30 @@ def _walk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             if n_read is not None:
                 read = n_read if read is None else read + n_read
         else:
-            y = mixers["*" if kind == "A" else kind](
+            y = mixers[_MIXER.get(kind, kind)](
                 {n: a[i] for n, a in stack.items()}, h, i)
         x = x + y
-        if kind in _WITH_FFN:
+        if kind in _WITH_FFN and cfg.n_experts and at >= cfg.first_k_dense:
+            flat = x.ndim == 2      # a decode step's [B, D]
+            y, chosen = _expert_ffn(cfg, params["experts"],
+                                    at - cfg.first_k_dense,
+                                    x[:, None] if flat else x)
+            x = x + (y[:, 0] if flat else y)
+            # Every expert is held here: every choice counts.
+            held, routes = held + chosen.size, routes + [chosen]
+        elif kind in _WITH_FFN:
             lp = {n: a[n_ffn] for n, a in params["ffn"].items()}
             n_ffn += 1
             x = x + _ffn(cfg, lp, _ffn_input(cfg, lp, x))
     return x, held, routes, read
 
 
-def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
-    """h [..., D] -> q [..., H, Dh], k and v [..., Hkv, Dh]; no rotation. A
+def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray,
+         positions: jnp.ndarray | None = None):
+    """h [..., D] -> q [..., H, Dh], k and v [..., Hkv, Dh]; no rotation,
+    but for a layer whose parameters hold a norm a head for q and k (a "Q"
+    layer): that norm, then the rotary embedding of ``positions`` [...]
+    (None: a run from position 0 on). A
     model's ONE KV head is handed on twice (``cfg.kv_heads_kept``): the pages
     keep it so, and each copy serves half the query heads."""
     lead, Dh = h.shape[:-1], cfg.head_dim
@@ -604,6 +792,12 @@ def _qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
         q = (h @ lp["wq"]).reshape(*lead, cfg.n_heads, Dh)
         k = (h @ lp["wk"]).reshape(*lead, cfg.n_kv_heads, Dh)
         v = (h @ lp["wv"]).reshape(*lead, cfg.n_kv_heads, Dh)
+        if "q_norm" in lp:
+            q, k = qk_normed(cfg, lp, q, k)
+            if positions is None:
+                positions = jnp.arange(h.shape[-2], dtype=jnp.int32)
+            cos, sin = rope_table(positions, Dh, cfg.rope_theta)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         if cfg.kv_heads_kept != cfg.n_kv_heads:
             k, v = (jnp.repeat(t, cfg.kv_heads_kept // cfg.n_kv_heads,
                                axis=-2) for t in (k, v))
@@ -617,12 +811,16 @@ def _out(lp: Params, attn: jnp.ndarray) -> jnp.ndarray:
 
 
 def _scan_of(cfg: ModelConfig):
-    """The state-space layers' form for a run of positions."""
+    """The form for a run of positions of the layers that keep a slot row."""
+    if cfg.conv_mixers:
+        return conv_run
     return ssm1_scan if cfg.ssm_dt_rank else ssm_scan
 
 
 def _step_of(cfg: ModelConfig):
-    """The state-space layers' form for one position a sequence."""
+    """Their form for one position a sequence."""
+    if cfg.conv_mixers:
+        return conv_step
     return ssm1_step if cfg.ssm_dt_rank else ssm_step
 
 
@@ -637,7 +835,7 @@ def forward(
     params: Params,
     cfg: ModelConfig,
     tokens: jnp.ndarray,                   # [B, S]
-    positions: jnp.ndarray | None = None,  # unused: no rotary embedding
+    positions: jnp.ndarray | None = None,  # read by "Q" layers alone
     *,
     want_kv: bool = False,
     want_hidden: bool = False,
@@ -668,7 +866,7 @@ def forward(
         return out
 
     def attend(lp, h, i):
-        q, k, v = _qkv(cfg, lp, h)
+        q, k, v = _qkv(cfg, lp, h, positions)
         ks.append(k), vs.append(v)
         with scopes.block("attn.core"):
             out = causal_attention(q, k, v, kv_valid=kv_valid)
@@ -684,7 +882,9 @@ def forward(
         kv_like = (B, S, cfg.kv_heads_kept, cfg.head_dim)
         kv = (state.Fresh(
             _stacked(ks, kv_like, dt), _stacked(vs, kv_like, dt),
-            _stacked(ssms, (B, *cfg.ssm_row), jnp.float32),
+            # (None: layers that keep a tail and no recurrent state.)
+            _stacked(ssms, (B, *cfg.ssm_row), jnp.float32) if cfg.ssm_row
+            else None,
             _stacked(tails, (B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dt),
             held), None)
     with scopes.block("head"):
@@ -726,7 +926,7 @@ def decode_step(
         return out
 
     def attend(lp, h, i):
-        q, k, v = _qkv(cfg, lp, h)
+        q, k, v = _qkv(cfg, lp, h, positions)
         ks.append(k), vs.append(v)
         with scopes.block("attn.core"):
             out = attention_fn(q, cache.k, cache.v, jnp.asarray(i, jnp.int32),
@@ -805,11 +1005,12 @@ def prefill_with_prefix(
         return out
 
     def attend(lp, h, i):
-        q, k, v = _qkv(cfg, lp, h)
+        q, k, v = _qkv(cfg, lp, h, positions)
         ks.append(k), vs.append(v)
         with scopes.block("attn.core"):
-            k_prior, v_prior = pages.read_prefix(cache.k, cache.v,
-                                                 prior_table_row, layer=i)
+            k_prior, v_prior = pages.read_prefix(
+                cache.k, cache.v, prior_table_row, layer=i,
+                heads=k.shape[-2:])
             out = causal_attention(
                 q, jnp.concatenate([k_prior, k], axis=1),
                 jnp.concatenate([v_prior, v], axis=1), q_positions=positions,
@@ -818,8 +1019,10 @@ def prefill_with_prefix(
 
     x, held, routes, _ = _walk(params, cfg, _embedded(params, tokens),
                                {"M": ssm, "S": ssm, "*": attend})
-    cache = _written(cache, ks, vs, ssms, tails, held, pages.sequence_slots(
-        cache.k, block_table_row, suffix_len, S, prefix_len))
+    # (No states to write where the layers keep a tail alone.)
+    cache = _written(cache, ks, vs, ssms if cfg.ssm_row else None, tails,
+                     held, pages.sequence_slots(cache.k, block_table_row,
+                                                suffix_len, S, prefix_len))
 
     out = (_last_logits(params, cfg, x, suffix_len), cache, None)
     return (*out, jnp.stack(routes)) if want_routes else out

@@ -87,6 +87,18 @@ no run.
 Neither form pads its query heads: 28 heads on 4 KV heads (7 a group, no
 multiple of 8) compile for a v5e as they are (tests/test_chip_compile.py).
 
+**Heads narrower than a lane** (``side`` > 1: kvcache/pages.py keeps ``side``
+adjacent KV heads of ``D / side`` values side by side as one page row of D =
+128, and hands the walk that many fewer, wider "KV heads"): the stages, the
+copies and the two products are the same program. A query row arrives zero
+outside the lanes of its own KV head, so the row's other heads' keys add
+nothing to its logits; the scale is the narrow head's, ``1 / sqrt(D /
+side)``; the second product fills all D lanes of a row's accumulator and the
+row's own ``D / side`` are taken out of it in VMEM (the first ``q_per_kv /
+side`` rows of a group belong to the row's first head, and so on), so the
+kernel hands back ``[B, H, D / side]``, the caller's own shape, with no
+operation behind it.
+
 **A window of a prompt** (:func:`kv_window_prefill_attention`, the op of that
 name): the same stages under a tile of queries, for the windows of a long
 prompt that continue what earlier ones cached; described where it stands, at
@@ -133,14 +145,15 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
             k_hbm, v_hbm,              # stacked page arrays (ANY/HBM)
             out_ref,                   # [1, H, D]
             k_tile, v_tile, sem_k, sem_v,   # [2, P, block, Hkv, D] each pool
-            *, max_blocks: int, pages: int, block: int, group: int,
-            skip_ref=None, first_ref=None):
+            *more,                     # with ``side`` > 1: a [H, D] f32 scratch
+            max_blocks: int, pages: int, block: int, group: int,
+            side: int = 1, skip_ref=None, first_ref=None):
     b = pl.program_id(0)
     n_kv, head_dim = cur_k_ref.shape[1:]
     H = q_ref.shape[1]
     q_per_kv = H // n_kv
     rows = pages * block                              # tokens a stage
-    scale = 1.0 / (head_dim ** 0.5)
+    scale = 1.0 / ((head_dim // side) ** 0.5)
 
     # A KV head's query heads apart (nothing is padded: 7 a group as they
     # are), once a program.
@@ -204,10 +217,38 @@ def _kernel(bt_ref, run_ref, sl_ref, layer_ref,  # scalar prefetch: [B*maxB],
                     preferred_element_type=jnp.float32))
 
     _, l, acc = jax.lax.fori_loop(0, n_stages, stage_body, carry)
-    out_ref[0] = (acc / l).reshape(H, head_dim).astype(out_ref.dtype)
+    out = (acc / l).reshape(H, head_dim)
+    if side > 1:
+        out = _rows_side_by_side(out, more[0], q_per_kv, side)
+    out_ref[0] = out.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def _rows_side_by_side(out, scratch, q_per_kv: int, side: int):
+    """out [H, D] f32, row h holding its query head's result in the lanes of
+    its own KV head (head ``(h % q_per_kv) // (q_per_kv / side)`` of the
+    ``side`` a page row holds, ``D / side`` lanes each) and another head's in
+    the others -> [H / side, D]: row j the results of query heads ``side x
+    j ... side x j + side - 1`` side by side, ``D / side`` lanes each."""
+    H, D = out.shape
+    d = D // side
+    of = (jax.lax.broadcasted_iota(jnp.int32, (H, D), 0) % q_per_kv
+          ) // (q_per_kv // side)
+    # Every row's own lanes rotated to the front.
+    front = out
+    for i in range(1, side):
+        front = jnp.where(of == i, pltpu.roll(out, D - i * d, 1), front)
+    scratch[...] = front
+    lane = jax.lax.broadcasted_iota(jnp.int32, (H // side, D), 1)
+    rows = scratch[pl.ds(0, H // side, stride=side), :]
+    for i in range(1, side):
+        rows = jnp.where(
+            lane // d == i,
+            pltpu.roll(scratch[pl.ds(i, H // side, stride=side), :], i * d, 1),
+            rows)
+    return rows
+
+
+@functools.partial(jax.jit, static_argnames=("side", "interpret"))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,            # [B, H, D]
     k_pages: jnp.ndarray,      # [L, N, block, Hkv, D] — every layer's pool
@@ -218,11 +259,12 @@ def paged_decode_attention_pallas(
     cur_k: jnp.ndarray,         # [B, Hkv, D]
     cur_v: jnp.ndarray,
     *,
+    side: int = 1,              # narrow KV heads side by side in a page row
     interpret: bool = False,
 ) -> jnp.ndarray:
     return _call(_kernel, block_tables, seq_lens, seq_lens, (), q, k_pages,
                  v_pages, layer, cur_k, cur_v, block_tables.shape[1],
-                 interpret=interpret)
+                 side=side, interpret=interpret)
 
 
 def _window_kernel(bt_ref, run_ref, sl_ref, skip_ref, first_ref, layer_ref,
@@ -265,14 +307,17 @@ def swa_paged_decode_attention_kernel(
 
 def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
           cur_k, cur_v, walk: int, *, interpret: bool,
-          name: str | None = None):
+          name: str | None = None, side: int = 1):
     """One program a batch row over the stacked pools. The kernel's scalar
     operands: ``tables`` [B, maxB], which of their groups are runs (by
     ``seq_lens``, the lanes' whole lengths), ``lens`` [B] (the lengths the
     walk counts), whatever ``more`` holds, and the layer. ``walk``: the table
     entries a lane's walk reads at most, which bounds a stage. The query
     goes in in the pool's dtype; no operation stands between the caller's
-    arrays and the kernel, or behind it."""
+    arrays and the kernel, or behind it. ``side`` > 1: a page row is that
+    many narrow heads side by side, and the result is ``[B, H / side, D]``,
+    as many query heads' outputs side by side a row (the module's
+    docstring)."""
     B, H, D = q.shape
     _, _, block, n_kv, _ = k_pages.shape
     maxB = tables.shape[1]
@@ -283,7 +328,8 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
                 *more)
 
     kernel = functools.partial(
-        kernel, max_blocks=maxB, pages=pages, block=block, group=group)
+        kernel, max_blocks=maxB, pages=pages, block=block, group=group,
+        **({"side": side} if side > 1 else {}))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch) + 1,
@@ -295,18 +341,19 @@ def _call(kernel, tables, seq_lens, lens, more, q, k_pages, v_pages, layer,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H // side, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, pages, block, n_kv, D), k_pages.dtype),
             pltpu.VMEM((2, pages, block, n_kv, D), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
+            *([pltpu.VMEM((H, D), jnp.float32)] if side > 1 else []),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H // side, D), q.dtype),
         interpret=interpret,
         name=name,      # the op's in a device trace; None: the caller's own
     )(*prefetch, jnp.asarray(layer, jnp.int32).reshape(1),

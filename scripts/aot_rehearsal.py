@@ -159,7 +159,10 @@ def main(argv=None) -> int:
         read = sds((), jnp.int32) if reads else None
         if state_geom:
             return (kvstate.Cache(
-                pages, pages, sds(state_geom.ssm_shape, jnp.float32),
+                pages, pages,
+                # (None: the slots keep a convolution's tail alone.)
+                None if state_geom.ssm_shape is None
+                else sds(state_geom.ssm_shape, jnp.float32),
                 sds(state_geom.conv_shape, jnp.dtype(state_geom.dtype)),
                 slots=sds((rows,), jnp.int32), held=sds((), jnp.int32),
                 read=read), None)

@@ -1022,3 +1022,137 @@ def test_the_jamba_decode_step_compiles_and_copies_no_pool(one_chip):
     # Under one set of the 64 lanes' rows of one layer.
     assert (compiled.memory_analysis().temp_size_in_bytes
             < batch * 4 * math.prod(sgeom.ssm_shape[2:]))
+
+
+def _lfm2_cut(n_layers=3, pattern="CQC"):
+    """LFM2-8B-A1B's widths, a few layers of it: one dense FFN, then routed
+    experts."""
+    from llm_d_inference_scheduler_tpu.models.configs import ModelConfig
+
+    return ModelConfig(
+        name="lfm2-cut", vocab_size=65536, d_model=2048, n_layers=n_layers,
+        n_heads=32, n_kv_heads=8, d_ff=7168, rope_theta=1e6, qk_norm=True,
+        n_experts=32, experts_per_token=4, first_k_dense=1, moe_d_ff=1792,
+        layer_pattern=pattern, ssm_conv=3)
+
+
+def test_a_pool_of_64_wide_heads_lies_padded_where_a_kernel_reads_it(
+        one_chip):
+    """Why two heads of 64 lie side by side a page row
+    (``ModelConfig.kv_heads_a_row``): in the row-major layout a Pallas call's
+    operand has, a bf16 pool ``[.., 16, 8, 64]`` takes twice the model's
+    bytes (its minor dim padded to a tile's 128 lanes), and the same values
+    as ``[.., 16, 4, 128]`` take them once."""
+    from jax.experimental.layout import Format, Layout
+
+    n = 2049
+    for shape, padding in (((4, n, 16, 8, 64), 2), ((4, n, 16, 4, 128), 1)):
+        fmt = Format(Layout(major_to_minor=(0, 1, 2, 3, 4)), one_chip)
+        pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=fmt)
+        compiled = jax.jit(lambda p: p.at[0, 0, 0].set(1),
+                           out_shardings=fmt).lower(pool).compile()
+        assert (compiled.memory_analysis().argument_size_in_bytes
+                == padding * 2 * math.prod(shape))
+
+
+@pytest.mark.parametrize("lanes,table_width", [(64, 288), (2, 288)])
+def test_the_paged_walk_compiles_over_two_heads_of_64_a_row(
+        one_chip, lanes, table_width):
+    """32 query heads on 8 KV heads of 64, the cell's four attention layers'
+    18,433 pages of two heads a row ([16, 4, 128]): the walk is the four-head
+    program of 128 lanes, the query zeroed outside its own head's lanes
+    ahead of it, and each row's own 64 lanes taken inside it. The call hands
+    back two query heads a row of whole lanes, so what the block does next --
+    the heads flattened ahead of the output projection -- is no operation:
+    nothing but a bitcast reads the kernel's result (a [lanes, 32, 64]
+    result was re-laid out by a copy that named the kernel, which the
+    benchmark's roofline reader counted as a call: it read 145)."""
+    m = _lfm2_cut()
+    assert (m.kv_heads_a_row, m.head_dim) == (2, 64)
+    bf16 = jnp.bfloat16
+    pool = _sds(one_chip, (4, 18433, 16, 4, 128), bf16)
+    cur = _sds(one_chip, (lanes, 8, 64), bf16)
+
+    def attend(*args):
+        out = decode_attention(*args, kernel=True)
+        assert out.shape == (lanes, 32, 64)
+        return out.reshape(lanes, -1)       # as models/hybrid._out does
+
+    compiled = jax.jit(attend).lower(
+        _sds(one_chip, (lanes, 32, 64), bf16), pool, pool,
+        _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (lanes, table_width), jnp.int32),
+        _sds(one_chip, (lanes,), jnp.int32), cur, cur).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "gather" not in hlo
+    readers = [ln.strip()[:200] for ln in hlo.splitlines()
+               if re.search(r"\(.*%paged_decode_attention_pallas", ln)
+               and "bitcast(" not in ln]
+    assert not readers, readers
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_lfm2_decode_step_compiles_and_copies_no_pool(one_chip):
+    """One decode chunk step at LFM2-8B-A1B's widths (a convolution layer
+    with the dense FFN, an attention layer and a convolution layer with 32
+    experts each), 64 lanes on the cell's pools, as a ``lax.scan`` of two
+    steps: the paged walk is in the program, and neither the tails' pool nor
+    the page pool is made anew."""
+    from llm_d_inference_scheduler_tpu.kvcache import state
+    from llm_d_inference_scheduler_tpu.kvcache.pages import PageGeometry
+    from llm_d_inference_scheduler_tpu.models import bind, hybrid
+
+    bound = bind(_lfm2_cut(), platform="tpu")
+    batch = 64
+    m = bound.model_for(batch)
+    assert m.moe_impl == "dense" and bound.model_for(1024).moe_impl == "grouped"
+    geom = PageGeometry.for_engine(m, batch, 4608)
+    assert geom.shape == (1, 18433, 16, 4, 128) and geom.token_bytes == 2048
+    sgeom = geom.state
+    assert sgeom.ssm_shape is None and sgeom.conv_shape == (2, 65, 4096)
+    dt = jnp.dtype(m.dtype)
+    pages = _sds(one_chip, geom.shape, dt)
+    cache = state.Cache(
+        pages, pages, None, _sds(one_chip, sgeom.conv_shape, dt),
+        slots=_sds(one_chip, (batch,), jnp.int32),
+        held=_sds(one_chip, (), jnp.int32))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: hybrid.init_params(m, k), jax.random.key(0)))
+
+    def chunk(params, tokens, positions, cache, tables):
+        def step(carry, _):
+            tokens, positions, cache = carry
+            logits, cache, _ = hybrid.decode_step(
+                params, m, tokens, positions, cache, None, tables,
+                attention_fn=functools.partial(decode_attention, kernel=True))
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (nxt, positions + 1, cache), nxt
+
+        (_, _, cache), toks = jax.lax.scan(
+            step, (tokens, positions, cache), None, length=2)
+        return toks, cache
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        params, _sds(one_chip, (batch,), jnp.int32),
+        _sds(one_chip, (batch,), jnp.int32), cache,
+        _sds(one_chip, (batch, geom.max_blocks_per_seq), jnp.int32)
+    ).compile()
+    hlo = compiled.as_text()
+    assert "paged_decode_attention" in hlo
+    for pool, dtype in ((sgeom.conv_shape, "bf16"), (geom.shape, "bf16")):
+        shape = f"{dtype}[" + ",".join(map(str, pool)) + "]"
+        made = [ln.strip()[:160] for ln in hlo.splitlines()
+                if re.search(r"=\s*" + re.escape(shape), ln)
+                and "parameter(" not in ln and "bitcast(" not in ln
+                and "scatter" not in ln and "dynamic-update-slice" not in ln
+                and "fusion(" not in ln and "get-tuple-element(" not in ln]
+        assert not made, made
+    # No pool padded to 128 lanes from a minor dim of 64.
+    assert not re.search(r"bf16\[\d+,18433,16,8,64\]", hlo)
+    # The walk's result goes to the output projection as it lies: a copy or
+    # reshape that read it would be counted as a call of the kernel.
+    readers = [ln.strip()[:200] for ln in hlo.splitlines()
+               if re.search(r"= \S+ (copy|reshape|transpose)\(.*"
+                            r"%paged_decode_attention_pallas", ln)]
+    assert not readers, readers
