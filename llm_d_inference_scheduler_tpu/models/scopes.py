@@ -27,7 +27,7 @@ BLOCKS = (
     "kv.write",         # rows written into a page, window, index or state pool
     "ffn.router",       # router scores, top-k, the on-device pair counts
     "ffn.experts",      # the routed experts in every form
-    "ffn.experts.glue",  # the grouped form's sort, gathers and scatter
+    "ffn.experts.glue",  # the grouped form's layout and its two gathers
     "ffn.shared",       # shared experts
     "ffn.dense",        # a dense layer's FFN
     "state.proj",       # a state-space layer's projections, conv, gated norm

@@ -16,12 +16,15 @@ chosen per traced program, from its shapes: :func:`use_grouped`,
 
 The grouped form computes only the (token, chosen expert) rows:
 
-1. XLA side (:func:`moe_ffn_grouped`): router top-k → each token becomes its k
-   (token, expert) rows → stable sort by expert → a *group-padded* layout in
-   which every expert's rows start at a row-tile boundary. The buffer is
-   static, T·k + E·tm rows; only the offsets are data, so no token is ever
-   dropped, whatever the routing. A tile → expert map and the count of tiles
-   that hold rows go to the kernel as prefetched scalars.
+1. XLA side (:func:`grouped_experts`, :func:`group_layout`): each token
+   becomes its k (token, expert) rows, laid out *group-padded*: every
+   expert's rows start at a row-tile boundary, in the order a stable sort by
+   expert would give. A row's place comes from COUNTING the earlier rows of
+   its expert (a product with a triangle of ones), the inverse from one sort
+   of the rows' keys read a tile's slice at a time; nothing is scattered.
+   The buffer is static, T·k + E·tm rows; only the offsets are data, so no
+   token is ever dropped, whatever the routing. A tile → expert map and the
+   count of tiles that hold rows go to the kernel as prefetched scalars.
 2. Pallas side (:func:`_grouped_matmul`), twice: ``silu(x·w1) * (x·w3)`` →
    ``[Tp, F]``, then ``·w2``. The grid is (N tiles, K tiles, row tiles) with
    the ROW tiles innermost: consecutive row tiles of one expert map to the
@@ -30,8 +33,8 @@ The grouped form computes only the (token, chosen expert) rows:
    sums over K wait in an f32 scratch that holds every row tile. Tiles past
    the last group hold no rows: they are skipped, and their block indices
    repeat the last live tile's so that nothing is copied for them.
-3. Back in XLA: gather each token's k rows out of the padded layout, weight
-   them by the router's gates and add.
+3. Back in XLA: gather each token's k rows out of the padded layout a choice
+   at a time, weight them by the router's gates and add: one pass.
 
 The form of few rows (:func:`chosen_experts`) is the same kernel with ONE
 row tile, the program's rows whole, shared by every expert some row chose: no
@@ -81,6 +84,10 @@ CHOSEN_MAX_ROWS_PER_EXPERT = 3.0
 # Rows the one shared tile of :func:`chosen_experts` is padded to: a bf16
 # tile's sublanes.
 _CHOSEN_ROW_ALIGN = 16
+
+# Rows of one block of the layout's count (:func:`group_layout`): a product
+# with a triangle of ones that is one pass of the MXU.
+_COUNT_BLOCK = 128
 
 # What one grouped matmul may keep in VMEM (of a v5e's 128 MiB; the
 # compiler's default scoped limit of 16 MiB is raised to what the tiles need).
@@ -335,13 +342,13 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
     ``first`` says that the weights hold a range of the experts the router
     chose among: the ``n_experts`` from expert id ``first`` on. A (token,
     expert) row whose expert is absent is dropped before the group layout --
-    it is sorted behind the last group, into rows no live tile covers, and
+    its place is behind the last group, in rows no live tile covers, and it
     contributes nothing -- so the buffer stays T·k + n_experts·tm rows and no
     token is dropped at any skew. ``gated`` False: an expert is
     relu(x·w1)²·w2, and there is no w3. ``reglu``: a gated expert's
     activation is relu(x·w1) * (x·w3), not SwiGLU's."""
     T, D = xt.shape
-    E, k = n_experts, top_idx.shape[1]
+    E = n_experts
 
     # The glue names itself in a device trace, inside whatever scope the
     # model gave the experts (models/scopes.py; imported at call time:
@@ -349,48 +356,16 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
     from ..models import scopes
 
     with scopes.block("ffn.experts.glue"):
-        # The T·k (token, expert) rows, stable-sorted by expert.
-        flat_expert = top_idx.reshape(-1)                           # [T*k]
+        # The rows a choice at a time, [k, T]: the way back sums over the
+        # major axis, and no index is transposed on the way.
+        expert = top_idx.T                                          # [k, T]
+        here = None
         if first is not None:
-            here = (flat_expert >= first) & (flat_expert < first + E)
-            flat_expert = jnp.where(here, flat_expert - first, E)   # absent: last
-        order = jnp.argsort(flat_expert, stable=True)               # [T*k]
-        sorted_expert = flat_expert[order]
-
-        # Group-padded layout: expert e's rows start at off[e], every group
-        # padded up to a multiple of tm. Static buffer: Tp = T*k + E*tm rows.
-        counts = jnp.bincount(flat_expert, length=E)                # [E]
-        padded = ((counts + tm - 1) // tm) * tm
-        zero = jnp.zeros((1,), counts.dtype)
-        off = jnp.concatenate([zero, jnp.cumsum(padded)])           # [E+1]
-        start = jnp.concatenate([zero, jnp.cumsum(counts)])         # [E+1]
-        # A sorted row's place: its group's start plus its rank in the group.
-        dest_sorted = (off[sorted_expert] + jnp.arange(T * k)
-                       - start[sorted_expert])                      # [T*k]
-
-        # Both moves of D-wide rows are gathers (small integer scatters make
-        # their indices): padded row → its source token (T: a row of zeros),
-        # and (token, choice) → its padded row.
-        Tp = T * k + E * tm
-        src = jnp.full((Tp,), T, jnp.int32).at[dest_sorted].set(
-            (order // k).astype(jnp.int32))
-        dest = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            dest_sorted.astype(jnp.int32))
-        x_pad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[src]
-
-        # tile → expert: whose [off[e], off[e+1]) holds the tile's first row.
-        tile_starts = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
-        tile_expert = jnp.minimum(
-            jnp.searchsorted(off[1:], tile_starts, side="right"),
-            E - 1).astype(jnp.int32)
-        n_live = (off[E:] // tm).astype(jnp.int32)                  # [1]
-        if first is not None:
-            # A program none of whose choices is held has no group at all, and
-            # the kernel's block index min(i, n_live - 1) would be -1: the chip
-            # halts on that copy's bounds check (the interpreter clamps it and
-            # says nothing). One tile then counts as live; what it computes lies
-            # in rows of absent choices, which are masked below.
-            n_live = jnp.maximum(n_live, 1)
+            here = (expert >= first) & (expert < first + E)
+            expert = jnp.where(here, expert - first, E)     # absent: last
+        dest, src, tile_expert, n_live = group_layout(
+            expert, E, tm=tm, absent=first is not None)
+        x_pad = _rows(xt, src)                                      # [Tp, D]
 
     w_up, w2, layer = _stacked(lp, layer, gated)
     h = _grouped_matmul(x_pad, w_up, layer, tile_expert, n_live,
@@ -400,12 +375,116 @@ def grouped_experts(lp, xt, top_idx, gates, n_experts: int, *, layer=None,
                               tm=tm, tiles=tiles_down, interpret=interpret)
 
     with scopes.block("ffn.experts.glue"):
-        rows = out_pad[dest].reshape(T, k, D)
-        if first is not None:
+        # Token t's k result rows, times their gates in the rows' dtype,
+        # summed in choice order: one gather and one pass over [k, T, D].
+        rows = _rows(out_pad, dest).reshape(-1, T, D)
+        if here is not None:
             # An absent expert's row lies where no tile wrote: whatever is there.
-            rows = jnp.where(here.reshape(T, k, 1), rows, 0)
-        y = (rows * gates[..., None].astype(xt.dtype)).sum(axis=1)
+            rows = jnp.where(here[..., None], rows, 0)
+        y = (rows * gates.T[..., None].astype(xt.dtype)).sum(axis=0)
         return y.astype(xt.dtype)
+
+
+def _rows(table, index):
+    """``table[index]`` along the first axis, for an index the caller made
+    and knows to lie inside: no wrap of a negative one, no clamp."""
+    return table.at[index.astype(jnp.uint32)].get(mode="promise_in_bounds")
+
+
+def group_layout(expert, n_experts: int, *, tm: int = ROW_TILE,
+                 absent: bool = False):
+    """The group-padded layout of the (choice, token) rows ``expert`` [k, T]
+    (a row's expert, 0 .. E - 1; E: an expert that is not held, where
+    ``absent``), from COUNTING: a row's place is its group's start plus how
+    many earlier rows chose the same expert -- what a stable sort by expert
+    gives -- and a count is a product with a triangle of ones: no scatter
+    makes an index, and small tables are summed under a mask, not gathered.
+    Rows are taken in the order they lie in, a choice at a time. Expert e's
+    rows start at off[e], every group padded up to a multiple of ``tm``; the
+    buffer is static, Tp = k*T + E*tm rows made whole tiles; absent rows lie
+    behind the last group in their own order, where no live tile is.
+
+    Returns (dest [k*T]: a row's place in the buffer; src [Tp]: the token
+    whose row a place holds, some token where it holds none; tile_expert
+    [Tp // tm]: the expert whose group holds a tile's first row; n_live [1]:
+    the tiles that hold rows, at least one where ``absent``), all int32."""
+    k, T = expert.shape
+    E, N = n_experts, k * T
+    G = E + absent                  # the groups counted: absent rows, last
+    i32 = jnp.int32
+
+    # One key a row: its expert, then its choice, then its token, so that the
+    # keys' order is the rows' stable order by expert and a key's low bits
+    # are the row's token.
+    t_bits, j_bits = (max(n - 1, 1).bit_length() for n in (T, k))
+    assert G << (j_bits + t_bits) < 2 ** 31
+    key = (((expert.astype(i32) << j_bits)
+            + jax.lax.broadcasted_iota(i32, (k, T), 0)) << t_bits
+           ) + jax.lax.broadcasted_iota(i32, (k, T), 1)
+    key = key.reshape(-1)                                       # [N]
+
+    # How many rows up to and with row i chose expert g, in two levels: inside
+    # a block of _COUNT_BLOCK rows a product of a triangle of ones with the
+    # rows' one-hot (0 / 1 in bf16, sums in f32: exact), across blocks a
+    # running sum of the blocks' totals.
+    nb = -(-N // _COUNT_BLOCK)
+    blocks = jnp.pad(key >> (j_bits + t_bits), (0, nb * _COUNT_BLOCK - N),
+                     constant_values=G).reshape(nb, _COUNT_BLOCK)
+    hot = blocks[..., None] == jnp.arange(G, dtype=i32)         # [nb, B, G]
+    tri = jnp.tril(jnp.ones((_COUNT_BLOCK,) * 2, jnp.bfloat16))
+    within = jnp.einsum("ij,bjg->big", tri, hot.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32).astype(i32)
+    upto = jnp.cumsum(within[:, -1], axis=0)                    # [nb, G]
+    counts = upto[-1, :E]                                       # [E]
+
+    # off[e], start[e]: the padded and the plain rows of the groups before e.
+    padded = jax.lax.div(counts + (tm - 1), tm) * tm
+    before = jnp.arange(E, dtype=i32) < jnp.arange(E + 1, dtype=i32)[:, None]
+    off, start = (jnp.sum(jnp.where(before, n, 0), axis=-1)
+                  for n in (padded, counts))                    # [E+1] each
+    # A row's place: its group's start, the rows of it in earlier blocks,
+    # the rows of it up to here in this block, less itself.
+    base = off[:G] - 1 + upto - within[:, -1]                   # [nb, G]
+    dest = jnp.sum(jnp.where(hot, within + base[:, None], 0),
+                   axis=-1).reshape(-1)[:N]
+
+    # A tile's expert is the one whose [off[e], off[e+1]) holds its first
+    # row: as many groups end at or before it. The rows that stand before
+    # that group in the sorted order, less those before it in the buffer,
+    # turn a place into a rank; the rows up to and with the group bound it.
+    tile_start = jnp.arange(-(-N // tm) + E, dtype=i32)[:, None] * tm
+    ended, begun = off[1:] <= tile_start, off[:E] <= tile_start  # [tiles, E]
+    tile_expert = jnp.minimum(jnp.sum(ended, axis=-1, dtype=i32), E - 1)
+    shift = jnp.sum(jnp.where(ended, counts - padded, 0), axis=-1)
+    bound = jnp.sum(jnp.where(begun, counts, 0), axis=-1)
+    n_live = jnp.sum(tile_start < off[E:], axis=0, dtype=i32)   # [1]
+    if absent:
+        # A program none of whose choices is held has no group at all, and
+        # the kernel's block index min(i, n_live - 1) would be -1: the chip
+        # halts on that copy's bounds check (the interpreter clamps it and
+        # says nothing). One tile then counts as live; what it computes lies
+        # in places no choice has for its own.
+        n_live = jnp.maximum(n_live, 1)
+
+    # The way in is a gather too, so it needs the inverse: which row stands
+    # r-th in expert e's group. One sort of the keys says it, and a tile's
+    # places are consecutive ranks, tm of them from rank on: they lie in two
+    # consecutive rows of the sorted keys laid tm a row, which are gathered
+    # as ROWS and shifted under a mask. (A gather of single integers is 7 ns
+    # an element on the chip, 58 us a layer at 8,192 places, and a slice at
+    # a rank of its own a loop of 0.8 us a tile; PERF.md section 6, PR 55.)
+    rows = N // tm + 2
+    order = jnp.pad(jnp.sort(key, stable=False), (0, rows * tm - N))
+    rank = jnp.minimum(tile_start + shift[:, None], N)          # [tiles, 1]
+    row, lane = jax.lax.div(rank, tm), jax.lax.rem(rank, tm)
+    pair = _rows(order.reshape(rows, tm),
+                 jnp.concatenate([row, row + 1], axis=1))       # [tiles, 2, tm]
+    at = jnp.arange(tm, dtype=i32)
+    picks = (lane + at)[..., None] == jnp.arange(2 * tm, dtype=i32)
+    token = jnp.sum(jnp.where(picks, pair.reshape(-1, 1, 2 * tm), 0),
+                    axis=-1) & ((1 << t_bits) - 1)              # [tiles, tm]
+    src = jnp.where(rank + at < bound[:, None], token, 0)
+    return dest, src.reshape(-1), tile_expert, n_live
 
 
 def chosen_experts(lp, xt, local, gates, n_experts: int, *, layer=None,

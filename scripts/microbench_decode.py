@@ -31,6 +31,11 @@ too. Then it checks the mathematics (``--moe-check-seeds``): the layer's
 output, grouped and dense against a plain float32 FFN, and the last token's
 logits through the four-layer model, grouped against dense.
 
+``--moe-glue`` books one grouped expert layer's device time op by op (the
+XLA side around the two kernels: names, microseconds, XLA's reckoned bytes;
+``--moe-interpret --moe-glue-shapes tiny --moe-glue-tokens 64
+--moe-glue-iters 1`` rehearses it here, without a table).
+
 ``--moe-decode`` times the held experts of one expert layer at DECODE shapes
 instead (a chip that holds a range of the experts its router scores, 2-128
 rows, the widths of the benchmark's four such configurations): the dense
@@ -86,6 +91,8 @@ Usage: python scripts/microbench_decode.py [--model qwen3-4b]
            [--kv-window 4096] [--config-file FILE] [--attn-only]
        python scripts/microbench_decode.py --moe [--moe-tokens 256,512,1024]
            [--moe-candidates] [--moe-check-seeds 0,1]
+       python scripts/microbench_decode.py --moe-glue
+           [--moe-glue-shapes lfm2,kimi,longcat,mixtral]
        python scripts/microbench_decode.py --moe-decode [--moe-candidates]
            [--moe-decode-configs longcat-flash-omni-cut,...]
            [--moe-decode-rows 2,8,16,32,64,128]
@@ -308,6 +315,117 @@ def moe_main(args):
                  same_argmax=bool(last["grouped"].argmax()
                                   == last["dense"].argmax()))
         del params
+
+
+def moe_glue_main(args):
+    """The grouped experts' XLA side, op by op: one expert layer of 1,024
+    rows at the widths of ``--moe-glue-shapes`` through
+    ops/pallas_moe.grouped_experts under the profiler, every executed op
+    booked by its name (microseconds a layer, XLA's reckoned bytes a layer,
+    whether it stands in the ``ffn.experts.glue`` scope), then the sums: the
+    glue, the two kernels, the whole layer. Run it in the parent's checkout
+    too (copy this file there): the function's name and arguments are the
+    parent's."""
+    import glob
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from llm_d_inference_scheduler_tpu.ops import pallas_moe
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench"))
+    import trace_scopes
+
+    T, iters = args.moe_glue_tokens, args.moe_glue_iters
+    for name in args.moe_glue_shapes.split(","):
+        # Experts held, choices a token, widths, the router's outputs a
+        # choice is drawn from (more than held: a held range of them).
+        E, k, D, F, routed_over = _GLUE_SHAPES[name]
+        keys = jax.random.split(jax.random.key(args.seed), 6)
+        init = lambda key, shape: jax.random.normal(
+            key, shape, jnp.bfloat16) * shape[-2] ** -0.5
+        lp = {"w1": init(keys[0], (E, D, F)), "w3": init(keys[1], (E, D, F)),
+              "w2": init(keys[2], (E, F, D))}
+        x = jax.random.normal(keys[3], (T, D), jnp.bfloat16)
+        top, idx = jax.lax.top_k(
+            jax.random.normal(keys[4], (T, routed_over), jnp.float32), k)
+        gates = jax.nn.softmax(top, axis=-1)
+        held = routed_over != E
+        first = routed_over // 2 if held else None
+        live = int(((idx >= (first or 0)) & (idx < (first or 0) + E)).sum())
+        fn = jax.jit(lambda lp, x, idx, gates: pallas_moe.grouped_experts(
+            lp, x, idx, gates, E, first=first,
+            interpret=args.moe_interpret))
+        ms = timeit(fn, lp, x, idx, gates, iters=iters)
+        out = dict(component="moe_glue", shape=name, T=T, E=E, k=k, D=D, F=F,
+                   routed_over=routed_over, live_rows=live,
+                   buffer_rows=T * k + E * pallas_moe.ROW_TILE,
+                   wall_ms=round(ms, 4))
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with jax.profiler.trace(trace_dir):
+                for _ in range(iters):
+                    y = fn(lp, x, idx, gates)
+                jax.block_until_ready(y)
+            paths = sorted(glob.glob(os.path.join(
+                trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+            planes = [p for p in trace_scopes.read_planes(paths[-1])
+                      if p["ops"]] if paths else []
+        if not planes:      # the CPU: no device plane, nothing to book
+            print(json.dumps(dict(out, ops=None)), flush=True)
+            continue
+        plane = planes[0]
+        ops = {}
+        for mid, _, duration in plane["ops"]:
+            md = plane["op_metadata"][str(mid)]
+            if trace_scopes._is_control_flow(md.get("name", "")):
+                continue
+            op, _, text = md.get("name", "").partition(" = ")
+            op = op.lstrip("%")
+            kind = ("kernel" if "moe_grouped" in op else
+                    "glue" if trace_scopes.scope_of(md.get("tf_op") or "")
+                    == "ffn.experts.glue" else "other")
+            row = ops.setdefault(op, [kind, 0, 0.0, 0, text.split("{")[0]])
+            row[1] += 1
+            row[2] += duration / 1e6                # ps -> us
+            row[3] += md.get("bytes_accessed") or 0
+        sums = {kind: [0, 0.0, 0] for kind in ("glue", "kernel", "other")}
+        for op, (kind, calls, us, nbytes, result) in sorted(
+                ops.items(), key=lambda kv: -kv[1][2]):
+            print(json.dumps(dict(
+                component="moe_glue_op", shape=name, op=op, kind=kind,
+                result=result,
+                calls_a_layer=round(calls / iters, 2),
+                us_a_layer=round(us / iters, 2),
+                xla_mb_a_layer=round(nbytes / iters / 1e6, 2))), flush=True)
+            for i, v in enumerate((calls, us, nbytes)):
+                sums[kind][i] += v / iters
+        print(json.dumps(dict(
+            out,
+            glue_ops=round(sums["glue"][0], 1),
+            glue_us=round(sums["glue"][1], 1),
+            glue_xla_mb=round(sums["glue"][2] / 1e6, 1),
+            kernels_us=round(sums["kernel"][1], 1),
+            other_us=round(sums["other"][1], 1),
+            layer_us=round(sum(v[1] for v in sums.values()), 1))), flush=True)
+        del lp
+
+
+# --moe-glue's shapes: (experts held, choices a token, d_model, an expert's
+# width, router outputs a choice is drawn from) of the four cells whose
+# glue differs most: 32 and 64 narrow groups all held (lfm2-8b-a1b-cut,
+# kimi-vl-a3b-cut), 16 of LongCat's 768 outputs held at its width (about 2%
+# of the rows live), Mixtral's 8 wide groups; and two more held ranges.
+_GLUE_SHAPES = {
+    "lfm2": (32, 4, 2048, 1792, 32),
+    "kimi": (64, 6, 2048, 1408, 64),
+    "longcat": (16, 12, 6144, 2048, 768),
+    "mixtral": (8, 2, 4096, 14336, 8),
+    "dots3": (32, 8, 5120, 1536, 256),
+    "deepseek": (16, 8, 7168, 2048, 256),
+    "tiny": (4, 2, 128, 256, 8),      # the CPU rehearsal's
+}
 
 
 def moe_decode_main(args):
@@ -1141,6 +1259,13 @@ def main(argv=None):
     ap.add_argument("--moe-interpret", action="store_true",
                     help="interpret the kernels: rehearses the control flow "
                          "on the CPU; its times mean nothing")
+    ap.add_argument("--moe-glue", action="store_true",
+                    help="book one grouped expert layer's device time op "
+                         "by op instead: the XLA side, the kernels, the "
+                         "whole (from a profiler trace)")
+    ap.add_argument("--moe-glue-shapes", default="lfm2,kimi,longcat,mixtral")
+    ap.add_argument("--moe-glue-tokens", type=int, default=1024)
+    ap.add_argument("--moe-glue-iters", type=int, default=20)
     ap.add_argument("--moe-decode", action="store_true",
                     help="time the held experts of one expert layer at "
                          "decode shapes instead: dense over all of them "
@@ -1268,6 +1393,8 @@ def main(argv=None):
     )
 
     configure_compile_cache()
+    if args.moe_glue:
+        return moe_glue_main(args)
     if args.moe_decode:
         return moe_decode_main(args)
     if args.moe:

@@ -58,6 +58,8 @@ def test_grouped_matches_dense(shape):
     (4, 1, (2,)),        # every token on one expert: maximally ragged groups
     (8, 2, (2, 5)),      # every token to the same two experts, six empty
     (8, 2, None),        # one expert never chosen
+    (8, 3, (7, 0, 4)),   # the last and the first expert: the groups' ends
+    (16, 4, (15, 0, 8, 3)),   # four of sixteen, the first and last among them
 ])
 def test_grouped_skewed_routing(E, k, favoured):
     """Routing skewed to the limit drops no token: the layout is static and
@@ -268,6 +270,8 @@ def _plain_experts(lp, x, idx, gates, first, count, gated, reglu=False):
     (4, 4, 16),      # the second quarter of sixteen
     (12, 4, 16),     # the last quarter: absent rows sort behind nothing
     (3, 2, 8),       # a range aligned to nothing
+    (0, 1, 16),      # one held expert: a single group, most rows absent
+    (5, 3, 8),       # the range's end is the router's last output
 ])
 def test_grouped_experts_on_a_held_range(act, first, count, routed_over):
     """Routing is over all the experts; the rows of absent ones are dropped
@@ -343,6 +347,115 @@ def test_every_choice_absent_keeps_the_kernels_block_indices_in_the_buffer(
         assert 1 <= live <= tiles
         assert ((0 <= tile_expert) & (tile_expert < 2)).all()
     assert [live for _, live, _ in seen] == [1, 1, 3, 3, 6, 6]
+
+
+# ---------- the layout from counting against the sort-built one ----------
+
+def _sorted_layout(flat_expert, E, tm):
+    """The group-padded layout as PR 31-54 built it: a stable sort of the
+    rows by expert, a bincount, two cumulative sums and two integer
+    scatters. ``flat_expert`` [N] with E for a row whose expert is absent.
+    Returns (dest [N], the row that stands at each place of the buffer or -1
+    [Tp], tile_expert, n_live before the floor of one tile)."""
+    N = flat_expert.shape[0]
+    order = jnp.argsort(flat_expert, stable=True)
+    sorted_expert = flat_expert[order]
+    counts = jnp.bincount(flat_expert, length=E)
+    padded = ((counts + tm - 1) // tm) * tm
+    zero = jnp.zeros((1,), counts.dtype)
+    off = jnp.concatenate([zero, jnp.cumsum(padded)])
+    start = jnp.concatenate([zero, jnp.cumsum(counts)])
+    dest_sorted = off[sorted_expert] + jnp.arange(N) - start[sorted_expert]
+    Tp = N + E * tm
+    row_at = jnp.full((Tp + N,), -1, jnp.int32).at[dest_sorted].set(
+        order.astype(jnp.int32))[:Tp]
+    dest = jnp.zeros((N,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    tile_starts = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(off[1:], tile_starts, side="right"), E - 1)
+    return (np.asarray(dest), np.asarray(row_at), np.asarray(tile_expert),
+            int(off[E] // tm))
+
+
+def _routing(kind, T, k, E, routed_over, seed):
+    """[k, T] experts 0 .. E - 1, E where a choice's expert is not held."""
+    rng = np.random.default_rng(seed)
+    if kind == "random":
+        idx = np.argsort(rng.random((T, routed_over)), axis=-1)[:, :k]
+    elif kind == "skewed":          # most tokens on the first two experts
+        pull = (np.arange(routed_over) < 2) * (rng.random((T, 1)) < 0.9)
+        idx = np.argsort(rng.random((T, routed_over)) - pull, axis=-1)[:, :k]
+    elif kind == "one_takes_all":   # every token's first choice is expert 1
+        idx = np.argsort(rng.random((T, routed_over)), axis=-1)[:, :k]
+        idx = np.where(idx == 1, idx[:, :1], idx)
+        idx[:, 0] = 1
+    elif kind == "all_absent":
+        idx = np.full((T, k), routed_over)
+    return jnp.asarray(np.where(idx < E, idx, E).T, jnp.int32)
+
+
+@pytest.mark.parametrize("T,k,E,routed_over,tm", [
+    (37, 3, 4, 4, 8),        # every expert held; rows that fill no count block
+    (256, 4, 32, 32, 16),    # lfm2's ratios: 32 narrow groups, 8 count blocks
+    (200, 6, 8, 64, 8),      # a held range: most choices absent
+    (64, 2, 3, 5, 128),      # the served row tile, groups far under a tile
+])
+@pytest.mark.parametrize("kind", ["random", "skewed", "one_takes_all",
+                                  "all_absent"])
+def test_the_counted_layout_is_the_sorted_one(kind, T, k, E, routed_over, tm):
+    """Row for row: a row's place, the row at each place that holds one,
+    the tile -> expert map and the live tiles are what the stable sort and
+    the scatters gave, at every skew; no row is dropped and no two share a
+    place."""
+    from llm_d_inference_scheduler_tpu.ops.pallas_moe import group_layout
+
+    absent = routed_over != E or kind == "all_absent"
+    if kind == "all_absent" and routed_over == E:
+        pytest.skip("a chip that holds every expert has no absent choice")
+    expert = _routing(kind, T, k, E, routed_over, seed=T + k)
+    dest, src, tile_expert, n_live = (np.asarray(a) for a in jax.jit(
+        lambda e: group_layout(e, E, tm=tm, absent=absent))(expert))
+    flat = np.asarray(expert).reshape(-1)
+    want_dest, row_at, want_tiles, want_live = _sorted_layout(
+        jnp.asarray(flat), E, tm)
+    np.testing.assert_array_equal(dest, want_dest)
+    # (Whole tiles: one more than the sorted one's where k*T fills no tile.)
+    np.testing.assert_array_equal(tile_expert[:want_tiles.size], want_tiles)
+    assert tile_expert.size == -(-flat.size // tm) + E
+    assert n_live[0] == max(want_live, 1 if absent else 0)
+    assert len(set(dest.tolist())) == dest.size      # nobody shares a place
+    held = flat < E
+    assert (dest[held] < want_live * tm).all()           # inside a live tile
+    assert (tile_expert[dest[held] // tm] == flat[held]).all()
+    assert (dest[~held] >= want_live * tm).all()     # behind the last group
+    # The way in: a place that holds a row names that row's token (a row of
+    # the choice-major order is token row % T).
+    holds = row_at >= 0
+    holds[want_live * tm:] = False          # absent rows: no tile reads them
+    assert src.size == -(-row_at.size // tm) * tm        # whole tiles
+    np.testing.assert_array_equal(src[:row_at.size][holds], row_at[holds] % T)
+    assert ((0 <= src) & (src < T)).all()
+    if kind == "one_takes_all":
+        assert np.bincount(flat, minlength=E + 1)[1] == T
+
+
+def test_the_layout_is_made_without_a_scatter_or_a_loop():
+    """What ISSUE 55 took out stays out: the layout's program holds one
+    sort, and no scatter (an integer scatter is a few microseconds of fixed
+    cost on the chip whatever its size), no while (a searchsorted) and no
+    second sort."""
+    from llm_d_inference_scheduler_tpu.ops.pallas_moe import group_layout
+
+    for absent in (False, True):
+        text = jax.jit(lambda e: group_layout(e, 32, absent=absent)).lower(
+            jax.ShapeDtypeStruct((4, 1024), jnp.int32)).as_text()
+        assert "scatter" not in text and "while" not in text
+        assert text.count("stablehlo.sort") == 1
+        assert text.count("stablehlo.dot_general") == 1   # the count
+        # One gather of integers (a place's row out of the sorted keys); the
+        # two of D-wide rows are the caller's.
+        assert text.count('"stablehlo.gather"(') == 1
 
 
 # ---------- few rows: dense over the held experts that a row chose ----------
