@@ -50,17 +50,9 @@ class EngineConfig:
     checkpoint_path: str = ""     # orbax dir; empty = random init (dev/bench)
     enable_prefix_caching: bool = True  # automatic prefix caching (block reuse)
     warmup: bool = False          # compile prefill/decode/sample before serving
-    # Batched prefill: admit up to N same-bucket plain prompts per fused
-    # prefill dispatch ([N, S] forward instead of N × [1, S]) — prefill is
-    # HBM-bound at serving prompt lengths, so one weights pass covers N
-    # prompts. Partial groups pad up to N (padding rows write the trash
-    # block), so exactly ONE extra traced shape per bucket. Prompts with a
-    # prefix-cache hit, multimodal embeds, or a cache probe keep the
-    # single-dispatch paths; pp engines always dispatch singly (the stage
-    # ring prefill is traced at [1, S]). 1 = classic per-prompt prefill.
-    prefill_batch: int = 1
-    # Incremental prefill for LONG prompts: when > 0, a prompt whose
-    # un-cached suffix exceeds this many tokens prefills in windows of this
+    # Every text prompt is written into its pages in windows, by one
+    # function (TpuEngine._write_prefill_window). When > 0, a prompt whose
+    # un-cached suffix exceeds this many tokens is written in windows of this
     # size (rounded up to a KV-block multiple), one window an engine step
     # for each slot still prefilling (at most core.PREFILL_STEP_TOKENS of
     # prompt a step), interleaved with the decode chunks of established
@@ -68,9 +60,9 @@ class EngineConfig:
     # ~one window, or that budget when several lanes wait, instead of the
     # full prompt. Windows after the first ride the
     # prefix-continuation jits (the same O(prefix) path prefix-cache hits
-    # use). 0 = classic whole-prompt prefill. Multimodal prompts always
-    # prefill whole (the embed splice targets absolute positions in the
-    # first forward).
+    # use). A suffix that fits has one window, written as it is admitted.
+    # 0 = one window a prompt. Multimodal prompts always have one (the
+    # embed splice targets absolute positions in the first forward).
     prefill_chunk: int = 0
     # Secure serving for the engine's HTTP surface (the in-cluster legs the
     # sidecar's use-tls-for-prefiller/decoder knobs target): cert dir with

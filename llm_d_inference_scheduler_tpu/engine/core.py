@@ -143,19 +143,17 @@ class _Slot:
     # TpuEngine._slot_tokens); _finalize_prefills() lands it on the host
     # afterwards, off the dispatch critical path.
     pending_tok: Any = None
-    # Row of this slot's first token inside pending_tok (batched prefill
-    # shares one [N] device array across the group; singles use row 0).
-    pending_idx: int = 0
     prompt_len: int = 0
-    # Incremental (chunked) prefill: while True the slot is excluded from
-    # decode batches; _advance_prefills writes it a window at a time between
-    # decode chunks, so long prompts never stall the decode lanes for their
-    # full length.
+    # The prompt is written in windows (_write_prefill_window): while True
+    # the slot is excluded from decode batches. A prompt of one window is
+    # written as it is admitted; a longer one a window at a time between
+    # decode chunks (_advance_prefills), so long prompts never stall the
+    # decode lanes for their full length.
     prefilling: bool = False
     prefill_rest: list[int] = dataclasses.field(default_factory=list)
     prefill_written: int = 0
-    # (hashes, caching) — prefix-cache commit + KV-event publication are
-    # deferred until the last window lands.
+    # (hashes, caching) — prefix-cache commit + KV-event publication wait
+    # for the last window.
     chunk_meta: Any = None
 
 
@@ -645,21 +643,32 @@ class TpuEngine:
                                jnp.zeros((K, tokens.size), tokens.dtype)))
         return toks, k_pages, v_pages
 
-    def _prefill_fn(self, bucket: int):
+    def _prefill_fn(self, bucket: int, mm_bucket: int | None = None):
         """Per-bucket jitted prefill: forward + KV scatter + fused first-token
         sample (one dispatch covers prefill AND the first token — no separate
-        sampler round-trip on the TTFT path)."""
-        if bucket not in self._prefill_fns and self.pp_mesh is not None:
+        sampler round-trip on the TTFT path). With ``mm_bucket`` the program
+        takes two more operands behind seq_len (E/P/D phase 2): encoder
+        vectors that overwrite the placeholder-token embeddings at their
+        positions; padding entries point out of range and are dropped by
+        the scatter."""
+        mm = mm_bucket is not None
+        name = (f"mm_prefill_b{bucket}_m{mm_bucket}" if mm
+                else f"prefill_b{bucket}")
+        fn_key = ("mm", bucket, mm_bucket) if mm else bucket
+        if fn_key not in self._prefill_fns and self.pp_mesh is not None:
             from ..parallel.pp_serve import make_pp_prefill
 
-            self._prefill_fns[bucket] = make_pp_prefill(self.mcfg,
-                                                        self.pp_mesh, bucket)
-        if bucket not in self._prefill_fns:
-            def impl(params, tokens, seq_len, k_pages, v_pages, block_table_row,
-                     key, temps, top_k, top_p):
+            self._prefill_fns[fn_key] = make_pp_prefill(
+                self.mcfg, self.pp_mesh, bucket, mm=mm)
+        if fn_key not in self._prefill_fns:
+            def impl(params, tokens, seq_len, *rest):
+                (*mm_ops, k_pages, v_pages, block_table_row,
+                 key, temps, top_k, top_p) = rest
                 logits, (k_new, v_new) = self.model.forward(
                     params, self.bound.model_for(tokens.size), tokens,
-                    want_kv=True, seq_len=seq_len)
+                    want_kv=True, **(
+                        dict(zip(("mm_embeds", "mm_positions"), mm_ops))
+                        if mm else {"seq_len": seq_len}))
                 with scopes.block("kv.write"):
                     k_pages, v_pages = pages.write_sequences(
                         k_pages, v_pages, k_new, v_new, block_table_row,
@@ -670,41 +679,10 @@ class TpuEngine:
                 with scopes.block("sample"):
                     tok = sample_tokens(last, key, temps, top_k, top_p)
                 return tok, k_pages, v_pages
-            self._prefill_fns[bucket] = jax.jit(
-                _named(impl, f"prefill_b{bucket}"), donate_argnums=(3, 4))
-        return self._prefill_fns[bucket]
-
-    def _mm_prefill_fn(self, bucket: int, mm_bucket: int):
-        """Prefill with multimodal embedding injection (E/P/D phase 2):
-        encoder vectors overwrite the placeholder-token embeddings; padding
-        entries point out of range and are dropped by the scatter."""
-        key = ("mm", bucket, mm_bucket)
-        if key not in self._prefill_fns and self.pp_mesh is not None:
-            from ..parallel.pp_serve import make_pp_prefill
-
-            self._prefill_fns[key] = make_pp_prefill(self.mcfg, self.pp_mesh,
-                                                     bucket, mm=True)
-        if key not in self._prefill_fns:
-            def impl(params, tokens, seq_len, mm_embeds, mm_positions,
-                     k_pages, v_pages, block_table_row,
-                     rng, temps, top_k, top_p):
-                logits, (k_new, v_new) = self.model.forward(
-                    params, self.bound.model_for(tokens.size), tokens,
-                    want_kv=True, mm_embeds=mm_embeds, mm_positions=mm_positions)
-                with scopes.block("kv.write"):
-                    k_pages, v_pages = pages.write_sequences(
-                        k_pages, v_pages, k_new, v_new, block_table_row,
-                        seq_len)
-                with scopes.block("head"):
-                    last = jnp.take_along_axis(
-                        logits, (seq_len - 1)[:, None, None], axis=1)[:, 0]
-                with scopes.block("sample"):
-                    tok = sample_tokens(last, rng, temps, top_k, top_p)
-                return tok, k_pages, v_pages
-            self._prefill_fns[key] = jax.jit(
-                _named(impl, f"mm_prefill_b{bucket}_m{mm_bucket}"),
-                donate_argnums=(5, 6))
-        return self._prefill_fns[key]
+            self._prefill_fns[fn_key] = jax.jit(
+                _named(impl, name),
+                donate_argnums=(5, 6) if mm else (3, 4))
+        return self._prefill_fns[fn_key]
 
     def _prefix_prefill_fn(self, suffix_bucket: int, prefix_bucket: int):
         """Jitted prefill continuing from cached prefix KV, keyed on
@@ -978,17 +956,6 @@ class TpuEngine:
             row=np.zeros((1, self.max_blocks_per_seq), np.int32),
             slots=nobody, warm=True, **self._window_tables([], 1),
             **self._sample_np([_DUMMY_REQ])))
-        if self.cfg.prefill_batch > 1 and self.pp_mesh is None:
-            # Batched prefill pads every group to exactly prefill_batch rows,
-            # so ONE extra traced shape per bucket covers it.
-            K = self.cfg.prefill_batch
-            self._device_call(("prefill", bucket), dict(
-                tokens=np.zeros((K, bucket), np.int32),
-                seq_len=np.ones((K,), np.int32),
-                row=np.zeros((K, self.max_blocks_per_seq), np.int32),
-                slots=np.full((K,), B, np.int32),
-                warm=True, **self._window_tables([], K),
-                **self._sample_np([_DUMMY_REQ] * K)))
         if self._prefill_window():
             # Incremental prefill's mid-stream shapes: every intermediate
             # window is FULL-width, so precompiling (win_bucket × pb ladder)
@@ -1433,7 +1400,6 @@ class TpuEngine:
         is the successor's from its prefill on, by the queue's order alone;
         its pages are its own, the predecessor keeps its blocks until its
         last chunk is booked (_place, _book_chunk)."""
-        group: list[tuple[int, EngineRequest, Any, Any, int]] = []
         empty, vacating = self._open_slots()
         for i in empty + vacating:
             when = "after" if self.slots[i] is None else "ahead"
@@ -1468,122 +1434,20 @@ class TpuEngine:
                     self._note_admission(req)
                     self._start_kv_fetch(req, out, loop)
                     continue
-                available = getattr(self.allocator, "reusable_blocks",
-                                    self.allocator.free_blocks)
-                # Blocks the collected-but-not-yet-allocated group will claim
-                # count against capacity (allocation is deferred to the
-                # flush; only this thread allocates between here and there).
-                if need + sum(g[4] for g in group) > available:
+                if need > getattr(self.allocator, "reusable_blocks",
+                                  self.allocator.free_blocks):
                     break  # head-of-line waits for capacity
                 self._waiting.pop(0)
                 self.telemetry.waiting.set(len(self._waiting))
                 self._note_admission(req)
                 self.telemetry.slot_refills[when].inc()
                 self.telemetry.admissions[at].inc()
-            group.append((i, req, out, loop, need))
-        self._flush_admissions(group)
-
-    def _flush_admissions(self, group):
-        """Dispatch collected admissions: same-bucket plain prompts batch
-        into one [N, S] prefill (cfg.prefill_batch rows, padded); everything
-        else — multimodal, cache probes, prefix-cache hits, in-group
-        duplicate prompts, solo entries, pp engines — takes the classic
-        single-dispatch paths. Batches go first so reroutes (duplicates /
-        hits) see the hashes the batch just committed. Any dispatch failure
-        cleans up EVERY not-yet-dispatched entry (they are already off
-        _waiting, so nothing else can reach them)."""
-        K = max(self.cfg.prefill_batch, 1)
-        # singles: (i, req, out, loop, need, precomputed|None)
-        singles: list[tuple] = []
-        by_bucket: dict[int, list] = {}
-        for i, req, out, loop, need in group:
-            if (K <= 1 or self.pp_mesh is not None
-                    or req.mm_embeds is not None
-                    or req.cache_hit_threshold is not None
-                    or (req.kv_transfer_params or {}).get("do_remote_decode")):
-                singles.append((i, req, out, loop, need, None))
-                continue
-            pre = self._prompt_and_hashes(req)
-            win = self._prefill_window()
-            if win and len(pre[0]) > win:
-                # Long prompt: the single path chunks it incrementally.
-                singles.append((i, req, out, loop, need, pre))
-                continue
-            by_bucket.setdefault(self._bucket(len(pre[0])), []).append(
-                (i, req, out, loop, need, pre))
-        # batches: (bucket, [(i, req, out, loop, prompt, hashes, blocks)])
-        batches: list[tuple[int, list]] = []
-        seen_chains: set[tuple] = set()
-        for bucket, entries in by_bucket.items():
-            while entries:
-                chunk, entries = entries[:K], entries[K:]
-                if len(chunk) == 1:
-                    # Solo prompt: the already-traced [1, S] path is cheaper
-                    # than a padded [K, S] dispatch (nothing allocated yet).
-                    singles.append(chunk[0])
-                    continue
-                prepared = []
-                for i, req, out, loop, need, pre in chunk:
-                    prompt, hashes, caching = pre
-                    if hashes and tuple(hashes) in seen_chains:
-                        blocks = None  # duplicate: prefix-hit off the batch
-                    else:
-                        blocks = self._try_prepare_batch_entry(
-                            req, need, prompt, hashes, caching)
-                    if blocks is None:
-                        singles.append((i, req, out, loop, need, pre))
-                        continue
-                    if hashes:
-                        seen_chains.add(tuple(hashes))
-                    prepared.append((i, req, out, loop, need, pre, blocks))
-                if len(prepared) == 1:
-                    # Reroutes shrank the chunk to one survivor: demote it to
-                    # the [1, S] single path too (give back its blocks — the
-                    # single path allocates its own, possibly fewer after a
-                    # prefix match).
-                    i, req, out, loop, need, pre, blocks = prepared[0]
-                    with self._cond:
-                        self.allocator.free(blocks)
-                        self.telemetry.observe_allocator(self.allocator)
-                    singles.append((i, req, out, loop, need, pre))
-                elif prepared:
-                    batches.append((bucket, prepared))
-        n_done = 0
-        try:
-            for bucket, prepared in batches:
-                self._run_batched_prefill(bucket, prepared)
-                n_done += 1
-            while singles:
-                i, req, out, loop, need, pre = singles.pop(0)
-                self._prefill_into_slot(i, req, out, loop, need,
-                                        precomputed=pre)
-        except Exception:
-            # The failing dispatch cleaned up its own entries; the rest
-            # would orphan without this (clients awaiting forever, blocks
-            # leaked).
-            leftover = batches[n_done + 1:] if n_done < len(batches) \
-                else []
-            with self._cond:
-                for _, prepared in leftover:
-                    for *_x, blocks in prepared:
-                        self.allocator.free(blocks)
-                self.telemetry.observe_allocator(self.allocator)
-            for _, prepared in leftover:
-                for i, req, out, loop, need, pre, blocks in prepared:
-                    self._emit_to(out, loop, TokenEvent(
-                        request_id=req.request_id, token_id=None,
-                        finish_reason=FinishReason.ABORT,
-                        prompt_tokens=len(pre[0])))
-            for i, req, out, loop, need, pre in singles:
-                self._emit_to(out, loop, TokenEvent(
-                    request_id=req.request_id, token_id=None,
-                    finish_reason=FinishReason.ABORT,
-                    prompt_tokens=len(req.prompt_token_ids)))
-            raise
+            # A dispatch that fails has cleaned up after its own request and
+            # raises; the requests behind it still wait.
+            self._prefill_into_slot(i, req, out, loop, need)
 
     def _prompt_and_hashes(self, req):
-        """Truncated prompt + content-hash chain + caching gate — shared by
-        the single and batched prefill paths so they cannot drift."""
+        """Truncated prompt + content-hash chain + caching gate."""
         prompt = req.prompt_token_ids[: self.cfg.max_model_len - 1]
         if len(prompt) < len(req.prompt_token_ids):
             # Last-resort guard for direct submit() callers; the HTTP surface
@@ -1605,25 +1469,6 @@ class TpuEngine:
                    and not (self.geom.state or self.geom.window))
                   else [])
         return prompt, hashes, caching
-
-    def _try_prepare_batch_entry(self, req, need: int, prompt, hashes,
-                                 caching: bool):
-        """Allocation for a batchable plain prefill. Returns the block list,
-        or None when a prefix-cache hit makes the O(prefix) single-dispatch
-        path the better deal."""
-        block = self.mcfg.kv_block_size
-        with self._cond:
-            if caching and hashes:
-                max_match = (len(prompt) - 1) // block
-                if self.allocator.match_prefix(hashes)[:max_match]:
-                    return None
-            blocks = self.allocator.alloc(need)
-            evicted = list(getattr(self.allocator, "last_evicted_hashes", []))
-            self.telemetry.observe_allocator(self.allocator)
-        if evicted and self.kv_events is not None:
-            self.kv_events.removed(evicted)
-        self._note_table(blocks)
-        return blocks
 
     def _window_tables(self, steps, rows: int) -> dict:
         """What rides with a program where some cache layers keep a window
@@ -1659,87 +1504,6 @@ class TpuEngine:
         self.telemetry.kv_table_groups["run"].inc(runs)
         self.telemetry.kv_table_groups["split"].inc(splits)
 
-    def _run_batched_prefill(self, bucket: int, entries: list[tuple]):
-        """One fused [K, bucket] prefill dispatch for up to K plain prompts.
-        Rows pad to cfg.prefill_batch (seq_len 1 + all-zero table → the one
-        garbage token writes the trash block), so the jit traces exactly one
-        batched shape per bucket. Slot bookkeeping mirrors the single path;
-        each slot lands PENDING with its row index into the shared token
-        array."""
-        K = self.cfg.prefill_batch
-        block = self.mcfg.kv_block_size
-        try:
-            # Staging is inside the try: a bad sampling knob on ONE request
-            # (e.g. non-numeric temperature from a direct submit() caller)
-            # must clean up the whole group like the single path would.
-            tokens = np.zeros((K, bucket), np.int32)
-            seq_len = np.ones((K,), np.int32)
-            rows = np.zeros((K, self.max_blocks_per_seq), np.int32)
-            slots = np.full((K,), self.cfg.max_batch, np.int32)
-            for k, (i, req, _, _, need, pre, blocks) in enumerate(entries):
-                prompt = pre[0]
-                tokens[k, : len(prompt)] = prompt
-                seq_len[k] = len(prompt)
-                rows[k, : len(blocks)] = blocks
-                slots[k] = i
-            reqs = [e[1] for e in entries]
-            samp = self._sample_np(reqs + [_DUMMY_REQ] * (K - len(reqs)))
-            tok_dev = self._device_call(("prefill", bucket), dict(
-                tokens=tokens, seq_len=seq_len, row=rows, slots=slots,
-                **self._window_tables(
-                    [(e[6], 0, len(e[5][0]), True) for e in entries], K),
-                **samp))
-        except Exception:
-            with self._cond:
-                for *_, blocks in entries:
-                    self.allocator.free(blocks)
-                self.telemetry.observe_allocator(self.allocator)
-            for _, req, out, loop, need, pre, _ in entries:
-                self._emit_to(out, loop, TokenEvent(
-                    request_id=req.request_id, token_id=None,
-                    finish_reason=FinishReason.ABORT,
-                    prompt_tokens=len(pre[0])))
-            raise
-        caching = isinstance(self.allocator, PrefixCachingAllocator)
-        try:
-            for k, (i, req, out, loop, need, pre, blocks) in enumerate(entries):
-                prompt, hashes, _ = pre
-                self.telemetry.prompt_tokens.inc(len(prompt))
-                # Batched entries are hit-free by construction (_flush_
-                # admissions reroutes prefix hits to the single path) but
-                # still count into the admitted-token denominator.
-                self._note_prefix_hit(req.request_id, 0, len(prompt))
-                slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
-                             position=len(prompt), generated=[],
-                             cached_tokens=0, pending_tok=tok_dev, pending_idx=k,
-                             prompt_len=len(prompt))
-                n_complete = len(prompt) // block
-                if caching:
-                    with self._cond:
-                        self.allocator.commit_hashes(blocks[:n_complete],
-                                                     hashes[:n_complete])
-                slot.block_hashes = hashes[:n_complete]
-                if self.kv_events is not None and slot.block_hashes:
-                    self.kv_events.stored(slot.block_hashes)
-                self._place(i, slot)
-        except BaseException:
-            # Post-dispatch bookkeeping failed (hash commit / event publish):
-            # the dispatch itself landed, but entries not yet slotted would
-            # leak their blocks and strand their clients. Clean
-            # up every entry whose slot assignment did not happen.
-            for i, req, out, loop, need, pre, blocks in entries:
-                s = self.slots[i]
-                if s is not None and s.req is req:
-                    continue  # fully slotted before the failure
-                with self._cond:
-                    self.allocator.free(blocks)
-                    self.telemetry.observe_allocator(self.allocator)
-                self._emit_to(out, loop, TokenEvent(
-                    request_id=req.request_id, token_id=None,
-                    finish_reason=FinishReason.ABORT,
-                    prompt_tokens=len(pre[0])))
-            raise
-
     # ---- prefill -------------------------------------------------------
 
     def _place(self, idx: int, slot: _Slot) -> None:
@@ -1751,8 +1515,12 @@ class TpuEngine:
         self.slots[idx] = slot
         self.telemetry.running.set(sum(s is not None for s in self.slots))
 
-    def _prefill_into_slot(self, idx, req, out, loop, need: int,
-                           precomputed=None):
+    def _prefill_into_slot(self, idx, req, out, loop, need: int):
+        """Admit req into slot idx: its block table (the longest cached run
+        of whole prompt blocks, then new ones) and the slot, parked
+        PREFILLING with what is left of the prompt to write. A rest of one
+        window is written here and now; a longer one a window a step
+        (_advance_prefills)."""
         if (self._dist and self.kv_transfer_server is None
                 and (req.kv_transfer_params or {}).get("do_remote_decode")):
             # Multi-host staging is shard-registered on every process's
@@ -1768,14 +1536,11 @@ class TpuEngine:
                 prompt_tokens=len(req.prompt_token_ids)))
             return
         block = self.mcfg.kv_block_size
-        prompt, hashes, caching_enabled = (
-            precomputed if precomputed is not None
-            else self._prompt_and_hashes(req))
+        prompt, hashes, caching = self._prompt_and_hashes(req)
 
         # Automatic prefix caching: reuse the longest cached run of complete
         # prompt blocks (keeping ≥1 suffix token so logits can be computed).
         matched_bids: list[int] = []
-        caching = caching_enabled
         with self._cond:
             if caching and hashes:
                 max_match = (len(prompt) - 1) // block
@@ -1816,62 +1581,17 @@ class TpuEngine:
         self._note_table(blocks)
 
         cached_tokens = len(matched_bids) * block
-        suffix = prompt[cached_tokens:]
         self._note_prefix_hit(req.request_id, cached_tokens, len(prompt))
-
-        win = self._prefill_window()
-        if win and len(suffix) > win and req.mm_embeds is None:
-            # Long prompt: park the slot PREFILLING; _advance_prefills
-            # writes its windows between the decode chunks.
-            if matched_bids:
-                self.telemetry.prefix_cached_tokens.inc(cached_tokens)
-            slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
-                         position=len(prompt), generated=[],
-                         cached_tokens=cached_tokens, prompt_len=len(prompt),
-                         prefilling=True)
-            slot.prefill_rest = list(suffix)
-            slot.prefill_written = cached_tokens
-            slot.chunk_meta = (hashes, caching)
-            self._place(idx, slot)
-            return
-
-        row = np.zeros((1, self.max_blocks_per_seq), np.int32)
-        row[0, : len(blocks)] = blocks
-        try:
-            tok_dev = self._run_prefill_compute(
-                req, prompt, suffix, cached_tokens, matched_bids, row,
-                np.asarray([idx], np.int32), blocks)
-        except Exception:
-            with self._cond:
-                self.allocator.free(blocks)
-                self.telemetry.observe_allocator(self.allocator)
-            self._emit_to(out, loop, TokenEvent(
-                request_id=req.request_id, token_id=None,
-                finish_reason=FinishReason.ABORT,
-                prompt_tokens=len(prompt)))
-            raise
-
-        self.telemetry.prompt_tokens.inc(len(suffix))
-
-        # Slot lands PENDING: the first token is still on device (transfer in
-        # flight). _finalize_prefills completes it after the decode chunk for
-        # the established lanes has been dispatched, hiding the readback
-        # behind device work.
+        self.telemetry.prefix_cached_tokens.inc(cached_tokens)
         slot = _Slot(req=req, out=out, loop=loop, blocks=blocks,
                      position=len(prompt), generated=[],
-                     cached_tokens=cached_tokens, pending_tok=tok_dev,
-                     prompt_len=len(prompt))
-        n_complete = len(prompt) // block
-        if caching:
-            # Content-address the freshly computed complete prompt blocks.
-            with self._cond:
-                self.allocator.commit_hashes(
-                    blocks[len(matched_bids):n_complete],
-                    hashes[len(matched_bids):n_complete])
-        slot.block_hashes = hashes[:n_complete]
-        if self.kv_events is not None and slot.block_hashes:
-            self.kv_events.stored(slot.block_hashes)
+                     cached_tokens=cached_tokens, prompt_len=len(prompt),
+                     prefilling=True, prefill_rest=list(prompt[cached_tokens:]),
+                     prefill_written=cached_tokens,
+                     chunk_meta=(hashes, caching))
         self._place(idx, slot)
+        if len(self._next_window(slot)) == len(slot.prefill_rest):
+            self._write_prefill_window(idx)
 
     def _finalize_prefills(self):
         """Land pending first tokens and emit/finish accordingly. Reading
@@ -1884,7 +1604,7 @@ class TpuEngine:
         if not pending:
             return
         with self._phase("decode_wait"):
-            landed = [int(self._read_tokens(slot.pending_tok)[slot.pending_idx])
+            landed = [int(self._read_tokens(slot.pending_tok)[0])
                       for _, slot in pending]
         self._first_tokens_read = self._clock()
         self._period_prefills += len(pending)
@@ -1919,13 +1639,22 @@ class TpuEngine:
             self.telemetry.admit_to_first_token.observe(now - req.admit_time)
 
     def _prefill_window(self) -> int:
-        """Incremental-prefill window in tokens (a KV-block multiple so
-        every intermediate boundary is block-aligned); 0 = disabled."""
+        """A prompt's window in tokens (a KV-block multiple so every
+        intermediate boundary is block-aligned); 0 = the whole prompt."""
         w = self.cfg.prefill_chunk
         if w <= 0:
             return 0
         block = self.mcfg.kv_block_size
         return max(block, (w + block - 1) // block * block)
+
+    def _next_window(self, s: "_Slot") -> list[int]:
+        """The tokens s's next window writes: the rest of its prompt where
+        no window size is set, and of an image prompt (the embed splice
+        targets absolute positions in the first forward)."""
+        win = self._prefill_window()
+        if win and s.req.mm_embeds is None:
+            return s.prefill_rest[:win]
+        return s.prefill_rest
 
     def _maybe_stage_chunk(self, s: "_Slot") -> None:
         """Incremental KV staging for a chunk-streamed remote-decode
@@ -2028,12 +1757,16 @@ class TpuEngine:
                 budget -= 1
 
     def _write_prefill_window(self, idx: int) -> None:
-        """One window of slot idx's prompt into its pages. The final
-        window's fused sample becomes the pending first token; prefix-cache
-        commit + KV events are deferred to that point."""
+        """One window of slot idx's prompt into its pages: the one place a
+        prompt's programs are dispatched from. A first window with nothing
+        cached before it is a plain prefill; any other continues from the
+        (block-aligned) pages already written, a prefix-cache hit's or an
+        earlier window's alike. The last window's fused sample becomes the
+        pending first token, and with it the prompt's whole blocks are
+        content-addressed and published. A dispatch that fails ends the
+        request (ABORT, its blocks back) and raises."""
         s = self.slots[idx]
-        win = self._prefill_window()
-        window = s.prefill_rest[:win]
+        window = self._next_window(s)
         last = len(window) == len(s.prefill_rest)
         written = s.prefill_written
         block = self.mcfg.kv_block_size
@@ -2047,22 +1780,28 @@ class TpuEngine:
         slots = np.asarray([idx if last or self.geom.state
                             else self.cfg.max_batch], np.int32)
         try:
+            bucket = self._bucket(len(window))
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, : len(window)] = window
+            n = np.asarray([len(window)], np.int32)
             riders = self._window_tables(
                 [(s.blocks, written, written + len(window), True)], 1)
-            if written == 0:
-                bucket = self._bucket(len(window))
-                tokens = np.zeros((1, bucket), np.int32)
-                tokens[0, : len(window)] = window
+            if req.mm_embeds is not None:
+                # (No riders: submit() refuses an image prompt wherever the
+                # cache is not plain K/V pages.)
+                mm_pad, pos_pad = self._mm_operands(req, bucket)
+                tok_dev = self._device_call(
+                    ("mm_prefill", bucket, mm_pad.shape[1]), dict(
+                        tokens=tokens, seq_len=n, mm_pad=mm_pad,
+                        pos_pad=pos_pad, row=row, slots=slots,
+                        **self._sample_np([req])))
+            elif written == 0:
                 tok_dev = self._device_call(("prefill", bucket), dict(
-                    tokens=tokens,
-                    seq_len=np.asarray([len(window)], np.int32),
-                    row=row, slots=slots, **riders,
+                    tokens=tokens, seq_len=n, row=row, slots=slots, **riders,
                     **self._sample_np([req])))
             else:
-                # Continuation window: gather the already-written prefix
-                # from its (block-aligned) pages, scatter this window at
-                # offset `written` — the prefix-cache-hit jit, reused.
-                sb = self._bucket(len(window))
+                # The blocks before this window, to a power of two of them
+                # (padding → trash): a continuation costs O(prefix).
                 prior_n = written // block
                 pb = 1
                 while pb < prior_n:
@@ -2070,16 +1809,15 @@ class TpuEngine:
                 pb = min(pb, self.max_blocks_per_seq)
                 prior = np.zeros((1, pb), np.int32)
                 prior[0, :prior_n] = s.blocks[:prior_n]
-                tokens = np.zeros((1, sb), np.int32)
-                tokens[0, : len(window)] = window
                 tok_dev = self._device_call(
-                    ("prefix_prefill", sb, pb), dict(
-                        tokens=tokens,
-                        suffix_len=np.asarray([len(window)], np.int32),
+                    ("prefix_prefill", bucket, pb), dict(
+                        tokens=tokens, suffix_len=n,
                         prefix_len=np.asarray([written], np.int32),
                         row=row, prior=prior, slots=slots, **riders,
                         **self._sample_np([req])))
         except Exception:
+            # (A predecessor that _place retired stays retired: the chunk in
+            # flight books it.)
             self.slots[idx] = None
             self._drop_partial_export(req.request_id)
             with self._cond:
@@ -2101,76 +1839,45 @@ class TpuEngine:
             # chunk k while chunk k+1 computes. The final (partial)
             # block rides the completion staging in _finish_slot.
             self._maybe_stage_chunk(s)
-        if last:
-            hashes, caching = s.chunk_meta
-            s.chunk_meta = None
-            s.prefilling = False
-            s.pending_tok = tok_dev  # intermediate samples were discarded
-            n_complete = s.prompt_len // block
-            matched_n = s.cached_tokens // block
-            if caching:
-                with self._cond:
-                    self.allocator.commit_hashes(
-                        s.blocks[matched_n:n_complete],
-                        hashes[matched_n:n_complete])
-            s.block_hashes = hashes[:n_complete]
-            if self.kv_events is not None and s.block_hashes:
-                self.kv_events.stored(s.block_hashes)
+            return
+        # The slot lands PENDING: the first token is still on the device
+        # (transfer in flight; the samples of the windows before it were
+        # nobody's). _finalize_prefills completes it after the decode chunk
+        # for the established lanes has been dispatched, hiding the readback
+        # behind device work.
+        hashes, caching = s.chunk_meta
+        s.chunk_meta = None
+        s.prefilling = False
+        s.pending_tok = tok_dev
+        n_complete = s.prompt_len // block
+        matched_n = s.cached_tokens // block
+        if caching:
+            # Content-address the freshly computed complete prompt blocks.
+            with self._cond:
+                self.allocator.commit_hashes(
+                    s.blocks[matched_n:n_complete],
+                    hashes[matched_n:n_complete])
+        s.block_hashes = hashes[:n_complete]
+        if self.kv_events is not None and s.block_hashes:
+            self.kv_events.stored(s.block_hashes)
 
-    def _run_prefill_compute(self, req, prompt, suffix, cached_tokens,
-                             matched_bids, row, slots, blocks):
-        """Dispatch the fused prefill+first-token jit; returns the sampled
-        token as a DEVICE array ([1] i32) with its host transfer already
-        started — _finalize_prefills lands it. ``slots`` names the slot the
-        token is kept for on the device (_op_keep_tokens)."""
-        if req.mm_embeds is not None:
-            bucket = self._bucket(len(prompt))
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, : len(prompt)] = prompt
-            mm = np.asarray(req.mm_embeds, np.float32)
-            mm_bucket = 1
-            while mm_bucket < mm.shape[0]:
-                mm_bucket *= 2
-            mm_pad = np.zeros((1, mm_bucket, mm.shape[1]), np.float32)
-            mm_pad[0, : mm.shape[0]] = mm
-            # Padding positions land out of range → dropped by the scatter.
-            # Missing/short mm_positions default to an image-first layout.
-            positions = list(req.mm_positions or [])
-            while len(positions) < mm.shape[0]:
-                positions.append(len(positions))
-            pos_pad = np.full((1, mm_bucket), bucket, np.int32)
-            pos_pad[0, : mm.shape[0]] = positions[: mm.shape[0]]
-            return self._device_call(("mm_prefill", bucket, mm_bucket), dict(
-                tokens=tokens, seq_len=np.asarray([len(prompt)], np.int32),
-                mm_pad=mm_pad, pos_pad=pos_pad, row=row, slots=slots,
-                **self._sample_np([req])))
-        if matched_bids:
-            bucket = self._bucket(len(suffix))
-            prefix_bucket = 1
-            while prefix_bucket < len(matched_bids):
-                prefix_bucket *= 2
-            prefix_bucket = min(prefix_bucket, self.max_blocks_per_seq)
-            prior = np.zeros((1, prefix_bucket), np.int32)  # padding → trash
-            prior[0, : len(matched_bids)] = matched_bids
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, : len(suffix)] = suffix
-            tok = self._device_call(("prefix_prefill", bucket, prefix_bucket),
-                                    dict(tokens=tokens,
-                                         suffix_len=np.asarray([len(suffix)], np.int32),
-                                         prefix_len=np.asarray([cached_tokens], np.int32),
-                                         row=row, prior=prior, slots=slots,
-                                         **self._sample_np([req])))
-            self.telemetry.prefix_cached_tokens.inc(cached_tokens)
-        else:
-            bucket = self._bucket(len(prompt))
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, : len(prompt)] = prompt
-            tok = self._device_call(("prefill", bucket), dict(
-                tokens=tokens, seq_len=np.asarray([len(prompt)], np.int32),
-                row=row, slots=slots,
-                **self._window_tables([(blocks, 0, len(prompt), True)], 1),
-                **self._sample_np([req])))
-        return tok
+    def _mm_operands(self, req, bucket: int):
+        """An image prompt's two operands more: its encoder vectors padded
+        to a power of two of them, and where in the prompt each goes."""
+        mm = np.asarray(req.mm_embeds, np.float32)
+        mm_bucket = 1
+        while mm_bucket < mm.shape[0]:
+            mm_bucket *= 2
+        mm_pad = np.zeros((1, mm_bucket, mm.shape[1]), np.float32)
+        mm_pad[0, : mm.shape[0]] = mm
+        # Padding positions land out of range → dropped by the scatter.
+        # Missing/short mm_positions default to an image-first layout.
+        positions = list(req.mm_positions or [])
+        while len(positions) < mm.shape[0]:
+            positions.append(len(positions))
+        pos_pad = np.full((1, mm_bucket), bucket, np.int32)
+        pos_pad[0, : mm.shape[0]] = positions[: mm.shape[0]]
+        return mm_pad, pos_pad
 
     # ---- P/D import (decode side) --------------------------------------
 
@@ -3012,7 +2719,7 @@ class TpuEngine:
 
     def _op_mm_prefill(self, bucket, mm_bucket, tokens, seq_len, mm_pad,
                        pos_pad, row, slots, temps, top_k, top_p):
-        fn = self._mm_prefill_fn(bucket, mm_bucket)
+        fn = self._prefill_fn(bucket, mm_bucket)
         tok, self.k_pages, self.v_pages = fn(
             self.params, self._put(tokens), self._put(seq_len),
             self._put(mm_pad), self._put(pos_pad), self.k_pages,

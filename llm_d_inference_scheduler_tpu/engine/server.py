@@ -1476,11 +1476,9 @@ def main(argv: list[str] | None = None):
                    help="the most decode steps fused per device dispatch "
                         "(the loop sends half where a slot is open and "
                         "nobody waits)")
-    p.add_argument("--prefill-batch", type=int, default=1,
-                   help="same-bucket prompts fused per prefill dispatch")
     p.add_argument("--prefill-chunk", type=int, default=0,
-                   help="incremental prefill window in tokens for long "
-                        "prompts (0 = whole-prompt prefill)")
+                   help="prefill window in tokens: a longer prompt is written "
+                        "a window a step (0 = one window a prompt)")
     p.add_argument("--drain-timeout", type=float, default=30.0,
                    help="seconds to let in-flight requests finish after "
                         "SIGTERM before stopping (readiness 503s "
@@ -1539,7 +1537,6 @@ def main(argv: list[str] | None = None):
                        checkpoint_path=args.checkpoint, warmup=args.warmup,
                        tp_size=args.tp_size, ep_size=args.ep_size,
                        pp_size=args.pp_size, decode_chunk=args.decode_chunk,
-                       prefill_batch=args.prefill_batch,
                        prefill_chunk=args.prefill_chunk,
                        secure_serving=args.secure_serving,
                        cert_path=args.cert_path,

@@ -9,6 +9,7 @@ import signal
 import time
 
 import httpx
+import numpy as np
 import pytest
 
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
@@ -227,97 +228,6 @@ def test_engine_warmup_compiles_before_serving():
     run(body())
 
 
-def test_batched_prefill_token_parity():
-    """prefill_batch > 1: same-bucket plain prompts admitted together run
-    as ONE [K, S] fused prefill (padded to K) — greedy tokens must match
-    the per-prompt path exactly, including the prefix-cache-hit rerun
-    (hits route back to the O(prefix) single path)."""
-    import asyncio
-
-    from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
-
-    prompts = [[1] + [(i * 13 + j * 7) % 400 + 3 for j in range(40)]
-               for i in range(6)]
-    base = dict(model="tiny", backend="tpu", max_batch=8, max_model_len=64,
-                decode_chunk=4, kv_events_port=0, seed=5)
-
-    async def serve(cfg, tag, rounds=1):
-        eng = TpuEngine(cfg)
-        await eng.start()
-        try:
-            async def one(rid, prompt):
-                out = eng.submit(EngineRequest(
-                    request_id=rid, prompt_token_ids=list(prompt),
-                    max_tokens=5, temperature=0.0, ignore_eos=True))
-                toks = []
-                while True:
-                    ev = await asyncio.wait_for(out.get(), timeout=120)
-                    if ev.token_id is not None:
-                        toks.append(ev.token_id)
-                    if ev.finish_reason is not None:
-                        return toks
-
-            out = []
-            for r in range(rounds):
-                out.append(await asyncio.gather(
-                    *[one(f"{tag}{r}-{i}", p) for i, p in enumerate(prompts)]))
-            return out
-        finally:
-            await eng.stop()
-
-    single = asyncio.run(serve(EngineConfig(**base), "s"))[0]
-    cold, warm = asyncio.run(serve(
-        EngineConfig(**base, prefill_batch=4), "b", rounds=2))
-    assert cold == single
-    assert warm == single  # prefix-cache hits take the single path
-
-
-def test_batched_prefill_in_group_duplicates_share_prefix():
-    """K identical prompts admitted in ONE group: the first prefills in the
-    batch, the duplicates reroute to the prefix path AFTER the batch commits
-    its hashes — same tokens, and the duplicates report cached tokens."""
-    import asyncio
-
-    from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
-    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
-
-    prompt = [1] + [(j * 11) % 400 + 3 for j in range(40)]
-
-    async def body():
-        eng = TpuEngine(EngineConfig(model="tiny", backend="tpu", max_batch=8,
-                                     max_model_len=64, decode_chunk=4,
-                                     kv_events_port=0, seed=5,
-                                     prefill_batch=4))
-        await eng.start()
-        try:
-            async def one(rid):
-                out = eng.submit(EngineRequest(
-                    request_id=rid, prompt_token_ids=list(prompt),
-                    max_tokens=4, temperature=0.0, ignore_eos=True))
-                toks, cached = [], 0
-                while True:
-                    ev = await asyncio.wait_for(out.get(), timeout=120)
-                    if ev.token_id is not None:
-                        toks.append(ev.token_id)
-                        cached = max(cached, ev.cached_tokens or 0)
-                    if ev.finish_reason is not None:
-                        return toks, cached
-
-            results = await asyncio.gather(*[one(f"d{i}") for i in range(4)])
-            toks = [t for t, _ in results]
-            cached = [c for _, c in results]
-            assert all(t == toks[0] for t in toks)
-            # At least the rerouted duplicates hit the freshly-committed
-            # prefix blocks (2 complete 16-token blocks of the 41-token
-            # prompt).
-            assert sum(1 for c in cached if c >= 32) >= 3
-        finally:
-            await eng.stop()
-
-    asyncio.run(body())
-
-
 def test_incremental_prefill_token_parity_and_no_stall():
     """prefill_chunk: a long prompt prefills in block-aligned windows, one
     per engine step, interleaved with other lanes. Greedy tokens must match
@@ -475,10 +385,12 @@ def test_prefill_windows_a_step_follow_the_waiting_lanes(
         def counted_advance():
             steps.append([])
             advance()
+            steps.append(None)      # what follows is no window of this step
 
         def counted_write(idx):
-            steps[-1].append(idx)
-            write(idx)
+            if steps and steps[-1] is not None:
+                steps[-1].append(idx)
+            write(idx)      # (else a prompt of one window, at its admission)
 
         eng._advance_prefills = counted_advance
         eng._write_prefill_window = counted_write
@@ -981,6 +893,201 @@ def test_a_lane_that_ends_on_a_stop_token_is_not_refilled_ahead(family):
     assert _refills(eng) == (0, 3)
     assert _counter(eng, "jetstream:decode_lanes_discarded_total") == 1
     assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+# ---- a prompt is written in windows, and a short prompt has one ------------
+# One function dispatches a text prompt's programs (_write_prefill_window),
+# at admission where the prompt fits a window (ISSUE 56). _ONE_WINDOW holds
+# what the parent commit (ab98553: a path of its own for a whole prompt)
+# dispatched and served for the same requests under the same driver.
+
+_SHORT_PROMPT = _prompt(41, 41)     # two whole blocks of 16, and nine tokens
+
+
+class _Programs:
+    """The prefill programs a run dispatched, each as (op, its operands'
+    shapes by name, the loop's phase), and every event it emitted."""
+
+    def __init__(self, fail=None):
+        self.ops, self.events, self.failed = [], [], []
+        self.fail = fail    # the prompt whose program the device refuses
+
+    def __call__(self, eng, step):
+        if step is not None:
+            return
+        real_op, real_phase = eng._exec_op, eng._phase
+        real_emit, real_step = eng._emit, eng._step
+        now = []
+
+        @contextlib.contextmanager
+        def phase(name):
+            now.append(name)
+            try:
+                with real_phase(name):
+                    yield
+            finally:
+                now.pop()
+
+        def exec_op(op, args):
+            if "prefill" in op[0]:
+                n = len(self.fail or ())
+                if n and args["tokens"][0, :n].tolist() == self.fail:
+                    raise RuntimeError("the device refuses this program")
+                self.ops.append((op, {k: np.shape(v) for k, v in args.items()},
+                                 now[-1]))
+            return real_op(op, args)
+
+        def emit(slot, ev):
+            self.events.append(ev)
+            real_emit(slot, ev)
+
+        def step_():
+            try:
+                real_step()
+            except RuntimeError:    # the failed request is cleaned up: go on
+                self.failed.append([r.request_id for r, *_ in eng._waiting])
+
+        eng._exec_op, eng._phase = exec_op, phase
+        eng._emit, eng._step = emit, step_
+
+
+def _shapes(width, **more):
+    return {"tokens": (1, width), "row": (1, 8), "slots": (1,),
+            "temps": (1,), "top_k": (1,), "top_p": (1,), **more}
+
+
+_SERVED = [177, 41, 256, 244, 278, 415]
+_IMAGE_SERVED = [305, 69, 395, 145, 341]
+_ONE_WINDOW = {
+    False: ([(("prefill", 64), _shapes(64, seq_len=(1,)), "admit")],
+            {"P": _SERVED}),
+    True: ([(("prefill", 64), _shapes(64, seq_len=(1,)), "admit"),
+            (("prefix_prefill", 16, 2),
+             _shapes(16, suffix_len=(1,), prefix_len=(1,), prior=(1, 2)),
+             "admit")],
+           {"P": _SERVED, "Q": _SERVED}),
+}
+
+
+@pytest.mark.parametrize("prefill_chunk", [0, 64])
+@pytest.mark.parametrize("hit", [False, True], ids=["cold", "prefix-hit"])
+def test_a_prompt_of_one_window_is_the_parents_whole_prompt(hit, prefill_chunk):
+    """A prompt that fits a window (or has no window size) goes out as the
+    parent's whole prompt did: the same program with the same operands in
+    the same phase, the same tokens; after a prefix hit, the continuation."""
+    reqs = [_req("P", _SHORT_PROMPT, 6, 0.0)]
+    if hit:
+        reqs.append(_req("Q", _SHORT_PROMPT, 6, 0.0))
+    seen = _Programs()
+    toks, why, eng = _by_hand(reqs, submit_at={"Q": 20}, watch=seen,
+                              prefill_chunk=prefill_chunk)
+    assert (seen.ops, toks) == _ONE_WINDOW[hit]
+    assert _counter(eng, "jetstream:prompt_tokens_total") == 41 + 9 * hit
+    assert _counter(eng, "jetstream:prefix_cached_tokens_total") == 32 * hit
+    assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+def test_duplicates_admitted_in_one_step_share_the_firsts_blocks():
+    """Four requests with one prompt, admitted in one step: the first is
+    written, and each of the others finds its two whole blocks committed
+    and continues from them; the same tokens, the hit reported."""
+    seen = _Programs()
+    toks, why, eng = _by_hand(
+        [_req(f"d{i}", _SHORT_PROMPT, 4, 0.0) for i in range(4)], watch=seen)
+    assert [(op[0], phase) for op, _, phase in seen.ops] == [
+        ("prefill", "admit")] + [("prefix_prefill", "admit")] * 3
+    assert all(t == toks["d0"] and len(t) == 4 for t in toks.values())
+    assert [ev.cached_tokens for ev in seen.events if ev.is_first] == [
+        0, 32, 32, 32]
+    assert _counter(eng, "jetstream:prefix_cached_tokens_total") == 96
+    assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+@pytest.mark.parametrize("prefill_chunk", [0, 16])
+def test_an_image_prompt_is_one_window_with_two_more_operands(prefill_chunk):
+    """Three encoder vectors over the placeholders at positions 1 to 3 of a
+    34-token prompt: one program, whole, whatever the window size (the
+    splice targets absolute positions), as the parent dispatched it."""
+    vectors = np.random.default_rng(0).standard_normal((3, 128)).tolist()
+    req = _req("M", [1, 5, 5, 5] + list(range(10, 40)), 5, 0.0)
+    req.mm_embeds, req.mm_positions = vectors, [1, 2, 3]
+    seen = _Programs()
+    toks, why, eng = _by_hand([req], watch=seen, prefill_chunk=prefill_chunk)
+    assert seen.ops == [(("mm_prefill", 64, 4), _shapes(
+        64, seq_len=(1,), mm_pad=(1, 4, 128), pos_pad=(1, 4)), "admit")]
+    assert toks == {"M": _IMAGE_SERVED}
+    assert _counter(eng, "jetstream:prompt_tokens_total") == 34
+    assert _free_blocks(eng) == eng.n_blocks - 1
+
+
+@pytest.mark.parametrize("into", ["an empty slot", "a vacating slot"])
+def test_a_dispatch_that_fails_ends_its_own_request_and_no_other(into):
+    """The device refuses one request's program. That client gets ABORT and
+    the blocks come back; the requests behind it still wait, and are served
+    as they are when nothing fails; a predecessor that the failed request
+    had retired (refilled ahead) is served to its end by its chunk."""
+    if into == "an empty slot":
+        reqs = [_req("A", _prompt(5, 9), 7, 0.0),
+                _req("B", _prompt(7, 20), 5, 0.0),
+                _req("C", _prompt(11, 27), 6, 0.0)]
+        kw, bad, behind = dict(max_batch=4), "B", ["C"]
+        served = _by_hand(reqs, **kw)[0]
+    else:
+        reqs, kw, bad, behind = _queue(), dict(max_batch=2), "C", ["D"]
+        served = _queued("tiny")[0]
+    watch = _Watch()
+    seen = _Programs(fail=next(r.prompt_token_ids for r in reqs
+                               if r.request_id == bad))
+
+    def both(eng, step):
+        watch(eng, step)
+        seen(eng, step)
+
+    toks, why, eng = _by_hand(reqs, watch=both, **kw)
+    assert seen.failed == [behind]
+    assert why == {**dict.fromkeys(served, "length"), bad: "abort"}
+    assert toks == {**served, bad: []}
+    assert len(watch.freed) == len(reqs) and not watch.twice and not watch.held
+    assert _free_blocks(eng) == eng.n_blocks - 1
+    assert not eng._retired and not any(eng.slots)
+    if into == "a vacating slot":
+        assert _refills(eng) == (1, 3)      # C ahead; D after, in its place
+
+
+def test_engine_config_has_no_prefill_batch():
+    import dataclasses
+
+    names = [f.name for f in dataclasses.fields(EngineConfig)]
+    assert "prefill_batch" not in names and len(names) == 44
+
+
+def test_the_server_refuses_prefill_batch_as_any_unknown_flag(capsys):
+    from llm_d_inference_scheduler_tpu.engine import server
+
+    with pytest.raises(SystemExit) as refused:
+        server.main(["--backend", "sim", "--prefill-batch", "2"])
+    assert refused.value.code == 2
+    assert "unrecognized arguments: --prefill-batch" in capsys.readouterr().err
+
+
+def test_a_prompts_programs_are_dispatched_from_one_place_each():
+    """Outside the warm-up, one dispatch of each prefill program in
+    TpuEngine's source, and one commit of a finished prompt's blocks."""
+    import inspect
+    import re
+
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    source = inspect.getsource(TpuEngine)
+    for apart in (TpuEngine._warmup, TpuEngine._import_into_slot):
+        source = source.replace(inspect.getsource(apart), "")
+    sites = collections.Counter(re.findall(
+        r'_device_call\(\s*\("(\w*prefill)"', source))
+    assert sites == {"prefill": 1, "prefix_prefill": 1, "mm_prefill": 1}
+    assert source.count(".commit_hashes(") == 1
+    assert not re.search(
+        "_flush_admissions|_run_batched_prefill|_try_prepare_batch_entry"
+        "|pending_idx|prefill_batch", source)
 
 
 # ---- the next chunk is held back for an arrival ----------------------------

@@ -223,9 +223,9 @@ def test_over_context_prompt_rejected_400():
 
 def test_mixed_admission_fuzz_batched_and_chunked():
     """Randomized mix of short/long prompts, mid-flight aborts, and varied
-    max_tokens against an engine running BOTH batched prefill (groups of 4)
-    and incremental prefill (32-token windows) with prefix caching on:
-    every request must terminate, and every block must come back."""
+    max_tokens against an engine that writes prompts in 32-token windows
+    (a short prompt in one, a long one a window a step) with prefix caching
+    on: every request must terminate, and every block must come back."""
     import random
 
     from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
@@ -235,7 +235,7 @@ def test_mixed_admission_fuzz_batched_and_chunked():
     async def body():
         eng = TpuEngine(_cfg("tpu", 0, max_batch=6, max_model_len=256,
                              decode_chunk=4, kv_events_port=0, seed=11,
-                             prefill_batch=4, prefill_chunk=32))
+                             prefill_chunk=32))
         await eng.start()
         outcomes = {"finished": 0, "aborted": 0}
         try:
