@@ -87,9 +87,9 @@ class EngineConfig:
     # once-a-chunk work at the cost of bursty token streaming, of
     # up-to-(chunk-1) wasted steps for sequences that hit a stop condition
     # mid-chunk, and of an arrival's wait for the chunk that runs: the loop
-    # dispatches half of it where a slot is open and nobody waits
-    # (core.TpuEngine._chunk_steps; PERF.md section 6, PR 43, has both
-    # lengths on the chip). 1 = classic per-step decode.
+    # dispatches a quarter of it, or half, where a slot is open and nobody
+    # waits (core.TpuEngine._chunk_steps; PERF.md section 6, PR 43 and PR 57,
+    # has the lengths on the chip). 1 = classic per-step decode.
     decode_chunk: int = 8
     # Pallas paged-attention decode kernel. None = auto: enabled on a real
     # TPU backend for unsharded engines whose head_dim is lane-aligned

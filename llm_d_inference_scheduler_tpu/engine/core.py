@@ -24,6 +24,7 @@ import asyncio
 import collections
 import contextlib
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -69,16 +70,33 @@ HOLD_MARGIN_S = 0.020
 # A shape's expected device time is the least of its last clean periods: a
 # period can only read longer than the chunk took (a late read, a late chunk).
 HOLD_PERIODS = 8
-# A short chunk is decode_chunk // SHORT_CHUNK_DIV steps (_chunk_steps: a slot
-# open, nobody waiting, the host's work a chunk fitting inside it). Sized on
-# the chip with the length fixed by --decode-chunk (qwen3-4b.chat-steady,
-# 3.6 requests/s on 16 lanes, three seeds each; PERF.md section 6, PR 43):
-# at 8 steps ttft_p50_ms 115.7, tpot_p95_ms 18.75, a chunk 111.7 ms; at 4
-# steps 84.9-86.6, 18.55-18.84 (-1.1 to +0.4%), 57.4 ms; at 2 steps
-# 63.7-66.3, 19.52-19.85 (+4.1 to +5.8%), 30.5 ms. A quarter costs a running
-# stream more than the 4% it was allowed (the chunk's once-a-chunk work,
-# four times a period), so half it is.
-SHORT_CHUNK_DIV = 2
+# A short chunk is decode_chunk over one of these, the shortest the host's work
+# fits in (_chunk_steps: a slot open, nobody waiting). Sized on the chip
+# (qwen3-4b, 16 lanes x 2,048, --decode-chunk 8; PERF.md section 6, PR 57).
+# The ONE decode program of the 8-lane bucket, five lanes live, at 2 / 4 / 8
+# steps: 27.3 / 51.7 / 100.4 ms, 12.18 ms a step and 2.96 ms a chunk whatever
+# its length, which was the stacked wq and wk copied whole into the order of
+# axes the layer loop reads; held in that order (_laid_out) 24.6 / 49.0 /
+# 97.8 ms, 12.21 a step and 0.14 a chunk. So a quarter costs a running
+# stream nothing any more: in qwen3-4b.chat-steady (3.6 requests/s, four
+# seeds a tree) chunks of 4 steps on the tree before read ttft_p50_ms
+# 83.7-85.6 and tpot_p95_ms 18.0-18.7, chunks of 2 steps here 62.2-68.6 and
+# 16.9-17.7, 99.9-100% of the chunks quarters. (PR 43 had read a quarter at
+# 63.7-66.3 and +4.1 to +5.8% of tpot_p95_ms, the copies four times a
+# period, and kept to a half.)
+SHORT_CHUNK_DIVS = {"quarter": 4, "half": 2}
+# What a short chunk's reckoned time must leave the loop beyond its own
+# measured work a period (_chunk_steps): the most the loop was seen late by
+# (above: 9.5 ms from a deadline to "chunk enqueued", a turn of the GIL in
+# it, where the median, which the measured work holds, is 4.1-4.7). This
+# keeps the DEVICE fed: a chunk goes out while the one before it runs, so a
+# loop that needs its work and this much inside a chunk never leaves the
+# queue empty. It is not HOLD_MARGIN_S, which says how early a HELD chunk
+# goes out and holds the dispatch itself: asked of a quarter's 24.6 ms with
+# the host's 7 (dispatch in both) it left nothing, and no quarter was ever
+# dispatched (PERF.md section 6, PR 57). Where the work fits and the hold's
+# margin does not, the chunk is short and simply not held.
+KEEP_UP_S = 0.010
 
 
 def _tcp_preflight(address: str, timeout: float = 2.0) -> None:
@@ -374,6 +392,10 @@ class TpuEngine:
             self.mesh = make_serve_mesh(devices, tp=cfg.tp_size,
                                         ep=cfg.ep_size)
 
+        # The stacked weights a one-chip TPU engine holds in the layout its
+        # decode program chose, by name: their axes from major to minor as
+        # the arrays lie (_laid_out); nothing on any other engine.
+        self.weight_layouts: dict[str, list[int]] = {}
         if params is not None or cfg.checkpoint_path:
             if params is None:
                 from .checkpoint import load_params
@@ -390,7 +412,7 @@ class TpuEngine:
 
                 params = shard_params_pp(params, self.mcfg, self.pp_mesh)
             else:
-                params = jax.device_put(params, self.device)
+                params = self._laid_out(jax.device_put(params, self.device))
             self.params = params
         elif self.mesh is not None:
             from ..parallel.serve import init_sharded_params
@@ -409,10 +431,10 @@ class TpuEngine:
             # already held, which a 16 GB chip does not have.
             from jax.sharding import SingleDeviceSharding
 
-            self.params = jax.jit(
+            self.params = self._laid_out(jax.jit(
                 lambda k: self.model.init_params(self.mcfg, k),
                 out_shardings=SingleDeviceSharding(self.device))(
-                    jax.random.key(cfg.seed))
+                    jax.random.key(cfg.seed)))
         mesh = self._page_mesh()
         self.k_pages, self.v_pages = (
             pages.alloc(self.geom, sharding=pages.page_sharding(mesh))
@@ -587,6 +609,7 @@ class TpuEngine:
                             else "host"),
                 "kv_wire_error": self.kv_transfer_error,
                 "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+                "weight_layouts": dict(self.weight_layouts),
             },
             "decode_kernel_in_program": dict(self.decode_kernel_in_program),
             "kv_imports": {"device": self.kv_import_device_count,
@@ -598,6 +621,93 @@ class TpuEngine:
         }
 
     # ---- jitted bodies -------------------------------------------------
+
+    def _param_formats(self, params):
+        """The formats (``jax.experimental.layout``) a one-chip engine holds
+        ``params`` (arrays, or their shapes) in on a TPU, where the family
+        names stacked weights whose layout is the decode program's to choose
+        (``LAID_BY_DECODE``; models/llama.py has why); None wherever they
+        stay as they come. The compiler is asked, not told: the decode chunk
+        of every lane is compiled once from shapes alone, with
+        ``Layout.AUTO`` on those weights, and what it settles on is what
+        they are moved into (_laid_out): one copy of each, in the order of
+        axes its layer loop reads. Every other program is then built for
+        the arrays as they lie (jit takes a committed argument's layout),
+        and every decode bucket's as well: this compile is nobody's
+        program, and a warm start finds it in the compile cache (it takes
+        its arguments in those layouts and returns none in one: see
+        _laid_out)."""
+        names = getattr(self.model, "LAID_BY_DECODE", ())
+        if (not names or self.device.platform != "tpu"
+                or self.mesh is not None or self.pp_mesh is not None):
+            return None
+        from jax.experimental.layout import Format, Layout
+        from jax.sharding import SingleDeviceSharding
+
+        here = SingleDeviceSharding(self.device)
+
+        def shape(*dims, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=here)
+
+        def shapes(tree):
+            return jax.tree.map(
+                lambda a: shape(*a.shape, dtype=a.dtype), tree)
+
+        lanes = self._batch_bucket(self.cfg.max_batch)
+        width = self.max_blocks_per_seq
+        k_pages, v_pages = jax.eval_shape(
+            functools.partial(pages.alloc, self.geom))
+        cache = state_pool.at_slots(
+            k_pages, np.zeros((lanes,), np.int32),
+            np.zeros((lanes, width), np.int32) if self.geom.window else None,
+            reads=self.bound.decode_expert_visits(lanes) > 0)
+        args = shapes((
+            params, shape(lanes), shape(lanes), cache, v_pages,
+            shape(lanes, width), jax.eval_shape(lambda: jax.random.key(0)),
+            shape(lanes, dtype=jnp.float32), shape(lanes),
+            shape(lanes, dtype=jnp.float32), shape()))
+        asked = jax.tree_util.tree_map_with_path(
+            lambda path, _: Format(
+                Layout.AUTO if path[-1].key in names else None, here),
+            args[0])
+        return jax.jit(
+            self._decode_chunk_impl, donate_argnums=(3, 4),
+            in_shardings=(asked, *(None,) * (len(args) - 1))).lower(
+                *args).compile().input_formats[0][0]
+
+    def _laid_out(self, params):
+        """``params``, on the device, with the weights _param_formats speaks
+        of moved into the layout it says, one at a time and each in place of
+        the array it was (the rest, and every engine it says nothing to, as
+        they come): no second copy of the weights is kept, and the largest
+        of them is what a move needs beside them, before the pools are made.
+        A move is a copy program compiled in a fraction of a second, OUTSIDE
+        the compile cache: an executable whose result has a layout of its
+        own comes back from the cache writing that layout into an array
+        labelled with the default one (utils/compile_cache.py), which is
+        also why the weights are not drawn in their layout by the program
+        that makes them. /health says how they lie (``weight_layouts``),
+        read off the arrays."""
+        formats = self._param_formats(params)
+        if formats is None:
+            return params
+        from ..utils.compile_cache import outside_compile_cache
+
+        layers = dict(params["layers"])
+        with outside_compile_cache():
+            for name in self.model.LAID_BY_DECODE:
+                want = formats["layers"][name]
+                order = want.layout.major_to_minor
+                if layers[name].format.layout.major_to_minor != order:
+                    layers[name] = jax.device_put(layers[name], want,
+                                                  donate=True)
+                held = layers[name].format.layout.major_to_minor
+                if held != order:       # its rows would be out of order
+                    raise RuntimeError(
+                        f"{name} was asked into {want.layout} and says it "
+                        f"lies in {layers[name].format.layout}")
+                self.weight_layouts[name] = list(held)
+        return {**params, "layers": layers}
 
     def _decode_chunk_impl(self, params, tokens, positions, k_pages, v_pages,
                            block_tables, key, temps, top_k, top_p, n_steps):
@@ -1153,26 +1263,32 @@ class TpuEngine:
                     None)
 
     def _chunk_steps(self, shape: str) -> int:
-        """How many steps the chunk now going out runs: decode_chunk, or the
+        """How many steps the chunk now going out runs: decode_chunk, or a
         short length where an arrival could be placed at once
         (_room_for_arrival) and its prefill would go ahead of the chunk
-        after this one: it then waits out the rest of a chunk half as long.
-        A short chunk pays the chunk's fixed costs twice as often, and the
-        loop must still do its own work a chunk and send the next one
-        HOLD_MARGIN_S early inside it: where what it measured of itself
-        over the last periods does not fit, and wherever an arrival could
-        not be placed sooner anyway (no open slot, a queue, windows being
-        written), the chunk is full. A pp engine's program is K steps."""
+        after this one: it then waits out the rest of a chunk a quarter as
+        long, or half. A short chunk pays the chunk's fixed costs that much
+        more often, and the loop must still do its own work a chunk inside
+        it with KEEP_UP_S to spare, or the device's queue runs empty: the
+        length is the shortest of SHORT_CHUNK_DIVS' that what the loop
+        measured of itself over the last periods fits in, so a host too slow
+        for a quarter falls to a half and not to a whole chunk; where it
+        fits neither, and wherever an arrival could not be placed sooner
+        anyway (no open slot, a queue, windows being written), the chunk is
+        full. (Whether the NEXT chunk can then be held back is _hold_until's
+        own question, asked with its own margin.) A pp engine's program is K
+        steps."""
         full = self.cfg.decode_chunk
-        short = max(full // SHORT_CHUNK_DIV, 1)
-        if (short == full or self.pp_mesh is not None
+        if (self.pp_mesh is not None or not self._host_work
                 or not self._room_for_arrival()):
             return full
-        reckoned = self._chunk_time(shape, short)
-        if reckoned is None or not self._host_work:
-            return full
         host = sum(self._host_work) / len(self._host_work)
-        return short if host + HOLD_MARGIN_S <= reckoned else full
+        for div in SHORT_CHUNK_DIVS.values():       # (the shortest first)
+            steps = full // div
+            reckoned = steps and self._chunk_time(shape, steps)
+            if reckoned and host + KEEP_UP_S <= reckoned:
+                return steps
+        return full
 
     def _hold_until(self) -> float | None:
         """Until when, on the loop's clock, the next chunk can be held back
@@ -2810,8 +2926,11 @@ class TpuEngine:
             toks = self._device_call(("decode",), args)
         self.telemetry.decode_chunks[
             "alone" if self._inflight is None else "ahead"].inc()
+        fraction = next((name for name, div in SHORT_CHUNK_DIVS.items()
+                         if steps == self.cfg.decode_chunk // div), "full")
         self.telemetry.decode_chunk_lengths[
-            "full" if steps == self.cfg.decode_chunk else "short"].inc()
+            "full" if fraction == "full" else "short"].inc()
+        self.telemetry.decode_chunk_fractions[fraction].inc()
         for _, s in lanes:
             s.ahead += steps
         behind, self._calls_since_chunk = self._calls_since_chunk, 0

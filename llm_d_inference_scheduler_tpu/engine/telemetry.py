@@ -340,13 +340,24 @@ class EngineTelemetry:
         decode_chunk_lengths = Counter(
             "jetstream:decode_chunk_lengths_total",
             "Decode chunks dispatched, by their length in steps: `full` "
-            "(decode_chunk), or `short` (half of it) where a slot was open, "
-            "nobody waited and the loop's own work a chunk fitted inside: an "
-            "arrival then waits out a shorter chunk", ("length",),
+            "(decode_chunk), or `short` (any length below it: "
+            "decode_chunk_fractions_total says which) where a slot was "
+            "open, nobody waited and the loop's own work a chunk fitted "
+            "inside: an arrival then waits out a shorter chunk", ("length",),
             registry=self.registry)
         self.decode_chunk_lengths = {
             n: decode_chunk_lengths.labels(length=n)
             for n in ("short", "full")}
+        decode_chunk_fractions = Counter(
+            "jetstream:decode_chunk_fractions_total",
+            "The same chunks by the fraction of decode_chunk they ran: "
+            "`quarter` (the shortest the loop asks for: its own measured "
+            "work a period fitted inside a quarter's reckoned time with "
+            "KEEP_UP_S to spare), `half` (it fitted a half and not a "
+            "quarter) or `full`", ("fraction",), registry=self.registry)
+        self.decode_chunk_fractions = {
+            n: decode_chunk_fractions.labels(fraction=n)
+            for n in ("quarter", "half", "full")}
         slot_refills = Counter(
             "jetstream:slot_refills_total",
             "Requests admitted into an engine slot: `ahead` of the booking "

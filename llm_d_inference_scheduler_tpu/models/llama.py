@@ -51,6 +51,20 @@ from .configs import ModelConfig
 
 Params = dict[str, Any]
 
+# The stacked weights whose layout in device memory is the decode program's to
+# choose (engine/core.TpuEngine._param_formats asks its compile and holds them
+# so). With few rows the TPU compiler streams a projection's weight against
+# the rows and wants the contracted axis minor, [L, H * Dh, D] in memory: it
+# wants that of ``wq`` and ``wk`` in every llama-family decode program, with
+# QK-norm or without, and handed [L, D, H * Dh] it copied both whole once a
+# chunk, outside the layer loop (qwen3-4b: 0.94 GB read and written, 2.96 ms a
+# chunk whatever its length, 0.14 since; PERF.md section 6, PR 57), and every
+# prefill program copied a layer's slice of both once a layer. ``wv`` it reads
+# as it comes.
+# The shapes stay: a checkpoint, a sharding rule and a reference see what
+# they always did.
+LAID_BY_DECODE = ("wq", "wk", "wv")
+
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
     """Random-init parameters (stacked-layer layout).

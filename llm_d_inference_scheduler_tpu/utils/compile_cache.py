@@ -8,6 +8,7 @@ so it is never derived from a relative ``__file__``, a pid or a time.
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -38,3 +39,26 @@ def configure_compile_cache() -> str:
         return placed
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR
+
+
+@contextlib.contextmanager
+def outside_compile_cache():
+    """Compile what runs inside with the persistent cache off: neither read
+    from it nor written to it. For a program whose RESULT has a layout of
+    its own (``jax.experimental.layout``): such an executable, read back from
+    the cache, writes its rows in that layout into an array that says it has
+    the default one -- the values come out in another order, silently
+    (jax 0.9.0, libtpu 0.0.34; seen on a v5e, PERF.md section 6, PR 57). A
+    program that only TAKES an argument in such a layout comes back right.
+    The switch is the process's, not a thread's: for start-up code."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()     # (whether it is used is remembered)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()

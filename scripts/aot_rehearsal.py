@@ -6,9 +6,14 @@ programs a single-device `TpuEngine` serves with — the random-weight init,
 the fused decode chunk, plain prefill buckets and prefix-prefill buckets, of
 any block family (models.family) and its page pool (and state pool) — at a registered
 model's full size, and prints for each the compile seconds,
-`memory_analysis()` and whether the Pallas call (`tpu_custom_call`) is in the
-compiled text. What the compiler refuses here (a kernel it cannot lower, a
-program that does not fit the device) it would refuse on the chip.
+`memory_analysis()`, whether the Pallas call (`tpu_custom_call`) is in the
+compiled text, and every `copy` of a parameter-shaped operand the text holds
+(`param_copies`: a weight re-laid out once a program). The weights are handed
+over as the engine holds them: where the family leaves a stacked weight's
+layout to the decode program (`TpuEngine._param_formats`), in the layout its
+compile settles on, printed first. What the compiler refuses here (a kernel
+it cannot lower, a program that does not fit the device) it would refuse on
+the chip.
 
 Nothing runs: no result and no time printed here says anything about the
 device. Each whole-model program takes minutes to compile on a few CPU cores,
@@ -25,6 +30,8 @@ program's lowered StableHLO text to DIR and compiles nothing; run it in both
 checkouts and `diff -r` the directories. The text carries no source
 locations; a Pallas kernel's body (serialized in its custom call with file
 names, lines and the Python call stack) is printed as assembly without them.
+With `--lowered-dir` and a compile, each program's compiled text is kept
+beside it (`<program>.hlo.txt`).
 `--config-file` takes a published config.json as the benchmark's
 configurations are (`chipbench/configs/*.json`), for a model the registry
 does not name.
@@ -60,6 +67,31 @@ def _without_locations(text: str) -> str:
                 enable_debug_info=False))
 
     return re.sub(r'(?<=\\22body\\22: )\\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
+def _param_copies(text: str, params) -> list[str]:
+    """Every ``copy`` in a compiled program's text whose result has the
+    shape and dtype of a parameter of two axes or more, as ``name:
+    dtype[shape] {operand's layout} -> {result's layout}``: the compiler
+    re-laying a weight out once a program (PERF.md section 6, PR 57), or,
+    where the two orders of axes are one, moving it to another memory
+    (``S(1)``). The CPU cannot make this check: its layouts are not the
+    chip's."""
+    import jax
+
+    short = {"bfloat16": "bf16", "float32": "f32", "float16": "f16"}
+    shapes = {f"{short.get(a.dtype.name, a.dtype.name)}"
+              f"[{','.join(map(str, a.shape))}]"
+              for a in jax.tree.leaves(params) if len(a.shape) >= 2}
+    layout_of = {m.group(1): m.group(2) for m in re.finditer(
+        r"%?([\w.-]+) = \w+\[[\d,]*\](\{[^}]*\})", text)}
+    return [
+        f"{m.group(1)}: {m.group(2)} {layout_of.get(m.group(4), '{?}')} -> "
+        f"{m.group(3)}"
+        for m in re.finditer(
+            r"%?([\w.-]+) = (\w+\[[\d,]*\])(\{[^}]*\}) copy\(%?([\w.-]+)\)",
+            text)
+        if m.group(2) in shapes]
 
 
 def main(argv=None) -> int:
@@ -135,7 +167,8 @@ def main(argv=None) -> int:
     # instance carries them — building a real engine would materialise the
     # weights on the host.
     eng = object.__new__(TpuEngine)
-    eng.cfg, eng.pp_mesh, eng._prefill_fns = cfg, None, {}
+    eng.cfg, eng.mesh, eng.pp_mesh, eng._prefill_fns = cfg, None, None, {}
+    eng.device = topo.devices[0]
     # (Bound for the described chip, not this host's CPU.)
     eng.bound = bind(cfg.model_config, platform="tpu")
     eng.model = model = eng.bound.module
@@ -149,7 +182,22 @@ def main(argv=None) -> int:
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     params = on_chip(jax.eval_shape(
         lambda k: model.init_params(mcfg, k), jax.random.key(0)))
-    width = geom.max_blocks_per_seq
+    width = eng.max_blocks_per_seq = geom.max_blocks_per_seq
+    # The weights as the engine holds them: where the family leaves some
+    # stacked weights' layout to the decode program, in the formats its
+    # compile settles on (TpuEngine._param_formats).
+    t0 = time.monotonic()
+    formats = eng._param_formats(params)
+    if formats is not None:
+        print(json.dumps({
+            "weight_layouts_major_to_minor": {
+                name: list(formats["layers"][name].layout.major_to_minor)
+                for name in model.LAID_BY_DECODE},
+            "compile_s_on_this_host": round(time.monotonic() - t0, 1)}),
+            flush=True)
+        params = jax.tree.map(
+            lambda a, f: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=f),
+            params, formats)
     pages = sds(geom.shape, jnp.dtype(geom.dtype))
 
     def pool(rows, reads=False):
@@ -235,6 +283,10 @@ def main(argv=None) -> int:
             continue
         mem = compiled.memory_analysis()
         text = compiled.as_text()
+        if args.lowered_dir:
+            with open(os.path.join(args.lowered_dir,
+                                   name.replace(" ", "_") + ".hlo.txt"), "w") as f:
+                f.write(text)
         # A ``copy`` whose result has a page pool's shape is the compiler
         # re-laying a whole pool out (PERF.md section 7, PR 48).
         pool_copy = re.compile("|".join(
@@ -246,6 +298,7 @@ def main(argv=None) -> int:
             "compile_s_on_this_host": round(time.monotonic() - t0, 1),
             "tpu_custom_call": "tpu_custom_call" in text,
             "pool_copies": len(pool_copy.findall(text)),
+            "param_copies": _param_copies(text, params),
             "argument_bytes": mem.argument_size_in_bytes,
             "output_bytes": mem.output_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.experimental import topologies
-from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from llm_d_inference_scheduler_tpu.kvcache.pages import decode_attention
@@ -32,6 +31,9 @@ from llm_d_inference_scheduler_tpu.ops import pallas_moe
 from llm_d_inference_scheduler_tpu.ops.pallas_paged_attention import (
     paged_decode_attention_pallas,
     pages_per_stage,
+)
+from llm_d_inference_scheduler_tpu.utils.compile_cache import (
+    outside_compile_cache,
 )
 
 
@@ -45,12 +47,8 @@ def one_chip():
     # A compile for a described device is written to the persistent cache
     # but cannot be read back without the device (the next run would warn
     # and compile again): keep these out of it.
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
+    with outside_compile_cache():
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype):
@@ -126,6 +124,78 @@ def test_decode_step_copies_no_layers_page_pool(one_chip):
     assert not made, made
     assert (compiled.memory_analysis().temp_size_in_bytes
             < jnp.dtype(m.dtype).itemsize * math.prod(one_layer))
+
+
+def test_the_engine_holds_the_projections_as_its_decode_chunk_reads_them(
+        one_chip):
+    """Handed ``wq`` and ``wk`` as [L, D, H * Dh] the TPU compiler copies
+    both whole into another order of axes once a decode chunk, outside the
+    layer loop. A one-chip engine asks the decode program's compile which
+    layout it wants of the weights the family names (``LAID_BY_DECODE``) and
+    holds them so (TpuEngine._param_formats): built for the arrays as they
+    then lie, neither a decode bucket nor a prefill program holds a copy
+    with a stacked weight's shape. On any other device the weights stay as
+    they come."""
+    from llm_d_inference_scheduler_tpu.engine.config import EngineConfig
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+    from llm_d_inference_scheduler_tpu.kvcache import pages as kvpages
+    from llm_d_inference_scheduler_tpu.models import bind, configs
+
+    name = "qwen3-4b-two-layers"
+    configs._REGISTRY[name] = dataclasses.replace(QWEN3_4B, name=name,
+                                                  n_layers=2)
+    try:
+        cfg = EngineConfig(model=name, max_batch=16, max_model_len=2048,
+                           pallas_attention=True)
+        # (A bare instance, as scripts/aot_rehearsal.py makes one: the
+        # jitted bodies read these and nothing else.)
+        eng = object.__new__(TpuEngine)
+        eng.cfg, eng.mesh, eng.pp_mesh, eng._prefill_fns = cfg, None, None, {}
+        eng.device = next(iter(one_chip.device_set))
+        eng.bound = bind(cfg.model_config, platform="tpu")
+        eng.model, eng.mcfg = eng.bound.module, eng.bound.mcfg
+        eng.geom = kvpages.PageGeometry.for_engine(eng.mcfg, 16, 2048, 0)
+        eng.max_blocks_per_seq = width = eng.geom.max_blocks_per_seq
+        eng._decode_attention = kvpages.attention_for(
+            eng.geom, kernel=True, interpret=False)
+    finally:
+        del configs._REGISTRY[name]
+    sds = functools.partial(_sds, one_chip)
+    shapes = jax.eval_shape(
+        lambda k: llama.init_params(eng.mcfg, k), jax.random.key(0))
+    formats = eng._param_formats(shapes)
+    assert jax.tree.structure(formats) == jax.tree.structure(shapes)
+    assert all(sorted(formats["layers"][w].layout.major_to_minor) == [0, 1, 2]
+               for w in llama.LAID_BY_DECODE)
+    params = jax.tree.map(
+        lambda a, f: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=f),
+        shapes, formats)
+    stacked = {"bf16[" + ",".join(map(str, a.shape)) + "]"
+               for a in jax.tree.leaves(shapes["layers"]) if a.ndim == 3}
+    pool = sds(eng.geom.shape, jnp.dtype(eng.geom.dtype))
+    key = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                       jax.eval_shape(lambda: jax.random.key(0)))
+
+    def sampling(rows):
+        return (key, sds((rows,), jnp.float32), sds((rows,), jnp.int32),
+                sds((rows,), jnp.float32))
+
+    programs = {
+        "decode": (jax.jit(eng._decode_chunk_impl, donate_argnums=(3, 4)),
+                   (params, sds((8,), jnp.int32), sds((8,), jnp.int32), pool,
+                    pool, sds((8, width), jnp.int32), *sampling(8),
+                    sds((), jnp.int32))),
+        "prefill": (eng._prefill_fn(512),
+                    (params, sds((1, 512), jnp.int32), sds((1,), jnp.int32),
+                     pool, pool, sds((1, width), jnp.int32), *sampling(1)))}
+    for what, (fn, args) in programs.items():
+        copies = [ln.strip()[:120] for ln in
+                  fn.lower(*args).compile().as_text().splitlines()
+                  if (m := re.search(r"= (\w+\[[\d,]*\])\S* copy\(", ln))
+                  and m.group(1) in stacked]
+        assert not copies, (what, copies)
+    eng.device = jax.devices("cpu")[0]
+    assert eng._param_formats(shapes) is None
 
 
 def test_decode_step_names_its_blocks_for_the_device_trace(one_chip):

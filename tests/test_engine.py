@@ -7,6 +7,7 @@ import functools
 import json
 import signal
 import time
+import types
 
 import httpx
 import numpy as np
@@ -15,7 +16,8 @@ import pytest
 from llm_d_inference_scheduler_tpu.engine import EngineConfig, EngineRequest
 from llm_d_inference_scheduler_tpu.engine.core import (
     HOLD_MARGIN_S,
-    SHORT_CHUNK_DIV,
+    KEEP_UP_S,
+    SHORT_CHUNK_DIVS,
 )
 from llm_d_inference_scheduler_tpu.engine.server import EngineServer
 
@@ -1092,8 +1094,8 @@ def test_a_prompts_programs_are_dispatched_from_one_place_each():
 
 # ---- the next chunk is held back for an arrival ----------------------------
 
-_PREFILL_S, _CHUNK_S = 0.040, 0.100
-_SHORT = _CHUNK // SHORT_CHUNK_DIV
+_PREFILL_S, _CHUNK_S = 0.040, 0.200
+_SHORT, _HALF = (_CHUNK // SHORT_CHUNK_DIVS[n] for n in ("quarter", "half"))
 
 
 @contextlib.contextmanager
@@ -1113,7 +1115,7 @@ def _time_limit(seconds):
 
 class _Device:
     """A clock the test owns and a device with an in-order queue: a prefill
-    takes 40 ms and a chunk 25 ms a step (100 at its full length), each
+    takes 40 ms and a chunk 50 ms a step (200 at its full length), each
     after whatever was dispatched before it; reading an op's tokens moves
     the clock to that op's end; the host costs nothing. The sleep of a held
     chunk (TpuEngine._await_work) is the
@@ -1184,7 +1186,7 @@ class _Device:
         return [kind for kind, *_ in self.log]
 
 
-def _held(requests, *, script=None, at_step=None, hold=True, steps=80,
+def _held(requests, *, script=None, at_step=None, hold=True, steps=200,
           setup=None, **cfg):
     """Serve by hand on a _Device. ``at_step``: request id -> the step before
     which it is submitted. ``script``: hold number -> [(seconds into it, the
@@ -1282,6 +1284,12 @@ def _lengths(eng):
                           {"length": n}) for n in ("short", "full"))
 
 
+def _fractions(eng):
+    return tuple(_counter(eng, "jetstream:decode_chunk_fractions_total",
+                          {"fraction": n})
+                 for n in ("quarter", "half", "full"))
+
+
 def test_with_no_arrival_a_held_chunk_goes_out_at_the_deadline():
     """Every chunk from the first hold on is dispatched HOLD_MARGIN_S before
     the end of the chunk ahead of it, never later and (the periods being all
@@ -1342,7 +1350,7 @@ def _no_hold_idle():
     own first, find nothing in flight, though the shape is timed and every
     slot is free (so B's first chunk, held back by nothing, is short)."""
     return dict(requests=[_long(max_tokens=21), _long("B", max_tokens=5)],
-                at_step={"A": 0, "B": 13}), "inflight", range(10, 14)
+                at_step={"A": 0, "B": 16}), "inflight", range(12, 17)
 
 
 def _no_hold_untimed():
@@ -1361,17 +1369,44 @@ def _slow_booking(seconds):
     return setup
 
 
-def _full_for_the_host():
-    """Booking a chunk costs the host 40 ms: with the 20 ms of margin that is
-    more than a short chunk's 50, so every chunk is full; the holds are taken
-    as ever (60 ms are left of a full chunk's 100)."""
+def _quarter_for_the_host():
+    """Booking a chunk costs the host 35 ms: with the 10 ms it must have to
+    spare that fits a quarter's 50, so every chunk from the fourth on is a
+    quarter as long, as with a host that costs nothing; but 30 ms before a
+    quarter ends the loop is still booking, so none of them is held back
+    (the hold asks its own margin of the chunk: 20 ms)."""
     return dict(requests=[_long()], at_step={"A": 0},
-                also=_slow_booking(0.040)), "host", range(3, 24)
+                also=_slow_booking(0.035)), "host", range(3, 24)
+
+
+def _half_for_the_host():
+    """Booking a chunk costs the host 45 ms: with the 10 ms to spare that is
+    more than a quarter's 50 and fits a half's 100, so every chunk from the
+    fourth on is half as long and none is full; the holds are taken as ever
+    (35 ms are left of a half's 100 before its deadline)."""
+    return dict(requests=[_long()], at_step={"A": 0},
+                also=_slow_booking(0.045)), "host", range(3, 24)
+
+
+def _full_for_the_host():
+    """Booking a chunk costs the host 95 ms: with the 10 ms to spare that is
+    more than a half's 100, so every chunk is full; the holds are taken as
+    ever (85 ms are left of a full chunk's 200 before its deadline)."""
+    return dict(requests=[_long()], at_step={"A": 0},
+                also=_slow_booking(0.095)), "host", range(3, 24)
+
+
+for _case in (_no_hold_waits, _no_hold_busy, _no_hold_prefilling,
+              _no_hold_idle, _no_hold_untimed):
+    _case.length = _CHUNK
+_quarter_for_the_host.length = _SHORT
+_half_for_the_host.length, _full_for_the_host.length = _HALF, _CHUNK
 
 
 @pytest.mark.parametrize("case", [
     _no_hold_waits, _no_hold_busy, _no_hold_prefilling, _no_hold_idle,
-    _no_hold_untimed, _full_for_the_host], ids=lambda case: case.__name__[1:])
+    _no_hold_untimed, _quarter_for_the_host, _half_for_the_host,
+    _full_for_the_host], ids=lambda case: case.__name__[1:])
 def test_a_chunk_is_held_back_and_cut_short_only_where_an_arrival_fits(case):
     """In the steps named, the one thing named stands in the way: of the
     hold (the chunk goes out at once) and of the short length (it is full),
@@ -1380,7 +1415,9 @@ def test_a_chunk_is_held_back_and_cut_short_only_where_an_arrival_fits(case):
     chunk is held in exactly the steps in which nothing stands in the hold's
     way, and short in exactly those in which nothing stands in its length's:
     an arrival could be placed at once, the chunk's shape has been timed (at
-    either length), and the loop's work a chunk fits inside the short one."""
+    any length), and the loop's work a chunk fits inside a short one: the
+    shortest it fits in, a quarter, or a half where a quarter is too short
+    for it. Both counters say the same as the device's log."""
     plan, blocker, quiet = case()
     also = plan.pop("also", None)
     holds, lengths = {}, {}
@@ -1397,21 +1434,29 @@ def test_a_chunk_is_held_back_and_cut_short_only_where_an_arrival_fits(case):
 
         def hold_until(real=eng._hold_until):
             chunk = eng._inflight
+            reckoned = chunk and eng._chunk_time(chunk.shape, chunk.steps)
             state = dict(
                 inflight=chunk is not None, **room(eng),
                 timed=any(shape == chunk.shape for shape, _ in eng._chunk_times)
-                if chunk else bool(eng._chunk_times))
+                if chunk else bool(eng._chunk_times),
+                # (The deadline still lies ahead: the host is done in time.)
+                early=not reckoned or dev.now < max(
+                    chunk.t0, eng._last_readback, eng._first_tokens_read)
+                + reckoned - HOLD_MARGIN_S)
             until = real()
             holds.setdefault(step(), (state, until))
             return until
 
         def chunk_steps(shape, real=eng._chunk_steps):
+            # (The shortest length the host's work and the margin fit in.)
+            fits = next(n for n in (_SHORT, _HALF, _CHUNK) if n == _CHUNK
+                        or max(eng._host_work, default=0) + KEEP_UP_S
+                        <= _CHUNK_S * n / _CHUNK)
             state = dict(
                 **room(eng),
                 timed=any(sh == shape for sh, _ in eng._chunk_times),
-                host=max(eng._host_work, default=0) + HOLD_MARGIN_S
-                <= _CHUNK_S * _SHORT / _CHUNK)
-            lengths[step()] = (state, real(shape))
+                host=fits < _CHUNK)
+            lengths[step()] = (state, real(shape), fits)
             return lengths[step()][1]
 
         eng._hold_until, eng._chunk_steps = hold_until, chunk_steps
@@ -1422,29 +1467,95 @@ def test_a_chunk_is_held_back_and_cut_short_only_where_an_arrival_fits(case):
     assert set(why.values()) == {"length"}
     for step, (state, until) in holds.items():
         assert (until is not None) == all(state.values()), (step, state)
-    for step, (state, steps) in lengths.items():
-        assert steps == (_SHORT if all(state.values()) else _CHUNK), (
+    for step, (state, steps, fits) in lengths.items():
+        assert steps == (fits if all(state.values()) else _CHUNK), (
             step, state)
-    assert sorted(dev.lengths) == sorted(n for _, n in lengths.values())
-    assert _lengths(eng) == (dev.lengths.count(_SHORT),
+    assert sorted(dev.lengths) == sorted(n for _, n, _ in lengths.values())
+    assert _lengths(eng) == (len(dev.lengths) - dev.lengths.count(_CHUNK),
                              dev.lengths.count(_CHUNK))
+    assert _fractions(eng) == tuple(
+        dev.lengths.count(n) for n in (_SHORT, _HALF, _CHUNK))
     for step in quiet:
-        for state, _ in filter(None, (holds.get(step), lengths.get(step))):
+        for state, *_ in filter(None, (holds.get(step), lengths.get(step))):
             if blocker in state:    # (the blocker of one of the two alone)
-                assert [k for k, ok in state.items()
-                        if not ok] == [blocker], (step, state)
+                # (A host that fits a short length stands in nothing's way.)
+                assert [k for k, ok in state.items() if not ok] == (
+                    [blocker] if case.length == _CHUNK else []), (step, state)
     if blocker == "host":
-        assert set(dev.lengths) == {_CHUNK}
-        assert all(holds[step][1] is not None for step in quiet)
+        # (Three full chunks until the shape is timed and the host measured.)
+        assert set(dev.lengths[3:]) == {case.length}
+        # A chunk is held back wherever the host is done HOLD_MARGIN_S
+        # before the one in flight ends, whatever the length it then gets:
+        # not at a quarter whose 50 ms the host's 35 nearly fill.
+        # (The first quarter is in flight from the quiet steps' third on.)
+        assert all((holds[step][1] is not None)
+                   == (case is not _quarter_for_the_host)
+                   == holds[step][0]["early"] for step in quiet[2:])
     elif blocker == "inflight":
         assert lengths[quiet[-1]][1] == _SHORT      # B's first chunk
     else:
         assert holds[quiet[0] - 1][1] is not None or blocker == "timed"
         assert all(lengths[step][1] == _CHUNK for step in quiet)
     assert any(until is not None for step, (_, until) in holds.items()
-               if step > quiet[-1])
-    assert _SHORT in [n for step, (_, n) in lengths.items()
+               if step > quiet[-1]) or case is _quarter_for_the_host
+    assert _SHORT in [n for step, (_, n, _) in lengths.items()
                       if step > quiet[-1]] or blocker == "host"
+
+
+def _stands_queue(eng):
+    eng._waiting.append((_long("Q"), None, None))
+
+
+def _stands_windows(eng):
+    eng.slots[1] = types.SimpleNamespace(prefilling=True)
+
+
+def _stands_pp(eng):
+    eng.pp_mesh = object()
+
+
+def _stands_untimed(eng):
+    eng._chunk_times.clear()
+
+
+def _stands_unmeasured(eng):
+    eng._host_work.clear()
+
+
+def _quarter_timed(eng):
+    """The quarter has a period of its own, shorter than pro rata."""
+    eng._chunk_times[("4x8", _SHORT)] = collections.deque([0.045])
+
+
+@pytest.mark.parametrize("host, also, steps", [
+    (0.0, None, _SHORT), (0.040, None, _SHORT), (0.041, None, _HALF),
+    (0.090, None, _HALF), (0.091, None, _CHUNK), (0.500, None, _CHUNK),
+    (0.030, _quarter_timed, _SHORT), (0.040, _quarter_timed, _HALF),
+    (0.0, _stands_queue, _CHUNK), (0.0, _stands_windows, _CHUNK),
+    (0.0, _stands_pp, _CHUNK), (0.0, _stands_untimed, _CHUNK),
+    (0.0, _stands_unmeasured, _CHUNK)],
+    ids=lambda v: getattr(v, "__name__", str(v)).lstrip("_"))
+def test_a_short_chunk_is_the_shortest_the_hosts_work_fits_in(host, also,
+                                                             steps):
+    """The shape's full chunk was timed at 200 ms, so a quarter is reckoned
+    at 50 and a half at 100 until either has a period of its own: the chunk
+    is the shortest of quarter, half and full that the host's mean work a
+    period plus KEEP_UP_S fits in, a host too slow for a quarter falls to
+    a half and not to a whole chunk, and with a queue, windows being
+    written, a pipeline's program, a shape never timed or a loop never
+    measured it is full whatever the host costs."""
+    from llm_d_inference_scheduler_tpu.engine.core import TpuEngine
+
+    eng = TpuEngine(EngineConfig(
+        model="tiny", backend="tpu", max_model_len=128, decode_chunk=_CHUNK,
+        seed=11, kv_events_port=0, max_batch=4), params=_tiny_f32())
+    eng._chunk_times[("4x8", _CHUNK)] = collections.deque([_CHUNK_S, 0.3])
+    eng._host_work.extend([host - 0.01, host + 0.01, host])
+    if also is not None:
+        also(eng)
+    assert eng._chunk_steps("4x8") == steps
+    # Another shape's periods say nothing of this one's.
+    assert eng._chunk_steps("2x8") == _CHUNK
 
 
 def test_a_request_is_served_whole_through_short_and_full_chunks():

@@ -474,8 +474,8 @@ def test_pairs_per_row_come_from_the_bound_value():
 
 # ---------- what a family keeps, in words ----------
 
-# /health's settings as PR 43 served them (chipbench/ reads them), and
-# PR 47's ``expanded_attention``.
+# /health's settings as PR 43 served them (chipbench/ reads them), PR 47's
+# ``expanded_attention`` and PR 57's ``weight_layouts``.
 SETTINGS_KEYS = {
     "model", "n_layers", "dtype", "max_batch", "max_model_len", "kv_blocks",
     "kv_layers", "kv_token_bytes", "kv_pool_bytes", "kv_run_pages",
@@ -484,7 +484,7 @@ SETTINGS_KEYS = {
     "experts_chosen_max_rows", "state_slot_bytes",
     "state_pool_bytes", "state_update", "prefix_caching",
     "off_for_state_layers", "decode_chunk", "pallas_attention", "kv_wire",
-    "kv_wire_error", "compile_cache_dir"}
+    "kv_wire_error", "compile_cache_dir", "weight_layouts"}
 
 
 @pytest.mark.parametrize("name, one_chip, want", [
@@ -539,6 +539,7 @@ def test_an_engines_health_has_the_parents_settings_keys_exactly():
         settings = eng.describe()["settings"]
         assert set(settings) == SETTINGS_KEYS
         assert settings["prefix_caching"] is (name != "tiny-hybrid")
+        assert settings["weight_layouts"] == {}     # (not on a CPU)
 
 
 def test_the_cache_says_how_it_is_allocated_and_attended():
